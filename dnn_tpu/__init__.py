@@ -16,31 +16,6 @@ stages as jit-compiled JAX programs on TPU chips, maps the config's
 activations with `jax.lax.ppermute` (XLA CollectivePermute) over ICI.
 """
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # this codebase targets the modern `jax.shard_map(..., check_vma=)`
-    # API; on older jax (<= 0.4.x) the function lives in
-    # jax.experimental.shard_map with the kwarg named check_rep.
-    # Install a translating alias ONCE at package import so every
-    # runtime/parallel module runs unmodified on either version.
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def _compat_shard_map(f, *, mesh, in_specs, out_specs,
-                          check_vma=None, **kw):
-        if check_vma is not None:
-            kw.setdefault("check_rep", check_vma)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-
-    _jax.shard_map = _compat_shard_map
-
-if not hasattr(_jax.lax, "axis_size"):
-    # same vintage gap: modern code calls lax.axis_size(name) for the
-    # mapped-axis size; on older jax psum of the constant 1 folds to the
-    # same Python int inside shard_map tracing.
-    _jax.lax.axis_size = lambda axis_name: _jax.lax.psum(1, axis_name)
-
 from dnn_tpu.version import __version__
 from dnn_tpu.registry import get_model, register_model, available_models
 from dnn_tpu.config import TopologyConfig

@@ -10,8 +10,10 @@ family is accepted.
 Extended keys (all optional, with reference-equivalent defaults):
   model:           model-zoo name (default "cifar_cnn", the reference's only
                    wired family — node.py:11,29-32)
-  device_type:     "tpu" | "cpu" (BASELINE.json north-star `device_type=tpu`
-                   dispatch)
+  device_type:     "tpu" | "cpu" — the platform the engine must run on
+                   (BASELINE.json north-star `device_type=tpu` dispatch);
+                   a named platform JAX cannot find is an error. Absent =
+                   JAX's default backend
   runtime:         "spmd" (shard_map+ppermute pipeline) | "relay"
                    (device-per-stage sequential relay, the reference's
                    semantics) | "auto"
@@ -72,7 +74,7 @@ class TopologyConfig:
     model_weights: Optional[str] = None
     return_to_node_id: Optional[str] = None
     model: str = "cifar_cnn"
-    device_type: str = "tpu"
+    device_type: Optional[str] = None  # None = JAX's default backend
     runtime: str = "auto"
     microbatches: int = 0  # 0 = auto (see engine._effective_microbatches)
     # spmd-runtime weight placement: "stage" (packed, each device holds only
@@ -108,7 +110,7 @@ class TopologyConfig:
             model_weights=d.get("model_weights"),
             return_to_node_id=d.get("return_to_node_id"),
             model=d.get("model", "cifar_cnn"),
-            device_type=d.get("device_type", "tpu"),
+            device_type=d.get("device_type"),
             runtime=d.get("runtime", "auto"),
             microbatches=int(d.get("microbatches", 0)),
             param_placement=d.get("param_placement", "auto"),
@@ -128,6 +130,9 @@ class TopologyConfig:
     def validate(self):
         if self.num_parts < 1:
             raise ValueError(f"num_parts must be >= 1, got {self.num_parts}")
+        if self.device_type not in (None, "tpu", "cpu"):
+            raise ValueError(
+                f"device_type must be 'tpu' or 'cpu', got {self.device_type!r}")
         if self.nodes:
             part_indices = sorted(n.part_index for n in self.nodes)
             if part_indices != list(range(self.num_parts)):

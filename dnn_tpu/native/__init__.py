@@ -3,7 +3,9 @@
 The reference is 100% Python (SURVEY §2 — no native layer exists to port),
 but a full framework wants its host-side hot paths native. This package
 compiles `codec.cpp` with the system g++ the first time it's imported
-(cached as a .so next to the source, keyed by source mtime) and binds it
+(cached as a .so next to the source, keyed by a hash of the source text
+and the build flags — a binary built from other source can never load)
+and binds it
 via ctypes — no pybind11 required. Every entry point has a pure-Python
 fallback producing bit-identical results, so the framework degrades
 gracefully on hosts without a toolchain.
@@ -18,6 +20,7 @@ API:
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import subprocess
@@ -43,9 +46,14 @@ def _build_src(src: str, stem: str, extra_flags=()) -> Optional[str]:
     raise."""
     tmp = None
     try:
-        # key the cache on source mtime so edits rebuild automatically
+        # key the cache on the source TEXT (and the flags it is built
+        # with): an mtime says nothing in a fresh checkout or a copied
+        # tree, where a stale binary of the same name could load
         src_dir = os.path.dirname(src)
-        tag = int(os.stat(src).st_mtime)
+        flags = ["-O3", "-shared", "-fPIC", "-std=c++17", *extra_flags]
+        with open(src, "rb") as f:
+            tag = hashlib.sha256(
+                " ".join(flags).encode() + b"\0" + f.read()).hexdigest()[:16]
         so = os.path.join(src_dir, f"_{stem}_{tag}.so")
         if os.path.exists(so):
             return so
@@ -58,8 +66,7 @@ def _build_src(src: str, stem: str, extra_flags=()) -> Optional[str]:
                     pass
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=src_dir)
         os.close(fd)
-        cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-               *extra_flags, src, "-o", tmp]
+        cmd = ["g++", *flags, src, "-o", tmp]
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
         os.replace(tmp, so)
         return so

@@ -112,7 +112,9 @@ def build_parser() -> argparse.ArgumentParser:
                    help="--serve_lm: HF-style repetition penalty over each "
                         "request's tokens (per-request r= overrides)")
     p.add_argument("--seed", type=int, default=0,
-                   help="Sampling rng seed for --generate")
+                   help="rng seed: sampling (--generate / --serve_lm), "
+                        "and the random weights when the config names "
+                        "no checkpoint")
     p.add_argument("--beam", type=int, default=None, metavar="K",
                    help="--generate: deterministic beam search with K beams "
                         "instead of sampling (dense GPT family; "
@@ -368,8 +370,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "too (dnn_tpu/chaos/supervisor.py)")
     p.add_argument("--watchdog_s", type=float, default=None, metavar="S",
                    help="--serve_lm: run the hung-device watchdog with "
-                        "this probe period in seconds (subprocess-bounded "
-                        "device probe + decode heartbeat; /healthz "
+                        "this probe period in seconds (deadline-bounded "
+                        "in-process device probe + decode heartbeat; /healthz "
                         "degrades ok|degraded|wedged and /statusz carries "
                         "detail — dnn_tpu/obs/watchdog.py). Off unless "
                         "given")
@@ -446,6 +448,8 @@ def main(argv=None) -> int:
     setup_logging(args.log_level, node_id=args.node_id)
 
     if args.supervise:
+        # the supervising parent stays off the device: it initializes no
+        # JAX backend, so the child it spawns can own the chip
         if not (args.serve or args.serve_lm):
             log.error("--supervise applies to the serving modes "
                       "(--serve / --serve_lm)")
@@ -490,14 +494,20 @@ def main(argv=None) -> int:
         return _route(args, config, me)
 
     if config.device_type == "cpu":
-        # Platform choice must land before first backend use; on hosts where
-        # a TPU plugin wins selection regardless of JAX_PLATFORMS (see
-        # tests/conftest.py), the in-process config update is the only
-        # override that sticks. The update never raises — whether it took
-        # effect is verified after the backend initializes, below.
+        # the config names the platform for the whole process (default
+        # device included, not just the engine's device list). By now
+        # `import dnn_tpu` has imported jax, so putting JAX_PLATFORMS
+        # into os.environ here would come too late; the config option
+        # is read when the backend first initializes, which no code on
+        # this path has done yet. (JAX_PLATFORMS set by the caller's
+        # shell is honoured without any of this.)
         import jax
 
         jax.config.update("jax_platforms", "cpu")
+
+    from dnn_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # before the first compile
 
     if config.distributed is not None:
         # multi-host: join the jax.distributed job before any backend use so
@@ -519,7 +529,7 @@ def main(argv=None) -> int:
     _BOOT["compile_at_engine0"] = _compile_total_s()
     try:
         engine = PipelineEngine(config, role="stage" if args.serve else "full",
-                                lora_path=args.lora)
+                                lora_path=args.lora, rng_seed=args.seed)
     except Exception as e:  # noqa: BLE001 — CLI boundary: checkpoint loads
         # raise FileNotFoundError/unpickling errors etc.; exit with a clean
         # one-liner like the reference does for every config problem
@@ -533,17 +543,6 @@ def main(argv=None) -> int:
         "node=%s part=%d/%d runtime=%s model=%s",
         me.id, me.part_index, config.num_parts - 1, engine.runtime, config.model,
     )
-    if config.device_type == "cpu":
-        import jax
-
-        if jax.default_backend() != "cpu":
-            # config update above came too late (backend was already up)
-            log.warning(
-                "device_type=cpu requested but backend is '%s' — the JAX "
-                "backend was initialized before this CLI ran",
-                jax.default_backend(),
-            )
-
     if args.generate is None and (args.beam is not None
                                   or args.eos_id is not None
                                   or args.length_penalty != 0.0):

@@ -1,63 +1,77 @@
 """Hung-device watchdog: bounded liveness probes + heartbeat staleness.
 
-The failure this machine keeps demonstrating (BENCH_r05, VERDICT round
-5): a wedged TPU hangs `jax.devices()` — or the first device op — for
-90+ seconds, IN PROCESS, where nothing can catch it. A server on a
-wedged chip doesn't crash; it just stops, and /healthz (which only
-checked thread liveness) kept saying "ok". This module is the
+The failure this guards against: a wedged accelerator hangs the first
+device op — or a later one — IN PROCESS, where nothing can catch it. A
+server on a wedged chip doesn't crash; it just stops, and a /healthz that
+only checked thread liveness would keep saying "ok". This module is the
 detector:
 
-  * a daemon thread runs a DEVICE PROBE once per period, in a
-    SUBPROCESS with a hard deadline (`subprocess_device_probe`) — a
-    wedged chip hangs the probe child, never the server. Custom probe
-    callables (tests stub a hanging one) are additionally bounded by a
-    probe thread joined with the deadline, so even an in-process hang
-    costs one leaked daemon thread, not the watchdog;
+  * a daemon thread runs a DEVICE PROBE once per period. Every probe
+    callable is bounded by a probe thread joined at the deadline, so a
+    hang costs one leaked daemon thread, not the watchdog. Which probe
+    depends on who owns the device — a chip belongs to ONE process:
+      - a process that HOLDS the device (the LM daemon) probes it
+        in-process (`in_process_device_probe`): a child could not open
+        the chip its parent holds, and would read a healthy server as
+        degraded or wedged;
+      - a process that holds NO device (bench.py before it measures, the
+        supervisor's `recover_backend`) probes in a SUBPROCESS with a
+        hard deadline (`subprocess_device_probe`) — a wedged chip hangs
+        the child, never the caller;
   * a DECODE HEARTBEAT: the LM batcher worker calls `beat()` every loop
     iteration; a heartbeat older than `heartbeat_stale_s` while the
     thread is supposedly alive means a step wedged inside the device
-    runtime — the in-process hang the probe subprocess cannot see;
+    runtime;
   * state is the worst component: `ok` -> `degraded` (probe errored
     fast — backend unhealthy but not hung) -> `wedged` (probe deadline
     exceeded, or heartbeat stale). Transitions land in the flight
     recorder (obs/flight.py) and the `dnn_tpu_watchdog_state` gauge
     (0/1/2); `GET /statusz` serves the full per-component detail and
     /healthz degrades from binary to ok|degraded|wedged (obs/http.py).
-
-`bench.py`'s backend probe reuses `subprocess_device_probe` — the
-round-robin bench and the serving watchdog share one definition of
-"the chip answered".
 """
 
 from __future__ import annotations
 
+import os
 import subprocess
 import sys
 import threading
 import time
 from typing import Callable, Optional, Tuple
 
-__all__ = ["Watchdog", "subprocess_device_probe", "STATE_VALUES"]
+__all__ = ["Watchdog", "subprocess_device_probe",
+           "in_process_device_probe", "STATE_VALUES"]
 
 STATE_VALUES = {"ok": 0.0, "degraded": 1.0, "wedged": 2.0}
 
-_PROBE_CODE = ("import jax, jax.numpy as jnp; {pin}"
+_PROBE_CODE = ("import jax, jax.numpy as jnp; "
                "x = jnp.ones((128,128)) @ jnp.ones((128,128)); "
                "x.block_until_ready(); print(jax.default_backend())")
-# in-process config, NOT a JAX_PLATFORMS env var: an out-of-tree device
-# plugin can win platform selection over the env var, and the whole
-# point of pinning is that a cpu-substrate server's probe must not
-# touch (or queue behind) a device it doesn't serve on
-_PIN_CODE = "jax.config.update('jax_platforms', {platform!r}); "
+
+
+def in_process_device_probe(deadline_s: float = 10.0) -> Tuple[bool, str]:
+    """One probe from the process that HOLDS the device: a tiny matmul on
+    the default backend, queued behind whatever the server has in flight
+    (the device stream is in-order, so it also shows the queue drains).
+    `deadline_s` is enforced by the caller — `Watchdog` joins the probe
+    thread at the deadline, so a hang here reads as wedged; an exception
+    reads as degraded."""
+    import jax
+    import jax.numpy as jnp
+
+    x = jnp.ones((128, 128), jnp.float32)
+    (x @ x).block_until_ready()
+    return True, f"ok ({jax.default_backend()}, in-process)"
 
 
 def subprocess_device_probe(deadline_s: float = 10.0,
                             platform: Optional[str] = None,
                             ) -> Tuple[bool, str, bool]:
-    """One bounded probe: a tiny matmul in a child process, on
-    `platform` if given (the backend the CALLER serves on — a probe
-    that queues behind a device the server never uses answers the
-    wrong liveness question), else the default backend. Returns
+    """One bounded probe for a caller that holds NO device: a tiny matmul
+    in a child process, on `platform` if given (JAX_PLATFORMS in the
+    child's environment), else the default backend. Never use it from a
+    process that has initialized an accelerator backend — the child
+    cannot open a chip its parent holds. Returns
     (ok, detail, timed_out) — `timed_out` is the STRUCTURED hung-vs-
     failed distinction the watchdog classifies on (wedged vs degraded);
     the free-text detail is for humans only.
@@ -70,9 +84,9 @@ def subprocess_device_probe(deadline_s: float = 10.0,
     The deadline clock covers the child's whole lifetime, `import jax`
     included (~4 s cold on a quiet 2-core host) — deadlines below ~6 s
     read a HEALTHY backend as wedged."""
-    pin = _PIN_CODE.format(platform=platform) if platform else ""
+    env = dict(os.environ, JAX_PLATFORMS=platform) if platform else None
     proc = subprocess.Popen(
-        [sys.executable, "-c", _PROBE_CODE.format(pin=pin)],
+        [sys.executable, "-c", _PROBE_CODE], env=env,
         stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL)
     try:
         rc = proc.wait(timeout=deadline_s)
@@ -88,7 +102,9 @@ class Watchdog:
 
     device_probe: callable(deadline_s) -> (ok, detail) or (ok, detail,
     timed_out), or None to disable the device leg (CPU-only test
-    servers). The default is `subprocess_device_probe`. Hung-vs-failed
+    servers). The default is `subprocess_device_probe`, for callers
+    that hold no device; a process that holds one passes
+    `in_process_device_probe` (module docstring). Hung-vs-failed
     is decided STRUCTURALLY, never by sniffing the detail text: wedged
     when the probe reports timed_out=True, or when the call itself
     outlives its deadline (even if it eventually returns); a fast
@@ -173,7 +189,7 @@ class Watchdog:
         worker calls this after every successful step). Until the first
         one, a stale heartbeat reads `degraded`, not `wedged`: the first
         step's XLA compile on a cold chip legitimately blocks the loop
-        for minutes (bench.py allows 300 s for exactly this), and a 503
+        for minutes, and a 503
         there makes an orchestrator evict a healthy warming server —
         potentially forever, since each restart re-compiles."""
         self._warmed = True

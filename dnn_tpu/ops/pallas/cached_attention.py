@@ -40,9 +40,6 @@ import jax.numpy as jnp
 _NEG_BIG = -1e30
 
 
-from dnn_tpu.ops.pallas._compat import _compiler_params  # noqa: E402
-
-
 # ----------------------------------------------------------------------
 # reference (fallback + test oracle) — the kvcache.py einsum math
 # ----------------------------------------------------------------------
@@ -190,8 +187,8 @@ def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((bh, t, d), jnp.float32),
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(pos1d, *args)
@@ -405,8 +402,8 @@ def _decode_call(q, k, v, pos1d, ks, vs, *, block_s, interpret):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, r, d), jnp.float32),
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(pos1d, *args)
@@ -584,8 +581,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, r, d), jnp.float32),
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32), *args)

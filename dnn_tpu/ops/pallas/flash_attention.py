@@ -25,9 +25,6 @@ import jax.numpy as jnp
 _NEG_BIG = -1e30  # finite "minus infinity": keeps exp()/max() NaN-free
 
 
-from dnn_tpu.ops.pallas._compat import _compiler_params  # noqa: E402
-
-
 # ----------------------------------------------------------------------
 # reference path (also the off-TPU fallback and the test oracle)
 # ----------------------------------------------------------------------
@@ -256,8 +253,8 @@ def _call_fwd(q3, k3, v3, *, causal, block_q, block_k, interpret, with_lse):
             pltpu.VMEM((block_q, 128), jnp.float32),  # running row sum
             pltpu.VMEM((block_q, d), jnp.float32),  # output accumulator
         ],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(q3, k3, v3)
@@ -330,8 +327,8 @@ def _flash_tpu_bwd(causal, block_q, block_k, interpret, residuals, do):
         out_specs=_qspec(block_q, d),
         out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(q3, k3, v3, do3, lse, di)
@@ -354,8 +351,8 @@ def _flash_tpu_bwd(causal, block_q, block_k, interpret, residuals, do):
                    jax.ShapeDtypeStruct((bh, s_len, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=_compiler_params(
-            pltpu, dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
     )(q3, k3, v3, do3, lse, di)

@@ -796,8 +796,8 @@ class LMServer:
     /healthz, POST /profilez (on-demand jax.profiler capture, ?auto=1
     arms capture-the-next-slow-step). `watchdog` (None/False = off;
     True or a period in seconds, or a prebuilt obs.watchdog.Watchdog)
-    runs the hung-device watchdog: subprocess-bounded device probes plus
-    this worker's loop heartbeat decide ok|degraded|wedged."""
+    runs the hung-device watchdog: deadline-bounded in-process device
+    probes plus this worker's loop heartbeat decide ok|degraded|wedged."""
 
     def __init__(self, cfg, prepared, *, default_max_new: int = 32,
                  request_timeout: float = 120.0, tokenizer=None,
@@ -967,30 +967,22 @@ class LMServer:
             # Watchdog (tests inject stubbed probes). Wired to the
             # worker's loop heartbeat + thread liveness, started here —
             # after _init_rest, so the worker exists to monitor.
-            from dnn_tpu.obs.watchdog import Watchdog
+            from dnn_tpu.obs.watchdog import Watchdog, in_process_device_probe
 
             if isinstance(watchdog, Watchdog):
                 self._watchdog = watchdog
             else:
-                import functools
-
-                import jax
-
-                from dnn_tpu.obs.watchdog import subprocess_device_probe
 
                 period = 30.0 if watchdog is True else float(watchdog)
                 self._watchdog = Watchdog(
                     period_s=period,
-                    # floor 6 s: the probe child pays ~4 s of import
-                    # before its first device op — a shorter deadline
-                    # reads a healthy backend as wedged
                     probe_deadline_s=min(10.0, max(6.0, period / 3)),
-                    # pin the probe to THIS server's backend: a
-                    # cpu-substrate daemon must not answer "is the TPU
-                    # alive" (nor queue behind a chip it never uses)
-                    device_probe=functools.partial(
-                        subprocess_device_probe,
-                        platform=jax.default_backend()))
+                    # this process HOLDS the device it serves on, and a
+                    # chip belongs to one process: a probe child could
+                    # not open it and would read a healthy server as
+                    # degraded or wedged. Probe in-process; the Watchdog
+                    # bounds the call by a thread joined at the deadline
+                    device_probe=in_process_device_probe)
             if self._watchdog.alive_check is None:
                 # a LAMBDA over self.worker, not a bound method: the
                 # worker-death requeue path swaps in a successor worker,

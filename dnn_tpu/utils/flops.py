@@ -38,10 +38,23 @@ _TPU_PEAK_BF16 = (
 )
 
 
+def _tpu_peak(device: jax.Device, table, what: str) -> float:
+    """Look `device.device_kind` up in a peak table. A TPU the table does
+    not know is an ERROR, not None: a utilization against no peak — or a
+    guessed one — must never reach a record of a chip run."""
+    kind = device.device_kind.lower()
+    for sub, peak in table:
+        if sub in kind:
+            return peak
+    raise ValueError(
+        f"no {what} peak for TPU device_kind {device.device_kind!r}; add "
+        f"it (with its source) to the table in dnn_tpu/utils/flops.py")
+
+
 def device_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
-    """bf16 peak FLOPs/s of `device` (default: the first default device), or
-    None when unknown (CPU hosts, unrecognized accelerators) — callers omit
-    the mfu field rather than publish a made-up one. DNN_TPU_PEAK_FLOPS
+    """bf16 peak FLOPs/s of `device` (default: the first default device).
+    None off-TPU (CPU hosts have no peak worth a utilization — callers
+    omit the mfu field); an unrecognized TPU raises. DNN_TPU_PEAK_FLOPS
     overrides the table (the opt-in roofline for CPU hosts and
     accelerators the table doesn't know; utilization numbers against an
     operator-stated peak beat no numbers at all)."""
@@ -54,11 +67,7 @@ def device_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
         device = jax.devices()[0]
     if device.platform != "tpu":
         return None
-    kind = device.device_kind.lower()
-    for sub, peak in _TPU_PEAK_BF16:
-        if sub in kind:
-            return peak
-    return None
+    return _tpu_peak(device, _TPU_PEAK_BF16, "bf16 FLOP/s")
 
 
 def _env_peak(raw) -> Optional[float]:
@@ -347,8 +356,9 @@ _TPU_PEAK_HBM = (
 
 
 def device_peak_hbm_bw(device: Optional[jax.Device] = None) -> Optional[float]:
-    """HBM peak bytes/s of `device`, or None when unknown (CPU hosts).
-    DNN_TPU_PEAK_HBM_BW overrides, like DNN_TPU_PEAK_FLOPS above."""
+    """HBM peak bytes/s of `device`: None off-TPU, an error for a TPU the
+    table does not know. DNN_TPU_PEAK_HBM_BW overrides, like
+    DNN_TPU_PEAK_FLOPS above."""
     import os
 
     env = _env_peak(os.environ.get("DNN_TPU_PEAK_HBM_BW"))
@@ -358,11 +368,7 @@ def device_peak_hbm_bw(device: Optional[jax.Device] = None) -> Optional[float]:
         device = jax.devices()[0]
     if device.platform != "tpu":
         return None
-    kind = device.device_kind.lower()
-    for sub, bw in _TPU_PEAK_HBM:
-        if sub in kind:
-            return bw
-    return None
+    return _tpu_peak(device, _TPU_PEAK_HBM, "HBM bytes/s")
 
 
 def mbu(bytes_per_item: float, items_per_sec: float,

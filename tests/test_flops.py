@@ -85,7 +85,11 @@ def test_peak_table_matching():
     assert flops.device_peak_flops(FakeDev("TPU v5 lite")) == 197e12
     assert flops.device_peak_flops(FakeDev("TPU v4")) == 275e12
     assert flops.device_peak_flops(FakeDev("TPU v5p")) == 459e12
-    assert flops.device_peak_flops(FakeDev("TPU weird-future")) is None
+    # a TPU the tables do not know is an error, never a silent None
+    for lookup in (flops.device_peak_flops, flops.device_peak_hbm_bw):
+        with pytest.raises(ValueError, match="weird-future"):
+            lookup(FakeDev("TPU weird-future"))
+    assert flops.device_peak_hbm_bw(FakeDev("TPU v5 lite")) == 819e9
     # mfu math: 100 items/s at 1e12 FLOPs/item on a 197e12 chip
     assert flops.mfu(1e12, 100.0, FakeDev("TPU v5e")) == pytest.approx(
         100e12 / 197e12

@@ -310,20 +310,30 @@ def test_incident_bundle_ok_report_snapshot(tmp_path):
 # ledger: the real checked-in artifacts parse
 # ----------------------------------------------------------------------
 
-def test_ledger_parses_real_bench_rounds():
+def test_ledger_parses_real_bench_rounds(tmp_path):
+    import shutil
+
     from benchmarks.ledger import bench_rounds
 
     rounds = bench_rounds(REPO)
     nums = [e["round"] for e in rounds]
-    assert nums == sorted(nums) and len(nums) >= 5
-    r1 = next(e for e in rounds if e["round"] == 1)
-    assert isinstance(r1["value"], (int, float))
-    assert r1["vs_baseline"] > 1.0  # the committed on-chip round
-    # r02 crashed before printing a row: present, honestly marked
-    r2 = next(e for e in rounds if e["round"] == 2)
-    assert r2["metric"] is None and "no row" in r2["substrate"]
+    assert nums == sorted(nums) and len(nums) >= 3
+    r3 = next(e for e in rounds if e["round"] == 3)
+    assert isinstance(r3["value"], (int, float))
+    assert r3["substrate"] == "cpu"
     r5 = next(e for e in rounds if e["round"] == 5)
     assert r5["substrate"] == "cpu" and r5["stale_tpu_reference"]
+    # a round that crashed before printing a row (the driver's shape for
+    # it: rc != 0, no parseable line in `tail`) stays in the trajectory,
+    # honestly marked — beside a real committed round
+    shutil.copy(os.path.join(REPO, "BENCH_r05.json"), tmp_path)
+    (tmp_path / "BENCH_r06.json").write_text(json.dumps(
+        {"n": 6, "cmd": "python bench.py", "rc": 1,
+         "tail": "RuntimeError: backend failed to initialise\n",
+         "parsed": None}))
+    r5b, r6 = bench_rounds(str(tmp_path))
+    assert r5b == r5
+    assert r6["metric"] is None and r6["substrate"] == "no row (rc=1)"
 
 
 def test_ledger_run_rows_parse_results_md():
@@ -369,7 +379,7 @@ def test_ledger_cli_runs_green_on_checked_in_artifacts():
         capture_output=True, text=True, cwd=REPO, timeout=120)
     assert proc.returncode == 0, proc.stderr
     assert "Perf trajectory" in proc.stdout
-    assert "| r01 " in proc.stdout
+    assert "| r03 " in proc.stdout
 
 
 def test_run_all_scenarios_filter_rejects_unknown():
