@@ -75,17 +75,17 @@ kvlens,trainlens,caplens} ...` — obs tooling.
     python -m dnn_tpu.obs timeline --url http://host:port
         Fetch a running server's /stepz and print the per-phase
         decode-step decomposition (admit/host/dispatch/wait/commit/obs
-        with fractions, dispatch-slack, sync-tax, host fraction).
-        --out steps.json additionally writes the last N steps as a
-        Perfetto-loadable host track (?format=trace).
+        with fractions, dispatch-slack, sync-tax, host fraction, the
+        admit phase by part). The steps on a timeline are in a POST
+        /profilez capture (step.* / admit* annotations).
 
     python -m dnn_tpu.obs timeline PATH
         Analyze one device capture (a POST /profilez capture dir, or a
         *.trace.json[.gz] file) with obs/timeline.analyze: per-track
         busy fractions, device busy/idle, the host-gap histogram
-        between consecutive device ops, top-K ops by device time, and
-        — when the capture's sidecar meta.json is present — its
-        position on the step axis. --json for the raw dict.
+        between consecutive device ops, top-K ops by device time
+        (inside the armed window, when the capture's sidecar meta.json
+        is present). --json for the raw dict.
 
     python -m dnn_tpu.obs timeline --selftest
         In-process smoke: a deterministic StepClock (injected clock)
@@ -377,8 +377,8 @@ def _fleet_selftest() -> int:
 def _timeline_selftest() -> int:
     """Deterministic StepClock (injected clock) + a synthetic gzipped
     Perfetto capture with a sidecar meta, checked end to end: phase
-    arithmetic, derived series, chrome export, prom render, registry
-    histograms, capture analysis, step alignment, garbage rejection."""
+    arithmetic, derived series, the admit split, prom render, registry
+    histograms and exact totals, capture analysis, garbage rejection."""
     import gzip
     import os
     import tempfile
@@ -410,13 +410,12 @@ def _timeline_selftest() -> int:
     assert abs(s["dispatch_slack"] - 3.5 / 6.0) < 1e-3, s
     assert abs(s["sync_tax"] - 4.0 / 9.5) < 1e-3, s
     assert s["tokens"] == 12, s
-    ct = clk.chrome_trace()
-    xs = [e for e in ct["traceEvents"] if e.get("ph") == "X"]
-    assert len(xs) == 3 * 6, len(xs)  # 5 phases + 1 admit slice / step
-    assert {e["name"] for e in xs} == {"admit", "host", "dispatch",
-                                       "wait", "commit", "obs"}
+    # an admission that reports no parts is all its own host time
+    assert abs(s["admit_split"]["self"] - 0.0015) < 1e-9, s
+    assert abs(s["pure_host_s"] - s["host_s"]) < 1e-9, s
     prom = clk.render_prom()
     assert "dnn_tpu_step_host_fraction" in prom, prom
+    assert reg.snapshot()["gauges"]["step.steps_total"] == 3
     snap = reg.snapshot()
     assert 'step.phase_seconds{phase="wait"}' in snap["histogram"], snap
 
@@ -439,17 +438,14 @@ def _timeline_selftest() -> int:
     with open(os.path.join(d, "meta.json"), "w") as f:
         json.dump({"perf_begin": 100.0, "perf_end": 100.0305,
                    "step_begin": 0, "step_end": 3, "backend": "cpu"}, f)
-    a = analyze(d, clock=clk)
+    a = analyze(d)
     assert a["device"]["ops"] == 3, a["device"]
     assert abs(a["device"]["busy_s"] - 0.018) < 1e-6, a["device"]
     assert a["host_gaps"]["count"] == 2, a["host_gaps"]
     assert abs(a["host_gaps"]["p50_ms"] - 4.0) < 0.01, a["host_gaps"]
     assert a["top_ops"][0]["name"] == "fusion.1", a["top_ops"]
-    st = a["steps"]
-    assert st and st["aligned"] and st["n_steps"] == 3, st
-    assert st["steps_in_capture"] == 3, st
-    # each step: 6 ms device busy inside a 9.5 ms attributed wall
-    assert abs(st["device_overlap_frac"] - 18.0 / 28.5) < 1e-3, st
+    # the window is the armed one of the sidecar meta
+    assert abs(a["window_s"] - 0.0305) < 1e-6, a["window_s"]
 
     # garbage and truncated inputs fail loud, not half-parsed
     bad = os.path.join(d, "garbage.json")
@@ -464,12 +460,12 @@ def _timeline_selftest() -> int:
     print("timeline selftest ok: 3 deterministic steps (host fraction "
           f"{s['host_fraction']:.2%}, slack {s['dispatch_slack']:.2f}, "
           f"sync tax {s['sync_tax']:.2%}), synthetic capture analyzed "
-          f"(device busy {a['device']['busy_frac']:.1%}, 3 steps "
-          "aligned), garbage rejected")
+          f"(device busy {a['device']['busy_frac']:.1%}), garbage "
+          "rejected")
     return 0
 
 
-def _timeline_url(url: str, out=None, last=None) -> int:
+def _timeline_url(url: str, last=None) -> int:
     from urllib.request import urlopen
 
     base = url.rstrip("/") + "/stepz"
@@ -487,15 +483,12 @@ def _timeline_url(url: str, out=None, last=None) -> int:
           f"sync tax {s.get('sync_tax', 0):.1%} | "
           f"{s.get('steps_per_sec', 0):.1f} steps/s | last step "
           f"{s.get('last_wall_ms', 0):.2f} ms")
-    if out:
-        trace = urlopen(base + "?format=trace"
-                        + (f"&last={last}" if last else ""),
-                        timeout=10).read().decode()
-        with open(out, "w") as f:
-            f.write(trace)
-        n = sum(1 for e in json.loads(trace)["traceEvents"]
-                if e.get("ph") == "X")
-        print(f"wrote {out}: {n} phase slices (load in Perfetto)")
+    split = s.get("admit_split")
+    if split:
+        print("admit by part: " + " | ".join(
+            f"{k} {v * 1e3:.1f} ms" for k, v in split.items())
+            + f" | pure host {s.get('pure_host_s', 0) * 1e3:.1f} ms of "
+              f"host {s.get('host_s', 0) * 1e3:.1f} ms")
     return 0
 
 
@@ -1101,9 +1094,6 @@ def main(argv=None) -> int:
                          "synthetic capture); exit 0 on pass")
     tl.add_argument("--url", default=None,
                     help="obs endpoint base URL to fetch /stepz from")
-    tl.add_argument("--out", default=None,
-                    help="with --url: write the step host track "
-                         "(?format=trace Perfetto JSON) here")
     tl.add_argument("--last", type=int, default=None,
                     help="bound the /stepz window to the newest N steps")
     tl.add_argument("--json", action="store_true",
@@ -1186,7 +1176,7 @@ def main(argv=None) -> int:
         if args.selftest:
             return _timeline_selftest()
         if args.url:
-            return _timeline_url(args.url, args.out, args.last)
+            return _timeline_url(args.url, args.last)
         if args.path:
             return _timeline_path(args.path, args.json, args.top)
         ap.error("timeline needs --selftest, --url URL, or a capture "

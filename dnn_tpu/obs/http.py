@@ -33,9 +33,10 @@ Prometheus scraper or a plain curl can watch the serving stack:
                        decomposition (admit/host/dispatch/wait/commit/
                        obs), dispatch-slack, sync-tax, host fraction
                        (JSON; ?format=prom re-renders as gauges,
-                       ?format=trace exports the last N steps as a
-                       Perfetto-loadable host track, ?last=N bounds
-                       the window)
+                       ?last=N bounds the window). The steps on a
+                       timeline are in a POST /profilez capture: the
+                       batcher writes them there as step.* / admit*
+                       annotations, beside the device's operations
     GET  /trainz       training-step observatory (obs/trainlens.py)
                        when a TrainClock is attached: per-phase
                        training-iteration decomposition (data/dispatch/
@@ -96,13 +97,16 @@ def _status_prom(status: dict) -> str:
     """Render a /statusz payload (watchdog or fleet shape) as Prometheus
     gauges: dnn_tpu_status_state 0|1|2 (ok|degraded|wedged) plus one
     per-component series — the ?format=prom passthrough for collectors
-    that only speak scrapes."""
+    that only speak scrapes. A component that carries no `state` (facts
+    only, nothing probed) gets no series."""
     from dnn_tpu.utils.metrics import Metrics, labeled, render_prometheus
 
     m = Metrics()
     m.set("dnn_tpu_status_state",
           _STATE_GAUGE.get(status.get("state"), 1.0))
     for name, comp in (status.get("components") or {}).items():
+        if "state" not in (comp or {}):
+            continue
         m.set(labeled("dnn_tpu_status_component_state", component=name),
               _STATE_GAUGE.get((comp or {}).get("state"), 1.0))
     return render_prometheus(m)
@@ -258,13 +262,9 @@ class MetricsHTTPServer:
                 elif fmt == "prom":
                     self._send(200, outer._stepclock.render_prom(last),
                                "text/plain; version=0.0.4; charset=utf-8")
-                elif fmt == "trace":
-                    self._send(200, json.dumps(
-                        outer._stepclock.chrome_trace(last)),
-                        "application/json")
                 else:
                     self._send(400, f"unknown format {fmt!r} "
-                               "(json|prom|trace)\n",
+                               "(json|prom)\n",
                                "text/plain; charset=utf-8")
 
             def _trainz(self, q):

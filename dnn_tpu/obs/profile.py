@@ -15,8 +15,10 @@ under the capture dir) taken from a RUNNING server:
     have to be watching when the slow step happens);
   * `annotation(name)` / `step_annotation(step)` are the obs-gated host
     span annotations (jax.profiler.TraceAnnotation) that make captures
-    readable — the serving runtime wraps decode steps, prefill chunks
-    and relay stage hops in them, and the models thread
+    readable — the serving runtime writes every batcher step and its
+    StepClock phases (`step`, `step.<phase>`, with a `step=` stat),
+    every admission and its parts (`admit`, `admit.prefill`, ..., with
+    `rid=`), prefill chunks and relay stage hops, and the models thread
     `jax.named_scope` through their blocks so TPU timelines name layers
     too. utils/tracing.py re-exports these (its original span API
     predates the obs gate and is deprecated).
@@ -33,9 +35,8 @@ cannot fill the disk.
 Every obs-driven capture writes a sidecar `meta.json` at the capture
 root — monotonic (perf_counter) begin/end, wall-clock bounds, the
 StepClock step-counter range, and the backend — so
-`obs/timeline.analyze()` can place the capture on the decode-step axis
-(which steps the window covers, and how much of each the device was
-busy for).
+`obs/timeline.analyze()` knows the armed window. Which step ran when
+is in the capture itself (the `step=` stat of the annotations).
 """
 
 from __future__ import annotations
@@ -50,8 +51,8 @@ import time
 from typing import Iterator, Optional
 
 __all__ = ["ProfilerBusy", "capture", "capture_step", "spool_dir",
-           "list_captures", "annotation", "annotation_ctx",
-           "step_annotation", "Profiler"]
+           "list_captures", "annotation", "annotation_ctx", "open_span",
+           "close_span", "step_annotation", "Profiler"]
 
 
 class ProfilerBusy(RuntimeError):
@@ -129,11 +130,10 @@ def _step_counter() -> Optional[int]:
 
 def _write_meta(path: str, meta: dict):
     """Sidecar `meta.json` at the capture root: monotonic begin/end
-    (perf_counter — the clock StepClock records on), wall-clock
-    bounds, the step-counter range, and the backend. This is what lets
-    `timeline.analyze()` place a spooled capture on the step axis —
-    without it a capture floats free of the step stream entirely.
-    Best-effort: an unwritable spool loses the meta, never the trace."""
+    (perf_counter), wall-clock bounds, the step-counter range, and the
+    backend: the armed window `timeline.analyze()` reads idle time
+    inside. Best-effort: an unwritable spool loses the meta, never the
+    trace."""
     try:
         with open(os.path.join(path, "meta.json"), "w") as f:
             json.dump(meta, f)
@@ -160,9 +160,8 @@ def _traced(capture_root: Optional[str], keep: int) -> Iterator[str]:
         except Exception:  # noqa: BLE001 — a wedged backend still traces
             backend = None
         jax.profiler.start_trace(path)
-        # perf_begin lands right after start_trace returns: the trace's
-        # ts axis starts ~here, so (perf_counter - perf_begin) maps a
-        # StepClock timestamp onto the capture's microsecond axis
+        # perf_begin lands right after start_trace returns: the armed
+        # window starts here (a first capture's profiler init is before)
         meta = {"perf_begin": time.perf_counter(),
                 "t_begin_unix": time.time(),
                 "step_begin": _step_counter(),
@@ -261,22 +260,29 @@ _NULL_CTX = contextlib.nullcontext()
 _trace_annotation = False  # unresolved; None = profiler unavailable
 
 
-def annotation_ctx(name: str):
+def annotation_ctx(name: str, **stats):
     """HOT-PATH form: returns a jax.profiler.TraceAnnotation (obs on AND
     an obs-driven capture recording) or a shared nullcontext — a plain
-    call + two checks, no generator. Two measured costs forced this
-    shape: the @contextmanager `annotation` below costs ~30 µs around a
-    jit dispatch (generator machinery + per-call imports), and even a
-    bare TraceAnnotation costs ~6 µs there — both real money against a
-    ms-scale decode step, paid EVERY step for annotations nobody is
-    recording. Gating on `capturing()` (set by _traced during POST
-    /profilez and the auto-trigger) makes the steady state ~0.3 µs; a
-    capture driven outside obs.profile (bare jax.profiler.start_trace)
-    won't see these annotations unless it wraps its body in
-    `mark_recording` (utils/tracing.trace_to does) — prefer
-    obs.profile.capture. The
+    call + two checks, no generator. `stats` (ints, floats, strings)
+    become the event's stats in the capture: `step=` on the batcher's
+    step phases, `rid=` on an admission's parts. Two measured costs
+    forced this shape: the @contextmanager `annotation` below costs
+    ~30 µs around a jit dispatch (generator machinery + per-call
+    imports), and even a bare TraceAnnotation costs ~6 µs there — both
+    real money against a ms-scale decode step, paid EVERY step for
+    annotations nobody is recording. Gating on `capturing()` (set by
+    _traced during POST /profilez and the auto-trigger) makes the steady
+    state ~0.3 µs; a capture driven outside obs.profile (bare
+    jax.profiler.start_trace) won't see these annotations unless it
+    wraps its body in `mark_recording` (utils/tracing.trace_to does) —
+    prefer obs.profile.capture. The
     TraceAnnotation class is resolved once, lazily — importing this
-    module still never touches jax."""
+    module still never touches jax.
+
+    The annotation lands on the capture's `/host:CPU` plane, on the
+    calling thread's line and on the same clock as the device planes'
+    operations, so a reader intersects the two with no clock arithmetic
+    (chipbench/spans.py does)."""
     global _trace_annotation
     from dnn_tpu import obs
 
@@ -291,7 +297,24 @@ def annotation_ctx(name: str):
             _trace_annotation = None
     if _trace_annotation is None:
         return _NULL_CTX
-    return _trace_annotation(name)
+    return _trace_annotation(name, **stats)
+
+
+def open_span(name: str, **stats):
+    """`annotation_ctx` for a span whose end is not a block's end (a
+    StepClock phase closes where the next mark is stamped): enters the
+    annotation now and returns it for `close_span`, or None when no
+    capture records. A span that is never closed is never written."""
+    ctx = annotation_ctx(name, **stats)
+    if ctx is _NULL_CTX:
+        return None
+    ctx.__enter__()
+    return ctx
+
+
+def close_span(span):
+    if span is not None:
+        span.__exit__(None, None, None)
 
 
 @contextlib.contextmanager

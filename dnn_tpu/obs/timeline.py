@@ -13,7 +13,10 @@ This is the intra-step instrument, in two connected halves:
 
         admit     submit() end-to-end: validation, slot install,
                   prefill chunks, first-token sample (accumulated onto
-                  the NEXT step's record — admits happen between steps)
+                  the NEXT step's record — admits happen between steps);
+                  split by ADMIT_PARTS into self / prefill / first_token
+                  / install, of which only self and install are host
+                  work the device does not overlap
         host      step-entry bookkeeping before the device call
                   (bucket growth, constraint-row flush)
         dispatch  the jit call itself, call-to-return — host time spent
@@ -50,24 +53,27 @@ This is the intra-step instrument, in two connected halves:
     DNN_TPU_OBS gate: `begin()` returns None when the gate is off, and
     every producer site guards on that one None. Scrape-time CALLABLE
     gauges (step.dispatch_slack / step.sync_tax / step.host_fraction /
-    step.per_sec / step.last_wall_ms) + fixed-bucket histograms
+    step.per_sec / step.last_wall_ms, and the cumulative totals
+    step.steps_total / step.tokens_advanced_total /
+    step.phase_seconds_total{phase=} / step.admit_seconds_total{part=},
+    exact at every scrape to the last ended step) + fixed-bucket histograms
     (step.phase_seconds{phase=...}, step.wall_seconds). Phase-boundary
-    timestamps are ring-buffered, so the last N steps export as a
-    Perfetto-loadable host track (`chrome_trace()`, GET
-    /stepz?format=trace).
+    timestamps are ring-buffered for /stepz. While a profiler capture
+    records (POST /profilez), the same boundaries are ALSO written into
+    the capture as `step` / `step.<phase>` annotations (_StepSpans), and
+    submit() writes `admit` / `admit.prefill` / `admit.first_token` /
+    `admit.install`: host spans on the device trace's own clock, which
+    is what attributes a device idle gap to a phase.
 
   * **analyze()** — device-trace analysis: parses the gzipped Perfetto
     JSON the obs/profile.py Profiler already spools (stdlib gzip+json,
     no new deps) into structured numbers — per-track busy fraction,
-    device busy/idle inside the capture window, the host-gap histogram
-    between consecutive device ops (the serialization bubbles made
-    visible), top-K ops by device time — and correlates them with the
-    StepClock's step stream via the capture's sidecar `meta.json`
-    (profile.py writes monotonic begin/end + step-counter range +
-    backend), answering "how much of each step was the device actually
-    busy".
+    device busy/idle inside the capture window (the armed window, from
+    the capture's sidecar `meta.json`), the host-gap histogram between
+    consecutive device ops (the serialization bubbles made visible),
+    top-K ops by device time.
 
-Served via GET /stepz (JSON; ?format=prom|trace) on the obs endpoint
+Served via GET /stepz (JSON; ?format=prom) on the obs endpoint
 and `python -m dnn_tpu.obs timeline [--url URL | PATH]`. The asserted
 baseline lives in benchmarks/step_timeline_probe.py: phase accounting
 must cover >= 95% of externally measured wall time (no unattributed
@@ -92,6 +98,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from dnn_tpu import obs as _obs
+from dnn_tpu.obs import profile as _profile
 from dnn_tpu.utils.metrics import labeled
 
 __all__ = ["StepClock", "PHASES", "STEP_BUCKETS", "analyze",
@@ -107,6 +114,47 @@ STEP_BUCKETS = (2.5e-5, 5e-5, 1e-4, 2.5e-4, 5e-4, 1e-3, 2.5e-3, 5e-3,
 
 _HOST_PHASES = ("admit", "host", "commit", "obs")
 _DEVICE_PHASES = ("dispatch", "wait")
+
+#: what one admission's wall time divides into: `prefill` (dispatching
+#: the chunk and finish programs), `first_token` (the device-to-host read
+#: the host waits for the prefill in), `install` (the eager per-slot
+#: scatters), and `self` — the rest: validation, slot and block
+#: allocation, key folding, prefix lookup. submit() stamps the three
+#: inner intervals; `self` is what they leave of the admit slice.
+ADMIT_PARTS = ("self", "prefill", "first_token", "install")
+_NO_PARTS = (0.0, 0.0, 0.0)
+
+#: the phase whose annotation opens when a mark closes phase P (None
+#: after the last): the in-step order of PHASES, one definition
+_PHASE_AFTER = dict(zip(PHASES[1:], PHASES[2:] + (None,)))
+
+
+class _StepSpans:
+    """One step's `step` annotation and, under it, the annotation of the
+    phase now running: the StepClock phases written into a recording
+    profiler capture (jax.profiler.TraceAnnotation, via obs/profile.py),
+    on the device trace's own clock. Exists only while a capture
+    records; `begin()` opens it, each mark moves it on, `end()` closes
+    it. Every annotation carries `step=<index>`, the value of
+    `steps_total` when the step began."""
+
+    __slots__ = ("step", "phase", "idx")
+
+    def __init__(self, idx: int, first: str):
+        self.idx = idx
+        self.step = _profile.open_span("step", step=idx)
+        self.phase = _profile.open_span("step." + first, step=idx)
+
+    def next(self, closed: str):
+        """The mark that closed phase `closed` was just stamped."""
+        _profile.close_span(self.phase)
+        nxt = _PHASE_AFTER[closed]
+        self.phase = None if nxt is None else _profile.open_span(
+            "step." + nxt, step=self.idx)
+
+    def close(self):
+        _profile.close_span(self.phase)
+        _profile.close_span(self.step)
 
 #: shared empty admit-slice seq — most steps have no admissions, and
 #: the per-step allocation was measurable against the <2% obs budget;
@@ -125,7 +173,7 @@ class _StepRec:
     scrape thread recomputes the same values it would assign twice."""
 
     __slots__ = ("t0", "t_end", "marks", "n_adv", "wall", "phases",
-                 "admit_slices", "mixed")
+                 "admit_slices", "admit_parts", "mixed", "spans")
 
     def __init__(self, t0: float):
         self.t0 = t0
@@ -135,6 +183,14 @@ class _StepRec:
         self.wall = 0.0
         self.phases: "Optional[Dict[str, float]]" = None
         self.admit_slices = _NO_ADMITS
+        # per admit slice, the seconds of its (prefill, first_token,
+        # install) parts — the slice's remainder is admission's own host
+        # time (ADMIT_PARTS)
+        self.admit_parts = _NO_ADMITS
+        # the open profiler annotations of this step while a capture
+        # records (_StepSpans), else None — producers check this ONE
+        # attribute after each mark
+        self.spans: "Optional[_StepSpans]" = None
         # mixed = this step's dispatch folded an interleaved prefill
         # chunk (serving prefill_chunk_tokens) — /stepz distinguishes
         # interleaved-prefill steps from pure-decode steps with it
@@ -186,12 +242,8 @@ class StepClock:
     steps, and the worker loop's iteration = admits + one step).
 
     Thread safety: the worker thread produces; /stepz scrapes read the
-    ring under the lock. `now` is injectable for deterministic tests —
-    but it governs only the CLOCK-driven methods (begin/mark/end/
-    note_admit): the serving producers stamp `time.perf_counter()`
-    inline (a method call per mark was measurable against the <2%
-    obs budget), so attach only default-`now` clocks to a real pool;
-    injected clocks are for hand-driven records.
+    ring under the lock. `now` is injectable for deterministic tests:
+    every stamp (begin/mark/end/note_admit) reads it.
 
     Registry cost: per-step observations are accumulated locally and
     FLUSHED in one bulk update every `FLUSH_EVERY` steps (summary()/
@@ -211,7 +263,17 @@ class StepClock:
         self._now = now
         self._lock = threading.Lock()
         self._pending_admit: list = []
+        self._pending_parts: list = []
+        # cumulative since process start, EXACT at every scrape to the
+        # last ended step: plain numbers the producer adds to in end() /
+        # note_admit(), read by scrape-time callables (_gauges) — never
+        # through the FLUSH_EVERY bulk, so a /metrics window difference
+        # of them covers the whole window
         self.steps_total = 0
+        self.tokens_advanced_total = 0
+        self.phase_seconds_total = {p: 0.0 for p in PHASES}
+        self.admit_seconds_total = {p: 0.0 for p in ADMIT_PARTS}
+        self._gauges_registered = False
         self._registry = registry
         self._t_last_end: Optional[float] = None
         # registry batch: records awaiting the bulk flush (end() only
@@ -246,7 +308,23 @@ class StepClock:
         # it reports covered constraint-live traffic). Set by the
         # producer at admit/retire with one attr store, never per step.
         self.constrained_slots = 0
+        def _weak_total(attr, key=None):
+            def read():
+                c = ref()
+                if c is None:
+                    return 0.0
+                v = getattr(c, attr)
+                return float(v if key is None else v[key])
+            return read
+
         self._gauges = {
+            "step.steps_total": _weak_total("steps_total"),
+            "step.tokens_advanced_total":
+                _weak_total("tokens_advanced_total"),
+            **{labeled("step.phase_seconds_total", phase=p):
+               _weak_total("phase_seconds_total", p) for p in PHASES},
+            **{labeled("step.admit_seconds_total", part=p):
+               _weak_total("admit_seconds_total", p) for p in ADMIT_PARTS},
             "step.dispatch_slack": _weak("dispatch_slack"),
             "step.sync_tax": _weak("sync_tax"),
             "step.host_fraction": _weak("host_fraction"),
@@ -265,40 +343,71 @@ class StepClock:
 
     # -- producer side (the batcher worker thread) ---------------------
 
-    def begin(self) -> Optional[_StepRec]:
+    def begin(self, first: str = "host") -> Optional[_StepRec]:
         """Start one step's record — None when observability is off
-        (the producer's one None check covers every later site)."""
+        (the producer's one None check covers every later site). While
+        a profiler capture records, the step and its phases are also
+        written into it as annotations (_StepSpans); `first` names the
+        phase the step opens in (`wait` for a call that only commits)."""
         if not _obs.enabled():
             return None
-        return _StepRec(self._now())
+        rec = _StepRec(self._now())
+        if _profile._capturing:
+            rec.spans = _StepSpans(self.steps_total, first)
+        return rec
 
     def mark(self, rec: _StepRec, phase: str):
-        """Close the current phase at now (one perf_counter read + one
-        tuple append on the hot path)."""
+        """Close the current phase at now: one perf_counter read, one
+        tuple append, and one attribute check for the open annotations
+        (`rec.spans`, None unless a capture records)."""
         rec.marks.append((phase, self._now()))
+        if rec.spans is not None:
+            rec.spans.next(phase)
 
-    def note_admit(self, t0: float):
+    def _register_gauges(self):
+        """Put the scrape-time callables on the registry before the
+        first bulk flush would (FLUSH_EVERY steps in): the cumulative
+        series must be there at a window's first scrape."""
+        m = self._registry if self._registry is not None \
+            else _obs.metrics()
+        if m is not None:
+            m.bulk(gauge_fns=self._gauges)
+            self._gauges_registered = True
+
+    def note_admit(self, t0: float, parts: tuple = _NO_PARTS):
         """One submit()'s wall interval [t0, now) — attached to the
-        next step's record. Bounded: a pathological admit storm with no
-        steps keeps the newest 64 slices. Lock-free: submit and step
-        run on the ONE thread that owns the batcher (the lm_server
-        worker contract), so the producer side never races itself —
-        and flush()'s swap-then-read is safe against a GIL-atomic
-        append (an append racing the swap lands in whichever list the
-        interpreter saw, and both are drained)."""
+        next step's record — and the seconds of its (prefill,
+        first_token, install) parts (ADMIT_PARTS; an admission that
+        dispatches nothing reports none). Bounded: a pathological admit
+        storm with no steps keeps the newest 64 slices. Lock-free:
+        submit and step run on the ONE thread that owns the batcher
+        (the lm_server worker contract), so the producer side never
+        races itself — and flush()'s swap-then-read is safe against a
+        GIL-atomic append (an append racing the swap lands in whichever
+        list the interpreter saw, and both are drained)."""
         if not _obs.enabled():
             return
         t1 = self._now()
         pa = self._pending_admit
         pa.append((t0, t1))
+        self._pending_parts.append(parts)
         if len(pa) > 64:
-            del pa[0]
+            del pa[0], self._pending_parts[0]
+        self.phase_seconds_total["admit"] += t1 - t0
+        tot = self.admit_seconds_total
+        tot["self"] += (t1 - t0) - sum(parts)
+        tot["prefill"] += parts[0]
+        tot["first_token"] += parts[1]
+        tot["install"] += parts[2]
+        if not self._gauges_registered:
+            self._register_gauges()
 
     def end(self, rec: _StepRec, n_adv: int = 0):
         """Stamp and publish one step. Deliberately MINIMAL — one
-        perf_counter read and ONE GIL-atomic append, no lock: this
-        runs inside the decode loop the clock exists to measure, and
-        the obs_overhead <2% contract prices every microsecond here.
+        perf_counter read, the cumulative totals (a handful of adds)
+        and ONE GIL-atomic append, no lock: this runs inside the decode
+        loop the clock exists to measure, and the obs_overhead <2%
+        contract prices every microsecond here.
         Single-producer by the batcher's threading contract. The rec
         lands only in the pending batch here; flush() moves the batch
         into the scrape ring (and runs the ring's evictions) every
@@ -308,11 +417,25 @@ class StepClock:
         The phase fold and the registry bulk run off this path too."""
         rec.t_end = self._now()
         rec.n_adv = n_adv
+        if rec.spans is not None:
+            rec.spans.close()
+            rec.spans = None
         if self._pending_admit:
             rec.admit_slices, self._pending_admit = \
                 self._pending_admit, []
+            rec.admit_parts, self._pending_parts = \
+                self._pending_parts, []
+        tot = self.phase_seconds_total
+        t = rec.t0
+        for name, tm in rec.marks:
+            tot[name] += tm - t
+            t = tm
+        tot["obs"] += rec.t_end - t  # as _fold attributes the remainder
+        self.tokens_advanced_total += n_adv
         self.steps_total += 1
         self._t_last_end = rec.t_end
+        if not self._gauges_registered:
+            self._register_gauges()
         pf = self._pending_flush
         pf.append(rec)
         if len(pf) >= self.FLUSH_EVERY:
@@ -361,8 +484,7 @@ class StepClock:
                 hists.setdefault(self._hist_keys[p], []).append(v)
             walls.append(r.wall)
         hists["step.wall_seconds"] = walls
-        m.bulk(counters={"step.steps_total": len(pending)},
-               hists=hists, hist_buckets=STEP_BUCKETS,
+        m.bulk(hists=hists, hist_buckets=STEP_BUCKETS,
                gauge_fns=self._gauges)
 
     # -- derived series (scrape-time reads over the ring) --------------
@@ -383,6 +505,19 @@ class StepClock:
             wall += r.wall
             n_adv += r.n_adv
         return recs, tot, wall, n_adv
+
+    @staticmethod
+    def _admit_split(recs, admit_s: float) -> Dict[str, float]:
+        """Seconds of each ADMIT_PARTS part over `recs`; the four sum to
+        the records' admit phase (`admit_s`)."""
+        pf = ft = ins = 0.0
+        for r in recs:
+            for a, b, c in r.admit_parts:
+                pf += a
+                ft += b
+                ins += c
+        return {"self": admit_s - pf - ft - ins, "prefill": pf,
+                "first_token": ft, "install": ins}
 
     def _derived(self) -> dict:
         """The three ring-derived gauges from ONE _sums pass, memoized
@@ -451,7 +586,7 @@ class StepClock:
 
     def records(self, last: Optional[int] = None) -> List[dict]:
         """Ring records as plain dicts (newest last) — what the probe's
-        coverage assertion and analyze()'s step alignment read."""
+        coverage assertion reads."""
         self._land()
         with self._lock:
             recs = list(self._ring)
@@ -481,6 +616,7 @@ class StepClock:
                          "mean_ms": round(s / n * 1e3, 4) if n else 0.0}
         dev = sum(tot[p] for p in _DEVICE_PHASES)
         host = sum(tot[p] for p in _HOST_PHASES)
+        split = self._admit_split(recs, tot["admit"])
         return {
             "steps_total": self.steps_total,
             "window_steps": n,
@@ -499,6 +635,13 @@ class StepClock:
             "phases": phases,
             "device_s": round(dev, 6),
             "host_s": round(host, 6),
+            # the admit phase by what the worker did in it (ADMIT_PARTS):
+            # host_s counts all of it, though `prefill` and `first_token`
+            # are device time the host dispatches and waits for —
+            # pure_host_s is host_s without those two
+            "admit_split": {k: round(v, 6) for k, v in split.items()},
+            "pure_host_s": round(
+                host - split["prefill"] - split["first_token"], 6),
             "host_fraction": round(host / wall, 4) if wall > 0 else 0.0,
             "dispatch_slack": round(host / dev, 4) if dev > 0 else 0.0,
             "sync_tax": round(tot["wait"] / wall, 4) if wall > 0 else 0.0,
@@ -544,51 +687,6 @@ class StepClock:
                   d["s"])
             m.set(labeled("dnn_tpu_step_phase_frac", phase=p), d["frac"])
         return render_prometheus(m)
-
-    def chrome_trace(self, last: Optional[int] = None) -> dict:
-        """The ring as a Perfetto-loadable HOST track: one process
-        ("stepclock"), one slice per phase per step (admit slices keep
-        their own real boundaries — they happened before the step).
-        Timestamps are perf_counter µs REBASED so the oldest exported
-        slice starts at ts 0 (Perfetto renders absolute monotonic
-        stamps days into the timeline). A device capture has its OWN ts
-        origin (the profiler session start), so the two files do not
-        overlay directly — `analyze()` + the sidecar meta do that
-        correlation numerically (per-step device busy / overlap)."""
-        self._land()
-        with self._lock:
-            recs = list(self._ring)
-        if last:
-            recs = recs[-last:]
-        origin = 0.0
-        if recs:
-            r0 = recs[0]
-            origin = min([r0.t0] + [a for a, _ in r0.admit_slices])
-        events = [
-            {"ph": "M", "pid": 1, "name": "process_name",
-             "args": {"name": "stepclock"}},
-            {"ph": "M", "pid": 1, "tid": 1, "name": "thread_name",
-             "args": {"name": "decode-step phases"}},
-        ]
-        for i, r in enumerate(recs):
-            for a0, a1 in r.admit_slices:
-                events.append({"ph": "X", "pid": 1, "tid": 1,
-                               "name": "admit",
-                               "ts": (a0 - origin) * 1e6,
-                               "dur": (a1 - a0) * 1e6,
-                               "args": {"step": i}})
-            t = r.t0
-            args = {"step": i, "n_adv": r.n_adv}
-            if r.mixed:
-                args["mixed"] = True
-            for name, tm in r.marks:
-                events.append({"ph": "X", "pid": 1, "tid": 1,
-                               "name": name,
-                               "ts": (t - origin) * 1e6,
-                               "dur": (tm - t) * 1e6,
-                               "args": args})
-                t = tm
-        return {"traceEvents": events, "displayTimeUnit": "ms"}
 
 
 # the process's active clock (profile.py sidecar meta reads it)
@@ -681,8 +779,8 @@ def find_meta(path: str) -> Optional[dict]:
     return None
 
 
-def analyze(path: str, *, clock: Optional[StepClock] = None,
-            meta: Optional[dict] = None, top_k: int = 10) -> dict:
+def analyze(path: str, *, meta: Optional[dict] = None,
+            top_k: int = 10) -> dict:
     """Structured numbers out of one device capture.
 
     `path` is a capture dir (POST /profilez's return) or a trace JSON
@@ -699,10 +797,11 @@ def analyze(path: str, *, clock: Optional[StepClock] = None,
                           device ops — each gap is host serialization
                           the device sat idle through
       top_ops             top-K op names by summed device time
-      steps               StepClock correlation when a sidecar meta
-                          (and optionally a live clock) places the
-                          capture on the step axis: steps in window,
-                          per-step device busy, device-overlap fraction
+
+    Where the steps and admissions lie in the capture is IN the capture:
+    the batcher writes its phases as `step.*` / `admit*` annotations on
+    the profiler's own clock (_StepSpans), so nothing here places a
+    StepClock record on the trace's axis.
 
     Stdlib only; tolerant of the capture's host-side noise (the
     profiler's own start_trace span, threadpool markers)."""
@@ -736,7 +835,7 @@ def analyze(path: str, *, clock: Optional[StepClock] = None,
     t_min = min(_num(e, "ts") for e in xs)
     t_max = max(_num(e, "ts") + _num(e, "dur") for e in xs)
 
-    # ts-axis anchor for StepClock correlation: the trace's ts 0 is the
+    # ts-axis anchor of the armed window: the trace's ts 0 is the
     # profiler SESSION start (start_trace entry), but the sidecar meta's
     # perf_begin lands at start_trace RETURN — a first capture pays
     # seconds of profiler init in between. The host track records that
@@ -838,60 +937,6 @@ def analyze(path: str, *, clock: Optional[StepClock] = None,
                for n, (s, c) in sorted(by_op.items(),
                                        key=lambda kv: -kv[1][0])[:top_k]]
 
-    steps = None
-    if meta is not None:
-        steps = {
-            "backend": meta.get("backend"),
-            "step_begin": meta.get("step_begin"),
-            "step_end": meta.get("step_end"),
-            "steps_in_capture": None,
-            "aligned": False,
-        }
-        sb, se = meta.get("step_begin"), meta.get("step_end")
-        if isinstance(sb, int) and isinstance(se, int):
-            steps["steps_in_capture"] = se - sb
-        pb = meta.get("perf_begin")
-        if clock is None:
-            clock = active_clock()
-        if clock is not None and isinstance(pb, (int, float)):
-            pe = meta.get("perf_end", float("inf"))
-
-            def _ivals(r):
-                # a record's PHYSICAL extent: its admit slices (which
-                # happened before t0 — submit runs between steps) plus
-                # the in-step span; wall is the summed length of these
-                admit_s = sum(t1 - t0 for t0, t1 in r["admit_slices"])
-                return list(r["admit_slices"]) + [
-                    (r["t0"], r["t0"] + (r["wall"] - admit_s))]
-
-            recs = [r for r in clock.records()
-                    if all(pb <= a and b <= pe for a, b in _ivals(r))]
-            if recs:
-                # map each step's perf intervals onto the capture's ts
-                # axis (perf_begin sits at `anchor`) and intersect with
-                # the merged device intervals: per-step device busy
-                per_step = []
-                for r in recs:
-                    busy = 0.0
-                    for ia, ib in _ivals(r):
-                        a = (ia - pb) * 1e6 + anchor
-                        b = (ib - pb) * 1e6 + anchor
-                        busy += sum(max(0.0, min(b, t1) - max(a, t0))
-                                    for t0, t1 in dev_ivals)
-                    per_step.append((r["wall"], busy / 1e6))
-                wall_sum = sum(w for w, _ in per_step)
-                busy_sum = sum(b for _, b in per_step)
-                steps.update({
-                    "aligned": True,
-                    "n_steps": len(per_step),
-                    "mean_wall_ms": round(wall_sum / len(per_step) * 1e3,
-                                          4),
-                    "mean_device_busy_ms": round(
-                        busy_sum / len(per_step) * 1e3, 4),
-                    "device_overlap_frac": round(busy_sum / wall_sum, 4)
-                    if wall_sum > 0 else 0.0,
-                })
-
     return {
         "trace_file": trace_file,
         "window_s": round(window_s, 6),
@@ -900,7 +945,6 @@ def analyze(path: str, *, clock: Optional[StepClock] = None,
         "device": device,
         "host_gaps": host_gaps,
         "top_ops": top_ops,
-        "steps": steps,
     }
 
 
@@ -924,20 +968,6 @@ def render_report(a: dict) -> str:
             lines.append(f"  {op['total_ms']:10.3f} ms  "
                          f"{op['frac_of_device']:6.1%}  x{op['count']:<5d}"
                          f" {op['name']}")
-    st = a.get("steps")
-    if st:
-        if st.get("aligned"):
-            lines.append(
-                f"steps: {st['n_steps']} aligned to the capture — mean "
-                f"wall {st['mean_wall_ms']:.3f} ms, device busy "
-                f"{st['mean_device_busy_ms']:.3f} ms/step (overlap "
-                f"{st['device_overlap_frac']:.1%})")
-        elif st.get("steps_in_capture") is not None:
-            lines.append(f"steps: {st['steps_in_capture']} in capture "
-                         f"(counter {st['step_begin']}..{st['step_end']},"
-                         f" backend {st.get('backend')}); none aligned "
-                         "(no step records inside the window, or no "
-                         "live clock)")
     lines.append("tracks:")
     for name, t in sorted(a["tracks"].items(),
                           key=lambda kv: -kv[1]["busy_s"]):

@@ -101,7 +101,12 @@ def linear(params, x, *, compute_dtype=None, accum_dtype=None):
     orig_dtype = x.dtype
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
-        kernel = kernel.astype(compute_dtype)
+        # a name for the device trace (chipbench/spans.py reads it): a
+        # weight kept in another dtype is converted on every call, and
+        # the compiler hoists the convert of a scanned stack out of the
+        # layer loop, where nothing else says what it is
+        with jax.named_scope("weights.cast"):
+            kernel = kernel.astype(compute_dtype)
     if accum_dtype is not None:
         out = lax.dot_general(
             x, kernel,

@@ -191,9 +191,15 @@ def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="cached_attention",
     )(pos1d, *args)
 
 
+# The scopes on the three entry points (attn.prefill / attn.decode /
+# attn.paged_decode) and the kernels' `name=` are what a device trace
+# calls this file's work (the HLO op_name path; the custom call's own
+# name) — chipbench/spans.py reads them, so renaming one moves a metric.
+@jax.named_scope("attn.prefill")
 def cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
                      block_s=128, interpret=None):
     """Cache attention with runtime position limits (see module docstring).
@@ -406,6 +412,7 @@ def _decode_call(q, k, v, pos1d, ks, vs, *, block_s, interpret):
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="decode_attention",
     )(pos1d, *args)
 
 
@@ -517,6 +524,7 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
             .astype(o_ref.dtype)
 
 
+@jax.named_scope("attn.paged_decode")
 def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
                            interpret=None):
     """Fused paged decode attention (see the section comment above).
@@ -585,9 +593,11 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="paged_decode_attention",
     )(pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32), *args)
 
 
+@jax.named_scope("attn.decode")
 def decode_attention(q, k, v, pos, *, ks=None, vs=None, block_s=512,
                      interpret=None):
     """Decode-step cache attention (see the section comment above).

@@ -75,21 +75,26 @@ def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: GPTConfig,
     plugs its routed FFN in here, dnn_tpu/runtime/generate_moe.py)."""
     codec = codec or codec_for_cache(layer_cache)
     t = x.shape[1]
-    h = layer_norm(bp["ln_1"], x, eps=cfg.ln_eps)
-    q, k, v = _qkv_heads(bp, h, cfg=cfg, compute_dtype=compute_dtype)
-    layer_cache = codec.write(layer_cache, k, v, start_pos)
-    pos_limit = start_pos + jnp.arange(t)  # causal within the new tokens
-    # base= asserts the contiguous-limit contract the Pallas kernel needs
-    # (kvcache.FloatKV.attend) — einsum codecs ignore it
-    y = codec.attend(q, layer_cache, pos_limit, base=start_pos)
-    x = x + linear(bp["attn"]["proj"], merge_heads(y.astype(x.dtype)),
-                   compute_dtype=compute_dtype)
-    h = layer_norm(bp["ln_2"], x, eps=cfg.ln_eps)
-    if ffn is None:
-        m = linear(bp["mlp"]["proj"], gelu(linear(bp["mlp"]["fc"], h, compute_dtype=compute_dtype)),
-                   compute_dtype=compute_dtype)
-    else:
-        m = ffn(bp, h).astype(x.dtype)
+    # the scope names of models/gpt._block_core (device-trace names)
+    with jax.named_scope("gpt.block.attn"):
+        h = layer_norm(bp["ln_1"], x, eps=cfg.ln_eps)
+        q, k, v = _qkv_heads(bp, h, cfg=cfg, compute_dtype=compute_dtype)
+        layer_cache = codec.write(layer_cache, k, v, start_pos)
+        pos_limit = start_pos + jnp.arange(t)  # causal within the new tokens
+        # base= asserts the contiguous-limit contract the Pallas kernel
+        # needs (kvcache.FloatKV.attend) — einsum codecs ignore it
+        y = codec.attend(q, layer_cache, pos_limit, base=start_pos)
+        x = x + linear(bp["attn"]["proj"], merge_heads(y.astype(x.dtype)),
+                       compute_dtype=compute_dtype)
+    with jax.named_scope("gpt.block.mlp"):
+        h = layer_norm(bp["ln_2"], x, eps=cfg.ln_eps)
+        if ffn is None:
+            m = linear(bp["mlp"]["proj"],
+                       gelu(linear(bp["mlp"]["fc"], h,
+                                   compute_dtype=compute_dtype)),
+                       compute_dtype=compute_dtype)
+        else:
+            m = ffn(bp, h).astype(x.dtype)
     return x + m, layer_cache
 
 
@@ -118,7 +123,8 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: GPTConfig,
         )
         return x, layer_cache
 
-    x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
+    with jax.named_scope("layers.scan"):  # the loop's own slicing
+        x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
     logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                   compute_dtype=compute_dtype)
     return logits, new_cache

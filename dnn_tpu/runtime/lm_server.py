@@ -912,6 +912,7 @@ class LMServer:
         # range so a capture aligns to the step axis. Auto-built like
         # the goodput tracker; off with the obs gate.
         self.step_clock = None
+        self._device_static = {}  # _device_facts; read below, once
         if obs.enabled():
             from dnn_tpu.obs.timeline import StepClock
 
@@ -954,6 +955,15 @@ class LMServer:
             # housekeeping rides the worker loop (lease TTL + kvput
             # inbox TTL), rate-limited inside the tick
             self.worker.tick = self._housekeeping_tick
+            # what /statusz says of the device: read here, on the
+            # constructing thread, now that the engine holds the backend
+            # (never from the HTTP thread)
+            import jax
+
+            devs = jax.devices()
+            self._device_static = {
+                "device_kind": devs[0].device_kind,
+                "device_count": len(devs), "platform": devs[0].platform}
         except BaseException:
             # a failed construction (bad batcher kwargs) must release the
             # already-bound endpoint, or a retry hits EADDRINUSE forever
@@ -1046,6 +1056,20 @@ class LMServer:
     def auto_profile(self, value):
         self.worker.auto_profile = value
 
+    def _device_facts(self) -> dict:
+        """`device_kind`, `device_count`, `platform` (read once, in
+        __init__) and the boot gauges node.py published
+        (`dnn_tpu_boot_*_seconds`, as `boot_*_s`), for /statusz's
+        `device` component."""
+        out = dict(self._device_static)
+        m = obs.metrics()
+        if m is not None:
+            prefix, suffix = "dnn_tpu_boot_", "_seconds"
+            out.update({"boot_" + k[len(prefix):-len(suffix)] + "_s": v
+                        for k, v in list(m.gauges.items())
+                        if k.startswith(prefix) and k.endswith(suffix)})
+        return out
+
     def _statusz(self):
         """The /statusz payload: watchdog state when one runs, else None
         — the HTTP handler then falls back to its worker-liveness shape
@@ -1091,6 +1115,16 @@ class LMServer:
         else:
             s = dict(s)
         s["role"] = self.role
+        comps = dict(s.get("components") or {})
+        # what the chip is and what boot cost, beside the watchdog's
+        # probe verdict: a client learns the device without taking it.
+        # With no probe the component carries the facts and NO `state`:
+        # a device nobody probed is not reported as ok
+        comps["device"] = {
+            **(comps.get("device")
+               or {"detail": "facts only: no device probe runs"}),
+            **self._device_facts()}
+        s["components"] = comps
         if self._kvtier_on():
             # KV-tier residency rides /statusz (informational): the
             # FleetCollector's per-replica rows read it next to role

@@ -224,6 +224,11 @@ class PagedKV:
     # --- decode-row paths (per-layer views: pool (n_blocks, H, bp, D),
     #     tables (B, nb_max)) ------------------------------------------
 
+    # write_rows / gather_view / install_row carry `kv_pool.*` scopes and
+    # the attention after them `attn.paged_decode`: the names a device
+    # trace files this codec's operations under (chipbench/spans.py).
+
+    @jax.named_scope("kv_pool.write")
     def write_rows(self, c, k, v, pos, write_gate):
         """k/v (B, H, 1, D) at per-slot positions pos (B,); write_gate (B,)
         keeps inactive slots' LIVE state untouched. Physical target: block
@@ -265,6 +270,7 @@ class PagedKV:
         out["v"] = c["v"].at[blk, :, row].set(v[:, :, 0].astype(c["v"].dtype))
         return out
 
+    @jax.named_scope("kv_pool.gather")
     def gather_view(self, c, names=("k", "v")):
         """Dense (B, H, S_max, ...) views of every slot's logical cache —
         the einsum attention baseline (a paged Pallas kernel would skip
@@ -316,28 +322,30 @@ class PagedKV:
             k, v, ks, vs = self.gather_view(c, ("k", "v", "ks", "vs"))
         else:
             k, v = self.gather_view(c)
-        d = q.shape[-1]
-        s = jnp.einsum("bhtd,bhsd->bhts", q.astype(jnp.float32),
-                       k.astype(jnp.float32),
-                       preferred_element_type=jnp.float32)
-        if quant:
-            s = s * ks[:, :, None, :]
-        s = s / jnp.sqrt(d)
-        cols = jnp.arange(k.shape[2])
-        mask = band_keep(cols[None, None, None, :],
-                         pos[:, None, None, None], self.window)
-        s = jnp.where(mask, s, _NEG_BIG)
-        p = jax.nn.softmax(s, axis=-1)
-        if quant:
-            p = p * vs[:, :, None, :]
-        out = jnp.einsum("bhts,bhsd->bhtd", p.astype(jnp.float32),
-                         v.astype(jnp.float32),
-                         preferred_element_type=jnp.float32)
-        return out if quant else out.astype(c["v"].dtype)
+        with jax.named_scope("attn.paged_decode"):
+            d = q.shape[-1]
+            s = jnp.einsum("bhtd,bhsd->bhts", q.astype(jnp.float32),
+                           k.astype(jnp.float32),
+                           preferred_element_type=jnp.float32)
+            if quant:
+                s = s * ks[:, :, None, :]
+            s = s / jnp.sqrt(d)
+            cols = jnp.arange(k.shape[2])
+            mask = band_keep(cols[None, None, None, :],
+                             pos[:, None, None, None], self.window)
+            s = jnp.where(mask, s, _NEG_BIG)
+            p = jax.nn.softmax(s, axis=-1)
+            if quant:
+                p = p * vs[:, :, None, :]
+            out = jnp.einsum("bhts,bhsd->bhtd", p.astype(jnp.float32),
+                             v.astype(jnp.float32),
+                             preferred_element_type=jnp.float32)
+            return out if quant else out.astype(c["v"].dtype)
 
     # --- prefill install (full-cache view: pool (L, n_blocks, H, bp, D),
     #     tables (L, B, nb_max)) ---------------------------------------
 
+    @jax.named_scope("kv_pool.install")
     def install_row(self, cache, row, blk_ids):
         """Scatter a finished transient row cache (the dense chunked-
         prefill output, leaves (L, 1, H, row_len, D)) into the physical

@@ -42,6 +42,7 @@ key sequence.)
 
 from __future__ import annotations
 
+import functools
 import time
 from typing import Dict, List, Optional
 
@@ -51,6 +52,7 @@ import numpy as np
 from jax import lax
 
 from dnn_tpu import obs
+from dnn_tpu.obs import profile as _profile
 from dnn_tpu.obs.profile import annotation_ctx as _prof_annotation
 from dnn_tpu.models.gpt import GPTConfig, head
 from dnn_tpu.utils.metrics import Throughput, labeled
@@ -76,18 +78,24 @@ def _decode_block_rows(bp, x, layer_cache, pos, write, *, cfg, compute_dtype,
     The cache codec (float or int8 — dnn_tpu/runtime/kvcache.py) owns the
     per-row write/attend; `ffn(bp, h)` overrides the dense MLP (MoE
     serving, dnn_tpu/runtime/generate_moe.moe_cache_ffn)."""
-    h = layer_norm(bp["ln_1"], x, eps=cfg.ln_eps)
-    q, k, v = _qkv_heads(bp, h, cfg=cfg, compute_dtype=compute_dtype)
-    layer_cache = codec.write_rows(layer_cache, k, v, pos, write)
-    y = codec.attend_rows(q, layer_cache, pos)
-    x = x + linear(bp["attn"]["proj"], merge_heads(y.astype(x.dtype)),
-                   compute_dtype=compute_dtype)
-    h = layer_norm(bp["ln_2"], x, eps=cfg.ln_eps)
-    if ffn is None:
-        m = linear(bp["mlp"]["proj"], gelu(linear(bp["mlp"]["fc"], h, compute_dtype=compute_dtype)),
-                   compute_dtype=compute_dtype)
-    else:
-        m = ffn(bp, h).astype(x.dtype)
+    # the same scope names as models/gpt._block_core, so a device trace
+    # files the cached step's matmuls with the plain forward's
+    with jax.named_scope("gpt.block.attn"):
+        h = layer_norm(bp["ln_1"], x, eps=cfg.ln_eps)
+        q, k, v = _qkv_heads(bp, h, cfg=cfg, compute_dtype=compute_dtype)
+        layer_cache = codec.write_rows(layer_cache, k, v, pos, write)
+        y = codec.attend_rows(q, layer_cache, pos)
+        x = x + linear(bp["attn"]["proj"], merge_heads(y.astype(x.dtype)),
+                       compute_dtype=compute_dtype)
+    with jax.named_scope("gpt.block.mlp"):
+        h = layer_norm(bp["ln_2"], x, eps=cfg.ln_eps)
+        if ffn is None:
+            m = linear(bp["mlp"]["proj"],
+                       gelu(linear(bp["mlp"]["fc"], h,
+                                   compute_dtype=compute_dtype)),
+                       compute_dtype=compute_dtype)
+        else:
+            m = ffn(bp, h).astype(x.dtype)
     return x + m, layer_cache
 
 
@@ -188,7 +196,8 @@ class GPTFamilyRows:
                 m = self.ffn(bp, h).astype(carry.dtype)
             return carry + m, layer_cache
 
-        x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
+        with jax.named_scope("layers.scan"):
+            x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
         logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                       compute_dtype=compute_dtype)
         return logits, new_cache
@@ -210,12 +219,33 @@ class GPTFamilyRows:
             )
             return y, layer_cache
 
-        x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache),
-                                unroll=cfg.n_layer if self.unroll_layers
-                                else 1)
+        # `layers.scan` is what a device trace files the loop's OWN work
+        # under: slicing each layer's weights and pool out of the stacks
+        # and writing the pool slice back (and the layout copies the
+        # compiler hangs on those); the block's work carries the inner
+        # scopes (gpt.block.*, attn.*, kv_pool.*)
+        with jax.named_scope("layers.scan"):
+            x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache),
+                                    unroll=cfg.n_layer if self.unroll_layers
+                                    else 1)
         logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                       compute_dtype=compute_dtype)
         return logits[:, -1], new_cache
+
+
+def _admit_span(submit):
+    """`submit()` as an `admit` annotation (stat `prompt_len`) in a
+    recording profiler capture, entry to return: the parent of the
+    `admit.prefill` / `admit.first_token` / `admit.install` spans the
+    body opens, which carry the `rid` it assigns. One attribute check
+    when no capture records."""
+    @functools.wraps(submit)
+    def traced(self, prompt, *args, **kw):
+        if not _profile._capturing:
+            return submit(self, prompt, *args, **kw)
+        with _prof_annotation("admit", prompt_len=int(np.size(prompt))):
+            return submit(self, prompt, *args, **kw)
+    return traced
 
 
 class ContinuousBatcher:
@@ -783,38 +813,41 @@ class ContinuousBatcher:
             construction — the mixed==convoy token-parity contract."""
             logits, new_cache = self.family.decode_rows(
                 prepared, cache, tok, pos, active, codec)
-            # repetition penalty on raw logits (HF order: before the
-            # temperature/filters inside _sample_rows); rows at the
-            # neutral 1.0 pass through bit-identically. ONE formula for
-            # solo and pool paths: generate.apply_repetition_penalty
-            b = logits.shape[0]
-            rp_on = rep != 1.0
-            lg = apply_repetition_penalty(
-                logits, rp_on[:, None] & seen, rep[:, None])
-            if self._allow_bias:
-                lg = lg + bias
-            if self._allow_constraints:
-                lg = jnp.where(ctable[crow], lg, _NEG_BIG)
-            # advance each slot's own stream; sample each row with its key
-            split = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
-            new_keys, subs = split[:, 0], split[:, 1]
-            # inactive slots sample greedy (result discarded below): a
-            # RETIRED sampled request's stale temperature must not keep
-            # an otherwise-greedy pool on the filtered-sampling branch
-            nxt = _sample_rows(lg, subs,
-                               temperature=jnp.where(active, temp, 0.0),
-                               top_k=tk, top_p=tp, min_p=mp)
-            nxt = jnp.where(active, nxt, tok)
-            new_keys = jnp.where(active[:, None], new_keys, keys)
-            seen_upd = seen.at[jnp.arange(b), nxt].set(True)
-            new_seen = jnp.where(active[:, None], seen_upd, seen)
-            if self._allow_constraints:
-                # device DFA walk: self-loop closure (trans_table) makes
-                # the gather total over masked-off tokens AND eos, so a
-                # stale overlap step replays to the same state
-                new_crow = jnp.where(active, ctrans[crow, nxt], crow)
-            else:
-                new_crow = crow
+            # `sample` names the sampling tail on a device trace (the
+            # scopes of chipbench/spans.py; the model's own are gpt.*)
+            with jax.named_scope("sample"):
+                # repetition penalty on raw logits (HF order: before the
+                # temperature/filters inside _sample_rows); rows at the
+                # neutral 1.0 pass through bit-identically. ONE formula for
+                # solo and pool paths: generate.apply_repetition_penalty
+                b = logits.shape[0]
+                rp_on = rep != 1.0
+                lg = apply_repetition_penalty(
+                    logits, rp_on[:, None] & seen, rep[:, None])
+                if self._allow_bias:
+                    lg = lg + bias
+                if self._allow_constraints:
+                    lg = jnp.where(ctable[crow], lg, _NEG_BIG)
+                # advance each slot's own stream; sample each row with its key
+                split = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
+                new_keys, subs = split[:, 0], split[:, 1]
+                # inactive slots sample greedy (result discarded below): a
+                # RETIRED sampled request's stale temperature must not keep
+                # an otherwise-greedy pool on the filtered-sampling branch
+                nxt = _sample_rows(lg, subs,
+                                   temperature=jnp.where(active, temp, 0.0),
+                                   top_k=tk, top_p=tp, min_p=mp)
+                nxt = jnp.where(active, nxt, tok)
+                new_keys = jnp.where(active[:, None], new_keys, keys)
+                seen_upd = seen.at[jnp.arange(b), nxt].set(True)
+                new_seen = jnp.where(active[:, None], seen_upd, seen)
+                if self._allow_constraints:
+                    # device DFA walk: self-loop closure (trans_table) makes
+                    # the gather total over masked-off tokens AND eos, so a
+                    # stale overlap step replays to the same state
+                    new_crow = jnp.where(active, ctrans[crow, nxt], crow)
+                else:
+                    new_crow = crow
             out = (new_cache, pos + active.astype(jnp.int32), nxt, new_keys,
                    new_seen, new_crow)
             if logprobs_k:
@@ -871,18 +904,19 @@ class ContinuousBatcher:
             empty placeholder). `crow` (scalar) indexes this request's
             start-state row in the constraint mask pool (0 =
             unconstrained) so the FIRST token obeys the grammar too."""
-            lg = logits[:, last_local][0:1]  # (1, V)
-            raw = lg
-            lg = apply_repetition_penalty(
-                lg, (rep != 1.0) & seen_row[None, :], rep)
-            if self._allow_bias:
-                lg = lg + bias_row[None, :]
-            if self._allow_constraints:
-                lg = jnp.where(ctable[crow][None, :], lg, _NEG_BIG)
-            first = _sample_rows(
-                lg, rng[None], temperature=temp[None], top_k=tk[None],
-                top_p=tp[None], min_p=mp[None],
-            )[0]
+            with jax.named_scope("sample"):
+                lg = logits[:, last_local][0:1]  # (1, V)
+                raw = lg
+                lg = apply_repetition_penalty(
+                    lg, (rep != 1.0) & seen_row[None, :], rep)
+                if self._allow_bias:
+                    lg = lg + bias_row[None, :]
+                if self._allow_constraints:
+                    lg = jnp.where(ctable[crow][None, :], lg, _NEG_BIG)
+                first = _sample_rows(
+                    lg, rng[None], temperature=temp[None], top_k=tk[None],
+                    top_p=tp[None], min_p=mp[None],
+                )[0]
             # the row cache is chunk-rounded (possibly > the pool); only
             # the pool's own position count installs — the overhang holds
             # nothing but tail-pad garbage (real prompt tokens always fit:
@@ -1095,18 +1129,19 @@ class ContinuousBatcher:
                 crosses to host, and even that readback is deferred to
                 the next step's commit — admission costs zero blocking
                 syncs."""
-                lg = logits[:, last_local][0:1]  # (1, V)
-                raw = lg
-                lg = apply_repetition_penalty(
-                    lg, (rp != 1.0) & seen_row[None, :], rp)
-                if self._allow_bias:
-                    lg = lg + b_row[None, :]
-                if self._allow_constraints:
-                    lg = jnp.where(ctable[c_row][None, :], lg, _NEG_BIG)
-                first = _sample_rows(
-                    lg, rng[None], temperature=t[None], top_k=k[None],
-                    top_p=p[None], min_p=mp_[None],
-                )[0]
+                with jax.named_scope("sample"):
+                    lg = logits[:, last_local][0:1]  # (1, V)
+                    raw = lg
+                    lg = apply_repetition_penalty(
+                        lg, (rp != 1.0) & seen_row[None, :], rp)
+                    if self._allow_bias:
+                        lg = lg + b_row[None, :]
+                    if self._allow_constraints:
+                        lg = jnp.where(ctable[c_row][None, :], lg, _NEG_BIG)
+                    first = _sample_rows(
+                        lg, rng[None], temperature=t[None], top_k=k[None],
+                        top_p=p[None], min_p=mp_[None],
+                    )[0]
                 if self._paged:
                     cache = codec.install_row(cache, row, install_ids)
                 else:
@@ -1206,6 +1241,7 @@ class ContinuousBatcher:
     def n_active(self) -> int:
         return sum(r is not None for r in self._slot_req)
 
+    @_admit_span
     def submit(self, prompt, max_new_tokens: int,
                seed: Optional[int] = None, *,
                temperature: Optional[float] = None,
@@ -1272,9 +1308,13 @@ class ContinuousBatcher:
         weights)."""
         # step-timeline: this submit's whole wall (validation, slot
         # install, prefill chunks, first-token sample) is the "admit"
-        # phase, attached to the NEXT step's record in note_admit
+        # phase, attached to the NEXT step's record in note_admit —
+        # with the seconds of its prefill / first_token / install parts
+        # (timeline.ADMIT_PARTS), stamped below where each begins and
+        # ends; the rest of the wall is admission's own host time
         _sc = self.step_clock
         _t_sub = time.perf_counter() if _sc is not None else 0.0
+        _parts = (0.0, 0.0, 0.0)
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("prompt must have at least one token")
@@ -1669,6 +1709,8 @@ class ContinuousBatcher:
             t_pf = time.perf_counter()  # the PREFILL interval only —
             # submit-entry-to-here is validation/slot/host bookkeeping,
             # which belongs to the admit span, not this metric
+            _sp = _profile.open_span("admit.prefill", rid=rid,
+                                     chunks=n_chunks - start_chunk)
             chunks_before = self.prefill_chunks_run
             last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
             kv_boundary_rows: dict = {}
@@ -1725,6 +1767,8 @@ class ContinuousBatcher:
                           else c_off + constraint.start),
                 self._ctable,
             )
+            t_pf1 = time.perf_counter()  # both programs are dispatched
+            _profile.close_span(_sp)
             if use_radix:
                 # insert this prompt's full-block path now that the
                 # install has populated the owned blocks. The store
@@ -1747,7 +1791,16 @@ class ContinuousBatcher:
                 self.cache, first, c_lp, t_lp, t_ids = fin
             else:
                 self.cache, first = fin
+            # the device-to-host read: where the host waits for the
+            # prefill (admit.first_token)
+            t_ft0 = time.perf_counter()
+            _sp = _profile.open_span("admit.first_token", rid=rid)
             first = int(first)  # blocks until the prefill really finished
+            if logprobs and self._logprobs_k:
+                first_lp = (float(np.asarray(c_lp)[0]),
+                            (np.asarray(t_ids)[0], np.asarray(t_lp)[0]))
+            t_ft1 = time.perf_counter()
+            _profile.close_span(_sp)
             sp_pf.end()
             m = obs.metrics()
             if m is not None:
@@ -1799,6 +1852,10 @@ class ContinuousBatcher:
                     self._kvlens.note_prefill(
                         self.prefill_chunks_run - chunks_before,
                         time.perf_counter() - t_pf)
+            # the eager per-slot scatters (admit.install): each is a
+            # small program of its own, dispatched from here
+            t_in0 = time.perf_counter()
+            _sp = _profile.open_span("admit.install", rid=rid)
             self.pos = self.pos.at[slot].set(len(prompt))
             self.tok = self.tok.at[slot].set(first)
             self.active = self.active.at[slot].set(True)
@@ -1811,6 +1868,9 @@ class ContinuousBatcher:
             self._seen = self._seen.at[slot].set(
                 seen_row.at[first].set(True))
             self._bias = self._bias.at[slot].set(b_row)
+            _profile.close_span(_sp)
+            _parts = (t_pf1 - t_pf, t_ft1 - t_ft0,
+                      time.perf_counter() - t_in0)
             if self._lora is not None and self._aid[slot] != aid:
                 self._aid[slot] = aid
                 self._decode_view = self._lora_prepared(self._aid)
@@ -1830,8 +1890,8 @@ class ContinuousBatcher:
                 req["c_off"] = c_off
                 self._note_constrained(+1)
             if req["logprobs"]:
-                req["lp"] = [float(np.asarray(c_lp)[0])]
-                req["lp_top"] = [(np.asarray(t_ids)[0], np.asarray(t_lp)[0])]
+                req["lp"] = [first_lp[0]]
+                req["lp_top"] = [first_lp[1]]
             if trace:
                 req["trace"] = trace  # step() hangs decode spans off this
             req["t_last"] = time.perf_counter()  # inter-token clock
@@ -1854,8 +1914,12 @@ class ContinuousBatcher:
                 # grammar constrains GENERATED tokens only, so the
                 # adopted prefix's state is still `start`.
                 self._constraint_advance(slot, first)
-                self._crow = self._crow.at[slot].set(
-                    jnp.int32(c_off + req["c_state"]))
+                with _prof_annotation("admit.install", rid=rid):
+                    t_in0 = time.perf_counter()
+                    self._crow = self._crow.at[slot].set(
+                        jnp.int32(c_off + req["c_state"]))
+                    _parts = _parts[:2] + (
+                        _parts[2] + time.perf_counter() - t_in0,)
             # a prompt longer than the window rolls blocks out at install
             self._free_rolled_blocks(slot)
             self._retire_if_done(slot)
@@ -1887,7 +1951,7 @@ class ContinuousBatcher:
         finally:
             adm.end()
             if _sc is not None:
-                _sc.note_admit(_t_sub)
+                _sc.note_admit(_t_sub, _parts)
 
     def _ensure_cache_len(self, need: int):
         """Grow the bucketed dense pool to the smallest ladder bucket
@@ -2965,10 +3029,10 @@ class ContinuousBatcher:
                 out[req["rid"]] = (committed[0] if len(committed) == 1
                                    else committed)
         if rec is not None:
-            rec.marks.append(("commit", time.perf_counter()))
+            sc.mark(rec, "commit")
         self._obs_step_end(m, n_adv, it_samples)
         if rec is not None:
-            rec.marks.append(("obs", time.perf_counter()))
+            sc.mark(rec, "obs")
             sc.end(rec, n_adv)
         return out
 
@@ -3003,12 +3067,11 @@ class ContinuousBatcher:
         yet) — shared by the dense and speculative step loops so the
         StepClock phase protocol stays identical across batchers."""
         if rec is not None:
-            t = time.perf_counter()
-            rec.marks.append(("wait", t))
-            rec.marks.append(("commit", t))
+            sc.mark(rec, "wait")
+            sc.mark(rec, "commit")
         self._obs_step_end(obs.metrics(), 0, None)
         if rec is not None:
-            rec.marks.append(("obs", time.perf_counter()))
+            sc.mark(rec, "obs")
             sc.end(rec, 0)
         return {}
 
@@ -3021,13 +3084,13 @@ class ContinuousBatcher:
         if self._inflight is None:
             return {}
         sc = self.step_clock
-        rec = sc.begin() if sc is not None else None
+        rec = sc.begin("wait") if sc is not None else None
         p_idx, p_tok, p_lps = self._inflight
         self._inflight = None
         toks = np.asarray(p_tok)
         c_lp, t_lp, t_ids = self._lp_host(p_lps)
         if rec is not None:
-            rec.marks.append(("wait", time.perf_counter()))
+            sc.mark(rec, "wait")
         return self._commit_step(p_idx, toks, c_lp, t_lp, t_ids, rec, sc)
 
     def step(self) -> Dict[int, int]:
@@ -3054,11 +3117,10 @@ class ContinuousBatcher:
                 self._ensure_cache_len(need)
         ilv = self._ilv_next() if self._ilv else None
         if rec is not None:
-            rec.marks.append(("host", time.perf_counter()))
-        # host annotation: a POST /profilez capture shows each pool step
-        # as a named block on the host track (obs/profile.annotation_ctx
-        # — the non-generator form; ~6 µs on / ~0.2 µs off, inside the
-        # <2% obs budget)
+            sc.mark(rec, "host")
+        # (while a POST /profilez capture records, the clock writes each
+        # phase into it as a `step.<phase>` annotation: `rec.spans`,
+        # obs/timeline._StepSpans — `step.dispatch` is this call)
         # one shared positional block for both dispatch forms — the
         # mixed program's decode leg takes the decode step's exact
         # argument order (donate_argnums indices align by construction)
@@ -3066,15 +3128,14 @@ class ContinuousBatcher:
                  self._temp, self._topk, self._topp, self._minp,
                  self._rep, self._seen, self._bias, self._crow,
                  self._ctable, self._ctrans)
-        with _prof_annotation("serving.decode_step"):
-            if ilv is None:
-                res = self._decode(self._decode_view, *state)
-            else:
-                res = self._mixed(
-                    self._decode_view,
-                    self._lora_prefill_view(ilv["p"]["aid"]), *state,
-                    ilv["p"]["row"], ilv["chunk"], ilv["start"])
-                res, pf_logits, new_row = res[:-2], res[-2], res[-1]
+        if ilv is None:
+            res = self._decode(self._decode_view, *state)
+        else:
+            res = self._mixed(
+                self._decode_view,
+                self._lora_prefill_view(ilv["p"]["aid"]), *state,
+                ilv["p"]["row"], ilv["chunk"], ilv["start"])
+            res, pf_logits, new_row = res[:-2], res[-2], res[-1]
         # drop the tuple's references to the just-donated buffers NOW:
         # holding them to frame teardown makes their deletion run after
         # the step record closes, and deleting a donated-but-pending
@@ -3083,7 +3144,7 @@ class ContinuousBatcher:
         # timeline probe's coverage assert caught it)
         del state
         if rec is not None:
-            rec.marks.append(("dispatch", time.perf_counter()))
+            sc.mark(rec, "dispatch")
             rec.mixed = ilv is not None
         lp_refs = None
         if self._logprobs_k:
@@ -3116,7 +3177,7 @@ class ContinuousBatcher:
                 # with the pipeline live, "wait" is only the RESIDUAL
                 # unhidden device time of step N-1 — the hiding the
                 # dispatch_slack gauge predicted, verified here
-                rec.marks.append(("wait", time.perf_counter()))
+                sc.mark(rec, "wait")
             return self._commit_step(p_idx, toks, c_lp, t_lp, t_ids,
                                      rec, sc)
         toks = np.asarray(self.tok)
@@ -3124,7 +3185,7 @@ class ContinuousBatcher:
         if rec is not None:
             # the np.asarray above is the per-token device->host sync:
             # dispatch-return -> committed-tokens-on-host is the "wait"
-            rec.marks.append(("wait", time.perf_counter()))
+            sc.mark(rec, "wait")
         return self._commit_step(s_idx, toks, c_lp, t_lp, t_ids, rec, sc)
 
     def drain(self) -> Dict[int, np.ndarray]:

@@ -596,10 +596,10 @@ class SpeculativeBatcher(ContinuousBatcher):
                                  samples=it_samples)
             out[req["rid"]] = emitted
         if rec is not None:
-            rec.marks.append(("commit", time.perf_counter()))
+            sc.mark(rec, "commit")
         self._obs_step_end(obs_m, n_adv, it_samples)
         if rec is not None:
-            rec.marks.append(("obs", time.perf_counter()))
+            sc.mark(rec, "obs")
             sc.end(rec, n_adv)
         return out
 
@@ -610,12 +610,12 @@ class SpeculativeBatcher(ContinuousBatcher):
         if self._inflight is None:
             return {}
         sc = self.step_clock
-        rec = sc.begin() if sc is not None else None
+        rec = sc.begin("wait") if sc is not None else None
         s_idx, w_ref, m_ref = self._inflight
         self._inflight = None
         w_np, m_np = np.asarray(w_ref), np.asarray(m_ref)
         if rec is not None:
-            rec.marks.append(("wait", time.perf_counter()))
+            sc.mark(rec, "wait")
         return self._commit_spec(s_idx, w_np, m_np, rec, sc)
 
     def step(self):
@@ -643,7 +643,7 @@ class SpeculativeBatcher(ContinuousBatcher):
                 self._ensure_cache_len(need)
         ilv = self._ilv_next() if self._ilv else None
         if rec is not None:
-            rec.marks.append(("host", time.perf_counter()))
+            sc.mark(rec, "host")
         if ilv is None:
             (self.cache, self.d_cache, self.tok, self.pos, self.keys,
              self.prev_chunk, self.prev_pos, w, m) = self._spec_step(
@@ -660,7 +660,7 @@ class SpeculativeBatcher(ContinuousBatcher):
                 self.keys, self.prev_chunk, self.prev_pos,
                 p["row"], p["d_row"], ilv["chunk"], ilv["start"])
         if rec is not None:
-            rec.marks.append(("dispatch", time.perf_counter()))
+            sc.mark(rec, "dispatch")
             rec.mixed = ilv is not None
         s_idx = self._step_idx
         self._step_idx += 1
@@ -677,9 +677,9 @@ class SpeculativeBatcher(ContinuousBatcher):
             s_prev, w_prev, m_prev = prev
             w_np, m_np = np.asarray(w_prev), np.asarray(m_prev)
             if rec is not None:
-                rec.marks.append(("wait", time.perf_counter()))
+                sc.mark(rec, "wait")
             return self._commit_spec(s_prev, w_np, m_np, rec, sc)
         w_np, m_np = np.asarray(w), np.asarray(m)
         if rec is not None:
-            rec.marks.append(("wait", time.perf_counter()))
+            sc.mark(rec, "wait")
         return self._commit_spec(s_idx, w_np, m_np, rec, sc)

@@ -87,10 +87,10 @@ _MODULE_COST_S = {
     "test_obs_v2": 36.0,  # obs v2 (flight recorder, watchdog, /profilez,
     # memory watermarks): the wedged-probe and crash-dump subprocess legs
     # dominate; placed with test_obs inside the tier-1 budget
-    "test_obs_timeline": 12.0,  # ISSUE 11 step-timeline attribution:
+    "test_obs_timeline": 17.0,  # ISSUE 11 step-timeline attribution:
     # StepClock phase arithmetic (injected clock), capture-analysis
-    # goldens over synthetic Perfetto JSON, one real profiler capture
-    # with sidecar-meta alignment, /stepz scrape, CLI smoke — cheap,
+    # goldens over synthetic Perfetto JSON, real profiler captures
+    # holding the step.* / admit* spans, /stepz scrape, CLI smoke — cheap,
     # certified early in the tier-1 budget with the other obs modules
     "test_obs_kvlens": 12.0,  # ISSUE 18 memory-economy observatory:
     # MRC goldens at rate=1 (exact LRU), sampling determinism, thrash

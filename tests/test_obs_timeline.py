@@ -10,8 +10,13 @@ Covers the layer's contracts:
     stepped pool records nothing);
   * analyze() goldens over synthetic Perfetto JSON, including
     truncated/garbage inputs failing loud;
-  * step<->capture alignment via the profile.py sidecar meta;
-  * /stepz scrape (JSON + ?format=prom + ?format=trace);
+  * the steps and admissions IN a capture (ISSUE 24): `step.*` /
+    `admit*` annotations on the worker's line of the `.xplane.pb`,
+    nested, with `step` / `rid` stats — and none with no capture
+    recording or with the obs gate off;
+  * the admit split and the cumulative totals that are exact at a
+    /metrics scrape between two flushes;
+  * /stepz scrape (JSON + ?format=prom);
   * CLI smoke (`python -m dnn_tpu.obs timeline --selftest`).
 """
 
@@ -113,7 +118,7 @@ def test_registry_histograms_and_gauges_land():
         _drive(clk, t)
     clk.flush()  # batched flush: tests force it (FLUSH_EVERY is 32)
     snap = reg.snapshot()
-    assert snap["counters"]["step.steps_total"] == 3
+    assert snap["gauges"]["step.steps_total"] == 3
     h = snap["histogram"]['step.phase_seconds{phase="wait"}']
     assert h["count"] == 3
     assert snap["histogram"]["step.wall_seconds"]["count"] == 3
@@ -129,31 +134,79 @@ def test_registry_histograms_and_gauges_land():
 
 def test_summary_flushes_pending():
     """A scrape must never read a stale histogram: summary() flushes
-    the batch even below FLUSH_EVERY."""
+    the batch even below FLUSH_EVERY. The cumulative totals need no
+    flush at all."""
     reg = Metrics()
     clk, t = _fake_clock(registry=reg)
     _drive(clk, t)
-    assert reg.snapshot()["counters"].get("step.steps_total") is None
+    snap = reg.snapshot()
+    assert snap["gauges"]["step.steps_total"] == 1
+    assert "histogram" not in snap
     clk.summary()
-    assert reg.snapshot()["counters"]["step.steps_total"] == 1
+    assert reg.snapshot()["histogram"]["step.wall_seconds"]["count"] == 1
 
 
-def test_chrome_trace_phase_slices():
+def test_admit_split_sums_to_admit_phase():
+    """(b) the four parts of `admit_split` sum to the `admit` phase, and
+    pure_host_s is host_s less the prefill an admission dispatches and
+    waits for."""
     clk, t = _fake_clock()
-    _drive(clk, t, admit=0.0005)
+    t[0] += 0.010
+    clk.note_admit(t[0] - 0.010, (0.004, 0.003, 0.002))
     _drive(clk, t)
-    ct = clk.chrome_trace()
-    xs = [e for e in ct["traceEvents"] if e.get("ph") == "X"]
-    assert len(xs) == 11  # 5 phases x 2 steps + 1 admit slice
-    names = [e["name"] for e in xs if e["args"].get("step") == 0]
-    assert names[0] == "admit"
-    # in-step slices are contiguous: each starts where the last ended
-    step0 = [e for e in xs if e["args"].get("step") == 0
-             and e["name"] != "admit"]
-    for a, b in zip(step0, step0[1:]):
-        assert b["ts"] == pytest.approx(a["ts"] + a["dur"], abs=1e-3)
-    assert {e["name"] for e in ct["traceEvents"]
-            if e.get("ph") == "M"} == {"process_name", "thread_name"}
+    _drive(clk, t, admit=0.002)  # an admission that reports no parts
+    s = clk.summary()
+    split = s["admit_split"]
+    assert set(split) == set(tl.ADMIT_PARTS)
+    assert sum(split.values()) == pytest.approx(s["phases"]["admit"]["s"])
+    assert split == pytest.approx({"self": 0.003, "prefill": 0.004,
+                                   "first_token": 0.003,
+                                   "install": 0.002})
+    assert s["pure_host_s"] == pytest.approx(s["host_s"] - 0.007)
+    assert s["pure_host_s"] <= s["host_s"]
+    assert s["pure_host_s"] == pytest.approx(
+        split["self"] + split["install"] + sum(
+            s["phases"][p]["s"] for p in ("host", "commit", "obs")))
+    # the same split, cumulative
+    assert clk.admit_seconds_total == pytest.approx(split)
+
+
+def test_cumulative_series_exact_between_flushes():
+    """(c) drive 5 steps (FLUSH_EVERY is 32, so nothing was billed),
+    scrape /metrics: the totals read 5, and the scrape — which renders
+    the gauges under the registry's lock — does not deadlock."""
+    from dnn_tpu.utils.metrics import render_prometheus
+
+    reg = Metrics()
+    clk, t = _fake_clock(registry=reg)
+    for i in range(5):
+        _drive(clk, t, admit=0.0005 if i == 0 else 0.0)
+    assert len(clk._pending_flush) == 5  # between two flushes
+    text = render_prometheus(reg)
+    series = dict(line.rsplit(" ", 1) for line in text.splitlines()
+                  if line and not line.startswith("#"))
+    assert float(series["step_steps_total"]) == 5
+    assert float(series["step_tokens_advanced_total"]) == 20
+    assert float(series['step_phase_seconds_total{phase="wait"}']) == \
+        pytest.approx(5 * 0.004)
+    assert float(series['step_phase_seconds_total{phase="admit"}']) == \
+        pytest.approx(0.0005)
+    assert float(series['step_admit_seconds_total{part="self"}']) == \
+        pytest.approx(0.0005)
+    assert "step_steps_total" not in reg.snapshot()["counters"]
+    # a scrape from INSIDE a registry render: a gauge whose callable
+    # scrapes the clock's own gauges again while the lock is held
+    reg.set_fn("outer", lambda: clk._gauges["step.steps_total"]()
+               + clk.host_fraction())
+    import threading
+
+    out = []
+    th = threading.Thread(target=lambda: out.append(reg.snapshot()),
+                          daemon=True)
+    th.start()
+    th.join(10)
+    assert not th.is_alive(), "a /metrics scrape deadlocked"
+    assert out[0]["gauges"]["outer"] > 5
 
 
 def test_metrics_bulk_hists():
@@ -330,7 +383,7 @@ def test_analyze_synthetic_golden(tmp_path):
     assert a["top_ops"][0]["frac_of_device"] == pytest.approx(1.0)
     # host python track exists and is distinct from the device ops
     assert any("python" in k for k in a["tracks"])
-    assert a["steps"] is None  # no meta -> no step section
+    assert "steps" not in a  # the steps are IN a capture (annotations)
 
 
 def test_analyze_plain_json_equals_gzip(tmp_path):
@@ -369,36 +422,52 @@ def test_analyze_rejects_garbage_and_truncated(tmp_path):
         analyze(str(empty))
 
 
-def test_step_capture_alignment_via_meta(tmp_path):
-    """Synthetic meta + synthetic clock records: each 10 ms step holds
-    one 6 ms device op -> per-step overlap 6/10, steps_in_capture from
-    the counter range."""
+def test_meta_bounds_the_analysis_window(tmp_path):
+    """With a sidecar meta the window is the ARMED window, not the
+    event span (a first capture's profiler init is not idle time)."""
     d = str(tmp_path)
     _synthetic_trace(d, meta={"perf_begin": 100.0, "perf_end": 100.032,
                               "step_begin": 5, "step_end": 8,
                               "backend": "cpu"})
-    clk, t = _fake_clock()
-    t[0] = 100.0015  # first step entry aligns with the first device op
-    for _ in range(3):
-        _drive(clk, t, host=0.0, dispatch=0.002, wait=0.004,
-               commit=0.002, obs_p=0.002)  # wall 10 ms
-        # no gap: steps are back to back like the synthetic ops
-    a = analyze(d, clock=clk)
-    st = a["steps"]
-    assert st["aligned"] and st["n_steps"] == 3
-    assert st["steps_in_capture"] == 3
-    assert st["backend"] == "cpu"
-    assert st["mean_wall_ms"] == pytest.approx(10.0, abs=1e-3)
-    assert st["mean_device_busy_ms"] == pytest.approx(6.0, abs=1e-2)
-    assert st["device_overlap_frac"] == pytest.approx(0.6, abs=1e-3)
-    # with meta, the window is the ARMED window, not the event span
+    a = analyze(d)
     assert a["window_s"] == pytest.approx(0.032, abs=1e-6)
+    assert a["device"]["ops"] == 3
 
 
-def test_real_capture_sidecar_meta_and_alignment(pool, tmp_path):
-    """End to end on a REAL jax.profiler capture: profile.py writes the
-    sidecar meta (perf bounds, step range, backend), and analyze()
-    places the pool's steps inside it."""
+def _host_spans(capture_dir):
+    """{line name: [(name, start_ns, end_ns, stats)]} of the `step*` /
+    `admit*` annotations on the capture's /host:CPU plane."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    path = glob.glob(os.path.join(capture_dir, "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    out = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns,
+                    dict(e.stats)) for e in line.events
+                   if e.name.split(".")[0] in ("step", "admit")]
+            if evs:
+                out[line.name] = evs
+    return out
+
+
+def _children(spans, parent):
+    _, p0, p1, _ = parent
+    return [s for s in spans if s is not parent and p0 <= s[1]
+            and s[2] <= p1]
+
+
+def test_capture_holds_step_and_admit_spans(pool, tmp_path):
+    """(a) End to end on a REAL jax.profiler capture (CPU backend): the
+    `.xplane.pb` holds the batcher's phases as annotations on ONE
+    thread's line, nested step > step.<phase> and admit > admit.<part>,
+    with `step` / `rid` stats; profile.py's sidecar meta carries the
+    step-counter range the `step` stats lie in."""
     from dnn_tpu.obs.profile import capture_step
 
     clock = StepClock(capacity=1024).install()
@@ -413,13 +482,142 @@ def test_real_capture_sidecar_meta_and_alignment(pool, tmp_path):
         assert meta["step_end"] == clock.steps_total
         assert meta["perf_end"] > meta["perf_begin"]
         assert meta["backend"] == "cpu"
-        a = analyze(path, clock=clock)
-        st = a["steps"]
-        assert st["aligned"], st
-        assert st["n_steps"] == clock.steps_total - before
-        assert 0.0 < st["device_overlap_frac"] <= 1.0
-        assert a["device"]["ops"] > 0
+        lines = _host_spans(path)
+        assert len(lines) == 1, list(lines)  # the worker's line
+        (spans,) = lines.values()
+        steps = [s for s in spans if s[0] == "step"]
+        assert len(steps) == clock.steps_total - before
+        assert [s[3]["step"] for s in steps] == list(
+            range(before, clock.steps_total))
+        for st in steps:
+            kids = _children(spans, st)
+            # the five phases, in order, each carrying the step's index
+            assert [k[0] for k in kids] == [
+                "step." + p for p in PHASES[1:]], kids
+            assert all(k[3]["step"] == st[3]["step"] for k in kids)
+            # contiguous: each phase starts where the last one ended
+            for a, b in zip(kids, kids[1:]):
+                assert a[2] <= b[1] <= a[2] + 1_000_000  # < 1 ms apart
+        admits = [s for s in spans if s[0] == "admit"]
+        assert len(admits) == pool.slots
+        rids = set()
+        for ad in admits:
+            assert ad[3]["prompt_len"] == 4
+            kids = _children(spans, ad)
+            assert [k[0] for k in kids] == [
+                "admit.prefill", "admit.first_token", "admit.install"]
+            assert kids[0][3]["chunks"] == 1
+            assert len({k[3]["rid"] for k in kids}) == 1
+            rids.add(kids[0][3]["rid"])
+        assert len(rids) == pool.slots  # one rid per admission
+        # no step lies inside an admission: they alternate on the thread
+        assert not any(_children(steps, ad) for ad in admits)
+        # the old name of step.dispatch is gone with its annotation
+        assert not any(s[0].startswith("serving.decode_step")
+                       for s in spans)
     finally:
+        pool.step_clock = None
+
+
+@pytest.mark.parametrize("spec", [False, True])
+def test_overlap_steps_keep_the_phase_order_in_a_capture(tmp_path, spec):
+    """The overlap pipeline's calls share the protocol: a filling
+    dispatch closes `wait` and `commit` empty, the trailing flush opens
+    in `wait`, and the speculative batcher's override does the same."""
+    import jax
+
+    from dnn_tpu.models import gpt
+    from dnn_tpu.obs.profile import capture_step
+
+    cfg = gpt.GPTConfig(block_size=32, vocab_size=128, n_layer=2,
+                        n_head=2, n_embd=64)
+    prepared = gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg),
+                                   cfg)
+    kw = dict(slots=2, max_len=32, prompt_pad=8, prefill_chunk_tokens=8,
+              overlap=True)
+    if spec:
+        from dnn_tpu.runtime.serving_spec import SpeculativeBatcher
+
+        srv = SpeculativeBatcher(cfg, prepared, cfg, prepared, spec_k=2,
+                                 **kw)
+    else:
+        from dnn_tpu.runtime.serving import ContinuousBatcher
+
+        srv = ContinuousBatcher(cfg, prepared, **kw)
+    clock = StepClock(capacity=256)
+    srv.step_clock = clock
+    _round(srv, new_tokens=5)  # warm
+    before = clock.steps_total
+    path, _ = capture_step(lambda: _round(srv, new_tokens=5),
+                           capture_root=str(tmp_path))
+    (spans,) = _host_spans(path).values()
+    steps = [s for s in spans if s[0] == "step"]
+    assert len(steps) == clock.steps_total - before
+    full = ["step." + p for p in PHASES[1:]]
+    shapes = set()
+    for st in steps:
+        kids = [k[0] for k in _children(spans, st)]
+        assert kids in (full, full[2:]), kids  # a flush opens in `wait`
+        shapes.add(len(kids))
+    assert shapes == {5, 3}
+    # interleaved admission dispatches nothing in submit(): an `admit`
+    # with no parts, all of it the admission's own host time
+    admits = [s for s in spans if s[0] == "admit"]
+    assert len(admits) == srv.slots
+    assert not any(s[0].startswith("admit.") for s in spans)
+    assert clock.admit_seconds_total["prefill"] == 0.0
+    assert clock.admit_seconds_total["self"] > 0.0
+    assert clock.summary()["mixed_steps"] > 0
+
+
+def test_no_annotation_without_a_recording_capture(pool, tmp_path,
+                                                   monkeypatch):
+    """(a) With no obs-driven capture recording the batcher opens no
+    annotation at all (rec.spans stays None; a bare start_trace sees
+    nothing), and none with the obs gate off even while one records."""
+    import jax
+
+    from dnn_tpu.obs import profile
+
+    opened = []
+    real = profile.annotation_ctx
+
+    def spy(name, **stats):
+        ctx = real(name, **stats)
+        if ctx is not profile._NULL_CTX:
+            opened.append(name)
+        return ctx
+
+    monkeypatch.setattr(profile, "annotation_ctx", spy)
+    clock = StepClock(capacity=64)
+    pool.step_clock = clock
+    try:
+        _round(pool, new_tokens=4)  # warm
+        assert not profile.capturing()
+        rec = clock.begin()
+        assert rec is not None and rec.spans is None
+        bare = tmp_path / "bare"
+        jax.profiler.start_trace(str(bare))  # not an obs-driven capture
+        try:
+            _round(pool, new_tokens=4)
+        finally:
+            jax.profiler.stop_trace()
+        assert opened == []
+        assert _host_spans(str(bare)) == {}
+        # gate off, capture recording: still nothing
+        obs.set_enabled(False)
+        path, _ = profile.capture_step(
+            lambda: _round(pool, new_tokens=4),
+            capture_root=str(tmp_path / "off"))
+        obs.set_enabled(True)
+        assert opened == []
+        assert _host_spans(path) == {}
+        # and with both on, the spy does see them
+        profile.capture_step(lambda: _round(pool, new_tokens=4),
+                             capture_root=str(tmp_path / "on"))
+        assert "step.dispatch" in opened and "admit.install" in opened
+    finally:
+        obs.set_enabled(True)
         pool.step_clock = None
 
 
@@ -427,7 +625,7 @@ def test_real_capture_sidecar_meta_and_alignment(pool, tmp_path):
 # /stepz + CLI
 # ----------------------------------------------------------------------
 
-def test_stepz_endpoint_json_prom_trace():
+def test_stepz_endpoint_json_prom():
     clk, t = _fake_clock()
     for _ in range(3):
         _drive(clk, t, admit=0.0005)
@@ -437,18 +635,18 @@ def test_stepz_endpoint_json_prom_trace():
         s = json.loads(urllib.request.urlopen(base, timeout=10).read())
         assert s["window_steps"] == 3
         assert s["phases"]["wait"]["mean_ms"] == pytest.approx(4.0)
+        assert s["admit_split"]["self"] == pytest.approx(0.0015)
+        assert s["pure_host_s"] == pytest.approx(s["host_s"])
         prom = urllib.request.urlopen(base + "?format=prom",
                                       timeout=10).read().decode()
         assert "dnn_tpu_step_host_fraction" in prom
         assert 'dnn_tpu_step_phase_frac{phase="wait"}' in prom
-        ct = json.loads(urllib.request.urlopen(
-            base + "?format=trace&last=2", timeout=10).read())
-        xs = [e for e in ct["traceEvents"] if e.get("ph") == "X"]
-        assert len(xs) == 12  # 2 steps x (5 phases + admit)
-        code = urllib.request.urlopen(
-            base + "?format=nope", timeout=10)
-    except urllib.error.HTTPError as e:
-        assert e.code == 400
+        # the host-only timeline went with StepClock.chrome_trace():
+        # the steps are in a POST /profilez capture now
+        with pytest.raises(urllib.error.HTTPError) as e:
+            urllib.request.urlopen(base + "?format=trace&last=2",
+                                   timeout=10)
+        assert e.value.code == 400
     finally:
         srv.close()
 

@@ -421,10 +421,14 @@ def test_profilez_auto_trigger_captures_annotated_step(lm_v2_server):
     tf = trace_files(caps[-1])
     assert tf, f"no trace.json.gz under {caps[-1]}"
     raw = gzip.open(tf[0]).read().decode(errors="replace")
-    assert "serving.decode_step" in raw  # the new annotation, in Perfetto
+    # the step's StepClock phases, written into the capture as
+    # annotations (the jit call is `step.dispatch`)
+    assert "step.dispatch" in raw
     events = [e for e in json.loads(raw)["traceEvents"]
-              if e.get("name") == "serving.decode_step"]
-    assert events and all(e.get("ph") == "X" for e in events)
+              if e.get("name") in ("step", "step.dispatch", "step.wait")]
+    assert {e["name"] for e in events} == {"step", "step.dispatch",
+                                           "step.wait"}
+    assert all(e.get("ph") == "X" for e in events)
 
 
 def test_concurrent_metrics_and_profilez_scrape_under_load(lm_v2_server):
@@ -494,6 +498,21 @@ def test_statusz_without_watchdog_reports_worker(tiny_gpt):
         assert st["state"] == "ok"
         assert st["components"]["worker"]["state"] == "ok"
         assert _get(base + "/healthz").status == 200
+        # the device component says what the chip is, probe or no probe;
+        # with no probe it carries no verdict, in JSON or as a gauge
+        dev = st["components"]["device"]
+        assert "state" not in dev
+        prom = _get(base + "/statusz?format=prom").read().decode()
+        assert 'component="worker"' in prom
+        assert 'component="device"' not in prom
+        assert dev["device_kind"] == "cpu" and dev["platform"] == "cpu"
+        assert dev["device_count"] >= 1
+        # ... and what boot cost, once node.py has published its gauges
+        obs.metrics().bulk(gauges={"dnn_tpu_boot_ready_total_seconds": 7.5,
+                                   "dnn_tpu_boot_imports_seconds": 1.25})
+        dev = json.load(_get(base + "/statusz"))["components"]["device"]
+        assert dev["boot_ready_total_s"] == 7.5
+        assert dev["boot_imports_s"] == 1.25
     finally:
         srv.close()
 
