@@ -211,8 +211,7 @@ def measure(light: bool = False) -> dict:
             dense = _leg(cfg, prepared, peak_f, peak_b,
                          new_tokens=new_tokens, kv="dense")
             pq = _leg(cfg, prepared, peak_f, peak_b,
-                      new_tokens=new_tokens, kv="paged", kv_dtype="int8",
-                      unroll_layers=True)
+                      new_tokens=new_tokens, kv="paged", kv_dtype="int8")
             row["dense_mbu"] = round(dense["mbu"], 4)
             row["paged_int8_mbu"] = round(pq["mbu"], 4)
             row["paged_int8_tokens_per_sec"] = pq["tokens_per_sec"]

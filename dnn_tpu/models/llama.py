@@ -1287,9 +1287,9 @@ class LlamaFamilyRows:
         cos, sin = cos[:, None, None, :], sin[:, None, None, :]
         q, k = _rope_apply(q, cos, sin, cfg), _rope_apply(k, cos, sin, cfg)
         q = _q_rescale(q, cfg)
-        layer_cache = codec.write_rows(layer_cache, k, v, pos, write)
         qg = q.reshape(b, kv, g, d)  # group rows share the slot's limit
-        y = codec.attend_rows(qg, layer_cache, pos, window=window)
+        y, layer_cache = codec.write_attend_rows(qg, layer_cache, k, v, pos,
+                                                 write, window=window)
         y = y.reshape(b, cfg.n_head, 1, d)
         o = linear(bp["attn"]["o"], merge_heads(y.astype(x.dtype)),
                    compute_dtype=compute_dtype)
@@ -1372,27 +1372,20 @@ class LlamaFamilyRows:
         return logits, new_cache
 
     def decode_rows(self, prepared, cache, tok, pos, active, codec):
+        from dnn_tpu.runtime.paged_kvcache import scan_blocks
+
         x = _scaled_embed(prepared, tok[:, None], self.cfg)  # (B, 1, C)
         if self.compute_dtype is not None:
             x = x.astype(self.compute_dtype)
 
-        if self._wins is None:
-            def layer(carry, layer_in):
-                bp, layer_cache = layer_in
-                y, layer_cache = self._block_rows(
-                    bp, carry, layer_cache, pos, active, codec)
-                return y, layer_cache
+        def block(bp, x, c, codec, window=None):
+            return self._block_rows(bp, x, c, pos, active, codec,
+                                    window=window)
 
-            x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
-        else:
-            def layer_w(carry, layer_in):
-                bp, layer_cache, w = layer_in
-                y, layer_cache = self._block_rows(
-                    bp, carry, layer_cache, pos, active, codec, window=w)
-                return y, layer_cache
-
-            x, new_cache = lax.scan(
-                layer_w, x, (prepared["blocks"], cache, self._wins))
+        # a paged pool rides the loop whole, a dense cache by layer
+        wins = () if self._wins is None else (self._wins,)
+        x, new_cache = scan_blocks(block, x, prepared["blocks"], cache,
+                                   codec, *wins)
         logits = head(prepared, x.astype(jnp.float32), cfg=self.cfg,
                       compute_dtype=self.compute_dtype)
         return logits[:, -1], new_cache

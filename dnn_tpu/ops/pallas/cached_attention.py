@@ -454,21 +454,48 @@ def reference_paged_decode_attention(q, kp, vp, tables, pos, *, ks=None,
         g = jnp.moveaxis(g, 1, 2)
         return g.reshape(b, hk, nb * bp, *rest)
 
+    d = q.shape[-1]  # a pool may store its rows lane-padded, wider than q
     return reference_decode_attention(
-        q, view(kp), view(vp), pos,
+        q, view(kp)[..., :d], view(vp)[..., :d], pos,
         ks=view(ks) if ks is not None else None,
         vs=view(vs) if vs is not None else None)
 
 
-def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
-                         scale, block_len, quant):
+def _reference_paged_step(q, pools, tables, pos, layer, new):
+    """paged_decode_attention's whole-pool forms in plain jnp: place
+    `new`'s rows at [layer, block, :, row] (gated-off slots at junk
+    block 0, row 0), then the oracle on that layer. Returns what the
+    kernel does: the attention output, and with `new` the pools too."""
+    bp = pools[0].shape[-2]
+    if new is not None:
+        *rows, gate = new
+        blk = jnp.take_along_axis(tables, (pos // bp)[:, None], axis=1)[:, 0]
+        blk, row = jnp.where(gate, blk, 0), jnp.where(gate, pos % bp, 0)
+        pools = [p.at[layer, blk, :, row].set(r[:, :, 0])
+                 for p, r in zip(pools, rows)]
+    kp, vp, *scales = [p[layer] for p in pools]
+    ks, vs = scales or (None, None)
+    out = reference_paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
+                                           vs=vs)
+    return out if new is None else (out, *pools)
+
+
+def _paged_decode_kernel(*refs, scale, block_len, quant, write):
+    """Scalar prefetch: pos, table, layer (and with `write` the gate).
+    Inputs: q, the K/V (and scale) blocks, and with `write` this step's
+    rows. Outputs: the attention rows, and with `write` the block that
+    holds position `pos` with the row placed."""
     from jax.experimental import pallas as pl
 
-    if quant:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
+    n_pool = 4 if quant else 2
+    pos_ref = refs[0]
+    gate_ref = refs[3] if write else None
+    refs = refs[4 if write else 3:]
+    q_ref, pool_refs, refs = refs[0], refs[1:1 + n_pool], refs[1 + n_pool:]
+    if write:
+        new_refs, refs = refs[:n_pool], refs[n_pool:]
+        out_refs, refs = refs[1:1 + n_pool], refs[:1] + refs[1 + n_pool:]
+    o_ref, m_scr, l_scr, acc_scr = refs
 
     si = pl.program_id(1)
     ns = pl.num_programs(1)
@@ -481,18 +508,42 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
 
     pos = pos_ref[pl.program_id(0)]
     live = si * block_len <= pos
+    if write:
+        gate = gate_ref[pl.program_id(0)] != 0
 
     @pl.when(live)
     def _step():
         q = q_ref[0].astype(jnp.float32)   # (Hk, R, d)
-        k = k_ref[0].astype(jnp.float32)   # (Hk, block_len, d)
+        # the block's leaves as the softmax reads them: K, V (Hk,
+        # block_len, d) and an int8 pool's scales (Hk, block_len)
+        blk = [r[0].astype(jnp.float32) for r in pool_refs]
+        if write:
+            # the step's own row goes into the block that holds `pos`
+            # before it is attended, and that one block goes back out
+            # through the aliased pool: the pool is updated by the
+            # kernel that reads it and XLA never lays a hand on it. A
+            # gated-off slot places nothing: its block goes, as it was,
+            # to junk block 0 (the output's index map)
+            last = si == jnp.minimum(pos // block_len, ns - 1)
+            row = jnp.where(last & gate, pos % block_len, -1)
+            here = {shp: jax.lax.broadcasted_iota(jnp.int32, shp, 1) == row
+                    for shp in {b.shape for b in blk}}
+            blk = [jnp.where(here[b.shape], n[0].astype(jnp.float32), b)
+                   for b, n in zip(blk, new_refs)]
+
+            @pl.when(last)
+            def _place():
+                for o, b in zip(out_refs, blk):
+                    o[0] = b.astype(o.dtype)
+
+        k, v = blk[:2]
         hk, r, d = q.shape
         s = jax.lax.dot_general(
             q, k, (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
         )  # (Hk, R, block_len)
         if quant:
-            s = s * ks_ref[0][:, None, :]
+            s = s * blk[2][:, None, :]
         s = s * scale
         s2 = s.reshape(hk * r, block_len)
         cols = jax.lax.broadcasted_iota(
@@ -504,10 +555,9 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s2 - m_new)
         if quant:
-            pv = p.reshape(hk, r, block_len) * vs_ref[0][:, None, :]
+            pv = p.reshape(hk, r, block_len) * blk[3][:, None, :]
         else:
             pv = p.reshape(hk, r, block_len)
-        v = v_ref[0].astype(jnp.float32)
         out = jax.lax.dot_general(
             pv, v, (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
@@ -526,7 +576,7 @@ def _paged_decode_kernel(pos_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
 
 @jax.named_scope("attn.paged_decode")
 def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
-                           interpret=None):
+                           layer=None, new=None, interpret=None):
     """Fused paged decode attention (see the section comment above).
 
     q (B, Hk, R, D) — R query rows per KV head, all attending logical
@@ -534,67 +584,130 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     pool — float, or int8 with ks/vs (n_blocks, Hk, bp) scales; tables
     (B, nb_max) int32 logical->physical block map; pos (B,) int32.
     Returns (B, Hk, R, D) f32, identical math to the gather_view einsum
-    (reference_paged_decode_attention is the oracle).
+    (reference_paged_decode_attention is the oracle). The pool's rows
+    may be stored wider than D (paged_kvcache.lane_padded, the upper
+    lanes zero): q is then zero-padded to match, which leaves every
+    score as it was, and the result cut back to D.
+
+    With `layer` (a traced int32 scalar) kp/vp/ks/vs are the WHOLE
+    pool, one more leading (L,) axis: the layer rides scalar prefetch
+    beside `pos` and the table and leads each block index, so the
+    kernel reads layer `layer`'s blocks in place and nobody slices the
+    pool (the decode loop's form, paged_kvcache.scan_blocks).
+
+    With `new` = (k (B, Hk, 1, D), v[, ks (B, Hk, 1), vs], gate (B,)) —
+    this step's rows as the pool stores them, whole-pool form only — the
+    kernel also WRITES: each slot's row goes into the block that holds
+    position pos[b] before it is attended (a gated-off slot places
+    nothing, and its block goes as it was to junk block 0), and the
+    pools come back updated through aliased outputs:
+    returns (out, kp, vp[, ks, vs]). The step then touches the pool with
+    nothing but this call.
 
     Dispatches to the Pallas kernel on TPU; otherwise runs the
     reference. `interpret=True` forces the kernel in interpreter mode
     (CPU CI runs the real table-chasing index maps)."""
+    quant = ks is not None
+    pools = [kp, vp] + ([ks.astype(jnp.float32), vs.astype(jnp.float32)]
+                        if quant else [])
+    if new is not None and layer is None:
+        raise ValueError("the kernel places rows in the whole pool only: "
+                         "pass layer= with new=")
     on_tpu = jax.default_backend() == "tpu"
     if interpret is None:
         if not on_tpu:
-            return reference_paged_decode_attention(
-                q, kp, vp, tables, pos, ks=ks, vs=vs)
+            if layer is None:
+                return reference_paged_decode_attention(
+                    q, kp, vp, tables, pos, ks=ks, vs=vs)
+            return _reference_paged_step(q, pools, tables, pos, layer, new)
         interpret = False
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    b, hk, r, d = q.shape
+    b, hk, r, d_q = q.shape
+    d = kp.shape[-1]  # the pool's row width: D, or D lane-padded
+    if d != d_q:
+        q = jnp.pad(q, [(0, 0)] * 3 + [(0, d - d_q)])
     nb_max = tables.shape[1]
-    bp = kp.shape[2]
-    quant = ks is not None
+    bp = kp.shape[-2]
+    write = new is not None
     kernel = functools.partial(
-        _paged_decode_kernel, scale=1.0 / (d ** 0.5), block_len=bp,
-        quant=quant,
+        _paged_decode_kernel, scale=1.0 / (d_q ** 0.5), block_len=bp,
+        quant=quant, write=write,
     )
 
     # the block table chases through scalar prefetch: logical block si of
     # slot bi lives at physical pool block tab[bi * nb_max + si], and
     # blocks past the live limit re-target the last LIVE logical block
-    # (repeated physical index -> no DMA)
-    def _pool_map(bi, si, p, tab):
-        return (tab[bi * nb_max + jnp.minimum(si, p[bi] // bp)], 0, 0, 0)
+    # (repeated physical index -> no DMA). A whole pool is entered at
+    # its layer: one squeezed leading block index, the same kernel body.
+    whole = layer is not None
+    lead = (None,) if whole else ()
 
-    def _scale_map(bi, si, p, tab):
-        return (tab[bi * nb_max + jnp.minimum(si, p[bi] // bp)], 0, 0)
+    def _pool_map(bi, si, p, tab, lay, *_):
+        blk = tab[bi * nb_max + jnp.minimum(si, p[bi] // bp)]
+        return ((lay[0],) if whole else ()) + (blk, 0, 0, 0)
 
-    qspec = pl.BlockSpec((1, hk, r, d), lambda bi, si, p, tab: (bi, 0, 0, 0))
-    cspec = pl.BlockSpec((1, hk, bp, d), _pool_map)
-    in_specs = [qspec, cspec, cspec]
-    args = [q, kp, vp]
-    if quant:
-        in_specs += [pl.BlockSpec((1, hk, bp), _scale_map)] * 2
-        args += [ks.astype(jnp.float32), vs.astype(jnp.float32)]
+    # the written block: the one that holds `pos`, whatever the grid
+    # step — one write-back per slot, when the slot's steps are over
+    def _out_map(bi, si, p, tab, lay, gate):
+        blk = tab[bi * nb_max + jnp.minimum(p[bi] // bp, nb_max - 1)]
+        return (lay[0], jnp.where(gate[bi] != 0, blk, 0), 0, 0, 0)
+
+    def _row_map(bi, si, *_):
+        return (bi, 0, 0, 0)
+
+    def cut(index_map, n):  # a scale leaf has no D axis
+        return lambda *a: index_map(*a)[:n]
+
+    qspec = pl.BlockSpec((1, hk, r, d), _row_map)
+    shapes = [lead + (1, hk, bp, d)] * 2 + [lead + (1, hk, bp)] * 2
+    in_specs = [qspec] + [
+        pl.BlockSpec(shp, cut(_pool_map, len(shp)))
+        for shp in shapes[:len(pools)]]
+    out_specs, out_shape = qspec, jax.ShapeDtypeStruct((b, hk, r, d),
+                                                       jnp.float32)
+    scalars = [pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
+               jnp.asarray(0 if layer is None else layer,
+                           jnp.int32).reshape(1)]
+    aliases, rows = {}, ()
+    if write:
+        *rows, gate = new
+        scalars.append(gate.astype(jnp.int32))
+        in_specs += [pl.BlockSpec((1,) + x.shape[1:],
+                                  cut(_row_map, x.ndim)) for x in rows]
+        out_specs = [qspec] + [
+            pl.BlockSpec(shp, cut(_out_map, len(shp)))
+            for shp in shapes[:len(pools)]]
+        out_shape = [out_shape] + [
+            jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools]
+        # operand numbers count the scalars: 4 of them, then q
+        aliases = {5 + i: 1 + i for i in range(len(pools))}
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=len(scalars),
         grid=(b, nb_max),
         in_specs=in_specs,
-        out_specs=qspec,
+        out_specs=out_specs,
         scratch_shapes=[
             pltpu.VMEM((hk * r, 128), jnp.float32),  # running row max
             pltpu.VMEM((hk * r, 128), jnp.float32),  # running row sum
             pltpu.VMEM((hk * r, d), jnp.float32),    # output accumulator
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, r, d), jnp.float32),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
         name="paged_decode_attention",
-    )(pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32), *args)
+    )(*scalars, q, *pools, *rows)
+    if write:
+        return (out[0][..., :d_q], *out[1:])
+    return out[..., :d_q]
 
 
 @jax.named_scope("attn.decode")

@@ -127,6 +127,15 @@ class _KernelDispatch:
                     and c["k"].shape[2] >= AUTO_KERNEL_MIN_S)
         return bool(self.use_kernel)
 
+    def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None):
+        """One decode step against this cache: write the step's k/v rows
+        at `pos`, then attend through them -> (y, c). The block's one
+        call into its codec; a codec whose write and attend are ONE
+        device operation (PagedKV's kernel over the whole pool)
+        overrides it."""
+        c = self.write_rows(c, k, v, pos, write_gate)
+        return self.attend_rows(q, c, pos, window=window), c
+
     def _cap(self, s):
         """Apply attention-logit softcapping (identity when unset)."""
         if self.softcap is not None:

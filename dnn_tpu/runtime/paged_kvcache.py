@@ -19,20 +19,25 @@ What this buys a serving pool (tests/test_paged.py measures both):
 
 Layout (per K and per V, mirroring the dense cache's (L, B, H, S, D)):
 
-    pool   (L, n_blocks, H, block_len, D)
-    tables (L, B, max_blocks)  int32   -- replicated over L so the decode
-                                          scan over layers peels tables
-                                          alongside the pool leaves
+    pool   (L, n_blocks, H, block_len, Dp) Dp = D rounded up to whole
+                                          128-lane tiles (`lane_padded`)
+    tables (L, B, max_blocks)  int32   -- replicated over L (the leaf
+                                          shape every install / copy /
+                                          tier program was written to)
     pos    (B,)                        -- slot lengths, as in dense
 
 The codec interface matches FloatKV (write_rows / attend_rows /
-install_row), so GPTFamilyRows / LlamaFamilyRows decode through it
-unchanged. Attention gathers the slot's blocks into a (B, H, S_max, D)
-view and runs the identical masked einsum — the reference math is the
-dense codec's, so token parity is exact. (A Pallas paged-attention kernel
-would instead feed the table through the scalar-prefetch index map of
-ops/pallas/cached_attention._decode_call, reading blocks straight from
-the pool; the einsum path is the correctness baseline.)
+write_attend_rows / install_row), so GPTFamilyRows / LlamaFamilyRows
+decode through it unchanged. The decode step reaches the pool IN PLACE:
+the layer loop carries the whole pytree (`scan_blocks`) and the codec
+takes the layer (`PagedKV.at_layer`), so no layer's slice is ever cut
+out of the pool or written back. Attention gathers the slot's blocks
+into a (B, H, S_max, D) view and runs the identical masked einsum — the
+reference math is the dense codec's, so token parity is exact — or, with
+the kernel on, ops/pallas/cached_attention.paged_decode_attention chases
+the table through its scalar-prefetch index maps, reads blocks straight
+from the pool and places the step's own rows in them; the einsum path is
+the correctness baseline.
 
 No counterpart exists in the reference framework (its only state is a
 per-request activation, /root/reference/node.py:45-105 — no cache at
@@ -53,7 +58,7 @@ from dnn_tpu.runtime.kvcache import band_keep
 _NEG_BIG = -1e30
 
 __all__ = ["PagedKV", "BlockAllocator", "InsufficientBlocks",
-           "init_paged_cache"]
+           "init_paged_cache", "lane_padded", "scan_blocks"]
 
 
 class InsufficientBlocks(RuntimeError):
@@ -142,13 +147,42 @@ class BlockAllocator:
                 self._rc[b] = rc
 
 
+LANES = 128  # the minor-most extent of a TPU tile
+
+
+def lane_padded(head_dim: int) -> int:
+    """The stored width of a pool block's rows: `head_dim` rounded up to
+    whole 128-lane tiles (64-wide heads are stored 128 wide, the upper
+    lanes zero and never read back). Why the pool pays for it: a block
+    (H, block_len, D) must be one contiguous run on the device — it is
+    what the decode kernel's block DMA and every block-granular install
+    / copy address — and the TPU lays an array out by its SHAPE: with a
+    minor-most extent under 128 it puts n_blocks minor-most instead
+    (bf16[L,1025,20,16,64] comes out {1,4,3,2,0}), no block is
+    contiguous, and every program that touches blocks relayouts the
+    whole pool at its edges, every step. A row-major layout of the
+    narrow shape would pad the same lanes in HBM anyway (and asking for
+    one by `jax.experimental.layout` does not survive the persistent
+    compile cache: a deserialized executable's results report the
+    default layout). So the padding is put in the shape, where every
+    program sees it."""
+    return -(-head_dim // LANES) * LANES
+
+
+def _pad_lanes(x, width):
+    """Zero-pad x's last axis up to `width` (a pool block's stored row)."""
+    if x.shape[-1] == width:
+        return x
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
+
+
 def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
                      block_len: int = 16, dtype=jnp.float32,
                      kv_heads: Optional[int] = None):
     """Pool + tables pytree for `slots` decode rows of up to `max_len`
     positions each, sharing `n_blocks` physical blocks of `block_len`
-    positions. The pytree rides the same lax.scan-over-layers as the
-    dense cache (leading L on every leaf). `kv_heads` overrides the
+    positions (leading L on every leaf, like the dense cache; K/V rows
+    stored `lane_padded`). `kv_heads` overrides the
     pool's head width — GQA families store KV heads, not query heads
     (llama.init_cache's narrowing, here applied to the pool).
     dtype="int8" / "int4" build the quantized pools: int8/int4 K/V
@@ -160,7 +194,8 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
     head_dim = cfg.n_embd // cfg.n_head
     heads = kv_heads if kv_heads is not None else cfg.n_head
     nb_max = max_len // block_len
-    shape = (cfg.n_layer, n_blocks, heads, block_len, head_dim)
+    # K/V blocks are stored LANE-PADDED (`lane_padded`): see there
+    shape = (cfg.n_layer, n_blocks, heads, block_len, lane_padded(head_dim))
     tables = jnp.zeros((cfg.n_layer, slots, nb_max), jnp.int32)
     if dtype in ("int8", "int4"):
         qdt = jnp.int8 if dtype == "int8" else jnp.int4
@@ -229,12 +264,18 @@ class PagedKV:
     # trace files this codec's operations under (chipbench/spans.py).
 
     @jax.named_scope("kv_pool.write")
-    def write_rows(self, c, k, v, pos, write_gate):
+    def write_rows(self, c, k, v, pos, write_gate, layer=None):
         """k/v (B, H, 1, D) at per-slot positions pos (B,); write_gate (B,)
         keeps inactive slots' LIVE state untouched. Physical target: block
         tables[b, pos//bp], row pos%bp — one scatter per leaf. An int8
         pool quantizes the incoming rows first (kvcache._quantize_rows)
         and scatters the per-(position, head) scales alongside.
+
+        `layer` selects the WHOLE-POOL form: `c` is then the full cache
+        pytree (pool (L, n_blocks, H, bp, D), tables (L, B, nb_max)) and
+        the same rows scatter at [layer, blk, :, row] — the pool is
+        updated in place by index, never sliced per layer (the decode
+        loop's form, `scan_blocks`).
 
         Gated-off slots are ROUTED TO the reserved junk block (0, row 0)
         rather than restored-in-place: a retired slot's stale table can
@@ -245,43 +286,61 @@ class PagedKV:
         between gated slots are harmless (block 0 is never owned, never
         attended live)."""
         bp = self.block_len
+        tables = c["tables"] if layer is None else c["tables"][layer]
         blk = jnp.take_along_axis(
-            c["tables"], (pos // bp)[:, None], axis=1)[:, 0]  # (B,)
+            tables, (pos // bp)[:, None], axis=1)[:, 0]  # (B,)
         row = pos % bp
         blk = jnp.where(write_gate, blk, 0)
         row = jnp.where(write_gate, row, 0)
+        at = (blk, slice(None), row)  # -> (B, H[, D]) rows
+        if layer is not None:
+            at = (layer,) + at
         out = {"tables": c["tables"]}
-        if "ks" in c:
-            from dnn_tpu.runtime.kvcache import (
-                _quantize_rows,
-                _quantize_rows_int4,
-            )
-
-            quantize = (_quantize_rows_int4 if c["k"].dtype == jnp.int4
-                        else _quantize_rows)
-            kq, ks = quantize(k)  # (B,H,1,D), (B,H,1)
-            vq, vs = quantize(v)
-            out["k"] = c["k"].at[blk, :, row].set(kq[:, :, 0])
-            out["v"] = c["v"].at[blk, :, row].set(vq[:, :, 0])
-            out["ks"] = c["ks"].at[blk, :, row].set(ks[:, :, 0])
-            out["vs"] = c["vs"].at[blk, :, row].set(vs[:, :, 0])
-            return out
-        out["k"] = c["k"].at[blk, :, row].set(k[:, :, 0].astype(c["k"].dtype))
-        out["v"] = c["v"].at[blk, :, row].set(v[:, :, 0].astype(c["v"].dtype))
+        for name, r in self._rows(c, k, v).items():
+            out[name] = c[name].at[at].set(r[:, :, 0])
         return out
 
+    @staticmethod
+    def _rows(c, k, v):
+        """The step's k/v (B, H, 1, D) as pool `c` stores them, by leaf:
+        cast to the pool's dtype, or quantized with their (B, H, 1)
+        scale rows beside them, and padded to the pool's row width."""
+        width = c["k"].shape[-1]  # lane-padded (`lane_padded`)
+        if "ks" not in c:
+            return {"k": _pad_lanes(k.astype(c["k"].dtype), width),
+                    "v": _pad_lanes(v.astype(c["v"].dtype), width)}
+        from dnn_tpu.runtime.kvcache import (
+            _quantize_rows,
+            _quantize_rows_int4,
+        )
+
+        quantize = (_quantize_rows_int4 if c["k"].dtype == jnp.int4
+                    else _quantize_rows)
+        kq, ks = quantize(k)  # (B,H,1,D), (B,H,1)
+        vq, vs = quantize(v)
+        return {"k": _pad_lanes(kq, width), "v": _pad_lanes(vq, width),
+                "ks": ks, "vs": vs}
+
     @jax.named_scope("kv_pool.gather")
-    def gather_view(self, c, names=("k", "v")):
+    def gather_view(self, c, names=("k", "v"), layer=None, width=None):
         """Dense (B, H, S_max, ...) views of every slot's logical cache —
-        the einsum attention baseline (a paged Pallas kernel would skip
-        this materialization). Handles K/V blocks (…, bp, D) and scale
-        blocks (…, bp) alike."""
-        tables = c["tables"]  # (B, nb_max)
-        b, nb = tables.shape
+        the einsum attention baseline (the paged Pallas kernel skips this
+        materialization). Handles K/V blocks (…, bp, D) and scale blocks
+        (…, bp) alike. With `layer`, `c` is the whole cache pytree and
+        the blocks are gathered at [layer, tables[layer]]. `width` cuts
+        K/V rows back from the pool's lane-padded width to head_dim."""
+        tables = c["tables"] if layer is None else c["tables"][layer]
+        b, nb = tables.shape  # (B, nb_max)
+        ids = tables.reshape(-1)
         out = []
         for name in names:
             leaf = c[name]
-            g = jnp.take(leaf, tables.reshape(-1), axis=0)  # (B*nb, H, bp[, D])
+            if layer is None:
+                g = jnp.take(leaf, ids, axis=0)  # (B*nb, H, bp[, D])
+            else:
+                g = leaf[layer, ids]
+            if g.ndim == 4 and width is not None:
+                g = g[..., :width]
             h, bp = g.shape[1], g.shape[2]
             rest = g.shape[3:]
             g = g.reshape(b, nb, h, bp, *rest)
@@ -289,12 +348,14 @@ class PagedKV:
             out.append(g.reshape(b, h, nb * bp, *rest))
         return out
 
-    def attend_rows(self, q, c, pos, window=None):
+    def attend_rows(self, q, c, pos, window=None, layer=None):
         """q (B, H, R, D); every row of slot b attends logical positions
         <= pos[b] (identical math to kvcache.FloatKV/Int8KV.attend_rows
         on the gathered view — int8 pools fold their per-position scales
         onto the score/probability matrices, never a float cache copy),
-        band-limited by the codec's `window` when set. A per-call
+        band-limited by the codec's `window` when set. `layer` selects
+        the whole-pool form (see write_rows): the kernel and the gather
+        both reach the pool at [layer, block] in place. A per-call
         `window` override is the dense codecs' per-LAYER channel
         (alt-window configs) — those are rejected at batcher
         construction for paged pools, so an override here is a
@@ -311,19 +372,21 @@ class PagedKV:
             )
 
             interp = True if self.use_kernel == "interpret" else None
+            tables = c["tables"] if layer is None else c["tables"][layer]
             out = paged_decode_attention(
-                q, c["k"], c["v"], c["tables"], pos,
+                q, c["k"], c["v"], tables, pos,
                 ks=c["ks"] if quant else None,
                 vs=c["vs"] if quant else None,
-                interpret=interp)
+                layer=layer, interpret=interp)
             # same output-dtype recipe as the einsum path below
             return out if quant else out.astype(c["v"].dtype)
+        d = q.shape[-1]
         if quant:
-            k, v, ks, vs = self.gather_view(c, ("k", "v", "ks", "vs"))
+            k, v, ks, vs = self.gather_view(c, ("k", "v", "ks", "vs"),
+                                            layer=layer, width=d)
         else:
-            k, v = self.gather_view(c)
+            k, v = self.gather_view(c, layer=layer, width=d)
         with jax.named_scope("attn.paged_decode"):
-            d = q.shape[-1]
             s = jnp.einsum("bhtd,bhsd->bhts", q.astype(jnp.float32),
                            k.astype(jnp.float32),
                            preferred_element_type=jnp.float32)
@@ -341,6 +404,39 @@ class PagedKV:
                              v.astype(jnp.float32),
                              preferred_element_type=jnp.float32)
             return out if quant else out.astype(c["v"].dtype)
+
+    def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None,
+                          layer=None):
+        """The decode step's one call (kvcache._KernelDispatch.
+        write_attend_rows): k/v rows in at `pos`, attention out -> (y, c).
+        On the whole pool with the kernel on, both are ONE operation: the
+        kernel places each slot's row in the block it is about to read
+        and hands the pool back through aliased outputs, so the compiled
+        step holds no other operation on the pool — no scatter whose
+        layout XLA would have to reconcile with the kernel's."""
+        if layer is None or window is not None or not self._kernel_on(c):
+            c = self.write_rows(c, k, v, pos, write_gate, layer=layer)
+            return self.attend_rows(q, c, pos, window=window, layer=layer), c
+        from dnn_tpu.ops.pallas.cached_attention import paged_decode_attention
+
+        with jax.named_scope("kv_pool.write"):
+            rows = self._rows(c, k, v)
+        names = list(rows)  # k, v[, ks, vs]: the kernel's operand order
+        y, *pools = paged_decode_attention(
+            q, c["k"], c["v"], c["tables"][layer], pos,
+            ks=c.get("ks"), vs=c.get("vs"), layer=layer,
+            new=(*rows.values(), write_gate),
+            interpret=True if self.use_kernel == "interpret" else None)
+        c = {**c, **dict(zip(names, pools))}
+        # same output-dtype recipe as attend_rows
+        return (y if "ks" in c else y.astype(c["v"].dtype)), c
+
+    def at_layer(self, layer):
+        """This codec bound to one layer of the WHOLE pool: the same
+        write_attend_rows a block calls on a per-layer cache view, but
+        `c` is the full cache pytree and nothing is ever sliced out of
+        it."""
+        return _PagedLayer(self, layer)
 
     # --- prefill install (full-cache view: pool (L, n_blocks, H, bp, D),
     #     tables (L, B, nb_max)) ---------------------------------------
@@ -366,10 +462,62 @@ class PagedKV:
             rest = r.shape[3:]
             blocks = r.reshape(l_, h, rl // bp, bp, *rest)[:, :, :nb_max]
             blocks = jnp.moveaxis(blocks, 2, 1)  # (L, nb_max, H, bp[, D])
+            if rest:  # K/V rows go in at the pool's lane-padded width
+                blocks = _pad_lanes(blocks, cache[kk].shape[-1])
             out[kk] = cache[kk].at[:, blk_ids].set(
                 blocks.astype(cache[kk].dtype))
         return out
 
 
+class _PagedLayer:
+    """PagedKV.at_layer's binding (see there)."""
+
+    def __init__(self, codec: PagedKV, layer):
+        self.codec, self.layer = codec, layer
+
+    def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None):
+        return self.codec.write_attend_rows(
+            q, c, k, v, pos, write_gate, window=window, layer=self.layer)
+
+
 def codec_is_paged(cache) -> bool:
     return isinstance(cache, dict) and "tables" in cache
+
+
+def scan_blocks(block, x, blocks, cache, codec, *xs, unroll=1):
+    """Run the stacked `blocks` over a KV cache: `block(bp, x, c, codec,
+    *xs_l) -> (x, c)` once per layer -> (x, cache). What the cache IS
+    decides how it rides the loop (a property of the input, not an
+    option):
+
+      * a paged pool is CARRIED whole and reached by layer index
+        (`codec.at_layer(l)`): the pool (L, n_blocks, H, bp, D) is never
+        an xs/ys of the scan, so no layer's slice is ever cut out of it
+        or written back — the step's donated buffer is the carry and the
+        output, touched only by the row scatter and the kernel's block
+        reads;
+      * a dense cache (L, B, H, S, D) rides as xs/ys, one layer's slots
+        per iteration (`unroll` is that scan's CPU-lowering lever,
+        GPTFamilyRows.unroll_layers).
+
+    `xs` are further per-layer inputs (LLaMA's per-layer windows)."""
+    # `layers.scan` names the loop's OWN work on a device trace: slicing
+    # each layer's weights (and a dense cache's layer) out of the stacks
+    # and writing the slice back; the block's work carries the inner
+    # scopes (gpt.block.*, attn.*, kv_pool.*)
+    with jax.named_scope("layers.scan"):
+        if not codec_is_paged(cache):
+            def dense(x, layer_in):
+                bp, c, *rest = layer_in
+                return block(bp, x, c, codec, *rest)
+
+            return lax.scan(dense, x, (blocks, cache, *xs), unroll=unroll)
+
+        def paged(carry, layer_in):
+            bp, layer, *rest = layer_in
+            return block(bp, *carry, codec.at_layer(layer), *rest), None
+
+        n_layer = cache["tables"].shape[0]
+        (x, cache), _ = lax.scan(
+            paged, (x, cache), (blocks, jnp.arange(n_layer), *xs))
+        return x, cache

@@ -69,6 +69,7 @@ from dnn_tpu.runtime.generate import (
     logit_bias_row,
 )
 from dnn_tpu.runtime.kvcache import codec_for_cache
+from dnn_tpu.runtime.paged_kvcache import scan_blocks
 
 
 def _decode_block_rows(bp, x, layer_cache, pos, write, *, cfg, compute_dtype,
@@ -83,8 +84,8 @@ def _decode_block_rows(bp, x, layer_cache, pos, write, *, cfg, compute_dtype,
     with jax.named_scope("gpt.block.attn"):
         h = layer_norm(bp["ln_1"], x, eps=cfg.ln_eps)
         q, k, v = _qkv_heads(bp, h, cfg=cfg, compute_dtype=compute_dtype)
-        layer_cache = codec.write_rows(layer_cache, k, v, pos, write)
-        y = codec.attend_rows(q, layer_cache, pos)
+        y, layer_cache = codec.write_attend_rows(q, layer_cache, k, v, pos,
+                                                 write)
         x = x + linear(bp["attn"]["proj"], merge_heads(y.astype(x.dtype)),
                        compute_dtype=compute_dtype)
     with jax.named_scope("gpt.block.mlp"):
@@ -137,7 +138,10 @@ class GPTFamilyRows:
         # "auto" (default) = the length-aware policy — kernel only on TPU
         # against caches >= kvcache.AUTO_KERNEL_MIN_S positions
         self.attn_kernel = attn_kernel
-        # unroll_layers=True unrolls the DECODE-step layer scan into
+        # unroll_layers=True unrolls the DECODE-step layer scan over a
+        # dense cache (a paged pool is carried through the loop whole and
+        # has no per-layer slices to unroll for: paged_kvcache.
+        # scan_blocks ignores it there) into
         # straight-line code: the CPU backend then updates each layer's
         # cache slice truly in place instead of copying the scan-carried
         # cache state around the while loop (the PR-1 "three full-cache
@@ -211,23 +215,15 @@ class GPTFamilyRows:
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
 
-        def layer(carry, layer_in):
-            bp, layer_cache = layer_in
-            y, layer_cache = _decode_block_rows(
-                bp, carry, layer_cache, pos, active, cfg=cfg,
-                compute_dtype=compute_dtype, codec=codec, ffn=self.ffn,
-            )
-            return y, layer_cache
+        def block(bp, x, c, codec):
+            return _decode_block_rows(
+                bp, x, c, pos, active, cfg=cfg,
+                compute_dtype=compute_dtype, codec=codec, ffn=self.ffn)
 
-        # `layers.scan` is what a device trace files the loop's OWN work
-        # under: slicing each layer's weights and pool out of the stacks
-        # and writing the pool slice back (and the layout copies the
-        # compiler hangs on those); the block's work carries the inner
-        # scopes (gpt.block.*, attn.*, kv_pool.*)
-        with jax.named_scope("layers.scan"):
-            x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache),
-                                    unroll=cfg.n_layer if self.unroll_layers
-                                    else 1)
+        # a paged pool rides the loop whole, a dense cache by layer
+        x, new_cache = scan_blocks(
+            block, x, prepared["blocks"], cache, codec,
+            unroll=cfg.n_layer if self.unroll_layers else 1)
         logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                       compute_dtype=compute_dtype)
         return logits[:, -1], new_cache
@@ -492,6 +488,8 @@ class ContinuousBatcher:
                             use_kernel=getattr(self.family, "attn_kernel",
                                                False))
 
+            head_dim = cfg.n_embd // cfg.n_head  # init_paged_cache's
+
             def gather_row(cache, ids_row):
                 """Rebuild a transient prefill row from pool blocks (the
                 prefix-hit path: remaining chunks attend the shared
@@ -504,6 +502,8 @@ class ContinuousBatcher:
                     if kk == "tables":
                         continue
                     g = jnp.take(cache[kk], ids_row, axis=1)
+                    if g.ndim == 5:  # K/V: drop the pool's lane padding
+                        g = g[..., :head_dim]
                     l_, nb, h, bl = g.shape[:4]  # (L, nb_max, H, bp[, D])
                     rest = g.shape[4:]
                     r = jnp.moveaxis(g, 1, 2).reshape(l_, h, nb * bl, *rest)

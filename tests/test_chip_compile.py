@@ -20,6 +20,8 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 # all but the first fail to describe the topology and skip
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -87,6 +89,37 @@ def test_paged_decode_kernel_compiles(chip, shape, dtype):
              *([scales, scales] if quant else []))
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    (GPT2, BF16), (GPT2, I8), (GQA, BF16)])
+def test_paged_decode_kernel_compiles_on_the_whole_pool(chip, shape, dtype):
+    """The decode loop's form: the whole (L, n_blocks, ...) pool entered at
+    a layer that rides scalar prefetch, the step's rows placed by the
+    kernel and the pools handed back through aliased outputs."""
+    b, hk, r, d = shape
+    nb, n_layer = CTX // BLOCK_LEN, 3
+    pool = ((n_layer, b * nb + 1, hk, BLOCK_LEN, d), dtype)
+    scales = (pool[0][:-1], F32)
+    quant = dtype == I8
+    leaves = [pool, pool] + ([scales, scales] if quant else [])
+    rows = [((b, hk, 1, d), dtype)] * 2 + (
+        [((b, hk, 1), F32)] * 2 if quant else [])
+
+    def fn(q, tables, pos, layer, gate, *rest):
+        kp, vp, *ksvs = rest[:len(leaves)]
+        ks, vs = ksvs if quant else (None, None)
+        return ca.paged_decode_attention(
+            q, kp, vp, tables, pos, ks=ks, vs=vs, layer=layer,
+            new=(*rest[len(leaves):], gate), interpret=False)
+
+    compiled = _compile(
+        chip, fn, ((b, hk, r, d), BF16), ((b, nb), jnp.int32),
+        ((b,), jnp.int32), ((), jnp.int32), ((b,), jnp.bool_),
+        *leaves, *rows)
+    # the pools are updated where they lie: nothing of a pool's size is
+    # allocated beside the arguments
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
 @pytest.mark.parametrize("shape", [GPT2, GQA])
 def test_dense_decode_kernel_compiles(chip, shape):
     b, hk, r, d = shape
@@ -121,22 +154,24 @@ def test_flash_attention_compiles(chip, shape, grad):
     _compile(chip, fn, *[(shape, BF16)] * 3)
 
 
-def test_serving_step_programs_compile_with_the_kernels(chip, monkeypatch):
-    """The whole step programs, not the kernels alone: the paged kernel
-    inside the layer scan with donation and the paged scatter, and the
-    chunked-prefill kernel inside forward_with_cache — GPT-2's widths and
-    the daemon's default pool geometry, depth cut to 2 layers. Each
-    program is lowered from its real call's arguments; `_kernel_on` is
-    steered from here (the backend answers "tpu" while lowering), not
-    through an option of the program."""
+@pytest.fixture(scope="module")
+def step_programs(chip):
+    """The daemon's step programs compiled for the chip — the whole
+    programs, not the kernels alone: the paged kernel inside the layer
+    loop with donation, and the chunked-prefill kernel inside
+    forward_with_cache — GPT-2 Large's widths and 16 slots of the
+    daemon's default pool geometry (what the benchmark serves: at a toy
+    pool the compiler prefetches whole layer slices into VMEM, which the
+    real one never fits), depth cut to 2 layers. Each program is lowered
+    from its real call's arguments; `_kernel_on` is steered from here
+    (the backend answers "tpu" while lowering), not through an option of
+    the program. -> {name: compiled}, the pool's (n_blocks, H, block_len,
+    Dp) and its bytes."""
     from dnn_tpu.models import gpt
     from dnn_tpu.runtime.serving import ContinuousBatcher
 
-    cfg = gpt.GPTConfig(n_layer=2)
+    cfg = gpt.GPTConfig(n_layer=2, n_embd=1280, n_head=20)
     prepared = gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg), cfg)
-    b = ContinuousBatcher(cfg, prepared, slots=4, compute_dtype=BF16,
-                          kv="auto")
-    assert b._paged and b.max_len == CTX
     compiled = {}
 
     def described(x):
@@ -146,12 +181,12 @@ def test_serving_step_programs_compile_with_the_kernels(chip, monkeypatch):
                 weak_type=getattr(x, "weak_type", False))
         return x
 
-    def lower_first(name):
+    def lower_first(b, name):
         fn = getattr(b, name)
 
         def call(*args):
             if name not in compiled:
-                with monkeypatch.context() as m:
+                with pytest.MonkeyPatch.context() as m:
                     m.setattr(jax, "default_backend", lambda: "tpu")
                     compiled[name] = fn.lower(
                         *jax.tree.map(described, args)).compile()
@@ -160,15 +195,73 @@ def test_serving_step_programs_compile_with_the_kernels(chip, monkeypatch):
 
         setattr(b, name, call)
 
+    convoy = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
+                               kv="auto")
+    mixed = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
+                              kv="auto", prefill_chunk_tokens=64)
+    assert convoy._paged and convoy.max_len == CTX
     for name in ("_prefill_chunk", "_prefill_finish", "_decode"):
-        lower_first(name)
-    b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
-    b.drain()
-    has_kernel = {n: "tpu_custom_call" in c.as_text()
-                  for n, c in compiled.items()}
-    assert has_kernel == {"_prefill_chunk": True, "_prefill_finish": False,
-                          "_decode": True}
-    # the decode step's donated pool and per-slot state alias its outputs
-    mem = compiled["_decode"].memory_analysis()
-    assert mem.alias_size_in_bytes >= sum(
-        x.nbytes for x in jax.tree.leaves(b.cache) if x.ndim > 3)
+        lower_first(convoy, name)
+    for name in ("_mixed", "_ilv_finish"):
+        lower_first(mixed, name)
+    for b in (convoy, mixed):
+        b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
+        b.step()
+        b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
+        b.drain()
+    pool_bytes = sum(x.nbytes for x in jax.tree.leaves(convoy.cache)
+                     if x.ndim > 3)
+    return compiled, convoy.cache["k"].shape[1:], pool_bytes
+
+
+def _pool_extent_ops(compiled, pool):
+    """(opcode, name) of every instruction of a compiled program whose
+    result has the extent of one layer's pool slice or of the whole pool
+    — arguments, tuples and the loop itself apart."""
+    extent = re.compile(r"\[(?:\d+,)?%d,%d,%d,%d\]" % tuple(pool))
+    found = []
+    for line in compiled.as_text().splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (.+?) ([\w\-]+)\(", line)
+        if m and extent.search(m.group(2)) and m.group(3) not in (
+                "parameter", "get-tuple-element", "tuple", "bitcast",
+                "while"):
+            found.append((m.group(3), m.group(1)))
+    return found
+
+
+@pytest.mark.parametrize("name,kernel", [
+    ("_prefill_chunk", True), ("_prefill_finish", False), ("_decode", True),
+    ("_mixed", True), ("_ilv_finish", False)])
+def test_serving_step_programs_compile_with_the_kernels(step_programs, name,
+                                                        kernel):
+    compiled, _, pool_bytes = step_programs
+    assert ("tpu_custom_call" in compiled[name].as_text()) == kernel
+    if name != "_prefill_chunk":  # it works on the transient row
+        # the donated pool aliases the program's result
+        assert compiled[name].memory_analysis().alias_size_in_bytes \
+            >= pool_bytes
+
+
+@pytest.mark.parametrize("name", ["_decode", "_mixed"])
+def test_decode_programs_leave_the_pool_in_place(step_programs, name):
+    """ISSUE 25 point 4, on the chip's compiled text: no operation of the
+    decode step (nor of the mixed step, which shares its core) has a
+    result of the extent of a layer's pool slice or of the whole pool —
+    no copy, no dynamic-slice / dynamic-update-slice fusion, no
+    copy-start / copy-done, no scatter — but the kernel's own aliased
+    pool results."""
+    compiled, pool, _ = step_programs
+    ops = _pool_extent_ops(compiled[name], pool)
+    assert [o for o in ops if o[0] != "custom-call"] == []
+    assert ops, "the kernel hands the pool back through its results"
+
+
+@pytest.mark.parametrize("name", ["_prefill_finish", "_ilv_finish"])
+def test_finish_programs_install_without_a_pool_copy(step_programs, name):
+    """The finish installs the row's blocks with one in-place scatter a
+    leaf; stored lane-padded the pool is block-contiguous by its shape and
+    needs no relayout around it."""
+    compiled, pool, _ = step_programs
+    ops = _pool_extent_ops(compiled[name], pool)
+    assert {o[0] for o in ops} <= {"scatter", "fusion"}, ops
+    assert compiled[name].memory_analysis().temp_size_in_bytes < 2 ** 24

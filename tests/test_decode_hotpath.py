@@ -68,6 +68,68 @@ def test_serving_decode_fully_aliased_no_cache_copies():
         assert v["cache_sized_ops"] == {}, (name, v)
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs (scan / cond / pjit bodies)
+    included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("variant", [
+    "paged", "paged_int8", "paged_kernel", "paged_mixed", "dense"])
+def test_paged_pool_rides_the_layer_loop_as_carry(tiny, variant):
+    """ISSUE 25: the decode step reaches a paged pool in place, by layer
+    index. From the step's jaxpr: no leaf of any scan's xs / ys has a pool
+    leaf's shape (the pool is a carry, never sliced per layer and
+    stacked back), and no dynamic_slice / dynamic_update_slice in the
+    step reads or writes a layer-slice-sized operand. The dense cache
+    keeps riding as xs / ys — which is what this test would catch on a
+    paged pool."""
+    from dnn_tpu.runtime.serving import GPTFamilyRows
+
+    cfg, prepared = tiny
+    kw = {"kv": "dense"} if variant == "dense" else {"kv": "paged"}
+    if variant == "paged_int8":
+        kw["kv_dtype"] = "int8"
+    if variant == "paged_kernel":
+        kw["family"] = GPTFamilyRows(cfg, attn_kernel="interpret")
+    if variant == "paged_mixed":
+        kw["prefill_chunk_tokens"] = 16
+    b = ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
+                          prompt_pad=16, **kw)
+    args = (b._decode_view, b.cache, b.pos, b.tok, b.active, b.keys,
+            b._temp, b._topk, b._topp, b._minp, b._rep, b._seen,
+            b._bias, b._crow, b._ctable, b._ctrans)
+    fn = b._decode
+    if variant == "paged_mixed":
+        fn = b._mixed
+        args = (args[0],) + args + (b._ilv_new_row(),
+                                    jnp.zeros((1, 16), jnp.int32),
+                                    jnp.int32(0))
+    jaxpr = fn.trace(*args).jaxpr.jaxpr
+    pool_shapes = {x.shape for x in jax.tree.leaves(b.cache) if x.ndim > 3}
+    slice_elems = min(int(np.prod(s[1:])) for s in pool_shapes)
+    stacked, sliced = [], []
+    for eqn in _eqns(jaxpr):
+        if eqn.primitive.name == "scan":
+            n_in = eqn.params["num_consts"] + eqn.params["num_carry"]
+            xs_ys = (eqn.invars[n_in:]
+                     + eqn.outvars[eqn.params["num_carry"]:])
+            stacked += [v.aval.shape for v in xs_ys
+                        if v.aval.shape in pool_shapes]
+        elif eqn.primitive.name in ("dynamic_slice",
+                                    "dynamic_update_slice"):
+            sliced += [v.aval.shape for v in eqn.invars[:2]
+                       if hasattr(v.aval, "shape")
+                       and int(np.prod(v.aval.shape)) >= slice_elems]
+    if variant == "dense":
+        assert stacked  # (L, B, H, S, D) still rides xs / ys by layer
+    else:
+        assert stacked == [] and sliced == []
+
+
 # ----------------------------------------------------------------------
 # the kv flag
 # ----------------------------------------------------------------------
@@ -178,42 +240,111 @@ def test_int4_rolling_rejected():
 # maps on CPU)
 # ----------------------------------------------------------------------
 
-def test_paged_kernel_matches_gather_einsum():
+def _random_pool(key, lead, quant):
+    """K/V pool (*lead, Hk, bp, D) — float, or int8 with scale leaves."""
+    Hk, bp, D = 2, 16, 16
+    if not quant:
+        return (jax.random.normal(jax.random.fold_in(key, 14),
+                                  (*lead, Hk, bp, D)),
+                jax.random.normal(jax.random.fold_in(key, 15),
+                                  (*lead, Hk, bp, D)), None, None)
+    kp, vp = (jax.random.randint(
+        jax.random.fold_in(key, i), (*lead, Hk, bp, D), -127, 128,
+        dtype=jnp.int32).astype(jnp.int8) for i in (10, 11))
+    ks, vs = (jax.random.uniform(
+        jax.random.fold_in(key, i), (*lead, Hk, bp)) + 0.5 for i in (12, 13))
+    return kp, vp, ks, vs
+
+
+@pytest.mark.parametrize("r,quant", [(1, False), (4, False), (1, True)])
+def test_paged_kernel_matches_gather_einsum(r, quant):
     from dnn_tpu.ops.pallas.cached_attention import (
         paged_decode_attention,
         reference_paged_decode_attention,
     )
 
     key = jax.random.PRNGKey(2)
-    B, Hk, D, nb, bp, NB = 3, 2, 16, 4, 16, 12
+    B, Hk, D, nb, NB = 3, 2, 16, 4, 12
     tables = jnp.asarray(
         np.random.RandomState(0).randint(1, NB, (B, nb)), jnp.int32)
     pos = jnp.asarray([5, 33, 63], jnp.int32)
-    for r, quant in ((1, False), (4, False), (1, True)):
-        q = jax.random.normal(jax.random.fold_in(key, r), (B, Hk, r, D))
-        if quant:
-            kp = jax.random.randint(
-                jax.random.fold_in(key, 10), (NB, Hk, bp, D), -127, 128,
-                dtype=jnp.int32).astype(jnp.int8)
-            vp = jax.random.randint(
-                jax.random.fold_in(key, 11), (NB, Hk, bp, D), -127, 128,
-                dtype=jnp.int32).astype(jnp.int8)
-            ks = jax.random.uniform(
-                jax.random.fold_in(key, 12), (NB, Hk, bp)) + 0.5
-            vs = jax.random.uniform(
-                jax.random.fold_in(key, 13), (NB, Hk, bp)) + 0.5
-        else:
-            kp = jax.random.normal(
-                jax.random.fold_in(key, 14), (NB, Hk, bp, D))
-            vp = jax.random.normal(
-                jax.random.fold_in(key, 15), (NB, Hk, bp, D))
-            ks = vs = None
-        ref = reference_paged_decode_attention(q, kp, vp, tables, pos,
-                                               ks=ks, vs=vs)
-        out = paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
-                                     vs=vs, interpret=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=2e-5, atol=2e-5)
+    q = jax.random.normal(jax.random.fold_in(key, r), (B, Hk, r, D))
+    kp, vp, ks, vs = _random_pool(key, (NB,), quant)
+    ref = reference_paged_decode_attention(q, kp, vp, tables, pos,
+                                           ks=ks, vs=vs)
+    out = paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
+                                 vs=vs, interpret=True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2, 4])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_paged_kernel_on_the_whole_pool(layer, quant):
+    """The decode loop's form (interpret mode): the whole (L, n_blocks,
+    ...) pool entered at `layer`, first read-only, then with the step's
+    rows placed by the kernel — a gated-off slot's row lands in junk
+    block 0 and nowhere else. The oracle is the plain scatter at
+    [layer, block, :, row] and the gather einsum on that layer."""
+    from dnn_tpu.ops.pallas.cached_attention import (
+        paged_decode_attention,
+        reference_paged_decode_attention,
+    )
+
+    key = jax.random.PRNGKey(3)
+    L, B, Hk, D, nb, bp, NB = 5, 3, 2, 16, 4, 16, 13
+    # every slot its own blocks (a written block has one owner)
+    tables = jnp.asarray(1 + np.random.RandomState(1).permutation(
+        NB - 1).reshape(B, nb), jnp.int32)
+    pos = jnp.asarray([5, 33, 63], jnp.int32)
+    gate = jnp.asarray([True, False, True])
+    q = jax.random.normal(jax.random.fold_in(key, 1), (B, Hk, 1, D))
+    kp, vp, ks, vs = _random_pool(key, (L, NB), quant)
+    pools = [x for x in (kp, vp, ks, vs) if x is not None]
+
+    # read-only at a layer == the per-layer form on that layer's slice
+    got = paged_decode_attention(q, kp, vp, tables, pos, ks=ks, vs=vs,
+                                 layer=jnp.int32(layer), interpret=True)
+    want = reference_paged_decode_attention(
+        q, *(x[layer] for x in pools[:2]), tables, pos,
+        **({"ks": ks[layer], "vs": vs[layer]} if quant else {}))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+    # with the step's rows: the kernel writes, then attends what it wrote
+    rows = [jax.random.normal(jax.random.fold_in(key, 20 + i),
+                              (B, Hk, 1, D)) for i in range(2)]
+    if quant:
+        rows = [jnp.round(x * 40).astype(jnp.int8) for x in rows] + [
+            jax.random.uniform(jax.random.fold_in(key, 30 + i),
+                               (B, Hk, 1)) + 0.5 for i in range(2)]
+    new = (*rows, gate)
+    got, *got_pools = paged_decode_attention(
+        q, kp, vp, tables, pos, ks=ks, vs=vs, layer=jnp.int32(layer),
+        new=new, interpret=True)
+    # interpret=None off the TPU is the plain-jnp form of the same call
+    want, *want_pools = paged_decode_attention(
+        q, kp, vp, tables, pos, ks=ks, vs=vs, layer=jnp.int32(layer),
+        new=new)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+    row_at = np.asarray(pos) % bp
+    for g, w, before, r in zip(got_pools, want_pools, pools, rows):
+        g, before = np.asarray(g), np.asarray(before)
+        # off the junk block the kernel's pool IS the oracle's, bit for bit
+        np.testing.assert_array_equal(g[:, 1:], np.asarray(w)[:, 1:])
+        # live slots: the row is in its block; the gated-off slot's block
+        # is untouched, and so is every other layer
+        for b_ in range(B):
+            blk = int(tables[b_, int(pos[b_]) // bp])
+            at = g[layer, blk][:, row_at[b_]]
+            if bool(gate[b_]):
+                np.testing.assert_array_equal(at, np.asarray(r)[b_, :, 0])
+            else:
+                np.testing.assert_array_equal(g[layer, blk],
+                                              before[layer, blk])
+        others = [x for x in range(L) if x != layer]
+        np.testing.assert_array_equal(g[others], before[others])
 
 
 def test_paged_kernel_serving_parity(tiny):
