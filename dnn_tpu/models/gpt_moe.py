@@ -35,6 +35,7 @@ from dnn_tpu.parallel.moe import (
     init_moe,
     moe_capacity,
     moe_ffn,
+    moe_ffn_grouped,
     moe_ffn_local,
 )
 from dnn_tpu.registry import ModelSpec, StageSpec, register_model
@@ -109,15 +110,20 @@ def _block_core(block_params, x, ffn_fn, *, cfg: GPTMoEConfig, compute_dtype=Non
 
 def block_apply(block_params, x, *, cfg: GPTMoEConfig, groups: int = 1,
                 compute_dtype=None):
-    """Dense-path block: the FFN routes locally in `groups` groups."""
-    return _block_core(
-        block_params, x,
-        lambda mp, h: moe_ffn(
-            mp, h, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
-            groups=groups, compute_dtype=compute_dtype,
-        ),
-        cfg=cfg, compute_dtype=compute_dtype,
-    )
+    """Single-device block: drop-free grouped experts (parallel/moe.
+    moe_ffn_grouped; no capacity). `groups` > 1 is the EP path's dense
+    twin — static capacity per routing group — for the parity tests."""
+    if groups == 1:
+        def ffn(mp, h):
+            return moe_ffn_grouped(mp, h, top_k=cfg.top_k,
+                                   compute_dtype=compute_dtype)
+    else:
+        def ffn(mp, h):
+            return moe_ffn(
+                mp, h, top_k=cfg.top_k, capacity_factor=cfg.capacity_factor,
+                groups=groups, compute_dtype=compute_dtype)
+    return _block_core(block_params, x, ffn, cfg=cfg,
+                       compute_dtype=compute_dtype)
 
 
 def _blocks_scan(stacked, x, *, cfg, groups, compute_dtype):

@@ -689,10 +689,11 @@ def _scaled_embed(p, ids, cfg: LlamaConfig):
     every path (dense forward, cached decode, batcher rows, seq-parallel,
     pipeline embed hook) must share, or their parity contracts break on
     embed_scale configs."""
-    e = embedding(p["wte"], ids)
-    if cfg.embed_scale:
-        e = e * jnp.asarray(cfg.n_embd ** 0.5, e.dtype)
-    return e
+    with jax.named_scope("llama.embed"):
+        e = embedding(p["wte"], ids)
+        if cfg.embed_scale:
+            e = e * jnp.asarray(cfg.n_embd ** 0.5, e.dtype)
+        return e
 
 
 def embed(params, idx, *, cfg: LlamaConfig):
@@ -812,6 +813,27 @@ def make_apply_stacked(cfg: LlamaConfig, *, compute_dtype=None,
 # KV-cache decode (kvcache codecs; cache holds KV heads, not H)
 # --------------------------------------------------------------------------
 
+def _run_block(ffn, acc, run):
+    """`run(ffn) -> result` for one block of a layer loop whose carry also
+    holds `acc`: None, or the int32 (3,) MoE stats summed so far. With an
+    `acc`, the block is traced with the hook's counting form
+    (`ffn.with_stats`, llama_moe.make_ffn) and this layer call's stats
+    are added. Returns (result, acc). The list is filled and read inside
+    one trace of the block, so nothing leaves the loop's body but through
+    its carry."""
+    if acc is None:
+        return run(ffn), None
+    got = []
+
+    def counting(bp, h):
+        out, stats = ffn.with_stats(bp, h)
+        got.append(stats)
+        return out
+
+    result = run(counting)
+    return result, acc + got[0]
+
+
 def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: LlamaConfig,
                       compute_dtype, codec, window=None, ffn=None):
     """Block over x (B, T, C) at absolute positions [start_pos,
@@ -862,7 +884,10 @@ def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.float32):
 
 def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
                        compute_dtype=None, attn_kernel="auto", rolling=False,
-                       ffn=None):
+                       ffn=None, moe_stats=False):
+    """-> (logits, new_cache); with `moe_stats` (an ffn that has
+    `with_stats`: the MoE hook) also the int32 (3,) sum over the layers
+    of what each expert layer call cost (parallel/moe.moe_ffn_grouped)."""
     from dnn_tpu.runtime.kvcache import codec_for_cache
 
     ffn = ffn or cfg.default_ffn(compute_dtype)
@@ -875,27 +900,27 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
 
-    if wins is None:
-        def layer(carry, layer_in):
-            bp, layer_cache = layer_in
-            y, layer_cache = _block_with_cache(
-                bp, carry, layer_cache, start_pos, cfg=cfg,
-                compute_dtype=compute_dtype, codec=codec, ffn=ffn)
-            return y, layer_cache
+    def layer(carry, layer_in):
+        x, acc = carry
+        bp, layer_cache, *w = layer_in  # w: this layer's window, if any
 
-        x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
-    else:
-        def layer_w(carry, layer_in):
-            bp, layer_cache, w = layer_in
-            y, layer_cache = _block_with_cache(
-                bp, carry, layer_cache, start_pos, cfg=cfg,
-                compute_dtype=compute_dtype, codec=codec, window=w,
-                ffn=ffn)
-            return y, layer_cache
+        def run(f):
+            return _block_with_cache(
+                bp, x, layer_cache, start_pos, cfg=cfg,
+                compute_dtype=compute_dtype, codec=codec,
+                window=w[0] if w else None, ffn=f)
 
-        x, new_cache = lax.scan(layer_w, x, (prepared["blocks"], cache, wins))
+        (y, layer_cache), acc = _run_block(ffn, acc, run)
+        return (y, acc), layer_cache
+
+    acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
+    (x, acc), new_cache = lax.scan(
+        layer, (x, acc0),
+        (prepared["blocks"], cache) + (() if wins is None else (wins,)))
     logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                   compute_dtype=compute_dtype)
+    if moe_stats:
+        return logits, new_cache, acc
     return logits, new_cache
 
 
@@ -1238,6 +1263,10 @@ class LlamaFamilyRows:
         # Resolved from the config when not passed, so
         # LlamaFamilyRows(mixtral_cfg) just works.
         self.ffn = ffn or cfg.default_ffn(compute_dtype)
+        # an MoE hook can count what its layer calls cost: prefill and
+        # decode_rows then take `moe_stats=True` and return the sums as
+        # a third result (ContinuousBatcher's moe_* counters)
+        self.moe_stats = getattr(self.ffn, "with_stats", None) is not None
         # paged-pool head width: the cache stores KV heads (GQA)
         self.kv_heads = cfg.n_kv_head
         # picked up by ContinuousBatcher: sliding-window masking over the
@@ -1264,14 +1293,25 @@ class LlamaFamilyRows:
     def init_cache(self, batch, max_len, dtype):
         return init_cache(self.cfg, batch, max_len, dtype)
 
-    def prefill(self, prepared, padded, row_cache, start_pos=0):
+    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
+                moe_stats=False):
         return forward_with_cache(
             prepared, padded, row_cache, start_pos, cfg=self.cfg,
             compute_dtype=self.compute_dtype, attn_kernel=self.attn_kernel,
-            ffn=self.ffn)
+            ffn=self.ffn, moe_stats=moe_stats)
 
     def _block_rows(self, bp, x, layer_cache, pos, write, codec,
-                    window=None):
+                    window=None, ffn=None):
+        with jax.named_scope("llama.block.cached_attn"):
+            h, o, layer_cache = self._attn_rows(bp, x, layer_cache, pos,
+                                                write, codec, window)
+        with jax.named_scope("llama.block.mlp"):
+            return (_branches_residual(bp, x, o, h, cfg=self.cfg,
+                                       compute_dtype=self.compute_dtype,
+                                       ffn=ffn or self.ffn),
+                    layer_cache)
+
+    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
         cfg, compute_dtype = self.cfg, self.compute_dtype
         b = x.shape[0]
         kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
@@ -1293,10 +1333,7 @@ class LlamaFamilyRows:
         y = y.reshape(b, cfg.n_head, 1, d)
         o = linear(bp["attn"]["o"], merge_heads(y.astype(x.dtype)),
                    compute_dtype=compute_dtype)
-        return (_branches_residual(bp, x, o, h, cfg=cfg,
-                                   compute_dtype=compute_dtype,
-                                   ffn=self.ffn),
-                layer_cache)
+        return h, o, layer_cache
 
     def verify_rows(self, prepared, cache, chunk, pos, active, codec):
         """A (B, T) token block at PER-ROW start positions pos (B,) —
@@ -1371,23 +1408,35 @@ class LlamaFamilyRows:
                       compute_dtype=compute_dtype)
         return logits, new_cache
 
-    def decode_rows(self, prepared, cache, tok, pos, active, codec):
+    def decode_rows(self, prepared, cache, tok, pos, active, codec, *,
+                    moe_stats=False):
         from dnn_tpu.runtime.paged_kvcache import scan_blocks
 
         x = _scaled_embed(prepared, tok[:, None], self.cfg)  # (B, 1, C)
         if self.compute_dtype is not None:
             x = x.astype(self.compute_dtype)
 
-        def block(bp, x, c, codec, window=None):
-            return self._block_rows(bp, x, c, pos, active, codec,
-                                    window=window)
+        # the loop's carry is (x, the MoE stats summed so far or None):
+        # scan_blocks hands it through whole
+        def block(bp, carry, c, codec, window=None):
+            x, acc = carry
+
+            def run(f):
+                return self._block_rows(bp, x, c, pos, active, codec,
+                                        window=window, ffn=f)
+
+            (y, c), acc = _run_block(self.ffn, acc, run)
+            return (y, acc), c
 
         # a paged pool rides the loop whole, a dense cache by layer
         wins = () if self._wins is None else (self._wins,)
-        x, new_cache = scan_blocks(block, x, prepared["blocks"], cache,
-                                   codec, *wins)
+        acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
+        (x, acc), new_cache = scan_blocks(
+            block, (x, acc0), prepared["blocks"], cache, codec, *wins)
         logits = head(prepared, x.astype(jnp.float32), cfg=self.cfg,
                       compute_dtype=self.compute_dtype)
+        if moe_stats:
+            return logits[:, -1], new_cache, acc
         return logits[:, -1], new_cache
 
 

@@ -1,31 +1,37 @@
-"""Mixtral: the LLaMA block with a sparse mixture-of-experts MLP.
+"""Mixtral, Qwen2-MoE, OLMoE: the LLaMA block with a sparse
+mixture-of-experts MLP.
 
-No counterpart exists in the reference (no MoE anywhere — SURVEY §2);
-this closes the last major open-weight family gap in the zoo: Mixtral =
-LLaMA attention (GQA, RoPE, RMSNorm) + per-layer top-2-of-8 SwiGLU
-experts with renormalized routing.
+No counterpart exists in the reference (no MoE anywhere — SURVEY §2).
+Mixtral = LLaMA attention (GQA, RoPE, RMSNorm) + per-layer top-2-of-8
+SwiGLU experts with renormalized routing; Qwen2-MoE adds raw top-k
+weights and an always-on shared expert; OLMoE is 64 fine-grained experts,
+8 per token, raw weights, q/k RMSNorm over the projection width.
 
 TPU-first composition, not a new model implementation:
 
-  * the block is llama.py's — every Mixtral path (dense forward, cached
-    decode, batcher rows, speculative verify) is the LLaMA path with the
-    `ffn` hook installed, so parity contracts and runtime features
-    (int8 caches, constraints, streaming, beam) carry over wherever the
-    hook threads;
-  * the expert math is parallel/moe.py's GShard-style static-capacity
-    dispatch with the GATED expert stack (silu(x@wg)*(x@wu)@wd — one
-    batched matmul triple over (E, cap, D)); `route_topk(normalize=True)`
-    IS Mixtral's routing (softmax over all experts, take top-k,
-    renormalize the selected weights);
-  * capacity is the TPU-shaped trade: HF computes every selected token
-    densely, we cap per-expert slots for static shapes. With
-    `capacity_factor >= n_expert` nothing can drop and logits match HF
-    exactly (the parity-test setting); serving configs size it down and
-    dropped tokens degrade to the residual (the standard MoE fallback).
+  * the block is llama.py's — every path (dense forward, cached decode,
+    batcher rows, speculative verify) is the LLaMA path with the `ffn`
+    hook installed, so parity contracts and runtime features (int8
+    caches, constraints, streaming, beam) carry over wherever the hook
+    threads;
+  * on one device the expert math is parallel/moe.moe_ffn_grouped:
+    softmax over all experts, top-k, rows sorted by expert, the gated
+    stack (silu(x@wg)*(x@wu)@wd) as ragged matmuls. DROP-FREE: there is
+    no capacity, every routed row is computed, as the HF modules do —
+    `capacity_factor` does not reach this path;
+  * across devices (make_apply_ep / make_generate_ep /
+    make_pipeline_generate_ep) per-rank static shapes need GShard's
+    static-capacity dispatch (parallel/moe.moe_ffn_local): there
+    `capacity_factor >= n_expert` guarantees nothing drops, smaller
+    factors trade drops for memory, and dropped tokens degrade to the
+    residual. `make_ffn(groups=n)` is that path's dense twin, for the
+    parity tests.
 
 Param pytree: llama's, with each block's "mlp" replaced by
   "moe": {"router": {"kernel" (D, E)}, "wg"/"wu" (E, D, F), "wd" (E, F, D)}
-(HF MixtralForCausalLM: block_sparse_moe.gate + experts.i.{w1,w3,w2}).
+(HF MixtralForCausalLM: block_sparse_moe.gate + experts.i.{w1,w3,w2};
+Qwen2MoeForCausalLM / OlmoeForCausalLM: mlp.gate + mlp.experts.i.
+{gate,up,down}_proj).
 """
 
 from __future__ import annotations
@@ -37,7 +43,7 @@ import jax
 import jax.numpy as jnp
 
 from dnn_tpu.models import gpt, llama
-from dnn_tpu.parallel.moe import init_moe_gated, moe_ffn
+from dnn_tpu.parallel.moe import init_moe_gated, moe_ffn, moe_ffn_grouped
 from dnn_tpu.registry import ModelSpec, register_model
 
 
@@ -45,8 +51,9 @@ from dnn_tpu.registry import ModelSpec, register_model
 class MixtralConfig(llama.LlamaConfig):
     n_expert: int = 8
     router_top_k: int = 2
-    # >= n_expert guarantees no token ever drops (parity configs);
-    # serving configs trade capacity for static-shape efficiency
+    # expert-parallel paths only (per-rank static shapes): >= n_expert
+    # guarantees no token ever drops. The single-device path is
+    # drop-free and never reads it (parallel/moe.moe_ffn_grouped)
     capacity_factor: float = 8.0
     # ---- Qwen2-MoE-class switches (defaults = Mixtral semantics) ----
     # Always-on SHARED expert (DeepSeek/Qwen-MoE recipe): a dense SwiGLU
@@ -102,7 +109,39 @@ PRESETS = {
                                    n_expert=4, router_top_k=2,
                                    capacity_factor=4.0, d_shared=96,
                                    router_norm_topk=False),
+    # OLMoE-1B-7B (allenai/OLMoE-1B-7B-0125-Instruct config.json): the
+    # pre-norm LLaMA block, MHA with 128-wide heads, RMSNorm over the
+    # whole q/k projection width before RoPE, and in EVERY layer 64
+    # experts of width 1024, 8 per token, the eight RAW softmax
+    # probabilities as weights (norm_topk_prob false), no shared expert
+    "olmoe-1b-7b": MixtralConfig(block_size=4096, vocab_size=50304,
+                                 n_layer=16, n_head=16, n_kv_head=16,
+                                 n_embd=2048, d_ff=1024,
+                                 rope_theta=10000.0, rms_eps=1e-5,
+                                 tie_word_embeddings=False, attn_bias=False,
+                                 pre_norm=True, qk_norm=True,
+                                 qk_norm_width="proj",
+                                 n_expert=64, router_top_k=8,
+                                 router_norm_topk=False,
+                                 capacity_factor=64.0),
+    # tiny OLMoE for the CPU tests: every switch of the real one acts
+    # (proj-width q/k norm, raw top-k weights, no GQA — OLMoE has none)
+    "olmoe-test": MixtralConfig(block_size=64, vocab_size=256,
+                                n_layer=3, n_head=4, n_kv_head=4,
+                                n_embd=64, d_ff=32,
+                                rope_theta=10000.0, rms_eps=1e-5,
+                                tie_word_embeddings=False, attn_bias=False,
+                                pre_norm=True, qk_norm=True,
+                                qk_norm_width="proj",
+                                n_expert=8, router_top_k=4,
+                                router_norm_topk=False,
+                                capacity_factor=8.0),
 }
+# the benchmark's cut (chipbench/configs/olmoe-1b-7b-1chip.json): three of
+# the sixteen layers — the pattern has period 1 — so that float32 weights,
+# a 16-slot pool of 4096 positions and the programs fit one 16 GB chip
+PRESETS["olmoe-1b-7b-1chip"] = dataclasses.replace(
+    PRESETS["olmoe-1b-7b"], n_layer=3)
 
 
 def _shared_expert_out(moe_p, h, *, compute_dtype=None):
@@ -151,20 +190,42 @@ def _local_ep_ffn(cfg: MixtralConfig, *, axis: str, capacity: int,
 
 
 def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
-    """The llama `ffn` hook: (block_params, h) -> MoE MLP output.
-    `groups` must match between paths that share a cache for
-    token-identical decode (1 everywhere by default)."""
+    """The llama `ffn` hook: (block_params, h) -> MoE MLP output, through
+    the grouped drop-free experts. `ffn.with_stats(bp, h)` also returns
+    that layer call's cost (moe_ffn_grouped's int32 (3,)), which the
+    batcher's adapter sums into the moe_* counters.
 
-    def ffn(bp, h):
-        out = moe_ffn(bp["moe"], h, top_k=cfg.router_top_k,
-                      capacity_factor=cfg.capacity_factor, groups=groups,
-                      compute_dtype=compute_dtype,
-                      normalize=cfg.router_norm_topk)
+    `groups` > 1 is NOT a serving option: it selects the expert-parallel
+    path's dense twin (static capacity per routing group, parallel/moe.
+    moe_ffn), which the EP parity tests compare an n-device run with."""
+    from dnn_tpu.ops.nn import silu
+
+    def routed(bp, h, return_stats):
+        if groups != 1:
+            return moe_ffn(bp["moe"], h, top_k=cfg.router_top_k,
+                           capacity_factor=cfg.capacity_factor,
+                           groups=groups, compute_dtype=compute_dtype,
+                           normalize=cfg.router_norm_topk)
+        return moe_ffn_grouped(bp["moe"], h, top_k=cfg.router_top_k,
+                               normalize=cfg.router_norm_topk,
+                               activation=silu, compute_dtype=compute_dtype,
+                               return_stats=return_stats)
+
+    def with_shared(bp, h, out):
         if cfg.d_shared:
             out = out + _shared_expert_out(bp["moe"], h,
                                            compute_dtype=compute_dtype)
         return out
 
+    def ffn(bp, h):
+        return with_shared(bp, h, routed(bp, h, False))
+
+    if groups == 1:
+        def with_stats(bp, h):
+            out, stats = routed(bp, h, True)
+            return with_shared(bp, h, out), stats
+
+        ffn.with_stats = with_stats
     return ffn
 
 
@@ -578,14 +639,17 @@ def make_pipeline_generate_ep(cfg: MixtralConfig, mesh, *,
 # --------------------------------------------------------------------------
 
 def params_from_state_dict(sd, *, n_layer: Optional[int] = None):
-    """HF MixtralForCausalLM OR Qwen2MoeForCausalLM state dict -> this
-    pytree (layout auto-detected from the keys). Attention/norm/embed
+    """HF MixtralForCausalLM, Qwen2MoeForCausalLM OR OlmoeForCausalLM
+    state dict -> this pytree (layout auto-detected from the keys).
+    Attention/norm/embed
     leaves ride checkpoint.llama_params_from_state_dict's mapping; each
     layer's MoE converts here: the router weight (E, D) -> kernel
     (D, E); per-expert SwiGLU triples stack expert-major to wg/wu/wd
     (Mixtral: block_sparse_moe.experts.i.{w1,w3,w2}; Qwen2-MoE:
     mlp.experts.i.{gate,up,down}_proj, plus mlp.shared_expert.* and the
-    sigmoid shared_expert_gate)."""
+    sigmoid shared_expert_gate; OLMoE: the same expert and router names,
+    no shared expert, and self_attn.{q,k}_norm over the projection
+    width, which the llama converter maps)."""
     import numpy as np
 
     sd = {(k[len("model."):] if k.startswith("model.") else k): v
@@ -635,9 +699,10 @@ def params_from_state_dict(sd, *, n_layer: Optional[int] = None):
 
 
 def _qwen2_moe_from_sd(sd, *, n_layer: Optional[int] = None):
-    """Qwen2MoeForCausalLM layout (already model.-stripped): routed
-    experts under mlp.experts.i.{gate,up,down}_proj, router under
-    mlp.gate, shared expert + its scalar gate alongside."""
+    """Qwen2MoeForCausalLM / OlmoeForCausalLM layout (already
+    model.-stripped): routed experts under mlp.experts.i.{gate,up,down}
+    _proj, router under mlp.gate, and for Qwen2-MoE the shared expert +
+    its scalar gate alongside."""
     import numpy as np
 
     if n_layer is None:
@@ -677,17 +742,18 @@ def _qwen2_moe_from_sd(sd, *, n_layer: Optional[int] = None):
                             for e in range(n_expert)]),
             "wd": np.stack([_t(sd[f"{p}experts.{e}.down_proj.weight"])
                             for e in range(n_expert)]),
-            "shared": {
+        }
+        if p + "shared_expert_gate.weight" in sd:  # Qwen2-MoE; OLMoE has none
+            blk["moe"]["shared"] = {
                 "gate": {"kernel": _t(sd[p + "shared_expert.gate_proj"
                                          ".weight"])},
                 "up": {"kernel": _t(sd[p + "shared_expert.up_proj"
                                        ".weight"])},
                 "down": {"kernel": _t(sd[p + "shared_expert.down_proj"
                                          ".weight"])},
-            },
-            "shared_gate": {
-                "kernel": _t(sd[p + "shared_expert_gate.weight"])},
-        }
+            }
+            blk["moe"]["shared_gate"] = {
+                "kernel": _t(sd[p + "shared_expert_gate.weight"])}
         params[f"h_{i}"] = blk
     return params
 

@@ -846,18 +846,30 @@ def _kv_dtype_arg(name):
     return {"f32": jnp.float32, "bf16": jnp.bfloat16}[name]
 
 
-def _release_layer_params(params):
-    """Free the per-layer `h_<i>` originals once `prepare_stacked` has
-    copied them into the stack: the daemon serves from the stacked copy
-    alone and does not run the engine again, and otherwise every block's
-    weights stay on the device twice (2.8 GB of a 16 GB chip for GPT-2
-    Large)."""
+def _stack_and_release(params, cfg):
+    """`gpt.prepare_stacked` for the daemon, which serves from the stacked
+    copy alone and does not run the engine again: the per-layer `h_<i>`
+    originals leave `params`, and each leaf's originals are freed as
+    soon as its stack exists. Stacking the whole tree first held every
+    block's weights on the device twice until the release (2.8 GB of a
+    16 GB chip for GPT-2 Large, 5 GB for three OLMoE layers); now the
+    boot peak is the blocks once plus the largest leaf's stack."""
     import jax
+    import jax.numpy as jnp
 
-    for name in [k for k in params if k.startswith("h_")]:
-        for leaf in jax.tree.leaves(params.pop(name)):
+    layers = [params.pop(f"h_{i}") for i in range(cfg.n_layer)]
+    flat = [jax.tree_util.tree_flatten(layer) for layer in layers]
+    stacked = []
+    for leaves in zip(*(leaves for leaves, _ in flat)):
+        # done before the originals go, so that the next leaf's stack
+        # is allocated after this one's originals are free
+        stacked.append(jax.block_until_ready(jnp.stack(leaves)))
+        for leaf in leaves:
             if isinstance(leaf, jax.Array):
                 leaf.delete()
+    out = dict(params)
+    out["blocks"] = jax.tree_util.tree_unflatten(flat[0][1], stacked)
+    return out
 
 
 def _serve_lm(engine: PipelineEngine, args) -> int:
@@ -913,8 +925,7 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
 
     _t_prep = _time.monotonic()
     _compile_at_prep = _compile_total_s()
-    prepared = prepare_stacked(engine.params, cfg)
-    _release_layer_params(engine.params)
+    prepared = _stack_and_release(engine.params, cfg)
     _BOOT["prepare_wall_s"] = _time.monotonic() - _t_prep
     _BOOT["compile_in_prepare_s"] = max(
         0.0, _compile_total_s() - _compile_at_prep)

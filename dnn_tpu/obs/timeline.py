@@ -56,7 +56,12 @@ This is the intra-step instrument, in two connected halves:
     step.per_sec / step.last_wall_ms, and the cumulative totals
     step.steps_total / step.tokens_advanced_total /
     step.phase_seconds_total{phase=} / step.admit_seconds_total{part=},
-    exact at every scrape to the last ended step) + fixed-bucket histograms
+    and for a model with experts moe.layer_calls_total /
+    moe.assignments_total / moe.active_experts_total /
+    moe.peak_expert_rows_total{program=decode|prefill} — what the expert
+    layers of the step and chunk programs cost, as the programs
+    themselves counted it — exact at every scrape to the last ended
+    step) + fixed-bucket histograms
     (step.phase_seconds{phase=...}, step.wall_seconds). Phase-boundary
     timestamps are ring-buffered for /stepz. While a profiler capture
     records (POST /profilez), the same boundaries are ALSO written into
@@ -123,6 +128,11 @@ _DEVICE_PHASES = ("dispatch", "wait")
 #: inner intervals; `self` is what they leave of the admit slice.
 ADMIT_PARTS = ("self", "prefill", "first_token", "install")
 _NO_PARTS = (0.0, 0.0, 0.0)
+# the moe.* cumulative series (StepClock.note_moe), each labeled with the
+# program whose expert layers it counts
+MOE_PROGRAMS = ("decode", "prefill")
+MOE_SERIES = ("layer_calls_total", "assignments_total",
+              "active_experts_total", "peak_expert_rows_total")
 
 #: the phase whose annotation opens when a mark closes phase P (None
 #: after the last): the in-step order of PHASES, one definition
@@ -173,7 +183,7 @@ class _StepRec:
     scrape thread recomputes the same values it would assign twice."""
 
     __slots__ = ("t0", "t_end", "marks", "n_adv", "wall", "phases",
-                 "admit_slices", "admit_parts", "mixed", "spans")
+                 "admit_slices", "admit_parts", "mixed", "spans", "moe")
 
     def __init__(self, t0: float):
         self.t0 = t0
@@ -195,6 +205,10 @@ class _StepRec:
         # chunk (serving prefill_chunk_tokens) — /stepz distinguishes
         # interleaved-prefill steps from pure-decode steps with it
         self.mixed = False
+        # {program: [layer calls, assignments, active experts, peak
+        # expert rows]} of the expert layers noted since the last step
+        # ended (StepClock.note_moe), or None: a model without experts
+        self.moe: "Optional[Dict[str, list]]" = None
 
 
 def _fold(rec: _StepRec) -> _StepRec:
@@ -273,6 +287,9 @@ class StepClock:
         self.tokens_advanced_total = 0
         self.phase_seconds_total = {p: 0.0 for p in PHASES}
         self.admit_seconds_total = {p: 0.0 for p in ADMIT_PARTS}
+        # expert layers (note_moe): per program, MOE_SERIES in order
+        self.moe_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
+        self._pending_moe: "Optional[Dict[str, list]]" = None
         self._gauges_registered = False
         self._registry = registry
         self._t_last_end: Optional[float] = None
@@ -317,6 +334,19 @@ class StepClock:
                 return float(v if key is None else v[key])
             return read
 
+        def _weak_moe(program, i):
+            def read():
+                c = ref()
+                return float(c.moe_total[program][i]) if c is not None \
+                    else 0.0
+            return read
+
+        # registered with the first note_moe: a model without experts
+        # shows no moe_* series
+        self._moe_registered = False
+        self._moe_gauges = {
+            labeled(f"moe.{name}", program=p): _weak_moe(p, i)
+            for p in MOE_PROGRAMS for i, name in enumerate(MOE_SERIES)}
         self._gauges = {
             "step.steps_total": _weak_total("steps_total"),
             "step.tokens_advanced_total":
@@ -402,6 +432,32 @@ class StepClock:
         if not self._gauges_registered:
             self._register_gauges()
 
+    def note_moe(self, program: str, layer_calls: int, stats):
+        """What the expert layers of one executed program cost:
+        `layer_calls` of them, and `stats` = (rows through experts,
+        sum over the calls of experts with at least one row, sum of the
+        fullest expert's rows), as the program counted them on the
+        device (parallel/moe.moe_ffn_grouped) and handed back with its
+        tokens. Adds to the cumulative moe.* totals; the next ended
+        step's record carries it for /stepz. The series appear on
+        /metrics with the first note — a model without experts has
+        none."""
+        if not _obs.enabled():
+            return
+        add = (layer_calls, int(stats[0]), int(stats[1]), int(stats[2]))
+        tot = self.moe_total[program]
+        pend = self._pending_moe
+        if pend is None:
+            pend = self._pending_moe = {}
+            if not self._moe_registered:
+                self._moe_registered = True
+                self._gauges.update(self._moe_gauges)
+                self._gauges_registered = False  # re-register with them
+        cur = pend.setdefault(program, [0, 0, 0, 0])
+        for i, v in enumerate(add):
+            tot[i] += v
+            cur[i] += v
+
     def end(self, rec: _StepRec, n_adv: int = 0):
         """Stamp and publish one step. Deliberately MINIMAL — one
         perf_counter read, the cumulative totals (a handful of adds)
@@ -425,6 +481,8 @@ class StepClock:
                 self._pending_admit, []
             rec.admit_parts, self._pending_parts = \
                 self._pending_parts, []
+        if self._pending_moe is not None:
+            rec.moe, self._pending_moe = self._pending_moe, None
         tot = self.phase_seconds_total
         t = rec.t0
         for name, tm in rec.marks:
@@ -647,7 +705,20 @@ class StepClock:
             "sync_tax": round(tot["wait"] / wall, 4) if wall > 0 else 0.0,
             "steps_per_sec": round(self.steps_per_sec(), 3),
             "last_wall_ms": round(self.last_wall_ms(), 4),
+            # the expert layers over the same steps, per program
+            # (MOE_SERIES); {} for a model without experts
+            "moe": self._moe_sums(recs),
         }
+
+    @staticmethod
+    def _moe_sums(recs) -> dict:
+        out: Dict[str, dict] = {}
+        for r in recs:
+            for program, vals in (r.moe or {}).items():
+                cur = out.setdefault(program, dict.fromkeys(MOE_SERIES, 0))
+                for name, v in zip(MOE_SERIES, vals):
+                    cur[name] += v
+        return out
 
     def status_component(self) -> dict:
         """The /statusz `step` component: slow-but-healthy vs wedged at

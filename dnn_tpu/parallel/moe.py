@@ -1,27 +1,34 @@
-"""Mixture-of-Experts FFN with expert parallelism (EP).
+"""Mixture-of-Experts FFN: the grouped drop-free path every
+single-device caller runs, and expert parallelism (EP).
 
 The reference has no MoE anywhere (SURVEY.md §2: "no MoE modules exist" —
 verified absence), so this module is pure capability extension, designed
-TPU-first rather than ported:
+TPU-first rather than ported. Two dispatches, one per place they fit:
 
-  * GShard-style top-k routing with STATIC capacity: dispatch/combine are
-    dense one-hot tensors consumed by einsums — static shapes, no
-    data-dependent control flow under jit, and the expert FFNs run as one
-    batched (E, cap, D) x (E, D, F) matmul that tiles straight onto the
-    MXU. Tokens beyond an expert's capacity are dropped (their combine
-    weight is zero); callers keep a residual connection so dropped tokens
-    pass through unchanged — the standard MoE contract.
-  * Tokens are routed in GROUPS (the GShard "group" = the EP shard unit):
-    capacity is per (group, expert), so the grouped dense path and the
-    expert-parallel path compute IDENTICAL results — the parity invariant
-    the tests pin down.
-  * Expert parallelism: `moe_ffn_ep` runs under `shard_map` with groups
-    sharded over the "expert" mesh axis and expert weights sharded on
-    their leading E axis. Tokens travel to their experts and back via
-    `jax.lax.all_to_all` (XLA AllToAll over ICI) — the TPU-native
-    equivalent of the dispatch the reference would have done with gRPC
-    sends, and the 4th collective family the framework uses (ppermute /
-    psum / all_gather already ride the pipeline, dp×tp, and ring paths).
+  * `moe_ffn_grouped` — ONE device holds every expert: top-k routing,
+    the S*k (token, expert) rows sorted by expert, the group sizes the
+    router gave, and the expert FFN as ragged matmuls over the sorted
+    rows (`jax.lax.ragged_dot`: a grouped-matmul kernel on the TPU).
+    There is NO capacity: every routed row is computed, whatever the
+    imbalance, and the shapes are static in S*k. The work is top_k
+    expert FLOPs per token and each active expert's weights read once —
+    what fine-grained experts (OLMoE: 64 experts, 8 per token) need;
+    the one-hot dispatch below would run every expert on every slot.
+    Serving (`llama_moe.make_ffn`, `generate_moe.moe_cache_ffn`,
+    `gpt_moe.block_apply`) goes through it.
+  * GShard-style top-k routing with STATIC capacity, where per-rank
+    static shapes need it — the `all_to_all` path: dispatch/combine are
+    dense one-hot tensors consumed by einsums, the expert FFNs run as
+    one batched (E, cap, D) x (E, D, F) matmul, and tokens beyond an
+    expert's capacity are dropped (their combine weight is zero; the
+    caller's residual passes them through). Tokens are routed in GROUPS
+    (the GShard "group" = the EP shard unit): capacity is per (group,
+    expert), so `moe_ffn(groups=n)` — the EP path's dense twin, kept for
+    the parity tests — and `moe_ffn_ep` on n devices compute IDENTICAL
+    results. `moe_ffn_ep` runs under `shard_map` with groups sharded
+    over the "expert" mesh axis and expert weights sharded on their
+    leading E axis; tokens travel to their experts and back via
+    `jax.lax.all_to_all` (XLA AllToAll over ICI).
 
 Routing is computed in f32 regardless of compute dtype (router logits are
 tiny and routing decisions must not flip with the activation dtype).
@@ -221,6 +228,114 @@ def _expert_ffn(params, expert_in, *, activation, compute_dtype):
     return out + bo[:, None, :].astype(jnp.float32)  # f32
 
 
+def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True):
+    """Drop-free routing of (S, D) tokens: softmax over all experts (f32),
+    `lax.top_k`, and the S*k (token, expert) assignments sorted by expert
+    (stable: token order within an expert).
+
+    Returns (weights (S, k) f32 — the selected probabilities, renormalized
+    over the k when `normalize`; `order` (S*k,) — sorted position ->
+    flat assignment index t*k + j; `expert_of_row` (S*k,) — the expert of
+    each sorted row; `group_sizes` (E,) int32 — rows per expert)."""
+    e = router_kernel.shape[-1]
+    # "highest": on a TPU a float32 matmul at the default precision rounds
+    # its operands to bfloat16, and the eighth and ninth of 64 experts are
+    # often closer than that; the matmul is (S, D) x (D, E), next to nothing
+    logits = jnp.dot(xs.astype(jnp.float32), router_kernel.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, top_k)  # (S, k)
+    if normalize:
+        weights = weights / jnp.maximum(
+            weights.sum(axis=-1, keepdims=True), 1e-9)
+    flat = experts.reshape(-1).astype(jnp.int32)
+    order = jnp.argsort(flat, stable=True)
+    group_sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
+    return weights, order, flat[order], group_sizes
+
+
+def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
+                     activation, compute_dtype):
+    """The expert FFN over rows sorted by expert, (R, D) -> (R, D) f32:
+    `silu(x@wg) * (x@wu) @ wd` for the gated stack ("wg"), `act(x@wi + bi)
+    @ wo + bo` for the plain one, as ragged matmuls — row r meets only
+    its own expert's weights. Same dtype recipe as `_expert_ffn`
+    (operands in compute_dtype, f32 accumulation); int8 stacks'
+    per-(expert, out-channel) `*_scale` factors are gathered per row and
+    applied to the f32 accumulators."""
+    gated = "wg" in params
+    names = ("wg", "wu", "wd") if gated else ("wi", "wo")
+    ws = [params[k] for k in names]
+    scales = [params.get(k + "_scale") for k in names]
+    cd = compute_dtype if compute_dtype is not None else (
+        jnp.float32 if ws[0].dtype == jnp.int8 else None)
+    x = rows
+    if cd is not None:
+        x = x.astype(cd)
+        ws = [w.astype(cd) for w in ws]
+
+    def rdot(a, w, scale):
+        out = jax.lax.ragged_dot(a, w, group_sizes,
+                                 preferred_element_type=jnp.float32)
+        if scale is not None:  # (E, 1, out) -> this row's expert's (out,)
+            out = out * scale[expert_of_row, 0]
+        return out
+
+    if gated:
+        h = jax.nn.silu(rdot(x, ws[0], scales[0])) * rdot(x, ws[1], scales[1])
+    else:
+        h = activation(rdot(x, ws[0], scales[0])
+                       + params["bi"][expert_of_row].astype(jnp.float32))
+    if cd is not None:
+        h = h.astype(cd)
+    out = rdot(h, ws[-1], scales[-1])
+    if not gated:
+        out = out + params["bo"][expert_of_row].astype(jnp.float32)
+    return out
+
+
+def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
+                    activation=gelu, compute_dtype=None,
+                    return_stats: bool = False):
+    """Drop-free MoE FFN on one device: (..., D) -> (..., D), every token
+    of `x` routed to its top_k experts and every routed row computed.
+    Output does NOT include the residual; callers add it.
+
+    The three scopes name its parts on a device trace: `moe.route`
+    (router matmul, softmax, top-k, the sort and the row gather),
+    `moe.experts` (the ragged matmuls and the gate's product),
+    `moe.combine` (un-sort, weighting, sum over the k).
+
+    `return_stats` adds an int32 (3,): rows through the experts (S*k),
+    experts with at least one row, and the fullest expert's rows — what
+    this layer call cost, for the serving counters."""
+    shape, d = x.shape, x.shape[-1]
+    xs = x.reshape(-1, d)
+    s = xs.shape[0]
+    with jax.named_scope("moe.route"):
+        weights, order, expert_of_row, group_sizes = route_rows(
+            params["router"]["kernel"], xs, top_k=top_k, normalize=normalize)
+        rows = xs[order // top_k]  # (S*k, D), sorted by expert
+    with jax.named_scope("moe.experts"):
+        out = _experts_grouped(params, rows, expert_of_row, group_sizes,
+                               activation=activation,
+                               compute_dtype=compute_dtype)
+    with jax.named_scope("moe.combine"):
+        # the inverse permutation (a scatter, not a second sort) puts row
+        # t*k + j back at (t, j)
+        inverse = jnp.zeros_like(order).at[order].set(
+            jnp.arange(order.shape[0], dtype=order.dtype))
+        unsorted = out[inverse].reshape(s, top_k, d)
+        y = (unsorted * weights[..., None]).sum(axis=1)
+        y = y.reshape(shape).astype(x.dtype)
+    if not return_stats:
+        return y
+    stats = jnp.stack([jnp.int32(s * top_k),
+                       (group_sizes > 0).sum().astype(jnp.int32),
+                       group_sizes.max()])
+    return y, stats
+
+
 def _group_dispatch(params, xg, *, top_k, capacity, normalize):
     """Routing for one (S, D) group -> dispatch/combine/aux (f32)."""
     logits = xg.astype(jnp.float32) @ params["router"]["kernel"].astype(jnp.float32)
@@ -230,7 +345,10 @@ def _group_dispatch(params, xg, *, top_k, capacity, normalize):
 def moe_ffn(params, x, *, top_k: int = 2, capacity_factor: float = 1.25,
             groups: int = 1, activation=gelu, compute_dtype=None,
             return_aux: bool = False, normalize: bool = True):
-    """Dense (single-program) MoE FFN: (B, T, D) -> (B, T, D).
+    """The EP path's dense twin (single-program, static capacity): (B, T,
+    D) -> (B, T, D). Serving does not come here — `moe_ffn_grouped` is the
+    single-device path; this one exists so that the parity tests can
+    compute on one device exactly what `moe_ffn_ep` computes on n.
 
     Tokens are routed in `groups` independent groups (B*T must divide by
     groups); with groups == n_devices this computes exactly what

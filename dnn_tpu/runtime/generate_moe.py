@@ -9,15 +9,13 @@ family's cached-attention machinery (dnn_tpu/runtime/generate.py) and
 swaps the block MLP for the routed MoE FFN (dnn_tpu/parallel/moe.py).
 
 Routing granularity during decode: the MoE FFN routes over whatever
-tokens a forward sees. Prefill routes the whole prompt as one group
-(identical to the stateless forward at batch 1); each decode step routes
-the B current tokens. Per-token top-k routing is batch-independent as
-long as no token is dropped for capacity, so decode output matches the
-full-sequence forward exactly whenever capacity is not exceeded — the
-contract `tests/test_generate_moe.py` pins with a generous
-capacity_factor. (Capacity drops are batch-dependent by construction in
-any capacity-based MoE; that caveat is inherent, not an artifact of the
-cache.)
+tokens a forward sees. On one device the experts are drop-free
+(parallel/moe.moe_ffn_grouped), so per-token top-k routing is
+batch-independent and decode output matches the full-sequence forward —
+the contract `tests/test_generate_moe.py` pins. The expert-parallel
+decoders keep GShard's static capacity (per-rank static shapes for the
+all_to_all), where drops are batch-dependent by construction; their
+parity tests use a generous capacity_factor and the `groups=n` dense twin.
 
 Expert-parallel decode (`make_generate_moe_ep`) runs the WHOLE generate —
 prefill + `lax.scan` decode — as one shard_map program on the expert
@@ -42,7 +40,12 @@ from jax.sharding import PartitionSpec as P
 from dnn_tpu.models.gpt import head
 from dnn_tpu.models.gpt_moe import GPTMoEConfig
 from dnn_tpu.parallel.mesh import EXPERT_AXIS
-from dnn_tpu.parallel.moe import moe_capacity, moe_ffn, moe_ffn_local
+from dnn_tpu.parallel.moe import (
+    moe_capacity,
+    moe_ffn,
+    moe_ffn_grouped,
+    moe_ffn_local,
+)
 from dnn_tpu.runtime.generate import (
     _embed_at,
     _sample,
@@ -64,9 +67,15 @@ __all__ = [
 def moe_cache_ffn(cfg: GPTMoEConfig, *, groups: int = 1, compute_dtype=None):
     """The `ffn(bp, h)` hook that turns any dense cached decoder
     (forward_with_cache / make_generate / ContinuousBatcher) into its MoE
-    counterpart: routes h's tokens through bp["moe"] in `groups` groups."""
+    counterpart: h's tokens through bp["moe"], drop-free (parallel/moe.
+    moe_ffn_grouped — no capacity on a single device). `groups` > 1 is
+    the expert-parallel decoders' dense twin (static capacity per routing
+    group), which their parity tests compare an n-device run with."""
 
     def ffn(bp, h):
+        if groups == 1:
+            return moe_ffn_grouped(bp["moe"], h, top_k=cfg.top_k,
+                                   compute_dtype=compute_dtype)
         return moe_ffn(
             bp["moe"], h, top_k=cfg.top_k,
             capacity_factor=cfg.capacity_factor, groups=groups,

@@ -44,6 +44,7 @@ from __future__ import annotations
 
 import functools
 import time
+from collections import deque
 from typing import Dict, List, Optional
 
 import jax
@@ -623,6 +624,14 @@ class ContinuousBatcher:
         # and the clock itself gates on DNN_TPU_OBS (begin() returns
         # None when off).
         self.step_clock = None
+        # a family whose MLP is a mixture of experts counts what its
+        # expert layers cost (LlamaFamilyRows.moe_stats): the step and
+        # chunk programs then return an int32 (3,) beside the tokens
+        # they already hand back, noted here in dispatch order and given
+        # to the StepClock once a token of the same or a later dispatch
+        # is on the host (_moe_flush) — never a device wait of its own
+        self._moe_stats = bool(getattr(self.family, "moe_stats", False))
+        self._moe_pending: deque = deque(maxlen=4096)
         # live slots holding a grammar constraint — pushed to the
         # StepClock's constrained_slots gauge at admit/retire (one attr
         # store per transition, nothing per step)
@@ -811,8 +820,12 @@ class ContinuousBatcher:
             + one interleaved prefill chunk in the same compiled
             program), so the two paths' decode math is identical by
             construction — the mixed==convoy token-parity contract."""
-            logits, new_cache = self.family.decode_rows(
-                prepared, cache, tok, pos, active, codec)
+            if self._moe_stats:
+                logits, new_cache, moe = self.family.decode_rows(
+                    prepared, cache, tok, pos, active, codec, moe_stats=True)
+            else:
+                logits, new_cache = self.family.decode_rows(
+                    prepared, cache, tok, pos, active, codec)
             # `sample` names the sampling tail on a device trace (the
             # scopes of chipbench/spans.py; the model's own are gpt.*)
             with jax.named_scope("sample"):
@@ -854,6 +867,8 @@ class ContinuousBatcher:
                 # logprobs report the MODEL's distribution (pre-penalty,
                 # pre-temperature — the usual serving-API convention)
                 out += _lp_outputs(logits, nxt)
+            if self._moe_stats:
+                out += (moe,)  # last, after the optional logprobs
             return out
 
         def decode_step(prepared, cache, pos, tok, active, keys,
@@ -880,16 +895,20 @@ class ContinuousBatcher:
             out = _decode_core(prepared, cache, pos, tok, active, keys,
                                temp, tk, tp, mp, rep, seen, bias, crow,
                                ctable, ctrans)
-            pf_logits, new_row = self.family.prefill(
-                pf_prepared, chunk, row, chunk_start)
-            return out + (pf_logits, new_row)
+            # (pf_logits, new_row) and, for an MoE family, the chunk's
+            # expert-layer stats last
+            return out + prefill_chunk(pf_prepared, row, chunk, chunk_start)
 
         def prefill_chunk(prepared, row, chunk, chunk_start):
             """One (1, prompt_pad) chunk of a prompt into the slot-row
             cache at positions [chunk_start, chunk_start+P). Long prompts
             loop this (full chunks + one padded tail) — ONE compiled
             program for any prompt length. Pad positions in the tail write
-            K/V that the per-row position mask never attends."""
+            K/V that the per-row position mask never attends. An MoE
+            family returns its expert layers' stats as a third result."""
+            if self._moe_stats:
+                return self.family.prefill(prepared, chunk, row, chunk_start,
+                                           moe_stats=True)
             return self.family.prefill(prepared, chunk, row, chunk_start)
 
         def prefill_finish(cache, row, logits, last_local, slot, rng,
@@ -1728,7 +1747,7 @@ class ContinuousBatcher:
             else:
                 for c in range(start_chunk, n_chunks):
                     with _prof_annotation("serving.prefill_chunk"):
-                        logits, row = self._prefill_chunk(
+                        logits, row = self._run_prefill_chunk(
                             pf_prepared, row,
                             jnp.asarray(
                                 padded[:, c * p_pad:(c + 1) * p_pad]),
@@ -1796,6 +1815,8 @@ class ContinuousBatcher:
             t_ft0 = time.perf_counter()
             _sp = _profile.open_span("admit.first_token", rid=rid)
             first = int(first)  # blocks until the prefill really finished
+            if self._moe_stats:
+                self._moe_flush()
             if logprobs and self._logprobs_k:
                 first_lp = (float(np.asarray(c_lp)[0]),
                             (np.asarray(t_ids)[0], np.asarray(t_lp)[0]))
@@ -1953,6 +1974,39 @@ class ContinuousBatcher:
             if _sc is not None:
                 _sc.note_admit(_t_sub, _parts)
 
+    def _run_prefill_chunk(self, *args):
+        """The chunk program -> (logits, row); an MoE family's third
+        result, the chunk's expert-layer stats, is noted on the way."""
+        res = self._prefill_chunk(*args)
+        if self._moe_stats:
+            self._moe_note("prefill", res[2])
+        return res[0], res[1]
+
+    def _moe_note(self, program: str, stats, idx: Optional[int] = None):
+        """Keep one dispatched program's expert-layer stats (a device
+        array) until it is known to have run. `idx`: the decode
+        dispatch it belongs to (a step's own, or the next one for a
+        program dispatched between steps)."""
+        self._moe_pending.append(
+            (self._step_idx if idx is None else idx, program, stats))
+
+    def _moe_flush(self, upto: Optional[int] = None):
+        """Give the StepClock the stats of every noted program that has
+        finished: those of decode dispatch `upto` and earlier, once its
+        tokens are on the host (None: all of them — a first token was
+        just read, and the device runs programs in dispatch order). One
+        transfer of twelve bytes a program, from programs already
+        complete."""
+        pend, sc = self._moe_pending, self.step_clock
+        ready = []
+        while pend and (upto is None or pend[0][0] <= upto):
+            ready.append(pend.popleft())
+        if sc is None or not ready:
+            return
+        for (_, program, _), stats in zip(
+                ready, jax.device_get([r[2] for r in ready])):
+            sc.note_moe(program, self.cfg.n_layer, stats)
+
     def _ensure_cache_len(self, need: int):
         """Grow the bucketed dense pool to the smallest ladder bucket
         covering `need` live positions (no-op when already covered, or on
@@ -2030,7 +2084,7 @@ class ContinuousBatcher:
         t_pf = time.perf_counter()
         for c in range(n_chunks):
             with _prof_annotation("serving.prefill_chunk"):
-                logits, row = self._prefill_chunk(
+                logits, row = self._run_prefill_chunk(
                     self.prepared, row,
                     jnp.asarray(padded[:, c * p_pad:(c + 1) * p_pad]),
                     jnp.int32(c * p_pad),
@@ -2300,7 +2354,7 @@ class ContinuousBatcher:
             for i in range(n_k):
                 start = resume + i * p_pad
                 with _prof_annotation("serving.prefill_chunk"):
-                    logits, row = self._prefill_chunk(
+                    logits, row = self._run_prefill_chunk(
                         self.prepared, row,
                         jnp.asarray(padded[:, i * p_pad:(i + 1) * p_pad]),
                         jnp.int32(start))
@@ -2431,7 +2485,7 @@ class ContinuousBatcher:
         for i in range(n_k):
             start = resume + i * p_pad
             with _prof_annotation("serving.prefill_chunk"):
-                logits, row = self._prefill_chunk(
+                logits, row = self._run_prefill_chunk(
                     pf_prepared, row,
                     jnp.asarray(padded_r[:, i * p_pad:(i + 1) * p_pad]),
                     jnp.int32(start))
@@ -3031,6 +3085,8 @@ class ContinuousBatcher:
         if rec is not None:
             sc.mark(rec, "commit")
         self._obs_step_end(m, n_adv, it_samples)
+        if self._moe_stats:
+            self._moe_flush(s_idx)  # this dispatch's tokens are here
         if rec is not None:
             sc.mark(rec, "obs")
             sc.end(rec, n_adv)
@@ -3135,6 +3191,8 @@ class ContinuousBatcher:
                 self._decode_view,
                 self._lora_prefill_view(ilv["p"]["aid"]), *state,
                 ilv["p"]["row"], ilv["chunk"], ilv["start"])
+            if self._moe_stats:
+                res, pf_moe = res[:-1], res[-1]
             res, pf_logits, new_row = res[:-2], res[-2], res[-1]
         # drop the tuple's references to the just-donated buffers NOW:
         # holding them to frame teardown makes their deletion run after
@@ -3146,6 +3204,13 @@ class ContinuousBatcher:
         if rec is not None:
             sc.mark(rec, "dispatch")
             rec.mixed = ilv is not None
+        s_idx = self._step_idx
+        self._step_idx += 1
+        if self._moe_stats:
+            self._moe_note("decode", res[-1], s_idx)
+            res = res[:-1]
+            if ilv is not None:
+                self._moe_note("prefill", pf_moe, s_idx)
         lp_refs = None
         if self._logprobs_k:
             (self.cache, self.pos, self.tok, self.keys, self._seen,
@@ -3154,8 +3219,6 @@ class ContinuousBatcher:
         else:
             (self.cache, self.pos, self.tok, self.keys, self._seen,
              self._crow) = res
-        s_idx = self._step_idx
-        self._step_idx += 1
         if ilv is not None:
             self._ilv_after_chunk(ilv, pf_logits, new_row, s_idx)
         if self._overlap:

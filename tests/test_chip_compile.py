@@ -172,6 +172,24 @@ def step_programs(chip):
 
     cfg = gpt.GPTConfig(n_layer=2, n_embd=1280, n_head=20)
     prepared = gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg), cfg)
+    convoy = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
+                               kv="auto")
+    mixed = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
+                              kv="auto", prefill_chunk_tokens=64)
+    assert convoy._paged and convoy.max_len == CTX
+    compiled = _lower_programs(chip, [
+        (convoy, ("_prefill_chunk", "_prefill_finish", "_decode")),
+        (mixed, ("_mixed", "_ilv_finish"))])
+    pool_bytes = sum(x.nbytes for x in jax.tree.leaves(convoy.cache)
+                     if x.ndim > 3)
+    return compiled, convoy.cache["k"].shape[1:], pool_bytes
+
+
+def _lower_programs(chip, batchers):
+    """{name: compiled for the chip} of the named step programs of each
+    (batcher, names): every program is lowered from its first real call's
+    arguments while two short requests run through the batcher here on
+    the CPU."""
     compiled = {}
 
     def described(x):
@@ -195,23 +213,41 @@ def step_programs(chip):
 
         setattr(b, name, call)
 
-    convoy = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
-                               kv="auto")
-    mixed = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
-                              kv="auto", prefill_chunk_tokens=64)
-    assert convoy._paged and convoy.max_len == CTX
-    for name in ("_prefill_chunk", "_prefill_finish", "_decode"):
-        lower_first(convoy, name)
-    for name in ("_mixed", "_ilv_finish"):
-        lower_first(mixed, name)
-    for b in (convoy, mixed):
+    for b, names in batchers:
+        for name in names:
+            lower_first(b, name)
         b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
         b.step()
         b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
         b.drain()
-    pool_bytes = sum(x.nbytes for x in jax.tree.leaves(convoy.cache)
-                     if x.ndim > 3)
-    return compiled, convoy.cache["k"].shape[1:], pool_bytes
+    return compiled
+
+
+@pytest.fixture(scope="module")
+def olmoe_programs(chip):
+    """The same for the first LLaMA-family model the chip serves: OLMoE at
+    its published widths (64 experts of 1024, 8 per token, 16 heads of
+    128, RoPE, q/k norm), 16 slots of the 4096-position pool the
+    benchmark's daemon holds, 256-token chunks, depth cut to ONE layer
+    (1.7 GB of float32 weights run the two short requests on the CPU).
+    Interleaved admission, so that one batcher gives the decode step, the
+    mixed step and the fused finish."""
+    import dataclasses
+
+    from dnn_tpu.models import gpt, llama_moe
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(llama_moe.PRESETS["olmoe-1b-7b"], n_layer=1)
+    prepared = gpt.prepare_stacked(
+        llama_moe.init(jax.random.PRNGKey(0), cfg), cfg)
+    b = ContinuousBatcher(
+        cfg, prepared, slots=16, kv="auto", prompt_pad=256,
+        prefill_chunk_tokens=256,
+        family=llama_moe.family_rows(cfg, compute_dtype=BF16))
+    assert b._paged and b.max_len == 4096 and b._moe_stats
+    compiled = _lower_programs(
+        chip, [(b, ("_mixed", "_ilv_finish", "_decode"))])
+    return compiled, b.cache["k"].shape[1:], cfg
 
 
 def _pool_extent_ops(compiled, pool):
@@ -242,26 +278,58 @@ def test_serving_step_programs_compile_with_the_kernels(step_programs, name,
             >= pool_bytes
 
 
-@pytest.mark.parametrize("name", ["_decode", "_mixed"])
-def test_decode_programs_leave_the_pool_in_place(step_programs, name):
+@pytest.fixture
+def programs(request):
+    """(compiled, pool extent) of the model a case names: GPT-2 Large or
+    OLMoE — resolved here, so that one model's fixture is built only for
+    its own cases."""
+    got = request.getfixturevalue(
+        {"gpt2": "step_programs", "olmoe": "olmoe_programs"}[request.param])
+    return got[0], got[1]
+
+
+@pytest.mark.parametrize("programs,name", [
+    ("gpt2", "_decode"), ("gpt2", "_mixed"),
+    ("olmoe", "_decode"), ("olmoe", "_mixed")], indirect=["programs"])
+def test_decode_programs_leave_the_pool_in_place(programs, name):
     """ISSUE 25 point 4, on the chip's compiled text: no operation of the
     decode step (nor of the mixed step, which shares its core) has a
     result of the extent of a layer's pool slice or of the whole pool —
     no copy, no dynamic-slice / dynamic-update-slice fusion, no
     copy-start / copy-done, no scatter — but the kernel's own aliased
     pool results."""
-    compiled, pool, _ = step_programs
+    compiled, pool = programs
     ops = _pool_extent_ops(compiled[name], pool)
     assert [o for o in ops if o[0] != "custom-call"] == []
     assert ops, "the kernel hands the pool back through its results"
 
 
-@pytest.mark.parametrize("name", ["_prefill_finish", "_ilv_finish"])
-def test_finish_programs_install_without_a_pool_copy(step_programs, name):
+@pytest.mark.parametrize("programs,name", [
+    ("gpt2", "_prefill_finish"), ("gpt2", "_ilv_finish"),
+    ("olmoe", "_ilv_finish")], indirect=["programs"])
+def test_finish_programs_install_without_a_pool_copy(programs, name):
     """The finish installs the row's blocks with one in-place scatter a
     leaf; stored lane-padded the pool is block-contiguous by its shape and
-    needs no relayout around it."""
-    compiled, pool, _ = step_programs
+    needs no relayout around it (OLMoE's 128-wide heads need no padding
+    at all)."""
+    compiled, pool = programs
     ops = _pool_extent_ops(compiled[name], pool)
     assert {o[0] for o in ops} <= {"scatter", "fusion"}, ops
     assert compiled[name].memory_analysis().temp_size_in_bytes < 2 ** 24
+
+
+def test_olmoe_decode_step_gathers_no_expert_weights_by_token(
+        olmoe_programs):
+    """The grouped experts meet their weights through the ragged matmul
+    alone: no operation of the decode step has a result with one expert
+    matrix per routed row — (S*k, D, F) or (S*k, F, D), 128 rows x 2 M
+    weights here — which is what gathering `wg[expert_of_row]` would
+    build; and the ragged matmul is the chip's grouped kernel, not a
+    dense product over all 64 experts."""
+    compiled, _, cfg = olmoe_programs
+    text = compiled["_decode"].as_text()
+    rows = 16 * cfg.router_top_k
+    d, f = cfg.n_embd, cfg.d_ff
+    per_row = re.compile(r"\[%d,(?:%d,%d|%d,%d)\]" % (rows, d, f, f, d))
+    assert not [l for l in text.splitlines() if per_row.search(l)]
+    assert "ragged-dot" in text and "tpu_custom_call" in text

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from dnn_tpu.models import gpt, llama_moe
+from dnn_tpu.models import gpt, llama, llama_moe
 
 CFG = llama_moe.PRESETS["mixtral-test"]
 
@@ -96,14 +96,26 @@ def test_batcher_matches_solo():
         np.testing.assert_array_equal(srv.results[rid], w)
 
 
-def test_capacity_drop_degrades_to_residual():
-    """A starved capacity factor must still run (dropped tokens pass
-    through on the residual) and change the output vs full capacity."""
+@pytest.mark.parametrize("path", ["single_device", "ep_dense_twin"])
+def test_capacity_acts_only_on_the_ep_path(path):
+    """A starved capacity factor changes nothing on one device — the
+    grouped experts are drop-free and never read it — and on the
+    expert-parallel path's dense twin (`groups=n`, static capacity per
+    routing group) it still runs, dropped tokens passing through on the
+    residual, with a different output than full capacity. (Before PR 26
+    this pinned dropping on the single-device path.)"""
     p = _params(seed=6)
     tight = dataclasses.replace(CFG, capacity_factor=0.25)
-    ids = np.random.RandomState(7).randint(0, CFG.vocab_size, (2, 16))
-    full = np.asarray(llama_moe.make_apply(CFG)(p, jnp.asarray(ids)))
-    dropped = np.asarray(llama_moe.make_apply(tight)(p, jnp.asarray(ids)))
+    ids = jnp.asarray(
+        np.random.RandomState(7).randint(0, CFG.vocab_size, (2, 16)))
+    if path == "single_device":
+        full = np.asarray(llama_moe.make_apply(CFG)(p, ids))
+        starved = np.asarray(llama_moe.make_apply(tight)(p, ids))
+        np.testing.assert_array_equal(starved, full)
+        return
+    full, dropped = (np.asarray(llama.make_apply(
+        c, ffn=llama_moe.make_ffn(c, groups=2))(p, ids))
+        for c in (CFG, tight))
     assert np.isfinite(dropped).all()
     assert np.abs(full - dropped).max() > 1e-6
 

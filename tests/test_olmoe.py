@@ -1,0 +1,288 @@
+"""OLMoE (64 fine-grained experts, 8 per token, raw top-k weights, q/k
+norm over the projection width) on the normal path, against the plain
+float32 reference the benchmark keeps (chipbench/reference/olmoe.py, which
+imports no model code from dnn_tpu). CPU, `olmoe-test`, seeded.
+
+Tolerances: everything here is float32 on the CPU, where the program and
+the reference differ only in summation order (ragged matmuls over sorted
+rows against a dense loop over experts; a scan over stacked layers against
+a Python loop). Logits of O(1) through three layers agree to ~2e-6; 1e-4
+leaves two orders for other CPUs' matmul kernels and is still ~1000x
+tighter than a bfloat16 operand anywhere would pass (2^-8 relative on
+O(1) values), and far tighter than a wrong expert, a dropped row or a
+renormalised weight (all O(0.1)).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench.reference import olmoe as reference
+from dnn_tpu.models import gpt, llama, llama_moe
+from dnn_tpu.parallel import moe
+
+CFG = llama_moe.PRESETS["olmoe-test"]
+ATOL = 1e-4
+
+
+def _params(seed=0):
+    """Seeded init with the norm scales moved off 1.0, so that a norm
+    applied at the wrong width or not at all shows."""
+    p = llama_moe.init(jax.random.PRNGKey(seed), CFG)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 100), 64))
+
+    def jitter(tree):
+        return {"scale": tree["scale"] * (
+            1.0 + 0.2 * jax.random.normal(next(keys), tree["scale"].shape))}
+
+    for i in range(CFG.n_layer):
+        blk = p[f"h_{i}"]
+        blk["ln_1"], blk["ln_2"] = jitter(blk["ln_1"]), jitter(blk["ln_2"])
+        blk["attn"]["q_norm"] = jitter(blk["attn"]["q_norm"])
+        blk["attn"]["k_norm"] = jitter(blk["attn"]["k_norm"])
+    p["ln_f"] = jitter(p["ln_f"])
+    return p
+
+
+def _ids(seed, shape):
+    return np.random.RandomState(seed).randint(0, CFG.vocab_size, shape)
+
+
+def test_presets_are_the_published_config():
+    full = llama_moe.PRESETS["olmoe-1b-7b"]
+    assert (full.n_layer, full.n_embd, full.n_head, full.n_kv_head,
+            full.head_dim, full.d_ff, full.n_expert, full.router_top_k,
+            full.vocab_size, full.block_size) == (
+        16, 2048, 16, 16, 128, 1024, 64, 8, 50304, 4096)
+    assert (full.rope_theta, full.rms_eps) == (10000.0, 1e-5)
+    assert full.qk_norm and full.qk_norm_width == "proj" and full.pre_norm
+    assert not (full.router_norm_topk or full.tie_word_embeddings
+                or full.attn_bias or full.d_shared)
+    cut = llama_moe.PRESETS["olmoe-1b-7b-1chip"]
+    assert cut == dataclasses.replace(full, n_layer=3)
+    assert (CFG.n_head, CFG.n_kv_head, CFG.head_dim, CFG.n_expert,
+            CFG.router_top_k) == (4, 4, 16, 8, 4)
+
+
+def test_forward_logits_match_the_reference():
+    p = _params()
+    ids = _ids(1, (2, 40))
+    got = np.asarray(llama_moe.make_apply(CFG)(p, jnp.asarray(ids)))
+    want = np.asarray(reference.logits(CFG, p, ids))
+    assert np.abs(want).max() > 0.1  # the comparison is not of zeros
+    np.testing.assert_allclose(got, want, atol=ATOL, rtol=0)
+
+
+def test_prefill_then_paged_decode_logits_match_the_reference():
+    """Chunked prefill of two prompts into transient rows, installed into
+    a PAGED pool at two slots, then twelve decode steps of both slots at
+    their own positions (10.. and 23..) through LlamaFamilyRows — every
+    step's logits against the reference's full forward of the whole
+    sequence. The step also counts its expert layers."""
+    from dnn_tpu.runtime.paged_kvcache import PagedKV, init_paged_cache
+
+    p = _params(seed=2)
+    prepared = gpt.prepare_stacked(p, CFG)
+    fam = llama_moe.family_rows(CFG)
+    assert fam.moe_stats
+    lens, n_new, bl, max_len = (10, 23), 12, 16, 64
+    seqs = [_ids(10 + i, (n + n_new,)) for i, n in enumerate(lens)]
+    want = [np.asarray(reference.logits(CFG, p, s[None]))[0] for s in seqs]
+
+    codec = PagedKV(bl)
+    pool = init_paged_cache(CFG, 2, max_len, n_blocks=9, block_len=bl,
+                            kv_heads=CFG.n_kv_head)
+    tables = np.array([[1, 2, 3, 4], [5, 6, 7, 8]], np.int32)
+    pool["tables"] = jnp.broadcast_to(jnp.asarray(tables),
+                                      pool["tables"].shape)
+    for slot, (n, s) in enumerate(zip(lens, seqs)):
+        row = fam.init_cache(1, max_len, jnp.float32)
+        padded = np.zeros((1, 32), np.int32)
+        padded[0, :n] = s[:n]
+        for c in range(2):  # two chunks of 16, the second padded
+            logits, row, stats = fam.prefill(
+                prepared, jnp.asarray(padded[:, 16 * c:16 * (c + 1)]), row,
+                16 * c, moe_stats=True)
+            assert int(stats[0]) == CFG.n_layer * 16 * CFG.router_top_k
+            live = max(0, min(16, n - 16 * c))
+            np.testing.assert_allclose(
+                np.asarray(logits[0, :live]),
+                want[slot][16 * c:16 * c + live], atol=ATOL, rtol=0)
+        pool = codec.install_row(pool, row, jnp.asarray(tables[slot]))
+
+    step = jax.jit(lambda cache, tok, pos: fam.decode_rows(
+        prepared, cache, tok, pos, jnp.ones((2,), bool), codec,
+        moe_stats=True))
+    pos = np.array(lens, np.int32)
+    for j in range(n_new):
+        tok = np.array([s[n + j] for n, s in zip(lens, seqs)], np.int32)
+        logits, pool, stats = step(pool, jnp.asarray(tok), jnp.asarray(pos))
+        for slot in range(2):
+            np.testing.assert_allclose(
+                np.asarray(logits[slot]), want[slot][pos[slot]], atol=ATOL,
+                rtol=0, err_msg=f"step {j} slot {slot}")
+        rows, active, peak = (int(v) for v in stats)
+        assert rows == CFG.n_layer * 2 * CFG.router_top_k
+        assert CFG.n_layer * CFG.router_top_k <= active <= rows
+        assert CFG.n_layer <= peak <= 2 * CFG.n_layer
+        pos += 1
+
+
+def _dense_loop(params, x, *, top_k, normalize):
+    """Every expert on every row, masked by the routing weights: the
+    reference's formulation, on parallel/moe's parameter names."""
+    xs = np.asarray(x, np.float64).reshape(-1, x.shape[-1])
+    logits = xs @ np.asarray(params["router"]["kernel"], np.float64)
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    idx = np.argsort(-probs, axis=-1, kind="stable")[:, :top_k]
+    out = np.zeros_like(xs)
+    for t in range(xs.shape[0]):
+        w = probs[t, idx[t]]
+        if normalize:
+            w = w / w.sum()
+        for e, we in zip(idx[t], w):
+            g = xs[t] @ np.asarray(params["wg"][e], np.float64)
+            u = xs[t] @ np.asarray(params["wu"][e], np.float64)
+            out[t] += we * ((g / (1 + np.exp(-g))) * u) @ np.asarray(
+                params["wd"][e], np.float64)
+    return out.reshape(x.shape)
+
+
+@pytest.mark.parametrize("case", ["empty_expert", "all_rows_on_one_expert",
+                                  "odd_row_count", "renormalised"])
+def test_grouped_experts_are_drop_free(case):
+    """The grouped path against the dense loop where a capacity would
+    bite: an expert that gets no row; EVERY row on one expert (a static
+    capacity would keep S*k/E of them); S*k a multiple of nothing."""
+    d, f, e = 32, 16, 8
+    params = moe.init_moe_gated(jax.random.PRNGKey(3), d, e, f)
+    top_k, normalize, shape = 2, False, (2, 8, d)
+    router = np.asarray(params["router"]["kernel"]).copy()
+    if case == "empty_expert":
+        router[:, 5] = 0.0
+        router[0, 5] = -1e4  # with x[..., 0] > 0: never chosen
+    elif case == "all_rows_on_one_expert":
+        top_k = 1
+        router[:] = 0.0
+        router[0, 3] = 1e4
+    elif case == "odd_row_count":
+        top_k, shape = 3, (1, 7, d)  # 21 rows over 8 experts
+    else:
+        normalize = True
+    params["router"]["kernel"] = jnp.asarray(router)
+    x = jax.random.normal(jax.random.PRNGKey(4), shape)
+    x = x.at[..., 0].set(jnp.abs(x[..., 0]) + 0.1)
+    from dnn_tpu.ops.nn import silu
+
+    got, stats = jax.jit(lambda p, v: moe.moe_ffn_grouped(
+        p, v, top_k=top_k, normalize=normalize, activation=silu,
+        return_stats=True))(params, x)
+    want = _dense_loop(params, x, top_k=top_k, normalize=normalize)
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
+    rows, active, peak = (int(v) for v in stats)
+    n = int(np.prod(shape[:-1]))
+    assert rows == n * top_k
+    if case == "empty_expert":
+        assert active <= e - 1
+    if case == "all_rows_on_one_expert":
+        assert (active, peak) == (1, n)  # nothing dropped: all 16 computed
+        assert np.abs(np.asarray(got)).reshape(n, d).sum(-1).min() > 0
+
+
+def test_hf_olmoe_names_map_onto_the_tree():
+    """`OlmoeForCausalLM`'s names on a synthetic state dict (no download):
+    mlp.gate, mlp.experts.i.{gate,up,down}_proj, self_attn.{q,k}_norm —
+    and no shared expert."""
+    p = jax.tree.map(np.asarray, _params(seed=5))
+    sd = {"model.embed_tokens.weight": p["wte"]["embedding"],
+          "model.norm.weight": p["ln_f"]["scale"],
+          "lm_head.weight": p["lm_head"]["kernel"].T}
+    for i in range(CFG.n_layer):
+        blk, pre = p[f"h_{i}"], f"model.layers.{i}."
+        sd[pre + "input_layernorm.weight"] = blk["ln_1"]["scale"]
+        sd[pre + "post_attention_layernorm.weight"] = blk["ln_2"]["scale"]
+        for ours, theirs in (("q", "q_proj"), ("k", "k_proj"),
+                             ("v", "v_proj"), ("o", "o_proj")):
+            sd[pre + f"self_attn.{theirs}.weight"] = \
+                blk["attn"][ours]["kernel"].T
+        sd[pre + "self_attn.q_norm.weight"] = blk["attn"]["q_norm"]["scale"]
+        sd[pre + "self_attn.k_norm.weight"] = blk["attn"]["k_norm"]["scale"]
+        sd[pre + "mlp.gate.weight"] = blk["moe"]["router"]["kernel"].T
+        for e in range(CFG.n_expert):
+            for ours, theirs in (("wg", "gate_proj"), ("wu", "up_proj"),
+                                 ("wd", "down_proj")):
+                sd[pre + f"mlp.experts.{e}.{theirs}.weight"] = \
+                    blk["moe"][ours][e].T
+    got = llama_moe.params_from_state_dict(sd, n_layer=CFG.n_layer)
+    assert "shared" not in got["h_0"]["moe"]
+    flat_got = dict(jax.tree_util.tree_leaves_with_path(got))
+    flat_want = dict(jax.tree_util.tree_leaves_with_path(p))
+    assert set(flat_got) == set(flat_want)
+    for path, leaf in flat_want.items():
+        np.testing.assert_array_equal(np.asarray(flat_got[path]), leaf,
+                                      err_msg=str(path))
+    ids = _ids(6, (1, 12))
+    np.testing.assert_allclose(
+        np.asarray(llama_moe.make_apply(CFG)(got, jnp.asarray(ids))),
+        np.asarray(reference.logits(CFG, p, ids)), atol=ATOL, rtol=0)
+
+
+@pytest.mark.parametrize("overlap", [False, True])
+def test_moe_counters_after_a_known_number_of_steps(overlap, monkeypatch):
+    """The four moe_* series per program on /metrics, after one admission
+    of two chunks and a known number of decode steps through the batcher:
+    exact to the last ended step, the same over /stepz's ring."""
+    from dnn_tpu import obs
+    from dnn_tpu.obs.timeline import StepClock
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+    from dnn_tpu.utils.metrics import Metrics, render_prometheus
+
+    monkeypatch.setenv("DNN_TPU_OBS", "1")
+    if not obs.enabled():
+        pytest.skip("observability gate is off in this process")
+    p = _params(seed=7)
+    slots, pad, n_new = 2, 16, 5
+    srv = ContinuousBatcher(
+        CFG, gpt.prepare_stacked(p, CFG), slots=slots, max_len=64,
+        prompt_pad=pad, paged_blocks=9, block_len=16, overlap=overlap,
+        family=llama_moe.family_rows(CFG))
+    reg = Metrics()
+    srv.step_clock = clk = StepClock(registry=reg)
+    text = render_prometheus(reg)
+    assert "moe_" not in text  # no expert layer has run yet
+    rid = srv.submit(_ids(8, (20,)), max_new_tokens=n_new)  # two chunks
+    out = srv.drain()
+    assert len(out[rid]) == n_new
+    k, layers = CFG.router_top_k, CFG.n_layer
+    steps = clk.moe_total["decode"][0] // layers
+    # the first token comes from the prefill; each later one from a step
+    # (overlap dispatches one step more than it commits)
+    assert steps in (n_new - 1, n_new)
+    calls, rows, active, peak = clk.moe_total["decode"]
+    assert calls == steps * layers and rows == calls * slots * k
+    assert calls * k <= active <= rows and calls <= peak <= calls * slots
+    assert clk.moe_total["prefill"][:2] == [2 * layers, 2 * layers * pad * k]
+    series = dict(line.rsplit(" ", 1)
+                  for line in render_prometheus(reg).splitlines()
+                  if line and not line.startswith("#"))
+    for program in ("decode", "prefill"):
+        for i, name in enumerate(("layer_calls_total", "assignments_total",
+                                  "active_experts_total",
+                                  "peak_expert_rows_total")):
+            assert float(series[f'moe_{name}{{program="{program}"}}']) == \
+                clk.moe_total[program][i]
+    ring = clk.summary()["moe"]
+    assert ring["decode"]["layer_calls_total"] == calls
+    assert ring["prefill"]["assignments_total"] == 2 * layers * pad * k
+    assert not srv._moe_pending
+
+
+def test_a_dense_family_has_no_moe_series():
+    fam = llama.LlamaFamilyRows(llama.PRESETS["llama-test"])
+    assert not fam.moe_stats
