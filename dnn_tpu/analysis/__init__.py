@@ -37,7 +37,7 @@ CLI paths), plus one runtime companion:
   * loop-lag sanitizer (analysis/sanitize.py) — the RUNTIME companion
     for blocking calls no per-module AST pass can see through an
     indirection: an env-gated event-loop self-timer emitting bounded
-    flight events, asserted in-run by the transport/chaos probes.
+    flight events a run can read back from /debugz.
 
 Gate: `python -m dnn_tpu.analysis` — exits nonzero on any finding not in
 analysis/baseline.json; baselined findings are enumerated (never hidden)

@@ -157,8 +157,7 @@ def main(argv=None) -> int:
                          "document on stdout for CI annotation")
     ap.add_argument("--max-len", type=int, default=128,
                     help="cache allocation the decode census sweeps to "
-                         "(default 128; benchmarks/STUDIES.md §7 records "
-                         "1024)")
+                         "(default 128)")
     ap.add_argument("--json", action="store_true", dest="as_json",
                     help="machine-readable report on stdout")
     ap.add_argument("--list-rules", action="store_true")

@@ -362,6 +362,25 @@ def check_decode_program(name, jit_fn, args, donate_idx, layer_elems,
              "cache_sized_ops": copies}, findings)
 
 
+def decode_step_args(b) -> tuple:
+    """The argument tuple of a ContinuousBatcher's decode-step program
+    (`b._decode`), from the batcher's own state. The one place the audit
+    — and any test that lowers these programs — spells the signature."""
+    return (b._decode_view, b.cache, b.pos, b.tok, b.active, b.keys,
+            b._temp, b._topk, b._topp, b._minp, b._rep, b._seen,
+            b._bias, b._crow, b._ctable, b._ctrans)
+
+
+def mixed_step_args(b, chunk_tokens: int) -> tuple:
+    """The argument tuple of the mixed-step program (`b._mixed`): the
+    decode step's, with the prefill view first and a fresh row cache, one
+    (1, chunk_tokens) prompt chunk and its start position last."""
+    base = decode_step_args(b)
+    return (base[0], base[0]) + base[1:] + (
+        b._ilv_new_row(), jnp.zeros((1, chunk_tokens), jnp.int32),
+        jnp.int32(0))
+
+
 def audit_serving_decode(cfg=None, *, slots: int = 2,
                          max_len: int = 128) -> dict:
     """ISSUE 6 donation-coverage GATE over the SERVING decode programs:
@@ -392,11 +411,6 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
         findings.extend(f)
         report[name] = entry
 
-    def batcher_args(b):
-        return (b._decode_view, b.cache, b.pos, b.tok, b.active, b.keys,
-                b._temp, b._topk, b._topp, b._minp, b._rep, b._seen,
-                b._bias, b._crow, b._ctable, b._ctrans)
-
     variants = {
         "dense_f32": {},
         "dense_int8": {"kv_dtype": "int8"},
@@ -424,7 +438,7 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
             layer_elems = slots * cfg.n_head * b._cache_len * hd
         # donated argnums mirror serving.py's jit construction
         # (cache, pos, tok, keys, seen — plus crow when constrained)
-        lower_and_check(name, b._decode, batcher_args(b),
+        lower_and_check(name, b._decode, decode_step_args(b),
                         b._decode_donate, layer_elems)
 
     # the speculative step (serving_spec.py): both caches + the per-slot
@@ -448,11 +462,8 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
     p_c = 16
 
     def ilv_args(b):
+        m_args = mixed_step_args(b, p_c)
         row = b._ilv_new_row()
-        chunk = jnp.zeros((1, p_c), jnp.int32)
-        base = batcher_args(b)
-        m_args = (base[0], base[0]) + base[1:] + (row, chunk,
-                                                  jnp.int32(0))
         v = cfg.vocab_size
         nb_max = (b.cache["tables"].shape[-1] if b._paged else 0)
         f_args = (b.cache, row,

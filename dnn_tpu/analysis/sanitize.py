@@ -14,9 +14,8 @@ Shape follows the obs conventions: OFF by one boolean
 instrument, not a production default), bounded (a deque of recent lag
 samples, a cap on emitted flight events), and flight-event-emitting —
 each breach lands in the ring as a `loop_lag` event with the measured
-lag, so `benchmarks/chaos_probe.py` / `relay_transport_probe.py` read
-the served /debugz back and assert the bound IN-RUN against the
-artifact. A `loop_sanitize_on` event at install proves the sanitizer
+lag, so a run can read the served /debugz back and assert the bound
+against the artifact. A `loop_sanitize_on` event at install proves the sanitizer
 actually ran (an assertion against an empty ring must not pass
 vacuously).
 
@@ -137,9 +136,9 @@ class LoopLagSanitizer:
 
     def assert_bounded(self, bound_s: float):
         """Raise AssertionError when any observed lag exceeded
-        `bound_s` — the in-run contract the transport/chaos probes
-        hold (their bound tolerates first-compile GIL stalls; a
-        reintroduced blocking-primitive wait blows well past it)."""
+        `bound_s` (a bound that tolerates first-compile GIL stalls
+        still catches a reintroduced blocking-primitive wait, which
+        blows well past it)."""
         if self.max_lag_s > bound_s:
             raise AssertionError(
                 f"event loop lag {self.max_lag_s * 1e3:.0f} ms exceeds "
@@ -151,7 +150,7 @@ class LoopLagSanitizer:
 
 def read_endpoint(base_url: str, timeout: float = 10.0) -> dict:
     """Read a serving process's sanitizer record back off its /debugz
-    (the probes' assertion input is the served ARTIFACT, not in-process
+    (what a caller asserts on is the served ARTIFACT, not in-process
     state): -> {installed, breaches, max_lag_ms}. `installed` False
     means the assertion would be vacuous — the caller should fail it."""
     import json as _json

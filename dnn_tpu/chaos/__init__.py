@@ -24,12 +24,6 @@ This package is the other half:
     restoring from the latest GOOD checkpoint
     (`restore_latest_good`) and re-warming before declaring recovery
     (`supervisor_restart` flight events pair with the injections).
-
-`benchmarks/chaos_probe.py` closes the loop: open-loop load through a
-real 2-stage pipeline under the standard FaultPlan, asserting
-availability, p99-TTFT-after-recovery and inject/recovery event
-pairing — resilience as a regression-asserted number, the way PR 6 did
-MBU and PR 7 did bubble fraction.
 """
 
 from dnn_tpu.chaos.inject import (  # noqa: F401

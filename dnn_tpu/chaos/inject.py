@@ -220,7 +220,7 @@ class Injector:
     # -- wedge (watchdog probe hook) ------------------------------------
 
     def activate_wedge(self, duration_s: Optional[float] = None):
-        """Manual wedge window (tests / the probe driver); None = until
+        """Manual wedge window (tests / a chaos driver); None = until
         clear_wedge()."""
         with self._lock:
             self._wedge_until = (float("inf") if duration_s is None

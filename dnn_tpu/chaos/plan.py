@@ -1,8 +1,7 @@
 """FaultPlan: a deterministic, seeded schedule of injected faults.
 
 A plan is data, not behavior: the process-level faults (`kill_stage`,
-`hang_stage`) are executed by whoever supervises the processes (the
-chaos probe's driver, `benchmarks/chaos_probe.py`); the in-process
+`hang_stage`) are executed by whoever supervises the processes; the in-process
 faults are installed as an `inject.Injector` and consulted at the
 seams (comm client/service, relay assembler, LM batcher worker,
 watchdog probe).
@@ -46,7 +45,7 @@ import dataclasses
 import hashlib
 import json
 import os
-from typing import List, Optional
+from typing import List
 
 __all__ = ["Fault", "FaultPlan", "decide", "KINDS"]
 
@@ -163,37 +162,5 @@ class FaultPlan:
                 "faults": [dataclasses.asdict(f) for f in self.faults]}
 
 
-def standard_plan(*, kill_target: str = "node2",
-                  hang_target: str = "node1",
-                  kill_at_s: float = 15.0,
-                  hang_at_s: float = 40.0,
-                  hang_duration_s: float = 120.0,
-                  donor_kill_at_s: Optional[float] = None,
-                  donor_target: str = "") -> FaultPlan:
-    """THE standard FaultPlan the acceptance contract names: one stage
-    kill plus one injected wedge (a hang the supervisor must detect and
-    recover) during an open-loop run. `hang_duration_s` outlives any
-    plausible health-poll detection window, so recovery always comes
-    from the supervisor's kill+restart, never from the hang expiring.
-
-    `donor_kill_at_s` (the KV-tier leg, dnn_tpu/kvtier) appends a
-    `kill_donor` fault: the harness SIGKILLs the replica currently
-    acting as a block-migration DONOR at that offset — mid-migration
-    by construction when the driver times it inside a pull window.
-    The asserted outcome (kv_tier probe / tests/test_kvtier.py): the
-    donor's lease expires, the adopter re-prefills via its
-    `kvtier_fallback` path with ZERO token divergence, and the pool
-    high-water returns to baseline (zero leaked blocks)."""
-    faults = [
-        Fault(kind="kill_stage", target=kill_target, at_s=kill_at_s),
-        Fault(kind="hang_stage", target=hang_target, at_s=hang_at_s,
-              duration_s=hang_duration_s),
-    ]
-    if donor_kill_at_s is not None:
-        faults.append(Fault(kind="kill_donor", target=donor_target,
-                            at_s=float(donor_kill_at_s)))
-    return FaultPlan(faults=tuple(faults))
-
-
-__all__ += ["standard_plan", "PROCESS_KINDS", "INPROCESS_KINDS",
+__all__ += ["PROCESS_KINDS", "INPROCESS_KINDS",
             "FILE_KINDS"]

@@ -25,8 +25,7 @@ LM daemon), and
 
 Flight events (`supervisor_*`) pair with the injections that caused
 them: `stage_down`/`stage_wedged` on detection, `supervisor_restart`
-on a completed recovery — `benchmarks/chaos_probe.py` asserts the
-pairing from the dumped ring.
+on a completed recovery.
 """
 
 from __future__ import annotations
@@ -40,7 +39,7 @@ from typing import Callable, List, Optional
 
 from dnn_tpu.obs import flight
 
-__all__ = ["Supervisor", "restore_latest_good", "recover_backend"]
+__all__ = ["Supervisor", "restore_latest_good"]
 
 
 class Supervisor:
@@ -387,24 +386,3 @@ def restore_latest_good(ckpt_dir: str, like, *, max_back: int = 5):
         + "; ".join(f"{os.path.basename(p)}: {e[:80]}"
                     for p, e in errors))
 
-
-def recover_backend(platform: Optional[str] = None, *,
-                    deadline_s: float = 300.0):
-    """The supervisor restart path for a WEDGED DEVICE BACKEND (no
-    child process to restart — the wedge lives in the driver/plugin):
-    a fresh subprocess re-initializes the platform from nothing and
-    runs one real op, which is the only restart a user-space harness
-    can give a device runtime. Returns (ok, detail). Used by bench.py's
-    round driver when the probe reports wedged mid-round; `deadline_s`
-    defaults to the longest healthy cold init the bench ladder allows
-    (300 s), so a slow-but-recovering plugin is never re-declared dead
-    by its own recovery probe."""
-    from dnn_tpu.obs.watchdog import subprocess_device_probe
-
-    flight.record("supervisor_device_restart", platform=platform,
-                  deadline_s=deadline_s)
-    ok, detail, timed_out = subprocess_device_probe(
-        deadline_s, platform=platform)
-    flight.record("supervisor_device_restart_done", ok=ok,
-                  detail=detail[:200], timed_out=timed_out)
-    return ok, detail

@@ -188,6 +188,22 @@ def _gen_rid(max_new_tokens, seed, temperature, top_k, top_p,
     return rid
 
 
+class _Channel:
+    """One gRPC channel and the count of calls riding it. A channel
+    NodeClient has replaced is closed when its last call ends, never
+    under one: closing cancels every call on it, and a call that is
+    decoding on a live server is not the wedged connect the rebuild is
+    for."""
+
+    __slots__ = ("chan", "live", "retired")
+
+    def __init__(self, address: str):
+        self.chan = grpc.insecure_channel(
+            address, options=_tx.GRPC_MSG_OPTIONS)
+        self.live = 0
+        self.retired = False
+
+
 class NodeClient:
     """Sync client for a NodeService endpoint (ours or a reference node's —
     the wire protocol is identical).
@@ -211,7 +227,8 @@ class NodeClient:
     channel whose first connects failed can sit out gRPC's internal
     reconnect backoff and miss a server that has since come up — the
     PR 7 lesson the transport test used to work around with a fresh
-    client per poll. Health probes count toward (and benefit from) the
+    client per poll. Calls still running on the replaced channel finish
+    there (`_Channel`). Health probes count toward (and benefit from) the
     rebuild streak but bypass the breaker — they ARE the recovery
     probe."""
 
@@ -228,8 +245,7 @@ class NodeClient:
                 f"{transport!r}")
         self.address = address
         self.transport = transport
-        self._channel = grpc.insecure_channel(
-            address, options=_tx.GRPC_MSG_OPTIONS)
+        self._chan = _Channel(address)
         self._chan_lock = threading.Lock()
         self._conn_fail_streak = 0
         self._last_rebuild = 0.0
@@ -261,6 +277,35 @@ class NodeClient:
         if self._conn_fail_streak >= self.rebuild_after:
             self._rebuild_channel()
 
+    def _lease(self) -> _Channel:
+        """The current channel, with one more call counted on it; every
+        lease is handed back through `_release`."""
+        with self._chan_lock:
+            ch = self._chan
+            ch.live += 1
+        return ch
+
+    def _release(self, ch: _Channel) -> None:
+        with self._chan_lock:
+            ch.live -= 1
+            close = ch.retired and ch.live == 0
+        if close:
+            ch.chan.close()
+
+    def _unary(self, method: str, request, *, timeout: float,
+               request_serializer, response_deserializer):
+        """One unary call on the current channel (looked up per call: a
+        rebuild between attempts takes effect on the next attempt)."""
+        ch = self._lease()
+        try:
+            return ch.chan.unary_unary(
+                f"/{SERVICE_NAME}/{method}",
+                request_serializer=request_serializer,
+                response_deserializer=response_deserializer,
+            )(request, timeout=timeout)
+        finally:
+            self._release(ch)
+
     def _rebuild_channel(self):
         with self._chan_lock:
             now = time.monotonic()
@@ -271,15 +316,13 @@ class NodeClient:
                 self._conn_fail_streak = 0
                 return
             self._last_rebuild = now
-            old, self._channel = self._channel, grpc.insecure_channel(
-                self.address, options=_tx.GRPC_MSG_OPTIONS)
+            old, self._chan = self._chan, _Channel(self.address)
+            old.retired = True
             self._conn_fail_streak = 0
             self.channel_rebuilds += 1
-        try:
-            old.close()  # cancels any straggler calls still parked on
-            # the backoff channel — they were failing anyway
-        except Exception:  # noqa: BLE001 — already-closed channel
-            pass
+            close = old.live == 0
+        if close:
+            old.chan.close()  # else its last call's _release closes it
         m = obs.metrics()
         if m is not None:
             m.inc(labeled("comm.channel_rebuilds_total",
@@ -295,14 +338,13 @@ class NodeClient:
                           timeout: float = 10.0) -> str:
         """Bare SendMessage (no spans/tagging) — the negotiation
         side-channel."""
-        call = self._channel.unary_unary(
-            f"/{SERVICE_NAME}/SendMessage",
+        return self._unary(
+            "SendMessage",
+            pb.MessageRequest(sender_id=sender_id, message_text=text),
+            timeout=timeout,
             request_serializer=pb.MessageRequest.SerializeToString,
             response_deserializer=pb.MessageReply.FromString,
-        )
-        return call(pb.MessageRequest(sender_id=sender_id,
-                                      message_text=text),
-                    timeout=timeout).confirmation_text
+        ).confirmation_text
 
     def _ensure_negotiated(self) -> _tx.Negotiated:
         """Negotiate once per client. A transport-level RPC failure
@@ -334,13 +376,12 @@ class NodeClient:
             return neg
 
     def health_check(self, timeout: float = 5.0) -> bool:
-        call = self._channel.unary_unary(
-            f"/{SERVICE_NAME}/HealthCheck",
-            request_serializer=pb.Empty.SerializeToString,
-            response_deserializer=pb.HealthCheckResponse.FromString,
-        )
         try:
-            healthy = bool(call(pb.Empty(), timeout=timeout).is_healthy)
+            healthy = bool(self._unary(
+                "HealthCheck", pb.Empty(), timeout=timeout,
+                request_serializer=pb.Empty.SerializeToString,
+                response_deserializer=pb.HealthCheckResponse.FromString,
+            ).is_healthy)
             self._note_conn_result(None)
             return healthy
         except grpc.RpcError as e:
@@ -353,19 +394,17 @@ class NodeClient:
             return False
 
     def send_message(self, sender_id: str, text: str, timeout: float = 5.0) -> str:
-        call = self._channel.unary_unary(
-            f"/{SERVICE_NAME}/SendMessage",
-            request_serializer=pb.MessageRequest.SerializeToString,
-            response_deserializer=pb.MessageReply.FromString,
-        )
         # trace tag rides sender_id (the text front's request_id analog)
         with obs.start_span("rpc.SendMessage", parent=obs.current_span(),
                             target=self.address) as sp:
-            return call(
+            return self._unary(
+                "SendMessage",
                 pb.MessageRequest(
                     sender_id=obs.tag_request_id(sender_id, sp),
                     message_text=text),
                 timeout=timeout,
+                request_serializer=pb.MessageRequest.SerializeToString,
+                response_deserializer=pb.MessageReply.FromString,
             ).confirmation_text
 
     def wait_healthy(self, deadline: float = 30.0, interval: float = 0.5) -> bool:
@@ -450,18 +489,14 @@ class NodeClient:
                     # (and the server's direction="in" count)
                     m.inc(labeled("comm.payload_bytes_total",
                                   direction="out"), request.ByteSize())
-                # inside the loop: a channel rebuild between attempts
-                # must take effect on the NEXT attempt, not the next
-                # send_tensor call
-                call = self._channel.unary_unary(
-                    f"/{SERVICE_NAME}/SendTensor",
-                    request_serializer=wc.serialize_request,
-                    response_deserializer=wc.parse_response,
-                )
                 try:
                     _chaos_inject.perturb_rpc("client", self.address)
                     t_send_wall = time.time() if sp else 0.0
-                    resp = call(request, timeout=max(remaining, 0.001))
+                    resp = self._unary(
+                        "SendTensor", request,
+                        timeout=max(remaining, 0.001),
+                        request_serializer=wc.serialize_request,
+                        response_deserializer=wc.parse_response)
                     dt = time.perf_counter() - t_try
                     if sp:
                         # clock-offset sampling for cross-host trace
@@ -614,12 +649,13 @@ class NodeClient:
                 send_ts[seq] = time.perf_counter()
                 yield from _tx.split_requests(req, seq)
 
-        call = self._channel.stream_stream(
-            f"/{SERVICE_NAME}/Relay",
-            request_serializer=wc.serialize_request,
-            response_deserializer=wc.parse_response,
-        )
+        ch = self._lease()
         try:
+            call = ch.chan.stream_stream(
+                f"/{SERVICE_NAME}/Relay",
+                request_serializer=wc.serialize_request,
+                response_deserializer=wc.parse_response,
+            )
             for resp in call(frames(), timeout=timeout):
                 seq = _tx.parse_ack(resp.status)
                 if seq is not None:
@@ -666,6 +702,7 @@ class NodeClient:
             _breaker_done(False)
             raise
         finally:
+            self._release(ch)
             for req in pending.values():
                 neg.sender.cleanup(req)
             pending.clear()
@@ -829,21 +866,29 @@ class NodeClient:
         re-encodes options; a front door must forward the original
         id, dl=/tr=/d= segments and all). Abandoning the iterator
         cancels the RPC, which frees the upstream decode slot."""
-        call = self._channel.unary_stream(
+        ch = self._lease()
+        try:
+            stream = self._generate_stream_call(ch)(
+                wc.TensorRequest(
+                    request_id=request_id,
+                    tensor=_tensor_msg(
+                        np.asarray(arr, np.int32).reshape(-1))),
+                timeout=timeout,
+            )
+            try:
+                yield from stream
+            finally:
+                stream.cancel()  # no-op on a finished stream
+        finally:
+            self._release(ch)
+
+    @staticmethod
+    def _generate_stream_call(ch: _Channel):
+        return ch.chan.unary_stream(
             f"/{SERVICE_NAME}/GenerateStream",
             request_serializer=wc.serialize_request,
             response_deserializer=wc.parse_response,
         )
-        stream = call(
-            wc.TensorRequest(
-                request_id=request_id,
-                tensor=_tensor_msg(np.asarray(arr, np.int32).reshape(-1))),
-            timeout=timeout,
-        )
-        try:
-            yield from stream
-        finally:
-            stream.cancel()  # no-op on a finished stream
 
     def generate_stream(
         self,
@@ -872,29 +917,28 @@ class NodeClient:
         rid = _gen_rid(max_new_tokens, seed, temperature, top_k, top_p,
                        adapter, min_p, repetition_penalty, logit_bias,
                        dedup)
-        call = self._channel.unary_stream(
-            f"/{SERVICE_NAME}/GenerateStream",
-            request_serializer=wc.serialize_request,
-            response_deserializer=wc.parse_response,
-        )
         sp = obs.start_span("rpc.GenerateStream",
                             parent=obs.current_span(),
                             target=self.address)
-        stream = call(
-            wc.TensorRequest(
-                request_id=obs.tag_request_id(rid, sp),
-                tensor=_tensor_msg(
-                    np.asarray(prompt_ids, np.int32).reshape(-1))),
-            timeout=timeout,
-        )
         n = 0
+        ch = self._lease()
         try:
-            for resp in stream:
-                if resp.HasField("result_tensor"):
-                    n += 1
-                    yield int(_tensor_arr(resp.result_tensor)[0])
+            stream = self._generate_stream_call(ch)(
+                wc.TensorRequest(
+                    request_id=obs.tag_request_id(rid, sp),
+                    tensor=_tensor_msg(
+                        np.asarray(prompt_ids, np.int32).reshape(-1))),
+                timeout=timeout,
+            )
+            try:
+                for resp in stream:
+                    if resp.HasField("result_tensor"):
+                        n += 1
+                        yield int(_tensor_arr(resp.result_tensor)[0])
+            finally:
+                stream.cancel()  # no-op on a finished stream
         finally:
-            stream.cancel()  # no-op on a finished stream
+            self._release(ch)
             sp.end(tokens=n)
 
     def generate_text(
@@ -968,4 +1012,4 @@ class NodeClient:
         neg, self._negotiated = self._negotiated, None
         if neg is not None:
             neg.sender.close()
-        self._channel.close()
+        self._chan.chan.close()

@@ -884,9 +884,8 @@ async def serve_stage(engine, node_id: str, *, port: Optional[int] = None,
              node_id, listen, servicer.part_index, servicer.transport)
     await server.start()
     # loop-lag sanitizer (analysis/sanitize.py): env-gated tripwire for
-    # blocking calls the AST pass can't see through an indirection —
-    # the transport/chaos probes run their stage children with it on
-    # and assert the bound from the served /debugz. Installed AFTER
+    # blocking calls the AST pass can't see through an indirection.
+    # Installed AFTER
     # startup so the native-codec warm compile doesn't count.
     from dnn_tpu.analysis import sanitize as _sanitize
 

@@ -1,8 +1,7 @@
 """Pluggable inter-stage transport: device-native hops, shm, gRPC.
 
-PR 5's fleet-stitched trace put the warm 2-stage cifar pipeline at 75.9%
-bubble (STUDIES.md §10): each gRPC hop is a nested unary RPC held open
-for the full downstream latency, and every payload round-trips through
+A gRPC-relayed pipeline is mostly bubble: each hop is a nested unary RPC
+held open for the full downstream latency, and every payload round-trips through
 host serialization copies. This module makes the hop a NEGOTIATED,
 pluggable layer — ROADMAP item 1 — with gRPC demoted to the cross-pod /
 reference-interop fallback:

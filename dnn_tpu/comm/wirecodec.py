@@ -3,9 +3,7 @@
 The reference transport round-trips every activation through THREE host
 copies per direction: `arr.tobytes()` (copy 1), protobuf's internal
 bytes-field store + `SerializeToString` (copy 2), and
-`np.frombuffer(...).copy()` on the receiver (copy 3) — measured as the
-dominant term of the 75.9% warm bubble fraction at cifar scale
-(STUDIES.md §10). Python protobuf cannot take a memoryview for a bytes
+`np.frombuffer(...).copy()` on the receiver (copy 3). Python protobuf cannot take a memoryview for a bytes
 field, so the fix is one layer down: this module hand-assembles and
 hand-parses the proto3 *wire format* of the three Tensor-carrying
 messages (`Tensor`, `TensorRequest`, `TensorResponse` —

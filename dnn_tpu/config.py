@@ -10,8 +10,7 @@ family is accepted.
 Extended keys (all optional, with reference-equivalent defaults):
   model:           model-zoo name (default "cifar_cnn", the reference's only
                    wired family — node.py:11,29-32)
-  device_type:     "tpu" | "cpu" — the platform the engine must run on
-                   (BASELINE.json north-star `device_type=tpu` dispatch);
+  device_type:     "tpu" | "cpu" — the platform the engine must run on;
                    a named platform JAX cannot find is an error. Absent =
                    JAX's default backend
   runtime:         "spmd" (shard_map+ppermute pipeline) | "relay"

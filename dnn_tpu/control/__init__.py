@@ -32,8 +32,7 @@ into a *fleet*:
 
 CLI: `python -m dnn_tpu.control` spawns a whole fleet (router + N
 supervised replicas); `node --route` runs the router alone against
-explicit targets. Measured contract:
-`benchmarks/fleet_serving_probe.py` (the run_all `fleet_serving` row).
+explicit targets. Not measured on the chip (PERF.md section 7).
 """
 
 from dnn_tpu.control.policy import (  # noqa: F401

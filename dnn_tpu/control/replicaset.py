@@ -13,7 +13,7 @@ The table is DECLARED in `analysis/protocol.REPLICA` and model-checked
 both directions by the CI gate, exactly like breaker/drain/supervisor
 — edit the two together. Transitions land in the flight ring
 (`replica_*` events), so a fleet incident reconstructs from /debugz
-the way a chaos incident does (STUDIES §13/§17).
+the way a chaos incident does.
 
 `ReplicaSet` owns the handles plus the monitor thread that drives the
 machines off fresh health probes, and (when the replicas expose obs
@@ -449,8 +449,8 @@ def lm_replica_argv(node_id: str, config_path: str, *,
                     seed: int = 0, kv: str = "auto",
                     extra_args: Optional[List[str]] = None) -> List[str]:
     """The replica child's command line — one place, so the CLI
-    (`python -m dnn_tpu.control`), the fleet probe, and tests spawn
-    byte-identical children."""
+    (`python -m dnn_tpu.control`) and tests spawn byte-identical
+    children."""
     argv = [sys.executable, "-m", "dnn_tpu.node",
             "--node_id", node_id, "--config", config_path,
             "--serve_lm", "--role", role,

@@ -10,12 +10,11 @@ router unchanged and gets a FLEET. Per request the router:
      replica views — when every candidate is saturated (the router's
      exact per-replica in-flight bound) or burning error budget past
      the configured rate, the request is shed with UNAVAILABLE, the
-     status the whole client ladder (retry, breaker, chaos probe
-     accounting) already treats as explicitly-rejected-retriable.
+     status the whole client ladder (retry, breaker) already treats
+     as explicitly-rejected-retriable.
      Shedding is what keeps an overloaded fleet's queues short enough
      that admitted work finishes inside its deadline instead of
-     degenerating into admit-then-deadline-cancel waste (STUDIES §17
-     measures exactly that collapse on the unfronted baseline).
+     degenerating into admit-then-deadline-cancel waste.
   2. PICKS a replica via the pluggable policy (`round_robin |
      least_queue | slo_burn`), honoring dedup-key session affinity:
      a `d=`/`h=` tagged request re-routes to the replica that saw the
@@ -151,8 +150,7 @@ class Router:
         #     pick by policy and INSTRUCT A PULL from the holder —
         #     affinity stops being a cache-correctness constraint;
         #   "pull" — never prefer the holder (the policy alone places),
-        #     always instruct pulls — the migration-stress mode the
-        #     kv_tier probe measures cross-replica hits under;
+        #     always instruct pulls — the migration-stress mode;
         #   "off"  — PR 12 behavior (dedup-key affinity only).
         if kvtier not in ("auto", "pull", "off"):
             raise ValueError(
@@ -1023,7 +1021,7 @@ async def serve_router(replicaset: ReplicaSet, *, port: int,
 
 def start_router_in_background(replicaset: ReplicaSet, *, port: int,
                                **router_kwargs):
-    """Test/probe helper: router on a daemon thread; returns
+    """Test helper: router on a daemon thread; returns
     (router, stop_callback) — mirrors start_lm_server_in_background."""
     loop = asyncio.new_event_loop()
     started = threading.Event()

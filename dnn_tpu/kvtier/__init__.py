@@ -23,8 +23,7 @@ Four connected pieces:
 
 The serving integration lives in runtime/serving.py (admission +
 stage/export/adopt) and runtime/lm_server.py (the kvstage/kvlease/
-kvfetch/kvack/kvpull endpoints). `benchmarks/kv_tier_probe.py` is the
-asserted contract.
+kvfetch/kvack/kvpull endpoints); tests/test_kvtier.py holds the contract.
 """
 
 from dnn_tpu.kvtier.radix import RadixIndex, RadixNode  # noqa: F401

@@ -53,8 +53,7 @@ class RadixNode:
     them — what lets an exactly-block-aligned full-prompt hit sample
     its first token without running a single chunk), and `origin`
     ("local" = prefilled here, "adopted" = migrated in from a sibling
-    replica — the cross-replica hit accounting the kv_tier probe
-    asserts reads this)."""
+    replica — the cross-replica hit accounting reads this)."""
 
     __slots__ = ("chunk", "block", "children", "parent", "logit_row",
                  "origin", "lru", "obskey")

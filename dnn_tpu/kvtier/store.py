@@ -47,8 +47,7 @@ class PrefixHit:
     Lookup itself counts NOTHING: the admission may truncate the run,
     hold the request back, or fail — the caller reports what it
     actually reused via `note_reuse` (the counters behind the
-    cross-replica ratio the kv_tier probe floors must never exceed
-    blocks genuinely served)."""
+    cross-replica ratio must never exceed blocks genuinely served)."""
 
     shared: List[int]
     origins: List[str]
@@ -85,8 +84,8 @@ class PrefixStore:
         self.evictions = 0
         # optional memory-economy observer (obs/kvlens.py), attached by
         # the serving layer when the obs gate is on. Every hook below is
-        # one `is not None` test when absent — the <2% contract's cost
-        # when observability is off.
+        # one `is not None` test when absent: all that observability
+        # costs here when it is off.
         self.lens = None
 
     # -- scrape-side ---------------------------------------------------
@@ -126,7 +125,7 @@ class PrefixStore:
                    cow: bool = False):
         """Admission succeeded reusing `n_blocks` resident blocks, of
         which `n_remote` were adopted from a sibling — the counters
-        the gauges and the kv_tier probe read. `cow` marks that the
+        the gauges read. `cow` marks that the
         reuse included the boundary copy-on-write block (lifecycle
         forensics; the counters are unchanged by it)."""
         self.block_hits += int(n_blocks)

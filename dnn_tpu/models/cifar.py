@@ -82,9 +82,9 @@ def init(rng, dtype=jnp.float32):
 
 def _seg_conv1(params, x, compute_dtype=None):
     # Input channels padded 3 -> 8 before the conv: XLA's TPU conv emitter
-    # handles the degenerate cin=3 contraction poorly — the zero-pad
-    # measures ~2x forward throughput on a v5e (19.7% -> 39.1% MFU at
-    # B=1024, benchmarks/cifar_mfu_probe.py). Zero kernel rows contribute
+    # handles the degenerate cin=3 contraction poorly (no cell of the
+    # chip benchmark runs this model: not measured this round). Zero
+    # kernel rows contribute
     # exact zeros to the accumulation, so outputs are bit-identical in
     # every dtype; params keep the reference's (3, 32) kernel shape
     # (cifar_model_parts.py:9) so checkpoints are unaffected.

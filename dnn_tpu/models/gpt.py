@@ -218,16 +218,16 @@ def head(params, x, *, cfg: GPTConfig, compute_dtype=None, logits_dtype=None):
     accumulates f32 (`preferred_element_type`) — logits stay f32. This is
     the dominant-cost matmul of a forward (C x V = 768 x 50257 for
     gpt2-small). On v5e the default f32 matmul "precision" is a bf16 MXU
-    pass already, so output is bit-identical (measured: zero logit diff)
-    and throughput is within noise; the explicit operand dtype matters on
-    platforms where f32 matmul really runs f32, and makes the memory
-    traffic intent visible rather than relying on a backend default.
+    pass already, so the output is the same there; the explicit operand
+    dtype matters on platforms where f32 matmul really runs f32, and makes
+    the memory traffic intent visible rather than relying on a backend
+    default.
 
     `logits_dtype=bf16` rounds the f32-accumulated logits on the way out
     (XLA fuses the cast into the matmul epilogue): the (B, T, V) logit
     write is the single largest HBM store of a forward — 823 MB at
-    B=8/T=512/V=50257 in f32 — and halving it measures +11% end-to-end
-    throughput on v5e (benchmarks/explore_fwd_perf.py). Accumulation is
+    B=8/T=512/V=50257 in f32 (PERF.md section 5 has its share of the
+    pipeline cell's busy time). Accumulation is
     still f32; only the stored values are rounded. Default None keeps f32
     logits (the parity-test configuration)."""
     with jax.named_scope("gpt.head"):

@@ -12,7 +12,7 @@ Behavior by mode:
     if `--input_image` is given the client path runs end to end and prints
     `FINAL PREDICTION (Index): N` exactly like node.py:192. The reference
     needed N machines + N terminals for this; here one process does it
-    with zero gRPC hops (BASELINE.json north star).
+    with zero gRPC hops.
 
   * `--serve` (distributed edge mode): behave like one reference node —
     host this node's stage behind the gRPC NodeService and relay to

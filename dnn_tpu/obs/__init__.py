@@ -60,19 +60,17 @@ arc (ROADMAP item 4):
     series on /stepz (+ a Perfetto host-track export), capture
     analysis over the profiler's spooled artifacts (device busy/idle,
     host-gap histogram, top ops) aligned to the step axis through
-    profile.py's sidecar meta, and an asserted phase-accounting
-    baseline (benchmarks/step_timeline_probe.py) whose measured
-    host-serialization fraction is the item-4 ratchet (BASELINE.md).
+    profile.py's sidecar meta. The chip benchmark reads the same
+    counters and spans (chipbench/spans.py; PERF.md section 3).
 
-v5 adds the JUDGMENT layer — the workload suite's verdict machinery
-(ISSUE 14):
+v5 adds the JUDGMENT layer:
 
-  * SLO verdicts + incident bundles (obs/slo.py): a scenario's
-    per-request records judged against its declared SLOSpec into one
-    ok/breach report, and — on breach — an on-disk incident bundle
-    (flight ring over the breach window, /stepz, /fleetz) that
+  * SLO verdicts + incident bundles (obs/slo.py): a run's per-request
+    records judged against a declared SLOSpec into one ok/breach
+    report, and — on breach — an on-disk incident bundle (flight ring
+    over the breach window, /stepz, /fleetz) that
     `python -m dnn_tpu.obs incident PATH` renders back as the
-    event-by-event post-mortem (dnn_tpu/workloads drives it).
+    event-by-event post-mortem (trainlens writes one on divergence).
 
 v6 adds the MEMORY-ECONOMY layer — the sizing instrument for the KV
 capacity hierarchy (ROADMAP item 4) and the autoscaler's
@@ -87,9 +85,7 @@ capacity-vs-compute question (item 3):
     lifecycle ledger (birth/share/COW/evict/migrate/refetch with
     cause attribution), and a thrash detector pricing
     evict→refetch-within-window churn in re-prefill chunk-seconds and
-    migrated bytes; benchmarks/kv_economy_probe.py asserts the curve
-    against ground truth (|predicted − measured| ≤ 0.10 at an
-    untested pool size).
+    migrated bytes.
 
 v7 adds the TRAINING layer — the observatory for the one ROADMAP
 pillar that had none (built before the training-at-scale PR it
@@ -107,17 +103,13 @@ judges, the instrument-first pattern):
     stats leg (grad_spike / loss_nan / train_stall flight events, an
     incident bundle on divergence) and checkpoint observability
     (save/restore histograms, dnn_tpu_ckpt_last_good_step /
-    staleness gauges, ckpt_saved/ckpt_restored events);
-    benchmarks/train_goodput_probe.py asserts phase coverage, the
-    MFU floor, stall attribution, sentinel latency, and the <2%
-    overhead budget.
+    staleness gauges, ckpt_saved/ckpt_restored events).
 
 Gate: DNN_TPU_OBS=off (or 0/false) disables everything — producers see
 `metrics()` return None, `start_span` return the free NULL_SPAN, and
 `flight.record` short-circuit on one boolean. The gate is re-checked
-per call, so benchmarks can flip it at runtime (`set_enabled`) to
-measure the instrumentation tax (benchmarks/obs_overhead_probe.py pins
-it < 2% of a decode step, flight + watchdog included).
+per call, so a run can flip it (`set_enabled`) to measure the
+instrumentation tax.
 
 Import cost: this package imports stdlib + utils.metrics only; jax is
 touched lazily inside install_compile_telemetry() and obs/profile.

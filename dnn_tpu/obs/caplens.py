@@ -48,19 +48,13 @@ object:
      bounded audit trail and as a `caplens_decision` flight event.
      Served on `/capz` (JSON | `?format=prom`), as `/fleetz` rollup
      columns, and via `python -m dnn_tpu.obs caplens
-     [--url|PATH|--selftest]`. `benchmarks/capacity_plan_probe.py`
-     closes the loop the kvlens way: observe a 1-replica fleet under
-     a PR 13 arrival trace, predict the 2-replica fleet, then measure
-     the real 2-replica fleet on the identical trace and assert the
-     prediction-error ceiling.
+     [--url|PATH|--selftest]`.
 
 Overhead contract: every producer opens with the obs gate check and
 the router/replicaset hook sites guard with one `lens is not None`
 test; producers append to bounded deques and bump counters — all
 derivation (windowing, quantiles, planning) is scrape-side, and
-planning is additionally throttled by `replan_interval_s`. The
-`obs_overhead_probe --caplens` leg holds the admission path under
-the repo-wide <2% tax with the lens live.
+planning is additionally throttled by `replan_interval_s`.
 
 Threading: producers run on the router's event loop and the
 replicaset monitor thread; scrape-side readers copy bounded deques
@@ -331,8 +325,7 @@ class CapLens:
         Coverage = sum(buckets) / (t_first - t_spawn). What the sum
         honestly misses: fork->exec lag, the child's serve-bind span
         (grpc server construction), and the caller's poll gap before
-        the first request — the capacity_plan_probe asserts these
-        stay under 5% of the wall."""
+        the first request."""
         done = []
         for name, ent in list(self._pending.items()):
             t_first = ent.get("t_first")

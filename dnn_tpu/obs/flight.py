@@ -6,8 +6,7 @@ stdout logs scroll away. This module is the black box: every notable
 serving event (admissions, held-back requests, evictions, RPC retries,
 deadline misses, compile events, worker errors, watchdog firings) lands
 in one process-wide bounded ring, cheap enough to feed from hot paths
-(one gate check + one lock + one deque append; the obs overhead probe
-covers it), and dumpable three ways:
+(one gate check + one lock + one deque append), and dumpable three ways:
 
   * on demand: `GET /debugz` on the obs HTTP endpoint (obs/http.py), or
     `python -m dnn_tpu.obs flight --url http://host:port`;

@@ -3,9 +3,8 @@
 The ROADMAP's "fast as the hardware allows" is unverifiable from raw
 tokens/sec — the number that proves it is UTILIZATION: what fraction of
 the chip's peak FLOPs (MFU) and peak HBM bytes (MBU) the serving stack
-actually achieves, live, while real traffic flows. Benchmarks compute
-these offline (bench.py, utils/flops.py); this module computes them
-continuously from the decode/prefill step stream the batcher already
+actually achieves, live, while real traffic flows. This module computes
+them continuously from the decode/prefill step stream the batcher already
 produces, and exports them as scrape-time gauges:
 
     dnn_tpu_mfu                     achieved FLOPs/s over the window /
@@ -28,10 +27,9 @@ the published MFU bookkeeping (PaLM appendix) — flash kernels that skip
 masked tiles simply bank the savings as higher measured throughput.
 
 Peaks: on TPU the per-generation table in utils/flops.py supplies them;
-elsewhere they're unknown and the gauges read 0 unless the operator
-states a roofline via DNN_TPU_PEAK_FLOPS / DNN_TPU_PEAK_HBM_BW (or the
-explicit constructor args) — a stated peak beats no number, and tests
-pin the arithmetic with explicit peaks.
+elsewhere they're unknown and the gauges read 0 (a CPU run reports no
+utilization under a device's name); tests pin the arithmetic with the
+explicit constructor args.
 
 SLO tracking: configure objectives (TTFT, inter-token latency,
 availability) and the tracker turns the same event stream into
@@ -241,8 +239,8 @@ class GoodputTracker:
         self._tokens = Throughput(window_s, now=now)
         # decode-step accumulator, flushed into the windows every
         # _FLUSH_STEPS steps by the ONE producer thread: three
-        # locked deque updates per sub-ms step were measurable against
-        # the serving obs budget, and a 60 s rate window cannot resolve
+        # locked deque updates per step are host work inside the decode
+        # loop, and a 60 s rate window cannot resolve
         # a <100 ms batching delay anyway. Scrapes read the windows
         # as-is (≤ _FLUSH_STEPS-steps stale, idle decay unaffected);
         # only the producer touches the _acc_* fields, so there is no

@@ -25,9 +25,6 @@ oracle, three instruments in one object:
      scrape-time gauges (`prom_gauges()`), as `/kvz` on the obs HTTP
      server (JSON | `?format=prom`), as `/fleetz` rollup columns, and
      via `python -m dnn_tpu.obs kvlens [--url|PATH|--selftest]`.
-     `benchmarks/kv_economy_probe.py` proves the instrument against
-     ground truth: the curve's prediction for an untested pool size
-     must land within 0.10 absolute of the ratio measured there.
 
   3. **Block-lifetime forensics + thrash detector.** A bounded
      per-block lifecycle ledger (its own FlightRecorder ring, so the
@@ -41,9 +38,7 @@ oracle, three instruments in one object:
 
 Overhead contract: every producer method opens with the obs gate
 check (one boolean when DNN_TPU_OBS is off) and the hook sites in
-kvtier/store.py guard with one `lens is not None` test — the
-`obs_overhead_probe --kvlens` leg holds the admission path under the
-repo-wide <2% tax with the tracker live.
+kvtier/store.py guard with one `lens is not None` test.
 
 Threading: producer methods run on the pool's single worker thread
 (the PrefixStore contract); scrape-side readers (`curve`, `summary`,

@@ -205,8 +205,8 @@ def capture_step(fn, *, capture_root: Optional[str] = None,
     past the call. Returns (capture_dir, fn's result).
 
     NOTE the capture wall time is dominated by profiler init + trace
-    EXPORT (stop_trace writes the json.gz + xplane.pb — measured ~10 s
-    for a first capture on this host), during which the calling thread
+    EXPORT (stop_trace writes the json.gz + xplane.pb: seconds for a
+    first capture), during which the calling thread
     (the batcher worker, for the auto trigger) is stalled: requests
     queue behind an auto capture. That is the accepted cost of an
     operator-armed post-mortem, not a steady-state tax.
@@ -265,14 +265,14 @@ def annotation_ctx(name: str, **stats):
     an obs-driven capture recording) or a shared nullcontext — a plain
     call + two checks, no generator. `stats` (ints, floats, strings)
     become the event's stats in the capture: `step=` on the batcher's
-    step phases, `rid=` on an admission's parts. Two measured costs
-    forced this shape: the @contextmanager `annotation` below costs
-    ~30 µs around a jit dispatch (generator machinery + per-call
-    imports), and even a bare TraceAnnotation costs ~6 µs there — both
-    real money against a ms-scale decode step, paid EVERY step for
-    annotations nobody is recording. Gating on `capturing()` (set by
-    _traced during POST /profilez and the auto-trigger) makes the steady
-    state ~0.3 µs; a capture driven outside obs.profile (bare
+    step phases, `rid=` on an admission's parts. Two host costs
+    forced this shape: the @contextmanager `annotation` below pays
+    generator machinery and per-call imports around a jit dispatch, and
+    even a bare TraceAnnotation is not free there — both paid EVERY
+    step of a ms-scale decode loop for annotations nobody is recording.
+    Gating on `capturing()` (set by _traced during POST /profilez and
+    the auto-trigger) leaves the steady state two attribute checks; a
+    capture driven outside obs.profile (bare
     jax.profiler.start_trace) won't see these annotations unless it
     wraps its body in `mark_recording` (utils/tracing.trace_to does) —
     prefer obs.profile.capture. The

@@ -1,15 +1,14 @@
 """SLO verdict engine + automatic breach forensics.
 
 obs/goodput.py tracks LIVE burn rates (the paging signal); this module
-is the after-the-fact judge: a scenario (dnn_tpu/workloads) hands it
-the per-request records it collected plus the scenario's declared SLO,
-and gets back a per-objective report with one ok/breach VERDICT — the
-per-scenario goodput-under-SLO accounting the Gemma-on-TPU serving
-comparison (PAPERS.md 2605.25645) reports, as an asserted artifact
-instead of a table in a paper.
+is the after-the-fact judge: a caller hands it the per-request records
+of a run plus the run's declared SLO, and gets back a per-objective
+report with one ok/breach verdict — the goodput-under-SLO accounting
+the Gemma-on-TPU serving comparison (PAPERS.md 2605.25645) reports, as
+an asserted artifact instead of a table in a paper.
 
-Record schema (one dict per request; the workloads runner produces
-these, but anything shaped like this evaluates):
+Record schema (one dict per request; anything shaped like this
+evaluates):
 
     {"i": int, "t": sched offset s, "outcome": "ok"|"rejected"|None,
      "tokens": int, "ttft_s": float|None, "itl_s": [float, ...],
@@ -48,12 +47,12 @@ from dnn_tpu.utils.metrics import percentile as _percentile  # noqa: E402
 
 @dataclasses.dataclass(frozen=True)
 class SLOSpec:
-    """A scenario's declared objectives. Latency objectives are
+    """A run's declared objectives. Latency objectives are
     (percentile, threshold) pairs — `ttft_p=95, ttft_s=0.5` reads "the
     95th-percentile time-to-first-token stays under 500 ms".
     `availability` is the COMPLETED fraction of submitted requests —
-    stricter than the chaos probe's completed-or-rejected accounting,
-    because a scenario declares the demand it expects SERVED: a shed
+    stricter than a completed-or-rejected accounting, because the
+    spec declares the demand it expects SERVED: a shed
     request is a served-SLO failure even when it is a correct admission
     decision. Silently-lost requests additionally fail the always-on
     `lost` objective, which tolerates ZERO. `goodput_floor_tps` is the
@@ -172,8 +171,7 @@ def evaluate(scenario: str, records: List[dict], spec: SLOSpec, *,
             avail >= spec.availability and not lost,
             bad_records=rejected + lost)
     # silent loss is unconditionally asserted — a record without an
-    # outcome is the failure mode every probe in this repo exists to
-    # make impossible
+    # outcome is the one failure no objective may forgive
     obj("lost", len(lost), 0, not lost, bad_records=lost)
     if spec.goodput_floor_tps is not None:
         obj("goodput_tps", goodput, spec.goodput_floor_tps,

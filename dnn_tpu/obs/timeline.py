@@ -36,8 +36,8 @@ This is the intra-step instrument, in two connected halves:
                           step program is in flight)
         host_s          = admit + host + commit + obs  (host work NOT
                           overlapped with the device program)
-        host_fraction   = host_s / wall     — THE ratchet number: the
-                          host-serialization share of step wall time.
+        host_fraction   = host_s / wall     — the host-serialization
+                          share of step wall time.
                           Chunked-prefill interleave removes the admit
                           convoy; double-buffered dispatch hides
                           host/commit/obs under device steps.
@@ -79,11 +79,9 @@ This is the intra-step instrument, in two connected halves:
     top-K ops by device time.
 
 Served via GET /stepz (JSON; ?format=prom) on the obs endpoint
-and `python -m dnn_tpu.obs timeline [--url URL | PATH]`. The asserted
-baseline lives in benchmarks/step_timeline_probe.py: phase accounting
-must cover >= 95% of externally measured wall time (no unattributed
-dark time), and the measured host-serialization fraction is committed
-to BASELINE.md as the floor item 4 must ratchet DOWN.
+and `python -m dnn_tpu.obs timeline [--url URL | PATH]`. The phases
+partition the step (no unattributed dark time); the chip benchmark
+reads the same totals and spans (chipbench/spans.py; PERF.md section 3).
 
 No jax import anywhere in this module — the clock is pure
 perf_counter bookkeeping and analyze() is stdlib-only, so the CLI
@@ -167,7 +165,7 @@ class _StepSpans:
         _profile.close_span(self.step)
 
 #: shared empty admit-slice seq — most steps have no admissions, and
-#: the per-step allocation was measurable against the <2% obs budget;
+#: a per-step allocation is host work paid inside every decode step;
 #: end() REPLACES the attribute (never appends) when slices exist, and
 #: every consumer (fold/summary/stepz) only iterates, so sharing is safe
 _NO_ADMITS: tuple = ()
@@ -262,8 +260,8 @@ class StepClock:
     Registry cost: per-step observations are accumulated locally and
     FLUSHED in one bulk update every `FLUSH_EVERY` steps (summary()/
     render_prom() flush first, so scrapes stay fresh) — per-step
-    histogram observes measurably taxed the sub-ms decode step this
-    clock exists to measure (the obs_overhead <2% contract prices it).
+    histogram observes tax the very decode step this clock exists to
+    measure (a lock and a reservoir update per series per step).
     The derived gauges are scrape-time callables over the ring, so
     they are exact at every scrape regardless of the flush cadence.
     """
@@ -462,8 +460,8 @@ class StepClock:
         """Stamp and publish one step. Deliberately MINIMAL — one
         perf_counter read, the cumulative totals (a handful of adds)
         and ONE GIL-atomic append, no lock: this runs inside the decode
-        loop the clock exists to measure, and the obs_overhead <2%
-        contract prices every microsecond here.
+        loop the clock exists to measure, so every microsecond here
+        is host time added to the step.
         Single-producer by the batcher's threading contract. The rec
         lands only in the pending batch here; flush() moves the batch
         into the scrape ring (and runs the ring's evictions) every
@@ -643,8 +641,8 @@ class StepClock:
         return None if t is None else max(0.0, self._now() - t)
 
     def records(self, last: Optional[int] = None) -> List[dict]:
-        """Ring records as plain dicts (newest last) — what the probe's
-        coverage assertion reads."""
+        """Ring records as plain dicts (newest last) — what a coverage
+        assertion reads."""
         self._land()
         with self._lock:
             recs = list(self._ring)

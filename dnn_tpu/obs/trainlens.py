@@ -11,7 +11,7 @@ in three connected pieces on the existing obs substrate:
 
   * **TrainClock** — the training loop's phase clock, in the StepClock
     idiom (single producer, one-None-check gate, 32-step batched
-    registry flush honoring the <2% obs contract). `train.fit` splits
+    registry flush). `train.fit` splits
     every iteration into named contiguous phases:
 
         data      next(batch_iter): host input pipeline (+ any chaos
@@ -29,8 +29,8 @@ in three connected pieces on the existing obs substrate:
     peak — priced by the utils/flops.py training helpers
     (gpt_train_step_flops / llama_train_step_flops, 3× forward,
     microbatch/remat-aware) against the same `device_peak_flops`
-    roofline the serving goodput gauges use (DNN_TPU_PEAK_FLOPS is the
-    CPU-host opt-in). Exported as weak scrape-time gauges
+    roofline the serving goodput gauges use (off a TPU the peak is
+    unknown and no MFU is reported). Exported as weak scrape-time gauges
     (`dnn_tpu_train_mfu`, `dnn_tpu_train_tokens_per_sec`,
     `dnn_tpu_train_data_stall`, ...), a `/trainz` endpoint
     (JSON|prom|trace) next to /stepz, a Perfetto host-track export,
@@ -56,13 +56,6 @@ in three connected pieces on the existing obs substrate:
     would lose RIGHT NOW), and `ckpt_saved`/`ckpt_restored` flight
     events, so a restore-latest-good incident reconstructs from
     /debugz.
-
-The asserted baseline lives in benchmarks/train_goodput_probe.py:
-phase coverage ≥95% of external wall, an MFU floor on the pinned
-roofline, injected-sleep → data_stall attribution, injected-NaN →
-sentinel within 2 steps, and a trainlens-live obs-overhead leg <2%
-(BASELINE.md ratchets train_mfu_floor / train_phase_coverage /
-trainlens_overhead_budget).
 
 No jax import anywhere in this module — the clock is pure perf_counter
 bookkeeping (the obs/__main__.py contract); peak-FLOPs resolution
@@ -157,10 +150,10 @@ class TrainClock:
     pinned shape (utils.flops.gpt_train_step_flops / llama_...);
     `tokens_per_step` the tokens one optimizer step consumes (end()'s
     default). `peak_flops` pins the MFU roofline explicitly; left None
-    it resolves lazily from utils.flops.device_peak_flops (TPU table /
-    DNN_TPU_PEAK_FLOPS env) the first time a scrape asks — never at
-    construction, and never fatally (a CPU host without the env opt-in
-    simply reports no MFU rather than a made-up one).
+    it resolves lazily from utils.flops.device_peak_flops (the TPU
+    table) the first time a scrape asks — never at construction, and
+    never fatally (a CPU host simply reports no MFU rather than a
+    made-up one).
 
     Threading/registry discipline is StepClock's verbatim: end() is one
     perf_counter read + a GIL-atomic append; `_land()` (ring-only) is
@@ -229,8 +222,8 @@ class TrainClock:
 
     def peak_flops(self) -> Optional[float]:
         """The MFU denominator, resolved lazily (goodput-style): an
-        explicit constructor value wins; else the utils.flops table /
-        DNN_TPU_PEAK_FLOPS env the first time asked. Never raises — an
+        explicit constructor value wins; else the utils.flops table
+        the first time asked. Never raises — an
         unresolvable roofline means "no MFU", not a crash."""
         if not self._peak_resolved:
             self._peak_resolved = True
@@ -432,8 +425,8 @@ class TrainClock:
         return None if t is None else max(0.0, self._now() - t)
 
     def records(self, last: Optional[int] = None) -> List[dict]:
-        """Ring records as plain dicts (newest last) — what the probe's
-        coverage assertion reads."""
+        """Ring records as plain dicts (newest last) — what a coverage
+        assertion reads."""
         self._land()
         with self._lock:
             recs = list(self._ring)
@@ -614,8 +607,8 @@ class GradSentinel:
     3-vector the `grad_stats=True` steps return ([global grad-norm,
     update/param-norm ratio, nonfinite grad count], already on host),
     or None when the step runs without the leg (the loss-only checks
-    still fire). Returns the list of event kinds fired this call (what
-    the probe asserts on); every firing is a bounded flight event:
+    still fire). Returns the list of event kinds fired this call; every
+    firing is a bounded flight event:
 
       loss_nan     nonfinite loss OR any nonfinite gradient — latched
                    per episode (one event per divergence, not one per
@@ -674,8 +667,8 @@ class GradSentinel:
         nonfinite = 0
         if stats is not None:
             # ONE host transfer for the 3-vector: iterating a device
-            # array element-wise costs three dispatched index reads —
-            # measurable against the <2% per-step obs budget
+            # array element-wise costs three dispatched index reads
+            # every step
             vals = stats.tolist() if hasattr(stats, "tolist") \
                 else [float(v) for v in stats]
             grad_norm, ratio = vals[0], vals[1]

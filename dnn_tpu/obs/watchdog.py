@@ -14,8 +14,7 @@ detector:
         in-process (`in_process_device_probe`): a child could not open
         the chip its parent holds, and would read a healthy server as
         degraded or wedged;
-      - a process that holds NO device (bench.py before it measures, the
-        supervisor's `recover_backend`) probes in a SUBPROCESS with a
+      - a process that holds NO device probes in a SUBPROCESS with a
         hard deadline (`subprocess_device_probe`) — a wedged chip hangs
         the child, never the caller;
   * a DECODE HEARTBEAT: the LM batcher worker calls `beat()` every loop

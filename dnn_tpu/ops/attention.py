@@ -33,10 +33,10 @@ def merge_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b, t, h * d)
 
 
-# Measured on the v5e chip (bf16, gpt2-small shapes): XLA's fused attention
-# beats the Pallas kernel up to S=2048 (ratios 0.66-0.73), flash wins from
-# S=4096 (1.42x) where XLA's materialized (B,H,T,T) scores start thrashing
-# HBM. "auto" switches on the flash kernel at this crossover.
+# "auto" switches on the Pallas flash kernel from this sequence length:
+# XLA's path materializes (B,H,T,T) scores, which grow as T^2, and the
+# flash kernel never holds them. A heuristic: no cell of the chip
+# benchmark runs a forward this long, so the threshold is not measured.
 FLASH_AUTO_THRESHOLD = 4096
 
 
@@ -46,7 +46,7 @@ def causal_self_attention(params, x, *, n_head, use_flash=False, compute_dtype=N
     `use_flash`: True routes the inner attention through the Pallas TPU
     kernel (falls back to the jnp path off-TPU or for tiny shapes); False
     uses the XLA einsum path; "auto" picks flash when the sequence length
-    reaches FLASH_AUTO_THRESHOLD (the measured crossover — see above).
+    reaches FLASH_AUTO_THRESHOLD (a heuristic — see above).
     `compute_dtype` (e.g. bf16) casts the matmul operands for the MXU.
     """
     qkv = linear(params["qkv"], x, compute_dtype=compute_dtype)  # (B, T, 3C)

@@ -245,8 +245,7 @@ def cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
 #
 # Why the general kernel above fails at decode: with block_q=1 its grid is
 # (B*H, 1, S/128) — thousands of programs each DMAing a 128-row cache tile
-# (~32 KB), a latency-bound pipeline that measured 23x SLOWER than the XLA
-# einsum at S=4096 (benchmarks/attn_kernel_probe.py). Decode attention is
+# (~32 KB), a latency-bound pipeline. Decode attention is
 # pure bandwidth: the right shape is FEW programs streaming BIG blocks.
 # This kernel folds all heads into one program — grid (B, S/block_s),
 # each step DMAing an (Hk, block_s, D) K and V slab (hundreds of KB) —
@@ -255,18 +254,14 @@ def cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
 # steps map to the same block): per-step traffic scales with the ACTIVE
 # context, not the allocation.
 #
-# MEASURED VERDICT (v5e, benchmarks/attn_kernel_probe.py, B=8 H=12 D=64):
-# this shape wins at moderate context (1.8x at S=256, 1.2x at S=1024 bf16)
-# but XLA's einsum decode attention is already near-bandwidth-optimal on
-# this chip — 600-700 GB/s at S=16384 INCLUDING the fused int8 dequant
-# (int8 runs 1.7x faster than bf16 einsum, i.e. the byte reduction is
-# fully realized with no materialized float cache) — while this kernel
-# tops out ~200 GB/s: with D=64 the cache block's minor dim fills only
-# half of the 128 VMEM lanes, so every DMA moves half-empty tiles.
-# Consequence: `attn_kernel` stays OFF by default; the einsum is the
-# decode hot path, and this kernel is (a) the runtime-position chunked
-# prefill program (which flash_attention.py cannot express) and (b) the
-# 1-byte-read guarantee should a future XLA stop fusing the int8 upcast.
+# On a DENSE cache with D=64 the block's minor dim fills only half of the
+# 128 VMEM lanes, so every DMA moves half-empty tiles, and XLA's einsum
+# already fuses the int8 dequant into its read: a dense cache takes this
+# kernel only under `attn_kernel="auto"`'s length rule
+# (kvcache.AUTO_KERNEL_MIN_S) or when asked, and is otherwise served by
+# the einsum. This kernel is also the runtime-position chunked prefill
+# program (which flash_attention.py cannot express). The paged pool's
+# kernel below is the one the chip benchmark measures (PERF.md section 5).
 #
 # The query is (B, Hk, R, D): R rows per KV head, ALL sharing their
 # slot's limit pos[b]. R=1 is plain MHA decode; R=G covers GQA's folded

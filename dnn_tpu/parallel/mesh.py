@@ -4,7 +4,7 @@ The reference's topology is a list of (node, address, part_index) entries
 (config.json:3-14) with next-hop resolution by part_index+1
 (node.py:262-271). The TPU-native equivalent: `part_index` becomes a
 coordinate on the "stage" axis of a `jax.sharding.Mesh`, and the "hop" is
-`lax.ppermute` over ICI instead of a gRPC call (BASELINE.json north star).
+`lax.ppermute` over ICI instead of a gRPC call.
 
 Axis conventions used across the framework:
   "data"   — data parallelism (batch sharding, gradient psum)

@@ -117,8 +117,8 @@ class RelayExecutor:
                 # (POST /profilez, obs/profile.py) names each relay stage
                 # on the host track. annotation_ctx, not the generator
                 # `annotation` form — this runs once per hop per decode
-                # step, where the generator shape costs ~30 µs/call even
-                # with nothing recording (STUDIES.md §9)
+                # step, and the generator shape pays a frame per call
+                # even with nothing recording
                 with _prof_annotation(f"relay.stage{i}"):
                     x = fn(params, jax.device_put(x, dev))
             self.last_stage_times = None
@@ -814,8 +814,8 @@ def spmd_pipeline_interleaved(
 def stacked_param_placement(stacked_params, *, axis_name: str = STAGE_AXIS):
     """The declared placement contract of the stacked pipeline: every
     leaf of the (S, ...)-stacked param tree shards its leading stage
-    axis — each device holds exactly its own stage's 1/S slice (the
-    HBM-resident per-stage weights of BASELINE.json's north star).
+    axis — each device holds exactly its own stage's 1/S slice,
+    resident in its HBM.
     Registered as the `pipeline.stacked_param_placement` sharding
     contract: the analysis gate lowers spmd_pipeline_stacked and fails
     if any leaf's compiled placement drifts from this declaration."""
@@ -840,8 +840,8 @@ def spmd_pipeline_stacked(
     """Homogeneous-stage SPMD pipeline over stacked params.
 
     `stacked_params` has a leading stage axis (S, ...) that lives sharded
-    P('stage', ...) — each device holds only its own stage's slice (the
-    HBM-resident per-stage weights of BASELINE.json's north star). No
+    P('stage', ...) — each device holds only its own stage's slice,
+    resident in its HBM. No
     switch, no padding: this is the fast path for transformer block stacks.
     `block_fn(params_slice, x) -> y` must map (mb, ...) -> (mb, ...) with an
     unchanged shape.

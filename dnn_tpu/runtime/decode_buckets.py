@@ -2,8 +2,7 @@
 
 Decode is bandwidth-bound, and the XLA decode step reads the whole
 preallocated cache every step — at `max_len` allocation with a short live
-context, bytes/step are proportional to the ALLOCATION, not the position
-(the prime suspect behind the 13%-MBU long-context row, BASELINE.md).
+context, bytes/step are proportional to the ALLOCATION, not the position.
 The Pallas decode kernel fixes this on TPU by clamping its cache fetches
 at the live limit (ops/pallas/cached_attention.decode_attention); this
 module is the portable XLA-side counterpart:
@@ -22,8 +21,7 @@ module is the portable XLA-side counterpart:
     bit-identical to the unbucketed program (tests/test_decode_buckets.py
     pins this for f32, bf16, and int8 caches, through a bucket edge).
 
-Two consumers: `make_bucketed_generate` (the solo host-loop decoder —
-also the `decode_bucketing` benchmark's subject, benchmarks/run_all.py)
+Two consumers: `make_bucketed_generate` (the solo host-loop decoder)
 and `ContinuousBatcher(decode_buckets=...)` (runtime/serving.py), whose
 pool grows bucket-by-bucket as its slots advance. Compiled-program count
 is bounded by the ladder length (one step program per live bucket), a

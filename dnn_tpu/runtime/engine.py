@@ -262,8 +262,8 @@ class PipelineEngine:
     # Auto param-placement threshold: below this total param size the
     # per-device HBM savings of packed placement can't matter (every shipped
     # small model's weights fit everywhere many times over) while its
-    # per-scan-step unpack work shows up — measured 10-18% on the cpu-mesh
-    # CIFAR pipeline configs. Above it, per-stage HBM residency wins.
+    # per-scan-step unpack work is paid every step. Above it, per-stage
+    # HBM residency wins. Not measured on the chip.
     PLACEMENT_AUTO_BYTES = 32 * 1024 * 1024
 
     def _resolve_param_placement(self) -> str:
@@ -366,7 +366,7 @@ class PipelineEngine:
 
         # One-time, load-side: stack blocks stage-major (S, per_stage, ...)
         # and place each stage's slice on its device (HBM-resident per-stage
-        # weights — BASELINE.json north star). prepare_pipeline_stacked is
+        # weights, no host round trip between stages). prepare_pipeline_stacked is
         # the single owner of this layout; generation consumes the same
         # placement (self._gen_parts).
         stage_major, aux = prepare_pipeline_stacked(
@@ -606,7 +606,7 @@ class PipelineEngine:
     # ------------------------------------------------------------------
 
     def benchmark(self, x, *, iters: int = 20, warmup: int = 3) -> dict:
-        """Measure the BASELINE.json metrics on this engine's pipeline:
+        """Time this engine's pipeline on whatever backend it runs on:
         items/sec (images or tokens), p50/p90 end-to-end step latency, and —
         in relay mode, where hops are individually observable — p50
         inter-stage hop latency (device->device transfer, stage 0's host
@@ -623,8 +623,7 @@ class PipelineEngine:
             )
         m = Metrics()
         xs = np.asarray(x).shape
-        # items: tokens (B*T) for integer id inputs, else examples (B) —
-        # the BASELINE.json tokens/sec vs images/sec distinction.
+        # items: tokens (B*T) for integer id inputs, else examples (B)
         if np.issubdtype(np.asarray(x).dtype, np.integer) and len(xs) == 2:
             batch_items = int(xs[0] * xs[1])
         else:

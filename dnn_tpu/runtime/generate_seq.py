@@ -4,8 +4,7 @@ Why: at long context the KV cache, not the weights, is what no longer
 fits: a GPT-2-small-shaped model at S=128k, B=8 carries a multi-GB f32
 cache. The two sequence-parallel strategies already in the tree
 (ring attention, Ulysses — dnn_tpu/parallel/{ring_attention,ulysses}.py)
-cover STATELESS forwards; this module is the missing serving bridge
-(VERDICT r2, next #8): a decode loop whose cache is sharded over the
+cover STATELESS forwards; this module is the serving bridge: a decode loop whose cache is sharded over the
 "seq" mesh axis, each device owning a contiguous block of positions.
 
 Design (and why it is NOT a ring):

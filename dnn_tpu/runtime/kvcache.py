@@ -4,7 +4,7 @@ Decode at long context is bounded by CACHE reads, not weights: every step
 streams the whole (L, B, H, S, D) K/V history from HBM for one token of
 compute. Weight-only quantization (dnn_tpu/quant.py) halves/quarters the
 weight bytes; this module does the same for the cache — the other half of
-the decode-bandwidth story (VERDICT r2, weak #6).
+the decode-bandwidth story.
 
 Scheme, mirroring quant.py's weight recipe:
 
@@ -58,16 +58,15 @@ __all__ = ["FloatKV", "Int8KV", "Int4KV", "RollingFloatKV", "RollingInt8KV",
            "band_keep", "codec_for_cache", "AUTO_KERNEL_MIN_S"]
 
 # `use_kernel="auto"` threshold: below this many cache positions the XLA
-# einsum path is at least as fast as the Pallas streaming kernel on every
-# measured shape (benchmarks/attn_kernel_probe.py: einsum is
-# near-bandwidth-optimal at short/moderate context, and the bucketed
-# decode path — runtime/decode_buckets.py — keeps the allocation tracking
-# the live length anyway). At or above it, a decode step against a LONG
-# preallocated cache routes through the position-clamped kernel
-# (ops/pallas/cached_attention.decode_attention), whose index-map clamp
-# makes bytes/step proportional to the live position instead of the
-# allocation — the regime behind the 13%-MBU long-context row
-# (BASELINE.md). Heuristic, to be refined when the chip can re-measure.
+# einsum path is preferred: it reads a short allocation whole at little
+# cost, and the bucketed decode path — runtime/decode_buckets.py — keeps
+# the allocation tracking the live length anyway. At or above it, a
+# decode step against a LONG preallocated cache routes through the
+# position-clamped kernel (ops/pallas/cached_attention.decode_attention),
+# whose index-map clamp makes bytes/step proportional to the live
+# position instead of the allocation. A heuristic: no cell of the chip
+# benchmark runs a dense cache (PERF.md section 7), so the threshold is
+# not measured.
 AUTO_KERNEL_MIN_S = 1024
 
 
@@ -246,7 +245,7 @@ class FloatKV(_KernelDispatch):
             if q.shape[2] == 1:
                 # decode step: the heads-folded streaming kernel (few
                 # programs, big DMAs) — the general kernel's block_q=1
-                # grid measured 23x slower (ops/pallas/cached_attention)
+                # grid runs one tiny program a (row, head, block)
                 return decode_attention(
                     q, c["k"], c["v"], pos_b,
                     interpret=self._interp()).astype(c["v"].dtype)

@@ -484,7 +484,7 @@ def codec_is_paged(cache) -> bool:
     return isinstance(cache, dict) and "tables" in cache
 
 
-def scan_blocks(block, x, blocks, cache, codec, *xs, unroll=1):
+def scan_blocks(block, x, blocks, cache, codec, *xs):
     """Run the stacked `blocks` over a KV cache: `block(bp, x, c, codec,
     *xs_l) -> (x, c)` once per layer -> (x, cache). What the cache IS
     decides how it rides the loop (a property of the input, not an
@@ -497,8 +497,7 @@ def scan_blocks(block, x, blocks, cache, codec, *xs, unroll=1):
         output, touched only by the row scatter and the kernel's block
         reads;
       * a dense cache (L, B, H, S, D) rides as xs/ys, one layer's slots
-        per iteration (`unroll` is that scan's CPU-lowering lever,
-        GPTFamilyRows.unroll_layers).
+        per iteration.
 
     `xs` are further per-layer inputs (LLaMA's per-layer windows)."""
     # `layers.scan` names the loop's OWN work on a device trace: slicing
@@ -511,7 +510,7 @@ def scan_blocks(block, x, blocks, cache, codec, *xs, unroll=1):
                 bp, c, *rest = layer_in
                 return block(bp, x, c, codec, *rest)
 
-            return lax.scan(dense, x, (blocks, cache, *xs), unroll=unroll)
+            return lax.scan(dense, x, (blocks, cache, *xs))
 
         def paged(carry, layer_in):
             bp, layer, *rest = layer_in

@@ -130,7 +130,7 @@ class GPTFamilyRows:
     KV-head-width cache; MoE stays a GPT block with `ffn` overridden)."""
 
     def __init__(self, cfg, *, compute_dtype=None, ffn=None,
-                 attn_kernel="auto", unroll_layers: bool = False):
+                 attn_kernel="auto"):
         self.cfg = cfg
         self.compute_dtype = compute_dtype
         self.ffn = ffn
@@ -139,20 +139,6 @@ class GPTFamilyRows:
         # "auto" (default) = the length-aware policy — kernel only on TPU
         # against caches >= kvcache.AUTO_KERNEL_MIN_S positions
         self.attn_kernel = attn_kernel
-        # unroll_layers=True unrolls the DECODE-step layer scan over a
-        # dense cache (a paged pool is carried through the loop whole and
-        # has no per-layer slices to unroll for: paged_kvcache.
-        # scan_blocks ignores it there) into
-        # straight-line code: the CPU backend then updates each layer's
-        # cache slice truly in place instead of copying the scan-carried
-        # cache state around the while loop (the PR-1 "three full-cache
-        # copies per step" lowering — measured 1.6x step wall-clock at
-        # long context, benchmarks/decode_mbu_probe.py). Costs one body
-        # copy per layer at compile time, so it is opt-in; prefill and
-        # verify keep the scan (not per-token-hot, and the chunk program
-        # compiles per prompt bucket already). TPU while-loops alias
-        # loop state natively, so this knob is a CPU-lowering lever.
-        self.unroll_layers = bool(unroll_layers)
 
     def init_cache(self, batch, max_len, dtype):
         return init_cache(self.cfg, batch, max_len, dtype)
@@ -223,8 +209,7 @@ class GPTFamilyRows:
 
         # a paged pool rides the loop whole, a dense cache by layer
         x, new_cache = scan_blocks(
-            block, x, prepared["blocks"], cache, codec,
-            unroll=cfg.n_layer if self.unroll_layers else 1)
+            block, x, prepared["blocks"], cache, codec)
         logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                       compute_dtype=compute_dtype)
         return logits[:, -1], new_cache
@@ -281,7 +266,6 @@ class ContinuousBatcher:
                  allow_logit_bias: bool = False,
                  allow_constraints: bool = False,
                  constraint_rows: int = 1024,
-                 unroll_layers: bool = False,
                  prefill_chunk_tokens: int = 0,
                  overlap: bool = False):
         self.cfg = cfg
@@ -338,10 +322,6 @@ class ContinuousBatcher:
                 raise ValueError(
                     "pass attn_kernel on the family adapter, not alongside "
                     "family= (the adapter owns its attention path)")
-            if unroll_layers:
-                raise ValueError(
-                    "pass unroll_layers on the family adapter, not "
-                    "alongside family= (the adapter owns its layer scan)")
             fam_dtype = getattr(family, "compute_dtype", None)
             if compute_dtype is not None and fam_dtype != compute_dtype:
                 raise ValueError(
@@ -350,7 +330,7 @@ class ContinuousBatcher:
             compute_dtype = fam_dtype
         self.family = family or GPTFamilyRows(
             cfg, compute_dtype=compute_dtype, ffn=ffn,
-            attn_kernel=attn_kernel, unroll_layers=unroll_layers)
+            attn_kernel=attn_kernel)
         # kv_dtype picks the cache storage codec (None follows
         # compute_dtype; "int8" = quantized cache, kvcache.Int8KV)
         cache_dtype = kv_dtype if kv_dtype is not None else (compute_dtype or jnp.float32)
@@ -617,8 +597,8 @@ class ContinuousBatcher:
         self.goodput = None
         # step-timeline attribution (obs/timeline.StepClock): splits
         # every decode step into named phases (admit/host/dispatch/
-        # wait/commit/obs) for the /stepz endpoint and the item-4
-        # host-serialization ratchet. Attached post-construction like
+        # wait/commit/obs) for the /stepz endpoint and the whole-window
+        # totals on /metrics the chip benchmark reads. Attached post-construction like
         # goodput (`pool.step_clock = StepClock().install()` — LMServer
         # auto-builds one); unset it costs one attribute read per step,
         # and the clock itself gates on DNN_TPU_OBS (begin() returns
@@ -675,7 +655,7 @@ class ContinuousBatcher:
         self._active_hw = 0
         # step-obs accumulator (see _obs_flush): the per-step registry
         # bulk (lock + counter updates + reservoir + gauge check) was
-        # the single largest line in the obs_overhead bill, so steps
+        # the largest part of what observability adds to a step, so steps
         # batch into plain fields and land every _OBS_FLUSH_STEPS
         # steps, on a bucket switch, or when the pool goes idle (end
         # of every drain — tests and scrapes that look after traffic
@@ -753,7 +733,7 @@ class ContinuousBatcher:
             # KV-tier residency + cross-replica effectiveness: resident
             # radix blocks, and the fraction of block-granular hits
             # served from ADOPTED (migrated-in) blocks — the fleet
-            # tier's whole point, asserted by benchmarks/kv_tier_probe
+            # tier's whole point
             self._obs_gauges["dnn_tpu_kvtier_blocks"] = _weak_gauge(
                 "_kvtier_blocks_read")
             self._obs_gauges["dnn_tpu_kvtier_remote_hit_ratio"] = \
@@ -763,8 +743,8 @@ class ContinuousBatcher:
         # the radix store. Attached only when the obs gate is ON at
         # construction — a gate-off process pays exactly one
         # `lens is not None` check per store hook. The lens itself
-        # re-checks the gate per call, so runtime flips (the overhead
-        # probe's on/off interleave) stop recording immediately.
+        # re-checks the gate per call, so a runtime flip of the gate
+        # stops recording immediately.
         self._kvlens = None
         if self._prefix_store is not None and obs.enabled():
             from dnn_tpu.obs.kvlens import KVLens
@@ -1047,8 +1027,8 @@ class ContinuousBatcher:
         # --------------------------------------------------------------
         # prefill_chunk_tokens > 0 switches ADMISSION from the convoy
         # path (submit() runs the whole chunk loop + finish inline,
-        # stalling every decode slot for the prefill's duration — the
-        # 0.54 admit fraction PR 10's StepClock measured) to the MIXED
+        # stalling every decode slot for the prefill's duration: the
+        # `admit` share of PERF.md section 5) to the MIXED
         # step: submit() only validates, allocates and enqueues, and
         # each subsequent decode step folds ONE prompt chunk of that
         # width into the same compiled program. The fused finish then
@@ -1090,7 +1070,7 @@ class ContinuousBatcher:
         # step N and commits step N-1's tokens, so the host slot loop
         # (commit/obs, and the next admission's bookkeeping) runs while
         # the device executes step N — the dispatch_slack headroom the
-        # StepClock measured, actually spent. Tokens surface one step()
+        # StepClock reports, actually spent. Tokens surface one step()
         # call later; drain()/flush_overlap() commit the trailing step.
         self._overlap = bool(overlap)
         # allow_constraints composes with the one-step pipeline since
@@ -1592,9 +1572,9 @@ class ContinuousBatcher:
             install_ids = jnp.asarray(inst)
             if kv_hit is not None and (n_shared or cow_tok):
                 # admission HOLDS the blocks now — record the reuse
-                # (post-truncation, post-allocation: the ratio the
-                # kv_tier probe floors must never count blocks the
-                # request didn't actually get)
+                # (post-truncation, post-allocation: the hit ratio
+                # must never count blocks the request didn't actually
+                # get)
                 self._prefix_store.note_reuse(
                     n_shared + (1 if cow_tok > 0 else 0),
                     kv_hit.remote_used(n_shared, cow_tok > 0),
@@ -1843,8 +1823,7 @@ class ContinuousBatcher:
                         # physical blocks this admission reused, and
                         # how many of them arrived by MIGRATION from a
                         # sibling replica (origin="adopted") — the
-                        # cross-replica number the kv_tier probe
-                        # floors. Post-truncation counts, matching
+                        # cross-replica number. Post-truncation counts, matching
                         # note_reuse above.
                         counters["serving.prefix_blocks_reused_total"] \
                             = n_shared + (1 if cow_tok > 0 else 0)
@@ -2665,8 +2644,8 @@ class ContinuousBatcher:
         tokens committed across all slots): counters/samples land in ONE
         bulk registry update, and the pool gauges are CALLABLE — read at
         scrape time from host state. Both choices are load-bearing:
-        per-series locking measurably taxes a sub-ms CPU decode step
-        (benchmarks/obs_overhead_probe.py), and stored gauges freeze at
+        per-series locking taxes a sub-ms decode step once per series
+        instead of once per step, and stored gauges freeze at
         the last step's value on an idle pool (throughput would never
         decay, occupancy would report the retired batch forever)."""
         if m is None:
@@ -2675,7 +2654,7 @@ class ContinuousBatcher:
         # this stays inside the bulk-update budget): the gauges above
         # read them at scrape time. One pass over the slots for both
         # live positions and the active count — this runs every step,
-        # and the obs_overhead contract prices a second genexpr sweep.
+        # and a second genexpr sweep would be paid every step too.
         live = 0
         n_act = 0
         for r in self._slot_req:
@@ -3197,9 +3176,9 @@ class ContinuousBatcher:
         # drop the tuple's references to the just-donated buffers NOW:
         # holding them to frame teardown makes their deletion run after
         # the step record closes, and deleting a donated-but-pending
-        # buffer blocks on the in-flight computation — measured as ~a
-        # device-step of unattributed dark time per call (the step
-        # timeline probe's coverage assert caught it)
+        # buffer blocks on the in-flight computation: about a device
+        # step of time per call that no phase accounts for
+        # (tests/test_obs_timeline.py's coverage bound)
         del state
         if rec is not None:
             sc.mark(rec, "dispatch")

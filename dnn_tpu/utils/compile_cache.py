@@ -3,7 +3,7 @@
 Every chip call starts from nothing unless compiled programs persist on
 disk, and the cache directory is part of the cache key — a directory that
 moves between runs never hits. So the entry points (`dnn_tpu.node`,
-`chip_smoke.py`, `bench.py`, each `benchmarks/run_all.py` child) call
+`chip_smoke.py`, `chipbench/pipe.py`) call
 `enable_compile_cache()` once, before their first compile:
 
   * `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself; nothing is

@@ -1,12 +1,14 @@
 """FLOPs accounting and MFU (model FLOPs utilization).
 
-Round-1 review: "vs torch-CPU is an honest but nearly information-free
-comparison ... nothing reports MFU, the number that would actually prove
-'fast on TPU'". This module supplies the accounting: analytic forward
+A rate says nothing about the hardware until it is set against the
+chip's peak. This module supplies the accounting: analytic forward
 FLOPs for the model families (matmuls + attention — the operations the MXU
 executes; elementwise and gathers are noise at these shapes) and a peak-
-FLOPs table per TPU generation, so every benchmark row can report
+FLOPs table per TPU generation, so the live gauges (obs/goodput.py,
+obs/trainlens.py) can report
     mfu = achieved FLOPs/s / chip peak FLOPs/s.
+The chip benchmark keeps its own copy of the peaks and the forward count
+(chipbench/peaks.py), so that the yardstick does not move with the program.
 
 Conventions (the standard MFU bookkeeping, e.g. the PaLM appendix):
   * a matmul (m, k) @ (k, n) costs 2*m*k*n FLOPs;
@@ -53,39 +55,14 @@ def _tpu_peak(device: jax.Device, table, what: str) -> float:
 
 def device_peak_flops(device: Optional[jax.Device] = None) -> Optional[float]:
     """bf16 peak FLOPs/s of `device` (default: the first default device).
-    None off-TPU (CPU hosts have no peak worth a utilization — callers
-    omit the mfu field); an unrecognized TPU raises. DNN_TPU_PEAK_FLOPS
-    overrides the table (the opt-in roofline for CPU hosts and
-    accelerators the table doesn't know; utilization numbers against an
-    operator-stated peak beat no numbers at all)."""
-    import os
-
-    env = _env_peak(os.environ.get("DNN_TPU_PEAK_FLOPS"))
-    if env is not None:
-        return env
+    None off-TPU (a CPU host has no peak worth a utilization — callers
+    omit the mfu field, and nothing in the environment can state one);
+    an unrecognized TPU raises."""
     if device is None:
         device = jax.devices()[0]
     if device.platform != "tpu":
         return None
     return _tpu_peak(device, _TPU_PEAK_BF16, "bf16 FLOP/s")
-
-
-def _env_peak(raw) -> Optional[float]:
-    """Parse an operator-stated roofline env var; garbage or <= 0 reads
-    as unset (the degrade-don't-crash rule every env knob follows —
-    DNN_TPU_PEAK_FLOPS=0 must mean "unknown", not ZeroDivisionError in
-    every MFU consumer)."""
-    if not raw:
-        return None
-    try:
-        v = float(raw)
-    except ValueError:
-        import logging
-
-        logging.getLogger("dnn_tpu.utils").warning(
-            "ignoring malformed peak override %r (want a number)", raw)
-        return None
-    return v if v > 0 else None
 
 
 def gpt_forward_flops(cfg, batch: int, seq: int) -> float:
@@ -233,18 +210,6 @@ def kv_bytes_per_pos(cfg, *, kv_bytes: float = 2,
     return float(2 * cfg.n_layer * kv_width * kv_bytes)
 
 
-def decode_step_bytes(weight_bytes: float, kv_live_positions: float,
-                      cfg, *, kv_bytes: int = 2) -> float:
-    """HBM traffic of ONE decode step over a whole slot pool: the weights
-    stream once per STEP (shared by every active row — batching's whole
-    point) plus every live row's cache positions. `weight_bytes` is the
-    total parameter bytes (count the real tree when you have it:
-    goodput.ModelCost.from_prepared); `kv_live_positions` the summed
-    live positions across active slots. The live-MBU numerator."""
-    return float(weight_bytes) + float(kv_live_positions) * \
-        kv_bytes_per_pos(cfg, kv_bytes=kv_bytes)
-
-
 def _train_step_factor(batch: int, accum_steps: int, remat: bool) -> float:
     """The forward→train-step multiplier (the PaLM-appendix bookkeeping):
     3x a forward (fwd + backward's two matmuls per forward matmul), 4x
@@ -268,8 +233,7 @@ def gpt_train_step_flops(cfg, batch: int, seq: int, *,
     4x with remat — the backward replays the forward). `accum_steps`
     validates the microbatch split but leaves the total unchanged
     (forward FLOPs are linear in batch). The trainlens MFU numerator
-    (obs/trainlens.py) and the dev_gpt2_train_step row both price from
-    this one walk."""
+    (obs/trainlens.py) prices from this walk."""
     return _train_step_factor(batch, accum_steps, remat) \
         * gpt_forward_flops(cfg, batch, seq)
 
@@ -280,57 +244,6 @@ def llama_train_step_flops(cfg, batch: int, seq: int, *,
     as gpt_train_step_flops over the GQA/SwiGLU forward walk."""
     return _train_step_factor(batch, accum_steps, remat) \
         * llama_forward_flops(cfg, batch, seq)
-
-
-def cifar_forward_flops(batch: int) -> float:
-    """Forward FLOPs of the CIFAR CNN (dnn_tpu/models/cifar.py: conv 3->32,
-    conv 32->64 on pooled maps, fc 4096->512, fc 512->10)."""
-    conv1 = 2 * 32 * 32 * 32 * (3 * 3 * 3)
-    conv2 = 2 * 16 * 16 * 64 * (3 * 3 * 32)
-    fc1 = 2 * 4096 * 512
-    fc2 = 2 * 512 * 10
-    return float(batch) * (conv1 + conv2 + fc1 + fc2)
-
-
-def cifar_forward_bytes(batch: int, *, dtype_bytes: int = 2) -> float:
-    """Per-batch HBM traffic of the CIFAR forward, assuming XLA's typical
-    fusion (bias/relu fused into each conv; pool, transpose, and each
-    matmul read their input and write their output). The CNN is TINY —
-    ~15.6 MFLOPs/image against ~0.27 MB of activation traffic — so its
-    arithmetic intensity (~60 FLOPs/byte) sits far below a v5e's ridge
-    point (~240 FLOPs/byte): the model is HBM-BOUND at any batch size,
-    and its MFU ceiling is intensity/ridge (~24%), not 100%. The bench
-    row reports this cap next to the measured MFU (VERDICT r2 weak #3).
-
-    The cap is CONSERVATIVE: it charges every op boundary a full HBM
-    round trip, but XLA keeps some producer->consumer tiles in VMEM (the
-    conv1-padded forward measures ~39% MFU at B=1024 on a v5e —
-    benchmarks/cifar_mfu_probe.py), so `roofline_frac` can legitimately
-    exceed 1.0."""
-    act = dtype_bytes * (
-        32 * 32 * 3          # input read by conv1
-        + 32 * 32 * 32 * 2   # conv1 write + pool1 read
-        + 16 * 16 * 32 * 2   # pool1 write + conv2 read
-        + 16 * 16 * 64 * 2   # conv2 write + pool2 read
-        + 8 * 8 * 64 * 2     # pool2 write + transpose read
-        + 4096 * 2           # transpose write + fc1 read
-        + 512 * 2            # fc1 write + fc2 read
-        + 10                 # fc2 write
-    )
-    weights = dtype_bytes * (27 * 32 + 288 * 64 + 4096 * 512 + 512 * 10
-                             + 32 + 64 + 512 + 10)
-    return float(batch) * act + weights  # weights stream once per batch
-
-
-def roofline_items_per_sec(flops_per_item: float, bytes_per_item: float,
-                           device: Optional[jax.Device] = None) -> Optional[float]:
-    """min(compute, bandwidth) roofline for one benchmark item, or None
-    off-TPU: the throughput ceiling the hardware admits for this op mix."""
-    peak_f = device_peak_flops(device)
-    peak_b = device_peak_hbm_bw(device)
-    if peak_f is None or peak_b is None:
-        return None
-    return min(peak_f / flops_per_item, peak_b / bytes_per_item)
 
 
 def mfu(flops_per_item: float, items_per_sec: float,
@@ -362,13 +275,7 @@ _TPU_PEAK_HBM = (
 
 def device_peak_hbm_bw(device: Optional[jax.Device] = None) -> Optional[float]:
     """HBM peak bytes/s of `device`: None off-TPU, an error for a TPU the
-    table does not know. DNN_TPU_PEAK_HBM_BW overrides, like
-    DNN_TPU_PEAK_FLOPS above."""
-    import os
-
-    env = _env_peak(os.environ.get("DNN_TPU_PEAK_HBM_BW"))
-    if env is not None:
-        return env
+    table does not know."""
     if device is None:
         device = jax.devices()[0]
     if device.platform != "tpu":

@@ -1,11 +1,8 @@
 """Static HLO bytes audit for decode steps.
 
-BASELINE.md's open long-context question names hypothesis (a): XLA
-materializing a cache-sized (transposed) copy per decode step for the
-(B, H, 1, S) matvec layout — a 2x+ traffic multiplier that would explain
-the 13%-MBU `llama_mha_longctx_decode_dense` row without any new
-measurement. The chip has been wedged for three rounds; this module
-answers the question ON PAPER: `jax.jit(...).lower(...)` needs no healthy
+The question: does XLA materialize a cache-sized (transposed) copy per
+decode step for the (B, H, 1, S) matvec layout of a dense cache — a 2x+
+traffic multiplier at long context? This module answers it ON PAPER: `jax.jit(...).lower(...)` needs no healthy
 backend (shapes ride `jax.eval_shape`, so even the 1.1B-parameter audit
 costs no memory), and the resulting program text can be scanned for
 cache-sized copies/transposes.
@@ -18,7 +15,7 @@ Two inspection levels, honestly distinct:
   * `optimize=True` — the backend-optimized HLO after XLA's pipeline on
     THIS host's backend (CPU under the test suite). This is where
     materialization decisions live; a CPU count is a proxy for the TPU
-    answer, labeled as such wherever it is recorded (BASELINE.md).
+    answer, and is labeled as such wherever it is recorded.
 
 The counters are format-tolerant (StableHLO `tensor<8x12x256x64xf32>`
 result types and classic HLO `f32[8,12,256,64]{...} opcode(...)` lines
@@ -215,8 +212,8 @@ def audit_decode_step(step_fn, args, layer_cache_elems, *,
 
 
 def _main():
-    """Reproduce the BASELINE.md long-context audit: the 13%-MBU row's
-    exact decode-step shape (TinyLlama widened to MHA, B=8, S=1536),
+    """The long-context audit at one dense decode-step shape
+    (TinyLlama widened to MHA, B=8, S=1536),
     StableHLO level plus this host's optimized HLO."""
     import dataclasses
     import json

@@ -4,7 +4,7 @@ The reference's only observability is ad-hoc stdout prints (SURVEY §5
 'Metrics': node.py:38-39, 85-86, 120-122 — no levels, no counters, no
 timers). This module supplies the rebuild's structured replacement: named
 counters/gauges plus a latency reservoir with percentiles and fixed-bucket
-histograms, emitting the BASELINE.json metrics (images/sec, tokens/sec,
+histograms, emitting rates and latencies (images/sec, tokens/sec,
 p50 inter-stage latency) as plain dicts / JSON lines — and, for the
 serving stack's `/metrics` endpoint (dnn_tpu/obs/http.py), as Prometheus
 text exposition format (`render_prometheus`).
@@ -67,7 +67,7 @@ class LatencyReservoir:
 
     def record_many(self, values):
         """Batch form for Metrics.bulk — one call per step instead of
-        one per sample (the per-step obs budget prices the difference)."""
+        one per sample (one lock take instead of one per value)."""
         for v in values:
             self._count += 1
             self._sum += v
@@ -276,7 +276,7 @@ class _Timer:
 
 class Throughput:
     """items/sec over a sliding wall-clock window (default 60 s) — the
-    BASELINE.json images/sec / tokens/sec counters, and the
+    images/sec / tokens/sec counters, and the
     `serving.tokens_per_sec` gauge the `/metrics` endpoint exports.
 
     A real window, not cumulative-since-first-add: events older than
@@ -313,8 +313,8 @@ class Throughput:
     def add_at(self, t: float, n: int):
         """add() with a caller-supplied timestamp — a producer updating
         several windows in one step (goodput's flops/bytes/tokens) reads
-        the clock once and shares it; three clock reads per step were
-        measurable against the serving obs budget."""
+        the clock once and shares it instead of reading it three times
+        a step."""
         with self._lock:
             self._evict(t)
             self._events.append((t, n))

@@ -10,7 +10,7 @@ go through `obs.profile.capture` / POST /profilez rather than the bare
 `trace_to` kept here for compatibility.
 
 `device_sync` / `timed_blocked` are NOT spans — they are the honest
-device-completion barrier the benchmarks are built on — and live on
+device-completion barrier `PipelineEngine.benchmark` is built on — and live on
 here as this module's real content.
 """
 
@@ -53,8 +53,7 @@ def device_sync(out) -> None:
     measures dispatch only. The barrier here is a 1-element host read per
     device: execution is in-order, so the read completes only after
     everything queued before it — the same guarantee as
-    `jax.block_until_ready`, at a constant cost the slope method
-    (utils/timing.device_time) cancels.
+    `jax.block_until_ready`, at a constant cost.
     """
     import numpy as np
 

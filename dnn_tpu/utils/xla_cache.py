@@ -5,10 +5,9 @@ that keeps compiling DISTINCT programs eventually segfaults inside the
 XLA CPU compiler — the full test suite (600+ tests, several programs
 each) dies at ~85% unless compiled executables drop between modules
 (tests/conftest.py's between-modules `jax.clear_caches()` fixture).
-`benchmarks/xla_cache_probe.py` probes minimal forms: 6000 distinct
-TINY programs do NOT crash (flat RSS — the trigger is the suite's
-program population, SPMD collectives/donation/scans, not raw count),
-so the suite-scale evidence is the operative fact. A long-lived
+Thousands of distinct TINY programs do NOT crash (the trigger is the
+suite's program population, SPMD collectives/donation/scans, not raw
+count), so the suite-scale evidence is the operative fact. A long-lived
 serving daemon that keeps admitting new program shapes (models,
 adapters, pooling variants, padded-length buckets) accumulates the
 same compiled-artifact volume over days.

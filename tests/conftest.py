@@ -35,173 +35,6 @@ def _cpu_mesh_guard():
     assert len(jax.devices()) >= 8, f"expected >=8 virtual devices, got {jax.devices()}"
 
 
-# Measured wall-clock per test module (seconds, full suite on the 2-core
-# CI host — regenerate with `pytest --durations=0` and summing per file).
-# The tier-1 gate runs under a FIXED TIME BUDGET (ROADMAP.md: 870 s via
-# `timeout`), far less than the ~36 min the whole suite takes here, so
-# execution ORDER decides how much of the suite the budget certifies.
-# Alphabetical order spent the window on a handful of compile-heavy mesh/
-# kernel integration modules early in the alphabet; running cheapest
-# modules first maximizes tests-verified-per-budget, and truncation then
-# falls on the slowest integration tail (which the unbudgeted full run
-# still covers). Nothing is deselected — every test remains collected
-# and runs when the budget allows.
-_MODULE_COST_S = {
-    "test_interop_reference": 0.1, "test_config": 0.2, "test_data": 0.2,
-    "test_checkpoint": 0.4, "test_bench_echo": 0.5,
-    "test_run_all_state": 0.5, "test_flops": 0.6,
-    "test_native_loader": 0.7, "test_native": 0.8,
-    "test_chip_compile": 1.0,  # ISSUE 21: the main path's Pallas kernels
-    # (and the daemon's step programs at depth 2) compiled for a DESCRIBED
-    # v5e — ~11 s measured, skipped where the TPU compiler is absent
-    "test_chip_smoke": 1.5,  # ISSUE 21: chip_smoke.py's own logic at tiny
-    # size on the CPU (device ownership, no-chip exit, compile-cache
-    # placement, strict device_type) — ~35 s measured, one real daemon
-    # subprocess dominates. Both sort early on purpose: they guard the
-    # path every later PR is measured on
-    "test_hlo_audit": 3.4,
-    "test_metrics": 3.7, "test_models_cifar": 4.6, "test_multihost": 4.6,
-    "test_comm": 5.7, "test_models_mlp": 7.3, "test_tokenizer": 7.8,
-    "test_transport": 14.0,  # ISSUE 7 pluggable transport: wirecodec
-    # goldens vs protobuf, negotiation matrix, grpc|shm|device parity on
-    # a real 2-stage engine, streamed relay, and one real 2-process shm
-    # hop (subprocess) — cheap, certified early in the tier-1 budget
-    "test_param_placement": 8.7, "test_qwen3": 9.6,
-    "test_torch_export": 11.1, "test_models_gpt": 11.4,
-    "test_analysis": 13.7,  # the static-analyzer gate: cheap, CPU-only,
-    # and placed early so the tier-1 budget always certifies it
-    "test_analysis_shard": 8.5,  # ISSUE 17 sharding-safety analyzer:
-    # SHD rule fixture pairs, buggy-program variants through the audit
-    # helpers (replicated bill, axis-divergent psum, contract drift,
-    # un-aliased sharded donation), the real-program goldens (one
-    # module-scoped run_shard_audit), SARIF + CLI exit codes — cheap,
-    # certified early in the tier-1 budget next to test_analysis
-    "test_analysis_concurrency": 8.0,  # ISSUE 10 concurrency-hazard
-    # analyzer: CON rule fixture pairs, the three historical shipped
-    # bugs as fixtures, protocol-table goldens, loop-lag sanitizer,
-    # CLI --diff/sarif — pure AST + tiny asyncio loops, certified
-    # early in the tier-1 budget next to test_analysis
-    "test_obs": 28.0,  # the observability layer (spans, /metrics, compile
-    # telemetry + the `python -m dnn_tpu.obs trace --selftest` CI smoke):
-    # mid-pack cost, certified within the tier-1 budget
-    "test_obs_v2": 36.0,  # obs v2 (flight recorder, watchdog, /profilez,
-    # memory watermarks): the wedged-probe and crash-dump subprocess legs
-    # dominate; placed with test_obs inside the tier-1 budget
-    "test_obs_timeline": 17.0,  # ISSUE 11 step-timeline attribution:
-    # StepClock phase arithmetic (injected clock), capture-analysis
-    # goldens over synthetic Perfetto JSON, real profiler captures
-    # holding the step.* / admit* spans, /stepz scrape, CLI smoke — cheap,
-    # certified early in the tier-1 budget with the other obs modules
-    "test_obs_kvlens": 12.0,  # ISSUE 18 memory-economy observatory:
-    # MRC goldens at rate=1 (exact LRU), sampling determinism, thrash
-    # arithmetic on an injected clock, /kvz json+prom, CLI smoke, and
-    # one real forced-eviction batcher feeding the radix-store seams —
-    # the CLI subprocess and batcher compile dominate; placed with the
-    # other obs modules inside the tier-1 budget
-    "test_obs_caplens": 6.0,  # ISSUE 20 capacity observatory: planner
-    # replay goldens + determinism on an injected clock, demand-window
-    # and change-point arithmetic, cold-start bucket attribution off
-    # the boot gauges, audit-trailed wanted-replicas transitions,
-    # /capz json+prom, the /fleetz wanted-rollup max regression, CLI
-    # selftest, and the replica-handle lifecycle seams — the CLI
-    # subprocess dominates; placed with the other obs modules
-    "test_obs_trainlens": 14.0,  # ISSUE 19 training-step observatory:
-    # TrainClock phase arithmetic + stall attribution on an injected
-    # clock, MFU vs hand arithmetic, GradSentinel NaN/spike/stall
-    # episodes, ckpt staleness, /trainz json+prom, CLI selftest, and
-    # one real fit() on a tiny GPT feeding every seam — the fit
-    # compile dominates; placed with the other obs modules
-    "test_obs_fleet": 21.0,  # fleet layer (cross-host stitching, goodput
-    # MFU/MBU, SLO burn rates + the `obs fleet --selftest` CLI smoke):
-    # cheap HTTP endpoints + one real 2-stage gRPC request, certified
-    # inside the tier-1 budget ahead of the obs integration modules
-    "test_workloads": 20.0,  # ISSUE 14 SLO observatory: golden arrival
-    # schedules, scenario-script determinism, SLO-verdict arithmetic,
-    # incident-bundle roundtrip + CLI render, ledger parsing vs the
-    # real BENCH_r*.json/RESULTS.md, prefix-cache counters/gauge, one
-    # green light scenario + the chaos breach asserted from its bundle
-    # — cheap, certified early in the tier-1 budget
-    "test_grad_accum": 12.9, "test_train_ckpt": 14.3, "test_remat": 14.6,
-    "test_qwen2": 14.7, "test_olmo2": 14.8, "test_tp_generate": 15.6,
-    "test_pipeline": 16.5, "test_seq_parallel": 17.0,
-    "test_generate": 17.7, "test_eval_distill": 17.8, "test_fsdp": 18.2,
-    "test_dp_pp": 18.3, "test_int4": 18.6, "test_prefix_cache": 19.7,
-    "test_rope_scaling": 20.4, "test_lm_server_failures": 20.6,
-    "test_generate_seq": 20.8, "test_pipeline_dtypes": 22.2,
-    "test_phi": 22.3, "test_train_serve_example": 23.1, "test_lora": 23.1,
-    "test_qwen2_moe": 23.2, "test_composition": 23.3,
-    "test_pipeline_generate": 23.3, "test_ulysses": 24.1,
-    "test_quant": 24.3, "test_kvcache": 24.7, "test_lm_streaming": 27.4,
-    "test_beam": 28.9, "test_flash_attention": 28.9, "test_moe": 29.3,
-    "test_interleaved": 33.5, "test_sampler_extras": 33.6,
-    "test_gpt_moe": 34.4, "test_generate_moe": 34.6, "test_train": 35.2,
-    "test_constrain": 35.4, "test_engine_cli": 37.0,
-    "test_cached_attention": 37.4, "test_serving": 37.6,
-    "test_serving_options": 37.6, "test_decode_buckets": 39.9,
-    "test_ring_attention": 39.9, "test_gemma": 40.5,
-    "test_embeddings": 44.4, "test_audit": 50.6, "test_lm_server": 52.1,
-    "test_decode_hotpath": 36.0,  # ISSUE 6 decode hot path: donation/
-    # aliasing invariant, kv flag, int4 KV, paged flash-decode kernel,
-    # quantized byte accounting — certified inside the tier-1 budget
-    "test_spec_buckets": 36.0,  # speculative x bucketed composition
-    # parity (greedy + sampled, rung crossings, draft-pool lockstep)
-    "test_constrained_hotpath": 56.2,  # ISSUE 16 on-device grammar
-    # walk: constrained mixed/overlap token parity vs convoy (dense/
-    # paged/bucketed, mid-decode admission, rung crossing, multi-
-    # grammar pool, EOS-at-accept), overlap ordering + crow reset,
-    # prefix-cache DFA-state adoption, loud spec rejection, transition-
-    # pool LRU golden — measured cost (nine parity server builds
-    # dominate); sorts with the heavy serving integration modules
-    "test_overlap": 50.0,  # ISSUE 12 overlap & fusion: mixed-step token
-    # parity vs the convoy path (dense/paged/bucketed/speculative,
-    # sampled draw-for-draw, mid-decode admission), double-buffer
-    # ordering, fused-sampling logprob agreement, the un-aliased-mixed
-    # gate test, int8-weights serving parity + byte pricing — certified
-    # inside the tier-1 budget with the serving modules
-    "test_control": 55.0,  # ISSUE 13 fleet front door: policy/admission
-    # goldens, REPLICA/ROUTER protocol tables + buggy fixtures, KV
-    # handoff pack/adopt parity (incl. paged), router e2e over real
-    # gRPC (round trip, round-robin spread, dedup affinity join,
-    # streaming, disaggregated prefill/decode parity, shed, drain-to-
-    # sibling) — in-process replicas; certified inside the tier-1
-    # budget with the serving-resilience modules
-    "test_kvtier": 46.0,  # ISSUE 15 fleet KV tier: radix trie goldens
-    # (insert/lookup/COW/leaf-LRU/refcount protection), block wire
-    # codec incl. int4 nibble packing, lease machine + TTL + shm nonce
-    # proof + PRO002-both-directions, radix admission parity (COW /
-    # full-hit / retire-insert / row-backoff), cross-pool export/adopt
-    # parity with block accounting, donor-death fallback with zero
-    # divergence and zero leaks, kvput inbox TTL sweep, worker control
-    # ops — certified inside the tier-1 budget with the serving modules
-    "test_chaos": 42.0,  # ISSUE 8 chaos + self-healing: injection
-    # goldens, supervisor restart/backoff/crash-loop (tiny python -c
-    # children), requeue token parity, drain-under-load, circuit
-    # breaker, corrupted-checkpoint fallback — certified inside the
-    # tier-1 budget with the other serving-resilience modules
-    "test_serving_spec": 53.1, "test_multilora": 57.9,
-    "test_sliding_window": 58.0, "test_tp_pp": 59.9,
-    "test_speculative": 62.4, "test_paged": 64.2,
-    "test_models_llama": 67.1, "test_mixtral": 79.4, "test_1f1b": 88.0,
-    "test_graft_entry": 224.6,
-}
-_DEFAULT_COST_S = 25.0  # unmeasured/new modules slot in mid-pack
-
-
-def pytest_collection_modifyitems(config, items):
-    """Cheapest-module-first execution order (see _MODULE_COST_S).
-    Stable sort keyed per MODULE, so tests within a module stay
-    contiguous and in their original relative order (module-scoped
-    fixtures and intra-module contracts are untouched)."""
-    def key(item):
-        # nodeid, not item.module: never forces an import here
-        mod = item.nodeid.split("::", 1)[0].rsplit("/", 1)[-1]
-        if mod.endswith(".py"):
-            mod = mod[:-3]
-        return (_MODULE_COST_S.get(mod, _DEFAULT_COST_S), mod)
-
-    items.sort(key=key)
-
-
 def _rss_gb() -> float:
     """Current resident set of this process, GB. Non-Linux hosts fall
     back to getrusage peak RSS; an unreadable RSS returns inf so the
@@ -238,7 +71,7 @@ def _drop_compile_caches_between_modules():
     Gated on actual resident memory (default 3 GB, override with
     DNN_TEST_CLEAR_RSS_GB; 0 = clear every module, the old behavior):
     an unconditional clear forced every module to recompile the shared
-    helpers, costing the time-budgeted tier-1 run a large slice of its
+    helpers, costing the tier-1 run a large slice of its
     window for protection that is only needed near the memory ceiling."""
     yield
     threshold = float(os.environ.get("DNN_TEST_CLEAR_RSS_GB", "3"))

@@ -23,7 +23,7 @@ from dnn_tpu.analysis.lint import lint_paths, lint_source
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG_DIR = os.path.join(REPO_ROOT, "dnn_tpu")
-BASELINE = os.path.join(PKG_DIR, "analysis", "baseline.json")
+ACCEPTED = os.path.join(PKG_DIR, "analysis", "baseline.json")
 
 
 def rules_of(src):
@@ -485,7 +485,7 @@ def test_sanitizer_catches_planted_blocking_callback():
     assert s.max_lag_s >= 0.2
     with pytest.raises(AssertionError, match="blocked the loop"):
         s.assert_bounded(0.1)
-    # the breach landed in the flight ring (the probes' artifact)
+    # the breach landed in the flight ring (what /debugz serves)
     from dnn_tpu import obs
 
     evs = obs.flight.recorder().events(kind="loop_lag")
@@ -534,7 +534,7 @@ def test_sanitizer_event_cap_bounds_ring_traffic():
 
 def test_sanitizer_endpoint_readback():
     """read_endpoint reads installed/breaches/max_lag off a served
-    /debugz — the exact readback the chaos/transport probes assert."""
+    /debugz."""
     from dnn_tpu import obs
     from dnn_tpu.analysis.sanitize import LoopLagSanitizer, read_endpoint
 
@@ -594,7 +594,7 @@ def test_serving_stack_con_clean_modulo_baseline():
                os.path.join(PKG_DIR, "runtime", "lm_server.py")]
     findings = lint_paths(targets, repo_root=REPO_ROOT)
     _report, proto = run_protocol_audit(REPO_ROOT)
-    entries = load_baseline(BASELINE)
+    entries = load_baseline(ACCEPTED)
     new, suppressed, _stale = diff_against_baseline(
         list(findings) + list(proto), entries)
     assert not new, "unbaselined findings:\n" + "\n".join(

@@ -184,7 +184,7 @@ def test_train_fault_seam_golden():
 def test_poison_batch_floats_only():
     # the nan directive's executor: float leaves drown, int leaves
     # (token batches) pass through untouched — the documented contract
-    # that forces the sentinel probe onto a float toy model
+    # that puts the sentinel test on a float toy model
     from dnn_tpu.train import poison_batch
 
     batch = {"tokens": np.arange(6, dtype=np.int32).reshape(2, 3),
@@ -299,17 +299,29 @@ def test_supervisor_recovers_killed_child():
         name="healthy", backoff_s=0.05, health_interval_s=0.05)
     sup.start()
     try:
-        time.sleep(0.3)
+        # start() launches synchronously: the first child is alive
+        first = sup.proc
+        assert first is not None and first.poll() is None
         sup.inject_kill()
-        t0 = time.monotonic()
-        while sup.restarts < 1 and time.monotonic() - t0 < 20:
+
+        def recovered():
+            # restarts is bumped BEFORE the replacement is spawned and
+            # the event recorded, so wait on what recovery means: a
+            # live process that is not the one that was killed, and
+            # the supervisor's own record of having restarted it
+            p = sup.proc
+            return (sup.restarts >= 1 and p.pid != first.pid
+                    and p.poll() is None
+                    and any(e["stage"] == "healthy" and e["pid"] == p.pid
+                            for e in flight.recorder().events(
+                                kind="supervisor_restart")))
+
+        deadline = time.monotonic() + 30
+        while not recovered() and time.monotonic() < deadline:
             time.sleep(0.05)
-        assert sup.restarts == 1
-        assert any(e["stage"] == "healthy" for e in
-                   flight.recorder().events(kind="supervisor_restart"))
-        # the replacement is a live, different process
-        time.sleep(0.2)
-        assert sup.proc.poll() is None
+        assert recovered(), (sup.restarts, sup.state, first.pid,
+                             sup.proc.pid, sup.proc.poll())
+        assert first.poll() is not None  # the killed child is gone
     finally:
         sup.stop()
 
@@ -602,6 +614,60 @@ def test_client_sheds_fast_when_open_and_rebuilds_channel():
     assert any(e["target"] == "127.0.0.1:59399" for e in
                flight.recorder().events(kind="channel_rebuild"))
     c.close()
+
+
+def test_channel_rebuild_spares_the_call_in_flight():
+    """A draining server answers UNAVAILABLE to what it will not admit;
+    two of those in a row make NodeClient replace its channel. The call
+    still running on the old channel finishes there — closing the
+    channel under it would cancel a request the server is serving."""
+    from concurrent import futures
+
+    from dnn_tpu.comm import wirecodec as wc
+    from dnn_tpu.comm.client import NodeClient
+    from dnn_tpu.comm.service import _handlers
+
+    admitted, release = threading.Event(), threading.Event()
+
+    class _Draining:
+        def SendTensor(self, request, context):
+            if not request.request_id.startswith("slow"):  # dl= / tr= tags follow
+                context.abort(grpc.StatusCode.UNAVAILABLE, "draining")
+            admitted.set()
+            assert release.wait(30)
+            return wc.TensorResponse(status="done")
+
+        HealthCheck = SendMessage = None  # never called here
+
+    server = grpc.server(futures.ThreadPoolExecutor(max_workers=4))
+    server.add_generic_rpc_handlers((_handlers(_Draining()),))
+    port = server.add_insecure_port("127.0.0.1:0")
+    server.start()
+    c = NodeClient(f"127.0.0.1:{port}", transport="grpc", breaker=False)
+    x, out = np.arange(4.0), {}
+
+    def slow():
+        try:
+            out["status"], _ = c.send_tensor(
+                x, request_id="slow", timeout=60.0, retries=0)
+        except grpc.RpcError as e:
+            out["error"] = e.code()
+
+    t = threading.Thread(target=slow)
+    t.start()
+    try:
+        assert admitted.wait(30)
+        for _ in range(c.rebuild_after):
+            with pytest.raises(grpc.RpcError) as ei:
+                c.send_tensor(x, request_id="late", timeout=10.0, retries=0)
+            assert ei.value.code() == grpc.StatusCode.UNAVAILABLE
+        assert c.channel_rebuilds == 1
+    finally:
+        release.set()
+        t.join(30)
+        c.close()
+        server.stop(0)
+    assert out == {"status": "done"}
 
 
 def test_wait_healthy_rides_channel_rebuild_to_late_server():

@@ -189,41 +189,59 @@ def step_programs(chip):
             _held_weight_shapes(prepared))
 
 
-def _lower_programs(chip, batchers):
-    """{name: compiled for the chip} of the named step programs of each
-    (batcher, names): every program is lowered from its first real call's
-    arguments while two short requests run through the batcher here on
-    the CPU."""
-    compiled = {}
+def first_calls(batchers, prompt_len=69):
+    """{name: (jitted program, the arguments of its first real call as
+    shapes)} of the named step programs of each (batcher, names), while
+    two requests of `prompt_len` tokens run through the batcher here on
+    the CPU: the programs the batcher really dispatches, with the
+    arguments it really passes (tests/test_benchmark_contract.py lowers
+    the same calls for their names and scopes)."""
+    calls = {}
 
-    def described(x):
+    def shape(x):
         if isinstance(x, (jax.Array, np.ndarray)):
             return jax.ShapeDtypeStruct(
-                x.shape, x.dtype, sharding=chip,
-                weak_type=getattr(x, "weak_type", False))
+                x.shape, x.dtype, weak_type=getattr(x, "weak_type", False))
         return x
 
-    def lower_first(b, name):
+    def record_first(b, name):
         fn = getattr(b, name)
 
         def call(*args):
-            if name not in compiled:
-                with pytest.MonkeyPatch.context() as m:
-                    m.setattr(jax, "default_backend", lambda: "tpu")
-                    compiled[name] = fn.lower(
-                        *jax.tree.map(described, args)).compile()
-                jax.clear_caches()  # drop the trace made under the patch
+            calls.setdefault(name, (fn, jax.tree.map(shape, args)))
             return fn(*args)
 
         setattr(b, name, call)
 
     for b, names in batchers:
         for name in names:
-            lower_first(b, name)
-        b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
+            record_first(b, name)
+        prompt = np.arange(1, prompt_len + 1, dtype=np.int32)
+        b.submit(prompt, max_new_tokens=2)
         b.step()
-        b.submit(np.arange(1, 70, dtype=np.int32), max_new_tokens=2)
+        b.submit(prompt, max_new_tokens=2)
         b.drain()
+    return calls
+
+
+def _lower_programs(chip, batchers):
+    """{name: compiled for the chip} of the named step programs of each
+    (batcher, names), each lowered from its first real call's arguments
+    (`first_calls`) with the backend answering "tpu"."""
+    def described(x):
+        if isinstance(x, jax.ShapeDtypeStruct):
+            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip,
+                                        weak_type=x.weak_type)
+        return x
+
+    calls = first_calls(batchers)
+    compiled = {}
+    jax.clear_caches()  # the traces the requests made with the kernels off
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        for name, (fn, args) in calls.items():
+            compiled[name] = fn.lower(*jax.tree.map(described, args)).compile()
+    jax.clear_caches()  # drop the traces made under the patch
     return compiled
 
 

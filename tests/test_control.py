@@ -3,8 +3,8 @@ KV handoff, and the router end to end.
 
 The e2e legs run REAL gRPC through an in-process router over
 in-process LM servers (start_lm_server_in_background) — the same wire
-path `node --route` serves, minus subprocesses (the fleet probe and
-`python -m dnn_tpu.control` own the real-subprocess shape). Policy,
+path `node --route` serves, minus subprocesses
+(`python -m dnn_tpu.control` owns the real-subprocess shape). Policy,
 admission, autoscaling and protocol checks are pure host goldens with
 injected signals."""
 
@@ -665,11 +665,11 @@ def test_router_drain_hands_queued_work_to_sibling(fleet, client):
     assert s2.batcher._next_rid > rid_before
     # the replica set noticed the drain (healthz 503s) — r0 leaves the
     # serving set within a few monitor ticks
-    deadline = time.monotonic() + 15
+    deadline = time.monotonic() + 60
     while time.monotonic() < deadline:
         if fleet["rset"].replicas["r0"].state != "serving":
             break
-        time.sleep(0.3)
+        time.sleep(0.1)
     assert fleet["rset"].replicas["r0"].state in ("draining", "dead")
     # ...and the router recorded sibling retries for the handed-back work
     assert obs.flight.recorder().events(kind="router_retry_sibling") \

@@ -2,8 +2,8 @@
 invariant, the kv=paged|dense serving flag, int4 quantized KV, the fused
 paged flash-decode kernel, and quantization-aware byte accounting.
 
-The perf claims live in benchmarks/decode_mbu_probe.py and STUDIES §11;
-this module pins the CORRECTNESS surface those claims stand on:
+This module pins the CORRECTNESS surface of the decode hot path (its
+speed is the chip benchmark's business, PERF.md):
 
   * every donated leaf of every decode-step program (dense f32/int8/
     int4, bucketed, paged, speculative) aliases an output, and the
@@ -387,14 +387,6 @@ def test_rows_write_multirow_gate_keeps_inactive_rows():
     # inactive slot: bitwise untouched everywhere
     np.testing.assert_array_equal(np.asarray(out["k"][1]),
                                   np.asarray(base_k[1]))
-
-
-def test_unroll_layers_token_parity(tiny):
-    cfg, prepared = tiny
-    prompt = np.arange(1, 13) % 89
-    t_scan, _ = _run(cfg, prepared, prompt)
-    t_unroll, _ = _run(cfg, prepared, prompt, unroll_layers=True)
-    np.testing.assert_array_equal(t_scan, t_unroll)
 
 
 # ----------------------------------------------------------------------

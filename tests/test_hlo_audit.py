@@ -1,6 +1,6 @@
 """Static HLO bytes audit (utils/hlo_audit.py): parser pins for both
-program-text formats, plus the decode-step regressions that answer
-BASELINE.md's long-context hypotheses on paper —
+program-text formats, plus the decode-step regressions that answer the
+long-context hypotheses of a dense cache on paper —
 
   (a) cache-sized TRANSPOSE: absent at the StableHLO level for every
       decode step (the program never demands a transposed cache copy);
@@ -65,8 +65,8 @@ def test_optimized_unbucketed_step_materializes_cache_scale_copies():
     """Hypothesis (b) on this host's backend: the compiled unbucketed
     decode step carries cache-scale copies (scan-carry materialization)
     — the structural 2x+ traffic multiplier the bucketed program bounds.
-    Count > 0 is the finding, not a bug: it is recorded in BASELINE.md
-    as the CPU-lowering answer to the 13%-MBU question."""
+    Count > 0 is the finding, not a bug: it is the CPU lowering's answer,
+    a proxy for the chip's."""
     (step_u, args_u), _, layer_alloc = _steps()
     out = H.audit_decode_step(step_u, args_u, layer_alloc, optimize=True)
     assert out["counts"].get("transpose", 0) == 0  # (a) stays dead

@@ -346,12 +346,14 @@ def test_kvlease_machine_clean_and_both_directions():
 
 
 def test_chaos_plan_gains_donor_kill_fault():
-    from dnn_tpu.chaos.plan import FaultPlan, standard_plan
+    from dnn_tpu.chaos.plan import Fault, FaultPlan
 
-    plan = standard_plan(donor_kill_at_s=12.0, donor_target="r0")
+    plan = FaultPlan(faults=(
+        Fault(kind="kill_stage", target="node2", at_s=15.0),
+        Fault(kind="kill_donor", target="r0", at_s=12.0)))
     kinds = [f.kind for f in plan.process_faults()]
     assert "kill_donor" in kinds
-    # schema roundtrip (the probe ships plans as JSON)
+    # schema roundtrip (plans ship as JSON)
     back = FaultPlan.from_dict(plan.to_dict())
     assert back == plan
     # the in-process migration fault parses too

@@ -520,19 +520,17 @@ def test_budget_window_buckets_evict_and_stay_exact():
     assert w._buckets == {} and w._n == 0 and w._bad == 0
 
 
-def test_peak_env_overrides_degrade_on_garbage(monkeypatch):
-    """DNN_TPU_PEAK_FLOPS=0 or garbage must read as 'unknown', not crash
-    every MFU consumer (the degrade-don't-crash env-knob rule)."""
+def test_peak_env_variables_are_ignored(monkeypatch):
+    """Nothing in the environment can state a peak: off a TPU it stays
+    unknown whatever the old override variables hold, so a CPU run
+    cannot report MFU or MBU under a device's name."""
     from dnn_tpu.utils import flops as F
 
-    monkeypatch.setenv("DNN_TPU_PEAK_FLOPS", "not a number")
-    assert F.device_peak_flops() is None  # cpu host, table miss
-    monkeypatch.setenv("DNN_TPU_PEAK_FLOPS", "0")
+    for name, value in (("PEAK_FLOPS", "1.25e11"), ("PEAK_HBM_BW", "8e11")):
+        monkeypatch.setenv("DNN_TPU_" + name, value)
     assert F.device_peak_flops() is None
-    monkeypatch.setenv("DNN_TPU_PEAK_HBM_BW", "-5")
     assert F.device_peak_hbm_bw() is None
-    monkeypatch.setenv("DNN_TPU_PEAK_FLOPS", "1.25e11")
-    assert F.device_peak_flops() == 1.25e11
+    assert F.mfu(1e9, 1000.0) is None and F.mbu(1e6, 1e6) is None
 
 
 def test_fleetz_not_yet_polled_reads_degraded():

@@ -245,11 +245,9 @@ def _round(srv, new_tokens=12):
 
 
 def test_phase_sum_covers_measured_wall(pool):
-    """The probe's coverage assertion in miniature: attributed seconds
-    vs an EXTERNAL wall clock around the round. The bound here is
-    loose (0.85) because this pool's sub-ms steps make the python loop
-    glue proportionally larger than the probe's asserted standard
-    config — the 0.95 floor is asserted by step_timeline_probe."""
+    """Attributed seconds against an EXTERNAL wall clock around the
+    round. The bound is loose (0.85) because this pool's sub-ms steps
+    make the python loop glue a large share of each step."""
     clock = StepClock(capacity=1024)
     pool.step_clock = clock
     try:

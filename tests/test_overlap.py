@@ -246,14 +246,6 @@ def test_eos_on_deferred_first_token(model):
 
 def test_interleave_validations(model):
     cfg, prepared = model
-    with pytest.raises(ValueError, match="allow_constraints"):
-        ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
-                          prompt_pad=8, prefill_chunk_tokens=8,
-                          allow_constraints=True)
-    with pytest.raises(ValueError, match="allow_constraints"):
-        ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
-                          prompt_pad=8, overlap=True,
-                          allow_constraints=True)
     with pytest.raises(ValueError, match="prefix cache"):
         ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
                           prompt_pad=8, prefill_chunk_tokens=8,
@@ -307,17 +299,17 @@ def test_audit_covers_mixed_step_programs():
 def test_audit_gate_fails_unaliased_mixed_variant(model):
     """The gate actually gates: the REAL mixed-step program re-jitted
     WITHOUT donation fails the donation-coverage check."""
-    from dnn_tpu.analysis.program import check_decode_program
+    from dnn_tpu.analysis.program import (
+        check_decode_program,
+        mixed_step_args,
+    )
 
     cfg, prepared = model
     b = ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
                           prompt_pad=8, prefill_chunk_tokens=8)
-    row = b._ilv_new_row()
-    chunk = jnp.zeros((1, 8), jnp.int32)
-    args = (b._decode_view, b._decode_view, b.cache, b.pos, b.tok,
-            b.active, b.keys, b._temp, b._topk, b._topp, b._minp,
-            b._rep, b._seen, b._bias, b._crow, b._ctable,
-            row, chunk, jnp.int32(0))
+    # the tuple the real audit lowers with: a signature change reaches
+    # this test through it
+    args = mixed_step_args(b, 8)
     elems = 2 * cfg.n_head * 64 * (cfg.n_embd // cfg.n_head)
     # HEAD's program passes...
     _, ok_findings = check_decode_program(

@@ -6,6 +6,7 @@ change), and the three-program compile contract intact."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dnn_tpu.models import gpt
 from dnn_tpu.runtime.serving import ContinuousBatcher
@@ -129,3 +130,52 @@ def test_compile_count_unchanged():
     assert srv._prefill_chunk._cache_size() == 1
     assert srv._prefill_finish._cache_size() == 1
     assert srv._decode._cache_size() == 1
+
+
+# ----------------------------------------------------------------------
+# prefix-cache counters + gauge on /metrics
+# ----------------------------------------------------------------------
+
+def test_prefix_counters_and_hit_ratio_gauge():
+    cfg = gpt.GPTConfig(block_size=32, vocab_size=64, n_layer=1,
+                        n_head=1, n_embd=16)
+    prepared = gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg),
+                                   cfg)
+    srv = ContinuousBatcher(cfg, prepared, slots=2, max_len=24,
+                            prompt_pad=4, prefix_cache=2)
+    p = np.arange(1, 9, dtype=np.int32)  # 2 full chunks
+    srv.submit(p, max_new_tokens=2)
+    srv.drain()
+    assert (srv.prefix_hits, srv.prefix_misses) == (0, 1)
+    assert srv._prefix_ratio_read() == 0.0
+    srv.submit(p, max_new_tokens=2)  # identical prompt: full-chunk hit
+    srv.drain()
+    assert (srv.prefix_hits, srv.prefix_misses) == (1, 1)
+    assert srv._prefix_ratio_read() == pytest.approx(0.5)
+    # the gauge is registered (weakly) under the public name
+    assert "dnn_tpu_prefix_hit_ratio" in srv._obs_gauges
+    assert srv._obs_gauges["dnn_tpu_prefix_hit_ratio"]() == \
+        pytest.approx(0.5)
+    # capacity 2: a different 2-chunk prompt's inserts evict
+    before = srv.prefix_evictions
+    srv.submit(np.arange(20, 28, dtype=np.int32), max_new_tokens=2)
+    srv.drain()
+    assert srv.prefix_evictions > before
+    # the registry counters moved with the attrs
+    from dnn_tpu import obs
+
+    m = obs.metrics()
+    if m is not None:
+        snap = m.snapshot()["counters"]
+        assert snap.get("serving.prefix_misses_total", 0) >= 1
+        assert snap.get("serving.prefix_evictions_total", 0) >= 1
+
+
+def test_prefix_ratio_gauge_absent_without_cache():
+    cfg = gpt.GPTConfig(block_size=32, vocab_size=64, n_layer=1,
+                        n_head=1, n_embd=16)
+    prepared = gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg),
+                                   cfg)
+    srv = ContinuousBatcher(cfg, prepared, slots=1, max_len=16,
+                            prompt_pad=4)
+    assert "dnn_tpu_prefix_hit_ratio" not in srv._obs_gauges
