@@ -337,12 +337,15 @@ def kernel_parity(cfg, *, slots=SLOTS, block_len=16, max_len=1024,
                   prompt_pad=64, seed=0):
     """The two attention kernels of the serving path at the served shapes,
     each against its jnp reference on random bf16 inputs: the paged
-    decode kernel over a shuffled block table, and the chunked-prefill
-    kernel at a runtime start position. Returns max abs differences."""
+    decode kernel over a shuffled block table (the pool's rows stored
+    128 lanes wide, as the daemon's pool stores them), and the
+    chunked-prefill kernel at a runtime start position. Returns max abs
+    differences."""
     import jax
     import jax.numpy as jnp
 
     from dnn_tpu.ops.pallas import cached_attention as ca
+    from dnn_tpu.runtime.paged_kvcache import lane_padded
 
     h, d = cfg.n_head, cfg.n_embd // cfg.n_head
     nb = max_len // block_len
@@ -350,8 +353,11 @@ def kernel_parity(cfg, *, slots=SLOTS, block_len=16, max_len=1024,
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
     bf = jnp.bfloat16
     q = jax.random.normal(ks[0], (slots, h, 1, d), bf)
-    kp = jax.random.normal(ks[1], (n_blocks, h, block_len, d), bf)
-    vp = jax.random.normal(ks[2], (n_blocks, h, block_len, d), bf)
+    lanes = [(0, 0)] * 3 + [(0, lane_padded(d) - d)]
+    kp = jnp.pad(jax.random.normal(ks[1], (n_blocks, h, block_len, d), bf),
+                 lanes)
+    vp = jnp.pad(jax.random.normal(ks[2], (n_blocks, h, block_len, d), bf),
+                 lanes)
     tables = (jax.random.permutation(ks[3], n_blocks - 1)[: slots * nb] + 1
               ).reshape(slots, nb).astype(jnp.int32)
     pos = jnp.asarray([3, block_len, max_len // 2 + 5, max_len - 1][:slots],

@@ -67,10 +67,13 @@ Prometheus scraper or a plain curl can watch the serving stack:
     GET  /profilez     capture spool + auto-trigger arm state (JSON)
     POST /profilez?ms=N            capture N ms of device+host profile
                        into the bounded spool (obs/profile.py); returns
-                       the capture path + Perfetto-loadable trace files
+                       the capture path + its .xplane.pb files
     POST /profilez?auto=1&threshold_ms=T[&ms=N]   arm the auto trigger:
                        capture the next decode step after one exceeds
                        T ms (LM daemon only); ?auto=0 disarms
+                       (&perfetto=1 on either: also export the
+                       Perfetto-loadable *.trace.json.gz, which takes
+                       several times as long as the capture itself)
     POST /drainz       connection draining (LM daemon): stop admission,
                        finish in-flight decodes, hand queued work back
                        retriable, then exit — 202 + drain state JSON;
@@ -446,8 +449,11 @@ class MetricsHTTPServer:
                         self._send(404, "no profiler attached\n",
                                    "text/plain; charset=utf-8")
                         return
-                    from dnn_tpu.obs.profile import ProfilerBusy, trace_files
+                    from dnn_tpu.obs.profile import (
+                        ProfilerBusy, trace_files, xplane_files)
 
+                    perfetto = q.get("perfetto", ["0"])[0] not in (
+                        "0", "false", "off")
                     if "auto" in q:
                         arm = q["auto"][0] not in ("0", "false", "off")
                         if not arm:
@@ -457,7 +463,7 @@ class MetricsHTTPServer:
                         try:
                             outer._profiler.arm_auto(
                                 float(q.get("threshold_ms", ["100"])[0]),
-                                float(q.get("ms", ["0"])[0]))
+                                float(q.get("ms", ["0"])[0]), perfetto)
                         except ValueError as e:
                             self._send(400, str(e) + "\n",
                                        "text/plain; charset=utf-8")
@@ -471,13 +477,14 @@ class MetricsHTTPServer:
                                    "text/plain; charset=utf-8")
                         return
                     try:
-                        path = outer._profiler.capture(ms)
+                        path = outer._profiler.capture(ms, perfetto)
                     except ProfilerBusy as e:
                         self._send(409, str(e) + "\n",
                                    "text/plain; charset=utf-8")
                         return
                     self._send_json(200, {
                         "capture": path, "ms": ms,
+                        "xplane_files": xplane_files(path),
                         "trace_files": trace_files(path)})
                 except BrokenPipeError:
                     pass

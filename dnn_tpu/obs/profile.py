@@ -3,8 +3,9 @@
 The span collector (obs/trace.py) answers "where did this request's
 milliseconds go" at host granularity; this module answers the next
 question — "what was the DEVICE doing" — with a real `jax.profiler`
-capture (device + host timeline, Perfetto-loadable `*.trace.json.gz`
-under the capture dir) taken from a RUNNING server:
+capture (device + host timeline: the profiler's `*.xplane.pb` under
+the capture dir, which TensorBoard / xprof load and
+`jax.profiler.ProfileData` reads) taken from a RUNNING server:
 
   * `POST /profilez?ms=N` on the obs HTTP endpoint (obs/http.py)
     captures N milliseconds into a bounded spool directory and returns
@@ -13,6 +14,14 @@ under the capture dir) taken from a RUNNING server:
     trigger: the LM batcher worker captures the next decode step after
     one exceeds T milliseconds (the p99-breach post-mortem: you never
     have to be watching when the slow step happens);
+  * `&perfetto=1` on either asks for the Perfetto-loadable
+    `*.trace.json.gz` beside the `.xplane.pb` (what
+    `obs/timeline.analyze()` reads). It is not written unasked because
+    it is most of what ending a capture costs: the JSON export gzips
+    every event, 57-70 s of the 76-90 s that `stop_trace` took after a
+    4 s capture of the loaded daemon (0.5 M device events), against
+    19-20 s to collect the events (my chip runs, PR 29) — a minute in
+    which a core of the serving host compresses text;
   * `annotation(name)` / `step_annotation(step)` are the obs-gated host
     span annotations (jax.profiler.TraceAnnotation) that make captures
     readable — the serving runtime writes every batcher step and its
@@ -46,13 +55,15 @@ import glob
 import json
 import os
 import shutil
+import socket
 import threading
 import time
 from typing import Iterator, Optional
 
 __all__ = ["ProfilerBusy", "capture", "capture_step", "spool_dir",
-           "list_captures", "annotation", "annotation_ctx", "open_span",
-           "close_span", "step_annotation", "Profiler"]
+           "list_captures", "xplane_files", "trace_files", "annotation",
+           "annotation_ctx", "open_span", "close_span", "step_annotation",
+           "Profiler"]
 
 
 class ProfilerBusy(RuntimeError):
@@ -85,8 +96,15 @@ def _prune(root: str, keep: int):
         shutil.rmtree(old, ignore_errors=True)
 
 
+def xplane_files(capture_dir: str) -> list:
+    """The profiler's own artifacts inside one capture dir."""
+    return sorted(glob.glob(os.path.join(
+        capture_dir, "plugins", "profile", "*", "*.xplane.pb")))
+
+
 def trace_files(capture_dir: str) -> list:
-    """The Perfetto-loadable artifacts inside one capture dir."""
+    """The Perfetto-loadable artifacts inside one capture dir: those of
+    a capture made with `perfetto`, else none."""
     return sorted(glob.glob(os.path.join(
         capture_dir, "plugins", "profile", "*", "*.trace.json.gz")))
 
@@ -130,10 +148,10 @@ def _step_counter() -> Optional[int]:
 
 def _write_meta(path: str, meta: dict):
     """Sidecar `meta.json` at the capture root: monotonic begin/end
-    (perf_counter), wall-clock bounds, the step-counter range, and the
-    backend: the armed window `timeline.analyze()` reads idle time
-    inside. Best-effort: an unwritable spool loses the meta, never the
-    trace."""
+    (perf_counter), wall-clock bounds, the step-counter range, the
+    backend, and how long ending the capture took (`stop_s`): the
+    armed window `timeline.analyze()` reads idle time inside.
+    Best-effort: an unwritable spool loses the meta, never the trace."""
     try:
         with open(os.path.join(path, "meta.json"), "w") as f:
             json.dump(meta, f)
@@ -141,11 +159,45 @@ def _write_meta(path: str, meta: dict):
         pass
 
 
+def _stop_trace(path: str, perfetto: bool):
+    """End the session that `jax.profiler.start_trace(path)` began and
+    write its `.xplane.pb` where the profiler's own export puts it
+    (`plugins/profile/<run>/<host>.xplane.pb`). `jax.profiler.stop_trace`
+    has one form, which also exports the Perfetto JSON (module
+    docstring: most of its time); the session object it ends hands over
+    the collected XSpace without it. Where this JAX keeps that session
+    elsewhere, or the JSON is asked for, `stop_trace` does the whole."""
+    import jax
+
+    state = None
+    if not perfetto:
+        try:
+            from jax._src.profiler import _profile_state as state
+        except ImportError:
+            pass
+    sess = getattr(state, "profile_session", None)
+    if not hasattr(sess, "stop"):
+        jax.profiler.stop_trace()
+        return
+    with state.lock:
+        try:
+            xspace = sess.stop()
+        finally:
+            state.reset()
+    run = os.path.join(path, "plugins", "profile",
+                       time.strftime("%Y_%m_%d_%H_%M_%S"))
+    os.makedirs(run, exist_ok=True)
+    with open(os.path.join(run, socket.gethostname() + ".xplane.pb"),
+              "wb") as f:
+        f.write(xspace)
+
+
 @contextlib.contextmanager
-def _traced(capture_root: Optional[str], keep: int) -> Iterator[str]:
-    """Exclusive start_trace/stop_trace around the body; yields the
-    capture dir. Raises ProfilerBusy instead of queueing — a capture
-    request against a busy profiler wants a fast 409, not a pile-up."""
+def _traced(capture_root: Optional[str], keep: int,
+            perfetto: bool = False) -> Iterator[str]:
+    """Exclusive start_trace/stop around the body; yields the capture
+    dir. Raises ProfilerBusy instead of queueing — a capture request
+    against a busy profiler wants a fast 409, not a pile-up."""
     global _capturing
     import jax
 
@@ -174,7 +226,8 @@ def _traced(capture_root: Optional[str], keep: int) -> Iterator[str]:
             meta["perf_end"] = time.perf_counter()
             meta["t_end_unix"] = time.time()
             meta["step_end"] = _step_counter()
-            jax.profiler.stop_trace()
+            _stop_trace(path, perfetto)
+            meta["stop_s"] = time.perf_counter() - meta["perf_end"]
             _write_meta(path, meta)
             try:
                 keep_n = int(os.environ["DNN_TPU_OBS_PROFILE_KEEP"])
@@ -186,27 +239,31 @@ def _traced(capture_root: Optional[str], keep: int) -> Iterator[str]:
 
 
 def capture(duration_ms: float = 1000.0, *,
-            capture_root: Optional[str] = None, keep: int = 8) -> str:
+            capture_root: Optional[str] = None, keep: int = 8,
+            perfetto: bool = False) -> str:
     """Capture `duration_ms` of whatever the process is doing (the
     serving worker keeps stepping; this thread just sleeps inside the
-    trace). Returns the capture dir; flight-records the capture."""
+    trace). Returns the capture dir; flight-records the capture.
+    `perfetto` adds the `*.trace.json.gz` (module docstring)."""
     from dnn_tpu.obs import flight
 
-    with _traced(capture_root, keep) as path:
+    with _traced(capture_root, keep, perfetto) as path:
         time.sleep(max(0.0, float(duration_ms)) / 1e3)
     flight.record("profile_capture", path=path, ms=float(duration_ms))
     return path
 
 
 def capture_step(fn, *, capture_root: Optional[str] = None,
-                 keep: int = 8, extra_s: float = 0.0):
+                 keep: int = 8, extra_s: float = 0.0,
+                 perfetto: bool = False):
     """Capture exactly one call of `fn` (the auto-trigger's "next decode
     step") instead of a wall-clock window; `extra_s` extends the trace
     past the call. Returns (capture_dir, fn's result).
 
     NOTE the capture wall time is dominated by profiler init + trace
-    EXPORT (stop_trace writes the json.gz + xplane.pb: seconds for a
-    first capture), during which the calling thread
+    EXPORT (ending it collects the events and writes the xplane.pb, and
+    with `perfetto` the json.gz: seconds for a first capture), during
+    which the calling thread
     (the batcher worker, for the auto trigger) is stalled: requests
     queue behind an auto capture. That is the accepted cost of an
     operator-armed post-mortem, not a steady-state tax.
@@ -226,7 +283,7 @@ def capture_step(fn, *, capture_root: Optional[str] = None,
     t0 = time.perf_counter()
     ran, out, step_err, step_ms, path = False, None, None, None, None
     try:
-        with _traced(capture_root, keep) as path:
+        with _traced(capture_root, keep, perfetto) as path:
             t1 = time.perf_counter()
             try:
                 out = fn()
@@ -364,15 +421,16 @@ class Profiler:
         self.keep = keep
         self._arm_target = arm_target
 
-    def capture(self, duration_ms: float) -> str:
+    def capture(self, duration_ms: float, perfetto: bool = False) -> str:
         return capture(duration_ms, capture_root=self.capture_root,
-                       keep=self.keep)
+                       keep=self.keep, perfetto=perfetto)
 
     @property
     def can_arm(self) -> bool:
         return self._arm_target is not None
 
-    def arm_auto(self, threshold_ms: float, duration_ms: float = 0.0):
+    def arm_auto(self, threshold_ms: float, duration_ms: float = 0.0,
+                 perfetto: bool = False):
         """Arm the next-slow-step auto capture. duration_ms > 0 extends
         the capture past the triggering step by that wall window (0 =
         exactly one step)."""
@@ -383,6 +441,7 @@ class Profiler:
             "threshold_s": float(threshold_ms) / 1e3,
             "extra_s": max(0.0, float(duration_ms)) / 1e3,
             "capture_root": self.capture_root, "keep": self.keep,
+            "perfetto": bool(perfetto),
         }
 
     def disarm(self):

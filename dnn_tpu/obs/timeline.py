@@ -56,6 +56,9 @@ This is the intra-step instrument, in two connected halves:
     step.per_sec / step.last_wall_ms, and the cumulative totals
     step.steps_total / step.tokens_advanced_total /
     step.phase_seconds_total{phase=} / step.admit_seconds_total{part=},
+    over a paged KV pool step.attn_live_blocks_total /
+    step.attn_table_blocks_total (the blocks the paged decode kernel
+    walks, of those the slots' tables have),
     and for a model with experts moe.layer_calls_total /
     moe.assignments_total / moe.active_experts_total /
     moe.peak_expert_rows_total{program=decode|prefill} — what the expert
@@ -287,6 +290,8 @@ class StepClock:
         self.admit_seconds_total = {p: 0.0 for p in ADMIT_PARTS}
         # expert layers (note_moe): per program, MOE_SERIES in order
         self.moe_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
+        # a paged KV pool's blocks (note_attn_blocks): live, in the tables
+        self.attn_blocks_total = [0, 0]
         self._pending_moe: "Optional[Dict[str, list]]" = None
         self._gauges_registered = False
         self._registry = registry
@@ -339,6 +344,13 @@ class StepClock:
                     else 0.0
             return read
 
+        # registered with the first note_attn_blocks: a dense cache shows
+        # no step_attn_* series
+        self._attn_gauges = {
+            "step.attn_live_blocks_total":
+                _weak_total("attn_blocks_total", 0),
+            "step.attn_table_blocks_total":
+                _weak_total("attn_blocks_total", 1)}
         # registered with the first note_moe: a model without experts
         # shows no moe_* series
         self._moe_registered = False
@@ -429,6 +441,23 @@ class StepClock:
         tot["install"] += parts[2]
         if not self._gauges_registered:
             self._register_gauges()
+
+    def note_attn_blocks(self, live: int, table: int):
+        """One decode step over a paged pool: `live` blocks hold a
+        position some slot attends (sum over the slots of ceil(positions
+        / block_len)), of the `table` entries the slots' block tables
+        have (slots x blocks a slot). The paged decode kernel's work
+        follows the first; a grid over table entries would follow the
+        second. Cumulative step.attn_{live,table}_blocks_total, on
+        /metrics with the first note — a dense cache has none."""
+        if not _obs.enabled():
+            return
+        tot = self.attn_blocks_total
+        if not tot[1]:
+            self._gauges.update(self._attn_gauges)
+            self._gauges_registered = False  # re-register with them
+        tot[0] += live
+        tot[1] += table
 
     def note_moe(self, program: str, layer_calls: int, stats):
         """What the expert layers of one executed program cost:
@@ -824,7 +853,9 @@ def find_trace_file(path: str) -> str:
             or glob.glob(os.path.join(path, "*.json.gz"))
             or glob.glob(os.path.join(path, "*.json")))
         if not hits:
-            raise ValueError(f"no trace json found under {path}")
+            raise ValueError(
+                f"no trace json found under {path}: a capture holds one "
+                "when it was asked for (POST /profilez?...&perfetto=1)")
         return hits[-1]
     return path
 

@@ -419,15 +419,29 @@ def _decode_call(q, k, v, pos1d, ks, vs, *, block_s, interpret):
 # view of every slot's blocks each step (PagedKV.gather_view) — a full
 # logical-cache copy in HBM before attention even starts, which is the
 # one place the paged layout pays bandwidth the dense layout doesn't.
-# This kernel removes the materialization: the slot's block TABLE rides
-# scalar prefetch, and each grid step's index map chases the table to DMA
-# the PHYSICAL block straight from the pool into VMEM. Two clamps do the
-# live-length work:
-#   * logical blocks past the slot's live limit re-target the last live
-#     block (repeated index -> the Pallas pipeline skips the copy), so
-#     per-step traffic scales with each slot's ACTUAL context — the pool
-#     analog of _decode_call's position clamp;
-#   * columns past `pos` are masked inside the online softmax as usual.
+# This kernel removes the materialization, and does work in proportion to
+# what each slot HOLDS, not to what its table could hold:
+#   * the pool's leaves stay in HBM (`memory_space=ANY`) and the grid is
+#     over SLOTS alone. Inside a slot's grid step a loop walks its live
+#     blocks 0 .. pos // block_len, G at a time: the table entry comes
+#     from SMEM (scalar prefetch), each physical block is DMAed straight
+#     from the pool into a double-buffered VMEM scratch, and the next
+#     group — the next SLOT's first, when this is the slot's last — is in
+#     flight while this one is attended, so the copies run on through the
+#     slots' edges. A table entry past `pos` costs nothing — no grid
+#     step, no DMA, no compare;
+#   * one online-softmax update covers a whole group: G = 128 //
+#     block_len blocks (at least 128 positions a step, capped by the
+#     table), read from the shapes of the call — `_paged_group`;
+#   * columns past `pos` (the tail of the last live block, and the rest
+#     of a last group that is not full) are masked as usual;
+#   * with `new`, the slot's own row is placed in the VMEM copy of the
+#     block that holds `pos` before that block is attended, and that one
+#     block goes back to the pool, which is the kernel's aliased output:
+#     the pool is updated by the kernel that reads it and XLA never lays
+#     a hand on it. A gated-off slot (a retired slot keeps a stale `pos`
+#     and a stale table) is an EMPTY slot: it reads no block, places and
+#     writes nothing, and its output rows are zeros.
 # int8 pools stream their 1-byte payload with the per-(position, head)
 # scales folded in VMEM, exactly like the dense decode kernel. (int4
 # pools stay on the einsum: sub-byte VMEM loads are not wired.)
@@ -460,8 +474,10 @@ def _reference_paged_step(q, pools, tables, pos, layer, new):
     """paged_decode_attention's whole-pool forms in plain jnp: place
     `new`'s rows at [layer, block, :, row] (gated-off slots at junk
     block 0, row 0), then the oracle on that layer. Returns what the
-    kernel does: the attention output, and with `new` the pools too."""
+    kernel does: the attention output (zeros for a gated-off slot), and
+    with `new` the pools too."""
     bp = pools[0].shape[-2]
+    gate = None
     if new is not None:
         *rows, gate = new
         blk = jnp.take_along_axis(tables, (pos // bp)[:, None], axis=1)[:, 0]
@@ -472,101 +488,207 @@ def _reference_paged_step(q, pools, tables, pos, layer, new):
     ks, vs = scales or (None, None)
     out = reference_paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
                                            vs=vs)
-    return out if new is None else (out, *pools)
+    if gate is None:
+        return out
+    return (jnp.where(gate[:, None, None, None], out, 0.0), *pools)
 
 
-def _paged_decode_kernel(*refs, scale, block_len, quant, write):
-    """Scalar prefetch: pos, table, layer (and with `write` the gate).
-    Inputs: q, the K/V (and scale) blocks, and with `write` this step's
-    rows. Outputs: the attention rows, and with `write` the block that
-    holds position `pos` with the row placed."""
+def _paged_group(block_len, nb_max):
+    """Blocks attended in one online-softmax update: as many as make 128
+    positions, and never more than the table has."""
+    return max(1, min(128 // block_len, nb_max))
+
+
+def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole):
+    """One grid step = one slot. Scalar prefetch: pos, table, layer (and
+    with `write` the gate). Inputs: q, the pool's leaves where they lie
+    in HBM, and with `write` this step's rows. Outputs: the attention
+    rows, and with `write` the pool's leaves again (aliased). Scratch: a
+    two-deep buffer of G blocks a leaf, the DMA semaphores (one a buffer,
+    one for the write-back), which of the two buffers the slot's first
+    group is in, and G running softmax states a query row (state g
+    attends every G-th block, and the G are merged when the slot's blocks
+    are through)."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     n_pool = 4 if quant else 2
-    pos_ref = refs[0]
+    pos_ref, tab_ref, lay_ref = refs[:3]
     gate_ref = refs[3] if write else None
-    refs = refs[4 if write else 3:]
-    q_ref, pool_refs, refs = refs[0], refs[1:1 + n_pool], refs[1 + n_pool:]
+    q_ref, *refs = refs[4 if write else 3:]
+    pools, refs = refs[:n_pool], refs[n_pool:]
     if write:
-        new_refs, refs = refs[:n_pool], refs[n_pool:]
-        out_refs, refs = refs[1:1 + n_pool], refs[:1] + refs[1 + n_pool:]
-    o_ref, m_scr, l_scr, acc_scr = refs
+        # the pool is read where it is written: through the aliased
+        # output (on the chip the same memory as the input; under
+        # interpret=True the copy the outputs start from)
+        new_refs, o_ref, refs = refs[:n_pool], refs[n_pool], refs[n_pool + 1:]
+        pools, refs = refs[:n_pool], refs[n_pool:]
+    else:
+        o_ref, *refs = refs
+    bufs, (sem, first_ref, m_scr, l_scr, acc_scr) = \
+        refs[:n_pool], refs[n_pool:]
 
-    si = pl.program_id(1)
-    ns = pl.num_programs(1)
+    bi, n_slots = pl.program_id(0), pl.num_programs(0)
+    hk, r, d = q_ref.shape[1:]
+    group, bp = bufs[0].shape[1], bufs[0].shape[3]
+    rows = hk * r  # query rows of the slot; each has `group` states
 
-    @pl.when(si == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+    def live_blocks(slot_i):
+        """Of slot `slot_i`: how many blocks hold a position it attends
+        (none where the gate is off, whatever its stale `pos` says), and
+        the block that holds `pos`."""
+        last = jnp.minimum(pos_ref[slot_i] // bp, nb_max - 1)
+        if not write:
+            return last + 1, last
+        return jnp.where(gate_ref[slot_i] != 0, last + 1, 0), last
 
-    pos = pos_ref[pl.program_id(0)]
-    live = si * block_len <= pos
-    if write:
-        gate = gate_ref[pl.program_id(0)] != 0
+    def at(blk):  # a physical block of each leaf, where it lies
+        return (lay_ref[0], blk) if whole else (blk,)
 
-    @pl.when(live)
-    def _step():
-        q = q_ref[0].astype(jnp.float32)   # (Hk, R, d)
-        # the block's leaves as the softmax reads them: K, V (Hk,
-        # block_len, d) and an int8 pool's scales (Hk, block_len)
-        blk = [r[0].astype(jnp.float32) for r in pool_refs]
+    def group_dma(slot_i, gi, buf_i, go):
+        """Start, or wait for, the copies of the live blocks of slot
+        `slot_i`'s group gi into buffer `buf_i`: nothing is copied for a
+        block past the slot's last."""
+        n_live, _ = live_blocks(slot_i)
+
+        def block(g, _):
+            blk = tab_ref[slot_i * nb_max + gi * group + g]
+            for pool, buf in zip(pools, bufs):
+                getattr(pltpu.make_async_copy(
+                    pool.at[at(blk)], buf.at[buf_i, g], sem.at[buf_i]),
+                    go)()
+
+        jax.lax.fori_loop(
+            0, jnp.clip(n_live - gi * group, 0, group), block, None)
+
+    @pl.when(bi == 0)
+    def _first():
+        # a group's unread tail must hold numbers: 0 x it has to be 0
+        for buf in bufs:
+            buf[...] = jnp.zeros_like(buf)
+        first_ref[0] = 0
+        group_dma(0, 0, 0, "start")
+
+    pos = pos_ref[bi]
+    n_live, last = live_blocks(bi)
+    n_groups = (n_live + group - 1) // group
+    # the slot's group gi is in buffer (first + gi) % 2. Its group 0 is
+    # on its way already: whoever finishes before a slot — its own last
+    # group, or an empty slot's whole step — starts the next slot's first
+    # copies, so the DMA engine runs on through the slots' edges
+    first = first_ref[0]
+    first_ref[0] = jax.lax.rem(first + n_groups, 2)
+
+    def start_next_slot(buf_i):
+        @pl.when(bi + 1 < n_slots)
+        def _():
+            group_dma(bi + 1, 0, buf_i, "start")
+
+    @pl.when(n_groups == 0)
+    def _empty():  # a gated-off slot: nothing read, nothing placed
+        start_next_slot(first)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    def attend(gi, _):
+        buf_i = jax.lax.rem(first + gi, 2)
+
+        @pl.when(gi + 1 < n_groups)
+        def _():
+            group_dma(bi, gi + 1, 1 - buf_i, "start")
+
+        @pl.when(gi + 1 == n_groups)
+        def _():
+            start_next_slot(1 - buf_i)
+
+        group_dma(bi, gi, buf_i, "wait")
+
         if write:
-            # the step's own row goes into the block that holds `pos`
-            # before it is attended, and that one block goes back out
-            # through the aliased pool: the pool is updated by the
-            # kernel that reads it and XLA never lays a hand on it. A
-            # gated-off slot places nothing: its block goes, as it was,
-            # to junk block 0 (the output's index map)
-            last = si == jnp.minimum(pos // block_len, ns - 1)
-            row = jnp.where(last & gate, pos % block_len, -1)
-            here = {shp: jax.lax.broadcasted_iota(jnp.int32, shp, 1) == row
-                    for shp in {b.shape for b in blk}}
-            blk = [jnp.where(here[b.shape], n[0].astype(jnp.float32), b)
-                   for b, n in zip(blk, new_refs)]
+            # the group that holds `pos` is the slot's last: its row goes
+            # into the buffered block before the block is attended, and
+            # that one block goes back to the pool meanwhile
+            holds_pos = gi == n_groups - 1
+            g = last - gi * group
 
-            @pl.when(last)
+            def write_back(go):
+                blk = tab_ref[bi * nb_max + last]
+                for pool, buf in zip(pools, bufs):
+                    getattr(pltpu.make_async_copy(
+                        buf.at[buf_i, g], pool.at[at(blk)], sem.at[2]), go)()
+
+            @pl.when(holds_pos)
             def _place():
-                for o, b in zip(out_refs, blk):
-                    o[0] = b.astype(o.dtype)
+                for buf, new in zip(bufs, new_refs):
+                    blk = buf[buf_i, g].astype(jnp.float32)  # (Hk, bp[, d])
+                    here = jax.lax.broadcasted_iota(
+                        jnp.int32, blk.shape, 1) == pos % bp
+                    buf[buf_i, g] = jnp.where(
+                        here, new[0].astype(jnp.float32), blk
+                    ).astype(buf.dtype)
+                write_back("start")
 
-        k, v = blk[:2]
-        hk, r, d = q.shape
+        # the group's leaves as the softmax reads them, one batch entry a
+        # (block, head): K, V (G*Hk, bp, d), an int8 pool's scales
+        # (G*Hk, bp)
+        k, v, *scales = [
+            buf[buf_i].reshape(group * hk, *buf.shape[3:]) for buf in bufs]
+        # bfloat16 rows meet a bfloat16 pool (or int8, exact in bfloat16)
+        # on the MXU as they are: the products and the float32 sums are
+        # those of the float32 form
+        narrow = (q_ref.dtype == jnp.bfloat16
+                  and k.dtype in (jnp.bfloat16, jnp.int8))
+        cdt = jnp.bfloat16 if narrow else jnp.float32
+        q = jnp.tile(q_ref[0].astype(cdt), (group, 1, 1))  # (G*Hk, R, d)
         s = jax.lax.dot_general(
-            q, k, (((2,), (2,)), ((0,), (0,))),
+            q, k.astype(cdt), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )  # (Hk, R, block_len)
+        )  # (G*Hk, R, bp)
         if quant:
-            s = s * blk[2][:, None, :]
-        s = s * scale
-        s2 = s.reshape(hk * r, block_len)
-        cols = jax.lax.broadcasted_iota(
-            jnp.int32, (hk * r, block_len), 1) + si * block_len
-        s2 = jnp.where(cols <= pos, s2, _NEG_BIG)
+            s = s * scales[0][:, None, :]
+        s2 = (s * scale).reshape(group * rows, bp)
+        # row n of s2 is block n // rows of the group
+        shape = (group * rows, bp)
+        cols = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // rows
+                + gi * group) * bp + jax.lax.broadcasted_iota(
+                    jnp.int32, shape, 1)
+        seen = cols <= pos
+        s2 = jnp.where(seen, s2, _NEG_BIG)
 
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s2 - m_new)
+        # a block past the last is all masked: its state stays empty
+        p = jnp.where(seen, jnp.exp(s2 - m_new), 0.0)
+        pv = p.reshape(group * hk, r, bp)
         if quant:
-            pv = p.reshape(hk, r, block_len) * blk[3][:, None, :]
-        else:
-            pv = p.reshape(hk, r, block_len)
+            pv = pv * scales[1][:, None, :]
         out = jax.lax.dot_general(
-            pv, v, (((2,), (1,)), ((0,), (0,))),
+            pv, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32,
-        )
+        )  # (G*Hk, R, d)
         l_new = l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + out.reshape(hk * r, d)
+        acc_scr[...] = acc_scr[...] * alpha + out.reshape(group * rows, d)
         m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
         l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
 
-    @pl.when(si == ns - 1)
-    def _finish():
-        hk, r, d = q_ref.shape[1:]
-        o_ref[0] = (acc_scr[...] / l_scr[:, :1]).reshape(hk, r, d) \
-            .astype(o_ref.dtype)
+        if write:
+            pl.when(holds_pos)(lambda: write_back("wait"))
+
+    @pl.when(n_groups > 0)
+    def _live():
+        m_scr[...] = jnp.full_like(m_scr, _NEG_BIG)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+        jax.lax.fori_loop(0, n_groups, attend, None)
+        # merge the `group` states of each query row
+        states = [pl.ds(g * rows, rows) for g in range(group)]
+        m = functools.reduce(jnp.maximum,
+                             [m_scr[at_g, :1] for at_g in states])
+        l = acc = 0.0
+        for at_g in states:
+            w = jnp.exp(m_scr[at_g, :1] - m)
+            l = l + w * l_scr[at_g, :1]
+            acc = acc + w * acc_scr[at_g, :]
+        o_ref[0] = (acc / l).reshape(hk, r, d).astype(o_ref.dtype)
 
 
 @jax.named_scope("attn.paged_decode")
@@ -586,36 +708,41 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
 
     With `layer` (a traced int32 scalar) kp/vp/ks/vs are the WHOLE
     pool, one more leading (L,) axis: the layer rides scalar prefetch
-    beside `pos` and the table and leads each block index, so the
+    beside `pos` and the table and leads each block's address, so the
     kernel reads layer `layer`'s blocks in place and nobody slices the
     pool (the decode loop's form, paged_kvcache.scan_blocks).
 
     With `new` = (k (B, Hk, 1, D), v[, ks (B, Hk, 1), vs], gate (B,)) —
     this step's rows as the pool stores them, whole-pool form only — the
     kernel also WRITES: each slot's row goes into the block that holds
-    position pos[b] before it is attended (a gated-off slot places
-    nothing, and its block goes as it was to junk block 0), and the
-    pools come back updated through aliased outputs:
-    returns (out, kp, vp[, ks, vs]). The step then touches the pool with
-    nothing but this call.
+    position pos[b] before it is attended, and the pools come back
+    updated through aliased outputs: returns (out, kp, vp[, ks, vs]).
+    The step then touches the pool with nothing but this call. A
+    gated-off slot is empty whatever its `pos` and table say: no block
+    of it is read or written, and its output rows are zeros.
 
     Dispatches to the Pallas kernel on TPU; otherwise runs the
     reference. `interpret=True` forces the kernel in interpreter mode
-    (CPU CI runs the real table-chasing index maps)."""
+    (CPU CI runs the real table chase and block copies)."""
     quant = ks is not None
     pools = [kp, vp] + ([ks.astype(jnp.float32), vs.astype(jnp.float32)]
                         if quant else [])
     if new is not None and layer is None:
         raise ValueError("the kernel places rows in the whole pool only: "
                          "pass layer= with new=")
-    on_tpu = jax.default_backend() == "tpu"
-    if interpret is None:
-        if not on_tpu:
-            if layer is None:
-                return reference_paged_decode_attention(
-                    q, kp, vp, tables, pos, ks=ks, vs=vs)
-            return _reference_paged_step(q, pools, tables, pos, layer, new)
+    if interpret is None and jax.default_backend() == "tpu":
         interpret = False
+    # the chip's compiler copies a block out of a leaf only in whole
+    # 128-lane rows ("Slice shape along dimension 3 must be aligned to
+    # tiling (128), but is 16"): rows stored narrower than a tile, and an
+    # int8 pool's (Hk, bp) scale blocks unless bp tiles, take the
+    # gather-and-einsum form there
+    lowers = all(x.shape[-1] % 128 == 0 for x in pools)
+    if interpret is None or not (interpret or lowers):
+        if layer is None:
+            return reference_paged_decode_attention(
+                q, kp, vp, tables, pos, ks=ks, vs=vs)
+        return _reference_paged_step(q, pools, tables, pos, layer, new)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -625,41 +752,21 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, d - d_q)])
     nb_max = tables.shape[1]
     bp = kp.shape[-2]
+    group = _paged_group(bp, nb_max)
     write = new is not None
+    whole = layer is not None
     kernel = functools.partial(
-        _paged_decode_kernel, scale=1.0 / (d_q ** 0.5), block_len=bp,
-        quant=quant, write=write,
+        _paged_decode_kernel, scale=1.0 / (d_q ** 0.5), nb_max=nb_max,
+        quant=quant, write=write, whole=whole,
     )
 
-    # the block table chases through scalar prefetch: logical block si of
-    # slot bi lives at physical pool block tab[bi * nb_max + si], and
-    # blocks past the live limit re-target the last LIVE logical block
-    # (repeated physical index -> no DMA). A whole pool is entered at
-    # its layer: one squeezed leading block index, the same kernel body.
-    whole = layer is not None
-    lead = (None,) if whole else ()
+    def rows_of_slot(x):  # grid step bi sees x[bi]
+        return pl.BlockSpec((1,) + x.shape[1:],
+                            lambda bi, *_: (bi,) + (0,) * (x.ndim - 1))
 
-    def _pool_map(bi, si, p, tab, lay, *_):
-        blk = tab[bi * nb_max + jnp.minimum(si, p[bi] // bp)]
-        return ((lay[0],) if whole else ()) + (blk, 0, 0, 0)
-
-    # the written block: the one that holds `pos`, whatever the grid
-    # step — one write-back per slot, when the slot's steps are over
-    def _out_map(bi, si, p, tab, lay, gate):
-        blk = tab[bi * nb_max + jnp.minimum(p[bi] // bp, nb_max - 1)]
-        return (lay[0], jnp.where(gate[bi] != 0, blk, 0), 0, 0, 0)
-
-    def _row_map(bi, si, *_):
-        return (bi, 0, 0, 0)
-
-    def cut(index_map, n):  # a scale leaf has no D axis
-        return lambda *a: index_map(*a)[:n]
-
-    qspec = pl.BlockSpec((1, hk, r, d), _row_map)
-    shapes = [lead + (1, hk, bp, d)] * 2 + [lead + (1, hk, bp)] * 2
-    in_specs = [qspec] + [
-        pl.BlockSpec(shp, cut(_pool_map, len(shp)))
-        for shp in shapes[:len(pools)]]
+    qspec = rows_of_slot(q)
+    in_place = pl.BlockSpec(memory_space=pl.ANY)  # a leaf, left in HBM
+    in_specs = [qspec] + [in_place] * len(pools)
     out_specs, out_shape = qspec, jax.ShapeDtypeStruct((b, hk, r, d),
                                                        jnp.float32)
     scalars = [pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
@@ -669,24 +776,28 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     if write:
         *rows, gate = new
         scalars.append(gate.astype(jnp.int32))
-        in_specs += [pl.BlockSpec((1,) + x.shape[1:],
-                                  cut(_row_map, x.ndim)) for x in rows]
-        out_specs = [qspec] + [
-            pl.BlockSpec(shp, cut(_out_map, len(shp)))
-            for shp in shapes[:len(pools)]]
+        in_specs += [rows_of_slot(x) for x in rows]
+        out_specs = [qspec] + [in_place] * len(pools)
         out_shape = [out_shape] + [
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools]
         # operand numbers count the scalars: 4 of them, then q
         aliases = {5 + i: 1 + i for i in range(len(pools))}
+    n_states = group * hk * r
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
-        grid=(b, nb_max),
+        grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((hk * r, 128), jnp.float32),  # running row max
-            pltpu.VMEM((hk * r, 128), jnp.float32),  # running row sum
-            pltpu.VMEM((hk * r, d), jnp.float32),    # output accumulator
+            # two buffers of `group` blocks a leaf: (Hk, bp, d) rows,
+            # (Hk, bp) scales
+            *(pltpu.VMEM((2, group) + x.shape[1 + whole:], x.dtype)
+              for x in pools),
+            pltpu.SemaphoreType.DMA((3,)),
+            pltpu.SMEM((1,), jnp.int32),  # the slot's first buffer
+            pltpu.VMEM((n_states, 128), jnp.float32),  # running row max
+            pltpu.VMEM((n_states, 128), jnp.float32),  # running row sum
+            pltpu.VMEM((n_states, d), jnp.float32),    # output accumulator
         ],
     )
     out = pl.pallas_call(
@@ -695,7 +806,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         out_shape=out_shape,
         input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")
+            # slot 0 clears the buffers, and the slots share them
+            dimension_semantics=("arbitrary",)
         ),
         interpret=interpret,
         name="paged_decode_attention",

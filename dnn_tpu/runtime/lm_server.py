@@ -613,7 +613,8 @@ class _BatcherWorker(threading.Thread):
             try:
                 path, stepped = capture_step(
                     b.step, capture_root=ap.get("capture_root"),
-                    keep=ap.get("keep", 8), extra_s=ap.get("extra_s", 0.0))
+                    keep=ap.get("keep", 8), extra_s=ap.get("extra_s", 0.0),
+                    perfetto=ap.get("perfetto", False))
                 log.info("auto-profile captured slow-step follow-up to %s",
                          path)
                 return stepped

@@ -34,10 +34,18 @@ takes the layer (`PagedKV.at_layer`), so no layer's slice is ever cut
 out of the pool or written back. Attention gathers the slot's blocks
 into a (B, H, S_max, D) view and runs the identical masked einsum — the
 reference math is the dense codec's, so token parity is exact — or, with
-the kernel on, ops/pallas/cached_attention.paged_decode_attention chases
-the table through its scalar-prefetch index maps, reads blocks straight
-from the pool and places the step's own rows in them; the einsum path is
-the correctness baseline.
+the kernel on, ops/pallas/cached_attention.paged_decode_attention takes
+one grid step a SLOT and walks that slot's live blocks inside it: table
+entries from SMEM, each physical block copied straight from the pool
+(which stays in HBM) into a double-buffered VMEM scratch, 128 positions'
+worth of blocks an online-softmax update, the step's own row placed in
+the block that holds `pos` and that block written back. Its work follows
+what the slots HOLD — a table entry past `pos` and a gated-off slot cost
+nothing — which is what `step.attn_live_blocks_total` over
+`step.attn_table_blocks_total` measures (obs/timeline.StepClock). The
+einsum path is the correctness baseline, and what the chip runs for the
+shapes the kernel's block copies cannot take (an int8 pool's scale blocks
+unless block_len fills 128 lanes).
 
 No counterpart exists in the reference framework (its only state is a
 per-request activation, /root/reference/node.py:45-105 — no cache at
@@ -227,9 +235,9 @@ class PagedKV:
 
     `use_kernel` routes attend_rows through the fused paged flash-decode
     kernel (ops/pallas/cached_attention.paged_decode_attention): the
-    slot's block table rides scalar prefetch and each grid step DMAs its
-    PHYSICAL block straight from the pool — no gather_view
-    materialization, per-step traffic clamped at each slot's live
+    slot's block table rides scalar prefetch and the kernel copies each
+    live PHYSICAL block straight from the pool — no gather_view
+    materialization, work and traffic in proportion to each slot's live
     length. True/"interpret" are unconditional; "auto" engages it only
     on TPU against pools whose per-slot logical length reaches
     kvcache.AUTO_KERNEL_MIN_S (the dense codecs' length-aware policy).

@@ -2657,14 +2657,24 @@ class ContinuousBatcher:
         # and a second genexpr sweep would be paid every step too.
         live = 0
         n_act = 0
+        blocks = 0  # of a paged pool that hold a live position
+        bp = self._block_len if self._paged else 0
         for r in self._slot_req:
             if r is not None:
-                live += r["prompt_len"] + len(r["emitted"])
+                n = r["prompt_len"] + len(r["emitted"])
+                live += n
                 n_act += 1
+                if bp:
+                    blocks += -(-n // bp)
         if live > self._kv_live_hw:
             self._kv_live_hw = live
         if n_act > self._active_hw:
             self._active_hw = n_act
+        if bp and self.step_clock is not None:
+            # what the paged decode kernel walks, against what its tables
+            # could hold (step.attn_{live,table}_blocks_total)
+            self.step_clock.note_attn_blocks(
+                blocks, self.slots * (self.max_len // bp))
         # batched registry feed (fields documented at construction): a
         # bucket switch flushes first so the whole batch shares one
         # dispatch-counter key; an idle pool flushes so totals are

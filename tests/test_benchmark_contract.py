@@ -322,3 +322,63 @@ def test_program_is_the_one_the_engine_dispatches(tmp_path, config, program):
     dispatched = {"jit_" + e.params["name"] for e in jaxpr.eqns
                   if "jaxpr" in e.params and "name" in e.params}
     assert program in dispatched, dispatched
+
+
+# ----------------------------------------------------------------------
+# the paged kernel's counters: what they count, a step at a time
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("kv", ["paged", "dense"])
+def test_attn_block_counters_follow_the_live_blocks(monkeypatch, kv):
+    """`step_attn_live_blocks_total` advances by the blocks that hold a
+    live position (sum over the slots of ceil(positions / block_len)) and
+    `step_attn_table_blocks_total` by slots x blocks a slot, each decode
+    step of a paged pool; a dense cache writes neither."""
+    import jax
+    import numpy as np
+
+    from dnn_tpu import obs
+    from dnn_tpu.models import gpt
+    from dnn_tpu.obs.timeline import StepClock
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+    from dnn_tpu.utils.metrics import Metrics, render_prometheus
+
+    monkeypatch.setenv("DNN_TPU_OBS", "1")
+    if not obs.enabled():
+        pytest.skip("observability gate is off in this process")
+    cfg = gpt.GPTConfig(vocab_size=89, block_size=128, n_layer=2, n_head=2,
+                        n_embd=32)
+    slots, max_len, bp = 3, 64, 8
+    srv = ContinuousBatcher(
+        cfg, gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg), cfg),
+        slots=slots, max_len=max_len, prompt_pad=16, kv=kv,
+        **({"block_len": bp} if kv == "paged" else {}))
+    reg = Metrics()
+    srv.step_clock = clk = StepClock(registry=reg)
+
+    def series():
+        return dict(line.rsplit(" ", 1)
+                    for line in render_prometheus(reg).splitlines()
+                    if line and not line.startswith("#"))
+
+    srv.submit(np.arange(1, 6), max_new_tokens=20)    # 5 positions
+    srv.submit(np.arange(1, 18), max_new_tokens=20)   # 17: three blocks
+    want_live = want_table = 0
+    for _ in range(6):
+        srv.step()
+        held = [r["prompt_len"] + len(r["emitted"])
+                for r in srv._slot_req if r is not None]
+        assert len(held) == 2
+        want_live += sum(-(-n // bp) for n in held)
+        want_table += slots * (max_len // bp)
+        if kv == "paged":
+            assert clk.attn_blocks_total == [want_live, want_table]
+    got = series()
+    if kv == "paged":
+        assert float(got["step_attn_live_blocks_total"]) == want_live
+        assert float(got["step_attn_table_blocks_total"]) == want_table
+        assert 0 < want_live < want_table
+    else:
+        assert clk.attn_blocks_total == [0, 0]
+        assert not [k for k in got if k.startswith("step_attn_")]
+        assert "step_steps_total" in got

@@ -63,45 +63,52 @@ def _compile(chip, fn, *shapes):
 
 
 # (slots, kv heads, query rows per kv head, head dim): GPT-2 as the smoke
-# serves it, and a GQA shape with 128-wide heads
+# serves it, a GQA shape with 128-wide heads, and the two configurations
+# the benchmark's cells serve at 16 slots (GPT-2 Large; OLMoE)
 GPT2 = (4, 12, 1, 64)
 GQA = (4, 8, 4, 128)
+LARGE = (16, 20, 1, 64)
+OLMOE = (16, 16, 1, 128)
 BLOCK_LEN, CTX = 16, 1024
 
+# (shape, pool dtype, block_len, positions a slot): the pool holds its rows
+# 128 lanes wide (paged_kvcache.lane_padded), as the daemon's does. An
+# int8 pool's (Hk, block_len) scale blocks go through the kernel only where
+# block_len fills the lanes (`test_paged_kernel_leaves_what_it_cannot_copy`)
+PAGED = [(GPT2, F32, 16, CTX), (GPT2, BF16, 16, CTX), (GPT2, I8, 128, CTX),
+         (GQA, BF16, 16, CTX), (LARGE, BF16, 16, 1024),
+         (OLMOE, BF16, 16, 4096)]
 
-@pytest.mark.parametrize("shape,dtype", [
-    (GPT2, F32), (GPT2, BF16), (GPT2, I8), (GQA, BF16)])
-def test_paged_decode_kernel_compiles(chip, shape, dtype):
+
+def _paged_call(shape, dtype, bp, ctx, *, whole, n_layer=3, width=None):
+    """(fn, argument shapes) of one paged_decode_attention call: per
+    layer and read-only, or (`whole`) the decode loop's form — the whole
+    (L, n_blocks, ...) pool entered at a layer that rides scalar prefetch,
+    the step's rows placed by the kernel and the pools handed back
+    through aliased outputs. `width`: the pool's row width, where it is
+    not the daemon's."""
+    from dnn_tpu.runtime.paged_kvcache import lane_padded
+
     b, hk, r, d = shape
-    nb = CTX // BLOCK_LEN
-    pool = ((b * nb + 1, hk, BLOCK_LEN, d), dtype)
-    scales = ((b * nb + 1, hk, BLOCK_LEN), F32)
-    quant = dtype == I8
-    qdt = BF16 if quant else dtype
-
-    def fn(q, kp, vp, tables, pos, *ksvs):
-        ks, vs = ksvs if quant else (None, None)
-        return ca.paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
-                                         vs=vs, interpret=False)
-
-    _compile(chip, fn, ((b, hk, r, d), qdt), pool, pool,
-             ((b, nb), jnp.int32), ((b,), jnp.int32),
-             *([scales, scales] if quant else []))
-
-
-@pytest.mark.parametrize("shape,dtype", [
-    (GPT2, BF16), (GPT2, I8), (GQA, BF16)])
-def test_paged_decode_kernel_compiles_on_the_whole_pool(chip, shape, dtype):
-    """The decode loop's form: the whole (L, n_blocks, ...) pool entered at
-    a layer that rides scalar prefetch, the step's rows placed by the
-    kernel and the pools handed back through aliased outputs."""
-    b, hk, r, d = shape
-    nb, n_layer = CTX // BLOCK_LEN, 3
-    pool = ((n_layer, b * nb + 1, hk, BLOCK_LEN, d), dtype)
+    nb = ctx // bp
+    width = width or lane_padded(d)
+    lead = (n_layer, b * nb + 1) if whole else (b * nb + 1,)
+    pool = (lead + (hk, bp, width), dtype)
     scales = (pool[0][:-1], F32)
     quant = dtype == I8
     leaves = [pool, pool] + ([scales, scales] if quant else [])
-    rows = [((b, hk, 1, d), dtype)] * 2 + (
+    q = ((b, hk, r, d), BF16 if quant else dtype)
+    tables, pos = ((b, nb), jnp.int32), ((b,), jnp.int32)
+
+    if not whole:
+        def fn(q, tables, pos, kp, vp, *ksvs):
+            ks, vs = ksvs if quant else (None, None)
+            return ca.paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
+                                             vs=vs, interpret=False)
+
+        return fn, [q, tables, pos, *leaves]
+
+    rows = [((b, hk, 1, width), dtype)] * 2 + (
         [((b, hk, 1), F32)] * 2 if quant else [])
 
     def fn(q, tables, pos, layer, gate, *rest):
@@ -111,13 +118,59 @@ def test_paged_decode_kernel_compiles_on_the_whole_pool(chip, shape, dtype):
             q, kp, vp, tables, pos, ks=ks, vs=vs, layer=layer,
             new=(*rest[len(leaves):], gate), interpret=False)
 
-    compiled = _compile(
-        chip, fn, ((b, hk, r, d), BF16), ((b, nb), jnp.int32),
-        ((b,), jnp.int32), ((), jnp.int32), ((b,), jnp.bool_),
-        *leaves, *rows)
+    return fn, [q, tables, pos, ((), jnp.int32), ((b,), jnp.bool_),
+                *leaves, *rows]
+
+
+def _kernel_grids(fn, shapes):
+    """The grid of every pallas_call of fn's jaxpr."""
+    jaxpr = jax.make_jaxpr(fn)(*(jax.ShapeDtypeStruct(s, d)
+                                 for s, d in shapes))
+    return [tuple(e.params["grid_mapping"].grid) for e in jaxpr.eqns
+            if e.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED)
+def test_paged_decode_kernel_compiles(chip, shape, dtype, bp, ctx):
+    fn, shapes = _paged_call(shape, dtype, bp, ctx, whole=False)
+    _compile(chip, fn, *shapes)
+
+
+@pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED[1:])
+def test_paged_decode_kernel_compiles_on_the_whole_pool(chip, shape, dtype,
+                                                        bp, ctx):
+    fn, shapes = _paged_call(shape, dtype, bp, ctx, whole=True)
+    compiled = _compile(chip, fn, *shapes)
     # the pools are updated where they lie: nothing of a pool's size is
     # allocated beside the arguments
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+
+
+@pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED[-2:],
+                         ids=["gpt2-large", "olmoe"])
+@pytest.mark.parametrize("whole", [False, True], ids=["read", "write"])
+def test_paged_kernel_grid_is_over_slots_alone(shape, dtype, bp, ctx, whole):
+    """ISSUE 29: one grid step a SLOT, whatever its table could hold (64
+    entries for GPT-2 Large, 256 for OLMoE): the live blocks are walked
+    inside the step."""
+    fn, shapes = _paged_call(shape, dtype, bp, ctx, whole=whole)
+    assert _kernel_grids(fn, shapes) == [(shape[0],)]
+
+
+@pytest.mark.parametrize("dtype,width", [(I8, 128), (BF16, 64)],
+                         ids=["int8-scales", "narrow-rows"])
+def test_paged_kernel_leaves_what_it_cannot_copy(chip, dtype, width):
+    """The chip's compiler copies a block out of a leaf only in whole
+    128-lane rows ("Slice shape along dimension 3 must be aligned to tiling
+    (128), but is 16"): an int8 pool's (Hk, 16) scale blocks, and rows
+    stored narrower than a tile, take the gather-and-einsum form on the
+    chip — no kernel, and a program that compiles."""
+    fn, shapes = _paged_call(GPT2, dtype, BLOCK_LEN, CTX, whole=False,
+                             width=width)
+    assert _kernel_grids(fn, shapes) == []
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    assert "tpu_custom_call" not in jax.jit(fn).lower(
+        *args).compile().as_text()
 
 
 @pytest.mark.parametrize("shape", [GPT2, GQA])

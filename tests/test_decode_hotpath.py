@@ -347,6 +347,142 @@ def test_paged_kernel_on_the_whole_pool(layer, quant):
         np.testing.assert_array_equal(g[others], before[others])
 
 
+def _paged_step_case(key, *, pos, gate=None, bp=16, nb=4, r=1, d=16,
+                     width=None, quant=False, layers=1, layer=0,
+                     tables=None):
+    """One call of the kernel (interpret mode) on a random whole pool
+    against the plain-jnp form of the same call: read-only without
+    `gate`, with the step's rows placed under it. -> (got, want, the
+    pools before, tables): `got` / `want` are (out, *pools) with a gate
+    and (out,) without. `width` stores the rows lane-padded."""
+    from dnn_tpu.ops.pallas.cached_attention import (
+        _reference_paged_step,
+        paged_decode_attention,
+    )
+
+    B, Hk, W = len(pos), 2, width or d
+    NB = B * nb + 1
+    if tables is None:  # every slot its own blocks
+        tables = 1 + np.random.RandomState(len(pos) + nb).permutation(
+            NB - 1).reshape(B, nb)
+    tables = jnp.asarray(tables, jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
+    lead = (layers, NB)
+    pad = [(0, 0)] * 4 + [(0, W - d)]
+
+    def rows_of(i, shape):
+        x = jax.random.normal(jax.random.fold_in(key, i), shape)
+        if quant:
+            x = jnp.round(x * 40).astype(jnp.int8)
+        return jnp.pad(x, pad[-x.ndim:])
+
+    pools = [rows_of(i, (*lead, Hk, bp, d)) for i in (1, 2)]
+    rows = [rows_of(i, (B, Hk, 1, d)) for i in (3, 4)]
+    if quant:
+        pools += [jax.random.uniform(jax.random.fold_in(key, i),
+                                     (*lead, Hk, bp)) + 0.5 for i in (5, 6)]
+        rows += [jax.random.uniform(jax.random.fold_in(key, i),
+                                    (B, Hk, 1)) + 0.5 for i in (7, 8)]
+    q = jax.random.normal(jax.random.fold_in(key, 9), (B, Hk, r, d))
+    new = None if gate is None else (*rows, jnp.asarray(gate))
+    ks, vs = pools[2:] or (None, None)
+    got = paged_decode_attention(q, *pools[:2], tables, pos, ks=ks, vs=vs,
+                                 layer=jnp.int32(layer), new=new,
+                                 interpret=True)
+    want = _reference_paged_step(q, pools, tables, pos, jnp.int32(layer),
+                                 new)
+    if gate is None:
+        got, want = (got,), (want,)
+    return got, want, pools, tables
+
+
+def _assert_step_matches(got, want):
+    """The attention rows within the oracle's tolerance; the pools the
+    oracle's bit for bit off junk block 0."""
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(want[0]),
+                               rtol=2e-5, atol=2e-5)
+    for g, w in zip(got[1:], want[1:]):
+        np.testing.assert_array_equal(np.asarray(g)[:, 1:],
+                                      np.asarray(w)[:, 1:])
+
+
+# 20 blocks of 16 a slot, walked 8 at a time (8, 8 and 4): the first
+# position, a block's last and the next block's first, a group's last and
+# the next group's first, the table's last
+@pytest.mark.parametrize("pos", [0, 15, 16, 127, 128, 319])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
+def test_paged_kernel_at_the_edges_of_blocks_and_groups(pos, quant, write):
+    got, want, _, _ = _paged_step_case(
+        jax.random.PRNGKey(pos), pos=[pos, 40], nb=20, quant=quant,
+        gate=[True, True] if write else None)
+    _assert_step_matches(got, want)
+
+
+@pytest.mark.parametrize("bp", [8, 16, 32, 128])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("r", [1, 4])
+def test_paged_kernel_block_lengths_and_query_rows(bp, quant, r):
+    """Every block length groups by its own rule (16, 8, 4 and 1 blocks
+    an update), on 3 blocks a slot — fewer than a group, or not a
+    multiple of one."""
+    from dnn_tpu.ops.pallas.cached_attention import _paged_group
+
+    nb = 3
+    assert _paged_group(bp, nb) == min(128 // bp, nb)
+    got, want, _, _ = _paged_step_case(
+        jax.random.PRNGKey(bp + r), pos=[bp * nb - 1, bp + 1, 0], bp=bp,
+        nb=nb, r=r, quant=quant, layers=2, layer=1,
+        gate=[True, True, True])
+    _assert_step_matches(got, want)
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("r", [1, 4])
+def test_paged_kernel_on_lane_padded_rows(quant, r):
+    """64-wide heads stored 128 lanes wide (paged_kvcache.lane_padded):
+    q is padded to the stored width and the result cut back."""
+    got, want, _, _ = _paged_step_case(
+        jax.random.PRNGKey(7), pos=[37, 5], d=64, width=128, r=r,
+        quant=quant, gate=[True, False])
+    assert got[0].shape[-1] == 64
+    _assert_step_matches(got, want)
+
+
+@pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+def test_paged_kernel_full_slot_among_empty_ones(layer, quant):
+    """One slot at the table's last position, one short one, and two
+    gated-off slots that kept a large stale `pos` and a stale table — the
+    FULL slot's own block ids, as a retired slot's table points at blocks
+    since handed to another request. The gated-off slots are empty: zeros
+    out, nothing of theirs read or placed, so the pool is the oracle's
+    off junk block 0 (the full slot's blocks hold exactly its own row)
+    and the live slots' rows are what they are without the others."""
+    nb, bp = 20, 16
+    own = 1 + np.arange(4 * nb).reshape(4, nb)
+    tables = np.stack([own[0], own[0], own[2], own[0]])
+    pos, gate = [nb * bp - 1, 300, 21, 77], [True, False, True, False]
+    key = jax.random.PRNGKey(11)
+    got, want, before, _ = _paged_step_case(
+        key, pos=pos, gate=gate, nb=nb, quant=quant, layers=3, layer=layer,
+        tables=tables)
+    _assert_step_matches(got, want)
+    out = np.asarray(got[0])
+    assert (out[[1, 3]] == 0).all() and np.isfinite(out).all()
+    live = [0, 2]
+    alone, _, _, _ = _paged_step_case(
+        key, pos=pos, gate=[True, True, True, True], nb=nb, quant=quant,
+        layers=3, layer=layer, tables=own)
+    np.testing.assert_array_equal(out[live], np.asarray(alone[0])[live])
+    for g, b in zip(got[1:], before):
+        g, b = np.asarray(g), np.asarray(b)
+        changed = {tuple(i[:2]) for i in np.argwhere(
+            (g != b).reshape(*g.shape[:2], -1).any(-1))} - {(layer, 0)}
+        assert changed == {(layer, int(tables[s, pos[s] // bp]))
+                           for s in live}
+
+
 def test_paged_kernel_serving_parity(tiny):
     """attn_kernel="interpret" on a paged pool runs the REAL kernel
     inside the decode loop — token-identical to the einsum pool."""

@@ -15,6 +15,7 @@ utils.tracing shim honoring the obs gate."""
 
 import gzip
 import json
+import os
 import subprocess
 import sys
 import threading
@@ -402,7 +403,7 @@ def test_profilez_auto_trigger_captures_annotated_step(lm_v2_server):
     # the trace-viewer JSON exporter's 1M-event cap cannot drop the
     # annotation events)
     req = urllib.request.Request(
-        base + "/profilez?auto=1&threshold_ms=0", method="POST")
+        base + "/profilez?auto=1&threshold_ms=0&perfetto=1", method="POST")
     armed = json.load(urllib.request.urlopen(req, timeout=30))
     assert armed["armed"]["threshold_ms"] == 0
     c = NodeClient("127.0.0.1:59561")
@@ -483,7 +484,30 @@ def test_concurrent_metrics_and_profilez_scrape_under_load(lm_v2_server):
     assert len(oks) >= 1  # at least one capture succeeded
     assert all(r == 409 for r in results if not isinstance(r, dict))
     for r in oks:
-        assert r["trace_files"], r  # Perfetto artifact exists
+        assert r["xplane_files"], r  # the capture's artifact exists
+
+
+def test_profilez_writes_the_perfetto_json_only_when_asked(lm_v2_server):
+    # ending a capture collects the events and writes the .xplane.pb;
+    # the Perfetto JSON export (most of stop_trace's time on a loaded
+    # daemon) is made for &perfetto=1 alone
+    base = f"http://127.0.0.1:{lm_v2_server.metrics_server.port}"
+
+    def post(query):
+        req = urllib.request.Request(base + "/profilez?ms=50" + query,
+                                     method="POST")
+        return json.load(urllib.request.urlopen(req, timeout=60))
+
+    plain, asked = post(""), post("&perfetto=1")
+    assert plain["capture"] != asked["capture"]
+    for r in (plain, asked):
+        assert r["xplane_files"] and all(
+            os.path.getsize(f) > 0 for f in r["xplane_files"]), r
+        assert os.path.isfile(os.path.join(r["capture"], "meta.json"))
+    assert plain["trace_files"] == []
+    assert asked["trace_files"], asked
+    with gzip.open(asked["trace_files"][0]) as f:
+        assert json.load(f)["traceEvents"]
 
 
 def test_statusz_without_watchdog_reports_worker(tiny_gpt):
