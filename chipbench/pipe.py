@@ -108,4 +108,6 @@ def run(cell, *, seed, seconds, trace, rehearse, workdir, emit):
         "config": config, "traffic": traffic, "memory_peak_bytes": peak,
         "trace_capture": traced, "attempted": n, "failed": 0,
         "device": device, "correct": res["max_abs_diff"] <= tol, "t0": t0,
+        "compared": {"max_abs_diff": {"value": res["max_abs_diff"],
+                                      "limit": tol, "passes": "at most"}},
     }

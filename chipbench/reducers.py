@@ -6,12 +6,13 @@ when what it reads is not there — the harness then leaves the metric out.
 
 `facts` holds what the harness gathered:
   trace     the summary of `tracered.reduce_trace`, or None
-  stepz     the daemon's /stepz JSON over the newest steps of the window
   metrics0 / metrics1   /metrics series at the window's start and end
   client    statistics from the client's timestamps
   config / traffic      the cell's files
   peaks     the table row of the attached device kind, or None
   memory_peak_bytes     peak bytes in use on the fullest chip, or None
+  notes     rows a reader leaves for the run's output beside its number
+            (which resource bounds a roofline), printed before the result
 """
 
 from __future__ import annotations
@@ -23,24 +24,6 @@ from chipbench import peaks as pk
 
 def client_stat(facts, *, stat: str) -> Optional[float]:
     return facts["client"].get(stat)
-
-
-def stepz_occupancy_pct(facts) -> Optional[float]:
-    """Tokens advanced per decode step over the slots, newest steps."""
-    s = facts.get("stepz")
-    slots = facts["config"]["run"]["serve_flags"]["slots"]
-    if not s or not s.get("window_steps"):
-        return None
-    return 100.0 * s["tokens"] / (s["window_steps"] * slots)
-
-
-def stepz_host_share_pct(facts) -> Optional[float]:
-    """Share of the step wall clock the worker spent in host phases
-    (admit, host, commit, obs) rather than dispatching or waiting."""
-    s = facts.get("stepz")
-    if not s or not s.get("window_wall_s"):
-        return None
-    return 100.0 * s["host_s"] / s["window_wall_s"]
 
 
 def metrics_ratio_pct(facts, *, num: str, den: list) -> Optional[float]:
@@ -104,7 +87,12 @@ def decode_step_roofline_pct(facts, *, program: str) -> Optional[float]:
         facts["config"], tokens=tokens, live_positions=live,
         bytes_per_param=run["weight_bytes_per_param"],
         kv_bytes=run["kv_bytes_per_element"], peaks=peaks)
-    return 100.0 * least["least_s"] * 1e3 / t["programs"][program]["mean_ms"]
+    step_ms = t["programs"][program]["mean_ms"]
+    facts.setdefault("notes", []).append(
+        {"roofline": program, "bound": least["bound"],
+         "least_ms": 1e3 * least["least_s"], "step_ms": step_ms,
+         "bytes": least["bytes"], "flops": least["flops"]})
+    return 100.0 * least["least_s"] * 1e3 / step_ms
 
 
 def forward_mfu_pct(facts) -> Optional[float]:

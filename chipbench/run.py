@@ -7,8 +7,10 @@ One run of one cell of BENCHMARK.json, in a new process: set-up (weights
 from the seed, compile or load from the compile cache, warm-up of this
 cell's shapes), a measured window of `--seconds`, the correctness check,
 and as the LAST line of stdout one JSON object: `correct`, `attempted`,
-`failed`, `metrics`, `device` (and `breakdown` with `--trace 1`). Earlier
-lines are JSON too: sample counts, generator lateness, margins.
+`failed`, `metrics`, `device` (and `breakdown` with `--trace 1`), then
+`compared`: each number `correct` rests on beside its limit, which are also
+the last lines of stderr. Earlier lines are JSON too: sample counts,
+generator lateness, margins.
 
 `--trace 0` reports the cell's end-to-end metrics; `--trace 1` profiles a
 few seconds of the window and reports its per-layer metrics.
@@ -128,6 +130,8 @@ def main(argv=None) -> int:
         if missing:
             raise RuntimeError(f"no value for end-to-end metrics {missing}")
     values = {k: v for k, v in values.items() if v is not None}
+    for note in facts.get("notes", ()):
+        emit(phase="note", **note)
 
     if args.rehearse:
         # counts are real, device numbers are not measured
@@ -157,6 +161,12 @@ def main(argv=None) -> int:
         device["busy_s"], device["window_s"] = t["busy_s"], t["window_s"]
         line["breakdown"] = {"device_ops": t["device_ops"],
                              "idle_gaps": t["idle_gaps"]}
+    # each number `correct` compared, beside its limit: the result's last
+    # key and the last lines of stderr
+    line["compared"] = facts["compared"]
+    for name, c in facts["compared"].items():
+        print(f"compared {name} {c['value']!r} {c['passes']} {c['limit']!r}",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 

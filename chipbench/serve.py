@@ -114,7 +114,6 @@ def run(cell, *, seed, seconds, trace, rehearse, workdir, emit):
             prof_thread.start()
         time.sleep(max(0.0, t1 - time.perf_counter()))
         metrics1 = daemon.metrics()
-        stepz = daemon.get_json("/stepz") if trace else None
 
         # open loop: requests due inside the window are owed a first token
         unanswered = gen.unanswered(t0, t1)
@@ -185,7 +184,7 @@ def run(cell, *, seed, seconds, trace, rehearse, workdir, emit):
                 if k.startswith("dnn_tpu_device_peak_bytes_in_use")),
                default=None)
     facts = {
-        "client": client_stats, "stepz": stepz, "metrics0": metrics0,
+        "client": client_stats, "metrics0": metrics0,
         "metrics1": metrics1, "config": config, "traffic": traffic,
         "memory_peak_bytes": peak, "trace_capture": prof_box.get("capture"),
         "trace_error": prof_box.get("error"),
@@ -203,22 +202,29 @@ def _ms(x):
     return None if x is None else 1e3 * x
 
 
-def check_served(facts, *, seed, emit) -> bool:
+def check_served(facts, *, seed, emit, margins=None) -> bool:
     """After the daemon has exited: the served check tokens against the
-    plain reference on the same weights."""
+    plain reference on the same weights. `margins` is
+    `check.served_margins` or a driver's own form of it (`serve_rows`).
+    Leaves each number compared, beside its limit, in `facts["compared"]`."""
     from chipbench import check
 
     config = facts["config"]
     t = time.perf_counter()
     cfg, params = check.init_params(config["run"]["model"], seed)
     t_init = time.perf_counter() - t
-    res = check.served_margins(config["reference"], cfg, params,
-                               facts["check"]["prompts"],
-                               facts["check"]["tokens"])
+    res = (margins or check.served_margins)(
+        config["reference"], cfg, params, facts["check"]["prompts"],
+        facts["check"]["tokens"])
     bound = config["check"]["margin_bound"]
     floor = config["check"]["argmax_floor"]
     emit(phase="check", **res,
          window_streams=facts["check"]["window_streams"], margin_bound=bound,
          argmax_floor=floor, init_s=t_init,
          reference_s=time.perf_counter() - t - t_init)
+    facts["compared"] = {
+        "worst_margin": {"value": res["worst_margin"], "limit": bound,
+                         "passes": "at most"},
+        "argmax_share": {"value": res["argmax_share"], "limit": floor,
+                         "passes": "at least"}}
     return res["worst_margin"] <= bound and res["argmax_share"] >= floor

@@ -14,10 +14,9 @@ statistics and the two tests are `check.served_margins`' and
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
+from chipbench import serve
 from chipbench.serve import run  # noqa: F401 — the driver's `run`
 
 __all__ = ["run", "served_margins", "check_served"]
@@ -59,20 +58,6 @@ def served_margins(reference, cfg, params, prompts, tokens) -> dict:
 
 
 def check_served(facts, *, seed, emit) -> bool:
-    """After the daemon has exited: the served check tokens against the
-    plain reference on the same weights (`serve.check_served`'s tests)."""
-    from chipbench import check
-
-    config = facts["config"]
-    t = time.perf_counter()
-    cfg, params = check.init_params(config["run"]["model"], seed)
-    t_init = time.perf_counter() - t
-    res = served_margins(config["reference"], cfg, params,
-                         facts["check"]["prompts"], facts["check"]["tokens"])
-    bound = config["check"]["margin_bound"]
-    floor = config["check"]["argmax_floor"]
-    emit(phase="check", **res,
-         window_streams=facts["check"]["window_streams"], margin_bound=bound,
-         argmax_floor=floor, init_s=t_init,
-         reference_s=time.perf_counter() - t - t_init)
-    return res["worst_margin"] <= bound and res["argmax_share"] >= floor
+    """`serve.check_served` with the margins taken a sequence at a time."""
+    return serve.check_served(facts, seed=seed, emit=emit,
+                              margins=served_margins)

@@ -51,8 +51,9 @@ window's start and end. The StepClock totals (`step_steps_total`,
 `step_tokens_advanced_total`, `step_phase_seconds_total{phase=}`,
 `step_admit_seconds_total{part=}`) are exact at a scrape to the last ended
 step, so their difference covers the whole window and not the newest 256
-steps of `/stepz`. A reader returns None when a series it needs is missing
-(a program that predates the series), and the harness leaves the metric out.
+steps of `/stepz`'s ring. A reader returns None when a series it needs is
+missing (a program that predates the series), and the harness leaves the
+metric out.
 A program that predates the spans gets no idle share for the same reason;
 one that predates a scope name has its operations counted as unscoped.
 """
@@ -70,11 +71,9 @@ __all__ = ["SCOPES", "load_capture", "worker_line", "capture_of",
            "gauge_at_end"]
 
 #: the scope names the program gives its device work (prefixes):
-#: attention kernels, the paged KV pool, the weight cast, the layer loop's
-#: own slicing of its stacked weights and pool, the sampling tail, the
-#: model's own blocks
-SCOPES = ("attn.", "kv_pool.", "weights.cast", "layers.scan", "sample",
-          "gpt.")
+#: attention kernels, the paged KV pool, the layer loop's own slicing of
+#: its stacked weights and pool, the sampling tail, the model's own blocks
+SCOPES = ("attn.", "kv_pool.", "layers.scan", "sample", "gpt.")
 SPAN_ROOTS = ("step", "admit")
 
 
@@ -353,8 +352,8 @@ def pure_host_share_pct(facts) -> Optional[float]:
     """Over the window: seconds the worker spent in host work the device
     does not overlap — admission's own (`self`) and its eager installs,
     and the `host`, `commit` and `obs` phases of a step — over the seconds
-    of all six StepClock phases. Unlike `stepz_host_share_pct` it leaves
-    out the prefill an admission dispatches and waits for."""
+    of all six StepClock phases. The prefill an admission dispatches and
+    waits for is left out: it is the device's time, not the host's."""
     host = _delta_sum(facts, "step_phase_seconds_total", "phase",
                       ("host", "commit", "obs"))
     admit = _delta_sum(facts, "step_admit_seconds_total", "part",

@@ -140,3 +140,34 @@ def test_batches_from_the_seed():
     assert all((x == y).all() for x, y in zip(a, b))
     assert not (a[0] == a[1]).all()
     assert 1 <= a[0].min() and a[0].max() < 50257
+
+
+def _backlog_files():
+    return sorted(n[:-len(".json")] for n in os.listdir(TRAFFIC)
+                  if n.endswith(".json") and load(n[:-len(".json")])["kind"]
+                  == "backlog")
+
+
+@pytest.mark.parametrize("name", _backlog_files())
+def test_a_backlog_cannot_run_dry(name):
+    """A traced run submits until the capture is on disk and a faster
+    program completes more: the list has to outlast both (the chat cell
+    sends ~350-570 a run, PERF.md section 4)."""
+    t = load(name)
+    assert t["requests"] >= 4000, name
+    assert t["requests_why"]
+
+
+@pytest.mark.parametrize("name", _backlog_files())
+def test_a_longer_backlog_keeps_its_first_requests(name):
+    """Sizes come from `layout_seed` block by block and ids from (seed,
+    index): raising `requests` appends, it does not change what a run
+    that ends earlier was sent."""
+    t = load(name)
+    short = tg.make_requests(dict(t, requests=640), BIG_SEED, 50257)
+    full = tg.make_requests(t, BIG_SEED, 50257)
+    assert len(full) == t["requests"] > len(short)
+    for a, b in zip(short, full):
+        assert (a.index, a.prompt_len, a.max_new) == \
+            (b.index, b.prompt_len, b.max_new)
+        assert (a.prompt == b.prompt).all()
