@@ -149,6 +149,23 @@ class LlamaConfig:
     # post_norms=True; blocks carry post_ln_1/post_ln_2 but no
     # ln_1/ln_2 leaves).
     pre_norm: bool = True
+    # ---- learned sparse attention (DeepSeek-Sparse-Attention-style
+    # indexer, models/dsa.py; all default off) ----
+    # `index_topk` set: each query attends only the index_topk positions
+    # s <= t of largest index score (ties to the smaller s; all of them
+    # while fewer exist). The score is the indexer's: index_n_head query
+    # heads of index_head_dim against ONE key head per position, which
+    # the caches store beside K and V (a third leaf, "ik").
+    index_topk: Optional[int] = None
+    index_n_head: int = 16
+    index_head_dim: int = 64
+    # the seeded init draws each q/k norm gain as this times (1 + 0.1 z):
+    # per-head unit-RMS q and k give attention logits a sigma of gain_q *
+    # gain_k, which decides how far a served token can tell a right
+    # selection from a wrong one and float32 from bfloat16 (the Keye
+    # presets' value is measured, models/llama_moe.py). 1.0 keeps the
+    # gains at exactly one.
+    qk_norm_init: float = 1.0
 
     def __post_init__(self):
         if self.parallel_block and self.post_norms:
@@ -390,6 +407,16 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
         kn = d if cfg.qk_norm_width == "head" else cfg.n_kv_head * d
         blk["attn"]["q_norm"] = {"scale": jnp.ones((qn,), dtype)}
         blk["attn"]["k_norm"] = {"scale": jnp.ones((kn,), dtype)}
+        if cfg.qk_norm_init != 1.0:
+            kq, kk = jax.random.split(jax.random.fold_in(key, 11))
+            for name, kg, n in (("q_norm", kq, qn), ("k_norm", kk, kn)):
+                blk["attn"][name]["scale"] = (cfg.qk_norm_init * (
+                    1.0 + 0.1 * jax.random.normal(kg, (n,)))).astype(dtype)
+    if cfg.index_topk is not None:
+        from dnn_tpu.models import dsa
+
+        blk["attn"]["indexer"] = dsa.init_indexer(
+            jax.random.fold_in(key, 13), cfg, dtype)
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -674,6 +701,11 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
     `ffn(bp, h)` overrides the MLP (Mixtral MoE)."""
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
+    if attn_fn is None and cfg.index_topk is not None:
+        from dnn_tpu.models import dsa
+
+        fn = lambda bp2, h: dsa.dense_attn(  # noqa: E731
+            bp2, h, cfg=cfg, compute_dtype=compute_dtype)
     # trace-time scopes: device profiles (obs/profile.py) name the
     # attention branch vs the residual/MLP compose; zero runtime cost
     with jax.named_scope("llama.block.attn"):
@@ -1311,11 +1343,12 @@ class LlamaFamilyRows:
                                        ffn=ffn or self.ffn),
                     layer_cache)
 
-    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
+    def _qkv_rows(self, bp, h, pos):
+        """h (B, 1, C) normed rows at per-slot positions pos (B,) -> q (B,
+        H, 1, D) normed, rotated and rescaled, k (rotated) and v (B, KV,
+        1, D)."""
         cfg, compute_dtype = self.cfg, self.compute_dtype
-        b = x.shape[0]
-        kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
-        h = _pre_normed(bp, x, cfg)
+        kv = cfg.n_kv_head
         q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
                         cfg.n_head)
         k = split_heads(linear(bp["attn"]["k"], h, compute_dtype=compute_dtype),
@@ -1326,7 +1359,14 @@ class LlamaFamilyRows:
         cos, sin = _rope_tables(cfg, pos)  # (B, D)
         cos, sin = cos[:, None, None, :], sin[:, None, None, :]
         q, k = _rope_apply(q, cos, sin, cfg), _rope_apply(k, cos, sin, cfg)
-        q = _q_rescale(q, cfg)
+        return _q_rescale(q, cfg), k, v
+
+    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
+        cfg, compute_dtype = self.cfg, self.compute_dtype
+        b = x.shape[0]
+        kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
+        h = _pre_normed(bp, x, cfg)
+        q, k, v = self._qkv_rows(bp, h, pos)
         qg = q.reshape(b, kv, g, d)  # group rows share the slot's limit
         y, layer_cache = codec.write_attend_rows(qg, layer_cache, k, v, pos,
                                                  write, window=window)

@@ -63,6 +63,21 @@ class MixtralConfig(llama.LlamaConfig):
     # True (Mixtral): renormalize the selected top-k router weights.
     # False (Qwen2-MoE norm_topk_prob=false): keep raw softmax probs.
     router_norm_topk: bool = True
+    # ---- one chip's share of an expert-parallel deployment ----
+    # `experts_held` set: this process holds the stacks of experts
+    # [experts_first, experts_first + experts_held) only. The router
+    # still scores all n_expert and every token still picks its top_k
+    # among them; a pick whose expert is not held contributes nothing
+    # here (the chip that holds it computes it). None = every expert.
+    experts_first: int = 0
+    experts_held: Optional[int] = None
+
+    @property
+    def held(self):
+        """(first, count) of the experts held, or None for all."""
+        if self.experts_held is None:
+            return None
+        return self.experts_first, self.experts_held
 
     def default_ffn(self, compute_dtype=None):
         """The config-resolved MLP override every llama runtime entry
@@ -137,6 +152,47 @@ PRESETS = {
                                 router_norm_topk=False,
                                 capacity_factor=8.0),
 }
+# Keye-VL-2.0-30B-A3B's language model (Kwai-Keye/Keye-VL-2.0-30B-A3B
+# config.json): a Qwen3-MoE block — GQA 32 query / 4 KV heads of 128
+# (decoupled from 2048 / 32), per-head q/k RMSNorm, RoPE theta 1e7 (for
+# text positions `mrope_section`'s three streams are equal: ordinary
+# RoPE), every layer 128 experts of width 768, 8 per token renormalised,
+# no shared expert — whose attention reads only the 2048 positions a
+# DeepSeek-Sparse-Attention-style indexer selects (`sa_config`: 16 index
+# heads of 64, one index key head; models/dsa.py). The vision tower is
+# not served: the daemon takes token ids. `qk_norm_init` 0.7: the seeded
+# init's q/k norm gains, MEASURED (PERF.md section 6, PR 33): attention
+# logits then have a sigma of ~0.5, any bfloat16 computation agrees with
+# float32 on 85-89 % of tokens and a wrong selection (top-1024) on 37-53
+# %; at gains of 1.3 / 1.7 (sigma 1.7 / 3) bfloat16 itself agrees on 53 /
+# 9 % only and `correct` could tell nothing apart.
+PRESETS["keye-vl-2.0-30b-a3b"] = MixtralConfig(
+    block_size=262144, vocab_size=151936, n_layer=48, n_head=32,
+    n_kv_head=4, n_embd=2048, d_ff=768, head_dim_override=128,
+    rope_theta=10_000_000.0, rms_eps=1e-6, qk_norm=True,
+    qk_norm_width="head", qk_norm_init=0.7,
+    n_expert=128, router_top_k=8, router_norm_topk=True,
+    capacity_factor=128.0,
+    index_topk=2048, index_n_head=16, index_head_dim=64)
+# the benchmark's cut (chipbench/configs/keye-vl-2.0-30b-a3b-ep8-1chip
+# .json): one chip's share of an 8-chip expert-parallel deployment —
+# experts 0-15 of each layer's 128 held, attention, indexer, router,
+# embedding and head whole — and 6 of the 48 layers (8 completed 68
+# requests a 45 s window of the cell where 100 were asked; PERF.md)
+PRESETS["keye-vl-2.0-30b-a3b-ep8-1chip"] = dataclasses.replace(
+    PRESETS["keye-vl-2.0-30b-a3b"], n_layer=6, experts_first=0,
+    experts_held=16)
+# tiny Keye for the CPU tests, every switch of the real one acting: GQA
+# 2:1, a head width decoupled from n_embd / n_head, head-width q/k norm
+# with drawn gains, an indexer whose topk is far below the contexts,
+# normalised top-k, a held share smaller than the expert count
+PRESETS["keye-test"] = MixtralConfig(
+    block_size=64, vocab_size=256, n_layer=3, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=32, head_dim_override=32, rope_theta=10_000_000.0,
+    rms_eps=1e-6, qk_norm=True, qk_norm_width="head", qk_norm_init=0.7,
+    n_expert=8, router_top_k=4, router_norm_topk=True, capacity_factor=8.0,
+    experts_first=0, experts_held=4,
+    index_topk=8, index_n_head=4, index_head_dim=16)
 # the benchmark's cut (chipbench/configs/olmoe-1b-7b-1chip.json): three of
 # the sixteen layers — the pattern has period 1 — so that float32 weights,
 # a 16-slot pool of 4096 positions and the programs fit one 16 GB chip
@@ -209,7 +265,7 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
         return moe_ffn_grouped(bp["moe"], h, top_k=cfg.router_top_k,
                                normalize=cfg.router_norm_topk,
                                activation=silu, compute_dtype=compute_dtype,
-                               return_stats=return_stats)
+                               return_stats=return_stats, held=cfg.held)
 
     def with_shared(bp, h, out):
         if cfg.d_shared:
@@ -241,7 +297,7 @@ def init(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
     keys = jax.random.split(jax.random.fold_in(rng, 7), cfg.n_layer)
     for i in range(cfg.n_layer):
         moe = init_moe_gated(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
-                             dtype)
+                             dtype, n_held=cfg.experts_held)
         if cfg.d_shared:
             ks = jax.random.split(jax.random.fold_in(keys[i], 1), 4)
             si = 1.0 / math.sqrt(cfg.n_embd)
@@ -283,6 +339,11 @@ def family_rows(cfg: MixtralConfig, *, compute_dtype=None,
     """ContinuousBatcher adapter: LlamaFamilyRows resolves the MoE hook
     from the config — prefill chunks, per-slot decode rows, and
     speculative verify all route through the experts."""
+    if cfg.index_topk is not None:
+        from dnn_tpu.models.dsa import DsaFamilyRows
+
+        return DsaFamilyRows(cfg, compute_dtype=compute_dtype,
+                             attn_kernel=attn_kernel)
     return llama.LlamaFamilyRows(cfg, compute_dtype=compute_dtype,
                                  attn_kernel=attn_kernel)
 

@@ -911,7 +911,10 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
 
         ffn = moe_cache_ffn(cfg, compute_dtype=engine.compute_dtype)
     elif isinstance(cfg, LlamaConfig):
-        family = LlamaFamilyRows(cfg, compute_dtype=engine.compute_dtype)
+        rows = LlamaFamilyRows
+        if cfg.index_topk is not None:
+            from dnn_tpu.models.dsa import DsaFamilyRows as rows
+        family = rows(cfg, compute_dtype=engine.compute_dtype)
     elif type(cfg) is not GPTConfig:
         log.error("--serve_lm requires a GPT-family model; '%s' (config %s) "
                   "is not one", engine.config.model, type(cfg).__name__)

@@ -64,7 +64,10 @@ This is the intra-step instrument, in two connected halves:
     moe.peak_expert_rows_total{program=decode|prefill} — what the expert
     layers of the step and chunk programs cost, as the programs
     themselves counted it — exact at every scrape to the last ended
-    step) + fixed-bucket histograms
+    step; for a model whose attention selects what it reads
+    dsa.layer_calls_total / dsa.candidate_positions_total /
+    dsa.selected_positions_total{program=decode|prefill}, counted on the
+    host from each slot's position) + fixed-bucket histograms
     (step.phase_seconds{phase=...}, step.wall_seconds). Phase-boundary
     timestamps are ring-buffered for /stepz. While a profiler capture
     records (POST /profilez), the same boundaries are ALSO written into
@@ -134,6 +137,10 @@ _NO_PARTS = (0.0, 0.0, 0.0)
 MOE_PROGRAMS = ("decode", "prefill")
 MOE_SERIES = ("layer_calls_total", "assignments_total",
               "active_experts_total", "peak_expert_rows_total")
+# the dsa.* cumulative series (StepClock.note_dsa), same programs: what a
+# learned-sparse-attention model's indexer scored and what it selected
+DSA_SERIES = ("layer_calls_total", "candidate_positions_total",
+              "selected_positions_total")
 
 #: the phase whose annotation opens when a mark closes phase P (None
 #: after the last): the in-step order of PHASES, one definition
@@ -292,6 +299,8 @@ class StepClock:
         self.moe_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
         # a paged KV pool's blocks (note_attn_blocks): live, in the tables
         self.attn_blocks_total = [0, 0]
+        # an indexer's work (note_dsa): per program, DSA_SERIES in order
+        self.dsa_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
         self._pending_moe: "Optional[Dict[str, list]]" = None
         self._gauges_registered = False
         self._registry = registry
@@ -344,6 +353,18 @@ class StepClock:
                     else 0.0
             return read
 
+        def _weak_dsa(program, i):
+            def read():
+                c = ref()
+                return float(c.dsa_total[program][i]) if c is not None \
+                    else 0.0
+            return read
+
+        # registered with the first note_dsa: a model without an indexer
+        # shows no dsa_* series
+        self._dsa_gauges = {
+            labeled(f"dsa.{name}", program=p): _weak_dsa(p, i)
+            for p in MOE_PROGRAMS for i, name in enumerate(DSA_SERIES)}
         # registered with the first note_attn_blocks: a dense cache shows
         # no step_attn_* series
         self._attn_gauges = {
@@ -458,6 +479,25 @@ class StepClock:
             self._gauges_registered = False  # re-register with them
         tot[0] += live
         tot[1] += table
+
+    def note_dsa(self, program: str, layer_calls: int, candidates: int,
+                 selected: int):
+        """One dispatched program of a model whose attention selects what
+        it reads (models/dsa.py): `layer_calls` attention layers, whose
+        indexers scored `candidates` live positions and selected
+        `selected` of them (min(position + 1, topk) a query), both summed
+        over the layers and the queries. Counted by the batcher on the
+        host from each slot's position — no device read. Cumulative
+        dsa.* totals, on /metrics with the first note."""
+        if not _obs.enabled():
+            return
+        tot = self.dsa_total[program]
+        if not (self.dsa_total["decode"][0] or self.dsa_total["prefill"][0]):
+            self._gauges.update(self._dsa_gauges)
+            self._gauges_registered = False  # re-register with them
+        tot[0] += layer_calls
+        tot[1] += candidates
+        tot[2] += selected
 
     def note_moe(self, program: str, layer_calls: int, stats):
         """What the expert layers of one executed program cost:

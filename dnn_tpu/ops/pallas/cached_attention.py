@@ -448,10 +448,12 @@ def _decode_call(q, k, v, pos1d, ks, vs, *, block_s, interpret):
 
 
 def reference_paged_decode_attention(q, kp, vp, tables, pos, *, ks=None,
-                                     vs=None):
+                                     vs=None, sel=None):
     """Oracle for the paged kernel: gather the dense view, then the
     dense decode reference. q (B, Hk, R, D); kp/vp (n_blocks, Hk, bp, D)
-    pool; tables (B, nb_max) int32; pos (B,). Returns (B, Hk, R, D) f32."""
+    pool; tables (B, nb_max) int32; pos (B,); `sel` (B, nb_max * bp) bool
+    narrows slot b's columns to those it is true at. Returns (B, Hk, R,
+    D) f32."""
     b, nb = tables.shape
     bp = kp.shape[2]
 
@@ -464,13 +466,25 @@ def reference_paged_decode_attention(q, kp, vp, tables, pos, *, ks=None,
         return g.reshape(b, hk, nb * bp, *rest)
 
     d = q.shape[-1]  # a pool may store its rows lane-padded, wider than q
-    return reference_decode_attention(
-        q, view(kp)[..., :d], view(vp)[..., :d], pos,
-        ks=view(ks) if ks is not None else None,
-        vs=view(vs) if vs is not None else None)
+    if sel is None:
+        return reference_decode_attention(
+            q, view(kp)[..., :d], view(vp)[..., :d], pos,
+            ks=view(ks) if ks is not None else None,
+            vs=view(vs) if vs is not None else None)
+    if ks is not None:
+        raise ValueError("a selection reads a float pool")
+    k, v = view(kp)[..., :d], view(vp)[..., :d]
+    s = jnp.einsum("bhrd,bhsd->bhrs", q.astype(jnp.float32),
+                   k.astype(jnp.float32),
+                   preferred_element_type=jnp.float32) / jnp.sqrt(d)
+    keep = (jnp.arange(k.shape[2])[None, :] <= pos[:, None]) & sel
+    s = jnp.where(keep[:, None, None, :], s, _NEG_BIG)
+    return jnp.einsum("bhrs,bhsd->bhrd", jax.nn.softmax(s, axis=-1),
+                      v.astype(jnp.float32),
+                      preferred_element_type=jnp.float32)
 
 
-def _reference_paged_step(q, pools, tables, pos, layer, new):
+def _reference_paged_step(q, pools, tables, pos, layer, new, sel=None):
     """paged_decode_attention's whole-pool forms in plain jnp: place
     `new`'s rows at [layer, block, :, row] (gated-off slots at junk
     block 0, row 0), then the oracle on that layer. Returns what the
@@ -487,7 +501,7 @@ def _reference_paged_step(q, pools, tables, pos, layer, new):
     kp, vp, *scales = [p[layer] for p in pools]
     ks, vs = scales or (None, None)
     out = reference_paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
-                                           vs=vs)
+                                           vs=vs, sel=sel)
     if gate is None:
         return out
     return (jnp.where(gate[:, None, None, None], out, 0.0), *pools)
@@ -499,7 +513,8 @@ def _paged_group(block_len, nb_max):
     return max(1, min(128 // block_len, nb_max))
 
 
-def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole):
+def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
+                         select=False):
     """One grid step = one slot. Scalar prefetch: pos, table, layer (and
     with `write` the gate). Inputs: q, the pool's leaves where they lie
     in HBM, and with `write` this step's rows. Outputs: the attention
@@ -508,7 +523,8 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole):
     one for the write-back), which of the two buffers the slot's first
     group is in, and G running softmax states a query row (state g
     attends every G-th block, and the G are merged when the slot's blocks
-    are through)."""
+    are through). With `select` one more input, last: the slot's set
+    (n_groups, G, bp) int32, nonzero where a position is read."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -517,14 +533,18 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole):
     gate_ref = refs[3] if write else None
     q_ref, *refs = refs[4 if write else 3:]
     pools, refs = refs[:n_pool], refs[n_pool:]
+    new_refs = ()
+    if write:
+        new_refs, refs = refs[:n_pool], refs[n_pool:]
+    sel_ref = None
+    if select:
+        sel_ref, *refs = refs
+    o_ref, *refs = refs
     if write:
         # the pool is read where it is written: through the aliased
         # output (on the chip the same memory as the input; under
         # interpret=True the copy the outputs start from)
-        new_refs, o_ref, refs = refs[:n_pool], refs[n_pool], refs[n_pool + 1:]
         pools, refs = refs[:n_pool], refs[n_pool:]
-    else:
-        o_ref, *refs = refs
     bufs, (sem, first_ref, m_scr, l_scr, acc_scr) = \
         refs[:n_pool], refs[n_pool:]
 
@@ -651,6 +671,10 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole):
                 + gi * group) * bp + jax.lax.broadcasted_iota(
                     jnp.int32, shape, 1)
         seen = cols <= pos
+        if select:
+            chosen = sel_ref[0, gi] != 0  # (G, bp), this group's blocks
+            seen = seen & jnp.broadcast_to(
+                chosen[:, None, :], (group, rows, bp)).reshape(shape)
         s2 = jnp.where(seen, s2, _NEG_BIG)
 
         m_prev = m_scr[:, :1]
@@ -693,7 +717,7 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole):
 
 @jax.named_scope("attn.paged_decode")
 def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
-                           layer=None, new=None, interpret=None):
+                           layer=None, new=None, sel=None, interpret=None):
     """Fused paged decode attention (see the section comment above).
 
     q (B, Hk, R, D) — R query rows per KV head, all attending logical
@@ -721,6 +745,12 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     gated-off slot is empty whatever its `pos` and table say: no block
     of it is read or written, and its output rows are zeros.
 
+    With `sel` (B, nb_max * bp) bool, slot b's softmax runs over the
+    positions <= pos[b] at which `sel` is true and no others (the set
+    models/dsa.py chose). The kernel still walks every live block — a
+    set scattered over the context leaves hardly a block without a
+    member — and masks inside the group's update; float pools only.
+
     Dispatches to the Pallas kernel on TPU; otherwise runs the
     reference. `interpret=True` forces the kernel in interpreter mode
     (CPU CI runs the real table chase and block copies)."""
@@ -741,8 +771,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     if interpret is None or not (interpret or lowers):
         if layer is None:
             return reference_paged_decode_attention(
-                q, kp, vp, tables, pos, ks=ks, vs=vs)
-        return _reference_paged_step(q, pools, tables, pos, layer, new)
+                q, kp, vp, tables, pos, ks=ks, vs=vs, sel=sel)
+        return _reference_paged_step(q, pools, tables, pos, layer, new, sel)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -755,9 +785,13 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     group = _paged_group(bp, nb_max)
     write = new is not None
     whole = layer is not None
+    select = sel is not None
+    if select and quant:
+        raise ValueError("a selection reads a float pool")
     kernel = functools.partial(
         _paged_decode_kernel, scale=1.0 / (d_q ** 0.5), nb_max=nb_max,
-        quant=quant, write=write, whole=whole,
+        quant=quant, write=write, whole=whole, **(
+            {"select": True} if select else {}),
     )
 
     def rows_of_slot(x):  # grid step bi sees x[bi]
@@ -782,6 +816,15 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools]
         # operand numbers count the scalars: 4 of them, then q
         aliases = {5 + i: 1 + i for i in range(len(pools))}
+    if select:
+        # the set as the kernel's groups see it: (B, n_groups, G, bp),
+        # the table's tail padded with positions never read
+        n_groups = -(-nb_max // group)
+        sel4 = jnp.pad(sel.astype(jnp.int32),
+                       ((0, 0), (0, n_groups * group * bp - nb_max * bp))
+                       ).reshape(b, n_groups, group, bp)
+        in_specs.append(rows_of_slot(sel4))
+        rows = (*rows, sel4)
     n_states = group * hk * r
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),

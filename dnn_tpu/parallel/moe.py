@@ -147,20 +147,23 @@ def load_balance_loss(aux) -> jax.Array:
 
 
 def init_moe_gated(rng, n_embd: int, n_experts: int, d_ff: int,
-                   dtype=jnp.float32):
+                   dtype=jnp.float32, *, n_held: Optional[int] = None):
     """Param pytree for a GATED (SwiGLU) MoE FFN layer — the Mixtral
     expert shape: per-expert gate/up/down projections, no biases.
     Expert-major stacking exactly as init_moe (EP shards the leading
-    axis; the dense path batches over it)."""
+    axis; the dense path batches over it). `n_held`: the stacks of that
+    many experts only (one chip's share), under the whole layer's
+    router."""
     kr, kg, ku, kd = jax.random.split(rng, 4)
     scale_in = 1.0 / math.sqrt(n_embd)
     scale_out = 1.0 / math.sqrt(d_ff)
+    e = n_experts if n_held is None else n_held
     return {
         "router": {"kernel": jax.random.normal(
             kr, (n_embd, n_experts), dtype) * scale_in},
-        "wg": _expert_stack(kg, (n_experts, n_embd, d_ff), dtype, scale_in),
-        "wu": _expert_stack(ku, (n_experts, n_embd, d_ff), dtype, scale_in),
-        "wd": _expert_stack(kd, (n_experts, d_ff, n_embd), dtype, scale_out),
+        "wg": _expert_stack(kg, (e, n_embd, d_ff), dtype, scale_in),
+        "wu": _expert_stack(ku, (e, n_embd, d_ff), dtype, scale_in),
+        "wd": _expert_stack(kd, (e, d_ff, n_embd), dtype, scale_out),
     }
 
 
@@ -238,7 +241,8 @@ def _expert_ffn(params, expert_in, *, activation, compute_dtype):
     return out + bo[:, None, :].astype(jnp.float32)  # f32
 
 
-def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True):
+def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True,
+               held=None):
     """Drop-free routing of (S, D) tokens: softmax over all experts (f32),
     `lax.top_k`, and the S*k (token, expert) assignments sorted by expert
     (stable: token order within an expert).
@@ -246,7 +250,16 @@ def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True):
     Returns (weights (S, k) f32 — the selected probabilities, renormalized
     over the k when `normalize`; `order` (S*k,) — sorted position ->
     flat assignment index t*k + j; `expert_of_row` (S*k,) — the expert of
-    each sorted row; `group_sizes` (E,) int32 — rows per expert)."""
+    each sorted row; `group_sizes` (E,) int32 — rows per expert).
+
+    `held` = (first, count): only experts [first, first + count) have
+    their stacks here (one chip's share of an expert-parallel layer).
+    Routing is unchanged — every token picks among ALL experts and its
+    weights are those of the whole layer — but the rows are sorted by
+    HELD expert, counted from `first`, with every pick of an expert held
+    elsewhere behind them: `expert_of_row` is then the index into the
+    held stacks (`count` marks a row not held) and `group_sizes` is
+    (count,), summing to the rows held."""
     e = router_kernel.shape[-1]
     # "highest": on a TPU a float32 matmul at the default precision rounds
     # its operands to bfloat16, and the eighth and ninth of 64 experts are
@@ -259,7 +272,13 @@ def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True):
         weights = weights / jnp.maximum(
             weights.sum(axis=-1, keepdims=True), 1e-9)
     flat = experts.reshape(-1).astype(jnp.int32)
+    if held is not None:
+        first, e = held
+        local = flat - first
+        flat = jnp.where((local >= 0) & (local < e), local, e)
     order = jnp.argsort(flat, stable=True)
+    # (a pick held elsewhere adds at index e: out of range, which a
+    # scatter drops)
     group_sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
     return weights, order, flat[order], group_sizes
 
@@ -321,7 +340,7 @@ def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
 
 def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
                     activation=gelu, compute_dtype=None,
-                    return_stats: bool = False):
+                    return_stats: bool = False, held=None):
     """Drop-free MoE FFN on one device: (..., D) -> (..., D), every token
     of `x` routed to its top_k experts and every routed row computed.
     Output does NOT include the residual; callers add it.
@@ -333,18 +352,32 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
 
     `return_stats` adds an int32 (3,): rows through the experts (S*k),
     experts with at least one row, and the fullest expert's rows — what
-    this layer call cost, for the serving counters."""
+    this layer call cost, for the serving counters.
+
+    `held` = (first, count) (`route_rows`): `params` carries the stacks
+    of those experts only, beside the whole layer's router. The ragged
+    matmuls cover the rows whose expert is held; a pick of an expert
+    held elsewhere contributes ZERO, so the result is this chip's part
+    of the layer's sum, and the shares of all chips add up to the whole
+    layer's result. The statistics count the held experts' rows. None:
+    every expert is held, and the program is what it was without the
+    argument."""
     shape, d = x.shape, x.shape[-1]
     xs = x.reshape(-1, d)
     s = xs.shape[0]
     with jax.named_scope("moe.route"):
         weights, order, expert_of_row, group_sizes = route_rows(
-            params["router"]["kernel"], xs, top_k=top_k, normalize=normalize)
+            params["router"]["kernel"], xs, top_k=top_k, normalize=normalize,
+            held=held)
         rows = xs[order // top_k]  # (S*k, D), sorted by expert
     with jax.named_scope("moe.experts"):
         out = _experts_grouped(params, rows, expert_of_row, group_sizes,
                                activation=activation,
                                compute_dtype=compute_dtype)
+        if held is not None:
+            # rows behind the last group belong to no expert here: the
+            # ragged matmul leaves them unspecified
+            out = jnp.where((expert_of_row < held[1])[:, None], out, 0.0)
     with jax.named_scope("moe.combine"):
         # the inverse permutation (a scatter, not a second sort) puts row
         # t*k + j back at (t, j)
@@ -355,7 +388,8 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
         y = y.reshape(shape).astype(x.dtype)
     if not return_stats:
         return y
-    stats = jnp.stack([jnp.int32(s * top_k),
+    n_rows = jnp.int32(s * top_k) if held is None else group_sizes.sum()
+    stats = jnp.stack([n_rows.astype(jnp.int32),
                        (group_sizes > 0).sum().astype(jnp.int32),
                        group_sizes.max()])
     return y, stats

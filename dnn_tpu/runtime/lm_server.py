@@ -1145,6 +1145,18 @@ class LMServer:
             "detail": "bytes of the served tree by dtype",
             "bytes": sum(self._weight_bytes.values()),
             "bytes_by_dtype": self._weight_bytes}
+        # facts, no `state`: the KV cache's bytes leaf by leaf (K, V, an
+        # int8 pool's scales, a selecting model's index keys "ik"), from
+        # shapes alone
+        kv_leaves = getattr(self.batcher, "cache", None)
+        if isinstance(kv_leaves, dict):
+            from dnn_tpu.obs.mem import logical_nbytes
+
+            by_leaf = {k: int(logical_nbytes(v))
+                       for k, v in kv_leaves.items() if k != "tables"}
+            comps["kv_cache"] = {
+                "detail": "bytes of the KV cache by leaf",
+                "bytes": sum(by_leaf.values()), "bytes_by_leaf": by_leaf}
         s["components"] = comps
         if self._kvtier_on():
             # KV-tier residency rides /statusz (informational): the

@@ -17,10 +17,16 @@ What this buys a serving pool (tests/test_paged.py measures both):
   * allocation/free at block granularity per request lifetime, host-side
     (a free-list of ints — no device work to retire a request).
 
-Layout (per K and per V, mirroring the dense cache's (L, B, H, S, D)):
+Layout (per leaf KIND, mirroring the dense cache's (L, B, H, S, D)). A
+position's state is K and V per KV head and, for a family whose
+attention selects what it reads (models/dsa.py), the position's index
+key as a third leaf "ik" — one head, its own width, written, installed,
+gathered and freed with the block like K and V; a family without an
+indexer allocates and moves nothing new:
 
     pool   (L, n_blocks, H, block_len, Dp) Dp = D rounded up to whole
                                           128-lane tiles (`lane_padded`)
+    ik     (L, n_blocks, 1, block_len, Dip)  Dip = lane_padded(index_dim)
     tables (L, B, max_blocks)  int32   -- replicated over L (the leaf
                                           shape every install / copy /
                                           tier program was written to)
@@ -66,7 +72,7 @@ from dnn_tpu.runtime.kvcache import band_keep
 _NEG_BIG = -1e30
 
 __all__ = ["PagedKV", "BlockAllocator", "InsufficientBlocks",
-           "init_paged_cache", "lane_padded", "scan_blocks"]
+           "init_paged_cache", "lane_padded", "cache_head_dim", "scan_blocks"]
 
 
 class InsufficientBlocks(RuntimeError):
@@ -177,6 +183,12 @@ def lane_padded(head_dim: int) -> int:
     return -(-head_dim // LANES) * LANES
 
 
+def cache_head_dim(cfg) -> int:
+    """The width of a K/V row: the config's own `head_dim` where it has
+    one (a LLaMA-family config may decouple it from n_embd / n_head)."""
+    return getattr(cfg, "head_dim", None) or cfg.n_embd // cfg.n_head
+
+
 def _pad_lanes(x, width):
     """Zero-pad x's last axis up to `width` (a pool block's stored row)."""
     if x.shape[-1] == width:
@@ -186,7 +198,8 @@ def _pad_lanes(x, width):
 
 def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
                      block_len: int = 16, dtype=jnp.float32,
-                     kv_heads: Optional[int] = None):
+                     kv_heads: Optional[int] = None,
+                     index_dim: Optional[int] = None):
     """Pool + tables pytree for `slots` decode rows of up to `max_len`
     positions each, sharing `n_blocks` physical blocks of `block_len`
     positions (leading L on every leaf, like the dense cache; K/V rows
@@ -196,16 +209,20 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
     dtype="int8" / "int4" build the quantized pools: int8/int4 K/V
     blocks plus per-(position, head) f32 scale blocks, the paged forms
     of kvcache.Int8KV / Int4KV's layouts (int4 stores native jnp.int4,
-    two values per byte)."""
+    two values per byte). `index_dim` adds the index-key leaf "ik" (one
+    head of that width a position, float pools only)."""
     if max_len % block_len:
         raise ValueError(f"max_len {max_len} must tile block_len {block_len}")
-    head_dim = cfg.n_embd // cfg.n_head
+    head_dim = cache_head_dim(cfg)
     heads = kv_heads if kv_heads is not None else cfg.n_head
     nb_max = max_len // block_len
     # K/V blocks are stored LANE-PADDED (`lane_padded`): see there
     shape = (cfg.n_layer, n_blocks, heads, block_len, lane_padded(head_dim))
     tables = jnp.zeros((cfg.n_layer, slots, nb_max), jnp.int32)
     if dtype in ("int8", "int4"):
+        if index_dim is not None:
+            raise ValueError("a pool with an index-key leaf is float: "
+                             "int8 / int4 pools assume K and V alone")
         qdt = jnp.int8 if dtype == "int8" else jnp.int4
         return {
             "k": jnp.zeros(shape, qdt),
@@ -214,11 +231,15 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
             "vs": jnp.ones(shape[:-1], jnp.float32),
             "tables": tables,
         }
-    return {
+    pool = {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
         "tables": tables,
     }
+    if index_dim is not None:
+        pool["ik"] = jnp.zeros(
+            shape[:2] + (1, block_len, lane_padded(index_dim)), dtype)
+    return pool
 
 
 class PagedKV:
@@ -293,6 +314,13 @@ class PagedKV:
         request's K/V inside the new one's cache. Junk-block collisions
         between gated slots are harmless (block 0 is never owned, never
         attended live)."""
+        return self._scatter_rows(c, lambda: self._rows(c, k, v), pos,
+                                  write_gate, layer)
+
+    def _scatter_rows(self, c, rows, pos, write_gate, layer):
+        """`rows()` {leaf: (B, H, 1[, D])} into block tables[b, pos //
+        bp], row pos % bp of each named leaf; the other leaves pass
+        through."""
         bp = self.block_len
         tables = c["tables"] if layer is None else c["tables"][layer]
         blk = jnp.take_along_axis(
@@ -303,10 +331,25 @@ class PagedKV:
         at = (blk, slice(None), row)  # -> (B, H[, D]) rows
         if layer is not None:
             at = (layer,) + at
-        out = {"tables": c["tables"]}
-        for name, r in self._rows(c, k, v).items():
+        out = dict(c)
+        for name, r in rows().items():
             out[name] = c[name].at[at].set(r[:, :, 0])
         return out
+
+    @jax.named_scope("kv_pool.write")
+    def write_index_rows(self, c, ik, pos, write_gate, layer=None):
+        """This step's index keys ik (B, 1, Di) into the "ik" leaf at
+        `pos`, gated and junk-routed exactly as `write_rows`."""
+        row = _pad_lanes(ik.astype(c["ik"].dtype)[:, None],
+                         c["ik"].shape[-1])  # (B, 1, 1, Dip)
+        return self._scatter_rows(c, lambda: {"ik": row}, pos, write_gate,
+                                  layer)
+
+    def index_view(self, c, index_dim, layer=None):
+        """Every slot's index keys in logical order, (B, S_max, Di): what
+        the indexer scores one query a slot against."""
+        (view,) = self.gather_view(c, ("ik",), layer=layer, width=index_dim)
+        return view[:, 0]
 
     @staticmethod
     def _rows(c, k, v):
@@ -356,7 +399,7 @@ class PagedKV:
             out.append(g.reshape(b, h, nb * bp, *rest))
         return out
 
-    def attend_rows(self, q, c, pos, window=None, layer=None):
+    def attend_rows(self, q, c, pos, window=None, layer=None, sel=None):
         """q (B, H, R, D); every row of slot b attends logical positions
         <= pos[b] (identical math to kvcache.FloatKV/Int8KV.attend_rows
         on the gathered view — int8 pools fold their per-position scales
@@ -367,7 +410,9 @@ class PagedKV:
         `window` override is the dense codecs' per-LAYER channel
         (alt-window configs) — those are rejected at batcher
         construction for paged pools, so an override here is a
-        programming error."""
+        programming error. `sel` (B, S_max) bool narrows what slot b
+        reads to the positions it is true at (models/dsa.py's set; it
+        lies within <= pos[b])."""
         if window is not None:
             raise ValueError(
                 "PagedKV has no per-layer window channel (alt-window "
@@ -385,7 +430,7 @@ class PagedKV:
                 q, c["k"], c["v"], tables, pos,
                 ks=c["ks"] if quant else None,
                 vs=c["vs"] if quant else None,
-                layer=layer, interpret=interp)
+                layer=layer, sel=sel, interpret=interp)
             # same output-dtype recipe as the einsum path below
             return out if quant else out.astype(c["v"].dtype)
         d = q.shape[-1]
@@ -404,6 +449,8 @@ class PagedKV:
             cols = jnp.arange(k.shape[2])
             mask = band_keep(cols[None, None, None, :],
                              pos[:, None, None, None], self.window)
+            if sel is not None:
+                mask = mask & sel[:, None, None, :]
             s = jnp.where(mask, s, _NEG_BIG)
             p = jax.nn.softmax(s, axis=-1)
             if quant:
@@ -414,7 +461,7 @@ class PagedKV:
             return out if quant else out.astype(c["v"].dtype)
 
     def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None,
-                          layer=None):
+                          layer=None, sel=None):
         """The decode step's one call (kvcache._KernelDispatch.
         write_attend_rows): k/v rows in at `pos`, attention out -> (y, c).
         On the whole pool with the kernel on, both are ONE operation: the
@@ -424,7 +471,8 @@ class PagedKV:
         layout XLA would have to reconcile with the kernel's."""
         if layer is None or window is not None or not self._kernel_on(c):
             c = self.write_rows(c, k, v, pos, write_gate, layer=layer)
-            return self.attend_rows(q, c, pos, window=window, layer=layer), c
+            return self.attend_rows(q, c, pos, window=window, layer=layer,
+                                    sel=sel), c
         from dnn_tpu.ops.pallas.cached_attention import paged_decode_attention
 
         with jax.named_scope("kv_pool.write"):
@@ -433,7 +481,7 @@ class PagedKV:
         y, *pools = paged_decode_attention(
             q, c["k"], c["v"], c["tables"][layer], pos,
             ks=c.get("ks"), vs=c.get("vs"), layer=layer,
-            new=(*rows.values(), write_gate),
+            new=(*rows.values(), write_gate), sel=sel,
             interpret=True if self.use_kernel == "interpret" else None)
         c = {**c, **dict(zip(names, pools))}
         # same output-dtype recipe as attend_rows
@@ -483,9 +531,18 @@ class _PagedLayer:
     def __init__(self, codec: PagedKV, layer):
         self.codec, self.layer = codec, layer
 
-    def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None):
+    def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None,
+                          sel=None):
         return self.codec.write_attend_rows(
-            q, c, k, v, pos, write_gate, window=window, layer=self.layer)
+            q, c, k, v, pos, write_gate, window=window, layer=self.layer,
+            sel=sel)
+
+    def write_index_rows(self, c, ik, pos, write_gate):
+        return self.codec.write_index_rows(c, ik, pos, write_gate,
+                                           layer=self.layer)
+
+    def index_view(self, c, index_dim):
+        return self.codec.index_view(c, index_dim, layer=self.layer)
 
 
 def codec_is_paged(cache) -> bool:

@@ -331,6 +331,27 @@ class ContinuousBatcher:
         self.family = family or GPTFamilyRows(
             cfg, compute_dtype=compute_dtype, ffn=ffn,
             attn_kernel=attn_kernel)
+        # a family whose cache has a third leaf (models/dsa.py: the
+        # index key of every position) serves from the paged pool alone
+        # (checked below, once paging is decided), and what assumes K and
+        # V alone refuses it here, by name
+        self._index_topk = getattr(self.family, "index_topk", None)
+        if getattr(self.family, "requires_paged", False):
+            leaves = "/".join(self.family.cache_leaves)
+            refused = None
+            if prefix_cache > 0:
+                refused = ("prefix_cache (the radix prefix store and the "
+                           "fleet KV tier share and move K/V blocks alone)")
+            elif kv_dtype in ("int8", "int4"):
+                refused = (f"an {kv_dtype} KV pool (its scale leaves "
+                           "assume K and V alone)")
+            elif prefill_chunk_tokens or overlap:
+                refused = ("interleaved / overlapped prefill (its mixed "
+                           "step was not built for the third leaf)")
+            if refused is not None:
+                raise ValueError(
+                    f"this model's cache has the leaves {leaves}: "
+                    f"{refused} is not available with it")
         # kv_dtype picks the cache storage codec (None follows
         # compute_dtype; "int8" = quantized cache, kvcache.Int8KV)
         cache_dtype = kv_dtype if kv_dtype is not None else (compute_dtype or jnp.float32)
@@ -395,6 +416,12 @@ class ContinuousBatcher:
         # tables (runtime/paged_kvcache.py): admission is then by ACTUAL
         # request length (sum of blocks), not slots x max_len.
         self._paged = int(paged_blocks) > 0
+        if getattr(self.family, "requires_paged", False) and not self._paged:
+            raise ValueError(
+                "this model's cache has the leaves "
+                + "/".join(self.family.cache_leaves)
+                + " and lives in the paged pool: a dense per-slot cache is "
+                "not available with it (kv='auto' could not page here)")
         # decode bucketing (runtime/decode_buckets.py): the dense pool is
         # allocated at the smallest ladder bucket covering the longest
         # LIVE position and grown bucket-by-bucket as sequences advance,
@@ -441,7 +468,7 @@ class ContinuousBatcher:
                     "— serve windowed families with prefix_cache=0")
             self._paged_window = fam_window
             from dnn_tpu.runtime.paged_kvcache import (
-                BlockAllocator, PagedKV, init_paged_cache,
+                BlockAllocator, PagedKV, cache_head_dim, init_paged_cache,
             )
 
             if self.max_len % block_len:
@@ -457,7 +484,8 @@ class ContinuousBatcher:
             self.cache = init_paged_cache(
                 cfg, slots, self.max_len, n_blocks=paged_blocks,
                 block_len=block_len, dtype=cache_dtype,
-                kv_heads=getattr(self.family, "kv_heads", None))
+                kv_heads=getattr(self.family, "kv_heads", None),
+                index_dim=getattr(self.family, "index_dim", None))
             self._allocator = BlockAllocator(paged_blocks)
             self._block_len = block_len
             # the family's attn_kernel policy routes paged decode through
@@ -469,7 +497,7 @@ class ContinuousBatcher:
                             use_kernel=getattr(self.family, "attn_kernel",
                                                False))
 
-            head_dim = cfg.n_embd // cfg.n_head  # init_paged_cache's
+            head_dim = cache_head_dim(cfg)  # init_paged_cache's
 
             def gather_row(cache, ids_row):
                 """Rebuild a transient prefill row from pool blocks (the
@@ -1959,6 +1987,19 @@ class ContinuousBatcher:
         res = self._prefill_chunk(*args)
         if self._moe_stats:
             self._moe_note("prefill", res[2])
+        if self._index_topk and self.step_clock is not None:
+            # what the chunk's indexers scored and selected, from its
+            # start and length alone (pad rows too: the device scores
+            # them): row t reads min(start + t + 1, topk) of start + t + 1
+            start, t = int(args[3]), int(args[2].shape[-1])
+            k = self._index_topk
+            n_all = min(max(k - start, 0), t)  # rows that read everything
+            self.step_clock.note_dsa(
+                "prefill", self.cfg.n_layer,
+                self.cfg.n_layer * (t * start + t * (t + 1) // 2),
+                self.cfg.n_layer * (
+                    n_all * start + n_all * (n_all + 1) // 2
+                    + (t - n_all) * k))
         return res[0], res[1]
 
     def _moe_note(self, program: str, stats, idx: Optional[int] = None):
@@ -2659,6 +2700,8 @@ class ContinuousBatcher:
         n_act = 0
         blocks = 0  # of a paged pool that hold a live position
         bp = self._block_len if self._paged else 0
+        topk = self._index_topk
+        picked = 0  # by an indexer, of the live - n_act it scored
         for r in self._slot_req:
             if r is not None:
                 n = r["prompt_len"] + len(r["emitted"])
@@ -2666,6 +2709,14 @@ class ContinuousBatcher:
                 n_act += 1
                 if bp:
                     blocks += -(-n // bp)
+                if topk:
+                    # the step's query stood at n - 2: n - 1 candidates
+                    picked += min(n - 1, topk)
+        if topk and self.step_clock is not None:
+            self.step_clock.note_dsa(
+                "decode", self.cfg.n_layer,
+                self.cfg.n_layer * (live - n_act),
+                self.cfg.n_layer * picked)
         if live > self._kv_live_hw:
             self._kv_live_hw = live
         if n_act > self._active_hw:

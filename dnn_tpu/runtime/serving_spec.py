@@ -105,6 +105,14 @@ class SpeculativeBatcher(ContinuousBatcher):
             raise ValueError(
                 f"draft vocab {draft_cfg.vocab_size} != target vocab "
                 f"{cfg.vocab_size}")
+        if getattr(kw.get("family"), "requires_paged", False):
+            raise ValueError(
+                "speculative decoding is not available with this model: "
+                "its verify step attends every cached position and its "
+                "codecs assume K and V alone, where this model's cache "
+                "has the leaves "
+                + "/".join(kw["family"].cache_leaves)
+                + " and its attention selects what it reads")
         if kw.get("kv") == "paged":
             raise ValueError(
                 "SpeculativeBatcher pins the dense pool (the spec codecs "

@@ -14,8 +14,6 @@ what the program really wrote — the benchmark's own spawner
 (`chipbench.daemon.Daemon`) running `python -m dnn_tpu.node --serve_lm` at
 the cell's rehearsal size, the step programs lowered from the arguments of
 their first real calls, the engine's traced forward, one real capture.
-The one name listed below is listed because the program no longer writes
-it: its case is a strict xfail until the benchmark stops reading it.
 """
 
 import json
@@ -107,14 +105,6 @@ _SERVED = {c["name"]: _read_by(c["name"]) for c in _BENCH["configs"]
            if c["name"] not in _PIPED}
 
 
-# Read by a per-layer metric and written by no program a cell runs (PERF.md
-# section 7): for the next `benchmark` PR to drop from `chipbench/layers/`.
-_NOT_WRITTEN = {
-    "weights.cast": "since PR 27 the daemon holds its matmul weights in "
-                    "the compute dtype: `ops/nn.linear` has no cast to name",
-}
-
-
 def _cases(read_by, kind, once=True):
     """(configuration, name) for every name of `kind`. With `once`, a name
     that several configurations read through the same code of the program
@@ -125,10 +115,7 @@ def _cases(read_by, kind, once=True):
         for name in read_by[config][kind]:
             if not (once and name in seen):
                 seen.add(name)
-                marks = [pytest.mark.xfail(strict=True,
-                                           reason=_NOT_WRITTEN[name])] \
-                    if name in _NOT_WRITTEN else []
-                out.append(pytest.param(config, name, marks=marks,
+                out.append(pytest.param(config, name,
                                         id=f"{config}:{name}"))
     return out
 
@@ -258,8 +245,12 @@ def lowered():
 
         prepared = _stack_and_release(
             spec.init(jax.random.PRNGKey(0)), cfg, held_in)
-        family = LlamaFamilyRows(cfg, compute_dtype=held_in) \
-            if isinstance(cfg, LlamaConfig) else None
+        family = None
+        if isinstance(cfg, LlamaConfig):  # as node._serve_lm picks it
+            rows = LlamaFamilyRows
+            if cfg.index_topk is not None:
+                from dnn_tpu.models.dsa import DsaFamilyRows as rows
+            family = rows(cfg, compute_dtype=held_in)
         batcher = ContinuousBatcher(
             cfg, prepared, compute_dtype=held_in, family=family,
             kv="auto",  # the daemon's default (LMServer's)

@@ -511,3 +511,54 @@ def test_olmoe_decode_step_gathers_no_expert_weights_by_token(
     per_row = re.compile(r"\[%d,(?:%d,%d|%d,%d)\]" % (rows, d, f, f, d))
     assert not [l for l in text.splitlines() if per_row.search(l)]
     assert "ragged-dot" in text and "tpu_custom_call" in text
+
+
+# ----------------------------------------------------------------------
+# learned sparse attention (models/dsa.py) at the Keye cut's shapes: a
+# 1024-token chunk against a 16 384-position row, 16 slots of 16 384
+# positions in blocks of 16, 4 KV heads of 128 with 8 query heads each
+# ----------------------------------------------------------------------
+
+def _sparse_cases():
+    from dnn_tpu.models import dsa
+    from dnn_tpu.ops.pallas import sparse_attention as sa
+
+    t, s, kv, g, d, hi, di = 1024, 16384, 4, 8, 128, 16, 64
+    b, nb, bp, n_layer = 16, 1024, 16, 2
+
+    def prefill_attention(dt):
+        return (lambda q, k, v, sel, st: sa.sparse_prefill_attention(
+            q, k, v, sel, st, interpret=False),
+            [((kv, g, t, d), dt), ((kv, s, d), dt), ((kv, s, d), dt),
+             ((t, s), jnp.bool_), ((), jnp.int32)])
+
+    def index_scores(dt):
+        return (lambda qi, w, ki, st: sa.chunk_index_scores(
+            qi, w, ki, st, interpret=False),
+            [((t, hi, di), dt), ((t, hi), F32), ((s, di), dt),
+             ((), jnp.int32)])
+
+    def paged_decode_under_a_set(dt):
+        pool = ((n_layer, b * nb + 1, kv, bp, d), dt)
+        row = ((b, kv, 1, d), dt)
+
+        def fn(q, tables, pos, layer, gate, kp, vp, nk, nv, sel):
+            return ca.paged_decode_attention(
+                q, kp, vp, tables, pos, layer=layer, new=(nk, nv, gate),
+                sel=sel, interpret=False)
+
+        return fn, [((b, kv, g, d), dt), ((b, nb), jnp.int32),
+                    ((b,), jnp.int32), ((), jnp.int32), ((b,), jnp.bool_),
+                    pool, pool, row, row, ((b, nb * bp), jnp.bool_)]
+
+    return {"prefill_attention": prefill_attention,
+            "index_scores": index_scores,
+            "paged_decode_under_a_set": paged_decode_under_a_set}
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("kernel", ["prefill_attention", "index_scores",
+                                    "paged_decode_under_a_set"])
+def test_sparse_attention_kernels_compile(chip, kernel, dtype):
+    fn, shapes = _sparse_cases()[kernel](dtype)
+    _compile(chip, fn, *shapes)
