@@ -381,6 +381,22 @@ def mixed_step_args(b, chunk_tokens: int) -> tuple:
         jnp.int32(0))
 
 
+def finish_args(b, chunk_tokens: int) -> tuple:
+    """The argument tuple of the finish-and-install program
+    (`b._prefill_finish`, which ends every admission): the batcher's
+    state, a fresh row cache, one chunk's logits, and a request's two
+    number arrays, seen-mask, bias row and block ids."""
+    v = b.cfg.vocab_size
+    row = b._ilv_new_row() if b._ilv else b._new_row()
+    blocks = (np.zeros((2, b.cache["tables"].shape[-1]), np.int32)
+              if b._paged else b._no_blocks)
+    return b._slot_state() + (
+        row, jnp.zeros((1, chunk_tokens, v), jnp.float32),
+        np.zeros((7,), np.int32), np.ones((4,), np.float32),
+        np.zeros((v,), np.bool_), b._no_bias, blocks,
+        b._ctable, b._ctrans)
+
+
 def audit_serving_decode(cfg=None, *, slots: int = 2,
                          max_len: int = 128) -> dict:
     """ISSUE 6 donation-coverage GATE over the SERVING decode programs:
@@ -440,6 +456,32 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
         # (cache, pos, tok, keys, seen — plus crow when constrained)
         lower_and_check(name, b._decode, decode_step_args(b),
                         b._decode_donate, layer_elems)
+        # ISSUE 34 — the finish-and-install program that ends every
+        # admission: the pool AND every per-slot vector it sets are
+        # donated, and each must alias (an un-aliased one is a copy an
+        # admission, and the eager scatters it replaced come back)
+        lower_and_check(name + "_finish", b._prefill_finish,
+                        finish_args(b, 16), b._finish_donate, layer_elems)
+
+    # the same program over the other served families' paged pools: a
+    # LLaMA-MoE (OLMoE's test preset) and one whose pool has a third leaf
+    # (Keye's: the index key installs and aliases with K and V)
+    from dnn_tpu.registry import get_model
+
+    for name, preset in {"paged_olmoe_finish": "olmoe-test",
+                         "paged_keye_finish": "keye-test"}.items():
+        spec = get_model(preset)
+        b = ContinuousBatcher(
+            spec.config,
+            gpt.prepare_stacked(dict(spec.init(jax.random.PRNGKey(0))),
+                                spec.config),
+            slots=slots, max_len=64, prompt_pad=16, kv="paged",
+            allow_logit_bias=True, allow_constraints=True,
+            constraint_rows=8, family=spec.extras["family_rows"]())
+        lower_and_check(
+            name, b._prefill_finish, finish_args(b, 16), b._finish_donate,
+            max(int(np.prod(x.shape[1:])) for kk, x in b.cache.items()
+                if kk != "tables"))
 
     # the speculative step (serving_spec.py): both caches + the per-slot
     # vectors it returns must all alias
@@ -461,26 +503,6 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
     # every donated leaf, zero cache-sized copies.
     p_c = 16
 
-    def ilv_args(b):
-        m_args = mixed_step_args(b, p_c)
-        row = b._ilv_new_row()
-        v = cfg.vocab_size
-        nb_max = (b.cache["tables"].shape[-1] if b._paged else 0)
-        f_args = (b.cache, row,
-                  jnp.zeros((1, p_c, v), jnp.float32),
-                  jnp.int32(0), jnp.int32(0),
-                  jnp.zeros((2,), jnp.uint32), jnp.zeros((2,), jnp.uint32),
-                  b.pos, b.tok, b.active, b.keys, b._temp, b._topk,
-                  b._topp, b._minp, b._rep, b._seen, b._bias,
-                  jnp.float32(0), jnp.int32(0), jnp.float32(0),
-                  jnp.float32(0), jnp.float32(1),
-                  jnp.zeros((v,), jnp.bool_),
-                  jnp.zeros((v if b._allow_bias else 0,), jnp.float32),
-                  jnp.int32(8),
-                  jnp.zeros((nb_max,), jnp.int32),
-                  b._crow, jnp.int32(0), b._ctable, b._ctrans)
-        return m_args, f_args
-
     for name, kw in {"mixed_dense": {},
                      "mixed_paged": {"kv": "paged"},
                      "mixed_bucketed": {"decode_buckets": True},
@@ -498,11 +520,10 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
                            * b._block_len * hd)
         else:
             layer_elems = slots * cfg.n_head * b._cache_len * hd
-        m_args, f_args = ilv_args(b)
-        lower_and_check(name, b._mixed, m_args, b._mixed_donate,
-                        layer_elems)
-        lower_and_check(name + "_finish", b._ilv_finish, f_args,
-                        b._ilv_finish_donate, layer_elems)
+        lower_and_check(name, b._mixed, mixed_step_args(b, p_c),
+                        b._mixed_donate, layer_elems)
+        lower_and_check(name + "_finish", b._prefill_finish,
+                        finish_args(b, p_c), b._finish_donate, layer_elems)
 
     sbm = SpeculativeBatcher(cfg, prepared, cfg, prepared, spec_k=2,
                              slots=slots, max_len=max_len, prompt_pad=16,
@@ -517,22 +538,9 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
     spec_elems = slots * cfg.n_head * max_len * hd
     lower_and_check("mixed_speculative", sbm._spec_mixed, spm_args,
                     sbm._spec_mixed_donate, spec_elems)
-    v = cfg.vocab_size
-    spf_args = (sbm.cache, sbm.d_cache, row, d_row,
-                jnp.zeros((1, p_c, v), jnp.float32),
-                jnp.int32(0), jnp.int32(0),
-                jnp.zeros((2,), jnp.uint32), jnp.zeros((2,), jnp.uint32),
-                sbm.pos, sbm.tok, sbm.active, sbm.keys, sbm._temp,
-                sbm._topk, sbm._topp, sbm._minp, sbm._rep, sbm._seen,
-                sbm._bias,
-                jnp.float32(0), jnp.int32(0), jnp.float32(0),
-                jnp.float32(0), jnp.float32(1),
-                jnp.zeros((v,), jnp.bool_),
-                jnp.zeros((v if sbm._allow_bias else 0,), jnp.float32),
-                jnp.int32(8), jnp.zeros((0,), jnp.int32),
-                sbm._crow, jnp.int32(0), sbm._ctable, sbm._ctrans,
-                jnp.zeros((sbm.spec_k + 1,), jnp.int32),
-                sbm.prev_chunk, sbm.prev_pos)
+    spf_args = finish_args(sbm, p_c) + (
+        sbm.d_cache, sbm.prev_chunk, sbm.prev_pos, d_row,
+        np.zeros((sbm.spec_k + 1,), np.int32))
     lower_and_check("mixed_speculative_finish", sbm._spec_ilv_finish,
                     spf_args, sbm._spec_ilv_finish_donate, spec_elems)
 

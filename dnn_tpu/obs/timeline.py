@@ -125,11 +125,12 @@ _HOST_PHASES = ("admit", "host", "commit", "obs")
 _DEVICE_PHASES = ("dispatch", "wait")
 
 #: what one admission's wall time divides into: `prefill` (dispatching
-#: the chunk and finish programs), `first_token` (the device-to-host read
-#: the host waits for the prefill in), `install` (the eager per-slot
-#: scatters), and `self` — the rest: validation, slot and block
-#: allocation, key folding, prefix lookup. submit() stamps the three
-#: inner intervals; `self` is what they leave of the admit slice.
+#: the chunk programs), `install` (building the inputs of the
+#: finish-and-install program and launching it), `first_token` (the
+#: device-to-host read the host then waits for the prefill in), and
+#: `self` — the rest: validation, slot and block allocation, prefix
+#: lookup. submit() stamps the three inner intervals; `self` is what
+#: they leave of the admit slice.
 ADMIT_PARTS = ("self", "prefill", "first_token", "install")
 _NO_PARTS = (0.0, 0.0, 0.0)
 # the moe.* cumulative series (StepClock.note_moe), each labeled with the

@@ -23,8 +23,12 @@ programs total — chunk prefill, prefill finish, decode step):
     chunk. Tail pad positions write garbage K/V that is never attended
     (the per-row position mask stops at the true length) and is
     overwritten as the sequence grows through it; a second small program
-    samples the first token from the true last prompt row and installs
-    the finished slot-row cache into the pool.
+    FINISHES AND INSTALLS: it derives the request's rng stream, samples
+    the first token from the true last prompt row, installs the finished
+    slot-row cache into the pool and sets every per-slot state vector,
+    all donated — an admission is a fresh row, its chunks and that one
+    program, and nothing else touches the device from `submit()`
+    (tests/test_admit_program.py counts the launches).
   * Slot bookkeeping (which request owns which slot, emitted tokens, EOS)
     is plain host Python — it changes per request, so it must not live
     inside the compiled graphs.
@@ -919,30 +923,61 @@ class ContinuousBatcher:
                                            moe_stats=True)
             return self.family.prefill(prepared, chunk, row, chunk_start)
 
-        def prefill_finish(cache, row, logits, last_local, slot, rng,
-                           temp, tk, tp, mp, rep, seen_row, bias_row,
-                           install_ids, crow, ctable):
-            """Sample the first token from the final chunk's true-last
-            logit row and install the finished row cache into `slot`.
+        def prefill_finish(cache, pos, tok, active, keys, temp_v, tk_v,
+                           tp_v, mp_v, rep_v, seen, bias_buf, crow,
+                           row, logits, ints, floats, seen_row, b_row,
+                           blocks, ctable, ctrans):
+            """FINISH AND INSTALL, the one program that ends every
+            admission (convoy, interleaved, `prefilled=` adoption, a
+            radix hit; the speculative finish wraps it): sample the first
+            token from the final chunk's true-last logit row, install the
+            finished row cache into the slot, and set EVERY per-slot
+            state vector — nothing of an admission is left for Python to
+            scatter. The first thirteen arguments are the batcher's state
+            (`_slot_state`), donated and handed back in the same order;
+            the request arrives as two host arrays:
+
+              ints (7,) int32: slot, last_local (the true last prompt
+                row within `logits`), prompt_len, top_k, c_row (this
+                request's start-state row in the constraint mask pool, 0
+                = unconstrained, so the FIRST token obeys the grammar
+                too and `crow[slot] = ctrans[c_row, first]` seeds the
+                device walk), and the two uint32 words that name its rng
+                stream (bit patterns): the namespace and the request
+                seed or id;
+              floats (4,) float32: temperature, top_p, min_p and the
+                repetition penalty.
+
+            The request's private stream is derived HERE, from (server
+            seed, namespace, request seed) — independent of what else is
+            in the pool or when this arrived; the namespace fold keeps
+            auto-assigned rids and explicit seeds from colliding (rid=3
+            vs seed=3 must be distinct streams). One half samples the
+            first token, the other is the slot's key from then on.
             `seen_row` (V,) marks the prompt's tokens so the repetition
-            penalty applies to the FIRST sample too. `install_ids` (paged
-            mode): the per-logical-block physical install targets — shared
-            prefix blocks routed to junk block 0 (dense mode receives an
-            empty placeholder). `crow` (scalar) indexes this request's
-            start-state row in the constraint mask pool (0 =
-            unconstrained) so the FIRST token obeys the grammar too."""
+            penalty applies to the FIRST sample too. `blocks` (2, nb_max)
+            (paged mode; (2, 0) for a dense pool): the slot's table row,
+            and the per-logical-block physical install targets — shared
+            prefix blocks routed to junk block 0."""
+            slot, last_local, prompt_len, k, c_row = (
+                ints[i] for i in range(5))
+            words = lax.bitcast_convert_type(ints[5:7], jnp.uint32)
+            t, p, mp_, rp = (floats[i] for i in range(4))
+            rng, slot_key = jax.random.split(jax.random.fold_in(
+                jax.random.fold_in(jax.random.PRNGKey(self._seed), words[0]),
+                words[1]))
             with jax.named_scope("sample"):
                 lg = logits[:, last_local][0:1]  # (1, V)
                 raw = lg
                 lg = apply_repetition_penalty(
-                    lg, (rep != 1.0) & seen_row[None, :], rep)
+                    lg, (rp != 1.0) & seen_row[None, :], rp)
                 if self._allow_bias:
-                    lg = lg + bias_row[None, :]
+                    lg = lg + b_row[None, :]
                 if self._allow_constraints:
-                    lg = jnp.where(ctable[crow][None, :], lg, _NEG_BIG)
+                    lg = jnp.where(ctable[c_row][None, :], lg, _NEG_BIG)
                 first = _sample_rows(
-                    lg, rng[None], temperature=temp[None], top_k=tk[None],
-                    top_p=tp[None], min_p=mp[None],
+                    lg, rng[None], temperature=t[None], top_k=k[None],
+                    top_p=p[None], min_p=mp_[None],
                 )[0]
             # the row cache is chunk-rounded (possibly > the pool); only
             # the pool's own position count installs — the overhang holds
@@ -950,13 +985,30 @@ class ContinuousBatcher:
             # submit() bounds the prompt by max_len and, on a bucketed
             # pool, grows the pool past the prompt before finishing)
             if self._paged:
-                cache = codec.install_row(cache, row, install_ids)
+                cache = codec.install_row(cache, row, blocks[1])
+                cache["tables"] = cache["tables"].at[:, slot].set(blocks[0])
             else:
                 cache = install_dense_row(cache, row, slot)
+            pos = pos.at[slot].set(prompt_len)
+            tok = tok.at[slot].set(first)
+            active = active.at[slot].set(True)
+            keys = keys.at[slot].set(slot_key)
+            temp_v = temp_v.at[slot].set(t)
+            tk_v = tk_v.at[slot].set(k)
+            tp_v = tp_v.at[slot].set(p)
+            mp_v = mp_v.at[slot].set(mp_)
+            rep_v = rep_v.at[slot].set(rp)
+            seen = seen.at[slot].set(seen_row.at[first].set(True))
+            if self._allow_bias:
+                bias_buf = bias_buf.at[slot].set(b_row)
+            if self._allow_constraints:
+                crow = crow.at[slot].set(ctrans[c_row, first])
+            out = (cache, pos, tok, active, keys, temp_v, tk_v, tp_v, mp_v,
+                   rep_v, seen, bias_buf, crow, first)
             if logprobs_k:
                 # raw model distribution, as in decode_step
-                return (cache, first) + _lp_outputs(raw, first[None])
-            return cache, first
+                out += _lp_outputs(raw, first[None])
+            return out
 
         # the transient slot-row cache rounds max_len UP to whole chunks:
         # a tail chunk starting at (n_chunks-1)*prompt_pad must never have
@@ -964,7 +1016,16 @@ class ContinuousBatcher:
         # updates clamp silently — an unrounded row corrupts the cache
         # whenever max_len % prompt_pad != 0)
         self._row_len = -(-self.max_len // self.prompt_pad) * self.prompt_pad
-        self._new_row = lambda: self.family.init_cache(1, self._row_len, cache_dtype)
+
+        def row_program(row_len):
+            """A fresh transient row as ONE launch (eagerly it is a
+            broadcast a leaf); a new buffer every call — the chunk loop
+            donates the row."""
+            def new_row():
+                return self.family.init_cache(1, row_len, cache_dtype)
+            return jax.jit(new_row)
+
+        self._new_row = row_program(self._row_len)
         # donate the caches: without aliasing, every token would copy the
         # whole (L, B, H, S, D) cache (hundreds of MB of HBM traffic per
         # step at real sizes). The call sites reassign from the results,
@@ -985,11 +1046,33 @@ class ContinuousBatcher:
         self._decode = jax.jit(decode_step,
                                donate_argnums=self._decode_donate)
         self._prefill_chunk = jax.jit(prefill_chunk, donate_argnums=(1,))
-        # the transient row (arg 1) is SLICED into the pool, never
-        # returned whole — donating it aliases nothing (an unusable
-        # donation that warned on every prefill); only the pool cache
-        # donation is real
-        self._prefill_finish = jax.jit(prefill_finish, donate_argnums=(0,))
+        # the finish donates the pool cache and every per-slot vector it
+        # returns (`active` included — the finish RETURNS it, unlike the
+        # decode step where it is host-updated between calls), the bias
+        # buffer only when it is real and crow only on constrained
+        # servers (elsewhere the finish returns them untouched — an
+        # un-aliasable donation). The transient row is SLICED into the
+        # pool, never returned whole: donating it would alias nothing.
+        # The speculative variant composes its own finish from this core
+        # (serving_spec.SpeculativeBatcher)
+        self._finish_core = prefill_finish
+        self._finish_donate = tuple(range(11)) + (
+            (11,) if self._allow_bias else ()) + (
+            (12,) if self._allow_constraints else ())
+        self._prefill_finish = jax.jit(
+            prefill_finish, donate_argnums=self._finish_donate)
+        # the finish-shaped logits of an admission that ran no chunk (a
+        # whole-prompt prefix hit, an adopted prefill): the stored true-
+        # last logit row in place, so the finish keeps its one shape
+        self._row_logits = jax.jit(
+            lambda lr, at: jnp.zeros(
+                (1, self.prompt_pad, lr.shape[-1]), lr.dtype
+            ).at[0, at].set(lr))
+        # what an admission passes for an absent logit_bias and, on a
+        # dense pool, for the block ids — built once, not per request
+        self._no_bias = jnp.zeros(
+            (cfg.vocab_size if self._allow_bias else 0,), jnp.float32)
+        self._no_blocks = np.zeros((2, 0), np.int32)
 
         # KV-tier device programs (dnn_tpu/kvtier) — only compiled-in
         # when the radix store is on:
@@ -1119,13 +1202,9 @@ class ContinuousBatcher:
         # _row_len above)
         self._ilv_row_len = (-(-self.max_len // self._ilv) * self._ilv
                              if self._ilv else 0)
-        self._ilv_new_row = (
-            (lambda: self.family.init_cache(1, self._ilv_row_len,
-                                            cache_dtype))
-            if self._ilv else None)
+        self._ilv_new_row = (row_program(self._ilv_row_len)
+                             if self._ilv else None)
         self._mixed = None
-        self._ilv_finish = None
-        self._ilv_finish_core = None
         if self._ilv:
             # donate the decode leg's state exactly as _decode does, plus
             # the prefill leg's transient row — audited like every other
@@ -1134,83 +1213,6 @@ class ContinuousBatcher:
                 (14,) if self._allow_constraints else ())
             self._mixed = jax.jit(mixed_step,
                                   donate_argnums=self._mixed_donate)
-
-            def ilv_finish(cache, row, logits, last_local, slot, rng,
-                           slot_key, pos, tok, active, keys, temp_v,
-                           tk_v, tp_v, mp_v, rep_v, seen, bias_buf,
-                           t, k, p, mp_, rp, seen_row, b_row,
-                           prompt_len, install_ids, crow, c_row,
-                           ctable, ctrans):
-                """Fused admission finish: sample the first token from
-                the final chunk's true-last logit row (the request's own
-                temperature/top-k/top-p/min-p/repetition params and rng
-                stream — the same math as the convoy prefill_finish, so
-                sampled streams agree draw-for-draw), install the row
-                cache into `slot`, and scatter EVERY per-slot state
-                vector (pos/tok/active/keys/sampling params/seen/bias
-                — and the slot's DFA state: `c_row` (scalar) is the
-                grammar's global start row, masking the FIRST token and
-                seeding `crow[slot] = ctrans[c_row, first]` on device,
-                so constrained interleaved admission never syncs). Only
-                the sampled token id (+ logprobs when compiled in) ever
-                crosses to host, and even that readback is deferred to
-                the next step's commit — admission costs zero blocking
-                syncs."""
-                with jax.named_scope("sample"):
-                    lg = logits[:, last_local][0:1]  # (1, V)
-                    raw = lg
-                    lg = apply_repetition_penalty(
-                        lg, (rp != 1.0) & seen_row[None, :], rp)
-                    if self._allow_bias:
-                        lg = lg + b_row[None, :]
-                    if self._allow_constraints:
-                        lg = jnp.where(ctable[c_row][None, :], lg, _NEG_BIG)
-                    first = _sample_rows(
-                        lg, rng[None], temperature=t[None], top_k=k[None],
-                        top_p=p[None], min_p=mp_[None],
-                    )[0]
-                if self._paged:
-                    cache = codec.install_row(cache, row, install_ids)
-                else:
-                    cache = install_dense_row(cache, row, slot)
-                pos = pos.at[slot].set(prompt_len)
-                tok = tok.at[slot].set(first)
-                active = active.at[slot].set(True)
-                keys = keys.at[slot].set(slot_key)
-                temp_v = temp_v.at[slot].set(t)
-                tk_v = tk_v.at[slot].set(k)
-                tp_v = tp_v.at[slot].set(p)
-                mp_v = mp_v.at[slot].set(mp_)
-                rep_v = rep_v.at[slot].set(rp)
-                seen = seen.at[slot].set(seen_row.at[first].set(True))
-                if self._allow_bias:
-                    bias_buf = bias_buf.at[slot].set(b_row)
-                if self._allow_constraints:
-                    crow = crow.at[slot].set(ctrans[c_row, first])
-                out = (cache, pos, tok, active, keys, temp_v, tk_v,
-                       tp_v, mp_v, rep_v, seen, bias_buf, crow, first)
-                if logprobs_k:
-                    out += _lp_outputs(raw, first[None])
-                return out
-
-            # the speculative variant composes its own fused finish from
-            # this core (serving_spec.SpeculativeBatcher)
-            self._ilv_finish_core = ilv_finish
-            # donate the pool cache and every returned per-slot vector
-            # (active included — the finish RETURNS it, unlike the decode
-            # step where it is host-updated between calls); the transient
-            # row is sliced, never returned whole (the prefill_finish
-            # lesson), the bias buffer only when it is real, and crow
-            # only on constrained servers (unconstrained finishes return
-            # it untouched — an un-aliasable donation)
-            donate = [0, 7, 8, 9, 10, 11, 12, 13, 14, 15, 16]
-            if self._allow_bias:
-                donate.append(17)
-            if self._allow_constraints:
-                donate.append(27)
-            self._ilv_finish_donate = tuple(donate)
-            self._ilv_finish = jax.jit(
-                ilv_finish, donate_argnums=self._ilv_finish_donate)
 
         # the decode step's param argument: a lora_view when multi-LoRA is
         # on (rebuilt whenever a slot's adapter assignment changes — same
@@ -1222,13 +1224,14 @@ class ContinuousBatcher:
         counts toward its compile-cache budget (lm_server's
         CompileCacheGuard). Variants with extra programs
         (SpeculativeBatcher) extend this."""
-        fns = [self._decode, self._prefill_chunk, self._prefill_finish]
+        fns = [self._decode, self._new_row, self._prefill_chunk,
+               self._prefill_finish]
         if self._paged:
             fns.append(self._gather_row)
         if self._buckets is not None:
             fns.append(self._grow_cache)
         if self._mixed is not None:
-            fns += [self._mixed, self._ilv_finish]
+            fns += [self._mixed, self._ilv_new_row]
         if self._prefix_store is not None:
             fns += [self._cow_copy, self._kv_put_block,
                     self._kvtier_install, self._kv_get_block]
@@ -1408,9 +1411,7 @@ class ContinuousBatcher:
                     "a dedicated special token as eos")
         b_row = logit_bias_row(logit_bias, self.cfg.vocab_size)
         if b_row is None:
-            b_row = jnp.zeros(
-                (self.cfg.vocab_size if self._allow_bias else 0,),
-                jnp.float32)
+            b_row = self._no_bias
         c_off = None
         if constraint is not None:
             # a grammar matching ONLY the empty string is legal when eos
@@ -1492,7 +1493,7 @@ class ContinuousBatcher:
         if use_radix:
             kv_hit = self._prefix_store.lookup(prompt)
 
-        paged_taken, install_ids, n_shared = None, None, 0
+        paged_taken, blocks, n_shared = None, self._no_blocks, 0
         cow_src, cow_tok = -1, 0
         if self._paged:
             from dnn_tpu.runtime.paged_kvcache import InsufficientBlocks
@@ -1568,11 +1569,12 @@ class ContinuousBatcher:
                 raise
             self._pool_exhausted_episode = False  # blocks came free
             paged_taken = shared_ids + owned
-            nb_max = self.cache["tables"].shape[-1]
-            ids_row = np.zeros((nb_max,), np.int32)
-            ids_row[:n_need] = paged_taken
-            self.cache["tables"] = self.cache["tables"].at[:, slot].set(
-                jnp.asarray(ids_row))
+            # the slot's table row, and under it the install targets:
+            # both reach the device with the finish program, which writes
+            # the row into the tables (nothing reads a slot's row before
+            # its first decode step)
+            blocks = np.zeros((2, self.cache["tables"].shape[-1]), np.int32)
+            blocks[:, :n_need] = paged_taken
             if cow_tok > 0:
                 # copy-on-write at the divergence boundary: duplicate
                 # the ONE cached block this prompt still partially
@@ -1587,17 +1589,14 @@ class ContinuousBatcher:
                 # write could recycle the source).
                 try:
                     self.cache = self._cow_copy(
-                        self.cache, jnp.int32(cow_src),
-                        jnp.int32(owned[0]))
+                        self.cache, np.int32(cow_src), np.int32(owned[0]))
                 finally:
                     # the temporary reference drops either way — a
                     # failed dispatch must not strand the source block
                     self._allocator.free([cow_src])
             # install must NOT touch shared blocks (another request's live
             # prefix): their install targets are routed to junk block 0
-            inst = ids_row.copy()
-            inst[:n_shared] = 0
-            install_ids = jnp.asarray(inst)
+            blocks[1, :n_shared] = 0
             if kv_hit is not None and (n_shared or cow_tok):
                 # admission HOLDS the blocks now — record the reuse
                 # (post-truncation, post-allocation: the hit ratio
@@ -1620,15 +1619,27 @@ class ContinuousBatcher:
         try:
             rid = self._next_rid
             self._next_rid += 1
-            # this request's private stream: (server seed, namespace, request
-            # seed) — independent of what else is in the pool or when this
-            # arrived. The namespace fold keeps auto-assigned rids and explicit
-            # seeds from colliding (rid=3 vs seed=3 must be distinct streams).
-            base = jax.random.fold_in(
-                jax.random.PRNGKey(self._seed), 0 if seed is None else 1
-            )
-            req_key = jax.random.fold_in(base, rid if seed is None else seed)
-            prefill_key, slot_key = jax.random.split(req_key)
+            # what names this request's private rng stream (derived in
+            # the finish program): the namespace — 0: the auto-assigned
+            # rid, 1: an explicit seed — and the rid or seed; a seed that
+            # is no uint32 fails here, before any prefill
+            stream = np.asarray((0, rid) if seed is None else (1, seed),
+                                np.uint32)
+
+            def finish_request(last_local):
+                """The request as the finish program takes it
+                (prefill_finish documents the fields): its numbers as TWO
+                host arrays, the prompt's seen-mask, the bias row and the
+                block ids — the same for both admit paths, so greedy AND
+                sampled streams agree token-for-token across them."""
+                ints = np.empty((7,), np.int32)
+                ints[:5] = (slot, last_local, len(prompt), tk,
+                            0 if c_off is None else c_off + constraint.start)
+                ints[5:] = stream.view(np.int32)
+                seen_row = np.zeros((self.cfg.vocab_size,), bool)
+                seen_row[prompt] = True
+                return (ints, np.asarray((temp, tp, mp, rp), np.float32),
+                        seen_row, b_row, blocks)
 
             if self._ilv:
                 # interleaved admission (ISSUE 12): NO device work here.
@@ -1636,15 +1647,11 @@ class ContinuousBatcher:
                 # (mixed_step), the fused finish samples the first token
                 # on device, and its readback rides a later step's
                 # commit — submit() is host bookkeeping only, so the
-                # prefill convoy never forms. rng derivation above is
-                # identical to the convoy path, so greedy AND sampled
-                # streams agree token-for-token across the two paths.
+                # prefill convoy never forms.
                 p_c = self._ilv
                 n_c = -(-len(prompt) // p_c)
                 padded_i = np.zeros((1, n_c * p_c), np.int32)
                 padded_i[0, : len(prompt)] = prompt
-                seen_np = np.zeros((self.cfg.vocab_size,), bool)
-                seen_np[prompt] = True
                 if self._lora is not None and self._aid[slot] != aid:
                     self._aid[slot] = aid
                     self._decode_view = self._lora_prepared(self._aid)
@@ -1658,23 +1665,10 @@ class ContinuousBatcher:
                            "padded": padded_i, "n_chunks": n_c,
                            "next": 0, "row": self._ilv_new_row(),
                            "aid": aid,
-                           "last_local":
-                               len(prompt) - 1 - (n_c - 1) * p_c,
-                           "prefill_key": prefill_key,
-                           "slot_key": slot_key,
-                           "t": temp, "k": tk, "p": tp, "mp": mp,
-                           "rp": rp,
-                           "seen_row": jnp.asarray(seen_np),
-                           "b_row": b_row,
-                           "install_ids": install_ids
-                           if install_ids is not None
-                           else jnp.zeros((0,), jnp.int32),
-                           # the grammar's global start row: the fused
-                           # finish masks the first token with it and
-                           # seeds crow[slot] on device (0 = the
-                           # reserved unconstrained row)
-                           "c_row": (0 if c_off is None
-                                     else c_off + constraint.start),
+                           # what the finish takes beside the state, the
+                           # row and the last chunk's logits
+                           "finish": finish_request(
+                               (len(prompt) - 1) % p_c),
                        }}
                 if constraint is not None:
                     req["constraint"] = constraint
@@ -1726,10 +1720,7 @@ class ContinuousBatcher:
                     # p_pad-1 == the true last prompt token of an exact
                     # full-chunk prompt) so _prefill_finish keeps its one
                     # compiled shape
-                    logits = jnp.zeros(
-                        (1, p_pad, last_logit_row.shape[-1]),
-                        last_logit_row.dtype,
-                    ).at[0, p_pad - 1].set(last_logit_row)
+                    logits = self._row_logits(last_logit_row, p_pad - 1)
             pf_prepared = self._lora_prefill_view(aid)
             sp_pf = adm.child("prefill", chunks=n_chunks - start_chunk,
                               prompt_len=len(prompt))
@@ -1739,7 +1730,7 @@ class ContinuousBatcher:
             _sp = _profile.open_span("admit.prefill", rid=rid,
                                      chunks=n_chunks - start_chunk)
             chunks_before = self.prefill_chunks_run
-            last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
+            last_local = (len(prompt) - 1) % p_pad
             kv_boundary_rows: dict = {}
             if prefilled is not None:
                 # KV ADOPTION (disaggregated serving, dnn_tpu/control):
@@ -1750,16 +1741,16 @@ class ContinuousBatcher:
                 row, logits = self._adopt_prefilled(prefilled, prompt)
             elif use_radix:
                 row, logits, last_local = self._radix_prefill(
-                    prompt, slot, pf_prepared, row, kv_hit, n_shared,
+                    prompt, blocks[0], pf_prepared, row, kv_hit, n_shared,
                     cow_tok, kv_boundary_rows)
             else:
+                ahead: deque = deque()
                 for c in range(start_chunk, n_chunks):
                     with _prof_annotation("serving.prefill_chunk"):
                         logits, row = self._run_prefill_chunk(
-                            pf_prepared, row,
-                            jnp.asarray(
-                                padded[:, c * p_pad:(c + 1) * p_pad]),
-                            jnp.int32(c * p_pad),
+                            ahead, pf_prepared, row,
+                            padded[:, c * p_pad:(c + 1) * p_pad],
+                            np.int32(c * p_pad),
                         )
                     self.prefill_chunks_run += 1
                     if self._prefix_cache is not None \
@@ -1778,23 +1769,16 @@ class ContinuousBatcher:
                             jax.tree.map(jnp.copy, row),
                             jnp.copy(logits[0, -1]))
                         self._prefix_cache.move_to_end(key, last=False)
-            t_arr = jnp.float32(temp)
-            k_arr = jnp.int32(tk)
-            p_arr = jnp.float32(tp)
-            seen_np = np.zeros((self.cfg.vocab_size,), bool)
-            seen_np[prompt] = True
-            seen_row = jnp.asarray(seen_np)
-            fin = self._prefill_finish(
-                self.cache, row, logits, last_local, slot, prefill_key,
-                t_arr, k_arr, p_arr, jnp.float32(mp), jnp.float32(rp),
-                seen_row, b_row,
-                install_ids if install_ids is not None
-                else jnp.zeros((0,), jnp.int32),
-                jnp.int32(0 if c_off is None
-                          else c_off + constraint.start),
-                self._ctable,
-            )
-            t_pf1 = time.perf_counter()  # both programs are dispatched
+            t_pf1 = time.perf_counter()  # every chunk is dispatched
+            _profile.close_span(_sp)
+            # finish and install (admit.install): build the program's
+            # inputs and launch it — the admission's LAST device program,
+            # which leaves nothing for Python to scatter
+            t_in0 = time.perf_counter()
+            _sp = _profile.open_span("admit.install", rid=rid)
+            first, first_lps = self._finish(
+                row, logits, finish_request(last_local))
+            t_in1 = time.perf_counter()
             _profile.close_span(_sp)
             if use_radix:
                 # insert this prompt's full-block path now that the
@@ -1814,10 +1798,6 @@ class ContinuousBatcher:
                         prompt[: n_cover * self._block_len],
                         [int(x) for x in paged_taken[:n_cover]],
                         logit_rows=kv_boundary_rows, origin=kv_borig)
-            if self._logprobs_k:
-                self.cache, first, c_lp, t_lp, t_ids = fin
-            else:
-                self.cache, first = fin
             # the device-to-host read: where the host waits for the
             # prefill (admit.first_token)
             t_ft0 = time.perf_counter()
@@ -1826,6 +1806,7 @@ class ContinuousBatcher:
             if self._moe_stats:
                 self._moe_flush()
             if logprobs and self._logprobs_k:
+                c_lp, t_lp, t_ids = first_lps
                 first_lp = (float(np.asarray(c_lp)[0]),
                             (np.asarray(t_ids)[0], np.asarray(t_lp)[0]))
             t_ft1 = time.perf_counter()
@@ -1880,25 +1861,7 @@ class ContinuousBatcher:
                     self._kvlens.note_prefill(
                         self.prefill_chunks_run - chunks_before,
                         time.perf_counter() - t_pf)
-            # the eager per-slot scatters (admit.install): each is a
-            # small program of its own, dispatched from here
-            t_in0 = time.perf_counter()
-            _sp = _profile.open_span("admit.install", rid=rid)
-            self.pos = self.pos.at[slot].set(len(prompt))
-            self.tok = self.tok.at[slot].set(first)
-            self.active = self.active.at[slot].set(True)
-            self.keys = self.keys.at[slot].set(slot_key)
-            self._temp = self._temp.at[slot].set(temp)
-            self._topk = self._topk.at[slot].set(tk)
-            self._topp = self._topp.at[slot].set(tp)
-            self._minp = self._minp.at[slot].set(mp)
-            self._rep = self._rep.at[slot].set(rp)
-            self._seen = self._seen.at[slot].set(
-                seen_row.at[first].set(True))
-            self._bias = self._bias.at[slot].set(b_row)
-            _profile.close_span(_sp)
-            _parts = (t_pf1 - t_pf, t_ft1 - t_ft0,
-                      time.perf_counter() - t_in0)
+            _parts = (t_pf1 - t_pf, t_ft1 - t_ft0, t_in1 - t_in0)
             if self._lora is not None and self._aid[slot] != aid:
                 self._aid[slot] = aid
                 self._decode_view = self._lora_prepared(self._aid)
@@ -1932,22 +1895,14 @@ class ContinuousBatcher:
                 req["install_step"] = self._step_idx - 1
             self._slot_req[slot] = req
             if constraint is not None:
-                # convoy admission is the one place the host seeds the
-                # device walk: the first token was sampled by
-                # _prefill_finish (masked with the grammar's start row)
-                # and read back above, so mirror-walk it and install
-                # the post-first-token state — every later advance
-                # happens inside the decode program. Prefix-cache /
-                # kvtier / prefilled adoption changes nothing: the
-                # grammar constrains GENERATED tokens only, so the
-                # adopted prefix's state is still `start`.
+                # host mirror of the walk the finish already did on
+                # device (the first token masked with the grammar's start
+                # row, crow[slot] seeded from it) — finish detection
+                # only. Prefix-cache / kvtier / prefilled adoption
+                # changes nothing: the grammar constrains GENERATED
+                # tokens only, so the adopted prefix's state is still
+                # `start`.
                 self._constraint_advance(slot, first)
-                with _prof_annotation("admit.install", rid=rid):
-                    t_in0 = time.perf_counter()
-                    self._crow = self._crow.at[slot].set(
-                        jnp.int32(c_off + req["c_state"]))
-                    _parts = _parts[:2] + (
-                        _parts[2] + time.perf_counter() - t_in0,)
             # a prompt longer than the window rolls blocks out at install
             self._free_rolled_blocks(slot)
             self._retire_if_done(slot)
@@ -1981,10 +1936,50 @@ class ContinuousBatcher:
             if _sc is not None:
                 _sc.note_admit(_t_sub, _parts)
 
-    def _run_prefill_chunk(self, *args):
+    def _slot_state(self) -> tuple:
+        """The device state an admission's finish rewrites — the pool
+        cache and every per-slot vector — in the order the finish
+        program takes and returns it."""
+        return (self.cache, self.pos, self.tok, self.active, self.keys,
+                self._temp, self._topk, self._topp, self._minp, self._rep,
+                self._seen, self._bias, self._crow)
+
+    def _set_slot_state(self, state):
+        (self.cache, self.pos, self.tok, self.active, self.keys,
+         self._temp, self._topk, self._topp, self._minp, self._rep,
+         self._seen, self._bias, self._crow) = state
+
+    def _finish(self, row, logits, request):
+        """Launch the finish-and-install program for `request` (submit's
+        `finish_request`) on the finished `row` and the last chunk's
+        `logits` -> (first token, its logprob outputs or ()), all still
+        on the device."""
+        out = self._prefill_finish(*self._slot_state(), row, logits,
+                                   *request, self._ctable, self._ctrans)
+        self._set_slot_state(out[:13])
+        return out[13], out[14:]
+
+    #: how many bytes of chunk logits a chunk loop may have dispatched and
+    #: not yet computed. The loop runs ahead of the device, and a
+    #: dispatched chunk's (1, prompt_pad, V) logits are allocated at
+    #: dispatch and freed when it has run: GPT-2's 13 MB a chunk never
+    #: reach this, a 1024-token chunk over a 152 k vocabulary (622 MB)
+    #: runs two deep — one computing, one queued, the device never
+    #: waiting for the host — where a dozen deep was 4.4 GB of the chip
+    _CHUNK_AHEAD_BYTES = 1 << 30
+
+    def _run_prefill_chunk(self, ahead: deque, *args):
         """The chunk program -> (logits, row); an MoE family's third
-        result, the chunk's expert-layer stats, is noted on the way."""
+        result, the chunk's expert-layer stats, is noted on the way.
+        `ahead`: the loop's own queue of the logits it has dispatched
+        and the device has not computed yet (empty before its first
+        chunk), held to _CHUNK_AHEAD_BYTES by waiting for the oldest."""
+        while ahead and ahead[0].is_ready():
+            ahead.popleft()
         res = self._prefill_chunk(*args)
+        ahead.append(res[0])
+        if len(ahead) * res[0].nbytes > self._CHUNK_AHEAD_BYTES:
+            ahead.popleft().block_until_ready()
         if self._moe_stats:
             self._moe_note("prefill", res[2])
         if self._index_topk and self.step_clock is not None:
@@ -2102,12 +2097,13 @@ class ContinuousBatcher:
         row = self._new_row()
         logits = None
         t_pf = time.perf_counter()
+        ahead: deque = deque()
         for c in range(n_chunks):
             with _prof_annotation("serving.prefill_chunk"):
                 logits, row = self._run_prefill_chunk(
-                    self.prepared, row,
-                    jnp.asarray(padded[:, c * p_pad:(c + 1) * p_pad]),
-                    jnp.int32(c * p_pad),
+                    ahead, self.prepared, row,
+                    padded[:, c * p_pad:(c + 1) * p_pad],
+                    np.int32(c * p_pad),
                 )
             self.prefill_chunks_run += 1
         last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
@@ -2166,16 +2162,10 @@ class ContinuousBatcher:
             raise ValueError(
                 f"handoff logits_row has shape {lr.shape}, expected "
                 f"({self.cfg.vocab_size},)")
-        p_pad = self.prompt_pad
-        n_chunks = -(-len(prompt) // p_pad)
-        last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
-        lr_j = jnp.asarray(lr)
-        logits = jnp.zeros((1, p_pad, lr_j.shape[0]), lr_j.dtype
-                           ).at[0, last_local].set(lr_j)
         m = obs.metrics()
         if m is not None:
             m.inc("serving.kv_adoptions_total")
-        return row, logits
+        return row, self._row_logits(lr, (len(prompt) - 1) % self.prompt_pad)
 
     # -- fleet KV tier (dnn_tpu/kvtier): stage / export / adopt ---------
 
@@ -2371,13 +2361,14 @@ class ContinuousBatcher:
             boundary: dict = {}
             logits = None
             t_pf = time.perf_counter()
+            ahead: deque = deque()
             for i in range(n_k):
                 start = resume + i * p_pad
                 with _prof_annotation("serving.prefill_chunk"):
                     logits, row = self._run_prefill_chunk(
-                        self.prepared, row,
-                        jnp.asarray(padded[:, i * p_pad:(i + 1) * p_pad]),
-                        jnp.int32(start))
+                        ahead, self.prepared, row,
+                        padded[:, i * p_pad:(i + 1) * p_pad],
+                        np.int32(start))
                 self.prefill_chunks_run += 1
                 for b in range(start // bp, n_cover):
                     pos = (b + 1) * bp - 1
@@ -2446,7 +2437,7 @@ class ContinuousBatcher:
                           cause=cause))
         obs.flight.record("prefix_evict", entries_left=left, cause=cause)
 
-    def _radix_prefill(self, prompt, slot, pf_prepared, row, kv_hit,
+    def _radix_prefill(self, prompt, ids_row, pf_prepared, row, kv_hit,
                        n_shared, cow_tok, boundary_rows):
         """The radix-store admission prefill: resume the chunk loop at
         the first non-cached position instead of chunk 0.
@@ -2481,11 +2472,9 @@ class ContinuousBatcher:
         p_pad = self.prompt_pad
         if kv_hit.logit_row is not None and p_len == n_shared * bp \
                 and cow_tok == 0:
-            lr = jnp.asarray(kv_hit.logit_row)
             last_local = (p_len - 1) % p_pad
-            logits = jnp.zeros((1, p_pad, lr.shape[-1]), lr.dtype
-                               ).at[0, last_local].set(lr)
-            return row, logits, last_local
+            return (row, self._row_logits(kv_hit.logit_row, last_local),
+                    last_local)
         resume = min(n_shared * bp + cow_tok, p_len - 1)
         if resume + (-(-(p_len - resume) // p_pad)) * p_pad \
                 > self._row_len:
@@ -2496,19 +2485,21 @@ class ContinuousBatcher:
             # positions — whose installs route to junk, never corrupt
             resume = (resume // p_pad) * p_pad
         if resume:
-            row = self._gather_row(self.cache,
-                                   self.cache["tables"][0, slot])
+            # by the slot's table row `ids_row`, which reaches the
+            # device's tables only with the finish
+            row = self._gather_row(self.cache, ids_row)
         n_k = -(-(p_len - resume) // p_pad)
         padded_r = np.zeros((1, n_k * p_pad), np.int32)
         padded_r[0, : p_len - resume] = prompt[resume:]
         logits = None
+        ahead: deque = deque()
         for i in range(n_k):
             start = resume + i * p_pad
             with _prof_annotation("serving.prefill_chunk"):
                 logits, row = self._run_prefill_chunk(
-                    pf_prepared, row,
-                    jnp.asarray(padded_r[:, i * p_pad:(i + 1) * p_pad]),
-                    jnp.int32(start))
+                    ahead, pf_prepared, row,
+                    padded_r[:, i * p_pad:(i + 1) * p_pad],
+                    np.int32(start))
             self.prefill_chunks_run += 1
             for b in range(start // bp, p_len // bp):
                 pos = (b + 1) * bp - 1
@@ -3017,9 +3008,8 @@ class ContinuousBatcher:
             c = p["next"]
             p_c = self._ilv
             return {"slot": slot, "req": req, "p": p,
-                    "chunk": jnp.asarray(
-                        p["padded"][:, c * p_c:(c + 1) * p_c]),
-                    "start": jnp.int32(c * p_c),
+                    "chunk": p["padded"][:, c * p_c:(c + 1) * p_c],
+                    "start": np.int32(c * p_c),
                     "last": c + 1 == p["n_chunks"]}
         return None
 
@@ -3029,7 +3019,7 @@ class ContinuousBatcher:
         (install + on-device first-token sample + slot-state scatter,
         one program) and defer the first token's readback to the next
         step's commit: admission never blocks on a device->host sync."""
-        req, p, slot = ilv["req"], ilv["p"], ilv["slot"]
+        req, p = ilv["req"], ilv["p"]
         self.prefill_chunks_run += 1
         m = obs.metrics()
         if m is not None:
@@ -3039,23 +3029,8 @@ class ContinuousBatcher:
             p["next"] += 1
             return
         self._pending_q.pop(0)
-        fin = self._ilv_finish(
-            self.cache, new_row, pf_logits,
-            jnp.int32(p["last_local"]), jnp.int32(slot),
-            p["prefill_key"], p["slot_key"],
-            self.pos, self.tok, self.active, self.keys,
-            self._temp, self._topk, self._topp, self._minp, self._rep,
-            self._seen, self._bias,
-            jnp.float32(p["t"]), jnp.int32(p["k"]), jnp.float32(p["p"]),
-            jnp.float32(p["mp"]), jnp.float32(p["rp"]),
-            p["seen_row"], p["b_row"],
-            jnp.int32(req["prompt_len"]), p["install_ids"],
-            self._crow, jnp.int32(p["c_row"]),
-            self._ctable, self._ctrans)
-        (self.cache, self.pos, self.tok, self.active, self.keys,
-         self._temp, self._topk, self._topp, self._minp, self._rep,
-         self._seen, self._bias, self._crow, first) = fin[:14]
-        req["first_dev"] = (first, fin[14:] if req["logprobs"] else None)
+        first, first_lps = self._finish(new_row, pf_logits, p["finish"])
+        req["first_dev"] = (first, first_lps if req["logprobs"] else None)
         req["install_step"] = s_idx
         del req["pending"]
 
@@ -3220,10 +3195,7 @@ class ContinuousBatcher:
         # one shared positional block for both dispatch forms — the
         # mixed program's decode leg takes the decode step's exact
         # argument order (donate_argnums indices align by construction)
-        state = (self.cache, self.pos, self.tok, self.active, self.keys,
-                 self._temp, self._topk, self._topp, self._minp,
-                 self._rep, self._seen, self._bias, self._crow,
-                 self._ctable, self._ctrans)
+        state = self._slot_state() + (self._ctable, self._ctrans)
         if ilv is None:
             res = self._decode(self._decode_view, *state)
         else:

@@ -353,22 +353,23 @@ class SpeculativeBatcher(ContinuousBatcher):
             self._spec_mixed = jax.jit(
                 spec_mixed, donate_argnums=self._spec_mixed_donate)
 
-            parent_fin = self._ilv_finish_core
+            parent_fin = self._finish_core
+            # its arguments: the slot state, then row, logits, ints and
+            # six more (serving.prefill_finish)
+            i_ints = len(self._slot_state()) + 2
+            n_core = i_ints + 7
             kk1 = k + 1
 
-            def spec_ilv_finish(cache, d_cache, row, d_row, logits,
-                                last_local, slot, rng, slot_key, pos,
-                                tok, active, keys, temp_v, tk_v, tp_v,
-                                mp_v, rep_v, seen, bias_buf, t, kk_, p,
-                                mp_, rp, seen_row, b_row, prompt_len,
-                                install_ids, crow, c_row, ctable,
-                                ctrans, tail, prev_chunk, prev_pos):
-                out = parent_fin(cache, row, logits, last_local, slot,
-                                 rng, slot_key, pos, tok, active, keys,
-                                 temp_v, tk_v, tp_v, mp_v, rep_v, seen,
-                                 bias_buf, t, kk_, p, mp_, rp, seen_row,
-                                 b_row, prompt_len, install_ids, crow,
-                                 c_row, ctable, ctrans)
+            def spec_ilv_finish(*args):
+                """The parent's finish-and-install (its arguments
+                first, unchanged), then `d_cache, prev_chunk, prev_pos,
+                d_row, tail`: the draft row installs beside the target's
+                and the slot's draft-sync state is seeded in the same
+                dispatch. Returns the parent's results, then the three
+                draft-side buffers."""
+                core = args[:n_core]
+                d_cache, prev_chunk, prev_pos, d_row, tail = args[n_core:]
+                slot, prompt_len = core[i_ints][0], core[i_ints][2]
                 # draft-row install: the one shared clamped install
                 # (serving.install_dense_row)
                 d_cache = install_dense_row(d_cache, d_row, slot)
@@ -376,17 +377,13 @@ class SpeculativeBatcher(ContinuousBatcher):
                 # positions — an exact no-op re-feed
                 prev_chunk = prev_chunk.at[slot].set(tail)
                 prev_pos = prev_pos.at[slot].set(prompt_len - kk1)
-                return out + (d_cache, prev_chunk, prev_pos)
+                return parent_fin(*core) + (d_cache, prev_chunk, prev_pos)
 
-            # the spec batcher never enables constraints
-            # (_constraints_ok=False), so the parent core passes crow
-            # through untouched — not donated (args 29-32 are the
-            # constraint tail, all placeholders here)
-            donate = [0, 1, 9, 10, 11, 12, 13, 14, 15, 16, 17, 18,
-                      34, 35]
-            if self._allow_bias:
-                donate.append(19)
-            self._spec_ilv_finish_donate = tuple(sorted(donate))
+            # the parent's donations (the spec batcher never enables
+            # constraints, so crow passes through undonated) and the
+            # three draft-side buffers
+            self._spec_ilv_finish_donate = self._finish_donate + (
+                n_core, n_core + 1, n_core + 2)
             self._spec_ilv_finish = jax.jit(
                 spec_ilv_finish,
                 donate_argnums=self._spec_ilv_finish_donate)
@@ -488,7 +485,7 @@ class SpeculativeBatcher(ContinuousBatcher):
             if p is not None:
                 p["d_row"] = self._d_family.init_cache(
                     1, self._ilv_row_len, self.d_cache["k"].dtype)
-                p["tail"] = jnp.asarray(prompt_arr[-(k + 1):])
+                p["tail"] = prompt_arr[-(k + 1):]
             return rid
         # draft prefill: same chunk loop as the parent, through the draft
         p_pad = self.prompt_pad
@@ -517,7 +514,7 @@ class SpeculativeBatcher(ContinuousBatcher):
         returned; the final chunk dispatches the fused finish that
         installs BOTH rows, samples the first token on device, and
         seeds the draft-sync state."""
-        req, p, slot = ilv["req"], ilv["p"], ilv["slot"]
+        req, p = ilv["req"], ilv["p"]
         new_row, new_d_row = rows
         self.prefill_chunks_run += 1
         m = obs.metrics()
@@ -528,27 +525,17 @@ class SpeculativeBatcher(ContinuousBatcher):
             p["next"] += 1
             return
         self._pending_q.pop(0)
-        fin = self._spec_ilv_finish(
-            self.cache, self.d_cache, new_row, new_d_row, pf_logits,
-            jnp.int32(p["last_local"]), jnp.int32(slot),
-            p["prefill_key"], p["slot_key"],
-            self.pos, self.tok, self.active, self.keys,
-            self._temp, self._topk, self._topp, self._minp, self._rep,
-            self._seen, self._bias,
-            jnp.float32(p["t"]), jnp.int32(p["k"]), jnp.float32(p["p"]),
-            jnp.float32(p["mp"]), jnp.float32(p["rp"]),
-            p["seen_row"], p["b_row"], jnp.int32(req["prompt_len"]),
-            p["install_ids"], self._crow, jnp.int32(0),
+        out = self._spec_ilv_finish(
+            *self._slot_state(), new_row, pf_logits, *p["finish"],
             self._ctable, self._ctrans,
-            p["tail"], self.prev_chunk, self.prev_pos)
-        (self.cache, self.pos, self.tok, self.active, self.keys,
-         self._temp, self._topk, self._topp, self._minp, self._rep,
-         self._seen, self._bias, self._crow, first) = fin[:14]
+            self.d_cache, self.prev_chunk, self.prev_pos, new_d_row,
+            p["tail"])
+        self._set_slot_state(out[:13])
         # the parent core appends logprob outputs only when logprobs_k
         # is compiled in — the spec batcher bans it, so the tail is
         # exactly (d_cache, prev_chunk, prev_pos)
-        self.d_cache, self.prev_chunk, self.prev_pos = fin[14:]
-        req["first_dev"] = (first, None)
+        self.d_cache, self.prev_chunk, self.prev_pos = out[14:]
+        req["first_dev"] = (out[13], None)
         req["install_step"] = s_idx
         del req["pending"]
 

@@ -235,7 +235,7 @@ def step_programs(chip):
     assert convoy._paged and convoy.max_len == CTX
     compiled = _lower_programs(chip, [
         (convoy, ("_prefill_chunk", "_prefill_finish", "_decode")),
-        (mixed, ("_mixed", "_ilv_finish"))])
+        (mixed, ("_mixed", "_ilv_finish=_prefill_finish"))])
     pool_bytes = sum(x.nbytes for x in jax.tree.leaves(convoy.cache)
                      if x.ndim > 3)
     return (compiled, convoy.cache["k"].shape[1:], pool_bytes,
@@ -248,7 +248,9 @@ def first_calls(batchers, prompt_len=69):
     two requests of `prompt_len` tokens run through the batcher here on
     the CPU: the programs the batcher really dispatches, with the
     arguments it really passes (tests/test_benchmark_contract.py lowers
-    the same calls for their names and scopes)."""
+    the same calls for their names and scopes). `_ilv_finish` names the
+    one finish-and-install program, `_prefill_finish`, as an INTERLEAVED
+    admission calls it."""
     calls = {}
 
     def shape(x):
@@ -258,13 +260,16 @@ def first_calls(batchers, prompt_len=69):
         return x
 
     def record_first(b, name):
-        fn = getattr(b, name)
+        # "key=attribute": the program under a name of the caller's (two
+        # batchers' `_prefill_finish` in one table)
+        name, _, attr = name.partition("=")
+        fn = getattr(b, attr or name)
 
         def call(*args):
             calls.setdefault(name, (fn, jax.tree.map(shape, args)))
             return fn(*args)
 
-        setattr(b, name, call)
+        setattr(b, attr or name, call)
 
     for b, names in batchers:
         for name in names:
@@ -323,7 +328,7 @@ def olmoe_programs(chip):
         family=llama_moe.family_rows(cfg, compute_dtype=BF16))
     assert b._paged and b.max_len == 4096 and b._moe_stats
     compiled = _lower_programs(
-        chip, [(b, ("_mixed", "_ilv_finish", "_decode"))])
+        chip, [(b, ("_mixed", "_ilv_finish=_prefill_finish", "_decode"))])
     return (compiled, b.cache["k"].shape[1:], cfg,
             _held_weight_shapes(prepared))
 

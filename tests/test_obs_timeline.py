@@ -502,8 +502,9 @@ def test_capture_holds_step_and_admit_spans(pool, tmp_path):
         for ad in admits:
             assert ad[3]["prompt_len"] == 4
             kids = _children(spans, ad)
+            # the finish-and-install launch precedes the first-token read
             assert [k[0] for k in kids] == [
-                "admit.prefill", "admit.first_token", "admit.install"]
+                "admit.prefill", "admit.install", "admit.first_token"]
             assert kids[0][3]["chunks"] == 1
             assert len({k[3]["rid"] for k in kids}) == 1
             rids.add(kids[0][3]["rid"])
