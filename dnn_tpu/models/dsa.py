@@ -232,13 +232,12 @@ def _chunk_block(bp, x, layer_cache, start_pos, *, cfg, compute_dtype, ffn,
 class DsaFamilyRows(llama.LlamaFamilyRows):
     """`LlamaFamilyRows` for a config with an indexer: the caches carry
     the index key as a third leaf, the prefill chunk and the decode rows
-    select before they attend. Paged pools only (`index_dim` tells the
-    batcher to allocate the leaf); what assumes two leaves — the prefix
+    select before they attend. Paged pools only (`cache_leaves` tells the
+    batcher what to allocate); what assumes two leaves — the prefix
     store, the KV tier, int8 pools, speculative verify — is refused by
     the batcher at construction (`requires_paged`, `cache_leaves`)."""
 
     requires_paged = True
-    cache_leaves = ("k", "v", "ik")
 
     def __init__(self, cfg, **kw):
         super().__init__(cfg, **kw)
@@ -247,6 +246,10 @@ class DsaFamilyRows(llama.LlamaFamilyRows):
                              "sliding window, no softcap")
         self.index_dim = cfg.index_head_dim
         self.index_topk = cfg.index_topk
+        # what a position's state is, name -> (heads, width): the pool
+        # is built from it (paged_kvcache.init_paged_cache)
+        kv = (cfg.n_kv_head, cfg.head_dim)
+        self.cache_leaves = {"k": kv, "v": kv, "ik": (1, self.index_dim)}
 
     def init_cache(self, batch, max_len, dtype):
         if dtype in ("int8", "int4"):

@@ -164,8 +164,21 @@ def prepare_stacked(params, cfg: GPTConfig):
     `make_apply_stacked`. The stacked layout is also what the pipeline
     runtime shards over the 'stage' mesh axis."""
     out = {k: v for k, v in params.items() if not k.startswith("h_")}
-    out["blocks"] = stack_blocks(params, range(cfg.n_layer))
+    for name, (first, stop) in stack_ranges(cfg).items():
+        out[name] = stack_blocks(params, range(first, stop))
     return out
+
+
+def stack_ranges(cfg):
+    """{name in the prepared tree: (first layer, stop)} in layer order.
+    A model's layers are ONE stack, "blocks", unless the config has a
+    dense prefix (llama_moe.MixtralConfig.first_k_dense): layers of
+    another kind, which stack apart as "dense_blocks" in front of it.
+    `prepare_stacked`, `node._stack_and_release` and `llama.layer_stacks`
+    all lay the tree out from this."""
+    k = getattr(cfg, "first_k_dense", 0)
+    return {**({"dense_blocks": (0, k)} if k else {}),
+            "blocks": (k, cfg.n_layer)}
 
 
 def blocks_scan(stacked, x, *, cfg: GPTConfig, use_flash=False, compute_dtype=None,

@@ -359,6 +359,18 @@ def _kernel(key, shape, dtype, std=0.02):
     return {"kernel": (jax.random.normal(key, shape) * std).astype(dtype)}
 
 
+def init_gated_mlp(keys, cfg: LlamaConfig, d_ff: int, dtype=jnp.float32):
+    """A dense gated MLP of width `d_ff` from three keys (a block's own,
+    or a dense-prefix block's of another width, models/llama_moe.py)."""
+    c = cfg.n_embd
+    return {
+        "gate": _kernel(keys[0], (c, d_ff), dtype),
+        "up": _kernel(keys[1], (c, d_ff), dtype),
+        "down": _kernel(keys[2], (d_ff, c), dtype,
+                        std=0.02 / (2 * cfg.n_layer) ** 0.5),
+    }
+
+
 def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
                include_mlp: bool = True):
     """`include_mlp=False` builds the attention/norm half only — MoE
@@ -417,6 +429,10 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
 
         blk["attn"]["indexer"] = dsa.init_indexer(
             jax.random.fold_in(key, 13), cfg, dtype)
+    if getattr(cfg, "mla", None) is not None:
+        from dnn_tpu.models import mla
+
+        blk["attn"] = mla.init_attn(jax.random.fold_in(key, 17), cfg, dtype)
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -424,12 +440,7 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
         del blk["ln_2"]
     if include_mlp:
         if cfg.mlp_gated:
-            blk["mlp"] = {
-                "gate": _kernel(ks[4], (c, cfg.d_ff), dtype),
-                "up": _kernel(ks[5], (c, cfg.d_ff), dtype),
-                "down": _kernel(ks[6], (cfg.d_ff, c), dtype,
-                                std=0.02 / (2 * cfg.n_layer) ** 0.5),
-            }
+            blk["mlp"] = init_gated_mlp(ks[4:7], cfg, cfg.d_ff, dtype)
         else:  # Phi plain MLP: fc1 -> act -> fc2
             blk["mlp"] = {
                 "up": _dense(ks[5], (c, cfg.d_ff)),
@@ -706,6 +717,11 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
 
         fn = lambda bp2, h: dsa.dense_attn(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype)
+    if attn_fn is None and getattr(cfg, "mla", None) is not None:
+        from dnn_tpu.models import mla
+
+        fn = lambda bp2, h: mla.dense_attn(  # noqa: E731
+            bp2, h, cfg=cfg, compute_dtype=compute_dtype)
     # trace-time scopes: device profiles (obs/profile.py) name the
     # attention branch vs the residual/MLP compose; zero runtime cost
     with jax.named_scope("llama.block.attn"):
@@ -756,6 +772,31 @@ def head(params, x, *, cfg: LlamaConfig, compute_dtype=None, logits_dtype=None):
         return out if logits_dtype is None else out.astype(logits_dtype)
 
 
+def layer_stacks(prepared, cfg):
+    """[(stacked blocks, (first layer, stop) or None)] in layer order: a
+    model's layers are ONE stack, `prepared["blocks"]` (None: all of
+    them), unless the config has a dense prefix (`first_k_dense`,
+    models/llama_moe.py) — layers of another kind, whose params stack
+    apart as `prepared["dense_blocks"]` in front of the expert layers'.
+    Each is scanned on its own; a paged pool is reached by layer index
+    across both (`paged_kvcache.scan_blocks(layers=)`), a dense cache or
+    a transient row is sliced by the range."""
+    ranges = gpt.stack_ranges(cfg)
+    if len(ranges) == 1:
+        return [(prepared["blocks"], None)]
+    return [(prepared[name], r) for name, r in ranges.items()]
+
+
+def _scan_all_stacks(prepared, x, *, cfg, **kw):
+    """`blocks_scan` over every stack of `layer_stacks`."""
+    wins = kw.pop("windows", None)
+    for stack, layers in layer_stacks(prepared, cfg):
+        w = wins if wins is None or layers is None else wins[
+            layers[0]:layers[1]]
+        x = blocks_scan(stack, x, cfg=cfg, windows=w, **kw)
+    return x
+
+
 def blocks_scan(stacked, x, *, cfg, compute_dtype, remat=False, attn_fn=None,
                 windows=None, ffn=None):
     """Scan the stacked blocks. `windows` is the per-layer window array
@@ -790,9 +831,10 @@ def make_apply(cfg: LlamaConfig, *, compute_dtype=None, remat=False,
         x = embed(params, idx, cfg=cfg)
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
-        stacked = gpt.stack_blocks(params, range(cfg.n_layer))
-        x = blocks_scan(stacked, x, cfg=cfg, compute_dtype=compute_dtype,
-                         remat=remat, windows=layer_windows(cfg), ffn=ffn)
+        x = _scan_all_stacks(
+            gpt.prepare_stacked(params, cfg), x, cfg=cfg,
+            compute_dtype=compute_dtype, remat=remat,
+            windows=layer_windows(cfg), ffn=ffn)
         return head(params, x.astype(jnp.float32), cfg=cfg,
                     compute_dtype=compute_dtype)
 
@@ -813,9 +855,9 @@ def make_hidden_stacked(cfg: LlamaConfig, *, compute_dtype=None):
         x = embed(prepared, idx, cfg=cfg)
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
-        x = blocks_scan(prepared["blocks"], x, cfg=cfg,
-                        compute_dtype=compute_dtype,
-                        windows=layer_windows(cfg), ffn=ffn)
+        x = _scan_all_stacks(prepared, x, cfg=cfg,
+                             compute_dtype=compute_dtype,
+                             windows=layer_windows(cfg), ffn=ffn)
         return _norm(prepared["ln_f"], x.astype(jnp.float32), cfg)
 
     return hidden
@@ -832,9 +874,9 @@ def make_apply_stacked(cfg: LlamaConfig, *, compute_dtype=None,
         x = embed(prepared, idx, cfg=cfg)
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
-        x = blocks_scan(prepared["blocks"], x, cfg=cfg,
-                         compute_dtype=compute_dtype, remat=remat,
-                         windows=layer_windows(cfg), ffn=ffn)
+        x = _scan_all_stacks(prepared, x, cfg=cfg,
+                             compute_dtype=compute_dtype, remat=remat,
+                             windows=layer_windows(cfg), ffn=ffn)
         return head(prepared, x.astype(jnp.float32), cfg=cfg,
                     compute_dtype=compute_dtype, logits_dtype=logits_dtype)
 
@@ -922,6 +964,13 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     of what each expert layer call cost (parallel/moe.moe_ffn_grouped)."""
     from dnn_tpu.runtime.kvcache import codec_for_cache
 
+    if getattr(cfg, "mla", None) is not None or getattr(
+            cfg, "first_k_dense", 0):
+        raise ValueError(
+            "forward_with_cache holds K and V of one stack of layers: a "
+            "model with latent attention or a dense prefix prefills and "
+            "decodes through its family adapter (models/mla.py) and the "
+            "paged pool")
     ffn = ffn or cfg.default_ffn(compute_dtype)
     wins = layer_windows(cfg)  # (L,) for alternating configs, else None
     codec = codec_for_cache(cache, use_kernel=attn_kernel,
@@ -1276,6 +1325,20 @@ def make_generate_seq_sharded(cfg: LlamaConfig, mesh, *, max_new_tokens: int,
     return generate
 
 
+def family_rows(cfg, **kw):
+    """The batcher adapter a LLaMA-family config serves through: by what
+    its attention keeps a position (`LlamaFamilyRows`' K and V; with an
+    indexer models/dsa.py's third leaf; with latent attention
+    models/mla.py's one). `kw`: `LlamaFamilyRows`' own."""
+    if cfg.index_topk is not None:
+        from dnn_tpu.models.dsa import DsaFamilyRows as rows
+    elif getattr(cfg, "mla", None) is not None:
+        from dnn_tpu.models.mla import MlaFamilyRows as rows
+    else:
+        rows = LlamaFamilyRows
+    return rows(cfg, **kw)
+
+
 class LlamaFamilyRows:
     """ContinuousBatcher family adapter (see
     runtime/serving.GPTFamilyRows for the protocol): per-slot LLaMA decode
@@ -1469,10 +1532,17 @@ class LlamaFamilyRows:
             return (y, acc), c
 
         # a paged pool rides the loop whole, a dense cache by layer
-        wins = () if self._wins is None else (self._wins,)
         acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
-        (x, acc), new_cache = scan_blocks(
-            block, (x, acc0), prepared["blocks"], cache, codec, *wins)
+        carry, new_cache = (x, acc0), cache
+        for stack, layers in layer_stacks(prepared, self.cfg):
+            # one of several stacks scans its own range of the pool
+            wins = () if self._wins is None else (
+                self._wins if layers is None
+                else self._wins[layers[0]:layers[1]],)
+            carry, new_cache = scan_blocks(
+                block, carry, stack, new_cache, codec, *wins,
+                layers=None if layers is None else jnp.arange(*layers))
+        x, acc = carry
         logits = head(prepared, x.astype(jnp.float32), cfg=self.cfg,
                       compute_dtype=self.compute_dtype)
         if moe_stats:

@@ -43,8 +43,31 @@ import jax
 import jax.numpy as jnp
 
 from dnn_tpu.models import gpt, llama
+from dnn_tpu.models.mla import MlaConfig
 from dnn_tpu.parallel.moe import init_moe_gated, moe_ffn, moe_ffn_grouped
 from dnn_tpu.registry import ModelSpec, register_model
+
+
+# sigma of the seeded init's selection bias: at random init a token's
+# sigmoid scores lie close together, and at this sigma the bias changes
+# the set of eight on 99.6 % of tokens (PERF.md section 6, PR 35), so a
+# program that dropped it is told apart
+_SELECT_BIAS_INIT = 0.05
+
+
+@dataclasses.dataclass(frozen=True)
+class RouterConfig:
+    """How an expert layer scores and weighs (parallel/moe.route_rows).
+    The default is the softmax top-k every earlier preset has."""
+    # "softmax" over all experts, or "sigmoid" of each logit on its own
+    # (DeepSeek-V3's `scoring_func`)
+    scoring: str = "softmax"
+    # a per-expert bias added to the scores for the PICK alone
+    # (`e_score_correction_bias`, `topk_method` noaux_tc): the tree then
+    # carries `moe.router.select_bias` (E,) float32
+    select_bias: bool = False
+    # `routed_scaling_factor`: the k weights times this
+    scale: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +94,33 @@ class MixtralConfig(llama.LlamaConfig):
     # here (the chip that holds it computes it). None = every expert.
     experts_first: int = 0
     experts_held: Optional[int] = None
+    # ---- DeepSeek-V3-class switches (defaults = what was there) ----
+    router: RouterConfig = RouterConfig()
+    # False: the shared expert's output adds as it is (no sigmoid gate,
+    # no `shared_gate` leaf)
+    shared_gate: bool = True
+    # the first `first_k_dense` layers carry a dense gated MLP of width
+    # `d_ff_dense` in place of experts. They are a stack of their own
+    # (`prepared["dense_blocks"]`, llama.layer_stacks) in front of the
+    # expert stack; the expert hook computes a block by what its params
+    # hold, and the moe_* counters count expert layers only
+    first_k_dense: int = 0
+    d_ff_dense: Optional[int] = None
+    # multi-head latent attention (models/mla.py): the cache holds ONE
+    # compressed latent a position
+    mla: Optional[MlaConfig] = None
+
+    def __post_init__(self):
+        super().__post_init__()
+        if self.first_k_dense and not (
+                0 < self.first_k_dense < self.n_layer and self.d_ff_dense):
+            raise ValueError(
+                "first_k_dense needs d_ff_dense and at least one expert "
+                f"layer after it (n_layer {self.n_layer})")
+
+    @property
+    def n_expert_layer(self):
+        return self.n_layer - self.first_k_dense
 
     @property
     def held(self):
@@ -193,6 +243,48 @@ PRESETS["keye-test"] = MixtralConfig(
     n_expert=8, router_top_k=4, router_norm_topk=True, capacity_factor=8.0,
     experts_first=0, experts_held=4,
     index_topk=8, index_n_head=4, index_head_dim=16)
+# JoyAI-LLM-Flash (jdopensource/JoyAI-LLM-Flash config.json, `model_type`
+# joyai_llm_flash): a DeepSeek-V3-shaped decoder — latent attention
+# (models/mla.py) in all 40 layers, layer 0 a dense SwiGLU of 7168, then
+# 39 layers of 256 routed experts of 768, 8 a token by sigmoid scores
+# with a selection bias (`topk_method` noaux_tc; `n_group` = `topk_group`
+# = 1: the grouped top-k is the plain one), weights normalised and times
+# 2.5, and ONE shared expert that adds ungated. The multi-token-
+# prediction module (`num_nextn_predict_layers` 1) is a 41st block that
+# drafts: it is no part of the next token's logits and is not served.
+# The seeded init's choices (`_SELECT_BIAS_INIT`, norm gains of one): MEASURED
+# (PERF.md section 6, PR 35).
+PRESETS["joyai-llm-flash"] = MixtralConfig(
+    block_size=131072, vocab_size=129280, n_layer=40, n_head=32,
+    n_kv_head=32, n_embd=2048, d_ff=768, rope_theta=32_000_000.0,
+    rms_eps=1e-6, n_expert=256, router_top_k=8, router_norm_topk=True,
+    capacity_factor=256.0, d_shared=768, shared_gate=False,
+    first_k_dense=1, d_ff_dense=7168,
+    router=RouterConfig(scoring="sigmoid", select_bias=True,
+                        scale=2.5),
+    mla=MlaConfig(q_lora_rank=1536, kv_lora_rank=512, qk_nope_head_dim=128,
+                  qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True))
+# the benchmark's cut (chipbench/configs/joyai-llm-flash-ep16-1chip.json):
+# one chip's share of a 16-chip expert-parallel deployment — experts 0-15
+# of each expert layer's 256 held; attention, router, shared expert,
+# embedding and head whole — and layer 0 with four of the 39 expert layers
+# (the depth by requests completed a window: PERF.md section 6, PR 35)
+PRESETS["joyai-llm-flash-ep16-1chip"] = dataclasses.replace(
+    PRESETS["joyai-llm-flash"], n_layer=5, experts_first=0, experts_held=16)
+# tiny JoyAI for the CPU tests, every switch of the real one acting: a
+# query bottleneck, latent and rope widths with nope != value width, 1
+# dense + 2 expert layers, sigmoid + bias + scale, an ungated shared
+# expert, a held share smaller than the expert count
+PRESETS["joyai-test"] = MixtralConfig(
+    block_size=64, vocab_size=256, n_layer=3, n_head=4, n_kv_head=4,
+    n_embd=64, d_ff=32, rope_theta=32_000_000.0, rms_eps=1e-6,
+    n_expert=8, router_top_k=4, router_norm_topk=True, capacity_factor=8.0,
+    experts_first=0, experts_held=4, d_shared=32, shared_gate=False,
+    first_k_dense=1, d_ff_dense=96,
+    router=RouterConfig(scoring="sigmoid", select_bias=True,
+                        scale=2.5),
+    mla=MlaConfig(q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
+                  qk_rope_head_dim=8, v_head_dim=24, rope_interleave=True))
 # the benchmark's cut (chipbench/configs/olmoe-1b-7b-1chip.json): three of
 # the sixteen layers — the pattern has period 1 — so that float32 weights,
 # a 16-slot pool of 4096 positions and the programs fit one 16 GB chip
@@ -200,12 +292,14 @@ PRESETS["olmoe-1b-7b-1chip"] = dataclasses.replace(
     PRESETS["olmoe-1b-7b"], n_layer=3)
 
 
+@jax.named_scope("moe.shared")
 def _shared_expert_out(moe_p, h, *, compute_dtype=None):
     """The always-on shared expert (Qwen2-MoE / DeepSeek recipe): a
-    dense SwiGLU over h, scaled per token by sigmoid(h @ shared_gate).
-    Adds to the ROUTED output — identical math on the dense-grouped and
-    EP paths (the shared weights replicate; only routed experts
-    shard)."""
+    dense SwiGLU over h, scaled per token by sigmoid(h @ shared_gate)
+    where the tree carries that gate (Qwen2-MoE; DeepSeek-V3's adds as
+    it is). Adds to the ROUTED output — identical math on the
+    dense-grouped and EP paths (the shared weights replicate; only routed
+    experts shard)."""
     from dnn_tpu.ops.nn import linear, silu
 
     sp = moe_p["shared"]
@@ -213,6 +307,8 @@ def _shared_expert_out(moe_p, h, *, compute_dtype=None):
                silu(linear(sp["gate"], h, compute_dtype=compute_dtype))
                * linear(sp["up"], h, compute_dtype=compute_dtype),
                compute_dtype=compute_dtype)
+    if "shared_gate" not in moe_p:
+        return s.astype(h.dtype)
     g = jax.nn.sigmoid(
         linear(moe_p["shared_gate"], h,
                compute_dtype=compute_dtype).astype(jnp.float32))
@@ -265,7 +361,9 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
         return moe_ffn_grouped(bp["moe"], h, top_k=cfg.router_top_k,
                                normalize=cfg.router_norm_topk,
                                activation=silu, compute_dtype=compute_dtype,
-                               return_stats=return_stats, held=cfg.held)
+                               return_stats=return_stats, held=cfg.held,
+                               scoring=cfg.router.scoring,
+                               scale=cfg.router.scale)
 
     def with_shared(bp, h, out):
         if cfg.d_shared:
@@ -273,11 +371,19 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
                                            compute_dtype=compute_dtype)
         return out
 
+    def dense(bp, h):
+        # a block of the dense prefix (`first_k_dense`): its own gated MLP
+        return llama._mlp_out(bp, h, cfg=cfg, compute_dtype=compute_dtype)
+
     def ffn(bp, h):
+        if "moe" not in bp:
+            return dense(bp, h)
         return with_shared(bp, h, routed(bp, h, False))
 
     if groups == 1:
         def with_stats(bp, h):
+            if "moe" not in bp:  # no expert layer call: nothing counted
+                return dense(bp, h), jnp.zeros((3,), jnp.int32)
             out, stats = routed(bp, h, True)
             return with_shared(bp, h, out), stats
 
@@ -295,9 +401,17 @@ def init(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
 
     params = llama.init(rng, cfg, dtype, include_mlp=False)
     keys = jax.random.split(jax.random.fold_in(rng, 7), cfg.n_layer)
-    for i in range(cfg.n_layer):
+    for i in range(cfg.first_k_dense):
+        params[f"h_{i}"]["mlp"] = llama.init_gated_mlp(
+            jax.random.split(keys[i], 3), cfg, cfg.d_ff_dense, dtype)
+    for i in range(cfg.first_k_dense, cfg.n_layer):
         moe = init_moe_gated(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
                              dtype, n_held=cfg.experts_held)
+        if cfg.router.select_bias:
+            moe["router"]["select_bias"] = (
+                _SELECT_BIAS_INIT * jax.random.normal(
+                    jax.random.fold_in(keys[i], 2), (cfg.n_expert,))
+            ).astype(jnp.float32)
         if cfg.d_shared:
             ks = jax.random.split(jax.random.fold_in(keys[i], 1), 4)
             si = 1.0 / math.sqrt(cfg.n_embd)
@@ -310,8 +424,9 @@ def init(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
                 "down": {"kernel": (jax.random.normal(
                     ks[2], (cfg.d_shared, cfg.n_embd)) * so).astype(dtype)},
             }
-            moe["shared_gate"] = {"kernel": (jax.random.normal(
-                ks[3], (cfg.n_embd, 1)) * si).astype(dtype)}
+            if cfg.shared_gate:
+                moe["shared_gate"] = {"kernel": (jax.random.normal(
+                    ks[3], (cfg.n_embd, 1)) * si).astype(dtype)}
         params[f"h_{i}"]["moe"] = moe
     return params
 
@@ -339,13 +454,8 @@ def family_rows(cfg: MixtralConfig, *, compute_dtype=None,
     """ContinuousBatcher adapter: LlamaFamilyRows resolves the MoE hook
     from the config — prefill chunks, per-slot decode rows, and
     speculative verify all route through the experts."""
-    if cfg.index_topk is not None:
-        from dnn_tpu.models.dsa import DsaFamilyRows
-
-        return DsaFamilyRows(cfg, compute_dtype=compute_dtype,
+    return llama.family_rows(cfg, compute_dtype=compute_dtype,
                              attn_kernel=attn_kernel)
-    return llama.LlamaFamilyRows(cfg, compute_dtype=compute_dtype,
-                                 attn_kernel=attn_kernel)
 
 
 def _ep_param_spec(path, leaf, *, axis, stage_axis=None):

@@ -880,19 +880,26 @@ def _stack_and_release(params, cfg, compute_dtype=None):
             free(leaf)
         return out
 
-    layers = [params.pop(f"h_{i}") for i in range(cfg.n_layer)]
-    flat = [jax.tree_util.tree_flatten_with_path(layer) for layer in layers]
-    stacked = []
-    for column in zip(*(leaves for leaves, _ in flat)):
-        leaves = [held(path, leaf) for path, leaf in column]
-        # done before the originals go, so that the next leaf's stack
-        # is allocated after this one's originals are free
-        stacked.append(jax.block_until_ready(jnp.stack(leaves)))
-        for leaf in leaves:
-            free(leaf)
-    out = jax.tree_util.tree_map_with_path(held, dict(params))
-    out["blocks"] = jax.tree_util.tree_unflatten(flat[0][1], stacked)
-    return out
+    def stack(first, stop):
+        layers = [params.pop(f"h_{i}") for i in range(first, stop)]
+        flat = [jax.tree_util.tree_flatten_with_path(layer)
+                for layer in layers]
+        stacked = []
+        for column in zip(*(leaves for leaves, _ in flat)):
+            leaves = [held(path, leaf) for path, leaf in column]
+            # done before the originals go, so that the next leaf's stack
+            # is allocated after this one's originals are free
+            stacked.append(jax.block_until_ready(jnp.stack(leaves)))
+            for leaf in leaves:
+                free(leaf)
+        return jax.tree_util.tree_unflatten(flat[0][1], stacked)
+
+    # layers of another kind in front (a dense prefix before expert
+    # layers) are a stack of their own (`gpt.stack_ranges`)
+    from dnn_tpu.models.gpt import stack_ranges
+
+    stacks = {name: stack(*r) for name, r in stack_ranges(cfg).items()}
+    return {**jax.tree_util.tree_map_with_path(held, dict(params)), **stacks}
 
 
 def _serve_lm(engine: PipelineEngine, args) -> int:
@@ -901,7 +908,7 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
     GPT family serves; MoE plugs its routed FFN into the same pool."""
     from dnn_tpu.models.gpt import GPTConfig, prepare_stacked
     from dnn_tpu.models.gpt_moe import GPTMoEConfig
-    from dnn_tpu.models.llama import LlamaConfig, LlamaFamilyRows
+    from dnn_tpu.models.llama import LlamaConfig, family_rows
     from dnn_tpu.runtime.lm_server import serve_lm
 
     cfg = engine.spec.config
@@ -911,10 +918,7 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
 
         ffn = moe_cache_ffn(cfg, compute_dtype=engine.compute_dtype)
     elif isinstance(cfg, LlamaConfig):
-        rows = LlamaFamilyRows
-        if cfg.index_topk is not None:
-            from dnn_tpu.models.dsa import DsaFamilyRows as rows
-        family = rows(cfg, compute_dtype=engine.compute_dtype)
+        family = family_rows(cfg, compute_dtype=engine.compute_dtype)
     elif type(cfg) is not GPTConfig:
         log.error("--serve_lm requires a GPT-family model; '%s' (config %s) "
                   "is not one", engine.config.model, type(cfg).__name__)

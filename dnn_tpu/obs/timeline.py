@@ -67,7 +67,10 @@ This is the intra-step instrument, in two connected halves:
     step; for a model whose attention selects what it reads
     dsa.layer_calls_total / dsa.candidate_positions_total /
     dsa.selected_positions_total{program=decode|prefill}, counted on the
-    host from each slot's position) + fixed-bucket histograms
+    host from each slot's position; for a model whose cache is a
+    compressed latent mla.layer_calls_total /
+    mla.cached_positions_total / mla.query_pairs_total{program=}, counted
+    the same way) + fixed-bucket histograms
     (step.phase_seconds{phase=...}, step.wall_seconds). Phase-boundary
     timestamps are ring-buffered for /stepz. While a profiler capture
     records (POST /profilez), the same boundaries are ALSO written into
@@ -142,6 +145,11 @@ MOE_SERIES = ("layer_calls_total", "assignments_total",
 # learned-sparse-attention model's indexer scored and what it selected
 DSA_SERIES = ("layer_calls_total", "candidate_positions_total",
               "selected_positions_total")
+# the mla.* cumulative series (StepClock.note_mla), same programs: the
+# cached latents a latent-attention model's layers read, and a prefill
+# chunk's causal (query, position) pairs
+MLA_SERIES = ("layer_calls_total", "cached_positions_total",
+              "query_pairs_total")
 
 #: the phase whose annotation opens when a mark closes phase P (None
 #: after the last): the in-step order of PHASES, one definition
@@ -302,6 +310,8 @@ class StepClock:
         self.attn_blocks_total = [0, 0]
         # an indexer's work (note_dsa): per program, DSA_SERIES in order
         self.dsa_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
+        # latent attention's reads (note_mla): MLA_SERIES in order
+        self.mla_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
         self._pending_moe: "Optional[Dict[str, list]]" = None
         self._gauges_registered = False
         self._registry = registry
@@ -354,18 +364,22 @@ class StepClock:
                     else 0.0
             return read
 
-        def _weak_dsa(program, i):
+        def _weak_of(attr, program, i):
             def read():
                 c = ref()
-                return float(c.dsa_total[program][i]) if c is not None \
-                    else 0.0
+                return float(getattr(c, attr)[program][i]) \
+                    if c is not None else 0.0
             return read
 
-        # registered with the first note_dsa: a model without an indexer
-        # shows no dsa_* series
+        # registered with the first note_dsa / note_mla: a model without
+        # an indexer shows no dsa_* series, one without a latent cache no
+        # mla_* series
         self._dsa_gauges = {
-            labeled(f"dsa.{name}", program=p): _weak_dsa(p, i)
+            labeled(f"dsa.{name}", program=p): _weak_of("dsa_total", p, i)
             for p in MOE_PROGRAMS for i, name in enumerate(DSA_SERIES)}
+        self._mla_gauges = {
+            labeled(f"mla.{name}", program=p): _weak_of("mla_total", p, i)
+            for p in MOE_PROGRAMS for i, name in enumerate(MLA_SERIES)}
         # registered with the first note_attn_blocks: a dense cache shows
         # no step_attn_* series
         self._attn_gauges = {
@@ -490,15 +504,29 @@ class StepClock:
         over the layers and the queries. Counted by the batcher on the
         host from each slot's position — no device read. Cumulative
         dsa.* totals, on /metrics with the first note."""
+        self._note3(self.dsa_total, self._dsa_gauges, program,
+                    (layer_calls, candidates, selected))
+
+    def note_mla(self, program: str, layer_calls: int, cached: int,
+                 pairs: int):
+        """One dispatched program of a model whose cache is a compressed
+        latent (models/mla.py): `layer_calls` attention layers, which
+        read `cached` cached positions (decode: every live position of
+        every slot; prefill: the positions the chunk attends) and scored
+        `pairs` causal (query, position) pairs, both summed over the
+        layers. Counted on the host as the dsa.* series are. Cumulative
+        mla.* totals, on /metrics with the first note."""
+        self._note3(self.mla_total, self._mla_gauges, program,
+                    (layer_calls, cached, pairs))
+
+    def _note3(self, total, gauges, program, add):
         if not _obs.enabled():
             return
-        tot = self.dsa_total[program]
-        if not (self.dsa_total["decode"][0] or self.dsa_total["prefill"][0]):
-            self._gauges.update(self._dsa_gauges)
+        if not (total["decode"][0] or total["prefill"][0]):
+            self._gauges.update(gauges)
             self._gauges_registered = False  # re-register with them
-        tot[0] += layer_calls
-        tot[1] += candidates
-        tot[2] += selected
+        for i, v in enumerate(add):
+            total[program][i] += v
 
     def note_moe(self, program: str, layer_calls: int, stats):
         """What the expert layers of one executed program cost:

@@ -507,6 +507,36 @@ def _reference_paged_step(q, pools, tables, pos, layer, new, sel=None):
     return (jnp.where(gate[:, None, None, None], out, 0.0), *pools)
 
 
+def _reference_latent_step(q, cp, tables, pos, layer, new, latent, scale):
+    """paged_decode_attention's `latent` form in plain jnp: place `new`'s
+    row, then q (B, 1, R, D) against the slot's gathered rows, key whole
+    and value in the first `latent` lanes. As the kernel: zeros for a
+    gated-off slot, and with `new` the leaf too."""
+    bp = cp.shape[-2]
+    gate = None
+    if new is not None:
+        row, gate = new
+        blk = jnp.take_along_axis(tables, (pos // bp)[:, None], axis=1)[:, 0]
+        blk, at = jnp.where(gate, blk, 0), jnp.where(gate, pos % bp, 0)
+        cp = cp.at[layer, blk, :, at].set(row[:, :, 0])
+    leaf = cp if layer is None else cp[layer]
+    b, nb = tables.shape
+    kv = jnp.take(leaf, tables.reshape(-1), axis=0)[:, 0]  # (B*nb, bp, D)
+    kv = kv.reshape(b, nb * bp, -1).astype(jnp.float32)
+    qf = jnp.pad(q.astype(jnp.float32),
+                 [(0, 0)] * 3 + [(0, kv.shape[-1] - q.shape[-1])])
+    s = jnp.einsum("bhrd,bsd->bhrs", qf, kv,
+                   preferred_element_type=jnp.float32) * (
+        q.shape[-1] ** -0.5 if scale is None else scale)
+    s = jnp.where(jnp.arange(nb * bp)[None, None, None, :]
+                  <= pos[:, None, None, None], s, _NEG_BIG)
+    out = jnp.einsum("bhrs,bsd->bhrd", jax.nn.softmax(s, axis=-1),
+                     kv[..., :latent], preferred_element_type=jnp.float32)
+    if gate is None:
+        return out
+    return jnp.where(gate[:, None, None, None], out, 0.0), cp
+
+
 def _paged_group(block_len, nb_max):
     """Blocks attended in one online-softmax update: as many as make 128
     positions, and never more than the table has."""
@@ -514,7 +544,7 @@ def _paged_group(block_len, nb_max):
 
 
 def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
-                         select=False):
+                         select=False, latent=0):
     """One grid step = one slot. Scalar prefetch: pos, table, layer (and
     with `write` the gate). Inputs: q, the pool's leaves where they lie
     in HBM, and with `write` this step's rows. Outputs: the attention
@@ -524,11 +554,16 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
     group is in, and G running softmax states a query row (state g
     attends every G-th block, and the G are merged when the slot's blocks
     are through). With `select` one more input, last: the slot's set
-    (n_groups, G, bp) int32, nonzero where a position is read."""
+    (n_groups, G, bp) int32, nonzero where a position is read. With
+    `latent` = the value's width the pool is ONE leaf of one head
+    (models/mla.py): a group's blocks are one (G * bp, d) matrix, key as
+    it stands and value in its first `latent` lanes, the slot's R query
+    rows (its heads) meet it in one product each way, and a row has one
+    softmax state."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_pool = 4 if quant else 2
+    n_pool = 1 if latent else 4 if quant else 2
     pos_ref, tab_ref, lay_ref = refs[:3]
     gate_ref = refs[3] if write else None
     q_ref, *refs = refs[4 if write else 3:]
@@ -609,6 +644,33 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
         start_next_slot(first)
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    def update_latent(buf_i, gi):
+        """One online-softmax update of the slot's R rows over group gi
+        of the latent leaf: the G blocks as one (G * bp, d) matrix."""
+        kv = bufs[0][buf_i].reshape(group * bp, d)
+        narrow = q_ref.dtype == jnp.bfloat16 and kv.dtype == jnp.bfloat16
+        cdt = jnp.bfloat16 if narrow else jnp.float32
+        kv = kv.astype(cdt)
+        s2 = jax.lax.dot_general(
+            q_ref[0, 0].astype(cdt), kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (R, G*bp)
+        cols = gi * group * bp + jax.lax.broadcasted_iota(
+            jnp.int32, s2.shape, 1)
+        seen = cols <= pos
+        s2 = jnp.where(seen, s2, _NEG_BIG)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.where(seen, jnp.exp(s2 - m_new), 0.0)
+        out = jax.lax.dot_general(
+            p.astype(cdt), kv[:, :latent], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (R, latent)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True),
+            l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + out
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
     def attend(gi, _):
         buf_i = jax.lax.rem(first + gi, 2)
 
@@ -645,6 +707,12 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
                         here, new[0].astype(jnp.float32), blk
                     ).astype(buf.dtype)
                 write_back("start")
+
+        if latent:
+            update_latent(buf_i, gi)
+            if write:
+                pl.when(holds_pos)(lambda: write_back("wait"))
+            return
 
         # the group's leaves as the softmax reads them, one batch entry a
         # (block, head): K, V (G*Hk, bp, d), an int8 pool's scales
@@ -703,6 +771,10 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         jax.lax.fori_loop(0, n_groups, attend, None)
+        if latent:  # one state a row
+            o_ref[0] = (acc_scr[...] / l_scr[:, :1]).reshape(
+                hk, r, latent).astype(o_ref.dtype)
+            return
         # merge the `group` states of each query row
         states = [pl.ds(g * rows, rows) for g in range(group)]
         m = functools.reduce(jnp.maximum,
@@ -717,7 +789,8 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
 
 @jax.named_scope("attn.paged_decode")
 def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
-                           layer=None, new=None, sel=None, interpret=None):
+                           layer=None, new=None, sel=None, latent=None,
+                           scale=None, interpret=None):
     """Fused paged decode attention (see the section comment above).
 
     q (B, Hk, R, D) — R query rows per KV head, all attending logical
@@ -751,12 +824,24 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     set scattered over the context leaves hardly a block without a
     member — and masks inside the group's update; float pools only.
 
+    With `latent` = the value's width (latent attention, models/mla.py)
+    `kp` is the pool's ONE leaf of one head, `vp` is None and q is (B, 1,
+    R, D): the slot's R heads as rows, against rows that are key as they
+    stand (all D lanes, scores times `scale`) and value in their first
+    `latent` lanes. A block is copied into VMEM once and read both ways.
+    Returns (B, 1, R, latent), and with `new` = (row (B, 1, 1, D), gate)
+    the leaf. Float pools, no `sel`.
+
     Dispatches to the Pallas kernel on TPU; otherwise runs the
     reference. `interpret=True` forces the kernel in interpreter mode
     (CPU CI runs the real table chase and block copies)."""
     quant = ks is not None
-    pools = [kp, vp] + ([ks.astype(jnp.float32), vs.astype(jnp.float32)]
-                        if quant else [])
+    if latent and (quant or sel is not None or q.shape[1] != 1
+                   or kp.shape[-3] != 1):
+        raise ValueError("latent attention reads one float leaf of one "
+                         "head, whole")
+    pools = [kp] if latent else [kp, vp] + (
+        [ks.astype(jnp.float32), vs.astype(jnp.float32)] if quant else [])
     if new is not None and layer is None:
         raise ValueError("the kernel places rows in the whole pool only: "
                          "pass layer= with new=")
@@ -769,6 +854,9 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     # gather-and-einsum form there
     lowers = all(x.shape[-1] % 128 == 0 for x in pools)
     if interpret is None or not (interpret or lowers):
+        if latent:
+            return _reference_latent_step(q, kp, tables, pos, layer, new,
+                                          latent, scale)
         if layer is None:
             return reference_paged_decode_attention(
                 q, kp, vp, tables, pos, ks=ks, vs=vs, sel=sel)
@@ -789,9 +877,11 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     if select and quant:
         raise ValueError("a selection reads a float pool")
     kernel = functools.partial(
-        _paged_decode_kernel, scale=1.0 / (d_q ** 0.5), nb_max=nb_max,
+        _paged_decode_kernel,
+        scale=1.0 / (d_q ** 0.5) if scale is None else scale, nb_max=nb_max,
         quant=quant, write=write, whole=whole, **(
-            {"select": True} if select else {}),
+            {"select": True} if select else {}), **(
+            {"latent": latent} if latent else {}),
     )
 
     def rows_of_slot(x):  # grid step bi sees x[bi]
@@ -803,6 +893,10 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     in_specs = [qspec] + [in_place] * len(pools)
     out_specs, out_shape = qspec, jax.ShapeDtypeStruct((b, hk, r, d),
                                                        jnp.float32)
+    if latent:
+        out_shape = jax.ShapeDtypeStruct((b, hk, r, latent), jnp.float32)
+        out_specs = rows_of_slot(out_shape)
+    out_rows = out_specs
     scalars = [pos.astype(jnp.int32), tables.reshape(-1).astype(jnp.int32),
                jnp.asarray(0 if layer is None else layer,
                            jnp.int32).reshape(1)]
@@ -811,7 +905,7 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         *rows, gate = new
         scalars.append(gate.astype(jnp.int32))
         in_specs += [rows_of_slot(x) for x in rows]
-        out_specs = [qspec] + [in_place] * len(pools)
+        out_specs = [out_rows] + [in_place] * len(pools)
         out_shape = [out_shape] + [
             jax.ShapeDtypeStruct(x.shape, x.dtype) for x in pools]
         # operand numbers count the scalars: 4 of them, then q
@@ -825,7 +919,9 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
                        ).reshape(b, n_groups, group, bp)
         in_specs.append(rows_of_slot(sel4))
         rows = (*rows, sel4)
-    n_states = group * hk * r
+    # softmax states: `group` a query row, merged at a slot's end; the
+    # latent form reads a group as one matrix and keeps one
+    n_states = r if latent else group * hk * r
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b,),
@@ -840,7 +936,7 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
             pltpu.SMEM((1,), jnp.int32),  # the slot's first buffer
             pltpu.VMEM((n_states, 128), jnp.float32),  # running row max
             pltpu.VMEM((n_states, 128), jnp.float32),  # running row sum
-            pltpu.VMEM((n_states, d), jnp.float32),    # output accumulator
+            pltpu.VMEM((n_states, latent or d), jnp.float32),  # accumulator
         ],
     )
     out = pl.pallas_call(
@@ -855,6 +951,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         interpret=interpret,
         name="paged_decode_attention",
     )(*scalars, q, *pools, *rows)
+    if latent:
+        return out
     if write:
         return (out[0][..., :d_q], *out[1:])
     return out[..., :d_q]

@@ -242,10 +242,17 @@ def _expert_ffn(params, expert_in, *, activation, compute_dtype):
 
 
 def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True,
-               held=None):
+               held=None, scoring: str = "softmax", select_bias=None,
+               scale: float = 1.0):
     """Drop-free routing of (S, D) tokens: softmax over all experts (f32),
     `lax.top_k`, and the S*k (token, expert) assignments sorted by expert
     (stable: token order within an expert).
+
+    `scoring="sigmoid"` (DeepSeek-V3's router): each expert's score is
+    sigmoid(logit) on its own; the k are those of largest score +
+    `select_bias` ((E,) float32, the load-balancing correction: it moves
+    the PICK and never enters a weight); the weights are the picked
+    experts' scores, over their sum when `normalize`, times `scale`.
 
     Returns (weights (S, k) f32 — the selected probabilities, renormalized
     over the k when `normalize`; `order` (S*k,) — sorted position ->
@@ -266,11 +273,25 @@ def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True,
     # often closer than that; the matmul is (S, D) x (D, E), next to nothing
     logits = jnp.dot(xs.astype(jnp.float32), router_kernel.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, top_k)  # (S, k)
-    if normalize:
-        weights = weights / jnp.maximum(
-            weights.sum(axis=-1, keepdims=True), 1e-9)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        choice = scores if select_bias is None else (
+            scores + select_bias.astype(jnp.float32))
+        _, experts = jax.lax.top_k(choice, top_k)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+        if normalize:
+            weights = weights / (weights.sum(axis=-1, keepdims=True) + 1e-20)
+    elif scoring == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, experts = jax.lax.top_k(probs, top_k)  # (S, k)
+        if normalize:
+            weights = weights / jnp.maximum(
+                weights.sum(axis=-1, keepdims=True), 1e-9)
+    else:
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got "
+                         f"{scoring!r}")
+    if scale != 1.0:
+        weights = weights * scale
     flat = experts.reshape(-1).astype(jnp.int32)
     if held is not None:
         first, e = held
@@ -340,7 +361,8 @@ def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
 
 def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
                     activation=gelu, compute_dtype=None,
-                    return_stats: bool = False, held=None):
+                    return_stats: bool = False, held=None,
+                    scoring: str = "softmax", scale: float = 1.0):
     """Drop-free MoE FFN on one device: (..., D) -> (..., D), every token
     of `x` routed to its top_k experts and every routed row computed.
     Output does NOT include the residual; callers add it.
@@ -361,14 +383,18 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
     of the layer's sum, and the shares of all chips add up to the whole
     layer's result. The statistics count the held experts' rows. None:
     every expert is held, and the program is what it was without the
-    argument."""
+    argument.
+
+    `scoring` / `scale` are `route_rows`'; the selection bias is read
+    from `params["router"]["select_bias"]` where the tree carries one."""
     shape, d = x.shape, x.shape[-1]
     xs = x.reshape(-1, d)
     s = xs.shape[0]
     with jax.named_scope("moe.route"):
         weights, order, expert_of_row, group_sizes = route_rows(
             params["router"]["kernel"], xs, top_k=top_k, normalize=normalize,
-            held=held)
+            held=held, scoring=scoring,
+            select_bias=params["router"].get("select_bias"), scale=scale)
         rows = xs[order // top_k]  # (S*k, D), sorted by expert
     with jax.named_scope("moe.experts"):
         out = _experts_grouped(params, rows, expert_of_row, group_sizes,

@@ -18,15 +18,18 @@ What this buys a serving pool (tests/test_paged.py measures both):
     (a free-list of ints — no device work to retire a request).
 
 Layout (per leaf KIND, mirroring the dense cache's (L, B, H, S, D)). A
-position's state is K and V per KV head and, for a family whose
-attention selects what it reads (models/dsa.py), the position's index
-key as a third leaf "ik" — one head, its own width, written, installed,
-gathered and freed with the block like K and V; a family without an
-indexer allocates and moves nothing new:
+position's state is what its FAMILY says it is — `cache_leaves`, name ->
+(heads, width), every leaf written, installed, gathered and freed with
+the block. By default K and V per KV head; for a family whose attention
+selects what it reads (models/dsa.py) also the position's index key, a
+third leaf "ik" of one head; for latent attention (models/mla.py) ONE
+leaf "latent" of one head — the compressed latent and the shared rotary
+key side by side — which attention reads as key AND value:
 
     pool   (L, n_blocks, H, block_len, Dp) Dp = D rounded up to whole
                                           128-lane tiles (`lane_padded`)
     ik     (L, n_blocks, 1, block_len, Dip)  Dip = lane_padded(index_dim)
+    latent (L, n_blocks, 1, block_len, Dlp)  Dlp = lane_padded(r + dr)
     tables (L, B, max_blocks)  int32   -- replicated over L (the leaf
                                           shape every install / copy /
                                           tier program was written to)
@@ -198,8 +201,7 @@ def _pad_lanes(x, width):
 
 def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
                      block_len: int = 16, dtype=jnp.float32,
-                     kv_heads: Optional[int] = None,
-                     index_dim: Optional[int] = None):
+                     kv_heads: Optional[int] = None, leaves=None):
     """Pool + tables pytree for `slots` decode rows of up to `max_len`
     positions each, sharing `n_blocks` physical blocks of `block_len`
     positions (leading L on every leaf, like the dense cache; K/V rows
@@ -209,20 +211,28 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
     dtype="int8" / "int4" build the quantized pools: int8/int4 K/V
     blocks plus per-(position, head) f32 scale blocks, the paged forms
     of kvcache.Int8KV / Int4KV's layouts (int4 stores native jnp.int4,
-    two values per byte). `index_dim` adds the index-key leaf "ik" (one
-    head of that width a position, float pools only)."""
+    two values per byte). `leaves` = {name: (heads, width)} is a family's
+    own declaration of a position's state (`cache_leaves`: K, V and an
+    index key; one latent) in place of K and V, float pools only."""
     if max_len % block_len:
         raise ValueError(f"max_len {max_len} must tile block_len {block_len}")
+    nb_max = max_len // block_len
+    tables = jnp.zeros((cfg.n_layer, slots, nb_max), jnp.int32)
+    if leaves is not None:
+        if dtype in ("int8", "int4"):
+            raise ValueError(
+                "a pool with the leaves " + "/".join(leaves) + " is float: "
+                "int8 / int4 pools assume K and V alone")
+        # every leaf's rows are stored LANE-PADDED (`lane_padded`)
+        return {**{name: jnp.zeros((cfg.n_layer, n_blocks, heads, block_len,
+                                    lane_padded(width)), dtype)
+                   for name, (heads, width) in leaves.items()},
+                "tables": tables}
     head_dim = cache_head_dim(cfg)
     heads = kv_heads if kv_heads is not None else cfg.n_head
-    nb_max = max_len // block_len
     # K/V blocks are stored LANE-PADDED (`lane_padded`): see there
     shape = (cfg.n_layer, n_blocks, heads, block_len, lane_padded(head_dim))
-    tables = jnp.zeros((cfg.n_layer, slots, nb_max), jnp.int32)
     if dtype in ("int8", "int4"):
-        if index_dim is not None:
-            raise ValueError("a pool with an index-key leaf is float: "
-                             "int8 / int4 pools assume K and V alone")
         qdt = jnp.int8 if dtype == "int8" else jnp.int4
         return {
             "k": jnp.zeros(shape, qdt),
@@ -231,15 +241,11 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
             "vs": jnp.ones(shape[:-1], jnp.float32),
             "tables": tables,
         }
-    pool = {
+    return {
         "k": jnp.zeros(shape, dtype),
         "v": jnp.zeros(shape, dtype),
         "tables": tables,
     }
-    if index_dim is not None:
-        pool["ik"] = jnp.zeros(
-            shape[:2] + (1, block_len, lane_padded(index_dim)), dtype)
-    return pool
 
 
 class PagedKV:
@@ -275,7 +281,8 @@ class PagedKV:
         """Resolve use_kernel against a concrete per-layer pool view
         (pool (n_blocks, H, bp, D), tables (B, nb_max)) — the paged
         mirror of kvcache._KernelDispatch._kernel_on."""
-        if self.window is not None or c["k"].dtype == jnp.int4:
+        if self.window is not None or c.get("k", c.get("latent")).dtype \
+                == jnp.int4:
             return False
         if self.use_kernel == "auto":
             from dnn_tpu.runtime.kvcache import AUTO_KERNEL_MIN_S
@@ -344,6 +351,47 @@ class PagedKV:
                          c["ik"].shape[-1])  # (B, 1, 1, Dip)
         return self._scatter_rows(c, lambda: {"ik": row}, pos, write_gate,
                                   layer)
+
+    def write_attend_latent_rows(self, q, c, row, pos, write_gate, *,
+                                 value_dim: int, scale: float, layer=None):
+        """Latent attention's decode step (models/mla.py): this step's
+        latent rows `row` (B, 1, Dl) into the "latent" leaf at `pos`
+        (gated and junk-routed as `write_rows`), then every query row of
+        q (B, R, Dl) — the R heads of a slot — against the slot's cached
+        latents: scores q . latent * `scale` over the whole row, softmax
+        over the positions <= pos, and as VALUE the row's first
+        `value_dim` lanes. -> (y (B, R, value_dim) float32, c). The leaf
+        is read once: with the kernel on, a block is copied into VMEM
+        once and used as key and value (paged_decode_attention's
+        `latent=`), and the write is the kernel's as well; the einsum
+        form gathers one view."""
+        leaf = c["latent"]
+        new = _pad_lanes(row.astype(leaf.dtype)[:, None], leaf.shape[-1])
+        if layer is not None and self._kernel_on(c):
+            from dnn_tpu.ops.pallas.cached_attention import (
+                paged_decode_attention,
+            )
+
+            y, pool = paged_decode_attention(
+                q[:, None], leaf, None, c["tables"][layer], pos, layer=layer,
+                new=(new, write_gate), latent=value_dim, scale=scale,
+                interpret=True if self.use_kernel == "interpret" else None)
+            return y[:, 0], {**c, "latent": pool}
+        with jax.named_scope("kv_pool.write"):
+            c = self._scatter_rows(c, lambda: {"latent": new}, pos,
+                                   write_gate, layer)
+        (view,) = self.gather_view(c, ("latent",), layer=layer,
+                                   width=q.shape[-1])
+        kv = view[:, 0]  # (B, S_max, Dl)
+        s = jnp.einsum("brd,bsd->brs", q.astype(jnp.float32),
+                       kv.astype(jnp.float32),
+                       preferred_element_type=jnp.float32) * scale
+        cols = jnp.arange(kv.shape[1])
+        s = jnp.where(cols[None, None, :] <= pos[:, None, None], s, _NEG_BIG)
+        y = jnp.einsum("brs,bsd->brd", jax.nn.softmax(s, axis=-1),
+                       kv[..., :value_dim].astype(jnp.float32),
+                       preferred_element_type=jnp.float32)
+        return y, c
 
     def index_view(self, c, index_dim, layer=None):
         """Every slot's index keys in logical order, (B, S_max, Di): what
@@ -544,12 +592,16 @@ class _PagedLayer:
     def index_view(self, c, index_dim):
         return self.codec.index_view(c, index_dim, layer=self.layer)
 
+    def write_attend_latent_rows(self, q, c, row, pos, write_gate, **kw):
+        return self.codec.write_attend_latent_rows(
+            q, c, row, pos, write_gate, layer=self.layer, **kw)
+
 
 def codec_is_paged(cache) -> bool:
     return isinstance(cache, dict) and "tables" in cache
 
 
-def scan_blocks(block, x, blocks, cache, codec, *xs):
+def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):
     """Run the stacked `blocks` over a KV cache: `block(bp, x, c, codec,
     *xs_l) -> (x, c)` once per layer -> (x, cache). What the cache IS
     decides how it rides the loop (a property of the input, not an
@@ -564,7 +616,11 @@ def scan_blocks(block, x, blocks, cache, codec, *xs):
       * a dense cache (L, B, H, S, D) rides as xs/ys, one layer's slots
         per iteration.
 
-    `xs` are further per-layer inputs (LLaMA's per-layer windows)."""
+    `xs` are further per-layer inputs (LLaMA's per-layer windows).
+    `layers` (paged pools): the pool's layer indices that `blocks` are,
+    where they are not all of them — a model whose layers are of two
+    kinds scans each kind's stack over its own range of ONE pool
+    (llama.layer_stacks)."""
     # `layers.scan` names the loop's OWN work on a device trace: slicing
     # each layer's weights (and a dense cache's layer) out of the stacks
     # and writing the slice back; the block's work carries the inner
@@ -581,7 +637,7 @@ def scan_blocks(block, x, blocks, cache, codec, *xs):
             bp, layer, *rest = layer_in
             return block(bp, *carry, codec.at_layer(layer), *rest), None
 
-        n_layer = cache["tables"].shape[0]
-        (x, cache), _ = lax.scan(
-            paged, (x, cache), (blocks, jnp.arange(n_layer), *xs))
+        if layers is None:
+            layers = jnp.arange(cache["tables"].shape[0])
+        (x, cache), _ = lax.scan(paged, (x, cache), (blocks, layers, *xs))
         return x, cache

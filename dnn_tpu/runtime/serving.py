@@ -335,11 +335,13 @@ class ContinuousBatcher:
         self.family = family or GPTFamilyRows(
             cfg, compute_dtype=compute_dtype, ffn=ffn,
             attn_kernel=attn_kernel)
-        # a family whose cache has a third leaf (models/dsa.py: the
-        # index key of every position) serves from the paged pool alone
+        # a family whose cache is not K and V alone (models/dsa.py: the
+        # index key of every position as a third leaf; models/mla.py: ONE
+        # compressed latent a position) serves from the paged pool alone
         # (checked below, once paging is decided), and what assumes K and
         # V alone refuses it here, by name
         self._index_topk = getattr(self.family, "index_topk", None)
+        self._latent = bool(getattr(self.family, "latent_attention", False))
         if getattr(self.family, "requires_paged", False):
             leaves = "/".join(self.family.cache_leaves)
             refused = None
@@ -351,7 +353,7 @@ class ContinuousBatcher:
                            "assume K and V alone)")
             elif prefill_chunk_tokens or overlap:
                 refused = ("interleaved / overlapped prefill (its mixed "
-                           "step was not built for the third leaf)")
+                           "step was built for K and V alone)")
             if refused is not None:
                 raise ValueError(
                     f"this model's cache has the leaves {leaves}: "
@@ -489,7 +491,7 @@ class ContinuousBatcher:
                 cfg, slots, self.max_len, n_blocks=paged_blocks,
                 block_len=block_len, dtype=cache_dtype,
                 kv_heads=getattr(self.family, "kv_heads", None),
-                index_dim=getattr(self.family, "index_dim", None))
+                leaves=getattr(self.family, "cache_leaves", None))
             self._allocator = BlockAllocator(paged_blocks)
             self._block_len = block_len
             # the family's attn_kernel policy routes paged decode through
@@ -1995,6 +1997,14 @@ class ContinuousBatcher:
                 self.cfg.n_layer * (
                     n_all * start + n_all * (n_all + 1) // 2
                     + (t - n_all) * k))
+        if self._latent and self.step_clock is not None:
+            # the cached latents the chunk's layers attend (everything
+            # before it and itself) and its causal (query, position)
+            # pairs, pad rows too
+            start, t = int(args[3]), int(args[2].shape[-1])
+            self.step_clock.note_mla(
+                "prefill", self.cfg.n_layer, self.cfg.n_layer * (start + t),
+                self.cfg.n_layer * (t * start + t * (t + 1) // 2))
         return res[0], res[1]
 
     def _moe_note(self, program: str, stats, idx: Optional[int] = None):
@@ -2020,7 +2030,9 @@ class ContinuousBatcher:
             return
         for (_, program, _), stats in zip(
                 ready, jax.device_get([r[2] for r in ready])):
-            sc.note_moe(program, self.cfg.n_layer, stats)
+            # a dense prefix's layers are no expert layer calls
+            sc.note_moe(program, getattr(self.cfg, "n_expert_layer",
+                                         self.cfg.n_layer), stats)
 
     def _ensure_cache_len(self, need: int):
         """Grow the bucketed dense pool to the smallest ladder bucket
@@ -2708,6 +2720,11 @@ class ContinuousBatcher:
                 "decode", self.cfg.n_layer,
                 self.cfg.n_layer * (live - n_act),
                 self.cfg.n_layer * picked)
+        if self._latent and self.step_clock is not None:
+            # each live slot's query stood at n - 2 and read n - 1 latents
+            self.step_clock.note_mla(
+                "decode", self.cfg.n_layer,
+                self.cfg.n_layer * (live - n_act), 0)
         if live > self._kv_live_hw:
             self._kv_live_hw = live
         if n_act > self._active_hw:

@@ -111,8 +111,7 @@ class SpeculativeBatcher(ContinuousBatcher):
                 "its verify step attends every cached position and its "
                 "codecs assume K and V alone, where this model's cache "
                 "has the leaves "
-                + "/".join(kw["family"].cache_leaves)
-                + " and its attention selects what it reads")
+                + "/".join(kw["family"].cache_leaves))
         if kw.get("kv") == "paged":
             raise ValueError(
                 "SpeculativeBatcher pins the dense pool (the spec codecs "

@@ -226,7 +226,7 @@ def lowered():
     import jax.numpy as jnp
     from test_chip_compile import first_calls
 
-    from dnn_tpu.models.llama import LlamaConfig, LlamaFamilyRows
+    from dnn_tpu.models.llama import LlamaConfig, family_rows
     from dnn_tpu.node import _stack_and_release
     from dnn_tpu.registry import get_model
     from dnn_tpu.runtime.serving import ContinuousBatcher
@@ -247,10 +247,7 @@ def lowered():
             spec.init(jax.random.PRNGKey(0)), cfg, held_in)
         family = None
         if isinstance(cfg, LlamaConfig):  # as node._serve_lm picks it
-            rows = LlamaFamilyRows
-            if cfg.index_topk is not None:
-                from dnn_tpu.models.dsa import DsaFamilyRows as rows
-            family = rows(cfg, compute_dtype=held_in)
+            family = family_rows(cfg, compute_dtype=held_in)
         batcher = ContinuousBatcher(
             cfg, prepared, compute_dtype=held_in, family=family,
             kv="auto",  # the daemon's default (LMServer's)

@@ -567,3 +567,52 @@ def _sparse_cases():
 def test_sparse_attention_kernels_compile(chip, kernel, dtype):
     fn, shapes = _sparse_cases()[kernel](dtype)
     _compile(chip, fn, *shapes)
+
+
+# ----------------------------------------------------------------------
+# latent attention (models/mla.py) at the JoyAI cut's shapes: a
+# 1024-token chunk of 32 heads (key 128 | 64, value 128) against a prefix
+# of a 16 384-position row, 32 slots of 16 384 positions in blocks of 16,
+# ONE pool leaf of one head, 576 values stored 640 lanes wide
+# ----------------------------------------------------------------------
+
+def _mla_cases():
+    from dnn_tpu.ops.pallas import mla_attention as ma
+
+    h, t, dn, dr, dv, r = 32, 1024, 128, 64, 128, 512
+    b, nb, bp, n_layer, width = 32, 1024, 16, 2, 640
+
+    def prefill_attention(dt, s=16384):
+        return (lambda qn, qr, kn, kr, v, st: ma.mla_prefill_attention(
+            qn, qr, kn, kr, v, st, scale=(dn + dr) ** -0.5,
+            interpret=False),
+            [((h, t, dn), dt), ((h, t, dr), dt), ((h, s, dn), dt),
+             ((s, dr), dt), ((h, s, dv), dt), ((), jnp.int32)])
+
+    def prefill_attention_short(dt):
+        return prefill_attention(dt, s=2048)
+
+    def paged_decode_latent(dt):
+        pool = ((n_layer, b * nb + 1, 1, bp, width), dt)
+
+        def fn(q, tables, pos, layer, gate, cp, row):
+            return ca.paged_decode_attention(
+                q, cp, None, tables, pos, layer=layer, new=(row, gate),
+                latent=r, scale=(dn + dr) ** -0.5, interpret=False)
+
+        return fn, [((b, 1, h, r + dr), dt), ((b, nb), jnp.int32),
+                    ((b,), jnp.int32), ((), jnp.int32), ((b,), jnp.bool_),
+                    pool, ((b, 1, 1, width), dt)]
+
+    return {"prefill_attention": prefill_attention,
+            "prefill_attention_short": prefill_attention_short,
+            "paged_decode_latent": paged_decode_latent}
+
+
+@pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
+@pytest.mark.parametrize("kernel", ["prefill_attention",
+                                    "prefill_attention_short",
+                                    "paged_decode_latent"])
+def test_latent_attention_kernels_compile(chip, kernel, dtype):
+    fn, shapes = _mla_cases()[kernel](dtype)
+    _compile(chip, fn, *shapes)
