@@ -267,9 +267,12 @@ class DsaFamilyRows(llama.LlamaFamilyRows):
         if compute_dtype is not None:
             x = x.astype(compute_dtype)
 
+        blocks, bind = llama.scan_form(prepared["blocks"], self.ffn)
+
         def layer(carry, layer_in):
             x, acc = carry
             bp, layer_cache = layer_in
+            bp = bind(bp)
 
             def run(f):
                 return _chunk_block(
@@ -282,7 +285,7 @@ class DsaFamilyRows(llama.LlamaFamilyRows):
 
         acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
         (x, acc), new_cache = lax.scan(layer, (x, acc0),
-                                       (prepared["blocks"], row_cache))
+                                       (blocks, row_cache))
         logits = llama.head(prepared, x.astype(jnp.float32), cfg=cfg,
                             compute_dtype=compute_dtype)
         if moe_stats:

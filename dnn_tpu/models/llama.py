@@ -37,6 +37,7 @@ io/checkpoint.llama_params_from_state_dict):
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -787,6 +788,34 @@ def layer_stacks(prepared, cfg):
     return [(prepared[name], r) for name, r in ranges.items()]
 
 
+def scan_form(stack, ffn):
+    """How a stack of blocks rides a cached layer loop -> (xs, bind):
+    `xs` goes where the loop's xs had `stack`, and the body opens with
+    `bp = bind(xs_l)`. Under the grouped MoE hook (llama_moe.make_ffn:
+    it has `expert_forms`) the expert matrices do NOT ride as xs: the
+    loop keeps each `(L, E, K, N)` stack whole, `xs` carries the layer
+    index in their place, and `bp["moe"]` hands them on as
+    `moe.LayerOf(stack, layer)` — so no layer's matrices are ever cut
+    out of the stack for the grouped matmul (as `paged_kvcache.
+    scan_blocks` carries the pool). Any other stack or hook: `stack`
+    itself and the identity."""
+    from dnn_tpu.parallel.moe import EXPERT_MATRICES, LayerOf
+
+    moe = stack.get("moe") if hasattr(ffn, "expert_forms") else None
+    whole = {k: moe[k] for k in EXPERT_MATRICES if k in (moe or {})}
+    if not whole:
+        return stack, lambda bp: bp
+    rest = {**stack, "moe": {k: v for k, v in moe.items() if k not in whole}}
+    n_layer = next(iter(whole.values())).shape[0]
+
+    def bind(xs_l):
+        bp, layer = xs_l
+        return {**bp, "moe": {**bp["moe"], **{
+            k: LayerOf(w, layer) for k, w in whole.items()}}}
+
+    return (rest, jnp.arange(n_layer, dtype=jnp.int32)), bind
+
+
 def _scan_all_stacks(prepared, x, *, cfg, **kw):
     """`blocks_scan` over every stack of `layer_stacks`."""
     wins = kw.pop("windows", None)
@@ -981,9 +1010,12 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     if compute_dtype is not None:
         x = x.astype(compute_dtype)
 
+    blocks, bind = scan_form(prepared["blocks"], ffn)
+
     def layer(carry, layer_in):
         x, acc = carry
         bp, layer_cache, *w = layer_in  # w: this layer's window, if any
+        bp = bind(bp)
 
         def run(f):
             return _block_with_cache(
@@ -997,7 +1029,7 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
     (x, acc), new_cache = lax.scan(
         layer, (x, acc0),
-        (prepared["blocks"], cache) + (() if wins is None else (wins,)))
+        (blocks, cache) + (() if wins is None else (wins,)))
     logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
                   compute_dtype=compute_dtype)
     if moe_stats:
@@ -1521,8 +1553,9 @@ class LlamaFamilyRows:
 
         # the loop's carry is (x, the MoE stats summed so far or None):
         # scan_blocks hands it through whole
-        def block(bp, carry, c, codec, window=None):
+        def block(bind, bp, carry, c, codec, window=None):
             x, acc = carry
+            bp = bind(bp)
 
             def run(f):
                 return self._block_rows(bp, x, c, pos, active, codec,
@@ -1539,8 +1572,10 @@ class LlamaFamilyRows:
             wins = () if self._wins is None else (
                 self._wins if layers is None
                 else self._wins[layers[0]:layers[1]],)
+            blocks, bind = scan_form(stack, self.ffn)
             carry, new_cache = scan_blocks(
-                block, carry, stack, new_cache, codec, *wins,
+                functools.partial(block, bind), carry, blocks, new_cache,
+                codec, *wins,
                 layers=None if layers is None else jnp.arange(*layers))
         x, acc = carry
         logits = head(prepared, x.astype(jnp.float32), cfg=self.cfg,

@@ -345,12 +345,18 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
     """The llama `ffn` hook: (block_params, h) -> MoE MLP output, through
     the grouped drop-free experts. `ffn.with_stats(bp, h)` also returns
     that layer call's cost (moe_ffn_grouped's int32 (3,)), which the
-    batcher's adapter sums into the moe_* counters.
+    batcher's adapter sums into the moe_* counters. `ffn.expert_forms`:
+    the forms the expert matmuls of the programs traced so far took
+    (parallel/moe._experts_grouped: "stack_kernel" / "ragged_dot"; what
+    /statusz reports) — and, by being there, what tells a layer loop that
+    this hook takes its expert stacks whole (`llama.scan_form`).
 
     `groups` > 1 is NOT a serving option: it selects the expert-parallel
     path's dense twin (static capacity per routing group, parallel/moe.
     moe_ffn), which the EP parity tests compare an n-device run with."""
     from dnn_tpu.ops.nn import silu
+
+    forms = set()
 
     def routed(bp, h, return_stats):
         if groups != 1:
@@ -363,7 +369,7 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
                                activation=silu, compute_dtype=compute_dtype,
                                return_stats=return_stats, held=cfg.held,
                                scoring=cfg.router.scoring,
-                               scale=cfg.router.scale)
+                               scale=cfg.router.scale, forms=forms)
 
     def with_shared(bp, h, out):
         if cfg.d_shared:
@@ -388,6 +394,7 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
             return with_shared(bp, h, out), stats
 
         ffn.with_stats = with_stats
+        ffn.expert_forms = forms
     return ffn
 
 

@@ -58,6 +58,7 @@ RoPE), `mla.absorb` (W_uk on the query, W_uv on the output),
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -278,9 +279,10 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
         if self.compute_dtype is not None:
             x = x.astype(self.compute_dtype)
 
-        def layer(carry, layer_in):
+        def layer(bind, carry, layer_in):
             x, acc = carry
             bp, rows = layer_in
+            bp = bind(bp)
             (y, rows), acc = llama._run_block(
                 self.ffn, acc,
                 lambda f: self._chunk_block(bp, x, rows, start_pos, f))
@@ -292,7 +294,9 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
             rows = row_cache["latent"]
             if layers is not None:
                 rows = rows[layers[0]:layers[1]]
-            carry, rows = lax.scan(layer, carry, (stack, rows))
+            blocks, bind = llama.scan_form(stack, self.ffn)
+            carry, rows = lax.scan(functools.partial(layer, bind), carry,
+                                   (blocks, rows))
             new_rows.append(rows)
         x, acc = carry
         new_cache = {"latent": new_rows[0] if len(new_rows) == 1

@@ -1145,6 +1145,15 @@ class LMServer:
             "detail": "bytes of the served tree by dtype",
             "bytes": sum(self._weight_bytes.values()),
             "bytes_by_dtype": self._weight_bytes}
+        # how the expert layers of the programs built so far meet their
+        # matrices ("stack_kernel": read out of the held stack in place;
+        # "ragged_dot": a copy a layer on the TPU) — said while they were
+        # traced (parallel/moe._experts_grouped), so a daemon that fell
+        # back shows it without a capture
+        family = getattr(self.batcher, "family", None)
+        forms = getattr(getattr(family, "ffn", None), "expert_forms", None)
+        if forms:
+            comps["weights"]["moe_experts"] = "+".join(sorted(forms))
         # facts, no `state`: the KV cache's bytes leaf by leaf (K, V, an
         # int8 pool's scales, a selecting model's index keys "ik"), from
         # shapes alone

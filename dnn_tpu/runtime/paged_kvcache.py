@@ -616,6 +616,8 @@ def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):
       * a dense cache (L, B, H, S, D) rides as xs/ys, one layer's slots
         per iteration.
 
+    `blocks` is whatever of the layers' params rides the loop, `bp` its
+    layer's part (`llama.scan_form` keeps expert stacks out of it, whole).
     `xs` are further per-layer inputs (LLaMA's per-layer windows).
     `layers` (paged pools): the pool's layer indices that `blocks` are,
     where they are not all of them — a model whose layers are of two

@@ -4,7 +4,9 @@ a change made for one family is seen to leave the others' programs as they
 were. `python tests/step_program_texts.py` prints {preset: {program:
 sha256 of its StableHLO text}} for the checkout it runs in (the recorded
 file `tests/step_program_texts_parent.json` was made so on the commit
-before PR 35)."""
+before PR 35; PR 36 re-recorded `olmoe-test`'s and `keye-test`'s chunk and
+decode programs, whose layer loops keep the expert stacks whole since, and
+left `gpt2-test`'s as they were)."""
 
 import hashlib
 import json

@@ -178,6 +178,7 @@ def served(tmp_path_factory):
             taker.join()
             found = {"metrics": daemon.metrics(),
                      "stepz": daemon.get_json("/stepz"),
+                     "statusz": daemon.get_json("/statusz"),
                      "span_names": {s[0] for s in spans.load_capture(
                          tracered.find_xplane(box["capture"]))["spans"]}}
         except BaseException:
@@ -211,6 +212,19 @@ def test_span_is_written_during_a_capture(served, config, root):
     names = served(config)["span_names"]
     assert any(n == root or n.startswith(root + ".") for n in names), (
         root, sorted(names))
+
+
+@pytest.mark.parametrize("config", sorted(_SERVED))
+def test_statusz_says_how_the_expert_layers_meet_their_matrices(served,
+                                                                config):
+    """`/statusz` `components.weights.moe_experts`: the form the expert
+    matmuls of the built programs took, said while they were traced — on
+    the CPU the plain one (on the chip "stack_kernel", or a daemon that
+    fell back shows it without a capture); a dense model has no such
+    line."""
+    weights = served(config)["statusz"]["components"]["weights"]
+    moe = any(s.startswith("moe_") for s in _SERVED[config]["series"])
+    assert weights.get("moe_experts") == ("ragged_dot" if moe else None)
 
 
 # ----------------------------------------------------------------------

@@ -478,44 +478,56 @@ def test_step_programs_convert_no_weight_stack(programs, name):
     assert _converts_of_extent(compiled[name], weights) == []
 
 
+def _grouped_matmul_calls(compiled):
+    """The lines of a compiled program that call the grouped-matmul
+    kernel (ops/pallas/grouped_matmul.py)."""
+    return [l for l in compiled.as_text().splitlines()
+            if "tpu_custom_call" in l and "grouped_matmul" in l]
+
+
 @pytest.mark.parametrize("name", ["_decode", "_mixed"])
-def test_olmoe_expert_slices_are_named_for_the_experts(olmoe_programs, name):
-    """The grouped-matmul custom call needs its operand materialised, so
-    each layer's slice of an expert stack is copied out: the largest item
-    of the step, and expert-layer time. The benchmark's
-    `moe_experts_roofline_pct` divides by the device time under the
-    `moe.experts` scope, so every fusion that produces a per-layer expert
-    matrix carries that scope in its `op_name` — also now that the stacks
-    arrive in bfloat16 and no convert names the copy — and the identity
-    that carries it is fused into the copy, not an operation beside one
-    named for the layer loop."""
+def test_olmoe_expert_matrices_are_read_from_the_stack_in_place(
+        olmoe_programs, name):
+    """ISSUE 36, on the chip's compiled text: the layer loop keeps the
+    expert stacks whole and the grouped-matmul kernel reads the active
+    experts' tiles out of them, so NO operation of the decode step or the
+    mixed step has a result the size of one layer's expert matrices —
+    `bf16[64,2048,1024]` / `bf16[64,1024,2048]`, the 268 MB copy a matrix
+    that fed `ragged_dot`'s custom call in every layer of every step —
+    and the kernel's calls carry `/moe.experts/` in their `op_name`: the
+    benchmark's `moe_experts_roofline_pct` divides by the device time
+    under that scope, which is now the matmul's."""
     compiled, _, cfg, _ = olmoe_programs
     e, d, f = cfg.n_expert, cfg.n_embd, cfg.d_ff
-    matrix = re.compile(r" = bf16\[%d,(?:%d,%d|%d,%d)\]\S* fusion\("
-                        % (e, d, f, f, d))
+    matrix = re.compile(r" = bf16\[%d,(?:%d,%d|%d,%d)\]" % (e, d, f, f, d))
     made = [l for l in compiled[name].as_text().splitlines()
-            if matrix.search(l) and "calls=%bitcast_fusion" not in l]
-    assert len(made) >= 3, made
-    for line in made:
+            if matrix.search(l)]
+    assert made == [], made[:3]
+    calls = _grouped_matmul_calls(compiled[name])
+    # gate, up and down: one loop body for the decode step, the chunk's
+    # and the rows' for the mixed step
+    assert len(calls) >= 3, len(calls)
+    for line in calls:
         op_name = re.search(r'op_name="([^"]*)"', line)
         assert op_name and "/moe.experts/" in op_name.group(1), line[:300]
 
 
 def test_olmoe_decode_step_gathers_no_expert_weights_by_token(
         olmoe_programs):
-    """The grouped experts meet their weights through the ragged matmul
+    """The grouped experts meet their weights through the grouped matmul
     alone: no operation of the decode step has a result with one expert
     matrix per routed row — (S*k, D, F) or (S*k, F, D), 128 rows x 2 M
     weights here — which is what gathering `wg[expert_of_row]` would
-    build; and the ragged matmul is the chip's grouped kernel, not a
-    dense product over all 64 experts."""
+    build; and the grouped matmul is the kernel that reads the stack, not
+    `ragged_dot`'s custom call nor a dense product over all 64 experts."""
     compiled, _, cfg, _ = olmoe_programs
     text = compiled["_decode"].as_text()
     rows = 16 * cfg.router_top_k
     d, f = cfg.n_embd, cfg.d_ff
     per_row = re.compile(r"\[%d,(?:%d,%d|%d,%d)\]" % (rows, d, f, f, d))
     assert not [l for l in text.splitlines() if per_row.search(l)]
-    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert _grouped_matmul_calls(compiled["_decode"])
+    assert "ragged-dot" not in text
 
 
 # ----------------------------------------------------------------------

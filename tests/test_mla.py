@@ -520,8 +520,10 @@ def parent_texts():
 @pytest.mark.parametrize("preset", ["gpt2-test", "olmoe-test", "keye-test"])
 def test_step_programs_lower_to_the_parents_text(parent_texts, preset):
     """The chunk, finish-and-install and decode programs of the GPT-2,
-    OLMoE and Keye families lower to the text they had on the commit
-    before PR 35 (its sha256, recorded by tests/step_program_texts.py)."""
+    OLMoE and Keye families lower to the recorded text (its sha256, by
+    tests/step_program_texts.py): GPT-2's as on the commit before PR 35;
+    OLMoE's and Keye's chunk and decode programs as PR 36 left them, which
+    took the expert stacks out of the layer loops' xs on purpose."""
     import hashlib
 
     from tests.step_program_texts import texts
