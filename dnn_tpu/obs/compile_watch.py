@@ -9,6 +9,10 @@ registry series:
     jax_compile_seconds_total     — wall seconds spent compiling
     jax_trace_seconds_total       — jaxpr tracing seconds (the Python
                                     side of a cache miss)
+    jax_traces_total              — count of jaxpr traces: a program
+                                    first met shows here even when the
+                                    persistent compile cache served its
+                                    executable and nothing compiled
 
 A serving daemon whose step programs are stable sits at a small constant;
 a recompile storm (shape churn, traced-value leaks) shows up as a
@@ -45,6 +49,7 @@ def _on_duration(name: str, dur: float, **kwargs):
 
             flight.record("compile", seconds=round(dur, 4))
         elif name == _TRACE_KEY:
+            m.inc("jax_traces_total")
             m.inc("jax_trace_seconds_total", dur)
     except Exception:  # noqa: BLE001 — telemetry must never break compiles
         log.debug("compile telemetry listener failed", exc_info=True)

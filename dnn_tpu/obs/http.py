@@ -73,7 +73,8 @@ Prometheus scraper or a plain curl can watch the serving stack:
                        T ms (LM daemon only); ?auto=0 disarms
                        (&perfetto=1 on either: also export the
                        Perfetto-loadable *.trace.json.gz, which takes
-                       several times as long as the capture itself)
+                       several times as long as the capture itself;
+                       &py=0|1: leave out / record Python frames)
     POST /drainz       connection draining (LM daemon): stop admission,
                        finish in-flight decodes, hand queued work back
                        retriable, then exit — 202 + drain state JSON;
@@ -454,6 +455,10 @@ class MetricsHTTPServer:
 
                     perfetto = q.get("perfetto", ["0"])[0] not in (
                         "0", "false", "off")
+                    # py=0|1: Python frames in the capture or not; left
+                    # out, obs/profile.PYTHON_TRACER decides
+                    py = q["py"][0] not in ("0", "false", "off") \
+                        if "py" in q else None
                     if "auto" in q:
                         arm = q["auto"][0] not in ("0", "false", "off")
                         if not arm:
@@ -463,7 +468,8 @@ class MetricsHTTPServer:
                         try:
                             outer._profiler.arm_auto(
                                 float(q.get("threshold_ms", ["100"])[0]),
-                                float(q.get("ms", ["0"])[0]), perfetto)
+                                float(q.get("ms", ["0"])[0]), perfetto,
+                                py)
                         except ValueError as e:
                             self._send(400, str(e) + "\n",
                                        "text/plain; charset=utf-8")
@@ -477,7 +483,7 @@ class MetricsHTTPServer:
                                    "text/plain; charset=utf-8")
                         return
                     try:
-                        path = outer._profiler.capture(ms, perfetto)
+                        path = outer._profiler.capture(ms, perfetto, py)
                     except ProfilerBusy as e:
                         self._send(409, str(e) + "\n",
                                    "text/plain; charset=utf-8")

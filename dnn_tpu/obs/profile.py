@@ -192,9 +192,35 @@ def _stop_trace(path: str, perfetto: bool):
         f.write(xspace)
 
 
+#: whether a capture that does not say records Python frames
+#: (`jax.profiler.ProfileOptions.python_tracer_level` 1, the profiler's
+#: own default, which hooks every Python call of every thread while it
+#: records). Off since PR 37: on the chip it cost the loaded daemon
+#: 12-15 % of its steps a second while recording, against under 2 %
+#: without (PERF.md section 7), and the worker's `loop*` / `step*` /
+#: `admit*` spans name what the frames were needed for. `python_tracer=`
+#: / `POST /profilez?py=1` bring the frames back for hunting host code no
+#: span covers. The runtime's own host events (`DevicePut`, the launches,
+#: the reads: host tracer level 2) and the program's annotations are
+#: recorded either way.
+PYTHON_TRACER = False
+
+
+def _profile_options(python_tracer: Optional[bool]):
+    """`ProfileOptions` for `start_trace`: the profiler's defaults but
+    for the Python tracer (PYTHON_TRACER when `python_tracer` is None)."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = int(
+        PYTHON_TRACER if python_tracer is None else python_tracer)
+    return opts
+
+
 @contextlib.contextmanager
 def _traced(capture_root: Optional[str], keep: int,
-            perfetto: bool = False) -> Iterator[str]:
+            perfetto: bool = False,
+            python_tracer: Optional[bool] = None) -> Iterator[str]:
     """Exclusive start_trace/stop around the body; yields the capture
     dir. Raises ProfilerBusy instead of queueing — a capture request
     against a busy profiler wants a fast 409, not a pile-up."""
@@ -211,13 +237,15 @@ def _traced(capture_root: Optional[str], keep: int,
             backend = jax.default_backend()
         except Exception:  # noqa: BLE001 — a wedged backend still traces
             backend = None
-        jax.profiler.start_trace(path)
+        opts = _profile_options(python_tracer)
+        jax.profiler.start_trace(path, profiler_options=opts)
         # perf_begin lands right after start_trace returns: the armed
         # window starts here (a first capture's profiler init is before)
         meta = {"perf_begin": time.perf_counter(),
                 "t_begin_unix": time.time(),
                 "step_begin": _step_counter(),
-                "backend": backend}
+                "backend": backend,
+                "python_tracer": bool(opts.python_tracer_level)}
         _capturing = True
         try:
             yield path
@@ -228,6 +256,9 @@ def _traced(capture_root: Optional[str], keep: int,
             meta["step_end"] = _step_counter()
             _stop_trace(path, perfetto)
             meta["stop_s"] = time.perf_counter() - meta["perf_end"]
+            # the step counter once the events are collected: what the
+            # worker got done while ending the capture held a core
+            meta["step_stopped"] = _step_counter()
             _write_meta(path, meta)
             try:
                 keep_n = int(os.environ["DNN_TPU_OBS_PROFILE_KEEP"])
@@ -240,14 +271,17 @@ def _traced(capture_root: Optional[str], keep: int,
 
 def capture(duration_ms: float = 1000.0, *,
             capture_root: Optional[str] = None, keep: int = 8,
-            perfetto: bool = False) -> str:
+            perfetto: bool = False,
+            python_tracer: Optional[bool] = None) -> str:
     """Capture `duration_ms` of whatever the process is doing (the
     serving worker keeps stepping; this thread just sleeps inside the
     trace). Returns the capture dir; flight-records the capture.
-    `perfetto` adds the `*.trace.json.gz` (module docstring)."""
+    `perfetto` adds the `*.trace.json.gz` (module docstring);
+    `python_tracer` says whether Python frames are recorded
+    (PYTHON_TRACER when None)."""
     from dnn_tpu.obs import flight
 
-    with _traced(capture_root, keep, perfetto) as path:
+    with _traced(capture_root, keep, perfetto, python_tracer) as path:
         time.sleep(max(0.0, float(duration_ms)) / 1e3)
     flight.record("profile_capture", path=path, ms=float(duration_ms))
     return path
@@ -255,7 +289,8 @@ def capture(duration_ms: float = 1000.0, *,
 
 def capture_step(fn, *, capture_root: Optional[str] = None,
                  keep: int = 8, extra_s: float = 0.0,
-                 perfetto: bool = False):
+                 perfetto: bool = False,
+                 python_tracer: Optional[bool] = None):
     """Capture exactly one call of `fn` (the auto-trigger's "next decode
     step") instead of a wall-clock window; `extra_s` extends the trace
     past the call. Returns (capture_dir, fn's result).
@@ -283,7 +318,7 @@ def capture_step(fn, *, capture_root: Optional[str] = None,
     t0 = time.perf_counter()
     ran, out, step_err, step_ms, path = False, None, None, None, None
     try:
-        with _traced(capture_root, keep, perfetto) as path:
+        with _traced(capture_root, keep, perfetto, python_tracer) as path:
             t1 = time.perf_counter()
             try:
                 out = fn()
@@ -421,16 +456,19 @@ class Profiler:
         self.keep = keep
         self._arm_target = arm_target
 
-    def capture(self, duration_ms: float, perfetto: bool = False) -> str:
+    def capture(self, duration_ms: float, perfetto: bool = False,
+                python_tracer: Optional[bool] = None) -> str:
         return capture(duration_ms, capture_root=self.capture_root,
-                       keep=self.keep, perfetto=perfetto)
+                       keep=self.keep, perfetto=perfetto,
+                       python_tracer=python_tracer)
 
     @property
     def can_arm(self) -> bool:
         return self._arm_target is not None
 
     def arm_auto(self, threshold_ms: float, duration_ms: float = 0.0,
-                 perfetto: bool = False):
+                 perfetto: bool = False,
+                 python_tracer: Optional[bool] = None):
         """Arm the next-slow-step auto capture. duration_ms > 0 extends
         the capture past the triggering step by that wall window (0 =
         exactly one step)."""
@@ -441,7 +479,7 @@ class Profiler:
             "threshold_s": float(threshold_ms) / 1e3,
             "extra_s": max(0.0, float(duration_ms)) / 1e3,
             "capture_root": self.capture_root, "keep": self.keep,
-            "perfetto": bool(perfetto),
+            "perfetto": bool(perfetto), "python_tracer": python_tracer,
         }
 
     def disarm(self):

@@ -55,7 +55,8 @@ This is the intra-step instrument, in two connected halves:
     gauges (step.dispatch_slack / step.sync_tax / step.host_fraction /
     step.per_sec / step.last_wall_ms, and the cumulative totals
     step.steps_total / step.tokens_advanced_total /
-    step.phase_seconds_total{phase=} / step.admit_seconds_total{part=},
+    step.phase_seconds_total{phase=} / step.admit_seconds_total{part=}
+    and the loop's step.loop_seconds_total{part=} (below),
     over a paged KV pool step.attn_live_blocks_total /
     step.attn_table_blocks_total (the blocks the paged decode kernel
     walks, of those the slots' tables have),
@@ -87,10 +88,50 @@ This is the intra-step instrument, in two connected halves:
     consecutive device ops (the serialization bubbles made visible),
     top-K ops by device time.
 
+    **What is partitioned.** The six phases partition the inside of
+    `step()` and `submit()`, and until PR 37 nothing else: what the
+    worker did BETWEEN those calls (heartbeat, control ops, cancels,
+    the work around `submit()`, handing tokens to the streams,
+    publishing results, waiting for an arrival) was in no phase and
+    under no span. The worker's loop (`lm_server._BatcherWorker.run`)
+    now reports it through `loop_part()`, as LOOP_PARTS:
+
+        pre       heartbeat, control ops, tick, abandon / drain checks,
+                  cancels
+        wait      blocked on the request queue with nothing active,
+                  queued or held (the one part that is not a cost)
+        admit     the admission loop LESS the `submit()` walls the
+                  `admit` phase already holds: flight record,
+                  histograms, the first token's hand-off
+        emit      after the step: the watchdog's step_done, one
+                  hand-off a token a stream, publishing finished
+                  requests (and the few microseconds around `step()`)
+
+    step.loop_seconds_total{part=} with step.phase_seconds_total{phase=}
+    partition the worker thread's time: over a window the ten series
+    sum to the window (to the part in progress at each scrape). While a
+    capture records the parts are `loop` / `loop.<part>` annotations
+    (_LoopSpans, `iter=` stat; `loop.step` is the call of step(), with
+    the `step*` spans nested inside, as the `admit*` spans are in
+    `loop.admit`), and a retirement's device edits are
+    `step.commit.retire` (`rid`, `slot`) under `step.commit`.
+
+    **No CPU clock.** A wall clock cannot tell a phase in which the
+    worker ran from one in which it stood ready and waited for the
+    interpreter lock; the thread's CPU clock (`time.thread_time`) could,
+    and is NOT read here: on the chip's host one read costs 5.9 us (a
+    system call; `perf_counter` 0.07 us) and the clock advances in
+    steps of 10 ms and charges a quarter of a blocked thread's time to
+    it (my chip runs, PR 37; PERF.md section 6), so a stamp a phase
+    would cost a step more than all its other marks and read noise.
+    What the threads burn is read at scrape time only
+    (`process.thread_cpu_seconds_total`, lm_server.py), over windows
+    long enough for such a clock.
+
 Served via GET /stepz (JSON; ?format=prom) on the obs endpoint
-and `python -m dnn_tpu.obs timeline [--url URL | PATH]`. The phases
-partition the step (no unattributed dark time); the chip benchmark
-reads the same totals and spans (chipbench/spans.py; PERF.md section 3).
+and `python -m dnn_tpu.obs timeline [--url URL | PATH]`; the chip
+benchmark reads the same totals and spans (chipbench/spans.py,
+chipbench/hosttime.py; PERF.md section 3).
 
 No jax import anywhere in this module — the clock is pure
 perf_counter bookkeeping and analyze() is stdlib-only, so the CLI
@@ -136,6 +177,11 @@ _DEVICE_PHASES = ("dispatch", "wait")
 #: they leave of the admit slice.
 ADMIT_PARTS = ("self", "prefill", "first_token", "install")
 _NO_PARTS = (0.0, 0.0, 0.0)
+#: what the worker's loop does outside step() and submit() (module
+#: docstring). `loop_part("step")` names the call of step() itself: the
+#: microseconds around the step's own record go to `emit`.
+LOOP_PARTS = ("pre", "wait", "admit", "emit")
+_LOOP_ACCRUES = {"pre": 0, "wait": 1, "admit": 2, "emit": 3, "step": 3}
 # the moe.* cumulative series (StepClock.note_moe), each labeled with the
 # program whose expert layers it counts
 MOE_PROGRAMS = ("decode", "prefill")
@@ -183,6 +229,32 @@ class _StepSpans:
         _profile.close_span(self.phase)
         _profile.close_span(self.step)
 
+
+class _LoopSpans:
+    """One iteration of the worker's loop as a `loop` annotation and,
+    under it, the annotation of the part now running: `loop.<part>`, and
+    `loop.step` around the call of step(), whose `step*` spans nest in
+    it (what it keeps of itself is the call's own microseconds). Each
+    part's annotation opens where the last one closed, so `loop` keeps
+    nothing of itself. Exists only while a capture records, as
+    _StepSpans does; every annotation carries `iter=<n>`."""
+
+    __slots__ = ("loop", "part", "idx")
+
+    def __init__(self, idx: int, part: str):
+        self.idx = idx
+        self.loop = _profile.open_span("loop", iter=idx)
+        self.part = _profile.open_span("loop." + part, iter=idx)
+
+    def enter(self, part: str):
+        _profile.close_span(self.part)
+        self.part = _profile.open_span("loop." + part, iter=self.idx)
+
+    def close(self):
+        _profile.close_span(self.part)
+        _profile.close_span(self.loop)
+
+
 #: shared empty admit-slice seq — most steps have no admissions, and
 #: a per-step allocation is host work paid inside every decode step;
 #: end() REPLACES the attribute (never appends) when slices exist, and
@@ -200,7 +272,8 @@ class _StepRec:
     scrape thread recomputes the same values it would assign twice."""
 
     __slots__ = ("t0", "t_end", "marks", "n_adv", "wall", "phases",
-                 "admit_slices", "admit_parts", "mixed", "spans", "moe")
+                 "admit_slices", "admit_parts", "mixed", "spans", "moe",
+                 "loop")
 
     def __init__(self, t0: float):
         self.t0 = t0
@@ -226,6 +299,10 @@ class _StepRec:
         # expert rows]} of the expert layers noted since the last step
         # ended (StepClock.note_moe), or None: a model without experts
         self.moe: "Optional[Dict[str, list]]" = None
+        # seconds of each LOOP_PARTS part the worker's loop spent outside
+        # step() and submit() since the last record ended, or None: a
+        # clock nobody reports a loop to
+        self.loop: "Optional[list]" = None
 
 
 def _fold(rec: _StepRec) -> _StepRec:
@@ -304,6 +381,17 @@ class StepClock:
         self.tokens_advanced_total = 0
         self.phase_seconds_total = {p: 0.0 for p in PHASES}
         self.admit_seconds_total = {p: 0.0 for p in ADMIT_PARTS}
+        # the worker's loop outside step() and submit() (loop_part): the
+        # part it is in and where that began, the seconds step records
+        # and admissions have claimed inside it, the parts accrued since
+        # the last record ended, the open annotations while a capture
+        # records
+        self.loop_seconds_total = {p: 0.0 for p in LOOP_PARTS}
+        self._loop_part: Optional[str] = None
+        self._loop_t = self._loop_in_t = 0.0
+        self._pending_loop: Optional[list] = None
+        self._loop_spans: Optional[_LoopSpans] = None
+        self._loop_iter = 0
         # expert layers (note_moe): per program, MOE_SERIES in order
         self.moe_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
         # a paged KV pool's blocks (note_attn_blocks): live, in the tables
@@ -401,6 +489,8 @@ class StepClock:
                _weak_total("phase_seconds_total", p) for p in PHASES},
             **{labeled("step.admit_seconds_total", part=p):
                _weak_total("admit_seconds_total", p) for p in ADMIT_PARTS},
+            **{labeled("step.loop_seconds_total", part=p):
+               _weak_total("loop_seconds_total", p) for p in LOOP_PARTS},
             "step.dispatch_slack": _weak("dispatch_slack"),
             "step.sync_tax": _weak("sync_tax"),
             "step.host_fraction": _weak("host_fraction"),
@@ -440,6 +530,41 @@ class StepClock:
         if rec.spans is not None:
             rec.spans.next(phase)
 
+    def loop_part(self, part: str, iteration: int = 0):
+        """The worker's loop enters `part` (LOOP_PARTS, or "step" for
+        the call of step() itself): the part it was in ends here. Its
+        seconds, less what step records and admissions claimed inside
+        it, go to the cumulative loop totals and onto the next record.
+        While a capture records, the iteration and its parts are `loop`
+        / `loop.<part>` annotations (_LoopSpans), moved on BEFORE the
+        bookkeeping so that one part's span opens where the last one's
+        closed. One perf_counter read a part, and one check for a
+        recording capture when none does."""
+        if not _obs.enabled():
+            self._loop_part = None  # a gap is no part: start clean
+            return
+        spans = self._loop_spans
+        if part == "pre":  # the next iteration
+            self._loop_iter = iteration
+            if spans is not None:
+                spans.close()
+                spans = self._loop_spans = None
+        if spans is not None:
+            spans.enter(part)
+        elif _profile._capturing:
+            self._loop_spans = _LoopSpans(self._loop_iter, part)
+        t = self._now()
+        was = self._loop_part
+        if was is not None:
+            i = _LOOP_ACCRUES[was]
+            dt = (t - self._loop_t) - self._loop_in_t
+            self.loop_seconds_total[LOOP_PARTS[i]] += dt
+            pend = self._pending_loop
+            if pend is None:
+                pend = self._pending_loop = [0.0] * 4
+            pend[i] += dt
+        self._loop_part, self._loop_t, self._loop_in_t = part, t, 0.0
+
     def _register_gauges(self):
         """Put the scrape-time callables on the registry before the
         first bulk flush would (FLUSH_EVERY steps in): the cumulative
@@ -470,6 +595,7 @@ class StepClock:
         if len(pa) > 64:
             del pa[0], self._pending_parts[0]
         self.phase_seconds_total["admit"] += t1 - t0
+        self._loop_in_t += t1 - t0  # the loop's `admit` part less this
         tot = self.admit_seconds_total
         tot["self"] += (t1 - t0) - sum(parts)
         tot["prefill"] += parts[0]
@@ -579,6 +705,10 @@ class StepClock:
                 self._pending_parts, []
         if self._pending_moe is not None:
             rec.moe, self._pending_moe = self._pending_moe, None
+        if self._pending_loop is not None:
+            rec.loop, self._pending_loop = self._pending_loop, None
+        # the loop part this step ran in keeps what the record leaves
+        self._loop_in_t += rec.t_end - rec.t0
         tot = self.phase_seconds_total
         t = rec.t0
         for name, tm in rec.marks:
@@ -673,6 +803,15 @@ class StepClock:
         return {"self": admit_s - pf - ft - ins, "prefill": pf,
                 "first_token": ft, "install": ins}
 
+    @staticmethod
+    def _loop_split(recs) -> Dict[str, float]:
+        """Seconds of each LOOP_PARTS part the records carry."""
+        acc = [0.0] * 4
+        for r in recs:
+            if r.loop is not None:
+                acc = [a + b for a, b in zip(acc, r.loop)]
+        return {p: round(acc[i], 6) for i, p in enumerate(LOOP_PARTS)}
+
     def _derived(self) -> dict:
         """The three ring-derived gauges from ONE _sums pass, memoized
         on the step counter: a /metrics render calls each gauge in the
@@ -749,6 +888,7 @@ class StepClock:
         return [{"t0": r.t0, "wall": _fold(r).wall, "n_adv": r.n_adv,
                  "mixed": r.mixed,
                  "phases": dict(r.phases),
+                 "loop": None if r.loop is None else list(r.loop),
                  "admit_slices": list(r.admit_slices),
                  "marks": list(r.marks)} for r in recs]
 
@@ -796,6 +936,9 @@ class StepClock:
             "admit_split": {k: round(v, 6) for k, v in split.items()},
             "pure_host_s": round(
                 host - split["prefill"] - split["first_token"], 6),
+            # the worker's loop outside step() and submit() over the
+            # same records (LOOP_PARTS; not part of `window_wall_s`)
+            "loop_split": self._loop_split(recs),
             "host_fraction": round(host / wall, 4) if wall > 0 else 0.0,
             "dispatch_slack": round(host / dev, 4) if dev > 0 else 0.0,
             "sync_tax": round(tot["wait"] / wall, 4) if wall > 0 else 0.0,
@@ -832,6 +975,11 @@ class StepClock:
             "last_wall_ms": s["last_wall_ms"],
             "last_step_age_s": None if age is None else round(age, 3),
             "host_fraction": s["host_fraction"],
+            # host_fraction counts the prefill an admission dispatches
+            # and waits for as host time; this one leaves it out
+            "pure_host_fraction": round(
+                s["pure_host_s"] / s["window_wall_s"], 4)
+            if s["window_wall_s"] > 0 else 0.0,
             "steps_per_sec": s["steps_per_sec"],
             "steps_total": s["steps_total"],
         }

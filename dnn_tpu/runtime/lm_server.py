@@ -35,6 +35,7 @@ import logging
 import queue
 import threading
 import time
+import weakref
 from typing import Any, NamedTuple, Optional
 
 import grpc
@@ -211,6 +212,7 @@ class _BatcherWorker(threading.Thread):
         # its GoodputTracker — _admit feeds the TTFT objective; one
         # None check when off
         self.goodput = None
+        self.cpu_clock_id = None  # this thread's CPU clock, set by run()
         self._held_logged = None  # last item whose hold hit the flight
         # ring — identity-gates the per-retry held_back event
         # _lock serializes submit against the dead-marking in _fail_all /
@@ -614,7 +616,8 @@ class _BatcherWorker(threading.Thread):
                 path, stepped = capture_step(
                     b.step, capture_root=ap.get("capture_root"),
                     keep=ap.get("keep", 8), extra_s=ap.get("extra_s", 0.0),
-                    perfetto=ap.get("perfetto", False))
+                    perfetto=ap.get("perfetto", False),
+                    python_tracer=ap.get("python_tracer"))
                 log.info("auto-profile captured slow-step follow-up to %s",
                          path)
                 return stepped
@@ -627,8 +630,22 @@ class _BatcherWorker(threading.Thread):
         return stepped
 
     def run(self):
+        """The worker's loop. Each iteration tells the step clock which
+        part it is in (obs/timeline.LOOP_PARTS: `pre`, `wait`, `admit`,
+        the step, `emit`), so that the thread's time outside step() and
+        submit() is counted, and written as `loop.*` spans while a
+        capture records; one None check a part without a clock."""
         b = self.batcher
+        # this thread's CPU clock, for the scrape-time
+        # process.thread_cpu_seconds_total{thread="worker"} (the id of a
+        # thread that has ended fails cleanly in clock_gettime)
+        self.cpu_clock_id = time.pthread_getcpuclockid(self.ident)
+        n_iter = 0
         while True:
+            sc = b.step_clock
+            if sc is not None:
+                sc.loop_part("pre", n_iter)
+            n_iter += 1
             hb = self.heartbeat
             if hb is not None:
                 hb()
@@ -651,6 +668,7 @@ class _BatcherWorker(threading.Thread):
                         held.fut.cancel()
                 return
             self._process_cancels()  # step boundary: free cancelled slots
+            arrived = None  # what the idle wait below was woken by
             if self._draining:
                 # connection draining: queued work handed back
                 # retriable, in-flight decodes stepped to completion
@@ -684,14 +702,20 @@ class _BatcherWorker(threading.Thread):
                 # A guard failure must never kill the worker (callers
                 # would hang to request_timeout) — serving correctness
                 # does not depend on the clear happening.
+                if sc is not None:
+                    sc.loop_part("wait")
                 try:
                     self.cache_guard.maybe_clear()
                 except Exception:  # noqa: BLE001
                     log.exception("compile-cache guard failed; continuing")
                 try:
-                    self._admit(self.q.get(timeout=0.1))
+                    arrived = self.q.get(timeout=0.1)
                 except queue.Empty:
                     continue
+            if sc is not None:
+                sc.loop_part("admit")
+            if arrived is not None:
+                self._admit(arrived)
             while not self._draining and b.free_slots():
                 if self._held is not None:
                     # retry the held-back request before new work; still
@@ -706,6 +730,8 @@ class _BatcherWorker(threading.Thread):
                 except queue.Empty:
                     break
             had_active = bool(b.n_active)
+            if sc is not None:
+                sc.loop_part("step")
             try:
                 stepped = self._step_pool(b) if had_active else {}
             except Exception as e:  # noqa: BLE001 — one device-side error
@@ -742,6 +768,8 @@ class _BatcherWorker(threading.Thread):
                               "requests", len(self._futures))
                 self._fail_all(RuntimeError(f"LM batcher worker died: {e}"))
                 return
+            if sc is not None:
+                sc.loop_part("emit")
             if had_active and (sd := self.step_done) is not None:
                 sd()  # a real step completed: the watchdog is warmed
             for rid, tok in stepped.items():  # streaming: tokens as they
@@ -919,6 +947,14 @@ class LMServer:
             from dnn_tpu.obs.mem import install_memory_gauges
 
             install_memory_gauges()
+        # how long a committed token waits for the event-loop thread:
+        # [sum, count, max] seconds from the worker's on_token to the
+        # stream handler's dequeue, plain numbers only the loop thread
+        # writes, read by scrape-time callables (no observe a token)
+        self._emit_lag = [0.0, 0, 0.0]
+        self._rpc_cpu_clock_id = None  # note_rpc_loop_thread()
+        self._thread_cpu_last = {}
+        self._install_host_gauges()
         self.metrics_server = None
         self._watchdog = None
         # step-timeline attribution (obs/timeline.py): the daemon's
@@ -1061,6 +1097,58 @@ class LMServer:
             self.worker.goodput = self.goodput
         if self.step_clock is not None:
             self.batcher.step_clock = self.step_clock
+
+    def note_rpc_loop_thread(self):
+        """Called once ON the thread that runs the gRPC aio server's
+        event loop (serve_lm, start_lm_server_in_background): its CPU
+        clock is process.thread_cpu_seconds_total{thread="rpc_loop"}."""
+        self._rpc_cpu_clock_id = time.pthread_getcpuclockid(
+            threading.get_ident())
+
+    def _thread_cpu(self, thread: str) -> float:
+        """CPU seconds of the batcher worker or of the event-loop thread,
+        read at scrape time from the thread's own CPU clock; the last
+        reading once the thread has ended (a successor worker starts
+        from zero: a counter reset), 0.0 before it has started."""
+        cid = self._rpc_cpu_clock_id if thread == "rpc_loop" else getattr(
+            getattr(self, "worker", None), "cpu_clock_id", None)
+        if cid is not None:
+            try:
+                self._thread_cpu_last[thread] = time.clock_gettime(cid)
+            except OSError:
+                pass
+        return self._thread_cpu_last.get(thread, 0.0)
+
+    def _install_host_gauges(self):
+        """Scrape-time callables for who burns the host's CPU and how
+        long a token waits for the event loop; nothing on a hot path."""
+        m = obs.metrics()
+        if m is None:
+            return
+        from dnn_tpu.utils.metrics import labeled
+
+        lag = self._emit_lag
+        ref = weakref.ref(self)  # the registry outlives a server
+
+        def thread_cpu(thread):
+            def read():
+                srv = ref()
+                return srv._thread_cpu(thread) if srv is not None else 0.0
+            return read
+
+        for name, fn in {
+            **{labeled("process.thread_cpu_seconds_total", thread=t):
+               thread_cpu(t) for t in ("worker", "rpc_loop")},
+            "process.cpu_seconds_total": time.process_time,
+            # this process's perf_counter at the scrape: the clock of
+            # the step series and of a capture's meta.json, so a reader
+            # knows a window's seconds and where a capture lies in it
+            "process.perf_counter_seconds": time.perf_counter,
+            "serving.emit_lag_seconds_sum": lambda: lag[0],
+            "serving.emit_lag_seconds_count": lambda: float(lag[1]),
+            "serving.emit_lag_seconds_max": lambda: lag[2],
+        }.items():
+            m.set_fn(name, fn)
 
     @property
     def auto_profile(self):
@@ -2053,8 +2141,11 @@ class LMServer:
             q: "asyncio.Queue" = asyncio.Queue()
             cancel_evt = threading.Event()
 
+            lag = self._emit_lag
+
             def on_token(tok):
-                loop.call_soon_threadsafe(q.put_nowait, ("tok", tok))
+                loop.call_soon_threadsafe(
+                    q.put_nowait, ("tok", (tok, time.perf_counter())))
 
             fut = self.worker.submit(
                 np.asarray(prompt, np.int32).reshape(-1), max_new, seed,
@@ -2093,10 +2184,16 @@ class LMServer:
                     continue  # loop re-checks the deadline and aborts
                 if kind == "tok":
                     n += 1
+                    tok, t_commit = val
+                    waited = time.perf_counter() - t_commit
+                    lag[0] += waited
+                    lag[1] += 1
+                    if waited > lag[2]:
+                        lag[2] = waited
                     yield wc.TensorResponse(
                         status=f"[lm] token {n}",
                         result_tensor=_tensor_msg(
-                            np.asarray([val], np.int32)),
+                            np.asarray([tok], np.int32)),
                     )
                     continue
                 await self._result_or_abort(val, context)
@@ -2195,6 +2292,7 @@ async def serve_lm(cfg, prepared, *, port: int, **server_kwargs) -> int:
     log.info("gRPC LM server listening on %s (%d slots)", listen,
              servicer.batcher.slots)
     await server.start()
+    servicer.note_rpc_loop_thread()
     loop = asyncio.get_running_loop()
     sigterm_drained = False
 
@@ -2279,6 +2377,7 @@ def start_lm_server_in_background(cfg, prepared, *, port: int, **server_kwargs):
                 servicer.close()
                 raise RuntimeError(f"failed to bind gRPC server to [::]:{port}")
             await server.start()
+            servicer.note_rpc_loop_thread()
             state["servicer"], state["server"] = servicer, server
             state["done"] = asyncio.Event()
         except BaseException as e:

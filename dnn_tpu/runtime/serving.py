@@ -1748,12 +1748,11 @@ class ContinuousBatcher:
             else:
                 ahead: deque = deque()
                 for c in range(start_chunk, n_chunks):
-                    with _prof_annotation("serving.prefill_chunk"):
-                        logits, row = self._run_prefill_chunk(
-                            ahead, pf_prepared, row,
-                            padded[:, c * p_pad:(c + 1) * p_pad],
-                            np.int32(c * p_pad),
-                        )
+                    logits, row = self._run_prefill_chunk(
+                        ahead, pf_prepared, row,
+                        padded[:, c * p_pad:(c + 1) * p_pad],
+                        np.int32(c * p_pad),
+                    )
                     self.prefill_chunks_run += 1
                     if self._prefix_cache is not None \
                             and (c + 1) * p_pad <= len(prompt):
@@ -1907,7 +1906,7 @@ class ContinuousBatcher:
                 self._constraint_advance(slot, first)
             # a prompt longer than the window rolls blocks out at install
             self._free_rolled_blocks(slot)
-            self._retire_if_done(slot)
+            self._retire_if_done(slot, in_step=False)
             return rid
         except BaseException:
             # a failure ANYWHERE in the prefill path must return this
@@ -2111,12 +2110,11 @@ class ContinuousBatcher:
         t_pf = time.perf_counter()
         ahead: deque = deque()
         for c in range(n_chunks):
-            with _prof_annotation("serving.prefill_chunk"):
-                logits, row = self._run_prefill_chunk(
-                    ahead, self.prepared, row,
-                    padded[:, c * p_pad:(c + 1) * p_pad],
-                    np.int32(c * p_pad),
-                )
+            logits, row = self._run_prefill_chunk(
+                ahead, self.prepared, row,
+                padded[:, c * p_pad:(c + 1) * p_pad],
+                np.int32(c * p_pad),
+            )
             self.prefill_chunks_run += 1
         last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
         logits_row = np.asarray(logits[0, last_local])
@@ -2376,11 +2374,10 @@ class ContinuousBatcher:
             ahead: deque = deque()
             for i in range(n_k):
                 start = resume + i * p_pad
-                with _prof_annotation("serving.prefill_chunk"):
-                    logits, row = self._run_prefill_chunk(
-                        ahead, self.prepared, row,
-                        padded[:, i * p_pad:(i + 1) * p_pad],
-                        np.int32(start))
+                logits, row = self._run_prefill_chunk(
+                    ahead, self.prepared, row,
+                    padded[:, i * p_pad:(i + 1) * p_pad],
+                    np.int32(start))
                 self.prefill_chunks_run += 1
                 for b in range(start // bp, n_cover):
                     pos = (b + 1) * bp - 1
@@ -2507,11 +2504,10 @@ class ContinuousBatcher:
         ahead: deque = deque()
         for i in range(n_k):
             start = resume + i * p_pad
-            with _prof_annotation("serving.prefill_chunk"):
-                logits, row = self._run_prefill_chunk(
-                    ahead, pf_prepared, row,
-                    padded_r[:, i * p_pad:(i + 1) * p_pad],
-                    np.int32(start))
+            logits, row = self._run_prefill_chunk(
+                ahead, pf_prepared, row,
+                padded_r[:, i * p_pad:(i + 1) * p_pad],
+                np.int32(start))
             self.prefill_chunks_run += 1
             for b in range(start // bp, p_len // bp):
                 pos = (b + 1) * bp - 1
@@ -2865,7 +2861,12 @@ class ContinuousBatcher:
                           tokens=len(req["emitted"]),
                           trace_id=tr.trace_id if tr else None)
 
-    def _retire_if_done(self, slot: int):
+    def _retire_if_done(self, slot: int, in_step: bool = True):
+        """Retire `slot`'s request if it has ended. While a capture
+        records, the device edits of a retirement inside a step's commit
+        (the freed blocks' table entries, the constraint row, the slot's
+        `active` flag: one eager program each) are a `step.commit.retire`
+        span; submit() retires outside a step (`in_step=False`)."""
         req = self._slot_req[slot]
         reason = None
         if self.eos_id is not None and req["emitted"][-1] == self.eos_id:
@@ -2918,9 +2919,12 @@ class ContinuousBatcher:
             # windowed pools already reclaimed the rolled-out prefix
             self._allocator.free(req["blocks"][req["freed"]:])
             self._pool_exhausted_episode = False  # blocks came free
+        sp = _profile.open_span("step.commit.retire", rid=rid, slot=slot) \
+            if in_step else None
         self._release_slot_constraint(slot, req)
         self._slot_req[slot] = None
         self.active = self.active.at[slot].set(False)
+        _profile.close_span(sp)
         self._obs_retire(req, reason)
 
     def _note_constrained(self, delta: int):

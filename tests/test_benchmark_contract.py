@@ -23,7 +23,7 @@ import threading
 
 import pytest
 
-from chipbench import cells, spans, tracered
+from chipbench import cells, hosttime, spans, tracered
 from chipbench.daemon import Daemon
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -179,8 +179,13 @@ def served(tmp_path_factory):
             found = {"metrics": daemon.metrics(),
                      "stepz": daemon.get_json("/stepz"),
                      "statusz": daemon.get_json("/statusz"),
-                     "span_names": {s[0] for s in spans.load_capture(
-                         tracered.find_xplane(box["capture"]))["spans"]}}
+                     "capture": box["capture"]}
+            xplane = tracered.find_xplane(box["capture"])
+            # the worker's line as each of the benchmark's two loaders
+            # keeps it (`step*` and `admit*`; those and `loop*`)
+            found["span_names"] = {
+                s[0] for s in spans.load_capture(xplane)["spans"]
+                + hosttime.load_capture(xplane)}
         except BaseException:
             if daemon.proc.poll() is None:
                 daemon.proc.kill()
@@ -212,6 +217,253 @@ def test_span_is_written_during_a_capture(served, config, root):
     names = served(config)["span_names"]
     assert any(n == root or n.startswith(root + ".") for n in names), (
         root, sorted(names))
+
+
+# the loop's own span and the retirement's span are read by no metric
+# alone (`hosttime`'s `unnamed` holds what `loop` keeps of itself, and
+# `step.commit`'s share holds `step.commit.retire`): README's capture
+# walkthrough names them for whoever opens a capture
+@pytest.mark.parametrize("name", list(hosttime.ROOTS) + [
+    "loop." + p for p in hosttime.SPAN_PARTS] + ["step.commit.retire"])
+def test_worker_span_is_written_by_the_daemon(served, name):
+    config = sorted(_SERVED)[0]
+    assert name in served(config)["span_names"], sorted(
+        served(config)["span_names"])
+
+
+def test_capture_meta_says_where_the_capture_lies_in_the_window(served):
+    """`hosttime.capture_rates` reads the capture's `meta.json` on the
+    daemon's own clock, which `/metrics` shows at each scrape."""
+    found = served(sorted(_SERVED)[0])
+    with open(os.path.join(found["capture"], "meta.json")) as f:
+        meta = json.load(f)
+    assert meta["step_begin"] <= meta["step_end"] <= meta["step_stopped"]
+    assert meta["perf_begin"] < meta["perf_end"]
+    assert meta["stop_s"] > 0 and meta["python_tracer"] in (True, False)
+    assert found["metrics"]["process_perf_counter_seconds"] > \
+        meta["perf_end"] + meta["stop_s"]
+    facts = {"trace_capture": found["capture"],
+             "metrics0": {"process_perf_counter_seconds":
+                          meta["perf_begin"] - 2.0,
+                          "step_steps_total": meta["step_begin"] - 40},
+             "metrics1": dict(found["metrics"])}
+    rates = hosttime.capture_rates(facts)
+    assert rates["quiet_steps_per_s"] > 0 and rates["inside_steps_per_s"] > 0
+    assert rates["slowdown_pct"] == pytest.approx(100 * (
+        1 - rates["inside_steps_per_s"] / rates["quiet_steps_per_s"]))
+
+
+def test_worker_time_is_partitioned_on_the_daemons_page(served):
+    """The six `step_phase_seconds_total` and four `step_loop_seconds_total`
+    sum to the worker thread's life so far (the page's own clock less the
+    moment the loop began: under the daemon's uptime, over the time since
+    its first request); the threads' CPU clocks are read at the scrape."""
+    m = served(sorted(_SERVED)[0])["metrics"]
+
+    def total(family, label, values):
+        return sum(m[f'{family}{{{label}="{v}"}}'] for v in values)
+
+    wall_loop = total("step_loop_seconds_total", "part", hosttime.LOOP_PARTS)
+    wall = wall_loop + total("step_phase_seconds_total", "phase",
+                             spans.PHASES)
+    assert 0 < wall < m["process_perf_counter_seconds"]
+    assert m['step_loop_seconds_total{part="wait"}'] > 0
+    # the two threads' own clocks, and the process's
+    worker = m['process_thread_cpu_seconds_total{thread="worker"}']
+    rpc = m['process_thread_cpu_seconds_total{thread="rpc_loop"}']
+    assert 0 < worker and 0 < rpc
+    assert worker + rpc <= m["process_cpu_seconds_total"] + 0.05
+    assert m["serving_emit_lag_seconds_count"] > 0
+    assert 0 < m["serving_emit_lag_seconds_sum"] / \
+        m["serving_emit_lag_seconds_count"] <= \
+        m["serving_emit_lag_seconds_max"]
+    assert m["jax_traces_total"] >= m["jax_compilations_total"] > 0
+
+
+# ----------------------------------------------------------------------
+# hosttime's arithmetic, on a stretch of a real capture
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def recorded_loop():
+    """120 ms of `olmoe-chat-saturated`'s capture on the chip (PR 37): the
+    device's operations and the worker's `step*`, `admit*` and `loop*`
+    spans, in the plain lists `chipbench/spans.py` describes."""
+    import gzip
+
+    path = os.path.join(REPO, "tests", "recorded_loop_spans.json.gz")
+    with gzip.open(path, "rt") as f:
+        return json.load(f)
+
+
+def _facts_of(capture, roots):
+    keep = [s for s in capture["spans"] if s[0].split(".")[0] in roots]
+    return {"spans_capture": {"devices": capture["devices"], "spans": keep},
+            "hosttime_capture": capture, "trace_capture": None}
+
+
+def test_loop_shares_add_up_to_what_spans_calls_outside(recorded_loop):
+    """`hosttime.idle_pct` under each `loop.<part>` plus `unnamed` equals
+    `spans.idle_pct(under="outside")` on the same capture; the shares under
+    `step*` and `admit*` are what `spans` reads without the `loop` spans."""
+    facts = _facts_of(recorded_loop, spans.SPAN_ROOTS)
+    parts = {p: hosttime.idle_pct(facts, under="loop." + p)
+             for p in hosttime.SPAN_PARTS}
+    unnamed = hosttime.idle_pct(facts, under="unnamed")
+    outside = spans.idle_pct(facts, under="outside")
+    assert all(v is not None and v >= 0 for v in parts.values())
+    assert sum(parts.values()) + unnamed == pytest.approx(outside, abs=1e-9)
+    assert 0 <= unnamed < 0.5
+    assert outside > 1.0 and max(parts.values()) > 1.0
+    by = facts["hosttime_idle"]["by"]
+    for root in ("step", "admit"):
+        own = 100 * sum(v for k, v in by.items()
+                        if k == root or k.startswith(root + ".")) \
+            / facts["hosttime_idle"]["window_s"]
+        assert own == pytest.approx(spans.idle_pct(facts, under=root),
+                                    abs=1e-9)
+    phases = sum(spans.idle_pct(facts, under="step." + p)
+                 for p in spans.PHASES[1:])
+    assert phases <= spans.idle_pct(facts, under="step") <= phases + 0.5
+    # the whole split, as the run's `note` row carries it
+    row = next(n["hosttime"] for n in facts["notes"] if "hosttime" in n)
+    assert row["idle_loop_parts_sum_pct"] == pytest.approx(
+        row["idle_outside_pct"], abs=1e-9)
+    assert row["idle_unnamed_pct"] == pytest.approx(unnamed)
+    assert row["emit_lag_ms"] is None  # no scrape here
+
+
+def test_recorded_loop_spans_nest_as_the_program_writes_them(recorded_loop):
+    """Every iteration is a `loop` span whose children are its parts and
+    the step; `admit*` nests in `loop.admit`, `step.commit.retire` in
+    `step.commit`; all carry `iter` or `step` / `rid`."""
+    sp = recorded_loop["spans"]
+    loops = [s for s in sp if s[0] == "loop"]
+    assert len(loops) >= 5
+
+    def inside(s, parent):
+        return parent[1] <= s[1] and s[1] + s[2] <= parent[1] + parent[2]
+
+    for lp in loops:
+        kids = [s for s in sp if s is not lp and inside(s, lp)]
+        names = [s[0] for s in kids if s[0].startswith("loop.")]
+        assert names in (["loop.pre", "loop.admit", "loop.step",
+                          "loop.emit"],
+                         ["loop.pre", "loop.wait"],
+                         ["loop.pre", "loop.wait", "loop.admit",
+                          "loop.step", "loop.emit"]), names
+        assert all(s[3]["iter"] == lp[3]["iter"] for s in kids
+                   if s[0].startswith("loop."))
+    for s in sp:
+        root = s[0].split(".")[0]
+        if root == "admit":
+            assert any(inside(s, p) for p in sp if p[0] == "loop.admit")
+        if s[0] == "step":
+            assert any(inside(s, p) for p in sp if p[0] == "loop.step")
+        if s[0] == "step.commit.retire":
+            assert any(inside(s, p) for p in sp if p[0] == "step.commit")
+            assert "rid" in s[3] and "slot" in s[3]
+
+
+def _scripted_capture(lead_ms, n=12):
+    """A worker that dispatches for 0.5 ms (the program starts 0.3 ms in),
+    waits for a 4 ms program and 0.3 ms more, commits 0.2, observes 0.2,
+    emits for 1 ms and comes round in 6.2 ms; the device plane's clock
+    runs `lead_ms` ahead of the host's."""
+    ms = 1_000_000
+    sp, ops = [], []
+    for i in range(n):
+        t = i * 62 * ms // 10
+        it = {"iter": i}
+        st = {"step": i}
+        sp += [["loop", t, 62 * ms // 10, it],
+               ["loop.pre", t, ms // 10, it],
+               ["loop.admit", t + ms // 10, ms // 10, it],
+               ["loop.step", t + 2 * ms // 10, 5 * ms, it],
+               ["step", t + 2 * ms // 10, 5 * ms, st],
+               ["step.host", t + 2 * ms // 10, 0, st],
+               ["step.dispatch", t + 2 * ms // 10, ms // 2, st],
+               ["step.wait", t + 7 * ms // 10, 41 * ms // 10, st],
+               ["step.commit", t + 48 * ms // 10, 2 * ms // 10, st],
+               ["step.obs", t + 5 * ms, 2 * ms // 10, st],
+               ["loop.emit", t + 52 * ms // 10, ms, it]]
+        start = t + 5 * ms // 10 - int(lead_ms * ms)
+        ops += [[start + k * ms, ms, None] for k in range(4)]
+    return {"devices": [{"name": "/device:TPU:0", "ops": ops}], "spans": sp}
+
+
+@pytest.mark.parametrize("lead_ms", [0.0, 0.7, 1.5, 2.4])
+def test_step_cycle_needs_no_agreement_of_the_two_clocks(lead_ms):
+    """The device plane's clock leads the host plane's by another 1-2 ms
+    each capture on the chip, which moves idle time between neighbouring
+    spans by a tenth of the extent. `clock_lead_ms` brackets the lead from
+    what cannot happen (a program before its dispatch, a token before its
+    program's end); `step_cycle` splits a step's idle time into serial host
+    work, measured on the host's clock alone, and the rest, and reads the
+    same whatever the lead."""
+    cap = _scripted_capture(lead_ms)
+    lead = hosttime.clock_lead_ms(cap)
+    assert lead["lo"] == pytest.approx(lead_ms - 0.3, abs=1e-6)
+    assert lead["hi"] == pytest.approx(lead_ms + 0.3, abs=1e-6)
+    facts = {"spans_capture": cap, "hosttime_capture": cap,
+             "trace_capture": None}
+    cycle = hosttime.step_cycle(facts)
+    # 2.2 ms idle a step: 1.6 of serial host work (pre 0.1, admit 0.1,
+    # commit 0.2, obs 0.2, emit 1.0), 0.3 launch + 0.3 wake-up
+    assert cycle["host_serial"] == pytest.approx(1.6, rel=0.1)
+    assert cycle["launch_wake"] == pytest.approx(0.6, abs=0.2)
+    assert cycle["idle"] == pytest.approx(
+        cycle["under_admit"] + cycle["under_wait"] + cycle["host_serial"]
+        + cycle["launch_wake"], abs=1e-9)
+    # the shares by span, by contrast, follow the lead ...
+    raw = hosttime.split(dict(facts))
+    true = hosttime.split({"spans_capture": _scripted_capture(0.0),
+                           "hosttime_capture": _scripted_capture(0.0),
+                           "trace_capture": None})
+    assert true["idle_step_wait_pct"] == pytest.approx(100 * 0.3 / 6.2,
+                                                       abs=1.0)
+    if lead_ms >= 0.7:
+        assert raw["idle_step_wait_pct"] > true["idle_step_wait_pct"] + 8
+        assert raw["idle_loop_emit_pct"] < true["idle_loop_emit_pct"] - (
+            8 if lead_ms >= 1.5 else 2)
+    # ... and come back with the device plane moved to the bounds' middle
+    fixed = raw["lead_corrected"]
+    assert fixed["step.wait"] == pytest.approx(
+        true["idle_step_wait_pct"], abs=1.0)
+    assert fixed["loop.emit"] == pytest.approx(
+        true["idle_loop_emit_pct"], abs=1.0)
+
+
+def test_recorded_capture_has_a_clock_lead_and_a_cycle(recorded_loop):
+    lead = hosttime.clock_lead_ms(recorded_loop)
+    assert 0.5 < lead["lo"] < lead["hi"] < 4.0  # 1.27 .. 2.56 ms
+    facts = _facts_of(recorded_loop, spans.SPAN_ROOTS)
+    cycle = hosttime.step_cycle(facts)
+    assert cycle["steps"] >= 8
+    parts = sum(v for k, v in cycle.items() if k.startswith("own."))
+    assert parts == pytest.approx(cycle["host_serial"])
+    assert cycle["launch_wake"] > 0.5 and cycle["own.loop.emit"] > 0.5
+    assert hosttime.launch_wake_ms_per_step(facts) == cycle["launch_wake"]
+
+
+def test_hosttime_reads_nothing_from_a_program_without_the_spans(
+        recorded_loop):
+    """On the parent commit's capture (no `loop*` span, no new series) each
+    reader returns None and raises nothing."""
+    old = {"devices": recorded_loop["devices"],
+           "spans": [s for s in recorded_loop["spans"]
+                     if s[0].split(".")[0] in spans.SPAN_ROOTS
+                     and s[0] != "step.commit.retire"]}
+    facts = {"spans_capture": old, "hosttime_capture": old,
+             "trace_capture": None, "metrics0": {"step_steps_total": 1.0},
+             "metrics1": {"step_steps_total": 9.0}}
+    assert hosttime.idle_pct(facts, under="loop.emit") is None
+    assert hosttime.idle_pct(facts, under="unnamed") is None
+    assert hosttime.emit_lag_ms(facts) is None
+    assert hosttime.loop_ms_per_step(facts) is None
+    assert hosttime.launch_wake_ms_per_step(facts) is None
+    assert hosttime.capture_rates(facts) is None
+    assert spans.idle_pct(facts, under="step.wait") is not None
 
 
 @pytest.mark.parametrize("config", sorted(_SERVED))

@@ -218,6 +218,188 @@ def test_metrics_bulk_hists():
 
 
 # ----------------------------------------------------------------------
+# the worker's loop outside step() and submit(), and the CPU clock
+# (ISSUE 37; injected wall and CPU clocks)
+# ----------------------------------------------------------------------
+
+class _ScriptedWorker:
+    """A StepClock on an injected clock, driven as `_BatcherWorker.run`
+    drives it."""
+
+    def __init__(self):
+        self.t = [100.0]
+        self.clk = StepClock(registry=Metrics(), now=lambda: self.t[0])
+        self.n = 0
+
+    def spend(self, dt):
+        self.t[0] += dt
+
+    def admission(self):
+        """A submit(): 1 ms of its own, 2 ms of chunk launches, 1 ms
+        install, 4 ms waiting for the first token."""
+        t0 = self.t[0]
+        self.spend(0.008)
+        self.clk.note_admit(t0, (0.002, 0.004, 0.001))
+
+    def step(self):
+        clk = self.clk
+        rec = clk.begin()
+        for phase, dt in (("host", 0.0002), ("dispatch", 0.001),
+                          ("wait", 0.004), ("commit", 0.001),
+                          ("obs", 0.0005)):
+            self.spend(dt)
+            clk.mark(rec, phase)
+        clk.end(rec, n_adv=2)
+
+    def busy_iteration(self, admissions=0, report=True):
+        """`report=False`: the same steps and admissions from a caller
+        that reports no loop (a batcher driven directly)."""
+        part = self.clk.loop_part if report else (lambda *a: None)
+        part("pre", self.n)
+        self.n += 1
+        self.spend(0.0001)                  # heartbeat, cancels
+        part("admit")
+        for _ in range(admissions):
+            self.spend(0.0003)              # _admit around submit()
+            self.admission()
+        part("step")
+        self.spend(0.00001)                 # the call of step()
+        self.step()
+        part("emit")
+        self.spend(0.0008)                  # the hand-offs
+
+    def idle_iteration(self, waited=0.1):
+        clk = self.clk
+        clk.loop_part("pre", self.n)
+        self.n += 1
+        self.spend(0.0001)
+        clk.loop_part("wait")
+        self.spend(waited)                  # q.get(timeout=0.1)
+
+
+def _ten(clk):
+    return dict(**{"phase." + p: v
+                   for p, v in clk.phase_seconds_total.items()},
+                **{"loop." + p: v for p, v in clk.loop_seconds_total.items()})
+
+
+def test_ten_series_partition_a_scripted_worker_loop():
+    """`step_phase_seconds_total` (six) and `step_loop_seconds_total`
+    (four) partition the worker thread's time exactly: every second from
+    the first `loop_part` to the last is in one of the ten, admissions and
+    steps in their phases and the loop's parts less those."""
+    w = _ScriptedWorker()
+    clk = w.clk
+    t_first = w.t[0]
+    w.busy_iteration(admissions=2)
+    w.idle_iteration()
+    w.busy_iteration()
+    w.busy_iteration(admissions=1)
+    clk.loop_part("pre", w.n)  # the next iteration opens: `emit` ends
+    ten = _ten(clk)
+    assert sum(ten.values()) == pytest.approx(w.t[0] - t_first, abs=1e-12)
+    assert ten == pytest.approx({
+        "phase.admit": 3 * 0.008, "phase.host": 3 * 0.0002,
+        "phase.dispatch": 3 * 0.001, "phase.wait": 3 * 0.004,
+        "phase.commit": 3 * 0.001, "phase.obs": 3 * 0.0005,
+        "loop.pre": 4 * 0.0001, "loop.wait": 0.1,
+        "loop.admit": 3 * 0.0003,
+        # the microseconds around step()'s own record count as emit
+        "loop.emit": 3 * (0.0008 + 0.00001)}, abs=1e-12)
+    # the same totals as scrape-time series
+    from dnn_tpu.utils.metrics import render_prometheus
+
+    clk._register_gauges()
+    series = dict(line.rsplit(" ", 1)
+                  for line in render_prometheus(clk._registry).splitlines()
+                  if line and not line.startswith("#"))
+    for part in tl.LOOP_PARTS:
+        assert float(series[f'step_loop_seconds_total{{part="{part}"}}']) \
+            == pytest.approx(ten["loop." + part])
+
+
+def test_the_step_clock_reads_no_cpu_clock(monkeypatch):
+    """One read of the thread CPU clock is a system call of 5.9 us on the
+    chip's host, in steps of 10 ms (PERF.md section 6): nothing the
+    worker calls a step or an admission may read one."""
+    def refuse(*a):
+        raise AssertionError("a CPU clock was read on the step path")
+
+    for name in ("thread_time", "thread_time_ns", "process_time",
+                 "process_time_ns", "clock_gettime", "clock_gettime_ns"):
+        monkeypatch.setattr(time, name, refuse)
+    w = _ScriptedWorker()
+    w.busy_iteration(admissions=1)
+    w.idle_iteration()
+    w.busy_iteration()
+    assert w.clk.steps_total == 2
+
+
+def test_a_waiting_iteration_adds_to_wait_and_pre_alone():
+    w = _ScriptedWorker()
+    clk = w.clk
+    w.busy_iteration(admissions=1)
+    clk.loop_part("pre", w.n)
+    before = _ten(clk)
+    steps = clk.steps_total
+    w.idle_iteration(waited=0.1)
+    w.idle_iteration(waited=0.03)
+    clk.loop_part("pre", w.n)
+    after = _ten(clk)
+    moved = {k: after[k] - before[k] for k in after
+             if after[k] != before[k]}
+    assert moved == pytest.approx({"loop.wait": 0.13,
+                                   "loop.pre": 2 * 0.0001})
+    assert clk.steps_total == steps
+
+
+def test_stepz_is_unchanged_for_records_without_loop_parts():
+    """A clock nobody reports a loop to (a batcher driven directly) gives
+    the records, the fold and every /stepz field it gave before; a worker
+    that does report one changes none of them, and adds `loop_split`."""
+    bare, loop = _ScriptedWorker(), _ScriptedWorker()
+    for i in range(3):
+        bare.busy_iteration(admissions=i % 2, report=False)
+        loop.busy_iteration(admissions=i % 2)
+    sb, sl = bare.clk.summary(), loop.clk.summary()
+    assert {k: v for k, v in sb.items() if k != "loop_split"} == \
+        {k: v for k, v in sl.items() if k != "loop_split"}
+    assert set(sb["phases"]["wait"]) == {"s", "frac", "mean_ms"}
+    assert sb["loop_split"] == dict.fromkeys(tl.LOOP_PARTS, 0.0)
+    assert all(r["loop"] is None for r in bare.clk.records())
+    assert [r["phases"] for r in bare.clk.records()] == \
+        [r["phases"] for r in loop.clk.records()]
+    # what the loop adds: its parts over the ring's records (each record
+    # carries what the loop spent since the one before ended, so the last
+    # iteration's `emit` is not in yet)
+    assert sl["loop_split"] == pytest.approx({
+        "pre": 3 * 0.0001, "wait": 0.0, "admit": 0.0003,
+        "emit": 2 * (0.0008 + 0.00001)}, abs=1e-9)
+    comp = loop.clk.status_component()
+    assert comp["pure_host_fraction"] == pytest.approx(
+        sl["pure_host_s"] / sl["window_wall_s"], abs=1e-4)
+    assert comp["pure_host_fraction"] < comp["host_fraction"]
+
+
+def test_loop_parts_restart_clean_after_the_gate_was_off():
+    w = _ScriptedWorker()
+    clk = w.clk
+    w.busy_iteration()
+    obs.set_enabled(False)
+    clk.loop_part("pre", w.n)       # gate off: nothing accrues
+    w.spend(5.0)
+    obs.set_enabled(True)
+    before = _ten(clk)
+    clk.loop_part("pre", w.n)       # starts clean: the 5 s are no part
+    w.spend(0.0001)
+    clk.loop_part("admit")
+    after = _ten(clk)
+    assert after["loop.pre"] - before["loop.pre"] == pytest.approx(0.0001)
+    assert sum(after.values()) - sum(before.values()) == \
+        pytest.approx(0.0001)
+
+
+# ----------------------------------------------------------------------
 # the instrumented pool (real batcher)
 # ----------------------------------------------------------------------
 
@@ -487,8 +669,9 @@ def test_capture_holds_step_and_admit_spans(pool, tmp_path):
         assert len(steps) == clock.steps_total - before
         assert [s[3]["step"] for s in steps] == list(
             range(before, clock.steps_total))
+        retired = [s for s in spans if s[0] == "step.commit.retire"]
         for st in steps:
-            kids = _children(spans, st)
+            kids = [k for k in _children(spans, st) if k not in retired]
             # the five phases, in order, each carrying the step's index
             assert [k[0] for k in kids] == [
                 "step." + p for p in PHASES[1:]], kids
@@ -496,6 +679,14 @@ def test_capture_holds_step_and_admit_spans(pool, tmp_path):
             # contiguous: each phase starts where the last one ended
             for a, b in zip(kids, kids[1:]):
                 assert a[2] <= b[1] <= a[2] + 1_000_000  # < 1 ms apart
+        # a retirement's device edits: one span a request that ended in
+        # the capture, inside the `step.commit` of the step it ended in
+        commits = [s for s in spans if s[0] == "step.commit"]
+        assert len(retired) == pool.slots
+        assert len({r[3]["rid"] for r in retired}) == pool.slots
+        assert {r[3]["slot"] for r in retired} == set(range(pool.slots))
+        assert all(any(r in _children(spans, c) for c in commits)
+                   for r in retired)
         admits = [s for s in spans if s[0] == "admit"]
         assert len(admits) == pool.slots
         rids = set()
@@ -555,7 +746,8 @@ def test_overlap_steps_keep_the_phase_order_in_a_capture(tmp_path, spec):
     full = ["step." + p for p in PHASES[1:]]
     shapes = set()
     for st in steps:
-        kids = [k[0] for k in _children(spans, st)]
+        kids = [k[0] for k in _children(spans, st)
+                if k[0] != "step.commit.retire"]  # a grandchild
         assert kids in (full, full[2:]), kids  # a flush opens in `wait`
         shapes.add(len(kids))
     assert shapes == {5, 3}
@@ -618,6 +810,72 @@ def test_no_annotation_without_a_recording_capture(pool, tmp_path,
     finally:
         obs.set_enabled(True)
         pool.step_clock = None
+
+
+def test_worker_loop_builds_no_annotation_without_a_capture(pool,
+                                                            monkeypatch):
+    """`_BatcherWorker.run` and `step()` with no capture recording: no
+    span is opened and no TraceAnnotation object is built, whatever the
+    traffic (the loop checks `_capturing` once an iteration). While one
+    records, the loop's iterations and parts, the steps nested in them
+    and a retirement's device edits are all written, from the worker's
+    thread."""
+    import threading
+
+    import jax
+
+    from dnn_tpu.obs import profile
+    from dnn_tpu.runtime.lm_server import _BatcherWorker
+
+    built = []  # (name, thread) of every annotation object constructed
+
+    class Spy(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **stats):
+            built.append((name, threading.current_thread().name))
+            super().__init__(name, **stats)
+
+    monkeypatch.setattr(profile, "_trace_annotation", Spy)
+    clock = StepClock(capacity=64)
+    pool.step_clock = clock
+    worker = _BatcherWorker(pool)
+    worker.start()
+    try:
+        def serve(n):
+            streamed = []
+            futs = [worker.submit(np.arange(1, 5), 5, seed=i,
+                                  on_token=streamed.append)
+                    for i in range(n)]
+            assert all(len(f.result(timeout=120)) == 5 for f in futs)
+            assert len(streamed) == 5 * n
+            time.sleep(0.25)  # two timeouts of the idle wait
+
+        serve(3)  # more requests than slots: the admission loop holds one
+        assert not profile.capturing()
+        assert built == [] and clock._loop_spans is None
+        assert clock.loop_seconds_total["wait"] > 0.2
+        assert clock.loop_seconds_total["emit"] > 0.0
+        with profile.mark_recording():
+            serve(3)
+        names = {n for n, _ in built}
+        assert {"loop", "loop.pre", "loop.wait", "loop.admit", "loop.step",
+                "loop.emit",
+                "step", "step.commit", "step.commit.retire", "admit",
+                "admit.install"} <= names, sorted(names)
+        assert {t for _, t in built} == {"lm-batcher"}
+        n_built = len(built)
+        serve(1)  # the capture has ended: nothing more is built
+        # (the iteration in flight when it ended may close its parts)
+        assert len(built) <= n_built + 2
+        serve(1)
+        assert len(built) <= n_built + 2
+    finally:
+        worker.stop()
+        worker.join(30)
+        pool.step_clock = None
+        pool.results.clear()
+        pool.finish_reasons.clear()
+    assert not worker.is_alive()
+    assert worker.cpu_clock_id is not None  # for the scrape-time gauge
 
 
 # ----------------------------------------------------------------------
