@@ -384,14 +384,16 @@ def mixed_step_args(b, chunk_tokens: int) -> tuple:
 def finish_args(b, chunk_tokens: int) -> tuple:
     """The argument tuple of the finish-and-install program
     (`b._prefill_finish`, which ends every admission): the batcher's
-    state, a fresh row cache, one chunk's logits, and a request's two
-    number arrays, seen-mask, bias row and block ids."""
+    state, a fresh row cache, one chunk's hidden rows, the head's leaves,
+    and a request's two number arrays, seen-mask, bias row and block
+    ids."""
     v = b.cfg.vocab_size
     row = b._ilv_new_row() if b._ilv else b._new_row()
     blocks = (np.zeros((2, b.cache["tables"].shape[-1]), np.int32)
               if b._paged else b._no_blocks)
     return b._slot_state() + (
-        row, jnp.zeros((1, chunk_tokens, v), jnp.float32),
+        row, jnp.zeros((1, chunk_tokens, b.cfg.n_embd), jnp.float32),
+        b.family.head_leaves(b.prepared),
         np.zeros((7,), np.int32), np.ones((4,), np.float32),
         np.zeros((v,), np.bool_), b._no_bias, blocks,
         b._ctable, b._ctrans)

@@ -81,22 +81,24 @@ def _wire_view(arr: np.ndarray) -> Tuple[np.ndarray, str]:
 
 
 def pack(payload: Dict) -> np.ndarray:
-    """{'row': [leaves], 'logits_row': (V,), 'prompt_len': int,
-    'fingerprint': dict} -> one 1-D uint8 array (the wire tensor)."""
+    """{'row': [leaves], 'hidden_row': (C,), 'prompt_len': int,
+    'fingerprint': dict} -> one 1-D uint8 array (the wire tensor).
+    `hidden_row` is the last block's output at the prompt's last token:
+    the decode replica's finish program applies the head to it."""
     leaves: List[np.ndarray] = [np.asarray(x) for x in payload["row"]]
-    logits = np.ascontiguousarray(np.asarray(payload["logits_row"]))
+    hidden = np.ascontiguousarray(np.asarray(payload["hidden_row"]))
     chunks, specs = [], []
-    for leaf in leaves + [logits]:
+    for leaf in leaves + [hidden]:
         wire, name = _wire_view(leaf)
         chunks.append(wire.tobytes())
         specs.append({"shape": list(leaf.shape), "dtype": name,
                       "bytes": len(chunks[-1])})
     header = json.dumps({
-        "v": 1,
+        "v": 2,  # 1 carried a logits row (V values) where the hidden is
         "prompt_len": int(payload["prompt_len"]),
         "fingerprint": payload.get("fingerprint") or {},
         "leaves": specs[:-1],
-        "logits": specs[-1],
+        "hidden": specs[-1],
     }).encode()
     buf = b"".join([_MAGIC, len(header).to_bytes(4, "big"), header]
                    + chunks)
@@ -126,7 +128,7 @@ def _read_leaf(body: memoryview, off: int, spec: dict
 
 def unpack(buf) -> Dict:
     """Inverse of pack: the wire tensor -> {'row': [leaves],
-    'logits_row', 'prompt_len', 'fingerprint'}. Raises
+    'hidden_row', 'prompt_len', 'fingerprint'}. Raises
     HandoffFormatError (a ValueError) on anything malformed — a decode
     replica must answer INVALID_ARGUMENT, never adopt garbage KV."""
     raw = np.asarray(buf, np.uint8).tobytes()
@@ -151,10 +153,15 @@ def unpack(buf) -> Dict:
     for spec in head.get("leaves", []):
         leaf, off = _read_leaf(body, off, spec)
         leaves.append(leaf)
-    logits, off = _read_leaf(body, off, head["logits"])
+    if "hidden" not in head:
+        raise HandoffFormatError(
+            "handoff payload carries no hidden row (version "
+            f"{head.get('v')!r}: a prefill replica of an older version "
+            "sends a logits row)")
+    hidden, off = _read_leaf(body, off, head["hidden"])
     return {
         "row": leaves,
-        "logits_row": logits,
+        "hidden_row": hidden,
         "prompt_len": int(head["prompt_len"]),
         "fingerprint": head.get("fingerprint") or {},
     }

@@ -145,7 +145,7 @@ def pack_blocks(payload: Dict) -> np.ndarray:
         chunks.append(wire)
         leaf_specs[name] = {"shape": list(arr.shape), "dtype": true_dt,
                             "enc": enc, "bytes": len(wire)}
-    lr = payload.get("logit_rows") or {}
+    lr = payload.get("hidden_rows") or {}
     lr_idx = sorted(int(i) for i in lr)
     lr_arr = (np.stack([np.asarray(lr[i], np.float32) for i in lr_idx])
               if lr_idx else np.zeros((0, 0), np.float32))
@@ -156,8 +156,8 @@ def pack_blocks(payload: Dict) -> np.ndarray:
         "n_tokens": int(tokens.size),
         "fingerprint": fp,
         "leaves": leaf_specs,
-        "logit_idx": lr_idx,
-        "logit_shape": list(lr_arr.shape),
+        "hidden_idx": lr_idx,
+        "hidden_shape": list(lr_arr.shape),
     }).encode()
     buf = b"".join([_MAGIC, len(header).to_bytes(4, "big"), header]
                    + chunks)
@@ -217,16 +217,16 @@ def unpack_blocks(buf) -> Dict:
                     f"{shape} dtype {spec['dtype']}") from None
         leaves[name] = arr
         at += n
-    lr_shape = tuple(head.get("logit_shape") or (0, 0))
+    lr_shape = tuple(head.get("hidden_shape") or (0, 0))
     lr_count = int(np.prod(lr_shape)) if lr_shape else 0
     lr_arr = np.frombuffer(body[at:at + lr_count * 4], np.float32)
     if lr_arr.size != lr_count:
-        raise MigrateFormatError("kvtier payload truncated (logits)")
+        raise MigrateFormatError("kvtier payload truncated (hidden rows)")
     lr_arr = lr_arr.reshape(lr_shape) if lr_count else lr_arr
-    logit_rows = {int(i): lr_arr[j]
-                  for j, i in enumerate(head.get("logit_idx", []))}
+    hidden_rows = {int(i): lr_arr[j]
+                   for j, i in enumerate(head.get("hidden_idx", []))}
     return {"tokens": tokens, "block_len": int(head["block_len"]),
-            "leaves": leaves, "logit_rows": logit_rows,
+            "leaves": leaves, "hidden_rows": hidden_rows,
             "fingerprint": head.get("fingerprint") or {}}
 
 

@@ -48,14 +48,15 @@ def chunk_key(tokens: np.ndarray) -> bytes:
 class RadixNode:
     """One resident block: `chunk` (the block_len token ids), `block`
     (the physical pool block id the store holds one reference on),
-    `children` keyed by the next chunk's bytes, `logit_row` (the
-    model's logits AFTER this node's last token, when the insert had
-    them — what lets an exactly-block-aligned full-prompt hit sample
-    its first token without running a single chunk), and `origin`
+    `children` keyed by the next chunk's bytes, `hidden_row` (the
+    last block's output AT this node's last token — C values, which the
+    finish program's head turns into the logits after it — when the
+    insert had them: what lets an exactly-block-aligned full-prompt hit
+    sample its first token without running a single chunk), and `origin`
     ("local" = prefilled here, "adopted" = migrated in from a sibling
     replica — the cross-replica hit accounting reads this)."""
 
-    __slots__ = ("chunk", "block", "children", "parent", "logit_row",
+    __slots__ = ("chunk", "block", "children", "parent", "hidden_row",
                  "origin", "lru", "obskey")
 
     def __init__(self, chunk: np.ndarray, block: int,
@@ -64,7 +65,7 @@ class RadixNode:
         self.block = int(block)
         self.children: Dict[bytes, RadixNode] = {}
         self.parent = parent
-        self.logit_row = None
+        self.hidden_row = None
         self.origin = origin
         self.lru = 0
         # path digest stamped by obs/kvlens.py at insert time — evicted
@@ -163,7 +164,7 @@ class RadixIndex:
     # -- insert / evict ------------------------------------------------
 
     def insert(self, tokens: np.ndarray, blocks: List[int], *,
-               logit_rows: Optional[dict] = None,
+               hidden_rows: Optional[dict] = None,
                origin: str = "local"
                ) -> Tuple[List[RadixNode], List[RadixNode]]:
         """Insert the full-chunk path for `tokens` (block-aligned; the
@@ -172,8 +173,8 @@ class RadixIndex:
         blocks stay as-is and the corresponding entry of `blocks` is
         simply not referenced (the caller keeps ownership of it).
 
-        `logit_rows` maps chunk INDEX (0-based along this path) -> the
-        logits row after that chunk's last token; attached to the node
+        `hidden_rows` maps chunk INDEX (0-based along this path) -> the
+        hidden row at that chunk's last token; attached to the node
         (existing nodes only gain a row they lacked — a row is a pure
         function of the prefix, so overwriting is a no-op by value).
 
@@ -226,9 +227,9 @@ class RadixIndex:
                 node.children[key] = child
                 self._nodes.append(child)
                 created.append(child)
-            if logit_rows and i in logit_rows \
-                    and child.logit_row is None:
-                child.logit_row = logit_rows[i]
+            if hidden_rows and i in hidden_rows \
+                    and child.hidden_row is None:
+                child.hidden_row = hidden_rows[i]
             node = child
         return created, evicted
 

@@ -40,7 +40,7 @@ class PrefixHit:
     block candidate: `cow_tokens` leading tokens of the next partial
     chunk agree with the cached block `cow_src`, so copying that ONE
     block lets prefill resume mid-block (0 = no boundary sharing);
-    `logit_row` — the stored logits after the last shared token,
+    `hidden_row` — the stored hidden row at the last shared token,
     present only when the prompt is exactly the shared run (the
     full-hit fast path: zero chunks run).
 
@@ -54,7 +54,7 @@ class PrefixHit:
     cow_src: int = -1
     cow_tokens: int = 0
     cow_origin: str = "local"
-    logit_row: Optional[object] = None
+    hidden_row: Optional[object] = None
 
     @property
     def n_shared(self) -> int:
@@ -107,11 +107,11 @@ class PrefixStore:
             # adopt/export paths, not arriving traffic, and would skew
             # the reuse-distance sample if fed here
             self.lens.on_access(prompt, n_resident=len(matched))
-        logit_row = None
+        hidden_row = None
         bp = self.block_len
         p = int(np.asarray(prompt).size)
         if matched and p == len(matched) * bp:
-            logit_row = matched[-1].logit_row
+            hidden_row = matched[-1].hidden_row
         has_cow = cow_n > 0 and cow_node is not None
         return PrefixHit(
             shared=[n.block for n in matched],
@@ -119,7 +119,7 @@ class PrefixStore:
             cow_src=cow_node.block if has_cow else -1,
             cow_tokens=cow_n if has_cow else 0,
             cow_origin=cow_node.origin if has_cow else "local",
-            logit_row=logit_row)
+            hidden_row=hidden_row)
 
     def note_reuse(self, n_blocks: int, n_remote: int,
                    cow: bool = False):
@@ -134,7 +134,7 @@ class PrefixStore:
             self.lens.on_share(int(n_blocks), int(n_remote), cow=cow)
 
     def insert(self, tokens: np.ndarray, blocks: List[int], *,
-               logit_rows: Optional[dict] = None,
+               hidden_rows: Optional[dict] = None,
                origin="local") -> int:
         """Insert the full-chunk path for `tokens` over physical
         `blocks` (one per full chunk). The store refs every NEWLY
@@ -143,7 +143,7 @@ class PrefixStore:
         staging path frees its transient refs afterwards). Returns the
         number of nodes created."""
         created, evicted = self.index.insert(
-            tokens, blocks, logit_rows=logit_rows, origin=origin)
+            tokens, blocks, hidden_rows=hidden_rows, origin=origin)
         if created:
             self.allocator.ref([n.block for n in created])
             if self.lens is not None:

@@ -286,11 +286,10 @@ class DsaFamilyRows(llama.LlamaFamilyRows):
         acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
         (x, acc), new_cache = lax.scan(layer, (x, acc0),
                                        (blocks, row_cache))
-        logits = llama.head(prepared, x.astype(jnp.float32), cfg=cfg,
-                            compute_dtype=compute_dtype)
+        x = x.astype(jnp.float32)  # what `head` is handed, in the finish
         if moe_stats:
-            return logits, new_cache, acc
-        return logits, new_cache
+            return x, new_cache, acc
+        return x, new_cache
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
         """`LlamaFamilyRows._attn_rows` with the selection between the

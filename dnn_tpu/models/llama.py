@@ -990,7 +990,22 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
                        ffn=None, moe_stats=False):
     """-> (logits, new_cache); with `moe_stats` (an ffn that has
     `with_stats`: the MoE hook) also the int32 (3,) sum over the layers
-    of what each expert layer call cost (parallel/moe.moe_ffn_grouped)."""
+    of what each expert layer call cost (parallel/moe.moe_ffn_grouped).
+    `hidden_with_cache` and the head over every row."""
+    x, *rest = hidden_with_cache(
+        prepared, ids, cache, start_pos, cfg=cfg, compute_dtype=compute_dtype,
+        attn_kernel=attn_kernel, rolling=rolling, ffn=ffn,
+        moe_stats=moe_stats)
+    return (head(prepared, x, cfg=cfg, compute_dtype=compute_dtype), *rest)
+
+
+def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
+                      compute_dtype=None, attn_kernel="auto", rolling=False,
+                      ffn=None, moe_stats=False):
+    """`forward_with_cache` up to the last block: (hidden (B, T, C)
+    float32 — what `head` is handed — the cache and, with `moe_stats`,
+    the expert layers' sums). A serving prefill chunk ends here
+    (LlamaFamilyRows.prefill)."""
     from dnn_tpu.runtime.kvcache import codec_for_cache
 
     if getattr(cfg, "mla", None) is not None or getattr(
@@ -1030,11 +1045,10 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     (x, acc), new_cache = lax.scan(
         layer, (x, acc0),
         (blocks, cache) + (() if wins is None else (wins,)))
-    logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
-                  compute_dtype=compute_dtype)
+    x = x.astype(jnp.float32)
     if moe_stats:
-        return logits, new_cache, acc
-    return logits, new_cache
+        return x, new_cache, acc
+    return x, new_cache
 
 
 def _ring_from_prompt(prompt_cache, t: int, w: int):
@@ -1422,10 +1436,26 @@ class LlamaFamilyRows:
 
     def prefill(self, prepared, padded, row_cache, start_pos=0, *,
                 moe_stats=False):
-        return forward_with_cache(
+        """One (1, P) prompt chunk -> (hidden (1, P, C) float32: the
+        last block's output, no final norm, no head; the row cache[; the
+        expert layers' sums])."""
+        return hidden_with_cache(
             prepared, padded, row_cache, start_pos, cfg=self.cfg,
             compute_dtype=self.compute_dtype, attn_kernel=self.attn_kernel,
             ffn=self.ffn, moe_stats=moe_stats)
+
+    def head_leaves(self, prepared):
+        """The leaves `head` reads — the final norm and the head kernel
+        (the input table where the two are tied) — of a param view: what
+        the finish program is handed in place of the whole tree."""
+        return {k: prepared[k] for k in
+                ("ln_f", "lm_head" if "lm_head" in prepared else "wte")}
+
+    def head(self, aux, h):
+        """Logits of hidden rows h (..., C) under `llama.head`: final
+        norm, head kernel, softcap where the config has one."""
+        return head(aux, h.astype(jnp.float32), cfg=self.cfg,
+                    compute_dtype=self.compute_dtype)
 
     def _block_rows(self, bp, x, layer_cache, pos, write, codec,
                     window=None, ffn=None):

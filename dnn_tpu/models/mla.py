@@ -301,11 +301,10 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
         x, acc = carry
         new_cache = {"latent": new_rows[0] if len(new_rows) == 1
                      else jnp.concatenate(new_rows)}
-        logits = llama.head(prepared, x.astype(jnp.float32), cfg=cfg,
-                            compute_dtype=self.compute_dtype)
+        x = x.astype(jnp.float32)  # what `head` is handed, in the finish
         if moe_stats:
-            return logits, new_cache, acc
-        return logits, new_cache
+            return x, new_cache, acc
+        return x, new_cache
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
         """The absorbed form: this step's row goes into the pool, the

@@ -112,6 +112,19 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: GPTConfig,
     >= kvcache.AUTO_KERNEL_MIN_S positions (length-aware dispatch: the
     long-context regime where clamped streaming beats reading the full
     allocation) and is the plain einsum everywhere else."""
+    x, new_cache = hidden_with_cache(
+        prepared, ids, cache, start_pos, cfg=cfg,
+        compute_dtype=compute_dtype, ffn=ffn, attn_kernel=attn_kernel)
+    logits = head(prepared, x, cfg=cfg, compute_dtype=compute_dtype)
+    return logits, new_cache
+
+
+def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: GPTConfig,
+                      compute_dtype=None, ffn=None, attn_kernel="auto"):
+    """`forward_with_cache` up to the last block: (hidden (B, T, C)
+    float32 — what `head` is handed — and the cache). A serving prefill
+    chunk ends here (GPTFamilyRows.prefill): the head meets one row of
+    one chunk, where the first token is sampled."""
     codec = codec_for_cache(cache, use_kernel=attn_kernel)
     x = _embed_at(prepared, ids, start_pos, compute_dtype=compute_dtype)
 
@@ -125,9 +138,7 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: GPTConfig,
 
     with jax.named_scope("layers.scan"):  # the loop's own slicing
         x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
-    logits = head(prepared, x.astype(jnp.float32), cfg=cfg,
-                  compute_dtype=compute_dtype)
-    return logits, new_cache
+    return x.astype(jnp.float32), new_cache
 
 
 def logit_bias_row(logit_bias, vocab_size: int):

@@ -69,7 +69,7 @@ from dnn_tpu.runtime.generate import (
     _qkv_heads,
     _sample_rows,
     apply_repetition_penalty,
-    forward_with_cache,
+    hidden_with_cache,
     init_cache,
     logit_bias_row,
 )
@@ -149,12 +149,25 @@ class GPTFamilyRows:
 
     def prefill(self, prepared, padded, row_cache, start_pos=0):
         """One (1, P) prompt chunk at positions [start_pos, start_pos+P)
-        -> (logits (1, P, V), row_cache). Long prompts prefill as several
+        -> (hidden (1, P, C) float32, row_cache): the last block's
+        output — no final norm, no head: the head meets ONE row of an
+        admission, in the finish program. Long prompts prefill as several
         full chunks + one padded tail (the batcher's chunk loop)."""
-        return forward_with_cache(
+        return hidden_with_cache(
             prepared, padded, row_cache, start_pos, cfg=self.cfg,
             compute_dtype=self.compute_dtype, ffn=self.ffn,
             attn_kernel=self.attn_kernel)
+
+    def head_leaves(self, prepared):
+        """The leaves `head` reads (final norm, head kernel) of a param
+        view: what the finish program is handed in place of the whole
+        tree."""
+        return {k: prepared[k] for k in ("ln_f", "lm_head")}
+
+    def head(self, aux, h):
+        """Logits of hidden rows h (..., C) under `gpt.head`."""
+        return head(aux, h.astype(jnp.float32), cfg=self.cfg,
+                    compute_dtype=self.compute_dtype)
 
     def verify_rows(self, prepared, cache, chunk, pos, active, codec):
         """A (B, T) token block at PER-ROW start positions pos (B,):
@@ -909,7 +922,7 @@ class ContinuousBatcher:
             out = _decode_core(prepared, cache, pos, tok, active, keys,
                                temp, tk, tp, mp, rep, seen, bias, crow,
                                ctable, ctrans)
-            # (pf_logits, new_row) and, for an MoE family, the chunk's
+            # (pf_hidden, new_row) and, for an MoE family, the chunk's
             # expert-layer stats last
             return out + prefill_chunk(pf_prepared, row, chunk, chunk_start)
 
@@ -918,8 +931,11 @@ class ContinuousBatcher:
             cache at positions [chunk_start, chunk_start+P). Long prompts
             loop this (full chunks + one padded tail) — ONE compiled
             program for any prompt length. Pad positions in the tail write
-            K/V that the per-row position mask never attends. An MoE
-            family returns its expert layers' stats as a third result."""
+            K/V that the per-row position mask never attends. Returns
+            (hidden (1, P, C) float32, row): the program stops at the
+            last block, and the finish applies the head to the one row
+            that is sampled. An MoE family returns its expert layers'
+            stats as a third result."""
             if self._moe_stats:
                 return self.family.prefill(prepared, chunk, row, chunk_start,
                                            moe_stats=True)
@@ -927,20 +943,25 @@ class ContinuousBatcher:
 
         def prefill_finish(cache, pos, tok, active, keys, temp_v, tk_v,
                            tp_v, mp_v, rep_v, seen, bias_buf, crow,
-                           row, logits, ints, floats, seen_row, b_row,
-                           blocks, ctable, ctrans):
+                           row, hidden, head_leaves, ints, floats,
+                           seen_row, b_row, blocks, ctable, ctrans):
             """FINISH AND INSTALL, the one program that ends every
             admission (convoy, interleaved, `prefilled=` adoption, a
-            radix hit; the speculative finish wraps it): sample the first
-            token from the final chunk's true-last logit row, install the
-            finished row cache into the slot, and set EVERY per-slot
-            state vector — nothing of an admission is left for Python to
-            scatter. The first thirteen arguments are the batcher's state
-            (`_slot_state`), donated and handed back in the same order;
-            the request arrives as two host arrays:
+            radix hit; the speculative finish wraps it): apply the
+            family's head to the final chunk's true-last HIDDEN row (the
+            one head matmul of an admission, on one row), sample the
+            first token from it, install the finished row cache into the
+            slot, and set EVERY per-slot state vector — nothing of an
+            admission is left for Python to scatter. The first thirteen
+            arguments are the batcher's state (`_slot_state`), donated
+            and handed back in the same order; `hidden` (1, P, C) is the
+            last chunk's output and `head_leaves` what the family's head
+            reads (`family.head_leaves` of the admitting request's param
+            view, so a LoRA'd head stays right; not donated); the request
+            arrives as two host arrays:
 
               ints (7,) int32: slot, last_local (the true last prompt
-                row within `logits`), prompt_len, top_k, c_row (this
+                row within `hidden`), prompt_len, top_k, c_row (this
                 request's start-state row in the constraint mask pool, 0
                 = unconstrained, so the FIRST token obeys the grammar
                 too and `crow[slot] = ctrans[c_row, first]` seeds the
@@ -968,8 +989,9 @@ class ContinuousBatcher:
             rng, slot_key = jax.random.split(jax.random.fold_in(
                 jax.random.fold_in(jax.random.PRNGKey(self._seed), words[0]),
                 words[1]))
+            lg = self.family.head(head_leaves, lax.dynamic_slice_in_dim(
+                hidden, last_local, 1, axis=1))[:, 0]  # (1, 1, C) -> (1, V)
             with jax.named_scope("sample"):
-                lg = logits[:, last_local][0:1]  # (1, V)
                 raw = lg
                 lg = apply_repetition_penalty(
                     lg, (rp != 1.0) & seen_row[None, :], rp)
@@ -1063,13 +1085,13 @@ class ContinuousBatcher:
             (12,) if self._allow_constraints else ())
         self._prefill_finish = jax.jit(
             prefill_finish, donate_argnums=self._finish_donate)
-        # the finish-shaped logits of an admission that ran no chunk (a
-        # whole-prompt prefix hit, an adopted prefill): the stored true-
-        # last logit row in place, so the finish keeps its one shape
-        self._row_logits = jax.jit(
-            lambda lr, at: jnp.zeros(
-                (1, self.prompt_pad, lr.shape[-1]), lr.dtype
-            ).at[0, at].set(lr))
+        # the finish-shaped hidden array of an admission that ran no chunk
+        # (a whole-prompt prefix hit, an adopted prefill): the stored
+        # true-last hidden row in place, so the finish keeps its one shape
+        self._row_hidden = jax.jit(
+            lambda hr, at: jnp.zeros(
+                (1, self.prompt_pad, hr.shape[-1]), hr.dtype
+            ).at[0, at].set(hr))
         # what an admission passes for an absent logit_bias and, on a
         # dense pool, for the block ids — built once, not per request
         self._no_bias = jnp.zeros(
@@ -1327,7 +1349,7 @@ class ContinuousBatcher:
 
         `prefilled` (disaggregated serving, dnn_tpu/control): a
         PREFILL replica's `export_prefill` payload — this request's
-        transient row cache plus the final chunk's true-last logit
+        transient row cache plus the final chunk's true-last hidden
         row. Admission then ADOPTS the handed-off KV instead of
         running the chunk loop: same slot install, same
         `_prefill_finish` program, same rng derivation, so tokens
@@ -1630,8 +1652,9 @@ class ContinuousBatcher:
 
             def finish_request(last_local):
                 """The request as the finish program takes it
-                (prefill_finish documents the fields): its numbers as TWO
-                host arrays, the prompt's seen-mask, the bias row and the
+                (prefill_finish documents the fields): the head's leaves
+                under this request's adapter, its numbers as TWO host
+                arrays, the prompt's seen-mask, the bias row and the
                 block ids — the same for both admit paths, so greedy AND
                 sampled streams agree token-for-token across them."""
                 ints = np.empty((7,), np.int32)
@@ -1640,7 +1663,8 @@ class ContinuousBatcher:
                 ints[5:] = stream.view(np.int32)
                 seen_row = np.zeros((self.cfg.vocab_size,), bool)
                 seen_row[prompt] = True
-                return (ints, np.asarray((temp, tp, mp, rp), np.float32),
+                return (self.family.head_leaves(self._lora_prefill_view(aid)),
+                        ints, np.asarray((temp, tp, mp, rp), np.float32),
                         seen_row, b_row, blocks)
 
             if self._ilv:
@@ -1668,7 +1692,7 @@ class ContinuousBatcher:
                            "next": 0, "row": self._ilv_new_row(),
                            "aid": aid,
                            # what the finish takes beside the state, the
-                           # row and the last chunk's logits
+                           # row and the last chunk's hidden rows
                            "finish": finish_request(
                                (len(prompt) - 1) % p_c),
                        }}
@@ -1694,7 +1718,7 @@ class ContinuousBatcher:
             # prefilled (KV adoption): the row arrives from the prefill
             # replica — never allocate (or compute) one here
             row = self._new_row() if prefilled is None else None
-            logits = None
+            hidden = None
             start_chunk = 0
             prefix_hit_flag = False
             prefix_lookup_ran = prefilled is None and (
@@ -1714,15 +1738,14 @@ class ContinuousBatcher:
                 # through the chunk loop and must not invalidate the
                 # cached entry
                 start_chunk = hit_c
-                last_logit_row = hit_entry[1]
                 row = jax.tree.map(jnp.copy, hit_entry[0])
                 if hit_c == n_chunks:
-                    # whole prompt cached: rebuild a chunk-shaped logits
+                    # whole prompt cached: rebuild a chunk-shaped hidden
                     # array with the stored last row in place (position
                     # p_pad-1 == the true last prompt token of an exact
                     # full-chunk prompt) so _prefill_finish keeps its one
                     # compiled shape
-                    logits = self._row_logits(last_logit_row, p_pad - 1)
+                    hidden = self._row_hidden(hit_entry[1], p_pad - 1)
             pf_prepared = self._lora_prefill_view(aid)
             sp_pf = adm.child("prefill", chunks=n_chunks - start_chunk,
                               prompt_len=len(prompt))
@@ -1737,19 +1760,19 @@ class ContinuousBatcher:
             if prefilled is not None:
                 # KV ADOPTION (disaggregated serving, dnn_tpu/control):
                 # the prefill replica already ran this chunk loop;
-                # rebuild its transient row + the finish-shaped logits
-                # and fall through to the SAME _prefill_finish install
-                # below — the decode replica spends zero prompt FLOPs
-                row, logits = self._adopt_prefilled(prefilled, prompt)
+                # rebuild its transient row + the finish-shaped hidden
+                # array and fall through to the SAME _prefill_finish
+                # install below — the decode replica spends zero prompt
+                # FLOPs but the one head row
+                row, hidden = self._adopt_prefilled(prefilled, prompt)
             elif use_radix:
-                row, logits, last_local = self._radix_prefill(
+                row, hidden, last_local = self._radix_prefill(
                     prompt, blocks[0], pf_prepared, row, kv_hit, n_shared,
                     cow_tok, kv_boundary_rows)
             else:
-                ahead: deque = deque()
                 for c in range(start_chunk, n_chunks):
-                    logits, row = self._run_prefill_chunk(
-                        ahead, pf_prepared, row,
+                    hidden, row = self._run_prefill_chunk(
+                        pf_prepared, row,
                         padded[:, c * p_pad:(c + 1) * p_pad],
                         np.int32(c * p_pad),
                     )
@@ -1768,7 +1791,7 @@ class ContinuousBatcher:
                             self._evict_prefix_entry()
                         self._prefix_cache[key] = (
                             jax.tree.map(jnp.copy, row),
-                            jnp.copy(logits[0, -1]))
+                            jnp.copy(hidden[0, -1]))
                         self._prefix_cache.move_to_end(key, last=False)
             t_pf1 = time.perf_counter()  # every chunk is dispatched
             _profile.close_span(_sp)
@@ -1778,7 +1801,7 @@ class ContinuousBatcher:
             t_in0 = time.perf_counter()
             _sp = _profile.open_span("admit.install", rid=rid)
             first, first_lps = self._finish(
-                row, logits, finish_request(last_local))
+                row, hidden, finish_request(last_local))
             t_in1 = time.perf_counter()
             _profile.close_span(_sp)
             if use_radix:
@@ -1798,7 +1821,7 @@ class ContinuousBatcher:
                     self._prefix_store.insert(
                         prompt[: n_cover * self._block_len],
                         [int(x) for x in paged_taken[:n_cover]],
-                        logit_rows=kv_boundary_rows, origin=kv_borig)
+                        hidden_rows=kv_boundary_rows, origin=kv_borig)
             # the device-to-host read: where the host waits for the
             # prefill (admit.first_token)
             t_ft0 = time.perf_counter()
@@ -1950,37 +1973,23 @@ class ContinuousBatcher:
          self._temp, self._topk, self._topp, self._minp, self._rep,
          self._seen, self._bias, self._crow) = state
 
-    def _finish(self, row, logits, request):
+    def _finish(self, row, hidden, request):
         """Launch the finish-and-install program for `request` (submit's
         `finish_request`) on the finished `row` and the last chunk's
-        `logits` -> (first token, its logprob outputs or ()), all still
-        on the device."""
-        out = self._prefill_finish(*self._slot_state(), row, logits,
+        `hidden` rows -> (first token, its logprob outputs or ()), all
+        still on the device."""
+        out = self._prefill_finish(*self._slot_state(), row, hidden,
                                    *request, self._ctable, self._ctrans)
         self._set_slot_state(out[:13])
         return out[13], out[14:]
 
-    #: how many bytes of chunk logits a chunk loop may have dispatched and
-    #: not yet computed. The loop runs ahead of the device, and a
-    #: dispatched chunk's (1, prompt_pad, V) logits are allocated at
-    #: dispatch and freed when it has run: GPT-2's 13 MB a chunk never
-    #: reach this, a 1024-token chunk over a 152 k vocabulary (622 MB)
-    #: runs two deep — one computing, one queued, the device never
-    #: waiting for the host — where a dozen deep was 4.4 GB of the chip
-    _CHUNK_AHEAD_BYTES = 1 << 30
-
-    def _run_prefill_chunk(self, ahead: deque, *args):
-        """The chunk program -> (logits, row); an MoE family's third
-        result, the chunk's expert-layer stats, is noted on the way.
-        `ahead`: the loop's own queue of the logits it has dispatched
-        and the device has not computed yet (empty before its first
-        chunk), held to _CHUNK_AHEAD_BYTES by waiting for the oldest."""
-        while ahead and ahead[0].is_ready():
-            ahead.popleft()
+    def _run_prefill_chunk(self, *args):
+        """The chunk program -> (hidden, row); an MoE family's third
+        result, the chunk's expert-layer stats, is noted on the way. A
+        chunk loop dispatches all its chunks without reading or waiting:
+        what a dispatched chunk allocates is its (1, prompt_pad, C)
+        hidden rows (8 MB at 1024 x 2048) and the donated row."""
         res = self._prefill_chunk(*args)
-        ahead.append(res[0])
-        if len(ahead) * res[0].nbytes > self._CHUNK_AHEAD_BYTES:
-            ahead.popleft().block_until_ready()
         if self._moe_stats:
             self._moe_note("prefill", res[2])
         if self._index_topk and self.step_clock is not None:
@@ -2062,6 +2071,7 @@ class ContinuousBatcher:
         return {
             "family": type(self.family).__name__,
             "vocab_size": int(self.cfg.vocab_size),
+            "n_embd": int(self.cfg.n_embd),  # the hidden row's width
             "prompt_pad": int(self.prompt_pad),
             "row_len": int(self._row_len),
             "row_leaves": [[list(x.shape), str(x.dtype)] for x in leaves],
@@ -2081,8 +2091,9 @@ class ContinuousBatcher:
         the chunk loop for `prompt` — no slot held, no install, no
         sampling — and return the handoff payload a decode replica
         adopts via `submit(prefilled=...)`: the transient row cache's
-        leaves (host arrays) plus the final chunk's true-last logit
-        row. `max_new_tokens` only sizes the length check (the decode
+        leaves (host arrays) plus the final chunk's true-last HIDDEN
+        row (C values: the decode replica's finish applies the head to
+        it). `max_new_tokens` only sizes the length check (the decode
         side re-validates with the request's real budget).
 
         Prices like any prefill: the chunk counter, the prefill-
@@ -2106,18 +2117,17 @@ class ContinuousBatcher:
         # program is compiled unconditionally), so ANY replica can
         # take the prefill role
         row = self._new_row()
-        logits = None
+        hidden = None
         t_pf = time.perf_counter()
-        ahead: deque = deque()
         for c in range(n_chunks):
-            logits, row = self._run_prefill_chunk(
-                ahead, self.prepared, row,
+            hidden, row = self._run_prefill_chunk(
+                self.prepared, row,
                 padded[:, c * p_pad:(c + 1) * p_pad],
                 np.int32(c * p_pad),
             )
             self.prefill_chunks_run += 1
         last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
-        logits_row = np.asarray(logits[0, last_local])
+        hidden_row = np.asarray(hidden[0, last_local])
         leaves = [np.asarray(x) for x in jax.tree_util.tree_flatten(row)[0]]
         m = obs.metrics()
         if m is not None:
@@ -2128,14 +2138,14 @@ class ContinuousBatcher:
             )
             if (g := self.goodput) is not None:
                 g.on_prefill(len(prompt))
-        return {"row": leaves, "logits_row": logits_row,
+        return {"row": leaves, "hidden_row": hidden_row,
                 "prompt_len": len(prompt),
                 "fingerprint": self.handoff_fingerprint()}
 
     def _adopt_prefilled(self, prefilled, prompt) -> tuple:
         """Decode-replica half: verify the handed-off payload against
         THIS pool's row geometry, rebuild the row pytree and the
-        finish-shaped logits array (the stored true-last row placed at
+        finish-shaped hidden array (the stored true-last row placed at
         `last_local`, exactly like a whole-prompt prefix hit). Every
         mismatch is a loud ValueError — adopting mis-shaped KV would
         generate plausible garbage."""
@@ -2167,15 +2177,17 @@ class ContinuousBatcher:
                 f"this request's prompt has {len(prompt)} tokens")
         row = jax.tree_util.tree_unflatten(
             treedef, [jnp.asarray(x) for x in got])
-        lr = np.asarray(prefilled.get("logits_row"))
-        if lr.shape != (self.cfg.vocab_size,):
+        hr = np.asarray(prefilled.get("hidden_row"))
+        if hr.shape != (self.cfg.n_embd,) or hr.dtype != np.float32:
             raise ValueError(
-                f"handoff logits_row has shape {lr.shape}, expected "
-                f"({self.cfg.vocab_size},)")
+                f"handoff hidden_row is {hr.dtype}{hr.shape}, expected "
+                f"float32({self.cfg.n_embd},): the last block's output at "
+                "the prompt's last token (a payload that carries a "
+                "logits row comes from a replica of an older version)")
         m = obs.metrics()
         if m is not None:
             m.inc("serving.kv_adoptions_total")
-        return row, self._row_logits(lr, (len(prompt) - 1) % self.prompt_pad)
+        return row, self._row_hidden(hr, (len(prompt) - 1) % self.prompt_pad)
 
     # -- fleet KV tier (dnn_tpu/kvtier): stage / export / adopt ---------
 
@@ -2201,6 +2213,7 @@ class ContinuousBatcher:
             leaves[kk] = [[shp[0]] + shp[2:], str(self.cache[kk].dtype)]
         return {"family": type(self.family).__name__,
                 "vocab_size": int(self.cfg.vocab_size),
+                "n_embd": int(self.cfg.n_embd),  # the hidden rows' width
                 "block_len": int(self._block_len),
                 "leaves": leaves}
 
@@ -2227,13 +2240,13 @@ class ContinuousBatcher:
         blocks = [self._read_block(n.block) for n in nodes]
         leaves = {kk: np.stack([b[kk] for b in blocks], axis=1)
                   for kk in blocks[0]}
-        logit_rows = {i: np.asarray(n.logit_row)
-                      for i, n in enumerate(nodes)
-                      if n.logit_row is not None}
+        hidden_rows = {i: np.asarray(n.hidden_row)
+                       for i, n in enumerate(nodes)
+                       if n.hidden_row is not None}
         bp = self._block_len
         return {"tokens": tokens[: len(nodes) * bp],
                 "block_len": bp, "leaves": leaves,
-                "logit_rows": logit_rows,
+                "hidden_rows": hidden_rows,
                 "fingerprint": self.kvtier_fingerprint()}
 
     def kvtier_adopt(self, payload, *, origin: str = "adopted") -> int:
@@ -2299,10 +2312,10 @@ class ContinuousBatcher:
                 self.cache = self._kv_put_block(self.cache, vals,
                                                 jnp.int32(dst))
             ids = have_ids + owned
-            lrs = {int(i): jnp.asarray(r)
-                   for i, r in (payload.get("logit_rows") or {}).items()}
+            hrs = {int(i): jnp.asarray(r)
+                   for i, r in (payload.get("hidden_rows") or {}).items()}
             self._prefix_store.insert(tokens[: n_total * bp], ids,
-                                      logit_rows=lrs, origin=origin)
+                                      hidden_rows=hrs, origin=origin)
         finally:
             # the store now holds its own reference per inserted node;
             # dropping ours (owned allocs + the matched-run guards)
@@ -2369,13 +2382,12 @@ class ContinuousBatcher:
             padded = np.zeros((1, n_k * p_pad), np.int32)
             padded[0, : end - resume] = prompt[resume:end]
             boundary: dict = {}
-            logits = None
+            hidden = None
             t_pf = time.perf_counter()
-            ahead: deque = deque()
             for i in range(n_k):
                 start = resume + i * p_pad
-                logits, row = self._run_prefill_chunk(
-                    ahead, self.prepared, row,
+                hidden, row = self._run_prefill_chunk(
+                    self.prepared, row,
                     padded[:, i * p_pad:(i + 1) * p_pad],
                     np.int32(start))
                 self.prefill_chunks_run += 1
@@ -2384,14 +2396,14 @@ class ContinuousBatcher:
                     if pos >= start + p_pad:
                         break
                     if pos >= start:
-                        boundary[b] = jnp.copy(logits[0, pos - start])
+                        boundary[b] = jnp.copy(hidden[0, pos - start])
             inst = ids_row.copy()
             inst[:n_shared] = 0
             self.cache = self._kvtier_install(self.cache, row,
                                               jnp.asarray(inst))
             self._prefix_store.insert(
                 prompt[:end], [int(x) for x in ids_row[:n_cover]],
-                logit_rows=boundary)
+                hidden_rows=boundary)
             m = obs.metrics()
             if m is not None:
                 m.bulk(counters={"serving.prefill_chunks_total": n_k},
@@ -2451,11 +2463,11 @@ class ContinuousBatcher:
         """The radix-store admission prefill: resume the chunk loop at
         the first non-cached position instead of chunk 0.
 
-        Returns (row, logits, last_local). Three regimes:
+        Returns (row, hidden, last_local). Three regimes:
 
           * FULL HIT — the prompt is exactly the shared block run and
-            the final node stored its logit row: zero chunks, rebuild
-            the finish-shaped logits with the stored row in place;
+            the final node stored its hidden row: zero chunks, rebuild
+            the finish-shaped hidden array with the stored row in place;
           * PARTIAL — resume at `n_shared * block_len + cow_tok` (the
             copy-on-write boundary block, already duplicated into this
             request's first owned block, covers the agreed mid-block
@@ -2472,17 +2484,18 @@ class ContinuousBatcher:
             lesson); backing into already-shared territory only
             recomputes values the install then routes to junk.
 
-        Boundary logit rows (the model's logits after each completed
-        block) are collected into `boundary_rows` for the store insert
+        Boundary hidden rows (the last block's output at each completed
+        block's last token: what the head turns into that position's
+        logits) are collected into `boundary_rows` for the store insert
         — what makes a later exactly-block-aligned prompt a zero-chunk
         full hit."""
         p_len = len(prompt)
         bp = self._block_len
         p_pad = self.prompt_pad
-        if kv_hit.logit_row is not None and p_len == n_shared * bp \
+        if kv_hit.hidden_row is not None and p_len == n_shared * bp \
                 and cow_tok == 0:
             last_local = (p_len - 1) % p_pad
-            return (row, self._row_logits(kv_hit.logit_row, last_local),
+            return (row, self._row_hidden(kv_hit.hidden_row, last_local),
                     last_local)
         resume = min(n_shared * bp + cow_tok, p_len - 1)
         if resume + (-(-(p_len - resume) // p_pad)) * p_pad \
@@ -2500,12 +2513,11 @@ class ContinuousBatcher:
         n_k = -(-(p_len - resume) // p_pad)
         padded_r = np.zeros((1, n_k * p_pad), np.int32)
         padded_r[0, : p_len - resume] = prompt[resume:]
-        logits = None
-        ahead: deque = deque()
+        hidden = None
         for i in range(n_k):
             start = resume + i * p_pad
-            logits, row = self._run_prefill_chunk(
-                ahead, pf_prepared, row,
+            hidden, row = self._run_prefill_chunk(
+                pf_prepared, row,
                 padded_r[:, i * p_pad:(i + 1) * p_pad],
                 np.int32(start))
             self.prefill_chunks_run += 1
@@ -2514,9 +2526,9 @@ class ContinuousBatcher:
                 if pos >= start + p_pad:
                     break
                 if pos >= start:
-                    boundary_rows[b] = jnp.copy(logits[0, pos - start])
+                    boundary_rows[b] = jnp.copy(hidden[0, pos - start])
         last_local = (p_len - resume - 1) - (n_k - 1) * p_pad
-        return row, logits, last_local
+        return row, hidden, last_local
 
     @staticmethod
     def _stop_match(emitted: list, stop_seqs: list):
@@ -3034,7 +3046,7 @@ class ContinuousBatcher:
                     "last": c + 1 == p["n_chunks"]}
         return None
 
-    def _ilv_after_chunk(self, ilv, pf_logits, new_row, s_idx):
+    def _ilv_after_chunk(self, ilv, pf_hidden, new_row, s_idx):
         """Bookkeeping after a mixed step's prefill leg: stash the grown
         row, or — on the final chunk — dispatch the FUSED finish
         (install + on-device first-token sample + slot-state scatter,
@@ -3050,7 +3062,7 @@ class ContinuousBatcher:
             p["next"] += 1
             return
         self._pending_q.pop(0)
-        first, first_lps = self._finish(new_row, pf_logits, p["finish"])
+        first, first_lps = self._finish(new_row, pf_hidden, p["finish"])
         req["first_dev"] = (first, first_lps if req["logprobs"] else None)
         req["install_step"] = s_idx
         del req["pending"]
@@ -3226,7 +3238,7 @@ class ContinuousBatcher:
                 ilv["p"]["row"], ilv["chunk"], ilv["start"])
             if self._moe_stats:
                 res, pf_moe = res[:-1], res[-1]
-            res, pf_logits, new_row = res[:-2], res[-2], res[-1]
+            res, pf_hidden, new_row = res[:-2], res[-2], res[-1]
         # drop the tuple's references to the just-donated buffers NOW:
         # holding them to frame teardown makes their deletion run after
         # the step record closes, and deleting a donated-but-pending
@@ -3253,7 +3265,7 @@ class ContinuousBatcher:
             (self.cache, self.pos, self.tok, self.keys, self._seen,
              self._crow) = res
         if ilv is not None:
-            self._ilv_after_chunk(ilv, pf_logits, new_row, s_idx)
+            self._ilv_after_chunk(ilv, pf_hidden, new_row, s_idx)
         if self._overlap:
             if sc is not None:
                 sc.overlap_depth = 1

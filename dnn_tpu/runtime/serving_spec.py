@@ -342,20 +342,20 @@ class SpeculativeBatcher(ContinuousBatcher):
                 out = _spec_core(t_prepared, d_prepared, t_cache,
                                  d_cache, tok, pos, active, keys,
                                  prev_chunk, prev_pos)
-                pf_logits, new_row = t_family.prefill(
+                pf_hidden, new_row = t_family.prefill(
                     t_prepared, chunk, row, chunk_start)
                 _, new_d_row = d_family.prefill(
                     d_prepared, chunk, d_row, chunk_start)
-                return out + (pf_logits, new_row, new_d_row)
+                return out + (pf_hidden, new_row, new_d_row)
 
             self._spec_mixed_donate = (2, 3, 4, 5, 7, 8, 9, 10, 11)
             self._spec_mixed = jax.jit(
                 spec_mixed, donate_argnums=self._spec_mixed_donate)
 
             parent_fin = self._finish_core
-            # its arguments: the slot state, then row, logits, ints and
-            # six more (serving.prefill_finish)
-            i_ints = len(self._slot_state()) + 2
+            # its arguments: the slot state, then row, hidden, the head's
+            # leaves, ints and six more (serving.prefill_finish)
+            i_ints = len(self._slot_state()) + 3
             n_core = i_ints + 7
             kk1 = k + 1
 
@@ -507,7 +507,7 @@ class SpeculativeBatcher(ContinuousBatcher):
             len(prompt_arr) - (k + 1))
         return rid
 
-    def _ilv_after_chunk(self, ilv, pf_logits, rows, s_idx):
+    def _ilv_after_chunk(self, ilv, pf_hidden, rows, s_idx):
         """Speculative override of the interleave bookkeeping: `rows`
         is the (target row, draft row) pair the spec mixed program
         returned; the final chunk dispatches the fused finish that
@@ -525,7 +525,7 @@ class SpeculativeBatcher(ContinuousBatcher):
             return
         self._pending_q.pop(0)
         out = self._spec_ilv_finish(
-            *self._slot_state(), new_row, pf_logits, *p["finish"],
+            *self._slot_state(), new_row, pf_hidden, *p["finish"],
             self._ctable, self._ctrans,
             self.d_cache, self.prev_chunk, self.prev_pos, new_d_row,
             p["tail"])
@@ -647,7 +647,7 @@ class SpeculativeBatcher(ContinuousBatcher):
         else:
             p = ilv["p"]
             (self.cache, self.d_cache, self.tok, self.pos, self.keys,
-             self.prev_chunk, self.prev_pos, w, m, pf_logits, new_row,
+             self.prev_chunk, self.prev_pos, w, m, pf_hidden, new_row,
              new_d_row) = self._spec_mixed(
                 self.prepared, self.draft_prepared, self.cache,
                 self.d_cache, self.tok, self.pos, self.active,
@@ -659,7 +659,7 @@ class SpeculativeBatcher(ContinuousBatcher):
         s_idx = self._step_idx
         self._step_idx += 1
         if ilv is not None:
-            self._ilv_after_chunk(ilv, pf_logits, (new_row, new_d_row),
+            self._ilv_after_chunk(ilv, pf_hidden, (new_row, new_d_row),
                                   s_idx)
         if self._overlap:
             if sc is not None:

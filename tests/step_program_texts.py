@@ -6,7 +6,9 @@ sha256 of its StableHLO text}} for the checkout it runs in (the recorded
 file `tests/step_program_texts_parent.json` was made so on the commit
 before PR 35; PR 36 re-recorded `olmoe-test`'s and `keye-test`'s chunk and
 decode programs, whose layer loops keep the expert stacks whole since, and
-left `gpt2-test`'s as they were)."""
+left `gpt2-test`'s as they were; PR 38 re-recorded every chunk and finish
+program — the head moved from the one into the other — and no decode
+program)."""
 
 import hashlib
 import json

@@ -264,24 +264,21 @@ def test_warm_admission_launches_chunks_plus_two(convoy, monkeypatch, case,
     srv.drain()
 
 
-def test_chunk_loop_runs_ahead_by_bytes_not_by_chunks(convoy, monkeypatch):
-    """The chunk loop dispatches ahead of the device, and a dispatched
-    chunk's logits are allocated until it has run: the loop holds what it
-    has in flight to `_CHUNK_AHEAD_BYTES` (on the chip a dozen 622 MB
-    logits of a 152 k vocabulary were 4.4 GB of the peak)."""
-    from collections import deque
-
+def test_a_dispatched_chunk_holds_hidden_rows_not_logits(convoy):
+    """The chunk loop dispatches every chunk of an admission without
+    reading or waiting (ISSUE 38): what a dispatched chunk allocates is
+    its (1, P, C) hidden rows — the (1, P, V) float32 logits that the
+    loop once held to a budget of bytes in flight are never made
+    (tests/test_chunk_head.py reads the traced programs)."""
     _, srv = convoy
     ids = np.zeros((1, PAD), np.int32)
-    one = PAD * 256 * 4  # a chunk's float32 logits at these presets
-    for budget, deepest in ((one * 3 // 2, 1), (one * 5 // 2, 2)):
-        monkeypatch.setattr(type(srv), "_CHUNK_AHEAD_BYTES", budget)
-        ahead, row = deque(), srv._new_row()
-        for c in range(4):
-            logits, row = srv._run_prefill_chunk(
-                ahead, srv.prepared, row, ids, np.int32(c * PAD))
-            assert logits.nbytes == one
-            assert len(ahead) <= deepest and ahead[-1] is logits
+    row = srv._new_row()
+    for c in range(4):
+        hidden, row = srv._run_prefill_chunk(
+            srv.prepared, row, ids, np.int32(c * PAD))
+        assert hidden.shape == (1, PAD, srv.cfg.n_embd)
+        assert hidden.nbytes * 4 <= PAD * srv.cfg.vocab_size * 4
+    assert not hasattr(srv, "_CHUNK_AHEAD_BYTES")
 
 
 if __name__ == "__main__":

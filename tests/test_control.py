@@ -194,7 +194,7 @@ def test_handoff_pack_roundtrip_including_bf16():
         "row": [np.arange(24, dtype=np.float32).reshape(2, 3, 4),
                 np.arange(6, dtype=np.int8).reshape(2, 3),
                 np.ones((2, 2), ml_dtypes.bfloat16)],
-        "logits_row": np.linspace(0, 1, 7, dtype=np.float32),
+        "hidden_row": np.linspace(0, 1, 7, dtype=np.float32),
         "prompt_len": 5,
         "fingerprint": {"vocab_size": 7, "row_len": 4},
     }
@@ -206,18 +206,24 @@ def test_handoff_pack_roundtrip_including_bf16():
     for a, b in zip(payload["row"], back["row"]):
         assert a.dtype == b.dtype and a.shape == b.shape
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    np.testing.assert_array_equal(payload["logits_row"],
-                                  back["logits_row"])
+    np.testing.assert_array_equal(payload["hidden_row"],
+                                  back["hidden_row"])
 
 
 def test_handoff_malformed_payloads_fail_loud():
     buf = handoff.pack({"row": [np.zeros((2,), np.float32)],
-                        "logits_row": np.zeros((3,), np.float32),
+                        "hidden_row": np.zeros((3,), np.float32),
                         "prompt_len": 1, "fingerprint": {}})
     with pytest.raises(ValueError, match="bad magic"):
         handoff.unpack(np.zeros(16, np.uint8))
     with pytest.raises(ValueError, match="truncated"):
         handoff.unpack(buf[: buf.size - 4])
+    # a version-1 payload (a logits row where the hidden row is)
+    raw = buf.tobytes()
+    at = raw.index(b'"hidden"')
+    old = np.frombuffer(raw[:at] + b'"logits"' + raw[at + 8:], np.uint8)
+    with pytest.raises(ValueError, match="no hidden row"):
+        handoff.unpack(old)
 
 
 def test_export_adopt_parity_and_rejections(prepared):

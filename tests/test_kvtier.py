@@ -177,19 +177,19 @@ def test_store_block_hit_accounting_and_origin():
     assert miss.shared == [] and st.block_hits == 2
 
 
-def test_store_full_hit_needs_logit_row_and_alignment():
+def test_store_full_hit_needs_hidden_row_and_alignment():
     a = FakeAllocator()
     a.seed([1, 2])
     st = PrefixStore(a, BP, capacity=8)
     lr = np.arange(5.0)
-    st.insert(seq(8), [1, 2], logit_rows={1: lr})
-    assert st.lookup(seq(8)).logit_row is lr          # aligned + row
-    assert st.lookup(seq(7)).logit_row is None        # ragged
+    st.insert(seq(8), [1, 2], hidden_rows={1: lr})
+    assert st.lookup(seq(8)).hidden_row is lr          # aligned + row
+    assert st.lookup(seq(7)).hidden_row is None        # ragged
     a2 = FakeAllocator()
     a2.seed([3])
     st2 = PrefixStore(a2, BP, capacity=8)
-    st2.insert(seq(4), [3])                           # no logit row
-    assert st2.lookup(seq(4)).logit_row is None
+    st2.insert(seq(4), [3])                           # no hidden row
+    assert st2.lookup(seq(4)).hidden_row is None
 
 
 def test_store_concurrent_scrape_during_admit_evict():
@@ -248,7 +248,7 @@ def test_pack_unpack_roundtrip_f32_int8_int4_bf16():
     for name, arr in cases:
         pl = {"tokens": seq(2 * BP), "block_len": BP,
               "leaves": {"k": arr},
-              "logit_rows": {0: np.arange(7.0, dtype=np.float32)},
+              "hidden_rows": {0: np.arange(7.0, dtype=np.float32)},
               "fingerprint": {"leaves": {
                   "k": [list(arr.shape), name]}}}
         back = M.unpack_blocks(M.pack_blocks(pl))
@@ -259,12 +259,12 @@ def test_pack_unpack_roundtrip_f32_int8_int4_bf16():
                 arr.view(np.uint16))
         else:
             np.testing.assert_array_equal(back["leaves"]["k"], arr)
-        np.testing.assert_array_equal(back["logit_rows"][0],
-                                      pl["logit_rows"][0])
+        np.testing.assert_array_equal(back["hidden_rows"][0],
+                                      pl["hidden_rows"][0])
     # int4 ships nibble-packed: strictly under 1 byte/element on wire
     arr4 = cases[2][1]
     pl4 = {"tokens": seq(2 * BP), "block_len": BP,
-           "leaves": {"k": arr4}, "logit_rows": {},
+           "leaves": {"k": arr4}, "hidden_rows": {},
            "fingerprint": {"leaves": {"k": [list(arr4.shape),
                                             "int4"]}}}
     wire4 = M.pack_blocks(pl4)
@@ -283,7 +283,7 @@ def test_unpack_rejects_garbage_and_truncation():
         M.unpack_blocks(np.frombuffer(b"nonsense bytes!!", np.uint8))
     pl = {"tokens": seq(BP), "block_len": BP,
           "leaves": {"k": np.zeros((1, 1, 1, BP, 2), np.float32)},
-          "logit_rows": {}, "fingerprint": {}}
+          "hidden_rows": {}, "fingerprint": {}}
     wire = M.pack_blocks(pl)
     with pytest.raises(ValueError, match="truncated"):
         M.unpack_blocks(wire[: wire.size - 8])
@@ -448,7 +448,7 @@ def test_block_aligned_full_hit_zero_chunks(served):
     c0 = srv.prefill_chunks_run
     r2 = srv.submit(p, max_new_tokens=4)
     out = srv.drain()
-    assert srv.prefill_chunks_run == c0  # zero chunks: stored logit row
+    assert srv.prefill_chunks_run == c0  # zero chunks: stored hidden row
     np.testing.assert_array_equal(out[r1], out[r2])
     np.testing.assert_array_equal(out[r2], _oracle(served, p, 4))
 

@@ -103,9 +103,10 @@ def _prefill_then_paged_decode(prepared, fam, seqs, cache_dtype=jnp.float32):
         padded = np.zeros((1, 32), np.int32)
         padded[0, :n] = s[:n]
         for c in range(2):  # two chunks of 16, the second padded
-            logits, row, stats = fam.prefill(
+            hidden, row, stats = fam.prefill(
                 prepared, jnp.asarray(padded[:, 16 * c:16 * (c + 1)]), row,
                 16 * c, moe_stats=True)
+            logits = fam.head(prepared, hidden)  # a chunk ends at the last block
             assert int(stats[0]) == CFG.n_layer * 16 * CFG.router_top_k
             live = max(0, min(16, n - 16 * c))
             got[slot].append(np.asarray(logits[0, :live].astype(jnp.float32)))
