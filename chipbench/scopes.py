@@ -1,8 +1,10 @@
 """Readers for a model family that brings its own scope names and
 counters: device time by scope with the recognised prefixes taken from the
-metric's own `args` (so the next family brings data files only), ratios of
-window differences of /metrics counters, and a scoped kernel's roofline
-share. `layers/<metric>.json` names them as `"scopes:<function>"`.
+CONFIGURATION's file (`"trace": {"known_scopes": [...]}`: the scopes its
+programs write, so the next family brings data files only and a share
+that several families report is one entry), ratios of window differences
+of /metrics counters, and a scoped kernel's roofline share.
+`layers/<metric>.json` names them as `"scopes:<function>"`.
 
 `spans.py` reduces every operation's HLO `op_name` to one of ITS prefixes
 (`spans.SCOPES`, GPT-2's) while it reads the capture, so a `llama.*` or
@@ -18,12 +20,14 @@ field is the name already cut to a scope: cutting is idempotent).
 
 **Scope of an operation** (`scope_of`): the innermost `/`-separated
 component of its `op_name` that starts with one of `known`, the prefixes
-ALL the scope metrics of a cell recognise together; None if there is none.
-A metric's share is the own time (`tracered._self_times`) of the
-operations whose scope starts with one of ITS `scopes` over the busy time;
-`scopes: null` is the operations with no scope. Give every metric of one
-cell the same `known`, and each prefix of `known` to exactly one metric:
-the shares then add up to 100.
+ALL the scope metrics of a cell recognise together, which is its
+configuration's `trace.known_scopes`; None if there is none. A metric's
+share is the own time (`tracered._self_times`) of the operations whose
+scope starts with one of ITS `scopes` over the busy time; `scopes: null` is
+the operations with no scope. Give each prefix of a configuration's
+`known_scopes` to exactly one of the metrics its cells report: the shares
+then add up to 100. A configuration without the block has no scope shares
+(the GPT-2 cells are read by `spans.py`'s built-in `SCOPES`).
 
 **A kernel's roofline share** (`experts_roofline_pct`): least time of the
 expert layers of one decode step over the device time of the operations
@@ -52,8 +56,9 @@ from typing import Dict, Optional
 
 from chipbench import spans, tracered
 
-__all__ = ["scope_of", "load_ops", "scope_seconds", "share_pct",
-           "counter_ratio", "experts_least_s", "experts_roofline_pct"]
+__all__ = ["scope_of", "load_ops", "known_scopes", "scope_seconds",
+           "share_pct", "counter_ratio", "experts_least_s",
+           "experts_roofline_pct"]
 
 OPERAND_BYTES = {"bfloat16": 2, "float16": 2, "float32": 4}
 
@@ -101,6 +106,12 @@ def _capture_of(facts) -> Optional[dict]:
     return facts["scopes_capture"]
 
 
+def known_scopes(facts) -> Optional[list]:
+    """The prefixes the cell's configuration declares (`trace.known_scopes`
+    of its file); None where it declares none."""
+    return facts["config"].get("trace", {}).get("known_scopes")
+
+
 def scope_seconds(capture: dict, known, *,
                   inside: Optional[str] = None) -> Dict[Optional[str], float]:
     """{scope or None: seconds of the operations' own time}, mean over the
@@ -120,12 +131,12 @@ def scope_seconds(capture: dict, known, *,
     return {k: v / n / 1e9 for k, v in out.items()}
 
 
-def share_pct(facts, *, scopes: Optional[list], known: list) -> Optional[float]:
-    """Device time of the operations whose scope (among `known`) starts
-    with one of `scopes` (None: the operations with no scope), over busy
-    time."""
-    cap = _capture_of(facts)
-    if cap is None:
+def share_pct(facts, *, scopes: Optional[list]) -> Optional[float]:
+    """Device time of the operations whose scope (among the
+    configuration's `known_scopes`) starts with one of `scopes` (None: the
+    operations with no scope), over busy time."""
+    cap, known = _capture_of(facts), known_scopes(facts)
+    if cap is None or not known:
         return None
     cache = facts.setdefault("scopes_seconds", {})
     key = tuple(known)
@@ -177,13 +188,15 @@ def experts_least_s(config: dict, *, rows: float, active_experts: float,
 
 
 def experts_roofline_pct(facts, *, program: str, inside: str, scope: str,
-                         known: list, label: str) -> Optional[float]:
+                         label: str) -> Optional[float]:
     """Least time of the expert layers of one execution of `program` (from
     the window's `moe_*{program=label}` counters) over the device time of
     the operations under `scope` inside it (op_name prefix `inside`), per
     execution of it in the capture."""
     cap, t, peaks = _capture_of(facts), facts.get("trace"), facts.get("peaks")
-    if cap is None or not t or not peaks or program not in t["programs"]:
+    known = known_scopes(facts)
+    if cap is None or not known or not t or not peaks \
+            or program not in t["programs"]:
         return None
     d = _deltas(facts, [f'moe_{name}{{program="{label}"}}' for name in
                        ("layer_calls_total", "assignments_total",

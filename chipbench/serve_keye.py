@@ -13,9 +13,9 @@ and head over the rows that predicted a served token. Sequences are padded
 to one length a run (the longest, rounded up to 512), so the reference
 compiles once; what is padded lies after every served row and is causal
 future to all of them. The statistics and the two tests are
-`check.served_margins`' and `serve.check_served`'s
-(`tests/test_keye_cell.py` holds the margins equal on a model small enough
-for both).
+`check.served_margins`' and `serve.check_served`'s (`tests/test_dsa.py`
+and `tests/test_mla.py` hold the margins equal on models small enough for
+both).
 """
 
 from __future__ import annotations

@@ -1,8 +1,9 @@
 """What PR 26 added beside the files that were there: the scope reader
-with prefixes from a metric's own arguments, the one-sequence-at-a-time
-check, the counter ratios and the experts' roofline arithmetic, and the
-two new cells end to end at rehearsal size (these two start a daemon on
-the CPU; `rehearse.sh`, which cannot be edited, runs them as well)."""
+with prefixes a family brings (since PR 39 in its configuration's file),
+the one-sequence-at-a-time check, the counter ratios and the experts'
+roofline arithmetic, and the two new cells end to end at rehearsal size
+(these two start a daemon on the CPU; `rehearse.sh`, which cannot be
+edited, runs them as well)."""
 
 import json
 import os
@@ -22,7 +23,7 @@ def test_scopes_reproduce_spans_shares_for_gpt2s_prefixes():
     """On the recorded GPT-2 capture, with `spans.SCOPES` as the
     recognised prefixes, each of the benchmark's `sat_scope_*` shares
     comes out the same from `scopes.share_pct`; they add up to 100."""
-    known = list(spans.SCOPES)
+    config = {"trace": {"known_scopes": list(spans.SCOPES)}}
     total = 0.0
     for name in ("attn", "kv_pool", "layer_scan", "model", "unscoped"):
         with open(os.path.join(cells.HERE, "layers",
@@ -30,8 +31,9 @@ def test_scopes_reproduce_spans_shares_for_gpt2s_prefixes():
             want_scopes = json.load(f)["args"]["scopes"]
         want = spans.scope_share_pct({"spans_capture": recorded()},
                                      scopes=want_scopes)
-        got = scopes.share_pct({"scopes_capture": recorded()},
-                               scopes=want_scopes, known=known)
+        got = scopes.share_pct(
+            {"scopes_capture": recorded(), "config": config},
+            scopes=want_scopes)
         assert got == pytest.approx(want, rel=1e-12)
         total += got
     assert total == pytest.approx(100.0)
@@ -74,11 +76,10 @@ def _moe_facts():
 
 
 def test_counter_ratios_and_roofline_arithmetic():
-    facts = _moe_facts()
-    known = ["moe.experts", "moe.route", "llama.", "layers.scan"]
-    assert scopes.share_pct(facts, scopes=["moe.experts"], known=known) == \
+    facts = _moe_facts()  # the prefixes: the configuration's own
+    assert scopes.share_pct(facts, scopes=["moe.experts"]) == \
         pytest.approx(100 * 800 / 1600)
-    assert scopes.share_pct(facts, scopes=None, known=known) == \
+    assert scopes.share_pct(facts, scopes=None) == \
         pytest.approx(100 * 100 / 1600)
     assert scopes.counter_ratio(
         facts, num='moe_active_experts_total{program="decode"}',
@@ -97,13 +98,13 @@ def test_counter_ratios_and_roofline_arithmetic():
     # 300 ns under moe.experts inside jit(decode_step), two executions
     assert scopes.experts_roofline_pct(
         facts, program="jit_decode_step", inside="jit(decode_step)/",
-        scope="moe.experts", known=known, label="decode") == pytest.approx(
+        scope="moe.experts", label="decode") == pytest.approx(
         100 * least["least_s"] / 150e-9)
     # a program that predates the counters: nothing to read, no error
     facts["metrics1"] = facts["metrics0"] = {}
     assert scopes.experts_roofline_pct(
         facts, program="jit_decode_step", inside="jit(decode_step)/",
-        scope="moe.experts", known=known, label="decode") is None
+        scope="moe.experts", label="decode") is None
     assert scopes.counter_ratio(facts, num="a", den="b") is None
 
 
