@@ -22,6 +22,19 @@ The index key of every position is cache state: the transient prefill
 row and the paged pool carry it as a third leaf "ik" beside "k" and "v"
 (one head, Di wide; the pool stores it `lane_padded` like every row).
 
+The same scores and the same exact selection serve a SECOND shape of
+indexer (models/mla.py, a layer kind with `MlaConfig.index_topk`): index
+queries projected from the query latent c_q where this module projects them
+from h, a LayerNorm on the index key, RoPE on a part of the index lanes, and
+a set that masks the absorbed read of a latent pool (decode) and a chunk's
+up-projected latent attention (prefill) where this module's masks K and V of
+GQA heads. There the index key is a leaf of ONE layer kind — "ik" beside
+"latent" in the layers that select, absent from the layers under a window —
+and its width, its layers and its block table are that kind's
+(runtime/paged_kvcache.py, leaves by layer kind). `index_scores`, `select`
+and `select_live` are shared; the projections and the callers are each
+module's own.
+
 Three callers, one mathematics:
   * `dense_attn` — the whole-sequence forward (`llama.block_apply`);
   * `DsaFamilyRows.prefill` — a chunk of queries at [start, start + T)

@@ -164,8 +164,8 @@ def prepare_stacked(params, cfg: GPTConfig):
     `make_apply_stacked`. The stacked layout is also what the pipeline
     runtime shards over the 'stage' mesh axis."""
     out = {k: v for k, v in params.items() if not k.startswith("h_")}
-    for name, (first, stop) in stack_ranges(cfg).items():
-        out[name] = stack_blocks(params, range(first, stop))
+    for name, layers in stack_layers(cfg).items():
+        out[name] = stack_blocks(params, layers)
     return out
 
 
@@ -179,6 +179,51 @@ def stack_ranges(cfg):
     k = getattr(cfg, "first_k_dense", 0)
     return {**({"dense_blocks": (0, k)} if k else {}),
             "blocks": (k, cfg.n_layer)}
+
+
+def stack_layers(cfg):
+    """{name in the prepared tree: its layers' numbers} in the order of
+    each stack's first layer: `stack_ranges`' ranges, or — for a config
+    whose layers are of KINDS that interleave (`layer_types`, models/
+    mla.py: layers whose attention and cache differ) — the dense prefix,
+    the "full" expert layers ("blocks") and the "window" expert layers
+    ("window_blocks"), each stacked apart whatever lies between its
+    members."""
+    types = getattr(cfg, "layer_types", None)
+    if types is None:
+        return {name: tuple(range(*r))
+                for name, r in stack_ranges(cfg).items()}
+    k = getattr(cfg, "first_k_dense", 0)
+    out = {"dense_blocks": tuple(range(k))} if k else {}
+    for name, kind in (("blocks", "full"), ("window_blocks", "window")):
+        layers = tuple(i for i in range(k, cfg.n_layer) if types[i] == kind)
+        if layers:
+            out[name] = layers
+    return out
+
+
+def layer_runs(cfg):
+    """The layer loop of a config with `layer_types`, as runs of
+    consecutive layers that lie in one stack: [(stack name, (first, stop)
+    within the stack, kind, (first, stop) among the kind's layers)] in
+    layer order. A run is one scan; the kind's range is where its layers'
+    cache lives (a kind's leaves have that kind's layers only)."""
+    types = cfg.layer_types
+    where = {layer: (name, j) for name, layers in stack_layers(cfg).items()
+             for j, layer in enumerate(layers)}
+    seen = {}
+    runs = []
+    for layer in range(cfg.n_layer):
+        name, j = where[layer]
+        kind = types[layer]
+        nth = seen.get(kind, 0)
+        seen[kind] = nth + 1
+        if runs and runs[-1][0] == name and runs[-1][1][1] == j:
+            _, (a, _), _, (ka, _) = runs[-1]
+            runs[-1] = (name, (a, j + 1), kind, (ka, nth + 1))
+        else:
+            runs.append((name, (j, j + 1), kind, (nth, nth + 1)))
+    return runs
 
 
 def blocks_scan(stacked, x, *, cfg: GPTConfig, use_flash=False, compute_dtype=None,

@@ -373,11 +373,12 @@ def init_gated_mlp(keys, cfg: LlamaConfig, d_ff: int, dtype=jnp.float32):
 
 
 def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
-               include_mlp: bool = True):
+               include_mlp: bool = True, kind=None):
     """`include_mlp=False` builds the attention/norm half only — MoE
     families (llama_moe) add their expert stacks instead of allocating
     dense MLP weights just to delete them (22 GB of transient garbage at
-    mixtral-8x7b scale)."""
+    mixtral-8x7b scale). `kind`: the layer's kind where the config's
+    layers are of several (models/mla.py)."""
     c, d = cfg.n_embd, cfg.head_dim
     ks = jax.random.split(key, 7)
 
@@ -433,7 +434,8 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
     if getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models import mla
 
-        blk["attn"] = mla.init_attn(jax.random.fold_in(key, 17), cfg, dtype)
+        blk["attn"] = mla.init_attn(jax.random.fold_in(key, 17), cfg, dtype,
+                                    mla.kinds(cfg)[kind or "full"])
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -454,29 +456,51 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
     return blk
 
 
-def init(rng, cfg: LlamaConfig = PRESETS["llama-test"], dtype=jnp.float32,
-         *, include_mlp: bool = True):
+def init_parts(rng, cfg: LlamaConfig = PRESETS["llama-test"],
+               dtype=jnp.float32, *, include_mlp: bool = True):
+    """`init`, a top-level entry at a time: {name: a function that draws
+    that entry} — "wte", "ln_f", "lm_head" and each "h_<i>" from its own
+    key, so that a process which holds the tree in another dtype can draw,
+    cast and free one layer at a time (`registry.ParamParts`,
+    `node._stack_and_release`) and hold the very values `init` gives."""
     keys = jax.random.split(rng, cfg.n_layer + 3)
     c = cfg.n_embd
     norm_init = jnp.zeros if cfg.norm_plus_one else jnp.ones
-    ln_f = {"scale": norm_init((c,), dtype)}
-    if cfg.layer_norm:
-        ln_f["bias"] = jnp.zeros((c,), dtype)
-    params = {
-        "wte": {"embedding": (jax.random.normal(keys[0], (cfg.vocab_size, c))
-                              * 0.02).astype(dtype)},
+    types = getattr(cfg, "layer_types", None)
+
+    def ln_f():
+        p = {"scale": norm_init((c,), dtype)}
+        if cfg.layer_norm:
+            p["bias"] = jnp.zeros((c,), dtype)
+        return p
+
+    def lm_head():
+        p = _kernel(keys[1], (c, cfg.vocab_size), dtype)
+        if cfg.dense_bias:  # Phi: lm_head carries a bias too
+            p["bias"] = jnp.zeros((cfg.vocab_size,), dtype)
+        return p
+
+    parts = {
+        "wte": lambda: {"embedding": (
+            jax.random.normal(keys[0], (cfg.vocab_size, c))
+            * 0.02).astype(dtype)},
         "ln_f": ln_f,
     }
     if not cfg.tie_word_embeddings:
         # tied configs carry NO lm_head leaf — head() projects through
         # wte.embedding.T (one table in HBM, shared gradient)
-        params["lm_head"] = _kernel(keys[1], (c, cfg.vocab_size), dtype)
-        if cfg.dense_bias:  # Phi: lm_head carries a bias too
-            params["lm_head"]["bias"] = jnp.zeros((cfg.vocab_size,), dtype)
+        parts["lm_head"] = lm_head
     for i in range(cfg.n_layer):
-        params[f"h_{i}"] = init_block(keys[2 + i], cfg, dtype,
-                                      include_mlp=include_mlp)
-    return params
+        parts[f"h_{i}"] = functools.partial(
+            init_block, keys[2 + i], cfg, dtype, include_mlp=include_mlp,
+            kind=None if types is None else types[i])
+    return parts
+
+
+def init(rng, cfg: LlamaConfig = PRESETS["llama-test"], dtype=jnp.float32,
+         *, include_mlp: bool = True):
+    return {name: make() for name, make in init_parts(
+        rng, cfg, dtype, include_mlp=include_mlp).items()}
 
 
 # --------------------------------------------------------------------------
@@ -704,13 +728,14 @@ def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None):
 
 
 def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
-                window=None, ffn=None):
+                window=None, ffn=None, kind=None):
     """Pre-RMSNorm block: GQA attention + gated MLP, both residual
     (Gemma-2 additionally norms each branch output — post_norms).
     `attn_fn(bp, h)` overrides the attention (the sequence-parallel ring
     plugs in here — same hook pattern as gpt._block_core); `window` is
     the per-layer window override for the default dense attention;
-    `ffn(bp, h)` overrides the MLP (Mixtral MoE)."""
+    `ffn(bp, h)` overrides the MLP (Mixtral MoE); `kind` is the layer's
+    kind where the config's layers are of several (models/mla.py)."""
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
     if attn_fn is None and cfg.index_topk is not None:
@@ -722,7 +747,8 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
         from dnn_tpu.models import mla
 
         fn = lambda bp2, h: mla.dense_attn(  # noqa: E731
-            bp2, h, cfg=cfg, compute_dtype=compute_dtype)
+            bp2, h, cfg=cfg, compute_dtype=compute_dtype,
+            m=mla.kinds(cfg)[kind or "full"])
     # trace-time scopes: device profiles (obs/profile.py) name the
     # attention branch vs the residual/MLP compose; zero runtime cost
     with jax.named_scope("llama.block.attn"):
@@ -774,18 +800,48 @@ def head(params, x, *, cfg: LlamaConfig, compute_dtype=None, logits_dtype=None):
 
 
 def layer_stacks(prepared, cfg):
-    """[(stacked blocks, (first layer, stop) or None)] in layer order: a
-    model's layers are ONE stack, `prepared["blocks"]` (None: all of
-    them), unless the config has a dense prefix (`first_k_dense`,
-    models/llama_moe.py) — layers of another kind, whose params stack
-    apart as `prepared["dense_blocks"]` in front of the expert layers'.
-    Each is scanned on its own; a paged pool is reached by layer index
-    across both (`paged_kvcache.scan_blocks(layers=)`), a dense cache or
-    a transient row is sliced by the range."""
+    """[(stacked blocks, (first layer, stop) or None, kind or None)] in
+    layer order: a model's layers are ONE stack, `prepared["blocks"]`
+    (None: all of them), unless the config has a dense prefix
+    (`first_k_dense`, models/llama_moe.py) — layers of another kind,
+    whose params stack apart as `prepared["dense_blocks"]` in front of
+    the expert layers'. Each is scanned on its own; a paged pool is
+    reached by layer index across both (`paged_kvcache.scan_blocks(
+    layers=)`), a dense cache or a transient row is sliced by the range.
+
+    A config whose layers are of KINDS that interleave (`layer_types`,
+    models/mla.py) has a stack a kind of params (`gpt.stack_layers`) and
+    its loop is `gpt.layer_runs`: one entry, one scan, a run of
+    consecutive layers of one stack; the range is then among the KIND's
+    layers — where the kind's cache leaves hold them — and the kind is
+    named. (A 46-layer F F S S S F S S S ... model is 24 runs: the loop
+    over whole periods as ONE scan is not written; the cut that is served
+    is three runs, each a whole stack.)"""
+    if getattr(cfg, "layer_types", None) is not None:
+        return [(_span(prepared[name], span), layers, kind)
+                for name, span, kind, layers in gpt.layer_runs(cfg)]
     ranges = gpt.stack_ranges(cfg)
     if len(ranges) == 1:
-        return [(prepared["blocks"], None)]
-    return [(prepared[name], r) for name, r in ranges.items()]
+        return [(prepared["blocks"], None, None)]
+    return [(prepared[name], r, None) for name, r in ranges.items()]
+
+
+class _Span:
+    """Layers [first, stop) of a stack that holds more: `scan_form` cuts
+    what rides the loop and offsets the layer index of what does not."""
+
+    def __init__(self, stack, first, stop):
+        self.stack, self.first, self.stop = stack, first, stop
+
+    def cut(self, tree=None):
+        """The span's layers of `tree` (the stack itself when None)."""
+        return jax.tree.map(lambda x: x[self.first:self.stop],
+                            self.stack if tree is None else tree)
+
+
+def _span(stack, span):
+    n = len(jax.tree.leaves(stack)[0])
+    return stack if span == (0, n) else _Span(stack, *span)
 
 
 def scan_form(stack, ffn):
@@ -801,11 +857,16 @@ def scan_form(stack, ffn):
     itself and the identity."""
     from dnn_tpu.parallel.moe import EXPERT_MATRICES, LayerOf
 
+    span, cut = None, (lambda tree: tree)
+    if isinstance(stack, _Span):
+        span, cut, stack = (stack.first, stack.stop), stack.cut, stack.stack
+
     moe = stack.get("moe") if hasattr(ffn, "expert_forms") else None
     whole = {k: moe[k] for k in EXPERT_MATRICES if k in (moe or {})}
     if not whole:
-        return stack, lambda bp: bp
-    rest = {**stack, "moe": {k: v for k, v in moe.items() if k not in whole}}
+        return cut(stack), lambda bp: bp
+    rest = cut({**stack,
+                "moe": {k: v for k, v in moe.items() if k not in whole}})
     n_layer = next(iter(whole.values())).shape[0]
 
     def bind(xs_l):
@@ -813,28 +874,30 @@ def scan_form(stack, ffn):
         return {**bp, "moe": {**bp["moe"], **{
             k: LayerOf(w, layer) for k, w in whole.items()}}}
 
-    return (rest, jnp.arange(n_layer, dtype=jnp.int32)), bind
+    return (rest, jnp.arange(*(span or (n_layer,)), dtype=jnp.int32)), bind
 
 
 def _scan_all_stacks(prepared, x, *, cfg, **kw):
     """`blocks_scan` over every stack of `layer_stacks`."""
     wins = kw.pop("windows", None)
-    for stack, layers in layer_stacks(prepared, cfg):
+    for stack, layers, kind in layer_stacks(prepared, cfg):
         w = wins if wins is None or layers is None else wins[
             layers[0]:layers[1]]
-        x = blocks_scan(stack, x, cfg=cfg, windows=w, **kw)
+        if isinstance(stack, _Span):
+            stack = stack.cut()
+        x = blocks_scan(stack, x, cfg=cfg, windows=w, kind=kind, **kw)
     return x
 
 
 def blocks_scan(stacked, x, *, cfg, compute_dtype, remat=False, attn_fn=None,
-                windows=None, ffn=None):
+                windows=None, ffn=None, kind=None):
     """Scan the stacked blocks. `windows` is the per-layer window array
     for alternating-attention configs ((L',) — already sliced to this
     stack's layer range); None scans without the extra input. `ffn`
     overrides every block's MLP (Mixtral MoE)."""
     block = (lambda bp, carry, window=None: block_apply(
         bp, carry, cfg=cfg, compute_dtype=compute_dtype,
-        attn_fn=attn_fn, window=window, ffn=ffn))
+        attn_fn=attn_fn, window=window, ffn=ffn, kind=kind))
     if remat:
         block = jax.checkpoint(block)
 
@@ -1458,10 +1521,10 @@ class LlamaFamilyRows:
                     compute_dtype=self.compute_dtype)
 
     def _block_rows(self, bp, x, layer_cache, pos, write, codec,
-                    window=None, ffn=None):
+                    window=None, ffn=None, **kind):
         with jax.named_scope("llama.block.cached_attn"):
             h, o, layer_cache = self._attn_rows(bp, x, layer_cache, pos,
-                                                write, codec, window)
+                                                write, codec, window, **kind)
         with jax.named_scope("llama.block.mlp"):
             return (_branches_residual(bp, x, o, h, cfg=self.cfg,
                                        compute_dtype=self.compute_dtype,
@@ -1583,13 +1646,13 @@ class LlamaFamilyRows:
 
         # the loop's carry is (x, the MoE stats summed so far or None):
         # scan_blocks hands it through whole
-        def block(bind, bp, carry, c, codec, window=None):
+        def block(bind, kind, bp, carry, c, codec, window=None):
             x, acc = carry
             bp = bind(bp)
 
             def run(f):
                 return self._block_rows(bp, x, c, pos, active, codec,
-                                        window=window, ffn=f)
+                                        window=window, ffn=f, **kind)
 
             (y, c), acc = _run_block(self.ffn, acc, run)
             return (y, acc), c
@@ -1597,15 +1660,17 @@ class LlamaFamilyRows:
         # a paged pool rides the loop whole, a dense cache by layer
         acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
         carry, new_cache = (x, acc0), cache
-        for stack, layers in layer_stacks(prepared, self.cfg):
-            # one of several stacks scans its own range of the pool
+        for stack, layers, kind in layer_stacks(prepared, self.cfg):
+            # one of several stacks scans its own range of the pool (of
+            # its kind's leaves, where the layers are of kinds)
             wins = () if self._wins is None else (
                 self._wins if layers is None
                 else self._wins[layers[0]:layers[1]],)
             blocks, bind = scan_form(stack, self.ffn)
             carry, new_cache = scan_blocks(
-                functools.partial(block, bind), carry, blocks, new_cache,
-                codec, *wins,
+                functools.partial(block, bind,
+                                  {} if kind is None else {"kind": kind}),
+                carry, blocks, new_cache, codec, *wins,
                 layers=None if layers is None else jnp.arange(*layers))
         x, acc = carry
         logits = head(prepared, x.astype(jnp.float32), cfg=self.cfg,
@@ -1692,6 +1757,14 @@ def make_partition(cfg: LlamaConfig, *, compute_dtype=None):
     part_ffn = cfg.default_ffn(compute_dtype)
 
     def partition(num_parts):
+        if getattr(cfg, "layer_types", None) is not None:
+            # layers of kinds: one stage, the whole forward
+            keys = ("wte", "ln_f", "lm_head") + tuple(
+                f"h_{i}" for i in range(cfg.n_layer))
+            return [StageSpec(
+                name=f"llama_blocks[0:{cfg.n_layer}]+embed+head",
+                apply=make_apply(cfg, compute_dtype=compute_dtype),
+                param_keys=keys)]
         ranges = gpt.layer_ranges(cfg.n_layer, num_parts)
         stages = []
         wins = layer_windows(cfg)

@@ -37,6 +37,7 @@ Qwen2MoeForCausalLM / OlmoeForCausalLM: mlp.gate + mlp.experts.i.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional
 
 import jax
@@ -109,9 +110,27 @@ class MixtralConfig(llama.LlamaConfig):
     # multi-head latent attention (models/mla.py): the cache holds ONE
     # compressed latent a position
     mla: Optional[MlaConfig] = None
+    # layers of two KINDS (models/mla.py): `layer_types[i]` is "full"
+    # (`mla`'s widths) or "window" (`mla_window`'s, a latent attention of
+    # its own widths, head count and theta under a sliding window). The
+    # kinds' params stack apart (gpt.stack_layers) and their cache leaves
+    # and block tables are apart (paged_kvcache, by kind)
+    mla_window: Optional[MlaConfig] = None
+    layer_types: Optional[tuple] = None
 
     def __post_init__(self):
         super().__post_init__()
+        if (self.layer_types is None) != (self.mla_window is None):
+            raise ValueError("layer_types and mla_window come together")
+        if self.layer_types is not None and (
+                self.mla is None or len(self.layer_types) != self.n_layer
+                or set(self.layer_types) - {"full", "window"}
+                or "window" in self.layer_types[:self.first_k_dense]
+                or self.mla_window.window is None):
+            raise ValueError(
+                "layer_types names each of the n_layer layers \"full\" "
+                "(mla) or \"window\" (mla_window, which has a window); "
+                "the dense prefix is of full layers")
         if self.first_k_dense and not (
                 0 < self.first_k_dense < self.n_layer and self.d_ff_dense):
             raise ValueError(
@@ -285,6 +304,68 @@ PRESETS["joyai-test"] = MixtralConfig(
                         scale=2.5),
     mla=MlaConfig(q_lora_rank=48, kv_lora_rank=32, qk_nope_head_dim=16,
                   qk_rope_head_dim=8, v_head_dim=24, rope_interleave=True))
+# dots3-note-prev (dots-studio/dots3-note-prev config.json, `model_type`
+# dots3_note; 288B-A17B): 46 layers of latent attention of two KINDS
+# (models/mla.py) — 13 "full" layers (0, 1, 5, 9, ..., 45: 128 heads, a
+# latent of 512, and a DeepSeek-V3.2-style indexer on the query latent
+# that picks 2048 positions) and 33 "window" layers (64 heads, a latent
+# of 1024, nope 192, theta 50000, a window of 513 that counts the query's
+# own position) — both with the low-rank rescale and a head-wise output
+# gate; layer 0 a dense SwiGLU of 13824, then 256 experts of 1536, 8 a
+# token by sigmoid scores with a selection bias (`noaux_tc`, one group),
+# weights normalised, times 1, and one ungated shared expert. The vision
+# and audio towers and any MTP module are not served. What the config
+# leaves open is `assumed` in chipbench/configs/dots3-note-prev-ep8-1chip
+# .json. Never instantiated whole.
+_DOTS3_TYPES = tuple("full" if i < 2 or i % 4 == 1 else "window"
+                     for i in range(46))
+_DOTS3_FULL = MlaConfig(
+    q_lora_rank=1024, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True,
+    lora_rescale=True, head_gate=True, index_topk=2048, index_n_head=64,
+    index_head_dim=128, index_rope_dim=64)
+_DOTS3_WINDOW = MlaConfig(
+    q_lora_rank=1024, kv_lora_rank=1024, qk_nope_head_dim=192,
+    qk_rope_head_dim=64, v_head_dim=128, rope_interleave=True, n_head=64,
+    rope_theta=50_000.0, lora_rescale=True, head_gate=True, window=513)
+PRESETS["dots3-note-prev"] = MixtralConfig(
+    block_size=524288, vocab_size=152064, n_layer=46, n_head=128,
+    n_kv_head=128, n_embd=5120, d_ff=1536, rope_theta=80_000_000.0,
+    rms_eps=1e-5, n_expert=256, router_top_k=8, router_norm_topk=True,
+    capacity_factor=256.0, d_shared=1536, shared_gate=False,
+    first_k_dense=1, d_ff_dense=13824,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=1.0),
+    mla=_DOTS3_FULL, mla_window=_DOTS3_WINDOW, layer_types=_DOTS3_TYPES)
+# the benchmark's cut (chipbench/configs/dots3-note-prev-ep8-1chip.json):
+# one chip's share of an 8-chip expert-parallel deployment — experts 0-31
+# of each expert layer's 256, rows 0-19007 of the vocabulary (an eighth),
+# attention, router, shared expert and norms whole — and layer 0 with ONE
+# whole period of four (F | F S S S): 8.4 GB held, the guide's floors
+PRESETS["dots3-note-prev-ep8-1chip"] = dataclasses.replace(
+    PRESETS["dots3-note-prev"], n_layer=5, layer_types=_DOTS3_TYPES[:5],
+    vocab_size=19008, experts_first=0, experts_held=32)
+# tiny dots3 for the CPU tests, every switch of the real one acting: two
+# kinds of other head counts, latents, nope widths and thetas; the
+# rescale and the gate; a window and a topk that a 40-token sequence
+# exceeds; an indexer on the query latent with a partial RoPE; 1 dense +
+# 4 expert layers F F S S S; a held share smaller than the expert count
+PRESETS["dots3-test"] = MixtralConfig(
+    block_size=64, vocab_size=256, n_layer=5, n_head=4, n_kv_head=4,
+    n_embd=64, d_ff=32, rope_theta=80_000_000.0, rms_eps=1e-5,
+    n_expert=8, router_top_k=4, router_norm_topk=True, capacity_factor=8.0,
+    experts_first=0, experts_held=4, d_shared=32, shared_gate=False,
+    first_k_dense=1, d_ff_dense=96,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=1.0),
+    mla=MlaConfig(q_lora_rank=32, kv_lora_rank=16, qk_nope_head_dim=16,
+                  qk_rope_head_dim=8, v_head_dim=16, rope_interleave=True,
+                  lora_rescale=True, head_gate=True, index_topk=12,
+                  index_n_head=4, index_head_dim=16, index_rope_dim=8),
+    mla_window=MlaConfig(q_lora_rank=32, kv_lora_rank=32,
+                         qk_nope_head_dim=24, qk_rope_head_dim=8,
+                         v_head_dim=16, rope_interleave=True, n_head=2,
+                         rope_theta=50_000.0, lora_rescale=True,
+                         head_gate=True, window=9),
+    layer_types=("full", "full", "window", "window", "window"))
 # the benchmark's cut (chipbench/configs/olmoe-1b-7b-1chip.json): three of
 # the sixteen layers — the pattern has period 1 — so that float32 weights,
 # a 16-slot pool of 4096 positions and the programs fit one 16 GB chip
@@ -398,20 +479,24 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
     return ffn
 
 
-def init(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
-         dtype=jnp.float32):
-    """llama.init minus the dense MLPs (include_mlp=False — no transient
-    dense weights at 8x7b scale), plus each block's gated expert stack
-    (and, for d_shared configs, the always-on shared expert + its
-    sigmoid gate)."""
+def init_parts(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
+               dtype=jnp.float32):
+    """`init`, a top-level entry at a time (`llama.init_parts`): a layer's
+    function draws its attention half and then its dense MLP or its
+    expert stacks, from that layer's keys alone."""
     import math
 
-    params = llama.init(rng, cfg, dtype, include_mlp=False)
+    parts = llama.init_parts(rng, cfg, dtype, include_mlp=False)
     keys = jax.random.split(jax.random.fold_in(rng, 7), cfg.n_layer)
-    for i in range(cfg.first_k_dense):
-        params[f"h_{i}"]["mlp"] = llama.init_gated_mlp(
+
+    def dense_layer(i, block):
+        blk = block()
+        blk["mlp"] = llama.init_gated_mlp(
             jax.random.split(keys[i], 3), cfg, cfg.d_ff_dense, dtype)
-    for i in range(cfg.first_k_dense, cfg.n_layer):
+        return blk
+
+    def expert_layer(i, block):
+        blk = block()
         moe = init_moe_gated(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
                              dtype, n_held=cfg.experts_held)
         if cfg.router.select_bias:
@@ -434,8 +519,24 @@ def init(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
             if cfg.shared_gate:
                 moe["shared_gate"] = {"kernel": (jax.random.normal(
                     ks[3], (cfg.n_embd, 1)) * si).astype(dtype)}
-        params[f"h_{i}"]["moe"] = moe
-    return params
+        blk["moe"] = moe
+        return blk
+
+    for i in range(cfg.n_layer):
+        parts[f"h_{i}"] = functools.partial(
+            dense_layer if i < cfg.first_k_dense else expert_layer, i,
+            parts[f"h_{i}"])
+    return parts
+
+
+def init(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
+         dtype=jnp.float32):
+    """llama.init minus the dense MLPs (include_mlp=False — no transient
+    dense weights at 8x7b scale), plus each block's gated expert stack
+    (and, for d_shared configs, the always-on shared expert + its
+    sigmoid gate)."""
+    return {name: make() for name, make in init_parts(rng, cfg,
+                                                      dtype).items()}
 
 
 def make_apply(cfg: MixtralConfig, *, compute_dtype=None, remat=False):
@@ -990,10 +1091,14 @@ def _register(name: str, cfg: MixtralConfig):
         # multi-stage relay partitioning works like any llama family
         partition=llama.make_partition(cfg),
         example_input=gpt.make_example_input(cfg),
-        supported_parts=tuple(range(1, cfg.n_layer + 1)),
+        # layers of kinds that interleave do not cut into equal stages
+        supported_parts=(1,) if cfg.layer_types is not None
+        else tuple(range(1, cfg.n_layer + 1)),
         convert_state_dict=convert,
         config=cfg,
         extras={
+            "init_parts": lambda rng, dtype=jnp.float32, _cfg=cfg:
+                init_parts(rng, _cfg, dtype),
             "make_apply": lambda compute_dtype=None, **_kw: make_apply(
                 cfg, compute_dtype=compute_dtype),
             "family_rows": lambda compute_dtype=None, **_kw: family_rows(

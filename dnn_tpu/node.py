@@ -528,8 +528,10 @@ def main(argv=None) -> int:
     _BOOT["t_engine0"] = _time.monotonic()
     _BOOT["compile_at_engine0"] = _compile_total_s()
     try:
-        engine = PipelineEngine(config, role="stage" if args.serve else "full",
-                                lora_path=args.lora, rng_seed=args.seed)
+        engine = PipelineEngine(
+            config, role="stage" if args.serve else
+            "lm" if args.serve_lm else "full",
+            lora_path=args.lora, rng_seed=args.seed)
     except Exception as e:  # noqa: BLE001 — CLI boundary: checkpoint loads
         # raise FileNotFoundError/unpickling errors etc.; exit with a clean
         # one-liner like the reference does for every config problem
@@ -858,12 +860,21 @@ def _stack_and_release(params, cfg, compute_dtype=None):
     rounds as the in-program convert did: the matmuls see the same
     operands.
 
-    The per-layer `h_<i>` originals leave `params`, and each leaf is cast
-    layer by layer, its originals freed, before its stack is built:
-    the boot peak is the blocks once plus the largest leaf's stack in
-    the dtype it is held in, never a float32 stack beside its cast copy
-    (stacking the whole float32 tree first held every block twice:
-    2.8 GB of a 16 GB chip for GPT-2 Large, 5 GB for three OLMoE layers)."""
+    The per-layer `h_<i>` originals leave `params` ONE LAYER AT A TIME
+    (`params.pop`), each layer's leaves are cast and its originals freed
+    before the next layer is taken, and a stack is built a leaf at a time
+    from the held leaves, which then go. Where `params` makes an entry
+    when it is read (`registry.ParamParts`: the daemon's random init, a
+    layer from that layer's keys), no two layers ever exist in float32:
+    the boot peak is the held tree plus ONE layer as drawn — or, while
+    stacking, plus the largest leaf's stack in the dtype it is held in —
+    never the float32 tree (16.8 GB for one chip's share of five layers
+    of hidden 5120, which no 16 GB chip holds), nor a float32 stack beside
+    its cast copy (2.8 GB of a 16 GB chip for GPT-2 Large, 5 GB for three
+    OLMoE layers). A plain dict of a tree that is already whole is
+    handled alike, and holds the same values."""
+    import time
+
     import jax
     import jax.numpy as jnp
 
@@ -880,25 +891,38 @@ def _stack_and_release(params, cfg, compute_dtype=None):
             free(leaf)
         return out
 
-    def stack(first, stop):
-        layers = [params.pop(f"h_{i}") for i in range(first, stop)]
-        flat = [jax.tree_util.tree_flatten_with_path(layer)
-                for layer in layers]
+    def held_layer(i):
+        """Layer i, taken out of `params`, as it is held: (leaves, tree
+        structure)."""
+        t0 = time.monotonic()
+        leaves, structure = jax.tree_util.tree_flatten_with_path(
+            params.pop(f"h_{i}"))
+        out = [held(path, leaf) for path, leaf in leaves]
+        # the boot span of a layer's draw-and-cast: part of the wall time
+        # `dnn_tpu_boot_weight_load_seconds` reports, on its own as
+        # `dnn_tpu_boot_layer_init_seconds`
+        _BOOT["layer_init_s"] = _BOOT.get("layer_init_s", 0.0) + (
+            time.monotonic() - t0)
+        return out, structure
+
+    def stack(layers):
+        flat = [held_layer(i) for i in layers]
         stacked = []
         for column in zip(*(leaves for leaves, _ in flat)):
-            leaves = [held(path, leaf) for path, leaf in column]
-            # done before the originals go, so that the next leaf's stack
-            # is allocated after this one's originals are free
-            stacked.append(jax.block_until_ready(jnp.stack(leaves)))
-            for leaf in leaves:
+            # done before the leaves go, so that the next leaf's stack
+            # is allocated after this one's are free
+            stacked.append(jax.block_until_ready(jnp.stack(column)))
+            for leaf in column:
                 free(leaf)
         return jax.tree_util.tree_unflatten(flat[0][1], stacked)
 
-    # layers of another kind in front (a dense prefix before expert
-    # layers) are a stack of their own (`gpt.stack_ranges`)
-    from dnn_tpu.models.gpt import stack_ranges
+    # layers of another kind (a dense prefix before expert layers;
+    # layers whose attention differs) are stacks of their own
+    # (`gpt.stack_layers`)
+    from dnn_tpu.models.gpt import stack_layers
 
-    stacks = {name: stack(*r) for name, r in stack_ranges(cfg).items()}
+    stacks = {name: stack(layers)
+              for name, layers in stack_layers(cfg).items()}
     return {**jax.tree_util.tree_map_with_path(held, dict(params)), **stacks}
 
 
@@ -1063,6 +1087,8 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
         _m.bulk(gauges={
             "dnn_tpu_boot_imports_seconds": round(_imports, 4),
             "dnn_tpu_boot_weight_load_seconds": round(_weight, 4),
+            "dnn_tpu_boot_layer_init_seconds":
+                round(float(_BOOT.get("layer_init_s", 0.0)), 4),
             "dnn_tpu_boot_compile_preready_seconds":
                 round(_compile_total_s(), 4),
             "dnn_tpu_boot_ready_total_seconds": round(_ready, 4),

@@ -400,6 +400,9 @@ class StepClock:
         self.dsa_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
         # latent attention's reads (note_mla): MLA_SERIES in order
         self.mla_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
+        # the same reads by layer KIND (note_mla_kind): (kind, program) ->
+        # cached positions the kind's layers had to read
+        self.mla_kind_total: "Dict[tuple, int]" = {}
         self._pending_moe: "Optional[Dict[str, list]]" = None
         self._gauges_registered = False
         self._registry = registry
@@ -644,6 +647,29 @@ class StepClock:
         mla.* totals, on /metrics with the first note."""
         self._note3(self.mla_total, self._mla_gauges, program,
                     (layer_calls, cached, pairs))
+
+    def note_mla_kind(self, program: str, kind: str, cached: int):
+        """`note_mla`'s cached positions for a model whose layers are of
+        KINDS (models/mla.py), by kind: what the kind's layers had to
+        read — an indexer's selected positions for "full", the window's
+        for "window" — summed over its layers. Cumulative
+        `mla.cached_positions_read_total{kind=,program=}`, on /metrics
+        with a kind's first note."""
+        if not _obs.enabled():
+            return
+        key = (kind, program)
+        if key not in self.mla_kind_total:
+            self.mla_kind_total[key] = 0
+            ref = weakref.ref(self)
+
+            def read(key=key):
+                c = ref()
+                return float(c.mla_kind_total[key]) if c is not None else 0.0
+
+            self._gauges[labeled("mla.cached_positions_read_total",
+                                 kind=kind, program=program)] = read
+            self._gauges_registered = False  # re-register with it
+        self.mla_kind_total[key] += cached
 
     def _note3(self, total, gauges, program, add):
         if not _obs.enabled():

@@ -507,7 +507,8 @@ def _reference_paged_step(q, pools, tables, pos, layer, new, sel=None):
     return (jnp.where(gate[:, None, None, None], out, 0.0), *pools)
 
 
-def _reference_latent_step(q, cp, tables, pos, layer, new, latent, scale):
+def _reference_latent_step(q, cp, tables, pos, layer, new, latent, scale,
+                           sel=None):
     """paged_decode_attention's `latent` form in plain jnp: place `new`'s
     row, then q (B, 1, R, D) against the slot's gathered rows, key whole
     and value in the first `latent` lanes. As the kernel: zeros for a
@@ -528,8 +529,10 @@ def _reference_latent_step(q, cp, tables, pos, layer, new, latent, scale):
     s = jnp.einsum("bhrd,bsd->bhrs", qf, kv,
                    preferred_element_type=jnp.float32) * (
         q.shape[-1] ** -0.5 if scale is None else scale)
-    s = jnp.where(jnp.arange(nb * bp)[None, None, None, :]
-                  <= pos[:, None, None, None], s, _NEG_BIG)
+    keep = jnp.arange(nb * bp)[None, :] <= pos[:, None]
+    if sel is not None:
+        keep = keep & sel
+    s = jnp.where(keep[:, None, None, :], s, _NEG_BIG)
     out = jnp.einsum("bhrs,bsd->bhrd", jax.nn.softmax(s, axis=-1),
                      kv[..., :latent], preferred_element_type=jnp.float32)
     if gate is None:
@@ -657,6 +660,9 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
         cols = gi * group * bp + jax.lax.broadcasted_iota(
             jnp.int32, s2.shape, 1)
         seen = cols <= pos
+        if select:
+            # the group's set as one row of G * bp lanes, for every head
+            seen = seen & (sel_ref[0, gi] != 0)
         s2 = jnp.where(seen, s2, _NEG_BIG)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
@@ -830,14 +836,14 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     stand (all D lanes, scores times `scale`) and value in their first
     `latent` lanes. A block is copied into VMEM once and read both ways.
     Returns (B, 1, R, latent), and with `new` = (row (B, 1, 1, D), gate)
-    the leaf. Float pools, no `sel`.
+    the leaf. Float pools; `sel` masks the group's one matrix of scores
+    for every head alike.
 
     Dispatches to the Pallas kernel on TPU; otherwise runs the
     reference. `interpret=True` forces the kernel in interpreter mode
     (CPU CI runs the real table chase and block copies)."""
     quant = ks is not None
-    if latent and (quant or sel is not None or q.shape[1] != 1
-                   or kp.shape[-3] != 1):
+    if latent and (quant or q.shape[1] != 1 or kp.shape[-3] != 1):
         raise ValueError("latent attention reads one float leaf of one "
                          "head, whole")
     pools = [kp] if latent else [kp, vp] + (
@@ -856,7 +862,7 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     if interpret is None or not (interpret or lowers):
         if latent:
             return _reference_latent_step(q, kp, tables, pos, layer, new,
-                                          latent, scale)
+                                          latent, scale, sel)
         if layer is None:
             return reference_paged_decode_attention(
                 q, kp, vp, tables, pos, ks=ks, vs=vs, sel=sel)
@@ -916,7 +922,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         n_groups = -(-nb_max // group)
         sel4 = jnp.pad(sel.astype(jnp.int32),
                        ((0, 0), (0, n_groups * group * bp - nb_max * bp))
-                       ).reshape(b, n_groups, group, bp)
+                       ).reshape((b, n_groups, 1, group * bp) if latent
+                                 else (b, n_groups, group, bp))
         in_specs.append(rows_of_slot(sel4))
         rows = (*rows, sel4)
     # softmax states: `group` a query row, merged at a slot's end; the

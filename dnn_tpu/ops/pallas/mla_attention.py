@@ -18,6 +18,14 @@ neither fetched (its block index is clamped to the last live one, and a
 repeated index is not copied again) nor computed, and `start` rides scalar
 prefetch, so one compiled program serves every chunk start.
 
+Two narrowings of what a query reads, both off by default (models/mla.py's
+layer kinds): `window` = W bands it to the columns > its position - W — a
+column tile wholly behind the band of a query tile's first row is skipped
+like one past its last, so a chunk that was handed window + chunk
+positions pays for those; `sel` (T, S) is a set a query (an indexer's
+choice, within the causal limit), one more (bq, bs) int8 tile a step,
+applied inside the online softmax as ops/pallas/sparse_attention.py does.
+
 `reference_mla_prefill_attention` is the plain form: what runs off the TPU
 and for shapes that do not tile, and the oracle of tests/test_mla.py.
 """
@@ -37,25 +45,44 @@ __all__ = ["mla_prefill_attention", "reference_mla_prefill_attention"]
 
 
 def reference_mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start,
-                                    *, scale):
+                                    *, scale, window=None, sel=None):
     """q_nope (H, T, Dn), q_rope (H, T, Dr), k_nope (H, S, Dn), k_rope
     (S, Dr), v (H, S, Dv) -> (H, T, Dv) float32: query t at position
-    start + t reads the columns <= start + t."""
+    start + t reads the columns <= start + t (with `window`, those >
+    start + t - window; with `sel` (T, S), those it is true at)."""
     f32 = jnp.float32
     s = (jnp.einsum("htd,hsd->hts", q_nope.astype(f32), k_nope.astype(f32),
                     preferred_element_type=f32)
          + jnp.einsum("htd,sd->hts", q_rope.astype(f32), k_rope.astype(f32),
                       preferred_element_type=f32)) * scale
     t, s_len = s.shape[1:]
-    keep = jnp.arange(s_len)[None, :] <= start + jnp.arange(t)[:, None]
+    cols, rows = jnp.arange(s_len)[None, :], start + jnp.arange(t)[:, None]
+    keep = cols <= rows
+    if window is not None:
+        keep = keep & (cols > rows - window)
+    if sel is not None:
+        keep = keep & sel
     s = jnp.where(keep[None], s, _NEG_BIG)
     return jnp.einsum("hts,hsd->htd", jax.nn.softmax(s, axis=-1),
                       v.astype(f32), preferred_element_type=f32)
 
 
-def _kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
-            m_scr, l_scr, acc_scr, *, scale, bq, bs):
+def _first_live(start_ref, qi, bq, bs, window):
+    """The first column tile that holds a position some row of query
+    tile qi may read: 0 without a window."""
+    if window is None:
+        return 0
+    return jnp.maximum(start_ref[0] + qi * bq - window + 1, 0) // bs
+
+
+def _kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, *rest,
+            scale, bq, bs, window=None, select=False):
     from jax.experimental import pallas as pl
+
+    sel_ref = None
+    if select:
+        sel_ref, *rest = rest
+    o_ref, m_scr, l_scr, acc_scr = rest
 
     qi, si, ns = pl.program_id(1), pl.program_id(2), pl.num_programs(2)
 
@@ -65,7 +92,8 @@ def _kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(si <= _last_live(start_ref, qi, bq, bs))
+    @pl.when((si <= _last_live(start_ref, qi, bq, bs))
+             & (si >= _first_live(start_ref, qi, bq, bs, window)))
     def _step():
         contract = (((1,), (1,)), ((), ()))
         s = (jax.lax.dot_general(qn_ref[0], kn_ref[0], contract,
@@ -77,6 +105,10 @@ def _kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
             jnp.int32, s.shape, 0)
         cols = si * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         keep = cols <= rows
+        if window is not None:
+            keep = keep & (cols > rows - window)
+        if select:
+            keep = keep & (sel_ref[...].astype(jnp.int32) != 0)
         s = jnp.where(keep, s, _NEG_BIG)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
@@ -98,11 +130,13 @@ def _kernel(start_ref, qn_ref, qr_ref, kn_ref, kr_ref, v_ref, o_ref,
 
 @jax.named_scope("attn.mla_prefill")
 def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *,
-                          scale, block_q=512, block_s=512, interpret=None):
+                          scale, block_q=512, block_s=512, interpret=None,
+                          window=None, sel=None):
     """A chunk's causal attention with a two-part key (module docstring):
     q_nope (H, T, Dn) and q_rope (H, T, Dr) the queries at [start, start +
     T), k_nope (H, S, Dn), k_rope (S, Dr) shared by the heads, v (H, S,
-    Dv) -> (H, T, Dv) float32. The kernel on the TPU (`interpret=True`:
+    Dv) -> (H, T, Dv) float32; `window` (a static int) and `sel` (T, S)
+    bool as the module docstring says. The kernel on the TPU (`interpret=True`:
     interpreted, for the CPU tests); the plain form elsewhere and for
     shapes that do not tile."""
     h, t, _ = q_nope.shape
@@ -112,7 +146,8 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *,
         interpret = False
     if interpret is None or tiles is None:
         return reference_mla_prefill_attention(
-            q_nope, q_rope, k_nope, k_rope, v, start, scale=scale)
+            q_nope, q_rope, k_nope, k_rope, v, start, scale=scale,
+            window=window, sel=sel)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -125,7 +160,8 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *,
         k_rope = jnp.pad(k_rope, ((0, 0), (0, pad)))
 
     def col(i, j, st):
-        return jnp.minimum(j, _last_live(st, i, bq, bs))
+        return jnp.clip(j, _first_live(st, i, bq, bs, window),
+                        _last_live(st, i, bq, bs))
 
     def of_query(x):
         return pl.BlockSpec((1, bq, x.shape[-1]),
@@ -141,7 +177,9 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *,
         in_specs=[of_query(q_nope), of_query(q_rope), of_head(k_nope),
                   pl.BlockSpec((bs, k_rope.shape[-1]),
                                lambda hd, i, j, st: (col(i, j, st), 0)),
-                  of_head(v)],
+                  of_head(v)] + ([] if sel is None else [
+                      pl.BlockSpec((bq, bs),
+                                   lambda hd, i, j, st: (i, col(i, j, st)))]),
         out_specs=pl.BlockSpec((1, bq, dv), lambda hd, i, j, st: (hd, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((bq, 128), jnp.float32),  # running row max
@@ -150,7 +188,9 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *,
         ],
     )
     return pl.pallas_call(
-        functools.partial(_kernel, scale=scale, bq=bq, bs=bs),
+        functools.partial(_kernel, scale=scale, bq=bq, bs=bs, **(
+            {} if window is None else {"window": window}), **(
+            {} if sel is None else {"select": True})),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((h, t, dv), jnp.float32),
         compiler_params=pltpu.CompilerParams(
@@ -158,4 +198,4 @@ def mla_prefill_attention(q_nope, q_rope, k_nope, k_rope, v, start, *,
             vmem_limit_bytes=64 * 1024 * 1024),
         interpret=interpret, name="mla_prefill_attention",
     )(jnp.asarray(start, jnp.int32).reshape(1), q_nope, q_rope, k_nope,
-      k_rope, v)
+      k_rope, v, *(() if sel is None else (sel.astype(jnp.int8),)))

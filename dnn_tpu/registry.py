@@ -17,6 +17,7 @@ layers (node.py:306).
 from __future__ import annotations
 
 import dataclasses
+from collections.abc import MutableMapping
 from typing import Any, Callable, Dict, Optional, Sequence, Tuple
 
 
@@ -50,6 +51,67 @@ class ModelSpec:
     # Optional extras (model-family specific):
     config: Optional[Any] = None  # e.g. GPTConfig for transformer families
     extras: Dict[str, Any] = dataclasses.field(default_factory=dict)
+
+
+    def init_parts(self, rng) -> "ParamParts":
+        """`init(rng)` as a tree whose top-level entries are drawn when
+        first read: the family's own `extras["init_parts"]` (an entry a
+        layer, from that layer's keys), or the whole `init` on the spot,
+        handed out entry by entry."""
+        make = self.extras.get("init_parts")
+        if make is not None:
+            return ParamParts(make(rng))
+        return ParamParts.of_whole(self.init(rng))
+
+
+class ParamParts(MutableMapping):
+    """A param tree {name: subtree} whose entries are made on first read
+    (`makers`: name -> a function that makes it). `parts[name]` makes and
+    keeps; `parts.pop(name)` makes and hands over without keeping — what
+    lets a process that holds the tree in another dtype draw a layer,
+    cast it and free the draw before the next exists
+    (`node._stack_and_release`). Otherwise a dict."""
+
+    def __init__(self, makers):
+        self._makers = dict(makers)
+        self._made = {}
+
+    @classmethod
+    def of_whole(cls, tree):
+        """The entries of a tree that is made whole, here and now (a family
+        with no `init_parts` of its own): each is handed over, and let go
+        of, as a made one is."""
+        parts = cls({})
+        parts.update(tree)
+        return parts
+
+    def __getitem__(self, name):
+        if name not in self._made:
+            self._made[name] = self._makers[name]()
+        return self._made[name]
+
+    def __setitem__(self, name, value):
+        self._makers.setdefault(name, None)
+        self._made[name] = value
+
+    def __delitem__(self, name):
+        del self._makers[name]
+        self._made.pop(name, None)
+
+    def pop(self, name, *default):
+        if name not in self._makers:
+            if default:
+                return default[0]
+            raise KeyError(name)
+        made = self._made.pop(name, None)
+        make = self._makers.pop(name)
+        return made if made is not None else make()
+
+    def __iter__(self):
+        return iter(self._makers)
+
+    def __len__(self):
+        return len(self._makers)
 
 
 _REGISTRY: Dict[str, ModelSpec] = {}

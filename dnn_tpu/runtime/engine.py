@@ -83,8 +83,8 @@ class PipelineEngine:
         role: str = "full",
         lora_path: Optional[str] = None,
     ):
-        if role not in ("full", "stage"):
-            raise ValueError(f"role must be full|stage, got {role}")
+        if role not in ("full", "stage", "lm"):
+            raise ValueError(f"role must be full|stage|lm, got {role}")
         # runtime compile telemetry (dnn_tpu/obs): every XLA compile this
         # engine triggers — construction-time stage jits and any later
         # shape churn — lands in jax_compilations_total, the live
@@ -136,11 +136,25 @@ class PipelineEngine:
             from dnn_tpu import lora as _lora
 
             adapters, alpha = _lora.load_lora(lora_path)
-            self.params = _lora.merge_lora(self.params, adapters, alpha=alpha)
+            # (a tree whose entries are made on reading is made whole here)
+            self.params = _lora.merge_lora(dict(self.params), adapters,
+                                           alpha=alpha)
             log.info("merged LoRA adapters from %s (%d sites%s)",
                      lora_path, len(adapters),
                      f", alpha={alpha}" if alpha is not None else "")
 
+        if role == "lm":
+            # the LM daemon serves from its own held, stacked copy
+            # (node._stack_and_release), which it builds an entry at a
+            # time from `self.params`: no stage program, no runtime, and
+            # nothing here reads a weight
+            self.runtime, self.mesh = "lm", None
+            self._relay = self._pipeline_fn = None
+            self._stage_params, self._stage_jits = [], []
+            self.param_placement = None
+            log.info("engine ready: model=%s role=lm devices=%d dtype=%s",
+                     config.model, len(self.devices), config.dtype)
+            return
         # compiled-once per-stage programs (the unit the gRPC edge serves)
         self._stage_params = [s.slice_params(self.params) for s in self.stages]
         # resolved spmd weight placement ("stage"|"replicated"); None until
@@ -186,6 +200,9 @@ class PipelineEngine:
         path = self.config.model_weights
         if not path:
             log.warning("no model_weights in config; using random init")
+            if self.role == "lm":
+                # an entry (a layer) at a time, as the daemon reads them
+                return self.spec.init_parts(jax.random.PRNGKey(rng_seed))
             return self.spec.init(jax.random.PRNGKey(rng_seed))
         from dnn_tpu.io import checkpoint as ckpt
 

@@ -1250,7 +1250,8 @@ class LMServer:
             from dnn_tpu.obs.mem import logical_nbytes
 
             by_leaf = {k: int(logical_nbytes(v))
-                       for k, v in kv_leaves.items() if k != "tables"}
+                       for k, v in kv_leaves.items()
+                       if not k.startswith("tables")}
             comps["kv_cache"] = {
                 "detail": "bytes of the KV cache by leaf",
                 "bytes": sum(by_leaf.values()), "bytes_by_leaf": by_leaf}

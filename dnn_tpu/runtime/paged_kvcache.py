@@ -35,6 +35,30 @@ key side by side — which attention reads as key AND value:
                                           tier program was written to)
     pos    (B,)                        -- slot lengths, as in dense
 
+**Leaves by layer KIND.** A model whose layers differ in what they keep
+(models/mla.py: "full" layers keep every position's latent and index
+key, "window" layers a latent of another width for the last W positions
+only) declares `cache_kinds`: kind -> {"layers": L_k, "leaves": name ->
+(heads, width), "tables": the name of the kind's tables, "window": W or
+None}. Each kind's leaves have THAT kind's layers, their own width and
+their own number of blocks, and a block table of their own:
+
+    latent   (L_full, n_blocks,   1, block_len, Dlp)   under "tables"
+    ik       (L_full, n_blocks,   1, block_len, Dip)   under "tables"
+    latent_w (L_win,  n_blocks_w, 1, block_len, Dwp)   under "tables_w"
+    tables   (L_full, B, max_blocks);  tables_w (L_win, B, max_blocks)
+
+A layer reaches its kind's leaves at its index AMONG THE KIND's layers
+(`scan_blocks(layers=)`). Block ids are a kind's own (each kind's block 0
+is its junk block), drawn from ONE `BlockAllocator` (`of(kind)`): a slot
+of a window kind holds the blocks its window and the step's write can
+touch — ceil(W / block_len) + 1 — whatever its length, entry j of its
+table row pointing at the block of positions [j * bp, (j + 1) * bp) while
+that is within the window and at junk block 0 before and after
+(`ContinuousBatcher._roll_window_blocks` hands the block that fell behind
+back and draws the next). Decode over such a leaf gathers the window's
+blocks, not the row (`write_attend_latent_rows(window=)`).
+
 The codec interface matches FloatKV (write_rows / attend_rows /
 write_attend_rows / install_row), so GPTFamilyRows / LlamaFamilyRows
 decode through it unchanged. The decode step reaches the pool IN PLACE:
@@ -75,7 +99,8 @@ from dnn_tpu.runtime.kvcache import band_keep
 _NEG_BIG = -1e30
 
 __all__ = ["PagedKV", "BlockAllocator", "InsufficientBlocks",
-           "init_paged_cache", "lane_padded", "cache_head_dim", "scan_blocks"]
+           "init_paged_cache", "lane_padded", "cache_head_dim", "scan_blocks",
+           "window_blocks", "is_tables"]
 
 
 class InsufficientBlocks(RuntimeError):
@@ -93,9 +118,12 @@ class BlockAllocator:
     of aliasing a live block; its content is never attended (the per-row
     position mask stops at each slot's length)."""
 
-    def __init__(self, n_blocks: int):
+    def __init__(self, n_blocks: int, kinds=None):
         if n_blocks < 2:
             raise ValueError("need at least 2 blocks (block 0 is reserved)")
+        # the further layer kinds' blocks (module docstring): {kind:
+        # n_blocks}, an id space each, managed from here (`of`)
+        self._kinds = {k: BlockAllocator(n) for k, n in (kinds or {}).items()}
         self.n_blocks = n_blocks
         self._free: List[int] = list(range(1, n_blocks))
         # block id -> reference count. SHARING (paged prefix cache): a
@@ -108,6 +136,16 @@ class BlockAllocator:
         # number a used-right-now gauge cannot answer after the burst
         # has passed; the serving layer exports all three as gauges.
         self.high_water = 0
+
+    def of(self, kind=None) -> "BlockAllocator":
+        """The blocks of a layer kind, by the name of its tables: this
+        allocator for the first kind (None, "tables"), its own id space
+        for a further one."""
+        return self if kind in (None, "tables") else self._kinds[kind]
+
+    @property
+    def kinds(self):
+        return tuple(self._kinds)
 
     @property
     def n_free(self) -> int:
@@ -199,9 +237,16 @@ def _pad_lanes(x, width):
     return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, width - x.shape[-1])])
 
 
-def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
+def window_blocks(window: int, block_len: int) -> int:
+    """Blocks a slot of a window kind holds: the window's, and the one
+    the step's write opens."""
+    return -(-window // block_len) + 1
+
+
+def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks,
                      block_len: int = 16, dtype=jnp.float32,
-                     kv_heads: Optional[int] = None, leaves=None):
+                     kv_heads: Optional[int] = None, leaves=None,
+                     kinds=None):
     """Pool + tables pytree for `slots` decode rows of up to `max_len`
     positions each, sharing `n_blocks` physical blocks of `block_len`
     positions (leading L on every leaf, like the dense cache; K/V rows
@@ -213,10 +258,24 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks: int,
     of kvcache.Int8KV / Int4KV's layouts (int4 stores native jnp.int4,
     two values per byte). `leaves` = {name: (heads, width)} is a family's
     own declaration of a position's state (`cache_leaves`: K, V and an
-    index key; one latent) in place of K and V, float pools only."""
+    index key; one latent) in place of K and V, float pools only.
+    `kinds` = a family's `cache_kinds` says it by layer kind (module
+    docstring): `n_blocks` is then {tables name: blocks}."""
     if max_len % block_len:
         raise ValueError(f"max_len {max_len} must tile block_len {block_len}")
     nb_max = max_len // block_len
+    if kinds is not None:
+        if dtype in ("int8", "int4"):
+            raise ValueError("a pool with leaves by layer kind is float")
+        out = {}
+        for k in kinds.values():
+            for name, (heads, width) in k["leaves"].items():
+                out[name] = jnp.zeros(
+                    (k["layers"], n_blocks[k["tables"]], heads, block_len,
+                     lane_padded(width)), dtype)
+            out[k["tables"]] = jnp.zeros((k["layers"], slots, nb_max),
+                                         jnp.int32)
+        return out
     tables = jnp.zeros((cfg.n_layer, slots, nb_max), jnp.int32)
     if leaves is not None:
         if dtype in ("int8", "int4"):
@@ -272,10 +331,14 @@ class PagedKV:
     causally only / sub-byte VMEM loads are not wired)."""
 
     def __init__(self, block_len: int, window: Optional[int] = None,
-                 use_kernel=False):
+                 use_kernel=False, kinds=None):
         self.block_len = block_len
         self.window = window
         self.use_kernel = use_kernel
+        # leaf -> the name of its block tables, where the leaves are by
+        # layer kind (module docstring); every leaf under "tables" else
+        self.leaf_tables = {name: k["tables"] for k in (kinds or {}).values()
+                            for name in k["leaves"]}
 
     def _kernel_on(self, c) -> bool:
         """Resolve use_kernel against a concrete per-layer pool view
@@ -353,7 +416,9 @@ class PagedKV:
                                   layer)
 
     def write_attend_latent_rows(self, q, c, row, pos, write_gate, *,
-                                 value_dim: int, scale: float, layer=None):
+                                 value_dim: int, scale: float, layer=None,
+                                 sel=None, window=None, leaf="latent",
+                                 tables="tables"):
         """Latent attention's decode step (models/mla.py): this step's
         latent rows `row` (B, 1, Dl) into the "latent" leaf at `pos`
         (gated and junk-routed as `write_rows`), then every query row of
@@ -364,7 +429,18 @@ class PagedKV:
         is read once: with the kernel on, a block is copied into VMEM
         once and used as key and value (paged_decode_attention's
         `latent=`), and the write is the kernel's as well; the einsum
-        form gathers one view."""
+        form gathers one view. `sel` (B, S_max) bool narrows what slot b
+        reads to the positions it is true at (an indexer's set).
+
+        `window` = W (a layer kind's, with its `leaf` and `tables`): the
+        row is scattered, and ceil(W / bp) + 1 blocks are gathered a
+        slot — those that hold positions (pos - W, pos] — and attended
+        under the band; the rest of the row is not touched, and its
+        table entries may point anywhere."""
+        if window is not None:
+            return self._window_latent_rows(
+                q, c, row, pos, write_gate, value_dim=value_dim, scale=scale,
+                layer=layer, window=window, leaf=leaf, tables=tables)
         leaf = c["latent"]
         new = _pad_lanes(row.astype(leaf.dtype)[:, None], leaf.shape[-1])
         if layer is not None and self._kernel_on(c):
@@ -374,7 +450,7 @@ class PagedKV:
 
             y, pool = paged_decode_attention(
                 q[:, None], leaf, None, c["tables"][layer], pos, layer=layer,
-                new=(new, write_gate), latent=value_dim, scale=scale,
+                new=(new, write_gate), latent=value_dim, scale=scale, sel=sel,
                 interpret=True if self.use_kernel == "interpret" else None)
             return y[:, 0], {**c, "latent": pool}
         with jax.named_scope("kv_pool.write"):
@@ -387,11 +463,48 @@ class PagedKV:
                        kv.astype(jnp.float32),
                        preferred_element_type=jnp.float32) * scale
         cols = jnp.arange(kv.shape[1])
-        s = jnp.where(cols[None, None, :] <= pos[:, None, None], s, _NEG_BIG)
+        keep = cols[None, :] <= pos[:, None]
+        if sel is not None:
+            keep = keep & sel
+        s = jnp.where(keep[:, None, :], s, _NEG_BIG)
         y = jnp.einsum("brs,bsd->brd", jax.nn.softmax(s, axis=-1),
                        kv[..., :value_dim].astype(jnp.float32),
                        preferred_element_type=jnp.float32)
         return y, c
+
+    def _window_latent_rows(self, q, c, row, pos, write_gate, *, value_dim,
+                            scale, layer, window, leaf, tables):
+        """`write_attend_latent_rows` for a window kind (see there)."""
+        bp = self.block_len
+        pool = c[leaf]
+        new = _pad_lanes(row.astype(pool.dtype)[:, None], pool.shape[-1])
+        tab = c[tables] if layer is None else c[tables][layer]  # (B, nb)
+        nb = tab.shape[1]
+        with jax.named_scope("kv_pool.write"):
+            blk = jnp.take_along_axis(tab, (pos // bp)[:, None], axis=1)[:, 0]
+            blk = jnp.where(write_gate, blk, 0)
+            at = (blk, slice(None), jnp.where(write_gate, pos % bp, 0))
+            pool = pool.at[at if layer is None else (layer,) + at].set(
+                new[:, :, 0])
+        n = min(window_blocks(window, bp), nb)
+        with jax.named_scope("kv_pool.gather"):
+            first = jnp.clip(jnp.maximum(pos - window + 1, 0) // bp, 0,
+                             nb - n)  # (B,)
+            ids = jnp.take_along_axis(
+                tab, first[:, None] + jnp.arange(n)[None, :], axis=1)
+            g = (pool if layer is None else pool[layer])[ids.reshape(-1)]
+            kv = g[:, 0].reshape(ids.shape[0], n * bp, -1)  # (B, n*bp, Dp)
+        kv = kv[..., :q.shape[-1]]
+        s = jnp.einsum("brd,bsd->brs", q.astype(kv.dtype), kv,
+                       preferred_element_type=jnp.float32) * scale
+        cols = first[:, None] * bp + jnp.arange(n * bp)[None, :]  # (B, S)
+        keep = band_keep(cols, pos[:, None], window)
+        s = jnp.where(keep[:, None, :], s, _NEG_BIG)
+        p = jax.nn.softmax(s, axis=-1)
+        y = jnp.einsum("brs,bsd->brd", p.astype(kv.dtype),
+                       kv[..., :value_dim],
+                       preferred_element_type=jnp.float32)
+        return y, {**c, leaf: pool}
 
     def index_view(self, c, index_dim, layer=None):
         """Every slot's index keys in logical order, (B, S_max, Di): what
@@ -556,11 +669,14 @@ class PagedKV:
         reserved junk block 0, whose content is never attended live (the
         per-row position mask), so scribbling it is harmless."""
         bp = self.block_len
-        out = {"tables": cache["tables"]}
-        nb_max = blk_ids.shape[0]
+        out = {kk: cache[kk] for kk in cache if is_tables(kk)}
         for kk in cache:
-            if kk == "tables":
+            if is_tables(kk):
                 continue
+            # leaves by layer kind: `blk_ids` is then {tables name: ids}
+            ids = blk_ids[self.leaf_tables[kk]] if isinstance(
+                blk_ids, dict) else blk_ids
+            nb_max = ids.shape[0]
             r = row[kk][:, 0]  # (L, H, row_len[, D]) — scales have no D
             l_, h, rl = r.shape[:3]
             rest = r.shape[3:]
@@ -568,7 +684,7 @@ class PagedKV:
             blocks = jnp.moveaxis(blocks, 2, 1)  # (L, nb_max, H, bp[, D])
             if rest:  # K/V rows go in at the pool's lane-padded width
                 blocks = _pad_lanes(blocks, cache[kk].shape[-1])
-            out[kk] = cache[kk].at[:, blk_ids].set(
+            out[kk] = cache[kk].at[:, ids].set(
                 blocks.astype(cache[kk].dtype))
         return out
 
@@ -599,6 +715,11 @@ class _PagedLayer:
 
 def codec_is_paged(cache) -> bool:
     return isinstance(cache, dict) and "tables" in cache
+
+
+def is_tables(name: str) -> bool:
+    """Whether a cache entry is a kind's block tables, not a leaf."""
+    return name.startswith("tables")
 
 
 def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):

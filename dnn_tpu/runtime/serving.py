@@ -74,7 +74,9 @@ from dnn_tpu.runtime.generate import (
     logit_bias_row,
 )
 from dnn_tpu.runtime.kvcache import codec_for_cache
-from dnn_tpu.runtime.paged_kvcache import scan_blocks
+from dnn_tpu.runtime.paged_kvcache import (
+    is_tables, scan_blocks, window_blocks,
+)
 
 
 def _decode_block_rows(bp, x, layer_cache, pos, write, *, cfg, compute_dtype,
@@ -247,6 +249,14 @@ def _admit_span(submit):
     return traced
 
 
+def _capped_pairs(start: int, t: int, cap: int) -> int:
+    """Sum over the rows i of a chunk at [start, start + t) of min(start +
+    i + 1, cap): the positions its queries read where each reads at most
+    `cap` (an indexer's topk, a window) of the start + i + 1 it could."""
+    n_all = min(max(cap - start, 0), t)  # rows that read everything
+    return n_all * start + n_all * (n_all + 1) // 2 + (t - n_all) * cap
+
+
 class ContinuousBatcher:
     """Slot-pool decode server. `slots` concurrent sequences over one
     static cache of `max_len` positions; prompts prefill in
@@ -355,8 +365,20 @@ class ContinuousBatcher:
         # V alone refuses it here, by name
         self._index_topk = getattr(self.family, "index_topk", None)
         self._latent = bool(getattr(self.family, "latent_attention", False))
+        # leaves BY LAYER KIND (models/mla.py: layers that differ in what
+        # they keep; paged_kvcache's module docstring), or None
+        self._cache_kinds = getattr(self.family, "cache_kinds", None)
+        # the layers that select: all of them, or the full kind's; and the
+        # window kinds as the step-end counters read them
+        self._n_index_layers = (self._cache_kinds["full"]["layers"]
+                                if self._cache_kinds else cfg.n_layer)
+        self._win_kinds = [(kind, k["layers"], k["window"])
+                           for kind, k in (self._cache_kinds or {}).items()
+                           if k["window"] is not None]
         if getattr(self.family, "requires_paged", False):
-            leaves = "/".join(self.family.cache_leaves)
+            leaves = "/".join(
+                n for k in self._cache_kinds.values() for n in k["leaves"]
+            ) if self._cache_kinds else "/".join(self.family.cache_leaves)
             refused = None
             if prefix_cache > 0:
                 refused = ("prefix_cache (the radix prefix store and the "
@@ -470,6 +492,9 @@ class ContinuousBatcher:
             self._grow_cache = jax.jit(pad_cache_to, static_argnums=(1,))
         self._allocator = None
         self._paged_window = None
+        self._window_kinds = {}
+        self._kind_tables = [k["tables"]
+                             for k in (self._cache_kinds or {}).values()]
         if self._paged:
             fam_window = getattr(self.family, "window", None)
             if (getattr(self.family, "softcap", None) is not None
@@ -500,13 +525,38 @@ class ContinuousBatcher:
                     f"{block_len} (prefill rows install whole blocks)")
             # pool head width follows the FAMILY's cache (GQA families
             # store KV heads — llama.LlamaFamilyRows sets kv_heads)
+            # a window kind's slot holds its window's blocks whatever its
+            # length: its leaves are sized for every slot's, and no more
+            self._window_kinds = {
+                k["tables"]: k["window"]
+                for k in (self._cache_kinds or {}).values()
+                if k["window"] is not None}
+            if "tables" in self._window_kinds:
+                raise ValueError("the first layer kind keeps every "
+                                 "position: a window kind comes after it")
+            kind_blocks = {
+                t: slots * min(window_blocks(w, block_len),
+                               self.max_len // block_len) + 1
+                for t, w in self._window_kinds.items()}
             self.cache = init_paged_cache(
-                cfg, slots, self.max_len, n_blocks=paged_blocks,
+                cfg, slots, self.max_len,
+                n_blocks={"tables": paged_blocks, **kind_blocks}
+                if self._cache_kinds else paged_blocks,
                 block_len=block_len, dtype=cache_dtype,
                 kv_heads=getattr(self.family, "kv_heads", None),
-                leaves=getattr(self.family, "cache_leaves", None))
-            self._allocator = BlockAllocator(paged_blocks)
+                leaves=getattr(self.family, "cache_leaves", None),
+                kinds=self._cache_kinds)
+            self._allocator = BlockAllocator(paged_blocks, kinds=kind_blocks)
             self._block_len = block_len
+            # table entries of window kinds to set before the next
+            # dispatch: (tables name, slot, logical block, physical)
+            self._wtab_pending: list = []
+            self.window_blocks_freed = 0
+
+            def set_tables(tab, slot_ix, blk_ix, vals):
+                return tab.at[:, slot_ix, blk_ix].set(vals, mode="drop")
+
+            self._set_tables = jax.jit(set_tables, donate_argnums=(0,))
             # the family's attn_kernel policy routes paged decode through
             # the fused flash-decode kernel (paged_decode_attention): the
             # "auto" ladder rung for block pools — TPU + long slots
@@ -514,7 +564,8 @@ class ContinuousBatcher:
             # gather_view einsum (PagedKV._kernel_on)
             codec = PagedKV(block_len, window=fam_window,
                             use_kernel=getattr(self.family, "attn_kernel",
-                                               False))
+                                               False),
+                            kinds=self._cache_kinds)
 
             head_dim = cache_head_dim(cfg)  # init_paged_cache's
 
@@ -527,7 +578,7 @@ class ContinuousBatcher:
                 blocks (…, bp) alike."""
                 out = {}
                 for kk in cache:
-                    if kk == "tables":
+                    if is_tables(kk):
                         continue
                     g = jnp.take(cache[kk], ids_row, axis=1)
                     if g.ndim == 5:  # K/V: drop the pool's lane padding
@@ -674,11 +725,11 @@ class ContinuousBatcher:
 
         pool_ref = weakref.ref(self)
 
-        def _weak_gauge(method_name):
+        def _weak_gauge(method_name, *args):
             def read():
                 pool = pool_ref()
-                return getattr(pool, method_name)() if pool is not None \
-                    else 0.0
+                return getattr(pool, method_name)(*args) \
+                    if pool is not None else 0.0
             return read
 
         self._obs_gauges = {
@@ -723,6 +774,15 @@ class ContinuousBatcher:
                 "serving.paged_blocks_high_water":
                     _weak_gauge("_paged_hw_read"),
             })
+        if self._paged and self._cache_kinds:
+            # the pool BY LAYER KIND: blocks a kind holds now, and what
+            # its window kinds handed back while their requests ran
+            for kind, k in self._cache_kinds.items():
+                self._obs_gauges[labeled(
+                    "kv_pool.blocks_in_use", kind=kind)] = _weak_gauge(
+                        "_kind_used_read", k["tables"])
+            self._obs_gauges["kv_pool.window_blocks_freed_total"] = \
+                _weak_gauge("_window_freed_read")
         self.results: Dict[int, np.ndarray] = {}
         self.finish_reasons: Dict[int, str] = {}
         self.token_logprobs: Dict[int, dict] = {}
@@ -981,7 +1041,9 @@ class ContinuousBatcher:
             penalty applies to the FIRST sample too. `blocks` (2, nb_max)
             (paged mode; (2, 0) for a dense pool): the slot's table row,
             and the per-logical-block physical install targets — shared
-            prefix blocks routed to junk block 0."""
+            prefix blocks routed to junk block 0. A pool whose leaves are
+            by layer kind has two such rows a kind (a window kind's: the
+            blocks of its window at the prompt's end, junk elsewhere)."""
             slot, last_local, prompt_len, k, c_row = (
                 ints[i] for i in range(5))
             words = lax.bitcast_convert_type(ints[5:7], jnp.uint32)
@@ -1008,7 +1070,14 @@ class ContinuousBatcher:
             # nothing but tail-pad garbage (real prompt tokens always fit:
             # submit() bounds the prompt by max_len and, on a bucketed
             # pool, grows the pool past the prompt before finishing)
-            if self._paged:
+            if self._paged and self._cache_kinds:
+                # two rows of `blocks` a kind, in `_kind_tables`' order
+                names = self._kind_tables
+                cache = codec.install_row(cache, row, {
+                    name: blocks[2 * i + 1] for i, name in enumerate(names)})
+                for i, name in enumerate(names):
+                    cache[name] = cache[name].at[:, slot].set(blocks[2 * i])
+            elif self._paged:
                 cache = codec.install_row(cache, row, blocks[1])
                 cache["tables"] = cache["tables"].at[:, slot].set(blocks[0])
             else:
@@ -1519,6 +1588,7 @@ class ContinuousBatcher:
 
         paged_taken, blocks, n_shared = None, self._no_blocks, 0
         cow_src, cow_tok = -1, 0
+        w_taken = {}
         if self._paged:
             from dnn_tpu.runtime.paged_kvcache import InsufficientBlocks
 
@@ -1591,14 +1661,36 @@ class ContinuousBatcher:
                 if ref_ids:
                     self._allocator.free(ref_ids)
                 raise
+            # a window kind's blocks: those its window and the next write
+            # touch at the prompt's end, drawn now so that admission by
+            # actual length counts every kind
+            w_taken = {}
+            for t, w in self._window_kinds.items():
+                lo = max(0, len(prompt) - w + 1) // bp
+                n_w = min(n_need - lo, window_blocks(w, bp))
+                ids = self._allocator.of(t).alloc(n_w)
+                if ids is None:
+                    for t2, got in w_taken.items():
+                        self._allocator.of(t2).free(list(got.values()))
+                    self._allocator.free(ref_ids + owned)
+                    raise InsufficientBlocks(
+                        f"insufficient free cache blocks of the kind under "
+                        f"{t}: need {n_w}, have "
+                        f"{self._allocator.of(t).n_free}")
+                w_taken[t] = dict(zip(range(lo, lo + n_w), ids))
             self._pool_exhausted_episode = False  # blocks came free
             paged_taken = shared_ids + owned
             # the slot's table row, and under it the install targets:
             # both reach the device with the finish program, which writes
             # the row into the tables (nothing reads a slot's row before
             # its first decode step)
-            blocks = np.zeros((2, self.cache["tables"].shape[-1]), np.int32)
-            blocks[:, :n_need] = paged_taken
+            blocks = np.zeros((2 * max(1, len(self._kind_tables)),
+                               self.cache["tables"].shape[-1]), np.int32)
+            blocks[:2, :n_need] = paged_taken
+            for t, got in w_taken.items():
+                i = self._kind_tables.index(t)
+                for j, b in got.items():
+                    blocks[2 * i:2 * i + 2, j] = b
             if cow_tok > 0:
                 # copy-on-write at the divergence boundary: duplicate
                 # the ONE cached block this prompt still partially
@@ -1893,6 +1985,10 @@ class ContinuousBatcher:
                    "stop": stop_seqs, "logprobs": logprobs and self._logprobs_k,
                    "blocks": paged_taken, "prompt_len": len(prompt),
                    "freed": 0}
+            if w_taken:
+                # a window kind's blocks, {logical: physical}; the very
+                # dicts the failure path below would free
+                req["wblocks"] = w_taken
             if use_radix:
                 # retire-time store insertion needs the token ids and
                 # the per-block provenance (adopted blocks re-inserted
@@ -1929,6 +2025,7 @@ class ContinuousBatcher:
                 self._constraint_advance(slot, first)
             # a prompt longer than the window rolls blocks out at install
             self._free_rolled_blocks(slot)
+            self._flush_window_tables()
             self._retire_if_done(slot, in_step=False)
             return rid
         except BaseException:
@@ -1947,6 +2044,9 @@ class ContinuousBatcher:
                 self._allocator.free(paged_taken[skip:])
                 self.cache["tables"] = \
                     self.cache["tables"].at[:, slot].set(0)
+                for t, got in w_taken.items():  # (rolled in place)
+                    self._allocator.of(t).free(list(got.values()))
+                    self.cache[t] = self.cache[t].at[:, slot].set(0)
             # the slot was free at entry, so it must end inactive on ANY
             # failure — active may have been set before the req landed,
             # and a True-active/None-req slot would spin drain() forever
@@ -1997,14 +2097,14 @@ class ContinuousBatcher:
             # start and length alone (pad rows too: the device scores
             # them): row t reads min(start + t + 1, topk) of start + t + 1
             start, t = int(args[3]), int(args[2].shape[-1])
-            k = self._index_topk
-            n_all = min(max(k - start, 0), t)  # rows that read everything
+            n_sel = _capped_pairs(start, t, self._index_topk)
             self.step_clock.note_dsa(
-                "prefill", self.cfg.n_layer,
-                self.cfg.n_layer * (t * start + t * (t + 1) // 2),
-                self.cfg.n_layer * (
-                    n_all * start + n_all * (n_all + 1) // 2
-                    + (t - n_all) * k))
+                "prefill", self._n_index_layers,
+                self._n_index_layers * (t * start + t * (t + 1) // 2),
+                self._n_index_layers * n_sel)
+            if self._cache_kinds:
+                self.step_clock.note_mla_kind(
+                    "prefill", "full", self._n_index_layers * n_sel)
         if self._latent and self.step_clock is not None:
             # the cached latents the chunk's layers attend (everything
             # before it and itself) and its causal (query, position)
@@ -2013,6 +2113,10 @@ class ContinuousBatcher:
             self.step_clock.note_mla(
                 "prefill", self.cfg.n_layer, self.cfg.n_layer * (start + t),
                 self.cfg.n_layer * (t * start + t * (t + 1) // 2))
+            for kind, n_l, w in self._win_kinds:
+                # (query, position) pairs within the band
+                self.step_clock.note_mla_kind(
+                    "prefill", kind, n_l * _capped_pairs(start, t, w))
         return res[0], res[1]
 
     def _moe_note(self, program: str, stats, idx: Optional[int] = None):
@@ -2610,6 +2714,8 @@ class ContinuousBatcher:
         admits. No-op for dense/unwindowed pools."""
         w = self._paged_window
         req = self._slot_req[slot]
+        if req is not None and req.get("wblocks"):
+            self._roll_window_blocks(slot, req)
         if w is None or req is None or not req["blocks"]:
             return
         bp = self._block_len
@@ -2623,6 +2729,57 @@ class ContinuousBatcher:
         self.cache["tables"] = \
             self.cache["tables"].at[:, slot, freed:n_dead].set(0)
         req["freed"] = n_dead
+
+    def _roll_window_blocks(self, slot: int, req):
+        """A WINDOW KIND's blocks follow the slot (paged_kvcache's module
+        docstring): the next step's query stands at `limit` and reads
+        (limit - W, limit], so a block wholly at or before limit - W goes
+        back to the allocator and its table entry to junk block 0, and
+        the blocks ahead, up to the kind's quota, are drawn (never more
+        than were just handed back or held in reserve since admission:
+        the draw cannot fail). The table edits reach the device before
+        the next dispatch (`_flush_window_tables`)."""
+        bp = self._block_len
+        limit = req["prompt_len"] + len(req["emitted"]) - 1
+        n_need = len(req["blocks"])
+        for t, w in self._window_kinds.items():
+            got = req["wblocks"][t]
+            lo = max(0, limit - w + 1) // bp
+            dead = [j for j in got if j < lo]
+            if not dead:
+                continue
+            alloc = self._allocator.of(t)
+            alloc.free([got.pop(j) for j in dead])
+            self.window_blocks_freed += len(dead)
+            self._wtab_pending += [(t, slot, j, 0) for j in dead]
+            quota = min(n_need - lo, window_blocks(w, bp))
+            nxt = max(got, default=lo - 1) + 1
+            n_new = min(quota - len(got), n_need - nxt)
+            if n_new > 0:
+                for j, b in zip(range(nxt, nxt + n_new), alloc.alloc(n_new)):
+                    got[j] = b
+                    self._wtab_pending.append((t, slot, j, b))
+            self._pool_exhausted_episode = False  # blocks came free
+
+    def _flush_window_tables(self):
+        """The pending table edits of window kinds, one program a kind:
+        padded to a fixed count (the pad's slot index is out of range and
+        is dropped), so nothing compiles after the first."""
+        if not self._window_kinds or not self._wtab_pending:
+            return
+        pend, self._wtab_pending = self._wtab_pending, []
+        n = 4 * self.slots
+        for t in self._window_kinds:
+            mine = [e[1:] for e in pend if e[0] == t]
+            for i in range(0, len(mine), n):
+                part = np.full((3, n), self.slots, np.int32)
+                part[:, :len(mine[i:i + n])] = np.asarray(mine[i:i + n]).T
+                self.cache[t] = self._set_tables(self.cache[t], *part)
+
+    def _free_window_kinds(self, req):
+        for t, got in (req.get("wblocks") or {}).items():
+            self._allocator.of(t).free(list(got.values()))
+            got.clear()
 
     def _constraint_advance(self, slot: int, token: int):
         """HOST MIRROR of the device DFA walk, for finish detection
@@ -2713,6 +2870,8 @@ class ContinuousBatcher:
         bp = self._block_len if self._paged else 0
         topk = self._index_topk
         picked = 0  # by an indexer, of the live - n_act it scored
+        wins = self._win_kinds
+        in_window = [0] * len(wins)
         for r in self._slot_req:
             if r is not None:
                 n = r["prompt_len"] + len(r["emitted"])
@@ -2723,11 +2882,19 @@ class ContinuousBatcher:
                 if topk:
                     # the step's query stood at n - 2: n - 1 candidates
                     picked += min(n - 1, topk)
+                for i, (_, _, w) in enumerate(wins):
+                    in_window[i] += min(n - 1, w)
         if topk and self.step_clock is not None:
             self.step_clock.note_dsa(
-                "decode", self.cfg.n_layer,
-                self.cfg.n_layer * (live - n_act),
-                self.cfg.n_layer * picked)
+                "decode", self._n_index_layers,
+                self._n_index_layers * (live - n_act),
+                self._n_index_layers * picked)
+        if self._cache_kinds and self.step_clock is not None:
+            if topk:
+                self.step_clock.note_mla_kind(
+                    "decode", "full", self._n_index_layers * picked)
+            for (kind, n_l, _), n in zip(wins, in_window):
+                self.step_clock.note_mla_kind("decode", kind, n_l * n)
         if self._latent and self.step_clock is not None:
             # each live slot's query stood at n - 2 and read n - 1 latents
             self.step_clock.note_mla(
@@ -2852,6 +3019,12 @@ class ContinuousBatcher:
     def _paged_hw_read(self) -> float:
         return float(self._allocator.high_water)
 
+    def _kind_used_read(self, tables: str) -> float:
+        return float(self._allocator.of(tables).n_used)
+
+    def _window_freed_read(self) -> float:
+        return float(self.window_blocks_freed)
+
     def _obs_retire(self, req, reason: str):
         """Close a leaving request's decode span + outcome counter +
         flight event — the one block _retire_if_done and cancel share."""
@@ -2930,6 +3103,7 @@ class ContinuousBatcher:
         if req["blocks"]:
             # windowed pools already reclaimed the rolled-out prefix
             self._allocator.free(req["blocks"][req["freed"]:])
+            self._free_window_kinds(req)
             self._pool_exhausted_episode = False  # blocks came free
         sp = _profile.open_span("step.commit.retire", rid=rid, slot=slot) \
             if in_step else None
@@ -3011,6 +3185,7 @@ class ContinuousBatcher:
                                        if s != slot]
                 if req["blocks"]:
                     self._allocator.free(req["blocks"][req["freed"]:])
+                    self._free_window_kinds(req)
                     self._pool_exhausted_episode = False  # blocks came free
                 self._release_slot_constraint(slot, req)
                 self._slot_req[slot] = None
@@ -3130,6 +3305,7 @@ class ContinuousBatcher:
                 n_adv += len(committed)
                 out[req["rid"]] = (committed[0] if len(committed) == 1
                                    else committed)
+        self._flush_window_tables()
         if rec is not None:
             sc.mark(rec, "commit")
         self._obs_step_end(m, n_adv, it_samples)

@@ -73,6 +73,11 @@ def _read_by(config_name):
         cell = cells.resolve(w["name"], rehearse=True)
         facts = {"metrics0": series, "metrics1": series, "stepz": stepz,
                  "config": cell["config"], "client": {}}
+        # the prefixes the configuration itself declares (what its
+        # `scopes:share_pct` readers divide the step among), beside those
+        # a metric's file names
+        scopes.update(cell["config"].get("trace", {}).get("known_scopes")
+                      or ())
         for reader, args in cell["per_layer"].values():
             for key in ("series", "num", "den"):
                 series.keys_asked.update(_as_list(args.get(key)))
