@@ -37,8 +37,9 @@ Two forms of the same numbers, each where it is cheaper:
     the pool gets): up-projected K and V would be H (dn + dr + dv) values
     a position, 20 KB in bfloat16 at the published widths, 1.7 GB for a
     16 k row of 5 layers. The up-projection runs over the smallest of
-    `_PREFIXES` equal-step prefixes of the row that holds the chunk's
-    context (one compiled program, the prefix chosen as it runs).
+    at most `_PREFIXES` prefixes of the row that holds the chunk's context
+    (one compiled program, the prefix chosen as it runs), cut at multiples
+    of the kernel's full column tile (`prefix_lengths`).
 
 RoPE pairs dimensions (2i, 2i + 1) when `rope_interleave` (DeepSeek-V3's
 checkpoints); here the rotary part is de-interleaved first and rotated in
@@ -92,6 +93,7 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import functools
+import logging
 from typing import Optional
 
 import jax
@@ -103,9 +105,30 @@ from dnn_tpu.ops.attention import apply_rope, rope_cos_sin
 from dnn_tpu.ops.nn import linear, rms_norm
 
 __all__ = ["MlaConfig", "init_attn", "project", "dense_attn",
-           "MlaFamilyRows"]
+           "prefix_lengths", "MlaFamilyRows"]
+
+log = logging.getLogger(__name__)
 
 _PREFIXES = 8
+
+
+def prefix_lengths(s_len, t):
+    """The prefixes of a transient row of `s_len` positions over which a
+    chunk of `t` queries is attended, shortest first; the chunk whose last
+    position is p takes number p // lengths[0]. At most `_PREFIXES`, in
+    equal steps, the last the whole row — and the step a multiple of the
+    kernel's full column tile (ops/pallas/mla_attention.py `BLOCK_S`), not
+    `s_len // _PREFIXES`: a prefix the tile does not divide is attended in
+    128- or 256-column steps (a row of 13 312 cut in eighths of 1664 = 13
+    x 128 ran half its pairs in the former, a fifth in the latter), at 3.2
+    and 1.7 times a full tile's time a pair on a v5e (PERF.md section 5,
+    PR 42). A step is also at least the chunk, so that no prefix is
+    shorter than the queries it would hold. The up-projection covers at
+    most one step more than the context, as it did."""
+    from dnn_tpu.ops.pallas.mla_attention import BLOCK_S
+
+    step = -(-max(-(-s_len // _PREFIXES), t) // BLOCK_S) * BLOCK_S
+    return [min(n, s_len) for n in range(step, s_len + step, step)]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -418,6 +441,10 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
             self.cache_kinds = None
         # what `dsa_*` counters and /statusz report for a selecting kind
         self.index_topk = self.kinds["full"].index_topk
+        # kind -> {columns handed to the prefill kernel: what a grid step
+        # of that call covers}, said while the chunk programs were traced
+        # (/statusz `components.attention.mla_prefill`)
+        self.prefill_steps = {kind: {} for kind in self.kinds}
 
     def init_cache(self, batch, max_len, dtype):
         if dtype in ("int8", "int4"):
@@ -434,13 +461,42 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
                                      m.index_head_dim), dtype)
         return out
 
+    def _attend(self, kind, ap, q_nope, q_rope, rows, start, sel=None):
+        """`_chunk_attn` for a layer of `kind` over the cached `rows`;
+        what a grid step of its kernel covers (`grid_step` of the call's
+        shapes; None each for the plain form) is kept in
+        `self.prefill_steps[kind]` by the rows' count, and logged where it
+        is first said."""
+        from dnn_tpu.ops.pallas.mla_attention import grid_step
+
+        m = self.kinds[kind]
+        interpret = True if self.attn_kernel == "interpret" else None
+        step = None
+        if interpret or jax.default_backend() == "tpu":
+            step = grid_step(
+                q_nope.shape[1], len(q_nope), len(rows), m.qk_nope_head_dim,
+                m.qk_rope_head_dim, m.v_head_dim, q_nope.dtype.itemsize,
+                select=sel is not None)
+        step = dict(zip(("heads_per_step", "block_q", "block_s"),
+                        step or (None,) * 3))
+        if self.prefill_steps[kind].get(len(rows)) != step:
+            self.prefill_steps[kind][len(rows)] = step
+            log.info("mla prefill, %s layers over %d columns: a grid step "
+                     "of %s", kind, len(rows), step)
+        return _chunk_attn(
+            ap, q_nope, q_rope, rows, start, cfg=self.cfg,
+            compute_dtype=self.compute_dtype, m=m, sel=sel,
+            interpret=interpret)
+
     def _chunk_block(self, bp, x, rows, start_pos, ffn, kind="full"):
         """One block over a prefill chunk x (1, T, C) at [start_pos,
         start_pos + T): the chunk's cache rows written into the layer's
         transient rows `rows` {leaf: (1, 1, S, width)}, attention
         up-projected over the smallest prefix of the row that holds the
-        context — or, for a kind with a window, over the window and the
-        chunk."""
+        context (`prefix_lengths`: cut where the kernel's full column
+        tile divides them, one branch of a switch each) — or, for a kind
+        with a window, over the window and the chunk. What a grid step of
+        each kernel call covers goes to `self.prefill_steps` (`_attend`)."""
         cfg, compute_dtype = self.cfg, self.compute_dtype
         m = self.kinds[kind]
         latent, ik, _ = KIND_LEAVES[kind]
@@ -486,27 +542,26 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
                         m.index_topk, start_pos + t)
 
             def over(n):
-                return lambda: _chunk_attn(
-                    ap, q_nope[0], q_rope[0], lat[:n], start_pos, cfg=cfg,
-                    compute_dtype=compute_dtype, interpret=interpret, m=m,
-                    sel=None if sel is None else sel[:, :n])
+                return lambda: self._attend(
+                    kind, ap, q_nope[0], q_rope[0], lat[:n], start_pos,
+                    None if sel is None else sel[:, :n])
 
-            step = s_len // _PREFIXES
+            lengths = prefix_lengths(s_len, t)
             back = 0 if m.window is None else -(-(m.window - 1) // t) * t
             if m.window is not None and back + t < s_len:
                 # the window's positions before the chunk, and the chunk
                 first = jnp.clip(start_pos - back, 0, s_len - back - t)
-                y = _chunk_attn(
-                    ap, q_nope[0], q_rope[0],
+                y = self._attend(
+                    kind, ap, q_nope[0], q_rope[0],
                     lax.dynamic_slice_in_dim(lat, first, back + t),
-                    start_pos - first, cfg=cfg, compute_dtype=compute_dtype,
-                    interpret=interpret, m=m)
-            elif s_len % _PREFIXES or step < t:
+                    start_pos - first)
+            elif len(lengths) == 1:
                 y = over(s_len)()
             else:
                 y = lax.switch(
-                    jnp.clip((start_pos + t - 1) // step, 0, _PREFIXES - 1),
-                    [over(step * (i + 1)) for i in range(_PREFIXES)])
+                    jnp.clip((start_pos + t - 1) // lengths[0], 0,
+                             len(lengths) - 1),
+                    [over(n) for n in lengths])
             y = gated(ap, h, y[None].astype(x.dtype),
                       compute_dtype=compute_dtype)
             o = linear(ap["o"], y.reshape(1, t, -1),

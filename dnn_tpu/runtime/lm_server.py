@@ -1242,6 +1242,21 @@ class LMServer:
         forms = getattr(getattr(family, "ffn", None), "expert_forms", None)
         if forms:
             comps["weights"]["moe_experts"] = "+".join(sorted(forms))
+        # what a grid step of the latent prefill kernel covers in the
+        # chunk programs built so far, by layer kind and by the columns a
+        # call was handed (models/mla.py `prefix_lengths`): said while
+        # they were traced, as `moe_experts` is — a `block_s` under the
+        # full tile, or None (the plain form), shows without a capture
+        steps = getattr(family, "prefill_steps", None)
+        if steps and any(steps.values()):
+            comps["attention"] = {
+                "detail": "what a grid step of the built prefill programs' "
+                          "latent attention kernel covers",
+                "mla_prefill": {
+                    # a copy: a chunk program traced now adds an entry
+                    kind: [{"columns": n, **step}
+                           for n, step in sorted(dict(by_columns).items())]
+                    for kind, by_columns in steps.items()}}
         # facts, no `state`: the KV cache's bytes leaf by leaf (K, V, an
         # int8 pool's scales, a selecting model's index keys "ik"), from
         # shapes alone

@@ -484,6 +484,33 @@ def test_statusz_says_how_the_expert_layers_meet_their_matrices(served,
     assert weights.get("moe_experts") == ("ragged_dot" if moe else None)
 
 
+def _latent(config):
+    return any(s.startswith("mla_") for s in _SERVED[config]["series"])
+
+
+@pytest.mark.parametrize("config", sorted(_SERVED))
+def test_statusz_says_what_a_prefill_kernel_step_covers(served, config):
+    """`/statusz` `components.attention.mla_prefill`: by layer kind, for
+    every count of columns the built chunk programs hand the latent
+    prefill kernel, the heads, rows and columns one grid step covers —
+    said while they were traced. On the CPU the plain form runs and the
+    three are None (on the chip `block_s` 512 and `heads_per_step` above
+    1, or a daemon that fell to a narrow tile shows it without a
+    capture); a model without latent attention has no such component."""
+    comps = served(config)["statusz"]["components"]
+    if not _latent(config):
+        assert "attention" not in comps
+        return
+    kinds = comps["attention"]["mla_prefill"]
+    assert "full" in kinds and all(kinds.values())
+    for calls in kinds.values():
+        for call in calls:
+            assert sorted(call) == ["block_q", "block_s", "columns",
+                                    "heads_per_step"]
+            assert call["columns"] > 0
+            assert call["heads_per_step"] is call["block_s"] is None
+
+
 # ----------------------------------------------------------------------
 # program names and scope prefixes: the lowered step programs
 # ----------------------------------------------------------------------

@@ -628,6 +628,25 @@ def _mla_cases():
     def prefill_attention_short(dt):
         return prefill_attention(dt, s=2048)
 
+    # dots3's two kinds (PR 42: a group of heads a grid step, sized from
+    # these shapes): a full layer's 128 heads under the indexer's set over
+    # the whole 13 312-position row, a sliding layer's 64 heads of a
+    # 192-wide key under a band of 513 over window + chunk
+    def prefill_attention_selected(dt, h=128, s=13312):
+        return (lambda qn, qr, kn, kr, v, st, sel: ma.mla_prefill_attention(
+            qn, qr, kn, kr, v, st, scale=(dn + dr) ** -0.5, sel=sel,
+            interpret=False),
+            [((h, t, dn), dt), ((h, t, dr), dt), ((h, s, dn), dt),
+             ((s, dr), dt), ((h, s, dv), dt), ((), jnp.int32),
+             ((t, s), jnp.bool_)])
+
+    def prefill_attention_banded(dt, h=64, s=2048, dn=192):
+        return (lambda qn, qr, kn, kr, v, st: ma.mla_prefill_attention(
+            qn, qr, kn, kr, v, st, scale=(dn + dr) ** -0.5, window=513,
+            interpret=False),
+            [((h, t, dn), dt), ((h, t, dr), dt), ((h, s, dn), dt),
+             ((s, dr), dt), ((h, s, dv), dt), ((), jnp.int32)])
+
     def paged_decode_latent(dt):
         pool = ((n_layer, b * nb + 1, 1, bp, width), dt)
 
@@ -642,12 +661,16 @@ def _mla_cases():
 
     return {"prefill_attention": prefill_attention,
             "prefill_attention_short": prefill_attention_short,
+            "prefill_attention_selected": prefill_attention_selected,
+            "prefill_attention_banded": prefill_attention_banded,
             "paged_decode_latent": paged_decode_latent}
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
 @pytest.mark.parametrize("kernel", ["prefill_attention",
                                     "prefill_attention_short",
+                                    "prefill_attention_selected",
+                                    "prefill_attention_banded",
                                     "paged_decode_latent"])
 def test_latent_attention_kernels_compile(chip, kernel, dtype):
     fn, shapes = _mla_cases()[kernel](dtype)

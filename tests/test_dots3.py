@@ -247,6 +247,29 @@ def test_prefill_kernel_bands_and_selects(start, window, select):
     assert float(jnp.abs(a - b).max()) < 1e-5
 
 
+def test_every_branch_of_the_cells_row_is_handed_whole_column_tiles(
+        model, monkeypatch):
+    """`dots3-longnote-saturated` serves rows of 13 x `prompt_pad` (13 312
+    = 13 x 1024, a full column tile 512 = half a chunk), which eighths of
+    the row (1664 = 13 x 128) cut where only 128-column tiles fit. The
+    same row at 1/128: 13 chunks of 8 under a tile of 4. Every branch the
+    chunk program builds for a full layer — traced, not run — is handed a
+    count of columns the tile divides, seven of them, the last the whole
+    row; a window layer is handed the window and the chunk."""
+    assert mla.prefix_lengths(13312, 1024) == [
+        2048, 4096, 6144, 8192, 10240, 12288, 13312]
+    assert not any(n % ma.BLOCK_S for n in mla.prefix_lengths(13312, 1024))
+    spec, cfg, params = model
+    fam = spec.extras["family_rows"]()
+    monkeypatch.setattr(ma, "BLOCK_S", 4)
+    prepared = prepare_stacked(dict(params), cfg)
+    jax.eval_shape(fam.prefill, prepared, jnp.zeros((1, 8), jnp.int32),
+                   fam.init_cache(1, 13 * 8, jnp.float32), 40)
+    handed = sorted(fam.prefill_steps["full"])
+    assert handed == [16, 32, 48, 64, 80, 96, 104]  # each 4 x a whole number
+    assert sorted(fam.prefill_steps["window"]) == [8 + 8]  # window 9
+
+
 def test_latent_decode_kernel_reads_the_selected_positions():
     rng = np.random.default_rng(3)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731

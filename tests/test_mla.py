@@ -149,13 +149,23 @@ def test_chunked_prefill_and_paged_decode_match_the_reference(model,
         assert np.abs(b.token_logprobs[rid]["chosen"] - chosen).max() < TOL
 
 
-def test_chunks_of_unequal_size_then_decode_match_the_reference(model):
+@pytest.mark.parametrize("column_tile", [None, 8],
+                         ids=["whole_row", "prefix_switch"])
+def test_chunks_of_unequal_size_then_decode_match_the_reference(
+        model, monkeypatch, column_tile):
     """The family's own programs, no batcher: chunks of 16, 8 and 24
     tokens into a transient row, the row installed into the paged pool,
     then decode steps — each step's logits the reference's at that
-    position (contexts over seven blocks of 8)."""
+    position (contexts over seven blocks of 8). At the kernel's own
+    column tile a row of 64 is one prefix; at a tile of 8 the chunks go
+    through the switch over prefixes of 16 and 8 and 24 positions a step
+    (`mla.prefix_lengths`), each a multiple of the tile."""
     spec, cfg, params = model
     fam = spec.extras["family_rows"]()
+    if column_tile:
+        monkeypatch.setattr(ma, "BLOCK_S", column_tile)
+        assert mla.prefix_lengths(64, 8) == list(range(8, 72, 8))
+        assert mla.prefix_lengths(64, 24) == [24, 48, 64]
     prepared = prepare_stacked(dict(params), cfg)
     seq = _ids(56, 8)
     want = ref.forward(cfg, params, jnp.asarray(seq))
@@ -167,6 +177,8 @@ def test_chunks_of_unequal_size_then_decode_match_the_reference(model):
         logits = fam.head(prepared, hidden)  # a chunk ends at the last block
         assert float(jnp.abs(logits[0] - want[start:start + n]).max()) < TOL
         start += n
+    assert sorted(fam.prefill_steps["full"]) == (
+        [8, 16, 24, 32, 40, 48, 56, 64] if column_tile else [64])
     codec = PagedKV(8)
     cache = init_paged_cache(cfg, 2, 64, n_blocks=17, dtype=jnp.float32,
                              block_len=8, leaves=fam.cache_leaves)
@@ -181,6 +193,73 @@ def test_chunks_of_unequal_size_then_decode_match_the_reference(model):
             codec)
         assert float(jnp.abs(logits[1] - want[t]).max()) < TOL, t
         pos = pos.at[1].add(1)
+
+
+# what one grid step of the prefill kernel covers (G heads x bq rows x bs
+# columns), at the published widths' lane structure (value 128 wide, column
+# tiles of 128: the softmax state lane by lane), interpreted. S = 512 in
+# four column tiles (without a mask two of 256: two lane tiles folded into
+# the state, and tiles wholly under the diagonal), T = 32 in two query
+# tiles; for the window kind's own call S = window + chunk, rounded to
+# chunks, and `start` the first query's column. "mid" = 159: the first
+# query tile's first row reads from column 120 of tile 0, its last row
+# from column 135 of tile 1 — tile 0 holds no kept position for it, as
+# tiles 0 and 1 hold none for the odd rows of `sel`: the all-masked-so-far
+# case. mask -> (S, bs, window, a set)
+_STEP_T, _STEP_W = 32, 40
+_STEP_MASKS = {"none": (512, 256, None, False),
+               "window": (512, 128, _STEP_W, False),
+               "sel": (512, 128, None, True),
+               "window_and_chunk": (64 + _STEP_T, 32, _STEP_W, False)}
+
+
+@pytest.mark.parametrize("start", ["first", "mid", "last"])
+@pytest.mark.parametrize("dn", [128, 192])
+@pytest.mark.parametrize("heads", [4, 6, 1])
+@pytest.mark.parametrize("mask", sorted(_STEP_MASKS))
+def test_prefill_kernel_grid_step(mask, heads, dn, start):
+    s_len, bs, window, select = _STEP_MASKS[mask]
+    t, dr, dv = _STEP_T, 64, 128
+    start = {"first": 0, "mid": min(159, (s_len - t) // 2),
+             "last": s_len - t}[start]
+    rng = np.random.default_rng(heads * 1000 + dn + start)
+    f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    args = (f(heads, t, dn), f(heads, t, dr), f(heads, s_len, dn),
+            f(s_len, dr), f(heads, s_len, dv))
+    sel = None
+    if select:
+        rows, cols = start + np.arange(t)[:, None], np.arange(s_len)[None, :]
+        sel = (rng.random((t, s_len)) < 0.3) & (
+            (cols >= 2 * bs) | (rows % 2 == 0))
+        sel = jnp.asarray((sel | (cols == rows)) & (cols <= rows))
+    # `grid_step`'s budget: at the smallest VMEM limit at which four heads
+    # fit a step, six heads go three a step (the largest divisor that
+    # fits) and one goes alone; the kernel is then run at that step
+    shape = (t, s_len, dn, dr, dv, 4)
+    kw = dict(block_q=16, block_s=bs, select=select)
+    limit = next(n for n in range(1 << 14, 1 << 26, 1 << 12)
+                 if ma.grid_step(4, *shape, vmem_limit_bytes=n, **kw)[0] == 4)
+    step = ma.grid_step(heads, *shape, vmem_limit_bytes=limit, **kw)
+    assert step == ({4: 4, 6: 3, 1: 1}[heads], 16, bs)
+    a = ma.reference_mla_prefill_attention(*args, start, scale=0.1,
+                                           window=window, sel=sel)
+    b = ma._tiled(*args, start, sel, scale=0.1, step=step, window=window,
+                  interpret=True)
+    assert float(jnp.abs(a - b).max()) < 2e-5
+
+
+def test_a_column_tile_that_does_not_divide_falls_to_one_that_does():
+    """`grid_step`: the widest of `block_s`, its half, ... down to 128
+    that divides S; no tile for an S that 128 does not divide nor for a T
+    that `block_q` does not: the plain form then runs."""
+    widths = (128, 64, 128, 2)
+    assert ma.grid_step(32, 1024, 13312, *widths)[1:] == (512, 512)
+    assert ma.grid_step(32, 1024, 8320, *widths)[1:] == (512, 128)
+    assert ma.grid_step(32, 1024, 9984, *widths)[1:] == (512, 256)
+    assert ma.grid_step(32, 1024, 1000, *widths) is None
+    assert ma.grid_step(32, 1000, 2048, *widths) is None
+    assert ma.grid_step(2, 8, 24, 16, 8, 16, 4, block_q=8,
+                        block_s=16) is None
 
 
 @pytest.mark.parametrize("start", [0, 32, 96])
