@@ -352,6 +352,33 @@ def layer_windows(cfg: LlamaConfig):
          for i in range(cfg.n_layer)], jnp.int32)
 
 
+@dataclasses.dataclass(frozen=True)
+class KvKind:
+    """One KIND of K/V attention layer of a model whose layers differ
+    (`MixtralConfig.kv_full` / `kv_window` with `layer_types`, models/
+    llama_moe.py): `window` = W makes a query at t read t - W < u <= t
+    only (`band_keep`), and its cache leaf hold the window's blocks;
+    `rope` off leaves q and k unrotated (position then reaches the layer
+    through the mask alone). Everything else of the block is the
+    config's."""
+    window: Optional[int] = None
+    rope: bool = True
+
+
+# a K/V kind's cache leaves and block tables, by name (models/mla.py
+# `KIND_LEAVES` is the latent family's)
+KV_KIND_LEAVES = {"full": ("k", "v", "tables"),
+                  "window": ("k_w", "v_w", "tables_w")}
+
+
+def kv_kinds(cfg):
+    """{kind: its KvKind} of a config whose K/V layers are of two kinds,
+    "full" first; None for every other config."""
+    if getattr(cfg, "kv_window", None) is None:
+        return None
+    return {"full": cfg.kv_full or KvKind(), "window": cfg.kv_window}
+
+
 # --------------------------------------------------------------------------
 # init
 # --------------------------------------------------------------------------
@@ -610,9 +637,11 @@ def _qk_normed(bp, q, k, cfg: LlamaConfig):
             rms_norm(bp["attn"]["k_norm"], k, eps=cfg.rms_eps))
 
 
-def _qkv_rope(bp, h, positions, *, cfg: LlamaConfig, compute_dtype):
+def _qkv_rope(bp, h, positions, *, cfg: LlamaConfig, compute_dtype,
+              rope=True):
     """Project h (B, T, C) and rotate q/k at absolute `positions` (T,).
-    Returns q (B, H, T, D), k/v (B, KV, T, D) — KV heads stay narrow."""
+    Returns q (B, H, T, D), k/v (B, KV, T, D) — KV heads stay narrow.
+    `rope` off (a layer kind's, `KvKind`): q and k as they are normed."""
     q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
                     cfg.n_head)
     k = split_heads(linear(bp["attn"]["k"], h, compute_dtype=compute_dtype),
@@ -620,6 +649,8 @@ def _qkv_rope(bp, h, positions, *, cfg: LlamaConfig, compute_dtype):
     v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
                     cfg.n_kv_head)
     q, k = _qk_normed(bp, q, k, cfg)
+    if not rope:
+        return _q_rescale(q, cfg), k, v
     cos, sin = _rope_tables(cfg, positions)
     return (_q_rescale(_rope_apply(q, cos, sin, cfg), cfg),
             _rope_apply(k, cos, sin, cfg), v)
@@ -703,14 +734,16 @@ def _gqa_scores_attend(q, k, v, mask_fn, softcap=None):
     return y.reshape(b, h, t, d)
 
 
-def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None):
+def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None,
+                rope=True):
     """Default attention: local causal GQA over the whole (B, T, C) h,
     band-limited to cfg.sliding_window when set. `window` overrides the
     config's window for this call (traced allowed) — the per-layer hook
-    alternating-attention configs thread through blocks_scan."""
+    alternating-attention configs thread through blocks_scan, and a layer
+    kind's own (`KvKind`, with `rope`)."""
     t = h.shape[1]
     q, k, v = _qkv_rope(bp, h, jnp.arange(t), cfg=cfg,
-                        compute_dtype=compute_dtype)
+                        compute_dtype=compute_dtype, rope=rope)
     rows = jnp.arange(t)
     w = window if window is not None else cfg.sliding_window
 
@@ -738,6 +771,11 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
     kind where the config's layers are of several (models/mla.py)."""
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
+    if attn_fn is None and (kinds := kv_kinds(cfg)) is not None:
+        kk = kinds[kind or "full"]
+        fn = lambda bp2, h: _dense_attn(  # noqa: E731
+            bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=kk.window,
+            rope=kk.rope)
     if attn_fn is None and cfg.index_topk is not None:
         from dnn_tpu.models import dsa
 
@@ -827,16 +865,16 @@ def layer_stacks(prepared, cfg):
 
 
 class _Span:
-    """Layers [first, stop) of a stack that holds more: `scan_form` cuts
-    what rides the loop and offsets the layer index of what does not."""
+    """Layers [first, stop) of a stack that holds more: `scan_form` lets
+    their indices ride a cached loop (the body takes its layer out of the
+    whole stack); the whole-sequence forward scans `cut()`."""
 
     def __init__(self, stack, first, stop):
         self.stack, self.first, self.stop = stack, first, stop
 
-    def cut(self, tree=None):
-        """The span's layers of `tree` (the stack itself when None)."""
-        return jax.tree.map(lambda x: x[self.first:self.stop],
-                            self.stack if tree is None else tree)
+    def cut(self):
+        """The span's layers of the stack."""
+        return jax.tree.map(lambda x: x[self.first:self.stop], self.stack)
 
 
 def _span(stack, span):
@@ -854,27 +892,40 @@ def scan_form(stack, ffn):
     `moe.LayerOf(stack, layer)` — so no layer's matrices are ever cut
     out of the stack for the grouped matmul (as `paged_kvcache.
     scan_blocks` carries the pool). Any other stack or hook: `stack`
-    itself and the identity."""
+    itself and the identity. A `_Span` rides as its layers' INDICES
+    alone."""
     from dnn_tpu.parallel.moe import EXPERT_MATRICES, LayerOf
 
-    span, cut = None, (lambda tree: tree)
+    span = None
     if isinstance(stack, _Span):
-        span, cut, stack = (stack.first, stack.stop), stack.cut, stack.stack
+        span, stack = (stack.first, stack.stop), stack.stack
 
     moe = stack.get("moe") if hasattr(ffn, "expert_forms") else None
     whole = {k: moe[k] for k in EXPERT_MATRICES if k in (moe or {})}
-    if not whole:
-        return cut(stack), lambda bp: bp
-    rest = cut({**stack,
-                "moe": {k: v for k, v in moe.items() if k not in whole}})
-    n_layer = next(iter(whole.values())).shape[0]
+    rest = stack if not whole else {
+        **stack, "moe": {k: v for k, v in moe.items() if k not in whole}}
 
-    def bind(xs_l):
-        bp, layer = xs_l
+    def with_experts(bp, layer):
         return {**bp, "moe": {**bp["moe"], **{
             k: LayerOf(w, layer) for k, w in whole.items()}}}
 
-    return (rest, jnp.arange(*(span or (n_layer,)), dtype=jnp.int32)), bind
+    if span is not None:
+        # a span of a stack that holds more: ONLY the layer index rides
+        # the loop and the body takes its layer out of the whole stack
+        # (cutting the span out first copied its weights every step:
+        # `slice bf16[2,8192,6144]` in K-EXAONE's decode program, PERF.md
+        # section 6, PR 43)
+        def bind_span(layer):
+            bp = jax.tree.map(lambda x: lax.dynamic_index_in_dim(
+                x, layer, keepdims=False), rest)
+            return with_experts(bp, layer) if whole else bp
+
+        return jnp.arange(*span, dtype=jnp.int32), bind_span
+    if not whole:
+        return stack, lambda bp: bp
+    n_layer = next(iter(whole.values())).shape[0]
+    return (rest, jnp.arange(n_layer, dtype=jnp.int32)), \
+        lambda xs_l: with_experts(*xs_l)
 
 
 def _scan_all_stacks(prepared, x, *, cfg, **kw):
@@ -1072,12 +1123,12 @@ def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     from dnn_tpu.runtime.kvcache import codec_for_cache
 
     if getattr(cfg, "mla", None) is not None or getattr(
-            cfg, "first_k_dense", 0):
+            cfg, "first_k_dense", 0) or kv_kinds(cfg) is not None:
         raise ValueError(
             "forward_with_cache holds K and V of one stack of layers: a "
-            "model with latent attention or a dense prefix prefills and "
-            "decodes through its family adapter (models/mla.py) and the "
-            "paged pool")
+            "model with latent attention, a dense prefix or layers of "
+            "kinds prefills and decodes through its family adapter "
+            "(models/mla.py, LlamaKindRows) and the paged pool")
     ffn = ffn or cfg.default_ffn(compute_dtype)
     wins = layer_windows(cfg)  # (L,) for alternating configs, else None
     codec = codec_for_cache(cache, use_kernel=attn_kernel,
@@ -1436,13 +1487,16 @@ def make_generate_seq_sharded(cfg: LlamaConfig, mesh, *, max_new_tokens: int,
 
 def family_rows(cfg, **kw):
     """The batcher adapter a LLaMA-family config serves through: by what
-    its attention keeps a position (`LlamaFamilyRows`' K and V; with an
-    indexer models/dsa.py's third leaf; with latent attention
-    models/mla.py's one). `kw`: `LlamaFamilyRows`' own."""
+    its attention keeps a position (`LlamaFamilyRows`' K and V — by layer
+    kind `LlamaKindRows`'; with an indexer models/dsa.py's third leaf;
+    with latent attention models/mla.py's one). `kw`: `LlamaFamilyRows`'
+    own."""
     if cfg.index_topk is not None:
         from dnn_tpu.models.dsa import DsaFamilyRows as rows
     elif getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models.mla import MlaFamilyRows as rows
+    elif kv_kinds(cfg) is not None:
+        rows = LlamaKindRows
     else:
         rows = LlamaFamilyRows
     return rows(cfg, **kw)
@@ -1531,10 +1585,10 @@ class LlamaFamilyRows:
                                        ffn=ffn or self.ffn),
                     layer_cache)
 
-    def _qkv_rows(self, bp, h, pos):
+    def _qkv_rows(self, bp, h, pos, rope=True):
         """h (B, 1, C) normed rows at per-slot positions pos (B,) -> q (B,
         H, 1, D) normed, rotated and rescaled, k (rotated) and v (B, KV,
-        1, D)."""
+        1, D); `rope` off (a layer kind's): unrotated."""
         cfg, compute_dtype = self.cfg, self.compute_dtype
         kv = cfg.n_kv_head
         q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
@@ -1544,6 +1598,8 @@ class LlamaFamilyRows:
         v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
                         kv)
         q, k = _qk_normed(bp, q, k, cfg)
+        if not rope:
+            return _q_rescale(q, cfg), k, v
         cos, sin = _rope_tables(cfg, pos)  # (B, D)
         cos, sin = cos[:, None, None, :], sin[:, None, None, :]
         q, k = _rope_apply(q, cos, sin, cfg), _rope_apply(k, cos, sin, cfg)
@@ -1678,6 +1734,189 @@ class LlamaFamilyRows:
         if moe_stats:
             return logits[:, -1], new_cache, acc
         return logits[:, -1], new_cache
+
+
+def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
+                    moe_stats, leaves):
+    """A family's `prefill` where the transient row's leaves are BY LAYER
+    KIND (`leaves`: kind -> its leaf names; models/mla.py's latents,
+    `LlamaKindRows`' K and V): each stack of `layer_stacks` is scanned
+    over its kind's rows — its range of them where the layers are of
+    kinds — through `family._chunk_block(bp, x, rows, start_pos, ffn,
+    kind)` -> (x, rows). -> (hidden (1, P, C) float32, the row cache[,
+    the expert layers' sums])."""
+    x = _scaled_embed(prepared, padded, family.cfg)
+    if family.compute_dtype is not None:
+        x = x.astype(family.compute_dtype)
+
+    def layer(bind, kind, carry, layer_in):
+        x, acc = carry
+        bp, rows = layer_in
+        bp = bind(bp)
+        (y, rows), acc = _run_block(
+            family.ffn, acc,
+            lambda f: family._chunk_block(bp, x, rows, start_pos, f, kind))
+        return (y, acc), rows
+
+    carry = (x, jnp.zeros((3,), jnp.int32) if moe_stats else None)
+    new_rows = {name: [] for name in row_cache}
+    for stack, layers, kind in layer_stacks(prepared, family.cfg):
+        names = [n for n in leaves[kind or "full"] if n in row_cache]
+        rows = {n: row_cache[n] if layers is None
+                else row_cache[n][layers[0]:layers[1]] for n in names}
+        blocks, bind = scan_form(stack, family.ffn)
+        carry, rows = lax.scan(
+            functools.partial(layer, bind, kind or "full"), carry,
+            (blocks, rows))
+        for n in names:
+            new_rows[n].append(rows[n])
+    x, acc = carry
+    new_cache = {n: r[0] if len(r) == 1 else jnp.concatenate(r)
+                 for n, r in new_rows.items()}
+    x = x.astype(jnp.float32)  # what `head` is handed, in the finish
+    if moe_stats:
+        return x, new_cache, acc
+    return x, new_cache
+
+
+class LlamaKindRows(LlamaFamilyRows):
+    """`LlamaFamilyRows` for a config whose K/V layers are of two KINDS
+    (`kv_kinds`: "full" layers keep every position, "window" layers the
+    last W, and a kind may leave q and k unrotated). What a position's
+    cache holds is K and V either way, but a kind's leaves have THAT
+    kind's layers, blocks and tables: `cache_kinds` (kind -> {"layers",
+    "leaves", "tables", "window"}: runtime/paged_kvcache.py's module
+    docstring) names "k" / "v" under "tables" for the full kind and "k_w"
+    / "v_w" under "tables_w" for the window kind, whose slot holds
+    `window_blocks(W, block_len)` blocks whatever its length. Paged pools
+    only; what assumes ONE stack of K and V — the prefix store, the KV
+    tier, int8 / int4 pools, interleaved prefill, speculative verify — is
+    refused by the batcher at construction (`requires_paged`).
+
+    A prefill chunk writes its rows into the kind's transient row
+    (whole-length for either kind) and attends through the runtime-limit
+    kernel (ops/pallas/cached_attention.cached_attention) with the query
+    group folded into the rows: column tiles past a row tile's diagonal
+    — and, for a window kind, behind its band — are neither fetched nor
+    computed. Scopes: `attn.prefill` / `attn.paged_decode` are the full
+    kind's read, `attn.window_prefill` / `attn.window_decode` lie around
+    a window kind's."""
+
+    requires_paged = True
+
+    def __init__(self, cfg, **kw):
+        super().__init__(cfg, **kw)
+        if cfg.sliding_window is not None or cfg.attn_softcap is not None \
+                or cfg.alt_window or cfg.parallel_block:
+            raise ValueError(
+                "layers of kinds (kv_full / kv_window) are built for the "
+                "sequential block without a uniform sliding window, an "
+                "alternating one or a softcap: a kind's window is "
+                "`KvKind.window`")
+        self.kinds = kv_kinds(cfg)
+        d = cfg.head_dim
+        self.cache_kinds = {}
+        for kind, kk in self.kinds.items():
+            k_name, v_name, tables = KV_KIND_LEAVES[kind]
+            self.cache_kinds[kind] = {
+                "layers": sum(t == kind for t in cfg.layer_types),
+                "leaves": {k_name: (cfg.n_kv_head, d),
+                           v_name: (cfg.n_kv_head, d)},
+                "tables": tables, "window": kk.window}
+        self.cache_leaves = self.cache_kinds["full"]["leaves"]
+        # which form each kind's reads took in the programs traced so far
+        # (/statusz `components.attention.kinds`)
+        self.attn_forms = {kind: {} for kind in self.kinds}
+
+    def init_cache(self, batch, max_len, dtype):
+        if dtype in ("int8", "int4"):
+            raise ValueError("a cache of leaves by layer kind is float")
+        return {name: jnp.zeros((k["layers"], batch, heads, max_len, width),
+                                dtype)
+                for k in self.cache_kinds.values()
+                for name, (heads, width) in k["leaves"].items()}
+
+    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind):
+        """One block over a prefill chunk x (1, T, C) at [start_pos,
+        start_pos + T): the chunk's K and V written into the layer's
+        transient rows `rows` {leaf: (1, KV, S, D)}, then attended with
+        the group folded into the kernel's rows (row g * T + t reads
+        columns <= start_pos + t, within the kind's band)."""
+        from dnn_tpu.ops.pallas.cached_attention import cached_attention
+
+        cfg, compute_dtype = self.cfg, self.compute_dtype
+        kk = self.kinds[kind]
+        k_name, v_name, _ = KV_KIND_LEAVES[kind]
+        t = x.shape[1]
+        kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
+        with jax.named_scope("llama.block.cached_attn"):
+            h = _pre_normed(bp, x, cfg)
+            q, k, v = _qkv_rope(bp, h, start_pos + jnp.arange(t), cfg=cfg,
+                                compute_dtype=compute_dtype, rope=kk.rope)
+            with jax.named_scope("kv_pool.write"):
+                rows = {n: lax.dynamic_update_slice_in_dim(
+                    rows[n], new.astype(rows[n].dtype), start_pos, axis=2)
+                    for n, new in ((k_name, k), (v_name, v))}
+            interpret = True if self.attn_kernel == "interpret" else None
+            # the largest tile up to 512 that divides the chunk and the
+            # row: a 128 x 128 tile costs 3.4-4.7x a (512, 512) one on a
+            # v5e for the same pairs (PERF.md section 6, PR 43)
+            tile = next(n for n in (512, 256, 128, t)
+                        if t % n == 0 and rows[k_name].shape[2] % n == 0)
+            attend = functools.partial(
+                cached_attention, q.reshape(1, kv, g * t, d), rows[k_name],
+                rows[v_name], jnp.reshape(start_pos, (1,)).astype(jnp.int32),
+                rows_mod=t, block_q=tile, block_s=tile, interpret=interpret)
+            if kk.window is None:
+                y = attend()
+            else:
+                with jax.named_scope("attn.window_prefill"):
+                    y = attend(window=kk.window)
+            self.attn_forms[kind]["prefill"] = (
+                "banded_kernel" if kk.window else "kernel") if (
+                    interpret or jax.default_backend() == "tpu") else "plain"
+            y = y.reshape(1, cfg.n_head, t, d)
+            o = linear(bp["attn"]["o"], merge_heads(y.astype(x.dtype)),
+                       compute_dtype=compute_dtype)
+        with jax.named_scope("llama.block.mlp"):
+            return (_branches_residual(bp, x, o, h, cfg=cfg,
+                                       compute_dtype=compute_dtype, ffn=ffn),
+                    rows)
+
+    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
+                moe_stats=False):
+        return prefill_by_kind(
+            self, prepared, padded, row_cache, start_pos, moe_stats,
+            {kind: names[:2] for kind, names in KV_KIND_LEAVES.items()})
+
+    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
+                   kind="full"):
+        cfg, compute_dtype = self.cfg, self.compute_dtype
+        kk = self.kinds[kind]
+        b = x.shape[0]
+        kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
+        h = _pre_normed(bp, x, cfg)
+        q, k, v = self._qkv_rows(bp, h, pos, rope=kk.rope)
+        qg = q.reshape(b, kv, g, d)  # group rows share the slot's limit
+        self.attn_forms[kind]["decode"] = codec.decode_form(
+            layer_cache, kk.window)
+        if kk.window is None:
+            y, layer_cache = codec.write_attend_rows(qg, layer_cache, k, v,
+                                                     pos, write)
+        else:
+            k_name, v_name, tables = KV_KIND_LEAVES[kind]
+            with jax.named_scope("attn.window_decode"):
+                y, layer_cache = codec.write_attend_rows(
+                    qg, layer_cache, k, v, pos, write, window=kk.window,
+                    leaves=(k_name, v_name), tables=tables)
+        y = y.reshape(b, cfg.n_head, 1, d)
+        o = linear(bp["attn"]["o"], merge_heads(y.astype(x.dtype)),
+                   compute_dtype=compute_dtype)
+        return h, o, layer_cache
+
+    def verify_rows(self, *a, **kw):
+        raise ValueError("speculative verify reads ONE stack of K and V: "
+                         "not available with cache leaves by layer kind")
 
 
 class LlamaPipelineFamily:

@@ -117,12 +117,34 @@ class MixtralConfig(llama.LlamaConfig):
     # and block tables are apart (paged_kvcache, by kind)
     mla_window: Optional[MlaConfig] = None
     layer_types: Optional[tuple] = None
+    # the same for a family whose cache is K and V (models/llama.py
+    # `KvKind`: a kind's window and whether it rotates q and k):
+    # `layer_types[i]` is "full" (`kv_full`, None: every position,
+    # rotated) or "window" (`kv_window`, which has a window). Stacks are
+    # by (MLP kind, attention kind): a layer of the dense prefix may be of
+    # either kind
+    kv_full: Optional[llama.KvKind] = None
+    kv_window: Optional[llama.KvKind] = None
 
     def __post_init__(self):
         super().__post_init__()
-        if (self.layer_types is None) != (self.mla_window is None):
-            raise ValueError("layer_types and mla_window come together")
-        if self.layer_types is not None and (
+        if (self.layer_types is None) != (
+                self.mla_window is None and self.kv_window is None):
+            raise ValueError("layer_types comes with mla_window or "
+                             "kv_window, and they with it")
+        if self.kv_window is not None or self.kv_full is not None:
+            if (self.mla is not None or self.mla_window is not None
+                    or self.index_topk is not None or self.kv_window is None
+                    or self.kv_window.window is None
+                    or (self.kv_full or llama.KvKind()).window is not None
+                    or len(self.layer_types) != self.n_layer
+                    or set(self.layer_types) - {"full", "window"}):
+                raise ValueError(
+                    "kv_window (which has a window) names the \"window\" "
+                    "layers of layer_types and kv_full (which has none) "
+                    "the \"full\" ones, of a model whose cache is K and V "
+                    "(no mla, no indexer)")
+        elif self.layer_types is not None and (
                 self.mla is None or len(self.layer_types) != self.n_layer
                 or set(self.layer_types) - {"full", "window"}
                 or "window" in self.layer_types[:self.first_k_dense]
@@ -366,6 +388,56 @@ PRESETS["dots3-test"] = MixtralConfig(
                          rope_theta=50_000.0, lora_rescale=True,
                          head_gate=True, window=9),
     layer_types=("full", "full", "window", "window", "window"))
+# K-EXAONE-236B-A23B (LGAI-EXAONE/K-EXAONE-236B-A23B config.json,
+# `model_type` exaone_moe): 48 layers of GQA 64 query / 8 KV heads of 128
+# with per-head q/k RMSNorm, of two KINDS whose cache is K and V
+# (models/llama.py `KvKind`) in the period S S S F — 36 "window" layers
+# (0, 1, 2, 4, ...: a window of 128, RoPE theta 1e6) and 12 "full" layers
+# (3, 7, ...: every position, NO rotation) — layer 0 a dense SwiGLU of
+# 18432, then 128 experts of 2048, 8 a token by sigmoid scores with a
+# selection bias (one group), weights normalised, times 2.5, and one
+# ungated shared expert; vocabulary 153600, untied. The multi-token-
+# prediction layer (`num_nextn_predict_layers` 1) drafts: it is no part of
+# the next token's logits and is not served. What the config leaves open
+# (QK-norm, the unrotated full layers, the selection bias, pre-norm) is
+# `assumed` in chipbench/configs/k-exaone-236b-a23b-ep8-1chip.json. Never
+# instantiated whole.
+_KEXAONE_TYPES = tuple("full" if i % 4 == 3 else "window" for i in range(48))
+PRESETS["k-exaone-236b-a23b"] = MixtralConfig(
+    block_size=262144, vocab_size=153600, n_layer=48, n_head=64, n_kv_head=8,
+    n_embd=6144, d_ff=2048, head_dim_override=128, rope_theta=1_000_000.0,
+    rms_eps=1e-5, qk_norm=True, qk_norm_width="head", n_expert=128,
+    router_top_k=8, router_norm_topk=True, capacity_factor=128.0,
+    d_shared=2048, shared_gate=False, first_k_dense=1, d_ff_dense=18432,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=2.5),
+    kv_full=llama.KvKind(window=None, rope=False),
+    kv_window=llama.KvKind(window=128, rope=True),
+    layer_types=_KEXAONE_TYPES)
+# the benchmark's cut (chipbench/configs/k-exaone-236b-a23b-ep8-1chip
+# .json): one chip's share of an 8-chip expert-parallel deployment —
+# experts 0-15 of each expert layer's 128, rows 0-19199 of the vocabulary
+# (an eighth), attention, router, shared expert and norms whole — and
+# layer 0 with ONE whole period of four (S | S S F S): 7.67 GB held
+PRESETS["k-exaone-236b-a23b-ep8-1chip"] = dataclasses.replace(
+    PRESETS["k-exaone-236b-a23b"], n_layer=5,
+    layer_types=_KEXAONE_TYPES[:5], vocab_size=19200, experts_first=0,
+    experts_held=16)
+# tiny K-EXAONE for the CPU tests, every switch of the real one acting:
+# GQA 2:1 with a decoupled head width and head-width q/k norm, a window a
+# 40-token sequence exceeds four times over, unrotated full layers, a
+# dense AND windowed layer 0, S S S F S, sigmoid + bias + scale 2.5, an
+# ungated shared expert, a held share smaller than the expert count
+PRESETS["k-exaone-test"] = MixtralConfig(
+    block_size=64, vocab_size=256, n_layer=5, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=32, head_dim_override=32, rope_theta=1_000_000.0,
+    rms_eps=1e-5, qk_norm=True, qk_norm_width="head", n_expert=8,
+    router_top_k=4, router_norm_topk=True, capacity_factor=8.0,
+    experts_first=0, experts_held=4, d_shared=32, shared_gate=False,
+    first_k_dense=1, d_ff_dense=96,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=2.5),
+    kv_full=llama.KvKind(window=None, rope=False),
+    kv_window=llama.KvKind(window=8, rope=True),
+    layer_types=("window", "window", "window", "full", "window"))
 # the benchmark's cut (chipbench/configs/olmoe-1b-7b-1chip.json): three of
 # the sixteen layers — the pattern has period 1 — so that float32 weights,
 # a 16-slot pool of 4096 positions and the programs fit one 16 GB chip

@@ -92,7 +92,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 import logging
 from typing import Optional
 
@@ -573,40 +572,9 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
 
     def prefill(self, prepared, padded, row_cache, start_pos=0, *,
                 moe_stats=False):
-        cfg = self.cfg
-        x = llama._scaled_embed(prepared, padded, cfg)
-        if self.compute_dtype is not None:
-            x = x.astype(self.compute_dtype)
-
-        def layer(bind, kind, carry, layer_in):
-            x, acc = carry
-            bp, rows = layer_in
-            bp = bind(bp)
-            (y, rows), acc = llama._run_block(
-                self.ffn, acc,
-                lambda f: self._chunk_block(bp, x, rows, start_pos, f, kind))
-            return (y, acc), rows
-
-        carry = (x, jnp.zeros((3,), jnp.int32) if moe_stats else None)
-        new_rows = {name: [] for name in row_cache}
-        for stack, layers, kind in llama.layer_stacks(prepared, cfg):
-            names = [n for n in KIND_LEAVES[kind or "full"][:2]
-                     if n in row_cache]
-            rows = {n: row_cache[n] if layers is None
-                    else row_cache[n][layers[0]:layers[1]] for n in names}
-            blocks, bind = llama.scan_form(stack, self.ffn)
-            carry, rows = lax.scan(
-                functools.partial(layer, bind, kind or "full"), carry,
-                (blocks, rows))
-            for n in names:
-                new_rows[n].append(rows[n])
-        x, acc = carry
-        new_cache = {n: r[0] if len(r) == 1 else jnp.concatenate(r)
-                     for n, r in new_rows.items()}
-        x = x.astype(jnp.float32)  # what `head` is handed, in the finish
-        if moe_stats:
-            return x, new_cache, acc
-        return x, new_cache
+        return llama.prefill_by_kind(
+            self, prepared, padded, row_cache, start_pos, moe_stats,
+            {kind: names[:2] for kind, names in KIND_LEAVES.items()})
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
                    kind="full"):

@@ -648,16 +648,20 @@ class StepClock:
         self._note3(self.mla_total, self._mla_gauges, program,
                     (layer_calls, cached, pairs))
 
-    def note_mla_kind(self, program: str, kind: str, cached: int):
+    def note_mla_kind(self, program: str, kind: str, cached: int,
+                      series: str = "mla"):
         """`note_mla`'s cached positions for a model whose layers are of
         KINDS (models/mla.py), by kind: what the kind's layers had to
         read — an indexer's selected positions for "full", the window's
         for "window" — summed over its layers. Cumulative
         `mla.cached_positions_read_total{kind=,program=}`, on /metrics
-        with a kind's first note."""
+        with a kind's first note. `series` "attn": the same for kinds
+        whose cache is K and V (models/llama.py `LlamaKindRows`: pos + 1 a
+        full layer, min(pos + 1, W) a window layer, a chunk's pairs
+        within the band), `attn.cached_positions_read_total{...}`."""
         if not _obs.enabled():
             return
-        key = (kind, program)
+        key = (kind, program) if series == "mla" else (series, kind, program)
         if key not in self.mla_kind_total:
             self.mla_kind_total[key] = 0
             ref = weakref.ref(self)
@@ -666,7 +670,7 @@ class StepClock:
                 c = ref()
                 return float(c.mla_kind_total[key]) if c is not None else 0.0
 
-            self._gauges[labeled("mla.cached_positions_read_total",
+            self._gauges[labeled(f"{series}.cached_positions_read_total",
                                  kind=kind, program=program)] = read
             self._gauges_registered = False  # re-register with it
         self.mla_kind_total[key] += cached

@@ -44,11 +44,14 @@ _NEG_BIG = -1e30
 # reference (fallback + test oracle) — the kvcache.py einsum math
 # ----------------------------------------------------------------------
 
-def reference_cached_attention(q, k, v, pos, *, ks=None, vs=None):
+def reference_cached_attention(q, k, v, pos, *, ks=None, vs=None,
+                               rows_mod=None, window=None):
     """q (B, H, T, D) at absolute positions pos[b] + t; k/v (B, H, S, D)
     cache buffers (any float dtype, or int8 with `ks`/`vs` scales
     (B, H, S)); pos (B,) int32. Row (b, t) attends columns
-    <= pos[b] + t. Returns (B, H, T, D) f32."""
+    <= pos[b] + t — t modulo `rows_mod` where the rows are a query group
+    folded over its KV head — and, with `window`, > pos[b] + t - window.
+    Returns (B, H, T, D) f32."""
     d = q.shape[-1]
     s = jnp.einsum("bhtd,bhsd->bhts", q.astype(jnp.float32),
                    k.astype(jnp.float32),
@@ -58,8 +61,13 @@ def reference_cached_attention(q, k, v, pos, *, ks=None, vs=None):
     s = s / jnp.sqrt(d)
     cols = jnp.arange(k.shape[2])
     rows = jnp.arange(q.shape[2])
+    if rows_mod is not None:
+        rows = rows % rows_mod
     limit = pos[:, None, None, None] + rows[None, None, :, None]
-    s = jnp.where(cols[None, None, None, :] <= limit, s, _NEG_BIG)
+    keep = cols[None, None, None, :] <= limit
+    if window is not None:
+        keep &= cols[None, None, None, :] > limit - window
+    s = jnp.where(keep, s, _NEG_BIG)
     p = jax.nn.softmax(s, axis=-1)
     if vs is not None:
         p = p * vs[:, :, None, :]
@@ -71,8 +79,22 @@ def reference_cached_attention(q, k, v, pos, *, ks=None, vs=None):
 # kernel
 # ----------------------------------------------------------------------
 
+def _live_tiles(pos, q0, *, block_q, block_s, window):
+    """Of a row tile whose first row reads up to column pos + q0: the
+    first and the last column tile that holds a column some row of it
+    reads (the band's, with `window`)."""
+    last = (pos + q0 + block_q - 1) // block_s
+    if window is None:
+        return 0, last
+    return jnp.maximum(pos + q0 - window + 1, 0) // block_s, last
+
+
 def _cached_attn_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
-                        scale, block_q, block_s, quant):
+                        scale, block_q, block_s, quant, rows_mod=None,
+                        window=None):
+    """`rows_mod` / `window`: the folded-group and banded form
+    (`cached_attention`'s docstring), operands then meet in their own
+    dtype."""
     from jax.experimental import pallas as pl
 
     # the quant variant carries two extra scale inputs; the float variant
@@ -102,12 +124,29 @@ def _cached_attn_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
     # DYNAMIC predicate — pl.when skips the block's COMPUTE (the BlockSpec
     # pipeline still fetches every block; the bandwidth story is the int8
     # byte width and fused dequant, not block skipping).
-    live = si * block_s <= pos + (qi + 1) * block_q - 1
+    folded = rows_mod is not None or window is not None
+    if folded:
+        # the tile's first row among its head's T (`rows_mod`): the column
+        # tiles outside [lo, hi] are neither computed nor — the index map
+        # clamps to the same two — fetched. Under a band the grid's last
+        # axis is only as long as a band is wide (`_kernel_call`), and
+        # step si holds column tile lo + si
+        q0 = (qi * block_q) % (rows_mod or (block_q * pl.num_programs(1)))
+        lo, hi = _live_tiles(pos, q0, block_q=block_q, block_s=block_s,
+                             window=window)
+        ti = si if window is None else lo + si
+        live = ti <= hi
+    else:
+        ti = si
+        live = si * block_s <= pos + (qi + 1) * block_q - 1
 
     @pl.when(live)
     def _step():
-        q = q_ref[0].astype(jnp.float32)  # (block_q, d)
-        k = k_ref[0].astype(jnp.float32)  # (block_s, d) — int8 streams raw
+        # the folded form's operands meet in their own dtype (bfloat16
+        # products summed in float32 are the float32 form's)
+        cdt = q_ref.dtype if folded else jnp.float32
+        q = q_ref[0].astype(cdt)  # (block_q, d)
+        k = k_ref[0].astype(cdt)  # (block_s, d) — int8 streams raw
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -117,22 +156,30 @@ def _cached_attn_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
         s = s * scale
 
         rows = jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_s), 0) + qi * block_q
+            jnp.int32, (block_q, block_s), 0) + (q0 if folded
+                                                 else qi * block_q)
         cols = jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_s), 1) + si * block_s
-        s = jnp.where(cols <= pos + rows, s, _NEG_BIG)
+            jnp.int32, (block_q, block_s), 1) + ti * block_s
+        keep = cols <= pos + rows
+        if window is not None:
+            keep &= cols > pos + rows - window
+        s = jnp.where(keep, s, _NEG_BIG)
 
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
+        if window is not None:
+            # a row the band leaves nothing of in this tile: its max is
+            # still the mask's, and exp(0) would count the masked
+            p = jnp.where(keep, p, 0.0)
         if quant:
             # V scale folds into the (small) probability matrix; the raw
             # int8 V contracts directly (scales commute — kvcache.py)
             pv = p * vs_ref[0]
         else:
-            pv = p
-        v = v_ref[0].astype(jnp.float32)
+            pv = p.astype(cdt) if folded else p
+        v = v_ref[0].astype(cdt)
         l_new = l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             pv, v, (((1,), (0,)), ((), ())),
@@ -146,7 +193,8 @@ def _cached_attn_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
         o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
-def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret):
+def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret,
+                 rows_mod=None, window=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -154,14 +202,37 @@ def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret):
     s_len = k3.shape[1]
     nq, ns = t // block_q, s_len // block_s
     quant = ks3 is not None
+    folded = rows_mod is not None or window is not None
     kernel = functools.partial(
         _cached_attn_kernel, scale=1.0 / (d ** 0.5), block_q=block_q,
-        block_s=block_s, quant=quant,
+        block_s=block_s, quant=quant, **(
+            {"rows_mod": rows_mod, "window": window} if folded else {}),
     )
     # index maps gain a TRAILING scalar-prefetch ref argument (unused here
     # — blocks are addressed by grid coordinates alone)
     qspec = pl.BlockSpec((1, block_q, d), lambda b, qi, si, p: (b, qi, 0))
     sspec = pl.BlockSpec((1, block_s, d), lambda b, qi, si, p: (b, si, 0))
+    if folded:
+        # a column tile outside the row tile's live ones is asked for as
+        # the nearest live one: the pipeline skips a copy whose block
+        # index repeats, so what is not computed is not fetched either.
+        # Under a band a row tile reads at most `block_q + window - 1`
+        # columns, whatever the row's length: the grid's last axis is that
+        # many tiles (a skipped grid step still costs a quarter of a
+        # microsecond on a v5e: 24 576 of them were 6.6 ms a layer, PERF.md
+        # section 6, PR 43), counted from the band's first
+        n_tiles = ns  # of the row, whatever the grid's last axis is
+
+        def live_tile(b, qi, si, p):
+            lo, hi = _live_tiles(
+                p[b], (qi * block_q) % (rows_mod or t), block_q=block_q,
+                block_s=block_s, window=window)
+            at = si if window is None else lo + si
+            return b, jnp.clip(at, lo, jnp.minimum(hi, n_tiles - 1)), 0
+
+        sspec = pl.BlockSpec((1, block_s, d), live_tile)
+        if window is not None:
+            ns = min(ns, -(-(block_q + window - 1) // block_s) + 1)
     scale_spec = pl.BlockSpec((1, 1, block_s),
                               lambda b, qi, si, p: (b, 0, si))
     in_specs = [qspec, sspec, sspec]
@@ -191,7 +262,8 @@ def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret):
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-        name="cached_attention",
+        name="cached_attention_banded" if window is not None
+        else "cached_attention",
     )(pos1d, *args)
 
 
@@ -199,14 +271,33 @@ def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret):
 # attn.paged_decode) and the kernels' `name=` are what a device trace
 # calls this file's work (the HLO op_name path; the custom call's own
 # name) — chipbench/spans.py reads them, so renaming one moves a metric.
-@jax.named_scope("attn.prefill")
-def cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
-                     block_s=128, interpret=None):
+def cached_attention(q, k, v, pos, *, window=None, **kw):
+    """`_cached_attention` under the scope `attn.prefill` — or, with a
+    `window`, under none of its own: a window kind's read lies in its
+    caller's `attn.window_prefill`, and `attn.prefill` stays the reads of
+    every position."""
+    if window is not None:
+        return _cached_attention(q, k, v, pos, window=window, **kw)
+    with jax.named_scope("attn.prefill"):
+        return _cached_attention(q, k, v, pos, **kw)
+
+
+def _cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
+                      block_s=128, interpret=None, rows_mod=None,
+                      window=None):
     """Cache attention with runtime position limits (see module docstring).
 
     q (B, H, T, D); k/v (B, H, S, D) — float, or int8 with ks/vs (B, H, S)
     scales; pos (B,) int32 base positions (row t attends cols
     <= pos[b] + t). Returns (B, H, T, D) f32.
+
+    `rows_mod` = T' (GQA's fold: H is the KV heads and a head's T rows
+    are its G query heads' T' rows one after another, `block_q` dividing
+    T'): row t reads up to pos[b] + t % T'. `window` = W: and no column
+    <= that limit - W (`band_keep`'s predicate). With either, a column
+    tile no row of a row tile reads — past its diagonal or behind its
+    band — is neither fetched nor computed, and float operands meet in
+    their own dtype.
 
     Dispatches to the Pallas kernel on TPU when S tiles by `block_s`
     (T tiles by block_q, or T < block_q which shrinks the q block);
@@ -215,15 +306,20 @@ def cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
     b, h, t, d = q.shape
     s_len = k.shape[2]
     on_tpu = jax.default_backend() == "tpu"
+    folded = {} if rows_mod is None and window is None else {
+        "rows_mod": rows_mod, "window": window}
     if interpret is None:
         if not on_tpu:
-            return reference_cached_attention(q, k, v, pos, ks=ks, vs=vs)
+            return reference_cached_attention(q, k, v, pos, ks=ks, vs=vs,
+                                              **folded)
         interpret = False
     if t <= block_q:
         block_q = t  # decode: T=1 -> one q row per program
-    tiles = (s_len % block_s == 0 and t % block_q == 0)
+    tiles = (s_len % block_s == 0 and t % block_q == 0
+             and (rows_mod or block_q) % block_q == 0)
     if not tiles:
-        return reference_cached_attention(q, k, v, pos, ks=ks, vs=vs)
+        return reference_cached_attention(q, k, v, pos, ks=ks, vs=vs,
+                                          **folded)
 
     bh = b * h
     q3 = q.reshape(bh, t, d)
@@ -234,7 +330,7 @@ def cached_attention(q, k, v, pos, *, ks=None, vs=None, block_q=128,
     ks3 = ks.reshape(bh, 1, s_len).astype(jnp.float32) if ks is not None else None
     vs3 = vs.reshape(bh, 1, s_len).astype(jnp.float32) if vs is not None else None
     out = _kernel_call(q3, k3, v3, pos1d, ks3, vs3, block_q=block_q,
-                       block_s=block_s, interpret=interpret)
+                       block_s=block_s, interpret=interpret, **folded)
     return out.reshape(b, h, t, d)
 
 

@@ -1257,9 +1257,19 @@ class LMServer:
                     kind: [{"columns": n, **step}
                            for n, step in sorted(dict(by_columns).items())]
                     for kind, by_columns in steps.items()}}
+        # the forms the reads of K and V leaves by layer kind took in the
+        # programs built so far (models/llama.py `LlamaKindRows`): the
+        # paged kernel or a gather and einsums for a decode read, the
+        # (banded) kernel or the plain form for a chunk's
+        kinds = getattr(family, "attn_forms", None)
+        if kinds and any(kinds.values()):
+            comps["attention"] = {
+                "detail": "the form each layer kind's reads took in the "
+                          "built programs",
+                "kinds": {kind: dict(f) for kind, f in kinds.items()}}
         # facts, no `state`: the KV cache's bytes leaf by leaf (K, V, an
-        # int8 pool's scales, a selecting model's index keys "ik"), from
-        # shapes alone
+        # int8 pool's scales, a selecting model's index keys "ik"; a
+        # layer kind's leaves under their own names), from shapes alone
         kv_leaves = getattr(self.batcher, "cache", None)
         if isinstance(kv_leaves, dict):
             from dnn_tpu.obs.mem import logical_nbytes

@@ -48,7 +48,11 @@ their own number of blocks, and a block table of their own:
     latent_w (L_win,  n_blocks_w, 1, block_len, Dwp)   under "tables_w"
     tables   (L_full, B, max_blocks);  tables_w (L_win, B, max_blocks)
 
-A layer reaches its kind's leaves at its index AMONG THE KIND's layers
+A family whose cache is K and V declares kinds the same way
+(models/llama.py `LlamaKindRows`: "k" / "v" under "tables" for layers that
+keep every position, "k_w" / "v_w" under "tables_w" for layers under a
+window; `write_attend_rows(window=, leaves=, tables=)` is their decode
+read). A layer reaches its kind's leaves at its index AMONG THE KIND's layers
 (`scan_blocks(layers=)`). Block ids are a kind's own (each kind's block 0
 is its junk block), drawn from ONE `BlockAllocator` (`of(kind)`): a slot
 of a window kind holds the blocks its window and the step's write can
@@ -57,7 +61,8 @@ table row pointing at the block of positions [j * bp, (j + 1) * bp) while
 that is within the window and at junk block 0 before and after
 (`ContinuousBatcher._roll_window_blocks` hands the block that fell behind
 back and draws the next). Decode over such a leaf gathers the window's
-blocks, not the row (`write_attend_latent_rows(window=)`).
+blocks, not the row (`write_attend_latent_rows(window=)`; `_window_rows`
+for K and V leaves).
 
 The codec interface matches FloatKV (write_rows / attend_rows /
 write_attend_rows / install_row), so GPTFamilyRows / LlamaFamilyRows
@@ -506,6 +511,68 @@ class PagedKV:
                        preferred_element_type=jnp.float32)
         return y, {**c, leaf: pool}
 
+    def decode_form(self, c, layer=0, window=None):
+        """Which form a decode read of K and V takes against cache `c`:
+        the paged kernel, or a gather of blocks and two einsums — always
+        the latter for a window kind's read (`_window_rows`)."""
+        return "paged_kernel" if (
+            window is None and layer is not None and self._kernel_on(c)
+        ) else "gather_einsum"
+
+    def _window_rows(self, q, c, k, v, pos, write_gate, *, layer, window,
+                     leaves, tables):
+        """`write_attend_rows` for a window kind's K and V leaves: q (B,
+        Hk, R, D), the step's k / v (B, Hk, 1, D) scattered in at `pos`
+        (gated and junk-routed as `write_rows`), then ceil(W / bp) + 1
+        blocks a slot gathered — those that hold positions (pos - W, pos]
+        — and attended under the band -> (y (B, Hk, R, D), c). The rest of
+        the row is not touched, and its table entries may point anywhere.
+        On the chip as on the CPU: the paged kernel started at the window's
+        first block was built and measured against this form at 64 slots
+        and four layers — alone 1.20 ms against this form's 0.96, inside
+        the decode program 1.12 against 1.28 of a 16.5 ms step (it walks
+        two groups of eight blocks a slot for nine, and both forms are
+        bound by launches, not bytes) — and not kept: a hundredth of a
+        step either way did not pay for a second variant of that kernel
+        (PERF.md section 6, PR 43)."""
+        kn, vn = leaves
+        bp = self.block_len
+        width = c[kn].shape[-1]
+        rows = {kn: _pad_lanes(k.astype(c[kn].dtype), width),
+                vn: _pad_lanes(v.astype(c[vn].dtype), width)}
+        tab = c[tables] if layer is None else c[tables][layer]  # (B, nb)
+        nb = tab.shape[1]
+        d = q.shape[-1]
+        with jax.named_scope("kv_pool.write"):
+            blk = jnp.take_along_axis(tab, (pos // bp)[:, None], axis=1)[:, 0]
+            blk = jnp.where(write_gate, blk, 0)
+            at = (blk, slice(None), jnp.where(write_gate, pos % bp, 0))
+            at = at if layer is None else (layer,) + at
+            pools = {n: c[n].at[at].set(r[:, :, 0]) for n, r in rows.items()}
+        n = min(window_blocks(window, bp), nb)
+        with jax.named_scope("kv_pool.gather"):
+            first = jnp.clip(jnp.maximum(pos - window + 1, 0) // bp, 0,
+                             nb - n)  # (B,)
+            ids = jnp.take_along_axis(
+                tab, first[:, None] + jnp.arange(n)[None, :], axis=1)
+
+            def view(pool):  # -> (B, Hk, n * bp, D)
+                g = (pool if layer is None else pool[layer])[ids.reshape(-1)]
+                g = g.reshape(ids.shape[0], n, *g.shape[1:])[..., :d]
+                return jnp.moveaxis(g, 1, 2).reshape(
+                    ids.shape[0], g.shape[2], n * bp, d)
+
+            kv, vv = view(pools[kn]), view(pools[vn])
+        s = jnp.einsum("bhrd,bhsd->bhrs", q.astype(kv.dtype), kv,
+                       preferred_element_type=jnp.float32) / jnp.sqrt(d)
+        cols = first[:, None] * bp + jnp.arange(n * bp)[None, :]  # (B, S)
+        keep = band_keep(cols, pos[:, None], window)
+        s = jnp.where(keep[:, None, None, :], s, _NEG_BIG)
+        p = jax.nn.softmax(s, axis=-1)
+        y = jnp.einsum("bhrs,bhsd->bhrd", p.astype(vv.dtype), vv,
+                       preferred_element_type=jnp.float32)
+        return y.astype(c[vn].dtype), {**c, **pools}
+
     def index_view(self, c, index_dim, layer=None):
         """Every slot's index keys in logical order, (B, S_max, Di): what
         the indexer scores one query a slot against."""
@@ -622,14 +689,24 @@ class PagedKV:
             return out if quant else out.astype(c["v"].dtype)
 
     def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None,
-                          layer=None, sel=None):
+                          layer=None, sel=None, leaves=("k", "v"),
+                          tables="tables"):
         """The decode step's one call (kvcache._KernelDispatch.
         write_attend_rows): k/v rows in at `pos`, attention out -> (y, c).
         On the whole pool with the kernel on, both are ONE operation: the
         kernel places each slot's row in the block it is about to read
         and hands the pool back through aliased outputs, so the compiled
         step holds no other operation on the pool — no scatter whose
-        layout XLA would have to reconcile with the kernel's."""
+        layout XLA would have to reconcile with the kernel's.
+
+        `window` = W with a layer kind's `leaves` and `tables` (a pool
+        whose leaves are by kind): the slot reads (pos - W, pos] of those
+        leaves — `_window_rows`. For a pool without kinds a per-call
+        window stays `attend_rows`' refusal."""
+        if window is not None and self.leaf_tables:
+            return self._window_rows(q, c, k, v, pos, write_gate, layer=layer,
+                                     window=window, leaves=leaves,
+                                     tables=tables)
         if layer is None or window is not None or not self._kernel_on(c):
             c = self.write_rows(c, k, v, pos, write_gate, layer=layer)
             return self.attend_rows(q, c, pos, window=window, layer=layer,
@@ -696,10 +773,13 @@ class _PagedLayer:
         self.codec, self.layer = codec, layer
 
     def write_attend_rows(self, q, c, k, v, pos, write_gate, window=None,
-                          sel=None):
+                          sel=None, **kind):
         return self.codec.write_attend_rows(
             q, c, k, v, pos, write_gate, window=window, layer=self.layer,
-            sel=sel)
+            sel=sel, **kind)
+
+    def decode_form(self, c, window=None):
+        return self.codec.decode_form(c, self.layer, window)
 
     def write_index_rows(self, c, ik, pos, write_gate):
         return self.codec.write_index_rows(c, ik, pos, write_gate,

@@ -375,6 +375,9 @@ class ContinuousBatcher:
         self._win_kinds = [(kind, k["layers"], k["window"])
                            for kind, k in (self._cache_kinds or {}).items()
                            if k["window"] is not None]
+        # kinds whose cache is K and V (models/llama.py `LlamaKindRows`):
+        # their reads are the attn.* series', a latent family's the mla.*
+        self._kv_kinds = bool(self._cache_kinds) and not self._latent
         if getattr(self.family, "requires_paged", False):
             leaves = "/".join(
                 n for k in self._cache_kinds.values() for n in k["leaves"]
@@ -552,6 +555,7 @@ class ContinuousBatcher:
             # dispatch: (tables name, slot, logical block, physical)
             self._wtab_pending: list = []
             self.window_blocks_freed = 0
+            self.window_table_flushes = 0  # launches of `_set_tables`
 
             def set_tables(tab, slot_ix, blk_ix, vals):
                 return tab.at[:, slot_ix, blk_ix].set(vals, mode="drop")
@@ -783,6 +787,8 @@ class ContinuousBatcher:
                         "_kind_used_read", k["tables"])
             self._obs_gauges["kv_pool.window_blocks_freed_total"] = \
                 _weak_gauge("_window_freed_read")
+            self._obs_gauges["kv_pool.window_table_flushes_total"] = \
+                _weak_gauge("_window_flushes_read")
         self.results: Dict[int, np.ndarray] = {}
         self.finish_reasons: Dict[int, str] = {}
         self.token_logprobs: Dict[int, dict] = {}
@@ -2024,8 +2030,8 @@ class ContinuousBatcher:
                 # `start`.
                 self._constraint_advance(slot, first)
             # a prompt longer than the window rolls blocks out at install
-            self._free_rolled_blocks(slot)
-            self._flush_window_tables()
+            self._free_rolled_blocks(slot, in_step=False)
+            self._flush_window_tables(in_step=False)
             self._retire_if_done(slot, in_step=False)
             return rid
         except BaseException:
@@ -2117,6 +2123,17 @@ class ContinuousBatcher:
                 # (query, position) pairs within the band
                 self.step_clock.note_mla_kind(
                     "prefill", kind, n_l * _capped_pairs(start, t, w))
+        if self._kv_kinds and self.step_clock is not None:
+            # K and V leaves by kind: the chunk's causal pairs a full
+            # layer, those within the band a window layer (pad rows too)
+            start, t = int(args[3]), int(args[2].shape[-1])
+            self.step_clock.note_mla_kind(
+                "prefill", "full", self._n_index_layers
+                * (t * start + t * (t + 1) // 2), series="attn")
+            for kind, n_l, w in self._win_kinds:
+                self.step_clock.note_mla_kind(
+                    "prefill", kind, n_l * _capped_pairs(start, t, w),
+                    series="attn")
         return res[0], res[1]
 
     def _moe_note(self, program: str, stats, idx: Optional[int] = None):
@@ -2703,7 +2720,7 @@ class ContinuousBatcher:
         if e is not None and e["refs"] > 0:
             e["refs"] -= 1  # entry stays cached for reuse until evicted
 
-    def _free_rolled_blocks(self, slot: int):
+    def _free_rolled_blocks(self, slot: int, in_step: bool = True):
         """Windowed paged pools reclaim FULLY rolled-out blocks while
         the request still runs: block j (positions [j*bp, (j+1)*bp)) is
         dead once its last position <= attend_limit - window — the band
@@ -2715,7 +2732,7 @@ class ContinuousBatcher:
         w = self._paged_window
         req = self._slot_req[slot]
         if req is not None and req.get("wblocks"):
-            self._roll_window_blocks(slot, req)
+            self._roll_window_blocks(slot, req, in_step)
         if w is None or req is None or not req["blocks"]:
             return
         bp = self._block_len
@@ -2730,7 +2747,7 @@ class ContinuousBatcher:
             self.cache["tables"].at[:, slot, freed:n_dead].set(0)
         req["freed"] = n_dead
 
-    def _roll_window_blocks(self, slot: int, req):
+    def _roll_window_blocks(self, slot: int, req, in_step: bool = True):
         """A WINDOW KIND's blocks follow the slot (paged_kvcache's module
         docstring): the next step's query stands at `limit` and reads
         (limit - W, limit], so a block wholly at or before limit - W goes
@@ -2738,16 +2755,21 @@ class ContinuousBatcher:
         the blocks ahead, up to the kind's quota, are drawn (never more
         than were just handed back or held in reserve since admission:
         the draw cannot fail). The table edits reach the device before
-        the next dispatch (`_flush_window_tables`)."""
+        the next dispatch (`_flush_window_tables`). While a capture
+        records, a roll that hands a block back inside a step's commit is
+        a `step.commit.window` span, as the flush is."""
         bp = self._block_len
         limit = req["prompt_len"] + len(req["emitted"]) - 1
         n_need = len(req["blocks"])
+        sp = None
         for t, w in self._window_kinds.items():
             got = req["wblocks"][t]
             lo = max(0, limit - w + 1) // bp
             dead = [j for j in got if j < lo]
             if not dead:
                 continue
+            if sp is None and in_step:
+                sp = _profile.open_span("step.commit.window", slot=slot)
             alloc = self._allocator.of(t)
             alloc.free([got.pop(j) for j in dead])
             self.window_blocks_freed += len(dead)
@@ -2760,13 +2782,16 @@ class ContinuousBatcher:
                     got[j] = b
                     self._wtab_pending.append((t, slot, j, b))
             self._pool_exhausted_episode = False  # blocks came free
+        _profile.close_span(sp)
 
-    def _flush_window_tables(self):
+    def _flush_window_tables(self, in_step: bool = True):
         """The pending table edits of window kinds, one program a kind:
         padded to a fixed count (the pad's slot index is out of range and
-        is dropped), so nothing compiles after the first."""
+        is dropped), so nothing compiles after the first. Each launch
+        counts (`kv_pool.window_table_flushes_total`)."""
         if not self._window_kinds or not self._wtab_pending:
             return
+        sp = _profile.open_span("step.commit.window") if in_step else None
         pend, self._wtab_pending = self._wtab_pending, []
         n = 4 * self.slots
         for t in self._window_kinds:
@@ -2775,6 +2800,8 @@ class ContinuousBatcher:
                 part = np.full((3, n), self.slots, np.int32)
                 part[:, :len(mine[i:i + n])] = np.asarray(mine[i:i + n]).T
                 self.cache[t] = self._set_tables(self.cache[t], *part)
+                self.window_table_flushes += 1
+        _profile.close_span(sp)
 
     def _free_window_kinds(self, req):
         for t, got in (req.get("wblocks") or {}).items():
@@ -2890,11 +2917,18 @@ class ContinuousBatcher:
                 self._n_index_layers * (live - n_act),
                 self._n_index_layers * picked)
         if self._cache_kinds and self.step_clock is not None:
+            series = "attn" if self._kv_kinds else "mla"
             if topk:
                 self.step_clock.note_mla_kind(
                     "decode", "full", self._n_index_layers * picked)
+            elif self._kv_kinds:
+                # each live slot's query stood at n - 2 and read n - 1
+                self.step_clock.note_mla_kind(
+                    "decode", "full",
+                    self._n_index_layers * (live - n_act), series=series)
             for (kind, n_l, _), n in zip(wins, in_window):
-                self.step_clock.note_mla_kind("decode", kind, n_l * n)
+                self.step_clock.note_mla_kind("decode", kind, n_l * n,
+                                              series=series)
         if self._latent and self.step_clock is not None:
             # each live slot's query stood at n - 2 and read n - 1 latents
             self.step_clock.note_mla(
@@ -3024,6 +3058,9 @@ class ContinuousBatcher:
 
     def _window_freed_read(self) -> float:
         return float(self.window_blocks_freed)
+
+    def _window_flushes_read(self) -> float:
+        return float(self.window_table_flushes)
 
     def _obs_retire(self, req, reason: str):
         """Close a leaving request's decode span + outcome counter +

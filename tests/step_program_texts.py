@@ -8,14 +8,16 @@ before PR 35; PR 36 re-recorded `olmoe-test`'s and `keye-test`'s chunk and
 decode programs, whose layer loops keep the expert stacks whole since, and
 left `gpt2-test`'s as they were; PR 38 re-recorded every chunk and finish
 program — the head moved from the one into the other — and no decode
-program)."""
+program; PR 43 added `joyai-test`'s and `dots3-test`'s, recorded on its
+parent commit)."""
 
 import hashlib
 import json
 import os
 import sys
 
-PRESETS = ("gpt2-test", "olmoe-test", "keye-test")
+PRESETS = ("gpt2-test", "olmoe-test", "keye-test", "joyai-test",
+           "dots3-test")
 PROGRAMS = ("_prefill_chunk", "_prefill_finish", "_decode")
 
 

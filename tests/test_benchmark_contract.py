@@ -499,7 +499,7 @@ def test_statusz_says_what_a_prefill_kernel_step_covers(served, config):
     capture); a model without latent attention has no such component."""
     comps = served(config)["statusz"]["components"]
     if not _latent(config):
-        assert "attention" not in comps
+        assert "mla_prefill" not in comps.get("attention", {})
         return
     kinds = comps["attention"]["mla_prefill"]
     assert "full" in kinds and all(kinds.values())
@@ -509,6 +509,28 @@ def test_statusz_says_what_a_prefill_kernel_step_covers(served, config):
                                     "heads_per_step"]
             assert call["columns"] > 0
             assert call["heads_per_step"] is call["block_s"] is None
+
+
+@pytest.mark.parametrize("config", sorted(_SERVED))
+def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
+    """`/statusz` `components.attention.kinds`: for a model whose K and V
+    leaves are by layer kind (it writes `attn_cached_positions_read_total`),
+    the form each kind's chunk and decode reads took in the built programs
+    — on the CPU the plain ones (on the chip "kernel" / "banded_kernel"
+    and "paged_kernel", or a daemon that fell back shows it without a
+    capture) — and the pool's bytes by the kinds' leaves; no other model
+    has the line."""
+    comps = served(config)["statusz"]["components"]
+    by_kind = any(s.startswith("attn_cached_positions_read_total")
+                  for s in _SERVED[config]["series"])
+    if not by_kind:
+        assert "kinds" not in comps.get("attention", {})
+        return
+    assert comps["attention"]["kinds"] == {
+        kind: {"prefill": "plain", "decode": "gather_einsum"}
+        for kind in ("full", "window")}
+    assert sorted(comps["kv_cache"]["bytes_by_leaf"]) == [
+        "k", "k_w", "v", "v_w"]
 
 
 # ----------------------------------------------------------------------

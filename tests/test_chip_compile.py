@@ -194,6 +194,29 @@ def test_chunked_prefill_kernel_compiles(chip, heads, d):
              ((1, heads, 64, d), BF16), cache, cache, ((1,), jnp.int32))
 
 
+# K-EXAONE's cut as its cell serves it (64 slots, 8 KV heads of 128 under
+# 8 query heads each, rows of 6144, chunks of 1024, a window of 128)
+KX = dict(slots=64, kv=8, group=8, d=128, row=6144, chunk=1024, window=128)
+
+
+@pytest.mark.parametrize("window", [None, KX["window"]],
+                         ids=["full", "banded"])
+@pytest.mark.parametrize("tile", [(128, 128), (512, 512)],
+                         ids=lambda t: "x".join(map(str, t)))
+def test_folded_prefill_kernel_compiles(chip, window, tile):
+    """A 1024-token chunk of K-EXAONE against its 6144-position row: the
+    query group folded into the kernel's rows (8 x 1024 a KV head), the
+    column tiles clamped to the live ones — and, banded, to the window's."""
+    kx = KX
+    cache = ((1, kx["kv"], kx["row"], kx["d"]), BF16)
+    _compile(chip,
+             lambda q, k, v, pos: ca.cached_attention(
+                 q, k, v, pos, rows_mod=kx["chunk"], window=window,
+                 block_q=tile[0], block_s=tile[1], interpret=False),
+             ((1, kx["kv"], kx["group"] * kx["chunk"], kx["d"]), BF16),
+             cache, cache, ((1,), jnp.int32))
+
+
 @pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (2, 8, 1024, 128)])
 @pytest.mark.parametrize("grad", [False, True], ids=["fwd", "bwd"])
 def test_flash_attention_compiles(chip, shape, grad):
