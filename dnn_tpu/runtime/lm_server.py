@@ -151,6 +151,33 @@ def _fail_future(fut, exc):
         pass
 
 
+class TokenSink:
+    """What a streamed request registers with the worker as its `on_token`:
+    the event loop its consumer runs on and the consumer's `put` (an
+    asyncio.Queue's `put_nowait`). The worker never calls into it: the
+    sinks' tokens of one loop iteration cross to `loop` in ONE
+    `call_soon_threadsafe` (`_BatcherWorker._hand_off`), and `_fan_out`,
+    on the loop's thread, gives each `put` its `("tok", (token,
+    t_commit))`."""
+
+    __slots__ = ("loop", "put")
+
+    def __init__(self, loop, put):
+        self.loop = loop
+        self.put = put
+
+
+def _fan_out(pairs, t_commit):
+    """On the event loop's thread: each `(put, token)` of one hand-off to
+    its stream's queue, in commit order. A consumer that went away loses
+    its own token only."""
+    for put, tok in pairs:
+        try:
+            put(("tok", (tok, t_commit)))
+        except Exception:  # noqa: BLE001 — one dead stream consumer
+            log.debug("token sink refused a token", exc_info=True)
+
+
 class _QueuedRequest(NamedTuple):
     """One request waiting for the batcher worker — named fields so the
     submit/admit/hold/drain sites stay self-describing (the tuple form
@@ -239,13 +266,22 @@ class _BatcherWorker(threading.Thread):
         # periodic housekeeping hook (LMServer wires lease/handoff TTL
         # sweeps): called once per loop iteration, rate-limited inside
         self.tick = None
+        # [hand-offs, tokens they carried]: `call_soon_threadsafe` calls
+        # made for TokenSinks' tokens (_hand_off). LMServer points this at
+        # its own pair, so the scrape-time counters outlive a worker
+        self.emit_counts = [0, 0]
 
     def submit(self, prompt: np.ndarray, max_new: int, seed, *,
                opts=None, on_token=None, cancel_evt=None, trace=None):
         """Queue a request. `opts` (optional dict) forwards per-request
         sampling overrides to ContinuousBatcher.submit (temperature /
-        top_k / top_p). `on_token(tok)` (optional) fires from the worker
-        thread for every token as it commits — the streaming hook.
+        top_k / top_p). `on_token` (optional) is the streaming hook: a
+        `TokenSink` (what `GenerateStream` registers) has its tokens
+        handed to its event loop ONCE a loop iteration, together with
+        every other sink's of that step, in commit order — tokens cross
+        threads once a step, not once a token; a plain callable
+        `on_token(tok)` fires from the worker thread for every token as
+        it commits, outside that batch.
         `cancel_evt` (optional threading.Event) set by the caller retires
         the request's slot at the next step boundary; its future resolves
         cancelled. `trace` (optional obs span) parents this request's
@@ -473,18 +509,47 @@ class _BatcherWorker(threading.Thread):
             # TTFT at the first committed token
         self._futures[rid] = rec
         if item.on_token is not None and first is not None:
-            self._emit_token(rid, first)
+            # handed over now, alone: TTFT does not wait for the step
+            batch = {}
+            self._emit_token(rec, rid, first, batch)
+            self._hand_off(batch)
         return True
 
-    def _emit_token(self, rid, tok):
-        rec = self._futures.get(rid)
-        if rec is None or rec["on_token"] is None:
+    def _emit_token(self, rec, rid, tok, batch):
+        """One committed token of request `rid` (record `rec`) to its
+        consumer: a TokenSink's joins `batch` ({loop: [(put, token)]},
+        which the caller hands off), a plain callable is called here."""
+        cb = rec["on_token"]
+        if cb is None:
+            return
+        if type(cb) is TokenSink:
+            batch.setdefault(cb.loop, []).append((cb.put, int(tok)))
             return
         try:
-            rec["on_token"](int(tok))
+            cb(int(tok))
         except Exception:  # noqa: BLE001 — a dead stream consumer must not
             log.debug("on_token callback failed for rid %d", rid,
                       exc_info=True)  # kill the device loop
+
+    def _hand_off(self, batch):
+        """`batch`, {loop: [(put, token)] in commit order}, to the sinks'
+        event loops: ONE `call_soon_threadsafe` a loop (the daemon has
+        one), under one `perf_counter` stamp. Each call writes the loop's
+        self-pipe and wakes its thread, which then wants the interpreter
+        lock this thread needs to launch the next step — once a step
+        instead of once a token. A closed loop loses its own tokens only."""
+        if not batch:
+            return
+        t_commit = time.perf_counter()
+        for loop, pairs in batch.items():
+            try:
+                loop.call_soon_threadsafe(_fan_out, pairs, t_commit)
+            except RuntimeError:  # the loop closed under its streams
+                log.debug("hand-off of %d tokens failed", len(pairs),
+                          exc_info=True)
+                continue
+            self.emit_counts[0] += 1
+            self.emit_counts[1] += len(pairs)
 
     def _process_cancels(self):
         """Retire cancelled requests at the step boundary: the slot
@@ -772,12 +837,15 @@ class _BatcherWorker(threading.Thread):
                 sc.loop_part("emit")
             if had_active and (sd := self.step_done) is not None:
                 sd()  # a real step completed: the watchdog is warmed
+            batch = {}  # the step's tokens for the sinks, commit order
             for rid, tok in stepped.items():  # streaming: tokens as they
                 # commit, before done-publish; the speculative batcher
                 # (and an interleaved deferred-first commit) deliver a
                 # LIST of tokens per step
                 rec = self._futures.get(rid)
-                if rec is not None and "ttft_t0" in rec:
+                if rec is None:
+                    continue
+                if "ttft_t0" in rec:
                     # interleaved admission: this is the request's FIRST
                     # committed token — record the real TTFT now
                     t0 = rec.pop("ttft_t0")
@@ -789,9 +857,12 @@ class _BatcherWorker(threading.Thread):
                             g.on_ttft(ttft)
                 if isinstance(tok, (list, tuple)):
                     for t in tok:
-                        self._emit_token(rid, t)
+                        self._emit_token(rec, rid, t, batch)
                 else:
-                    self._emit_token(rid, tok)
+                    self._emit_token(rec, rid, tok, batch)
+            # before done-publish: call_soon_threadsafe is FIFO, so each
+            # stream's "done" trails its last token
+            self._hand_off(batch)
             self._publish_done()  # submit alone can retire (budget == 1)
 
 
@@ -948,10 +1019,14 @@ class LMServer:
 
             install_memory_gauges()
         # how long a committed token waits for the event-loop thread:
-        # [sum, count, max] seconds from the worker's on_token to the
+        # [sum, count, max] seconds from the worker's hand-off to the
         # stream handler's dequeue, plain numbers only the loop thread
         # writes, read by scrape-time callables (no observe a token)
         self._emit_lag = [0.0, 0, 0.0]
+        # [hand-offs, tokens they carried] of the streams' tokens, which
+        # only the worker thread writes (_BatcherWorker._hand_off); their
+        # ratio is the tokens a wake-up of the event loop delivers
+        self._emit_counts = [0, 0]
         self._rpc_cpu_clock_id = None  # note_rpc_loop_thread()
         self._thread_cpu_last = {}
         self._install_host_gauges()
@@ -1127,7 +1202,7 @@ class LMServer:
             return
         from dnn_tpu.utils.metrics import labeled
 
-        lag = self._emit_lag
+        lag, handed = self._emit_lag, self._emit_counts
         ref = weakref.ref(self)  # the registry outlives a server
 
         def thread_cpu(thread):
@@ -1147,6 +1222,8 @@ class LMServer:
             "serving.emit_lag_seconds_sum": lambda: lag[0],
             "serving.emit_lag_seconds_count": lambda: float(lag[1]),
             "serving.emit_lag_seconds_max": lambda: lag[2],
+            "serving.emit_handoffs_total": lambda: float(handed[0]),
+            "serving.emit_tokens_total": lambda: float(handed[1]),
         }.items():
             m.set_fn(name, fn)
 
@@ -1375,8 +1452,11 @@ class LMServer:
         (the pre-ISSUE-8 behavior), spawn a successor worker and
         REQUEUE the idempotent survivors: unary requests with retry
         budget left (`attempts` < max_request_retries) and deadline
-        remaining. Streaming requests (tokens already delivered) and
-        budget-exhausted ones fail fast. Restarts are bounded —
+        remaining. A streamed request that was still queued (no token
+        delivered) is requeued too and streams from the successor: its
+        item carries its sink. Streams already admitted (tokens
+        delivered) and budget-exhausted requests fail fast. Restarts are
+        bounded —
         `worker_restarts` within a 5-minute window — so a hard-broken
         device degrades to the old fail-fast shape instead of a
         requeue loop."""
@@ -1420,8 +1500,8 @@ class LMServer:
         self.worker = new_worker
         new_worker.start()
         requeued = failed = 0
-        for _rid, it in items:
-            ok = (it.on_token is None
+        for rid, it in items:
+            ok = ((it.on_token is None or rid is None)
                   and (it.cancel_evt is None or not it.cancel_evt.is_set())
                   and it.attempts < self.max_request_retries
                   and now - it.t_q < self.request_timeout)
@@ -1512,6 +1592,7 @@ class LMServer:
             lambda: self._embed_inflight > 0)
         if self.worker_restarts > 0:
             worker.on_death = self._on_worker_death
+        worker.emit_counts = self._emit_counts
         return worker
 
     _MAX_JSON_DEPTH = 3  # regex expansion grows with depth; bound it
@@ -2142,6 +2223,10 @@ class LMServer:
     async def GenerateStream(self, request: pb.TensorRequest, context):
         """Server-streaming generate: one TensorResponse PER TOKEN as it
         commits (result_tensor = [token]); stream end = generation done.
+        The request registers a `TokenSink` (this loop, this handler's
+        queue) with the worker: the tokens a step commits, of every
+        stream, cross to this thread in one `call_soon_threadsafe` and
+        are put on their queues here (`_fan_out`).
         Client cancellation (disconnect / stream.cancel) sets the request's
         cancel event, and the batcher worker retires the slot at the next
         step boundary — a dropped stream never decodes on to its budget.
@@ -2168,21 +2253,16 @@ class LMServer:
             cancel_evt = threading.Event()
 
             lag = self._emit_lag
-
-            def on_token(tok):
-                loop.call_soon_threadsafe(
-                    q.put_nowait, ("tok", (tok, time.perf_counter())))
-
             fut = self.worker.submit(
                 np.asarray(prompt, np.int32).reshape(-1), max_new, seed,
-                opts=opts, on_token=on_token, cancel_evt=cancel_evt,
-                trace=root)
+                opts=opts, on_token=TokenSink(loop, q.put_nowait),
+                cancel_evt=cancel_evt, trace=root)
 
             def _done(f):
-                # fires in the worker thread AFTER any on_token calls for
-                # this request: call_soon_threadsafe preserves that order,
-                # so the "done" sentinel always trails the last token in
-                # the queue
+                # fires in the worker thread AFTER the hand-off of this
+                # request's last token: call_soon_threadsafe preserves
+                # that order, so the "done" sentinel always trails the
+                # last token in the queue
                 loop.call_soon_threadsafe(q.put_nowait, ("done", f))
 
             fut.add_done_callback(_done)

@@ -282,7 +282,36 @@ def test_worker_time_is_partitioned_on_the_daemons_page(served):
     assert 0 < m["serving_emit_lag_seconds_sum"] / \
         m["serving_emit_lag_seconds_count"] <= \
         m["serving_emit_lag_seconds_max"]
+    # every streamed token crossed to the event loop in some hand-off, and
+    # the handlers have taken all of them off their queues by now
+    assert 0 < m["serving_emit_handoffs_total"] <= \
+        m["serving_emit_tokens_total"]
+    assert m["serving_emit_tokens_total"] == \
+        m["serving_emit_lag_seconds_count"]
     assert m["jax_traces_total"] >= m["jax_compilations_total"] > 0
+
+
+def test_tokens_per_handoff_reads_the_daemons_two_counters(served):
+    """`srv_tokens_per_handoff` is the benchmark's own `counter_ratio` over
+    the two series the worker's hand-off counts, in each of the cells that
+    report `srv_emit_lag_ms`; on a page without them (the commit before)
+    the reader returns nothing."""
+    from chipbench import scopes
+
+    entry = {m["name"]: m for m in _BENCH["per_layer"]}
+    assert entry["srv_tokens_per_handoff"]["workloads"] == \
+        entry["srv_emit_lag_ms"]["workloads"]
+    cell = cells.resolve("kexaone-reasoning-saturated", rehearse=True)
+    reader, args = cell["per_layer"]["srv_tokens_per_handoff"]
+    assert reader is scopes.counter_ratio
+    assert args == {"num": "serving_emit_tokens_total",
+                    "den": "serving_emit_handoffs_total"}
+    m = served(sorted(_SERVED)[0])["metrics"]
+    before = dict(m, **{args["num"]: 0.0, args["den"]: 0.0})
+    ratio = reader({"metrics0": before, "metrics1": m}, **args)
+    assert ratio == m[args["num"]] / m[args["den"]] >= 1.0
+    old = {k: v for k, v in m.items() if k not in args.values()}
+    assert reader({"metrics0": old, "metrics1": old}, **args) is None
 
 
 # ----------------------------------------------------------------------

@@ -1,0 +1,313 @@
+"""One wake-up a step for all streams (runtime/lm_server.py).
+
+A streamed request registers a `TokenSink` (its event loop, its queue's
+`put_nowait`) with the batcher worker. The tokens a loop iteration commits
+cross to the event loop in ONE `call_soon_threadsafe` and are fanned out to
+the streams' queues on the loop's thread. Held here: the number of
+hand-offs a step, the order of a stream's items (commit order, `done`
+last), that a dead consumer costs the others nothing, that a plain callable
+still works, and that a requeued stream keeps streaming."""
+
+import asyncio
+import threading
+import time
+
+import jax
+import numpy as np
+import pytest
+
+from dnn_tpu import chaos
+from dnn_tpu.chaos import inject as chaos_inject
+from dnn_tpu.comm.client import NodeClient
+from dnn_tpu.models import gpt
+from dnn_tpu.runtime import lm_server
+from dnn_tpu.runtime.lm_server import (
+    LMServer,
+    TokenSink,
+    _BatcherWorker,
+    start_lm_server_in_background,
+)
+from dnn_tpu.runtime.serving import ContinuousBatcher
+from dnn_tpu.runtime.serving_spec import SpeculativeBatcher
+
+CFG = gpt.PRESETS["gpt2-test"]
+DRAFT = gpt.GPTConfig(block_size=CFG.block_size, vocab_size=CFG.vocab_size,
+                      n_layer=1, n_head=2, n_embd=32)
+
+
+def _prepared(cfg=CFG, seed=0):
+    return gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(seed), cfg), cfg)
+
+
+PROMPTS = [np.array(p, np.int32) for p in
+           ([3, 1, 4, 1, 5], [9, 2, 6, 5], [5, 3, 5, 8, 9, 7], [2, 7, 1, 8])]
+
+
+class _Loop:
+    """A real event loop on a thread of its own, behind a
+    `call_soon_threadsafe` that writes down what crossed: ("handoff",
+    tokens) for a batch of tokens, ("call",) for anything else (a `done`).
+    Whoever else has events to order among them (a wrapped step) appends
+    to `log` too."""
+
+    def __init__(self):
+        self.log = []
+        self.loop = asyncio.new_event_loop()
+        self.thread = threading.Thread(target=self.loop.run_forever,
+                                       daemon=True)
+        self.thread.start()
+
+    def call_soon_threadsafe(self, fn, *args):
+        if fn is lm_server._fan_out:
+            self.log.append(("handoff", len(args[0]),
+                             threading.current_thread().name))
+        else:
+            self.log.append(("call",))
+        return self.loop.call_soon_threadsafe(fn, *args)
+
+    def stream(self, worker, prompt, max_new, seed, **kw):
+        """Submit as `GenerateStream` does; returns (future, queue)."""
+        q = asyncio.Queue()
+        fut = worker.submit(prompt, max_new, seed,
+                            on_token=TokenSink(self, q.put_nowait), **kw)
+        fut.add_done_callback(lambda f: self.call_soon_threadsafe(
+            q.put_nowait, ("done", f)))
+        return fut, q
+
+    def items(self, q):
+        """Everything put on `q`, read on the loop's thread up to the
+        stream's `done` and whatever trails it (nothing should)."""
+        async def drain():
+            out = []
+            while not out or out[-1][0] != "done":
+                out.append(await asyncio.wait_for(q.get(), 60))
+            await asyncio.sleep(0.05)
+            while not q.empty():
+                out.append(q.get_nowait())
+            return out
+        return asyncio.run_coroutine_threadsafe(drain(), self.loop).result(90)
+
+    def close(self):
+        self.loop.call_soon_threadsafe(self.loop.stop)
+        self.thread.join(timeout=10)
+        self.loop.close()
+
+
+@pytest.fixture
+def loop():
+    lp = _Loop()
+    yield lp
+    if not lp.loop.is_closed():
+        lp.close()
+
+
+def _batcher(kind, slots=4):
+    if kind == "spec":
+        return SpeculativeBatcher(CFG, _prepared(), DRAFT,
+                                  _prepared(DRAFT, seed=1), spec_k=3,
+                                  slots=slots, max_len=48, prompt_pad=8)
+    kw = {"interleaved": {"prefill_chunk_tokens": 8, "overlap": True}}.get(
+        kind, {})
+    return ContinuousBatcher(CFG, _prepared(), slots=slots, max_len=48,
+                             prompt_pad=8, **kw)
+
+
+def _tokens(items):
+    return [v[0] for kind, v in items if kind == "tok"]
+
+
+def _finish(worker):
+    worker.stop()
+    worker.join(timeout=30)
+    assert not worker.is_alive()
+
+
+def test_one_handoff_a_step_for_all_streams(loop):
+    """Four streams admitted in one iteration and stepped together: each
+    first token crosses alone, in the `admit` part and before the step,
+    then ONE hand-off a step carries the four streams' tokens; the
+    counters read the same."""
+    b = _batcher("plain")
+    real_step = b.step
+
+    def step():
+        loop.log.append(("step",))
+        return real_step()
+
+    b.step = step
+    w = _BatcherWorker(b)
+    streams = [loop.stream(w, p, 6, 10 + i) for i, p in enumerate(PROMPTS)]
+    w.start()  # all four are queued: one iteration admits them all
+    outs = [fut.result(timeout=120) for fut, _ in streams]
+    _finish(w)
+    events = [e[:2] for e in loop.log if e[0] != "call"]
+    assert events[:5] == [("handoff", 1)] * 4 + [("step",)]
+    after = events[4:]
+    assert after == [("step",), ("handoff", 4)] * 5, after
+    assert {e[2] for e in loop.log if e[0] == "handoff"} == {"lm-batcher"}
+    assert w.emit_counts == [4 + 5, 4 + 4 * 5]
+    for (_, q), out in zip(streams, outs):
+        assert _tokens(loop.items(q)) == [int(t) for t in out]
+
+
+@pytest.mark.parametrize("kind,max_new", [
+    ("plain", 6), ("plain", 1), ("interleaved", 6), ("interleaved", 1),
+    ("spec", 9), ("spec", 1)])
+def test_stream_items_in_commit_order_done_last(loop, kind, max_new):
+    """Each stream's queue holds its tokens in commit order, equal to the
+    future's result, with `done` as its last item: for a request that
+    retires in the iteration that admitted it (budget 1), for a deferred
+    first token (interleaved admission), and for the speculative batcher's
+    list of tokens a step."""
+    w = _BatcherWorker(_batcher(kind, slots=3))
+    streams = [loop.stream(w, p, max_new, 20 + i)
+               for i, p in enumerate(PROMPTS)]  # 4 requests, 3 slots
+    w.start()
+    outs = [fut.result(timeout=120) for fut, _ in streams]
+    _finish(w)
+    for (fut, q), out in zip(streams, outs):
+        items = loop.items(q)
+        assert [k for k, _ in items] == ["tok"] * max_new + ["done"]
+        assert _tokens(items) == [int(t) for t in out]
+        assert items[-1][1] is fut
+        stamps = [v[1] for k, v in items if k == "tok"]
+        assert stamps == sorted(stamps)
+    handoffs, tokens = w.emit_counts
+    assert tokens == 4 * max_new and 0 < handoffs <= tokens
+    if kind == "spec" and max_new > 1:
+        # a step commits several tokens of a stream: fewer hand-offs than
+        # one a stream a token
+        assert handoffs < tokens
+
+
+def test_streamed_tokens_equal_unary_over_the_wire():
+    """Concurrent streams through `GenerateStream` itself: every client
+    reads the unary result of the same seeded request, token by token."""
+    port = 59341
+    t, stop = start_lm_server_in_background(
+        CFG, _prepared(), port=port, slots=4, max_len=48, prompt_pad=8,
+        default_max_new=8)
+    try:
+        c = NodeClient(f"127.0.0.1:{port}")
+        want = [[int(x) for x in c.generate(p, max_new_tokens=8,
+                                            seed=30 + i)]
+                for i, p in enumerate(PROMPTS)]
+        got = [None] * len(PROMPTS)
+
+        def read(i):
+            ci = NodeClient(f"127.0.0.1:{port}")
+            got[i] = list(ci.generate_stream(PROMPTS[i], max_new_tokens=8,
+                                             seed=30 + i))
+            ci.close()
+
+        readers = [threading.Thread(target=read, args=(i,))
+                   for i in range(len(PROMPTS))]
+        for r in readers:
+            r.start()
+        for r in readers:
+            r.join(timeout=120)
+        assert got == want
+        c.close()
+    finally:
+        stop()
+
+
+@pytest.mark.parametrize("gone", ["put_raises", "loop_closed"])
+def test_a_gone_consumer_costs_the_others_nothing(loop, gone):
+    """One stream's queue refuses its tokens, or its event loop is closed:
+    the other streams of the batch receive every token, the worker lives
+    and serves the next request."""
+    w = _BatcherWorker(_batcher("plain"))
+    streams = [loop.stream(w, p, 6, 40 + i)
+               for i, p in enumerate(PROMPTS[:3])]
+    if gone == "put_raises":
+        def put(item):
+            raise RuntimeError("consumer went away")
+        sink = TokenSink(loop, put)
+    else:
+        dead = asyncio.new_event_loop()
+        dead.close()
+        sink = TokenSink(dead, lambda item: None)
+    lost = w.submit(PROMPTS[3], 6, 43, on_token=sink)
+    w.start()
+    outs = [fut.result(timeout=120) for fut, _ in streams]
+    assert len(lost.result(timeout=120)) == 6
+    for (_, q), out in zip(streams, outs):
+        items = loop.items(q)
+        assert _tokens(items) == [int(t) for t in out]
+        assert items[-1][0] == "done"
+    fut, q = loop.stream(w, PROMPTS[0], 3, 44)
+    assert len(fut.result(timeout=120)) == 3 and w.is_alive()
+    assert len(_tokens(loop.items(q))) == 3
+    _finish(w)
+
+
+def test_plain_callable_fires_a_token_from_the_worker_thread(loop):
+    """A plain `on_token` callable beside two sinks: called once a token,
+    on the worker thread, and not counted among the hand-offs."""
+    w = _BatcherWorker(_batcher("plain"))
+    called = []
+    plain = w.submit(PROMPTS[0], 5, 50, on_token=lambda tok: called.append(
+        (tok, threading.current_thread().name)))
+    streams = [loop.stream(w, p, 5, 51 + i)
+               for i, p in enumerate(PROMPTS[1:3])]
+    w.start()
+    out = plain.result(timeout=120)
+    for fut, _ in streams:
+        fut.result(timeout=120)
+    _finish(w)
+    assert [t for t, _ in called] == [int(t) for t in out]
+    assert {name for _, name in called} == {"lm-batcher"}
+    assert w.emit_counts[1] == 2 * 5
+
+
+def test_a_requeued_stream_keeps_streaming(loop):
+    """The worker dies with a streamed request still queued (no token
+    delivered): the death hook requeues it and the successor worker
+    streams it, tokens equal to an undisturbed run; the counters go on
+    from the predecessor's."""
+    srv = LMServer(CFG, _prepared(), slots=1, max_len=32, prompt_pad=8,
+                   default_max_new=6, worker_restarts=2)
+    try:
+        base = [srv.worker.submit(p, 6, 60 + i).result(timeout=120)
+                for i, p in enumerate(PROMPTS[:2])]
+        first = srv.worker
+        # hold the worker at the top of an iteration until both requests
+        # are queued, so that the one slot leaves the stream in the queue
+        held, gate = threading.Event(), threading.Event()
+        first.submit_control(lambda: (held.set(), gate.wait(60)))
+        assert held.wait(30)
+        chaos.install({"seed": 0, "faults": [
+            {"kind": "step_fault", "at_n": 0, "count": 1}]})
+        unary = first.submit(PROMPTS[0], 6, 60)
+        fut, q = loop.stream(first, PROMPTS[1], 6, 61)
+        gate.set()
+        np.testing.assert_array_equal(unary.result(timeout=120), base[0])
+        np.testing.assert_array_equal(fut.result(timeout=120), base[1])
+        assert srv.worker is not first and not first.is_alive()
+        items = loop.items(q)
+        assert _tokens(items) == [int(t) for t in base[1]]
+        assert items[-1][0] == "done"
+        assert srv.worker.emit_counts is first.emit_counts
+        assert srv.worker.emit_counts[1] == 6
+    finally:
+        chaos_inject.uninstall()
+        srv.close()
+
+
+def test_emit_lag_is_stamped_once_a_handoff(loop):
+    """The streams' tokens of one step carry one `perf_counter` stamp, the
+    worker's at the hand-off (what `serving_emit_lag_seconds_*` start
+    from), not later than the loop thread's own clock when it fans out."""
+    w = _BatcherWorker(_batcher("plain"))
+    streams = [loop.stream(w, p, 4, 70 + i) for i, p in enumerate(PROMPTS)]
+    w.start()
+    for fut, _ in streams:
+        fut.result(timeout=120)
+    _finish(w)
+    now = time.perf_counter()
+    by_stream = [[v[1] for k, v in loop.items(q) if k == "tok"]
+                 for _, q in streams]
+    for step in range(1, 4):  # stamps of the three common steps
+        assert len({stamps[step] for stamps in by_stream}) == 1
+    assert all(s <= now for stamps in by_stream for s in stamps)
