@@ -282,13 +282,17 @@ def build_parser() -> argparse.ArgumentParser:
                         "interleave (the grammar DFA advances on "
                         "device)")
     p.add_argument("--overlap", action="store_true",
-                   help="--serve_lm: double-buffered dispatch — the "
-                        "worker dispatches step N+1's device work "
-                        "before committing step N's tokens, hiding "
-                        "host bookkeeping under the device step "
-                        "(tokens surface one step later). JSON-mode "
-                        "constraints ride the overlap (the device DFA "
-                        "walk is idempotent under the replayed step)")
+                   help="--serve_lm: the one-step dispatch pipeline — "
+                        "the worker dispatches step N+1's device work "
+                        "before it reads step N's tokens, hiding the "
+                        "launch and the host's bookkeeping under the "
+                        "device step (a retirement is seen one step "
+                        "later). It is the daemon's DEFAULT for every "
+                        "model family: the flag is accepted and changes "
+                        "nothing, except with --draft_model, whose "
+                        "speculative batcher pipelines only when asked "
+                        "here. JSON-mode constraints ride it (the device "
+                        "DFA walk is idempotent under the replayed step)")
     p.add_argument("--tokenizer", default=None,
                    help="--serve_lm: text endpoint tokenizer — 'bytes' "
                         "(UTF-8 bytes as ids; any vocab >= 256) or a LOCAL "
@@ -1062,12 +1066,11 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
             availability=args.slo_avail,
             target=args.slo_target
             if args.slo_target is not None else 0.99)
-    if args.prefill_chunk_tokens or args.overlap:
-        log.info("overlap/interleave serving enabled "
-                 "(prefill_chunk_tokens=%d, overlap=%s): JSON-mode "
-                 "constraints ride this hot path too (the grammar DFA "
-                 "walks on device)",
-                 args.prefill_chunk_tokens, args.overlap)
+    if args.prefill_chunk_tokens:
+        log.info("interleaved admission enabled "
+                 "(prefill_chunk_tokens=%d): JSON-mode constraints ride "
+                 "this hot path too (the grammar DFA walks on device)",
+                 args.prefill_chunk_tokens)
     # publish the boot gauges the caplens cold-start ledger scrapes:
     # each bucket is an independent child-side measurement (weight
     # spans subtract the compile seconds that landed inside them, so
@@ -1114,7 +1117,10 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
             kv_lease_ttl_s=args.kv_lease_ttl_s,
             kv_handoff_ttl_s=args.kv_handoff_ttl_s,
             prefill_chunk_tokens=args.prefill_chunk_tokens,
-            overlap=args.overlap,
+            # the step loop is the server's to choose (LMServer: the
+            # pipeline wherever the batcher's class runs it by default);
+            # the flag only asks for it where it is not the default
+            **({"overlap": True} if args.overlap else {}),
             # the daemon's clients choose options per request, so the
             # per-slot bias capability is on at this edge — except for
             # speculative serving, whose batcher rejects per-request

@@ -1357,6 +1357,13 @@ class LMServer:
             comps["kv_cache"] = {
                 "detail": "bytes of the KV cache by leaf",
                 "bytes": sum(by_leaf.values()), "bytes_by_leaf": by_leaf}
+        # facts, no `state`: which loop the worker's step() runs, why,
+        # and how often the pipeline engaged (`step_pipelined_total`,
+        # `step_stale_rows_total` on /metrics)
+        loop = getattr(self.batcher, "step_loop", None)
+        if loop is not None:
+            comps["batcher"] = {
+                "detail": "the serving worker's step loop", **loop()}
         s["components"] = comps
         if self._kvtier_on():
             # KV-tier residency rides /statusz (informational): the
@@ -1548,12 +1555,17 @@ class LMServer:
             # tokens per device step (runtime/serving_spec.py)
             from dnn_tpu.runtime.serving_spec import SpeculativeBatcher
 
-            self.batcher = SpeculativeBatcher(
-                cfg, prepared, draft_cfg, draft_prepared, spec_k=spec_k,
-                **batcher_kwargs)
+            cls, models = SpeculativeBatcher, (
+                cfg, prepared, draft_cfg, draft_prepared)
+            batcher_kwargs["spec_k"] = spec_k
         else:
-            self.batcher = ContinuousBatcher(cfg, prepared,
-                                             **batcher_kwargs)
+            cls, models = ContinuousBatcher, (cfg, prepared)
+        # the worker's step loop: the one-step dispatch pipeline (step
+        # N+1 launched before step N is read) wherever the batcher's
+        # class runs it by default — every family and cache of the dense
+        # batcher; a speculative one only when `overlap=True` was passed
+        batcher_kwargs.setdefault("overlap", cls._daemon_pipelines)
+        self.batcher = cls(*models, **batcher_kwargs)
         self.default_max_new = default_max_new
         self.request_timeout = request_timeout
         # optional text front (dnn_tpu/io/tokenizer.py): with it,

@@ -277,6 +277,14 @@ class ContinuousBatcher:
     # initialization-order hazard to refactor away.
     _constraints_ok = True
 
+    # the step loop a DAEMON runs over this class when its caller did not
+    # say (lm_server.LMServer hands it to `overlap=`; the one place that
+    # decision is made): the one-step dispatch pipeline, for every family
+    # and cache this class serves. SpeculativeBatcher keeps False: its
+    # callers ask for its pipeline by name, as before. A batcher built
+    # directly keeps `overlap=False`: step() then returns its own tokens.
+    _daemon_pipelines = True
+
     def __init__(self, cfg: GPTConfig, prepared, *, slots: int = 4,
                  max_len: Optional[int] = None, prompt_pad: Optional[int] = None,
                  temperature: float = 0.0, top_k: Optional[int] = None,
@@ -389,9 +397,12 @@ class ContinuousBatcher:
             elif kv_dtype in ("int8", "int4"):
                 refused = (f"an {kv_dtype} KV pool (its scale leaves "
                            "assume K and V alone)")
-            elif prefill_chunk_tokens or overlap:
-                refused = ("interleaved / overlapped prefill (its mixed "
-                           "step was built for K and V alone)")
+            elif prefill_chunk_tokens:
+                # the one-step dispatch pipeline (overlap=) is NOT refused:
+                # it launches the very decode program this family runs and
+                # never the mixed step
+                refused = ("interleaved prefill (its mixed step was built "
+                           "for K and V alone)")
             if refused is not None:
                 raise ValueError(
                     f"this model's cache has the leaves {leaves}: "
@@ -752,6 +763,10 @@ class ContinuousBatcher:
             # itemsize walk would overstate an int4 pool 2x
             # (obs/mem.logical_nbytes owns the dtype pricing)
             "serving.kv_cache_bytes": _weak_gauge("_kv_bytes_read"),
+            # the step loop's pipeline (see `steps_pipelined` below),
+            # beside the clock's `step.steps_total`
+            "step.pipelined_total": _weak_gauge("_pipelined_read"),
+            "step.stale_rows_total": _weak_gauge("_stale_rows_read"),
         }
         self._kv_live_hw = 0
         self._active_hw = 0
@@ -961,7 +976,14 @@ class ContinuousBatcher:
                 # pre-temperature — the usual serving-API convention)
                 out += _lp_outputs(logits, nxt)
             if self._moe_stats:
-                out += (moe,)  # last, after the optional logprobs
+                out += (moe,)  # after the optional logprobs
+            if self._overlap:
+                # the step's tokens once more, as a result that is never
+                # fed back and so never donated: what the pipeline's commit
+                # reads a dispatch later, when `nxt`'s own buffer has gone
+                # into the next step (the compiler gives a result that
+                # appears twice a buffer each). Last of the core's results.
+                out += (nxt,)
             return out
 
         def decode_step(prepared, cache, pos, tok, active, keys,
@@ -1283,6 +1305,14 @@ class ContinuousBatcher:
         # StepClock reports, actually spent. Tokens surface one step()
         # call later; drain()/flush_overlap() commit the trailing step.
         self._overlap = bool(overlap)
+        # how often the pipeline engaged, and what it cost (scrape-time
+        # `step.pipelined_total` / `step.stale_rows_total`): steps that
+        # were dispatched while the step before them was uncommitted, and
+        # slot-rows a dispatched step computed whose token no commit took
+        # (the step went out past a retirement or a cancel: the one step
+        # by which the pipeline learns of either late)
+        self.steps_pipelined = 0
+        self.stale_rows = 0
         # allow_constraints composes with the one-step pipeline since
         # the DFA walk moved on device: step N+1's mask row comes from
         # the crow that step N's program computed and carried — never
@@ -1292,7 +1322,8 @@ class ContinuousBatcher:
         self._pending_q: List[int] = []   # slots awaiting interleaved
         # prefill, FIFO (one chunk folds per step)
         self._inflight = None             # overlap: the dispatched,
-        # not-yet-committed step — (step_idx, token refs, logprob refs)
+        # not-yet-committed step — (step_idx, token refs, logprob refs,
+        # slot-rows live at its dispatch)
         self._step_idx = 0                # monotonically counts dispatches;
         # install_step gating keys off it (a slot's decode tokens exist
         # only for steps dispatched AFTER its fused finish)
@@ -1317,6 +1348,20 @@ class ContinuousBatcher:
         # on (rebuilt whenever a slot's adapter assignment changes — same
         # structure, so the same compiled program), plain prepared when off
         self._decode_view = self._lora_prepared(self._aid)
+
+    def step_loop(self) -> dict:
+        """Which loop step() runs and why (/statusz `components.batcher`)."""
+        if not self._overlap:
+            return {"loop": "synchronous", "depth": 0,
+                    "why": "overlap=False (a batcher built directly, or a "
+                           "speculative one under a daemon): each step is "
+                           "read before the next is dispatched"}
+        return {"loop": "pipelined", "depth": 1,
+                "why": "overlap=True (what a daemon asks of a dense "
+                       "batcher): step N+1 is dispatched before step N is "
+                       "read",
+                "steps_pipelined": self.steps_pipelined,
+                "stale_rows": self.stale_rows}
 
     def jit_programs(self):
         """The batcher's compiled entry points — what a long-lived server
@@ -3056,6 +3101,12 @@ class ContinuousBatcher:
     def _kind_used_read(self, tables: str) -> float:
         return float(self._allocator.of(tables).n_used)
 
+    def _pipelined_read(self) -> float:
+        return float(self.steps_pipelined)
+
+    def _stale_rows_read(self) -> float:
+        return float(self.stale_rows)
+
     def _window_freed_read(self) -> float:
         return float(self.window_blocks_freed)
 
@@ -3279,7 +3330,8 @@ class ContinuousBatcher:
         req["install_step"] = s_idx
         del req["pending"]
 
-    def _commit_step(self, s_idx, toks, c_lp, t_lp, t_ids, rec, sc):
+    def _commit_step(self, s_idx, toks, c_lp, t_lp, t_ids, rec, sc,
+                     n_rows: int = 0):
         """Commit one completed step's tokens to host bookkeeping.
         `s_idx` names the DISPATCH this data came from: a slot whose
         fused admission finish landed at install_step >= s_idx had no
@@ -3287,10 +3339,13 @@ class ContinuousBatcher:
         and is skipped; the first commit past the install materializes
         the deferred first token (and its logprobs) ahead of the
         step's own token. Returns {rid: token | [tokens]} (a list when
-        the deferred first commits together with a decode token)."""
+        the deferred first commits together with a decode token).
+        `n_rows`: the slot-rows that were live when the pipeline
+        dispatched this step; those no commit takes are stale rows."""
         m = obs.metrics()
         t_now = time.perf_counter() if m is not None else 0.0
         n_adv = 0
+        n_dec = 0  # rows of `toks` committed
         it_samples: list = []
         out = {}
         for slot, req in enumerate(self._slot_req):
@@ -3325,6 +3380,7 @@ class ContinuousBatcher:
                     self._retire_if_done(slot)
             if self._slot_req[slot] is req:
                 token = int(toks[slot])
+                n_dec += 1
                 req["emitted"].append(token)
                 if req["logprobs"]:
                     req["lp"].append(float(c_lp[slot]))
@@ -3342,6 +3398,8 @@ class ContinuousBatcher:
                 n_adv += len(committed)
                 out[req["rid"]] = (committed[0] if len(committed) == 1
                                    else committed)
+        if n_rows > n_dec:
+            self.stale_rows += n_rows - n_dec
         self._flush_window_tables()
         if rec is not None:
             sc.mark(rec, "commit")
@@ -3402,13 +3460,14 @@ class ContinuousBatcher:
             return {}
         sc = self.step_clock
         rec = sc.begin("wait") if sc is not None else None
-        p_idx, p_tok, p_lps = self._inflight
+        p_idx, p_tok, p_lps, p_rows = self._inflight
         self._inflight = None
         toks = np.asarray(p_tok)
         c_lp, t_lp, t_ids = self._lp_host(p_lps)
         if rec is not None:
             sc.mark(rec, "wait")
-        return self._commit_step(p_idx, toks, c_lp, t_lp, t_ids, rec, sc)
+        return self._commit_step(p_idx, toks, c_lp, t_lp, t_ids, rec, sc,
+                                 p_rows)
 
     def step(self) -> Dict[int, int]:
         """One decode step for every active slot. Returns {rid: token}
@@ -3464,6 +3523,8 @@ class ContinuousBatcher:
             rec.mixed = ilv is not None
         s_idx = self._step_idx
         self._step_idx += 1
+        if self._overlap:
+            res, snap = res[:-1], res[-1]  # the core's last result
         if self._moe_stats:
             self._moe_note("decode", res[-1], s_idx)
             res = res[:-1]
@@ -3482,16 +3543,22 @@ class ContinuousBatcher:
         if self._overlap:
             if sc is not None:
                 sc.overlap_depth = 1
-            # snapshot THIS step's committed tokens before the next
-            # dispatch donates their buffer: jnp.copy enqueues its read
-            # ahead of the donation, and in-order device execution
-            # makes the copied value safe. The logprob outputs are
-            # never fed back (hence never donated) — bare refs suffice.
-            keep = (s_idx, jnp.copy(self.tok), lp_refs)
+            # THIS step's tokens are read a dispatch later, when the next
+            # step has taken `self.tok`'s buffer by donation: `snap` is
+            # the program's own second copy of them (the decode core's
+            # last result — no launch of its own). The logprob outputs
+            # are never fed back (hence never donated) — bare refs
+            # suffice. The rows live now are what the commit counts its
+            # stale rows against.
+            keep = (s_idx, snap, lp_refs,
+                    sum(r is not None and "pending" not in r
+                        and r.get("install_step") != s_idx
+                        for r in self._slot_req))
             prev, self._inflight = self._inflight, keep
             if prev is None:
                 return self._pipeline_fill_end(rec, sc)
-            p_idx, p_tok, p_lps = prev
+            self.steps_pipelined += 1
+            p_idx, p_tok, p_lps, p_rows = prev
             toks = np.asarray(p_tok)
             c_lp, t_lp, t_ids = self._lp_host(p_lps)
             if rec is not None:
@@ -3500,7 +3567,7 @@ class ContinuousBatcher:
                 # dispatch_slack gauge predicted, verified here
                 sc.mark(rec, "wait")
             return self._commit_step(p_idx, toks, c_lp, t_lp, t_ids,
-                                     rec, sc)
+                                     rec, sc, p_rows)
         toks = np.asarray(self.tok)
         c_lp, t_lp, t_ids = self._lp_host(lp_refs)
         if rec is not None:

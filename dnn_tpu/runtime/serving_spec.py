@@ -97,6 +97,7 @@ class SpeculativeBatcher(ContinuousBatcher):
     # a verified chunk commits up to k+1 tokens in one device call —
     # per-token grammar masks cannot gate it (submit rejects constraint=)
     _constraints_ok = False
+    _daemon_pipelines = False  # overlap= stays its callers' to ask for
 
     def __init__(self, cfg: GPTConfig, prepared, draft_cfg: GPTConfig,
                  draft_prepared, *, spec_k: int = 4, draft_family=None,
@@ -668,6 +669,7 @@ class SpeculativeBatcher(ContinuousBatcher):
             prev, self._inflight = self._inflight, keep
             if prev is None:
                 return self._pipeline_fill_end(rec, sc)
+            self.steps_pipelined += 1
             s_prev, w_prev, m_prev = prev
             w_np, m_np = np.asarray(w_prev), np.asarray(m_prev)
             if rec is not None:

@@ -314,6 +314,37 @@ def test_tokens_per_handoff_reads_the_daemons_two_counters(served):
     assert reader({"metrics0": old, "metrics1": old}, **args) is None
 
 
+def test_steps_pipelined_share_reads_the_batchers_two_counters(served):
+    """`srv_steps_pipelined_share` is the benchmark's own `counter_ratio`
+    over `step_pipelined_total` (the batcher's: steps dispatched while the
+    step before them was uncommitted) and the clock's `step_steps_total`, in
+    each `out_tok_s` cell; a daemon started as the benchmark starts it, with
+    no flag, pipelines and says so on `/statusz`; on a page without the
+    counter (the commit before) the reader returns nothing."""
+    from chipbench import scopes
+
+    entry = {m["name"]: m for m in _BENCH["per_layer"]}
+    e2e = {m["name"]: m for m in _BENCH["end_to_end"]}
+    assert entry["srv_steps_pipelined_share"]["workloads"] == \
+        e2e["out_tok_s"]["workloads"]
+    cell = cells.resolve("olmoe-chat-saturated", rehearse=True)
+    reader, args = cell["per_layer"]["srv_steps_pipelined_share"]
+    assert reader is scopes.counter_ratio
+    assert args == {"num": "step_pipelined_total", "den": "step_steps_total"}
+    for name in sorted(_SERVED):
+        found = served(name)
+        m = found["metrics"]
+        loop = found["statusz"]["components"]["batcher"]
+        assert loop["loop"] == "pipelined" and loop["depth"] == 1
+        assert 0 < m["step_pipelined_total"] < m["step_steps_total"]
+        assert 0 <= m["step_stale_rows_total"] <= m["step_pipelined_total"]
+        before = dict(m, **{args["num"]: 0.0, args["den"]: 0.0})
+        share = reader({"metrics0": before, "metrics1": m}, **args)
+        assert share == m[args["num"]] / m[args["den"]] > 0.5
+    old = {k: v for k, v in m.items() if k != args["num"]}
+    assert reader({"metrics0": old, "metrics1": old}, **args) is None
+
+
 # ----------------------------------------------------------------------
 # hosttime's arithmetic, on a stretch of a real capture
 # ----------------------------------------------------------------------

@@ -435,3 +435,279 @@ def test_worker_streams_interleaved_and_overlap_tokens(model):
     finally:
         w.stop()
         w.join(timeout=10)
+
+
+# ----------------------------------------------------------------------
+# ISSUE 45 — the pipeline is the daemon's step loop for every family
+# ----------------------------------------------------------------------
+
+#: one test-size model of each family the daemon serves: K/V (GPT-2),
+#: expert layers (OLMoE), an index-key leaf (Keye's `DsaFamilyRows`), one
+#: latent leaf (JoyAI's `MlaFamilyRows`), two latent kinds with a window
+#: kind (dots3's), K/V kinds with a window (K-EXAONE's)
+FAMILIES = ["gpt2-test", "olmoe-test", "keye-test", "joyai-test",
+            "dots3-test", "k-exaone-test"]
+_BUILT: dict = {}
+
+
+def _family(name):
+    """(cfg, prepared, family factory or None), built once a module."""
+    if name not in _BUILT:
+        from dnn_tpu.registry import get_model
+
+        spec = get_model(name)
+        cfg = spec.config
+        params = spec.init(jax.random.PRNGKey(3))
+        _BUILT[name] = (cfg, gpt.prepare_stacked(dict(params), cfg),
+                        (spec.extras or {}).get("family_rows"))
+    return _BUILT[name]
+
+
+def _family_batcher(name, **kw):
+    cfg, prepared, rows = _family(name)
+    opts = dict(slots=3, max_len=64, prompt_pad=16, kv="paged", block_len=8)
+    if rows is not None:
+        opts["family"] = rows()
+    opts.update(kw)
+    return ContinuousBatcher(cfg, prepared, **opts)
+
+
+def _prompt(n, seed):
+    return np.asarray(jax.random.randint(
+        jax.random.PRNGKey(seed), (n,), 1, 256), np.int32)
+
+
+#: every second request of `_script` samples, the others are greedy
+_SAMPLED = {"temperature": 0.9, "top_k": 12, "repetition_penalty": 1.2}
+
+
+def _script(srv):
+    """Admissions, retirements and a cancel between step() calls — under
+    the pipeline each lands while a step is in flight: three requests
+    fill the pool, one joins mid-decode after a CANCEL freed its slot
+    (the in-flight step still holds the cancelled row), one more when a
+    retirement frees a slot (admitted under the retired row's stale
+    step). Greedy and sampled requests side by side. Returns every
+    finished request's tokens by submission order."""
+    rids = [srv.submit(_prompt(9, 1), 14, seed=0),
+            srv.submit(_prompt(20, 2), 4, seed=1, **_SAMPLED),
+            srv.submit(_prompt(12, 3), 30, seed=2)]
+    for _ in range(3):
+        srv.step()
+    assert srv.cancel(rids[0])
+    rids.append(srv.submit(_prompt(17, 4), 9, seed=3, **_SAMPLED))
+    while not srv.free_slots():
+        srv.step()
+    rids.append(srv.submit(_prompt(5, 5), 11, seed=4, **_SAMPLED))
+    srv.drain()
+    return [srv.results[r].tolist() for r in rids[1:]]
+
+
+@pytest.mark.parametrize("name", FAMILIES)
+def test_pipeline_equals_synchronous_loop_for_every_family(name):
+    """Token streams of the pipeline equal the synchronous loop's, greedy
+    and sampled draw for draw, with admissions, retirements and a cancel
+    landing while a step is in flight; the stale rows are counted and
+    every block comes back."""
+    base = _script(_family_batcher(name))
+    srv = _family_batcher(name, overlap=True)
+    free = [a.n_free for a in _allocators(srv)]
+    assert _script(srv) == base
+    assert [len(t) for t in base] == [4, 30, 9, 11]
+    # one row the cancel left in flight, one a retirement (the last three
+    # retire into an emptying pool: their stale rows count too)
+    assert srv.steps_pipelined >= 25 and 2 <= srv.stale_rows <= 5
+    assert srv._inflight is None
+    assert [a.n_free for a in _allocators(srv)] == free
+
+
+def _allocators(srv):
+    """The pool's allocator and each window kind's."""
+    return [srv._allocator, *(srv._allocator.of(t)
+                              for t in srv._window_kinds)]
+
+
+def _window_batcher(name, **kw):
+    if name == "llama-window":  # a whole-family window: `_paged_window`
+        from dnn_tpu.models import llama
+
+        cfg = llama.LlamaConfig(block_size=64, vocab_size=256, n_layer=2,
+                                n_head=2, n_kv_head=1, n_embd=32,
+                                sliding_window=9)
+        if name not in _BUILT:
+            _BUILT[name] = gpt.prepare_stacked(
+                llama.init(jax.random.PRNGKey(5), cfg), cfg)
+        return ContinuousBatcher(
+            cfg, _BUILT[name], slots=2, max_len=64, prompt_pad=8,
+            kv="paged", block_len=8, family=llama.LlamaFamilyRows(cfg), **kw)
+    return _family_batcher(name, slots=2, **kw)
+
+
+def _watch_dispatches(srv, slot=0):
+    """Record, at every decode dispatch, the slot's row of each table the
+    program is handed and the slot's position."""
+    seen, real = [], srv._decode
+
+    def decode(view, cache, pos, *rest):
+        seen.append(({t: np.asarray(cache[t])[:, slot].copy()
+                      for t in cache if t.startswith("tables")},
+                     int(np.asarray(pos)[slot])))
+        return real(view, cache, pos, *rest)
+
+    srv._decode = decode
+    return seen
+
+
+@pytest.mark.parametrize("name", ["dots3-test", "k-exaone-test",
+                                  "llama-window"])
+def test_window_blocks_under_the_pipeline_are_the_synchronous_loops(name):
+    """A slot decoded to more than 3x its window: every dispatch of the
+    pipeline reads and writes, inside each kind's band, the very blocks
+    the synchronous loop's dispatch at that position used (its tables are
+    one roll older: what differs lies outside the band), the tables after
+    each commit are equal, and so are the allocators at the end."""
+    ref, srv = _window_batcher(name), _window_batcher(name, overlap=True)
+    windows = dict(srv._window_kinds)
+    if srv._paged_window is not None:
+        windows["tables"] = srv._paged_window
+    assert windows and max(windows.values()) <= 9
+    bp, n_new = srv._block_len, 40
+    seen_ref, seen_srv = _watch_dispatches(ref), _watch_dispatches(srv)
+    for b in (ref, srv):
+        b.submit(_prompt(11, 7), n_new, seed=0)
+    assert srv.step() == {}  # fills the pipeline
+    n = 0
+    while srv.n_active:
+        assert srv.step() == ref.step()
+        n += 1
+        for t in srv.cache:
+            if t.startswith("tables"):
+                np.testing.assert_array_equal(
+                    np.asarray(srv.cache[t]), np.asarray(ref.cache[t]))
+        if srv._slot_req[0] is not None:
+            assert srv._slot_req[0].get("wblocks") == \
+                ref._slot_req[0].get("wblocks")
+            assert srv._slot_req[0]["freed"] == ref._slot_req[0]["freed"]
+    assert n == n_new - 1 >= 3 * max(windows.values())
+    assert srv.results[0].tolist() == ref.results[0].tolist()
+    assert srv.flush_overlap() == {}
+    # the pipeline dispatched one step more: the stale one
+    assert len(seen_srv) == len(seen_ref) + 1 and srv.stale_rows == 1
+    for (tab_s, pos_s), (tab_r, pos_r) in zip(seen_srv, seen_ref):
+        assert pos_s == pos_r
+        for t, w in windows.items():
+            band = slice(max(0, pos_s - w + 1) // bp, pos_s // bp + 1)
+            np.testing.assert_array_equal(tab_s[t][:, band],
+                                          tab_r[t][:, band])
+            assert (tab_s[t][:, band] > 0).all()  # none of it the junk block
+    assert [a.n_free for a in _allocators(srv)] == \
+        [a.n_free for a in _allocators(ref)]
+    if srv._window_kinds:
+        assert srv.window_blocks_freed == ref.window_blocks_freed > 0
+
+
+@pytest.mark.parametrize("name", ["gpt2-test", "dots3-test",
+                                  "k-exaone-test"])
+def test_stale_step_at_max_len_writes_no_live_block(name):
+    """A request that ends at `max_len` with its last allocated position
+    unwritten: the stale step dispatched past its retirement stands at
+    that position (never past the table) and writes a block of the
+    RETIRED request alone, or the junk block — never one a live request
+    holds — and the request admitted into those blocks next reads what
+    the synchronous loop's does."""
+    ref, srv = (_family_batcher(name, slots=2),
+                _family_batcher(name, slots=2, overlap=True))
+    seen = _watch_dispatches(srv, slot=1)
+    max_len, bp = srv.max_len, srv._block_len
+    outs = []
+    for b in (ref, srv):
+        other = b.submit(_prompt(6, 9), 40, seed=5)  # a live neighbour
+        last = b.submit(_prompt(max_len - 12, 8), 12, seed=6)
+        held = {t: set(np.asarray(b.cache[t])[0, 1].tolist()) - {0}
+                for t in b.cache if t.startswith("tables")}
+        while last not in b.results:
+            b.step()
+        live = {t: set(np.asarray(b.cache[t])[0, 0].tolist()) - {0}
+                for t in b.cache if t.startswith("tables")}
+        again = b.submit(_prompt(max_len - 30, 10), 6, seed=7)
+        b.drain()
+        outs.append([b.results[r].tolist() for r in (other, last, again)])
+    assert outs[0] == outs[1]
+    # the stale dispatch: the last one that held slot 1's request
+    tabs, pos = [s for s in seen if s[1] == max_len - 1][0]
+    assert pos == max_len - 1
+    for t, row in tabs.items():
+        target = int(row[0, pos // bp])
+        assert target not in live[t]
+        if t not in srv._window_kinds:  # (a window kind's blocks change
+            # hands while a request runs: `held` is its admission's)
+            assert target == 0 or target in held[t]
+
+
+def test_the_daemon_pipelines_by_default(model):
+    """An `LMServer` built with no flag runs the pipeline — `/statusz`
+    says so, `/metrics` counts it — its worker streams through it, and
+    the idle worker commits the trailing step; a speculative daemon and a
+    batcher built directly keep the synchronous loop."""
+    import time as _t
+
+    from dnn_tpu import obs
+    from dnn_tpu.runtime.lm_server import LMServer
+
+    cfg, prepared = model
+    assert not ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
+                                 prompt_pad=8)._overlap
+    ref = ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
+                            prompt_pad=8, seed=0)
+    want = [ref.submit(np.arange(1, 10, dtype=np.int32), 9, seed=4),
+            ref.submit(np.arange(3, 9, dtype=np.int32), 5, seed=5)]
+    ref.drain()
+    srv = LMServer(cfg, prepared, slots=2, max_len=64, prompt_pad=8, seed=0)
+    try:
+        b = srv.batcher
+        assert b._overlap and b.step_loop()["loop"] == "pipelined"
+        streamed = []
+        futs = [srv.worker.submit(np.arange(1, 10, dtype=np.int32), 9, 4,
+                                  on_token=streamed.append),
+                srv.worker.submit(np.arange(3, 9, dtype=np.int32), 5, 5)]
+        outs = [f.result(timeout=120) for f in futs]
+        assert [o.tolist() for o in outs] == \
+            [ref.results[r].tolist() for r in want]
+        assert streamed == outs[0].tolist()
+        assert b.steps_pipelined >= 7
+        for _ in range(100):  # the idle worker commits the trailing step
+            if b._inflight is None and b.stale_rows == 2:
+                break  # (a row a retirement: the flush counts the last)
+            _t.sleep(0.05)
+        assert b._inflight is None and b.stale_rows == 2
+        comp = srv._statusz()["components"]["batcher"]
+        assert comp["loop"] == "pipelined" and comp["depth"] == 1
+        assert comp["steps_pipelined"] == b.steps_pipelined
+        if obs.enabled():
+            from dnn_tpu.utils.metrics import render_prometheus
+
+            page = render_prometheus(obs.metrics())
+            assert f"step_pipelined_total {b.steps_pipelined}\n" in page
+            assert f"step_stale_rows_total {b.stale_rows}\n" in page
+    finally:
+        srv.close()
+    spec = LMServer(cfg, prepared, draft_cfg=cfg, draft_prepared=prepared,
+                    spec_k=2, slots=2, max_len=64, prompt_pad=8)
+    try:
+        assert not spec.batcher._overlap
+        comp = spec._statusz()["components"]["batcher"]
+        assert comp["loop"] == "synchronous" and "speculative" in comp["why"]
+    finally:
+        spec.close()
+
+
+@pytest.mark.parametrize("name", ["keye-test", "joyai-test", "dots3-test",
+                                  "k-exaone-test"])
+def test_interleaved_admission_stays_refused_by_name(name):
+    """A family that lives in the paged pool alone still refuses the
+    mixed step, by name, with or without the pipeline — which it takes."""
+    for kw in ({"prefill_chunk_tokens": 8},
+               {"prefill_chunk_tokens": 8, "overlap": True}):
+        with pytest.raises(ValueError, match="interleaved prefill"):
+            _family_batcher(name, **kw)
+    assert _family_batcher(name, overlap=True)._overlap
