@@ -281,18 +281,6 @@ def build_parser() -> argparse.ArgumentParser:
                         "convoy path. JSON-mode constraints ride the "
                         "interleave (the grammar DFA advances on "
                         "device)")
-    p.add_argument("--overlap", action="store_true",
-                   help="--serve_lm: the one-step dispatch pipeline — "
-                        "the worker dispatches step N+1's device work "
-                        "before it reads step N's tokens, hiding the "
-                        "launch and the host's bookkeeping under the "
-                        "device step (a retirement is seen one step "
-                        "later). It is the daemon's DEFAULT for every "
-                        "model family: the flag is accepted and changes "
-                        "nothing, except with --draft_model, whose "
-                        "speculative batcher pipelines only when asked "
-                        "here. JSON-mode constraints ride it (the device "
-                        "DFA walk is idempotent under the replayed step)")
     p.add_argument("--tokenizer", default=None,
                    help="--serve_lm: text endpoint tokenizer — 'bytes' "
                         "(UTF-8 bytes as ids; any vocab >= 256) or a LOCAL "
@@ -1117,10 +1105,6 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
             kv_lease_ttl_s=args.kv_lease_ttl_s,
             kv_handoff_ttl_s=args.kv_handoff_ttl_s,
             prefill_chunk_tokens=args.prefill_chunk_tokens,
-            # the step loop is the server's to choose (LMServer: the
-            # pipeline wherever the batcher's class runs it by default);
-            # the flag only asks for it where it is not the default
-            **({"overlap": True} if args.overlap else {}),
             # the daemon's clients choose options per request, so the
             # per-slot bias capability is on at this edge — except for
             # speculative serving, whose batcher rejects per-request

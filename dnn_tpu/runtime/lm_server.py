@@ -1560,11 +1560,11 @@ class LMServer:
             batcher_kwargs["spec_k"] = spec_k
         else:
             cls, models = ContinuousBatcher, (cfg, prepared)
-        # the worker's step loop: the one-step dispatch pipeline (step
-        # N+1 launched before step N is read) wherever the batcher's
-        # class runs it by default — every family and cache of the dense
-        # batcher; a speculative one only when `overlap=True` was passed
-        batcher_kwargs.setdefault("overlap", cls._daemon_pipelines)
+            # the worker's step loop over a dense batcher is the one-step
+            # dispatch pipeline (step N+1 launched before step N is read),
+            # for every family and cache; a speculative batcher keeps its
+            # own default. A caller's explicit `overlap=` wins either way
+            batcher_kwargs.setdefault("overlap", True)
         self.batcher = cls(*models, **batcher_kwargs)
         self.default_max_new = default_max_new
         self.request_timeout = request_timeout

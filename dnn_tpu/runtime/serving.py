@@ -277,14 +277,6 @@ class ContinuousBatcher:
     # initialization-order hazard to refactor away.
     _constraints_ok = True
 
-    # the step loop a DAEMON runs over this class when its caller did not
-    # say (lm_server.LMServer hands it to `overlap=`; the one place that
-    # decision is made): the one-step dispatch pipeline, for every family
-    # and cache this class serves. SpeculativeBatcher keeps False: its
-    # callers ask for its pipeline by name, as before. A batcher built
-    # directly keeps `overlap=False`: step() then returns its own tokens.
-    _daemon_pipelines = True
-
     def __init__(self, cfg: GPTConfig, prepared, *, slots: int = 4,
                  max_len: Optional[int] = None, prompt_pad: Optional[int] = None,
                  temperature: float = 0.0, top_k: Optional[int] = None,
@@ -1354,10 +1346,11 @@ class ContinuousBatcher:
         if not self._overlap:
             return {"loop": "synchronous", "depth": 0,
                     "why": "overlap=False (a batcher built directly, or a "
-                           "speculative one under a daemon): each step is "
-                           "read before the next is dispatched"}
+                           "speculative one, which LMServer leaves its own "
+                           "default): each step is read before the next is "
+                           "dispatched"}
         return {"loop": "pipelined", "depth": 1,
-                "why": "overlap=True (what a daemon asks of a dense "
+                "why": "overlap=True (what LMServer asks of a dense "
                        "batcher): step N+1 is dispatched before step N is "
                        "read",
                 "steps_pipelined": self.steps_pipelined,

@@ -97,7 +97,6 @@ class SpeculativeBatcher(ContinuousBatcher):
     # a verified chunk commits up to k+1 tokens in one device call —
     # per-token grammar masks cannot gate it (submit rejects constraint=)
     _constraints_ok = False
-    _daemon_pipelines = False  # overlap= stays its callers' to ask for
 
     def __init__(self, cfg: GPTConfig, prepared, draft_cfg: GPTConfig,
                  draft_prepared, *, spec_k: int = 4, draft_family=None,

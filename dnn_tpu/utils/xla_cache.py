@@ -2,9 +2,10 @@
 
 Observed pathology (this environment's jaxlib CPU build): one process
 that keeps compiling DISTINCT programs eventually segfaults inside the
-XLA CPU compiler — the full test suite (600+ tests, several programs
-each) dies at ~85% unless compiled executables drop between modules
-(tests/conftest.py's between-modules `jax.clear_caches()` fixture).
+XLA CPU compiler — the full test suite run in one process (several
+programs a test) dies at ~85% unless compiled executables drop between
+modules (tests/conftest.py's between-modules `jax.clear_caches()`
+fixture, gated on resident memory).
 Thousands of distinct TINY programs do NOT crash (the trigger is the
 suite's program population, SPMD collectives/donation/scans, not raw
 count), so the suite-scale evidence is the operative fact. A long-lived

@@ -33,9 +33,15 @@ def _python(code, *, env=None, cwd=REPO, timeout=300):
 
 
 def _env(**extra):
+    """The suite's environment without its compile-cache directory
+    (conftest's, or an operator's): the children here are held to the rule
+    for a process that was given none, which places the cache in the
+    checkout — so the cache itself is off for them, or a test run would
+    write its programs into the tree."""
     env = {k: v for k, v in os.environ.items()
            if k != "JAX_COMPILATION_CACHE_DIR"}
-    env.update(PYTHONPATH=REPO, **extra)
+    env.update(PYTHONPATH=REPO, JAX_ENABLE_COMPILATION_CACHE="false")
+    env.update(extra)
     return env
 
 

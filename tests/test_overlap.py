@@ -645,9 +645,10 @@ def test_stale_step_at_max_len_writes_no_live_block(name):
 
 
 def test_the_daemon_pipelines_by_default(model):
-    """An `LMServer` built with no flag runs the pipeline — `/statusz`
-    says so, `/metrics` counts it — its worker streams through it, and
-    the idle worker commits the trailing step; a speculative daemon and a
+    """An `LMServer` over a dense batcher runs the pipeline, whatever its
+    caller left unsaid — `/statusz` says so and why, `/metrics` counts it
+    — its worker streams through it, and the idle worker commits the
+    trailing step; a speculative daemon (its batcher's own default) and a
     batcher built directly keep the synchronous loop."""
     import time as _t
 
@@ -682,6 +683,7 @@ def test_the_daemon_pipelines_by_default(model):
         assert b._inflight is None and b.stale_rows == 2
         comp = srv._statusz()["components"]["batcher"]
         assert comp["loop"] == "pipelined" and comp["depth"] == 1
+        assert "LMServer asks of a dense batcher" in comp["why"]
         assert comp["steps_pipelined"] == b.steps_pipelined
         if obs.enabled():
             from dnn_tpu.utils.metrics import render_prometheus
@@ -696,7 +698,8 @@ def test_the_daemon_pipelines_by_default(model):
     try:
         assert not spec.batcher._overlap
         comp = spec._statusz()["components"]["batcher"]
-        assert comp["loop"] == "synchronous" and "speculative" in comp["why"]
+        assert comp["loop"] == "synchronous"
+        assert "speculative one, which LMServer leaves" in comp["why"]
     finally:
         spec.close()
 
