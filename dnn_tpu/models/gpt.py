@@ -187,15 +187,16 @@ def stack_layers(cfg):
     whose layers are of KINDS that interleave (`layer_types`, models/
     mla.py: layers whose attention and cache differ) — the dense prefix,
     the "full" expert layers ("blocks") and the "window" expert layers
-    ("window_blocks"), each stacked apart whatever lies between its
-    members."""
+    ("window_blocks"; "linear_blocks" for models/kda.py's "linear"
+    layers), each stacked apart whatever lies between its members."""
     types = getattr(cfg, "layer_types", None)
     if types is None:
         return {name: tuple(range(*r))
                 for name, r in stack_ranges(cfg).items()}
     k = getattr(cfg, "first_k_dense", 0)
     out = {"dense_blocks": tuple(range(k))} if k else {}
-    for name, kind in (("blocks", "full"), ("window_blocks", "window")):
+    for name, kind in (("blocks", "full"), ("window_blocks", "window"),
+                       ("linear_blocks", "linear")):
         layers = tuple(i for i in range(k, cfg.n_layer) if types[i] == kind)
         if layers:
             out[name] = layers

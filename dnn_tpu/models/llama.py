@@ -374,6 +374,10 @@ KV_KIND_LEAVES = {"full": ("k", "v", "tables"),
 def kv_kinds(cfg):
     """{kind: its KvKind} of a config whose K/V layers are of two kinds,
     "full" first; None for every other config."""
+    if getattr(cfg, "kda", None) is not None:
+        # models/kda.py: the K/V layers are the "full" kind alone; the
+        # "linear" layers keep a state, no position's anything
+        return {"full": cfg.kv_full or KvKind()}
     if getattr(cfg, "kv_window", None) is None:
         return None
     return {"full": cfg.kv_full or KvKind(), "window": cfg.kv_window}
@@ -463,6 +467,16 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
 
         blk["attn"] = mla.init_attn(jax.random.fold_in(key, 17), cfg, dtype,
                                     mla.kinds(cfg)[kind or "full"])
+    if getattr(cfg, "kda", None) is not None:
+        if kind == "linear":
+            from dnn_tpu.models import kda
+
+            blk["attn"] = kda.init_mixer(jax.random.fold_in(key, 19), cfg,
+                                         dtype)
+        elif cfg.attn_gate:
+            # the softmax layer's sigmoid OUTPUT gate, element-wise
+            blk["attn"]["gate"] = _dense(jax.random.fold_in(key, 23),
+                                         (c, cfg.n_head * d))
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -734,6 +748,18 @@ def _gqa_scores_attend(q, k, v, mask_fn, softcap=None):
     return y.reshape(b, h, t, d)
 
 
+def _gated(bp, h, y, compute_dtype):
+    """The attention output y (B, T, H D), heads merged, times sigmoid(h
+    W_gate) element-wise where the layer's params hold an output gate
+    (scope `attn_gate`, models/kda.py's softmax layers); y itself else."""
+    if "gate" not in bp["attn"]:
+        return y
+    with jax.named_scope("attn_gate"):
+        g = linear(bp["attn"]["gate"], h, compute_dtype=compute_dtype)
+        return (y.astype(jnp.float32)
+                * jax.nn.sigmoid(g.astype(jnp.float32))).astype(y.dtype)
+
+
 def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None,
                 rope=True):
     """Default attention: local causal GQA over the whole (B, T, C) h,
@@ -756,8 +782,8 @@ def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None,
         return jnp.where(keep, s, _NEG_BIG)
 
     y = _gqa_scores_attend(q, k, v, causal, softcap=cfg.attn_softcap)
-    return linear(bp["attn"]["o"], merge_heads(y.astype(h.dtype)),
-                  compute_dtype=compute_dtype)
+    y = _gated(bp, h, merge_heads(y.astype(h.dtype)), compute_dtype)
+    return linear(bp["attn"]["o"], y, compute_dtype=compute_dtype)
 
 
 def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
@@ -771,7 +797,12 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
     kind where the config's layers are of several (models/mla.py)."""
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
-    if attn_fn is None and (kinds := kv_kinds(cfg)) is not None:
+    if attn_fn is None and kind == "linear":
+        from dnn_tpu.models import kda
+
+        fn = lambda bp2, h: kda.dense_mixer(  # noqa: E731
+            bp2["attn"], h, cfg=cfg, compute_dtype=compute_dtype)
+    elif attn_fn is None and (kinds := kv_kinds(cfg)) is not None:
         kk = kinds[kind or "full"]
         fn = lambda bp2, h: _dense_attn(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=kk.window,
@@ -1495,6 +1526,8 @@ def family_rows(cfg, **kw):
         from dnn_tpu.models.dsa import DsaFamilyRows as rows
     elif getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models.mla import MlaFamilyRows as rows
+    elif getattr(cfg, "kda", None) is not None:
+        from dnn_tpu.models.kda import KdaKindRows as rows
     elif kv_kinds(cfg) is not None:
         rows = LlamaKindRows
     else:
@@ -1737,14 +1770,15 @@ class LlamaFamilyRows:
 
 
 def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
-                    moe_stats, leaves):
+                    moe_stats, leaves, **chunk_kw):
     """A family's `prefill` where the transient row's leaves are BY LAYER
     KIND (`leaves`: kind -> its leaf names; models/mla.py's latents,
     `LlamaKindRows`' K and V): each stack of `layer_stacks` is scanned
     over its kind's rows — its range of them where the layers are of
     kinds — through `family._chunk_block(bp, x, rows, start_pos, ffn,
     kind)` -> (x, rows). -> (hidden (1, P, C) float32, the row cache[,
-    the expert layers' sums])."""
+    the expert layers' sums]). `chunk_kw` goes on to `_chunk_block` (a
+    state kind's count of real positions, models/kda.py)."""
     x = _scaled_embed(prepared, padded, family.cfg)
     if family.compute_dtype is not None:
         x = x.astype(family.compute_dtype)
@@ -1755,7 +1789,8 @@ def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
         bp = bind(bp)
         (y, rows), acc = _run_block(
             family.ffn, acc,
-            lambda f: family._chunk_block(bp, x, rows, start_pos, f, kind))
+            lambda f: family._chunk_block(bp, x, rows, start_pos, f, kind,
+                                          **chunk_kw))
         return (y, acc), rows
 
     carry = (x, jnp.zeros((3,), jnp.int32) if moe_stats else None)
@@ -1876,8 +1911,9 @@ class LlamaKindRows(LlamaFamilyRows):
                 "banded_kernel" if kk.window else "kernel") if (
                     interpret or jax.default_backend() == "tpu") else "plain"
             y = y.reshape(1, cfg.n_head, t, d)
-            o = linear(bp["attn"]["o"], merge_heads(y.astype(x.dtype)),
-                       compute_dtype=compute_dtype)
+            o = linear(bp["attn"]["o"],
+                       _gated(bp, h, merge_heads(y.astype(x.dtype)),
+                              compute_dtype), compute_dtype=compute_dtype)
         with jax.named_scope("llama.block.mlp"):
             return (_branches_residual(bp, x, o, h, cfg=cfg,
                                        compute_dtype=compute_dtype, ffn=ffn),
@@ -1910,8 +1946,9 @@ class LlamaKindRows(LlamaFamilyRows):
                     qg, layer_cache, k, v, pos, write, window=kk.window,
                     leaves=(k_name, v_name), tables=tables)
         y = y.reshape(b, cfg.n_head, 1, d)
-        o = linear(bp["attn"]["o"], merge_heads(y.astype(x.dtype)),
-                   compute_dtype=compute_dtype)
+        o = linear(bp["attn"]["o"],
+                   _gated(bp, h, merge_heads(y.astype(x.dtype)),
+                          compute_dtype), compute_dtype=compute_dtype)
         return h, o, layer_cache
 
     def verify_rows(self, *a, **kw):

@@ -44,6 +44,7 @@ import jax
 import jax.numpy as jnp
 
 from dnn_tpu.models import gpt, llama
+from dnn_tpu.models.kda import KdaConfig
 from dnn_tpu.models.mla import MlaConfig
 from dnn_tpu.parallel.moe import init_moe_gated, moe_ffn, moe_ffn_grouped
 from dnn_tpu.registry import ModelSpec, register_model
@@ -125,14 +126,35 @@ class MixtralConfig(llama.LlamaConfig):
     # either kind
     kv_full: Optional[llama.KvKind] = None
     kv_window: Optional[llama.KvKind] = None
+    # layers that keep a STATE and no position's anything (models/kda.py:
+    # the gated delta rule with a decay a channel behind a short
+    # convolution): `layer_types[i]` is "full" (K and V, `kv_full`) or
+    # "linear" (`kda`'s widths); `attn_gate`: the "full" layers multiply
+    # their attention output by sigmoid(h W_gate), element-wise
+    kda: Optional[KdaConfig] = None
+    attn_gate: bool = False
 
     def __post_init__(self):
         super().__post_init__()
         if (self.layer_types is None) != (
-                self.mla_window is None and self.kv_window is None):
-            raise ValueError("layer_types comes with mla_window or "
-                             "kv_window, and they with it")
-        if self.kv_window is not None or self.kv_full is not None:
+                self.mla_window is None and self.kv_window is None
+                and self.kda is None):
+            raise ValueError("layer_types comes with mla_window, "
+                             "kv_window or kda, and they with it")
+        if self.kda is not None:
+            if (self.mla is not None or self.mla_window is not None
+                    or self.index_topk is not None
+                    or self.kv_window is not None or self.first_k_dense
+                    or (self.kv_full or llama.KvKind()).window is not None
+                    or len(self.layer_types) != self.n_layer
+                    or set(self.layer_types) - {"full", "linear"}
+                    or "full" not in self.layer_types):
+                raise ValueError(
+                    "kda names the \"linear\" layers of layer_types and "
+                    "kv_full (which has no window) the \"full\" ones, of "
+                    "which there is at least one (no mla, no indexer, no "
+                    "window kind, no dense prefix)")
+        elif self.kv_window is not None or self.kv_full is not None:
             if (self.mla is not None or self.mla_window is not None
                     or self.index_topk is not None or self.kv_window is None
                     or self.kv_window.window is None
@@ -438,6 +460,48 @@ PRESETS["k-exaone-test"] = MixtralConfig(
     kv_full=llama.KvKind(window=None, rope=False),
     kv_window=llama.KvKind(window=8, rope=True),
     layer_types=("window", "window", "window", "full", "window"))
+# Solar-Open2-250B (upstage/Solar-Open2-250B config.json, `model_type`
+# solar_open2): 48 layers in periods of four — one softmax layer
+# (`gqa_layers` 0, 4, ...: 64 query / 8 KV heads of 128, NO rotation, a
+# sigmoid output gate) and three linear-attention layers (models/kda.py:
+# 64 heads of 128, a 4-tap convolution, the gated delta rule with a decay
+# a channel, beta in (0, 2)) — every layer 320 experts of 1280, 8 a token
+# by sigmoid scores with a selection bias, weights normalised, and one
+# ungated shared expert; vocabulary 196608, untied. What the config leaves
+# open is `assumed` in chipbench/configs/solar-open2-250b-ep8-1chip.json.
+# Never instantiated whole.
+_SOLAR_TYPES = tuple("full" if i % 4 == 0 else "linear" for i in range(48))
+PRESETS["solar-open2-250b"] = MixtralConfig(
+    block_size=1048576, vocab_size=196608, n_layer=48, n_head=64,
+    n_kv_head=8, n_embd=4096, d_ff=1280, head_dim_override=128,
+    rms_eps=1e-5, n_expert=320, router_top_k=8, router_norm_topk=True,
+    capacity_factor=320.0, d_shared=1280, shared_gate=False,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=1.0),
+    kv_full=llama.KvKind(window=None, rope=False), attn_gate=True,
+    kda=KdaConfig(n_head=64, head_dim=128, conv=4, rank=128, chunk=64),
+    layer_types=_SOLAR_TYPES)
+# the benchmark's cut (chipbench/configs/solar-open2-250b-ep8-1chip.json):
+# one chip's share of an 8-chip expert-parallel deployment — experts 0-39
+# of each layer's 320, rows 0-24575 of the vocabulary (an eighth), both
+# mixers, router, shared expert and norms whole — and ONE whole period of
+# four (F L L L): 6.8 GB held
+PRESETS["solar-open2-250b-ep8-1chip"] = dataclasses.replace(
+    PRESETS["solar-open2-250b"], n_layer=4, layer_types=_SOLAR_TYPES[:4],
+    vocab_size=24576, experts_first=0, experts_held=40)
+# tiny Solar-Open2 for the CPU tests, every switch of the real one acting:
+# GQA 2:1 with a decoupled head width, no rotation, the output gate, three
+# linear layers of 4 heads of 16 whose chunk of 8 a 16-token prefill chunk
+# holds twice, sigmoid + bias routing, an ungated shared expert, a held
+# share smaller than the expert count
+PRESETS["solar-open2-test"] = MixtralConfig(
+    block_size=128, vocab_size=256, n_layer=4, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=32, head_dim_override=32, rms_eps=1e-5, n_expert=8,
+    router_top_k=4, router_norm_topk=True, capacity_factor=8.0,
+    experts_first=0, experts_held=4, d_shared=32, shared_gate=False,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=1.0),
+    kv_full=llama.KvKind(window=None, rope=False), attn_gate=True,
+    kda=KdaConfig(n_head=4, head_dim=16, conv=4, rank=8, chunk=8),
+    layer_types=("full", "linear", "linear", "linear"))
 # the benchmark's cut (chipbench/configs/olmoe-1b-7b-1chip.json): three of
 # the sixteen layers — the pattern has period 1 — so that float32 weights,
 # a 16-slot pool of 4096 positions and the programs fit one 16 GB chip

@@ -403,6 +403,8 @@ class StepClock:
         # the same reads by layer KIND (note_mla_kind): (kind, program) ->
         # cached positions the kind's layers had to read
         self.mla_kind_total: "Dict[tuple, int]" = {}
+        # a state kind's counters (`note_state`): name -> total
+        self.state_total: "Dict[str, int]" = {}
         self._pending_moe: "Optional[Dict[str, list]]" = None
         self._gauges_registered = False
         self._registry = registry
@@ -674,6 +676,31 @@ class StepClock:
                                  kind=kind, program=program)] = read
             self._gauges_registered = False  # re-register with it
         self.mla_kind_total[key] += cached
+
+    def note_state(self, **adds):
+        """A model that keeps a STATE a slot beside K and V (models/
+        kda.py), counted on the host: `bytes_read` / `bytes_written` (a
+        decode step reads and writes every slot's state leaves),
+        `kv_bytes_read` (the live K and V positions the step read),
+        `prefill_real_positions` / `prefill_pad_positions` (a chunk's),
+        `installs` (states written into a slot by a finish, a layer
+        each). Cumulative `state_pool.<name>_total`, on /metrics with a
+        name's first note."""
+        if not _obs.enabled():
+            return
+        for name, n in adds.items():
+            if name not in self.state_total:
+                self.state_total[name] = 0
+                ref = weakref.ref(self)
+
+                def read(name=name):
+                    c = ref()
+                    return float(c.state_total[name]) if c is not None \
+                        else 0.0
+
+                self._gauges[f"state_pool.{name}_total"] = read
+                self._gauges_registered = False  # re-register with it
+            self.state_total[name] += n
 
     def _note3(self, total, gauges, program, add):
         if not _obs.enabled():

@@ -64,6 +64,22 @@ back and draws the next). Decode over such a leaf gathers the window's
 blocks, not the row (`write_attend_latent_rows(window=)`; `_window_rows`
 for K and V leaves).
 
+**A kind with NO position axis.** A layer that keeps a STATE — a matrix a
+head whatever the length, and the last rows of a short convolution
+(models/kda.py) — declares a kind with no `leaves`, no `tables` (None) and
+`slot_leaves`: name -> (the shape a slot a layer, dtype or None for the
+pool's):
+
+    state     (L_lin, B, H, d, d)  float32
+    conv_tail (L_lin, B, conv - 1, channels)
+
+They ride the same pytree through `scan_blocks(layers=)` and the step's
+donation, reached at the layer's index among ITS kind's layers and the
+slot's row; they draw nothing from the `BlockAllocator` ("blocks a slot":
+none), `install_row` leaves them alone, and the finish-and-install program
+writes the transient row's running state at the slot — which is also the
+only thing that resets a slot.
+
 The codec interface matches FloatKV (write_rows / attend_rows /
 write_attend_rows / install_row), so GPTFamilyRows / LlamaFamilyRows
 decode through it unchanged. The decode step reaches the pool IN PLACE:
@@ -278,8 +294,14 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks,
                 out[name] = jnp.zeros(
                     (k["layers"], n_blocks[k["tables"]], heads, block_len,
                      lane_padded(width)), dtype)
-            out[k["tables"]] = jnp.zeros((k["layers"], slots, nb_max),
-                                         jnp.int32)
+            for name, (shape, leaf_dtype) in k.get("slot_leaves",
+                                                   {}).items():
+                # no position axis, no blocks: a slot's own, a layer
+                out[name] = jnp.zeros((k["layers"], slots, *shape),
+                                      leaf_dtype or dtype)
+            if k["tables"] is not None:
+                out[k["tables"]] = jnp.zeros((k["layers"], slots, nb_max),
+                                             jnp.int32)
         return out
     tables = jnp.zeros((cfg.n_layer, slots, nb_max), jnp.int32)
     if leaves is not None:
@@ -344,6 +366,11 @@ class PagedKV:
         # layer kind (module docstring); every leaf under "tables" else
         self.leaf_tables = {name: k["tables"] for k in (kinds or {}).values()
                             for name in k["leaves"]}
+        # leaves with no position axis (a state kind's): not installed by
+        # blocks — the finish program writes them at the slot
+        self.slot_leaves = frozenset(
+            name for k in (kinds or {}).values()
+            for name in k.get("slot_leaves", ()))
 
     def _kernel_on(self, c) -> bool:
         """Resolve use_kernel against a concrete per-layer pool view
@@ -746,9 +773,10 @@ class PagedKV:
         reserved junk block 0, whose content is never attended live (the
         per-row position mask), so scribbling it is harmless."""
         bp = self.block_len
-        out = {kk: cache[kk] for kk in cache if is_tables(kk)}
+        out = {kk: cache[kk] for kk in cache
+               if is_tables(kk) or kk in self.slot_leaves}
         for kk in cache:
-            if is_tables(kk):
+            if kk in out:
                 continue
             # leaves by layer kind: `blk_ids` is then {tables name: ids}
             ids = blk_ids[self.leaf_tables[kk]] if isinstance(

@@ -378,9 +378,24 @@ class ContinuousBatcher:
         # kinds whose cache is K and V (models/llama.py `LlamaKindRows`):
         # their reads are the attn.* series', a latent family's the mla.*
         self._kv_kinds = bool(self._cache_kinds) and not self._latent
+        # a kind whose leaves have NO position axis (models/kda.py: a
+        # state and a convolution tail a slot a layer): name -> (shape a
+        # slot a layer, dtype). They ride the pool's pytree, are installed
+        # and reset by the finish program alone and draw no blocks; the
+        # chunk program of such a family is told how many of its
+        # positions are real (`prefill_chunk`)
+        self._slot_leaves = {
+            n: v for k in (self._cache_kinds or {}).values()
+            for n, v in k.get("slot_leaves", {}).items()}
+        self._n_state_layers = sum(
+            k["layers"] for k in (self._cache_kinds or {}).values()
+            if k.get("slot_leaves"))
+        self._takes_n_real = bool(getattr(self.family, "takes_n_real",
+                                          False))
         if getattr(self.family, "requires_paged", False):
             leaves = "/".join(
-                n for k in self._cache_kinds.values() for n in k["leaves"]
+                n for k in self._cache_kinds.values()
+                for n in (*k["leaves"], *k.get("slot_leaves", ()))
             ) if self._cache_kinds else "/".join(self.family.cache_leaves)
             refused = None
             if prefix_cache > 0:
@@ -500,7 +515,8 @@ class ContinuousBatcher:
         self._paged_window = None
         self._window_kinds = {}
         self._kind_tables = [k["tables"]
-                             for k in (self._cache_kinds or {}).values()]
+                             for k in (self._cache_kinds or {}).values()
+                             if k["tables"] is not None]
         if self._paged:
             fam_window = getattr(self.family, "window", None)
             if (getattr(self.family, "softcap", None) is not None
@@ -554,6 +570,15 @@ class ContinuousBatcher:
                 kinds=self._cache_kinds)
             self._allocator = BlockAllocator(paged_blocks, kinds=kind_blocks)
             self._block_len = block_len
+            if self._slot_leaves:
+                # the `state_pool.*` counters' units: every slot's state
+                # leaves, and a position's K and V in one full layer
+                self._state_step_bytes = sum(
+                    self.cache[n].nbytes for n in self._slot_leaves)
+                self._kv_position_bytes = sum(
+                    heads * width * self.cache[n].dtype.itemsize
+                    for n, (heads, width)
+                    in self._cache_kinds["full"]["leaves"].items())
             # table entries of window kinds to set before the next
             # dispatch: (tables name, slot, logical block, physical)
             self._wtab_pending: list = []
@@ -789,6 +814,8 @@ class ContinuousBatcher:
             # the pool BY LAYER KIND: blocks a kind holds now, and what
             # its window kinds handed back while their requests ran
             for kind, k in self._cache_kinds.items():
+                if k["tables"] is None:
+                    continue  # a state kind holds no blocks
                 self._obs_gauges[labeled(
                     "kv_pool.blocks_in_use", kind=kind)] = _weak_gauge(
                         "_kind_used_read", k["tables"])
@@ -1006,20 +1033,26 @@ class ContinuousBatcher:
             # expert-layer stats last
             return out + prefill_chunk(pf_prepared, row, chunk, chunk_start)
 
-        def prefill_chunk(prepared, row, chunk, chunk_start):
+        def prefill_chunk(prepared, row, chunk, chunk_start, n_real=None):
             """One (1, prompt_pad) chunk of a prompt into the slot-row
             cache at positions [chunk_start, chunk_start+P). Long prompts
             loop this (full chunks + one padded tail) — ONE compiled
             program for any prompt length. Pad positions in the tail write
-            K/V that the per-row position mask never attends. Returns
+            K/V that the per-row position mask never attends; a family
+            that keeps a STATE (`takes_n_real`, models/kda.py) has no mask
+            to hide them behind and is handed `n_real`, the count of the
+            chunk's real positions (a traced scalar: still one program),
+            to leave state and tail untouched by the rest. Returns
             (hidden (1, P, C) float32, row): the program stops at the
             last block, and the finish applies the head to the one row
             that is sampled. An MoE family returns its expert layers'
             stats as a third result."""
+            kw = {} if n_real is None else {"n_real": n_real}
             if self._moe_stats:
                 return self.family.prefill(prepared, chunk, row, chunk_start,
-                                           moe_stats=True)
-            return self.family.prefill(prepared, chunk, row, chunk_start)
+                                           moe_stats=True, **kw)
+            return self.family.prefill(prepared, chunk, row, chunk_start,
+                                       **kw)
 
         def prefill_finish(cache, pos, tok, active, keys, temp_v, tk_v,
                            tp_v, mp_v, rep_v, seen, bias_buf, crow,
@@ -1097,6 +1130,12 @@ class ContinuousBatcher:
                     name: blocks[2 * i + 1] for i, name in enumerate(names)})
                 for i, name in enumerate(names):
                     cache[name] = cache[name].at[:, slot].set(blocks[2 * i])
+                with jax.named_scope("state_pool.install"):
+                    # the row's RUNNING state after the prompt's last real
+                    # position becomes the slot's: what resets a slot
+                    for name in self._slot_leaves:
+                        cache[name] = cache[name].at[:, slot].set(
+                            row[name][:, 0].astype(cache[name].dtype))
             elif self._paged:
                 cache = codec.install_row(cache, row, blocks[1])
                 cache["tables"] = cache["tables"].at[:, slot].set(blocks[0])
@@ -1911,7 +1950,7 @@ class ContinuousBatcher:
                         pf_prepared, row,
                         padded[:, c * p_pad:(c + 1) * p_pad],
                         np.int32(c * p_pad),
-                    )
+                        *self._n_real(len(prompt), c))
                     self.prefill_chunks_run += 1
                     if self._prefix_cache is not None \
                             and (c + 1) * p_pad <= len(prompt):
@@ -2124,8 +2163,19 @@ class ContinuousBatcher:
         still on the device."""
         out = self._prefill_finish(*self._slot_state(), row, hidden,
                                    *request, self._ctable, self._ctrans)
+        if self._slot_leaves and self.step_clock is not None:
+            self.step_clock.note_state(installs=self._n_state_layers)
         self._set_slot_state(out[:13])
         return out[13], out[14:]
+
+    def _n_real(self, prompt_len: int, c: int) -> tuple:
+        """The chunk program's last argument for chunk `c` of a prompt:
+        the count of its real positions, for a family that keeps a state
+        (`prefill_chunk`); () for every other."""
+        if not self._takes_n_real:
+            return ()
+        return (np.int32(min(self.prompt_pad,
+                             prompt_len - c * self.prompt_pad)),)
 
     def _run_prefill_chunk(self, *args):
         """The chunk program -> (hidden, row); an MoE family's third
@@ -2161,6 +2211,11 @@ class ContinuousBatcher:
                 # (query, position) pairs within the band
                 self.step_clock.note_mla_kind(
                     "prefill", kind, n_l * _capped_pairs(start, t, w))
+        if self._takes_n_real and self.step_clock is not None:
+            t = int(args[2].shape[-1])
+            real = int(args[4]) if len(args) > 4 else t
+            self.step_clock.note_state(prefill_real_positions=real,
+                                       prefill_pad_positions=t - real)
         if self._kv_kinds and self.step_clock is not None:
             # K and V leaves by kind: the chunk's causal pairs a full
             # layer, those within the band a window layer (pad rows too)
@@ -2283,7 +2338,7 @@ class ContinuousBatcher:
                 self.prepared, row,
                 padded[:, c * p_pad:(c + 1) * p_pad],
                 np.int32(c * p_pad),
-            )
+                *self._n_real(len(prompt), c))
             self.prefill_chunks_run += 1
         last_local = len(prompt) - 1 - (n_chunks - 1) * p_pad
         hidden_row = np.asarray(hidden[0, last_local])
@@ -2964,6 +3019,14 @@ class ContinuousBatcher:
                 self.step_clock.note_mla_kind(
                     "decode", "full",
                     self._n_index_layers * (live - n_act), series=series)
+            if self._slot_leaves:
+                # a step reads AND writes every slot's state, live or not
+                # (`_state_step_bytes`), beside the live K and V it reads
+                self.step_clock.note_state(
+                    bytes_read=self._state_step_bytes,
+                    bytes_written=self._state_step_bytes,
+                    kv_bytes_read=self._kv_position_bytes
+                    * self._n_index_layers * (live - n_act))
             for (kind, n_l, _), n in zip(wins, in_window):
                 self.step_clock.note_mla_kind("decode", kind, n_l * n,
                                               series=series)

@@ -9,7 +9,7 @@ decode programs, whose layer loops keep the expert stacks whole since, and
 left `gpt2-test`'s as they were; PR 38 re-recorded every chunk and finish
 program — the head moved from the one into the other — and no decode
 program; PR 43 added `joyai-test`'s and `dots3-test`'s, recorded on its
-parent commit)."""
+parent commit; PR 47 `k-exaone-test`'s, on its own)."""
 
 import hashlib
 import json
@@ -17,7 +17,7 @@ import os
 import sys
 
 PRESETS = ("gpt2-test", "olmoe-test", "keye-test", "joyai-test",
-           "dots3-test")
+           "dots3-test", "k-exaone-test")
 PROGRAMS = ("_prefill_chunk", "_prefill_finish", "_decode")
 
 

@@ -578,11 +578,21 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
     the form each kind's chunk and decode reads took in the built programs
     — on the CPU the plain ones (on the chip "kernel" / "banded_kernel"
     and "paged_kernel", or a daemon that fell back shows it without a
-    capture) — and the pool's bytes by the kinds' leaves; no other model
-    has the line."""
+    capture) — and the pool's bytes by the kinds' leaves; a model with a
+    STATE kind beside K and V (it writes `state_pool_*`) names that kind's
+    forms too, and its leaves without a position axis are counted among
+    the pool's bytes; no other model has the line."""
     comps = served(config)["statusz"]["components"]
+    series = _SERVED[config]["series"]
+    if any(s.startswith("state_pool_") for s in series):
+        assert comps["attention"]["kinds"] == {
+            "full": {"prefill": "plain", "decode": "gather_einsum"},
+            "linear": {"prefill": "chunked_jnp", "decode": "step_jnp"}}
+        assert sorted(comps["kv_cache"]["bytes_by_leaf"]) == [
+            "conv_tail", "k", "state", "v"]
+        return
     by_kind = any(s.startswith("attn_cached_positions_read_total")
-                  for s in _SERVED[config]["series"])
+                  for s in series)
     if not by_kind:
         assert "kinds" not in comps.get("attention", {})
         return

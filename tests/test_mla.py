@@ -610,13 +610,15 @@ DECODE_BEFORE_PR38 = {
 
 
 @pytest.mark.parametrize("preset", ["gpt2-test", "olmoe-test", "keye-test",
-                                    "joyai-test", "dots3-test"])
+                                    "joyai-test", "dots3-test",
+                                    "k-exaone-test"])
 def test_step_programs_lower_to_the_parents_text(parent_texts, preset):
     """The chunk, finish-and-install and decode programs of the GPT-2,
     OLMoE and Keye families lower to the recorded text (its sha256, by
     tests/step_program_texts.py), and JoyAI's and dots3's to what they
     were on the commit before PR 43 (which gave K and V leaves their
-    layer kinds). The decode programs: GPT-2's as on the
+    layer kinds), K-EXAONE's to what they were before PR 47 (which put a
+    state kind and an output gate beside them). The decode programs: GPT-2's as on the
     commit before PR 35; OLMoE's and Keye's as PR 36 left them, which took
     the expert stacks out of the layer loops' xs on purpose. The chunk and
     finish programs: as PR 38 left them (the chunk ends at the last block,

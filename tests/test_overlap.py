@@ -444,9 +444,10 @@ def test_worker_streams_interleaved_and_overlap_tokens(model):
 #: one test-size model of each family the daemon serves: K/V (GPT-2),
 #: expert layers (OLMoE), an index-key leaf (Keye's `DsaFamilyRows`), one
 #: latent leaf (JoyAI's `MlaFamilyRows`), two latent kinds with a window
-#: kind (dots3's), K/V kinds with a window (K-EXAONE's)
+#: kind (dots3's), K/V kinds with a window (K-EXAONE's), a kind with no
+#: position axis beside K and V (Solar-Open2's state)
 FAMILIES = ["gpt2-test", "olmoe-test", "keye-test", "joyai-test",
-            "dots3-test", "k-exaone-test"]
+            "dots3-test", "k-exaone-test", "solar-open2-test"]
 _BUILT: dict = {}
 
 
@@ -607,7 +608,7 @@ def test_window_blocks_under_the_pipeline_are_the_synchronous_loops(name):
 
 
 @pytest.mark.parametrize("name", ["gpt2-test", "dots3-test",
-                                  "k-exaone-test"])
+                                  "k-exaone-test", "solar-open2-test"])
 def test_stale_step_at_max_len_writes_no_live_block(name):
     """A request that ends at `max_len` with its last allocated position
     unwritten: the stale step dispatched past its retirement stands at
@@ -705,7 +706,7 @@ def test_the_daemon_pipelines_by_default(model):
 
 
 @pytest.mark.parametrize("name", ["keye-test", "joyai-test", "dots3-test",
-                                  "k-exaone-test"])
+                                  "k-exaone-test", "solar-open2-test"])
 def test_interleaved_admission_stays_refused_by_name(name):
     """A family that lives in the paged pool alone still refuses the
     mixed step, by name, with or without the pipeline — which it takes."""
