@@ -399,6 +399,27 @@ def finish_args(b, chunk_tokens: int) -> tuple:
         b._ctable, b._ctrans)
 
 
+# name -> ContinuousBatcher options of each cache layout the decode-step
+# gate lowers (tests/test_constrained_hotpath.py lowers the constrained
+# ones again, to hold the step to `slots` rows of the mask pool)
+SERVING_DECODE_VARIANTS = {
+    "dense_f32": {},
+    "dense_int8": {"kv_dtype": "int8"},
+    "dense_int4": {"kv_dtype": "int4"},
+    "bucketed": {"decode_buckets": True},
+    "paged": {"kv": "paged"},
+    # constrained decoding (ISSUE 16): the grammar DFA walk is carried
+    # device state — crow joins the donate set, and the gate must see it
+    # aliased (an un-aliased crow would copy per step). The pools are
+    # read-only: the (S, W) uint32 bit-packed mask pool is read as one
+    # one-row dynamic_slice a slot and the (S, V) int32 ctrans pool by a
+    # one-word gather a slot, and neither may appear as a cache-sized copy
+    "dense_constrained": {"allow_constraints": True, "constraint_rows": 8},
+    "paged_constrained": {"kv": "paged", "allow_constraints": True,
+                          "constraint_rows": 8},
+}
+
+
 def audit_serving_decode(cfg=None, *, slots: int = 2,
                          max_len: int = 128) -> dict:
     """ISSUE 6 donation-coverage GATE over the SERVING decode programs:
@@ -429,24 +450,8 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
         findings.extend(f)
         report[name] = entry
 
-    variants = {
-        "dense_f32": {},
-        "dense_int8": {"kv_dtype": "int8"},
-        "dense_int4": {"kv_dtype": "int4"},
-        "bucketed": {"decode_buckets": True},
-        "paged": {"kv": "paged"},
-        # constrained decoding (ISSUE 16): the grammar DFA walk is
-        # carried device state — crow joins the donate set, and the
-        # gate must see it aliased (an un-aliased crow would copy per
-        # step; the (S, V) ctable/ctrans pools are read-only gathers
-        # and must NOT appear as cache-sized copies)
-        "dense_constrained": {"allow_constraints": True,
-                              "constraint_rows": 8},
-        "paged_constrained": {"kv": "paged", "allow_constraints": True,
-                              "constraint_rows": 8},
-    }
     hd = cfg.n_embd // cfg.n_head
-    for name, kw in variants.items():
+    for name, kw in SERVING_DECODE_VARIANTS.items():
         b = ContinuousBatcher(cfg, prepared, slots=slots, max_len=max_len,
                               prompt_pad=16, **kw)
         if b._paged:

@@ -28,9 +28,10 @@ Design (the outlines/guided-decoding construction, TPU-shaped):
      complete match.
 
   Cost note: the serving layer keeps each grammar's (S, V) allowed
-  table DEVICE-RESIDENT (uploaded once per grammar into a bool row
-  pool, `mask_table` below) and indexes it with a per-slot DFA-state
-  vector inside the compiled decode program — per-step host->device
+  table DEVICE-RESIDENT (uploaded once per grammar into a pool of
+  bit-packed rows, `mask_table` / `pack_mask_table` below) and reads it
+  by a per-slot DFA-state vector inside the compiled decode program —
+  per-step host->device
   traffic is one int32 per slot (the state vector), not a (V,) f32 row
   per constrained slot (~200 KB at GPT-2 vocab, the round-4 design
   this replaced). The host still walks the DFA (one int per committed
@@ -537,10 +538,11 @@ class TokenConstraint:
         return row
 
     def mask_table(self, eos_id: Optional[int]) -> np.ndarray:
-        """(S, V) bool: mask_row's allowed-set for EVERY state at once —
-        the device-resident form (True = allowed; the decode program
-        turns it into 0/-1e30 after a per-slot row gather). EOS column
-        overridden exactly as mask_row does."""
+        """(S, V) bool: mask_row's allowed-set for EVERY state at once
+        (True = allowed). EOS column overridden exactly as mask_row
+        does. The device-resident form is this table BIT-PACKED
+        (`pack_mask_table`): the decode program reads one packed row a
+        slot and turns its bits into 0/-1e30."""
         tab = self.allowed.copy()
         if eos_id is not None:
             tab[:, eos_id] = self.accepting.astype(bool)
@@ -573,6 +575,39 @@ _JSON_WS = r"[ \t\n\r]*"
 _JSON_ESC = r"\\([\"\\/bfnrt]|u[0-9a-fA-F]{4})"
 _JSON_STR = f'"([^"\\\\]|{_JSON_ESC})*"'
 _JSON_NUM = r"-?(0|[1-9][0-9]*)(\.[0-9]+)?([eE][+-]?[0-9]+)?"
+
+
+def mask_words(vocab_size: int) -> int:
+    """uint32 words in one packed mask row: ceil(V / 32), rounded up to
+    whole 128-lane tiles, so that a row is tile-aligned on the device and
+    every bit plane of it starts on a tile."""
+    return -(-(-(-vocab_size // 32)) // 128) * 128
+
+
+def pack_mask_table(tab: np.ndarray) -> np.ndarray:
+    """(S, V) bool -> (S, W) uint32, W = mask_words(V): token t is bit
+    t // W of word t % W — bit PLANES along the row, not 32 neighbouring
+    tokens a word, so that the device unpacks plane k as `(row >> k) & 1`
+    laid end to end with no interleave (`unpack_mask_table`). Lossless;
+    the padding bits past V are zero and cut off by the unpack."""
+    s, v = tab.shape
+    w = mask_words(v)
+    planes = np.zeros((s, 32 * w), bool)
+    planes[:, :v] = tab
+    # (S, 32, W) -> (S, 4, W) bytes: byte j of a word holds planes 8j..8j+7
+    b = np.packbits(planes.reshape(s, 32, w), axis=1,
+                    bitorder="little").astype(np.uint32)
+    return b[:, 0] | b[:, 1] << 8 | b[:, 2] << 16 | b[:, 3] << 24
+
+
+def unpack_mask_table(words, vocab_size: int, xp=np):
+    """pack_mask_table's inverse: (S, W) uint32 -> (S, V) bool, the bit
+    planes laid end to end. `xp` is the array module: numpy on the host,
+    `jax.numpy` inside the decode program (serving._mask_rows), where
+    each plane is one aligned elementwise piece."""
+    planes = -(-vocab_size // words.shape[1])
+    return xp.concatenate([(words >> k) & 1 != 0 for k in range(planes)],
+                          axis=1)[:, :vocab_size]
 
 
 def json_regex(max_depth: int = 2) -> str:

@@ -1544,9 +1544,11 @@ class LMServer:
         if (batcher_kwargs.get("allow_constraints")
                 and "constraint_rows" not in batcher_kwargs):
             # the daemon's JSON mode goes up to depth _MAX_JSON_DEPTH=3,
-            # whose byte DFA has 3519 states — the batcher's device mask
-            # pool must hold it (serving.ContinuousBatcher constraint_
-            # rows; bool bytes = rows x vocab, ~181 MB at GPT-2 vocab).
+            # whose byte DFA has 3519 states — the batcher's device pools
+            # must hold it (serving.ContinuousBatcher constraint_rows; the
+            # bit-packed mask pool is rows x vocab / 8 bytes or so, ~24 MB
+            # at GPT-2 vocab, and a step reads `slots` rows of it; the
+            # int32 transition pool is rows x vocab x 4, ~724 MB).
             # Operators who never serve deep JSON can pass a smaller
             # constraint_rows explicitly.
             batcher_kwargs["constraint_rows"] = 3600

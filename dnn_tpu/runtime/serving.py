@@ -73,6 +73,9 @@ from dnn_tpu.runtime.generate import (
     init_cache,
     logit_bias_row,
 )
+from dnn_tpu.runtime.constrain import (
+    mask_words, pack_mask_table, unpack_mask_table,
+)
 from dnn_tpu.runtime.kvcache import codec_for_cache
 from dnn_tpu.runtime.paged_kvcache import (
     is_tables, scan_blocks, window_blocks,
@@ -255,6 +258,19 @@ def _capped_pairs(start: int, t: int, cap: int) -> int:
     `cap` (an indexer's topk, a window) of the start + i + 1 it could."""
     n_all = min(max(cap - start, 0), t)  # rows that read everything
     return n_all * start + n_all * (n_all + 1) // 2 + (t - n_all) * cap
+
+
+def _mask_rows(ctable, crow, vocab: int):
+    """(B, vocab) bool, True = allowed: row `crow[i]` of the bit-packed
+    mask pool `ctable` (rows, W) uint32 for each of the B slots, unpacked.
+    The rows are read as B one-row `dynamic_slice`s, not as a gather: the
+    chip's compiler brings a gather's whole OPERAND into fast memory
+    first (the pool's relayout PR 48 removed, and an eighth of it for a
+    packed pool), a slice's row alone."""
+    words = jnp.concatenate(
+        [lax.dynamic_slice_in_dim(ctable, crow[i], 1, axis=0)
+         for i in range(crow.shape[0])], axis=0)
+    return unpack_mask_table(words, vocab, jnp)
 
 
 class ContinuousBatcher:
@@ -674,9 +690,10 @@ class ContinuousBatcher:
                       else jnp.zeros((slots, 0), jnp.float32))
         # constrained decoding (runtime/constrain.TokenConstraint) rides
         # DEVICE-RESIDENT table pools: each grammar uploads ONCE into
-        #   * `_ctable` (S, V) bool mask rows — what the decode program
-        #     gathers per slot to ban off-grammar logits (row 0 reserved
-        #     all-True = unconstrained), and
+        #   * `_ctable` (S, W) uint32 BIT-PACKED mask rows, W =
+        #     constrain.mask_words(V) — the decode program reads one row
+        #     a slot and unpacks it to ban off-grammar logits
+        #     (`_mask_rows`; row 0 reserved all-ones = unconstrained), and
         #   * `_ctrans` (S, V) int32 next-state rows in GLOBAL pool
         #     coordinates — the DFA walk itself, so the decode program
         #     advances each slot's state `crow' = ctrans[crow, sampled]`
@@ -688,18 +705,20 @@ class ContinuousBatcher:
         # what lets constrained requests ride the interleaved/overlap
         # hot path (the host still mirrors the walk per committed token
         # for finish detection, off the dispatch critical path).
-        # `constraint_rows` bounds both pools (bytes: rows x vocab x 1
-        # bool + rows x vocab x 4 int32 — 1024 x 50257 ≈ 51 + 206 MB);
-        # entries are refcounted by live slots, evicted LRU when
-        # unreferenced.
+        # `constraint_rows` bounds both pools (bytes: rows x W x 4, about
+        # rows x vocab / 8, packed + rows x vocab x 4 int32 — 1024 x
+        # 50257 ≈ 7 + 206 MB); a step touches `slots` rows of the mask
+        # pool and `slots` words of the other. Entries are refcounted by
+        # live slots, evicted LRU when unreferenced.
         self._ctab_rows = int(constraint_rows) if self._allow_constraints \
             else 0
         if self._allow_constraints:
             if self._ctab_rows < 2:
                 raise ValueError(
                     f"constraint_rows must be >= 2, got {constraint_rows}")
-            self._ctable = jnp.ones(
-                (self._ctab_rows, cfg.vocab_size), jnp.bool_)
+            self._ctable = jnp.full(
+                (self._ctab_rows, mask_words(cfg.vocab_size)),
+                0xFFFFFFFF, jnp.uint32)
             self._ctrans = jnp.zeros(
                 (self._ctab_rows, cfg.vocab_size), jnp.int32)
             from collections import OrderedDict as _OD
@@ -935,9 +954,10 @@ class ContinuousBatcher:
             parameters — see _sample_rows; `rep`/`seen` drive the
             repetition penalty, `mp` the min-p cutoff, `bias` (B, V) the
             per-slot additive logit bias, `crow` (B,) the per-slot
-            constraint-table row index into the device-resident bool
-            mask pool `ctable` — row 0 is the reserved all-allowed
-            row, so unconstrained slots add nothing). The grammar walk
+            constraint-table row index into the device-resident
+            bit-packed mask pool `ctable` — row 0 is the reserved
+            all-allowed row, so unconstrained slots add nothing). The
+            grammar walk
             happens HERE too: `ctrans` holds each grammar's next-state
             rows in global pool coordinates, so the step returns
             `crow' = ctrans[crow, sampled]` as donated carried state —
@@ -967,7 +987,8 @@ class ContinuousBatcher:
                 if self._allow_bias:
                     lg = lg + bias
                 if self._allow_constraints:
-                    lg = jnp.where(ctable[crow], lg, _NEG_BIG)
+                    lg = jnp.where(_mask_rows(ctable, crow, lg.shape[-1]),
+                                   lg, _NEG_BIG)
                 # advance each slot's own stream; sample each row with its key
                 split = jax.vmap(jax.random.split)(keys)  # (B, 2, 2)
                 new_keys, subs = split[:, 0], split[:, 1]
@@ -1113,7 +1134,9 @@ class ContinuousBatcher:
                 if self._allow_bias:
                     lg = lg + b_row[None, :]
                 if self._allow_constraints:
-                    lg = jnp.where(ctable[c_row][None, :], lg, _NEG_BIG)
+                    lg = jnp.where(
+                        _mask_rows(ctable, c_row[None], lg.shape[-1]),
+                        lg, _NEG_BIG)
                 first = _sample_rows(
                     lg, rng[None], temperature=t[None], top_k=k[None],
                     top_p=p[None], min_p=mp_[None],
@@ -2757,9 +2780,10 @@ class ContinuousBatcher:
         """Place a constraint's (S, V) mask table in the device pool,
         returning its row offset. A pool hit just bumps the refcount; a
         miss allocates a gap (evicting LRU unreferenced entries as
-        needed) and uploads the bool table ONCE. Raises when the grammar
-        cannot fit even an empty pool — size `constraint_rows` to the
-        grammar set (json_regex(2) needs ~900 rows)."""
+        needed) and uploads the table ONCE, bit-packed on the host.
+        Raises when the grammar cannot fit even an empty pool — size
+        `constraint_rows` to the grammar set (json_regex(2) needs ~900
+        rows)."""
         key = id(c)
         e = self._ctab_entries.get(key)
         if e is not None:
@@ -2797,7 +2821,7 @@ class ContinuousBatcher:
             del self._ctab_entries[victim]
             off = _free_gap()
         self._ctable = self._ctable.at[off:off + n].set(
-            jnp.asarray(c.mask_table(self.eos_id)))
+            jnp.asarray(pack_mask_table(c.mask_table(self.eos_id))))
         # transition rows upload in GLOBAL pool coordinates (local next
         # state + this grammar's offset), so the decode program's walk
         # `ctrans[crow, tok]` needs no per-grammar rebase — and the

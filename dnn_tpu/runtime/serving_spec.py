@@ -131,9 +131,9 @@ class SpeculativeBatcher(ContinuousBatcher):
         for bad in ("ffn", "paged_blocks", "logprobs_k",
                     "attn_kernel", "top_p", "min_p", "repetition_penalty",
                     "lora_adapters", "allow_constraints"):
-            # allow_constraints would allocate the (constraint_rows, V)
-            # device mask pool for a batcher that rejects every
-            # constrained submit (_constraints_ok=False) — fail at
+            # allow_constraints would allocate the constraint_rows-row
+            # device mask and transition pools for a batcher that rejects
+            # every constrained submit (_constraints_ok=False) — fail at
             # construction, not per request
             val = kw.get(bad)
             if val and not (bad == "attn_kernel" and val == "auto"):

@@ -70,6 +70,7 @@ GQA = (4, 8, 4, 128)
 LARGE = (16, 20, 1, 64)
 OLMOE = (16, 16, 1, 128)
 BLOCK_LEN, CTX = 16, 1024
+MASK_ROWS = 3600  # the daemon's constraint pools (lm_server.py)
 
 # (shape, pool dtype, block_len, positions a slot): the pool holds its rows
 # 128 lanes wide (paged_kvcache.lane_padded), as the daemon's does. An
@@ -267,12 +268,18 @@ def step_programs(chip):
                                   BF16)
     convoy = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
                                kv="auto")
+    # the interleaved batcher with the per-request capabilities the daemon
+    # compiles in (node.py: bias and constraints on; lm_server.py: a mask
+    # pool of MASK_ROWS rows), so that its programs carry the pools
     mixed = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
-                              kv="auto", prefill_chunk_tokens=64)
+                              kv="auto", prefill_chunk_tokens=64,
+                              allow_logit_bias=True, allow_constraints=True,
+                              constraint_rows=MASK_ROWS)
     assert convoy._paged and convoy.max_len == CTX
     compiled = _lower_programs(chip, [
         (convoy, ("_prefill_chunk", "_prefill_finish", "_decode")),
-        (mixed, ("_mixed", "_ilv_finish=_prefill_finish"))])
+        (mixed, ("_mixed", "_ilv_finish=_prefill_finish",
+                 "_decode_constrained=_decode"))])
     pool_bytes = sum(x.nbytes for x in jax.tree.leaves(convoy.cache)
                      if x.ndim > 3)
     return (compiled, convoy.cache["k"].shape[1:], pool_bytes,
@@ -420,6 +427,22 @@ def test_serving_step_programs_compile_with_the_kernels(step_programs, name,
         # the donated pool aliases the program's result
         assert compiled[name].memory_analysis().alias_size_in_bytes \
             >= pool_bytes
+
+
+@pytest.mark.parametrize("name", ["_decode_constrained", "_mixed",
+                                  "_ilv_finish"])
+def test_step_programs_read_rows_of_the_constraint_pools(step_programs, name):
+    """ISSUE 48: the step reads `slots` rows of the mask pool and `slots`
+    words of the transition pool, whatever they hold. The compiler answered
+    the parent's `bool[3600, vocab]` gather by bringing the whole table into
+    fast memory in column pieces every step (`slice-done pred[3600,25041]` +
+    `pred[3600,25216]` here, 5 % of GPT-2 Large's busy time on the chip and
+    1.5 ms of JoyAI's 11 ms step), and a bit-packed table read by a gather
+    the same at an eighth: no operation of a step program may have a
+    pool's row count as an extent."""
+    compiled = step_programs[0]
+    assert _extent_ops(compiled[name],
+                       re.compile(r"\[%d," % MASK_ROWS)) == []
 
 
 @pytest.fixture

@@ -500,3 +500,70 @@ def test_speculative_batcher_rejects_constraints():
     c = TokenConstraint.from_regex(r"a+", byte_vocab(cfg.vocab_size))
     with pytest.raises(ValueError, match="constraint"):
         srv.submit(np.asarray([1, 2, 3]), max_new_tokens=4, constraint=c)
+
+
+# ----------------------------------------------------------------------
+# the bit-packed mask pool (ISSUE 48)
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("eos_id", [None, 5], ids=["no-eos", "eos"])
+@pytest.mark.parametrize("vocab", [50257, 256, 70])
+def test_packed_mask_table_unpacks_exactly(vocab, eos_id):
+    """Packing a boolean is lossless at every vocabulary — GPT-2's 50 257
+    (32 and 128 divide neither it nor its word count), 256 (both do) and
+    70 (under one word's 128-lane tile) — on the host and through the
+    decode program's own unpack, for every row at once and in any
+    order."""
+    import jax.numpy as jnp
+
+    from dnn_tpu.runtime.serving import _mask_rows
+
+    c = TokenConstraint.from_regex(r"[a-c]{2}[0-9]+|x", byte_vocab(vocab))
+    want = c.mask_table(eos_id)
+    if vocab > 256:  # byte_vocab's tail is empty tokens: mark some rows
+        want = want.copy()
+        want[:, 256:] = np.random.default_rng(vocab).random(
+            (want.shape[0], vocab - 256)) < 0.5
+    packed = constrain.pack_mask_table(want)
+    assert packed.dtype == np.uint32
+    assert packed.shape == (want.shape[0], constrain.mask_words(vocab))
+    assert packed.shape[1] % 128 == 0 and packed.shape[1] * 32 >= vocab
+    np.testing.assert_array_equal(
+        constrain.unpack_mask_table(packed, vocab), want)
+    rows = np.arange(want.shape[0])[::-1].astype(np.int32)
+    got = jax.jit(_mask_rows, static_argnums=2)(
+        jnp.asarray(packed), jnp.asarray(rows), vocab)
+    assert got.dtype == jnp.bool_ and got.shape == (len(rows), vocab)
+    np.testing.assert_array_equal(np.asarray(got), want[rows])
+
+
+@pytest.mark.parametrize("vocab_size", [256, 70])
+def test_mask_pool_row_zero_allows_everything(vocab_size):
+    """Row 0 of the pool is the reserved unconstrained row: all ones, in
+    the pool as built and after an upload beside it."""
+    import dataclasses
+
+    import jax.numpy as jnp
+
+    from dnn_tpu.runtime.serving import ContinuousBatcher, _mask_rows
+
+    cfg = dataclasses.replace(CFG, vocab_size=vocab_size)
+    prepared = gpt.prepare_stacked(llama.init(jax.random.PRNGKey(0), cfg),
+                                   cfg)
+    srv = ContinuousBatcher(
+        cfg, prepared, slots=2, max_len=cfg.block_size, prompt_pad=8,
+        family=llama.LlamaFamilyRows(cfg), allow_constraints=True,
+        constraint_rows=8)
+    assert srv._ctable.dtype == jnp.uint32
+    assert srv._ctable.shape == (8, constrain.mask_words(vocab_size))
+    zero = jnp.zeros((2,), jnp.int32)
+    assert bool(_mask_rows(srv._ctable, zero, vocab_size).all())
+    c = TokenConstraint.from_regex(r"[ab]{3}", byte_vocab(vocab_size))
+    off = srv._ctab_register(c)
+    assert off >= 1
+    assert bool(_mask_rows(srv._ctable, zero, vocab_size).all())
+    np.testing.assert_array_equal(
+        np.asarray(_mask_rows(srv._ctable, jnp.asarray([off, 0]),
+                              vocab_size)),
+        np.stack([c.mask_table(srv.eos_id)[0],
+                  np.ones((vocab_size,), bool)]))

@@ -280,3 +280,132 @@ def test_constrained_slots_gauge(model):
         assert "constrained_slots" in clock.render_prom()
     finally:
         obs.set_enabled(was)
+
+
+# ----------------------------------------------------------------------
+# ISSUE 48 — the mask pool is bit-packed and a step reads `slots` rows
+# ----------------------------------------------------------------------
+
+def _pool_rows(srv, off, n):
+    """Rows [off, off + n) of the batcher's mask pool, unpacked on the
+    host."""
+    from dnn_tpu.runtime.constrain import unpack_mask_table
+
+    return unpack_mask_table(np.asarray(srv._ctable[off:off + n]),
+                             srv.cfg.vocab_size)
+
+
+@pytest.mark.parametrize("eos_id", [None, 0], ids=["no-eos", "eos"])
+def test_mask_pool_upload_release_evict_reupload(model, eos_id):
+    """The packed mask pool through the allocator's whole life: a grammar
+    uploaded at a non-zero offset reads back as its `mask_table`, and a
+    release, an LRU eviction and a re-upload change the rows they own and
+    no other grammar's — row 0 stays all ones throughout."""
+    cfg, prepared = model
+    srv = ContinuousBatcher(cfg, prepared, slots=2, max_len=64,
+                            prompt_pad=8, allow_constraints=True,
+                            constraint_rows=8, eos_id=eos_id)
+
+    def holds(c, off):
+        want = c.mask_table(srv.eos_id)
+        np.testing.assert_array_equal(_pool_rows(srv, off, len(want)), want)
+        assert _pool_rows(srv, 0, 1).all(), "row 0 = unconstrained"
+
+    off_d = srv._ctab_register(DIGITS)
+    off_e = srv._ctab_register(EVENS)
+    assert 1 <= off_d < off_e  # EVENS sits past DIGITS: a non-zero offset
+    holds(DIGITS, off_d)
+    holds(EVENS, off_e)
+    srv._ctab_release(DIGITS)
+    holds(DIGITS, off_d)  # released, still cached
+    off_o = srv._ctab_register(ODDS)  # evicts DIGITS, takes its gap
+    assert id(DIGITS) not in srv._ctab_entries and off_o == off_d
+    holds(ODDS, off_o)
+    holds(EVENS, off_e)  # the live neighbour's rows are untouched
+    srv._ctab_release(ODDS)
+    off_d2 = srv._ctab_register(DIGITS)  # re-upload over ODDS' rows
+    assert id(ODDS) not in srv._ctab_entries
+    holds(DIGITS, off_d2)
+    holds(EVENS, off_e)
+
+
+def _parent_mask_rows(ctable, crow, vocab):
+    """The parent's form of the read, from the same pool: the WHOLE table
+    as `bool[rows, vocab]` (what the pool was before PR 48), gathered by
+    row."""
+    import jax.numpy as jnp
+
+    from dnn_tpu.runtime.constrain import unpack_mask_table
+
+    return unpack_mask_table(ctable, vocab, jnp)[crow]
+
+
+@pytest.mark.parametrize("pool_kw", [
+    {},
+    {"kv": "paged", "block_len": 8},
+    {"prefill_chunk_tokens": 8},
+    {"kv": "paged", "block_len": 8, "prefill_chunk_tokens": 8,
+     "overlap": True},
+], ids=["dense", "paged", "mixed", "paged-mixed-overlap"])
+def test_row_read_samples_the_parents_tokens(model, pool_kw, monkeypatch):
+    """Constrained and unconstrained slots in one pool (two grammars, greedy
+    and sampled, a mid-decode admission, a rider) sample, for fixed seeds,
+    the tokens they sample when the step reads the mask as the parent did:
+    a gather from the whole boolean table."""
+    from dnn_tpu.runtime import serving
+
+    cfg, prepared = model
+    got, _ = _serve(cfg, prepared, SCHEDULE, **pool_kw)
+    monkeypatch.setattr(serving, "_mask_rows", _parent_mask_rows)
+    want, _ = _serve(cfg, prepared, SCHEDULE, **pool_kw)
+    assert got == want
+    assert len({tuple(t) for t in got}) == len(got)  # four streams, not one
+
+
+@pytest.mark.parametrize("variant,program", [
+    ("dense_constrained", "_decode"), ("paged_constrained", "_decode"),
+    ("dense_constrained", "_mixed"), ("paged_constrained", "_prefill_finish"),
+])
+def test_step_touches_slots_rows_of_the_mask_pool(model, variant, program):
+    """The HLO-level shadow of "a step reads the rows it uses": in the
+    lowered `*_constrained` programs of `analysis/program.py`, no
+    instruction but the parameter itself produces a value with the pool's
+    row count as an extent, and the mask pool is consumed by one-row
+    `dynamic_slice`s alone, one a slot (one in all for the finish) — never
+    by a gather, which the chip's compiler answers by relaying out its
+    whole operand (`tests/test_chip_compile.py` holds the compiled
+    programs to the same at the daemon's 3600 rows)."""
+    from dnn_tpu.analysis import program as prg
+    from dnn_tpu.runtime.constrain import mask_words
+    from tests.test_decode_hotpath import _eqns
+
+    cfg, prepared = model
+    rows, slots = 37, 3  # an extent nothing else in the program has
+    kw = dict(prg.SERVING_DECODE_VARIANTS[variant], constraint_rows=rows)
+    if program == "_mixed":
+        kw["prefill_chunk_tokens"] = 16
+    b = ContinuousBatcher(cfg, prepared, slots=slots, max_len=64,
+                          prompt_pad=16, **kw)
+    args = {"_decode": lambda: prg.decode_step_args(b),
+            "_mixed": lambda: prg.mixed_step_args(b, 16),
+            "_prefill_finish": lambda: prg.finish_args(b, 16)}[program]()
+    pool = (rows, mask_words(cfg.vocab_size))
+    assert b._ctable.shape == pool and b._ctrans.shape[0] == rows
+    traced = getattr(b, program).trace(*args)
+    reads = []
+    for eqn in _eqns(traced.jaxpr.jaxpr):
+        for v in eqn.outvars:
+            assert rows not in v.aval.shape, (eqn.primitive.name, v.aval)
+        if any(getattr(v.aval, "shape", None) == pool
+               and v.aval.dtype == np.uint32 for v in eqn.invars
+               if hasattr(v, "aval")):
+            reads.append((eqn.primitive.name, eqn.outvars[0].aval.shape))
+    want = 1 if program == "_prefill_finish" else slots
+    assert reads == [("dynamic_slice", (1, pool[1]))] * want, reads
+    # and in the lowered text: the pool's type appears on the signature
+    # and on those slices, nowhere else
+    pool_type = "tensor<%dx%dxui32>" % pool
+    lines = [ln for ln in traced.lower().as_text().splitlines()
+             if pool_type in ln and "func.func" not in ln]
+    assert len(lines) == want
+    assert all("stablehlo.dynamic_slice" in ln for ln in lines), lines
