@@ -528,7 +528,13 @@ def _decode_call(q, k, v, pos1d, ks, vs, *, block_s, interpret):
 #     step, no DMA, no compare;
 #   * one online-softmax update covers a whole group: G = 128 //
 #     block_len blocks (at least 128 positions a step, capped by the
-#     table), read from the shapes of the call — `_paged_group`;
+#     table), read from the shapes of the call — `_paged_group`. The
+#     copies lay a group's blocks side by side, so a KV head's keys are
+#     ONE (G * block_len, d) matrix and its values another: the scores of
+#     a group are (Hk, R, 128) — one (R, d) x (d, 128) product a head,
+#     every vector operation of the softmax on full 128-lane registers —
+#     and a query row keeps ONE running state (max, sum, accumulator)
+#     from the slot's first group to its last;
 #   * columns past `pos` (the tail of the last live block, and the rest
 #     of a last group that is not full) are masked as usual;
 #   * with `new`, the slot's own row is placed in the VMEM copy of the
@@ -648,17 +654,15 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
     with `write` the gate). Inputs: q, the pool's leaves where they lie
     in HBM, and with `write` this step's rows. Outputs: the attention
     rows, and with `write` the pool's leaves again (aliased). Scratch: a
-    two-deep buffer of G blocks a leaf, the DMA semaphores (one a buffer,
-    one for the write-back), which of the two buffers the slot's first
-    group is in, and G running softmax states a query row (state g
-    attends every G-th block, and the G are merged when the slot's blocks
-    are through). With `select` one more input, last: the slot's set
-    (n_groups, G, bp) int32, nonzero where a position is read. With
-    `latent` = the value's width the pool is ONE leaf of one head
-    (models/mla.py): a group's blocks are one (G * bp, d) matrix, key as
-    it stands and value in its first `latent` lanes, the slot's R query
-    rows (its heads) meet it in one product each way, and a row has one
-    softmax state."""
+    two-deep buffer of a group a leaf — its G blocks side by side, ONE
+    (G * bp, d) matrix a head — the DMA semaphores (one a buffer, one
+    for the write-back), which of the two buffers the slot's first group
+    is in, and ONE running softmax state a query row. With `select` one
+    more input, last: the slot's set (n_groups, 1, G * bp) int32, nonzero
+    where a position is read. With `latent` = the value's width the pool
+    is ONE leaf of one head (models/mla.py): the group's matrix is key as
+    it stands and value in its first `latent` lanes, and the slot's R
+    query rows (its heads) meet it in one product each way."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -684,8 +688,9 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
 
     bi, n_slots = pl.program_id(0), pl.num_programs(0)
     hk, r, d = q_ref.shape[1:]
-    group, bp = bufs[0].shape[1], bufs[0].shape[3]
-    rows = hk * r  # query rows of the slot; each has `group` states
+    bp = pools[0].shape[-2]
+    span = bufs[0].shape[2]  # positions a group: G blocks of bp
+    group = span // bp
 
     def live_blocks(slot_i):
         """Of slot `slot_i`: how many blocks hold a position it attends
@@ -699,6 +704,11 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
     def at(blk):  # a physical block of each leaf, where it lies
         return (lay_ref[0], blk) if whole else (blk,)
 
+    def in_group(buf, buf_i, g):
+        """Block g of the group in buffer `buf_i`: rows g * bp onward of
+        every head's matrix (of its row of scales)."""
+        return buf.at[buf_i, :, pl.ds(pl.multiple_of(g * bp, bp), bp)]
+
     def group_dma(slot_i, gi, buf_i, go):
         """Start, or wait for, the copies of the live blocks of slot
         `slot_i`'s group gi into buffer `buf_i`: nothing is copied for a
@@ -709,8 +719,8 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
             blk = tab_ref[slot_i * nb_max + gi * group + g]
             for pool, buf in zip(pools, bufs):
                 getattr(pltpu.make_async_copy(
-                    pool.at[at(blk)], buf.at[buf_i, g], sem.at[buf_i]),
-                    go)()
+                    pool.at[at(blk)], in_group(buf, buf_i, g),
+                    sem.at[buf_i]), go)()
 
         jax.lax.fori_loop(
             0, jnp.clip(n_live - gi * group, 0, group), block, None)
@@ -743,35 +753,69 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
         start_next_slot(first)
         o_ref[...] = jnp.zeros_like(o_ref)
 
+    def update(gi, s2, weigh):
+        """One online-softmax update of the slot's query rows with group
+        gi's scores s2 (rows, G * bp), lane c position gi * G * bp + c.
+        `weigh` (p) -> the rows' sums of values under the weights p."""
+        cols = gi * span + jax.lax.broadcasted_iota(jnp.int32, s2.shape, 1)
+        seen = cols <= pos
+        if select:
+            # the group's set as one row of G * bp lanes, for every row
+            seen = seen & (sel_ref[0, gi] != 0)
+        s2 = jnp.where(seen, s2, _NEG_BIG)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        # a group none of whose positions is read leaves the state empty
+        p = jnp.where(seen, jnp.exp(s2 - m_new), 0.0)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True),
+            l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + weigh(p)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
     def update_latent(buf_i, gi):
-        """One online-softmax update of the slot's R rows over group gi
-        of the latent leaf: the G blocks as one (G * bp, d) matrix."""
-        kv = bufs[0][buf_i].reshape(group * bp, d)
+        """The slot's R rows over group gi of the latent leaf."""
+        kv = bufs[0][buf_i, 0]  # (G * bp, d)
         narrow = q_ref.dtype == jnp.bfloat16 and kv.dtype == jnp.bfloat16
         cdt = jnp.bfloat16 if narrow else jnp.float32
         kv = kv.astype(cdt)
         s2 = jax.lax.dot_general(
             q_ref[0, 0].astype(cdt), kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (R, G*bp)
-        cols = gi * group * bp + jax.lax.broadcasted_iota(
-            jnp.int32, s2.shape, 1)
-        seen = cols <= pos
-        if select:
-            # the group's set as one row of G * bp lanes, for every head
-            seen = seen & (sel_ref[0, gi] != 0)
-        s2 = jnp.where(seen, s2, _NEG_BIG)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.where(seen, jnp.exp(s2 - m_new), 0.0)
-        out = jax.lax.dot_general(
+        update(gi, s2, lambda p: jax.lax.dot_general(
             p.astype(cdt), kv[:, :latent], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (R, latent)
-        l_scr[...] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True),
-            l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha + out
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+            preferred_element_type=jnp.float32))  # (R, latent)
+
+    def update_kv(buf_i, gi):
+        """The slot's Hk x R rows over group gi of K and V: one (R, d) x
+        (d, G * bp) product a KV head, and one back."""
+        # K, V (Hk, G*bp, d), an int8 pool's scales (Hk, G*bp)
+        k, v, *scales = [buf[buf_i] for buf in bufs]
+        # bfloat16 rows meet a bfloat16 pool (or int8, exact in bfloat16)
+        # on the MXU as they are: the products and the float32 sums are
+        # those of the float32 form
+        narrow = (q_ref.dtype == jnp.bfloat16
+                  and k.dtype in (jnp.bfloat16, jnp.int8))
+        cdt = jnp.bfloat16 if narrow else jnp.float32
+        s = jax.lax.dot_general(
+            q_ref[0].astype(cdt), k.astype(cdt),
+            (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # (Hk, R, G*bp)
+        if quant:
+            s = s * scales[0][:, None, :]
+
+        def weigh(p):
+            pv = p.reshape(hk, r, span)
+            if quant:
+                pv = pv * scales[1][:, None, :]
+            return jax.lax.dot_general(
+                pv, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32,
+            ).reshape(hk * r, d)
+
+        update(gi, (s * scale).reshape(hk * r, span), weigh)
 
     def attend(gi, _):
         buf_i = jax.lax.rem(first + gi, 2)
@@ -788,7 +832,7 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
 
         if write:
             # the group that holds `pos` is the slot's last: its row goes
-            # into the buffered block before the block is attended, and
+            # into the buffered block before the group is attended, and
             # that one block goes back to the pool meanwhile
             holds_pos = gi == n_groups - 1
             g = last - gi * group
@@ -797,72 +841,22 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
                 blk = tab_ref[bi * nb_max + last]
                 for pool, buf in zip(pools, bufs):
                     getattr(pltpu.make_async_copy(
-                        buf.at[buf_i, g], pool.at[at(blk)], sem.at[2]), go)()
+                        in_group(buf, buf_i, g), pool.at[at(blk)],
+                        sem.at[2]), go)()
 
             @pl.when(holds_pos)
             def _place():
                 for buf, new in zip(bufs, new_refs):
-                    blk = buf[buf_i, g].astype(jnp.float32)  # (Hk, bp[, d])
+                    view = in_group(buf, buf_i, g)  # (Hk, bp[, d])
+                    blk = view[...].astype(jnp.float32)
                     here = jax.lax.broadcasted_iota(
                         jnp.int32, blk.shape, 1) == pos % bp
-                    buf[buf_i, g] = jnp.where(
+                    view[...] = jnp.where(
                         here, new[0].astype(jnp.float32), blk
                     ).astype(buf.dtype)
                 write_back("start")
 
-        if latent:
-            update_latent(buf_i, gi)
-            if write:
-                pl.when(holds_pos)(lambda: write_back("wait"))
-            return
-
-        # the group's leaves as the softmax reads them, one batch entry a
-        # (block, head): K, V (G*Hk, bp, d), an int8 pool's scales
-        # (G*Hk, bp)
-        k, v, *scales = [
-            buf[buf_i].reshape(group * hk, *buf.shape[3:]) for buf in bufs]
-        # bfloat16 rows meet a bfloat16 pool (or int8, exact in bfloat16)
-        # on the MXU as they are: the products and the float32 sums are
-        # those of the float32 form
-        narrow = (q_ref.dtype == jnp.bfloat16
-                  and k.dtype in (jnp.bfloat16, jnp.int8))
-        cdt = jnp.bfloat16 if narrow else jnp.float32
-        q = jnp.tile(q_ref[0].astype(cdt), (group, 1, 1))  # (G*Hk, R, d)
-        s = jax.lax.dot_general(
-            q, k.astype(cdt), (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (G*Hk, R, bp)
-        if quant:
-            s = s * scales[0][:, None, :]
-        s2 = (s * scale).reshape(group * rows, bp)
-        # row n of s2 is block n // rows of the group
-        shape = (group * rows, bp)
-        cols = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) // rows
-                + gi * group) * bp + jax.lax.broadcasted_iota(
-                    jnp.int32, shape, 1)
-        seen = cols <= pos
-        if select:
-            chosen = sel_ref[0, gi] != 0  # (G, bp), this group's blocks
-            seen = seen & jnp.broadcast_to(
-                chosen[:, None, :], (group, rows, bp)).reshape(shape)
-        s2 = jnp.where(seen, s2, _NEG_BIG)
-
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, s2.max(axis=-1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        # a block past the last is all masked: its state stays empty
-        p = jnp.where(seen, jnp.exp(s2 - m_new), 0.0)
-        pv = p.reshape(group * hk, r, bp)
-        if quant:
-            pv = pv * scales[1][:, None, :]
-        out = jax.lax.dot_general(
-            pv, v.astype(jnp.float32), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32,
-        )  # (G*Hk, R, d)
-        l_new = l_scr[:, :1] * alpha + p.sum(axis=-1, keepdims=True)
-        acc_scr[...] = acc_scr[...] * alpha + out.reshape(group * rows, d)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+        (update_latent if latent else update_kv)(buf_i, gi)
 
         if write:
             pl.when(holds_pos)(lambda: write_back("wait"))
@@ -873,20 +867,8 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
         jax.lax.fori_loop(0, n_groups, attend, None)
-        if latent:  # one state a row
-            o_ref[0] = (acc_scr[...] / l_scr[:, :1]).reshape(
-                hk, r, latent).astype(o_ref.dtype)
-            return
-        # merge the `group` states of each query row
-        states = [pl.ds(g * rows, rows) for g in range(group)]
-        m = functools.reduce(jnp.maximum,
-                             [m_scr[at_g, :1] for at_g in states])
-        l = acc = 0.0
-        for at_g in states:
-            w = jnp.exp(m_scr[at_g, :1] - m)
-            l = l + w * l_scr[at_g, :1]
-            acc = acc + w * acc_scr[at_g, :]
-        o_ref[0] = (acc / l).reshape(hk, r, d).astype(o_ref.dtype)
+        o_ref[0] = (acc_scr[...] / l_scr[:, :1]).reshape(
+            o_ref.shape[1:]).astype(o_ref.dtype)
 
 
 @jax.named_scope("attn.paged_decode")
@@ -914,7 +896,7 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     With `new` = (k (B, Hk, 1, D), v[, ks (B, Hk, 1), vs], gate (B,)) —
     this step's rows as the pool stores them, whole-pool form only — the
     kernel also WRITES: each slot's row goes into the block that holds
-    position pos[b] before it is attended, and the pools come back
+    position pos[b] before its group is attended, and the pools come back
     updated through aliased outputs: returns (out, kp, vp[, ks, vs]).
     The step then touches the pool with nothing but this call. A
     gated-off slot is empty whatever its `pos` and table say: no block
@@ -924,7 +906,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     positions <= pos[b] at which `sel` is true and no others (the set
     models/dsa.py chose). The kernel still walks every live block — a
     set scattered over the context leaves hardly a block without a
-    member — and masks inside the group's update; float pools only.
+    member — and masks inside the group's update, one row of 128 lanes
+    for every query row alike; float pools only.
 
     With `latent` = the value's width (latent attention, models/mla.py)
     `kp` is the pool's ONE leaf of one head, `vp` is None and q is (B, 1,
@@ -1013,27 +996,24 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         # operand numbers count the scalars: 4 of them, then q
         aliases = {5 + i: 1 + i for i in range(len(pools))}
     if select:
-        # the set as the kernel's groups see it: (B, n_groups, G, bp),
-        # the table's tail padded with positions never read
+        # the set as the kernel's groups see it: a row of G * bp lanes a
+        # group, the table's tail padded with positions never read
         n_groups = -(-nb_max // group)
         sel4 = jnp.pad(sel.astype(jnp.int32),
                        ((0, 0), (0, n_groups * group * bp - nb_max * bp))
-                       ).reshape((b, n_groups, 1, group * bp) if latent
-                                 else (b, n_groups, group, bp))
+                       ).reshape(b, n_groups, 1, group * bp)
         in_specs.append(rows_of_slot(sel4))
         rows = (*rows, sel4)
-    # softmax states: `group` a query row, merged at a slot's end; the
-    # latent form reads a group as one matrix and keeps one
-    n_states = r if latent else group * hk * r
+    n_states = hk * r  # one softmax state a query row
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            # two buffers of `group` blocks a leaf: (Hk, bp, d) rows,
-            # (Hk, bp) scales
-            *(pltpu.VMEM((2, group) + x.shape[1 + whole:], x.dtype)
+            # two buffers of a group a leaf, its blocks side by side:
+            # (Hk, G * bp, d) rows, (Hk, G * bp) scales
+            *(pltpu.VMEM((2, hk, group * bp) + x.shape[3 + whole:], x.dtype)
               for x in pools),
             pltpu.SemaphoreType.DMA((3,)),
             pltpu.SMEM((1,), jnp.int32),  # the slot's first buffer

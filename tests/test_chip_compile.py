@@ -63,12 +63,15 @@ def _compile(chip, fn, *shapes):
 
 
 # (slots, kv heads, query rows per kv head, head dim): GPT-2 as the smoke
-# serves it, a GQA shape with 128-wide heads, and the two configurations
-# the benchmark's cells serve at 16 slots (GPT-2 Large; OLMoE)
+# serves it, a GQA shape with 128-wide heads, the two configurations the
+# benchmark's cells serve at 16 slots (GPT-2 Large; OLMoE), and the cells'
+# grouped shapes: Keye's, which reads a set, and K-EXAONE's and Solar's
 GPT2 = (4, 12, 1, 64)
 GQA = (4, 8, 4, 128)
 LARGE = (16, 20, 1, 64)
 OLMOE = (16, 16, 1, 128)
+KEYE = (16, 4, 8, 128)
+GROUPED = (64, 8, 8, 128)
 BLOCK_LEN, CTX = 16, 1024
 MASK_ROWS = 3600  # the daemon's constraint pools (lm_server.py)
 
@@ -78,7 +81,8 @@ MASK_ROWS = 3600  # the daemon's constraint pools (lm_server.py)
 # block_len fills the lanes (`test_paged_kernel_leaves_what_it_cannot_copy`)
 PAGED = [(GPT2, F32, 16, CTX), (GPT2, BF16, 16, CTX), (GPT2, I8, 128, CTX),
          (GQA, BF16, 16, CTX), (LARGE, BF16, 16, 1024),
-         (OLMOE, BF16, 16, 4096)]
+         (OLMOE, BF16, 16, 4096), (KEYE, BF16, 16, 16384),
+         (GROUPED, BF16, 16, 6144), (GROUPED, BF16, 16, 9216)]
 
 
 def _paged_call(shape, dtype, bp, ctx, *, whole, n_layer=3, width=None):
@@ -87,7 +91,7 @@ def _paged_call(shape, dtype, bp, ctx, *, whole, n_layer=3, width=None):
     (L, n_blocks, ...) pool entered at a layer that rides scalar prefetch,
     the step's rows placed by the kernel and the pools handed back
     through aliased outputs. `width`: the pool's row width, where it is
-    not the daemon's."""
+    not the daemon's. Keye's call reads a set (`sel=`), as its step's."""
     from dnn_tpu.runtime.paged_kvcache import lane_padded
 
     b, hk, r, d = shape
@@ -100,14 +104,16 @@ def _paged_call(shape, dtype, bp, ctx, *, whole, n_layer=3, width=None):
     leaves = [pool, pool] + ([scales, scales] if quant else [])
     q = ((b, hk, r, d), BF16 if quant else dtype)
     tables, pos = ((b, nb), jnp.int32), ((b,), jnp.int32)
+    sel = [((b, ctx), jnp.bool_)] if shape == KEYE else []
 
     if not whole:
-        def fn(q, tables, pos, kp, vp, *ksvs):
-            ks, vs = ksvs if quant else (None, None)
-            return ca.paged_decode_attention(q, kp, vp, tables, pos, ks=ks,
-                                             vs=vs, interpret=False)
+        def fn(q, tables, pos, kp, vp, *rest):
+            ks, vs = rest[:2] if quant else (None, None)
+            return ca.paged_decode_attention(
+                q, kp, vp, tables, pos, ks=ks, vs=vs,
+                sel=rest[-1] if sel else None, interpret=False)
 
-        return fn, [q, tables, pos, *leaves]
+        return fn, [q, tables, pos, *leaves, *sel]
 
     rows = [((b, hk, 1, width), dtype)] * 2 + (
         [((b, hk, 1), F32)] * 2 if quant else [])
@@ -117,18 +123,33 @@ def _paged_call(shape, dtype, bp, ctx, *, whole, n_layer=3, width=None):
         ks, vs = ksvs if quant else (None, None)
         return ca.paged_decode_attention(
             q, kp, vp, tables, pos, ks=ks, vs=vs, layer=layer,
-            new=(*rest[len(leaves):], gate), interpret=False)
+            new=(*rest[len(leaves):len(leaves) + len(rows)], gate),
+            sel=rest[-1] if sel else None, interpret=False)
 
     return fn, [q, tables, pos, ((), jnp.int32), ((b,), jnp.bool_),
-                *leaves, *rows]
+                *leaves, *rows, *sel]
+
+
+def _kernel_calls(fn, shapes):
+    """Every pallas_call equation of fn's jaxpr."""
+    jaxpr = jax.make_jaxpr(fn)(*(jax.ShapeDtypeStruct(s, d)
+                                 for s, d in shapes))
+    return [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
 
 
 def _kernel_grids(fn, shapes):
     """The grid of every pallas_call of fn's jaxpr."""
-    jaxpr = jax.make_jaxpr(fn)(*(jax.ShapeDtypeStruct(s, d)
-                                 for s, d in shapes))
-    return [tuple(e.params["grid_mapping"].grid) for e in jaxpr.eqns
-            if e.primitive.name == "pallas_call"]
+    return [tuple(e.params["grid_mapping"].grid)
+            for e in _kernel_calls(fn, shapes)]
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, those of its sub-jaxprs (the bodies of
+    loops and branches) included."""
+    for e in jaxpr.eqns:
+        yield e
+        for sub in jax.core.jaxprs_in_params(e.params):
+            yield from _eqns(sub)
 
 
 @pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED)
@@ -147,7 +168,7 @@ def test_paged_decode_kernel_compiles_on_the_whole_pool(chip, shape, dtype,
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
-@pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED[-2:],
+@pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED[4:6],
                          ids=["gpt2-large", "olmoe"])
 @pytest.mark.parametrize("whole", [False, True], ids=["read", "write"])
 def test_paged_kernel_grid_is_over_slots_alone(shape, dtype, bp, ctx, whole):
@@ -156,6 +177,29 @@ def test_paged_kernel_grid_is_over_slots_alone(shape, dtype, bp, ctx, whole):
     inside the step."""
     fn, shapes = _paged_call(shape, dtype, bp, ctx, whole=whole)
     assert _kernel_grids(fn, shapes) == [(shape[0],)]
+
+
+@pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED[1:])
+def test_paged_kernel_scores_a_group_as_one_tile_a_head(shape, dtype, bp,
+                                                        ctx):
+    """ISSUE 49: a group's G blocks are ONE matrix of G * bp = 128
+    positions a KV head, so neither product of the kernel is `bp` columns
+    (or `bp` rows of values) at a time, and a query row has ONE running
+    softmax state: the three scratch arrays behind the block buffers are
+    Hk * R rows, not G times that."""
+    _, hk, r, _ = shape
+    fn, shapes = _paged_call(shape, dtype, bp, ctx, whole=True)
+    call, = _kernel_calls(fn, shapes)
+    dots = [e for e in _eqns(call.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    span = ca._paged_group(bp, ctx // bp) * bp
+    assert span == 128 and len(dots) == 2
+    scores, values = dots
+    assert scores.outvars[0].aval.shape == (hk, r, span)
+    assert values.invars[0].aval.shape == (hk, r, span)
+    n_scratch = call.params["grid_mapping"].num_scratch_operands
+    states = call.params["jaxpr"].invars[-n_scratch:][-3:]
+    assert [v.aval.shape[0] for v in states] == [hk * r] * 3
 
 
 @pytest.mark.parametrize("dtype,width", [(I8, 128), (BF16, 64)],

@@ -347,20 +347,22 @@ def test_paged_kernel_on_the_whole_pool(layer, quant):
         np.testing.assert_array_equal(g[others], before[others])
 
 
-def _paged_step_case(key, *, pos, gate=None, bp=16, nb=4, r=1, d=16,
+def _paged_step_case(key, *, pos, gate=None, bp=16, nb=4, hk=2, r=1, d=16,
                      width=None, quant=False, layers=1, layer=0,
-                     tables=None):
+                     tables=None, select=False):
     """One call of the kernel (interpret mode) on a random whole pool
     against the plain-jnp form of the same call: read-only without
     `gate`, with the step's rows placed under it. -> (got, want, the
     pools before, tables): `got` / `want` are (out, *pools) with a gate
-    and (out,) without. `width` stores the rows lane-padded."""
+    and (out,) without. `width` stores the rows lane-padded. `select`
+    reads a random set: about half of a slot's positions, `pos` among
+    them."""
     from dnn_tpu.ops.pallas.cached_attention import (
         _reference_paged_step,
         paged_decode_attention,
     )
 
-    B, Hk, W = len(pos), 2, width or d
+    B, Hk, W = len(pos), hk, width or d
     NB = B * nb + 1
     if tables is None:  # every slot its own blocks
         tables = 1 + np.random.RandomState(len(pos) + nb).permutation(
@@ -386,11 +388,16 @@ def _paged_step_case(key, *, pos, gate=None, bp=16, nb=4, r=1, d=16,
     q = jax.random.normal(jax.random.fold_in(key, 9), (B, Hk, r, d))
     new = None if gate is None else (*rows, jnp.asarray(gate))
     ks, vs = pools[2:] or (None, None)
+    sel = None
+    if select:
+        sel = jax.random.bernoulli(
+            jax.random.fold_in(key, 10), 0.5, (B, nb * bp)) | (
+            jnp.arange(nb * bp)[None, :] == pos[:, None])
     got = paged_decode_attention(q, *pools[:2], tables, pos, ks=ks, vs=vs,
-                                 layer=jnp.int32(layer), new=new,
+                                 layer=jnp.int32(layer), new=new, sel=sel,
                                  interpret=True)
     want = _reference_paged_step(q, pools, tables, pos, jnp.int32(layer),
-                                 new)
+                                 new, sel=sel)
     if gate is None:
         got, want = (got,), (want,)
     return got, want, pools, tables
@@ -408,31 +415,38 @@ def _assert_step_matches(got, want):
 
 # 20 blocks of 16 a slot, walked 8 at a time (8, 8 and 4): the first
 # position, a block's last and the next block's first, a group's last and
-# the next group's first, the table's last
+# the next group's first, the table's last — over every position, and (a
+# float pool) over a set
 @pytest.mark.parametrize("pos", [0, 15, 16, 127, 128, 319])
-@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("quant,select", [(False, False), (True, False),
+                                          (False, True)],
+                         ids=["float", "int8", "set"])
 @pytest.mark.parametrize("write", [False, True], ids=["read", "write"])
-def test_paged_kernel_at_the_edges_of_blocks_and_groups(pos, quant, write):
+def test_paged_kernel_at_the_edges_of_blocks_and_groups(pos, quant, select,
+                                                        write):
     got, want, _, _ = _paged_step_case(
         jax.random.PRNGKey(pos), pos=[pos, 40], nb=20, quant=quant,
-        gate=[True, True] if write else None)
+        select=select, gate=[True, True] if write else None)
     _assert_step_matches(got, want)
 
 
 @pytest.mark.parametrize("bp", [8, 16, 32, 128])
 @pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
-@pytest.mark.parametrize("r", [1, 4])
-def test_paged_kernel_block_lengths_and_query_rows(bp, quant, r):
+@pytest.mark.parametrize("hk,r", [(2, 1), (2, 4), (4, 8), (8, 8)],
+                         ids=["1", "4", "4x8", "8x8"])
+def test_paged_kernel_block_lengths_and_query_rows(bp, quant, hk, r):
     """Every block length groups by its own rule (16, 8, 4 and 1 blocks
     an update), on 3 blocks a slot — fewer than a group, or not a
-    multiple of one."""
+    multiple of one — for one query row a KV head, for four, and for the
+    grouped shapes the cells serve (Keye's 4 x 8; K-EXAONE's and
+    Solar's 8 x 8)."""
     from dnn_tpu.ops.pallas.cached_attention import _paged_group
 
     nb = 3
     assert _paged_group(bp, nb) == min(128 // bp, nb)
     got, want, _, _ = _paged_step_case(
         jax.random.PRNGKey(bp + r), pos=[bp * nb - 1, bp + 1, 0], bp=bp,
-        nb=nb, r=r, quant=quant, layers=2, layer=1,
+        nb=nb, hk=hk, r=r, quant=quant, layers=2, layer=1,
         gate=[True, True, True])
     _assert_step_matches(got, want)
 
@@ -449,30 +463,39 @@ def test_paged_kernel_on_lane_padded_rows(quant, r):
     _assert_step_matches(got, want)
 
 
+# a slot's group gi lies in buffer (first + gi) % 2 and the next slot's
+# first group in the other one: three groups, an empty slot, two groups, an
+# empty slot, one group hand the buffers on in both parities
+@pytest.mark.parametrize("pos,gate", [
+    ([319, 300, 21, 77], [True, False, True, False]),
+    ([300, 200, 250, 100, 17], [True, False, True, False, True]),
+], ids=["full-among-empty", "off-between-live"])
 @pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
 @pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
-def test_paged_kernel_full_slot_among_empty_ones(layer, quant):
+def test_paged_kernel_full_slot_among_empty_ones(layer, quant, pos, gate):
     """One slot at the table's last position, one short one, and two
     gated-off slots that kept a large stale `pos` and a stale table — the
     FULL slot's own block ids, as a retired slot's table points at blocks
     since handed to another request. The gated-off slots are empty: zeros
     out, nothing of theirs read or placed, so the pool is the oracle's
     off junk block 0 (the full slot's blocks hold exactly its own row)
-    and the live slots' rows are what they are without the others."""
+    and the live slots' rows are what they are without the others. And
+    gated-off slots BETWEEN live ones whose last groups end in different
+    buffers."""
     nb, bp = 20, 16
-    own = 1 + np.arange(4 * nb).reshape(4, nb)
-    tables = np.stack([own[0], own[0], own[2], own[0]])
-    pos, gate = [nb * bp - 1, 300, 21, 77], [True, False, True, False]
+    own = 1 + np.arange(len(pos) * nb).reshape(len(pos), nb)
+    tables = np.stack([own[s] if on else own[0]
+                       for s, on in enumerate(gate)])
     key = jax.random.PRNGKey(11)
     got, want, before, _ = _paged_step_case(
         key, pos=pos, gate=gate, nb=nb, quant=quant, layers=3, layer=layer,
         tables=tables)
     _assert_step_matches(got, want)
     out = np.asarray(got[0])
-    assert (out[[1, 3]] == 0).all() and np.isfinite(out).all()
-    live = [0, 2]
+    live = [s for s, on in enumerate(gate) if on]
+    assert (np.delete(out, live, 0) == 0).all() and np.isfinite(out).all()
     alone, _, _, _ = _paged_step_case(
-        key, pos=pos, gate=[True, True, True, True], nb=nb, quant=quant,
+        key, pos=pos, gate=[True] * len(pos), nb=nb, quant=quant,
         layers=3, layer=layer, tables=own)
     np.testing.assert_array_equal(out[live], np.asarray(alone[0])[live])
     for g, b in zip(got[1:], before):
