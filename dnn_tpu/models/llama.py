@@ -53,6 +53,21 @@ _NEG_BIG = -1e30
 
 
 @dataclasses.dataclass(frozen=True)
+class RetentionConfig:
+    """What `LlamaConfig.retention` holds: every layer's mixer is degree-2
+    POWER RETENTION (models/retention.py) — no softmax, no K or V kept; a
+    slot keeps the decayed sum of its EXPANDED keys a KV head. `tile` is
+    the t of the expansion phi (tile pairs i <= j of t x t products:
+    `retention.state_width`), `chunk` the positions a closed-form chunk of
+    the chunked rule, `gate_range` the decays g a position that the seeded
+    gate biases are spread over, a KV head each, `eps` the normaliser's."""
+    tile: int = 8
+    chunk: int = 1024
+    gate_range: tuple = (0.9, 0.9999)
+    eps: float = 1e-6
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     block_size: int = 2048
     vocab_size: int = 32000
@@ -167,8 +182,21 @@ class LlamaConfig:
     # presets' value is measured, models/llama_moe.py). 1.0 keeps the
     # gains at exactly one.
     qk_norm_init: float = 1.0
+    # ---- power retention in place of attention (models/retention.py;
+    # default off): every layer keeps a STATE a slot and no position's
+    # anything
+    retention: Optional[RetentionConfig] = None
 
     def __post_init__(self):
+        if self.retention is not None and (
+                self.sliding_window is not None or self.alt_window
+                or self.attn_softcap is not None or self.parallel_block
+                or self.index_topk is not None or self.rotary_dim is not None
+                or self.head_dim % self.retention.tile):
+            raise ValueError(
+                "retention replaces softmax attention in the sequential "
+                "block: no window, softcap, indexer or partial rotation "
+                "goes with it, and its tile divides head_dim")
         if self.parallel_block and self.post_norms:
             raise ValueError(
                 "parallel_block (Phi) and post_norms (Gemma-2) describe "
@@ -331,7 +359,33 @@ PRESETS = {
                               rms_eps=1e-5, qk_norm=True,
                               qk_norm_width="proj", pre_norm=False,
                               post_norms=True),
+    # Brumby-14B-Base (manifestai/Brumby-14B-Base config.json, `model_type`
+    # brumby): Qwen3-14B's block — GQA 5:1 with heads of 128, per-head q/k
+    # RMSNorm, SwiGLU, untied head — with every layer's attention replaced
+    # by degree-2 power retention (models/retention.py). What config.json
+    # has no key for is `assumed` in chipbench/configs/
+    # brumby-14b-pp8-1chip.json. Never instantiated whole.
+    "brumby-14b": LlamaConfig(block_size=32768, vocab_size=151936,
+                              n_layer=40, n_head=40, n_kv_head=8,
+                              n_embd=5120, d_ff=17408,
+                              head_dim_override=128,
+                              rope_theta=1_000_000.0, rms_eps=1e-6,
+                              qk_norm=True, retention=RetentionConfig()),
+    # tiny Brumby for the CPU tests: GQA 2:1 with a decoupled head width,
+    # heads of 32 in tiles of 8 (a state 640 wide: five 128-lane blocks for
+    # the interpreted step kernel), a chunk of 8 that a 16-token prefill
+    # chunk holds twice
+    "brumby-test": LlamaConfig(block_size=128, vocab_size=256, n_layer=3,
+                               n_head=4, n_kv_head=2, n_embd=64, d_ff=128,
+                               head_dim_override=32, rms_eps=1e-6,
+                               qk_norm=True,
+                               retention=RetentionConfig(tile=8, chunk=8)),
 }
+# the benchmark's cut (chipbench/configs/brumby-14b-pp8-1chip.json): one of
+# eight pipeline stages of five whole layers, layers 0-4, with `wte` and the
+# head placed on it
+PRESETS["brumby-14b-pp8-1chip"] = dataclasses.replace(
+    PRESETS["brumby-14b"], n_layer=5)
 
 
 def layer_windows(cfg: LlamaConfig):
@@ -477,6 +531,11 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
             # the softmax layer's sigmoid OUTPUT gate, element-wise
             blk["attn"]["gate"] = _dense(jax.random.fold_in(key, 23),
                                          (c, cfg.n_head * d))
+    if cfg.retention is not None:
+        from dnn_tpu.models import retention
+
+        blk["attn"]["decay"] = retention.init_gate(
+            jax.random.fold_in(key, 29), cfg)
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -811,6 +870,11 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
         from dnn_tpu.models import dsa
 
         fn = lambda bp2, h: dsa.dense_attn(  # noqa: E731
+            bp2, h, cfg=cfg, compute_dtype=compute_dtype)
+    if attn_fn is None and cfg.retention is not None:
+        from dnn_tpu.models import retention
+
+        fn = lambda bp2, h: retention.dense_mixer(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype)
     if attn_fn is None and getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models import mla
@@ -1520,14 +1584,16 @@ def family_rows(cfg, **kw):
     """The batcher adapter a LLaMA-family config serves through: by what
     its attention keeps a position (`LlamaFamilyRows`' K and V — by layer
     kind `LlamaKindRows`'; with an indexer models/dsa.py's third leaf;
-    with latent attention models/mla.py's one). `kw`: `LlamaFamilyRows`'
-    own."""
+    with latent attention models/mla.py's one; NOTHING a position with
+    models/retention.py's state a slot). `kw`: `LlamaFamilyRows`' own."""
     if cfg.index_topk is not None:
         from dnn_tpu.models.dsa import DsaFamilyRows as rows
     elif getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models.mla import MlaFamilyRows as rows
     elif getattr(cfg, "kda", None) is not None:
         from dnn_tpu.models.kda import KdaKindRows as rows
+    elif cfg.retention is not None:
+        from dnn_tpu.models.retention import RetentionRows as rows
     elif kv_kinds(cfg) is not None:
         rows = LlamaKindRows
     else:
@@ -1795,13 +1861,14 @@ def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
 
     carry = (x, jnp.zeros((3,), jnp.int32) if moe_stats else None)
     new_rows = {name: [] for name in row_cache}
+    first = next(iter(leaves))  # the kind of a model that is ONE stack
     for stack, layers, kind in layer_stacks(prepared, family.cfg):
-        names = [n for n in leaves[kind or "full"] if n in row_cache]
+        names = [n for n in leaves[kind or first] if n in row_cache]
         rows = {n: row_cache[n] if layers is None
                 else row_cache[n][layers[0]:layers[1]] for n in names}
         blocks, bind = scan_form(stack, family.ffn)
         carry, rows = lax.scan(
-            functools.partial(layer, bind, kind or "full"), carry,
+            functools.partial(layer, bind, kind or first), carry,
             (blocks, rows))
         for n in names:
             new_rows[n].append(rows[n])
@@ -2212,6 +2279,12 @@ def _register(name: str, cfg: LlamaConfig):
             "make_apply": lambda compute_dtype=None, **_kw: make_apply(
                 cfg, compute_dtype=compute_dtype),
             "make_partition": lambda compute_dtype=None, **_kw: make_partition(
+                cfg, compute_dtype=compute_dtype),
+            # a layer at a time (`registry.ParamParts`): the daemon draws,
+            # casts and frees one before the next
+            "init_parts": lambda rng, dtype=jnp.float32, _cfg=cfg:
+                init_parts(rng, _cfg, dtype),
+            "family_rows": lambda compute_dtype=None, **_kw: family_rows(
                 cfg, compute_dtype=compute_dtype),
         },
     ))

@@ -678,9 +678,12 @@ class StepClock:
         self.mla_kind_total[key] += cached
 
     def note_state(self, **adds):
-        """A model that keeps a STATE a slot beside K and V (models/
-        kda.py), counted on the host: `bytes_read` / `bytes_written` (a
-        decode step reads and writes every slot's state leaves),
+        """A model that keeps a STATE a slot — beside K and V (models/
+        kda.py) or with no K and V at all (models/retention.py: the
+        counters then carry most of a step's bytes, state and normaliser,
+        and `kv_bytes_read` stays 0) —, counted on the host: `bytes_read`
+        / `bytes_written` (a decode step reads and writes every slot's
+        state leaves),
         `kv_bytes_read` (the live K and V positions the step read),
         `prefill_real_positions` / `prefill_pad_positions` (a chunk's),
         `installs` (states written into a slot by a finish, a layer

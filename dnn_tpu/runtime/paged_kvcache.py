@@ -80,6 +80,15 @@ none), `install_row` leaves them alone, and the finish-and-install program
 writes the transient row's running state at the slot — which is also the
 only thing that resets a slot.
 
+**A cache of state kinds ALONE is not paged at all.** A model with no K/V
+layer (models/retention.py: every layer keeps `state` (L, B, KV, d, D) and
+`norm` (L, B, KV, D), float32) declares ONE kind whose `tables` is None:
+there is no block, no table, no `BlockAllocator` and no codec for it — the
+batcher holds the family's own `init_cache` leaves, admits by slots, and
+the family's decode loop carries them whole and reaches them at the
+layer's index, as `scan_blocks` carries a paged pool (`serving.py`
+`nothing_to_page`).
+
 The codec interface matches FloatKV (write_rows / attend_rows /
 write_attend_rows / install_row), so GPTFamilyRows / LlamaFamilyRows
 decode through it unchanged. The decode step reaches the pool IN PLACE:

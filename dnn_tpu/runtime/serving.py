@@ -386,14 +386,15 @@ class ContinuousBatcher:
         self._cache_kinds = getattr(self.family, "cache_kinds", None)
         # the layers that select: all of them, or the full kind's; and the
         # window kinds as the step-end counters read them
-        self._n_index_layers = (self._cache_kinds["full"]["layers"]
-                                if self._cache_kinds else cfg.n_layer)
+        full = (self._cache_kinds or {}).get("full")
+        self._n_index_layers = (cfg.n_layer if self._cache_kinds is None
+                                else full["layers"] if full else 0)
         self._win_kinds = [(kind, k["layers"], k["window"])
                            for kind, k in (self._cache_kinds or {}).items()
                            if k["window"] is not None]
         # kinds whose cache is K and V (models/llama.py `LlamaKindRows`):
         # their reads are the attn.* series', a latent family's the mla.*
-        self._kv_kinds = bool(self._cache_kinds) and not self._latent
+        self._kv_kinds = full is not None and not self._latent
         # a kind whose leaves have NO position axis (models/kda.py: a
         # state and a convolution tail a slot a layer): name -> (shape a
         # slot a layer, dtype). They ride the pool's pytree, are installed
@@ -408,13 +409,22 @@ class ContinuousBatcher:
             if k.get("slot_leaves"))
         self._takes_n_real = bool(getattr(self.family, "takes_n_real",
                                           False))
-        if getattr(self.family, "requires_paged", False):
+        # a cache whose every kind is a state kind (models/retention.py)
+        # has no block tables: there is nothing to page, the leaves are
+        # held as they are, admission is by slots alone and `max_len`
+        # bounds positions, no memory
+        nothing_to_page = bool(self._cache_kinds) and all(
+            k["tables"] is None for k in self._cache_kinds.values())
+        if getattr(self.family, "requires_paged", False) or self._slot_leaves:
             leaves = "/".join(
                 n for k in self._cache_kinds.values()
                 for n in (*k["leaves"], *k.get("slot_leaves", ()))
             ) if self._cache_kinds else "/".join(self.family.cache_leaves)
             refused = None
-            if prefix_cache > 0:
+            if nothing_to_page and decode_buckets:
+                refused = ("decode_buckets (no leaf has a position axis to "
+                           "bucket)")
+            elif prefix_cache > 0:
                 refused = ("prefix_cache (the radix prefix store and the "
                            "fleet KV tier share and move K/V blocks alone)")
             elif kv_dtype in ("int8", "int4"):
@@ -458,7 +468,10 @@ class ContinuousBatcher:
                 f"{paged_blocks}; drop one of them")
         if kv in ("paged", "auto"):
             blocker = None
-            if decode_buckets:
+            if nothing_to_page:
+                blocker = ("no leaf of this model's cache has a position "
+                           "axis: there is nothing to page")
+            elif decode_buckets:
                 blocker = ("decode_buckets is a dense-pool feature (the "
                            "paged pool is already length-proportional)")
             elif (getattr(self.family, "softcap", None) is not None
@@ -486,7 +499,8 @@ class ContinuousBatcher:
                     + (f" with paged_blocks={paged_blocks}" if paged_blocks
                        else "")
                     + f" is not available: {blocker}")
-            else:  # auto, nothing explicit: dense fallback, visibly
+            elif not nothing_to_page:
+                # auto, nothing explicit: dense fallback, visibly
                 obs.flight.record("kv_fallback_dense", reason=blocker)
 
         # device state (functional updates). paged_blocks > 0 swaps the
@@ -586,15 +600,6 @@ class ContinuousBatcher:
                 kinds=self._cache_kinds)
             self._allocator = BlockAllocator(paged_blocks, kinds=kind_blocks)
             self._block_len = block_len
-            if self._slot_leaves:
-                # the `state_pool.*` counters' units: every slot's state
-                # leaves, and a position's K and V in one full layer
-                self._state_step_bytes = sum(
-                    self.cache[n].nbytes for n in self._slot_leaves)
-                self._kv_position_bytes = sum(
-                    heads * width * self.cache[n].dtype.itemsize
-                    for n, (heads, width)
-                    in self._cache_kinds["full"]["leaves"].items())
             # table entries of window kinds to set before the next
             # dispatch: (tables name, slot, logical block, physical)
             self._wtab_pending: list = []
@@ -653,11 +658,21 @@ class ContinuousBatcher:
                 # implementations mid-stream and break the bucketed==
                 # unbucketed token-identity contract
                 use_k = False
-            codec = codec_for_cache(
+            # a pool of state leaves alone has no K and V for a codec
+            codec = None if nothing_to_page else codec_for_cache(
                 self.cache,
                 use_kernel=use_k,
                 window=getattr(self.family, "window", None),
                 softcap=getattr(self.family, "softcap", None))
+        if self._slot_leaves:
+            # the `state_pool.*` counters' units: every slot's state
+            # leaves, and a position's K and V in one full layer
+            self._state_step_bytes = sum(
+                self.cache[n].nbytes for n in self._slot_leaves)
+            self._kv_position_bytes = sum(
+                heads * width * self.cache[n].dtype.itemsize
+                for n, (heads, width) in (full["leaves"] if full
+                                          else {}).items())
         self.pos = jnp.zeros((slots,), jnp.int32)      # next write position
         self.tok = jnp.zeros((slots,), jnp.int32)      # last sampled token
         self.active = jnp.zeros((slots,), bool)
@@ -1153,17 +1168,19 @@ class ContinuousBatcher:
                     name: blocks[2 * i + 1] for i, name in enumerate(names)})
                 for i, name in enumerate(names):
                     cache[name] = cache[name].at[:, slot].set(blocks[2 * i])
-                with jax.named_scope("state_pool.install"):
-                    # the row's RUNNING state after the prompt's last real
-                    # position becomes the slot's: what resets a slot
-                    for name in self._slot_leaves:
-                        cache[name] = cache[name].at[:, slot].set(
-                            row[name][:, 0].astype(cache[name].dtype))
             elif self._paged:
                 cache = codec.install_row(cache, row, blocks[1])
                 cache["tables"] = cache["tables"].at[:, slot].set(blocks[0])
             else:
-                cache = install_dense_row(cache, row, slot)
+                cache = {**cache, **install_dense_row(
+                    {kk: cache[kk] for kk in cache
+                     if kk not in self._slot_leaves}, row, slot)}
+            with jax.named_scope("state_pool.install"):
+                # the row's RUNNING state after the prompt's last real
+                # position becomes the slot's: what resets a slot
+                for name in self._slot_leaves:
+                    cache[name] = cache[name].at[:, slot].set(
+                        row[name][:, 0].astype(cache[name].dtype))
             pos = pos.at[slot].set(prompt_len)
             tok = tok.at[slot].set(first)
             active = active.at[slot].set(True)

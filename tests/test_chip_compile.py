@@ -791,3 +791,102 @@ def _mla_cases():
 def test_latent_attention_kernels_compile(chip, kernel, dtype):
     fn, shapes = _mla_cases()[kernel](dtype)
     _compile(chip, fn, *shapes)
+
+
+# ----------------------------------------------------------------------
+# ISSUE 50 — a model with no K/V layer: the state's one pass, in place
+# ----------------------------------------------------------------------
+
+# the Brumby cell's pool: 16 slots, 8 KV heads in groups of 5 query heads,
+# heads of 128, a state 8 704 wide
+RET = dict(slots=16, kv=8, group=5, d=128, wide=8704)
+
+
+@pytest.mark.parametrize("mm", [BF16, F32], ids=["bf16", "f32"])
+def test_retention_step_kernel_compiles(chip, mm):
+    """ops/pallas/retention_step.py on the cell's whole pool of five layers
+    (2.85 GB), entered at a layer that rides scalar prefetch: the pool is
+    the call's aliased result, and nothing else of its size exists."""
+    from dnn_tpu.ops.pallas.retention_step import retention_step
+
+    b, kv, g, d, wide = (RET[k] for k in ("slots", "kv", "group", "d",
+                                          "wide"))
+    pool = (5, b, kv, d, wide)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in (
+        (pool, F32), ((), jnp.int32), ((b, kv), F32), ((b, kv, d), F32),
+        ((b, kv, wide), F32), ((b, kv, g, wide), F32))]
+    compiled = jax.jit(
+        lambda *a: retention_step(*a, mm_dtype=mm, interpret=False),
+        donate_argnums=(0,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 4
+    assert mem.temp_size_in_bytes < 2 ** 26  # the spread value, the answers
+
+
+@pytest.fixture(scope="module")
+def brumby_programs(chip):
+    """Brumby's step programs at its published widths and the cell's pool
+    (16 slots of state, 1024-token chunks), depth cut to TWO layers and
+    the vocabulary to 8192 rows (the head is not what is held here).
+    -> ({name: compiled}, the state leaf's extent a layer, its bytes)."""
+    import dataclasses
+
+    from dnn_tpu.models import llama
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(llama.PRESETS["brumby-14b"], n_layer=2,
+                              vocab_size=8192)
+    prepared = _stack_and_release(llama.init(jax.random.PRNGKey(0), cfg),
+                                  cfg, BF16)
+    b = ContinuousBatcher(
+        cfg, prepared, slots=RET["slots"], max_len=4096, prompt_pad=1024,
+        kv="auto", family=llama.family_rows(cfg, compute_dtype=BF16))
+    assert not b._paged and b._allocator is None
+    assert b.cache["state"].shape == (
+        2, RET["slots"], RET["kv"], RET["d"], RET["wide"])
+    compiled = _lower_programs(
+        chip, [(b, ("_prefill_chunk", "_prefill_finish", "_decode"))])
+    return compiled, b.cache["state"].shape[1:], sum(
+        x.nbytes for x in b.cache.values())
+
+
+def _state_extent_ops(compiled, extent):
+    return _extent_ops(compiled, re.compile(
+        r"\[(?:\d+,)?%d,%d,%d,%d\]" % tuple(extent)))
+
+
+def test_brumby_decode_step_updates_the_state_in_place(brumby_programs):
+    """No operation of the decode step has a result of the extent of a
+    layer's states (0.57 GB) or of the leaf but the kernel's own aliased
+    result: no slice cut out, no copy written back; the donated leaves are
+    the program's results and its temporaries stay far under a layer's
+    states."""
+    compiled, extent, pool_bytes = brumby_programs
+    step = compiled["_decode"]
+    ops = _state_extent_ops(step, extent)
+    assert ops and {o[0] for o in ops} == {"custom-call"}, ops
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < int(np.prod(extent)) * 4 // 2
+
+
+def test_brumby_finish_installs_a_state_without_a_pool_copy(brumby_programs):
+    compiled, extent, pool_bytes = brumby_programs
+    finish = compiled["_prefill_finish"]
+    ops = _state_extent_ops(finish, extent)
+    assert {o[0] for o in ops} <= {"dynamic-update-slice", "fusion"}, ops
+    mem = finish.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 24
+
+
+def test_brumby_chunk_program_fits_beside_the_pool(brumby_programs):
+    """The chunk program works on the transient row alone (two leaves, no
+    position axis) and its temporaries — a KV head's expanded queries at a
+    time, own factors and partners — stay under a GB."""
+    compiled, extent, _ = brumby_programs
+    chunk = compiled["_prefill_chunk"]
+    assert _state_extent_ops(chunk, extent) == []
+    assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 30

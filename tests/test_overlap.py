@@ -445,9 +445,10 @@ def test_worker_streams_interleaved_and_overlap_tokens(model):
 #: expert layers (OLMoE), an index-key leaf (Keye's `DsaFamilyRows`), one
 #: latent leaf (JoyAI's `MlaFamilyRows`), two latent kinds with a window
 #: kind (dots3's), K/V kinds with a window (K-EXAONE's), a kind with no
-#: position axis beside K and V (Solar-Open2's state)
+#: position axis beside K and V (Solar-Open2's state), and no K/V layer at
+#: all (Brumby's state: nothing paged, no allocator)
 FAMILIES = ["gpt2-test", "olmoe-test", "keye-test", "joyai-test",
-            "dots3-test", "k-exaone-test", "solar-open2-test"]
+            "dots3-test", "k-exaone-test", "solar-open2-test", "brumby-test"]
 _BUILT: dict = {}
 
 
@@ -469,6 +470,9 @@ def _family_batcher(name, **kw):
     opts = dict(slots=3, max_len=64, prompt_pad=16, kv="paged", block_len=8)
     if rows is not None:
         opts["family"] = rows()
+        kinds = getattr(opts["family"], "cache_kinds", None)
+        if kinds and not any(k["tables"] for k in kinds.values()):
+            opts["kv"] = "auto"  # state leaves alone: nothing to page
     opts.update(kw)
     return ContinuousBatcher(cfg, prepared, **opts)
 
@@ -523,7 +527,10 @@ def test_pipeline_equals_synchronous_loop_for_every_family(name):
 
 
 def _allocators(srv):
-    """The pool's allocator and each window kind's."""
+    """The pool's allocator and each window kind's (none at all where
+    nothing is paged)."""
+    if srv._allocator is None:
+        return []
     return [srv._allocator, *(srv._allocator.of(t)
                               for t in srv._window_kinds)]
 
@@ -706,7 +713,8 @@ def test_the_daemon_pipelines_by_default(model):
 
 
 @pytest.mark.parametrize("name", ["keye-test", "joyai-test", "dots3-test",
-                                  "k-exaone-test", "solar-open2-test"])
+                                  "k-exaone-test", "solar-open2-test",
+                                  "brumby-test"])
 def test_interleaved_admission_stays_refused_by_name(name):
     """A family that lives in the paged pool alone still refuses the
     mixed step, by name, with or without the pipeline — which it takes."""
