@@ -59,7 +59,11 @@ This is the intra-step instrument, in two connected halves:
     and the loop's step.loop_seconds_total{part=} (below),
     over a paged KV pool step.attn_live_blocks_total /
     step.attn_table_blocks_total (the blocks the paged decode kernel
-    walks, of those the slots' tables have),
+    walks, of those the slots' tables have) and, where a decode program
+    calls that kernel, step.attn_groups_total /
+    step.attn_full_groups_total (the groups of blocks it walked, and
+    the whole ones, whose copies it awaits — and from a slot's second
+    group on starts — as straight-line code),
     and for a model with experts moe.layer_calls_total /
     moe.assignments_total / moe.active_experts_total /
     moe.peak_expert_rows_total{program=decode|prefill} — what the expert
@@ -396,6 +400,8 @@ class StepClock:
         self.moe_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
         # a paged KV pool's blocks (note_attn_blocks): live, in the tables
         self.attn_blocks_total = [0, 0]
+        # the paged decode kernel's groups (note_attn_groups): walked, full
+        self.attn_groups_total = [0, 0]
         # an indexer's work (note_dsa): per program, DSA_SERIES in order
         self.dsa_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
         # latent attention's reads (note_mla): MLA_SERIES in order
@@ -480,6 +486,13 @@ class StepClock:
                 _weak_total("attn_blocks_total", 0),
             "step.attn_table_blocks_total":
                 _weak_total("attn_blocks_total", 1)}
+        # registered with the first note_attn_groups: a pool no kernel
+        # reads shows none
+        self._attn_group_gauges = {
+            "step.attn_groups_total":
+                _weak_total("attn_groups_total", 0),
+            "step.attn_full_groups_total":
+                _weak_total("attn_groups_total", 1)}
         # registered with the first note_moe: a model without experts
         # shows no moe_* series
         self._moe_registered = False
@@ -625,6 +638,23 @@ class StepClock:
             self._gauges_registered = False  # re-register with them
         tot[0] += live
         tot[1] += table
+
+    def note_attn_groups(self, groups: int, full: int):
+        """One decode step through the paged decode kernel: it walked
+        `groups` groups of blocks (sum over the layers that call it and
+        the slots of ceil(blocks / blocks a group)), `full` of them whole
+        — those whose copies it awaits, and from a slot's second group on
+        starts, as straight-line code; a slot's last, partial group keeps
+        the loop. From the slots' positions, no device read. Cumulative step.attn_{groups,full_groups}_total, on
+        /metrics with the first note."""
+        if not _obs.enabled():
+            return
+        tot = self.attn_groups_total
+        if not tot[0]:
+            self._gauges.update(self._attn_group_gauges)
+            self._gauges_registered = False  # re-register with them
+        tot[0] += groups
+        tot[1] += full
 
     def note_dsa(self, program: str, layer_calls: int, candidates: int,
                  selected: int):

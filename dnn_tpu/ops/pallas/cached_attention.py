@@ -33,6 +33,7 @@ non-TPU backends and non-tiling shapes.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -525,16 +526,20 @@ def _decode_call(q, k, v, pos1d, ks, vs, *, block_s, interpret):
 #     group — the next SLOT's first, when this is the slot's last — is in
 #     flight while this one is attended, so the copies run on through the
 #     slots' edges. A table entry past `pos` costs nothing — no grid
-#     step, no DMA, no compare;
-#   * one online-softmax update covers a whole group: G = 128 //
-#     block_len blocks (at least 128 positions a step, capped by the
-#     table), read from the shapes of the call — `_paged_group`. The
-#     copies lay a group's blocks side by side, so a KV head's keys are
-#     ONE (G * block_len, d) matrix and its values another: the scores of
-#     a group are (Hk, R, 128) — one (R, d) x (d, 128) product a head,
-#     every vector operation of the softmax on full 128-lane registers —
-#     and a query row keeps ONE running state (max, sum, accumulator)
-#     from the slot's first group to its last;
+#     step, no DMA, no compare. Where a slot goes from one group to its
+#     next, a FULL group's copies are straight-line code (runs of 16
+#     blocks); the group a slot's last block falls in, and a slot's first
+#     group, loop over a count known at run time;
+#   * one online-softmax update covers a whole group: G blocks of 128 to
+#     1024 positions, capped by the table — `_paged_group`, a rule over
+#     the bytes the call's leaves hold a position: as wide as a group's
+#     copies stay within 1.25 MiB. The copies lay a group's blocks side by
+#     side, so a KV head's keys are ONE (G * block_len, d) matrix and its
+#     values another: the scores of a group are (Hk, R, G * block_len) —
+#     one (R, d) x (d, G * block_len) product a head, every vector
+#     operation of the softmax on full 128-lane registers — and a query
+#     row keeps ONE running state (max, sum, accumulator) from the slot's
+#     first group to its last;
 #   * columns past `pos` (the tail of the last live block, and the rest
 #     of a last group that is not full) are masked as usual;
 #   * with `new`, the slot's own row is placed in the VMEM copy of the
@@ -642,10 +647,49 @@ def _reference_latent_step(q, cp, tables, pos, layer, new, latent, scale,
     return jnp.where(gate[:, None, None, None], out, 0.0), cp
 
 
-def _paged_group(block_len, nb_max):
-    """Blocks attended in one online-softmax update: as many as make 128
-    positions, and never more than the table has."""
-    return max(1, min(128 // block_len, nb_max))
+# the widest group of the paged decode kernel, and the most its copies
+# may bring: 1.25 MiB is the largest group timed to win (a latent leaf of
+# 640 lanes at 1024 positions; 2 MiB — 8 K/V heads at 512 — ties 1 MiB)
+_WIDEST_GROUP = 1024
+_GROUP_BYTES = 5 * 2 ** 18
+_COPY_RUN = 16  # blocks whose copies are written out one after another
+
+
+def _paged_group(block_len, nb_max, position_bytes, widest=_WIDEST_GROUP):
+    """Blocks attended in one online-softmax update — a rule over the
+    shapes of the call alone. `position_bytes`: what the pool's leaves
+    hold a position, every leaf and head. A group costs its copies' bytes
+    AND an update that is a latency chain (two MXU round trips, two
+    cross-lane reductions: ~0.4 us + 0.1 us per 128 positions, whatever
+    the bytes), so a group is as WIDE as its copies stay within
+    `_GROUP_BYTES` — two buffers a leaf hold it in VMEM, and the update's
+    latency is spread over up to eight times the positions — between 128
+    and `widest` positions and never more than the table has: a latent
+    leaf of 640 lanes (1280 B a position) takes 1024, 4 K/V heads of 128
+    lanes 512, 8 heads 256, 16 heads and more 128, where the copies bind
+    already (PERF.md section 5, PR 51)."""
+    span = 128
+    while span < widest and 2 * span * position_bytes <= _GROUP_BYTES:
+        span *= 2
+    return max(1, min(span // block_len, nb_max))
+
+
+def paged_group(leaves, nb_max, *, whole):
+    """`_paged_group` of a call on the pool's `leaves` as the kernel gets
+    them — (n_blocks, Hk, bp[, d]), with `whole` one more leading (L,) —
+    against tables of `nb_max` entries a slot."""
+    lead = 1 + bool(whole)
+    bp = leaves[0].shape[lead + 1]
+    block_bytes = sum(math.prod(x.shape[lead:]) * x.dtype.itemsize
+                      for x in leaves)
+    # an int8 pool's (Hk, bp) scale blocks lie side by side as lane slices
+    # of a (Hk, span) buffer, which the chip's compiler copies into only
+    # where Hk fills whole sublane tiles ("Slice shape along dimension 1
+    # must be aligned to tiling (8), but is 12"): its groups stay at 128
+    # positions (no cell serves one, and none was timed wider)
+    scales = any(x.ndim == lead + 2 for x in leaves)
+    return _paged_group(bp, nb_max, block_bytes // bp,
+                        widest=128 if scales else _WIDEST_GROUP)
 
 
 def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
@@ -707,23 +751,56 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
     def in_group(buf, buf_i, g):
         """Block g of the group in buffer `buf_i`: rows g * bp onward of
         every head's matrix (of its row of scales)."""
-        return buf.at[buf_i, :, pl.ds(pl.multiple_of(g * bp, bp), bp)]
+        start = g * bp if isinstance(g, int) else pl.multiple_of(g * bp, bp)
+        return buf.at[buf_i, :, pl.ds(start, bp)]
 
-    def group_dma(slot_i, gi, buf_i, go):
+    # a full group's copies are straight-line code in runs of at most
+    # `_COPY_RUN` blocks, one run a turn of a loop whose count is static,
+    # and only where a slot goes from one group to its next (`attend`):
+    # every copy written out (64 a latent group) at all five places that
+    # copy is 0.58 ms of JoyAI's call where this is 0.66, but cost the
+    # daemon's boot 6-8 s of tracing and lowering the decode program
+    run = math.gcd(group, _COPY_RUN)
+
+    def group_dma(slot_i, gi, buf_i, go, straight=False):
         """Start, or wait for, the copies of the live blocks of slot
-        `slot_i`'s group gi into buffer `buf_i`: nothing is copied for a
-        block past the slot's last."""
+        `slot_i`'s group gi into buffer `buf_i`: a loop over a count known
+        only at run time — nothing is copied for a block past the slot's
+        last. With `straight` a FULL group's are straight-line code (in
+        runs of `run` blocks), the table read at offsets the loop does
+        not compute: the core issues them back to back, and only the
+        group a slot's last block falls in takes the loop."""
         n_live, _ = live_blocks(slot_i)
+        base = slot_i * nb_max + gi * group
+        n_here = jnp.clip(n_live - gi * group, 0, group)
 
-        def block(g, _):
-            blk = tab_ref[slot_i * nb_max + gi * group + g]
+        def block(g, _=None):
+            blk = tab_ref[base + g]
             for pool, buf in zip(pools, bufs):
                 getattr(pltpu.make_async_copy(
                     pool.at[at(blk)], in_group(buf, buf_i, g),
                     sem.at[buf_i]), go)()
 
-        jax.lax.fori_loop(
-            0, jnp.clip(n_live - gi * group, 0, group), block, None)
+        if not straight:
+            jax.lax.fori_loop(0, n_here, block, None)
+            return
+
+        @pl.when(n_here == group)
+        def _full():
+            if run == group:
+                for g in range(group):
+                    block(g)
+                return
+
+            def a_run(c, _):
+                for u in range(run):
+                    block(c * run + u)
+
+            jax.lax.fori_loop(0, group // run, a_run, None)
+
+        @pl.when(n_here < group)
+        def _partial():
+            jax.lax.fori_loop(0, n_here, block, None)
 
     @pl.when(bi == 0)
     def _first():
@@ -822,13 +899,13 @@ def _paged_decode_kernel(*refs, scale, nb_max, quant, write, whole,
 
         @pl.when(gi + 1 < n_groups)
         def _():
-            group_dma(bi, gi + 1, 1 - buf_i, "start")
+            group_dma(bi, gi + 1, 1 - buf_i, "start", straight=True)
 
         @pl.when(gi + 1 == n_groups)
         def _():
             start_next_slot(1 - buf_i)
 
-        group_dma(bi, gi, buf_i, "wait")
+        group_dma(bi, gi, buf_i, "wait", straight=True)
 
         if write:
             # the group that holds `pos` is the slot's last: its row goes
@@ -906,8 +983,8 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
     positions <= pos[b] at which `sel` is true and no others (the set
     models/dsa.py chose). The kernel still walks every live block — a
     set scattered over the context leaves hardly a block without a
-    member — and masks inside the group's update, one row of 128 lanes
-    for every query row alike; float pools only.
+    member — and masks inside the group's update, one row of the group's
+    lanes for every query row alike; float pools only.
 
     With `latent` = the value's width (latent attention, models/mla.py)
     `kp` is the pool's ONE leaf of one head, `vp` is None and q is (B, 1,
@@ -955,7 +1032,7 @@ def paged_decode_attention(q, kp, vp, tables, pos, *, ks=None, vs=None,
         q = jnp.pad(q, [(0, 0)] * 3 + [(0, d - d_q)])
     nb_max = tables.shape[1]
     bp = kp.shape[-2]
-    group = _paged_group(bp, nb_max)
+    group = paged_group(pools, nb_max, whole=layer is not None)
     write = new is not None
     whole = layer is not None
     select = sel is not None

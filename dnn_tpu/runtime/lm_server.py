@@ -1357,6 +1357,14 @@ class LMServer:
             comps["kv_cache"] = {
                 "detail": "bytes of the KV cache by leaf",
                 "bytes": sum(by_leaf.values()), "bytes_by_leaf": by_leaf}
+            # a paged pool's block, and the positions a group of the
+            # paged decode kernel covers in the built decode program, by
+            # the first leaf it reads — none where no program calls it
+            codec = getattr(self.batcher, "_paged_codec", None)
+            if codec is not None:
+                comps["kv_cache"]["block_len"] = int(codec.block_len)
+                comps["kv_cache"]["decode_group_span"] = dict(
+                    codec.kernel_spans)
         # facts, no `state`: which loop the worker's step() runs, why,
         # and how often the pipeline engaged (`step_pipelined_total`,
         # `step_stale_rows_total` on /metrics)

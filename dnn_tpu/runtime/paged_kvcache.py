@@ -100,8 +100,9 @@ reference math is the dense codec's, so token parity is exact — or, with
 the kernel on, ops/pallas/cached_attention.paged_decode_attention takes
 one grid step a SLOT and walks that slot's live blocks inside it: table
 entries from SMEM, each physical block copied straight from the pool
-(which stays in HBM) into a double-buffered VMEM scratch, 128 positions'
-worth of blocks an online-softmax update, the step's own row placed in
+(which stays in HBM) into a double-buffered VMEM scratch, 128 to 1024
+positions' worth of blocks an online-softmax update (by what the leaves
+hold a position: `cached_attention._paged_group`), the step's own row placed in
 the block that holds `pos` and that block written back. Its work follows
 what the slots HOLD — a table entry past `pos` and a gated-off slot cost
 nothing — which is what `step.attn_live_blocks_total` over
@@ -380,6 +381,10 @@ class PagedKV:
         self.slot_leaves = frozenset(
             name for k in (kinds or {}).values()
             for name in k.get("slot_leaves", ()))
+        # positions a group of the paged decode kernel covers, by the
+        # first leaf a call reads ("k" / "latent"): said while a decode
+        # program was traced, empty while none called the kernel
+        self.kernel_spans: dict = {}
 
     def _kernel_on(self, c) -> bool:
         """Resolve use_kernel against a concrete per-layer pool view
@@ -395,6 +400,14 @@ class PagedKV:
             return (jax.default_backend() == "tpu"
                     and logical >= AUTO_KERNEL_MIN_S)
         return bool(self.use_kernel)
+
+    def _note_span(self, name, leaves, tables, layer):
+        """Record what a group of the kernel call about to be traced on
+        `leaves` covers (`kernel_spans`)."""
+        from dnn_tpu.ops.pallas.cached_attention import paged_group
+
+        self.kernel_spans[name] = self.block_len * paged_group(
+            leaves, tables.shape[-1], whole=layer is not None)
 
     # --- decode-row paths (per-layer views: pool (n_blocks, H, bp, D),
     #     tables (B, nb_max)) ------------------------------------------
@@ -489,6 +502,7 @@ class PagedKV:
                 paged_decode_attention,
             )
 
+            self._note_span("latent", [leaf], c["tables"][layer], layer)
             y, pool = paged_decode_attention(
                 q[:, None], leaf, None, c["tables"][layer], pos, layer=layer,
                 new=(new, write_gate), latent=value_dim, scale=scale, sel=sel,
@@ -690,6 +704,8 @@ class PagedKV:
 
             interp = True if self.use_kernel == "interpret" else None
             tables = c["tables"] if layer is None else c["tables"][layer]
+            self._note_span("k", [c[n] for n in ("k", "v", "ks", "vs")
+                                  if n in c], tables, layer)
             out = paged_decode_attention(
                 q, c["k"], c["v"], tables, pos,
                 ks=c["ks"] if quant else None,
@@ -752,6 +768,7 @@ class PagedKV:
         with jax.named_scope("kv_pool.write"):
             rows = self._rows(c, k, v)
         names = list(rows)  # k, v[, ks, vs]: the kernel's operand order
+        self._note_span("k", [c[n] for n in names], c["tables"][layer], layer)
         y, *pools = paged_decode_attention(
             q, c["k"], c["v"], c["tables"][layer], pos,
             ks=c.get("ks"), vs=c.get("vs"), layer=layer,

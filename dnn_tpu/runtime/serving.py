@@ -619,6 +619,9 @@ class ContinuousBatcher:
                             use_kernel=getattr(self.family, "attn_kernel",
                                                False),
                             kinds=self._cache_kinds)
+            # says, once a decode program is traced, what a group of the
+            # paged decode kernel covers (`kernel_spans`)
+            self._paged_codec = codec
 
             head_dim = cache_head_dim(cfg)  # init_paged_cache's
 
@@ -2999,6 +3002,14 @@ class ContinuousBatcher:
             req["b_span"] = tr.child("decode", bucket=self._cache_len)
             req["b_bucket"] = self._cache_len
 
+    def attn_kernel_span(self) -> int:
+        """Positions a group of the paged decode kernel covers in the
+        decode program traced so far (`PagedKV.kernel_spans`; one leaf
+        kind of a pool calls it) — 0 while none does: a dense cache, a
+        pool read by gather and einsums, no program yet."""
+        codec = getattr(self, "_paged_codec", None)
+        return max(codec.kernel_spans.values(), default=0) if codec else 0
+
     def _bucket_key(self) -> str:
         """Memoized labeled() key for the current bucket — the string
         formatting is measurable on the per-step path."""
@@ -3029,6 +3040,10 @@ class ContinuousBatcher:
         n_act = 0
         blocks = 0  # of a paged pool that hold a live position
         bp = self._block_len if self._paged else 0
+        # blocks a group of the paged decode kernel, where a traced decode
+        # program calls it; its groups walked, and the full ones
+        group = self.attn_kernel_span() // bp if bp else 0
+        groups = full_groups = 0
         topk = self._index_topk
         picked = 0  # by an indexer, of the live - n_act it scored
         wins = self._win_kinds
@@ -3039,7 +3054,11 @@ class ContinuousBatcher:
                 live += n
                 n_act += 1
                 if bp:
-                    blocks += -(-n // bp)
+                    nb = -(-n // bp)
+                    blocks += nb
+                    if group:
+                        groups += -(-nb // group)
+                        full_groups += nb // group
                 if topk:
                     # the step's query stood at n - 2: n - 1 candidates
                     picked += min(n - 1, topk)
@@ -3085,6 +3104,11 @@ class ContinuousBatcher:
             # could hold (step.attn_{live,table}_blocks_total)
             self.step_clock.note_attn_blocks(
                 blocks, self.slots * (self.max_len // bp))
+            if group:
+                # every layer that calls the kernel walks them
+                self.step_clock.note_attn_groups(
+                    self._n_index_layers * groups,
+                    self._n_index_layers * full_groups)
         # batched registry feed (fields documented at construction): a
         # bucket switch flushes first so the whole batch shares one
         # dispatch-counter key; an idle pool flushes so totals are

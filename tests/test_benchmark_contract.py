@@ -615,6 +615,24 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
         "k", "k_w", "v", "v_w"]
 
 
+@pytest.mark.parametrize("config", sorted(_SERVED))
+def test_statusz_says_the_pools_block_and_the_kernels_span(served, config):
+    """`/statusz` `components.kv_cache`: a paged pool's `block_len`, and
+    under `decode_group_span` the positions a group of the paged decode
+    kernel covers in the built decode program, by the first leaf it reads
+    — empty on the CPU, where no program calls the kernel (on the chip
+    {"latent": 1024} for JoyAI and dots3, {"k": 512} Keye, 256 K-EXAONE and
+    Solar, 128 OLMoE and GPT-2 Large: ISSUE 51); a model with nothing to
+    page has neither line."""
+    kv = served(config)["statusz"]["components"]["kv_cache"]
+    paged = any(k.startswith(("serving_paged", "kv_pool"))
+                and "blocks" in k for k in served(config)["metrics"])
+    if not paged:
+        assert "block_len" not in kv and "decode_group_span" not in kv
+        return
+    assert kv["block_len"] in (8, 16) and kv["decode_group_span"] == {}
+
+
 # ----------------------------------------------------------------------
 # program names and scope prefixes: the lowered step programs
 # ----------------------------------------------------------------------
@@ -772,3 +790,70 @@ def test_attn_block_counters_follow_the_live_blocks(monkeypatch, kv):
         assert clk.attn_blocks_total == [0, 0]
         assert not [k for k in got if k.startswith("step_attn_")]
         assert "step_steps_total" in got
+
+
+@pytest.mark.parametrize("case", ["kernel", "einsum", "obs-off"])
+def test_attn_group_counters_follow_the_kernels_groups(monkeypatch, case):
+    """ISSUE 51: `step_attn_groups_total` advances by the groups the paged
+    decode kernel walks — sum over its layers and the slots of ceil(blocks
+    / blocks a group) — and `step_attn_full_groups_total` by the whole
+    ones among them (floor), whose copies are straight-line code, each
+    decode step, from the slots' positions; `/statusz` says the span. A
+    pool read by gather and einsums writes neither, and nothing is noted
+    with observability off."""
+    import jax
+    import numpy as np
+
+    from dnn_tpu import obs
+    from dnn_tpu.models import gpt
+    from dnn_tpu.obs.timeline import StepClock
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+    from dnn_tpu.utils.metrics import Metrics, render_prometheus
+    from tests.test_decode_hotpath import _pinned_span
+
+    monkeypatch.setattr(obs, "_enabled", case != "obs-off")
+    cfg = gpt.GPTConfig(vocab_size=89, block_size=512, n_layer=2, n_head=2,
+                        n_embd=32)
+    slots, max_len, bp, span = 3, 256, 8, 128
+    srv = ContinuousBatcher(
+        cfg, gpt.prepare_stacked(gpt.init(jax.random.PRNGKey(0), cfg), cfg),
+        slots=slots, max_len=max_len, prompt_pad=16, kv="paged",
+        block_len=bp,
+        attn_kernel=False if case == "einsum" else "interpret")
+    reg = Metrics()
+    srv.step_clock = clk = StepClock(registry=reg)
+    assert srv.attn_kernel_span() == 0  # no decode program traced yet
+    # 16 blocks a group: 5 positions are one partial group; 135 to 140
+    # are 17 or 18 blocks, one whole group and one partial; 128 + 6 steps
+    # stay at 17 blocks
+    srv.submit(np.arange(1, 6), max_new_tokens=20)
+    srv.submit(1 + np.arange(134) % 80, max_new_tokens=20)
+    want = [0, 0]
+    with _pinned_span(span):
+        for _ in range(6):
+            srv.step()
+            held = [r["prompt_len"] + len(r["emitted"])
+                    for r in srv._slot_req if r is not None]
+            assert len(held) == 2
+            blocks = [-(-n // bp) for n in held]
+            want[0] += cfg.n_layer * sum(-(-b // 16) for b in blocks)
+            want[1] += cfg.n_layer * sum(b // 16 for b in blocks)
+    got = dict(line.rsplit(" ", 1)
+               for line in render_prometheus(reg).splitlines()
+               if line and not line.startswith("#"))
+    if case == "kernel":
+        assert srv.attn_kernel_span() == span
+        assert srv._paged_codec.kernel_spans == {"k": span}
+        assert clk.attn_groups_total == want == [cfg.n_layer * 6 * 3,
+                                                 cfg.n_layer * 6]
+        assert float(got["step_attn_groups_total"]) == want[0]
+        assert float(got["step_attn_full_groups_total"]) == want[1]
+    else:
+        assert clk.attn_groups_total == [0, 0]
+        assert not [k for k in got if "attn_groups" in k
+                    or "attn_full_groups" in k]
+        if case == "obs-off":
+            assert clk.attn_blocks_total == [0, 0]
+        else:
+            assert srv.attn_kernel_span() == 0
+            assert float(got["step_attn_live_blocks_total"]) > 0

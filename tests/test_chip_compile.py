@@ -182,18 +182,25 @@ def test_paged_kernel_grid_is_over_slots_alone(shape, dtype, bp, ctx, whole):
 @pytest.mark.parametrize("shape,dtype,bp,ctx", PAGED[1:])
 def test_paged_kernel_scores_a_group_as_one_tile_a_head(shape, dtype, bp,
                                                         ctx):
-    """ISSUE 49: a group's G blocks are ONE matrix of G * bp = 128
-    positions a KV head, so neither product of the kernel is `bp` columns
-    (or `bp` rows of values) at a time, and a query row has ONE running
-    softmax state: the three scratch arrays behind the block buffers are
-    Hk * R rows, not G times that."""
+    """ISSUE 49: a group's G blocks are ONE matrix of G * bp positions a
+    KV head, so neither product of the kernel is `bp` columns (or `bp`
+    rows of values) at a time, and a query row has ONE running softmax
+    state: the three scratch arrays behind the block buffers are Hk * R
+    rows, not G times that. ISSUE 51: the group is as wide as the call's
+    leaves allow (`_paged_group`) — 512 positions for Keye's 4 KV heads,
+    256 for K-EXAONE's and Solar's 8, 128 from OLMoE's 16 on and for an
+    int8 pool, whose scale blocks the compiler lays side by side only at
+    128."""
     _, hk, r, _ = shape
     fn, shapes = _paged_call(shape, dtype, bp, ctx, whole=True)
     call, = _kernel_calls(fn, shapes)
     dots = [e for e in _eqns(call.params["jaxpr"])
             if e.primitive.name == "dot_general"]
-    span = ca._paged_group(bp, ctx // bp) * bp
-    assert span == 128 and len(dots) == 2
+    leaves = [jax.ShapeDtypeStruct(s, dt) for s, dt in shapes
+              if len(s) >= 4 and s[0] == 3]  # the (3, n_blocks, ...) pools
+    span = ca.paged_group(leaves, ctx // bp, whole=True) * bp
+    assert span == {KEYE: 512, GROUPED: 256, GQA: 256}.get(shape, 128)
+    assert len(dots) == 2
     scores, values = dots
     assert scores.outvars[0].aval.shape == (hk, r, span)
     assert values.invars[0].aval.shape == (hk, r, span)
@@ -780,6 +787,28 @@ def _mla_cases():
             "prefill_attention_selected": prefill_attention_selected,
             "prefill_attention_banded": prefill_attention_banded,
             "paged_decode_latent": paged_decode_latent}
+
+
+def test_latent_decode_kernel_walks_groups_of_1024(chip):
+    """ISSUE 51: JoyAI's absorbed decode call — 32 slots, the 32 heads as
+    rows, ONE bfloat16 leaf of 640 lanes in blocks of 16 — at the span the
+    rule picks for it: 64 blocks, one (1024, 640) matrix, a group; two
+    products and one softmax state a query row; lowered for the TPU within
+    the kernel's default VMEM."""
+    fn, shapes = _mla_cases()["paged_decode_latent"](BF16)
+    pool = jax.ShapeDtypeStruct(*shapes[5])
+    assert ca.paged_group([pool], 1024, whole=True) * 16 == 1024
+    call, = _kernel_calls(fn, shapes)
+    dots = [e for e in _eqns(call.params["jaxpr"])
+            if e.primitive.name == "dot_general"]
+    scores, values = dots
+    assert scores.outvars[0].aval.shape == (32, 1024)
+    assert values.invars[1].aval.shape == (1024, 512)
+    n_scratch = call.params["grid_mapping"].num_scratch_operands
+    buf, *_, m, l, acc = call.params["jaxpr"].invars[-n_scratch:]
+    assert buf.aval.shape == (2, 1, 1024, 640)
+    assert [v.aval.shape[0] for v in (m, l, acc)] == [32] * 3
+    _compile(chip, fn, *shapes)
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])

@@ -347,16 +347,34 @@ def test_paged_kernel_on_the_whole_pool(layer, quant):
         np.testing.assert_array_equal(g[others], before[others])
 
 
+def _pinned_span(span):
+    """The kernel's group held at `span` positions whatever the call's
+    shapes ask for (`_paged_group`'s rule would give these small pools
+    1024): the paths of a group — straight-line copies for a full one, the
+    loop for a slot's last — at every span the rule can pick. None leaves
+    the rule in place."""
+    import contextlib
+    from unittest import mock
+
+    from dnn_tpu.ops.pallas import cached_attention as ca
+
+    if span is None:
+        return contextlib.nullcontext()
+    return mock.patch.object(
+        ca, "_paged_group",
+        lambda bp, nb, _bytes, widest=None: max(1, min(span // bp, nb)))
+
+
 def _paged_step_case(key, *, pos, gate=None, bp=16, nb=4, hk=2, r=1, d=16,
                      width=None, quant=False, layers=1, layer=0,
-                     tables=None, select=False):
+                     tables=None, select=False, span=None):
     """One call of the kernel (interpret mode) on a random whole pool
     against the plain-jnp form of the same call: read-only without
     `gate`, with the step's rows placed under it. -> (got, want, the
     pools before, tables): `got` / `want` are (out, *pools) with a gate
     and (out,) without. `width` stores the rows lane-padded. `select`
     reads a random set: about half of a slot's positions, `pos` among
-    them."""
+    them. `span` pins the positions a group (`_pinned_span`)."""
     from dnn_tpu.ops.pallas.cached_attention import (
         _reference_paged_step,
         paged_decode_attention,
@@ -393,9 +411,10 @@ def _paged_step_case(key, *, pos, gate=None, bp=16, nb=4, hk=2, r=1, d=16,
         sel = jax.random.bernoulli(
             jax.random.fold_in(key, 10), 0.5, (B, nb * bp)) | (
             jnp.arange(nb * bp)[None, :] == pos[:, None])
-    got = paged_decode_attention(q, *pools[:2], tables, pos, ks=ks, vs=vs,
-                                 layer=jnp.int32(layer), new=new, sel=sel,
-                                 interpret=True)
+    with _pinned_span(span):
+        got = paged_decode_attention(q, *pools[:2], tables, pos, ks=ks,
+                                     vs=vs, layer=jnp.int32(layer), new=new,
+                                     sel=sel, interpret=True)
     want = _reference_paged_step(q, pools, tables, pos, jnp.int32(layer),
                                  new, sel=sel)
     if gate is None:
@@ -415,9 +434,9 @@ def _assert_step_matches(got, want):
 
 # 20 blocks of 16 a slot, walked 8 at a time (8, 8 and 4): the first
 # position, a block's last and the next block's first, a group's last and
-# the next group's first, the table's last — over every position, and (a
-# float pool) over a set
-@pytest.mark.parametrize("pos", [0, 15, 16, 127, 128, 319])
+# the next group's first, two groups' last, the table's last — over every
+# position, and (a float pool) over a set
+@pytest.mark.parametrize("pos", [0, 15, 16, 127, 128, 255, 319])
 @pytest.mark.parametrize("quant,select", [(False, False), (True, False),
                                           (False, True)],
                          ids=["float", "int8", "set"])
@@ -426,7 +445,7 @@ def test_paged_kernel_at_the_edges_of_blocks_and_groups(pos, quant, select,
                                                         write):
     got, want, _, _ = _paged_step_case(
         jax.random.PRNGKey(pos), pos=[pos, 40], nb=20, quant=quant,
-        select=select, gate=[True, True] if write else None)
+        select=select, gate=[True, True] if write else None, span=128)
     _assert_step_matches(got, want)
 
 
@@ -435,15 +454,24 @@ def test_paged_kernel_at_the_edges_of_blocks_and_groups(pos, quant, select,
 @pytest.mark.parametrize("hk,r", [(2, 1), (2, 4), (4, 8), (8, 8)],
                          ids=["1", "4", "4x8", "8x8"])
 def test_paged_kernel_block_lengths_and_query_rows(bp, quant, hk, r):
-    """Every block length groups by its own rule (16, 8, 4 and 1 blocks
-    an update), on 3 blocks a slot — fewer than a group, or not a
-    multiple of one — for one query row a KV head, for four, and for the
-    grouped shapes the cells serve (Keye's 4 x 8; K-EXAONE's and
-    Solar's 8 x 8)."""
+    """Every block length groups by the rule (`_paged_group`: 128, 64, 32
+    and 8 blocks an update where these small pools' copies allow 1024
+    positions, 16, 8, 4 and 1 where 16 KV heads' — or an int8 pool's
+    scale blocks — allow 128), on 3 blocks a slot — fewer than a group,
+    or not a multiple of one — for one query row a KV head, for four, and
+    for the grouped shapes the cells serve (Keye's 4 x 8; K-EXAONE's and
+    Solar's 8 x 8). The rule at the cells' own leaves:
+    tests/test_paged_groups.py."""
     from dnn_tpu.ops.pallas.cached_attention import _paged_group
 
     nb = 3
-    assert _paged_group(bp, nb) == min(128 // bp, nb)
+    # what these pools hold a position: K and V rows of 16 float32 (int8
+    # with a float32 scale each) a KV head
+    held = 2 * hk * (16 + 4 if quant else 16 * 4)
+    assert held * 512 * 2 <= 2 ** 20
+    assert _paged_group(bp, nb, held) == min(1024 // bp, nb)
+    assert _paged_group(bp, 1024, held) == 1024 // bp
+    assert _paged_group(bp, nb, held, widest=128) == min(128 // bp, nb)
     got, want, _, _ = _paged_step_case(
         jax.random.PRNGKey(bp + r), pos=[bp * nb - 1, bp + 1, 0], bp=bp,
         nb=nb, hk=hk, r=r, quant=quant, layers=2, layer=1,
@@ -489,14 +517,14 @@ def test_paged_kernel_full_slot_among_empty_ones(layer, quant, pos, gate):
     key = jax.random.PRNGKey(11)
     got, want, before, _ = _paged_step_case(
         key, pos=pos, gate=gate, nb=nb, quant=quant, layers=3, layer=layer,
-        tables=tables)
+        tables=tables, span=128)
     _assert_step_matches(got, want)
     out = np.asarray(got[0])
     live = [s for s, on in enumerate(gate) if on]
     assert (np.delete(out, live, 0) == 0).all() and np.isfinite(out).all()
     alone, _, _, _ = _paged_step_case(
         key, pos=pos, gate=[True] * len(pos), nb=nb, quant=quant,
-        layers=3, layer=layer, tables=own)
+        layers=3, layer=layer, tables=own, span=128)
     np.testing.assert_array_equal(out[live], np.asarray(alone[0])[live])
     for g, b in zip(got[1:], before):
         g, b = np.asarray(g), np.asarray(b)

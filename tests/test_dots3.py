@@ -270,10 +270,15 @@ def test_every_branch_of_the_cells_row_is_handed_whole_column_tiles(
     assert sorted(fam.prefill_steps["window"]) == [8 + 8]  # window 9
 
 
-def test_latent_decode_kernel_reads_the_selected_positions():
+@pytest.mark.parametrize("nb", [5, 40], ids=["one-group", "groups-of-128"])
+def test_latent_decode_kernel_reads_the_selected_positions(nb):
+    """`nb` 40 walked 128 positions at a time: whole groups, copied as
+    straight-line code, before a partial last one (ISSUE 51)."""
+    from tests.test_decode_hotpath import _pinned_span
+
     rng = np.random.default_rng(3)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    b, nb, bp, heads, d, dv = 3, 5, 8, 4, 40, 32
+    b, bp, heads, d, dv = 3, 8, 4, 40, 32
     pool = f(2, b * nb + 1, 1, bp, 128).at[..., d:].set(0.0)
     tables = jnp.asarray(1 + rng.permutation(b * nb).reshape(b, nb),
                          jnp.int32)
@@ -286,9 +291,10 @@ def test_latent_decode_kernel_reads_the_selected_positions():
     q, row = f(b, 1, heads, d), f(b, 1, 1, 128).at[..., d:].set(0.0)
     want, pool_a = ca._reference_latent_step(
         q, pool, tables, pos, 1, (row, gate), dv, 0.3, sel)
-    got, pool_b = ca.paged_decode_attention(
-        q, pool, None, tables, pos, layer=1, new=(row, gate), latent=dv,
-        scale=0.3, sel=sel, interpret=True)
+    with _pinned_span(128 if nb == 40 else None):
+        got, pool_b = ca.paged_decode_attention(
+            q, pool, None, tables, pos, layer=1, new=(row, gate), latent=dv,
+            scale=0.3, sel=sel, interpret=True)
     assert float(jnp.abs(got - want).max()) < 1e-5
     assert (np.asarray(pool_a) == np.asarray(pool_b)).all()
 

@@ -139,31 +139,36 @@ def _case_tie(model):
     assert np.asarray(got).tolist() == [[True, True, False, False, True]]
 
 
-def _case_gated_off_slot(model):
+def _case_gated_off_slot(model, nb=4, pos=(5, 19, 30), span=None):
     """The paged kernel under a set, interpreted: a gated-off slot reads
-    and writes nothing and returns zeros; the others attend their set."""
+    and writes nothing and returns zeros; the others attend their set.
+    `span` pins the positions a group of the kernel covers."""
+    from tests.test_decode_hotpath import _pinned_span
+
     rng = np.random.default_rng(1)
-    b, hk, r, d, nb, bp, n_layer = 3, 2, 2, 128, 4, 8, 2
+    b, hk, r, d, bp, n_layer = 3, 2, 2, 128, 8, 2
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
     kp, vp = f(n_layer, b * nb + 1, hk, bp, d), f(n_layer, b * nb + 1, hk, bp, d)
     tables = jnp.asarray(1 + rng.permutation(b * nb).reshape(b, nb), jnp.int32)
-    pos = jnp.asarray([5, 19, 30], jnp.int32)
+    pos = jnp.asarray(pos, jnp.int32)
     gate = jnp.asarray([True, False, True])
     valid = (jnp.arange(nb * bp)[None, :] <= pos[:, None]) & gate[:, None]
     sel = dsa.select(f(b, nb * bp), valid, 6)
     new = (f(b, hk, 1, d), f(b, hk, 1, d), gate)
     q = f(b, hk, r, d)
-    got = ca.paged_decode_attention(q, kp, vp, tables, pos,
-                                    layer=jnp.int32(1), new=new, sel=sel,
-                                    interpret=True)
+    with _pinned_span(span):
+        got = ca.paged_decode_attention(q, kp, vp, tables, pos,
+                                        layer=jnp.int32(1), new=new, sel=sel,
+                                        interpret=True)
     want = ca._reference_paged_step(q, [kp, vp], tables, pos, jnp.int32(1),
                                     new, sel)
     assert float(jnp.abs(got[0] - want[0]).max()) < 1e-5
     assert float(jnp.abs(got[0][1]).max()) == 0.0
     # what a set leaves out matters: the full read differs
-    full = ca.paged_decode_attention(q, kp, vp, tables, pos,
-                                     layer=jnp.int32(1), new=new,
-                                     interpret=True)
+    with _pinned_span(span):
+        full = ca.paged_decode_attention(q, kp, vp, tables, pos,
+                                         layer=jnp.int32(1), new=new,
+                                         interpret=True)
     assert float(jnp.abs(full[0][2] - got[0][2]).max()) > 1e-3
 
 
@@ -180,6 +185,15 @@ SELECTION_CASES = {
             family=m[0].extras["family_rows"](attn_kernel="interpret")),
     "tie": _case_tie,
     "gated_off_slot": _case_gated_off_slot,
+    # ISSUE 51, groups of 128 positions (16 blocks of 8): whole groups are
+    # copied as straight-line code, a slot's last by the loop — two whole
+    # and a partial one, an empty slot, exactly two whole
+    "gated_off_slot_between_full_groups":
+        lambda m: _case_gated_off_slot(m, nb=40, pos=(300, 290, 255),
+                                       span=128),
+    "groups_of_256_under_a_set":
+        lambda m: _case_gated_off_slot(m, nb=72, pos=(570, 290, 511),
+                                       span=256),
 }
 
 

@@ -275,14 +275,22 @@ def test_prefill_kernel_matches_its_plain_form(start):
     assert float(jnp.abs(a - b).max()) < 1e-5
 
 
+@pytest.mark.parametrize("nb", [9, 40], ids=["one-group", "groups-of-128"])
 @pytest.mark.parametrize("block_len", [8, 16])
-def test_latent_decode_kernel_matches_its_plain_form(block_len):
+def test_latent_decode_kernel_matches_its_plain_form(block_len, nb):
     """The paged kernel's latent form in interpret mode: one leaf copied
     once and read as key and value, the step's row placed and written
-    back, a gated-off slot empty; against the gather-and-einsum form."""
+    back, a gated-off slot empty; against the gather-and-einsum form. 9
+    blocks a slot are one group by the rule (`_paged_group`: 1024
+    positions for a leaf this small); 40 walked 128 positions at a time
+    are whole groups, copied as straight-line code, and a partial last
+    one, copied by the loop (ISSUE 51; tests/test_paged_groups.py has
+    every layout)."""
+    from tests.test_decode_hotpath import _pinned_span
+
     rng = np.random.default_rng(block_len)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    b, nb, layers, heads, d, dv = 4, 9, 2, 5, 40, 32
+    b, layers, heads, d, dv = 4, 2, 5, 40, 32
     pool = f(layers, b * nb + 1, 1, block_len, 128).at[..., d:].set(0.0)
     tables = jnp.asarray(1 + rng.permutation(b * nb).reshape(b, nb),
                          jnp.int32)
@@ -294,9 +302,10 @@ def test_latent_decode_kernel_matches_its_plain_form(block_len):
     for layer in range(layers):
         want, pool_w = ca._reference_latent_step(
             q, pool, tables, pos, layer, (row, gate), dv, 0.3)
-        got, pool_g = ca.paged_decode_attention(
-            q, pool, None, tables, pos, layer=jnp.int32(layer),
-            new=(row, gate), latent=dv, scale=0.3, interpret=True)
+        with _pinned_span(128 if nb == 40 else None):
+            got, pool_g = ca.paged_decode_attention(
+                q, pool, None, tables, pos, layer=jnp.int32(layer),
+                new=(row, gate), latent=dv, scale=0.3, interpret=True)
         assert got.shape == (b, 1, heads, dv)
         assert float(jnp.abs(got - want).max()) < 1e-5
         # (the plain form scribbles a gated-off slot's row into the junk
