@@ -925,6 +925,7 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
     from dnn_tpu.models.gpt import GPTConfig, prepare_stacked
     from dnn_tpu.models.gpt_moe import GPTMoEConfig
     from dnn_tpu.models.llama import LlamaConfig, family_rows
+    from dnn_tpu.obs.timeline import rpc_event_loop
     from dnn_tpu.runtime.lm_server import serve_lm
 
     cfg = engine.spec.config
@@ -1117,7 +1118,7 @@ def _serve_lm(engine: PipelineEngine, args) -> int:
             allow_logit_bias=not spec_kwargs,
             allow_constraints=not spec_kwargs,
             **lora_kwargs,
-        ))
+        ), loop_factory=rpc_event_loop)
     except KeyboardInterrupt:
         log.info("shutting down")
         return 0

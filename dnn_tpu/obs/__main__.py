@@ -407,8 +407,6 @@ def _timeline_selftest() -> int:
     assert s["window_steps"] == 3 and s["steps_total"] == 3, s
     # per step: wall 9 ms + 0.5 ms admit; host 3.5 ms, device 6 ms
     assert abs(s["host_fraction"] - 3.5 / 9.5) < 1e-3, s
-    assert abs(s["dispatch_slack"] - 3.5 / 6.0) < 1e-3, s
-    assert abs(s["sync_tax"] - 4.0 / 9.5) < 1e-3, s
     assert s["tokens"] == 12, s
     # an admission that reports no parts is all its own host time
     assert abs(s["admit_split"]["self"] - 0.0015) < 1e-9, s
@@ -458,8 +456,7 @@ def _timeline_selftest() -> int:
         except ValueError:
             pass
     print("timeline selftest ok: 3 deterministic steps (host fraction "
-          f"{s['host_fraction']:.2%}, slack {s['dispatch_slack']:.2f}, "
-          f"sync tax {s['sync_tax']:.2%}), synthetic capture analyzed "
+          f"{s['host_fraction']:.2%}), synthetic capture analyzed "
           f"(device busy {a['device']['busy_frac']:.1%}), garbage "
           "rejected")
     return 0
@@ -479,8 +476,6 @@ def _timeline_url(url: str, last=None) -> int:
     for p, d in phases.items():
         print(f"  {p:<9} {d['frac']:7.1%}  {d['mean_ms']:9.3f} ms/step")
     print(f"host fraction {s.get('host_fraction', 0):.1%} | "
-          f"dispatch slack {s.get('dispatch_slack', 0):.2f} | "
-          f"sync tax {s.get('sync_tax', 0):.1%} | "
           f"{s.get('steps_per_sec', 0):.1f} steps/s | last step "
           f"{s.get('last_wall_ms', 0):.2f} ms")
     split = s.get("admit_split")

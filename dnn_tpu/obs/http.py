@@ -31,7 +31,7 @@ Prometheus scraper or a plain curl can watch the serving stack:
     GET  /stepz        step-timeline attribution (obs/timeline.py) when
                        a StepClock is attached: per-phase decode-step
                        decomposition (admit/host/dispatch/wait/commit/
-                       obs), dispatch-slack, sync-tax, host fraction
+                       obs), host fraction
                        (JSON; ?format=prom re-renders as gauges,
                        ?last=N bounds the window). The steps on a
                        timeline are in a POST /profilez capture: the

@@ -41,19 +41,12 @@ This is the intra-step instrument, in two connected halves:
                           Chunked-prefill interleave removes the admit
                           convoy; double-buffered dispatch hides
                           host/commit/obs under device steps.
-        dispatch_slack  = host_s / device_s — the headroom
-                          double-buffered dispatch would exploit
-                          (< 1.0 means every host phase could hide
-                          entirely under the device step)
-        sync_tax        = wait / wall       — the per-token
-                          device->host sampling sync's share (fused
-                          on-device top-k/top-p sampling attacks this)
 
     All series land in the existing registry behind the one-None-check
     DNN_TPU_OBS gate: `begin()` returns None when the gate is off, and
     every producer site guards on that one None. Scrape-time CALLABLE
-    gauges (step.dispatch_slack / step.sync_tax / step.host_fraction /
-    step.per_sec / step.last_wall_ms, and the cumulative totals
+    gauges (step.host_fraction / step.per_sec / step.last_wall_ms, and
+    the cumulative totals
     step.steps_total / step.tokens_advanced_total /
     step.phase_seconds_total{phase=} / step.admit_seconds_total{part=}
     and the loop's step.loop_seconds_total{part=} (below),
@@ -132,6 +125,32 @@ This is the intra-step instrument, in two connected halves:
     (`process.thread_cpu_seconds_total`, lm_server.py), over windows
     long enough for such a clock.
 
+    **The other thread.** The gRPC aio server's event loop runs on a
+    thread of its own, which takes each step's tokens from the worker
+    in one hand-off and streams them. Its wall time divides into
+    RPC_PARTS, accumulated by that thread (RpcLoopClock) and read at a
+    scrape as `serving.rpc_loop_seconds_total{part=}`:
+
+        select    blocked in the loop's selector: nothing to do
+        fan_out   `_fan_out`: a hand-off's tokens onto their streams'
+                  queues
+        token     in `GenerateStream`, from the `q.get()` that returns a
+                  token to the `yield` of its message: the lag
+                  bookkeeping, `np.asarray`, the protobuf
+        rest      everything else between two `select()`s: asyncio's
+                  task wake-ups and timers, gRPC's serialization and
+                  write, the unary front, preflight
+
+    with `serving.rpc_loop_iterations_total` and
+    `serving.fan_out_lag_seconds_{sum,count}`. Over a window the four
+    sum to the window (to the run in progress at a scrape). While a
+    capture records the runs are `rpc.run` annotations (`iter=`) with
+    `rpc.fan_out` (`tokens=`, `handoff=`) nested in them and, at the
+    end of a run that built messages, one `rpc.tokens` marker with
+    their count (`tokens=`; with an annotation a token a capture slowed
+    the steps ~3 points more than the parent's, on the chip), on that
+    thread's line of the host plane.
+
 Served via GET /stepz (JSON; ?format=prom) on the obs endpoint
 and `python -m dnn_tpu.obs timeline [--url URL | PATH]`; the chip
 benchmark reads the same totals and spans (chipbench/spans.py,
@@ -144,10 +163,12 @@ works on any host (the obs/__main__.py contract).
 
 from __future__ import annotations
 
+import asyncio
 import glob
 import gzip
 import json
 import os
+import selectors
 import threading
 import time
 import weakref
@@ -159,7 +180,8 @@ from dnn_tpu.obs import profile as _profile
 from dnn_tpu.utils.metrics import labeled
 
 __all__ = ["StepClock", "PHASES", "STEP_BUCKETS", "analyze",
-           "active_clock", "render_report"]
+           "active_clock", "render_report", "RpcLoopClock", "RPC_PARTS",
+           "StampedSelector", "rpc_event_loop"]
 
 #: phase names, in within-step order (admit precedes the step proper)
 PHASES = ("admit", "host", "dispatch", "wait", "commit", "obs")
@@ -419,8 +441,6 @@ class StepClock:
         # appends; flush() does the per-phase fan-out off the hot path)
         self._pending_flush: list = []
         self._pending_bulk: list = []  # landed, not yet billed
-        # (steps_total, {...}) memo for the derived gauges — see _derived
-        self._derived_cache = None
         # memoized labeled histogram keys — string formatting is
         # measurable on the per-step path (the serving _bucket_key
         # lesson)
@@ -509,8 +529,6 @@ class StepClock:
                _weak_total("admit_seconds_total", p) for p in ADMIT_PARTS},
             **{labeled("step.loop_seconds_total", part=p):
                _weak_total("loop_seconds_total", p) for p in LOOP_PARTS},
-            "step.dispatch_slack": _weak("dispatch_slack"),
-            "step.sync_tax": _weak("sync_tax"),
             "step.host_fraction": _weak("host_fraction"),
             "step.per_sec": _weak("steps_per_sec"),
             "step.last_wall_ms": _weak("last_wall_ms"),
@@ -820,7 +838,7 @@ class StepClock:
         up to FLUSH_EVERY evictions instead of an append+eviction per
         step). This is the HALF of flush() ring readers need — and the
         only half they may run: the registry's own gauge render calls
-        the ring-derived series (dispatch_slack & co.) while HOLDING
+        the ring-derived series (host_fraction & co.) while HOLDING
         the registry lock, so a reader that reached Metrics.bulk from
         there would self-deadlock on that non-reentrant lock. Landed
         recs queue in _pending_bulk for the next real flush()'s
@@ -902,36 +920,11 @@ class StepClock:
                 acc = [a + b for a, b in zip(acc, r.loop)]
         return {p: round(acc[i], 6) for i, p in enumerate(LOOP_PARTS)}
 
-    def _derived(self) -> dict:
-        """The three ring-derived gauges from ONE _sums pass, memoized
-        on the step counter: a /metrics render calls each gauge in the
-        same scrape, and three independent ring copies + folds per
-        scrape is pointless lock traffic against the producer. The
-        cache read/write is a benign race (gauges may be stale by the
-        one step that landed mid-scrape)."""
-        key = self.steps_total
-        cached = self._derived_cache
-        if cached is not None and cached[0] == key:
-            return cached[1]
-        _, tot, wall, _ = self._sums()
-        dev = sum(tot[p] for p in _DEVICE_PHASES)
-        host = sum(tot[p] for p in _HOST_PHASES)
-        d = {
-            "dispatch_slack": host / dev if dev > 0 else 0.0,
-            "sync_tax": tot["wait"] / wall if wall > 0 else 0.0,
-            "host_fraction": host / wall if wall > 0 else 0.0,
-        }
-        self._derived_cache = (key, d)
-        return d
-
-    def dispatch_slack(self) -> float:
-        return self._derived()["dispatch_slack"]
-
-    def sync_tax(self) -> float:
-        return self._derived()["sync_tax"]
-
     def host_fraction(self) -> float:
-        return self._derived()["host_fraction"]
+        """(admit + host + commit + obs) / wall over the ring, for the
+        scrape-time gauge."""
+        _, tot, wall, _ = self._sums()
+        return sum(tot[p] for p in _HOST_PHASES) / wall if wall > 0 else 0.0
 
     def steps_per_sec(self) -> float:
         """Rate over the ring's newest 60 s of records — computed at
@@ -1030,8 +1023,6 @@ class StepClock:
             # same records (LOOP_PARTS; not part of `window_wall_s`)
             "loop_split": self._loop_split(recs),
             "host_fraction": round(host / wall, 4) if wall > 0 else 0.0,
-            "dispatch_slack": round(host / dev, 4) if dev > 0 else 0.0,
-            "sync_tax": round(tot["wait"] / wall, 4) if wall > 0 else 0.0,
             "steps_per_sec": round(self.steps_per_sec(), 3),
             "last_wall_ms": round(self.last_wall_ms(), 4),
             # the expert layers over the same steps, per program
@@ -1083,9 +1074,8 @@ class StepClock:
         s = self.summary(last)
         m = Metrics()
         for k in ("steps_total", "window_steps", "window_wall_s",
-                  "host_fraction", "dispatch_slack", "sync_tax",
-                  "steps_per_sec", "last_wall_ms", "mixed_steps",
-                  "overlap_depth", "constrained_slots"):
+                  "host_fraction", "steps_per_sec", "last_wall_ms",
+                  "mixed_steps", "overlap_depth", "constrained_slots"):
             m.set(f"dnn_tpu_step_{k}", float(s[k]))
         for p, d in s["phases"].items():
             m.set(labeled("dnn_tpu_step_phase_seconds_total", phase=p),
@@ -1103,6 +1093,141 @@ def active_clock() -> Optional[StepClock]:
     if ref is None:
         return None
     return ref()
+
+
+# ----------------------------------------------------------------------
+# the event-loop thread: the daemon's other thread
+# ----------------------------------------------------------------------
+
+#: what the event-loop thread's wall time divides into (module docstring)
+RPC_PARTS = ("select", "fan_out", "token", "rest")
+
+
+class RpcLoopClock:
+    """The event-loop thread's wall time by RPC_PARTS, accumulated by the
+    thread itself as the worker's loop accumulates LOOP_PARTS: plain
+    numbers only that thread adds to, read by scrape-time callables
+    (`lm_server._install_host_gauges`).
+
+    The loop's selector (StampedSelector) stamps `perf_counter` on each
+    side of `select()`: blocked in it is `select`, and from its return to
+    its next call is one RUN of the loop — callbacks, task steps, gRPC's
+    writes. Inside a run the program's own synchronous sections report
+    themselves (`section_ends`): `fan_out` (a hand-off's tokens put on
+    their queues) and `token` (one streamed token's message); `rest` is
+    what they leave of the run. While a capture records, a run is an
+    `rpc.run` annotation (`iter=`) with `rpc.fan_out` nested in it by its
+    caller and, where the run built messages, one `rpc.tokens` marker at
+    its end (`tokens=`: how many): synchronous stretches only, nothing is
+    open across an `await`.
+
+    `fan_out_lag` is [seconds, hand-offs] from the worker's stamp at a
+    hand-off to `_fan_out`'s entry on this thread: the loop's wake-up
+    (self-pipe, `select()`'s return, the interpreter lock)."""
+
+    __slots__ = ("seconds", "iterations", "tokens", "fan_out_lag", "_now",
+                 "_t_run", "_claimed", "_selecting", "_run_span",
+                 "_run_tokens")
+
+    def __init__(self, now=time.perf_counter):
+        self.seconds = dict.fromkeys(RPC_PARTS, 0.0)
+        self.iterations = 0
+        self.tokens = 0  # `token` sections, ever
+        self.fan_out_lag = [0.0, 0]
+        self._now = now
+        self._t_run: Optional[float] = None  # where the run began
+        self._claimed = 0.0  # seconds its sections have taken of it
+        self._selecting: Optional[float] = None  # where select() began
+        self._run_span = None
+        self._run_tokens = 0  # `tokens` when the run's annotation opened
+
+    def select_begins(self):
+        """A run ends: what its sections left of it is `rest`."""
+        t = self._now()
+        if self._run_span is not None:
+            built = self.tokens - self._run_tokens
+            if built:
+                _profile.close_span(_profile.open_span("rpc.tokens",
+                                                       tokens=built))
+            _profile.close_span(self._run_span)
+            self._run_span = None
+        if self._t_run is not None:
+            self.seconds["rest"] += (t - self._t_run) - self._claimed
+        self._selecting = t
+
+    def select_returns(self):
+        # cleared before the clock is read and the total grows: a scrape
+        # that still sees this block (select_seconds) read an earlier
+        # clock and a total without it
+        since, self._selecting = self._selecting, None
+        t = self._now()
+        self.seconds["select"] += t - since
+        self.iterations += 1
+        self._t_run, self._claimed = t, 0.0
+        if _profile._capturing:
+            self._run_tokens = self.tokens
+            self._run_span = _profile.open_span("rpc.run",
+                                                iter=self.iterations)
+
+    def fan_out_begins(self, t_commit: float) -> float:
+        t = self._now()
+        self.fan_out_lag[0] += t - t_commit
+        self.fan_out_lag[1] += 1
+        return t
+
+    def section_ends(self, part: str, t0: float, span=None):
+        """The section of the running iteration that began at `t0` (and
+        `span`, its annotation while a capture records) ends here."""
+        _profile.close_span(span)
+        dt = self._now() - t0
+        self.seconds[part] += dt
+        self._claimed += dt
+
+    def token_ends(self, t0: float):
+        """One streamed token's `token` section, begun at `t0`, ends."""
+        self.section_ends("token", t0)
+        self.tokens += 1
+
+    def select_seconds(self) -> float:
+        """`seconds["select"]` with the `select()` in progress, for a
+        scrape from another thread: an idle loop blocks for as long as
+        nothing arrives, and the parts would stop short of the window by
+        that much. The block counts only if it is the same one before
+        the total is read and after the clock is, so no scrape counts a
+        block twice and the series never steps back."""
+        since = self._selecting
+        total = self.seconds["select"]
+        now = self._now()
+        if since is not None and self._selecting is since:
+            return total + (now - since)
+        return self.seconds["select"]
+
+
+class StampedSelector(selectors.DefaultSelector):
+    """The platform's selector with its `clock`'s two stamps around
+    `select()`; without a clock, the platform's selector."""
+
+    clock: Optional[RpcLoopClock] = None
+
+    def select(self, timeout=None):
+        clock = self.clock
+        if clock is None:
+            return super().select(timeout)
+        clock.select_begins()
+        try:
+            return super().select(timeout)
+        finally:
+            clock.select_returns()
+
+
+def rpc_event_loop() -> asyncio.AbstractEventLoop:
+    """A selector event loop over a StampedSelector, which it keeps as
+    `stamped_selector` for whoever serves on it to give a clock
+    (`LMServer.note_rpc_loop_thread`): `asyncio.run`'s `loop_factory`."""
+    selector = StampedSelector()
+    loop = asyncio.SelectorEventLoop(selector)
+    loop.stamped_selector = selector
+    return loop
 
 
 # ----------------------------------------------------------------------

@@ -52,6 +52,8 @@ from dnn_tpu.comm.service import (
     _tensor_arr,
     _tensor_msg,
 )
+from dnn_tpu.obs import profile as _profile
+from dnn_tpu.obs.timeline import RPC_PARTS, RpcLoopClock, rpc_event_loop
 from dnn_tpu.runtime.serving import ContinuousBatcher
 
 log = logging.getLogger("dnn_tpu.lm_server")
@@ -167,15 +169,26 @@ class TokenSink:
         self.put = put
 
 
-def _fan_out(pairs, t_commit):
+def _fan_out(pairs, t_commit, clock=None, handoff=0):
     """On the event loop's thread: each `(put, token)` of one hand-off to
     its stream's queue, in commit order. A consumer that went away loses
-    its own token only."""
+    its own token only. `clock`, the loop thread's RpcLoopClock, gets the
+    hand-off's lag and the section's seconds (`fan_out`); while a capture
+    records the section is an `rpc.fan_out` annotation, `handoff` the
+    hand-off's number among `serving.emit_handoffs_total`."""
+    span = None
+    if clock is not None:
+        t = clock.fan_out_begins(t_commit)
+        if _profile._capturing:
+            span = _profile.open_span("rpc.fan_out", tokens=len(pairs),
+                                      handoff=handoff)
     for put, tok in pairs:
         try:
             put(("tok", (tok, t_commit)))
         except Exception:  # noqa: BLE001 — one dead stream consumer
             log.debug("token sink refused a token", exc_info=True)
+    if clock is not None:
+        clock.section_ends("fan_out", t, span)
 
 
 class _QueuedRequest(NamedTuple):
@@ -270,6 +283,9 @@ class _BatcherWorker(threading.Thread):
         # made for TokenSinks' tokens (_hand_off). LMServer points this at
         # its own pair, so the scrape-time counters outlive a worker
         self.emit_counts = [0, 0]
+        # the event-loop thread's clock, which `_fan_out` reports to
+        # there (LMServer's, as the pair above; None: nobody's)
+        self.rpc_clock = None
 
     def submit(self, prompt: np.ndarray, max_new: int, seed, *,
                opts=None, on_token=None, cancel_evt=None, trace=None):
@@ -543,7 +559,9 @@ class _BatcherWorker(threading.Thread):
         t_commit = time.perf_counter()
         for loop, pairs in batch.items():
             try:
-                loop.call_soon_threadsafe(_fan_out, pairs, t_commit)
+                loop.call_soon_threadsafe(_fan_out, pairs, t_commit,
+                                          self.rpc_clock,
+                                          self.emit_counts[0] + 1)
             except RuntimeError:  # the loop closed under its streams
                 log.debug("hand-off of %d tokens failed", len(pairs),
                           exc_info=True)
@@ -1027,6 +1045,11 @@ class LMServer:
         # only the worker thread writes (_BatcherWorker._hand_off); their
         # ratio is the tokens a wake-up of the event loop delivers
         self._emit_counts = [0, 0]
+        # the event-loop thread's wall time by part (obs/timeline.py),
+        # which that thread writes: its selector's two stamps once
+        # note_rpc_loop_thread() has met a stamped loop, `_fan_out` and
+        # GenerateStream's token section on any loop
+        self._rpc = RpcLoopClock()
         self._rpc_cpu_clock_id = None  # note_rpc_loop_thread()
         self._thread_cpu_last = {}
         self._install_host_gauges()
@@ -1176,9 +1199,16 @@ class LMServer:
     def note_rpc_loop_thread(self):
         """Called once ON the thread that runs the gRPC aio server's
         event loop (serve_lm, start_lm_server_in_background): its CPU
-        clock is process.thread_cpu_seconds_total{thread="rpc_loop"}."""
+        clock is process.thread_cpu_seconds_total{thread="rpc_loop"}, and
+        its selector, where the loop has a stamped one
+        (`timeline.rpc_event_loop`), stamps this server's RpcLoopClock
+        from here on."""
         self._rpc_cpu_clock_id = time.pthread_getcpuclockid(
             threading.get_ident())
+        selector = getattr(asyncio.get_running_loop(), "stamped_selector",
+                           None)
+        if selector is not None:
+            selector.clock = self._rpc
 
     def _thread_cpu(self, thread: str) -> float:
         """CPU seconds of the batcher worker or of the event-loop thread,
@@ -1202,7 +1232,7 @@ class LMServer:
             return
         from dnn_tpu.utils.metrics import labeled
 
-        lag, handed = self._emit_lag, self._emit_counts
+        lag, handed, rpc = self._emit_lag, self._emit_counts, self._rpc
         ref = weakref.ref(self)  # the registry outlives a server
 
         def thread_cpu(thread):
@@ -1224,6 +1254,16 @@ class LMServer:
             "serving.emit_lag_seconds_max": lambda: lag[2],
             "serving.emit_handoffs_total": lambda: float(handed[0]),
             "serving.emit_tokens_total": lambda: float(handed[1]),
+            # the event-loop thread's time (RpcLoopClock)
+            labeled("serving.rpc_loop_seconds_total", part="select"):
+                rpc.select_seconds,
+            **{labeled("serving.rpc_loop_seconds_total", part=p):
+               (lambda p=p: rpc.seconds[p]) for p in RPC_PARTS[1:]},
+            "serving.rpc_loop_iterations_total":
+                lambda: float(rpc.iterations),
+            "serving.fan_out_lag_seconds_sum": lambda: rpc.fan_out_lag[0],
+            "serving.fan_out_lag_seconds_count":
+                lambda: float(rpc.fan_out_lag[1]),
         }.items():
             m.set_fn(name, fn)
 
@@ -1615,6 +1655,7 @@ class LMServer:
         if self.worker_restarts > 0:
             worker.on_death = self._on_worker_death
         worker.emit_counts = self._emit_counts
+        worker.rpc_clock = self._rpc
         return worker
 
     _MAX_JSON_DEPTH = 3  # regex expansion grows with depth; bound it
@@ -2274,7 +2315,7 @@ class LMServer:
             q: "asyncio.Queue" = asyncio.Queue()
             cancel_evt = threading.Event()
 
-            lag = self._emit_lag
+            lag, rpc = self._emit_lag, self._rpc
             fut = self.worker.submit(
                 np.asarray(prompt, np.int32).reshape(-1), max_new, seed,
                 opts=opts, on_token=TokenSink(loop, q.put_nowait),
@@ -2313,16 +2354,21 @@ class LMServer:
                 if kind == "tok":
                     n += 1
                     tok, t_commit = val
-                    waited = time.perf_counter() - t_commit
+                    # the `token` section of this thread's time: from
+                    # here to the message's yield
+                    t_tok = time.perf_counter()
+                    waited = t_tok - t_commit
                     lag[0] += waited
                     lag[1] += 1
                     if waited > lag[2]:
                         lag[2] = waited
-                    yield wc.TensorResponse(
+                    msg = wc.TensorResponse(
                         status=f"[lm] token {n}",
                         result_tensor=_tensor_msg(
                             np.asarray([tok], np.int32)),
                     )
+                    rpc.token_ends(t_tok)
+                    yield msg
                     continue
                 await self._result_or_abort(val, context)
                 return
@@ -2492,7 +2538,7 @@ def start_lm_server_in_background(cfg, prepared, *, port: int, **server_kwargs):
     """Test/embedding helper: serve_lm on a daemon thread; returns
     (thread, stop_callback) — mirrors
     comm.service.start_stage_server_in_background."""
-    loop = asyncio.new_event_loop()
+    loop = rpc_event_loop()
     started = threading.Event()
     state = {}
 

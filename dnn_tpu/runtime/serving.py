@@ -1375,8 +1375,7 @@ class ContinuousBatcher:
         # overlap=True runs a ONE-STEP dispatch pipeline: step() DISPATCHES
         # step N and commits step N-1's tokens, so the host slot loop
         # (commit/obs, and the next admission's bookkeeping) runs while
-        # the device executes step N — the dispatch_slack headroom the
-        # StepClock reports, actually spent. Tokens surface one step()
+        # the device executes step N. Tokens surface one step()
         # call later; drain()/flush_overlap() commit the trailing step.
         self._overlap = bool(overlap)
         # how often the pipeline engaged, and what it cost (scrape-time
@@ -3684,8 +3683,7 @@ class ContinuousBatcher:
             c_lp, t_lp, t_ids = self._lp_host(p_lps)
             if rec is not None:
                 # with the pipeline live, "wait" is only the RESIDUAL
-                # unhidden device time of step N-1 — the hiding the
-                # dispatch_slack gauge predicted, verified here
+                # unhidden device time of step N-1
                 sc.mark(rec, "wait")
             return self._commit_step(p_idx, toks, c_lp, t_lp, t_ids,
                                      rec, sc, p_rows)

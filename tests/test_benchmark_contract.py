@@ -24,7 +24,7 @@ import time
 
 import pytest
 
-from chipbench import cells, hosttime, spans, tracered
+from chipbench import cells, hosttime, rpctime, spans, tracered
 from chipbench.daemon import Daemon
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -194,6 +194,8 @@ def served(tmp_path_factory):
             found["span_names"] = {
                 s[0] for s in spans.load_capture(xplane)["spans"]
                 + hosttime.load_capture(xplane)}
+            # the host plane's two lines as `rpctime` keeps them
+            found["rpc_lines"] = rpctime.load_lines(xplane)
         except BaseException:
             if daemon.proc.poll() is None:
                 daemon.proc.kill()
@@ -292,6 +294,104 @@ def test_worker_time_is_partitioned_on_the_daemons_page(served):
     assert m["serving_emit_tokens_total"] == \
         m["serving_emit_lag_seconds_count"]
     assert m["jax_traces_total"] >= m["jax_compilations_total"] > 0
+
+
+_RPC_ENTRIES = {"srv_rpc_loop_busy_pct": rpctime.loop_busy_pct,
+                "srv_rpc_us_per_token": rpctime.us_per_token,
+                "srv_rpc_token_build_us": rpctime.token_build_us,
+                "srv_fan_out_lag_ms": rpctime.fan_out_lag_ms,
+                "srv_host_overlap_rpc_ms_per_step":
+                    rpctime.host_overlap_rpc_ms_per_step,
+                "steady_rpc_us_per_token": rpctime.us_per_token}
+
+
+@pytest.mark.parametrize("name", sorted(_RPC_ENTRIES))
+def test_rpc_entry_lists_emit_lags_cells_and_names_its_reader(name):
+    """Each entry that reads the event-loop thread's time is reported by
+    the cells that report `srv_emit_lag_ms` (steady's by steady), under
+    `Daemon`, and resolves there to its reader in `chipbench/rpctime.py`
+    with no arguments; on a page and a capture that predate the series and
+    the spans (the commit before) the reader returns nothing."""
+    entry = {m["name"]: m for m in _BENCH["per_layer"]}
+    twin = "steady_emit_lag_ms" if name.startswith("steady_") \
+        else "srv_emit_lag_ms"
+    assert entry[name]["workloads"] == entry[twin]["workloads"]
+    assert entry[name]["moves"] == entry[twin]["moves"]
+    assert (entry[name]["layer"], entry[name]["better"]) == ("Daemon",
+                                                             "lower")
+    assert entry[name]["source"] == (
+        "program_span" if "overlap" in name else "program_counter")
+    for cell_name in entry[name]["workloads"]:
+        reader, args = cells.resolve(cell_name,
+                                     rehearse=True)["per_layer"][name]
+        assert reader is _RPC_ENTRIES[name] and args == {}
+    old = {"step_steps_total": 9.0, "serving_emit_tokens_total": 80.0,
+           "process_perf_counter_seconds": 50.0}
+    facts = {"metrics0": dict.fromkeys(old, 0.0), "metrics1": old,
+             "trace_capture": None}
+    assert _RPC_ENTRIES[name](facts) is None
+    row = next(n["rpctime"] for n in facts["notes"] if "rpctime" in n)
+    assert "error" not in row and row["overlap"] is None
+    assert row["loop_busy_pct"] is None and row["us_per_token"] is None
+
+
+@pytest.mark.parametrize("name,stats", [
+    ("rpc.run", ("iter",)), ("rpc.fan_out", ("tokens", "handoff")),
+    ("rpc.tokens", ("tokens",))])
+def test_rpc_span_is_written_by_the_daemons_loop_thread(served, name, stats):
+    """The event-loop thread's spans in a real capture of a daemon started
+    as the benchmark starts it: on ONE line of the host plane, which is not
+    the worker's, with the stats `rpctime`'s note pairs them by."""
+    lines = served(sorted(_SERVED)[0])["rpc_lines"]
+    found = [s for s in lines["rpc"] if s[0] == name]
+    assert found and all(k in s[3] for s in found for k in stats)
+    assert not any(s[0].startswith("rpc") for s in lines["worker"])
+    assert not any(s[0].split(".")[0] in hosttime.ROOTS
+                   for s in lines["rpc"])
+    if name != "rpc.run":  # nested in a run: a synchronous section of it
+        runs = [s for s in lines["rpc"] if s[0] == "rpc.run"]
+        inside = sum(any(r[1] <= s[1] and s[1] + s[2] <= r[1] + r[2]
+                         for r in runs) for s in found)
+        assert inside >= len(found) - 1  # the capture's edge may cut one
+
+
+def test_rpc_loop_time_is_partitioned_on_the_daemons_page(served):
+    """The four `serving_rpc_loop_seconds_total` parts sum to the loop
+    thread's life so far (under the daemon's uptime; the block in progress
+    counts), most of it `select` on a daemon that served a few requests;
+    every hand-off left one lag, and the readers turn the page into their
+    numbers."""
+    found = served(sorted(_SERVED)[0])
+    m = found["metrics"]
+    parts = {p: m[f'serving_rpc_loop_seconds_total{{part="{p}"}}']
+             for p in rpctime.PARTS}
+    assert all(v > 0 for v in parts.values())
+    assert parts["select"] > sum(parts.values()) / 2
+    assert sum(parts.values()) < m["process_perf_counter_seconds"]
+    assert m["serving_rpc_loop_iterations_total"] > \
+        m["serving_emit_handoffs_total"]
+    assert m["serving_fan_out_lag_seconds_count"] == \
+        m["serving_emit_handoffs_total"]
+    zero = dict.fromkeys(m, 0.0)
+    facts = {"metrics0": zero, "metrics1": m,
+             "trace_capture": found["capture"]}
+    busy = rpctime.loop_busy_pct(facts)
+    assert busy == pytest.approx(
+        100 * (1 - parts["select"] / sum(parts.values())))
+    per_token = rpctime.us_per_token(facts)
+    assert per_token == pytest.approx(
+        1e6 * (sum(parts.values()) - parts["select"])
+        / m["serving_emit_tokens_total"])
+    assert 0 < rpctime.token_build_us(facts) < per_token
+    assert 0 < rpctime.fan_out_lag_ms(facts) < 1e3
+    # the capture's own reading, and the whole split as ONE note row
+    assert rpctime.host_overlap_rpc_ms_per_step(facts) >= 0
+    (row,) = [n["rpctime"] for n in facts["notes"] if "rpctime" in n]
+    assert "error" not in row
+    assert row["overlap"]["steps"] > 0
+    assert sum(row["overlap"]["by"].values()) == pytest.approx(
+        row["overlap"]["ms_per_step"])
+    assert not set(row["overlap"]["by"]) & set(rpctime.WAITING)
 
 
 def test_tokens_per_handoff_reads_the_daemons_two_counters(served):
@@ -500,6 +600,138 @@ def test_step_cycle_needs_no_agreement_of_the_two_clocks(lead_ms):
         true["idle_step_wait_pct"], abs=1.0)
     assert fixed["loop.emit"] == pytest.approx(
         true["idle_loop_emit_pct"], abs=1.0)
+
+
+def _scripted_two_threads(lead_ms=0.0, n=12):
+    """`_scripted_capture`'s worker with the host plane's other two lines:
+    the runtime's enqueue 0.05 to 0.15 ms into each `step.dispatch` (the
+    program handed to the device at 0.1), and an event-loop thread that
+    runs from 0.2 ms into the dispatch for 2 ms (a hand-off's fan-out, then
+    four tokens) and again over the last 0.3 ms of `loop.emit` and the
+    0.1 ms of the next `loop.pre`."""
+    ms = 1_000_000
+    cap = _scripted_capture(lead_ms, n)
+    worker, rpc, runtime = list(cap["spans"]), [], []
+    for i in range(n):
+        t = i * 62 * ms // 10
+        d0 = t + 2 * ms // 10
+        runtime += [[rpctime.ENQUEUE, d0 + ms // 20, ms // 10, {}],
+                    [rpctime.ISSUE, d0 + ms // 10, ms // 50, {}]]
+        rpc += [["rpc.run", t + 4 * ms // 10, 2 * ms, {"iter": 2 * i}],
+                ["rpc.fan_out", t + 4 * ms // 10, ms // 10,
+                 {"tokens": 4, "handoff": i + 1}],
+                ["rpc.run", t + 59 * ms // 10, 4 * ms // 10,
+                 {"iter": 2 * i + 1}]]
+        rpc += [["rpc.tokens", t + 24 * ms // 10, 0, {"tokens": 4}]]
+    return {"worker": worker, "rpc": rpc, "runtime": runtime,
+            "devices": cap["devices"]}
+
+
+@pytest.mark.parametrize("shift_ms", [0.0, 2.0, -2.0])
+def test_overlap_and_dispatch_tail_read_the_host_plane_alone(shift_ms):
+    """Hand-computed on a scripted capture: a step's `step.dispatch`
+    (0.2-0.7 ms) lies under `rpc.run` (0.4-2.4 ms) for 0.3 ms, its
+    `loop.emit` (5.2-6.2) under the second run (5.9-6.3) for 0.3 and the
+    next `loop.pre` for 0.1; `step.wait`, where the worker waits, counts
+    nothing. The enqueue returns 0.15 ms into the dispatch: 0.35 ms of tail,
+    0.3 of it under `rpc.run`. Moving the device plane by 2 ms either way
+    changes neither reading."""
+    cap = _scripted_two_threads(lead_ms=shift_ms)
+    over = rpctime.overlap(cap)
+    # both threads' spans cover 0.4 ms .. 74.4 ms: the 11 steps that begin
+    # in there, the first iteration's dispatch and emit, and every later
+    # iteration's pre, dispatch and emit
+    assert over["steps"] == 11
+    assert over["by"] == pytest.approx(
+        {"loop.pre": 11 * 0.1 / 11, "step.dispatch": 12 * 0.3 / 11,
+         "loop.emit": 12 * 0.3 / 11})
+    assert over["ms_per_step"] == pytest.approx((0.6 + 11 * 0.7) / 11)
+    tail = rpctime.dispatch_tail(cap)
+    assert tail == pytest.approx({"steps": 12, "launch": 0.15, "tail": 0.35,
+                                  "tail_under_rpc": 0.3})
+    facts = {"rpctime_capture": cap, "trace_capture": None}
+    assert rpctime.host_overlap_rpc_ms_per_step(facts) == over["ms_per_step"]
+    row = next(n["rpctime"] for n in facts["notes"] if "rpctime" in n)
+    assert row["dispatch_tail"] == tail and row["overlap"] == over
+
+
+@pytest.mark.parametrize("lead_ms", [0.0, 0.7, 1.5, 2.4])
+def test_device_lead_is_bounded_below_by_the_runtimes_events(lead_ms):
+    """The scripted program starts 0.2 ms after the runtime hands it to the
+    device: the bound from the runtime's events is 0.1 ms closer to the
+    lead than the dispatch span's begin can be, whatever the lead."""
+    cap = _scripted_two_threads(lead_ms=lead_ms)
+    lead = rpctime.device_lead_ms(cap)
+    assert lead == {"lo": pytest.approx(lead_ms - 0.2, abs=1e-6),
+                    "launches": 12}
+    from_spans = hosttime.clock_lead_ms(
+        {"devices": cap["devices"], "spans": cap["worker"]})
+    assert from_spans["lo"] == pytest.approx(lead["lo"] - 0.1, abs=1e-6)
+    assert rpctime.device_lead_ms(dict(cap, runtime=[])) is None
+
+
+@pytest.fixture(scope="module")
+def recorded_rpc():
+    """A stretch of whole loop iterations of `olmoe-chat-saturated`'s
+    capture on the chip (PR 52), as `rpctime.load_lines` keeps it: the
+    worker's line (its spans), the event-loop thread's line (`rpc*`), the
+    runtime's enqueue events, and the device's busy intervals."""
+    import gzip
+
+    path = os.path.join(REPO, "tests", "recorded_rpc_lines.json.gz")
+    with gzip.open(path, "rt") as f:
+        return json.load(f)
+
+
+def test_recorded_overlap_is_what_a_microsecond_grid_counts(recorded_rpc):
+    """`srv_host_overlap_rpc_ms_per_step` on a real capture against a count
+    that shares none of its code: on a grid of microseconds, those at which
+    some span of the worker is open, none of the three it waits in, and an
+    `rpc.run` is open on the other line."""
+    import numpy as np
+
+    cap = recorded_rpc
+    prog = [s for s in cap["worker"] if s[0].split(".")[0] in hosttime.ROOTS]
+    runs = [s for s in cap["rpc"] if s[0] == "rpc.run"]
+    assert len(runs) > 50 and sum(s[0] == "step" for s in prog) >= 8
+    t0 = max(min(s[1] for s in runs), min(s[1] for s in prog))
+    t1 = min(max(s[1] + s[2] for s in runs),
+             max(s[1] + s[2] for s in prog))
+    n = (t1 - t0) // 1000
+
+    def grid(events):
+        on = np.zeros(n + 1, np.int32)
+        for _, start, dur, _ in events:
+            a = min(max((start - t0 + 500) // 1000, 0), n)
+            b = min(max((start + dur - t0 + 500) // 1000, 0), n)
+            on[a] += 1
+            on[b] -= 1
+        return np.cumsum(on)[:n] > 0
+
+    working = grid(prog) & ~grid([s for s in prog
+                                  if s[0] in rpctime.WAITING])
+    both = int((working & grid(runs)).sum())  # microseconds
+    steps = sum(1 for s in prog if s[0] == "step" and t0 <= s[1] < t1)
+    over = rpctime.overlap(cap)
+    assert over["steps"] == steps
+    assert over["ms_per_step"] == pytest.approx(both / 1e3 / steps, rel=0.01)
+    assert over["ms_per_step"] > 1.0  # the chip's reading: section 5
+    assert max(over["by"], key=over["by"].get) == "step.dispatch"
+    # the jit call returns long after the runtime's enqueue has, and nearly
+    # all of that tail lies under the other thread's run
+    tail = rpctime.dispatch_tail(cap)
+    assert tail["steps"] >= steps - 2
+    assert 0.05 < tail["launch"] < 1.0 < tail["tail"]
+    assert tail["tail_under_rpc"] > 0.8 * tail["tail"]
+    # ... on the host plane alone: the device plane may lie anywhere
+    for ns in (2_000_000, -2_000_000):
+        moved = dict(cap, devices=hosttime._shifted(
+            {"devices": cap["devices"], "spans": []}, ns)["devices"])
+        assert rpctime.overlap(moved) == over
+        assert rpctime.dispatch_tail(moved) == tail
+    # the one reading that does follow the device plane: its clock's lead
+    lead = rpctime.device_lead_ms(cap)
+    assert 0.1 < lead["lo"] < 3.0 and lead["launches"] >= 8
 
 
 def test_recorded_capture_has_a_clock_lead_and_a_cycle(recorded_loop):

@@ -311,3 +311,186 @@ def test_emit_lag_is_stamped_once_a_handoff(loop):
     for step in range(1, 4):  # stamps of the three common steps
         assert len({stamps[step] for stamps in by_stream}) == 1
     assert all(s <= now for stamps in by_stream for s in stamps)
+
+
+# ----------------------------------------------------------------------
+# the event-loop thread's own clock (obs/timeline.RpcLoopClock)
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def wire():
+    """A served daemon on a background thread, its clients and the
+    streams' reader: `stream_all(n_new)` reads one stream a prompt."""
+    port = 59343
+    _, stop = start_lm_server_in_background(
+        CFG, _prepared(), port=port, slots=4, max_len=48, prompt_pad=8,
+        default_max_new=8)
+
+    def stream_all(n_new):
+        got = [None] * len(PROMPTS)
+
+        def read(i):
+            ci = NodeClient(f"127.0.0.1:{port}")
+            got[i] = list(ci.generate_stream(PROMPTS[i],
+                                             max_new_tokens=n_new,
+                                             seed=40 + i))
+            ci.close()
+
+        readers = [threading.Thread(target=read, args=(i,))
+                   for i in range(len(PROMPTS))]
+        for r in readers:
+            r.start()
+        for r in readers:
+            r.join(timeout=120)
+        assert all(len(g) == n_new for g in got), got
+        time.sleep(0.1)  # the loop thread writes the last message out
+
+    stream_all(3)  # every program compiled
+    try:
+        yield stop.servicer, stream_all
+    finally:
+        stop()
+
+
+def _rpc_parts(srv):
+    rpc = srv._rpc
+    return dict(rpc.seconds, select=rpc.select_seconds())
+
+
+@pytest.mark.parametrize("traffic", ["served", "idle"])
+def test_rpc_loop_parts_sum_to_the_wall_time(wire, traffic):
+    """The four parts of the event-loop thread's time sum to the wall
+    time of a window, whatever it did in it: `select` is nearly all of an
+    idle server's (the block in progress counts at a read), and serving
+    streams takes time from it for the other three."""
+    srv, stream_all = wire
+    before, t0 = _rpc_parts(srv), time.perf_counter()
+    if traffic == "served":
+        stream_all(8)
+    else:
+        time.sleep(0.4)
+    wall = time.perf_counter() - t0
+    after = _rpc_parts(srv)
+    took = {p: after[p] - before[p] for p in after}
+    assert sum(took.values()) == pytest.approx(wall, rel=0.02)
+    assert all(v >= 0 for v in took.values())
+    if traffic == "idle":
+        assert took["select"] > 0.98 * wall
+        assert took["fan_out"] == took["token"] == 0.0
+    else:
+        assert took["fan_out"] > 0 and took["token"] > 0 and took["rest"] > 0
+        assert took["token"] + took["fan_out"] + took["rest"] < wall
+
+
+def test_a_scrape_never_counts_a_select_twice():
+    """`select_seconds` adds the block in progress only if it is the same
+    block before and after it read the total: a block that ends in between
+    is in the total already."""
+    from dnn_tpu.obs.timeline import RpcLoopClock
+
+    now = [10.0]
+    clock = RpcLoopClock(now=lambda: now[0])
+    clock.select_begins()
+    now[0] = 12.5
+    assert clock.select_seconds() == 2.5  # in progress
+    clock.select_returns()
+    assert clock.select_seconds() == clock.seconds["select"] == 2.5
+    t = now[0] = 13.0
+    now[0] = 13.25
+    clock.section_ends("token", t)
+    now[0] = 14.0
+    clock.select_begins()  # the run: 1.5 s, 0.25 of it a token's
+    assert clock.seconds["rest"] == 1.25 and clock.seconds["token"] == 0.25
+    assert clock.iterations == 1
+    now[0] = 15.0
+    assert sum(clock.seconds.values()) - clock.seconds["select"] \
+        + clock.select_seconds() == 5.0
+
+
+def test_a_scrape_races_the_loop_threads_stamps_and_never_steps_back():
+    """A thread stamps `select()`s back to back under a switch interval of
+    10 us while this one scrapes: `select_seconds` never decreases, never
+    passes the wall time, and ends at the thread's own total."""
+    import sys
+
+    from dnn_tpu.obs.timeline import RpcLoopClock
+
+    clock = RpcLoopClock()
+    stop = threading.Event()
+
+    def loop_thread():
+        while not stop.is_set():
+            clock.select_begins()
+            clock.select_returns()
+
+    was = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    t = threading.Thread(target=loop_thread)
+    try:
+        t0 = time.perf_counter()
+        t.start()
+        last, reads = 0.0, 0
+        while time.perf_counter() - t0 < 0.3:
+            seen = clock.select_seconds()
+            assert last <= seen <= time.perf_counter() - t0
+            last, reads = seen, reads + 1
+    finally:
+        stop.set()
+        t.join(timeout=10)
+        sys.setswitchinterval(was)
+    assert not t.is_alive() and reads > 100 and clock.iterations > 100
+    assert clock.select_seconds() == clock.seconds["select"] >= last
+    assert sum(clock.seconds.values()) <= time.perf_counter() - t0
+
+
+def test_rpc_sections_are_counted_once_and_spans_only_in_a_capture(
+        wire, monkeypatch):
+    """Every token the worker hands off is one `token` section on the loop
+    thread and every hand-off one `fan_out` section with one lag; with no
+    capture recording no `rpc.*` annotation object is built, and while one
+    records there is one `rpc.fan_out` a hand-off (`tokens=` summing to
+    the tokens, `handoff=` the counter's values), one `rpc.tokens` marker
+    a run of the loop that built messages (`tokens=` summing to the
+    tokens again: no annotation a token) and `rpc.run`s around them, all
+    from the loop's thread."""
+    from dnn_tpu.obs import profile
+
+    srv, stream_all = wire
+    built = []
+
+    class Spy(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **stats):
+            built.append((name, stats, threading.get_ident()))
+            super().__init__(name, **stats)
+
+    monkeypatch.setattr(profile, "_trace_annotation", Spy)
+    counts = srv._emit_counts
+    stream_all(5)
+    assert not profile.capturing()
+    assert [b for b in built if b[0].startswith("rpc")] == []
+    assert srv._rpc.fan_out_lag[1] == counts[0]  # a lag a hand-off
+    assert srv._rpc.tokens == counts[1]  # a `token` section a token
+    handoffs, tokens = counts
+    with profile.mark_recording():
+        stream_all(6)
+    rpc = [b for b in built if b[0].startswith("rpc")]
+    assert {b[0] for b in rpc} == {"rpc.run", "rpc.fan_out", "rpc.tokens"}
+    assert len({b[2] for b in rpc}) == 1  # one thread: the loop's
+    assert rpc[0][2] != srv.worker.ident
+    n_new = 6 * len(PROMPTS)
+    assert counts[1] - tokens == n_new
+    built_in_runs = [b[1]["tokens"] for b in rpc if b[0] == "rpc.tokens"]
+    assert sum(built_in_runs) == n_new == srv._rpc.tokens - tokens
+    assert 0 < len(built_in_runs) <= n_new and min(built_in_runs) > 0
+    fans = [b[1] for b in rpc if b[0] == "rpc.fan_out"]
+    assert len(fans) == counts[0] - handoffs
+    assert sum(f["tokens"] for f in fans) == n_new
+    assert [f["handoff"] for f in fans] == list(
+        range(handoffs + 1, counts[0] + 1))
+    runs = [b[1]["iter"] for b in rpc if b[0] == "rpc.run"]
+    assert runs == sorted(set(runs)) and len(runs) >= len(fans)
+    assert srv._rpc.fan_out_lag[1] == counts[0]
+    assert 0 < srv._rpc.fan_out_lag[0] / counts[0] < 1.0
+    n_built = len(built)
+    stream_all(3)  # the capture has ended: at most the open run closes
+    assert len([b for b in built[n_built:] if b[0].startswith("rpc")]) == 0
