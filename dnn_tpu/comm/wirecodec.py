@@ -35,6 +35,8 @@ from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
 
+from dnn_tpu import native as _native
+
 BytesLike = Union[bytes, memoryview]
 
 # wire types
@@ -348,3 +350,19 @@ def make_tensor(arr, *, crc: bool = True) -> Tensor:
             checksum = crc32c(view)
     return Tensor(tensor_data=view, shape=shape, dtype=dtype,
                   crc32c=checksum)
+
+
+def make_token_tensor(tok: int) -> Tensor:
+    """ONE int32 token -> the Tensor `make_tensor(np.asarray([tok],
+    np.int32))` gives, byte for byte on the wire, from the integer: its
+    four little-endian bytes, shape (1,), dtype "int32", and crc32c
+    declared exactly when `make_tensor` would declare it (the native
+    codec is built) with the same value, through the table in Python. A
+    streamed token's message is built once a token on the serving
+    daemon's event-loop thread (lm_server.GenerateStream): no array, no
+    import, no metrics lookup and no call that releases the interpreter
+    lock on that path."""
+    data = tok.to_bytes(4, "little", signed=True)
+    return Tensor(data, (1,), "int32",
+                  _native.crc32c_table(data)
+                  if _native.native_available() else None)

@@ -182,6 +182,19 @@ def _as_buffer(data) -> memoryview:
     return view.cast("B") if view.ndim else view.cast("B", (1,))
 
 
+def crc32c_table(data, seed: int = 0) -> int:
+    """CRC32C of `data` (bytes, or any iterable of byte values) by the
+    table alone, in Python: what `crc32c` gives without the compiled
+    codec and, bit for bit, with it. For a payload of a few bytes it is
+    cheaper than the native call's pointer set-up, and it never releases
+    the interpreter lock (wirecodec.make_token_tensor)."""
+    table = _py_table()
+    crc = (~seed) & 0xFFFFFFFF
+    for b in data:
+        crc = table[(crc ^ b) & 0xFF] ^ (crc >> 8)
+    return (~crc) & 0xFFFFFFFF
+
+
 def crc32c(data, seed: int = 0) -> int:
     """CRC32C (Castagnoli) checksum. Native slice-by-8 when the compiled
     codec is available; table-driven Python otherwise (bit-identical)."""
@@ -192,11 +205,7 @@ def crc32c(data, seed: int = 0) -> int:
         # c_void_p itself; frombuffer is a zero-copy view)
         ptr = np.frombuffer(buf, np.uint8).ctypes.data if len(buf) else 0
         return int(lib.dnn_crc32c(ptr, len(buf), ctypes.c_uint32(seed)))
-    table = _py_table()
-    crc = (~seed) & 0xFFFFFFFF
-    for b in buf:
-        crc = table[(crc ^ b) & 0xFF] ^ (crc >> 8)
-    return (~crc) & 0xFFFFFFFF
+    return crc32c_table(buf, seed)
 
 
 # ----------------------------------------------------------------------

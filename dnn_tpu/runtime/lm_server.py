@@ -160,7 +160,9 @@ class TokenSink:
     sinks' tokens of one loop iteration cross to `loop` in ONE
     `call_soon_threadsafe` (`_BatcherWorker._hand_off`), and `_fan_out`,
     on the loop's thread, gives each `put` its `("tok", (token,
-    t_commit))`."""
+    t_commit))`. The consumer waits on that queue alone: whatever else
+    must wake it (`GenerateStream`'s `"done"`, its deadline's one timer)
+    arrives as an item through the same `put`."""
 
     __slots__ = ("loop", "put")
 
@@ -2290,6 +2292,16 @@ class LMServer:
         queue) with the worker: the tokens a step commits, of every
         stream, cross to this thread in one `call_soon_threadsafe` and
         are put on their queues here (`_fan_out`).
+        A stream waits on its queue with a bare `get()`, and a token's
+        message is made from the integer (`wc.make_token_tensor`): no
+        timer and no array a token. The deadline is fixed when the
+        request arrives and armed ONCE (`loop.call_at`, cancelled when
+        the handler leaves, however it leaves): the timer puts a
+        `"deadline"` item on the queue, which wakes a stream that waits.
+        Its place in the queue decides nothing: the clock is read before
+        every `get()`, so a stream whose deadline has passed aborts
+        before it yields another token, whatever still waits in front of
+        the timer's item.
         Client cancellation (disconnect / stream.cancel) sets the request's
         cancel event, and the batcher worker retires the slot at the next
         step boundary — a dropped stream never decodes on to its budget.
@@ -2299,7 +2311,7 @@ class LMServer:
         root = self._request_span(request.request_id,
                                   method="GenerateStream")
         n = 0
-        cancel_evt = None
+        cancel_evt = timer = None
         try:
             max_new, seed, opts = await self._preflight(
                 request.request_id, context)
@@ -2333,24 +2345,14 @@ class LMServer:
             timeout_s = self.request_timeout if inbound_dl is None \
                 else max(min(self.request_timeout, inbound_dl), 0.001)
             deadline = loop.time() + timeout_s
+            # the request's ONE timer: it wakes a stream that waits
+            timer = loop.call_at(deadline, q.put_nowait, ("deadline", None))
             while True:
-                remaining = deadline - loop.time()
-                if remaining <= 0:
-                    cancel_evt.set()
-                    m = obs.metrics()
-                    if m is not None:
-                        m.inc("serving.deadline_exceeded_total")
-                    obs.flight.record(
-                        "deadline_miss", method="GenerateStream",
-                        timeout_s=timeout_s, tokens=n,
-                        trace_id=root.trace_id if root else None)
-                    await context.abort(
-                        grpc.StatusCode.DEADLINE_EXCEEDED,
-                        f"generation exceeded {timeout_s}s")
-                try:
-                    kind, val = await asyncio.wait_for(q.get(), remaining)
-                except asyncio.TimeoutError:
-                    continue  # loop re-checks the deadline and aborts
+                if loop.time() < deadline:
+                    kind, val = await q.get()
+                else:
+                    kind = "deadline"  # passed while this stream was at
+                    # work or its items waited their turn on the loop
                 if kind == "tok":
                     n += 1
                     tok, t_commit = val
@@ -2364,12 +2366,22 @@ class LMServer:
                         lag[2] = waited
                     msg = wc.TensorResponse(
                         status=f"[lm] token {n}",
-                        result_tensor=_tensor_msg(
-                            np.asarray([tok], np.int32)),
-                    )
+                        result_tensor=wc.make_token_tensor(tok))
                     rpc.token_ends(t_tok)
                     yield msg
                     continue
+                if kind == "deadline":
+                    cancel_evt.set()
+                    m = obs.metrics()
+                    if m is not None:
+                        m.inc("serving.deadline_exceeded_total")
+                    obs.flight.record(
+                        "deadline_miss", method="GenerateStream",
+                        timeout_s=timeout_s, tokens=n,
+                        trace_id=root.trace_id if root else None)
+                    await context.abort(
+                        grpc.StatusCode.DEADLINE_EXCEEDED,
+                        f"generation exceeded {timeout_s}s")
                 await self._result_or_abort(val, context)
                 return
         except asyncio.CancelledError:
@@ -2379,6 +2391,8 @@ class LMServer:
                 cancel_evt.set()
             raise
         finally:
+            if timer is not None:
+                timer.cancel()
             root.end(tokens=n)
 
     async def HealthCheck(self, request: pb.Empty, context) -> pb.HealthCheckResponse:
