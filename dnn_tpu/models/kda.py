@@ -70,7 +70,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dnn_tpu.models import llama
+from dnn_tpu.models import llama, state_kind
 from dnn_tpu.ops.attention import merge_heads, split_heads
 from dnn_tpu.ops.nn import linear, rms_norm, silu
 
@@ -325,12 +325,8 @@ def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype,
     t = h.shape[1]
     with jax.named_scope("kda.project"):
         pre, g, beta, gate = _project(p, h, m=m, compute_dtype=compute_dtype)
-        rows = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
-        taps = _taps(p)
-        conved = sum(taps[j] * rows[:, j:j + t].astype(jnp.float32)
-                     for j in range(m.conv))
-        # the last conv - 1 real rows: rows [n_real, n_real + conv - 1)
-        new_tail = lax.dynamic_slice_in_dim(rows, n_real, m.conv - 1, axis=1)
+        conved, new_tail = state_kind.conv_chunk(
+            tail, pre, lambda: _taps(p), n_real)
         q, k, v = _qkv_heads(conved, m)
         real = jnp.arange(t) < n_real
         g = jnp.where(real[None, :, None, None], g, 0.0)
@@ -352,8 +348,7 @@ def mixer_step(p, h, state, tail, *, cfg, compute_dtype):
     m = cfg.kda
     with jax.named_scope("kda.project"):
         pre, g, beta, gate = _project(p, h, m=m, compute_dtype=compute_dtype)
-        rows = jnp.concatenate([tail.astype(pre.dtype), pre], axis=1)
-        conved = (_taps(p) * rows.astype(jnp.float32)).sum(1, keepdims=True)
+        conved, rows = state_kind.conv_step(tail, pre, lambda: _taps(p))
         q, k, v = _qkv_heads(conved, m)
     with jax.named_scope("kda.step"):
         o, state = step_rule(q[:, :, 0], k[:, :, 0], v[:, :, 0], g[:, 0],
@@ -368,9 +363,7 @@ def fresh_state(cfg, batch, tail_dtype, layers=None):
     """Zeros of the linear kind's two leaves for `batch` slots — the state
     float32 whatever the cache's dtype — with a leading layer axis where
     `layers` is given."""
-    lead = (batch,) if layers is None else (layers, batch)
-    return {name: jnp.zeros((*lead, *shape), dtype or tail_dtype)
-            for name, (shape, dtype) in slot_leaves(cfg.kda).items()}
+    return state_kind.fresh(slot_leaves(cfg.kda), batch, tail_dtype, layers)
 
 
 def dense_mixer(p, h, *, cfg, compute_dtype):

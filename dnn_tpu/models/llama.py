@@ -68,6 +68,59 @@ class RetentionConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Mamba2Config:
+    """What `LlamaConfig.mamba` holds: every layer runs a Mamba-2 STATE-SPACE
+    mixer (models/mamba2.py) beside its softmax attention, on the same
+    normed input, and the two outputs are scaled and added before ONE
+    residual. `d_ssm` = `n_head` heads of `head_dim`; B and C are shared by
+    the heads of a group (`n_groups`) and `d_state` wide; `conv` taps of the
+    short causal convolution over [x | B | C]; `chunk` the positions a
+    closed-form chunk of the chunked rule. The muP multipliers by their
+    published names: `ssm_in` scales the mixer's input, `ssm_out` its
+    output, `ssm_multipliers` the in-projection's slices [z | x | B | C |
+    dt]."""
+    d_ssm: int = 4096
+    n_head: int = 32
+    d_state: int = 256
+    n_groups: int = 2
+    conv: int = 4
+    chunk: int = 128
+    ssm_in: float = 1.0
+    ssm_out: float = 1.0
+    ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+
+    @property
+    def head_dim(self):
+        return self.d_ssm // self.n_head
+
+    @property
+    def conv_width(self):
+        """Channels of [x | B | C]: what the convolution runs over."""
+        return self.d_ssm + 2 * self.n_groups * self.d_state
+
+    @property
+    def proj_width(self):
+        """The in-projection's outputs [z | x | B | C | dt]."""
+        return self.d_ssm + self.conv_width + self.n_head
+
+
+@dataclasses.dataclass(frozen=True)
+class MupConfig:
+    """What `LlamaConfig.mup` holds: the muP multipliers of a block that
+    are not the state-space mixer's own (`Mamba2Config`), by their
+    published names — a scale on the embedding, on the logits, on
+    attention's input, output and keys, on the MLP's gate product and on
+    its output. A multiplier left out is a different model that still
+    runs."""
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    attention_in: float = 1.0
+    attention_out: float = 1.0
+    key: float = 1.0
+    mlp: tuple = (1.0, 1.0)
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     block_size: int = 2048
     vocab_size: int = 32000
@@ -186,8 +239,26 @@ class LlamaConfig:
     # default off): every layer keeps a STATE a slot and no position's
     # anything
     retention: Optional[RetentionConfig] = None
+    # ---- a Mamba-2 state-space mixer BESIDE softmax attention in every
+    # layer (models/mamba2.py; default off): a slot keeps a state and a
+    # convolution tail a layer AND every position's K and V
+    mamba: Optional[Mamba2Config] = None
+    # muP multipliers (Falcon-H1; None = all 1, nothing traced)
+    mup: Optional[MupConfig] = None
 
     def __post_init__(self):
+        if self.mamba is not None and (
+                self.retention is not None or self.sliding_window is not None
+                or self.alt_window or self.attn_softcap is not None
+                or self.parallel_block or self.index_topk is not None
+                or self.post_norms or not self.pre_norm
+                or self.mamba.d_ssm % self.mamba.n_head
+                or self.mamba.n_head % self.mamba.n_groups):
+            raise ValueError(
+                "a state-space mixer beside attention is built for the "
+                "sequential pre-norm block with dense causal attention: no "
+                "window, softcap, indexer, retention or post-norm goes with "
+                "it; its heads divide d_ssm and its groups its heads")
         if self.retention is not None and (
                 self.sliding_window is not None or self.alt_window
                 or self.attn_softcap is not None or self.parallel_block
@@ -386,6 +457,45 @@ PRESETS = {
 # head placed on it
 PRESETS["brumby-14b-pp8-1chip"] = dataclasses.replace(
     PRESETS["brumby-14b"], n_layer=5)
+# Falcon-H1-34B-Instruct (tiiuae/Falcon-H1-34B-Instruct config.json,
+# `model_type` falcon_h1): 72 layers of ONE kind — a Mamba-2 mixer (32 heads
+# of 128, state 256, 2 groups, 4 taps with bias, gated grouped RMSNorm) and
+# GQA 5:1 softmax attention (heads of 128, theta 1e11) read the same normed
+# input, their scaled outputs are added before one residual; SwiGLU 21 504,
+# untied head, the muP multipliers. The equations are `assumed` in
+# chipbench/configs/falcon-h1-34b-pp8-1chip.json. Never instantiated whole.
+PRESETS["falcon-h1-34b"] = LlamaConfig(
+    block_size=262144, vocab_size=261120, n_layer=72, n_head=20, n_kv_head=4,
+    n_embd=5120, d_ff=21504, head_dim_override=128, rope_theta=1e11,
+    rms_eps=1e-5,
+    mamba=Mamba2Config(
+        d_ssm=4096, n_head=32, d_state=256, n_groups=2, conv=4, chunk=128,
+        ssm_in=0.25, ssm_out=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738)),
+    mup=MupConfig(embedding=5.656854249492381, lm_head=0.0078125,
+                  attention_in=1.0, attention_out=0.0375,
+                  key=0.011048543456039804,
+                  mlp=(0.1767766952966369, 0.011160714285714284)))
+# the benchmark's cut (chipbench/configs/falcon-h1-34b-pp8-1chip.json): one
+# of eight pipeline stages of nine whole layers, layers 0-8, and this chip's
+# eighth of the embedding's and the head's rows, rows 0-32 639
+PRESETS["falcon-h1-34b-pp8-1chip"] = dataclasses.replace(
+    PRESETS["falcon-h1-34b"], n_layer=9, vocab_size=32640)
+# tiny Falcon-H1 for the CPU tests: 2 groups of 2 state-space heads of 16
+# (state 16), GQA 2:1 with heads of 16, a chunk of 8 that a 16-token prefill
+# chunk holds twice, EVERY multiplier different from 1 and from each other;
+# theta 1e4, not 1e11, at which 96 positions turn one pair of 8 and a
+# rotation left out could not be seen
+PRESETS["falcon-h1-test"] = LlamaConfig(
+    block_size=128, vocab_size=256, n_layer=3, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=128, head_dim_override=16, rope_theta=1e4, rms_eps=1e-5,
+    mamba=Mamba2Config(
+        d_ssm=64, n_head=4, d_state=16, n_groups=2, conv=4, chunk=8,
+        ssm_in=0.6, ssm_out=0.45,
+        ssm_multipliers=(0.8, 1.3, 0.7, 1.6, 0.55)),
+    mup=MupConfig(embedding=2.5, lm_head=0.35, attention_in=1.2,
+                  attention_out=0.65, key=0.4, mlp=(1.7, 0.3)))
 
 
 def layer_windows(cfg: LlamaConfig):
@@ -432,6 +542,10 @@ def kv_kinds(cfg):
         # models/kda.py: the K/V layers are the "full" kind alone; the
         # "linear" layers keep a state, no position's anything
         return {"full": cfg.kv_full or KvKind()}
+    if getattr(cfg, "mamba", None) is not None:
+        # models/mamba2.py: ONE kind, whose every layer keeps K and V
+        # under tables AND a state a slot
+        return {"full": KvKind()}
     if getattr(cfg, "kv_window", None) is None:
         return None
     return {"full": cfg.kv_full or KvKind(), "window": cfg.kv_window}
@@ -536,6 +650,11 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
 
         blk["attn"]["decay"] = retention.init_gate(
             jax.random.fold_in(key, 29), cfg)
+    if cfg.mamba is not None:
+        from dnn_tpu.models import mamba2
+
+        blk["ssm"] = mamba2.init_mixer(jax.random.fold_in(key, 31), cfg,
+                                       dtype)
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -553,7 +672,28 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
     if cfg.post_norms:
         blk["post_ln_1"] = _norm_p((c,))
         blk["post_ln_2"] = _norm_p((c,))
+    if cfg.mup is not None:
+        # muP pairs each multiplier with an initial scale: every kernel
+        # whose product a multiplier scales is drawn with it divided out,
+        # so that at initialisation each product has the scale the
+        # family's other presets give it and a multiplier left out is a
+        # visible error, not one that random weights absorb
+        for name, by in (("q", cfg.mup.attention_in),
+                         ("k", cfg.mup.attention_in * cfg.mup.key),
+                         ("v", cfg.mup.attention_in),
+                         ("o", cfg.mup.attention_out)):
+            blk["attn"][name]["kernel"] = _unscaled(
+                blk["attn"][name]["kernel"], by)
+        if include_mlp:
+            for name, by in zip(("gate", "down"), cfg.mup.mlp):
+                blk["mlp"][name]["kernel"] = _unscaled(
+                    blk["mlp"][name]["kernel"], by)
     return blk
+
+
+def _unscaled(kernel, by: float):
+    """`kernel` with the multiplier `by` divided out, in its own dtype."""
+    return (kernel / by).astype(kernel.dtype) if by != 1.0 else kernel
 
 
 def init_parts(rng, cfg: LlamaConfig = PRESETS["llama-test"],
@@ -575,7 +715,8 @@ def init_parts(rng, cfg: LlamaConfig = PRESETS["llama-test"],
         return p
 
     def lm_head():
-        p = _kernel(keys[1], (c, cfg.vocab_size), dtype)
+        p = _kernel(keys[1], (c, cfg.vocab_size), dtype,
+                    std=0.02 / (cfg.mup.lm_head if cfg.mup else 1.0))
         if cfg.dense_bias:  # Phi: lm_head carries a bias too
             p["bias"] = jnp.zeros((cfg.vocab_size,), dtype)
         return p
@@ -583,7 +724,8 @@ def init_parts(rng, cfg: LlamaConfig = PRESETS["llama-test"],
     parts = {
         "wte": lambda: {"embedding": (
             jax.random.normal(keys[0], (cfg.vocab_size, c))
-            * 0.02).astype(dtype)},
+            * (0.02 / (cfg.mup.embedding if cfg.mup else 1.0))
+        ).astype(dtype)},
         "ln_f": ln_f,
     }
     if not cfg.tie_word_embeddings:
@@ -710,15 +852,28 @@ def _qk_normed(bp, q, k, cfg: LlamaConfig):
             rms_norm(bp["attn"]["k_norm"], k, eps=cfg.rms_eps))
 
 
+def _mup_scaled(x, cfg: LlamaConfig, name: str, at=None):
+    """x times the muP multiplier `name` (its entry `at` where it is a
+    pair) of `cfg.mup` (`MupConfig`); x itself — nothing traced — where the
+    config has none or it is 1."""
+    if cfg.mup is None:
+        return x
+    m = getattr(cfg.mup, name)
+    m = m if at is None else m[at]
+    return x if m == 1.0 else x * jnp.asarray(m, x.dtype)
+
+
 def _qkv_rope(bp, h, positions, *, cfg: LlamaConfig, compute_dtype,
               rope=True):
     """Project h (B, T, C) and rotate q/k at absolute `positions` (T,).
     Returns q (B, H, T, D), k/v (B, KV, T, D) — KV heads stay narrow.
     `rope` off (a layer kind's, `KvKind`): q and k as they are normed."""
+    h = _mup_scaled(h, cfg, "attention_in")
     q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
                     cfg.n_head)
-    k = split_heads(linear(bp["attn"]["k"], h, compute_dtype=compute_dtype),
-                    cfg.n_kv_head)
+    k = split_heads(_mup_scaled(
+        linear(bp["attn"]["k"], h, compute_dtype=compute_dtype), cfg, "key"),
+        cfg.n_kv_head)
     v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
                     cfg.n_kv_head)
     q, k = _qk_normed(bp, q, k, cfg)
@@ -741,11 +896,15 @@ def _mlp_out(bp, h, *, cfg: LlamaConfig, compute_dtype, ffn=None):
                       act(linear(bp["mlp"]["up"], h,
                                  compute_dtype=compute_dtype)),
                       compute_dtype=compute_dtype)
-    return linear(bp["mlp"]["down"],
-                  act(linear(bp["mlp"]["gate"], h,
-                             compute_dtype=compute_dtype))
-                  * linear(bp["mlp"]["up"], h, compute_dtype=compute_dtype),
-                  compute_dtype=compute_dtype)
+    # muP (`MupConfig.mlp`): the gate product scaled before its activation,
+    # the MLP's output after the down-projection
+    g = _mup_scaled(linear(bp["mlp"]["gate"], h, compute_dtype=compute_dtype),
+                    cfg, "mlp", 0)
+    return _mup_scaled(
+        linear(bp["mlp"]["down"],
+               act(g) * linear(bp["mlp"]["up"], h,
+                               compute_dtype=compute_dtype),
+               compute_dtype=compute_dtype), cfg, "mlp", 1)
 
 
 def _mlp_residual(bp, x, *, cfg: LlamaConfig, compute_dtype, ffn=None):
@@ -876,6 +1035,13 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
 
         fn = lambda bp2, h: retention.dense_mixer(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype)
+    if attn_fn is None and cfg.mamba is not None:
+        from dnn_tpu.models import mamba2
+
+        attend = fn  # dense causal attention, the config's one K/V kind
+        fn = lambda bp2, h: mamba2.mixers_sum(  # noqa: E731
+            attend(bp2, h), mamba2.dense_mixer(
+                bp2["ssm"], h, cfg=cfg, compute_dtype=compute_dtype), cfg)
     if attn_fn is None and getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models import mla
 
@@ -901,7 +1067,7 @@ def _scaled_embed(p, ids, cfg: LlamaConfig):
         e = embedding(p["wte"], ids)
         if cfg.embed_scale:
             e = e * jnp.asarray(cfg.n_embd ** 0.5, e.dtype)
-        return e
+        return _mup_scaled(e, cfg, "embedding")
 
 
 def embed(params, idx, *, cfg: LlamaConfig):
@@ -927,6 +1093,7 @@ def head(params, x, *, cfg: LlamaConfig, compute_dtype=None, logits_dtype=None):
         else:
             out = linear(lm, x, compute_dtype=compute_dtype,
                          accum_dtype=jnp.float32)
+        out = _mup_scaled(out, cfg, "lm_head")
         if cfg.final_softcap is not None:  # Gemma-2 final_logit_softcapping
             out = cfg.final_softcap * jnp.tanh(out / cfg.final_softcap)
         return out if logits_dtype is None else out.astype(logits_dtype)
@@ -1585,7 +1752,8 @@ def family_rows(cfg, **kw):
     its attention keeps a position (`LlamaFamilyRows`' K and V — by layer
     kind `LlamaKindRows`'; with an indexer models/dsa.py's third leaf;
     with latent attention models/mla.py's one; NOTHING a position with
-    models/retention.py's state a slot). `kw`: `LlamaFamilyRows`' own."""
+    models/retention.py's state a slot; K and V AND a state a slot in one
+    layer with models/mamba2.py). `kw`: `LlamaFamilyRows`' own."""
     if cfg.index_topk is not None:
         from dnn_tpu.models.dsa import DsaFamilyRows as rows
     elif getattr(cfg, "mla", None) is not None:
@@ -1594,6 +1762,8 @@ def family_rows(cfg, **kw):
         from dnn_tpu.models.kda import KdaKindRows as rows
     elif cfg.retention is not None:
         from dnn_tpu.models.retention import RetentionRows as rows
+    elif cfg.mamba is not None:
+        from dnn_tpu.models.mamba2 import HybridRows as rows
     elif kv_kinds(cfg) is not None:
         rows = LlamaKindRows
     else:
@@ -1690,10 +1860,12 @@ class LlamaFamilyRows:
         1, D); `rope` off (a layer kind's): unrotated."""
         cfg, compute_dtype = self.cfg, self.compute_dtype
         kv = cfg.n_kv_head
+        h = _mup_scaled(h, cfg, "attention_in")
         q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
                         cfg.n_head)
-        k = split_heads(linear(bp["attn"]["k"], h, compute_dtype=compute_dtype),
-                        kv)
+        k = split_heads(_mup_scaled(
+            linear(bp["attn"]["k"], h, compute_dtype=compute_dtype), cfg,
+            "key"), kv)
         v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
                         kv)
         q, k = _qk_normed(bp, q, k, cfg)
@@ -1918,10 +2090,12 @@ class LlamaKindRows(LlamaFamilyRows):
         self.kinds = kv_kinds(cfg)
         d = cfg.head_dim
         self.cache_kinds = {}
+        # a config without `layer_types` has ONE kind: every layer's
+        types = getattr(cfg, "layer_types", None) or ("full",) * cfg.n_layer
         for kind, kk in self.kinds.items():
             k_name, v_name, tables = KV_KIND_LEAVES[kind]
             self.cache_kinds[kind] = {
-                "layers": sum(t == kind for t in cfg.layer_types),
+                "layers": sum(t == kind for t in types),
                 "leaves": {k_name: (cfg.n_kv_head, d),
                            v_name: (cfg.n_kv_head, d)},
                 "tables": tables, "window": kk.window}
@@ -1938,52 +2112,60 @@ class LlamaKindRows(LlamaFamilyRows):
                 for k in self.cache_kinds.values()
                 for name, (heads, width) in k["leaves"].items()}
 
-    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind):
-        """One block over a prefill chunk x (1, T, C) at [start_pos,
-        start_pos + T): the chunk's K and V written into the layer's
-        transient rows `rows` {leaf: (1, KV, S, D)}, then attended with
-        the group folded into the kernel's rows (row g * T + t reads
-        columns <= start_pos + t, within the kind's band)."""
+    def _chunk_attn(self, bp, h, rows, start_pos, kind):
+        """Attention of one block over a prefill chunk's normed rows h (1,
+        T, C) at [start_pos, start_pos + T): the chunk's K and V written
+        into the layer's transient rows `rows` {leaf: (1, KV, S, D)}, then
+        attended with the group folded into the kernel's rows (row g * T +
+        t reads columns <= start_pos + t, within the kind's band) -> (the
+        o-projected output (1, T, C), rows)."""
         from dnn_tpu.ops.pallas.cached_attention import cached_attention
 
         cfg, compute_dtype = self.cfg, self.compute_dtype
         kk = self.kinds[kind]
         k_name, v_name, _ = KV_KIND_LEAVES[kind]
-        t = x.shape[1]
+        t = h.shape[1]
         kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
+        q, k, v = _qkv_rope(bp, h, start_pos + jnp.arange(t), cfg=cfg,
+                            compute_dtype=compute_dtype, rope=kk.rope)
+        with jax.named_scope("kv_pool.write"):
+            rows = {**rows, **{n: lax.dynamic_update_slice_in_dim(
+                rows[n], new.astype(rows[n].dtype), start_pos, axis=2)
+                for n, new in ((k_name, k), (v_name, v))}}
+        interpret = True if self.attn_kernel == "interpret" else None
+        # the largest tile up to 512 that divides the chunk and the
+        # row: a 128 x 128 tile costs 3.4-4.7x a (512, 512) one on a
+        # v5e for the same pairs (PERF.md section 6, PR 43)
+        tile = next(n for n in (512, 256, 128, t)
+                    if t % n == 0 and rows[k_name].shape[2] % n == 0)
+        attend = functools.partial(
+            cached_attention, q.reshape(1, kv, g * t, d), rows[k_name],
+            rows[v_name], jnp.reshape(start_pos, (1,)).astype(jnp.int32),
+            rows_mod=t, block_q=tile, block_s=tile, interpret=interpret)
+        if kk.window is None:
+            y = attend()
+        else:
+            with jax.named_scope("attn.window_prefill"):
+                y = attend(window=kk.window)
+        self.attn_forms[kind]["prefill"] = (
+            "banded_kernel" if kk.window else "kernel") if (
+                interpret or jax.default_backend() == "tpu") else "plain"
+        y = y.reshape(1, cfg.n_head, t, d)
+        return linear(bp["attn"]["o"],
+                      _gated(bp, h, merge_heads(y.astype(h.dtype)),
+                             compute_dtype), compute_dtype=compute_dtype), rows
+
+    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind):
+        """One block over a prefill chunk x (1, T, C) at [start_pos,
+        start_pos + T): `_chunk_attn`, then the residuals and the MLP."""
+        cfg = self.cfg
         with jax.named_scope("llama.block.cached_attn"):
             h = _pre_normed(bp, x, cfg)
-            q, k, v = _qkv_rope(bp, h, start_pos + jnp.arange(t), cfg=cfg,
-                                compute_dtype=compute_dtype, rope=kk.rope)
-            with jax.named_scope("kv_pool.write"):
-                rows = {n: lax.dynamic_update_slice_in_dim(
-                    rows[n], new.astype(rows[n].dtype), start_pos, axis=2)
-                    for n, new in ((k_name, k), (v_name, v))}
-            interpret = True if self.attn_kernel == "interpret" else None
-            # the largest tile up to 512 that divides the chunk and the
-            # row: a 128 x 128 tile costs 3.4-4.7x a (512, 512) one on a
-            # v5e for the same pairs (PERF.md section 6, PR 43)
-            tile = next(n for n in (512, 256, 128, t)
-                        if t % n == 0 and rows[k_name].shape[2] % n == 0)
-            attend = functools.partial(
-                cached_attention, q.reshape(1, kv, g * t, d), rows[k_name],
-                rows[v_name], jnp.reshape(start_pos, (1,)).astype(jnp.int32),
-                rows_mod=t, block_q=tile, block_s=tile, interpret=interpret)
-            if kk.window is None:
-                y = attend()
-            else:
-                with jax.named_scope("attn.window_prefill"):
-                    y = attend(window=kk.window)
-            self.attn_forms[kind]["prefill"] = (
-                "banded_kernel" if kk.window else "kernel") if (
-                    interpret or jax.default_backend() == "tpu") else "plain"
-            y = y.reshape(1, cfg.n_head, t, d)
-            o = linear(bp["attn"]["o"],
-                       _gated(bp, h, merge_heads(y.astype(x.dtype)),
-                              compute_dtype), compute_dtype=compute_dtype)
+            o, rows = self._chunk_attn(bp, h, rows, start_pos, kind)
         with jax.named_scope("llama.block.mlp"):
             return (_branches_residual(bp, x, o, h, cfg=cfg,
-                                       compute_dtype=compute_dtype, ffn=ffn),
+                                       compute_dtype=self.compute_dtype,
+                                       ffn=ffn),
                     rows)
 
     def prefill(self, prepared, padded, row_cache, start_pos=0, *,

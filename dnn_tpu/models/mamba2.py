@@ -1,0 +1,368 @@
+"""A Mamba-2 STATE-SPACE mixer beside softmax attention in every layer
+(Falcon-H1, `model_type` falcon_h1), in the LLaMA block (`LlamaConfig.mamba`,
+a `llama.Mamba2Config`; the other muP multipliers in `LlamaConfig.mup`): both
+mixers read the SAME normed input h and their scaled outputs are ADDED before
+one residual,
+
+    x' = x + ssm_out SSM(h) + attention_out Attn(h);  x'' = x' + MLP(norm(x')).
+
+Attn is models/llama.py's (GQA, rotary embedding, K and V a position). SSM,
+with H heads of P in G groups, a state N wide, u = ssm_in h:
+
+    p = (u W_in) * mup            [z H P | x H P | B G N | C G N | dt H], `mup`
+                                  holding ssm_multipliers[0..4] over the slices
+    c = silu(b + conv4([x | B | C]))      causal, depthwise, 4 taps, a bias
+    dt = softplus(dt + dt_bias_h), A_h = -exp(A_log_h), a = exp(dt A_h)
+    S_t = a_t S_{t-1} + dt_t x_t B_t^T    (P x N a head; head h reads group
+    y_t = S_t C_t + D_h x_t                h // (H / G)'s B and C)
+    g = y * silu(z), RMS-normalised within each of the G groups of H P / G
+    channels (the gate FIRST), times a gain; SSM(h) = g W_out
+
+**What a slot keeps, a layer: S (H, P, N) float32, the last three rows of
+the un-convolved [x | B | C] — the TAIL — and the K and V of every
+position.** ONE cache kind, "full", has paged `leaves` {k, v} under `tables`
+AND `slot_leaves` {`ssm_state`, `conv_tail`} (`HybridRows.cache_kinds`;
+runtime/paged_kvcache.py's module docstring): a layer reaches its K/V blocks
+through the slot's table and its state at the slot's row, in the same layer
+body.
+
+The multipliers ride where they cost nothing: `ssm_in` and the slices'
+multipliers are ONE vector over the in-projection's outputs, whose [x | B |
+C] part scales the convolution's taps (the convolution is linear) — so the
+tail holds the rows as W_in gives them, in the compute dtype, and a row reads
+the same whether it reaches the convolution through the tail or inside a
+chunk.
+
+Three forms of the same numbers:
+
+  * **the recurrence** (`recurrence`): a `lax.scan` over positions — what
+    chipbench/reference/falcon_h1.py computes on its own, here for the tests.
+  * **the step** (decode, `step_rule`): one token a slot, the state read and
+    written ONCE.
+  * **the chunked rule** (prefill, `chunk_rule`; "SSD"): positions in chunks
+    of `Mamba2Config.chunk` FROM AN INCOMING STATE. With G_t the cumulative
+    sum of dt A inside the chunk: y_t = sum_{s <= t} exp(G_t - G_s) dt_s (C_t
+    . B_s) x_s + exp(G_t) S_0 C_t + D x_t, and the outgoing state exp(G_c)
+    S_0 + sum_s exp(G_c - G_s) dt_s x_s B_s^T. Every decay is the exp of a
+    DIFFERENCE of cumulative logs that is <= 0. What does not depend on the
+    state is made for all chunks at once; the scan over chunks is two
+    matmuls a chunk.
+  * a PAD position (at or past `n_real` in the chunk) has dt = 0: it is the
+    identity on S and does not enter the tail (`takes_n_real`, as models/
+    kda.py).
+
+dt, the decays, their cumulative logs, the state and the rule's sums are
+float32 at "highest" matmul precision whatever the cache's dtype (the rule
+is 5.4 MFLOP a token a layer beside 860 of weights); the projections run in
+the compute dtype.
+"""
+
+from __future__ import annotations
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from dnn_tpu.models import llama, state_kind
+from dnn_tpu.ops.nn import linear, silu
+
+_HI = lax.Precision.HIGHEST
+# the seeded initialisation (Mamba-2's published ranges): A = exp(A_log) and
+# the step dt = softplus(dt_bias) are spread log-evenly a head over these
+_A_RANGE = (1.0, 16.0)
+_DT_RANGE = (1e-3, 1e-1)
+
+
+def slot_leaves(m: llama.Mamba2Config):
+    """The kind's leaves with no position axis: name -> (the shape a slot a
+    layer, dtype or None for the cache's)."""
+    return {"ssm_state": ((m.n_head, m.head_dim, m.d_state), jnp.float32),
+            "conv_tail": ((m.conv - 1, m.conv_width), None)}
+
+
+def _slices(m):
+    """The in-projection's slices [z | x | B | C | dt]: their widths."""
+    gn = m.n_groups * m.d_state
+    return (m.d_ssm, m.d_ssm, gn, gn, m.n_head)
+
+
+def _mup_vector(m) -> np.ndarray:
+    """`ssm_in` times `ssm_multipliers` over the in-projection's outputs."""
+    return m.ssm_in * np.concatenate([
+        np.full((w,), s, np.float32)
+        for w, s in zip(_slices(m), m.ssm_multipliers)])
+
+
+def init_mixer(key, cfg, dtype=jnp.float32):
+    """A layer's `ssm` entry. W_in and W_out N(0, 0.02^2) (W_out over sqrt(2
+    x layers)) with their multipliers divided out (`llama.init_block`); the
+    taps N(0, 1 / conv) over the scale a row has at initialisation, so that
+    c_t is of order 1 and every tap matters; `A_log` and `dt_bias` spread a
+    head, log-evenly, over `_A_RANGE` and `_DT_RANGE`: a token's retention
+    exp(dt A) runs from 0.999 in head 0 to 0.2 in the last, before the
+    token's own part of dt; gains and D exactly 1."""
+    m, c = cfg.mamba, cfg.n_embd
+    ks = jax.random.split(key, 3)
+    w_in = jax.random.normal(ks[0], (c, m.proj_width)) * 0.02 / _mup_vector(m)
+    w_out = (jax.random.normal(ks[1], (m.d_ssm, c)) * 0.02
+             / (2 * cfg.n_layer) ** 0.5 / m.ssm_out)
+    row_sigma = 0.02 * math.sqrt(c)  # of a row of W_in's products, scaled
+    a = jnp.exp(jnp.linspace(*(math.log(x) for x in _A_RANGE), m.n_head))
+    dt = jnp.exp(jnp.linspace(*(math.log(x) for x in _DT_RANGE), m.n_head))
+    return {
+        "in": {"kernel": w_in.astype(dtype)},
+        "out": {"kernel": w_out.astype(dtype)},
+        "conv": {"taps": (jax.random.normal(ks[2], (m.conv, m.conv_width))
+                          / (math.sqrt(m.conv) * row_sigma)
+                          ).astype(jnp.float32),
+                 "bias": jnp.zeros((m.conv_width,), jnp.float32)},
+        "a_log": jnp.log(a).astype(jnp.float32),
+        "d": jnp.ones((m.n_head,), jnp.float32),
+        # softplus(dt_bias) == dt
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(jnp.float32),
+        "norm": {"scale": jnp.ones((m.d_ssm,), jnp.float32)},
+    }
+
+
+def _project(p, h, *, m, compute_dtype):
+    """h (B, T, C) -> (z (B, T, H P) float32, the un-convolved rows [x | B |
+    C] (B, T, W) as W_in gives them, dt (B, T, H) float32 > 0)."""
+    proj = linear(p["in"], h, compute_dtype=compute_dtype)
+    wz, wc = m.d_ssm, m.d_ssm + m.conv_width
+    mup = _mup_vector(m)
+    z = proj[..., :wz].astype(jnp.float32) * mup[:wz]
+    dt = jax.nn.softplus(proj[..., wc:].astype(jnp.float32) * mup[wc:]
+                         + p["dt_bias"])
+    return z, proj[..., wz:wc], dt
+
+
+def _taps(p, m):
+    """The convolution's taps with the slices' multipliers in them."""
+    return p["conv"]["taps"] * _mup_vector(m)[m.d_ssm:m.d_ssm + m.conv_width]
+
+
+def _heads(conved, p, m):
+    """The convolution's output (B, T, W) float32 -> x (B, T, H, P), B and C
+    (B, T, G, N) after the bias and SiLU."""
+    c = silu(conved + p["conv"]["bias"])
+    gn = m.n_groups * m.d_state
+    lead = c.shape[:-1]
+    return (c[..., :m.d_ssm].reshape(*lead, m.n_head, m.head_dim),
+            c[..., m.d_ssm:m.d_ssm + gn].reshape(*lead, m.n_groups, m.d_state),
+            c[..., m.d_ssm + gn:].reshape(*lead, m.n_groups, m.d_state))
+
+
+def _out(p, y, z, x_dtype, *, m, eps, compute_dtype):
+    """y (B, T, H, P) float32 -> the mixer's output (B, T, C): the gate
+    FIRST, the RMS norm within each group's channels, the gain, W_out."""
+    lead = y.shape[:-2]
+    g = (y.reshape(*lead, m.d_ssm) * silu(z)).reshape(
+        *lead, m.n_groups, m.d_ssm // m.n_groups)
+    g = g * lax.rsqrt((g * g).mean(-1, keepdims=True) + eps)
+    g = g.reshape(*lead, m.d_ssm) * p["norm"]["scale"]
+    return linear(p["out"], g.astype(x_dtype), compute_dtype=compute_dtype)
+
+
+def recurrence(x, dt, a_log, d, bm, cm, state):
+    """The rule as it stands, a scan over positions: x (B, T, H, P), dt (B,
+    T, H), bm, cm (B, T, G, N), `state` (B, H, P, N), float32 -> (y (B, T, H,
+    P), the state after position T)."""
+    h, g = x.shape[2], bm.shape[2]
+    a = -jnp.exp(a_log)
+
+    def one(s, xs):
+        x_t, dt_t, b_t, c_t = xs
+        b_t, c_t = (jnp.repeat(v, h // g, axis=1) for v in (b_t, c_t))
+        s = (jnp.exp(dt_t * a)[..., None, None] * s
+             + (dt_t[..., None] * x_t)[..., None] * b_t[:, :, None, :])
+        return s, (s * c_t[:, :, None, :]).sum(-1) + d[:, None] * x_t
+
+    state, y = lax.scan(one, state, tuple(
+        jnp.moveaxis(v, 1, 0) for v in (x, dt, bm, cm)))
+    return jnp.moveaxis(y, 0, 1), state
+
+
+def chunk_rule(x, dt, a_log, d, bm, cm, state, *, m, chunk):
+    """The rule over T positions in closed-form chunks of `chunk` (module
+    docstring), arguments as `recurrence`'s; T is a multiple of `chunk`."""
+    b, t, h, p = x.shape
+    n = t // chunk
+
+    def split(v):  # (B, T, ...) -> (B, n, chunk, ...)
+        return v.reshape(b, n, chunk, *v.shape[2:])
+
+    xg = split(x).reshape(b, n, chunk, m.n_groups, -1, p)   # b n s g r p
+    dtg = split(dt).reshape(b, n, chunk, m.n_groups, -1)    # b n s g r
+    bm, cm = split(bm), split(cm)                           # b n s g k
+    cum = jnp.cumsum(dtg * -jnp.exp(a_log).reshape(m.n_groups, -1), axis=2)
+    # inside a chunk: exp(G_t - G_s) dt_s (C_t . B_s), s <= t
+    tri = jnp.tril(jnp.ones((chunk, chunk), bool))
+    cumt = jnp.moveaxis(cum, 2, -1)                          # b n g r t
+    decay = jnp.exp(jnp.where(tri, cumt[..., :, None] - cumt[..., None, :],
+                              -jnp.inf))                     # b n g r t s
+    cb = jnp.einsum("bntgk,bnsgk->bngts", cm, bm, precision=_HI)
+    w = decay * cb[:, :, :, None] * jnp.moveaxis(dtg, 2, -1)[..., None, :]
+    y = jnp.einsum("bngrts,bnsgrp->bntgrp", w, xg, precision=_HI)
+    # what a chunk hands on: sum_s exp(G_c - G_s) dt_s x_s B_s^T
+    last = cum[:, :, -1:]                                    # b n 1 g r
+    xs = xg * (dtg * jnp.exp(last - cum))[..., None]
+    add = jnp.einsum("bnsgrp,bnsgk->bngrpk", xs, bm, precision=_HI)
+    keep = jnp.exp(last[:, :, 0])[..., None, None]           # b n g r 1 1
+    into = jnp.exp(cum)                                      # b n t g r
+
+    def one(s0, xs):
+        c_c, into_c, add_c, keep_c = xs
+        from_state = jnp.einsum("bgrpk,btgk->btgrp", s0, c_c, precision=_HI)
+        return keep_c * s0 + add_c, into_c[..., None] * from_state
+
+    state, ys = lax.scan(one, state.reshape(b, m.n_groups, -1, p, m.d_state),
+                         tuple(jnp.moveaxis(v, 1, 0)
+                               for v in (cm, into, add, keep)))
+    y = y + jnp.moveaxis(ys, 0, 1) + d.reshape(m.n_groups, -1, 1) * xg
+    return y.reshape(b, t, h, p), state.reshape(b, h, p, m.d_state)
+
+
+def step_rule(x, dt, a_log, d, bm, cm, state, *, m):
+    """One position: x (B, H, P), dt (B, H), bm, cm (B, G, N), `state` (B, H,
+    P, N) -> (y (B, H, P), the new state); the state is read and written
+    once."""
+    b, h, p = x.shape
+    s = state.reshape(b, m.n_groups, -1, p, m.d_state)
+    a = jnp.exp(dt * -jnp.exp(a_log)).reshape(b, m.n_groups, -1, 1, 1)
+    s = a * s + (dt[..., None] * x).reshape(
+        b, m.n_groups, -1, p, 1) * bm[:, :, None, None, :]
+    y = (s * cm[:, :, None, None, :]).sum(-1).reshape(b, h, p)
+    return y + d[:, None] * x, s.reshape(state.shape)
+
+
+def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype):
+    """The state-space mixer over a chunk h (B, T, C) whose first `n_real`
+    positions are real: `state` (B, H, P, N) float32 and `tail` (B, conv - 1,
+    W) come in -> (SSM(h) (B, T, C), the state and the tail after the last
+    REAL position)."""
+    m = cfg.mamba
+    t = h.shape[1]
+    with jax.named_scope("ssm.project"):
+        z, pre, dt = _project(p, h, m=m, compute_dtype=compute_dtype)
+        dt = jnp.where((jnp.arange(t) < n_real)[None, :, None], dt, 0.0)
+    with jax.named_scope("ssm.conv"):
+        conved, new_tail = state_kind.conv_chunk(tail, pre, _taps(p, m),
+                                                 n_real)
+        x, bm, cm = _heads(conved, p, m)
+    with jax.named_scope("ssm.chunk"):
+        y, state = chunk_rule(x, dt, p["a_log"], p["d"], bm, cm, state, m=m,
+                              chunk=math.gcd(m.chunk, t))
+    with jax.named_scope("ssm.out"):
+        o = _out(p, y, z, h.dtype, m=m, eps=cfg.rms_eps,
+                 compute_dtype=compute_dtype)
+    return o, state, new_tail.astype(tail.dtype)
+
+
+def mixer_step(p, h, state, tail, *, cfg, compute_dtype):
+    """The state-space mixer for one token a slot: h (B, 1, C), `state` (B,
+    H, P, N), `tail` (B, conv - 1, W) -> (SSM(h) (B, 1, C), state, tail)."""
+    m = cfg.mamba
+    with jax.named_scope("ssm.project"):
+        z, pre, dt = _project(p, h, m=m, compute_dtype=compute_dtype)
+    with jax.named_scope("ssm.conv"):
+        conved, rows = state_kind.conv_step(tail, pre, _taps(p, m))
+        x, bm, cm = _heads(conved, p, m)
+    with jax.named_scope("ssm.step"):
+        y, state = step_rule(x[:, 0], dt[:, 0], p["a_log"], p["d"], bm[:, 0],
+                             cm[:, 0], state, m=m)
+    with jax.named_scope("ssm.out"):
+        o = _out(p, y[:, None], z, h.dtype, m=m, eps=cfg.rms_eps,
+                 compute_dtype=compute_dtype)
+    return o, state, rows[:, 1:].astype(tail.dtype)
+
+
+def dense_mixer(p, h, *, cfg, compute_dtype):
+    """The state-space mixer over whole sequences h (B, T, C) from an empty
+    state: the chunked rule, T padded up to whole chunks."""
+    b, t, _ = h.shape
+    pad = -t % cfg.mamba.chunk
+    s0 = state_kind.fresh(slot_leaves(cfg.mamba), b, h.dtype)
+    o, _, _ = mixer_chunk(p, jnp.pad(h, ((0, 0), (0, pad), (0, 0))),
+                          s0["ssm_state"], s0["conv_tail"], jnp.int32(t),
+                          cfg=cfg, compute_dtype=compute_dtype)
+    return o[:, :t]
+
+
+def mixers_sum(attn_o, ssm_o, cfg):
+    """attention_out Attn(h) + ssm_out SSM(h): what the block's first
+    residual adds."""
+    ao = 1.0 if cfg.mup is None else cfg.mup.attention_out
+    return (attn_o.astype(jnp.float32) * ao
+            + ssm_o.astype(jnp.float32) * cfg.mamba.ssm_out
+            ).astype(attn_o.dtype)
+
+
+class HybridRows(llama.LlamaKindRows):
+    """`LlamaKindRows` for a model whose every layer runs softmax attention
+    AND a state-space mixer: ONE cache kind, "full", with paged `leaves` {k,
+    v} under `tables` AND `slot_leaves` {`ssm_state` (L, slots, H, P, N)
+    float32, `conv_tail` (L, slots, conv - 1, W)}. The pool carries all four
+    through the layer loop; a decode step runs the paged read and the
+    one-token rule on the same normed input, each in place — the K/V blocks
+    through the slot's table, the state at the layer's index and the slot's
+    row — and adds their scaled outputs before the residual. The
+    finish-and-install program installs the row's blocks and writes the
+    transient row's running state and tail into the slot, which is also what
+    resets a slot; the chunk program is told how many of its positions are
+    real (`takes_n_real`). Admission is bounded by slots AND by blocks. What
+    assumes K and V alone — the prefix store, the KV tier, int8 / int4 pools,
+    interleaved prefill, speculative verify — is refused by the batcher at
+    construction, by the leaves' names."""
+
+    takes_n_real = True
+
+    def __init__(self, cfg, **kw):
+        super().__init__(cfg, **kw)
+        self.paged_ok = False  # a verifier would have no state to rewind
+        self.cache_kinds["full"]["slot_leaves"] = slot_leaves(cfg.mamba)
+        self.attn_forms["full"].update(ssm_prefill="chunked_jnp",
+                                       ssm_decode="step_jnp")
+
+    def init_cache(self, batch, max_len, dtype):
+        return {**super().init_cache(batch, max_len, dtype),
+                **state_kind.fresh(slot_leaves(self.cfg.mamba), batch, dtype,
+                                   self.cfg.n_layer)}
+
+    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, n_real=None):
+        cfg = self.cfg
+        with jax.named_scope("llama.block.cached_attn"):
+            h = llama._pre_normed(bp, x, cfg)
+            o, rows = self._chunk_attn(bp, h, rows, start_pos, kind)
+            s, state, tail = mixer_chunk(
+                bp["ssm"], h, rows["ssm_state"], rows["conv_tail"],
+                x.shape[1] if n_real is None else n_real, cfg=cfg,
+                compute_dtype=self.compute_dtype)
+            o = mixers_sum(o, s, cfg)
+        with jax.named_scope("llama.block.mlp"):
+            return (llama._branches_residual(
+                bp, x, o, h, cfg=cfg, compute_dtype=self.compute_dtype,
+                ffn=ffn), {**rows, "ssm_state": state, "conv_tail": tail})
+
+    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
+                moe_stats=False, n_real=None):
+        kind = self.cache_kinds["full"]
+        return llama.prefill_by_kind(
+            self, prepared, padded, row_cache, start_pos, moe_stats,
+            {"full": (*kind["leaves"], *kind["slot_leaves"])}, n_real=n_real)
+
+    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
+                   kind="full"):
+        h, o, c = super()._attn_rows(bp, x, layer_cache, pos, write, codec,
+                                     window, kind)
+        layer = codec.layer
+        with jax.named_scope("state_pool.read"):
+            state, tail = c["ssm_state"][layer], c["conv_tail"][layer]
+        s, state, tail = mixer_step(bp["ssm"], h, state, tail, cfg=self.cfg,
+                                    compute_dtype=self.compute_dtype)
+        with jax.named_scope("state_pool.write"):
+            c = {**c, "ssm_state": c["ssm_state"].at[layer].set(state),
+                 "conv_tail": c["conv_tail"].at[layer].set(tail)}
+        return h, mixers_sum(o, s, self.cfg), c

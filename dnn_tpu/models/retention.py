@@ -66,7 +66,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dnn_tpu.models import llama
+from dnn_tpu.models import llama, state_kind
 from dnn_tpu.ops.attention import merge_heads
 from dnn_tpu.ops.nn import linear
 
@@ -330,9 +330,7 @@ def mixer_chunk(bp, h, state, norm, start_pos, n_real, *, cfg,
 def fresh_state(cfg, batch, layers=None):
     """Zeros of the retention kind's two leaves for `batch` slots, with a
     leading layer axis where `layers` is given."""
-    lead = (batch,) if layers is None else (layers, batch)
-    return {name: jnp.zeros((*lead, *shape), dtype)
-            for name, (shape, dtype) in slot_leaves(cfg).items()}
+    return state_kind.fresh(slot_leaves(cfg), batch, layers=layers)
 
 
 def dense_mixer(bp, h, *, cfg, compute_dtype):

@@ -1402,6 +1402,15 @@ class LMServer:
             # a paged pool's block, and the positions a group of the
             # paged decode kernel covers in the built decode program, by
             # the first leaf it reads — none where no program calls it
+            kinds = getattr(family, "cache_kinds", None)
+            if kinds:
+                # which of the leaves a kind pages under its tables and
+                # which a slot holds whole (no position axis)
+                comps["kv_cache"]["kinds"] = {
+                    kind: {"leaves": sorted(k["leaves"]),
+                           "slot_leaves": sorted(k.get("slot_leaves", ())),
+                           "tables": k["tables"]}
+                    for kind, k in kinds.items()}
             codec = getattr(self.batcher, "_paged_codec", None)
             if codec is not None:
                 comps["kv_cache"]["block_len"] = int(codec.block_len)

@@ -64,19 +64,27 @@ back and draws the next). Decode over such a leaf gathers the window's
 blocks, not the row (`write_attend_latent_rows(window=)`; `_window_rows`
 for K and V leaves).
 
-**A kind with NO position axis.** A layer that keeps a STATE — a matrix a
-head whatever the length, and the last rows of a short convolution
-(models/kda.py) — declares a kind with no `leaves`, no `tables` (None) and
-`slot_leaves`: name -> (the shape a slot a layer, dtype or None for the
-pool's):
+**Leaves with NO position axis.** A layer that keeps a STATE — a matrix a
+head whatever the length, and the last rows of a short convolution — says so
+in its kind's `slot_leaves`: name -> (the shape a slot a layer, dtype or None
+for the pool's; models/state_kind.py). A kind may have `slot_leaves` ALONE
+(models/kda.py's "linear" kind: no `leaves`, `tables` None, beside a "full"
+kind of K and V in other layers) or paged `leaves` under `tables` AND
+`slot_leaves` (models/mamba2.py: ONE kind whose every layer keeps K and V a
+position and a state a slot):
 
-    state     (L_lin, B, H, d, d)  float32
+    state     (L_lin, B, H, d, d)  float32        kda's "linear" kind
     conv_tail (L_lin, B, conv - 1, channels)
+    k, v      (L, n_blocks, KV, block_len, Dp)    mamba2's "full" kind,
+    ssm_state (L, B, H, P, N)      float32        under "tables" (L, B,
+    conv_tail (L, B, conv - 1, channels)          max_blocks)
 
-They ride the same pytree through `scan_blocks(layers=)` and the step's
-donation, reached at the layer's index among ITS kind's layers and the
-slot's row; they draw nothing from the `BlockAllocator` ("blocks a slot":
-none), `install_row` leaves them alone, and the finish-and-install program
+Slot leaves ride the same pytree through `scan_blocks(layers=)` and the
+step's donation, reached at the layer's index among ITS kind's layers and
+the slot's row, in the same layer body that reaches the kind's blocks
+through the slot's table; they draw nothing from the `BlockAllocator`
+("blocks a slot" counts paged leaves alone), `install_row` installs the
+kind's blocks and leaves them alone, and the finish-and-install program
 writes the transient row's running state at the slot — which is also the
 only thing that resets a slot.
 

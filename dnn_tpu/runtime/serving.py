@@ -395,12 +395,13 @@ class ContinuousBatcher:
         # kinds whose cache is K and V (models/llama.py `LlamaKindRows`):
         # their reads are the attn.* series', a latent family's the mla.*
         self._kv_kinds = full is not None and not self._latent
-        # a kind whose leaves have NO position axis (models/kda.py: a
-        # state and a convolution tail a slot a layer): name -> (shape a
-        # slot a layer, dtype). They ride the pool's pytree, are installed
-        # and reset by the finish program alone and draw no blocks; the
-        # chunk program of such a family is told how many of its
-        # positions are real (`prefill_chunk`)
+        # leaves with NO position axis (a kind's `slot_leaves` — a state
+        # and a convolution tail a slot a layer: models/kda.py's kind of
+        # them alone, models/mamba2.py's beside paged K and V in the SAME
+        # kind): name -> (shape a slot a layer, dtype). They ride the
+        # pool's pytree, are installed and reset by the finish program
+        # alone and draw no blocks; the chunk program of such a family is
+        # told how many of its positions are real (`prefill_chunk`)
         self._slot_leaves = {
             n: v for k in (self._cache_kinds or {}).values()
             for n, v in k.get("slot_leaves", {}).items()}
@@ -852,7 +853,7 @@ class ContinuousBatcher:
             # its window kinds handed back while their requests ran
             for kind, k in self._cache_kinds.items():
                 if k["tables"] is None:
-                    continue  # a state kind holds no blocks
+                    continue  # slot leaves alone: the kind holds no blocks
                 self._obs_gauges[labeled(
                     "kv_pool.blocks_in_use", kind=kind)] = _weak_gauge(
                         "_kind_used_read", k["tables"])

@@ -816,18 +816,32 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
     capture) — and the pool's bytes by the kinds' leaves; a model with a
     STATE kind beside K and V (it writes `state_pool_*`) names that kind's
     forms too, and its leaves without a position axis are counted among
-    the pool's bytes; no other model has the line."""
+    the pool's bytes — whether they are a kind of their own or the slot
+    leaves of the kind that pages K and V (`kv_cache.kinds` says which
+    leaves a kind pages and which a slot holds whole); no other model has
+    the line."""
     comps = served(config)["statusz"]["components"]
     series = _SERVED[config]["series"]
     if any(s.startswith("state_pool_") for s in series):
         state = {"prefill": "chunked_jnp", "decode": "step_jnp"}
         kinds, leaves = comps["attention"]["kinds"], sorted(
             comps["kv_cache"]["bytes_by_leaf"])
-        if "full" in kinds:  # a state kind BESIDE K and V
+        if "linear" in kinds:  # a state kind BESIDE a kind of K and V
             assert kinds == {"full": {"prefill": "plain",
                                       "decode": "gather_einsum"},
                              "linear": state}
             assert leaves == ["conv_tail", "k", "state", "v"]
+        elif "full" in kinds:  # ONE kind: paged K and V AND slot leaves
+            assert kinds == {"full": {
+                "prefill": "plain", "decode": "gather_einsum",
+                "ssm_prefill": "chunked_jnp", "ssm_decode": "step_jnp"}}
+            assert leaves == ["conv_tail", "k", "ssm_state", "v"]
+            assert comps["kv_cache"]["kinds"] == {"full": {
+                "leaves": ["k", "v"], "tables": "tables",
+                "slot_leaves": ["conv_tail", "ssm_state"]}}
+            m = served(config)["metrics"]
+            assert m['kv_pool_blocks_in_use{kind="full"}'] >= 0
+            assert m["state_pool_installs_total"] > 0
         else:  # no K/V layer at all: ONE kind, nothing paged
             assert kinds == {"retention": state}
             assert leaves == ["norm", "state"]

@@ -919,3 +919,85 @@ def test_brumby_chunk_program_fits_beside_the_pool(brumby_programs):
     chunk = compiled["_prefill_chunk"]
     assert _state_extent_ops(chunk, extent) == []
     assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+# ----------------------------------------------------------------------
+# ISSUE 54 — ONE kind with paged K and V AND a state a slot (Falcon-H1)
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def falcon_h1_programs(chip):
+    """Falcon-H1's step programs at its published widths and the cell's
+    pool (64 slots of state and of 1536 positions, 256-token chunks), depth
+    cut to TWO layers and the vocabulary to 8192 rows (the head is not what
+    is held here). -> ({name: compiled}, the state leaf's extent a layer,
+    the K leaf's, the pool's bytes)."""
+    import dataclasses
+
+    from dnn_tpu.models import llama
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(llama.PRESETS["falcon-h1-34b"], n_layer=2,
+                              vocab_size=8192)
+    prepared = _stack_and_release(llama.init(jax.random.PRNGKey(0), cfg),
+                                  cfg, BF16)
+    b = ContinuousBatcher(
+        cfg, prepared, slots=64, max_len=1536, prompt_pad=256, kv="auto",
+        family=llama.family_rows(cfg, compute_dtype=BF16))
+    assert b._paged and b._allocator is not None
+    assert b.cache["ssm_state"].shape == (2, 64, 32, 128, 256)
+    assert b.cache["ssm_state"].dtype == jnp.float32
+    assert b.cache["k"].shape == (2, 64 * 96 + 1, 4, 16, 128)
+    compiled = _lower_programs(
+        chip, [(b, ("_prefill_chunk", "_prefill_finish", "_decode"))])
+    return (compiled, b.cache["ssm_state"].shape[1:], b.cache["k"].shape[1:],
+            sum(x.nbytes for x in b.cache.values()))
+
+
+def test_falcon_h1_decode_step_reaches_blocks_and_state_in_place(
+        falcon_h1_programs):
+    """The decode step runs the paged kernel and the one-token rule in one
+    layer body: nothing of the extent of the K/V leaves but the kernel's
+    aliased results, nothing of a layer's states' (0.27 GB) but the fused
+    update written into the leaf (the plain form reads the states twice —
+    once for the answers, once for the update — and writes them once: PERF.md
+    section 6, PR 54), the donated leaves are the program's results and its
+    temporaries stay under half a layer's states."""
+    compiled, state, pool, pool_bytes = falcon_h1_programs
+    step = compiled["_decode"]
+    assert "tpu_custom_call" in step.as_text()
+    assert {o[0] for o in _pool_extent_ops(step, pool)} <= {"custom-call"}
+    # the rule's own arithmetic lies inside fusions whose root writes the
+    # layer's states into the leaf; no copy, no stacking of layers
+    ops = {o[0] for o in _state_extent_ops(step, state)}
+    assert "fusion" in ops and "dynamic-update-slice" in ops
+    assert not ops & {"copy", "concatenate", "transpose", "pad"}, ops
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < int(np.prod(state)) * 4 // 2
+
+
+def test_falcon_h1_finish_installs_blocks_and_state_without_a_pool_copy(
+        falcon_h1_programs):
+    compiled, state, pool, pool_bytes = falcon_h1_programs
+    finish = compiled["_prefill_finish"]
+    for ops in (_state_extent_ops(finish, state),
+                _pool_extent_ops(finish, pool)):
+        assert {o[0] for o in ops} <= {"dynamic-update-slice", "fusion",
+                                       "scatter"}, ops
+    mem = finish.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 26
+
+
+def test_falcon_h1_chunk_program_fits_beside_the_pool(falcon_h1_programs):
+    """The chunk program works on the transient row alone (K and V of 1536
+    positions, one slot's state and tail) and its temporaries stay under a
+    quarter of a GB."""
+    compiled, state, pool, _ = falcon_h1_programs
+    chunk = compiled["_prefill_chunk"]
+    assert _state_extent_ops(chunk, state) == []
+    assert _pool_extent_ops(chunk, pool) == []
+    assert "tpu_custom_call" in chunk.as_text()  # the prefill kernel
+    assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 28
