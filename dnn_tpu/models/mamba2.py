@@ -38,7 +38,9 @@ Three forms of the same numbers:
   * **the recurrence** (`recurrence`): a `lax.scan` over positions — what
     chipbench/reference/falcon_h1.py computes on its own, here for the tests.
   * **the step** (decode, `step_rule`): one token a slot, the state read and
-    written ONCE.
+    written ONCE — on the chip by ops/pallas/ssm_step.py, in place in the
+    pool's leaf (`step_rule_kernel`); `step_rule` is the CPU's path and the
+    kernel's judge.
   * **the chunked rule** (prefill, `chunk_rule`; "SSD"): positions in chunks
     of `Mamba2Config.chunk` FROM AN INCOMING STATE. With G_t the cumulative
     sum of dt A inside the chunk: y_t = sum_{s <= t} exp(G_t - G_s) dt_s (C_t
@@ -59,6 +61,7 @@ the compute dtype.
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
@@ -228,7 +231,7 @@ def chunk_rule(x, dt, a_log, d, bm, cm, state, *, m, chunk):
 def step_rule(x, dt, a_log, d, bm, cm, state, *, m):
     """One position: x (B, H, P), dt (B, H), bm, cm (B, G, N), `state` (B, H,
     P, N) -> (y (B, H, P), the new state); the state is read and written
-    once."""
+    once. The plain form (the chip's is `step_rule_kernel`)."""
     b, h, p = x.shape
     s = state.reshape(b, m.n_groups, -1, p, m.d_state)
     a = jnp.exp(dt * -jnp.exp(a_log)).reshape(b, m.n_groups, -1, 1, 1)
@@ -236,6 +239,19 @@ def step_rule(x, dt, a_log, d, bm, cm, state, *, m):
         b, m.n_groups, -1, p, 1) * bm[:, :, None, None, :]
     y = (s * cm[:, :, None, None, :]).sum(-1).reshape(b, h, p)
     return y + d[:, None] * x, s.reshape(state.shape)
+
+
+def step_rule_kernel(x, dt, a_log, d, bm, cm, pool, *, m, layer,
+                     interpret=False):
+    """`step_rule` on the WHOLE pool leaf `pool` (L, B, H, P, N) at layer
+    `layer`: the state's one pass — decay, add (dt x) B^T, answer with C,
+    write back in place — in ops/pallas/ssm_step.py -> (y, pool)."""
+    from dnn_tpu.ops.pallas.ssm_step import ssm_step
+
+    del m  # the groups are bm's second axis
+    pool, y = ssm_step(pool, layer, jnp.exp(dt * -jnp.exp(a_log)), dt, d, x,
+                       bm, cm, interpret=interpret)
+    return y, pool
 
 
 def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype):
@@ -261,9 +277,11 @@ def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype):
     return o, state, new_tail.astype(tail.dtype)
 
 
-def mixer_step(p, h, state, tail, *, cfg, compute_dtype):
+def mixer_step(p, h, state, tail, *, cfg, compute_dtype, rule=step_rule):
     """The state-space mixer for one token a slot: h (B, 1, C), `state` (B,
-    H, P, N), `tail` (B, conv - 1, W) -> (SSM(h) (B, 1, C), state, tail)."""
+    H, P, N), `tail` (B, conv - 1, W) -> (SSM(h) (B, 1, C), state, tail).
+    `state` is whatever `rule` takes and returns: a layer's states for
+    `step_rule`, the whole leaf for `step_rule_kernel` bound to a layer."""
     m = cfg.mamba
     with jax.named_scope("ssm.project"):
         z, pre, dt = _project(p, h, m=m, compute_dtype=compute_dtype)
@@ -271,8 +289,8 @@ def mixer_step(p, h, state, tail, *, cfg, compute_dtype):
         conved, rows = state_kind.conv_step(tail, pre, _taps(p, m))
         x, bm, cm = _heads(conved, p, m)
     with jax.named_scope("ssm.step"):
-        y, state = step_rule(x[:, 0], dt[:, 0], p["a_log"], p["d"], bm[:, 0],
-                             cm[:, 0], state, m=m)
+        y, state = rule(x[:, 0], dt[:, 0], p["a_log"], p["d"], bm[:, 0],
+                        cm[:, 0], state, m=m)
     with jax.named_scope("ssm.out"):
         o = _out(p, y[:, None], z, h.dtype, m=m, eps=cfg.rms_eps,
                  compute_dtype=compute_dtype)
@@ -308,7 +326,8 @@ class HybridRows(llama.LlamaKindRows):
     through the layer loop; a decode step runs the paged read and the
     one-token rule on the same normed input, each in place — the K/V blocks
     through the slot's table, the state at the layer's index and the slot's
-    row — and adds their scaled outputs before the residual. The
+    row (on the chip ONE kernel's pass over the whole leaf, `_step_kernel`)
+    — and adds their scaled outputs before the residual. The
     finish-and-install program installs the row's blocks and writes the
     transient row's running state and tail into the slot, which is also what
     resets a slot; the chunk program is told how many of its positions are
@@ -325,6 +344,14 @@ class HybridRows(llama.LlamaKindRows):
         self.cache_kinds["full"]["slot_leaves"] = slot_leaves(cfg.mamba)
         self.attn_forms["full"].update(ssm_prefill="chunked_jnp",
                                        ssm_decode="step_jnp")
+
+    def _step_kernel(self):
+        """Whether the one-token rule runs in the Pallas kernel: on the
+        chip unless the family's kernels are off, interpreted where a test
+        asks."""
+        if self.attn_kernel == "interpret":
+            return "interpret"
+        return bool(self.attn_kernel) and jax.default_backend() == "tpu"
 
     def init_cache(self, batch, max_len, dtype):
         return {**super().init_cache(batch, max_len, dtype),
@@ -358,11 +385,22 @@ class HybridRows(llama.LlamaKindRows):
         h, o, c = super()._attn_rows(bp, x, layer_cache, pos, write, codec,
                                      window, kind)
         layer = codec.layer
+        kernel = self._step_kernel()
+        self.attn_forms["full"]["ssm_decode"] = (
+            "step_kernel" if kernel else "step_jnp")
+        # the kernel takes the WHOLE leaf and hands it back updated in place
+        rule = functools.partial(
+            step_rule_kernel, layer=layer,
+            interpret=kernel == "interpret") if kernel else step_rule
         with jax.named_scope("state_pool.read"):
-            state, tail = c["ssm_state"][layer], c["conv_tail"][layer]
+            tail = c["conv_tail"][layer]
+            state = c["ssm_state"] if kernel else c["ssm_state"][layer]
         s, state, tail = mixer_step(bp["ssm"], h, state, tail, cfg=self.cfg,
-                                    compute_dtype=self.compute_dtype)
+                                    compute_dtype=self.compute_dtype,
+                                    rule=rule)
         with jax.named_scope("state_pool.write"):
-            c = {**c, "ssm_state": c["ssm_state"].at[layer].set(state),
+            if not kernel:
+                state = c["ssm_state"].at[layer].set(state)
+            c = {**c, "ssm_state": state,
                  "conv_tail": c["conv_tail"].at[layer].set(tail)}
         return h, mixers_sum(o, s, self.cfg), c

@@ -812,8 +812,9 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
     leaves are by layer kind (it writes `attn_cached_positions_read_total`),
     the form each kind's chunk and decode reads took in the built programs
     — on the CPU the plain ones (on the chip "kernel" / "banded_kernel"
-    and "paged_kernel", or a daemon that fell back shows it without a
-    capture) — and the pool's bytes by the kinds' leaves; a model with a
+    and "paged_kernel", and Falcon-H1's `ssm_decode` reads "step_kernel"
+    there as Brumby's `decode` does, or a daemon that fell back shows it
+    without a capture) — and the pool's bytes by the kinds' leaves; a model with a
     STATE kind beside K and V (it writes `state_pool_*`) names that kind's
     forms too, and its leaves without a position axis are counted among
     the pool's bytes — whether they are a kind of their own or the slot

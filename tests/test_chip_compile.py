@@ -20,6 +20,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 # all but the first fail to describe the topology and skip
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
+import functools
 import re
 
 import jax
@@ -957,25 +958,50 @@ def falcon_h1_programs(chip):
 
 def test_falcon_h1_decode_step_reaches_blocks_and_state_in_place(
         falcon_h1_programs):
-    """The decode step runs the paged kernel and the one-token rule in one
-    layer body: nothing of the extent of the K/V leaves but the kernel's
-    aliased results, nothing of a layer's states' (0.27 GB) but the fused
-    update written into the leaf (the plain form reads the states twice —
-    once for the answers, once for the update — and writes them once: PERF.md
-    section 6, PR 54), the donated leaves are the program's results and its
-    temporaries stay under half a layer's states."""
+    """The decode step runs the paged kernel and the one-token rule's kernel
+    (ops/pallas/ssm_step.py, ISSUE 55) in one layer body: nothing of the
+    extent of the K/V leaves but the paged kernel's aliased results, nothing
+    of a layer's states' (0.27 GB) or of the state leaf's but the step
+    kernel's aliased result — no `fusion`, `dynamic-update-slice` or `copy`
+    of a layer's states, which is what the plain form's two reads and a
+    write were (PERF.md section 6, PR 54 and PR 55); the donated leaves are
+    the program's results and its temporaries stay under half a layer's
+    states."""
     compiled, state, pool, pool_bytes = falcon_h1_programs
     step = compiled["_decode"]
     assert "tpu_custom_call" in step.as_text()
     assert {o[0] for o in _pool_extent_ops(step, pool)} <= {"custom-call"}
-    # the rule's own arithmetic lies inside fusions whose root writes the
-    # layer's states into the leaf; no copy, no stacking of layers
-    ops = {o[0] for o in _state_extent_ops(step, state)}
-    assert "fusion" in ops and "dynamic-update-slice" in ops
-    assert not ops & {"copy", "concatenate", "transpose", "pad"}, ops
+    ops = _state_extent_ops(step, state)
+    assert ops and {o[0] for o in ops} == {"custom-call"}, ops
+    assert "ssm_step" in step.as_text()
     mem = step.memory_analysis()
     assert mem.alias_size_in_bytes >= pool_bytes
     assert mem.temp_size_in_bytes < int(np.prod(state)) * 4 // 2
+
+
+def test_ssm_step_kernel_compiles(chip):
+    """ops/pallas/ssm_step.py on the cell's whole state leaf of nine layers
+    (2.4 GB), entered at a layer that rides scalar prefetch: the leaf is the
+    call's aliased result, nothing else of its size exists, and the heads of
+    a grid step are a loop, not 16 written-out bodies (one transpose for the
+    column, one for the answers)."""
+    from dnn_tpu.ops.pallas.ssm_step import ssm_step
+
+    b, h, g, p, n = 64, 32, 2, 128, 256
+    pool = (9, b, h, p, n)
+    shapes = ((pool, F32), ((), jnp.int32), ((b, h), F32), ((b, h), F32),
+              ((h,), F32), ((b, h, p), F32), ((b, g, n), F32),
+              ((b, g, n), F32))
+    fn = functools.partial(ssm_step, interpret=False)
+    call, = _kernel_calls(fn, shapes)
+    names = [e.primitive.name for e in _eqns(call.params["jaxpr"])]
+    assert names.count("transpose") == 2 and "dot_general" not in names
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in shapes]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 4
+    assert mem.temp_size_in_bytes < 2 ** 22  # the rows beside the state
 
 
 def test_falcon_h1_finish_installs_blocks_and_state_without_a_pool_copy(

@@ -165,6 +165,8 @@ def test_served_logprobs_match_the_reference(model, plain, n):
                          ids=["strongest", "weakest"])
 @pytest.mark.parametrize("incoming", [False, True], ids=["zero", "state"])
 def test_the_three_forms_of_the_rule_agree(model, a, dt, incoming):
+    """... and a FOURTH: the step kernel (ops/pallas/ssm_step.py),
+    interpreted, walking the same positions in place in a pool leaf."""
     _, cfg, _ = model
     m = cfg.mamba
     b, t = 2, 4 * m.chunk
@@ -187,15 +189,83 @@ def test_the_three_forms_of_the_rule_agree(model, a, dt, incoming):
             y, s = mamba2.step_rule(x[:, i], steps[:, i], a_log, d,
                                     bm[:, i], cm[:, i], s, m=m)
             ys.append(y)
+
+        def kernel_step(pool, xs):
+            x_t, dt_t, b_t, c_t = xs
+            y, pool = mamba2.step_rule_kernel(
+                x_t, dt_t, a_log, d, b_t, c_t, pool, m=m, layer=jnp.int32(1),
+                interpret=True)
+            return pool, y
+
+        pool, y_ker = jax.lax.scan(
+            kernel_step, jnp.stack([s0 + 1.0, s0, s0 - 1.0]),
+            tuple(jnp.moveaxis(v, 1, 0) for v in (x, steps, bm, cm)))
     scale = float(jnp.abs(y_rec).max())
     assert float(jnp.abs(y_chk - y_rec).max()) < 1e-5 * scale
     assert float(jnp.abs(jnp.stack(ys, 1) - y_rec).max()) < 1e-5 * scale
+    assert float(jnp.abs(jnp.moveaxis(y_ker, 0, 1) - y_rec).max()) \
+        < 1e-5 * scale
     s_scale = max(float(jnp.abs(s_rec).max()), 1.0)
     assert float(jnp.abs(s_chk - s_rec).max()) < 1e-5 * s_scale
     assert float(jnp.abs(s - s_rec).max()) < 1e-5 * s_scale
+    assert float(jnp.abs(pool[1] - s_rec).max()) < 1e-5 * s_scale
+    assert jnp.array_equal(pool[0], s0 + 1.0)
+    assert jnp.array_equal(pool[2], s0 - 1.0)
     # the weakest decay REMEMBERS: an incoming state is still most of itself
     if incoming and a == 1.0:
         assert float(jnp.abs(s_rec).mean()) > 0.9 * float(jnp.abs(s0).mean())
+
+
+# the step kernel, interpreted, IS the plain step: (slots, heads, groups,
+# head dim, state width, layers, the layer) — an odd slot count, heads that
+# are ONE group, a state of two lane tiles, the test model's own shapes
+@pytest.mark.parametrize("slots,h,g,p,n,layers,layer", [
+    (3, 4, 2, 16, 16, 3, 1), (5, 4, 1, 16, 16, 4, 2), (1, 6, 3, 8, 256, 3, 1),
+    (2, 2, 2, 128, 128, 5, 3)],
+    ids=["test_model", "odd_slots_one_group", "two_lane_tiles",
+         "one_head_groups"])
+def test_the_step_kernel_is_the_plain_step(slots, h, g, p, n, layers, layer):
+    """ops/pallas/ssm_step.py, interpreted: one pass over the WHOLE leaf at
+    a layer's index that is neither first nor last gives the plain step's
+    answers and state and leaves every other layer's states bit-identical."""
+    m = llama.Mamba2Config(d_ssm=h * p, n_head=h, d_state=n, n_groups=g)
+    ks = jax.random.split(jax.random.PRNGKey(slots), 7)
+    pool = jax.random.normal(ks[0], (layers, slots, h, p, n))
+    x = jax.random.normal(ks[1], (slots, h, p))
+    dt = jax.nn.softplus(jax.random.normal(ks[2], (slots, h)))
+    bm, cm = (jax.random.normal(k, (slots, g, n)) for k in ks[3:5])
+    a_log = jax.random.uniform(ks[5], (h,), minval=0.0, maxval=np.log(16.0))
+    d = jax.random.normal(ks[6], (h,))
+    want, s_want = mamba2.step_rule(x, dt, a_log, d, bm, cm, pool[layer],
+                                    m=m)
+    got, pool2 = mamba2.step_rule_kernel(
+        x, dt, a_log, d, bm, cm, pool, m=m, layer=jnp.int32(layer),
+        interpret=True)
+    assert pool2.dtype == jnp.float32 and got.dtype == jnp.float32
+    scale = float(jnp.abs(want).max())
+    assert float(jnp.abs(got - want).max()) < 1e-5 * scale
+    assert float(jnp.abs(pool2[layer] - s_want).max()) < 1e-5
+    for other in set(range(layers)) - {layer}:
+        assert jnp.array_equal(pool2[other], pool[other])
+
+
+def test_the_step_kernel_serves_the_plain_steps_logprobs(model, plain):
+    """A batcher whose family runs the kernel (interpreted) serves what the
+    plain one serves — the reference's log-probabilities — and says so
+    under the name the daemon's /statusz reports."""
+    _, cfg, params = model
+    srv = _batcher(model, family=llama.family_rows(
+        cfg, attn_kernel="interpret"), logprobs_k=256)
+    prompt = _ids(2 * PAD + 7, 12)
+    toks, lps = _served_logprobs(srv, prompt, 6)
+    toks_plain, lps_plain = _served_logprobs(plain, prompt, 6)
+    assert np.array_equal(toks, toks_plain)
+    assert np.abs(lps - lps_plain).max() < 1e-4
+    assert np.abs(lps - _reference_logprobs(cfg, params, prompt, toks)
+                  ).max() < TOL
+    assert srv.family.attn_forms["full"]["ssm_decode"] == "step_kernel"
+    assert plain.family.attn_forms["full"]["ssm_decode"] == "step_jnp"
+    assert srv.cache["ssm_state"].dtype == jnp.float32
 
 
 # (3) the padded tail leaves state and convolution tail alone
