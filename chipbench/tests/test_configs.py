@@ -9,7 +9,7 @@ import os
 
 import pytest
 
-from chipbench import cells, scopes, spans
+from chipbench import cells, scopes, spans, step_roofline
 from chipbench import peaks as pk
 
 HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -37,29 +37,58 @@ LAYER_FILES = sorted(n[:-len(".json")]
 WRITTEN_SCOPES = set(spans.SCOPES).union(*(
     scopes.known_scopes({"config": c}) or () for c in CONFIGS.values()))
 
-#: each configuration's list, letter for letter as every `moe_scope_*`,
-#: `keye_scope_*` and `joy_scope_*` file (and `moe_experts_roofline_pct`)
-#: repeated it under `args.known` until PR 39
+#: each configuration's list, letter for letter: the three that every
+#: `moe_scope_*`, `keye_scope_*` and `joy_scope_*` file (and
+#: `moe_experts_roofline_pct`) repeated under `args.known` until PR 39, and
+#: the five that PRs 41-54 brought with their configurations
+_EXPERTS = ["moe.experts", "moe.route", "moe.combine"]
+_LLAMA = ["attn.", "kv_pool.", "llama.", "sample", "layers.scan"]
 KNOWN_SCOPES = {
-    "olmoe-1b-7b-1chip": [
-        "moe.experts", "moe.route", "moe.combine", "attn.", "kv_pool.",
-        "llama.", "sample", "layers.scan"],
-    "keye-vl-2.0-30b-a3b-ep8-1chip": [
-        "dsa.index", "dsa.select", "moe.experts", "moe.route", "moe.combine",
-        "attn.", "kv_pool.", "llama.", "sample", "layers.scan"],
-    "joyai-llm-flash-ep16-1chip": [
-        "mla.project", "mla.absorb", "mla.up_project", "moe.experts",
-        "moe.route", "moe.combine", "moe.shared", "attn.", "kv_pool.",
-        "llama.", "sample", "layers.scan"],
+    "olmoe-1b-7b-1chip": _EXPERTS + _LLAMA,
+    "keye-vl-2.0-30b-a3b-ep8-1chip":
+        ["dsa.index", "dsa.select"] + _EXPERTS + _LLAMA,
+    "joyai-llm-flash-ep16-1chip":
+        ["mla.project", "mla.absorb", "mla.up_project"] + _EXPERTS
+        + ["moe.shared"] + _LLAMA,
+    "dots3-note-prev-ep8-1chip":
+        ["dsa.index", "dsa.select", "mla.project", "mla.absorb",
+         "mla.up_project", "mla.gate"] + _EXPERTS + ["moe.shared"] + _LLAMA,
+    "k-exaone-236b-a23b-ep8-1chip": _EXPERTS + ["moe.shared"] + _LLAMA,
+    "solar-open2-250b-ep8-1chip":
+        ["kda.project", "kda.scan", "kda.step", "kda.out", "state_pool.",
+         "attn_gate"] + _EXPERTS + ["moe.shared"] + _LLAMA,
+    # no K/V layer: no `attn.`, no `kv_pool.`
+    "brumby-14b-pp8-1chip":
+        ["ret.project", "ret.chunk", "ret.step", "ret.out", "state_pool.",
+         "llama.", "sample", "layers.scan"],
+    "falcon-h1-34b-pp8-1chip": ["ssm.", "state_pool."] + _LLAMA,
 }
 
-#: readers a cell, in `BENCHMARK.json`'s order of cells (the ledger's PR 38
-#: lines hold as many per-layer readings a cell): merging entries that
-#: share a reader takes no reading from a cell and gives it none
-READERS = {"large-chat-saturated": 27, "large-chat-steady": 19,
-           "large-prefill-saturated": 26, "pipe4-batch-forward": 3,
-           "large-chat-bursty": 16, "olmoe-chat-saturated": 29,
-           "keye-videoqa-saturated": 27, "joyai-docreport-saturated": 29}
+#: the module whose `decode_step_roofline_pct` counts a configuration's
+#: step (`trace.roofline`, PR 56); OLMoE's and the pipeline's name none
+ROOFLINE = {
+    "gpt2-large": "reducers",
+    "keye-vl-2.0-30b-a3b-ep8-1chip": "keye_roofline",
+    "joyai-llm-flash-ep16-1chip": "mla_roofline",
+    "dots3-note-prev-ep8-1chip": "dots3_roofline",
+    "k-exaone-236b-a23b-ep8-1chip": "exaone_roofline",
+    "solar-open2-250b-ep8-1chip": "solar_roofline",
+    "brumby-14b-pp8-1chip": "brumby_roofline",
+    "falcon-h1-34b-pp8-1chip": "falcon_h1_roofline",
+}
+
+#: readers a cell, in `BENCHMARK.json`'s order of cells (the ledger's PR 55
+#: lines hold as many per-layer readings a cell, and PR 56's tree resolves
+#: to exactly these): merging entries that share a reader takes no reading
+#: from a cell and gives it none
+READERS = {"large-chat-saturated": 34, "large-chat-steady": 20,
+           "large-prefill-saturated": 33, "pipe4-batch-forward": 3,
+           "large-chat-bursty": 23, "olmoe-chat-saturated": 36,
+           "keye-videoqa-saturated": 34, "joyai-docreport-saturated": 36,
+           "dots3-longnote-saturated": 49,
+           "kexaone-reasoning-saturated": 45,
+           "solar2-longchat-saturated": 45, "brumby-fewshot-saturated": 37,
+           "falconh1-chat-saturated": 37}
 
 
 @pytest.mark.parametrize("name", SERVING)
@@ -67,7 +96,9 @@ def test_weights_are_priced_at_the_served_dtype(name):
     run = CONFIGS[name]["run"]
     assert run["weight_bytes_per_param"] == \
         scopes.OPERAND_BYTES[run["dtype"]]
-    assert run["kv_bytes_per_element"] == 2  # the daemon's pool: bfloat16
+    # the daemon's pool: bfloat16 (a configuration with no K/V layer states
+    # it too: its rooflines price no cached position)
+    assert run["kv_bytes_per_element"] == 2
 
 
 def test_least_time_of_a_gpt2_large_chat_step():
@@ -103,15 +134,31 @@ def test_a_layers_file_names_only_written_scopes(metric):
     assert not strays, (metric, strays)
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
+@pytest.mark.parametrize("name", sorted(KNOWN_SCOPES) + [
+    "gpt2-large", "gpt2-large-pipe4"])
 def test_a_configuration_declares_the_list_its_files_held(name):
     assert scopes.known_scopes({"config": CONFIGS[name]}) == \
         KNOWN_SCOPES.get(name)
 
 
+@pytest.mark.parametrize("name", sorted(ROOFLINE) + [
+    "olmoe-1b-7b-1chip", "gpt2-large-pipe4"])
+def test_a_configuration_names_the_module_that_counts_its_step(name):
+    assert step_roofline.roofline_module({"config": CONFIGS[name]}) == \
+        ROOFLINE.get(name)
+    assert set(CONFIGS[name].get("trace", {})) <= {"roofline",
+                                                   "known_scopes"}
+
+
 def test_every_cell_resolves_to_as_many_readers_as_before():
-    assert [w["name"] for w in BENCH["workloads"]] == list(READERS)
-    assert {w: len(cells.resolve(w)["per_layer"]) for w in READERS} == READERS
+    """The thirteen cells of PR 55; a cell a later PR adds is held by the
+    test file it brings."""
+    assert [w["name"] for w in BENCH["workloads"]][:13] == list(READERS)
+    got = {w: len(cells.resolve(w)["per_layer"]) for w in READERS}
+    # equal on PR 56's tree; a later PR's new entry may add to a cell, none
+    # takes a reading away (and none reports one twice:
+    # `test_merged_readings.py`)
+    assert all(got[w] >= n for w, n in READERS.items()), got
 
 
 @pytest.mark.parametrize("workload", [
@@ -129,12 +176,15 @@ def test_a_cells_scope_shares_cover_its_configurations_list_once(workload):
 
 
 def test_a_configuration_without_the_block_has_no_scope_shares():
-    for name in set(CONFIGS) - set(KNOWN_SCOPES):
+    without = [n for n, c in CONFIGS.items()
+               if "known_scopes" not in c.get("trace", {})]
+    assert sorted(without) == ["gpt2-large", "gpt2-large-pipe4"]
+    for name in without:
         facts = _family_facts(CONFIGS[name])
         assert scopes.share_pct(facts, scopes=["attn."]) is None
         assert scopes.share_pct(facts, scopes=None) is None
     for w in BENCH["workloads"]:
-        if w["config"] not in KNOWN_SCOPES:
+        if w["config"] in without:
             assert not [n for n, (fn, _) in cells.resolve(
                 w["name"])["per_layer"].items() if fn is scopes.share_pct]
 
@@ -185,8 +235,8 @@ _PARENT_READ = {
         "moe_experts_roofline_pct": 462241.2087078451,
     },
     "keye-videoqa-saturated": {
-        "keye_scope_index_pct": 4.711377659881206,
-        "keye_scope_select_pct": 1.3667031889741077,
+        "scope_index_pct": 4.711377659881206,
+        "scope_select_pct": 1.3667031889741077,
         "scope_attn_pct": 19.802779539818925,
         "scope_experts_pct": 29.952136554985294,
         "scope_route_pct": 5.501412836629953,
@@ -195,14 +245,14 @@ _PARENT_READ = {
         "scope_unscoped_pct": 3.84060896142091,
     },
     "joyai-docreport-saturated": {
-        "joy_scope_mla_project_pct": 8.056052130788304,
-        "joy_scope_mla_absorb_pct": 1.4820367914191799,
-        "joy_scope_mla_up_project_pct": 7.364050516117873,
+        "scope_mla_project_pct": 8.056052130788304,
+        "scope_mla_absorb_pct": 1.4820367914191799,
+        "scope_mla_up_project_pct": 7.364050516117873,
         "scope_attn_pct": 19.802779539818925,
         "scope_kv_pool_pct": 1.0207023816388905,
         "scope_experts_pct": 29.952136554985294,
         "scope_route_pct": 5.501412836629953,
-        "joy_scope_shared_pct": 2.635372815869904,
+        "scope_shared_pct": 2.635372815869904,
         "scope_model_pct": 20.344847471310768,
         "scope_unscoped_pct": 3.84060896142091,
     },

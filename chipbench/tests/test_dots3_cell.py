@@ -106,20 +106,40 @@ def test_the_rooflines_widths_are_the_issues_counts():
     assert x["expert_params"] == 23_592_960
 
 
+#: what PR 41 brought under `dots_` and PR 56 gave the names of the readers
+SHARED = ["srv_decode_step_roofline_pct", "scope_index_pct",
+          "scope_select_pct", "scope_mla_project_pct", "scope_mla_absorb_pct",
+          "scope_mla_up_project_pct", "scope_shared_pct",
+          "srv_selected_share", "srv_window_blocks_share",
+          "srv_window_blocks_freed_per_step"]
+
+
 def test_the_cell_resolves_to_its_readers():
     cell = cells.resolve(CELL)
-    assert len(cell["per_layer"]) == 42
+    assert len(cell["per_layer"]) >= 49  # 42 at PR 41, 49 since PR 52
     assert cell["end_to_end"] == ["out_tok_s", "setup_s"]
     shares = [args["scopes"] for fn, args in cell["per_layer"].values()
               if fn is scopes.share_pct]
     assert shares.count(None) == 1
     given = [p for s in shares if s is not None for p in s]
     assert sorted(given) == sorted(CONFIG["trace"]["known_scopes"])
-    for m in BENCH["per_layer"]:
-        if m["name"].startswith("dots_"):
-            assert m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
-            assert os.path.exists(os.path.join(HERE, "layers",
-                                               m["name"] + ".json"))
+    # sixteen at PR 41; since PR 56 the seven a second cell could read are
+    # shared entries with no model's prefix and the whole step's share is
+    # the shared entry's, its reader named by `trace.roofline`
+    dots = [m for m in BENCH["per_layer"] if m["name"].startswith("dots_")]
+    assert [m["name"] for m in dots] == [
+        "dots_sparse_decode_roofline_pct", "dots_window_decode_roofline_pct",
+        "dots_sparse_prefill_roofline_pct",
+        "dots_window_prefill_roofline_pct", "dots_index_roofline_pct",
+        "dots_scope_gate_pct"]
+    for m in dots:
+        assert m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
+        assert os.path.exists(os.path.join(HERE, "layers",
+                                           m["name"] + ".json"))
+    assert CONFIG["trace"]["roofline"] == "dots3_roofline"
+    for name in SHARED:
+        assert CELL in next(m for m in BENCH["per_layer"]
+                            if m["name"] == name)["workloads"], name
 
 
 def test_a_program_without_the_counters_reads_nothing():
@@ -128,7 +148,7 @@ def test_a_program_without_the_counters_reads_nothing():
     facts = {"config": CONFIG, "metrics0": {}, "metrics1": {}, "trace": None,
              "peaks": None, "trace_capture": None, "client": {}}
     for name, (fn, args) in cells.resolve(CELL)["per_layer"].items():
-        if name.startswith("dots_"):
+        if name.startswith("dots_") or name in SHARED:
             assert fn(facts, **args) is None, name
 
 

@@ -122,26 +122,37 @@ def test_the_rooflines_widths_are_the_issues_counts():
 
 def test_the_cell_resolves_to_its_readers():
     cell = cells.resolve(CELL)
-    assert len(cell["per_layer"]) == 38
+    assert len(cell["per_layer"]) >= 45  # 38 at PR 43, 45 since PR 52
     assert cell["end_to_end"] == ["out_tok_s", "setup_s"]
     shares = [args["scopes"] for fn, args in cell["per_layer"].values()
               if fn is scopes.share_pct]
     assert shares.count(None) == 1
     given = [p for s in shares if s is not None for p in s]
     assert sorted(given) == sorted(CONFIG["trace"]["known_scopes"])
+    # ten at PR 43; since PR 56 the whole step's share is the shared
+    # entry's (its reader named by `trace.roofline`) and `moe.shared`'s
+    # share is `scope_shared_pct`
     kx = [m for m in BENCH["per_layer"] if m["name"].startswith("kx_")]
-    assert len(kx) == 10
+    assert [m["name"] for m in kx] == [
+        "kx_full_decode_roofline_pct", "kx_window_decode_roofline_pct",
+        "kx_full_prefill_roofline_pct", "kx_window_prefill_roofline_pct",
+        "kx_experts_roofline_pct", "kx_window_roll_ms_per_step",
+        "kx_table_flushes_per_step", "kx_full_cache_read_share"]
     for m in kx:
         assert m["workloads"] == [CELL] and m["moves"] == "out_tok_s"
         assert os.path.exists(os.path.join(HERE, "layers",
                                            m["name"] + ".json"))
-    # it JOINS the two dots_* entries whose readers read counters it
-    # writes under the same kind names, and not the expert roofline that
-    # divides by every layer
+    assert CONFIG["trace"]["roofline"] == "exaone_roofline"
+    # it JOINS the two entries whose readers read counters any pool with a
+    # window kind writes, and not the expert roofline that divides by every
+    # layer
     for m in BENCH["per_layer"]:
-        if m["name"] in ("dots_window_blocks_share",
-                         "dots_window_blocks_freed_per_step"):
-            assert m["workloads"] == ["dots3-longnote-saturated", CELL]
+        if m["name"] in ("srv_window_blocks_share",
+                         "srv_window_blocks_freed_per_step"):
+            assert m["workloads"][:2] == ["dots3-longnote-saturated", CELL]
+        if m["name"] in ("srv_decode_step_roofline_pct",
+                         "scope_shared_pct"):
+            assert CELL in m["workloads"]
         if m["name"] == "moe_experts_roofline_pct":
             assert CELL not in m["workloads"]
 
@@ -153,7 +164,9 @@ def test_a_program_without_the_counters_reads_nothing():
     facts = {"config": CONFIG, "metrics0": {}, "metrics1": {}, "trace": None,
              "peaks": None, "trace_capture": None, "client": {}}
     for name, (fn, args) in cells.resolve(CELL)["per_layer"].items():
-        if name.startswith("kx_"):
+        if name.startswith("kx_") or name in (
+                "srv_decode_step_roofline_pct", "srv_window_blocks_share",
+                "srv_window_blocks_freed_per_step"):
             assert fn(facts, **args) is None, name
 
 

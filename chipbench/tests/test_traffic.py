@@ -15,7 +15,15 @@ def load(name):
         return json.load(f)
 
 
-@pytest.mark.parametrize("name", ["chat-backlog", "prefill-backlog"])
+def _files(*kinds):
+    """The traffic files of the given kinds, by what `traffic/` holds: a
+    mix a later PR adds is a case of every test over its kind."""
+    names = sorted(n[:-len(".json")] for n in os.listdir(TRAFFIC)
+                   if n.endswith(".json"))
+    return [n for n in names if load(n)["kind"] in kinds]
+
+
+@pytest.mark.parametrize("name", _files("backlog"))
 def test_same_seed_same_list_and_another_seed_differs(name):
     t = load(name)
     a = tg.make_requests(t, BIG_SEED, 50257)
@@ -31,8 +39,7 @@ def test_same_seed_same_list_and_another_seed_differs(name):
         or a[0].prompt_len != c[0].prompt_len
 
 
-@pytest.mark.parametrize("name", ["chat-backlog", "prefill-backlog",
-                                  "chat-steady"])
+@pytest.mark.parametrize("name", _files("backlog", "open_loop"))
 def test_every_block_holds_one_draw_per_stratum(name):
     t = load(name)
     k = t["strata"]
@@ -59,14 +66,14 @@ def test_every_seed_offers_the_same_sizes_block_by_block():
             assert sorted(x[col] for x in a[sl]) == sorted(x[col] for x in b[sl])
 
 
-def test_lengths_respect_the_files_limits():
-    for name in ("chat-backlog", "prefill-backlog", "chat-steady"):
-        t = load(name)
-        lo_p, hi_p = t["prompt_len"]["knots"][0][1], t["prompt_len"]["knots"][-1][1]
-        lo_o, hi_o = t["output_len"]["knots"][0][1], t["output_len"]["knots"][-1][1]
-        for p, o in tg.request_lengths(t, 3, 640):
-            assert lo_p <= p <= hi_p and lo_o <= o <= hi_o
-            assert p + o <= t["max_total"]
+@pytest.mark.parametrize("name", _files("backlog", "open_loop"))
+def test_lengths_respect_the_files_limits(name):
+    t = load(name)
+    lo_p, hi_p = t["prompt_len"]["knots"][0][1], t["prompt_len"]["knots"][-1][1]
+    lo_o, hi_o = t["output_len"]["knots"][0][1], t["output_len"]["knots"][-1][1]
+    for p, o in tg.request_lengths(t, 3, 640):
+        assert lo_p <= p <= hi_p and lo_o <= o <= hi_o
+        assert p + o <= t["max_total"]
 
 
 def test_chat_median_prompt_is_near_128():
@@ -142,13 +149,7 @@ def test_batches_from_the_seed():
     assert 1 <= a[0].min() and a[0].max() < 50257
 
 
-def _backlog_files():
-    return sorted(n[:-len(".json")] for n in os.listdir(TRAFFIC)
-                  if n.endswith(".json") and load(n[:-len(".json")])["kind"]
-                  == "backlog")
-
-
-@pytest.mark.parametrize("name", _backlog_files())
+@pytest.mark.parametrize("name", _files("backlog"))
 def test_a_backlog_cannot_run_dry(name):
     """A traced run submits until the capture is on disk and a faster
     program completes more: the list has to outlast both (the chat cell
@@ -158,7 +159,7 @@ def test_a_backlog_cannot_run_dry(name):
     assert t["requests_why"]
 
 
-@pytest.mark.parametrize("name", _backlog_files())
+@pytest.mark.parametrize("name", _files("backlog"))
 def test_a_longer_backlog_keeps_its_first_requests(name):
     """Sizes come from `layout_seed` block by block and ids from (seed,
     index): raising `requests` appends, it does not change what a run
