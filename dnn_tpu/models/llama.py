@@ -121,6 +121,49 @@ class MupConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class LightningConfig:
+    """What `LlamaConfig.lightning` holds: the widths of a model's "linear"
+    layers (models/lightning.py) — linear attention with a FIXED decay a
+    head, `n_head` heads of `head_dim`, q and k normed a head and rotated
+    (`rope_theta`, the whole head). `chunk`: the positions a closed-form
+    chunk of the chunked rule."""
+    n_head: int = 32
+    head_dim: int = 128
+    chunk: int = 256
+    rope_theta: float = 10000.0
+
+    @property
+    def width(self):
+        return self.n_head * self.head_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockSelectConfig:
+    """What `LlamaConfig.block_select` holds: the "full" layers read only
+    the BLOCKS of `block` positions that a score over mean-pooled keys
+    selects (models/block_select.py). A pooled key is the mean of `kernel`
+    = 2 x `stride` consecutive keys of a KV head, one every `stride`
+    positions; a query reads the `window` positions' blocks up to its own
+    always, and of the blocks before them the `topk` of largest score, the
+    first `init_blocks` forced among them."""
+    block: int = 64
+    topk: int = 64
+    window: int = 2048
+    init_blocks: int = 1
+    kernel: int = 32
+    stride: int = 16
+
+    @property
+    def local_blocks(self):
+        return self.window // self.block
+
+    @property
+    def rows(self):
+        """Pooled keys a block."""
+        return self.block // self.stride
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     block_size: int = 2048
     vocab_size: int = 32000
@@ -245,8 +288,46 @@ class LlamaConfig:
     mamba: Optional[Mamba2Config] = None
     # muP multipliers (Falcon-H1; None = all 1, nothing traced)
     mup: Optional[MupConfig] = None
+    # ---- layers of KINDS in a DENSE model (MiniCPM-SALA; `MixtralConfig`
+    # has the same three for its own hybrids): `layer_types[i]` is "full"
+    # (softmax attention over K and V, `kv_full`; `attn_gate`: its output
+    # times sigmoid(h W_gate), element-wise; `block_select`: it reads the
+    # blocks a score over mean-pooled keys selects) or "linear"
+    # (`lightning`: a fixed-decay linear-attention state a slot)
+    layer_types: Optional[tuple] = None
+    kv_full: Optional["KvKind"] = None
+    attn_gate: bool = False
+    lightning: Optional[LightningConfig] = None
+    block_select: Optional[BlockSelectConfig] = None
 
     def __post_init__(self):
+        if self.lightning is not None and (
+                self.layer_types is None
+                or len(self.layer_types) != self.n_layer
+                or set(self.layer_types) - {"full", "linear"}
+                or "full" not in self.layer_types
+                or (self.kv_full or KvKind()).window is not None
+                or self.retention is not None or self.mamba is not None
+                or self.sliding_window is not None or self.alt_window
+                or self.attn_softcap is not None or self.parallel_block
+                or self.index_topk is not None or self.post_norms
+                or not self.pre_norm):
+            raise ValueError(
+                "lightning names the \"linear\" layers of layer_types and "
+                "kv_full (which has no window) the \"full\" ones, of which "
+                "there is at least one, in the sequential pre-norm block: no "
+                "window, softcap, indexer, retention or state-space mixer "
+                "goes with it")
+        if self.block_select is not None:
+            m = self.block_select
+            if (self.lightning is None or m.kernel != 2 * m.stride
+                    or m.block % m.stride or m.window % m.block
+                    or not 0 <= m.init_blocks <= m.topk):
+                raise ValueError(
+                    "block_select goes with lightning's \"full\" layers; a "
+                    "pooled key spans two strides, a stride divides a block "
+                    "and a block the window, and the forced blocks are among "
+                    "the topk")
         if self.mamba is not None and (
                 self.retention is not None or self.sliding_window is not None
                 or self.alt_window or self.attn_softcap is not None
@@ -546,9 +627,63 @@ def kv_kinds(cfg):
         # models/mamba2.py: ONE kind, whose every layer keeps K and V
         # under tables AND a state a slot
         return {"full": KvKind()}
+    if getattr(cfg, "lightning", None) is not None:
+        # models/lightning.py: as kda's — K and V in the "full" layers, a
+        # state alone in the "linear" ones
+        return {"full": cfg.kv_full or KvKind()}
     if getattr(cfg, "kv_window", None) is None:
         return None
     return {"full": cfg.kv_full or KvKind(), "window": cfg.kv_window}
+
+
+# MiniCPM-SALA (openbmb/MiniCPM-SALA config.json, `model_type` minicpm_sala):
+# a DENSE model of 32 layers of two kinds — `minicpm4` ("full": GQA 16:1
+# softmax attention, q/k normed a head and NOT rotated, an element-wise output
+# gate, reading only the 64-position blocks a score over mean-pooled keys
+# selects beside a local window) and `lightning-attn` ("linear": 32 heads of
+# 128 with a fixed decay a head, q and k normed and rotated) —, SwiGLU 16384,
+# untied head, MiniCPM's three muP scalars: `scale_emb` 12 on the embedding,
+# `scale_depth` / sqrt(32) on BOTH residual branches, hidden / `dim_model_base`
+# = 16 dividing the head's input. The equations are `assumed` in
+# chipbench/configs/minicpm-sala-pp8-1chip.json. Never instantiated whole.
+_SALA_TYPES = tuple(
+    "full" if i in (0, 9, 16, 17, 22, 29, 30, 31) else "linear"
+    for i in range(32))
+_SALA_R = 1.4 / 32 ** 0.5
+PRESETS["minicpm-sala"] = LlamaConfig(
+    block_size=524288, vocab_size=73448, n_layer=32, n_head=32, n_kv_head=2,
+    n_embd=4096, d_ff=16384, head_dim_override=128, rope_theta=10000.0,
+    rms_eps=1e-6, qk_norm=True,
+    layer_types=_SALA_TYPES, kv_full=KvKind(window=None, rope=False),
+    attn_gate=True, lightning=LightningConfig(), block_select=BlockSelectConfig(),
+    mup=MupConfig(embedding=12.0, lm_head=1 / 16, attention_out=_SALA_R,
+                  mlp=(1.0, _SALA_R)))
+# the benchmark's cut (chipbench/configs/minicpm-sala-pp8-1chip.json): one of
+# eight pipeline stages of four whole layers, layers 0-3 (one period: a
+# `minicpm4` layer and three `lightning-attn`), with `wte` and the head on it;
+# r stays scale_depth / sqrt(32), the PUBLISHED depth's
+PRESETS["minicpm-sala-pp8-1chip"] = dataclasses.replace(
+    PRESETS["minicpm-sala"], n_layer=4, layer_types=_SALA_TYPES[:4])
+# tiny MiniCPM-SALA for the CPU tests: two KV heads of two query
+# heads each (so a selection a KV group differs), blocks of 8 with pooled keys
+# of 4 every 2 positions, a window of 2 blocks and the 2 blocks of largest
+# score (block 0 forced): a 200-position context holds 25 blocks and drops 21;
+# a chunk of 8 that a 16-token prefill chunk holds twice; the three scalars
+# different from 1 and from each other; q/k norm gains of 1.4 x (1 + 0.1 z),
+# so that at 16-wide heads a selection is far from a coin toss (the served
+# preset keeps gains of exactly 1: at 1.4 the float32 reference agrees with
+# itself in bfloat16 on 79 % of tokens, at 1.0 on 91 % — PERF.md, PR 58)
+PRESETS["minicpm-sala-test"] = LlamaConfig(
+    block_size=256, vocab_size=256, n_layer=4, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=128, head_dim_override=16, rope_theta=10000.0,
+    rms_eps=1e-6, qk_norm=True, qk_norm_init=1.4,
+    layer_types=("full", "linear", "linear", "linear"),
+    kv_full=KvKind(window=None, rope=False), attn_gate=True,
+    lightning=LightningConfig(n_head=4, head_dim=16, chunk=8),
+    block_select=BlockSelectConfig(block=8, topk=2, window=16, init_blocks=1,
+                                   kernel=4, stride=2),
+    mup=MupConfig(embedding=2.5, lm_head=0.35, attention_out=0.6,
+                  mlp=(1.0, 0.6)))
 
 
 # --------------------------------------------------------------------------
@@ -635,8 +770,13 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
 
         blk["attn"] = mla.init_attn(jax.random.fold_in(key, 17), cfg, dtype,
                                     mla.kinds(cfg)[kind or "full"])
-    if getattr(cfg, "kda", None) is not None:
-        if kind == "linear":
+    if getattr(cfg, "kda", None) is not None or cfg.lightning is not None:
+        if kind == "linear" and cfg.lightning is not None:
+            from dnn_tpu.models import lightning
+
+            blk["attn"] = lightning.init_mixer(jax.random.fold_in(key, 37),
+                                               cfg, dtype)
+        elif kind == "linear":
             from dnn_tpu.models import kda
 
             blk["attn"] = kda.init_mixer(jax.random.fold_in(key, 19), cfg,
@@ -927,6 +1067,10 @@ def _attn_out_residual(bp, x, o, cfg: LlamaConfig):
     output in x's dtype."""
     if cfg.post_norms:
         o = _norm(bp["post_ln_1"], o, cfg)
+    if cfg.mup is not None and cfg.mamba is None:
+        # a state-space mixer's block scales its two mixers as it adds them
+        # (models/mamba2.py `mixers_sum`)
+        o = _mup_scaled(o, cfg, "attention_out")
     return x + o.astype(x.dtype)
 
 
@@ -1015,7 +1159,16 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
     kind where the config's layers are of several (models/mla.py)."""
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
-    if attn_fn is None and kind == "linear":
+    if attn_fn is None and cfg.lightning is not None:
+        from dnn_tpu.models import block_select, lightning
+
+        if kind == "linear":
+            fn = lambda bp2, h: lightning.dense_mixer(  # noqa: E731
+                bp2["attn"], h, cfg=cfg, compute_dtype=compute_dtype)
+        else:
+            fn = lambda bp2, h: block_select.dense_attn(  # noqa: E731
+                bp2, h, cfg=cfg, compute_dtype=compute_dtype)
+    elif attn_fn is None and kind == "linear":
         from dnn_tpu.models import kda
 
         fn = lambda bp2, h: kda.dense_mixer(  # noqa: E731
@@ -1764,6 +1917,8 @@ def family_rows(cfg, **kw):
         from dnn_tpu.models.retention import RetentionRows as rows
     elif cfg.mamba is not None:
         from dnn_tpu.models.mamba2 import HybridRows as rows
+    elif cfg.lightning is not None:
+        from dnn_tpu.models.lightning import LightningKindRows as rows
     elif kv_kinds(cfg) is not None:
         rows = LlamaKindRows
     else:
@@ -2454,7 +2609,9 @@ def _register(name: str, cfg: LlamaConfig):
         apply=make_apply(cfg),
         partition=make_partition(cfg),
         example_input=gpt.make_example_input(cfg),
-        supported_parts=tuple(range(1, cfg.n_layer + 1)),
+        # layers of kinds that interleave do not cut into equal stages
+        supported_parts=(1,) if cfg.layer_types is not None
+        else tuple(range(1, cfg.n_layer + 1)),
         convert_state_dict=convert,
         config=cfg,
         extras={

@@ -47,13 +47,15 @@ def reference_chunk_index_scores(qi, w, ki):
 
 
 def reference_sparse_prefill_attention(q, k, v, sel):
-    """q (KV, G, T, D), k/v (KV, S, D), sel (T, S) bool -> (KV, G, T, D)
-    f32: softmax(q . k / sqrt(D)) over the selected columns, times v."""
+    """q (KV, G, T, D), k/v (KV, S, D), sel (T, S) bool — or (KV, T, S), a
+    set a KV head — -> (KV, G, T, D) f32: softmax(q . k / sqrt(D)) over the
+    selected columns, times v."""
     d = q.shape[-1]
     s = jnp.einsum("kgtd,ksd->kgts", q.astype(jnp.float32),
                    k.astype(jnp.float32),
                    preferred_element_type=jnp.float32) / jnp.sqrt(d)
-    s = jnp.where(sel[None, None], s, _NEG_BIG)
+    s = jnp.where(sel[None, None] if sel.ndim == 2 else sel[:, None], s,
+                  _NEG_BIG)
     return jnp.einsum("kgts,ksd->kgtd", jax.nn.softmax(s, axis=-1),
                       v.astype(jnp.float32),
                       preferred_element_type=jnp.float32)
@@ -172,6 +174,8 @@ def _sparse_prefill_kernel(start_ref, q_ref, k_ref, v_ref, sel_ref, o_ref,
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (rows, bs)
         chosen = sel_ref[...].astype(jnp.int32) != 0  # (bq, bs)
+        if chosen.ndim == 3:  # a set a KV head: this head's
+            chosen = chosen[0]
         keep = jnp.broadcast_to(chosen[None], (g, bq, bs)).reshape(rows, bs)
         s = jnp.where(keep, s, _NEG_BIG)
         m_prev = m_scr[:, :1]
@@ -199,7 +203,8 @@ def sparse_prefill_attention(q, k, v, sel, start, *, block_q=128,
     """Attention of a chunk under each query's set (module docstring): q
     (KV, G, T, D) the queries at [start, start + T), G query heads a KV
     head; k/v (KV, S, D) the row; sel (T, S) bool, true where query t
-    reads column s — within s <= start + t, and never empty. Returns
+    reads column s — within s <= start + t, and never empty —, or (KV, T, S)
+    where the set differs by KV head (models/block_select.py). Returns
     (KV, G, T, D) float32. The kernel on the TPU (`interpret=True`:
     interpreted); the plain form elsewhere and for shapes that do not
     tile."""
@@ -227,7 +232,10 @@ def sparse_prefill_attention(q, k, v, sel, start, *, block_q=128,
         grid=(kv, t // bq, s_len // bs),
         in_specs=[qspec, cspec, cspec,
                   pl.BlockSpec((bq, bs),
-                               lambda h, i, j, st: (i, col(i, j, st)))],
+                               lambda h, i, j, st: (i, col(i, j, st)))
+                  if sel.ndim == 2 else pl.BlockSpec(
+                      (1, bq, bs),
+                      lambda h, i, j, st: (h, i, col(i, j, st)))],
         out_specs=qspec,
         scratch_shapes=[
             pltpu.VMEM((g * bq, 128), jnp.float32),  # running row max

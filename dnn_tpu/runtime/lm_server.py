@@ -1408,6 +1408,12 @@ class LMServer:
                 # which a slot holds whole (no position axis)
                 comps["kv_cache"]["kinds"] = {
                     kind: {"leaves": sorted(k["leaves"]),
+                           # rows that are strides, not positions: name ->
+                           # the positions a row
+                           **({"strided_leaves": {
+                               n: v[2] for n, v in
+                               k["strided_leaves"].items()}}
+                              if k.get("strided_leaves") else {}),
                            "slot_leaves": sorted(k.get("slot_leaves", ())),
                            "tables": k["tables"]}
                     for kind, k in kinds.items()}

@@ -64,6 +64,21 @@ back and draws the next). Decode over such a leaf gathers the window's
 blocks, not the row (`write_attend_latent_rows(window=)`; `_window_rows`
 for K and V leaves).
 
+**Leaves whose rows are STRIDES.** A kind may also page leaves that hold one
+row every `stride` positions (models/block_select.py: the mean-pooled keys
+"kc" a KV head, one every 16 positions): `strided_leaves`, name -> (heads,
+width, stride). Such a leaf has `block_len / stride` rows a block and lives
+under the kind's `tables` like its other leaves — installed, gathered and
+freed with the block —
+
+    kc     (L_full, n_blocks, KV, block_len / stride, Dp)   under "tables"
+
+but is WRITTEN on the one slot-step in `stride` that completes a row
+(`PagedKV.write_pooled_rows`: the mean of the last 2 x stride keys the pool
+holds), and its transient row has row_len / stride rows. A slot's read may
+then be a LIST of table entries a KV head and not the live prefix of its
+table (`PagedKV.write_attend_block_rows`).
+
 **Leaves with NO position axis.** A layer that keeps a STATE — a matrix a
 head whatever the length, and the last rows of a short convolution — says so
 in its kind's `slot_leaves`: name -> (the shape a slot a layer, dtype or None
@@ -312,6 +327,14 @@ def init_paged_cache(cfg, slots: int, max_len: int, *, n_blocks,
                 out[name] = jnp.zeros(
                     (k["layers"], n_blocks[k["tables"]], heads, block_len,
                      lane_padded(width)), dtype)
+            for name, (heads, width, stride) in k.get("strided_leaves",
+                                                      {}).items():
+                if block_len % stride:
+                    raise ValueError(f"block_len {block_len} must tile the "
+                                     f"leaf {name}'s stride {stride}")
+                out[name] = jnp.zeros(
+                    (k["layers"], n_blocks[k["tables"]], heads,
+                     block_len // stride, lane_padded(width)), dtype)
             for name, (shape, leaf_dtype) in k.get("slot_leaves",
                                                    {}).items():
                 # no position axis, no blocks: a slot's own, a layer
@@ -383,7 +406,8 @@ class PagedKV:
         # leaf -> the name of its block tables, where the leaves are by
         # layer kind (module docstring); every leaf under "tables" else
         self.leaf_tables = {name: k["tables"] for k in (kinds or {}).values()
-                            for name in k["leaves"]}
+                            for name in (*k["leaves"],
+                                         *k.get("strided_leaves", ()))}
         # leaves with no position axis (a state kind's): not installed by
         # blocks — the finish program writes them at the slot
         self.slot_leaves = frozenset(
@@ -786,6 +810,93 @@ class PagedKV:
         # same output-dtype recipe as attend_rows
         return (y if "ks" in c else y.astype(c["v"].dtype)), c
 
+    def write_pooled_rows(self, c, k, pos, write_gate, *, stride, leaf="kc",
+                          source="k", layer=None):
+        """The strided leaf `leaf`'s row that THIS step completes: where pos
+        % stride == stride - 1, the mean of the last 2 x stride rows of
+        `source` up to `pos` — the 2 x stride - 1 before `pos` as the pool
+        holds them (they lie in the slot's last two blocks) and this step's
+        own k (B, Hk, 1, D), which need not be in the pool yet — goes into
+        row (pos % bp) // stride of the block that holds `pos`; every other
+        slot, and a gated-off one, writes junk block 0."""
+        bp = self.block_len
+        tab = c["tables"] if layer is None else c["tables"][layer]  # (B, nb)
+        bt = pos // bp
+        two = jnp.stack([jnp.maximum(bt - 1, 0), bt], axis=-1)
+        ids = jnp.take_along_axis(tab, two, axis=1)  # (B, 2) physical
+        pool = c[source]
+        kk = pool[ids] if layer is None else pool[layer, ids]
+        kk = jnp.moveaxis(kk, 1, 2)  # (B, Hk, 2, bp, Dp)
+        kk = kk.reshape(*kk.shape[:2], 2 * bp, kk.shape[-1])
+        idx = (bp + pos % bp - 2 * stride + 1)[:, None] \
+            + jnp.arange(2 * stride - 1)[None, :]
+        win = jnp.take_along_axis(kk, idx[:, None, :, None], axis=2)
+        own = _pad_lanes(k.astype(pool.dtype), pool.shape[-1])
+        row = (win.astype(jnp.float32).sum(2)
+               + own[:, :, 0].astype(jnp.float32)) / (2 * stride)
+        gate = write_gate & (pos % stride == stride - 1)
+        blk = jnp.where(gate, ids[:, 1], 0)
+        at = (blk, slice(None), jnp.where(gate, (pos % bp) // stride, 0))
+        return {**c, leaf: c[leaf].at[
+            at if layer is None else (layer,) + at].set(
+                row.astype(c[leaf].dtype))}
+
+    def pooled_view(self, c, leaf, width, layer=None):
+        """Every slot's rows of a strided leaf in logical order, (B, Hk,
+        nb_max x rows a block, width): `gather_view`'s gather, under the
+        caller's scope (the rows are what its scores read)."""
+        tab = c["tables"] if layer is None else c["tables"][layer]
+        b, nb = tab.shape
+        ids = tab.reshape(-1)
+        g = (c[leaf][ids] if layer is None else c[leaf][layer, ids])[
+            ..., :width]  # (B * nb, Hk, rows, width)
+        h, rows = g.shape[1:3]
+        return jnp.moveaxis(g.reshape(b, nb, h, rows, width), 1, 2).reshape(
+            b, h, nb * rows, width)
+
+    def block_form(self, c, layer=0):
+        """Which form `write_attend_block_rows` takes against cache `c`."""
+        return "list_kernel" if (
+            layer is not None and self._kernel_on(c)) else "list_gather"
+
+    def write_attend_block_rows(self, q, c, k, v, blocks, count, pos,
+                                write_gate, layer=None):
+        """The decode step of a selection by blocks: this step's k / v (B,
+        Hk, 1, D) in at `pos` (gated and junk-routed as `write_rows`), then a
+        read of a LIST of blocks a KV head: q (B, Hk, R, D) the rows of slot
+        b's KV head g; `blocks` (B, Hk, n) int32 logical block numbers in
+        ascending order of which the first `count` (B, Hk) are read — the
+        last of them holds `pos` (B,), and is read up to it —; no block
+        outside the list is touched -> (y (B, Hk, R, D), c). With the kernel
+        on (ops/pallas/block_list_attention.py) each listed block is copied
+        from the pool where it lies and the rows are placed by the kernel,
+        which hands the pools back through aliased outputs; else the rows
+        are scattered, the listed blocks gathered and attended by two
+        einsums (its plain form)."""
+        from dnn_tpu.ops.pallas.block_list_attention import (
+            block_list_attention,
+            reference_block_list_attention,
+        )
+
+        tab = c["tables"] if layer is None else c["tables"][layer]
+        ids = jnp.take_along_axis(tab[:, None, :], blocks, axis=2)
+        if layer is not None and self._kernel_on(c):
+            with jax.named_scope("kv_pool.write"):
+                rows = self._rows(c, k, v)
+            y, kp, vp = block_list_attention(
+                q, c["k"], c["v"], ids, count, pos, layer=layer,
+                new=(rows["k"], rows["v"], write_gate),
+                interpret=True if self.use_kernel == "interpret" else None)
+            return y.astype(c["v"].dtype), {**c, "k": kp, "v": vp}
+        c = self.write_rows(c, k, v, pos, write_gate, layer=layer)
+        with jax.named_scope("attn.block_decode"):
+            whole = layer is not None
+            y = reference_block_list_attention(
+                q, c["k"] if whole else c["k"][None],
+                c["v"] if whole else c["v"][None], ids, count, pos,
+                layer=layer if whole else 0)
+        return y.astype(c["v"].dtype), c
+
     def at_layer(self, layer):
         """This codec bound to one layer of the WHOLE pool: the same
         write_attend_rows a block calls on a per-layer cache view, but
@@ -806,7 +917,6 @@ class PagedKV:
         prefix blocks (another request's live data!) — are routed to the
         reserved junk block 0, whose content is never attended live (the
         per-row position mask), so scribbling it is harmless."""
-        bp = self.block_len
         out = {kk: cache[kk] for kk in cache
                if is_tables(kk) or kk in self.slot_leaves}
         for kk in cache:
@@ -819,6 +929,7 @@ class PagedKV:
             r = row[kk][:, 0]  # (L, H, row_len[, D]) — scales have no D
             l_, h, rl = r.shape[:3]
             rest = r.shape[3:]
+            bp = cache[kk].shape[3]  # a strided leaf's rows a block are fewer
             blocks = r.reshape(l_, h, rl // bp, bp, *rest)[:, :, :nb_max]
             blocks = jnp.moveaxis(blocks, 2, 1)  # (L, nb_max, H, bp[, D])
             if rest:  # K/V rows go in at the pool's lane-padded width
@@ -842,6 +953,21 @@ class _PagedLayer:
 
     def decode_form(self, c, window=None):
         return self.codec.decode_form(c, self.layer, window)
+
+    def write_pooled_rows(self, c, k, pos, write_gate, **kw):
+        return self.codec.write_pooled_rows(c, k, pos, write_gate,
+                                            layer=self.layer, **kw)
+
+    def pooled_view(self, c, leaf, width):
+        return self.codec.pooled_view(c, leaf, width, layer=self.layer)
+
+    def block_form(self, c):
+        return self.codec.block_form(c, self.layer)
+
+    def write_attend_block_rows(self, q, c, k, v, blocks, count, pos,
+                                write_gate):
+        return self.codec.write_attend_block_rows(
+            q, c, k, v, blocks, count, pos, write_gate, layer=self.layer)
 
     def write_index_rows(self, c, ik, pos, write_gate):
         return self.codec.write_index_rows(c, ik, pos, write_gate,

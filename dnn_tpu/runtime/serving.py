@@ -380,6 +380,17 @@ class ContinuousBatcher:
         # (checked below, once paging is decided), and what assumes K and
         # V alone refuses it here, by name
         self._index_topk = getattr(self.family, "index_topk", None)
+        # a selection whose unit is a BLOCK of the pool (models/
+        # block_select.py): the family says what a query reads of its
+        # context, in positions, and which `block_len` its pool needs
+        self._select_counts = getattr(self.family, "select_counts", None)
+        need_bp = getattr(self.family, "required_block_len", None)
+        if need_bp is not None and block_len != need_bp and kv != "dense" \
+                and (paged_blocks or kv in ("paged", "auto")):
+            raise ValueError(
+                f"this model selects what it reads by blocks of {need_bp} "
+                f"positions: its paged pool needs block_len {need_bp}, not "
+                f"{block_len}")
         self._latent = bool(getattr(self.family, "latent_attention", False))
         # leaves BY LAYER KIND (models/mla.py: layers that differ in what
         # they keep; paged_kvcache's module docstring), or None
@@ -419,7 +430,8 @@ class ContinuousBatcher:
         if getattr(self.family, "requires_paged", False) or self._slot_leaves:
             leaves = "/".join(
                 n for k in self._cache_kinds.values()
-                for n in (*k["leaves"], *k.get("slot_leaves", ()))
+                for n in (*k["leaves"], *k.get("strided_leaves", ()),
+                          *k.get("slot_leaves", ()))
             ) if self._cache_kinds else "/".join(self.family.cache_leaves)
             refused = None
             if nothing_to_page and decode_buckets:
@@ -677,6 +689,12 @@ class ContinuousBatcher:
                 heads * width * self.cache[n].dtype.itemsize
                 for n, (heads, width) in (full["leaves"] if full
                                           else {}).items())
+            # a strided leaf's rows, read whole by a step that selects: a
+            # row every `stride` positions
+            self._strided_position_bytes = sum(
+                heads * width * self.cache[n].dtype.itemsize / stride
+                for n, (heads, width, stride) in (
+                    full.get("strided_leaves", {}) if full else {}).items())
         self.pos = jnp.zeros((slots,), jnp.int32)      # next write position
         self.tok = jnp.zeros((slots,), jnp.int32)      # last sampled token
         self.active = jnp.zeros((slots,), bool)
@@ -2229,6 +2247,14 @@ class ContinuousBatcher:
         res = self._prefill_chunk(*args)
         if self._moe_stats:
             self._moe_note("prefill", res[2])
+        if self._select_counts is not None and self.step_clock is not None:
+            # a selection by blocks: the positions the chunk's queries read
+            # (their local blocks and the chosen ones) of those they could
+            start, t = int(args[3]), int(args[2].shape[-1])
+            self.step_clock.note_dsa(
+                "prefill", self._n_index_layers,
+                self._n_index_layers * (t * start + t * (t + 1) // 2),
+                self._n_index_layers * self._select_counts(start + 1, t))
         if self._index_topk and self.step_clock is not None:
             # what the chunk's indexers scored and selected, from its
             # start and length alone (pad rows too: the device scores
@@ -3062,9 +3088,12 @@ class ContinuousBatcher:
                 if topk:
                     # the step's query stood at n - 2: n - 1 candidates
                     picked += min(n - 1, topk)
+                elif self._select_counts is not None:
+                    picked += self._select_counts(n - 1)
                 for i, (_, _, w) in enumerate(wins):
                     in_window[i] += min(n - 1, w)
-        if topk and self.step_clock is not None:
+        if (topk or self._select_counts is not None) \
+                and self.step_clock is not None:
             self.step_clock.note_dsa(
                 "decode", self._n_index_layers,
                 self._n_index_layers * (live - n_act),
@@ -3075,18 +3104,25 @@ class ContinuousBatcher:
                 self.step_clock.note_mla_kind(
                     "decode", "full", self._n_index_layers * picked)
             elif self._kv_kinds:
-                # each live slot's query stood at n - 2 and read n - 1
+                # each live slot's query stood at n - 2 and read n - 1 (of
+                # which, under a selection by blocks, the chosen blocks')
                 self.step_clock.note_mla_kind(
-                    "decode", "full",
-                    self._n_index_layers * (live - n_act), series=series)
+                    "decode", "full", self._n_index_layers * (
+                        live - n_act if self._select_counts is None
+                        else picked), series=series)
             if self._slot_leaves:
                 # a step reads AND writes every slot's state, live or not
-                # (`_state_step_bytes`), beside the live K and V it reads
+                # (`_state_step_bytes`), beside the live K and V it reads —
+                # under a selection by blocks the positions chosen, and
+                # every live row of the strided leaves it scores
+                read = self._kv_position_bytes * (
+                    live - n_act if self._select_counts is None else picked)
+                if self._select_counts is not None:
+                    read += self._strided_position_bytes * (live - n_act)
                 self.step_clock.note_state(
                     bytes_read=self._state_step_bytes,
                     bytes_written=self._state_step_bytes,
-                    kv_bytes_read=self._kv_position_bytes
-                    * self._n_index_layers * (live - n_act))
+                    kv_bytes_read=int(read) * self._n_index_layers)
             for (kind, n_l, _), n in zip(wins, in_window):
                 self.step_clock.note_mla_kind("decode", kind, n_l * n,
                                               series=series)

@@ -827,7 +827,21 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
         state = {"prefill": "chunked_jnp", "decode": "step_jnp"}
         kinds, leaves = comps["attention"]["kinds"], sorted(
             comps["kv_cache"]["bytes_by_leaf"])
-        if "linear" in kinds:  # a state kind BESIDE a kind of K and V
+        if "linear" in kinds and "kc" in leaves:
+            # a state kind of ONE leaf beside a kind of K and V that
+            # selects by blocks: a strided leaf of pooled keys, a read of a
+            # list of blocks (on the chip "masked_kernel" / "list_kernel"
+            # and "step_kernel")
+            assert kinds == {"full": {"prefill": "plain",
+                                      "decode": "list_gather"},
+                             "linear": state}
+            assert leaves == ["k", "kc", "state", "v"]
+            assert comps["kv_cache"]["kinds"] == {
+                "full": {"leaves": ["k", "v"], "strided_leaves": {"kc": 2},
+                         "slot_leaves": [], "tables": "tables"},
+                "linear": {"leaves": [], "slot_leaves": ["state"],
+                           "tables": None}}
+        elif "linear" in kinds:  # a state kind BESIDE a kind of K and V
             assert kinds == {"full": {"prefill": "plain",
                                       "decode": "gather_einsum"},
                              "linear": state}

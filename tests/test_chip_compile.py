@@ -1027,3 +1027,125 @@ def test_falcon_h1_chunk_program_fits_beside_the_pool(falcon_h1_programs):
     assert _pool_extent_ops(chunk, pool) == []
     assert "tpu_custom_call" in chunk.as_text()  # the prefill kernel
     assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 28
+
+
+# ----------------------------------------------------------------------
+# ISSUE 58 — a strided leaf and a read of a LIST of blocks beside a state
+# kind of one leaf (MiniCPM-SALA)
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def sala_programs(chip):
+    """MiniCPM-SALA's step programs at its published widths and the cell's
+    pool (32 slots of 25 600 positions in blocks of 64, 1024-token chunks),
+    depth cut to TWO layers (one of each kind) and the vocabulary to 8192
+    rows. -> ({name: compiled}, the state leaf's extent a layer, the K
+    leaf's, the pool's bytes)."""
+    import dataclasses
+
+    from dnn_tpu.models import llama
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(
+        llama.PRESETS["minicpm-sala"], n_layer=2, vocab_size=8192,
+        layer_types=("full", "linear"))
+    prepared = _stack_and_release(llama.init(jax.random.PRNGKey(0), cfg),
+                                  cfg, BF16)
+    b = ContinuousBatcher(
+        cfg, prepared, slots=32, max_len=25600, prompt_pad=1024, kv="auto",
+        block_len=64, family=llama.family_rows(cfg, compute_dtype=BF16))
+    assert b._paged and b._allocator is not None
+    assert b.cache["state"].shape == (1, 32, 32, 128, 128)
+    assert b.cache["state"].dtype == jnp.float32
+    assert b.cache["k"].shape == (1, 32 * 400 + 1, 2, 64, 128)
+    assert b.cache["kc"].shape == (1, 32 * 400 + 1, 2, 4, 128)
+    compiled = _lower_programs(
+        chip, [(b, ("_prefill_chunk", "_prefill_finish", "_decode"))])
+    return (compiled, b.cache["state"].shape[1:], b.cache["k"].shape[1:],
+            sum(x.nbytes for x in b.cache.values()))
+
+
+def test_sala_decode_step_reads_a_list_of_blocks_in_place(sala_programs):
+    """The decode step scores the slot's pooled rows and hands the chosen
+    blocks' LIST and the step's K and V rows to ops/pallas/
+    block_list_attention.py, which places the rows itself: nothing of the K/V
+    leaves' extent but the kernel's aliased results (an XLA scatter into the
+    pool beside the kernel made the compiler copy each leaf twice a step to
+    reconcile their layouts: 4 x 0.42 GB), and the donated leaves are the
+    program's results."""
+    compiled, state, pool, pool_bytes = sala_programs
+    step = compiled["_decode"]
+    assert "block_list_attention" in step.as_text()
+    assert {o[0] for o in _pool_extent_ops(step, pool)} <= {"custom-call"}
+    # the one-token rule's kernel (ops/pallas/lin_step.py): nothing of a
+    # layer's states' extent but its aliased result
+    ops = _state_extent_ops(step, state)
+    assert ops and {o[0] for o in ops} == {"custom-call"}, ops
+    assert "lin_step" in step.as_text()
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # the gathered pooled rows and the scores, not a copy of a leaf
+    assert mem.temp_size_in_bytes < 2 ** 29
+
+
+def test_sala_finish_installs_blocks_and_state_without_a_pool_copy(
+        sala_programs):
+    compiled, state, pool, pool_bytes = sala_programs
+    finish = compiled["_prefill_finish"]
+    for ops in (_state_extent_ops(finish, state),
+                _pool_extent_ops(finish, pool)):
+        assert {o[0] for o in ops} <= {"dynamic-update-slice", "fusion",
+                                       "scatter"}, ops
+    mem = finish.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 27
+
+
+def test_sala_chunk_program_fits_beside_the_pool(sala_programs):
+    """The chunk program works on the transient row alone (K and V of 25 600
+    positions, their pooled keys, one slot's state): the prefill kernel
+    under a mask a KV group, temporaries under 1.5 GB (the pooled scores of
+    32 heads and the mask)."""
+    compiled, state, pool, _ = sala_programs
+    chunk = compiled["_prefill_chunk"]
+    assert _pool_extent_ops(chunk, pool) == []
+    assert "sparse_prefill_attention" in chunk.as_text()
+    assert chunk.memory_analysis().temp_size_in_bytes < 3 * 2 ** 29
+
+
+def test_lin_step_kernel_compiles(chip):
+    """ops/pallas/lin_step.py on the cell's whole state leaf of three layers
+    (0.6 GB), entered at a layer that rides scalar prefetch: the leaf is the
+    call's aliased result and nothing else of its size exists."""
+    from dnn_tpu.ops.pallas.lin_step import lin_step
+
+    b, h, d = 32, 32, 128
+    pool = (3, b, h, d, d)
+    shapes = ((pool, F32), ((), jnp.int32), ((h,), F32), ((b, h, d), F32),
+              ((b, h, d), F32), ((b, h, d), F32))
+    fn = functools.partial(lin_step, interpret=False)
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in shapes]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 4
+    assert mem.temp_size_in_bytes < 2 ** 22  # the rows beside the state
+
+
+def test_block_list_kernel_compiles(chip):
+    """ops/pallas/block_list_attention.py at the cell's call: 32 slots x 2
+    KV heads of 16 query rows, lists of 96 blocks of 64 positions out of a
+    pool of 12 801."""
+    from dnn_tpu.ops.pallas.block_list_attention import block_list_attention
+
+    pool = ((1, 12801, 2, 64, 128), BF16)
+    shapes = (((32, 2, 16, 128), BF16), pool, pool, ((32, 2, 96), jnp.int32),
+              ((32, 2), jnp.int32), ((32,), jnp.int32), ((), jnp.int32))
+
+    def fn(q, kp, vp, ids, count, pos, layer):
+        return block_list_attention(q, kp, vp, ids, count, pos, layer=layer,
+                                    interpret=False)
+
+    compiled = _compile(chip, fn, *shapes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22

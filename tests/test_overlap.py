@@ -446,11 +446,13 @@ def test_worker_streams_interleaved_and_overlap_tokens(model):
 #: latent leaf (JoyAI's `MlaFamilyRows`), two latent kinds with a window
 #: kind (dots3's), K/V kinds with a window (K-EXAONE's), a kind with no
 #: position axis beside K and V (Solar-Open2's state), and no K/V layer at
-#: all (Brumby's state: nothing paged, no allocator), and ONE kind with
-#: paged K and V AND a state a slot in every layer (Falcon-H1's)
+#: all (Brumby's state: nothing paged, no allocator), ONE kind with
+#: paged K and V AND a state a slot in every layer (Falcon-H1's), and a
+#: STRIDED leaf beside K and V read by lists of blocks, with a state kind of
+#: one leaf (MiniCPM-SALA's)
 FAMILIES = ["gpt2-test", "olmoe-test", "keye-test", "joyai-test",
             "dots3-test", "k-exaone-test", "solar-open2-test", "brumby-test",
-            "falcon-h1-test"]
+            "falcon-h1-test", "minicpm-sala-test"]
 _BUILT: dict = {}
 
 
@@ -716,7 +718,8 @@ def test_the_daemon_pipelines_by_default(model):
 
 @pytest.mark.parametrize("name", ["keye-test", "joyai-test", "dots3-test",
                                   "k-exaone-test", "solar-open2-test",
-                                  "brumby-test", "falcon-h1-test"])
+                                  "brumby-test", "falcon-h1-test",
+                                  "minicpm-sala-test"])
 def test_interleaved_admission_stays_refused_by_name(name):
     """A family that lives in the paged pool alone still refuses the
     mixed step, by name, with or without the pipeline — which it takes."""
