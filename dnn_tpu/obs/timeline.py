@@ -64,8 +64,9 @@ This is the intra-step instrument, in two connected halves:
     themselves counted it — exact at every scrape to the last ended
     step; for a model whose attention selects what it reads
     dsa.layer_calls_total / dsa.candidate_positions_total /
-    dsa.selected_positions_total{program=decode|prefill}, counted on the
-    host from each slot's position; for a model whose cache is a
+    dsa.selected_positions_total / dsa.walked_positions_total
+    {program=decode|prefill}, counted on the host from each slot's
+    position; for a model whose cache is a
     compressed latent mla.layer_calls_total /
     mla.cached_positions_total / mla.query_pairs_total{program=}, counted
     the same way) + fixed-bucket histograms
@@ -214,9 +215,10 @@ MOE_PROGRAMS = ("decode", "prefill")
 MOE_SERIES = ("layer_calls_total", "assignments_total",
               "active_experts_total", "peak_expert_rows_total")
 # the dsa.* cumulative series (StepClock.note_dsa), same programs: what a
-# learned-sparse-attention model's indexer scored and what it selected
+# learned-sparse-attention model's indexer scored, what it selected, and
+# the (query, column) pairs the masked prefill kernel's grid walked
 DSA_SERIES = ("layer_calls_total", "candidate_positions_total",
-              "selected_positions_total")
+              "selected_positions_total", "walked_positions_total")
 # the mla.* cumulative series (StepClock.note_mla), same programs: the
 # cached latents a latent-attention model's layers read, and a prefill
 # chunk's causal (query, position) pairs
@@ -425,7 +427,7 @@ class StepClock:
         # the paged decode kernel's groups (note_attn_groups): walked, full
         self.attn_groups_total = [0, 0]
         # an indexer's work (note_dsa): per program, DSA_SERIES in order
-        self.dsa_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
+        self.dsa_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
         # latent attention's reads (note_mla): MLA_SERIES in order
         self.mla_total = {p: [0, 0, 0] for p in MOE_PROGRAMS}
         # the same reads by layer KIND (note_mla_kind): (kind, program) ->
@@ -675,16 +677,20 @@ class StepClock:
         tot[1] += full
 
     def note_dsa(self, program: str, layer_calls: int, candidates: int,
-                 selected: int):
+                 selected: int, walked: int = 0):
         """One dispatched program of a model whose attention selects what
         it reads (models/dsa.py): `layer_calls` attention layers, whose
         indexers scored `candidates` live positions and selected
         `selected` of them (min(position + 1, topk) a query), both summed
-        over the layers and the queries. Counted by the batcher on the
-        host from each slot's position — no device read. Cumulative
-        dsa.* totals, on /metrics with the first note."""
+        over the layers and the queries; `walked`: the (query, column)
+        pairs the grid of a chunk's masked kernel covered for them
+        (ops/pallas/sparse_attention.py `walked_columns` a query — what
+        the kernel pays for, where `candidates` is what it had to).
+        Counted by the batcher on the host from each slot's position — no
+        device read. Cumulative dsa.* totals, on /metrics with the first
+        note."""
         self._note3(self.dsa_total, self._dsa_gauges, program,
-                    (layer_calls, candidates, selected))
+                    (layer_calls, candidates, selected, walked))
 
     def note_mla(self, program: str, layer_calls: int, cached: int,
                  pairs: int):

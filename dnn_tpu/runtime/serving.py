@@ -63,6 +63,7 @@ from dnn_tpu.models.gpt import GPTConfig, head
 from dnn_tpu.utils.metrics import Throughput, labeled
 from dnn_tpu.ops.attention import merge_heads
 from dnn_tpu.ops.nn import gelu, layer_norm, linear
+from dnn_tpu.ops.pallas.sparse_attention import walked_columns
 from dnn_tpu.runtime.generate import (
     TOP_P_PREFILTER_K,
     _NEG_BIG,
@@ -2254,17 +2255,23 @@ class ContinuousBatcher:
             self.step_clock.note_dsa(
                 "prefill", self._n_index_layers,
                 self._n_index_layers * (t * start + t * (t + 1) // 2),
-                self._n_index_layers * self._select_counts(start + 1, t))
+                self._n_index_layers * self._select_counts(start + 1, t),
+                self._n_index_layers * t * walked_columns(
+                    start, t, self._row_len))
         if self._index_topk and self.step_clock is not None:
             # what the chunk's indexers scored and selected, from its
             # start and length alone (pad rows too: the device scores
             # them): row t reads min(start + t + 1, topk) of start + t + 1
             start, t = int(args[3]), int(args[2].shape[-1])
             n_sel = _capped_pairs(start, t, self._index_topk)
+            # the masked kernel's grid (a latent model's chunk goes
+            # through ops/pallas/mla_attention.py, over its own prefixes)
             self.step_clock.note_dsa(
                 "prefill", self._n_index_layers,
                 self._n_index_layers * (t * start + t * (t + 1) // 2),
-                self._n_index_layers * n_sel)
+                self._n_index_layers * n_sel,
+                0 if self._latent else self._n_index_layers * t
+                * walked_columns(start, t, self._row_len))
             if self._cache_kinds:
                 self.step_clock.note_mla_kind(
                     "prefill", "full", self._n_index_layers * n_sel)

@@ -697,6 +697,15 @@ def _sparse_cases():
             [((kv, g, t, d), dt), ((kv, s, d), dt), ((kv, s, d), dt),
              ((t, s), jnp.bool_), ((), jnp.int32)])
 
+    # MiniCPM-SALA's call (models/block_select.py): a set a KV head, 16
+    # query heads each, a 25 600-position row (50 column tiles)
+    def prefill_attention_by_kv_head(dt):
+        kv, g, s = 2, 16, 25600
+        return (lambda q, k, v, sel, st: sa.sparse_prefill_attention(
+            q, k, v, sel, st, interpret=False),
+            [((kv, g, t, d), dt), ((kv, s, d), dt), ((kv, s, d), dt),
+             ((kv, t, s), jnp.bool_), ((), jnp.int32)])
+
     def index_scores(dt):
         return (lambda qi, w, ki, st: sa.chunk_index_scores(
             qi, w, ki, st, interpret=False),
@@ -717,16 +726,53 @@ def _sparse_cases():
                     pool, pool, row, row, ((b, nb * bp), jnp.bool_)]
 
     return {"prefill_attention": prefill_attention,
+            "prefill_attention_by_kv_head": prefill_attention_by_kv_head,
             "index_scores": index_scores,
             "paged_decode_under_a_set": paged_decode_under_a_set}
 
 
 @pytest.mark.parametrize("dtype", [BF16, F32], ids=["bf16", "f32"])
-@pytest.mark.parametrize("kernel", ["prefill_attention", "index_scores",
+@pytest.mark.parametrize("kernel", ["prefill_attention",
+                                    "prefill_attention_by_kv_head",
+                                    "index_scores",
                                     "paged_decode_under_a_set"])
 def test_sparse_attention_kernels_compile(chip, kernel, dtype):
     fn, shapes = _sparse_cases()[kernel](dtype)
     _compile(chip, fn, *shapes)
+
+
+def test_keye_chunk_program_attends_under_the_set_in_the_kernel(chip):
+    """The Keye cut's chunk program at its published widths and the cell's
+    row (16 slots of 16 384 positions in blocks of 16, 1024-token chunks),
+    depth cut to TWO layers and the vocabulary to 8192 rows: every layer
+    scores the row's index keys and attends under the set in the two
+    kernels of ops/pallas/sparse_attention.py, on the transient row alone;
+    its temporaries are the (T, S) scores, the selection's passes over them
+    and the set (64 MB + 16 MB at 1024 x 16 384), not a (T, Hi, S) product
+    nor a gathered K/V."""
+    import dataclasses
+
+    from dnn_tpu.models import llama_moe
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(
+        llama_moe.PRESETS["keye-vl-2.0-30b-a3b-ep8-1chip"], n_layer=2,
+        vocab_size=8192)
+    prepared = _stack_and_release(
+        llama_moe.init(jax.random.PRNGKey(0), cfg), cfg, BF16)
+    b = ContinuousBatcher(
+        cfg, prepared, slots=16, max_len=16384, prompt_pad=1024, kv="auto",
+        block_len=16, family=llama_moe.family_rows(cfg, compute_dtype=BF16))
+    assert b._paged and b._row_len == 16384
+    assert b.cache["ik"].shape == (2, 16 * 1024 + 1, 1, 16, 128)
+    chunk = _lower_programs(chip, [(b, ("_prefill_chunk",))])[
+        "_prefill_chunk"]
+    text = chunk.as_text()
+    assert "sparse_prefill_attention" in text
+    assert "chunk_index_scores" in text
+    assert _pool_extent_ops(chunk, b.cache["k"].shape[1:]) == []
+    assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 # ----------------------------------------------------------------------

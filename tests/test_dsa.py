@@ -202,23 +202,87 @@ def test_selection(model, case):
     SELECTION_CASES[case](model)
 
 
-@pytest.mark.parametrize("start", [0, 32, 96])
-def test_chunk_kernels_match_their_plain_forms(start):
-    rng = np.random.default_rng(start)
+# sparse_prefill_attention's cases beside the indexer's: the row's length
+# and the KV x G heads, the chunk's start, the set's kind ("topk": the
+# indexer's own over the plain scores; "hole": a (T, S) set with NO column
+# in the first two tiles but the query's own, so rows pass live tiles with
+# nothing chosen SO FAR and keep an empty state;
+# "heads": a set a KV head), and what the grid must walk
+ATTEND_CASES = {
+    # the three starts PR 33 tested, one column tile
+    "row128-start0": (128, 2, 2, 0, "topk", 128),
+    "row128-start32": (128, 2, 2, 32, "topk", 128),
+    "row128-start96": (128, 2, 2, 96, "topk", 128),
+    # a row of four column tiles: a start in each prefix the grid may end
+    # with, the last (the whole row) among them
+    "row512-start0": (512, 2, 2, 0, "topk", 128),
+    "row512-start96": (512, 2, 2, 96, "topk", 128),
+    "row512-start100": (512, 2, 2, 100, "topk", 256),
+    "row512-start300": (512, 2, 2, 300, "topk", 384),
+    "row512-start480": (512, 2, 2, 480, "topk", 512),
+    "hole-start224": (512, 2, 2, 224, "hole", 256),
+    "hole-start300": (512, 2, 2, 300, "hole", 384),
+    "hole-start480": (512, 2, 2, 480, "hole", 512),
+    "heads-start0": (512, 2, 2, 0, "heads", 128),
+    "heads-start300": (512, 2, 2, 300, "heads", 384),
+    "heads-start480": (512, 3, 4, 480, "heads", 512),
+    "g1-start300": (512, 2, 1, 300, "topk", 384),
+    "g4-start224": (512, 1, 4, 224, "hole", 256),
+    "g4-start480": (512, 2, 4, 480, "topk", 512),
+    # 200 is no multiple of a column tile: the plain form, the whole row
+    "row200-untiled": (200, 2, 2, 100, "topk", 200),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ATTEND_CASES))
+def test_chunk_kernels_match_their_plain_forms(case):
+    s_len, kv, g, start, kind, walked = ATTEND_CASES[case]
+    rng = np.random.default_rng(start + s_len + g)
     f = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
-    kv, g, t, d, s_len, hi, di = 2, 2, 32, 16, 128, 4, 8
+    t, d, hi, di = 32, 16, 4, 8
     qi, w, ki = f(t, hi, di), f(t, hi), f(s_len, di)
     plain = sa.reference_chunk_index_scores(qi, w, ki)
     got = sa.chunk_index_scores(qi, w, ki, start, block_q=16, block_s=128,
                                 interpret=True)
-    valid = np.arange(s_len)[None, :] <= (start + np.arange(t))[:, None]
+    pos = start + np.arange(t)
+    valid = np.arange(s_len)[None, :] <= pos[:, None]
     assert float(jnp.abs(jnp.where(valid, got - plain, 0.0)).max()) < 1e-5
-    sel = dsa.select(plain, jnp.asarray(valid), 8)
+    if kind == "topk":
+        sel = dsa.select(plain, jnp.asarray(valid), 8)
+    else:
+        shape = (kv, t, s_len) if kind == "heads" else (t, s_len)
+        sel = valid & (rng.random(shape) < 0.1)
+        if kind == "hole":
+            sel[:, :256] = False
+        sel[..., np.arange(t), pos] = True  # never empty
+        sel = jnp.asarray(sel)
     q, k, v = f(kv, g, t, d), f(kv, s_len, d), f(kv, s_len, d)
     a = sa.reference_sparse_prefill_attention(q, k, v, sel)
     b = sa.sparse_prefill_attention(q, k, v, sel, start, block_q=16,
                                     block_s=128, interpret=True)
     assert float(jnp.abs(a - b).max()) < 1e-5
+    # the grid ends with the chunk's live prefix: the same result from a
+    # row that IS that prefix, whatever lies behind it
+    assert sa.walked_columns(start, t, s_len, block_q=16,
+                             block_s=128) == walked
+    assert int(jax.jit(lambda st: sa.walked_columns(
+        st, t, s_len, block_q=16, block_s=128))(start)) == walked
+    c = sa.sparse_prefill_attention(
+        q, k[:, :walked], v[:, :walked], sel[..., :walked], start,
+        block_q=16, block_s=128, interpret=True)
+    assert float(jnp.abs(b - c).max()) < 1e-6
+
+
+def test_grid_step_fits_the_heads_rows_in_vmem():
+    """`grid_step` from the shapes alone: 2048 rows a step at the Keye
+    cut's G = 8 and at SALA's G = 16, fewer where they would not fit."""
+    assert sa.grid_step(8, 1024, 16384, 128, 2) == (256, 512)
+    assert sa.grid_step(16, 1024, 25600, 128, 2) == (128, 512)
+    assert sa.grid_step(64, 1024, 16384, 128, 2) == (32, 512)
+    assert sa.grid_step(8, 1024, 16384 + 64, 128, 2) is None
+    assert sa.grid_step(8, 1000, 16384, 128, 2) is None
+    # a row only narrower tiles divide
+    assert sa.grid_step(8, 1024, 13312 + 256, 128, 2) == (256, 256)
 
 
 # ----------------------------------------------------------------------
@@ -340,16 +404,21 @@ def test_pool_has_three_leaves_and_counts_what_it_selects(model):
     layers, k = cfg.n_layer, cfg.index_topk
     pf = clock.dsa_total["prefill"]
     # two chunks of 16 at 0 and 16: row t of a chunk at `start` scores
-    # start + t + 1 positions and reads min(that, topk)
+    # start + t + 1 positions and reads min(that, topk); the masked
+    # kernel's grid walks `walked_columns` of the transient row a query —
+    # the rule the kernel's wrapper sizes its grid with
     cand = sum(s + t + 1 for s in (0, 16) for t in range(16))
     picked = sum(min(s + t + 1, k) for s in (0, 16) for t in range(16))
-    assert pf == [2 * layers, layers * cand, layers * picked]
+    walked = sum(16 * sa.walked_columns(s, 16, b._row_len) for s in (0, 16))
+    assert pf == [2 * layers, layers * cand, layers * picked,
+                  layers * walked]
     # four decode steps (the first token comes from the prefill), the
     # query at 20, 21, 22, 23; positions are summed at each step's END
     # over the slots still live, as step_attn_live_blocks_total is: a
     # request's last step retires it first and adds no positions
     dec = clock.dsa_total["decode"]
-    assert dec == [4 * layers, layers * sum(range(21, 24)), layers * 3 * k]
+    assert dec == [4 * layers, layers * sum(range(21, 24)), layers * 3 * k,
+                   0]
 
 
 # ----------------------------------------------------------------------

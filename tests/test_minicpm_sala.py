@@ -519,6 +519,33 @@ def test_the_counters_count_positions_of_blocks(model):
     assert block_select.read_positions(big, 24577) == 1985 + 64 * 64
 
 
+def test_the_batcher_counts_what_the_prefill_grid_walks(model, plain):
+    """`dsa.walked_positions_total{program="prefill"}`: the full layers x a
+    chunk's queries x the columns the masked kernel's grid covers for it —
+    `sparse_attention.walked_columns`, the rule the kernel's wrapper sizes
+    its grid with, of the batcher's transient row."""
+    from dnn_tpu import obs
+    from dnn_tpu.obs.timeline import StepClock
+    from dnn_tpu.ops.pallas.sparse_attention import walked_columns
+
+    if not obs.enabled():
+        pytest.skip("observability is off")
+    layers = plain.family.cache_kinds["full"]["layers"]
+    before, plain.step_clock = plain.step_clock, StepClock().install()
+    try:
+        plain.submit(_ids(40, 5), 2)  # three chunks of 16: 0, 16, 32
+        plain.drain()
+        calls, cand, _, walked = plain.step_clock.dsa_total["prefill"]
+    finally:
+        plain.step_clock = before
+    assert calls == 3 * layers
+    assert cand == layers * sum(
+        s + t + 1 for s in (0, PAD, 2 * PAD) for t in range(PAD))
+    assert walked == layers * PAD * sum(
+        walked_columns(s, PAD, plain._row_len) for s in (0, PAD, 2 * PAD))
+    assert 0 < cand <= walked
+
+
 def test_statusz_names_the_strided_leaf(model):
     from dnn_tpu.runtime.lm_server import LMServer
 
