@@ -32,8 +32,9 @@ Three callers, one mathematics: `dense_attn` (whole sequences), `chunk_attn`
 (a prefill chunk against the transient row, the set as a mask a KV group for
 ops/pallas/sparse_attention.py) and `decode_attn` (one query a slot against
 the paged pool: the group's LIST of table entries, `PagedKV.
-write_attend_block_rows`). Scopes: `bsel.pool`, `bsel.score`, `dsa.select`,
-`attn.block_prefill`, `attn.block_decode`.
+write_attend_block_rows`); `BlockSelectRows` is the batcher's adapter of a
+model whose "full" layers select. Scopes: `bsel.pool`, `bsel.score`,
+`dsa.select`, `attn.block_prefill`, `attn.block_decode`.
 """
 
 from __future__ import annotations
@@ -45,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dnn_tpu.models import dsa, llama
+from dnn_tpu.models import dsa, llama, state_kind
 from dnn_tpu.ops.attention import merge_heads
 from dnn_tpu.ops.nn import linear
 
@@ -53,7 +54,7 @@ _NEG_BIG = -1e30
 
 __all__ = ["read_positions", "pooled_rows", "group_scores", "block_scores",
            "choose", "chosen_blocks", "block_list", "dense_attn", "chunk_attn",
-           "decode_attn"]
+           "decode_attn", "BlockSelectRows"]
 
 
 def read_positions(m, n: int, count: int = 1) -> int:
@@ -239,3 +240,59 @@ def decode_attn(q, k, v, c, pos, write, codec, *, cfg):
     y, c = codec.write_attend_block_rows(q.reshape(b, kv, g, d), c, k, v,
                                          blocks, count, pos, write)
     return y.reshape(b, cfg.n_head, 1, d), c, codec.block_form(c)
+
+
+class BlockSelectRows(state_kind.StateKindRows):
+    """`StateKindRows` for a model whose "full" layers select (MiniCPM-SALA:
+    models/lightning.py's rule in the "linear" layers): the "full" kind
+    keeps K and V under "tables" and a THIRD paged leaf whose rows are
+    STRIDES and not positions — the mean-pooled keys "kc" (L_full, n_blocks,
+    KV, block_len / stride, d), `cache_kinds["full"]["strided_leaves"]`: name
+    -> (heads, width, stride) — written by the chunk program for every
+    pooled window that completes inside the chunk and by the step on the one
+    slot-step in `stride` that completes one, from K as the pool holds it. A
+    query reads the blocks `choose` names for its KV group: the chunk program
+    under a mask a group (`chunk_attn`), the step by walking the group's LIST
+    of table entries (`decode_attn`); `block_len` must be the selection's
+    block."""
+
+    def __init__(self, cfg, **kw):
+        super().__init__(cfg, **kw)
+        self.select = cfg.block_select
+        self.cache_kinds["full"]["strided_leaves"] = {
+            "kc": (cfg.n_kv_head, cfg.head_dim, self.select.stride)}
+        # the paged pool's block must be the selection's (the batcher
+        # refuses another `block_len` by this name)
+        self.required_block_len = self.select.block
+
+    def select_counts(self, n, count=1):
+        """Positions the `count` queries of contexts n, n + 1, ... read: what
+        the `dsa.*` counters count for a selection whose unit is a block
+        (the batcher asks where the family has this method)."""
+        return read_positions(self.select, n, count)
+
+    def _chunk_attn(self, bp, h, rows, start_pos, kind, **chunk_kw):
+        if self._runs_rule(kind):
+            return super()._chunk_attn(bp, h, rows, start_pos, kind,
+                                       **chunk_kw)
+        o, rows, form = chunk_attn(
+            bp, h, rows, start_pos, cfg=self.cfg,
+            compute_dtype=self.compute_dtype, attn_kernel=self.attn_kernel)
+        self.attn_forms[kind]["prefill"] = form
+        return o, rows
+
+    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
+                   kind="full"):
+        if self._runs_rule(kind):
+            return super()._attn_rows(bp, x, layer_cache, pos, write, codec,
+                                      window, kind)
+        h = llama._pre_normed(bp, x, self.cfg)
+        q, k, v = self._qkv_rows(bp, h, pos, rope=self.kinds[kind].rope)
+        y, layer_cache, form = decode_attn(
+            q, k, v, layer_cache, pos, write, codec, cfg=self.cfg)
+        self.attn_forms[kind]["decode"] = form
+        o = linear(bp["attn"]["o"],
+                   llama._gated(bp, h, merge_heads(y.astype(x.dtype)),
+                                self.compute_dtype),
+                   compute_dtype=self.compute_dtype)
+        return h, o, layer_cache

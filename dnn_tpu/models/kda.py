@@ -18,7 +18,8 @@ With x the normed input of position t (H heads of d; r the low rank):
 **What a slot keeps is S and the last three rows of [q~ | k~ | v~]** — a
 state, float32, 4 d^2 bytes a head whatever the length, and a convolution
 tail — not a position's anything: the cache kind "linear" has leaves
-without a position axis and without blocks (`KdaKindRows.cache_kinds`,
+without a position axis and without blocks (`slot_leaves`, behind
+models/state_kind.py `StateKindRows` as this module's `RULE`;
 runtime/paged_kvcache.py's module docstring).
 
 Two forms of the same numbers:
@@ -47,7 +48,7 @@ Two forms of the same numbers:
     All of that is independent of the state and is made for every chunk
     of a prefill chunk at once; the scan over chunks is four matmuls a
     chunk — on the chip in ops/pallas/delta_scan.py, the state resident
-    in fast memory (`KdaKindRows._scan_kernel`).
+    in fast memory (`StateKindRows.kernel_form`).
   * a PAD position (at or past `n_real` in the chunk) is the identity on
     S — beta 0, g 0 — and does not enter the tail: a recurrence has no
     mask to hide a padded tail behind, so the chunk program is told how
@@ -70,7 +71,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dnn_tpu.models import llama, state_kind
+from dnn_tpu.models import state_kind
 from dnn_tpu.ops.attention import merge_heads, split_heads
 from dnn_tpu.ops.nn import linear, rms_norm, silu
 
@@ -92,9 +93,10 @@ class KdaConfig:
         return self.n_head * self.head_dim
 
 
-def slot_leaves(m: KdaConfig):
+def slot_leaves(cfg):
     """The linear kind's cache leaves — no position axis, no tables —: name
     -> (the shape a slot a layer, dtype or None for the cache's)."""
+    m = cfg.kda
     return {"state": ((m.n_head, m.head_dim, m.head_dim), jnp.float32),
             "conv_tail": ((m.conv - 1, 3 * m.width), None)}
 
@@ -314,15 +316,18 @@ def step_rule(q, k, v, g, beta, state):
     return o, s + k[..., None] * u[..., None, :]
 
 
-def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype,
+def mixer_chunk(p, h, leaves, start_pos, n_real, *, cfg, compute_dtype,
                 kernel=False):
-    """The linear mixer over a chunk h (B, T, C) whose first `n_real`
-    positions are real: `state` (B, H, d, d) float32 and `tail` (B, conv -
-    1, 3 H d) come in, -> (y (B, T, C), the state and the tail after the
-    last REAL position). `kernel` (True / "interpret"): the scan over the
-    rule's chunks runs in ops/pallas/delta_scan.py."""
+    """The rule's chunk form (`state_kind.Rule`): the linear mixer over a
+    chunk h (B, T, C) whose first `n_real` positions are real; `leaves` —
+    `state` (B, H, d, d) float32 and `conv_tail` (B, conv - 1, 3 H d) —
+    come in and are left as they are after the last REAL position -> y (B,
+    T, C). `kernel` (True / "interpret"): the scan over the rule's
+    chunks runs in ops/pallas/delta_scan.py. No position enters the rule."""
+    del start_pos
     m = cfg.kda
     t = h.shape[1]
+    state, tail = leaves["state"], leaves["conv_tail"]
     with jax.named_scope("kda.project"):
         pre, g, beta, gate = _project(p, h, m=m, compute_dtype=compute_dtype)
         conved, new_tail = state_kind.conv_chunk(
@@ -339,13 +344,18 @@ def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype,
     with jax.named_scope("kda.out"):
         y = _out(p, o, gate, h.dtype, m=m, eps=cfg.rms_eps,
                  compute_dtype=compute_dtype)
-    return y, state, new_tail.astype(tail.dtype)
+    leaves.update(state=state, conv_tail=new_tail.astype(tail.dtype))
+    return y
 
 
-def mixer_step(p, h, state, tail, *, cfg, compute_dtype):
-    """The linear mixer for one token a slot: h (B, 1, C), `state` (B, H,
-    d, d), `tail` (B, conv - 1, 3 H d) -> (y (B, 1, C), state, tail)."""
+def mixer_step(p, h, leaves, pos, *, cfg, compute_dtype, kernel=False,
+               layer=None):
+    """The rule's step form: the linear mixer for one token a slot, h (B, 1,
+    C), `leaves` as `mixer_chunk`'s -> y (B, 1, C). Plain
+    `jax.numpy` (S1: a step kernel enters as `RULE.kernel`'s second form)."""
+    del pos, kernel, layer
     m = cfg.kda
+    state, tail = leaves["state"], leaves["conv_tail"]
     with jax.named_scope("kda.project"):
         pre, g, beta, gate = _project(p, h, m=m, compute_dtype=compute_dtype)
         conved, rows = state_kind.conv_step(tail, pre, lambda: _taps(p))
@@ -356,105 +366,14 @@ def mixer_step(p, h, state, tail, *, cfg, compute_dtype):
     with jax.named_scope("kda.out"):
         y = _out(p, o[:, :, None], gate, h.dtype, m=m, eps=cfg.rms_eps,
                  compute_dtype=compute_dtype)
-    return y, state, rows[:, 1:].astype(tail.dtype)
+    leaves.update(state=state, conv_tail=rows[:, 1:].astype(tail.dtype))
+    return y
 
 
-def fresh_state(cfg, batch, tail_dtype, layers=None):
-    """Zeros of the linear kind's two leaves for `batch` slots — the state
-    float32 whatever the cache's dtype — with a leading layer axis where
-    `layers` is given."""
-    return state_kind.fresh(slot_leaves(cfg.kda), batch, tail_dtype, layers)
+def _init_block(blk, key, cfg, dtype):
+    blk["attn"] = init_mixer(jax.random.fold_in(key, 19), cfg, dtype)
 
 
-def dense_mixer(p, h, *, cfg, compute_dtype):
-    """The linear mixer over whole sequences h (B, T, C) from an empty
-    state: the chunked rule, T padded up to whole chunks."""
-    m = cfg.kda
-    b, t, _ = h.shape
-    pad = -t % m.chunk
-    s0 = fresh_state(cfg, b, h.dtype)
-    y, _, _ = mixer_chunk(p, jnp.pad(h, ((0, 0), (0, pad), (0, 0))),
-                          s0["state"], s0["conv_tail"], jnp.int32(t),
-                          cfg=cfg, compute_dtype=compute_dtype)
-    return y[:, :t]
-
-
-class KdaKindRows(llama.LlamaKindRows):
-    """`LlamaKindRows` for a model whose layers are "full" (K and V leaves
-    under "tables", as there) or "linear": a kind whose leaves — `state`
-    (L_lin, slots, H, d, d) float32 and `conv_tail` (L_lin, slots, conv -
-    1, 3 H d) — have NO position axis, no blocks and no tables
-    (`cache_kinds["linear"]["slot_leaves"]`: name -> (the shape a slot a
-    layer, dtype or None for the cache's)). The pool carries them through
-    the layer loop with the K and V leaves; a decode step reads and writes
-    every slot's state in place at the layer's index among the linear
-    layers; the finish-and-install program writes the transient row's
-    running state into the slot, which is also what resets a slot. The
-    chunk program is told how many of its positions are real
-    (`takes_n_real`)."""
-
-    takes_n_real = True
-
-    def __init__(self, cfg, **kw):
-        super().__init__(cfg, **kw)
-        self.cache_kinds["linear"] = {
-            "layers": sum(t == "linear" for t in cfg.layer_types),
-            "leaves": {}, "tables": None, "window": None,
-            "slot_leaves": slot_leaves(cfg.kda)}
-        self.attn_forms["linear"] = {"prefill": "chunked_jnp",
-                                     "decode": "step_jnp"}
-
-    def _scan_kernel(self):
-        """Whether the chunked rule's scan runs in the Pallas kernel: on
-        the chip unless the family's kernels are off, interpreted where a
-        test asks."""
-        if self.attn_kernel == "interpret":
-            return "interpret"
-        return bool(self.attn_kernel) and jax.default_backend() == "tpu"
-
-    def init_cache(self, batch, max_len, dtype):
-        return {**super().init_cache(batch, max_len, dtype),
-                **fresh_state(self.cfg, batch, dtype,
-                              self.cache_kinds["linear"]["layers"])}
-
-    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, n_real=None):
-        if kind != "linear":
-            return super()._chunk_block(bp, x, rows, start_pos, ffn, kind)
-        cfg = self.cfg
-        with jax.named_scope("llama.block.cached_attn"):
-            h = llama._pre_normed(bp, x, cfg)
-            kernel = self._scan_kernel()
-            self.attn_forms["linear"]["prefill"] = (
-                "chunked_kernel" if kernel else "chunked_jnp")
-            o, state, tail = mixer_chunk(
-                bp["attn"], h, rows["state"], rows["conv_tail"],
-                x.shape[1] if n_real is None else n_real, cfg=cfg,
-                compute_dtype=self.compute_dtype, kernel=kernel)
-        with jax.named_scope("llama.block.mlp"):
-            return (llama._branches_residual(
-                bp, x, o, h, cfg=cfg, compute_dtype=self.compute_dtype,
-                ffn=ffn), {"state": state, "conv_tail": tail})
-
-    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
-                moe_stats=False, n_real=None):
-        return llama.prefill_by_kind(
-            self, prepared, padded, row_cache, start_pos, moe_stats,
-            {"full": llama.KV_KIND_LEAVES["full"][:2],
-             "linear": tuple(self.cache_kinds["linear"]["slot_leaves"])},
-            n_real=n_real)
-
-    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
-                   kind="full"):
-        if kind != "linear":
-            return super()._attn_rows(bp, x, layer_cache, pos, write, codec,
-                                      window, kind)
-        cfg, c, layer = self.cfg, layer_cache, codec.layer
-        h = llama._pre_normed(bp, x, cfg)
-        with jax.named_scope("state_pool.read"):
-            state, tail = c["state"][layer], c["conv_tail"][layer]
-        o, state, tail = mixer_step(bp["attn"], h, state, tail, cfg=cfg,
-                                    compute_dtype=self.compute_dtype)
-        with jax.named_scope("state_pool.write"):
-            c = {**c, "state": c["state"].at[layer].set(state),
-                 "conv_tail": c["conv_tail"].at[layer].set(tail)}
-        return h, o, c
+RULE = state_kind.Rule(
+    field="kda", kind="linear", params="attn", slot_leaves=slot_leaves,
+    init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="chunk")

@@ -18,7 +18,8 @@ ONE gain of H d.
 
 **What a slot keeps is S alone** — float32, 4 d^2 bytes a head whatever the
 length (`slot_leaves`): the cache kind "linear" has one leaf with no position
-axis, no blocks and no tables, as models/kda.py's has two.
+axis, no blocks and no tables, as models/kda.py's has two — behind
+models/state_kind.py `StateKindRows` as this module's `RULE`.
 
 Three forms of the same numbers:
 
@@ -46,14 +47,13 @@ projections run in the compute dtype. Scopes: `lin.project`, `lin.chunk`,
 
 from __future__ import annotations
 
-import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dnn_tpu.models import block_select, llama, state_kind
+from dnn_tpu.models import state_kind
 from dnn_tpu.ops.attention import (
     apply_rope,
     merge_heads,
@@ -65,8 +65,8 @@ from dnn_tpu.ops.nn import linear, rms_norm
 _HI = lax.Precision.HIGHEST
 
 __all__ = ["slopes", "slot_leaves", "init_mixer", "recurrence", "step_rule",
-           "chunk_rule", "mixer_chunk", "mixer_step", "dense_mixer",
-           "LightningKindRows"]
+           "step_rule_kernel", "chunk_rule", "mixer_chunk", "mixer_step",
+           "RULE"]
 
 
 def slopes(n_head: int):
@@ -75,9 +75,10 @@ def slopes(n_head: int):
                         for h in range(1, n_head + 1)], jnp.float32)
 
 
-def slot_leaves(m):
+def slot_leaves(cfg):
     """The linear kind's ONE cache leaf — no position axis, no tables —:
     name -> (the shape a slot a layer, dtype)."""
+    m = cfg.lightning
     return {"state": ((m.n_head, m.head_dim, m.head_dim), jnp.float32)}
 
 
@@ -213,10 +214,14 @@ def _log_decay(m, real):
     return jnp.where(real[..., None, :], -slopes(m.n_head)[:, None], 0.0)
 
 
-def mixer_chunk(p, h, state, start_pos, n_real, *, cfg, compute_dtype):
-    """The linear mixer over a chunk h (B, T, C) at [start_pos, start_pos +
-    T) whose first `n_real` positions are real: `state` (B, H, d, d) float32
-    comes in -> (y (B, T, C), the state after the last REAL position)."""
+def mixer_chunk(p, h, leaves, start_pos, n_real, *, cfg, compute_dtype,
+                kernel=False):
+    """The rule's chunk form (`state_kind.Rule`): the linear mixer over a
+    chunk h (B, T, C) at [start_pos, start_pos + T) whose first `n_real`
+    positions are real; `leaves` — `state` (B, H, d, d) float32 — comes in
+    and is left as it is after the last REAL position -> y (B, T, C). The
+    chunk form has no kernel."""
+    del kernel
     m = cfg.lightning
     t = h.shape[1]
     with jax.named_scope("lin.project"):
@@ -226,187 +231,48 @@ def mixer_chunk(p, h, state, start_pos, n_real, *, cfg, compute_dtype):
         k = jnp.where(real[None, None, :, None], k, 0.0)
         g = jnp.broadcast_to(_log_decay(m, real), q.shape[:3])
     with jax.named_scope("lin.chunk"):
-        o, state = chunk_rule(q, k, v, g, state,
+        o, state = chunk_rule(q, k, v, g, leaves["state"],
                               chunk=math.gcd(m.chunk, t))
     with jax.named_scope("lin.out"):
         y = _out(p, o, gate, h.dtype, cfg=cfg, compute_dtype=compute_dtype)
-    return y, state
+    leaves.update(state=state)
+    return y
 
 
-def mixer_step(p, h, state, pos, *, cfg, compute_dtype, rule=None):
-    """The linear mixer for one token a slot: h (B, 1, C) at per-slot
-    positions `pos` (B,), `state` (B, H, d, d) -> (y (B, 1, C), state).
-    `rule(q, k, v, state)` replaces `step_rule` (the kernel's form, which
-    takes and returns the whole leaf)."""
+def mixer_step(p, h, leaves, pos, *, cfg, compute_dtype, kernel=False,
+               layer=None):
+    """The rule's step form: one token a slot, h (B, 1, C) at per-slot
+    positions `pos` (B,) -> y (B, 1, C). `state` is one layer's
+    for the plain form; under `kernel` (True / "interpret") the WHOLE leaf,
+    updated in place at `layer` (`step_rule_kernel`)."""
     m = cfg.lightning
+    state = leaves["state"]
     with jax.named_scope("lin.project"):
         q, k, v, gate = _project(p, h, pos[:, None], cfg=cfg,
                                  compute_dtype=compute_dtype)
     with jax.named_scope("lin.step"):
-        if rule is None:
+        if kernel:
+            o, state = step_rule_kernel(
+                q[:, :, 0], k[:, :, 0], v[:, :, 0], state, layer=layer,
+                interpret=kernel == "interpret")
+        else:
             g = jnp.broadcast_to(-slopes(m.n_head), q.shape[:2])
             o, state = step_rule(q[:, :, 0], k[:, :, 0], v[:, :, 0], g,
                                  state)
-        else:
-            o, state = rule(q[:, :, 0], k[:, :, 0], v[:, :, 0], state)
     with jax.named_scope("lin.out"):
         y = _out(p, o[:, :, None], gate, h.dtype, cfg=cfg,
                  compute_dtype=compute_dtype)
-    return y, state
+    leaves.update(state=state)
+    return y
 
 
-def dense_mixer(p, h, *, cfg, compute_dtype):
-    """The linear mixer over whole sequences h (B, T, C) from an empty
-    state: the chunked rule, T padded up to whole chunks."""
-    m = cfg.lightning
-    b, t, _ = h.shape
-    pad = -t % m.chunk
-    s0 = state_kind.fresh(slot_leaves(m), b)
-    y, _ = mixer_chunk(p, jnp.pad(h, ((0, 0), (0, pad), (0, 0))),
-                       s0["state"], 0, jnp.int32(t), cfg=cfg,
-                       compute_dtype=compute_dtype)
-    return y[:, :t]
+def _init_block(blk, key, cfg, dtype):
+    blk["attn"] = init_mixer(jax.random.fold_in(key, 37), cfg, dtype)
 
 
-class LightningKindRows(llama.LlamaKindRows):
-    """`LlamaKindRows` for a DENSE model whose layers are "full" or "linear"
-    (module docstring; models/kda.py `KdaKindRows` is the same pair for an
-    MoE model with another rule).
-
-    The "linear" kind has ONE leaf, `state` (L_lin, slots, H, d, d) float32,
-    with no position axis, no blocks and no tables (`slot_leaves`): a decode
-    step reads and writes every slot's state in place at the layer's index
-    among the linear layers; the finish-and-install program writes the
-    transient row's running state into the slot, which is also what resets
-    one; the chunk program is told how many of its positions are real
-    (`takes_n_real`).
-
-    The "full" kind keeps K and V under "tables" and, where the config has a
-    `block_select`, a THIRD paged leaf whose rows are STRIDES and not
-    positions — the mean-pooled keys "kc" (L_full, n_blocks, KV, block_len /
-    stride, d), `cache_kinds["full"]["strided_leaves"]`: name -> (heads,
-    width, stride) — written by the chunk program for every pooled window
-    that completes inside the chunk and by the step on the one slot-step in
-    `stride` that completes one, from K as the pool holds it. A query reads
-    the blocks `block_select.choose` names for its KV group: the chunk
-    program under a mask a group (`ops/pallas/sparse_attention.py`), the step
-    by walking the group's LIST of table entries (`PagedKV.
-    write_attend_block_rows`); `block_len` must be the selection's block."""
-
-    takes_n_real = True
-
-    def __init__(self, cfg, **kw):
-        super().__init__(cfg, **kw)
-        m = cfg.lightning
-        self.cache_kinds["linear"] = {
-            "layers": sum(t == "linear" for t in cfg.layer_types),
-            "leaves": {}, "tables": None, "window": None,
-            "slot_leaves": slot_leaves(m)}
-        self.attn_forms["linear"] = {"prefill": "chunked_jnp",
-                                     "decode": "step_jnp"}
-        self.select = cfg.block_select
-        if self.select is not None:
-            self.cache_kinds["full"]["strided_leaves"] = {
-                "kc": (cfg.n_kv_head, cfg.head_dim, self.select.stride)}
-            # the paged pool's block must be the selection's (the batcher
-            # refuses another `block_len` by this name)
-            self.required_block_len = self.select.block
-
-    def _step_kernel(self):
-        """Whether the one-token rule runs in the Pallas kernel: on the chip
-        unless the family's kernels are off (and where a head's width fills
-        128 lanes), interpreted where a test asks."""
-        if self.cfg.lightning.head_dim % 128:
-            return False
-        if self.attn_kernel == "interpret":
-            return "interpret"
-        return bool(self.attn_kernel) and jax.default_backend() == "tpu"
-
-    def select_counts(self, n, count=1):
-        """Positions the `count` queries of contexts n, n + 1, ... read: what
-        the `dsa.*` counters count for a selection whose unit is a block
-        (the batcher asks where the family has this method)."""
-        return block_select.read_positions(self.select, n, count)
-
-    def init_cache(self, batch, max_len, dtype):
-        c = {**super().init_cache(batch, max_len, dtype),
-             **state_kind.fresh(self.cache_kinds["linear"]["slot_leaves"],
-                                batch, dtype,
-                                self.cache_kinds["linear"]["layers"])}
-        if self.select is not None:
-            c["kc"] = jnp.zeros(
-                (self.cache_kinds["full"]["layers"], batch,
-                 self.cfg.n_kv_head, -(-max_len // self.select.stride),
-                 self.cfg.head_dim), dtype)
-        return c
-
-    def _chunk_attn(self, bp, h, rows, start_pos, kind):
-        if self.select is None:
-            return super()._chunk_attn(bp, h, rows, start_pos, kind)
-        cfg = self.cfg
-        o, rows, form = block_select.chunk_attn(
-            bp, h, rows, start_pos, cfg=cfg, compute_dtype=self.compute_dtype,
-            attn_kernel=self.attn_kernel)
-        self.attn_forms[kind]["prefill"] = form
-        return o, rows
-
-    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, n_real=None):
-        if kind != "linear":
-            return super()._chunk_block(bp, x, rows, start_pos, ffn, kind)
-        cfg = self.cfg
-        with jax.named_scope("llama.block.cached_attn"):
-            h = llama._pre_normed(bp, x, cfg)
-            o, state = mixer_chunk(
-                bp["attn"], h, rows["state"], start_pos,
-                x.shape[1] if n_real is None else n_real, cfg=cfg,
-                compute_dtype=self.compute_dtype)
-        with jax.named_scope("llama.block.mlp"):
-            return (llama._branches_residual(
-                bp, x, o, h, cfg=cfg, compute_dtype=self.compute_dtype,
-                ffn=ffn), {"state": state})
-
-    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
-                moe_stats=False, n_real=None):
-        full = self.cache_kinds["full"]
-        return llama.prefill_by_kind(
-            self, prepared, padded, row_cache, start_pos, moe_stats,
-            {"full": (*full["leaves"], *full.get("strided_leaves", ())),
-             "linear": tuple(self.cache_kinds["linear"]["slot_leaves"])},
-            n_real=n_real)
-
-    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
-                   kind="full"):
-        cfg = self.cfg
-        if kind == "linear":
-            c, layer = layer_cache, codec.layer
-            h = llama._pre_normed(bp, x, cfg)
-            kernel = self._step_kernel()
-            self.attn_forms["linear"]["decode"] = (
-                "step_kernel" if kernel else "step_jnp")
-            # the kernel takes the WHOLE leaf and hands it back updated in
-            # place
-            rule = functools.partial(
-                step_rule_kernel, layer=layer,
-                interpret=kernel == "interpret") if kernel else None
-            with jax.named_scope("state_pool.read"):
-                state = c["state"] if kernel else c["state"][layer]
-            o, state = mixer_step(bp["attn"], h, state, pos, cfg=cfg,
-                                  compute_dtype=self.compute_dtype, rule=rule)
-            with jax.named_scope("state_pool.write"):
-                if not kernel:
-                    state = c["state"].at[layer].set(state)
-                c = {**c, "state": state}
-            return h, o, c
-        if self.select is None:
-            return super()._attn_rows(bp, x, layer_cache, pos, write, codec,
-                                      window, kind)
-        h = llama._pre_normed(bp, x, cfg)
-        q, k, v = self._qkv_rows(bp, h, pos, rope=self.kinds[kind].rope)
-        y, layer_cache, form = block_select.decode_attn(
-            q, k, v, layer_cache, pos, write, codec, cfg=cfg)
-        self.attn_forms[kind]["decode"] = form
-        o = linear(bp["attn"]["o"],
-                   llama._gated(bp, h, merge_heads(y.astype(x.dtype)),
-                                self.compute_dtype),
-                   compute_dtype=self.compute_dtype)
-        return h, o, layer_cache
+RULE = state_kind.Rule(
+    field="lightning", kind="linear", params="attn", slot_leaves=slot_leaves,
+    init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="step",
+    whole=("state",),
+    # the kernel's tiles are whole lanes of a head's width
+    fits=lambda cfg: cfg.lightning.head_dim % 128 == 0)

@@ -618,19 +618,16 @@ KV_KIND_LEAVES = {"full": ("k", "v", "tables"),
 
 def kv_kinds(cfg):
     """{kind: its KvKind} of a config whose K/V layers are of two kinds,
-    "full" first; None for every other config."""
-    if getattr(cfg, "kda", None) is not None:
-        # models/kda.py: the K/V layers are the "full" kind alone; the
-        # "linear" layers keep a state, no position's anything
-        return {"full": cfg.kv_full or KvKind()}
-    if getattr(cfg, "mamba", None) is not None:
-        # models/mamba2.py: ONE kind, whose every layer keeps K and V
-        # under tables AND a state a slot
-        return {"full": KvKind()}
-    if getattr(cfg, "lightning", None) is not None:
-        # models/lightning.py: as kda's — K and V in the "full" layers, a
-        # state alone in the "linear" ones
-        return {"full": cfg.kv_full or KvKind()}
+    "full" first — of the ONE kind "full" where layers keep a state
+    (models/state_kind.py) in other layers or beside K and V —; None for
+    every other config."""
+    from dnn_tpu.models import state_kind
+
+    rule = state_kind.config_rule(cfg)
+    if rule is not None:
+        if rule.beside is None and getattr(cfg, "layer_types", None) is None:
+            return None  # the rule replaces every layer's attention
+        return {"full": getattr(cfg, "kv_full", None) or KvKind()}
     if getattr(cfg, "kv_window", None) is None:
         return None
     return {"full": cfg.kv_full or KvKind(), "window": cfg.kv_window}
@@ -770,31 +767,15 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
 
         blk["attn"] = mla.init_attn(jax.random.fold_in(key, 17), cfg, dtype,
                                     mla.kinds(cfg)[kind or "full"])
-    if getattr(cfg, "kda", None) is not None or cfg.lightning is not None:
-        if kind == "linear" and cfg.lightning is not None:
-            from dnn_tpu.models import lightning
+    from dnn_tpu.models import state_kind
 
-            blk["attn"] = lightning.init_mixer(jax.random.fold_in(key, 37),
-                                               cfg, dtype)
-        elif kind == "linear":
-            from dnn_tpu.models import kda
-
-            blk["attn"] = kda.init_mixer(jax.random.fold_in(key, 19), cfg,
-                                         dtype)
-        elif cfg.attn_gate:
-            # the softmax layer's sigmoid OUTPUT gate, element-wise
-            blk["attn"]["gate"] = _dense(jax.random.fold_in(key, 23),
-                                         (c, cfg.n_head * d))
-    if cfg.retention is not None:
-        from dnn_tpu.models import retention
-
-        blk["attn"]["decay"] = retention.init_gate(
-            jax.random.fold_in(key, 29), cfg)
-    if cfg.mamba is not None:
-        from dnn_tpu.models import mamba2
-
-        blk["ssm"] = mamba2.init_mixer(jax.random.fold_in(key, 31), cfg,
-                                       dtype)
+    rule = state_kind.layer_rule(cfg, kind)
+    if rule is not None:
+        rule.init(blk, key, cfg, dtype)
+    elif cfg.attn_gate:
+        # the softmax layer's sigmoid OUTPUT gate, element-wise
+        blk["attn"]["gate"] = _dense(jax.random.fold_in(key, 23),
+                                     (c, cfg.n_head * d))
     if not cfg.parallel_block:  # Phi's parallel block has ONE norm
         blk["ln_2"] = _norm_p((c,))
     if not cfg.pre_norm:  # OLMo-2: only the post-branch norms exist
@@ -1159,21 +1140,18 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
     kind where the config's layers are of several (models/mla.py)."""
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
-    if attn_fn is None and cfg.lightning is not None:
-        from dnn_tpu.models import block_select, lightning
+    rule = None
+    if attn_fn is None:
+        from dnn_tpu.models import state_kind
 
-        if kind == "linear":
-            fn = lambda bp2, h: lightning.dense_mixer(  # noqa: E731
-                bp2["attn"], h, cfg=cfg, compute_dtype=compute_dtype)
-        else:
-            fn = lambda bp2, h: block_select.dense_attn(  # noqa: E731
-                bp2, h, cfg=cfg, compute_dtype=compute_dtype)
-    elif attn_fn is None and kind == "linear":
-        from dnn_tpu.models import kda
+        rule = state_kind.layer_rule(cfg, kind)
+    attends = attn_fn is None and (rule is None or rule.beside is not None)
+    if attends and getattr(cfg, "block_select", None) is not None:
+        from dnn_tpu.models import block_select
 
-        fn = lambda bp2, h: kda.dense_mixer(  # noqa: E731
-            bp2["attn"], h, cfg=cfg, compute_dtype=compute_dtype)
-    elif attn_fn is None and (kinds := kv_kinds(cfg)) is not None:
+        fn = lambda bp2, h: block_select.dense_attn(  # noqa: E731
+            bp2, h, cfg=cfg, compute_dtype=compute_dtype)
+    elif attends and (kinds := kv_kinds(cfg)) is not None:
         kk = kinds[kind or "full"]
         fn = lambda bp2, h: _dense_attn(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=kk.window,
@@ -1183,24 +1161,16 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
 
         fn = lambda bp2, h: dsa.dense_attn(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype)
-    if attn_fn is None and cfg.retention is not None:
-        from dnn_tpu.models import retention
-
-        fn = lambda bp2, h: retention.dense_mixer(  # noqa: E731
-            bp2, h, cfg=cfg, compute_dtype=compute_dtype)
-    if attn_fn is None and cfg.mamba is not None:
-        from dnn_tpu.models import mamba2
-
-        attend = fn  # dense causal attention, the config's one K/V kind
-        fn = lambda bp2, h: mamba2.mixers_sum(  # noqa: E731
-            attend(bp2, h), mamba2.dense_mixer(
-                bp2["ssm"], h, cfg=cfg, compute_dtype=compute_dtype), cfg)
     if attn_fn is None and getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models import mla
 
         fn = lambda bp2, h: mla.dense_attn(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype,
             m=mla.kinds(cfg)[kind or "full"])
+    if rule is not None:
+        # the layer keeps a state: its rule in attention's place, or beside it
+        fn = state_kind.dense_mixer(rule, fn, cfg=cfg,
+                                    compute_dtype=compute_dtype)
     # trace-time scopes: device profiles (obs/profile.py) name the
     # attention branch vs the residual/MLP compose; zero runtime cost
     with jax.named_scope("llama.block.attn"):
@@ -1904,26 +1874,45 @@ def family_rows(cfg, **kw):
     """The batcher adapter a LLaMA-family config serves through: by what
     its attention keeps a position (`LlamaFamilyRows`' K and V — by layer
     kind `LlamaKindRows`'; with an indexer models/dsa.py's third leaf;
-    with latent attention models/mla.py's one; NOTHING a position with
-    models/retention.py's state a slot; K and V AND a state a slot in one
-    layer with models/mamba2.py). `kw`: `LlamaFamilyRows`' own."""
+    with latent attention models/mla.py's one) and by whether layers keep
+    a STATE a slot in its place or beside it (models/state_kind.py
+    `StateKindRows`; models/block_select.py's where the K/V layers select
+    the blocks they read). `kw`: `LlamaFamilyRows`' own."""
+    from dnn_tpu.models import state_kind
+
     if cfg.index_topk is not None:
         from dnn_tpu.models.dsa import DsaFamilyRows as rows
     elif getattr(cfg, "mla", None) is not None:
         from dnn_tpu.models.mla import MlaFamilyRows as rows
-    elif getattr(cfg, "kda", None) is not None:
-        from dnn_tpu.models.kda import KdaKindRows as rows
-    elif cfg.retention is not None:
-        from dnn_tpu.models.retention import RetentionRows as rows
-    elif cfg.mamba is not None:
-        from dnn_tpu.models.mamba2 import HybridRows as rows
-    elif cfg.lightning is not None:
-        from dnn_tpu.models.lightning import LightningKindRows as rows
-    elif kv_kinds(cfg) is not None:
-        rows = LlamaKindRows
+    elif state_kind.config_rule(cfg) is None:
+        rows = LlamaFamilyRows if kv_kinds(cfg) is None else LlamaKindRows
+    elif getattr(cfg, "block_select", None) is not None:
+        from dnn_tpu.models.block_select import BlockSelectRows as rows
     else:
-        rows = LlamaFamilyRows
+        rows = state_kind.StateKindRows
     return rows(cfg, **kw)
+
+
+def qkv_rows(bp, h, pos, *, cfg, compute_dtype, rope=True):
+    """h (B, 1, C) normed rows at per-slot positions pos (B,) -> q (B, H, 1,
+    D) normed, rotated and rescaled, k (rotated) and v (B, KV, 1, D); `rope`
+    off (a layer kind's): unrotated."""
+    kv = cfg.n_kv_head
+    h = _mup_scaled(h, cfg, "attention_in")
+    q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
+                    cfg.n_head)
+    k = split_heads(_mup_scaled(
+        linear(bp["attn"]["k"], h, compute_dtype=compute_dtype), cfg,
+        "key"), kv)
+    v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
+                    kv)
+    q, k = _qk_normed(bp, q, k, cfg)
+    if not rope:
+        return _q_rescale(q, cfg), k, v
+    cos, sin = _rope_tables(cfg, pos)  # (B, D)
+    cos, sin = cos[:, None, None, :], sin[:, None, None, :]
+    q, k = _rope_apply(q, cos, sin, cfg), _rope_apply(k, cos, sin, cfg)
+    return _q_rescale(q, cfg), k, v
 
 
 class LlamaFamilyRows:
@@ -2010,26 +1999,8 @@ class LlamaFamilyRows:
                     layer_cache)
 
     def _qkv_rows(self, bp, h, pos, rope=True):
-        """h (B, 1, C) normed rows at per-slot positions pos (B,) -> q (B,
-        H, 1, D) normed, rotated and rescaled, k (rotated) and v (B, KV,
-        1, D); `rope` off (a layer kind's): unrotated."""
-        cfg, compute_dtype = self.cfg, self.compute_dtype
-        kv = cfg.n_kv_head
-        h = _mup_scaled(h, cfg, "attention_in")
-        q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
-                        cfg.n_head)
-        k = split_heads(_mup_scaled(
-            linear(bp["attn"]["k"], h, compute_dtype=compute_dtype), cfg,
-            "key"), kv)
-        v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
-                        kv)
-        q, k = _qk_normed(bp, q, k, cfg)
-        if not rope:
-            return _q_rescale(q, cfg), k, v
-        cos, sin = _rope_tables(cfg, pos)  # (B, D)
-        cos, sin = cos[:, None, None, :], sin[:, None, None, :]
-        q, k = _rope_apply(q, cos, sin, cfg), _rope_apply(k, cos, sin, cfg)
-        return _q_rescale(q, cfg), k, v
+        return qkv_rows(bp, h, pos, cfg=self.cfg,
+                        compute_dtype=self.compute_dtype, rope=rope)
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
         cfg, compute_dtype = self.cfg, self.compute_dtype
@@ -2242,7 +2213,7 @@ class LlamaKindRows(LlamaFamilyRows):
                 "sequential block without a uniform sliding window, an "
                 "alternating one or a softcap: a kind's window is "
                 "`KvKind.window`")
-        self.kinds = kv_kinds(cfg)
+        self.kinds = kv_kinds(cfg) or {}  # none: every layer keeps a state
         d = cfg.head_dim
         self.cache_kinds = {}
         # a config without `layer_types` has ONE kind: every layer's
@@ -2254,18 +2225,31 @@ class LlamaKindRows(LlamaFamilyRows):
                 "leaves": {k_name: (cfg.n_kv_head, d),
                            v_name: (cfg.n_kv_head, d)},
                 "tables": tables, "window": kk.window}
-        self.cache_leaves = self.cache_kinds["full"]["leaves"]
+        self.cache_leaves = self.cache_kinds.get("full", {}).get("leaves", {})
         # which form each kind's reads took in the programs traced so far
         # (/statusz `components.attention.kinds`)
         self.attn_forms = {kind: {} for kind in self.kinds}
 
     def init_cache(self, batch, max_len, dtype):
+        """What `cache_kinds` says each kind keeps, dense: a row a position
+        of its `leaves`, one every `stride` of its `strided_leaves`, its
+        `slot_leaves` as they are."""
+        from dnn_tpu.models import state_kind
+
         if dtype in ("int8", "int4"):
-            raise ValueError("a cache of leaves by layer kind is float")
-        return {name: jnp.zeros((k["layers"], batch, heads, max_len, width),
-                                dtype)
-                for k in self.cache_kinds.values()
-                for name, (heads, width) in k["leaves"].items()}
+            raise ValueError("a cache of leaves by layer kind is float (a "
+                             "state leaf float32)")
+        cache = {}
+        for k in self.cache_kinds.values():
+            strided = k.get("strided_leaves", {})
+            for name, (heads, width, *stride) in (*k["leaves"].items(),
+                                                  *strided.items()):
+                rows = -(-max_len // stride[0]) if stride else max_len
+                cache[name] = jnp.zeros(
+                    (k["layers"], batch, heads, rows, width), dtype)
+            cache.update(state_kind.fresh(k.get("slot_leaves", {}), batch,
+                                          dtype, k["layers"]))
+        return cache
 
     def _chunk_attn(self, bp, h, rows, start_pos, kind):
         """Attention of one block over a prefill chunk's normed rows h (1,
@@ -2310,13 +2294,14 @@ class LlamaKindRows(LlamaFamilyRows):
                       _gated(bp, h, merge_heads(y.astype(h.dtype)),
                              compute_dtype), compute_dtype=compute_dtype), rows
 
-    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind):
+    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, **chunk_kw):
         """One block over a prefill chunk x (1, T, C) at [start_pos,
         start_pos + T): `_chunk_attn`, then the residuals and the MLP."""
         cfg = self.cfg
         with jax.named_scope("llama.block.cached_attn"):
             h = _pre_normed(bp, x, cfg)
-            o, rows = self._chunk_attn(bp, h, rows, start_pos, kind)
+            o, rows = self._chunk_attn(bp, h, rows, start_pos, kind,
+                                       **chunk_kw)
         with jax.named_scope("llama.block.mlp"):
             return (_branches_residual(bp, x, o, h, cfg=cfg,
                                        compute_dtype=self.compute_dtype,
@@ -2324,10 +2309,14 @@ class LlamaKindRows(LlamaFamilyRows):
                     rows)
 
     def prefill(self, prepared, padded, row_cache, start_pos=0, *,
-                moe_stats=False):
+                moe_stats=False, **chunk_kw):
+        """The transient row's leaves of a kind are what `cache_kinds` says
+        the kind keeps: paged, strided and slot leaves."""
         return prefill_by_kind(
             self, prepared, padded, row_cache, start_pos, moe_stats,
-            {kind: names[:2] for kind, names in KV_KIND_LEAVES.items()})
+            {kind: (*k["leaves"], *k.get("strided_leaves", ()),
+                    *k.get("slot_leaves", ()))
+             for kind, k in self.cache_kinds.items()}, **chunk_kw)
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
                    kind="full"):
@@ -2356,8 +2345,11 @@ class LlamaKindRows(LlamaFamilyRows):
         return h, o, layer_cache
 
     def verify_rows(self, *a, **kw):
-        raise ValueError("speculative verify reads ONE stack of K and V: "
-                         "not available with cache leaves by layer kind")
+        raise ValueError(
+            "speculative verify reads ONE stack of K and V: not available "
+            "with cache leaves by layer kind (" + "/".join(
+                n for k in self.cache_kinds.values()
+                for n in (*k["leaves"], *k.get("slot_leaves", ()))) + ")")
 
 
 class LlamaPipelineFamily:

@@ -21,7 +21,8 @@ with H heads of P in G groups, a state N wide, u = ssm_in h:
 **What a slot keeps, a layer: S (H, P, N) float32, the last three rows of
 the un-convolved [x | B | C] — the TAIL — and the K and V of every
 position.** ONE cache kind, "full", has paged `leaves` {k, v} under `tables`
-AND `slot_leaves` {`ssm_state`, `conv_tail`} (`HybridRows.cache_kinds`;
+AND `slot_leaves` {`ssm_state`, `conv_tail`} (behind models/state_kind.py
+`StateKindRows` as this module's `RULE`, which runs BESIDE attention;
 runtime/paged_kvcache.py's module docstring): a layer reaches its K/V blocks
 through the slot's table and its state at the slot's row, in the same layer
 body.
@@ -69,7 +70,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from dnn_tpu.models import llama, state_kind
+from dnn_tpu.models import state_kind
 from dnn_tpu.ops.nn import linear, silu
 
 _HI = lax.Precision.HIGHEST
@@ -79,9 +80,10 @@ _A_RANGE = (1.0, 16.0)
 _DT_RANGE = (1e-3, 1e-1)
 
 
-def slot_leaves(m: llama.Mamba2Config):
+def slot_leaves(cfg):
     """The kind's leaves with no position axis: name -> (the shape a slot a
     layer, dtype or None for the cache's)."""
+    m = cfg.mamba
     return {"ssm_state": ((m.n_head, m.head_dim, m.d_state), jnp.float32),
             "conv_tail": ((m.conv - 1, m.conv_width), None)}
 
@@ -254,13 +256,17 @@ def step_rule_kernel(x, dt, a_log, d, bm, cm, pool, *, m, layer,
     return y, pool
 
 
-def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype):
-    """The state-space mixer over a chunk h (B, T, C) whose first `n_real`
-    positions are real: `state` (B, H, P, N) float32 and `tail` (B, conv - 1,
-    W) come in -> (SSM(h) (B, T, C), the state and the tail after the last
-    REAL position)."""
+def mixer_chunk(p, h, leaves, start_pos, n_real, *, cfg, compute_dtype,
+                kernel=False):
+    """The rule's chunk form (`state_kind.Rule`): the state-space mixer over
+    a chunk h (B, T, C) whose first `n_real` positions are real; `leaves` —
+    `ssm_state` (B, H, P, N) float32 and `conv_tail` (B, conv - 1, W) — come
+    in and are left as they are after the last REAL position -> SSM(h) (B,
+    T, C). No position enters the rule and the chunk form has no kernel."""
+    del start_pos, kernel
     m = cfg.mamba
     t = h.shape[1]
+    state, tail = leaves["ssm_state"], leaves["conv_tail"]
     with jax.named_scope("ssm.project"):
         z, pre, dt = _project(p, h, m=m, compute_dtype=compute_dtype)
         dt = jnp.where((jnp.arange(t) < n_real)[None, :, None], dt, 0.0)
@@ -274,15 +280,22 @@ def mixer_chunk(p, h, state, tail, n_real, *, cfg, compute_dtype):
     with jax.named_scope("ssm.out"):
         o = _out(p, y, z, h.dtype, m=m, eps=cfg.rms_eps,
                  compute_dtype=compute_dtype)
-    return o, state, new_tail.astype(tail.dtype)
+    leaves.update(ssm_state=state, conv_tail=new_tail.astype(tail.dtype))
+    return o
 
 
-def mixer_step(p, h, state, tail, *, cfg, compute_dtype, rule=step_rule):
-    """The state-space mixer for one token a slot: h (B, 1, C), `state` (B,
-    H, P, N), `tail` (B, conv - 1, W) -> (SSM(h) (B, 1, C), state, tail).
-    `state` is whatever `rule` takes and returns: a layer's states for
-    `step_rule`, the whole leaf for `step_rule_kernel` bound to a layer."""
+def mixer_step(p, h, leaves, pos, *, cfg, compute_dtype, kernel=False,
+               layer=None):
+    """The rule's step form: one token a slot, h (B, 1, C), `leaves` as
+    `mixer_chunk`'s -> SSM(h) (B, 1, C). `ssm_state` is one
+    layer's states for the plain form; under `kernel` (True / "interpret")
+    the WHOLE leaf, updated in place at `layer` (`step_rule_kernel`)."""
+    del pos
     m = cfg.mamba
+    tail, state = leaves["conv_tail"], leaves["ssm_state"]
+    rule = functools.partial(
+        step_rule_kernel, layer=layer,
+        interpret=kernel == "interpret") if kernel else step_rule
     with jax.named_scope("ssm.project"):
         z, pre, dt = _project(p, h, m=m, compute_dtype=compute_dtype)
     with jax.named_scope("ssm.conv"):
@@ -294,19 +307,8 @@ def mixer_step(p, h, state, tail, *, cfg, compute_dtype, rule=step_rule):
     with jax.named_scope("ssm.out"):
         o = _out(p, y[:, None], z, h.dtype, m=m, eps=cfg.rms_eps,
                  compute_dtype=compute_dtype)
-    return o, state, rows[:, 1:].astype(tail.dtype)
-
-
-def dense_mixer(p, h, *, cfg, compute_dtype):
-    """The state-space mixer over whole sequences h (B, T, C) from an empty
-    state: the chunked rule, T padded up to whole chunks."""
-    b, t, _ = h.shape
-    pad = -t % cfg.mamba.chunk
-    s0 = state_kind.fresh(slot_leaves(cfg.mamba), b, h.dtype)
-    o, _, _ = mixer_chunk(p, jnp.pad(h, ((0, 0), (0, pad), (0, 0))),
-                          s0["ssm_state"], s0["conv_tail"], jnp.int32(t),
-                          cfg=cfg, compute_dtype=compute_dtype)
-    return o[:, :t]
+    leaves.update(ssm_state=state, conv_tail=rows[:, 1:].astype(tail.dtype))
+    return o
 
 
 def mixers_sum(attn_o, ssm_o, cfg):
@@ -318,89 +320,12 @@ def mixers_sum(attn_o, ssm_o, cfg):
             ).astype(attn_o.dtype)
 
 
-class HybridRows(llama.LlamaKindRows):
-    """`LlamaKindRows` for a model whose every layer runs softmax attention
-    AND a state-space mixer: ONE cache kind, "full", with paged `leaves` {k,
-    v} under `tables` AND `slot_leaves` {`ssm_state` (L, slots, H, P, N)
-    float32, `conv_tail` (L, slots, conv - 1, W)}. The pool carries all four
-    through the layer loop; a decode step runs the paged read and the
-    one-token rule on the same normed input, each in place — the K/V blocks
-    through the slot's table, the state at the layer's index and the slot's
-    row (on the chip ONE kernel's pass over the whole leaf, `_step_kernel`)
-    — and adds their scaled outputs before the residual. The
-    finish-and-install program installs the row's blocks and writes the
-    transient row's running state and tail into the slot, which is also what
-    resets a slot; the chunk program is told how many of its positions are
-    real (`takes_n_real`). Admission is bounded by slots AND by blocks. What
-    assumes K and V alone — the prefix store, the KV tier, int8 / int4 pools,
-    interleaved prefill, speculative verify — is refused by the batcher at
-    construction, by the leaves' names."""
+def _init_block(blk, key, cfg, dtype):
+    blk["ssm"] = init_mixer(jax.random.fold_in(key, 31), cfg, dtype)
 
-    takes_n_real = True
 
-    def __init__(self, cfg, **kw):
-        super().__init__(cfg, **kw)
-        self.paged_ok = False  # a verifier would have no state to rewind
-        self.cache_kinds["full"]["slot_leaves"] = slot_leaves(cfg.mamba)
-        self.attn_forms["full"].update(ssm_prefill="chunked_jnp",
-                                       ssm_decode="step_jnp")
-
-    def _step_kernel(self):
-        """Whether the one-token rule runs in the Pallas kernel: on the
-        chip unless the family's kernels are off, interpreted where a test
-        asks."""
-        if self.attn_kernel == "interpret":
-            return "interpret"
-        return bool(self.attn_kernel) and jax.default_backend() == "tpu"
-
-    def init_cache(self, batch, max_len, dtype):
-        return {**super().init_cache(batch, max_len, dtype),
-                **state_kind.fresh(slot_leaves(self.cfg.mamba), batch, dtype,
-                                   self.cfg.n_layer)}
-
-    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, n_real=None):
-        cfg = self.cfg
-        with jax.named_scope("llama.block.cached_attn"):
-            h = llama._pre_normed(bp, x, cfg)
-            o, rows = self._chunk_attn(bp, h, rows, start_pos, kind)
-            s, state, tail = mixer_chunk(
-                bp["ssm"], h, rows["ssm_state"], rows["conv_tail"],
-                x.shape[1] if n_real is None else n_real, cfg=cfg,
-                compute_dtype=self.compute_dtype)
-            o = mixers_sum(o, s, cfg)
-        with jax.named_scope("llama.block.mlp"):
-            return (llama._branches_residual(
-                bp, x, o, h, cfg=cfg, compute_dtype=self.compute_dtype,
-                ffn=ffn), {**rows, "ssm_state": state, "conv_tail": tail})
-
-    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
-                moe_stats=False, n_real=None):
-        kind = self.cache_kinds["full"]
-        return llama.prefill_by_kind(
-            self, prepared, padded, row_cache, start_pos, moe_stats,
-            {"full": (*kind["leaves"], *kind["slot_leaves"])}, n_real=n_real)
-
-    def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
-                   kind="full"):
-        h, o, c = super()._attn_rows(bp, x, layer_cache, pos, write, codec,
-                                     window, kind)
-        layer = codec.layer
-        kernel = self._step_kernel()
-        self.attn_forms["full"]["ssm_decode"] = (
-            "step_kernel" if kernel else "step_jnp")
-        # the kernel takes the WHOLE leaf and hands it back updated in place
-        rule = functools.partial(
-            step_rule_kernel, layer=layer,
-            interpret=kernel == "interpret") if kernel else step_rule
-        with jax.named_scope("state_pool.read"):
-            tail = c["conv_tail"][layer]
-            state = c["ssm_state"] if kernel else c["ssm_state"][layer]
-        s, state, tail = mixer_step(bp["ssm"], h, state, tail, cfg=self.cfg,
-                                    compute_dtype=self.compute_dtype,
-                                    rule=rule)
-        with jax.named_scope("state_pool.write"):
-            if not kernel:
-                state = c["ssm_state"].at[layer].set(state)
-            c = {**c, "ssm_state": state,
-                 "conv_tail": c["conv_tail"].at[layer].set(tail)}
-        return h, mixers_sum(o, s, self.cfg), c
+RULE = state_kind.Rule(
+    field="mamba", kind="full", params="ssm", slot_leaves=slot_leaves,
+    init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="step",
+    whole=("ssm_state",), beside=mixers_sum,
+    forms=("ssm_prefill", "ssm_decode"))

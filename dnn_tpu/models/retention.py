@@ -25,8 +25,9 @@ for d 128, t 8 (the untiled symmetric square is 8 256, the full outer product
 a layer whatever the length** (35.9 MB a layer at the published widths: the
 K and V of 8 772 positions) — the cache kind "retention" has two leaves
 without a position axis, `state` (L, slots, KV, d, D) and `norm` (L, slots,
-KV, D), no blocks and no tables (`RetentionRows.cache_kinds`; runtime/
-paged_kvcache.py's module docstring). The state is held value-major (d x
+KV, D), no blocks and no tables (`slot_leaves`, behind models/state_kind.py
+`StateKindRows` as this module's `RULE`; runtime/paged_kvcache.py's module
+docstring). The state is held value-major (d x
 D): the expanded axis fills the lanes, and a step's rank-one update needs
 the VALUE as a column (d numbers) and the expanded key as a row.
 
@@ -303,12 +304,13 @@ def _out(bp, y, x_dtype, *, compute_dtype):
                   compute_dtype=compute_dtype)
 
 
-def mixer_chunk(bp, h, state, norm, start_pos, n_real, *, cfg,
-                compute_dtype):
-    """The retention mixer over a chunk h (B, T, C) at positions
-    [start_pos, start_pos + T) whose first `n_real` are real: `state` (B,
-    KV, d, D) and `norm` (B, KV, D) come in -> (the mixer's output (B, T,
-    C), state and norm after the last REAL position)."""
+def mixer_chunk(bp, h, leaves, start_pos, n_real, *, cfg, compute_dtype,
+                kernel=False):
+    """The rule's chunk form (`state_kind.Rule`): the retention mixer over a
+    chunk h (B, T, C) at positions [start_pos, start_pos + T) whose first
+    `n_real` are real; `leaves` — `state` (B, KV, d, D) and `norm` (B, KV,
+    D) — come in and are left as they are after the last REAL position ->
+    the mixer's output (B, T, C). No kernel: `kernel` is ignored."""
     m = cfg.retention
     t = h.shape[1]
     with jax.named_scope("ret.project"):
@@ -320,158 +322,47 @@ def mixer_chunk(bp, h, state, norm, start_pos, n_real, *, cfg,
     with jax.named_scope("ret.chunk"):
         y, state, norm = chunk_rule(
             _grouped(q, cfg), k, v.astype(jnp.float32),
-            jnp.moveaxis(logg, 1, 2), state, norm,
+            jnp.moveaxis(logg, 1, 2), leaves["state"], leaves["norm"],
             chunk=math.gcd(m.chunk, t), tile=m.tile, eps=m.eps,
             mm_dtype=compute_dtype, fresh=start_pos == 0)
+    leaves.update(state=state, norm=norm)
     with jax.named_scope("ret.out"):
-        return _out(bp, y, h.dtype, compute_dtype=compute_dtype), state, norm
+        return _out(bp, y, h.dtype, compute_dtype=compute_dtype)
 
 
-def fresh_state(cfg, batch, layers=None):
-    """Zeros of the retention kind's two leaves for `batch` slots, with a
-    leading layer axis where `layers` is given."""
-    return state_kind.fresh(slot_leaves(cfg), batch, layers=layers)
+def mixer_step(bp, h, leaves, pos, *, cfg, compute_dtype, kernel=False,
+               layer=None):
+    """The rule's step form: one token a slot, h (B, 1, C) at per-slot
+    positions `pos` (B,) -> the mixer's output (B, 1, C). `leaves` are one
+    layer's for the plain form; under `kernel` (True / "interpret") the
+    WHOLE pool's, updated in place at `layer` (`step_rule_kernel`). They are
+    read after the projection and written before the output's."""
+    m = cfg.retention
+    with jax.named_scope("ret.project"):
+        q, k, v = llama.qkv_rows(bp, h, pos, cfg=cfg,
+                                 compute_dtype=compute_dtype)
+        q = _grouped(q, cfg)[:, :, :, 0]
+        k, v = (a[:, :, 0].astype(jnp.float32) for a in (k, v))
+        logg = log_gate(bp["attn"], h)[:, 0]
+    state, norm = leaves["state"], leaves["norm"]
+    rule = functools.partial(
+        step_rule_kernel, layer=layer,
+        interpret=kernel == "interpret") if kernel else step_rule
+    with jax.named_scope("ret.step"):
+        y, state, norm = rule(q, k, v, logg, state, norm, tile=m.tile,
+                              eps=m.eps, mm_dtype=compute_dtype)
+    leaves.update(state=state, norm=norm)
+    with jax.named_scope("ret.out"):
+        return _out(bp, y[:, :, :, None], h.dtype,
+                    compute_dtype=compute_dtype)
 
 
-def dense_mixer(bp, h, *, cfg, compute_dtype):
-    """The retention mixer over whole sequences h (B, T, C) from an empty
-    state: the chunked rule, T padded up to whole chunks."""
-    b, t, _ = h.shape
-    pad = -t % cfg.retention.chunk
-    s0 = fresh_state(cfg, b)
-    y, _, _ = mixer_chunk(bp, jnp.pad(h, ((0, 0), (0, pad), (0, 0))),
-                          s0["state"], s0["norm"], 0, jnp.int32(t), cfg=cfg,
-                          compute_dtype=compute_dtype)
-    return y[:, :t]
+def _init_block(blk, key, cfg, dtype):
+    del dtype  # the gate is float32
+    blk["attn"]["decay"] = init_gate(jax.random.fold_in(key, 29), cfg)
 
 
-class RetentionRows(llama.LlamaFamilyRows):
-    """`LlamaFamilyRows` for a model whose every layer keeps a state and
-    none keeps K or V: ONE cache kind, "retention", whose leaves `state`
-    (L, slots, KV, d, D) and `norm` (L, slots, KV, D), float32, have no
-    position axis, no blocks and no tables (`cache_kinds["retention"]
-    ["slot_leaves"]`). There is nothing to page: the batcher holds the two
-    leaves as they are, admits by slots alone, and `max_len` bounds
-    positions (the rotation's phases) and no memory. The decode step
-    carries the pool whole through its layer loop and updates every slot's
-    state IN PLACE at the layer's index; the finish-and-install program
-    writes the transient row's running state into the slot, which is also
-    what resets a slot; the chunk program is told how many of its positions
-    are real (`takes_n_real`). What assumes K and V — the prefix store, the
-    KV tier, int8 / int4 pools, interleaved prefill, speculative verify —
-    is refused by the batcher at construction, by the leaves' names."""
-
-    takes_n_real = True
-
-    def __init__(self, cfg, **kw):
-        super().__init__(cfg, **kw)
-        self.paged_ok = False  # no K and V for a verifier to attend
-        self.cache_kinds = {"retention": {
-            "layers": cfg.n_layer, "leaves": {}, "tables": None,
-            "window": None, "slot_leaves": slot_leaves(cfg)}}
-        self.attn_forms = {"retention": {"prefill": "chunked_jnp"}}
-
-    def _step_kernel(self):
-        """Whether the one-token rule runs in the Pallas kernel: on the
-        chip unless the family's kernels are off, interpreted where a test
-        asks."""
-        if self.attn_kernel == "interpret":
-            return "interpret"
-        return bool(self.attn_kernel) and jax.default_backend() == "tpu"
-
-    def init_cache(self, batch, max_len, dtype):
-        if dtype in ("int8", "int4"):
-            raise ValueError("a cache of state leaves is float32")
-        return fresh_state(self.cfg, batch, self.cfg.n_layer)
-
-    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, n_real=None):
-        cfg = self.cfg
-        with jax.named_scope("llama.block.cached_attn"):
-            h = llama._pre_normed(bp, x, cfg)
-            o, state, norm = mixer_chunk(
-                bp, h, rows["state"], rows["norm"], start_pos,
-                x.shape[1] if n_real is None else n_real, cfg=cfg,
-                compute_dtype=self.compute_dtype)
-        with jax.named_scope("llama.block.mlp"):
-            return (llama._branches_residual(
-                bp, x, o, h, cfg=cfg, compute_dtype=self.compute_dtype,
-                ffn=ffn), {"state": state, "norm": norm})
-
-    def prefill(self, prepared, padded, row_cache, start_pos=0, *,
-                moe_stats=False, n_real=None):
-        return llama.prefill_by_kind(
-            self, prepared, padded, row_cache, start_pos, moe_stats,
-            {"retention": tuple(slot_leaves(self.cfg))}, n_real=n_real)
-
-    def _attn_rows(self, bp, x, cache, pos, layer):
-        """One token a slot through layer `layer`'s mixer: x (B, 1, C),
-        `cache` the WHOLE pool -> (normed input, the mixer's output, the
-        pool with the layer's states updated)."""
-        cfg, m = self.cfg, self.cfg.retention
-        kernel = self._step_kernel()
-        self.attn_forms["retention"]["decode"] = (
-            "step_kernel" if kernel else "step_jnp")
-        h = llama._pre_normed(bp, x, cfg)
-        with jax.named_scope("ret.project"):
-            q, k, v = self._qkv_rows(bp, h, pos)
-            q = _grouped(q, cfg)[:, :, :, 0]
-            k, v = (a[:, :, 0].astype(jnp.float32) for a in (k, v))
-            logg = log_gate(bp["attn"], h)[:, 0]
-        if kernel:
-            with jax.named_scope("ret.step"):
-                y, state, norm = step_rule_kernel(
-                    q, k, v, logg, cache["state"], cache["norm"], layer,
-                    tile=m.tile, eps=m.eps, mm_dtype=self.compute_dtype,
-                    interpret=kernel == "interpret")
-            cache = {**cache, "state": state, "norm": norm}
-        else:
-            with jax.named_scope("state_pool.read"):
-                state, norm = cache["state"][layer], cache["norm"][layer]
-            with jax.named_scope("ret.step"):
-                y, state, norm = step_rule(
-                    q, k, v, logg, state, norm, tile=m.tile, eps=m.eps,
-                    mm_dtype=self.compute_dtype)
-            with jax.named_scope("state_pool.write"):
-                cache = {**cache,
-                         "state": cache["state"].at[layer].set(state),
-                         "norm": cache["norm"].at[layer].set(norm)}
-        with jax.named_scope("ret.out"):
-            o = _out(bp, y[:, :, :, None], x.dtype,
-                     compute_dtype=self.compute_dtype)
-        return h, o, cache
-
-    def decode_rows(self, prepared, cache, tok, pos, active, codec, *,
-                    moe_stats=False):
-        """`LlamaFamilyRows.decode_rows` over a pool that is CARRIED whole
-        through the layer loop and reached at the layer's index (as a paged
-        pool is, `paged_kvcache.scan_blocks`): no layer's states are ever
-        cut out of the leaf or stacked back. Every slot's state moves, live
-        or not: a retired slot's is whatever, until an admission installs
-        over it."""
-        cfg = self.cfg
-        x = llama._scaled_embed(prepared, tok[:, None], cfg)  # (B, 1, C)
-        if self.compute_dtype is not None:
-            x = x.astype(self.compute_dtype)
-
-        def block(carry, layer_in):
-            x, cache = carry
-            bp, layer = layer_in
-            with jax.named_scope("llama.block.cached_attn"):
-                h, o, cache = self._attn_rows(bp, x, cache, pos, layer)
-            with jax.named_scope("llama.block.mlp"):
-                x = llama._branches_residual(
-                    bp, x, o, h, cfg=cfg, compute_dtype=self.compute_dtype,
-                    ffn=self.ffn)
-            return (x, cache), None
-
-        with jax.named_scope("layers.scan"):
-            (x, cache), _ = lax.scan(
-                block, (x, cache),
-                (prepared["blocks"], jnp.arange(cfg.n_layer)))
-        logits = llama.head(prepared, x.astype(jnp.float32), cfg=cfg,
-                            compute_dtype=self.compute_dtype)
-        return logits[:, -1], cache
-
-    def verify_rows(self, *a, **kw):
-        raise ValueError("speculative verify attends K and V: not "
-                         "available with the cache leaves state/norm")
+RULE = state_kind.Rule(
+    field="retention", kind="retention", params=None, slot_leaves=slot_leaves,
+    init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="step",
+    whole=("state", "norm"))

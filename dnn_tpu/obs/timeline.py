@@ -1109,6 +1109,10 @@ def active_clock() -> Optional[StepClock]:
 RPC_PARTS = ("select", "fan_out", "token", "rest")
 
 
+# `RpcLoopClock._selecting` between a block's end and its entry in the total
+_ENDING = object()
+
+
 class RpcLoopClock:
     """The event-loop thread's wall time by RPC_PARTS, accumulated by the
     thread itself as the worker's loop accumulates LOOP_PARTS: plain
@@ -1143,7 +1147,8 @@ class RpcLoopClock:
         self._now = now
         self._t_run: Optional[float] = None  # where the run began
         self._claimed = 0.0  # seconds its sections have taken of it
-        self._selecting: Optional[float] = None  # where select() began
+        # where select() began; _ENDING while `select_returns` adds it up
+        self._selecting = None
         self._run_span = None
         self._run_tokens = 0  # `tokens` when the run's annotation opened
 
@@ -1162,12 +1167,14 @@ class RpcLoopClock:
         self._selecting = t
 
     def select_returns(self):
-        # cleared before the clock is read and the total grows: a scrape
-        # that still sees this block (select_seconds) read an earlier
-        # clock and a total without it
-        since, self._selecting = self._selecting, None
+        # marked as ending before the clock is read and the total grows: a
+        # scrape that still sees this block open (select_seconds) read an
+        # earlier clock, and none reads the total between the block's end
+        # and its entry in it
+        since, self._selecting = self._selecting, _ENDING
         t = self._now()
         self.seconds["select"] += t - since
+        self._selecting = None
         self.iterations += 1
         self._t_run, self._claimed = t, 0.0
         if _profile._capturing:
@@ -1198,15 +1205,22 @@ class RpcLoopClock:
         """`seconds["select"]` with the `select()` in progress, for a
         scrape from another thread: an idle loop blocks for as long as
         nothing arrives, and the parts would stop short of the window by
-        that much. The block counts only if it is the same one before
-        the total is read and after the clock is, so no scrape counts a
-        block twice and the series never steps back."""
-        since = self._selecting
-        total = self.seconds["select"]
-        now = self._now()
-        if since is not None and self._selecting is since:
-            return total + (now - since)
-        return self.seconds["select"]
+        that much. The block counts only if it is the same open one before
+        the total is read and after the clock is; a scrape that finds it
+        ending (`select_returns` is between its clock and the total: a few
+        bytecodes) or changed lets the loop thread run and reads again. So
+        no scrape counts a block twice and the series never steps back
+        (tests/test_emit_handoff.py walks every interleaving)."""
+        while True:
+            since = self._selecting
+            total = self.seconds["select"]
+            if since is None:
+                return total
+            if since is not _ENDING:
+                now = self._now()
+                if self._selecting is since:
+                    return total + (now - since)
+            time.sleep(0)
 
 
 class StampedSelector(selectors.DefaultSelector):

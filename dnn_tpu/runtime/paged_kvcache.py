@@ -108,15 +108,14 @@ layer (models/retention.py: every layer keeps `state` (L, B, KV, d, D) and
 `norm` (L, B, KV, D), float32) declares ONE kind whose `tables` is None:
 there is no block, no table, no `BlockAllocator` and no codec for it — the
 batcher holds the family's own `init_cache` leaves, admits by slots, and
-the family's decode loop carries them whole and reaches them at the
-layer's index, as `scan_blocks` carries a paged pool (`serving.py`
-`nothing_to_page`).
+`scan_blocks` carries them whole and reaches them at the layer's index, as
+it carries a paged pool (`serving.py` `nothing_to_page`).
 
 The codec interface matches FloatKV (write_rows / attend_rows /
 write_attend_rows / install_row), so GPTFamilyRows / LlamaFamilyRows
 decode through it unchanged. The decode step reaches the pool IN PLACE:
 the layer loop carries the whole pytree (`scan_blocks`) and the codec
-takes the layer (`PagedKV.at_layer`), so no layer's slice is ever cut
+takes the layer (`_PagedLayer`), so no layer's slice is ever cut
 out of the pool or written back. Attention gathers the slot's blocks
 into a (B, H, S_max, D) view and runs the identical masked einsum — the
 reference math is the dense codec's, so token parity is exact — or, with
@@ -897,13 +896,6 @@ class PagedKV:
                 layer=layer if whole else 0)
         return y.astype(c["v"].dtype), c
 
-    def at_layer(self, layer):
-        """This codec bound to one layer of the WHOLE pool: the same
-        write_attend_rows a block calls on a per-layer cache view, but
-        `c` is the full cache pytree and nothing is ever sliced out of
-        it."""
-        return _PagedLayer(self, layer)
-
     # --- prefill install (full-cache view: pool (L, n_blocks, H, bp, D),
     #     tables (L, B, nb_max)) ---------------------------------------
 
@@ -940,7 +932,10 @@ class PagedKV:
 
 
 class _PagedLayer:
-    """PagedKV.at_layer's binding (see there)."""
+    """A codec bound to one layer of the WHOLE pool (`scan_blocks`): the
+    same write_attend_rows a block calls on a per-layer cache view, but `c`
+    is the full cache pytree and nothing is ever sliced out of it. A pool
+    of state leaves alone has no codec (None): a block reads `layer`."""
 
     def __init__(self, codec: PagedKV, layer):
         self.codec, self.layer = codec, layer
@@ -997,11 +992,12 @@ def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):
     option):
 
       * a paged pool is CARRIED whole and reached by layer index
-        (`codec.at_layer(l)`): the pool (L, n_blocks, H, bp, D) is never
+        (`_PagedLayer`): the pool (L, n_blocks, H, bp, D) is never
         an xs/ys of the scan, so no layer's slice is ever cut out of it
         or written back — the step's donated buffer is the carry and the
         output, touched only by the row scatter and the kernel's block
-        reads;
+        reads; so is a pool of state leaves ALONE, which has no tables and
+        no codec (`codec` None: the block is handed the layer's index);
       * a dense cache (L, B, H, S, D) rides as xs/ys, one layer's slots
         per iteration.
 
@@ -1017,7 +1013,7 @@ def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):
     # and writing the slice back; the block's work carries the inner
     # scopes (gpt.block.*, attn.*, kv_pool.*)
     with jax.named_scope("layers.scan"):
-        if not codec_is_paged(cache):
+        if codec is not None and not codec_is_paged(cache):
             def dense(x, layer_in):
                 bp, c, *rest = layer_in
                 return block(bp, x, c, codec, *rest)
@@ -1026,9 +1022,10 @@ def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):
 
         def paged(carry, layer_in):
             bp, layer, *rest = layer_in
-            return block(bp, *carry, codec.at_layer(layer), *rest), None
+            return block(bp, *carry, _PagedLayer(codec, layer), *rest), None
 
         if layers is None:
-            layers = jnp.arange(cache["tables"].shape[0])
+            layers = jnp.arange(cache.get(
+                "tables", jax.tree.leaves(cache)[0]).shape[0])
         (x, cache), _ = lax.scan(paged, (x, cache), (blocks, layers, *xs))
         return x, cache

@@ -1179,6 +1179,36 @@ def test_lin_step_kernel_compiles(chip):
     assert mem.temp_size_in_bytes < 2 ** 22  # the rows beside the state
 
 
+def test_a_kernels_text_compares_without_its_callers_lines(chip):
+    """`tests/step_program_texts.without_kernel_locations`: one kernel
+    lowered from two call sites differs in its serialized body — the MLIR
+    bytecode carries the callers' lines — and compares equal once the body
+    is printed without debug locations (how PR 60 held the state families'
+    real-width programs to their parent's)."""
+    from dnn_tpu.ops.pallas.lin_step import lin_step
+    from tests.step_program_texts import without_kernel_locations
+
+    b, h, d = 4, 8, 128
+    shapes = (((2, b, h, d, d), F32), ((), jnp.int32), ((h,), F32),
+              ((b, h, d), F32), ((b, h, d), F32), ((b, h, d), F32))
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in shapes]
+
+    def here():
+        def call(*a):
+            return lin_step(*a, interpret=False)
+        return call
+
+    def there():
+        def call(*a):
+            return lin_step(*a, interpret=False)
+        return call
+
+    one, other = (jax.jit(f()).lower(*args).as_text() for f in (here, there))
+    assert "tpu_custom_call" in one and one != other
+    assert without_kernel_locations(one) == without_kernel_locations(other)
+    assert without_kernel_locations("no kernel here") == "no kernel here"
+
+
 def test_block_list_kernel_compiles(chip):
     """ops/pallas/block_list_attention.py at the cell's call: 32 slots x 2
     KV heads of 16 query rows, lists of 96 blocks of 64 positions out of a

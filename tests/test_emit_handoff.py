@@ -407,40 +407,114 @@ def test_a_scrape_never_counts_a_select_twice():
         + clock.select_seconds() == 5.0
 
 
-def test_a_scrape_races_the_loop_threads_stamps_and_never_steps_back():
-    """A thread stamps `select()`s back to back under a switch interval of
-    10 us while this one scrapes: `select_seconds` never decreases, never
-    passes the wall time, and ends at the thread's own total."""
-    import sys
-
+@pytest.mark.parametrize("ahead", [0.0, 0.25])
+def test_a_scrape_at_any_point_of_the_loops_stamps_never_steps_back(
+        monkeypatch, ahead):
+    """`select_seconds` against every interleaving of its reads with the
+    loop thread's writes, from ONE thread and an injected clock: two
+    `select()`s are stamped through a clock whose every write of
+    `_selecting` and of the total, and every clock read, is a MOMENT of a
+    recorded history; the scrape's own code then runs over a view whose
+    every read is answered from a moment of its choosing, no earlier than
+    its last (a yielded scrape resumes later), for every such choice. Its
+    clock reads `ahead` of the loop's last. No scrape counts a select
+    twice (it never passes the select time there was when it ended), none
+    falls short of the total there was when it began, and no scrape that
+    begins where another ended reads less."""
+    from dnn_tpu.obs import timeline
     from dnn_tpu.obs.timeline import RpcLoopClock
 
-    clock = RpcLoopClock()
-    stop = threading.Event()
+    history = []  # (`_selecting`, the total, the clock's last value)
+    stamps = [1.0, 2.5, 4.0, 7.0]  # two selects' begin, end
+    spans, ticks = list(zip(stamps[::2], stamps[1::2])), iter(stamps)
 
-    def loop_thread():
-        while not stop.is_set():
-            clock.select_begins()
-            clock.select_returns()
+    class Stamped(RpcLoopClock):
+        """The loop thread's side, each write and clock read a moment."""
+        t = 0.0
 
-    was = sys.getswitchinterval()
-    sys.setswitchinterval(1e-5)
-    t = threading.Thread(target=loop_thread)
-    try:
-        t0 = time.perf_counter()
-        t.start()
-        last, reads = 0.0, 0
-        while time.perf_counter() - t0 < 0.3:
-            seen = clock.select_seconds()
-            assert last <= seen <= time.perf_counter() - t0
-            last, reads = seen, reads + 1
-    finally:
-        stop.set()
-        t.join(timeout=10)
-        sys.setswitchinterval(was)
-    assert not t.is_alive() and reads > 100 and clock.iterations > 100
-    assert clock.select_seconds() == clock.seconds["select"] >= last
-    assert sum(clock.seconds.values()) <= time.perf_counter() - t0
+        def moment(self):
+            history.append((RpcLoopClock._selecting.__get__(self),
+                            dict.__getitem__(self.seconds, "select"), self.t))
+
+        @property
+        def _selecting(self):
+            return RpcLoopClock._selecting.__get__(self)
+
+        @_selecting.setter
+        def _selecting(self, since):
+            RpcLoopClock._selecting.__set__(self, since)
+            if history:
+                self.moment()
+
+    class Seconds(dict):
+        def __setitem__(self, part, value):
+            super().__setitem__(part, value)
+            clock.moment()
+
+    def tick():
+        clock.t = next(ticks)
+        clock.moment()
+        return clock.t
+
+    clock = Stamped(now=tick)
+    clock.seconds = Seconds(clock.seconds)
+    clock.moment()
+    for _ in spans:
+        clock.select_begins()
+        clock.select_returns()
+    assert clock.seconds["select"] == sum(e - b for b, e in spans) == 4.5
+    last = len(history) - 1
+
+    def select_time(t):
+        """The time inside `select()` up to the clock's t."""
+        return sum(max(0.0, min(e, t) - b) for b, e in spans)
+
+    class Scrape:
+        """What `select_seconds` reads, each read at a scripted moment."""
+
+        def __init__(self, script):
+            self.script, self.taken, self.floor = script, [], 0
+
+        def read(self, field):
+            i = len(self.taken)
+            at = self.script[i] if i < len(self.script) else self.floor
+            assert self.floor <= at <= last
+            self.taken.append(at)
+            self.floor = at
+            return history[at][field]
+
+        def yielded(self, _seconds):
+            self.floor = min(self.floor + 1, last)
+
+        _selecting = property(lambda self: self.read(0))
+        seconds = property(lambda self: {"select": self.read(1)})
+
+        def _now(self):
+            return self.read(2) + ahead
+
+    began, ended = {}, {}  # a moment -> the least / the most a scrape read
+    script, runs = [], 0
+    monkeypatch.setattr(timeline.time, "sleep",
+                        lambda seconds: scrape.yielded(seconds))
+    while True:
+        scrape = Scrape(script)
+        seen = RpcLoopClock.select_seconds(scrape)
+        first, end = scrape.taken[0], scrape.taken[-1]
+        assert history[first][1] <= seen <= select_time(
+            history[end][2] + ahead), scrape.taken
+        began[first] = min(seen, began.get(first, seen))
+        ended[end] = max(seen, ended.get(end, seen))
+        runs += 1
+        script = scrape.taken
+        while script and script[-1] == last:
+            script.pop()
+        if not script:
+            break
+        script[-1] += 1
+    assert runs > 1000 and set(began) == set(range(last + 1))
+    for end, most in ended.items():
+        for first, least in began.items():
+            assert first < end or most <= least, (end, first)
 
 
 def test_rpc_sections_are_counted_once_and_spans_only_in_a_capture(

@@ -121,7 +121,7 @@ def test_the_published_model_and_its_cut():
     assert ssm == 68_351_072 and attn == 31_457_280
     assert round((ssm + attn + 3 * c * f + 2 * c) / 1e6, 1) == 430.1
     # a slot's state a layer: 4.19 MB float32, the K and V of 2048 positions
-    leaves = mamba2.slot_leaves(m)
+    leaves = mamba2.slot_leaves(cfg)
     shape, dtype = leaves["ssm_state"]
     assert shape == (32, 128, 256) and dtype == jnp.float32
     assert int(np.prod(shape)) * 4 == 4_194_304 == 2048 * (4 * 128 * 2 * 2)
@@ -271,34 +271,36 @@ def test_the_step_kernel_serves_the_plain_steps_logprobs(model, plain):
 # (3) the padded tail leaves state and convolution tail alone
 def test_a_padded_tail_leaves_state_and_tail_alone(model):
     _, cfg, params = model
-    m = cfg.mamba
     p = params["h_1"]["ssm"]
     h = jax.random.normal(jax.random.PRNGKey(5), (1, PAD, cfg.n_embd))
-    fresh = state_kind.fresh(mamba2.slot_leaves(m), 1, jnp.float32)
-    s0 = fresh["ssm_state"] + 0.3
+    fresh = state_kind.fresh(mamba2.slot_leaves(cfg), 1, jnp.float32)
+    fresh["ssm_state"] = fresh["ssm_state"] + 0.3
     n_real = 11
-    o_pad, s_pad, tail_pad = mamba2.mixer_chunk(
-        p, h, s0, fresh["conv_tail"], jnp.int32(n_real), cfg=cfg,
-        compute_dtype=None)
+
+    def chunk(rows, n):
+        """(the output, the leaves a chunk of `rows` leaves behind)."""
+        leaves = dict(fresh)
+        o = mamba2.mixer_chunk(p, rows, leaves, 0, jnp.int32(n), cfg=cfg,
+                               compute_dtype=None)
+        return o, leaves
+
+    o_pad, pad = chunk(h, n_real)
     # the same real rows followed by OTHER pad rows: nothing real moves
     other = h.at[:, n_real:].set(7.0)
-    o2, s2, tail2 = mamba2.mixer_chunk(
-        p, other, s0, fresh["conv_tail"], jnp.int32(n_real), cfg=cfg,
-        compute_dtype=None)
-    assert jnp.array_equal(s_pad, s2) and jnp.array_equal(tail_pad, tail2)
+    o2, pad2 = chunk(other, n_real)
+    assert jnp.array_equal(pad["ssm_state"], pad2["ssm_state"])
+    assert jnp.array_equal(pad["conv_tail"], pad2["conv_tail"])
     assert jnp.array_equal(o_pad[:, :n_real], o2[:, :n_real])
     # and they are what a chunk of the real rows alone leaves behind
-    step_s, step_tail = s0, fresh["conv_tail"]
+    step = dict(fresh)
     for i in range(n_real):
-        _, step_s, step_tail = mamba2.mixer_step(
-            p, h[:, i:i + 1], step_s, step_tail, cfg=cfg, compute_dtype=None)
-    assert float(jnp.abs(s_pad - step_s).max()) < 1e-5
-    assert float(jnp.abs(tail_pad - step_tail).max()) < 1e-6
+        mamba2.mixer_step(p, h[:, i:i + 1], step, None, cfg=cfg,
+                          compute_dtype=None)
+    assert float(jnp.abs(pad["ssm_state"] - step["ssm_state"]).max()) < 1e-5
+    assert float(jnp.abs(pad["conv_tail"] - step["conv_tail"]).max()) < 1e-6
     # a program NOT told its count of real positions moves the state
-    _, s_all, _ = mamba2.mixer_chunk(p, other, s0, fresh["conv_tail"],
-                                     jnp.int32(PAD), cfg=cfg,
-                                     compute_dtype=None)
-    assert float(jnp.abs(s_all - s_pad).max()) > 1e-2
+    _, whole = chunk(other, PAD)
+    assert float(jnp.abs(whole["ssm_state"] - pad["ssm_state"]).max()) > 1e-2
 
 
 # (4) a retired and re-admitted slot is a fresh daemon's
@@ -418,20 +420,18 @@ def test_a_state_in_bfloat16_is_told_apart_on_the_cpu(model):
     """What `correct` cannot see on the chip: the state rounded to bfloat16
     after every step misses the tolerance."""
     _, cfg, params = model
-    m = cfg.mamba
     p = params["h_0"]["ssm"]
     h = jax.random.normal(jax.random.PRNGKey(9), (1, 40, cfg.n_embd))
-    fresh = state_kind.fresh(mamba2.slot_leaves(m), 1, jnp.float32)
+    fresh = state_kind.fresh(mamba2.slot_leaves(cfg), 1, jnp.float32)
     outs = {}
     for name, cast in (("f32", lambda s: s),
                        ("bf16", lambda s: s.astype(jnp.bfloat16).astype(
                            jnp.float32))):
-        s, tail, ys = fresh["ssm_state"], fresh["conv_tail"], []
+        leaves, ys = dict(fresh), []
         for i in range(40):
-            y, s, tail = mamba2.mixer_step(p, h[:, i:i + 1], s, tail, cfg=cfg,
-                                           compute_dtype=None)
-            s = cast(s)
-            ys.append(y)
+            ys.append(mamba2.mixer_step(p, h[:, i:i + 1], leaves, None,
+                                        cfg=cfg, compute_dtype=None))
+            leaves["ssm_state"] = cast(leaves["ssm_state"])
         outs[name] = jnp.concatenate(ys, 1)
     scale = float(jnp.abs(outs["f32"]).max())
     assert float(jnp.abs(outs["f32"] - outs["bf16"]).max()) > 1e-4 * scale

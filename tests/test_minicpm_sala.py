@@ -413,7 +413,7 @@ def test_the_step_kernel_is_the_step_rule():
     """ops/pallas/lin_step.py, interpreted, on a whole leaf of two layers at
     the served head width: layer 1's states get the plain rule's update and
     answers, layer 0's are untouched. (The test preset's heads are 16 wide:
-    the family keeps the plain form there, `_step_kernel`.)"""
+    the family keeps the plain form there, `kernel_form`.)"""
     n_layer, b, h, d = 2, 3, 32, 128
     ks = jax.random.split(jax.random.PRNGKey(0), 4)
     pool = jax.random.normal(ks[0], (n_layer, b, h, d, d))
@@ -429,10 +429,11 @@ def test_the_step_kernel_is_the_step_rule():
     spec = get_model("minicpm-sala-test")
     family = spec.extras["family_rows"]()
     family.attn_kernel = "interpret"
-    assert family._step_kernel() is False
+    assert family.kernel_form("step") is False
     served = llama.family_rows(get_model("minicpm-sala-pp8-1chip").config,
                                attn_kernel="interpret")
-    assert served._step_kernel() == "interpret"
+    assert served.kernel_form("step") == "interpret"
+    assert served.kernel_form("chunk") is False
 
 
 # (5) a slot freed and reused

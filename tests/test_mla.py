@@ -596,53 +596,9 @@ def test_stack_and_release_holds_two_stacks(model):
 
 
 # ----------------------------------------------------------------------
-# the other families' programs are what they were
+# the other families' pools are what they were (their step programs:
+# tests/test_step_programs.py)
 # ----------------------------------------------------------------------
-
-@pytest.fixture(scope="module")
-def parent_texts():
-    with open(os.path.join(REPO, "tests",
-                           "step_program_texts_parent.json")) as f:
-        return json.load(f)
-
-
-#: the decode step's hashes on the commit before PR 38, which moved the
-#: head out of the chunk program into the finish and did not touch the step
-DECODE_BEFORE_PR38 = {
-    "gpt2-test":
-        "71921c5a6ad4cd704980013f2b2edfd6bb19d7ea160083c34ad1b16e1c6613f0",
-    "olmoe-test":
-        "d655d320380938ab94c0f90a923d363c1352957960fdf5d4ab824ee16d298a9b",
-    "keye-test":
-        "11ffd38d3a905a2bbcc0e80c296e744a80a0509f4b44b0a595bf21ebf8c198ac",
-}
-
-
-@pytest.mark.parametrize("preset", ["gpt2-test", "olmoe-test", "keye-test",
-                                    "joyai-test", "dots3-test",
-                                    "k-exaone-test"])
-def test_step_programs_lower_to_the_parents_text(parent_texts, preset):
-    """The chunk, finish-and-install and decode programs of the GPT-2,
-    OLMoE and Keye families lower to the recorded text (its sha256, by
-    tests/step_program_texts.py), and JoyAI's and dots3's to what they
-    were on the commit before PR 43 (which gave K and V leaves their
-    layer kinds), K-EXAONE's to what they were before PR 47 (which put a
-    state kind and an output gate beside them). The decode programs: GPT-2's as on the
-    commit before PR 35; OLMoE's and Keye's as PR 36 left them, which took
-    the expert stacks out of the layer loops' xs on purpose. The chunk and
-    finish programs: as PR 38 left them (the chunk ends at the last block,
-    the finish applies the head to one row), with the decode step held to
-    what it was before."""
-    import hashlib
-
-    from tests.step_program_texts import texts
-
-    got = {name: hashlib.sha256(t.encode()).hexdigest()
-           for name, t in texts(preset).items()}
-    if preset in DECODE_BEFORE_PR38:
-        assert got["_decode"] == DECODE_BEFORE_PR38[preset]
-    assert got == parent_texts[preset]
-
 
 def test_families_with_k_and_v_get_the_pools_they_got():
     for preset, leaves in (("olmoe-test", ["k", "tables", "v"]),
