@@ -45,10 +45,13 @@ Two forms of the same numbers:
     scaled against the later sub-block's first cumulative log-decay; the
     triangular system is solved by exact block substitution (no power of
     its matrix: a run of equal keys makes them huge before they cancel).
-    All of that is independent of the state and is made for every chunk
-    of a prefill chunk at once; the scan over chunks is four matmuls a
-    chunk — on the chip in ops/pallas/delta_scan.py, the state resident
-    in fast memory (`StateKindRows.kernel_form`).
+    All of that is independent of the state. The plain form (the CPU's,
+    and the tests' twin of the kernel) makes it for every chunk of a
+    prefill chunk at once, as arrays with a pair axis in HBM, and scans
+    the chunks with four matmuls each; on the chip ONE kernel does all of
+    it, a chunk of a few heads a grid step, in fast memory
+    (ops/pallas/delta_rule.py, behind `StateKindRows.kernel_form`): what
+    crosses HBM is q, k, v, g, beta and the state.
   * a PAD position (at or past `n_real` in the chunk) is the identity on
     S — beta 0, g 0 — and does not enter the tail: a recurrence has no
     mask to hide a padded tail behind, so the chunk program is told how
@@ -56,10 +59,12 @@ Two forms of the same numbers:
 
 The rule's own arithmetic is float32 at "highest" matmul precision (it is
 ~9 GFLOP a 1024-position chunk a layer: nothing beside the projections);
-the projections run in the compute dtype. The step, and everything of
-the chunked rule but its scan, are plain `jax.numpy` inside the step and
-chunk programs (what was measured, and the pair-by-pair form this one
-replaced: PERF.md section 6, PR 47).
+the projections run in the compute dtype. The step is plain `jax.numpy`
+inside the step program; so is the chunked rule wherever the kernel is not
+built for the widths (`RULE.fits`: heads of 128 lanes, chunks of 64; a
+prefill chunk that is not whole chunks) or the backend is not the chip.
+(What was measured: PERF.md section 6 — PR 47, the pair-by-pair form the
+blocked one replaced and the scan's kernel; PR 61, the whole rule's.)
 """
 
 from __future__ import annotations
@@ -219,14 +224,17 @@ def chunk_rule(q, k, v, g, beta, state, *, chunk, block=16, kernel=False):
     (B, H, T), all float32; `state` (B, H, d, d) the incoming S -> (o (B,
     H, T, d), the outgoing S). T is a multiple of `chunk`.
 
-    What does not depend on the state is made for ALL chunks at once: the
-    decay products A and B — between sub-blocks of `block` positions as
-    matmuls of rows and columns scaled against the later sub-block's first
-    cumulative log-decay (both exponents <= 0), inside a sub-block pair by
-    pair —, T = (I + Diag(beta) A)^-1 by exact block substitution, and W =
-    T Diag(beta) (K * exp G), U~ = T Diag(beta) V. The scan over chunks is
-    then four matmuls a chunk: U = U~ - W S, O = (Q * exp G) S + B U, S' =
-    Diag(exp G_C) S + (K * exp(G_C - G))^T U."""
+    `kernel` (True / "interpret"): ops/pallas/delta_rule.py makes all of
+    it in fast memory, a chunk of a few heads a grid step (d a multiple of
+    128, `block` of 8). The plain form below makes what does not depend on
+    the state for ALL chunks at once: the decay products A and B — between
+    sub-blocks of `block` positions as matmuls of rows and columns scaled
+    against the later sub-block's first cumulative log-decay (both
+    exponents <= 0), inside a sub-block pair by pair —, T = (I + Diag(beta)
+    A)^-1 by exact block substitution, and W = T Diag(beta) (K * exp G),
+    U~ = T Diag(beta) V. The scan over chunks is then four matmuls a chunk:
+    U = U~ - W S, O = (Q * exp G) S + B U, S' = Diag(exp G_C) S + (K *
+    exp(G_C - G))^T U."""
     b, h, t, d = q.shape
     n = t // chunk
     block = math.gcd(block, chunk)
@@ -236,6 +244,13 @@ def chunk_rule(q, k, v, g, beta, state, *, chunk, block=16, kernel=False):
         return x.reshape(b, h, n, chunk, *x.shape[3:])
 
     q, k, v, g, beta = (split(x) for x in (q, k, v, g, beta))
+    if kernel:  # everything below, a chunk of a few heads a grid step
+        from dnn_tpu.ops.pallas.delta_rule import delta_rule
+
+        o, state = delta_rule(*(x.reshape(b * h, *x.shape[2:]) for x in (
+            q, k, v, g, beta, state)), block=block,
+            interpret=kernel == "interpret")
+        return o.reshape(b, h, t, d), state.reshape(b, h, d, d)
     cum = jnp.cumsum(g, axis=3)  # G_r (B, H, n, c, d)
     # sub-blocks: (B, H, n, m, block, d); `ref` the cumulative log-decay
     # before a sub-block's first position
@@ -282,14 +297,6 @@ def chunk_rule(q, k, v, g, beta, state, *, chunk, block=16, kernel=False):
     k_out = k * jnp.exp(last - cum)
     decay = jnp.swapaxes(jnp.exp(last), 3, 4)  # (B, H, n, d, 1)
 
-    if kernel:  # the scan with the state resident in fast memory
-        from dnn_tpu.ops.pallas.delta_scan import delta_scan
-
-        o, state = delta_scan(*(x.reshape(b * h, *x.shape[2:]) for x in (
-            w, u0, q * into, bq, k_out, decay, state)),
-            interpret=kernel == "interpret")
-        return o.reshape(b, h, t, d), state.reshape(b, h, d, d)
-
     def one(s0, xs):
         w_c, u_c, q_c, b_c, k_c, a_c = xs
         u = u_c - jnp.einsum("bhrc,bhcv->bhrv", w_c, s0, precision=_HI)
@@ -322,8 +329,9 @@ def mixer_chunk(p, h, leaves, start_pos, n_real, *, cfg, compute_dtype,
     chunk h (B, T, C) whose first `n_real` positions are real; `leaves` —
     `state` (B, H, d, d) float32 and `conv_tail` (B, conv - 1, 3 H d) —
     come in and are left as they are after the last REAL position -> y (B,
-    T, C). `kernel` (True / "interpret"): the scan over the rule's
-    chunks runs in ops/pallas/delta_scan.py. No position enters the rule."""
+    T, C). `kernel` (True / "interpret"): the chunked rule runs in
+    ops/pallas/delta_rule.py where T is whole chunks of `cfg.kda.chunk`,
+    else in the plain form. No position enters the rule."""
     del start_pos
     m = cfg.kda
     t = h.shape[1]
@@ -340,7 +348,7 @@ def mixer_chunk(p, h, leaves, start_pos, n_real, *, cfg, compute_dtype,
         c = math.gcd(m.chunk, t)
         o, state = chunk_rule(q, k, v, jnp.moveaxis(g, 1, 2),
                               jnp.moveaxis(beta, 1, 2), state, chunk=c,
-                              kernel=kernel)
+                              kernel=c == m.chunk and kernel)
     with jax.named_scope("kda.out"):
         y = _out(p, o, gate, h.dtype, m=m, eps=cfg.rms_eps,
                  compute_dtype=compute_dtype)
@@ -376,4 +384,7 @@ def _init_block(blk, key, cfg, dtype):
 
 RULE = state_kind.Rule(
     field="kda", kind="linear", params="attn", slot_leaves=slot_leaves,
-    init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="chunk")
+    init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="chunk",
+    # ops/pallas/delta_rule.py: a head's channels the 128 lanes, a chunk of
+    # 64 positions in four sub-blocks of 16
+    fits=lambda cfg: cfg.kda.head_dim % 128 == 0 and cfg.kda.chunk == 64)

@@ -15,9 +15,13 @@ the four STATE families (`STATE_PRESETS`: Solar Open 2, Brumby, Falcon-H1,
 MiniCPM-SALA) on PR 60's parent, before their four adapters became
 `models/state_kind.py`'s one — in the plain form and, as
 "<preset>@interpret", with the family's kernels interpreted (the
-delta-scan, retention-step, ssm-step and block-list calls with their
-aliasing and scalar prefetch are then in the text; `minicpm-sala-test`'s
-heads are 16 wide, where the family keeps lin-step's plain form)."""
+retention-step, ssm-step and block-list calls with their aliasing and
+scalar prefetch are then in the text; `minicpm-sala-test`'s and
+`solar-open2-test`'s heads are 16 wide, where the families keep
+lin-step's and the chunked delta rule's plain forms: PR 61, whose kernel
+for the whole chunked rule is built for heads of 128, re-recorded
+`solar-open2-test@interpret`'s chunk program, which held PR 47's scan
+kernel until then)."""
 
 import base64
 import functools

@@ -270,18 +270,20 @@ def test_folded_prefill_kernel_compiles(chip, window, tile):
              cache, cache, ((1,), jnp.int32))
 
 
-def test_delta_scan_kernel_compiles(chip):
+def test_delta_rule_kernel_compiles(chip):
     """Solar-Open2's chunked delta rule as its cell's chunk program runs
     it: 64 heads, sixteen closed-form chunks of 64 positions of a
-    1024-token prefill chunk, heads of 128, the (128, 128) float32 state a
-    head resident across the chunk axis."""
-    from dnn_tpu.ops.pallas.delta_scan import delta_scan
+    1024-token prefill chunk, heads of 128 — q, k, v, the log-decay and
+    beta in, the (128, 128) float32 state a head resident across the chunk
+    axis; the cumulative log-decay, the decay products, the substitution
+    and the state's products made in the grid step, eight heads in
+    lockstep."""
+    from dnn_tpu.ops.pallas.delta_rule import delta_rule
 
     g, n, c, d = 64, 16, 64, 128
     tile = ((g, n, c, d), F32)
-    _compile(chip, lambda *a: delta_scan(*a, interpret=False),
-             tile, tile, tile, ((g, n, c, c), F32), tile,
-             ((g, n, d, 1), F32), ((g, d, d), F32))
+    _compile(chip, lambda *a: delta_rule(*a, interpret=False),
+             tile, tile, tile, tile, ((g, n, c), F32), ((g, d, d), F32))
 
 
 @pytest.mark.parametrize("shape", [(8, 12, 1024, 64), (2, 8, 1024, 128)])
