@@ -158,7 +158,7 @@ def dense_attn(bp, h, *, cfg, compute_dtype):
     positions = jnp.arange(t)
     q, k, v = llama._qkv_rope(bp, h, positions, cfg=cfg,
                               compute_dtype=compute_dtype,
-                              rope=llama.kv_kinds(cfg)["full"].rope)
+                              kind=llama.kv_kinds(cfg)["full"])
     with jax.named_scope("bsel.pool"):
         kp = jnp.pad(k, ((0, 0), (0, 0), (0, -t % m.block), (0, 0)))
         kc = pooled_rows(jnp.zeros_like(kp[:, :, :m.stride]), kp, m)
@@ -187,7 +187,7 @@ def chunk_attn(bp, h, rows, start_pos, *, cfg, compute_dtype, attn_kernel):
     positions = start_pos + jnp.arange(t)
     q, k, v = llama._qkv_rope(bp, h, positions, cfg=cfg,
                               compute_dtype=compute_dtype,
-                              rope=llama.kv_kinds(cfg)["full"].rope)
+                              kind=llama.kv_kinds(cfg)["full"])
     kst = k.astype(rows["k"].dtype)  # as the pool holds it
     with jax.named_scope("bsel.pool"):
         # the stride before the chunk, from the row (at start 0 whatever
@@ -287,7 +287,7 @@ class BlockSelectRows(state_kind.StateKindRows):
             return super()._attn_rows(bp, x, layer_cache, pos, write, codec,
                                       window, kind)
         h = llama._pre_normed(bp, x, self.cfg)
-        q, k, v = self._qkv_rows(bp, h, pos, rope=self.kinds[kind].rope)
+        q, k, v = self._qkv_rows(bp, h, pos, kind=self.kinds[kind])
         y, layer_cache, form = decode_attn(
             q, k, v, layer_cache, pos, write, codec, cfg=self.cfg)
         self.attn_forms[kind]["decode"] = form

@@ -38,6 +38,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -164,6 +165,33 @@ class BlockSelectConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class Rotation:
+    """One rotary table: `theta` and a long-context scaling (`_rope_tables`
+    says what each type does). `scale` is the type's factor. The rest is
+    YaRN's: `original_len` the positions the rotation was trained at,
+    `beta_fast` / `beta_slow` the rotations over them that bound the ramp,
+    `attention_factor` what cos and sin are multiplied by (None: 0.1 ln
+    scale + 1), `truncate` whether the ramp's bounds are whole pairs."""
+    theta: float = 10000.0
+    scaling: Optional[str] = None
+    scale: float = 1.0
+    original_len: Optional[int] = None
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: Optional[float] = None
+    truncate: bool = True
+
+    @property
+    def cos_sin_factor(self) -> float:
+        """What cos and sin are multiplied by: 1 but under YaRN."""
+        if self.scaling != "yarn":
+            return 1.0
+        if self.attention_factor is None:
+            return 0.1 * math.log(self.scale) + 1.0
+        return self.attention_factor
+
+
+@dataclasses.dataclass(frozen=True)
 class LlamaConfig:
     block_size: int = 2048
     vocab_size: int = 32000
@@ -186,12 +214,18 @@ class LlamaConfig:
     #     (position interpolation; HF rope_scaling type "linear");
     #   "ntk" — theta multiplied by rope_scale^(d/(d-2)) (NTK-aware base
     #     stretch: high frequencies keep local resolution, low
-    #     frequencies interpolate).
+    #     frequencies interpolate);
+    #   "yarn" — frequencies by parts and an attention factor on cos and
+    #     sin (HF rope_type "yarn"); `rope_yarn` holds its further
+    #     parameters (a `Rotation` whose theta, scaling and scale are
+    #     these three fields').
     # Every RoPE site goes through _rope_tables, so the dense forward,
     # cached/ring decode, batcher rows, and seq-parallel ring all scale
-    # identically.
+    # identically. A layer KIND may name a table of its own
+    # (`KvKind.rotation`).
     rope_scaling: Optional[str] = None
     rope_scale: float = 1.0
+    rope_yarn: Optional[Rotation] = None
     # Qwen2-class q/k/v projection biases (o and the MLP stay bias-free).
     # ops.nn.linear applies any "bias" leaf it finds, so the flag only
     # affects init and the HF config mapping — converted checkpoints
@@ -604,10 +638,12 @@ class KvKind:
     llama_moe.py): `window` = W makes a query at t read t - W < u <= t
     only (`band_keep`), and its cache leaf hold the window's blocks;
     `rope` off leaves q and k unrotated (position then reaches the layer
-    through the mask alone). Everything else of the block is the
-    config's."""
+    through the mask alone); `rotation` is the kind's OWN table where the
+    kinds rotate by different ones (None: the config's `rope_theta` /
+    `rope_scaling`). Everything else of the block is the config's."""
     window: Optional[int] = None
     rope: bool = True
+    rotation: Optional[Rotation] = None
 
 
 # a K/V kind's cache leaves and block tables, by name (models/mla.py
@@ -870,33 +906,87 @@ def init(rng, cfg: LlamaConfig = PRESETS["llama-test"], dtype=jnp.float32,
 # forward
 # --------------------------------------------------------------------------
 
-def _rope_tables(cfg: LlamaConfig, positions):
-    """cos/sin at `positions` with the config's long-context scaling
-    applied — the ONE place scaling happens, shared by every attention
-    path (dense, cached decode, batcher rows, seq-parallel ring)."""
-    theta = cfg.rope_theta
+ROPE_SCALINGS = ("linear", "ntk", "yarn")
+
+
+def rotation_of(cfg: LlamaConfig, kind: Optional[KvKind] = None) -> Rotation:
+    """The table a layer of `kind` rotates by: the kind's own, else the
+    config's ONE (`rope_theta`, `rope_scaling`, `rope_scale`, `rope_yarn`)."""
+    if kind is not None and kind.rotation is not None:
+        return kind.rotation
+    yarn = cfg.rope_yarn or Rotation()
+    return dataclasses.replace(yarn, theta=cfg.rope_theta,
+                               scaling=cfg.rope_scaling, scale=cfg.rope_scale)
+
+
+def yarn_ramp(rot: Rotation, d: int):
+    """(low, high) of YaRN's ramp over the d / 2 pairs (arXiv:2309.00071 as
+    transformers' `_compute_yarn_parameters` states it): pair i turns
+    `original_len` positions c^-1(i) times, c(n) = d ln(original_len / (2 pi
+    n)) / (2 ln theta); pairs below c(beta_fast) keep their frequency, pairs
+    above c(beta_slow) take theta's divided by `scale`, those between a
+    mixture that is linear in i."""
+    def pair_of(rotations):
+        return d * math.log(rot.original_len / (rotations * 2 * math.pi)) / (
+            2 * math.log(rot.theta))
+
+    low, high = pair_of(rot.beta_fast), pair_of(rot.beta_slow)
+    if rot.truncate:
+        low, high = math.floor(low), math.ceil(high)
+    return max(low, 0), min(high, d - 1)
+
+
+def _yarn_tables(rot: Rotation, positions, d: int):
+    if rot.original_len is None:
+        raise ValueError("rope_scaling='yarn' needs the positions the "
+                         "rotation was trained at (Rotation.original_len)")
+    low, high = yarn_ramp(rot, d)
+    if low == high:
+        high += 0.001  # a ramp of no width: a step
+    freq = 1.0 / rot.theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0, 1)
+    cos, sin = rope_cos_sin(
+        positions, d, inv_freq=freq * (1 - ramp) + freq / rot.scale * ramp)
+    return cos * rot.cos_sin_factor, sin * rot.cos_sin_factor
+
+
+def _rope_tables(cfg: LlamaConfig, positions, kind: Optional[KvKind] = None):
+    """cos/sin at `positions` with the long-context scaling of the table a
+    layer of `kind` rotates by (`rotation_of`: the config's ONE where
+    kinds name none) applied — the ONE place scaling happens, shared by
+    every attention path (dense, cached decode, batcher rows, seq-parallel
+    ring):
+      "linear" — positions divided by the scale before the tables;
+      "ntk" — theta multiplied by scale^(d/(d-2));
+      "yarn" — frequencies by parts (`yarn_ramp`) and cos and sin times
+        the attention factor, so that q . k carries its square."""
+    rot = rotation_of(cfg, kind)
+    theta = rot.theta
     d = cfg.rotary_dim or cfg.head_dim  # partial rotary: narrow tables
-    if cfg.rope_scaling is None:
-        if cfg.rope_scale != 1.0:
+    if rot.scaling is None:
+        if rot.scale != 1.0:
             # the likely long-context typo: factor set, type forgotten —
             # serving an unscaled model here would silently collapse
             # quality past the trained range
             raise ValueError(
-                f"rope_scale={cfg.rope_scale} has no effect without "
-                "rope_scaling='linear' or 'ntk'")
+                f"rope_scale={rot.scale} has no effect without "
+                "rope_scaling='linear', 'ntk' or 'yarn'")
         return rope_cos_sin(positions, d, theta=theta)
-    if cfg.rope_scaling not in ("linear", "ntk"):
+    if rot.scaling not in ROPE_SCALINGS:
         raise ValueError(
-            f"unknown rope_scaling {cfg.rope_scaling!r} "
-            "(expected 'linear' or 'ntk')")
-    if cfg.rope_scale == 1.0:
+            f"unknown rope_scaling {rot.scaling!r} "
+            "(expected 'linear', 'ntk' or 'yarn')")
+    if rot.scale == 1.0:
         return rope_cos_sin(positions, d, theta=theta)
-    if cfg.rope_scale < 1.0:
-        raise ValueError(f"rope_scale must be >= 1, got {cfg.rope_scale}")
-    if cfg.rope_scaling == "linear":
-        positions = positions.astype(jnp.float32) / cfg.rope_scale
+    if rot.scale < 1.0:
+        raise ValueError(f"rope_scale must be >= 1, got {rot.scale}")
+    if rot.scaling == "yarn":
+        return _yarn_tables(rot, positions, d)
+    if rot.scaling == "linear":
+        positions = positions.astype(jnp.float32) / rot.scale
     else:  # "ntk"
-        theta = theta * cfg.rope_scale ** (d / (d - 2))
+        theta = theta * rot.scale ** (d / (d - 2))
     return rope_cos_sin(positions, d, theta=theta)
 
 
@@ -985,10 +1075,11 @@ def _mup_scaled(x, cfg: LlamaConfig, name: str, at=None):
 
 
 def _qkv_rope(bp, h, positions, *, cfg: LlamaConfig, compute_dtype,
-              rope=True):
+              kind: Optional[KvKind] = None):
     """Project h (B, T, C) and rotate q/k at absolute `positions` (T,).
     Returns q (B, H, T, D), k/v (B, KV, T, D) — KV heads stay narrow.
-    `rope` off (a layer kind's, `KvKind`): q and k as they are normed."""
+    `kind` is the layer's (`KvKind`) where layers are of kinds: its `rope`
+    off leaves q and k as they are normed, its `rotation` is the table."""
     h = _mup_scaled(h, cfg, "attention_in")
     q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
                     cfg.n_head)
@@ -998,9 +1089,9 @@ def _qkv_rope(bp, h, positions, *, cfg: LlamaConfig, compute_dtype,
     v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
                     cfg.n_kv_head)
     q, k = _qk_normed(bp, q, k, cfg)
-    if not rope:
+    if kind is not None and not kind.rope:
         return _q_rescale(q, cfg), k, v
-    cos, sin = _rope_tables(cfg, positions)
+    cos, sin = _rope_tables(cfg, positions, kind)
     return (_q_rescale(_rope_apply(q, cos, sin, cfg), cfg),
             _rope_apply(k, cos, sin, cfg), v)
 
@@ -1104,15 +1195,15 @@ def _gated(bp, h, y, compute_dtype):
 
 
 def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None,
-                rope=True):
+                kind: Optional[KvKind] = None):
     """Default attention: local causal GQA over the whole (B, T, C) h,
     band-limited to cfg.sliding_window when set. `window` overrides the
     config's window for this call (traced allowed) — the per-layer hook
     alternating-attention configs thread through blocks_scan, and a layer
-    kind's own (`KvKind`, with `rope`)."""
+    kind's own (`kind`, a `KvKind`, whose rotation q and k then take)."""
     t = h.shape[1]
     q, k, v = _qkv_rope(bp, h, jnp.arange(t), cfg=cfg,
-                        compute_dtype=compute_dtype, rope=rope)
+                        compute_dtype=compute_dtype, kind=kind)
     rows = jnp.arange(t)
     w = window if window is not None else cfg.sliding_window
 
@@ -1155,7 +1246,7 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
         kk = kinds[kind or "full"]
         fn = lambda bp2, h: _dense_attn(  # noqa: E731
             bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=kk.window,
-            rope=kk.rope)
+            kind=kk)
     if attn_fn is None and cfg.index_topk is not None:
         from dnn_tpu.models import dsa
 
@@ -1893,10 +1984,11 @@ def family_rows(cfg, **kw):
     return rows(cfg, **kw)
 
 
-def qkv_rows(bp, h, pos, *, cfg, compute_dtype, rope=True):
+def qkv_rows(bp, h, pos, *, cfg, compute_dtype, kind=None):
     """h (B, 1, C) normed rows at per-slot positions pos (B,) -> q (B, H, 1,
-    D) normed, rotated and rescaled, k (rotated) and v (B, KV, 1, D); `rope`
-    off (a layer kind's): unrotated."""
+    D) normed, rotated and rescaled, k (rotated) and v (B, KV, 1, D); `kind`
+    (the layer's `KvKind`): its table, or unrotated where its `rope` is
+    off."""
     kv = cfg.n_kv_head
     h = _mup_scaled(h, cfg, "attention_in")
     q = split_heads(linear(bp["attn"]["q"], h, compute_dtype=compute_dtype),
@@ -1907,9 +1999,9 @@ def qkv_rows(bp, h, pos, *, cfg, compute_dtype, rope=True):
     v = split_heads(linear(bp["attn"]["v"], h, compute_dtype=compute_dtype),
                     kv)
     q, k = _qk_normed(bp, q, k, cfg)
-    if not rope:
+    if kind is not None and not kind.rope:
         return _q_rescale(q, cfg), k, v
-    cos, sin = _rope_tables(cfg, pos)  # (B, D)
+    cos, sin = _rope_tables(cfg, pos, kind)  # (B, D)
     cos, sin = cos[:, None, None, :], sin[:, None, None, :]
     q, k = _rope_apply(q, cos, sin, cfg), _rope_apply(k, cos, sin, cfg)
     return _q_rescale(q, cfg), k, v
@@ -1998,9 +2090,9 @@ class LlamaFamilyRows:
                                        ffn=ffn or self.ffn),
                     layer_cache)
 
-    def _qkv_rows(self, bp, h, pos, rope=True):
+    def _qkv_rows(self, bp, h, pos, kind=None):
         return qkv_rows(bp, h, pos, cfg=self.cfg,
-                        compute_dtype=self.compute_dtype, rope=rope)
+                        compute_dtype=self.compute_dtype, kind=kind)
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window):
         cfg, compute_dtype = self.cfg, self.compute_dtype
@@ -2230,6 +2322,18 @@ class LlamaKindRows(LlamaFamilyRows):
         # (/statusz `components.attention.kinds`)
         self.attn_forms = {kind: {} for kind in self.kinds}
 
+    def kind_tables(self):
+        """{kind: its window and the table it rotates by (None: unrotated)}
+        for /statusz, beside the forms its reads took."""
+        out = {}
+        for kind, kk in self.kinds.items():
+            rot = rotation_of(self.cfg, kk)
+            out[kind] = {"window": kk.window, "rotation": {
+                "type": rot.scaling or "default", "theta": rot.theta,
+                "factor": rot.scale,
+                "attention_factor": rot.cos_sin_factor} if kk.rope else None}
+        return out
+
     def init_cache(self, batch, max_len, dtype):
         """What `cache_kinds` says each kind keeps, dense: a row a position
         of its `leaves`, one every `stride` of its `strided_leaves`, its
@@ -2266,7 +2370,7 @@ class LlamaKindRows(LlamaFamilyRows):
         t = h.shape[1]
         kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
         q, k, v = _qkv_rope(bp, h, start_pos + jnp.arange(t), cfg=cfg,
-                            compute_dtype=compute_dtype, rope=kk.rope)
+                            compute_dtype=compute_dtype, kind=kk)
         with jax.named_scope("kv_pool.write"):
             rows = {**rows, **{n: lax.dynamic_update_slice_in_dim(
                 rows[n], new.astype(rows[n].dtype), start_pos, axis=2)
@@ -2325,7 +2429,7 @@ class LlamaKindRows(LlamaFamilyRows):
         b = x.shape[0]
         kv, g, d = cfg.n_kv_head, cfg.n_head // cfg.n_kv_head, cfg.head_dim
         h = _pre_normed(bp, x, cfg)
-        q, k, v = self._qkv_rows(bp, h, pos, rope=kk.rope)
+        q, k, v = self._qkv_rows(bp, h, pos, kind=kk)
         qg = q.reshape(b, kv, g, d)  # group rows share the slot's limit
         self.attn_forms[kind]["decode"] = codec.decode_form(
             layer_cache, kk.window)
@@ -2529,9 +2633,26 @@ def to_hf_config(cfg: LlamaConfig, *, tie_word_embeddings: bool = False,
             return transformers.Gemma2Config(**kw)
         kw.update(overrides)
         return transformers.GemmaConfig(**kw)
+    if any(k.rotation is not None for k in (kv_kinds(cfg) or {}).values()):
+        # no transformers class of this mapping rotates by layer kind
+        raise ValueError(
+            "layer kinds with tables of their own (KvKind.rotation) have no "
+            "transformers config in this mapping — map this config by hand")
+    if cfg.rope_scaling is not None and cfg.rope_scaling not in ROPE_SCALINGS:
+        raise ValueError(f"rope_scaling {cfg.rope_scaling!r} has no "
+                         "transformers mapping here (linear, ntk, yarn)")
     if cfg.rope_scaling == "linear" and cfg.rope_scale != 1.0:
         kw["rope_scaling"] = {"rope_type": "linear",
                               "factor": cfg.rope_scale}
+    elif cfg.rope_scaling == "yarn" and cfg.rope_scale != 1.0:
+        rot = rotation_of(cfg)
+        kw["rope_scaling"] = {
+            "rope_type": "yarn", "factor": rot.scale,
+            "original_max_position_embeddings": rot.original_len,
+            "beta_fast": rot.beta_fast, "beta_slow": rot.beta_slow,
+            "truncate": rot.truncate,
+            **({} if rot.attention_factor is None else
+               {"attention_factor": rot.attention_factor})}
     elif cfg.rope_scaling == "ntk" and cfg.rope_scale != 1.0:
         # transformers has no STATIC ntk type (its "dynamic" rescales
         # with runtime length) — an equivalent HF config is theta

@@ -133,6 +133,11 @@ class MixtralConfig(llama.LlamaConfig):
     # their attention output by sigmoid(h W_gate), element-wise
     kda: Optional[KdaConfig] = None
     attn_gate: bool = False
+    # the SEEDED init's expert down projections (1 / sqrt(fan-in)) times
+    # this: how much of the residual stream the experts are at random
+    # weights, and so how far one expert swapped under bfloat16 moves a
+    # logit. No trained checkpoint reads it
+    expert_out_init: float = 1.0
 
     def __post_init__(self):
         super().__post_init__()
@@ -460,6 +465,63 @@ PRESETS["k-exaone-test"] = MixtralConfig(
     kv_full=llama.KvKind(window=None, rope=False),
     kv_window=llama.KvKind(window=8, rope=True),
     layer_types=("window", "window", "window", "full", "window"))
+# Mellum2-12B-A2.5B-Instruct (JetBrains/Mellum2-12B-A2.5B-Instruct
+# config.json, `model_type` mellum): a Qwen3-MoE-shaped decoder — GQA 32
+# query / 4 KV heads of 128 with per-head q/k RMSNorm, every layer 64
+# experts of 896, 8 a token by softmax scores renormalised, no shared
+# expert, no dense layer — whose 28 layers are of two K/V KINDS in the
+# period S S S F and BOTH kinds rotate q and k, each by its own table
+# (`KvKind.rotation`): 21 "window" layers under a window of 1024 and plain
+# RoPE (theta 5e5), 7 "full" layers under YaRN (theta 5e5, factor 16 over
+# 8192 original positions, cos and sin times 1.2772588722239782) up to
+# 131072 positions. `intermediate_size` 7168 is read by no layer; the
+# "MTP head" `described_as` mentions has no key and is not served. What
+# the config leaves open (QK-norm) is `assumed` in chipbench/configs/
+# mellum2-12b-a2.5b-pp4-1chip.json. Never instantiated whole.
+# `expert_out_init` 0.25: the seeded init's expert outputs, MEASURED
+# (PERF.md section 6, PR 62): at 1 the experts are ~7x the rest of the
+# stream, all 64 are held in 8 layers, and one expert swapped under
+# bfloat16 rewrites a row — the float32 reference agrees with ITSELF at
+# default precision on 57 % of tokens and `correct` could see neither
+# rotation table; at 0.25 on 91 %, with every control under 71 %; at 0.125
+# on 99 %, but then the weights not renormalised read 91 %.
+_MELLUM2_TYPES = tuple("full" if i % 4 == 3 else "window" for i in range(28))
+_MELLUM2_YARN = llama.Rotation(
+    theta=500_000.0, scaling="yarn", scale=16.0, original_len=8192,
+    beta_fast=32.0, beta_slow=1.0, attention_factor=1.2772588722239782)
+PRESETS["mellum2-12b-a2.5b"] = MixtralConfig(
+    block_size=131072, vocab_size=98304, n_layer=28, n_head=32, n_kv_head=4,
+    n_embd=2304, d_ff=896, head_dim_override=128, rope_theta=500_000.0,
+    rms_eps=1e-6, qk_norm=True, qk_norm_width="head",
+    tie_word_embeddings=False, n_expert=64, router_top_k=8,
+    router_norm_topk=True, capacity_factor=64.0, expert_out_init=0.25,
+    kv_full=llama.KvKind(window=None, rotation=_MELLUM2_YARN),
+    kv_window=llama.KvKind(window=1024,
+                           rotation=llama.Rotation(theta=500_000.0)),
+    layer_types=_MELLUM2_TYPES)
+# the benchmark's cut (chipbench/configs/mellum2-12b-a2.5b-pp4-1chip.json):
+# one of four pipeline stages — layers 0-7, two whole periods S S S F, ALL
+# 64 experts of each, with `wte` and the head on it: 8.05 GB held
+PRESETS["mellum2-12b-a2.5b-pp4-1chip"] = dataclasses.replace(
+    PRESETS["mellum2-12b-a2.5b"], n_layer=8, layer_types=_MELLUM2_TYPES[:8])
+# tiny Mellum2 for the CPU tests, every switch of the real one acting: GQA
+# 2:1 with a decoupled head width and head-width q/k norm with drawn
+# gains, a window a 40-token sequence exceeds four times over, the full
+# kind under YaRN whose original 8 positions the sequence exceeds four
+# times over and whose ramp (low 3, high 8 of 16 pairs at theta 100) holds
+# pairs in all three parts, an attention factor that is not 1, 8 experts 4
+# a token renormalised, S S S F twice
+PRESETS["mellum2-test"] = MixtralConfig(
+    block_size=64, vocab_size=256, n_layer=8, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=32, head_dim_override=32, rope_theta=100.0,
+    rms_eps=1e-6, qk_norm=True, qk_norm_width="head", qk_norm_init=1.4,
+    tie_word_embeddings=False, n_expert=8, router_top_k=4,
+    router_norm_topk=True, capacity_factor=8.0,
+    kv_full=llama.KvKind(window=None, rotation=llama.Rotation(
+        theta=100.0, scaling="yarn", scale=8.0, original_len=8,
+        beta_fast=0.5, beta_slow=0.15, attention_factor=1.25)),
+    kv_window=llama.KvKind(window=8, rotation=llama.Rotation(theta=100.0)),
+    layer_types=("window", "window", "window", "full") * 2)
 # Solar-Open2-250B (upstage/Solar-Open2-250B config.json, `model_type`
 # solar_open2): 48 layers in periods of four — one softmax layer
 # (`gqa_layers` 0, 4, ...: 64 query / 8 KV heads of 128, NO rotation, a
@@ -635,6 +697,9 @@ def init_parts(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
         blk = block()
         moe = init_moe_gated(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
                              dtype, n_held=cfg.experts_held)
+        if cfg.expert_out_init != 1.0:
+            moe["wd"] = moe["wd"] * jnp.asarray(cfg.expert_out_init,
+                                                moe["wd"].dtype)
         if cfg.router.select_bias:
             moe["router"]["select_bias"] = (
                 _SELECT_BIAS_INIT * jax.random.normal(

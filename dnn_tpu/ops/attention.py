@@ -69,13 +69,15 @@ def causal_self_attention(params, x, *, n_head, use_flash=False, compute_dtype=N
     return linear(params["proj"], y, compute_dtype=compute_dtype)
 
 
-def rope_cos_sin(positions, head_dim, *, theta=10000.0):
+def rope_cos_sin(positions, head_dim, *, theta=10000.0, inv_freq=None):
     """cos/sin tables for rotary position embedding at absolute
     `positions` (any shape P...), HF half-split convention: frequencies
-    1/theta^(2i/d) over the first half of the head dim, tables tiled to
-    the full dim. Returns (cos, sin) of shape (*P, head_dim), f32."""
-    inv_freq = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32)
-                                / head_dim))
+    1/theta^(2i/d) over the first half of the head dim — or `inv_freq`
+    (head_dim / 2,), a scaling's own —, tables tiled to the full dim.
+    Returns (cos, sin) of shape (*P, head_dim), f32."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (theta ** (
+            jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # (*P, d/2)
     emb = jnp.concatenate([angles, angles], axis=-1)
     return jnp.cos(emb), jnp.sin(emb)

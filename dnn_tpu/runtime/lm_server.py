@@ -1379,13 +1379,16 @@ class LMServer:
         # the forms the reads of K and V leaves by layer kind took in the
         # programs built so far (models/llama.py `LlamaKindRows`): the
         # paged kernel or a gather and einsums for a decode read, the
-        # (banded) kernel or the plain form for a chunk's
+        # (banded) kernel or the plain form for a chunk's — beside a K/V
+        # kind's window and the table it rotates q and k by (`KvKind`)
         kinds = getattr(family, "attn_forms", None)
         if kinds and any(kinds.values()):
+            tables = family.kind_tables()
             comps["attention"] = {
                 "detail": "the form each layer kind's reads took in the "
-                          "built programs",
-                "kinds": {kind: dict(f) for kind, f in kinds.items()}}
+                          "built programs, a K/V kind's window and rotation",
+                "kinds": {kind: {**f, **tables.get(kind, {})}
+                          for kind, f in kinds.items()}}
         # facts, no `state`: the KV cache's bytes leaf by leaf (K, V, an
         # int8 pool's scales, a selecting model's index keys "ik"; a
         # layer kind's leaves under their own names), from shapes alone

@@ -823,6 +823,10 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
     the line."""
     comps = served(config)["statusz"]["components"]
     series = _SERVED[config]["series"]
+    # a K/V kind's window and rotation ride beside its forms (held below)
+    tables = {kind: {n: f.pop(n) for n in ("window", "rotation") if n in f}
+              for kind, f in comps.get("attention", {}).get(
+                  "kinds", {}).items()}
     if any(s.startswith("state_pool_") for s in series):
         state = {"prefill": "chunked_jnp", "decode": "step_jnp"}
         kinds, leaves = comps["attention"]["kinds"], sorted(
@@ -874,6 +878,15 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
         for kind in ("full", "window")}
     assert sorted(comps["kv_cache"]["bytes_by_leaf"]) == [
         "k", "k_w", "v", "v_w"]
+    # the window kind has a window and plain RoPE; the full kind none, and
+    # is unrotated (K-EXAONE) or under a table of its OWN (Mellum2: YaRN)
+    assert tables["window"]["window"] > 0 and tables["full"]["window"] is None
+    assert tables["window"]["rotation"]["type"] == "default"
+    full = tables["full"]["rotation"]
+    assert full is None or (
+        full["type"] == "yarn" and full["factor"] > 1
+        and full["attention_factor"] != 1.0
+        and full["theta"] == tables["window"]["rotation"]["theta"])
 
 
 @pytest.mark.parametrize("config", sorted(_SERVED))

@@ -1227,3 +1227,100 @@ def test_block_list_kernel_compiles(chip):
 
     compiled = _compile(chip, fn, *shapes)
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 22
+
+
+# ----------------------------------------------------------------------
+# ISSUE 62 — a window kind of 65 blocks a slot beside a full kind of 1088,
+# each rotated by its own table (Mellum2)
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def mellum_programs(chip):
+    """Mellum2's step programs at its published widths and the cell's pool
+    (16 slots of 17 408 positions in blocks of 16, 1024-token chunks), depth
+    cut to ONE period (S S S F: the window kind's run is a loop over a leaf
+    of three layers, as the cell's) and the vocabulary to 8192 rows.
+    -> ({name: compiled}, the full kind's K leaf's extent a layer, the
+    window kind's, the pool's bytes)."""
+    import dataclasses
+
+    from dnn_tpu.models import llama_moe
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.registry import ParamParts
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(
+        llama_moe.PRESETS["mellum2-12b-a2.5b"], n_layer=4, vocab_size=8192,
+        layer_types=("window", "window", "window", "full"))
+    prepared = _stack_and_release(
+        ParamParts(llama_moe.init_parts(jax.random.PRNGKey(0), cfg)), cfg,
+        BF16)  # a layer at a time, as the daemon boots
+    b = ContinuousBatcher(
+        cfg, prepared, slots=16, max_len=17408, prompt_pad=1024, kv="auto",
+        family=llama_moe.family_rows(cfg, compute_dtype=BF16))
+    assert b._paged and b._allocator is not None
+    assert b.cache["k"].shape == (1, 16 * 1088 + 1, 4, 16, 128)
+    assert b.cache["k_w"].shape == (3, 16 * 65 + 1, 4, 16, 128)
+    compiled = _lower_programs(
+        chip, [(b, ("_prefill_chunk", "_prefill_finish", "_decode"))])
+    return (compiled, b.cache["k"].shape[1:], b.cache["k_w"].shape[1:],
+            sum(x.nbytes for x in b.cache.values()))
+
+
+#: the kinds of operation that have a window leaf's extent in the compiled
+#: programs: the step's rows scattered in, and the compiler's own moves of a
+#: leaf this small (51 MB at three layers) — another layout for the gather,
+#: a layer's slice or the whole leaf fetched into fast memory in pieces
+#: (`slice-start` ... `ConcatBitcast`)
+_WINDOW_LEAF_OPS = {"scatter", "fusion", "dynamic-update-slice",
+                    "dynamic-slice", "copy", "copy-start", "copy-done",
+                    "slice-start", "slice-done", "custom-call"}
+
+
+def test_mellum_decode_step_reaches_the_full_kind_in_place(mellum_programs):
+    """The full kind's read is the paged kernel over the pool left in HBM:
+    nothing of the leaf's extent but its aliased results, and the donated
+    leaves are the program's results. The window kind's 65 blocks a slot are
+    GATHERED, and the compiled text shows what that costs at this width
+    (PERF.md section 7, PR 62): the compiler gives the window leaves another
+    layout for the gather — a `copy` of each whole leaf on the way in and
+    on the way out — and brings a layer's whole slice (17 MB) into fast
+    memory for each gather (`dynamic-slice`, `copy` under
+    `kv_pool.gather`), as it does for every gather's operand. This case
+    holds the kinds of operation that have the leaf's extent, so that a
+    change to the read shows here before it shows on the chip."""
+    compiled, pool, pool_w, pool_bytes = mellum_programs
+    step = compiled["_decode"]
+    assert "tpu_custom_call" in step.as_text()
+    assert {o[0] for o in _pool_extent_ops(step, pool)} <= {"custom-call"}
+    ops_w = _pool_extent_ops(step, pool_w)
+    assert {o[0] for o in ops_w} <= _WINDOW_LEAF_OPS, ops_w
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 29
+
+
+def test_mellum_finish_installs_blocks_without_a_pool_copy(mellum_programs):
+    compiled, pool, pool_w, pool_bytes = mellum_programs
+    finish = compiled["_prefill_finish"]
+    ops = _pool_extent_ops(finish, pool)
+    assert {o[0] for o in ops} <= {"dynamic-update-slice", "fusion",
+                                   "scatter"}, ops
+    ops_w = _pool_extent_ops(finish, pool_w)
+    assert {o[0] for o in ops_w} <= _WINDOW_LEAF_OPS, ops_w
+    mem = finish.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 27
+
+
+def test_mellum_chunk_program_fits_beside_the_pool(mellum_programs):
+    """The chunk program works on the transient rows alone (K and V of
+    17 408 positions a layer, for the window kind too: ROADMAP R2 (d)):
+    both kinds' reads are the folded prefill kernel, the window kind's
+    banded; temporaries under 1 GB."""
+    compiled, pool, pool_w, _ = mellum_programs
+    chunk = compiled["_prefill_chunk"]
+    assert _pool_extent_ops(chunk, pool) == []
+    assert _pool_extent_ops(chunk, pool_w) == []
+    assert chunk.as_text().count("tpu_custom_call") >= 2
+    assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 30

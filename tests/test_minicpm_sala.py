@@ -274,7 +274,7 @@ def test_the_selected_set_is_the_equations(model):
     with jax.default_matmul_precision("highest"):
         h = llama._pre_normed(p, x[None], cfg)
         q, k, _ = llama._qkv_rope(p, h, jnp.arange(t), cfg=cfg,
-                                  compute_dtype=None, rope=False)
+                                  compute_dtype=None, kind=cfg.kv_full)
         kp = jnp.pad(k, ((0, 0), (0, 0), (0, -t % m.block), (0, 0)))
         kc = block_select.pooled_rows(jnp.zeros_like(kp[:, :, :m.stride]),
                                       kp, m)
