@@ -38,8 +38,10 @@ from dnn_tpu.analysis.findings import Finding, assign_occurrences
 from dnn_tpu.utils.hlo_audit import (
     count_aliased,
     count_cache_sized,
+    dus_updates,
     gpt_decode_step,
     lowered_text,
+    op_result_dims,
 )
 
 __all__ = [
@@ -47,7 +49,8 @@ __all__ = [
     "check_branch_collectives", "baked_constants",
     "donation_report", "recompile_census", "audit_decode_paths",
     "audit_serving_decode", "audit_pipeline_programs", "audit_engine",
-    "check_decode_program", "run_program_audit",
+    "check_decode_program", "check_chunk_program", "chunk_args",
+    "run_program_audit",
 ]
 
 _COLLECTIVE_PRIMS = {
@@ -362,6 +365,75 @@ def check_decode_program(name, jit_fn, args, donate_idx, layer_elems,
              "cache_sized_ops": copies}, findings)
 
 
+def check_chunk_program(name, jit_fn, args, state_leaves=()
+                        ) -> Tuple[dict, List[Finding]]:
+    """`check_decode_program`'s two rules turned to ONE prefill-chunk
+    program (`b._prefill_chunk` at `chunk_args(b)`: its second argument is
+    the donated transient row {leaf: (L, 1, H, S[, D])}): (a) every leaf
+    of the row aliases a result, and (b) a chunk moves a chunk's positions
+    — beyond the layer's one read (a `dynamic_slice`) the lowered text
+    holds nothing of a layer's row or more: no copy, transpose or
+    concatenate whose result is a layer's row of a leaf, or layers of
+    them, and no `dynamic_update_slice` whose UPDATE is a leaf's whole
+    layer or a layer row's elements (the row riding the layer loop as xs /
+    ys writes each layer's whole cut-out back; `paged_kvcache.scan_rows`
+    carries it and a block writes T positions). The exception is in the
+    rule, not in a baseline: a state leaf (`state_leaves`: no position
+    axis, models/state_kind.py) IS its layer's whole content and is written
+    whole at the layer's index."""
+    row = args[1]
+    avals = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype), args)
+    text = jit_fn.lower(*avals).as_text()
+    aliased, expected = count_aliased(text), len(jax.tree.leaves(row))
+    where = f"runtime/serving.prefill_chunk[{name}]"
+    findings: List[Finding] = []
+    if aliased < expected:
+        findings.append(Finding(
+            rule="PRG003", path=where, line=0,
+            message=f"only {aliased}/{expected} leaves of the donated "
+                    "transient row are aliased to outputs — an un-aliased "
+                    "leaf is copied every chunk",
+            snippet=f"{name}: aliased={aliased} expected={expected}"))
+    state = {tuple(row[n].shape) for n in state_leaves if n in row}
+    positional = {tuple(x.shape): int(np.prod(x.shape[1:]))
+                  for n, x in row.items() if n not in state_leaves}
+    layer_elems = max(positional.values()) if positional else max(
+        int(np.prod(x.shape[1:])) for x in row.values())
+    # a layer's row, or layers of them: a result whose trailing extents
+    # are a leaf's (a latent row's up-projection is larger than the row
+    # and is the model's own arithmetic)
+    rows_of = {shape[1:] for shape in positional}
+    moved: Dict[str, int] = {}
+    for op, dims in op_result_dims(text):
+        if op in ("transpose", "copy", "concatenate") and any(
+                dims[-len(r):] == r for r in rows_of):
+            moved[op] = moved.get(op, 0) + 1
+    whole = sum(
+        1 for operand, update in dus_updates(text) if operand not in state
+        and int(np.prod(update)) >= positional.get(operand, layer_elems))
+    if whole:
+        moved["dynamic_update_slice"] = whole
+    if moved:
+        findings.append(Finding(
+            rule="PRG003", path=where, line=0,
+            message=f"prefill chunk moves a layer's whole row (or more) "
+                    f"beyond the layer's one read: {moved}",
+            snippet=f"{name}: {moved}"))
+    return ({"aliased": aliased, "expected": expected,
+             "cache_sized_ops": moved}, findings)
+
+
+def chunk_args(b) -> tuple:
+    """The argument tuple of a ContinuousBatcher's prefill-chunk program
+    (`b._prefill_chunk`): the prefill view, a fresh transient row (as
+    shapes), one (1, prompt_pad) chunk, its start and — for a family that
+    keeps a state — its count of real positions."""
+    return (b._lora_prefill_view(0), jax.eval_shape(b._new_row),
+            jnp.zeros((1, b.prompt_pad), jnp.int32), np.int32(0),
+            *b._n_real(b.prompt_pad, 0))
+
+
 def decode_step_args(b) -> tuple:
     """The argument tuple of a ContinuousBatcher's decode-step program
     (`b._decode`), from the batcher's own state. The one place the audit
@@ -420,6 +492,14 @@ SERVING_DECODE_VARIANTS = {
 }
 
 
+#: the test presets of the families that prefill through
+#: `llama.prefill_by_kind`: JoyAI, dots3, K-EXAONE, Solar Open 2, Brumby,
+#: Falcon-H1, MiniCPM-SALA, Mellum2
+CHUNK_BY_KIND_PRESETS = (
+    "joyai-test", "dots3-test", "k-exaone-test", "solar-open2-test",
+    "brumby-test", "falcon-h1-test", "minicpm-sala-test", "mellum2-test")
+
+
 def audit_serving_decode(cfg=None, *, slots: int = 2,
                          max_len: int = 128) -> dict:
     """ISSUE 6 donation-coverage GATE over the SERVING decode programs:
@@ -450,6 +530,15 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
         findings.extend(f)
         report[name] = entry
 
+    def check_chunk(name, b):
+        # ISSUE 63 — the chunk program that every admission loops: its
+        # donated transient row aliases leaf for leaf and a chunk writes
+        # a chunk's positions (check_chunk_program)
+        entry, f = check_chunk_program(name + "_chunk", b._prefill_chunk,
+                                       chunk_args(b), b._slot_leaves)
+        findings.extend(f)
+        report[name + "_chunk"] = entry
+
     hd = cfg.n_embd // cfg.n_head
     for name, kw in SERVING_DECODE_VARIANTS.items():
         b = ContinuousBatcher(cfg, prepared, slots=slots, max_len=max_len,
@@ -469,26 +558,46 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
         # admission, and the eager scatters it replaced come back)
         lower_and_check(name + "_finish", b._prefill_finish,
                         finish_args(b, 16), b._finish_donate, layer_elems)
+        check_chunk(name, b)
 
     # the same program over the other served families' paged pools: a
     # LLaMA-MoE (OLMoE's test preset) and one whose pool has a third leaf
     # (Keye's: the index key installs and aliases with K and V)
     from dnn_tpu.registry import get_model
 
-    for name, preset in {"paged_olmoe_finish": "olmoe-test",
-                         "paged_keye_finish": "keye-test"}.items():
+    def family_batcher(preset, prompt_pad=16, **kw):
         spec = get_model(preset)
-        b = ContinuousBatcher(
+        return ContinuousBatcher(
             spec.config,
             gpt.prepare_stacked(dict(spec.init(jax.random.PRNGKey(0))),
                                 spec.config),
-            slots=slots, max_len=64, prompt_pad=16, kv="paged",
-            allow_logit_bias=True, allow_constraints=True,
-            constraint_rows=8, family=spec.extras["family_rows"]())
+            slots=slots, prompt_pad=prompt_pad,
+            family=spec.extras["family_rows"](), **kw)
+
+    for name, preset in {"paged_olmoe": "olmoe-test",
+                         "paged_keye": "keye-test"}.items():
+        b = family_batcher(preset, max_len=64, kv="paged",
+                           allow_logit_bias=True, allow_constraints=True,
+                           constraint_rows=8)
         lower_and_check(
-            name, b._prefill_finish, finish_args(b, 16), b._finish_donate,
+            name + "_finish", b._prefill_finish, finish_args(b, 16),
+            b._finish_donate,
             max(int(np.prod(x.shape[1:])) for kk, x in b.cache.items()
                 if kk != "tables"))
+        check_chunk(name, b)
+
+    # the chunk program of the families whose transient row's leaves are
+    # BY LAYER KIND (`llama.prefill_by_kind`: latents, K and V of two
+    # kinds, a strided leaf, state leaves beside K and V or alone), each
+    # stack over its own range of the one carried row; rows of sixteen
+    # chunks, so that a layer's row is larger than any activation
+    for preset in CHUNK_BY_KIND_PRESETS:
+        # a chunk is one block: MiniCPM-SALA's must be its selection's
+        bl = getattr(get_model(preset).config, "block_select", None)
+        bl = 16 if bl is None else bl.block
+        check_chunk(preset, family_batcher(
+            preset, prompt_pad=bl, max_len=16 * bl, kv="auto",
+            block_len=bl))
 
     # the speculative step (serving_spec.py): both caches + the per-slot
     # vectors it returns must all alias
@@ -531,6 +640,7 @@ def audit_serving_decode(cfg=None, *, slots: int = 2,
                         b._mixed_donate, layer_elems)
         lower_and_check(name + "_finish", b._prefill_finish,
                         finish_args(b, p_c), b._finish_donate, layer_elems)
+        check_chunk(name, b)
 
     sbm = SpeculativeBatcher(cfg, prepared, cfg, prepared, spec_k=2,
                              slots=slots, max_len=max_len, prompt_pad=16,

@@ -173,9 +173,11 @@ def dense_attn(bp, h, *, cfg, compute_dtype):
 
 def chunk_attn(bp, h, rows, start_pos, *, cfg, compute_dtype, attn_kernel):
     """Attention of one "full" block over a prefill chunk's normed rows h (1,
-    T, C) at [start_pos, start_pos + T): K and V written into the transient
-    rows {"k", "v" (1, KV, S, d), "kc" (1, KV, S / stride, d)}, the pooled
-    keys that complete inside the chunk written beside them, each query's
+    T, C) at [start_pos, start_pos + T): K and V written into the layer's
+    rows of the transient row cache `rows` (bound to the layer:
+    `paged_kvcache.LayerRows`; rows "k", "v" (1, KV, S, d), "kc" (1, KV, S /
+    stride, d)), the pooled keys that complete inside the chunk written
+    beside them, each query's
     blocks chosen a KV group, attention under the group's mask -> (the
     o-projected output (1, T, C), rows, the form the read took)."""
     from dnn_tpu.ops.pallas.sparse_attention import sparse_prefill_attention
@@ -188,27 +190,23 @@ def chunk_attn(bp, h, rows, start_pos, *, cfg, compute_dtype, attn_kernel):
     q, k, v = llama._qkv_rope(bp, h, positions, cfg=cfg,
                               compute_dtype=compute_dtype,
                               kind=llama.kv_kinds(cfg)["full"])
-    kst = k.astype(rows["k"].dtype)  # as the pool holds it
+    kst = k.astype(rows.leaves["k"].dtype)  # as the pool holds it
     with jax.named_scope("bsel.pool"):
         # the stride before the chunk, from the row (at start 0 whatever
         # lies there: row 0 is never a key)
         prev = lax.dynamic_slice_in_dim(
             rows["k"], jnp.maximum(start_pos - m.stride, 0), m.stride, axis=2)
-        kc = pooled_rows(prev, kst, m).astype(rows["kc"].dtype)
+        kc = pooled_rows(prev, kst, m)
     with jax.named_scope("kv_pool.write"):
-        rows = {**rows,
-                "k": lax.dynamic_update_slice_in_dim(
-                    rows["k"], kst, start_pos, axis=2),
-                "v": lax.dynamic_update_slice_in_dim(
-                    rows["v"], v.astype(rows["v"].dtype), start_pos, axis=2),
-                "kc": lax.dynamic_update_slice_in_dim(
-                    rows["kc"], kc, start_pos // m.stride, axis=2)}
+        rows.write(start_pos, k=kst, v=v)
+        rows.write(start_pos // m.stride, kc=kc)
+    c = rows.read("k", "v", "kc")
     sel = _position_mask(
-        chosen_blocks(q.reshape(1, kv, g, t, d), rows["kc"], positions,
-                      m)[0], positions, m, rows["k"].shape[2])  # (KV, T, S)
+        chosen_blocks(q.reshape(1, kv, g, t, d), c["kc"], positions,
+                      m)[0], positions, m, c["k"].shape[2])  # (KV, T, S)
     with jax.named_scope("attn.block_prefill"):
         y = sparse_prefill_attention(
-            q[0].reshape(kv, g, t, d), rows["k"][0], rows["v"][0], sel,
+            q[0].reshape(kv, g, t, d), c["k"][0], c["v"][0], sel,
             start_pos, interpret=interpret)
     form = "masked_kernel" if (
         interpret or jax.default_backend() == "tpu") else "plain"

@@ -51,6 +51,7 @@ from jax import lax
 from dnn_tpu.models import llama
 from dnn_tpu.ops.attention import apply_rope, merge_heads, rope_cos_sin
 from dnn_tpu.ops.nn import linear
+from dnn_tpu.runtime.paged_kvcache import scan_rows
 
 _NEG_BIG = -1e30
 
@@ -122,7 +123,9 @@ def select(scores, valid, k: int):
     # a valid key is never 0: the smallest, -inf's, is 0x007fffff
 
     def bit(i, tau):
-        cand = tau | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        # (`asarray`: the loop hands a Python int where jit is disabled)
+        cand = tau | (jnp.uint32(1) << (
+            jnp.uint32(31) - jnp.asarray(i).astype(jnp.uint32)))
         enough = (key >= cand).sum(-1, keepdims=True) >= k
         return jnp.where(enough, cand, tau)
 
@@ -190,12 +193,14 @@ def dense_attn(bp, h, *, cfg, compute_dtype):
                   compute_dtype=compute_dtype)
 
 
-def _chunk_block(bp, x, layer_cache, start_pos, *, cfg, compute_dtype, ffn,
+def _chunk_block(bp, x, rows, start_pos, *, cfg, compute_dtype, ffn,
                  attn_kernel):
     """One block over a prefill chunk x (1, T, C) at [start_pos,
-    start_pos + T): K, V and the index key written into the transient
-    row {"k", "v" (1, KV, S, D), "ik" (1, 1, S, Di)}, each query's set
-    chosen among the row's positions up to its own, attention under it."""
+    start_pos + T): K, V and the index key written into the layer's rows of
+    the transient row cache `rows` (bound to the layer: `paged_kvcache.
+    LayerRows`; rows "k", "v" (1, KV, S, D), "ik" (1, 1, S, Di)), each
+    query's set chosen among the row's positions up to its own, attention
+    under it."""
     from dnn_tpu.ops.pallas.sparse_attention import (
         chunk_index_scores,
         sparse_prefill_attention,
@@ -213,15 +218,9 @@ def _chunk_block(bp, x, layer_cache, start_pos, *, cfg, compute_dtype, ffn,
         with jax.named_scope("dsa.index"):
             qi, ki, w = index_project(bp["attn"]["indexer"], h, positions,
                                       cfg=cfg, compute_dtype=compute_dtype)
-        c = layer_cache
         with jax.named_scope("kv_pool.write"):
-            c = {"k": lax.dynamic_update_slice_in_dim(
-                     c["k"], k.astype(c["k"].dtype), start_pos, axis=2),
-                 "v": lax.dynamic_update_slice_in_dim(
-                     c["v"], v.astype(c["v"].dtype), start_pos, axis=2),
-                 "ik": lax.dynamic_update_slice_in_dim(
-                     c["ik"], ki[:, None].astype(c["ik"].dtype), start_pos,
-                     axis=2)}
+            rows.write(start_pos, k=k, v=v, ik=ki[:, None])
+        c = rows.read()
         with jax.named_scope("dsa.index"):
             scores = chunk_index_scores(qi[0], w[0], c["ik"][0, 0],
                                         start_pos, interpret=interpret)
@@ -239,7 +238,7 @@ def _chunk_block(bp, x, layer_cache, start_pos, *, cfg, compute_dtype, ffn,
     with jax.named_scope("llama.block.mlp"):
         return (llama._branches_residual(bp, x, o, h, cfg=cfg,
                                          compute_dtype=compute_dtype,
-                                         ffn=ffn), c)
+                                         ffn=ffn), rows)
 
 
 class DsaFamilyRows(llama.LlamaFamilyRows):
@@ -282,23 +281,21 @@ class DsaFamilyRows(llama.LlamaFamilyRows):
 
         blocks, bind = llama.scan_form(prepared["blocks"], self.ffn)
 
-        def layer(carry, layer_in):
+        def block(bp, carry, rows):
             x, acc = carry
-            bp, layer_cache = layer_in
             bp = bind(bp)
 
             def run(f):
                 return _chunk_block(
-                    bp, x, layer_cache, start_pos, cfg=cfg,
+                    bp, x, rows, start_pos, cfg=cfg,
                     compute_dtype=compute_dtype, ffn=f,
                     attn_kernel=self.attn_kernel)
 
-            (y, layer_cache), acc = llama._run_block(self.ffn, acc, run)
-            return (y, acc), layer_cache
+            (y, rows), acc = llama._run_block(self.ffn, acc, run)
+            return (y, acc), rows
 
         acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
-        (x, acc), new_cache = lax.scan(layer, (x, acc0),
-                                       (blocks, row_cache))
+        (x, acc), new_cache = scan_rows(block, (x, acc0), blocks, row_cache)
         x = x.astype(jnp.float32)  # what `head` is handed, in the finish
         if moe_stats:
             return x, new_cache, acc

@@ -1321,7 +1321,7 @@ def layer_stacks(prepared, cfg):
     whose params stack apart as `prepared["dense_blocks"]` in front of
     the expert layers'. Each is scanned on its own; a paged pool is
     reached by layer index across both (`paged_kvcache.scan_blocks(
-    layers=)`), a dense cache or a transient row is sliced by the range.
+    layers=)`), as is a transient row (`paged_kvcache.scan_rows(layers=)`).
 
     A config whose layers are of KINDS that interleave (`layer_types`,
     models/mla.py) has a stack a kind of params (`gpt.stack_layers`) and
@@ -1527,10 +1527,12 @@ def _run_block(ffn, acc, run):
     return result, acc + got[0]
 
 
-def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: LlamaConfig,
+def _block_with_cache(bp, x, rows, start_pos, *, cfg: LlamaConfig,
                       compute_dtype, codec, window=None, ffn=None):
     """Block over x (B, T, C) at absolute positions [start_pos,
-    start_pos+T), writing ROTATED k (and v) into the narrow KV-head cache.
+    start_pos+T), writing ROTATED k (and v) into its layer of the narrow
+    KV-head cache `rows` (the whole cache bound to the layer:
+    paged_kvcache.scan_rows).
     GQA against the cache rides the same codec.attend as the GPT family by
     folding the q group into the row dim and tiling pos_limit. `window`
     overrides the codec's window for this layer (the alternating-attention
@@ -1541,7 +1543,8 @@ def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: LlamaConfig,
         h = _pre_normed(bp, x, cfg)
         q, k, v = _qkv_rope(bp, h, start_pos + jnp.arange(t), cfg=cfg,
                             compute_dtype=compute_dtype)
-        layer_cache = codec.write(layer_cache, k, v, start_pos)
+        rows = codec.write(rows, k, v, start_pos)
+        layer_cache = rows.read()
         qg = q.reshape(b, kv, g * t, cfg.head_dim)
         if t == 1:
             # decode step: the folded group rows all share the slot's
@@ -1561,7 +1564,7 @@ def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: LlamaConfig,
     with jax.named_scope("llama.block.mlp"):
         return (_branches_residual(bp, x, o, h, cfg=cfg,
                                    compute_dtype=compute_dtype, ffn=ffn),
-                layer_cache)
+                rows)
 
 
 def init_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=jnp.float32):
@@ -1597,6 +1600,7 @@ def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
     the expert layers' sums). A serving prefill chunk ends here
     (LlamaFamilyRows.prefill)."""
     from dnn_tpu.runtime.kvcache import codec_for_cache
+    from dnn_tpu.runtime.paged_kvcache import scan_rows
 
     if getattr(cfg, "mla", None) is not None or getattr(
             cfg, "first_k_dense", 0) or kv_kinds(cfg) is not None:
@@ -1617,24 +1621,21 @@ def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
 
     blocks, bind = scan_form(prepared["blocks"], ffn)
 
-    def layer(carry, layer_in):
+    def block(bp, carry, rows, window=None):  # this layer's, if any
         x, acc = carry
-        bp, layer_cache, *w = layer_in  # w: this layer's window, if any
         bp = bind(bp)
 
         def run(f):
             return _block_with_cache(
-                bp, x, layer_cache, start_pos, cfg=cfg,
-                compute_dtype=compute_dtype, codec=codec,
-                window=w[0] if w else None, ffn=f)
+                bp, x, rows, start_pos, cfg=cfg, compute_dtype=compute_dtype,
+                codec=codec, window=window, ffn=f)
 
-        (y, layer_cache), acc = _run_block(ffn, acc, run)
-        return (y, acc), layer_cache
+        (y, rows), acc = _run_block(ffn, acc, run)
+        return (y, acc), rows
 
     acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
-    (x, acc), new_cache = lax.scan(
-        layer, (x, acc0),
-        (blocks, cache) + (() if wins is None else (wins,)))
+    (x, acc), new_cache = scan_rows(
+        block, (x, acc0), blocks, cache, *(() if wins is None else (wins,)))
     x = x.astype(jnp.float32)
     if moe_stats:
         return x, new_cache, acc
@@ -2226,22 +2227,26 @@ class LlamaFamilyRows:
 
 
 def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
-                    moe_stats, leaves, **chunk_kw):
+                    moe_stats, first="full", **chunk_kw):
     """A family's `prefill` where the transient row's leaves are BY LAYER
-    KIND (`leaves`: kind -> its leaf names; models/mla.py's latents,
-    `LlamaKindRows`' K and V): each stack of `layer_stacks` is scanned
-    over its kind's rows — its range of them where the layers are of
-    kinds — through `family._chunk_block(bp, x, rows, start_pos, ffn,
-    kind)` -> (x, rows). -> (hidden (1, P, C) float32, the row cache[,
-    the expert layers' sums]). `chunk_kw` goes on to `_chunk_block` (a
-    state kind's count of real positions, models/kda.py)."""
+    KIND (models/mla.py's latents, `LlamaKindRows`' K and V, a state
+    kind's slot leaves): each stack of `layer_stacks` scans the ONE row
+    cache over its own range of its kind's layers
+    (`paged_kvcache.scan_rows(layers=)`, as `decode_rows` scans the pool)
+    through `family._chunk_block(bp, x, rows, start_pos, ffn, kind)` ->
+    (x, rows), `rows` the whole row cache bound to the layer: a block
+    reaches its kind's leaves by name (`first`: the kind of a model that
+    is ONE stack). -> (hidden (1, P, C) float32, the row cache[, the
+    expert layers' sums]). `chunk_kw` goes on to `_chunk_block` (a state
+    kind's count of real positions, models/kda.py)."""
+    from dnn_tpu.runtime.paged_kvcache import scan_rows
+
     x = _scaled_embed(prepared, padded, family.cfg)
     if family.compute_dtype is not None:
         x = x.astype(family.compute_dtype)
 
-    def layer(bind, kind, carry, layer_in):
+    def block(bind, kind, bp, carry, rows):
         x, acc = carry
-        bp, rows = layer_in
         bp = bind(bp)
         (y, rows), acc = _run_block(
             family.ffn, acc,
@@ -2250,25 +2255,16 @@ def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
         return (y, acc), rows
 
     carry = (x, jnp.zeros((3,), jnp.int32) if moe_stats else None)
-    new_rows = {name: [] for name in row_cache}
-    first = next(iter(leaves))  # the kind of a model that is ONE stack
     for stack, layers, kind in layer_stacks(prepared, family.cfg):
-        names = [n for n in leaves[kind or first] if n in row_cache]
-        rows = {n: row_cache[n] if layers is None
-                else row_cache[n][layers[0]:layers[1]] for n in names}
         blocks, bind = scan_form(stack, family.ffn)
-        carry, rows = lax.scan(
-            functools.partial(layer, bind, kind or first), carry,
-            (blocks, rows))
-        for n in names:
-            new_rows[n].append(rows[n])
+        carry, row_cache = scan_rows(
+            functools.partial(block, bind, kind or first), carry, blocks,
+            row_cache, layers=None if layers is None else jnp.arange(*layers))
     x, acc = carry
-    new_cache = {n: r[0] if len(r) == 1 else jnp.concatenate(r)
-                 for n, r in new_rows.items()}
     x = x.astype(jnp.float32)  # what `head` is handed, in the finish
     if moe_stats:
-        return x, new_cache, acc
-    return x, new_cache
+        return x, row_cache, acc
+    return x, row_cache
 
 
 class LlamaKindRows(LlamaFamilyRows):
@@ -2358,7 +2354,8 @@ class LlamaKindRows(LlamaFamilyRows):
     def _chunk_attn(self, bp, h, rows, start_pos, kind):
         """Attention of one block over a prefill chunk's normed rows h (1,
         T, C) at [start_pos, start_pos + T): the chunk's K and V written
-        into the layer's transient rows `rows` {leaf: (1, KV, S, D)}, then
+        into the layer's rows of the transient row cache `rows` (bound to
+        the layer: `paged_kvcache.LayerRows`; a row (1, KV, S, D)), then
         attended with the group folded into the kernel's rows (row g * T +
         t reads columns <= start_pos + t, within the kind's band) -> (the
         o-projected output (1, T, C), rows)."""
@@ -2372,18 +2369,17 @@ class LlamaKindRows(LlamaFamilyRows):
         q, k, v = _qkv_rope(bp, h, start_pos + jnp.arange(t), cfg=cfg,
                             compute_dtype=compute_dtype, kind=kk)
         with jax.named_scope("kv_pool.write"):
-            rows = {**rows, **{n: lax.dynamic_update_slice_in_dim(
-                rows[n], new.astype(rows[n].dtype), start_pos, axis=2)
-                for n, new in ((k_name, k), (v_name, v))}}
+            rows.write(start_pos, **{k_name: k, v_name: v})
+        k_row, v_row = rows[k_name], rows[v_name]
         interpret = True if self.attn_kernel == "interpret" else None
         # the largest tile up to 512 that divides the chunk and the
         # row: a 128 x 128 tile costs 3.4-4.7x a (512, 512) one on a
         # v5e for the same pairs (PERF.md section 6, PR 43)
         tile = next(n for n in (512, 256, 128, t)
-                    if t % n == 0 and rows[k_name].shape[2] % n == 0)
+                    if t % n == 0 and k_row.shape[2] % n == 0)
         attend = functools.partial(
-            cached_attention, q.reshape(1, kv, g * t, d), rows[k_name],
-            rows[v_name], jnp.reshape(start_pos, (1,)).astype(jnp.int32),
+            cached_attention, q.reshape(1, kv, g * t, d), k_row, v_row,
+            jnp.reshape(start_pos, (1,)).astype(jnp.int32),
             rows_mod=t, block_q=tile, block_s=tile, interpret=interpret)
         if kk.window is None:
             y = attend()
@@ -2418,9 +2414,7 @@ class LlamaKindRows(LlamaFamilyRows):
         the kind keeps: paged, strided and slot leaves."""
         return prefill_by_kind(
             self, prepared, padded, row_cache, start_pos, moe_stats,
-            {kind: (*k["leaves"], *k.get("strided_leaves", ()),
-                    *k.get("slot_leaves", ()))
-             for kind, k in self.cache_kinds.items()}, **chunk_kw)
+            next(iter(self.cache_kinds)), **chunk_kw)
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
                    kind="full"):
@@ -2482,13 +2476,13 @@ class LlamaPipelineFamily:
         stage_cfg = dataclasses.replace(self.cfg, n_layer=per_stage)
         return init_cache(stage_cfg, batch, s_max, dt)
 
-    def block_with_cache(self, bp, x, layer_cache, start_pos):
+    def block_with_cache(self, bp, x, rows, start_pos):
         from dnn_tpu.runtime.kvcache import codec_for_cache
 
         return _block_with_cache(
-            bp, x, layer_cache, start_pos, cfg=self.cfg,
+            bp, x, rows, start_pos, cfg=self.cfg,
             compute_dtype=self.compute_dtype,
-            codec=codec_for_cache(layer_cache,
+            codec=codec_for_cache(rows.leaves,
                                   window=self.cfg.sliding_window,
                                   softcap=self.cfg.attn_softcap))
 

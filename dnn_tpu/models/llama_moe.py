@@ -988,6 +988,7 @@ def make_pipeline_generate_ep(cfg: MixtralConfig, mesh, *,
     from dnn_tpu.parallel.moe import moe_capacity, moe_ffn_local
     from dnn_tpu.runtime.generate import _sample
     from dnn_tpu.runtime.kvcache import codec_for_cache
+    from dnn_tpu.runtime.paged_kvcache import scan_rows
 
     if max_new_tokens < 1:
         raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
@@ -1040,13 +1041,11 @@ def make_pipeline_generate_ep(cfg: MixtralConfig, mesh, *,
             def sub(carry, s):
                 h, cache = carry
 
-                def layer(carry2, layer_in):
-                    bp, layer_cache = layer_in
-                    return llama._block_with_cache(
-                        bp, carry2, layer_cache, start_pos, cfg=cfg,
-                        compute_dtype=compute_dtype, codec=codec, ffn=ffn)
-
-                h2, cache2 = lax.scan(layer, h, (local, cache))
+                h2, cache2 = scan_rows(
+                    lambda bp, x, rows: llama._block_with_cache(
+                        bp, x, rows, start_pos, cfg=cfg,
+                        compute_dtype=compute_dtype, codec=codec, ffn=ffn),
+                    h, local, cache)
                 active = d == s
                 cache = jax.tree.map(
                     lambda new, old: jnp.where(active, new, old),

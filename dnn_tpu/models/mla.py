@@ -490,7 +490,8 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
     def _chunk_block(self, bp, x, rows, start_pos, ffn, kind="full"):
         """One block over a prefill chunk x (1, T, C) at [start_pos,
         start_pos + T): the chunk's cache rows written into the layer's
-        transient rows `rows` {leaf: (1, 1, S, width)}, attention
+        rows of the transient row cache `rows` (bound to the layer:
+        `paged_kvcache.LayerRows`; a row (1, 1, S, width)), attention
         up-projected over the smallest prefix of the row that holds the
         context (`prefix_lengths`: cut where the kernel's full column
         tile divides them, one branch of a switch each) — or, for a kind
@@ -500,7 +501,7 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
         m = self.kinds[kind]
         latent, ik, _ = KIND_LEAVES[kind]
         interpret = True if self.attn_kernel == "interpret" else None
-        t, s_len = x.shape[1], rows[latent].shape[2]
+        t, s_len = x.shape[1], rows.leaves[latent].shape[3]
         ap = bp["attn"]
         with jax.named_scope("llama.block.cached_attn"):
             h = llama._pre_normed(bp, x, cfg)
@@ -515,14 +516,9 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
                         ap["indexer"], c_q, h, positions, cfg=cfg, m=m,
                         compute_dtype=compute_dtype)
             with jax.named_scope("kv_pool.write"):
-                rows = dict(rows)
-                rows[latent] = lax.dynamic_update_slice_in_dim(
-                    rows[latent], new[:, None].astype(rows[latent].dtype),
-                    start_pos, axis=2)
+                rows.write(start_pos, **{latent: new[:, None]})
                 if index:
-                    rows[ik] = lax.dynamic_update_slice_in_dim(
-                        rows[ik], ki[:, None].astype(rows[ik].dtype),
-                        start_pos, axis=2)
+                    rows.write(start_pos, **{ik: ki[:, None]})
             lat = rows[latent][0, 0]
             sel = None
             if index:
@@ -573,8 +569,7 @@ class MlaFamilyRows(llama.LlamaFamilyRows):
     def prefill(self, prepared, padded, row_cache, start_pos=0, *,
                 moe_stats=False):
         return llama.prefill_by_kind(
-            self, prepared, padded, row_cache, start_pos, moe_stats,
-            {kind: names[:2] for kind, names in KIND_LEAVES.items()})
+            self, prepared, padded, row_cache, start_pos, moe_stats)
 
     def _attn_rows(self, bp, x, layer_cache, pos, write, codec, window,
                    kind="full"):

@@ -9,8 +9,9 @@ field that turns it on, the layer kind it serves, where its params sit in a
 block, its `slot_leaves(cfg)` and its two forms under ONE signature, the
 kind's leaves a mapping by name that comes in holding the incoming ones
 and is left holding those after the last real position (`leaves[name]`,
-`leaves.update(name=new, ...)`: a dict, or a layer of the pool as
-`LayerLeaves`) —
+`leaves.update(name=new, ...)`: a dict, a layer of the pool as
+`LayerLeaves`, or a layer of a chunk's transient row as
+`paged_kvcache.LayerRows`) —
 
     chunk(p, h, leaves, start_pos, n_real, *, cfg, compute_dtype, kernel)
         h (B, T, C) at [start_pos, start_pos + T), the first `n_real` real
@@ -243,7 +244,6 @@ class StateKindRows(llama.LlamaKindRows):
         kernel = self.kernel_form("chunk")
         self.attn_forms[rule.kind][rule.forms[0]] = (
             "chunked_kernel" if kernel else "chunked_jnp")
-        rows = dict(rows)
         o = rule.chunk(
             rule.of(bp), h, rows, start_pos,
             h.shape[1] if n_real is None else n_real, cfg=self.cfg,

@@ -37,6 +37,7 @@ from dnn_tpu.runtime.kvcache import (
     Int8KV,
     codec_for_cache,
 )
+from dnn_tpu.runtime.paged_kvcache import scan_rows
 
 _NEG_BIG = -1e30
 
@@ -65,25 +66,26 @@ def _qkv_heads(bp, h, *, cfg: GPTConfig, compute_dtype):
     return tuple(split_heads(t, cfg.n_head) for t in (q, k, v))  # (B,H,T,D)
 
 
-def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: GPTConfig,
+def _block_with_cache(bp, x, rows, start_pos, *, cfg: GPTConfig,
                       compute_dtype, ffn=None, codec=None):
     """One transformer block over x (B, T, C) whose tokens sit at positions
-    [start_pos, start_pos+T); writes this block's K/V into the per-layer
-    cache (a codec pytree — float or int8+scales) and attends against
-    everything cached so far. T=prompt_len for prefill, T=1 for decode —
-    same code path. `ffn(bp, h)` overrides the dense MLP (the MoE family
-    plugs its routed FFN in here, dnn_tpu/runtime/generate_moe.py)."""
-    codec = codec or codec_for_cache(layer_cache)
+    [start_pos, start_pos+T); writes this block's K/V into its layer of the
+    cache `rows` (the whole codec pytree — float or int8+scales — bound to
+    the layer: paged_kvcache.scan_rows) and attends against everything
+    cached so far. T=prompt_len for prefill, T=1 for decode — same code
+    path. `ffn(bp, h)` overrides the dense MLP (the MoE family plugs its
+    routed FFN in here, dnn_tpu/runtime/generate_moe.py)."""
+    codec = codec or codec_for_cache(rows.leaves)
     t = x.shape[1]
     # the scope names of models/gpt._block_core (device-trace names)
     with jax.named_scope("gpt.block.attn"):
         h = layer_norm(bp["ln_1"], x, eps=cfg.ln_eps)
         q, k, v = _qkv_heads(bp, h, cfg=cfg, compute_dtype=compute_dtype)
-        layer_cache = codec.write(layer_cache, k, v, start_pos)
+        rows = codec.write(rows, k, v, start_pos)
         pos_limit = start_pos + jnp.arange(t)  # causal within the new tokens
         # base= asserts the contiguous-limit contract the Pallas kernel
         # needs (kvcache.FloatKV.attend) — einsum codecs ignore it
-        y = codec.attend(q, layer_cache, pos_limit, base=start_pos)
+        y = codec.attend(q, rows.read(), pos_limit, base=start_pos)
         x = x + linear(bp["attn"]["proj"], merge_heads(y.astype(x.dtype)),
                        compute_dtype=compute_dtype)
     with jax.named_scope("gpt.block.mlp"):
@@ -95,7 +97,7 @@ def _block_with_cache(bp, x, layer_cache, start_pos, *, cfg: GPTConfig,
                        compute_dtype=compute_dtype)
         else:
             m = ffn(bp, h).astype(x.dtype)
-    return x + m, layer_cache
+    return x + m, rows
 
 
 def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: GPTConfig,
@@ -128,16 +130,13 @@ def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: GPTConfig,
     codec = codec_for_cache(cache, use_kernel=attn_kernel)
     x = _embed_at(prepared, ids, start_pos, compute_dtype=compute_dtype)
 
-    def layer(carry, layer_in):
-        bp, layer_cache = layer_in
-        x, layer_cache = _block_with_cache(
-            bp, carry, layer_cache, start_pos, cfg=cfg,
-            compute_dtype=compute_dtype, ffn=ffn, codec=codec,
-        )
-        return x, layer_cache
+    def block(bp, x, rows):
+        return _block_with_cache(
+            bp, x, rows, start_pos, cfg=cfg, compute_dtype=compute_dtype,
+            ffn=ffn, codec=codec)
 
     with jax.named_scope("layers.scan"):  # the loop's own slicing
-        x, new_cache = lax.scan(layer, x, (prepared["blocks"], cache))
+        x, new_cache = scan_rows(block, x, prepared["blocks"], cache)
     return x.astype(jnp.float32), new_cache
 
 
@@ -339,9 +338,9 @@ class GPTPipelineFamily:
         stage_cfg = dataclasses.replace(cfg, n_layer=per_stage)
         return init_cache(stage_cfg, batch, s_max, dt)
 
-    def block_with_cache(self, bp, x, layer_cache, start_pos):
+    def block_with_cache(self, bp, x, rows, start_pos):
         return _block_with_cache(
-            bp, x, layer_cache, start_pos, cfg=self.cfg,
+            bp, x, rows, start_pos, cfg=self.cfg,
             compute_dtype=self.compute_dtype, ffn=self.ffn)
 
     def embed(self, aux, ids, start_pos):
@@ -422,11 +421,10 @@ def make_pipeline_generate(cfg: GPTConfig, mesh, *, max_new_tokens: int,
         cache = fam.stage_cache(per_stage, b, s_max)
 
         def my_blocks(x, cache, start_pos):
-            def layer(carry, layer_in):
-                bp, layer_cache = layer_in
-                return fam.block_with_cache(bp, carry, layer_cache, start_pos)
-
-            return lax.scan(layer, x, (local, cache))
+            return scan_rows(
+                lambda bp, x, rows: fam.block_with_cache(bp, x, rows,
+                                                         start_pos),
+                x, local, cache)
 
         def ring_pass(x, cache, start_pos):
             """x real on stage 0 -> through all stages in order -> real

@@ -179,6 +179,7 @@ def make_pipeline_generate_moe_ep(cfg: GPTMoEConfig, mesh, *,
 
     from dnn_tpu.parallel.mesh import STAGE_AXIS
     from dnn_tpu.runtime.generate import _block_with_cache
+    from dnn_tpu.runtime.paged_kvcache import scan_rows
 
     if max_new_tokens < 1:
         raise ValueError(f"max_new_tokens must be >= 1, got {max_new_tokens}")
@@ -238,13 +239,11 @@ def make_pipeline_generate_moe_ep(cfg: GPTMoEConfig, mesh, *,
             def sub(carry, s):
                 h, cache = carry
 
-                def layer(carry2, layer_in):
-                    bp, layer_cache = layer_in
-                    return _block_with_cache(
-                        bp, carry2, layer_cache, start_pos, cfg=cfg,
-                        compute_dtype=compute_dtype, ffn=ffn)
-
-                h2, cache2 = lax.scan(layer, h, (local, cache))
+                h2, cache2 = scan_rows(
+                    lambda bp, x, rows: _block_with_cache(
+                        bp, x, rows, start_pos, cfg=cfg,
+                        compute_dtype=compute_dtype, ffn=ffn),
+                    h, local, cache)
                 active = d == s
                 cache = jax.tree.map(
                     lambda new, old: jnp.where(active, new, old),

@@ -24,10 +24,13 @@ Scheme, mirroring quant.py's weight recipe:
     path); the only new error is the per-row int8 rounding of K/V, which
     the parity test bounds (cosine > 0.999, token-parity on real decodes).
 
-A codec is three functions over a PER-LAYER cache pytree (every leaf
-carries a leading L axis at rest; `lax.scan` peels it): `init`, `write`,
-`attend`. `generate.forward_with_cache` threads whichever codec matches
-its cache, so the same decode loop serves f32, bf16, and int8 caches.
+A codec is three functions over a cache pytree whose every leaf carries a
+leading L axis: `init`; `write`, which is handed the WHOLE cache bound to
+one layer (`paged_kvcache.LayerRows`: the layer loop carries the cache and
+a block writes its T positions in place, `paged_kvcache.scan_rows`); and
+`attend` over one layer's leaves. `generate.forward_with_cache` threads
+whichever codec matches its cache, so the same decode loop serves f32,
+bf16, and int8 caches.
 
 **Sliding windows** (Mistral-class models) come in two forms:
 
@@ -216,13 +219,11 @@ class FloatKV(_KernelDispatch):
                 "v": jnp.zeros(shape, self.dtype)}
 
     def write(self, c, k, v, start_pos):
-        """c: per-layer {"k","v"} (B,H,S,D); k/v (B,H,T,D) at start_pos."""
-        return {
-            "k": lax.dynamic_update_slice_in_dim(
-                c["k"], k.astype(c["k"].dtype), start_pos, axis=2),
-            "v": lax.dynamic_update_slice_in_dim(
-                c["v"], v.astype(c["v"].dtype), start_pos, axis=2),
-        }
+        """k/v (B,H,T,D) at start_pos of the layer's rows of `c`: the whole
+        cache {"k","v"} (L,B,H,S,D) bound to the layer
+        (paged_kvcache.LayerRows) — T positions are written, in place."""
+        c.write(start_pos, k=k, v=v)
+        return c
 
     def attend(self, q, c, pos_limit, base=None, window=None):
         """q (B,H,T,D) against the full cache, masking key positions >
@@ -372,12 +373,8 @@ class Int8KV(_KernelDispatch):
     def write(self, c, k, v, start_pos):
         kq, ks = self._quant(k)
         vq, vs = self._quant(v)
-        return {
-            "k": lax.dynamic_update_slice_in_dim(c["k"], kq, start_pos, axis=2),
-            "v": lax.dynamic_update_slice_in_dim(c["v"], vq, start_pos, axis=2),
-            "ks": lax.dynamic_update_slice_in_dim(c["ks"], ks, start_pos, axis=2),
-            "vs": lax.dynamic_update_slice_in_dim(c["vs"], vs, start_pos, axis=2),
-        }
+        c.write(start_pos, k=kq, v=vq, ks=ks, vs=vs)
+        return c
 
     def attend(self, q, c, pos_limit, base=None, window=None):
         # `base` marks the pos_limit == base + arange(T) contract (see
@@ -559,19 +556,22 @@ class _RingStorage:
         return (ring_positions(pos, c["k"].shape[2]) >= 0)[:, None, None, :]
 
     @staticmethod
-    def _ring_scatter(c, new, start_pos, w: int):
+    def _ring_scatter(c, new, start_pos):
         """Write rows at absolute positions [start_pos, start_pos+t) into
-        their ring slots; only the last min(t, w) rows survive the wrap,
-        and their slots are distinct — a plain scatter."""
+        their ring slots of the layer's rows of `c` (a LayerRows); only
+        the last min(t, w) rows survive the wrap, and their slots are
+        distinct — a plain scatter into the layer's ring."""
         t = next(iter(new.values())).shape[2]
+        w = c.leaves["k"].shape[3]
         if t == 1:
-            slot = jnp.mod(start_pos, w)
-            return {kk: lax.dynamic_update_slice_in_dim(
-                c[kk], new[kk], slot, axis=2) for kk in new}
+            c.write(jnp.mod(start_pos, w), **new)
+            return c
         m = min(t, w)
         slots = jnp.mod(start_pos + jnp.arange(t - m, t), w)
-        return {kk: c[kk].at[:, :, slots].set(new[kk][:, :, t - m:])
-                for kk in new}
+        c.update(**{kk: c[kk].at[:, :, slots].set(
+            rows[:, :, t - m:].astype(c.leaves[kk].dtype))
+            for kk, rows in new.items()})
+        return c
 
 
 class RollingFloatKV(_RingStorage, FloatKV):
@@ -585,10 +585,7 @@ class RollingFloatKV(_RingStorage, FloatKV):
         super().__init__(dtype, use_kernel=False, window=window)
 
     def write(self, c, k, v, start_pos):
-        w = c["k"].shape[2]
-        return self._ring_scatter(
-            c, {"k": k.astype(c["k"].dtype), "v": v.astype(c["v"].dtype)},
-            start_pos, w)
+        return self._ring_scatter(c, {"k": k, "v": v}, start_pos)
     # attend_rows: FloatKV's einsum with _RingStorage._rows_keep
 
 
@@ -603,11 +600,10 @@ class RollingInt8KV(_RingStorage, Int8KV):
         super().__init__(use_kernel=False, window=window)
 
     def write(self, c, k, v, start_pos):
-        w = c["k"].shape[2]
         kq, ks = self._quant(k)
         vq, vs = self._quant(v)
         return self._ring_scatter(
-            c, {"k": kq, "v": vq, "ks": ks, "vs": vs}, start_pos, w)
+            c, {"k": kq, "v": vq, "ks": ks, "vs": vs}, start_pos)
     # attend_rows: Int8KV's scaled einsum with _RingStorage._rows_keep
 
 

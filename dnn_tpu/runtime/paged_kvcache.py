@@ -146,6 +146,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from dnn_tpu.runtime.kvcache import band_keep
 
@@ -153,7 +154,7 @@ _NEG_BIG = -1e30
 
 __all__ = ["PagedKV", "BlockAllocator", "InsufficientBlocks",
            "init_paged_cache", "lane_padded", "cache_head_dim", "scan_blocks",
-           "window_blocks", "is_tables"]
+           "scan_rows", "LayerRows", "window_blocks", "is_tables"]
 
 
 class InsufficientBlocks(RuntimeError):
@@ -983,6 +984,97 @@ def codec_is_paged(cache) -> bool:
 def is_tables(name: str) -> bool:
     """Whether a cache entry is a kind's block tables, not a leaf."""
     return name.startswith("tables")
+
+
+class LayerRows:
+    """The WHOLE transient row cache `leaves` {name: (L, 1, H, S[, D])}
+    (a state kind's slot leaves (L, 1, ...)) bound to one layer's index
+    (`scan_rows`): what a prefill chunk's block is handed in place of the
+    layer's rows cut out of it. Two things reach the row —
+
+      * `rows[name]` / `read(*names)`: the layer's row of a leaf, (1, H,
+        S[, D]) — one `dynamic_index_in_dim` of the whole leaf, the read
+        the attention kernel needs anyway;
+      * `write(start, name=new)`: `new` (1, H, T[, D]) goes in at `[layer,
+        :, :, start:start + T]` of the whole leaf — T rows move, not S (a
+        strided leaf's caller says its own start and hands its T / stride
+        rows); `update(name=new)`: a leaf that IS the layer's whole content
+        (a state and its convolution's tail) is written whole at the
+        layer's index — the mapping a state rule's chunk form is handed
+        (models/state_kind.py).
+
+    Write before read: a read after a write sees it."""
+
+    def __init__(self, leaves, layer):
+        self.leaves, self.layer = leaves, layer
+
+    def __getitem__(self, name):
+        # held as the leaf is: what reads the row relays the ROW (left to
+        # itself the compiler relaid a whole latent leaf and cut the
+        # layer's row out of the copy, every layer)
+        return _as_held(lax.dynamic_index_in_dim(
+            self.leaves[name], self.layer, 0, keepdims=False))
+
+    def read(self, *names):
+        return {name: self[name] for name in names or self.leaves}
+
+    def _put(self, new, start=None):
+        for name, rows in new.items():
+            leaf = self.leaves[name]
+            at = [self.layer] + [0] * (leaf.ndim - 1)
+            if start is not None:
+                at[3] = start
+            self.leaves = {**self.leaves, name: lax.dynamic_update_slice(
+                leaf, rows[None].astype(leaf.dtype), at)}
+
+    def write(self, start, **new):
+        self._put(new, start)
+
+    def update(self, **new):
+        self._put(new)
+
+
+def scan_rows(block, carry, blocks, rows, *xs, layers=None):
+    """`scan_blocks` turned to a prefill chunk's transient row cache `rows`
+    {name: (L, 1, H, S[, D])}: `block(bp, carry, rows, *xs_l) -> (carry,
+    rows)` once a layer, `rows` the WHOLE row cache bound to the layer's
+    index (`LayerRows`) -> (carry, the row cache). The row cache is the
+    loop's CARRY and never its xs / ys — a scan cannot alias the two, so a
+    row that rode as xs was copied whole, each layer's row cut out of it
+    and the whole cut-out written back for a chunk's T new positions —: the
+    chunk program's donated row is the carry and the result, touched only
+    by the blocks' writes. `blocks` and the layers' indices are the only
+    xs (`xs`: further per-layer inputs); `layers` is the stack's own range
+    of the row's layers where the model's layers are of kinds, each kind's
+    stack over ITS leaves of the one carry (llama.layer_stacks)."""
+    if layers is None:
+        layers = jnp.arange(jax.tree.leaves(rows)[0].shape[0])
+
+    def body(c, layer_in):
+        (carry, leaves), (bp, layer, *rest) = c, layer_in
+        carry, bound = block(bp, carry, LayerRows(leaves, layer), *rest)
+        return (carry, _as_held(bound.leaves)), None
+
+    (carry, rows), _ = lax.scan(body, (carry, rows), (blocks, layers, *xs))
+    return carry, rows
+
+
+def _as_held(leaves):
+    """`leaves`, each constrained to the layout in which the backend's
+    devices HOLD an array of its shape — the layout the row arrives in and
+    leaves in, which a program cannot choose. Left to itself the chip's
+    compiler gives the loop's carry the layout the attention kernel reads
+    (head width minor-most) where the device holds positions minor-most (a
+    width that does not fill whole 128-lane tiles: GPT-2's heads of 64, a
+    latent row of 576), and transposes the WHOLE row cache into the loop
+    and out of it again every chunk: two whole-row copies a leaf, more than
+    the xs / ys form paid. Held as it arrives, a layer's row is transposed
+    once, where the kernel reads it."""
+    device = jax.devices()[0]
+
+    return jax.tree.map(lambda x: with_layout_constraint(
+        x, Layout.from_pjrt_layout(device.client.get_default_layout(
+            x.dtype, x.shape, device))), leaves)
 
 
 def scan_blocks(block, x, blocks, cache, codec, *xs, layers=None):

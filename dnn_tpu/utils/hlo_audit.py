@@ -30,15 +30,17 @@ update.
 
 from __future__ import annotations
 
+import math
 import re
 from typing import Dict, Sequence
 
 import jax
 import jax.numpy as jnp
 
-__all__ = ["lowered_text", "op_result_sizes", "count_cache_sized",
-           "count_aliased", "count_aliased_compiled", "gpt_decode_step",
-           "llama_decode_step", "audit_decode_step"]
+__all__ = ["lowered_text", "op_result_dims", "op_result_sizes",
+           "count_cache_sized", "dus_updates", "count_aliased",
+           "count_aliased_compiled", "gpt_decode_step", "llama_decode_step",
+           "audit_decode_step"]
 
 # `%3 = stablehlo.transpose %2 ... -> tensor<8x12x64x256xf32>` (the last
 # tensor<...> on the line is the result type; rank-0 tensors have no dims)
@@ -63,30 +65,30 @@ def lowered_text(fn, *args, donate_argnums=(), optimize: bool = False) -> str:
         compiled, "runtime_executable") else compiled.as_text()
 
 
-def op_result_sizes(text: str):
-    """[(opcode, result_elem_count)] for every op in StableHLO or HLO
-    text (see module docstring for the two formats)."""
+def op_result_dims(text: str):
+    """[(opcode, result dims)] for every op in StableHLO or HLO text (see
+    module docstring for the two formats)."""
     rows = []
     for line in text.splitlines():
         m = _SHLO_OP.search(line)
         if m:
             tensors = _TENSOR.findall(line)
-            if not tensors:
-                continue
-            n = 1
-            for d in tensors[-1].split("x"):
-                if d:
-                    n *= int(d)
-            rows.append((m.group(1), n))
+            if tensors:
+                rows.append((m.group(1), _dims(tensors[-1], "x")))
             continue
         m = _HLO_INST.search(line)
         if m:
-            n = 1
-            for d in m.group(1).split(","):
-                if d:
-                    n *= int(d)
-            rows.append((m.group(2), n))
+            rows.append((m.group(2), _dims(m.group(1), ",")))
     return rows
+
+
+def _dims(text: str, sep: str):
+    return tuple(int(d) for d in text.split(sep) if d)
+
+
+def op_result_sizes(text: str):
+    """[(opcode, result_elem_count)] for every op in the text."""
+    return [(op, math.prod(dims)) for op, dims in op_result_dims(text)]
 
 
 def count_aliased(text: str) -> int:
@@ -128,6 +130,20 @@ def count_cache_sized(text: str, min_elems: int,
         if n >= min_elems and op in ops:
             counts[op] = counts.get(op, 0) + 1
     return counts
+
+
+def dus_updates(text: str):
+    """[(operand dims, update dims)] of every `dynamic_update_slice` in
+    StableHLO text: what each in-place write MOVES is its update — the
+    result has the operand's extent whatever was written (`stablehlo.
+    dynamic_update_slice %a, %u, %i... : (tensor<a>, tensor<u>, ...)`)."""
+    rows = []
+    for line in text.splitlines():
+        m = _SHLO_OP.search(line)
+        if m and m.group(1) == "dynamic_update_slice":
+            rows.append(tuple(_dims(t, "x") for t in _TENSOR.findall(
+                line.split(" : ", 1)[1])[:2]))
+    return rows
 
 
 # ----------------------------------------------------------------------

@@ -21,7 +21,10 @@ scalar prefetch are then in the text; `minicpm-sala-test`'s and
 lin-step's and the chunked delta rule's plain forms: PR 61, whose kernel
 for the whole chunked rule is built for heads of 128, re-recorded
 `solar-open2-test@interpret`'s chunk program, which held PR 47's scan
-kernel until then)."""
+kernel until then). PR 63 re-recorded the `_prefill_chunk` of all fourteen
+cases and nothing else: the transient row is the chunk program's carry
+since (`paged_kvcache.scan_rows`), written a chunk's positions at a time;
+every `_prefill_finish` and `_decode` hash stayed letter for letter."""
 
 import base64
 import functools

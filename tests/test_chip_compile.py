@@ -407,6 +407,9 @@ def _lower_programs(chip, batchers):
     jax.clear_caches()  # the traces the requests made with the kernels off
     with pytest.MonkeyPatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")
+        # and its devices are the described chip's: a chunk program carries
+        # its row in the layout the DEVICE holds it in (`scan_rows`)
+        m.setattr(jax, "devices", lambda *a: list(chip._device_assignment))
         for name, (fn, args) in calls.items():
             compiled[name] = fn.lower(*jax.tree.map(described, args)).compile()
     jax.clear_caches()  # drop the traces made under the patch
@@ -481,6 +484,25 @@ def test_serving_step_programs_compile_with_the_kernels(step_programs, name,
         # the donated pool aliases the program's result
         assert compiled[name].memory_analysis().alias_size_in_bytes \
             >= pool_bytes
+
+
+def test_chunk_program_writes_its_row_in_place(step_programs):
+    """ISSUE 63: the chunk program carries its donated transient row through
+    the layer loop (`paged_kvcache.scan_rows`) in the layout the device
+    holds it in — positions minor-most for GPT-2's heads of 64 — and writes
+    a chunk's positions into it: nothing of the row cache's extent but the
+    two in-place writes (riding the loop as xs / ys the row was copied whole
+    once a chunk, `copy.55` on the chip; carried in the layout the kernel
+    reads, the compiler transposed it whole into the loop and out again),
+    the row aliased to the result and no temporary of a leaf's size."""
+    chunk = step_programs[0]["_prefill_chunk"]
+    row = re.compile(r"\[2,1,20,%d,64\]" % CTX)
+    assert sorted(op for op, _ in _extent_ops(chunk, row)) == [
+        "dynamic-update-slice"] * 2
+    leaf = 2 * 20 * CTX * 64 * 2
+    mem = chunk.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * leaf
+    assert mem.temp_size_in_bytes < leaf
 
 
 @pytest.mark.parametrize("name", ["_decode_constrained", "_mixed",

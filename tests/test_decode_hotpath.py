@@ -203,18 +203,18 @@ def test_int4_attend_close_to_float():
     """Per-row int4 rounding stays bounded: cosine similarity of the
     attended output vs the f32 codec on the same K/V > 0.99."""
     from dnn_tpu.runtime.kvcache import FloatKV, Int4KV
+    from dnn_tpu.runtime.paged_kvcache import LayerRows
 
     cfg = gpt.GPTConfig(vocab_size=31, block_size=64, n_layer=1,
                         n_head=2, n_embd=32)
     key = jax.random.PRNGKey(1)
     f32 = FloatKV()
     i4 = Int4KV()
-    cf = jax.tree.map(lambda x: x[0], f32.init(cfg, 2, 48))
-    ci = jax.tree.map(lambda x: x[0], i4.init(cfg, 2, 48))
     k = jax.random.normal(jax.random.fold_in(key, 1), (2, 2, 40, 16))
     v = jax.random.normal(jax.random.fold_in(key, 2), (2, 2, 40, 16))
-    cf = f32.write(cf, k, v, 0)
-    ci = i4.write(ci, k, v, 0)
+    # a codec writes into the cache bound to a layer, and attends its rows
+    cf = f32.write(LayerRows(f32.init(cfg, 2, 48), 0), k, v, 0).read()
+    ci = i4.write(LayerRows(i4.init(cfg, 2, 48), 0), k, v, 0).read()
     assert ci["k"].dtype == jnp.int4
     q = jax.random.normal(jax.random.fold_in(key, 3), (2, 2, 1, 16))
     pos = jnp.asarray([20, 39], jnp.int32)

@@ -61,16 +61,18 @@ def test_stablehlo_demands_no_cache_sized_transpose_or_copy():
     assert H.audit_decode_step(step_b, args_b, layer_alloc)["total"] == 0
 
 
-def test_optimized_unbucketed_step_materializes_cache_scale_copies():
-    """Hypothesis (b) on this host's backend: the compiled unbucketed
-    decode step carries cache-scale copies (scan-carry materialization)
-    — the structural 2x+ traffic multiplier the bucketed program bounds.
-    Count > 0 is the finding, not a bug: it is the CPU lowering's answer,
-    a proxy for the chip's."""
+def test_optimized_unbucketed_step_carries_its_cache_in_place():
+    """Hypothesis (b) on this host's backend, as it stands since ISSUE 63:
+    the compiled unbucketed decode step carried cache-scale copies while
+    the cache rode its layer loop as xs in and ys out (a scan cannot alias
+    the two: the structural 2x+ traffic multiplier that bucketing bounded);
+    the loop now CARRIES the cache (`paged_kvcache.scan_rows`) and the
+    compiled step holds none. The CPU lowering's answer, a proxy for the
+    chip's."""
     (step_u, args_u), _, layer_alloc = _steps()
     out = H.audit_decode_step(step_u, args_u, layer_alloc, optimize=True)
     assert out["counts"].get("transpose", 0) == 0  # (a) stays dead
-    assert out["counts"].get("copy", 0) > 0        # (b) confirmed
+    assert out["counts"].get("copy", 0) == 0       # (b) is gone
 
 
 def test_optimized_bucketed_step_materializes_nothing_allocation_sized():

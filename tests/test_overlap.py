@@ -288,7 +288,8 @@ def test_audit_covers_mixed_step_programs():
     names = set(rep["variants"])
     for want in ("mixed_dense", "mixed_dense_finish", "mixed_paged",
                  "mixed_bucketed", "mixed_speculative",
-                 "mixed_speculative_finish"):
+                 "mixed_speculative_finish", "mixed_dense_chunk",
+                 "paged_chunk", "paged_keye_chunk", "mellum2-test_chunk"):
         assert want in names, names
         v = rep["variants"][want]
         assert v["aliased"] == v["expected"], (want, v)
@@ -320,6 +321,36 @@ def test_audit_gate_fails_unaliased_mixed_variant(model):
     entry, findings = check_decode_program(
         "mixed_unaliased", bad, args, b._mixed_donate, elems)
     assert entry["aliased"] == 0
+    assert findings and findings[0].rule == "PRG003"
+
+
+def test_audit_gate_fails_a_chunk_loop_that_rides_its_row(model,
+                                                          monkeypatch):
+    """The chunk gate gates (ISSUE 63): HEAD's chunk program — the row
+    carried, a chunk's positions written — passes; the SAME batcher's
+    program traced with the row riding its layer loop as xs in and ys out
+    (the form before: each layer's whole cut-out written back) trips it;
+    and so does the real program with its donation dropped."""
+    from dnn_tpu.analysis.program import check_chunk_program, chunk_args
+    from dnn_tpu.runtime import generate
+    from tests.test_chunk_rows_in_place import xs_ys_scan_rows
+
+    cfg, prepared = model
+    b = ContinuousBatcher(cfg, prepared, slots=2, max_len=64, prompt_pad=8)
+    args = chunk_args(b)
+    entry, ok_findings = check_chunk_program("chunk_ok", b._prefill_chunk,
+                                             args)
+    assert ok_findings == [] and entry["aliased"] == 2
+    bad = jax.jit(b._prefill_chunk.__wrapped__)
+    entry, findings = check_chunk_program("chunk_unaliased", bad, args)
+    assert entry["aliased"] == 0
+    assert findings and findings[0].rule == "PRG003"
+    monkeypatch.setattr(generate, "scan_rows", xs_ys_scan_rows)
+    rides = jax.jit(lambda *a: b._prefill_chunk.__wrapped__(*a),
+                    donate_argnums=(1,))  # a new function: a new trace
+    entry, findings = check_chunk_program("chunk_xs_ys", rides, args)
+    assert entry["aliased"] == 2  # the donation is there: the copies hide
+    assert entry["cache_sized_ops"].get("dynamic_update_slice", 0) >= 2
     assert findings and findings[0].rule == "PRG003"
 
 

@@ -25,6 +25,7 @@ import pytest
 
 from dnn_tpu.models import gpt, llama
 from dnn_tpu.runtime.kvcache import FloatKV, RollingFloatKV
+from dnn_tpu.runtime.paged_kvcache import LayerRows
 
 CFG = llama.PRESETS["mistral-test"]  # L=4, H=4, KV=2, C=64, V=256, W=16
 DENSE = dataclasses.replace(CFG, sliding_window=None)
@@ -96,14 +97,18 @@ def test_ring_codec_matches_masked_full_cache(p_query):
     wrap (p=5 < W) and after it (p=27 > W)."""
     B, H, D, W, S = 2, 2, 8, 16, 40
     rng = np.random.RandomState(0)
-    full = {"k": jnp.zeros((B, H, S, D)), "v": jnp.zeros((B, H, S, D))}
-    ring = {"k": jnp.zeros((B, H, W, D)), "v": jnp.zeros((B, H, W, D))}
+    # a codec writes into a cache bound to a layer: caches of ONE layer
+    full = LayerRows({"k": jnp.zeros((1, B, H, S, D)),
+                      "v": jnp.zeros((1, B, H, S, D))}, 0)
+    ring = LayerRows({"k": jnp.zeros((1, B, H, W, D)),
+                      "v": jnp.zeros((1, B, H, W, D))}, 0)
     flat, roll = FloatKV(window=W), RollingFloatKV(window=W)
     for p in range(p_query + 1):
         k = jnp.asarray(rng.randn(B, H, 1, D), jnp.float32)
         v = jnp.asarray(rng.randn(B, H, 1, D), jnp.float32)
         full = flat.write(full, k, v, p)
         ring = roll.write(ring, k, v, p)
+    full, ring = full.read(), ring.read()
     q = jnp.asarray(rng.randn(B, H, 3, D), jnp.float32)  # R=3 folded rows
     pos = jnp.full((B,), p_query, jnp.int32)
     np.testing.assert_allclose(
