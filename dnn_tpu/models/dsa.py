@@ -294,7 +294,7 @@ class DsaFamilyRows(llama.LlamaFamilyRows):
             (y, rows), acc = llama._run_block(self.ffn, acc, run)
             return (y, acc), rows
 
-        acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
+        acc0 = llama._stats_acc(moe_stats)
         (x, acc), new_cache = scan_rows(block, (x, acc0), blocks, row_cache)
         x = x.astype(jnp.float32)  # what `head` is handed, in the finish
         if moe_stats:

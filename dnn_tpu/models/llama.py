@@ -1506,9 +1506,17 @@ def make_apply_stacked(cfg: LlamaConfig, *, compute_dtype=None,
 # KV-cache decode (kvcache codecs; cache holds KV heads, not H)
 # --------------------------------------------------------------------------
 
+def _stats_acc(moe_stats):
+    """What a layer loop's carry starts with beside the rows: zeros for the
+    hook's stats a layer call (parallel/moe.N_STATS of them), or None."""
+    from dnn_tpu.parallel.moe import N_STATS
+
+    return jnp.zeros((N_STATS,), jnp.int32) if moe_stats else None
+
+
 def _run_block(ffn, acc, run):
     """`run(ffn) -> result` for one block of a layer loop whose carry also
-    holds `acc`: None, or the int32 (3,) MoE stats summed so far. With an
+    holds `acc`: None, or the int32 MoE stats summed so far. With an
     `acc`, the block is traced with the hook's counting form
     (`ffn.with_stats`, llama_moe.make_ffn) and this layer call's stats
     are added. Returns (result, acc). The list is filled and read inside
@@ -1582,7 +1590,7 @@ def forward_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
                        compute_dtype=None, attn_kernel="auto", rolling=False,
                        ffn=None, moe_stats=False):
     """-> (logits, new_cache); with `moe_stats` (an ffn that has
-    `with_stats`: the MoE hook) also the int32 (3,) sum over the layers
+    `with_stats`: the MoE hook) also the int32 (N_STATS,) sum over the layers
     of what each expert layer call cost (parallel/moe.moe_ffn_grouped).
     `hidden_with_cache` and the head over every row."""
     x, *rest = hidden_with_cache(
@@ -1633,7 +1641,7 @@ def hidden_with_cache(prepared, ids, cache, start_pos, *, cfg: LlamaConfig,
         (y, rows), acc = _run_block(ffn, acc, run)
         return (y, acc), rows
 
-    acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
+    acc0 = _stats_acc(moe_stats)
     (x, acc), new_cache = scan_rows(
         block, (x, acc0), blocks, cache, *(() if wins is None else (wins,)))
     x = x.astype(jnp.float32)
@@ -2204,7 +2212,7 @@ class LlamaFamilyRows:
             return (y, acc), c
 
         # a paged pool rides the loop whole, a dense cache by layer
-        acc0 = jnp.zeros((3,), jnp.int32) if moe_stats else None
+        acc0 = _stats_acc(moe_stats)
         carry, new_cache = (x, acc0), cache
         for stack, layers, kind in layer_stacks(prepared, self.cfg):
             # one of several stacks scans its own range of the pool (of
@@ -2254,7 +2262,7 @@ def prefill_by_kind(family, prepared, padded, row_cache, start_pos,
                                           **chunk_kw))
         return (y, acc), rows
 
-    carry = (x, jnp.zeros((3,), jnp.int32) if moe_stats else None)
+    carry = (x, _stats_acc(moe_stats))
     for stack, layers, kind in layer_stacks(prepared, family.cfg):
         blocks, bind = scan_form(stack, family.ffn)
         carry, row_cache = scan_rows(

@@ -46,7 +46,8 @@ import jax.numpy as jnp
 from dnn_tpu.models import gpt, llama
 from dnn_tpu.models.kda import KdaConfig
 from dnn_tpu.models.mla import MlaConfig
-from dnn_tpu.parallel.moe import init_moe_gated, moe_ffn, moe_ffn_grouped
+from dnn_tpu.parallel.moe import (
+    N_STATS, init_moe_gated, moe_ffn, moe_ffn_grouped)
 from dnn_tpu.registry import ModelSpec, register_model
 
 
@@ -623,7 +624,7 @@ def _local_ep_ffn(cfg: MixtralConfig, *, axis: str, capacity: int,
 def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
     """The llama `ffn` hook: (block_params, h) -> MoE MLP output, through
     the grouped drop-free experts. `ffn.with_stats(bp, h)` also returns
-    that layer call's cost (moe_ffn_grouped's int32 (3,)), which the
+    that layer call's cost (moe_ffn_grouped's int32 (N_STATS,)), which the
     batcher's adapter sums into the moe_* counters. `ffn.expert_forms`:
     the forms the expert matmuls of the programs traced so far took
     (parallel/moe._experts_grouped: "stack_kernel" / "ragged_dot"; what
@@ -668,7 +669,7 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
     if groups == 1:
         def with_stats(bp, h):
             if "moe" not in bp:  # no expert layer call: nothing counted
-                return dense(bp, h), jnp.zeros((3,), jnp.int32)
+                return dense(bp, h), jnp.zeros((N_STATS,), jnp.int32)
             out, stats = routed(bp, h, True)
             return with_shared(bp, h, out), stats
 

@@ -59,7 +59,8 @@ This is the intra-step instrument, in two connected halves:
     group on starts — as straight-line code),
     and for a model with experts moe.layer_calls_total /
     moe.assignments_total / moe.active_experts_total /
-    moe.peak_expert_rows_total{program=decode|prefill} — what the expert
+    moe.peak_expert_rows_total / moe.rows_permuted_total /
+    moe.extra_rounds_total{program=decode|prefill} — what the expert
     layers of the step and chunk programs cost, as the programs
     themselves counted it — exact at every scrape to the last ended
     step; for a model whose attention selects what it reads
@@ -213,7 +214,8 @@ _LOOP_ACCRUES = {"pre": 0, "wait": 1, "admit": 2, "emit": 3, "step": 3}
 # program whose expert layers it counts
 MOE_PROGRAMS = ("decode", "prefill")
 MOE_SERIES = ("layer_calls_total", "assignments_total",
-              "active_experts_total", "peak_expert_rows_total")
+              "active_experts_total", "peak_expert_rows_total",
+              "rows_permuted_total", "extra_rounds_total")
 # the dsa.* cumulative series (StepClock.note_dsa), same programs: what a
 # learned-sparse-attention model's indexer scored, what it selected, and
 # the (query, column) pairs the masked prefill kernel's grid walked
@@ -421,7 +423,7 @@ class StepClock:
         self._loop_spans: Optional[_LoopSpans] = None
         self._loop_iter = 0
         # expert layers (note_moe): per program, MOE_SERIES in order
-        self.moe_total = {p: [0, 0, 0, 0] for p in MOE_PROGRAMS}
+        self.moe_total = {p: [0] * len(MOE_SERIES) for p in MOE_PROGRAMS}
         # a paged KV pool's blocks (note_attn_blocks): live, in the tables
         self.attn_blocks_total = [0, 0]
         # the paged decode kernel's groups (note_attn_groups): walked, full
@@ -772,7 +774,8 @@ class StepClock:
         """What the expert layers of one executed program cost:
         `layer_calls` of them, and `stats` = (rows through experts,
         sum over the calls of experts with at least one row, sum of the
-        fullest expert's rows), as the program counted them on the
+        fullest expert's rows, rows the permutation moved, its rounds
+        beyond a call's first), as the program counted them on the
         device (parallel/moe.moe_ffn_grouped) and handed back with its
         tokens. Adds to the cumulative moe.* totals; the next ended
         step's record carries it for /stepz. The series appear on
@@ -780,7 +783,7 @@ class StepClock:
         none."""
         if not _obs.enabled():
             return
-        add = (layer_calls, int(stats[0]), int(stats[1]), int(stats[2]))
+        add = (layer_calls, *(int(v) for v in stats))
         tot = self.moe_total[program]
         pend = self._pending_moe
         if pend is None:
@@ -789,7 +792,7 @@ class StepClock:
                 self._moe_registered = True
                 self._gauges.update(self._moe_gauges)
                 self._gauges_registered = False  # re-register with them
-        cur = pend.setdefault(program, [0, 0, 0, 0])
+        cur = pend.setdefault(program, [0] * len(MOE_SERIES))
         for i, v in enumerate(add):
             tot[i] += v
             cur[i] += v

@@ -12,7 +12,10 @@ TPU-first rather than ported. Two dispatches, one per place they fit:
     that reads them in place (ops/pallas/grouped_matmul.py), a matrix
     already cut out through `jax.lax.ragged_dot` (`_experts_grouped`).
     There is NO capacity: every routed row is computed, whatever the
-    imbalance, and the shapes are static in S*k. The work is top_k
+    imbalance, and the shapes are static in S*k (for one chip's share of
+    an expert-parallel layer, `held=`, in the rows an even routing gives
+    it: `permutation_extent` a round, as many rounds as its rows need).
+    The work is top_k
     expert FLOPs per token and each active expert's weights read once —
     what fine-grained experts (OLMoE: 64 experts, 8 per token) need;
     the one-hot dispatch below would run every expert on every slot.
@@ -38,6 +41,7 @@ tiny and routing decisions must not flip with the activation dtype).
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import NamedTuple, Optional
 
@@ -299,11 +303,16 @@ def route_rows(router_kernel, xs, *, top_k: int, normalize: bool = True,
         first, e = held
         local = flat - first
         flat = jnp.where((local >= 0) & (local < e), local, e)
-    order = jnp.argsort(flat, stable=True)
-    # (a pick held elsewhere adds at index e: out of range, which a
-    # scatter drops)
-    group_sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-    return weights, order, flat[order], group_sizes
+    # the sort hands back its keys: the sorted experts cost no gather, and
+    # the sizes are a compare-and-sum over them (a scatter-add is an update
+    # at a time on the chip: 72 us for a chunk's 8192 picks, PERF.md
+    # section 6, PR 65); a pick held elsewhere matches no expert here
+    expert_of_row, order = jax.lax.sort(
+        (flat, jnp.arange(flat.shape[0], dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    group_sizes = (flat[None, :] == jnp.arange(e, dtype=jnp.int32)[:, None]
+                   ).sum(axis=1, dtype=jnp.int32)
+    return weights, order, expert_of_row, group_sizes
 
 
 class LayerOf(NamedTuple):
@@ -317,6 +326,34 @@ class LayerOf(NamedTuple):
 
 # the leaves of an expert layer that are one matrix an expert
 EXPERT_MATRICES = ("wg", "wu", "wd", "wi", "wo")
+
+
+def _operand_dtype(params, compute_dtype):
+    """What `_experts_grouped` casts rows and matrices to: the caller's
+    `compute_dtype`; float32 for an int8 stack without one; None: as they
+    come."""
+    w = params["wg" if "wg" in params else "wi"]
+    w_dtype = (w.stack if isinstance(w, LayerOf) else w).dtype
+    return compute_dtype if compute_dtype is not None else (
+        jnp.float32 if w_dtype == jnp.int8 else None)
+
+
+def _reads_stacks_in_place(params, compute_dtype, interpret, *, rows_dtype):
+    """Whether the experts of `params` go through the Pallas kernel that
+    reads a layer loop's whole stacks in place (`_experts_grouped`'s
+    "stack_kernel"): `LayerOf` matrices of a float stack held in the
+    operands' dtype (`rows_dtype` where no cast is made), no `*_scale`
+    factors, on a TPU (or `interpret`)."""
+    names = ("wg", "wu", "wd") if "wg" in params else ("wi", "wo")
+    w = params[names[0]]
+    if not isinstance(w, LayerOf):
+        return False
+    cd = _operand_dtype(params, compute_dtype)
+    x_dtype = cd if cd is not None else rows_dtype
+    return (w.stack.dtype == x_dtype
+            and jnp.issubdtype(w.stack.dtype, jnp.floating)
+            and all(params.get(k + "_scale") is None for k in names)
+            and bool(interpret or jax.default_backend() == "tpu"))
 
 
 def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
@@ -348,14 +385,10 @@ def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
     ws = [params[k] for k in names]
     scales = [params.get(k + "_scale") for k in names]
     stacked = isinstance(ws[0], LayerOf)
-    w_dtype = (ws[0].stack if stacked else ws[0]).dtype
-    cd = compute_dtype if compute_dtype is not None else (
-        jnp.float32 if w_dtype == jnp.int8 else None)
+    cd = _operand_dtype(params, compute_dtype)
     x = rows if cd is None else rows.astype(cd)
-    in_place = (stacked and w_dtype == x.dtype
-                and jnp.issubdtype(w_dtype, jnp.floating)
-                and all(s is None for s in scales)
-                and (interpret or jax.default_backend() == "tpu"))
+    in_place = _reads_stacks_in_place(params, compute_dtype, interpret,
+                                      rows_dtype=rows.dtype)
     if forms is not None:
         forms.add("stack_kernel" if in_place else "ragged_dot")
     if not in_place:
@@ -386,6 +419,39 @@ def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
     return out
 
 
+#: what `moe_ffn_grouped(return_stats=True)` counts a layer call, in order
+#: (the batcher's adapter sums them into the moe_* series): rows through
+#: the experts, experts with at least one row, the fullest expert's rows,
+#: rows the permutation moved in and out, rounds of it beyond the first
+N_STATS = 5
+
+# a round of the permutation under `held` covers this many times the rows
+# an even routing gives the held experts, in whole row tiles of the
+# grouped matmul
+_EXTENT_ROOM = 1.5
+# the rounds are a loop whose trips the DEVICE counts, and the chip charges
+# such a loop 30-90 us a layer call whatever is in it (92 us of a Keye
+# chunk's 388, 33 of a dots3 chunk's 2686, 87 of a 64-slot Keye step's 247:
+# PERF.md section 6, PR 65) — what moving ~1400 rows in and out costs (65
+# ns a row). A layer call that would save fewer rows than this keeps every
+# pick's row in one pass: every decode step does
+_MIN_ROWS_SAVED = 2048
+
+
+def permutation_extent(n_rows: int, n_expert: int, n_held: int) -> int:
+    """Rows ONE round of a share's permutation moves, of the `n_rows`
+    picks of a layer call whose `n_held` of `n_expert` experts are here:
+    `_EXTENT_ROOM` times the held experts' even share, rounded up to the
+    row tile; all of them (one pass, no round) where that would save fewer
+    than `_MIN_ROWS_SAVED` rows."""
+    from dnn_tpu.ops.pallas.grouped_matmul import _ROW_TILE
+
+    even = n_rows * n_held / n_expert
+    tiles = -(-math.ceil(_EXTENT_ROOM * even) // _ROW_TILE)
+    extent = max(tiles, 1) * _ROW_TILE
+    return extent if n_rows - extent >= _MIN_ROWS_SAVED else n_rows
+
+
 def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
                     activation=gelu, compute_dtype=None,
                     return_stats: bool = False, held=None,
@@ -398,11 +464,10 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
     The three scopes name its parts on a device trace: `moe.route`
     (router matmul, softmax, top-k, the sort and the row gather),
     `moe.experts` (the grouped matmuls and the gate's product),
-    `moe.combine` (un-sort, weighting, sum over the k).
+    `moe.combine` (each computed row, weighted, summed into its token).
 
-    `return_stats` adds an int32 (3,): rows through the experts (S*k),
-    experts with at least one row, and the fullest expert's rows — what
-    this layer call cost, for the serving counters.
+    `return_stats` adds an int32 (`N_STATS`,): what this layer call cost,
+    for the serving counters.
 
     `held` = (first, count) (`route_rows`): `params` carries the stacks
     of those experts only, beside the whole layer's router. The grouped
@@ -413,47 +478,120 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
     every expert is held, and the program is what it was without the
     argument.
 
+    The EXTENT of the permutation follows the rows held. The sort puts
+    them first, and where the experts go through the kernel that reads
+    their stacks in place (`_experts_grouped`: no VJP is asked of it) the
+    dispatch, the experts and the combine work on `permutation_extent`
+    rows a ROUND, for as many rounds as the live rows need
+    (`cdiv(live, extent)`, traced: one at any routing near even, none
+    when no pick is held here, S*k / extent when every pick is) — every
+    array between the gather and the sum is `extent` rows long, no row is
+    dropped at any routing, and a round's rows are added into their
+    tokens by `ops/pallas/row_accumulate.py`. Everywhere else (every
+    expert held; `ragged_dot`'s callers, training among them; a layer
+    call that a round would save too few rows to pay its loop, every
+    decode step among them: `permutation_extent`) the extent is S*k: one
+    pass, the rows back at their picks by the inverse permutation, a pick
+    not held here a flag where its row is summed.
+
     `scoring` / `scale` are `route_rows`'; the selection bias is read
     from `params["router"]["select_bias"]` where the tree carries one.
 
     The expert matrices may be `LayerOf(stack, layer)`: a layer loop's
     whole stacks, which the grouped-matmul kernel reads in place
-    (`_experts_grouped` says when; `interpret` runs that kernel in
+    (`_experts_grouped` says when; `interpret` runs the kernels in
     interpreter mode, for CPU tests; `forms`, a set, is told at trace
     time which form the matmuls took)."""
     shape, d = x.shape, x.shape[-1]
     xs = x.reshape(-1, d)
     s = xs.shape[0]
+    n_rows = s * top_k
+    experts = functools.partial(
+        _experts_grouped, params, activation=activation,
+        compute_dtype=compute_dtype, interpret=interpret, forms=forms)
     with jax.named_scope("moe.route"):
         weights, order, expert_of_row, group_sizes = route_rows(
             params["router"]["kernel"], xs, top_k=top_k, normalize=normalize,
             held=held, scoring=scoring,
             select_bias=params["router"].get("select_bias"), scale=scale)
-        rows = xs[order // top_k]  # (S*k, D), sorted by expert
-    with jax.named_scope("moe.experts"):
-        out = _experts_grouped(params, rows, expert_of_row, group_sizes,
-                               activation=activation,
-                               compute_dtype=compute_dtype,
-                               interpret=interpret, forms=forms)
-        if held is not None:
+    extent = n_rows
+    if held is not None and _reads_stacks_in_place(
+            params, compute_dtype, interpret, rows_dtype=xs.dtype):
+        extent = permutation_extent(
+            n_rows, params["router"]["kernel"].shape[-1], held[1])
+    live = jnp.int32(n_rows) if held is None else group_sizes.sum()
+    if extent == n_rows:
+        with jax.named_scope("moe.route"):
+            rows = xs[order // top_k]  # (S*k, D), sorted by expert
+        with jax.named_scope("moe.experts"):
             # rows behind the last group belong to no expert here: the
             # grouped matmul leaves them unspecified
-            out = jnp.where((expert_of_row < held[1])[:, None], out, 0.0)
+            out = experts(rows, expert_of_row, group_sizes)
+        with jax.named_scope("moe.combine"):
+            # row t*k + j back at (t, j): the inverse of a permutation is
+            # its argsort (8 us for 8192 where the scatter took 39)
+            inverse = jnp.argsort(order)
+            unsorted = out[inverse].reshape(s, top_k, d)
+            if held is not None:
+                unsorted = jnp.where(
+                    (inverse < live).reshape(s, top_k, 1), unsorted, 0.0)
+            y = (unsorted * weights[..., None]).sum(axis=1)
+        rounds = jnp.int32(1)
+    else:
+        y, rounds = _rounds(experts, xs, weights, order, expert_of_row,
+                            group_sizes, live, extent, interpret=interpret)
     with jax.named_scope("moe.combine"):
-        # the inverse permutation (a scatter, not a second sort) puts row
-        # t*k + j back at (t, j)
-        inverse = jnp.zeros_like(order).at[order].set(
-            jnp.arange(order.shape[0], dtype=order.dtype))
-        unsorted = out[inverse].reshape(s, top_k, d)
-        y = (unsorted * weights[..., None]).sum(axis=1)
         y = y.reshape(shape).astype(x.dtype)
     if not return_stats:
         return y
-    n_rows = jnp.int32(s * top_k) if held is None else group_sizes.sum()
-    stats = jnp.stack([n_rows.astype(jnp.int32),
+    stats = jnp.stack([live,
                        (group_sizes > 0).sum().astype(jnp.int32),
-                       group_sizes.max()])
+                       group_sizes.max(), rounds * extent,
+                       jnp.maximum(rounds - 1, 0)])
     return y, stats
+
+
+def _rounds(experts, xs, weights, order, expert_of_row, group_sizes, live,
+            extent, *, interpret):
+    """The compact form of `moe_ffn_grouped`: -> (y (S, D) float32, the
+    rounds it took). Round i gathers the tokens of sorted rows [i * extent,
+    (i + 1) * extent), runs the experts on them with each group's size cut
+    to the window, and adds the live ones, weighted, into y."""
+    from dnn_tpu.ops.pallas.row_accumulate import row_accumulate
+
+    s, top_k = weights.shape
+    n_rows, count = order.shape[0], group_sizes.shape[0]
+    with jax.named_scope("moe.route"):
+        rounds = -(-live // extent)
+        pad = -n_rows % extent  # the last window's rows past S*k: held by none
+        order = jnp.pad(order, (0, pad))
+        expert_of_row = jnp.pad(expert_of_row, (0, pad),
+                                constant_values=count)
+        ends = jnp.cumsum(group_sizes)
+        starts = ends - group_sizes
+        flat_weights = weights.reshape(-1)
+
+    def one(i, y):
+        lo = i * extent
+        with jax.named_scope("moe.route"):
+            picks = jax.lax.dynamic_slice(order, (lo,), (extent,))
+            tokens = picks // top_k
+            rows = xs[tokens]  # (extent, D), sorted by expert
+            sizes = (jnp.clip(ends, lo, lo + extent)
+                     - jnp.clip(starts, lo, lo + extent))
+        with jax.named_scope("moe.experts"):
+            out = experts(
+                rows, jax.lax.dynamic_slice(expert_of_row, (lo,), (extent,)),
+                sizes)
+        with jax.named_scope("moe.combine"):
+            return row_accumulate(y, out, tokens, flat_weights[picks],
+                                  live - lo, interpret=interpret)
+
+    with jax.named_scope("moe.combine"):
+        y = jnp.zeros((s, xs.shape[-1]), jnp.float32)
+    # (the loop itself stands under no part's scope: what the chip charges
+    # for a loop whose trips it counts is nobody's work)
+    return jax.lax.fori_loop(0, rounds, one, y), rounds
 
 
 def _group_dispatch(params, xg, *, top_k, capacity, normalize):

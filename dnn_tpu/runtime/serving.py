@@ -793,7 +793,7 @@ class ContinuousBatcher:
         self.step_clock = None
         # a family whose MLP is a mixture of experts counts what its
         # expert layers cost (LlamaFamilyRows.moe_stats): the step and
-        # chunk programs then return an int32 (3,) beside the tokens
+        # chunk programs then return an int32 (N_STATS,) beside the tokens
         # they already hand back, noted here in dispatch order and given
         # to the StepClock once a token of the same or a later dispatch
         # is on the host (_moe_flush) — never a device wait of its own
@@ -2318,7 +2318,7 @@ class ContinuousBatcher:
         finished: those of decode dispatch `upto` and earlier, once its
         tokens are on the host (None: all of them — a first token was
         just read, and the device runs programs in dispatch order). One
-        transfer of twelve bytes a program, from programs already
+        transfer of twenty bytes a program, from programs already
         complete."""
         pend, sc = self._moe_pending, self.step_clock
         ready = []

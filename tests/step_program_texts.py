@@ -24,7 +24,16 @@ for the whole chunked rule is built for heads of 128, re-recorded
 kernel until then). PR 63 re-recorded the `_prefill_chunk` of all fourteen
 cases and nothing else: the transient row is the chunk program's carry
 since (`paged_kvcache.scan_rows`), written a chunk's positions at a time;
-every `_prefill_finish` and `_decode` hash stayed letter for letter."""
+every `_prefill_finish` and `_decode` hash stayed letter for letter. PR 65
+re-recorded the `_prefill_chunk` and `_decode` of the presets with an
+expert layer and of nothing else (`olmoe-test`, `keye-test`, `joyai-test`,
+`dots3-test`, `k-exaone-test`, `solar-open2-test` and its "@interpret"
+case): `parallel/moe.route_rows` takes the sorted experts from the sort's
+keys and the group sizes from a compare-and-sum, `moe_ffn_grouped` the
+inverse permutation from an argsort, a share's mask is a flag where a row
+is summed, and the stats a layer call hands back are five; `gpt2-test`,
+`brumby-test`, `falcon-h1-test`, `minicpm-sala-test` and every
+`_prefill_finish` stayed letter for letter."""
 
 import base64
 import functools

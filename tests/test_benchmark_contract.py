@@ -779,6 +779,26 @@ def test_statusz_says_how_the_expert_layers_meet_their_matrices(served,
     assert weights.get("moe_experts") == ("ragged_dot" if moe else None)
 
 
+@pytest.mark.parametrize("config", sorted(_SERVED))
+def test_the_permutation_counts_the_rows_it_moves(served, config):
+    """ISSUE 65: beside the rows the experts ran, a model with experts
+    counts the rows its permutation moved and the rounds it took beyond a
+    layer call's first (`moe_rows_permuted_total`, `moe_extra_rounds_total`
+    on `/metrics`; no per-layer entry reads them yet). On the CPU the stacks
+    are cut and the extent is every pick: S*k rows a call, in one pass —
+    the picks of ALL the experts, so a share's moved rows are its routed
+    rows times experts over held."""
+    m = served(config)["metrics"]
+    if not any(s.startswith("moe_") for s in _SERVED[config]["series"]):
+        assert not [k for k in m if k.startswith("moe_")]
+        return
+    for program in ("decode", "prefill"):
+        moved = m[f'moe_rows_permuted_total{{program="{program}"}}']
+        routed = m[f'moe_assignments_total{{program="{program}"}}']
+        assert moved >= routed > 0
+        assert m[f'moe_extra_rounds_total{{program="{program}"}}'] == 0
+
+
 def _latent(config):
     return any(s.startswith("mla_") for s in _SERVED[config]["series"])
 

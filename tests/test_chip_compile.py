@@ -765,15 +765,13 @@ def test_sparse_attention_kernels_compile(chip, kernel, dtype):
     _compile(chip, fn, *shapes)
 
 
-def test_keye_chunk_program_attends_under_the_set_in_the_kernel(chip):
-    """The Keye cut's chunk program at its published widths and the cell's
-    row (16 slots of 16 384 positions in blocks of 16, 1024-token chunks),
-    depth cut to TWO layers and the vocabulary to 8192 rows: every layer
-    scores the row's index keys and attends under the set in the two
-    kernels of ops/pallas/sparse_attention.py, on the transient row alone;
-    its temporaries are the (T, S) scores, the selection's passes over them
-    and the set (64 MB + 16 MB at 1024 x 16 384), not a (T, Hi, S) product
-    nor a gathered K/V."""
+@pytest.fixture(scope="module")
+def keye_chunk(chip):
+    """(compiled, batcher) of the Keye cut's chunk program at its published
+    widths and the cell's row (16 slots of 16 384 positions in blocks of
+    16, 1024-token chunks), depth cut to TWO layers and the vocabulary to
+    4096 rows (not the 8192 of a chunk's picks: an extent the tests below
+    look for)."""
     import dataclasses
 
     from dnn_tpu.models import llama_moe
@@ -782,7 +780,7 @@ def test_keye_chunk_program_attends_under_the_set_in_the_kernel(chip):
 
     cfg = dataclasses.replace(
         llama_moe.PRESETS["keye-vl-2.0-30b-a3b-ep8-1chip"], n_layer=2,
-        vocab_size=8192)
+        vocab_size=4096)
     prepared = _stack_and_release(
         llama_moe.init(jax.random.PRNGKey(0), cfg), cfg, BF16)
     b = ContinuousBatcher(
@@ -790,13 +788,88 @@ def test_keye_chunk_program_attends_under_the_set_in_the_kernel(chip):
         block_len=16, family=llama_moe.family_rows(cfg, compute_dtype=BF16))
     assert b._paged and b._row_len == 16384
     assert b.cache["ik"].shape == (2, 16 * 1024 + 1, 1, 16, 128)
-    chunk = _lower_programs(chip, [(b, ("_prefill_chunk",))])[
-        "_prefill_chunk"]
+    return _lower_programs(chip, [(b, ("_prefill_chunk",))])[
+        "_prefill_chunk"], b
+
+
+def test_keye_chunk_program_attends_under_the_set_in_the_kernel(keye_chunk):
+    """Every layer of the Keye cut's chunk program scores the row's index
+    keys and attends under the set in the two
+    kernels of ops/pallas/sparse_attention.py, on the transient row alone;
+    its temporaries are the (T, S) scores, the selection's passes over them
+    and the set (64 MB + 16 MB at 1024 x 16 384), not a (T, Hi, S) product
+    nor a gathered K/V."""
+    chunk, b = keye_chunk
     text = chunk.as_text()
     assert "sparse_prefill_attention" in text
     assert "chunk_index_scores" in text
     assert _pool_extent_ops(chunk, b.cache["k"].shape[1:]) == []
     assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 29
+
+
+def _scatters_of_the_permutation(compiled):
+    """The lines of a compiled program that hold a scatter under
+    `moe.route` or `moe.combine`."""
+    return [l[:200] for l in compiled.as_text().splitlines()
+            if "scatter" in l.split("(")[0]
+            and re.search(r'op_name="[^"]*/moe\.(?:route|combine)/', l)]
+
+
+def test_keye_chunk_program_permutes_the_rows_held(keye_chunk):
+    """ISSUE 64: 16 of 128 experts are held here, so the chunk's 8192 picks
+    are sorted (integers) and the ROWS that move, in and out, are one
+    round's `permutation_extent` of 1536: no operation has a result of all
+    the picks' rows — `[8192, 2048]`, in either dtype: the dispatch's
+    gather, the mask over the experts' result, the combine's gather —
+    nothing under `moe.route` / `moe.combine` is a scatter, and the rows
+    come back through `row_accumulate` under `moe.combine`."""
+    from dnn_tpu.parallel.moe import permutation_extent
+
+    chunk, b = keye_chunk
+    cfg = b.cfg
+    picks = 1024 * cfg.router_top_k
+    assert permutation_extent(picks, cfg.n_expert, cfg.held[1]) == 1536
+    assert _extent_ops(chunk, re.compile(
+        r"\[%d,%d\]" % (picks, cfg.n_embd))) == []
+    assert _scatters_of_the_permutation(chunk) == []
+    calls = [l for l in chunk.as_text().splitlines()
+             if "tpu_custom_call" in l and "row_accumulate" in l]
+    assert calls and all("/moe.combine/" in l for l in calls)
+
+
+# (tokens S, width D, rows a round): a 1024-token chunk of Keye's and JoyAI's
+# (16 of 128 and of 256 experts held), of Solar's, dots3's and K-EXAONE's
+# widths (y in column tiles there), and Keye's 64-slot step
+ACCUMULATE = {"keye_chunk": (1024, 2048, 1536),
+              "joyai_chunk": (1024, 2048, 768),
+              "solar_chunk": (1024, 4096, 1536),
+              "dots3_chunk": (1024, 5120, 1536),
+              "kexaone_chunk": (1024, 6144, 1536),
+              "keye_step": (64, 2048, 128)}
+
+
+@pytest.mark.parametrize("case", sorted(ACCUMULATE))
+def test_row_accumulate_compiles(chip, case):
+    """ISSUE 64: the kernel that adds a round's live rows into their tokens
+    (ops/pallas/row_accumulate.py: a dynamic row of y a trip, y resident)
+    at the held cells' shapes, y aliased to the result."""
+    from dnn_tpu.ops.pallas.row_accumulate import row_accumulate
+
+    s, d, r = ACCUMULATE[case]
+    compiled = _compile(
+        chip, lambda y, rows, tok, w, live: row_accumulate(
+            y, rows, tok, w, live, interpret=False),
+        ((s, d), jnp.float32), ((r, d), jnp.float32), ((r,), jnp.int32),
+        ((r,), jnp.float32), ((), jnp.int32))
+    assert compiled.memory_analysis().temp_size_in_bytes < s * d * 4
+
+
+def test_olmoe_decode_step_permutes_with_no_scatter(olmoe_programs):
+    """ISSUE 64, where every expert is held: the group sizes are a
+    compare-and-sum over the picks and the inverse permutation an argsort
+    — no scatter under `moe.route` / `moe.combine` (on the chip a scatter
+    is an update at a time: 72 + 39 us of a chunk's layer call)."""
+    assert _scatters_of_the_permutation(olmoe_programs[0]["_decode"]) == []
 
 
 # ----------------------------------------------------------------------

@@ -324,6 +324,160 @@ def test_the_shares_add_up(layer128):
     assert float(jnp.abs(mine.reshape(-1, 16) - part).max()) < 1e-5
 
 
+# the extent of a share's permutation (ISSUES 64 and 65): 32 experts, 4 a
+# token, 4 a share, 80 tokens of width 512 (the router reads the first 32
+# columns: a token carries its own logits) — 320 picks, of which an even
+# routing holds 40 here and ONE round of the compact form covers 128
+_E, _K, _HELD, _T, _D = 32, 4, 4, 80, 512
+_OUTSIDE = np.arange(_HELD, _E)
+
+
+def _tokens_picking(n_all, n_one, rng, *, pad=0):
+    """(T, E) logits: `n_all` tokens pick exactly experts 0-3, `n_one`
+    expert 0 and three outside, the rest four outside — so share 0 holds
+    4 * n_all + n_one picks, whatever the scoring. `pad`: the last `pad`
+    tokens are ONE token (a prompt's last chunk), which picks experts 0-3."""
+    logits = 0.1 * rng.standard_normal((_T, _E)).astype(np.float32)
+    for t in range(_T):
+        mine = ([0, 1, 2, 3] if t < n_all else
+                [0] if t < n_all + n_one else [])
+        others = rng.choice(_OUTSIDE, _K - len(mine), replace=False)
+        logits[t, np.concatenate([mine, others]).astype(int)] += 6.0
+    if pad:
+        logits[_T - pad:] = 0.1 * rng.standard_normal(_E).astype(np.float32)
+        logits[_T - pad:, :_HELD] += 6.0
+    return logits
+
+
+#: case -> (tokens picking all four held experts, tokens picking one — None:
+#: the router's own picks of random tokens; pad positions; the extent pinned
+#: — None: `permutation_extent`'s 128; column tiles of y)
+EXTENT_CASES = {
+    "none_held": ((0, 0), 0, None, 1),
+    "far_under": (None, 0, None, 1),
+    "exactly_the_extent": ((32, 0), 0, None, 1),
+    "one_row_over": ((32, 1), 0, None, 1),
+    "twice_the_extent": ((64, 0), 0, None, 1),
+    "every_pick_held": ((_T, 0), 0, None, 1),
+    # each held expert's 80 rows lie over three windows of 32
+    "a_group_over_three_windows": ((_T, 0), 0, 32, 1),
+    # 50 pad positions: four groups of 50 and more, two rounds
+    "a_padded_chunk": ((2, 3), 50, None, 1),
+    "a_padded_chunk_in_short_windows": ((2, 3), 50, 24, 1),
+    # four consecutive rows, one row of y
+    "one_token_with_every_pick_held": ((1, 0), 0, None, 1),
+    "y_in_two_column_tiles": ((64, 0), 0, None, 2),
+    "y_in_four_column_tiles": ((64, 1), 0, None, 4),
+}
+
+
+def _plain_share(params, x, *, first, scoring, gated):
+    """What a share computes, with no sort and no group: every held expert
+    on every token, weighted by the token's weight for it."""
+    logits = jnp.dot(x, params["router"]["kernel"], precision="highest")
+    scores = (jax.nn.softmax(logits, -1) if scoring == "softmax"
+              else jax.nn.sigmoid(logits))
+    top, idx = jax.lax.top_k(scores, _K)
+    top = top / top.sum(-1, keepdims=True)
+    weights = (jax.nn.one_hot(idx, _E) * top[..., None]).sum(1)
+    out = jnp.zeros_like(x)
+    for j in range(first, first + _HELD):
+        if gated:
+            y = (jax.nn.silu(x @ params["wg"][j]) * (x @ params["wu"][j])
+                 ) @ params["wd"][j]
+        else:
+            y = jax.nn.silu(x @ params["wi"][j] + params["bi"][j]
+                            ) @ params["wo"][j] + params["bo"][j]
+        out = out + weights[:, j, None] * y
+    return out
+
+
+@pytest.mark.parametrize("scoring", ["softmax", "sigmoid"])
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "plain"])
+@pytest.mark.parametrize("case", sorted(EXTENT_CASES))
+def test_the_extent_follows_the_rows_held(case, gated, scoring, monkeypatch):
+    """ISSUES 64 and 65: where the experts go through the kernel that reads
+    their stacks in place, a share's dispatch, experts and combine cover
+    `permutation_extent` rows a round and as many rounds as its live rows
+    need — none when no pick is held here, one up to exactly the extent,
+    two from one row over it, S*k / extent when every pick is held; a group
+    may lie over several windows, a token's rows may follow each other, y
+    may be walked in column tiles: a share equals the plain form, no row is
+    dropped at any routing, and the eight shares add up to the whole
+    layer's result."""
+    from dnn_tpu.ops.pallas import row_accumulate as ra
+
+    picks, pad, pinned, y_tiles = EXTENT_CASES[case]
+    rng = np.random.default_rng(64)
+    f = 16
+    init = moe.init_moe_gated if gated else moe.init_moe
+    params = dict(init(jax.random.PRNGKey(2), _D, _E, f))
+    params["router"] = {"kernel": jnp.eye(_D, _E)}
+    if not gated:  # the seeded biases are zeros: a wrong row's would hide
+        params["bi"] = jnp.asarray(rng.standard_normal((_E, f)), jnp.float32)
+        params["bo"] = jnp.asarray(rng.standard_normal((_E, _D)), jnp.float32)
+    x = np.array(jax.random.normal(jax.random.PRNGKey(3), (_T, _D)))
+    if pad:
+        x[_T - pad:] = x[-1]
+    if picks is not None:
+        x[:, :_E] = _tokens_picking(*picks, rng, pad=pad)
+    x = jnp.asarray(x)
+    live = None if picks is None else 4 * picks[0] + picks[1] + 4 * pad
+    # a call this small keeps one pass in the program (the rounds' loop
+    # would cost more than it saves): here every size takes the rounds
+    assert moe.permutation_extent(_T * _K, _E, _HELD) == _T * _K
+    monkeypatch.setattr(moe, "_MIN_ROWS_SAVED", 0)
+    assert moe.permutation_extent(_T * _K, _E, _HELD) == 128 < _T * _K
+    extent = pinned or 128
+    if pinned:
+        monkeypatch.setattr(moe, "permutation_extent", lambda *a: pinned)
+    monkeypatch.setattr(ra, "_Y_BLOCK_BYTES", _T * (_D // y_tiles) * 4)
+    kw = dict(top_k=_K, normalize=True, activation=jax.nn.silu,
+              scoring=scoring, return_stats=True)
+
+    def share_of(first, interpret):
+        """Share `first`'s stacks as layer 1 of a loop's whole stacks
+        (`first` is traced: the eight shares are one compiled program)."""
+        mine = {n: w if n == "router" else
+                jax.lax.dynamic_slice_in_dim(w, first, _HELD)
+                for n, w in params.items()}
+        stacks = {n: moe.LayerOf(jnp.stack([jnp.full_like(w, jnp.nan), w]),
+                                 jnp.int32(1))
+                  for n, w in mine.items() if n in moe.EXPERT_MATRICES}
+        return moe.moe_ffn_grouped({**mine, **stacks}, x, held=(first, _HELD),
+                                   interpret=interpret, **kw)
+
+    compact = jax.jit(lambda first: share_of(first, True))
+    one_pass = jax.jit(lambda first: share_of(first, False))
+
+    def share(first, interpret=False):
+        return (compact if interpret else one_pass)(jnp.int32(first))
+
+    whole, _ = moe.moe_ffn_grouped(params, x, **{**kw, "held": None})
+    total = 0.0
+    for first in range(0, _E, _HELD):
+        y, stats = share(first, interpret=True)
+        total = total + y
+        rows, _, _, moved, extra = (int(v) for v in stats)
+        rounds = -(-rows // extent)
+        assert (moved, extra) == (rounds * extent, max(rounds - 1, 0))
+        want = _plain_share(params, x, first=first, scoring=scoring,
+                            gated=gated)
+        assert float(jnp.abs(y - want).max()) < 1e-5, first
+        if first == 0 and live is not None:
+            assert rows == live
+    assert float(jnp.abs(total - whole).max()) < 1e-5
+    if gated and scoring == "softmax":
+        part = ref._experts({"router": params["router"], **{
+            n: params[n][:_HELD] for n in ("wg", "wu", "wd")}}, x,
+            top_k=_K, first=0)
+        assert float(jnp.abs(share(0, interpret=True)[0] - part).max()) < 1e-5
+    # off the TPU the stacks are cut and the extent is S*k: one pass
+    y, stats = share(0)
+    assert int(stats[3]) == _T * _K and int(stats[4]) == 0
+    assert float(jnp.abs(y - share(0, interpret=True)[0]).max()) < 1e-5
+
+
 def test_every_expert_held_is_todays_program(layer128):
     params, x = layer128
     kw = dict(top_k=8, normalize=False, activation=jax.nn.silu,
@@ -332,6 +486,8 @@ def test_every_expert_held_is_todays_program(layer128):
     y1, s1 = moe.moe_ffn_grouped(params, x, held=(0, 128), **kw)
     assert (np.asarray(y0) == np.asarray(y1)).all()
     assert np.asarray(s0).tolist() == np.asarray(s1).tolist()
+    # the permutation moved every pick's row, in one pass
+    assert np.asarray(s0)[3:].tolist() == [2 * 24 * 8, 0]
     # and without the argument nothing of it is traced: the rows behind
     # the last group are masked only for a share
     def eqns(**more):

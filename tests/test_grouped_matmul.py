@@ -1,7 +1,9 @@
 """The grouped-matmul kernel that reads expert matrices out of the held
 stack in place (ops/pallas/grouped_matmul.py), in interpreter mode on the
 CPU: the real visit walk, block indexing and masked stores, against
-`jax.lax.ragged_dot` on the layer's slice and against one product a row."""
+`jax.lax.ragged_dot` on the layer's slice and against one product a row;
+and the kernel that adds the experts' live rows back into their tokens
+(ops/pallas/row_accumulate.py) against the plain scatter-add."""
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +15,7 @@ from dnn_tpu.ops.pallas.grouped_matmul import (
     reference_grouped_matmul,
     visits,
 )
+from dnn_tpu.ops.pallas import row_accumulate as ra
 from dnn_tpu.parallel import moe
 
 F32, BF16 = jnp.float32, jnp.bfloat16
@@ -99,7 +102,7 @@ def test_moe_ffn_grouped_on_a_stack_equals_it_on_the_slice(gated, held,
     """`moe_ffn_grouped` handed `LayerOf(stack, layer)` matrices (a layer
     loop's whole stacks, through the kernel) against the same call handed
     the layer's matrices cut out (`ragged_dot`): outputs and the int32
-    (3,) stats; and `forms` says which form each took."""
+    stats; and `forms` says which form each took."""
     n_layer, d, f, e, k = 3, 32, 48, 8, 2
     n_held = e if held is None else held[1]
     keys = jax.random.split(jax.random.PRNGKey(3), n_layer)
@@ -124,10 +127,61 @@ def test_moe_ffn_grouped_on_a_stack_equals_it_on_the_slice(gated, held,
     assert forms_stack == {"stack_kernel"} and forms_slice == {"ragged_dot"}
     tol = 1e-5 if cd is None else 2e-2
     np.testing.assert_allclose(y_stack, y_slice, atol=tol, rtol=tol)
-    assert stats_stack.dtype == jnp.int32 and stats_stack.shape == (3,)
+    assert stats_stack.dtype == jnp.int32
+    assert stats_stack.shape == (moe.N_STATS,)
     np.testing.assert_array_equal(stats_stack, stats_slice)
     # off the TPU a stack is cut and goes the plain way
     y_cpu = jax.jit(lambda p, x: moe.moe_ffn_grouped(
         p, x, forms=forms_cpu, **kw)[0])(on_stack, x)
     assert forms_cpu == {"ragged_dot"}
     np.testing.assert_array_equal(y_cpu, y_slice)
+
+
+# (case, tokens S, width D, rows R, live rows, bytes a column tile of y may
+# hold: None the module's)
+ACCUMULATE_CASES = [
+    ("no_row_live", 24, 256, 300, 0, None),
+    ("part_of_a_tile", 24, 256, 300, 5, None),
+    ("a_whole_tile", 24, 256, 300, 128, None),
+    ("one_row_into_the_next_tile", 24, 256, 300, 129, None),
+    ("every_row_live", 24, 256, 300, 300, None),
+    ("fewer_rows_than_a_tile", 24, 256, 20, 13, None),
+    ("live_past_the_rows", 24, 256, 20, 64, None),
+    ("y_in_two_column_tiles", 24, 512, 300, 200, 24 * 256 * 4),
+    ("y_in_four_column_tiles", 24, 512, 300, 200, 24 * 128 * 4),
+    ("y_in_four_column_tiles_no_row_live", 24, 512, 300, 0, 24 * 128 * 4),
+    ("y_in_four_column_tiles_every_row_live", 24, 512, 300, 300,
+     24 * 128 * 4),
+]
+
+
+@pytest.mark.parametrize("case,s,d,r,live,y_bytes", ACCUMULATE_CASES,
+                         ids=[c[0] for c in ACCUMULATE_CASES])
+def test_row_accumulate_adds_the_live_rows_alone(case, s, d, r, live,
+                                                 y_bytes, monkeypatch):
+    """Every row under `live`, times its weight, added into its token's
+    row of a y that already holds something; rows from `live` on hold NaN
+    (the grouped matmul leaves them unspecified) and are never read into a
+    sum; a token met by several rows gets them all, rows that follow each
+    other (a token all of whose picks are held) and rows either side of a
+    row tile's edge among them."""
+    if y_bytes is not None:
+        monkeypatch.setattr(ra, "_Y_BLOCK_BYTES", y_bytes)
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    y = jax.random.normal(ks[0], (s, d))
+    rows = jax.random.normal(ks[1], (r, d)).at[min(live, r):].set(jnp.nan)
+    tokens = jax.random.randint(ks[2], (r,), 0, s)
+    tokens = tokens.at[2:10].set(5)
+    if r > 131:
+        tokens = tokens.at[126:131].set(7)
+    weights = jax.random.uniform(ks[3], (r,))
+    got = jax.jit(lambda *a: ra.row_accumulate(*a, interpret=True))(
+        y, rows, tokens, weights, jnp.int32(live))
+    want = np.array(y)
+    for i in range(min(live, r)):
+        want[int(tokens[i])] += float(weights[i]) * np.asarray(rows[i])
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    # off the TPU the dispatcher is the plain form
+    np.testing.assert_allclose(
+        ra.row_accumulate(y, rows, tokens, weights, jnp.int32(live)), want,
+        atol=1e-5, rtol=1e-5)

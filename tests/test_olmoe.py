@@ -122,8 +122,9 @@ def _prefill_then_paged_decode(prepared, fam, seqs, cache_dtype=jnp.float32):
         for slot in range(2):
             got[slot].append(
                 np.asarray(logits[slot].astype(jnp.float32))[None])
-        rows, active, peak = (int(v) for v in stats)
-        assert rows == CFG.n_layer * 2 * CFG.router_top_k
+        rows, active, peak, moved, extra = (int(v) for v in stats)
+        assert rows == moved == CFG.n_layer * 2 * CFG.router_top_k
+        assert extra == 0  # every expert held: one pass over S*k rows
         assert CFG.n_layer * CFG.router_top_k <= active <= rows
         assert CFG.n_layer <= peak <= 2 * CFG.n_layer
         pos += 1
@@ -226,9 +227,9 @@ def test_grouped_experts_are_drop_free(case):
         return_stats=True))(params, x)
     want = _dense_loop(params, x, top_k=top_k, normalize=normalize)
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=1e-5)
-    rows, active, peak = (int(v) for v in stats)
+    rows, active, peak, moved, extra = (int(v) for v in stats)
     n = int(np.prod(shape[:-1]))
-    assert rows == n * top_k
+    assert rows == moved == n * top_k and extra == 0
     if case == "empty_expert":
         assert active <= e - 1
     if case == "all_rows_on_one_expert":
@@ -276,7 +277,7 @@ def test_hf_olmoe_names_map_onto_the_tree():
 
 @pytest.mark.parametrize("overlap", [False, True])
 def test_moe_counters_after_a_known_number_of_steps(overlap, monkeypatch):
-    """The four moe_* series per program on /metrics, after one admission
+    """The six moe_* series per program on /metrics, after one admission
     of two chunks and a known number of decode steps through the batcher:
     exact to the last ended step, the same over /stepz's ring."""
     from dnn_tpu import obs
@@ -305,8 +306,10 @@ def test_moe_counters_after_a_known_number_of_steps(overlap, monkeypatch):
     # the first token comes from the prefill; each later one from a step
     # (overlap dispatches one step more than it commits)
     assert steps in (n_new - 1, n_new)
-    calls, rows, active, peak = clk.moe_total["decode"]
+    calls, rows, active, peak, moved, extra = clk.moe_total["decode"]
     assert calls == steps * layers and rows == calls * slots * k
+    # every expert held: the permutation moves each pick's row, in one pass
+    assert (moved, extra) == (rows, 0)
     assert calls * k <= active <= rows and calls <= peak <= calls * slots
     assert clk.moe_total["prefill"][:2] == [2 * layers, 2 * layers * pad * k]
     series = dict(line.rsplit(" ", 1)
@@ -315,7 +318,9 @@ def test_moe_counters_after_a_known_number_of_steps(overlap, monkeypatch):
     for program in ("decode", "prefill"):
         for i, name in enumerate(("layer_calls_total", "assignments_total",
                                   "active_experts_total",
-                                  "peak_expert_rows_total")):
+                                  "peak_expert_rows_total",
+                                  "rows_permuted_total",
+                                  "extra_rounds_total")):
             assert float(series[f'moe_{name}{{program="{program}"}}']) == \
                 clk.moe_total[program][i]
     ring = clk.summary()["moe"]
