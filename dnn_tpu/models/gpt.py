@@ -188,7 +188,9 @@ def stack_layers(cfg):
     mla.py: layers whose attention and cache differ) — the dense prefix,
     the "full" expert layers ("blocks") and the "window" expert layers
     ("window_blocks"; "linear_blocks" for models/kda.py's "linear"
-    layers), each stacked apart whatever lies between its members."""
+    layers; "ssm_blocks" and "expert_blocks" for the blocks of ONE mixer
+    that is a state-space rule or the experts, models/llama.py
+    `one_mixer`), each stacked apart whatever lies between its members."""
     types = getattr(cfg, "layer_types", None)
     if types is None:
         return {name: tuple(range(*r))
@@ -196,7 +198,8 @@ def stack_layers(cfg):
     k = getattr(cfg, "first_k_dense", 0)
     out = {"dense_blocks": tuple(range(k))} if k else {}
     for name, kind in (("blocks", "full"), ("window_blocks", "window"),
-                       ("linear_blocks", "linear")):
+                       ("linear_blocks", "linear"), ("ssm_blocks", "ssm"),
+                       ("expert_blocks", "experts")):
         layers = tuple(i for i in range(k, cfg.n_layer) if types[i] == kind)
         if layers:
             out[name] = layers

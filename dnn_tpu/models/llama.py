@@ -79,7 +79,9 @@ class Mamba2Config:
     closed-form chunk of the chunked rule. The muP multipliers by their
     published names: `ssm_in` scales the mixer's input, `ssm_out` its
     output, `ssm_multipliers` the in-projection's slices [z | x | B | C |
-    dt]."""
+    dt]. `beside` False (Nemotron-H): the mixer stands IN PLACE of attention
+    in the layers of kind "ssm" of `layer_types` — a kind of slot leaves
+    alone — and the other layers run no rule."""
     d_ssm: int = 4096
     n_head: int = 32
     d_state: int = 256
@@ -89,6 +91,7 @@ class Mamba2Config:
     ssm_in: float = 1.0
     ssm_out: float = 1.0
     ssm_multipliers: tuple = (1.0, 1.0, 1.0, 1.0, 1.0)
+    beside: bool = True
 
     @property
     def head_dim(self):
@@ -333,6 +336,14 @@ class LlamaConfig:
     attn_gate: bool = False
     lightning: Optional[LightningConfig] = None
     block_select: Optional[BlockSelectConfig] = None
+
+    @property
+    def one_mixer(self) -> bool:
+        """Whether a block is ONE norm, ONE mixer and one residual — a
+        state-space mixer, attention or experts by the layer's kind
+        (`MixtralConfig.layer_types` with "experts": Nemotron-H) — and not
+        a mixer followed by a feed-forward part."""
+        return False
 
     def __post_init__(self):
         if self.lightning is not None and (
@@ -748,6 +759,23 @@ def init_block(key, cfg: LlamaConfig, dtype=jnp.float32, *,
     layers are of several (models/mla.py)."""
     c, d = cfg.n_embd, cfg.head_dim
     ks = jax.random.split(key, 7)
+    if cfg.one_mixer:
+        # one norm and the kind's ONE mixer: attention ("full"), the
+        # state rule's params, or — added by models/llama_moe.py — experts
+        from dnn_tpu.models import state_kind
+
+        blk = {"ln_1": {"scale": jnp.ones((c,), dtype)}}
+        if kind == "full":
+            blk["attn"] = {
+                "q": _kernel(ks[0], (c, cfg.n_head * d), dtype),
+                "k": _kernel(ks[1], (c, cfg.n_kv_head * d), dtype),
+                "v": _kernel(ks[2], (c, cfg.n_kv_head * d), dtype),
+                "o": _kernel(ks[3], (cfg.n_head * d, c), dtype,
+                             std=0.02 / (2 * cfg.n_layer) ** 0.5)}
+        rule = state_kind.layer_rule(cfg, kind)
+        if rule is not None:
+            rule.init(blk, key, cfg, dtype)
+        return blk
 
     def _qkv(k, shape):
         p = _kernel(k, shape, dtype)
@@ -1001,12 +1029,18 @@ def _norm(p, x, cfg: LlamaConfig):
     return rms_norm(p, x, eps=cfg.rms_eps, plus_one=cfg.norm_plus_one)
 
 
+def relu2(x):
+    return jnp.square(jax.nn.relu(x))
+
+
 def _mlp_act(cfg: LlamaConfig):
     if cfg.mlp_act == "silu":
         return silu
     if cfg.mlp_act == "gelu_tanh":  # Gemma GeGLU (gelu_pytorch_tanh)
         from dnn_tpu.ops.nn import gelu
         return gelu
+    if cfg.mlp_act == "relu2":  # Nemotron-H: relu(x)^2, ungated
+        return relu2
     raise ValueError(f"unknown mlp_act {cfg.mlp_act!r}")
 
 
@@ -1153,7 +1187,10 @@ def _branches_residual(bp, x, o, h, *, cfg: LlamaConfig, compute_dtype,
     forward, cached decode, batcher rows, verify rows, seq-sharded
     decode) shares. Sequential (LLaMA): x + o, then ln_2 + MLP +
     residual. Parallel (Phi, parallel_block): both branches read the
-    SAME ln_1 output `h`; y = x + o + mlp(h), no ln_2."""
+    SAME ln_1 output `h`; y = x + o + mlp(h), no ln_2. A block of ONE
+    mixer (`one_mixer`): x + o and nothing after it."""
+    if cfg.one_mixer:
+        return _attn_out_residual(bp, x, o, cfg)
     if cfg.parallel_block:
         m = _mlp_out(bp, h, cfg=cfg, compute_dtype=compute_dtype, ffn=ffn)
         return x + o.astype(x.dtype) + m.astype(x.dtype)
@@ -1220,6 +1257,17 @@ def _dense_attn(bp, h, *, cfg: LlamaConfig, compute_dtype, window=None,
     return linear(bp["attn"]["o"], y, compute_dtype=compute_dtype)
 
 
+# the kind of a block whose one mixer is its experts (`one_mixer`): it keeps
+# no state and no K or V, so no cache kind goes by this name
+EXPERTS = "experts"
+
+
+def experts_block(bp, x, ffn, *, cfg: LlamaConfig):
+    """A block of kind `EXPERTS`: x + ffn(norm x), `ffn` the MoE hook."""
+    with jax.named_scope("llama.block.mlp"):
+        return x + ffn(bp, _pre_normed(bp, x, cfg)).astype(x.dtype)
+
+
 def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
                 window=None, ffn=None, kind=None):
     """Pre-RMSNorm block: GQA attention + gated MLP, both residual
@@ -1229,6 +1277,8 @@ def block_apply(bp, x, *, cfg: LlamaConfig, compute_dtype=None, attn_fn=None,
     the per-layer window override for the default dense attention;
     `ffn(bp, h)` overrides the MLP (Mixtral MoE); `kind` is the layer's
     kind where the config's layers are of several (models/mla.py)."""
+    if kind == EXPERTS:
+        return experts_block(bp, x, ffn, cfg=cfg)
     fn = attn_fn or (lambda bp2, h: _dense_attn(
         bp2, h, cfg=cfg, compute_dtype=compute_dtype, window=window))
     rule = None
@@ -1532,7 +1582,8 @@ def _run_block(ffn, acc, run):
         return out
 
     result = run(counting)
-    return result, acc + got[0]
+    # a block of one mixer that is not its experts calls no hook
+    return result, acc + got[0] if got else acc
 
 
 def _block_with_cache(bp, x, rows, start_pos, *, cfg: LlamaConfig,
@@ -2600,6 +2651,12 @@ def to_hf_config(cfg: LlamaConfig, *, tie_word_embeddings: bool = False,
     kwargs pass through (e.g. attn_implementation="eager")."""
     import transformers
 
+    if cfg.one_mixer:
+        # no transformers class of this mapping has blocks of one mixer
+        raise ValueError(
+            "blocks of ONE mixer by kind (`hybrid_override_pattern`) and "
+            "experts in a latent (`moe_latent_size`) have no transformers "
+            "config in this mapping — map this config by hand")
     kw = dict(
         vocab_size=cfg.vocab_size, hidden_size=cfg.n_embd,
         intermediate_size=cfg.d_ff, num_hidden_layers=cfg.n_layer,

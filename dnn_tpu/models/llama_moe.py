@@ -27,6 +27,10 @@ TPU-first composition, not a new model implementation:
     residual. `make_ffn(groups=n)` is that path's dense twin, for the
     parity tests.
 
+Nemotron-H (`one_mixer`): a block is ONE mixer — the experts among them,
+in a LATENT (`moe_latent`: "moe" then holds "wi" / "wo" (E, L, F) / (E, F,
+L), "latent_down" / "latent_up" and an ungated "shared" {"up", "down"}).
+
 Param pytree: llama's, with each block's "mlp" replaced by
   "moe": {"router": {"kernel" (D, E)}, "wg"/"wu" (E, D, F), "wd" (E, F, D)}
 (HF MixtralForCausalLM: block_sparse_moe.gate + experts.i.{w1,w3,w2};
@@ -47,7 +51,7 @@ from dnn_tpu.models import gpt, llama
 from dnn_tpu.models.kda import KdaConfig
 from dnn_tpu.models.mla import MlaConfig
 from dnn_tpu.parallel.moe import (
-    N_STATS, init_moe_gated, moe_ffn, moe_ffn_grouped)
+    N_STATS, init_moe_gated, init_moe_plain, moe_ffn, moe_ffn_grouped)
 from dnn_tpu.registry import ModelSpec, register_model
 
 
@@ -139,15 +143,55 @@ class MixtralConfig(llama.LlamaConfig):
     # weights, and so how far one expert swapped under bfloat16 moves a
     # logit. No trained checkpoint reads it
     expert_out_init: float = 1.0
+    # ---- blocks of ONE mixer (Nemotron-H, `hybrid_override_pattern`):
+    # `layer_types[i]` is "ssm" (`mamba`, whose `beside` is False: the
+    # state-space rule in attention's place), "full" (softmax attention,
+    # `kv_full`) or "experts" (`llama.EXPERTS`) — one norm, that mixer, one
+    # residual, and nothing after it (`one_mixer`). `pattern_types` reads
+    # the published spelling (M, *, E).
+    # `moe_latent`: the experts work in a LATENT of this width — the router scores the
+    # model-wide h, the routed rows are h W_down, the weighted sum goes
+    # through ONE W_up (`moe.latent_down` / `latent_up`, replicated); the
+    # shared expert reads h itself
+    moe_latent: Optional[int] = None
+    # False: an expert (and the shared one) is two matrices without bias
+    # under `mlp_act` ("relu2"), no gate
+    expert_gated: bool = True
+
+    @property
+    def one_mixer(self) -> bool:
+        return llama.EXPERTS in (self.layer_types or ())
 
     def __post_init__(self):
         super().__post_init__()
         if (self.layer_types is None) != (
                 self.mla_window is None and self.kv_window is None
-                and self.kda is None):
+                and self.kda is None
+                and (self.mamba is None or self.mamba.beside)):
             raise ValueError("layer_types comes with mla_window, "
-                             "kv_window or kda, and they with it")
-        if self.kda is not None:
+                             "kv_window, kda or a mamba in attention's "
+                             "place, and they with it")
+        if self.mamba is not None and not self.mamba.beside:
+            if (self.mla is not None or self.kda is not None
+                    or self.kv_window is not None or self.first_k_dense
+                    or self.index_topk is not None or self.mup is not None
+                    or self.mamba.ssm_out != 1.0
+                    or (self.kv_full or llama.KvKind()).window is not None
+                    or len(self.layer_types) != self.n_layer
+                    or set(self.layer_types) - {"ssm", "full", llama.EXPERTS}
+                    or not {"ssm", llama.EXPERTS} <= set(self.layer_types)):
+                raise ValueError(
+                    "a mamba in attention's place (`beside` False) names "
+                    "the \"ssm\" blocks of layer_types, kv_full (which has "
+                    "no window) the \"full\" ones and \"experts\" the rest: "
+                    "blocks of ONE mixer, at least one of each of the two "
+                    "(no mla, kda, window kind, dense prefix, indexer or "
+                    "muP multiplier; ssm_out 1)")
+        elif self.moe_latent is not None or not self.expert_gated:
+            raise ValueError("moe_latent and ungated experts are built for "
+                             "the blocks of one mixer (a mamba in "
+                             "attention's place)")
+        elif self.kda is not None:
             if (self.mla is not None or self.mla_window is not None
                     or self.index_topk is not None
                     or self.kv_window is not None or self.first_k_dense
@@ -189,6 +233,8 @@ class MixtralConfig(llama.LlamaConfig):
 
     @property
     def n_expert_layer(self):
+        if self.one_mixer:
+            return self.layer_types.count(llama.EXPERTS)
         return self.n_layer - self.first_k_dense
 
     @property
@@ -572,6 +618,76 @@ PRESETS["olmoe-1b-7b-1chip"] = dataclasses.replace(
     PRESETS["olmoe-1b-7b"], n_layer=3)
 
 
+def pattern_types(pattern: str) -> tuple:
+    """`layer_types` of a `hybrid_override_pattern` (`model_type`
+    nemotron_h): M a Mamba-2 block, * an attention block, E an experts
+    block — the ONE spelling the block kinds have here."""
+    return tuple({"M": "ssm", "*": "full", "E": llama.EXPERTS}[c]
+                 for c in pattern)
+
+
+# NVIDIA-Nemotron-3-Super-120B-A12B (nvidia/NVIDIA-Nemotron-3-Super-120B-
+# A12B-BF16 config.json, `model_type` nemotron_h): 88 blocks of ONE mixer
+# each — h = RMSNorm(x), x' = x + Mixer(h) — 40 Mamba-2 blocks (128 heads of
+# 64 in 8 groups, state 128, 4 taps, the gate before a norm within each
+# group), 8 attention blocks (GQA 32 query / 2 KV heads of 128, NO rotary
+# embedding and no other position signal) and 40 LatentMoE blocks: sigmoid
+# scores of the 4096-wide h with a selection bias, 22 of 512 a token,
+# weights normalised times 5, the experts relu^2 (two matrices, no gate)
+# 1024 -> 2688 -> 1024 on u = h W_down, ONE W_up after the weighted sum, and
+# an ungated relu^2 shared expert 4096 -> 5376 -> 4096 on h itself; no bias
+# but the convolution's; vocabulary 131072, untied. The multi-token-
+# prediction module is not served. What the config leaves open is `assumed`
+# in chipbench/configs/nemotron-3-super-120b-a12b-ep4-1chip.json. Never
+# instantiated whole. The pattern is the published one: 40 M, 8 *, 40 E.
+# `expert_out_init` 0.5: the seeded init's expert and shared-expert output
+# projections (1 / sqrt(fan-in)) times this — relu^2 of a unit-variance
+# product has an RMS of 1.22, and at 1 an E block is 3-4x the stream a
+# Mamba-2 block leaves
+_NEMOTRON3_PATTERN = (
+    "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+    "EMEMEMEM*EMEMEMEME")
+PRESETS["nemotron-3-super-120b-a12b"] = MixtralConfig(
+    block_size=262144, vocab_size=131072, n_layer=88, n_head=32, n_kv_head=2,
+    n_embd=4096, d_ff=2688, head_dim_override=128, rms_eps=1e-5,
+    mlp_act="relu2", expert_gated=False, moe_latent=1024, n_expert=512,
+    router_top_k=22, router_norm_topk=True, capacity_factor=512.0,
+    d_shared=5376, shared_gate=False, expert_out_init=0.5,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=5.0),
+    kv_full=llama.KvKind(window=None, rope=False),
+    mamba=llama.Mamba2Config(d_ssm=8192, n_head=128, d_state=128, n_groups=8,
+                             conv=4, chunk=128, beside=False),
+    layer_types=pattern_types(_NEMOTRON3_PATTERN))
+# the benchmark's cut (chipbench/configs/nemotron-3-super-120b-a12b-ep4-
+# 1chip.json): one chip's share of a 4-chip expert-parallel layer — experts
+# 0-127 of each E block's 512, rows 0-32767 of the vocabulary (a quarter),
+# mixers, router, latent projections, shared expert and norms whole — and
+# the first period of eleven blocks (MEMEMEM*EME: 5 M, 1 *, 5 E)
+PRESETS["nemotron-3-super-120b-a12b-ep4-1chip"] = dataclasses.replace(
+    PRESETS["nemotron-3-super-120b-a12b"], n_layer=11,
+    layer_types=pattern_types(_NEMOTRON3_PATTERN[:11]), vocab_size=32768,
+    experts_first=0, experts_held=128)
+# tiny Nemotron-H for the CPU tests, every switch of the real one acting:
+# all three kinds in an order that puts an E first after an M and a *
+# between two E, 2 groups of 2 state heads of 16 (state 16, a chunk of 8
+# that a 16-token prefill chunk holds twice), GQA 2:1 with a decoupled head
+# width and NO rotation, 16 experts 6 a token with 4 held, a latent of 32
+# under a model of 64, an expert width (24) that is no multiple of a tile,
+# a seeded selection bias, scaling 2.5, an ungated relu^2 shared expert
+PRESETS["nemotron-h-test"] = MixtralConfig(
+    block_size=128, vocab_size=256, n_layer=7, n_head=4, n_kv_head=2,
+    n_embd=64, d_ff=24, head_dim_override=16, rms_eps=1e-5,
+    mlp_act="relu2", expert_gated=False, moe_latent=32, n_expert=16,
+    router_top_k=6, router_norm_topk=True, capacity_factor=16.0,
+    experts_first=0, experts_held=4, d_shared=48, shared_gate=False,
+    expert_out_init=0.5,
+    router=RouterConfig(scoring="sigmoid", select_bias=True, scale=2.5),
+    kv_full=llama.KvKind(window=None, rope=False),
+    mamba=llama.Mamba2Config(d_ssm=64, n_head=4, d_state=16, n_groups=2,
+                             conv=4, chunk=8, beside=False),
+    layer_types=pattern_types("MEME*EM"))
+
+
 @jax.named_scope("moe.shared")
 def _shared_expert_out(moe_p, h, *, compute_dtype=None):
     """The always-on shared expert (Qwen2-MoE / DeepSeek recipe): a
@@ -583,6 +699,10 @@ def _shared_expert_out(moe_p, h, *, compute_dtype=None):
     from dnn_tpu.ops.nn import linear, silu
 
     sp = moe_p["shared"]
+    if "gate" not in sp:  # two matrices under relu^2, no gate (Nemotron-H)
+        return linear(sp["down"], llama.relu2(
+            linear(sp["up"], h, compute_dtype=compute_dtype)),
+            compute_dtype=compute_dtype).astype(h.dtype)
     s = linear(sp["down"],
                silu(linear(sp["gate"], h, compute_dtype=compute_dtype))
                * linear(sp["up"], h, compute_dtype=compute_dtype),
@@ -634,7 +754,7 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
     `groups` > 1 is NOT a serving option: it selects the expert-parallel
     path's dense twin (static capacity per routing group, parallel/moe.
     moe_ffn), which the EP parity tests compare an n-device run with."""
-    from dnn_tpu.ops.nn import silu
+    from dnn_tpu.ops.nn import linear, silu
 
     forms = set()
 
@@ -644,12 +764,24 @@ def make_ffn(cfg: MixtralConfig, *, compute_dtype=None, groups: int = 1):
                            capacity_factor=cfg.capacity_factor,
                            groups=groups, compute_dtype=compute_dtype,
                            normalize=cfg.router_norm_topk)
-        return moe_ffn_grouped(bp["moe"], h, top_k=cfg.router_top_k,
-                               normalize=cfg.router_norm_topk,
-                               activation=silu, compute_dtype=compute_dtype,
-                               return_stats=return_stats, held=cfg.held,
-                               scoring=cfg.router.scoring,
-                               scale=cfg.router.scale, forms=forms)
+        grouped = functools.partial(
+            moe_ffn_grouped, bp["moe"], h, top_k=cfg.router_top_k,
+            normalize=cfg.router_norm_topk, compute_dtype=compute_dtype,
+            return_stats=return_stats, held=cfg.held,
+            scoring=cfg.router.scoring, scale=cfg.router.scale, forms=forms)
+        if cfg.moe_latent is None:
+            return grouped(activation=silu)
+        # the router scores h; the experts read, and their weighted sum is,
+        # a latent row; ONE up-projection follows the sum
+        with jax.named_scope("moe.latent_down"):
+            u = linear(bp["moe"]["latent_down"], h,
+                       compute_dtype=compute_dtype)
+        out = grouped(activation=llama._mlp_act(cfg), rows=u)
+        r, stats = out if return_stats else (out, None)
+        with jax.named_scope("moe.latent_up"):
+            y = linear(bp["moe"]["latent_up"], r,
+                       compute_dtype=compute_dtype).astype(h.dtype)
+        return (y, stats) if return_stats else y
 
     def with_shared(bp, h, out):
         if cfg.d_shared:
@@ -694,19 +826,40 @@ def init_parts(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
             jax.random.split(keys[i], 3), cfg, cfg.d_ff_dense, dtype)
         return blk
 
+    def kernel(key, shape):  # 1 / sqrt(fan-in)
+        return {"kernel": (jax.random.normal(key, shape)
+                           / math.sqrt(shape[0])).astype(dtype)}
+
     def expert_layer(i, block):
         blk = block()
-        moe = init_moe_gated(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
-                             dtype, n_held=cfg.experts_held)
+        if cfg.expert_gated:
+            moe = init_moe_gated(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
+                                 dtype, n_held=cfg.experts_held)
+        else:
+            moe = init_moe_plain(keys[i], cfg.n_embd, cfg.n_expert, cfg.d_ff,
+                                 dtype, n_held=cfg.experts_held,
+                                 d_in=cfg.moe_latent)
+        out = "wd" if cfg.expert_gated else "wo"
         if cfg.expert_out_init != 1.0:
-            moe["wd"] = moe["wd"] * jnp.asarray(cfg.expert_out_init,
-                                                moe["wd"].dtype)
+            moe[out] = moe[out] * jnp.asarray(cfg.expert_out_init,
+                                              moe[out].dtype)
+        if cfg.moe_latent is not None:
+            kd, ku = jax.random.split(jax.random.fold_in(keys[i], 3))
+            moe["latent_down"] = kernel(kd, (cfg.n_embd, cfg.moe_latent))
+            moe["latent_up"] = kernel(ku, (cfg.moe_latent, cfg.n_embd))
         if cfg.router.select_bias:
             moe["router"]["select_bias"] = (
                 _SELECT_BIAS_INIT * jax.random.normal(
                     jax.random.fold_in(keys[i], 2), (cfg.n_expert,))
             ).astype(jnp.float32)
-        if cfg.d_shared:
+        if cfg.d_shared and not cfg.expert_gated:
+            ks = jax.random.split(jax.random.fold_in(keys[i], 1), 2)
+            down = kernel(ks[1], (cfg.d_shared, cfg.n_embd))
+            down["kernel"] = down["kernel"] * jnp.asarray(
+                cfg.expert_out_init, dtype)
+            moe["shared"] = {"up": kernel(ks[0], (cfg.n_embd, cfg.d_shared)),
+                             "down": down}
+        elif cfg.d_shared:
             ks = jax.random.split(jax.random.fold_in(keys[i], 1), 4)
             si = 1.0 / math.sqrt(cfg.n_embd)
             so = 1.0 / math.sqrt(cfg.d_shared)
@@ -725,6 +878,8 @@ def init_parts(rng, cfg: MixtralConfig = PRESETS["mixtral-test"],
         return blk
 
     for i in range(cfg.n_layer):
+        if cfg.one_mixer and cfg.layer_types[i] != llama.EXPERTS:
+            continue  # the block's one mixer is llama.init_block's
         parts[f"h_{i}"] = functools.partial(
             dense_layer if i < cfg.first_k_dense else expert_layer, i,
             parts[f"h_{i}"])
@@ -1243,6 +1398,8 @@ def to_hf_config(cfg: MixtralConfig, **overrides):
     configs) for parity tests."""
     import transformers
 
+    if cfg.one_mixer:
+        return llama.to_hf_config(cfg)  # refuses, by name
     if cfg.d_shared:
         return transformers.Qwen2MoeConfig(
             vocab_size=cfg.vocab_size, hidden_size=cfg.n_embd,

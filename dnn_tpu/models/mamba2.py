@@ -6,6 +6,11 @@ one residual,
 
     x' = x + ssm_out SSM(h) + attention_out Attn(h);  x'' = x' + MLP(norm(x')).
 
+— or IN PLACE of attention in the blocks of kind "ssm" of a model whose blocks
+are ONE mixer each (Nemotron-H, `Mamba2Config.beside` False: `RULE_ALONE`, a
+kind of slot leaves alone, x' = x + SSM(h) and nothing after it; the other
+blocks run no rule). Which of the two a config runs is `Rule.resolve`'s.
+
 Attn is models/llama.py's (GQA, rotary embedding, K and V a position). SSM,
 with H heads of P in G groups, a state N wide, u = ssm_in h:
 
@@ -62,8 +67,10 @@ the compute dtype.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -94,8 +101,11 @@ def _slices(m):
     return (m.d_ssm, m.d_ssm, gn, gn, m.n_head)
 
 
-def _mup_vector(m) -> np.ndarray:
-    """`ssm_in` times `ssm_multipliers` over the in-projection's outputs."""
+def _mup_vector(m) -> Optional[np.ndarray]:
+    """`ssm_in` times `ssm_multipliers` over the in-projection's outputs;
+    None — nothing to trace — where every one of them is 1."""
+    if m.ssm_in == 1.0 and all(s == 1.0 for s in m.ssm_multipliers):
+        return None
     return m.ssm_in * np.concatenate([
         np.full((w,), s, np.float32)
         for w, s in zip(_slices(m), m.ssm_multipliers)])
@@ -111,7 +121,9 @@ def init_mixer(key, cfg, dtype=jnp.float32):
     token's own part of dt; gains and D exactly 1."""
     m, c = cfg.mamba, cfg.n_embd
     ks = jax.random.split(key, 3)
-    w_in = jax.random.normal(ks[0], (c, m.proj_width)) * 0.02 / _mup_vector(m)
+    mup = _mup_vector(m)
+    w_in = jax.random.normal(ks[0], (c, m.proj_width)) * 0.02 / (
+        1.0 if mup is None else mup)
     w_out = (jax.random.normal(ks[1], (m.d_ssm, c)) * 0.02
              / (2 * cfg.n_layer) ** 0.5 / m.ssm_out)
     row_sigma = 0.02 * math.sqrt(c)  # of a row of W_in's products, scaled
@@ -138,15 +150,22 @@ def _project(p, h, *, m, compute_dtype):
     proj = linear(p["in"], h, compute_dtype=compute_dtype)
     wz, wc = m.d_ssm, m.d_ssm + m.conv_width
     mup = _mup_vector(m)
-    z = proj[..., :wz].astype(jnp.float32) * mup[:wz]
-    dt = jax.nn.softplus(proj[..., wc:].astype(jnp.float32) * mup[wc:]
-                         + p["dt_bias"])
+
+    def scaled(v, part):
+        return v if mup is None else v * mup[part]
+
+    z = scaled(proj[..., :wz].astype(jnp.float32), slice(None, wz))
+    dt = jax.nn.softplus(
+        scaled(proj[..., wc:].astype(jnp.float32), slice(wc, None))
+        + p["dt_bias"])
     return z, proj[..., wz:wc], dt
 
 
 def _taps(p, m):
     """The convolution's taps with the slices' multipliers in them."""
-    return p["conv"]["taps"] * _mup_vector(m)[m.d_ssm:m.d_ssm + m.conv_width]
+    mup = _mup_vector(m)
+    return p["conv"]["taps"] if mup is None else (
+        p["conv"]["taps"] * mup[m.d_ssm:m.d_ssm + m.conv_width])
 
 
 def _heads(conved, p, m):
@@ -324,8 +343,19 @@ def _init_block(blk, key, cfg, dtype):
     blk["ssm"] = init_mixer(jax.random.fold_in(key, 31), cfg, dtype)
 
 
+def _rule_of(cfg):
+    """The rule as `cfg` runs it (`Mamba2Config.beside`)."""
+    return RULE if cfg.mamba.beside else RULE_ALONE
+
+
+# beside softmax attention in every layer, in the kind that pages K and V
+# (Falcon-H1)
 RULE = state_kind.Rule(
     field="mamba", kind="full", params="ssm", slot_leaves=slot_leaves,
     init=_init_block, chunk=mixer_chunk, step=mixer_step, kernel="step",
     whole=("ssm_state",), beside=mixers_sum,
-    forms=("ssm_prefill", "ssm_decode"))
+    forms=("ssm_prefill", "ssm_decode"), resolve=_rule_of)
+# in attention's PLACE in the layers of kind "ssm", a kind of slot leaves
+# alone (Nemotron-H: `Mamba2Config.beside` False)
+RULE_ALONE = dataclasses.replace(RULE, kind="ssm", beside=None,
+                                 forms=("prefill", "decode"))

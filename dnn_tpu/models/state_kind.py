@@ -1,8 +1,9 @@
 """The layers that keep a STATE a slot — a matrix a head whatever the
 length, and perhaps the last rows of a short convolution — behind ONE
 adapter: what models/kda.py (the gated delta rule), models/retention.py
-(power retention), models/mamba2.py (Mamba-2, BESIDE softmax attention) and
-models/lightning.py (fixed-decay linear attention) share.
+(power retention), models/mamba2.py (Mamba-2, BESIDE softmax attention or in
+its place, by the config) and models/lightning.py (fixed-decay linear
+attention) share.
 
 A rule module keeps its mathematics and exports one `Rule`: the config
 field that turns it on, the layer kind it serves, where its params sit in a
@@ -65,6 +66,9 @@ class Rule:
     # attention on the same normed input; None: in its place
     beside: Optional[Callable] = None
     forms: tuple = ("prefill", "decode")  # its names in `attn_forms[kind]`
+    # cfg -> the rule as THAT config runs it, where its kind and `beside`
+    # follow the config (models/mamba2.py); None: as it stands
+    resolve: Optional[Callable] = None
 
     def of(self, bp):
         return bp if self.params is None else bp[self.params]
@@ -80,8 +84,9 @@ def _rules():
 
 def config_rule(cfg) -> Optional[Rule]:
     """The rule a config's state layers run; None: it has none."""
-    return next((r for r in _rules()
+    rule = next((r for r in _rules()
                  if getattr(cfg, r.field, None) is not None), None)
+    return rule if rule is None or rule.resolve is None else rule.resolve(cfg)
 
 
 def layer_rule(cfg, kind) -> Optional[Rule]:
@@ -179,7 +184,8 @@ class LayerLeaves:
 class StateKindRows(llama.LlamaKindRows):
     """`LlamaKindRows` for a config whose layers — all of them, or those of
     one kind of `layer_types` — run a state `Rule` (`config_rule`), in place
-    of softmax attention or beside it.
+    of softmax attention or beside it; a block of kind `llama.EXPERTS`
+    (`one_mixer`) runs its experts and keeps nothing.
 
     The rule's kind has `slot_leaves` (`cache_kinds[kind]["slot_leaves"]`):
     leaves (L_kind, slots, ...) with NO position axis, no blocks and no
@@ -231,6 +237,20 @@ class StateKindRows(llama.LlamaKindRows):
 
     def _runs_rule(self, kind):
         return layer_rule(self.cfg, kind) is not None
+
+    def _chunk_block(self, bp, x, rows, start_pos, ffn, kind, **chunk_kw):
+        if kind == llama.EXPERTS:  # no state, no K or V: nothing of `rows`
+            return llama.experts_block(bp, x, ffn, cfg=self.cfg), rows
+        return super()._chunk_block(bp, x, rows, start_pos, ffn, kind,
+                                    **chunk_kw)
+
+    def _block_rows(self, bp, x, layer_cache, pos, write, codec,
+                    window=None, ffn=None, **kind):
+        if kind.get("kind") == llama.EXPERTS:
+            return (llama.experts_block(bp, x, ffn or self.ffn, cfg=self.cfg),
+                    layer_cache)
+        return super()._block_rows(bp, x, layer_cache, pos, write, codec,
+                                   window=window, ffn=ffn, **kind)
 
     def _chunk_attn(self, bp, h, rows, start_pos, kind, n_real=None):
         """`LlamaKindRows._chunk_attn` where the layer runs the rule: its

@@ -897,13 +897,25 @@ def _stack_and_release(params, cfg, compute_dtype=None):
             time.monotonic() - t0)
         return out, structure
 
+    def stack_of(column):
+        # eager `jnp.stack` first makes an expanded COPY of every member
+        # (each `expand_dims` is a program of its own), so a column stands
+        # in memory three times while it is stacked; a column of GBs (a
+        # layer kind's expert stacks of 128 experts: 3.5 GB, and the boot's
+        # high-water mark 15.8 GB of a 16 GB chip: PERF.md section 6, PR
+        # 66) is stacked by ONE program instead: twice. Smaller columns
+        # keep the eager form, which compiles nothing
+        if sum(leaf.nbytes for leaf in column) >= 2 ** 31:
+            return jax.jit(lambda *xs: jnp.stack(xs))(*column)
+        return jnp.stack(column)
+
     def stack(layers):
         flat = [held_layer(i) for i in layers]
         stacked = []
         for column in zip(*(leaves for leaves, _ in flat)):
             # done before the leaves go, so that the next leaf's stack
             # is allocated after this one's are free
-            stacked.append(jax.block_until_ready(jnp.stack(column)))
+            stacked.append(jax.block_until_ready(stack_of(column)))
             for leaf in column:
                 free(leaf)
         return jax.tree_util.tree_unflatten(flat[0][1], stacked)

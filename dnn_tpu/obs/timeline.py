@@ -424,6 +424,7 @@ class StepClock:
         self._loop_iter = 0
         # expert layers (note_moe): per program, MOE_SERIES in order
         self.moe_total = {p: [0] * len(MOE_SERIES) for p in MOE_PROGRAMS}
+        self.moe_latent_rows_total = dict.fromkeys(MOE_PROGRAMS, 0)
         # a paged KV pool's blocks (note_attn_blocks): live, in the tables
         self.attn_blocks_total = [0, 0]
         # the paged decode kernel's groups (note_attn_groups): walked, full
@@ -519,6 +520,11 @@ class StepClock:
                 _weak_total("attn_groups_total", 1)}
         # registered with the first note_moe: a model without experts
         # shows no moe_* series
+        self._latent_registered = False
+        self._latent_gauges = {
+            labeled("moe.latent_rows_total", program=p):
+                _weak_total("moe_latent_rows_total", p)
+            for p in MOE_PROGRAMS}
         self._moe_registered = False
         self._moe_gauges = {
             labeled(f"moe.{name}", program=p): _weak_moe(p, i)
@@ -769,6 +775,21 @@ class StepClock:
             self._gauges_registered = False  # re-register with them
         for i, v in enumerate(add):
             total[program][i] += v
+
+    def note_moe_latent(self, program: str, rows: int):
+        """Rows that went through an expert layer's latent down-projection
+        (models/llama_moe.py `moe_latent`: every row of every expert layer
+        call of one executed program): `moe.latent_rows_total{program}`,
+        the experts' input rows without the model's width. The series
+        appears with the first note — a model whose experts are as wide as
+        the model has none."""
+        if not _obs.enabled():
+            return
+        if not self._latent_registered:
+            self._latent_registered = True
+            self._gauges.update(self._latent_gauges)
+            self._gauges_registered = False  # re-register with them
+        self.moe_latent_rows_total[program] += rows
 
     def note_moe(self, program: str, layer_calls: int, stats):
         """What the expert layers of one executed program cost:

@@ -30,6 +30,18 @@ lane tiles (P, 128), turned once, summed down the sublanes — a (1, P) row,
 the layout y wants. Two transposes a head on a unit that is otherwise idle;
 the products and sums are the VPU's, float32 throughout, nothing on the
 MXU.
+
+Heads NARROWER than the 128 lanes (Nemotron-H: 128 heads of P = 64, N = 128)
+are FOLDED: 128 / P consecutive heads of a group are one (128, N) tile — the
+leaf (L, B, H, P, N) read as (L, B, H P / 128, 128, N) and x as (B, H P / 128,
+128), both the same bytes in the same order — so that the two transposes stay
+128 x 128 and a grid step walks H P / 128 tiles; the heads of a tile differ
+in their scalars alone, which become a row's (a: down the sublanes) and a
+lane's (dt, D: along x). Left as they were, heads of 64 cost a (128, 64)
+transpose each way and twice the trips: 2.69 ms a layer of 0.27 GB; folded
+1.38-1.43 (heads of 128 over a state of 256 take 0.84: one pair of
+transposes there serves two lane tiles of state, here one. PERF.md section
+6, PR 66).
 """
 
 from __future__ import annotations
@@ -46,7 +58,7 @@ _LANES = 128
 
 
 def _kernel(layer_ref, a_ref, dt_ref, d_ref, s_ref, x_ref, b_ref, c_ref,
-            o_ref, y_ref, *, per_group):
+            o_ref, y_ref, *, per_group, fold):
     from jax.experimental import pallas as pl
 
     del layer_ref  # the index maps' alone
@@ -57,10 +69,27 @@ def _kernel(layer_ref, a_ref, dt_ref, d_ref, s_ref, x_ref, b_ref, c_ref,
     # the group's B and C, a (1, w) row a lane tile of the state
     b_rows = [b_ref[:, t] for t in tiles]
     c_rows = [c_ref[:, t] for t in tiles]
+    if fold > 1:
+        # which of a tile's `fold` heads a row (of the state) and a lane (of
+        # x) belongs to
+        of_row = lax.broadcasted_iota(jnp.int32, (p, 1), 0) // (p // fold)
+        of_lane = lax.broadcasted_iota(jnp.int32, (1, p), 1) // (p // fold)
+
+    def scalars(i):
+        """A tile's decay (down its rows), dt and D (along x): scalars a
+        head, spread over the tile's heads where it holds several."""
+        h = g * per_group + i
+        if fold > 1:
+            h = h * fold
+        a, dt, d = a_ref[slot, h], dt_ref[slot, h], d_ref[0, h]
+        for j in range(1, fold):
+            a = jnp.where(of_row >= j, a_ref[slot, h + j], a)
+            dt = jnp.where(of_lane >= j, dt_ref[slot, h + j], dt)
+            d = jnp.where(of_lane >= j, d_ref[0, h + j], d)
+        return a, dt, d
 
     def head(i, carry):
-        h = g * per_group + i
-        a, dt, d = a_ref[slot, h], dt_ref[slot, h], d_ref[0, h]
+        a, dt, d = scalars(i)
         x = x_ref[pl.ds(i, 1), :]  # (1, P)
         # dt x down the rows, on every lane: the row over w sublanes, turned
         col = jnp.broadcast_to(dt * x, (w, p)).T
@@ -83,11 +112,19 @@ def ssm_step(pool, layer, a, dt, d, x, bm, cm, *, interpret=False):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    _, b, h, p, n = pool.shape
+    shape = pool.shape
+    _, b, h, p, n = shape
     g = bm.shape[1]
-    per_group = h // g
     if n % min(n, _LANES):
         raise ValueError(f"the state's width {n} must tile 128 lanes")
+    # heads narrower than the lanes: `fold` of them a tile (module docstring)
+    fold = _LANES // p if p < _LANES and _LANES % p == 0 else 1
+    if (h // g) % fold:
+        fold = 1
+    if fold > 1:
+        pool = pool.reshape(shape[0], b, h // fold, fold * p, n)
+        x = x.reshape(b, h // fold, fold * p)
+    per_group, p = h // g // fold, fold * p
     layer = jnp.asarray(layer, jnp.int32).reshape(1)
 
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)  # whole
@@ -103,11 +140,11 @@ def ssm_step(pool, layer, a, dt, d, x, bm, cm, *, interpret=False):
         out_specs=[state, rows],
     )
     f32 = jnp.float32
-    return pl.pallas_call(
-        functools.partial(_kernel, per_group=per_group),
+    pool, y = pl.pallas_call(
+        functools.partial(_kernel, per_group=per_group, fold=fold),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
-                   jax.ShapeDtypeStruct((b, h, p), f32)],
+                   jax.ShapeDtypeStruct(x.shape, f32)],
         # operand numbers count the scalar: layer, a, dt, d, then the pool
         input_output_aliases={4: 0},
         compiler_params=pltpu.CompilerParams(
@@ -118,3 +155,4 @@ def ssm_step(pool, layer, a, dt, d, x, bm, cm, *, interpret=False):
     )(layer, a.astype(f32), dt.astype(f32), d.astype(f32).reshape(1, h),
       pool, x.astype(f32), bm.astype(f32)[:, :, None],
       cm.astype(f32)[:, :, None])
+    return pool.reshape(shape), y.reshape(shape[1:4])

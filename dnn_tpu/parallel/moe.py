@@ -173,6 +173,25 @@ def init_moe_gated(rng, n_embd: int, n_experts: int, d_ff: int,
     }
 
 
+def init_moe_plain(rng, n_embd: int, n_experts: int, d_ff: int,
+                   dtype=jnp.float32, *, n_held: Optional[int] = None,
+                   d_in: Optional[int] = None):
+    """Param pytree for an UNGATED MoE FFN layer without biases — two
+    matrices an expert, "wi" (E, d_in, F) and "wo" (E, F, d_in) — whose
+    experts read and write rows `d_in` wide (None: the model's; narrower:
+    a latent, models/llama_moe.py `moe_latent`) under a router over the
+    model's width. `n_held` as `init_moe_gated`'s."""
+    kr, k1, k2 = jax.random.split(rng, 3)
+    d_in = n_embd if d_in is None else d_in
+    e = n_experts if n_held is None else n_held
+    return {
+        "router": {"kernel": jax.random.normal(
+            kr, (n_embd, n_experts), dtype) / math.sqrt(n_embd)},
+        "wi": _expert_stack(k1, (e, d_in, d_ff), dtype, 1.0 / math.sqrt(d_in)),
+        "wo": _expert_stack(k2, (e, d_ff, d_in), dtype, 1.0 / math.sqrt(d_ff)),
+    }
+
+
 def _expert_ffn_gated(params, expert_in, *, compute_dtype):
     """(E, cap, D) tokens through each expert's SwiGLU —
     silu(x@wg) * (x@wu) @ wd, one batched matmul triple (the Mixtral
@@ -360,7 +379,8 @@ def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
                      activation, compute_dtype, interpret=False, forms=None):
     """The expert FFN over rows sorted by expert, (R, D) -> (R, D) f32:
     `silu(x@wg) * (x@wu) @ wd` for the gated stack ("wg"), `act(x@wi + bi)
-    @ wo + bo` for the plain one, as grouped matmuls — row r meets only
+    @ wo + bo` for the plain one (the biases where the tree carries them),
+    as grouped matmuls — row r meets only
     its own expert's weights. Same dtype recipe as `_expert_ffn`
     (operands in compute_dtype, f32 accumulation).
 
@@ -408,13 +428,15 @@ def _experts_grouped(params, rows, expert_of_row, group_sizes, *,
 
     if gated:
         h = jax.nn.silu(gdot(x, ws[0], scales[0])) * gdot(x, ws[1], scales[1])
-    else:
+    elif "bi" in params:
         h = activation(gdot(x, ws[0], scales[0])
                        + params["bi"][expert_of_row].astype(jnp.float32))
+    else:
+        h = activation(gdot(x, ws[0], scales[0]))
     if cd is not None:
         h = h.astype(cd)
     out = gdot(h, ws[-1], scales[-1])
-    if not gated:
+    if "bo" in params:
         out = out + params["bo"][expert_of_row].astype(jnp.float32)
     return out
 
@@ -456,10 +478,16 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
                     activation=gelu, compute_dtype=None,
                     return_stats: bool = False, held=None,
                     scoring: str = "softmax", scale: float = 1.0,
-                    interpret: bool = False, forms=None):
+                    interpret: bool = False, forms=None, rows=None):
     """Drop-free MoE FFN on one device: (..., D) -> (..., D), every token
     of `x` routed to its top_k experts and every routed row computed.
     Output does NOT include the residual; callers add it.
+
+    `rows` (..., W): what the experts READ where that is not what the
+    router scores — a token's latent, W the experts' in / out width
+    (models/llama_moe.py `moe_latent`): `x` is scored, `rows` are permuted
+    and computed, and the result is (..., W), the weighted sum in the
+    experts' width. None: `x` itself.
 
     The three scopes name its parts on a device trace: `moe.route`
     (router matmul, softmax, top-k, the sort and the row gather),
@@ -502,8 +530,10 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
     (`_experts_grouped` says when; `interpret` runs the kernels in
     interpreter mode, for CPU tests; `forms`, a set, is told at trace
     time which form the matmuls took)."""
-    shape, d = x.shape, x.shape[-1]
-    xs = x.reshape(-1, d)
+    xs = x.reshape(-1, x.shape[-1])
+    # what the permutation moves: the scored rows, or the caller's `rows`
+    us = xs if rows is None else rows.reshape(-1, rows.shape[-1])
+    shape, d = (x if rows is None else rows).shape, us.shape[-1]
     s = xs.shape[0]
     n_rows = s * top_k
     experts = functools.partial(
@@ -516,17 +546,17 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
             select_bias=params["router"].get("select_bias"), scale=scale)
     extent = n_rows
     if held is not None and _reads_stacks_in_place(
-            params, compute_dtype, interpret, rows_dtype=xs.dtype):
+            params, compute_dtype, interpret, rows_dtype=us.dtype):
         extent = permutation_extent(
             n_rows, params["router"]["kernel"].shape[-1], held[1])
     live = jnp.int32(n_rows) if held is None else group_sizes.sum()
     if extent == n_rows:
         with jax.named_scope("moe.route"):
-            rows = xs[order // top_k]  # (S*k, D), sorted by expert
+            sorted_rows = us[order // top_k]  # (S*k, D), sorted by expert
         with jax.named_scope("moe.experts"):
             # rows behind the last group belong to no expert here: the
             # grouped matmul leaves them unspecified
-            out = experts(rows, expert_of_row, group_sizes)
+            out = experts(sorted_rows, expert_of_row, group_sizes)
         with jax.named_scope("moe.combine"):
             # row t*k + j back at (t, j): the inverse of a permutation is
             # its argsort (8 us for 8192 where the scatter took 39)
@@ -538,7 +568,7 @@ def moe_ffn_grouped(params, x, *, top_k: int = 2, normalize: bool = True,
             y = (unsorted * weights[..., None]).sum(axis=1)
         rounds = jnp.int32(1)
     else:
-        y, rounds = _rounds(experts, xs, weights, order, expert_of_row,
+        y, rounds = _rounds(experts, us, weights, order, expert_of_row,
                             group_sizes, live, extent, interpret=interpret)
     with jax.named_scope("moe.combine"):
         y = y.reshape(shape).astype(x.dtype)

@@ -1361,6 +1361,19 @@ class LMServer:
         forms = getattr(getattr(family, "ffn", None), "expert_forms", None)
         if forms:
             comps["weights"]["moe_experts"] = "+".join(sorted(forms))
+        # blocks of ONE mixer (models/llama.py `one_mixer`): how many of
+        # each kind, and what the experts are handed
+        cfg = getattr(self.batcher, "cfg", None)
+        if getattr(cfg, "one_mixer", False):
+            comps["blocks"] = {
+                "detail": "blocks of one mixer, by kind; the experts' "
+                          "latent width, picks a row and share held",
+                "kinds": {k: cfg.layer_types.count(k)
+                          for k in dict.fromkeys(cfg.layer_types)},
+                "moe": {"latent_size": cfg.moe_latent,
+                        "picks": cfg.router_top_k,
+                        "held": cfg.experts_held or cfg.n_expert,
+                        "of": cfg.n_expert}}
         # what a grid step of the latent prefill kernel covers in the
         # chunk programs built so far, by layer kind and by the columns a
         # call was handed (models/mla.py `prefix_lengths`): said while

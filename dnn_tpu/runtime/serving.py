@@ -2329,8 +2329,12 @@ class ContinuousBatcher:
         for (_, program, _), stats in zip(
                 ready, jax.device_get([r[2] for r in ready])):
             # a dense prefix's layers are no expert layer calls
-            sc.note_moe(program, getattr(self.cfg, "n_expert_layer",
-                                         self.cfg.n_layer), stats)
+            calls = getattr(self.cfg, "n_expert_layer", self.cfg.n_layer)
+            sc.note_moe(program, calls, stats)
+            if getattr(self.cfg, "moe_latent", None) is not None:
+                # every row of the program goes through W_down, a layer
+                sc.note_moe_latent(program, calls * (
+                    self.slots if program == "decode" else self.prompt_pad))
 
     def _ensure_cache_len(self, need: int):
         """Grow the bucketed dense pool to the smallest ladder bucket

@@ -826,6 +826,18 @@ def test_statusz_says_what_a_prefill_kernel_step_covers(served, config):
             assert call["heads_per_step"] is call["block_s"] is None
 
 
+def test_the_benchmark_counts():
+    """Thirteen configurations, sixteen cells (one on four chips), 127 of
+    the 128 per-layer entries the list holds (ISSUE 66; ROADMAP R0: the
+    next configuration's entries do not fit)."""
+    assert len(_BENCH["configs"]) == 13
+    assert len(_BENCH["workloads"]) == 16
+    assert sum(w["chips"] == 4 for w in _BENCH["workloads"]) == 1
+    assert len(_BENCH["per_layer"]) == 127 <= 128
+    assert len({w["config"] for w in _BENCH["workloads"]}) == 13
+    assert "blocks" not in {m["layer"] for m in _BENCH["per_layer"]}
+
+
 @pytest.mark.parametrize("config", sorted(_SERVED))
 def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
     """`/statusz` `components.attention.kinds`: for a model whose K and V
@@ -870,6 +882,33 @@ def test_statusz_says_which_form_each_layer_kinds_reads_took(served, config):
                                       "decode": "gather_einsum"},
                              "linear": state}
             assert leaves == ["conv_tail", "k", "state", "v"]
+        elif "ssm" in kinds:
+            # blocks of ONE mixer: a state kind of slot leaves alone IN
+            # PLACE of attention, a K/V kind for the attention blocks,
+            # nothing for the expert blocks — which `components.blocks`
+            # counts, with what the experts are handed
+            assert kinds == {"full": {"prefill": "plain",
+                                      "decode": "gather_einsum"},
+                             "ssm": state}
+            assert tables["full"] == {"window": None, "rotation": None}
+            assert leaves == ["conv_tail", "k", "ssm_state", "v"]
+            assert comps["kv_cache"]["kinds"] == {
+                "full": {"leaves": ["k", "v"], "slot_leaves": [],
+                         "tables": "tables"},
+                "ssm": {"leaves": [], "tables": None,
+                        "slot_leaves": ["conv_tail", "ssm_state"]}}
+            blocks = comps["blocks"]
+            assert blocks["kinds"] == {"ssm": 3, "experts": 3, "full": 1}
+            assert blocks["moe"] == {"latent_size": 32, "picks": 6,
+                                     "held": 4, "of": 16}
+            m = served(config)["metrics"]
+            assert m["state_pool_installs_total"] > 0
+            # rows through W_down: a chunk's 16 positions, a step's 4
+            # slots, three expert blocks each
+            for program, rows in (("prefill", 16), ("decode", 4)):
+                calls = m[f'moe_layer_calls_total{{program="{program}"}}']
+                assert m[f'moe_latent_rows_total{{program="{program}"}}'] \
+                    == calls * rows > 0
         elif "full" in kinds:  # ONE kind: paged K and V AND slot leaves
             assert kinds == {"full": {
                 "prefill": "plain", "decode": "gather_einsum",

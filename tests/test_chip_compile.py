@@ -1419,3 +1419,142 @@ def test_mellum_chunk_program_fits_beside_the_pool(mellum_programs):
     assert _pool_extent_ops(chunk, pool_w) == []
     assert chunk.as_text().count("tpu_custom_call") >= 2
     assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+# ----------------------------------------------------------------------
+# ISSUE 66 — blocks of ONE mixer: a state kind of slot leaves alone, a full
+# kind of one layer, expert blocks that keep nothing, experts in a latent
+# (Nemotron-3-Super)
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def nemotron_programs(chip):
+    """The Nemotron-3-Super cut's step programs at its published widths and
+    the cell's pool (64 slots of state and of 10 240 positions, 1024-token
+    chunks), depth cut to M E M * E (every kind, two runs of the state and
+    of the expert stack), 16 of the 512 experts held (each 11 MB: the
+    stack's extent is not what is held here) and the vocabulary to 8192
+    rows. -> ({name: compiled}, the state leaf's extent a layer, the K
+    leaf's, the pool's bytes, the held expert stacks' shapes)."""
+    import dataclasses
+
+    from dnn_tpu.models import llama_moe
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.registry import ParamParts
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = dataclasses.replace(
+        llama_moe.PRESETS["nemotron-3-super-120b-a12b-ep4-1chip"], n_layer=5,
+        layer_types=llama_moe.pattern_types("MEM*E"), vocab_size=8192,
+        experts_held=16)
+    prepared = _stack_and_release(
+        ParamParts(llama_moe.init_parts(jax.random.PRNGKey(0), cfg)), cfg,
+        BF16)  # a block at a time, as the daemon boots
+    b = ContinuousBatcher(
+        cfg, prepared, slots=64, max_len=10240, prompt_pad=1024, kv="auto",
+        family=llama_moe.family_rows(cfg, compute_dtype=BF16))
+    assert b._paged and b._allocator is not None and b._moe_stats
+    assert b.cache["ssm_state"].shape == (2, 64, 128, 64, 128)
+    assert b.cache["ssm_state"].dtype == jnp.float32
+    assert b.cache["conv_tail"].shape == (2, 64, 3, 10240)
+    assert b.cache["k"].shape == (1, 64 * 640 + 1, 2, 16, 128)
+    stacks = {n: prepared["expert_blocks"]["moe"][n].shape
+              for n in ("wi", "wo")}
+    assert stacks == {"wi": (2, 16, 1024, 2688), "wo": (2, 16, 2688, 1024)}
+    compiled = _lower_programs(
+        chip, [(b, ("_prefill_chunk", "_prefill_finish", "_decode"))])
+    return (compiled, b.cache["ssm_state"].shape[1:], b.cache["k"].shape[1:],
+            sum(x.nbytes for x in b.cache.values()), stacks)
+
+
+def _stack_extent_ops(compiled, shape):
+    return _extent_ops(compiled, re.compile(
+        r"\[(?:\d+,)?%d,%d,%d\]" % tuple(shape[1:])))
+
+
+def test_nemotron_decode_step_reaches_state_blocks_and_experts_in_place(
+        nemotron_programs):
+    """One decode step runs the one-token rule's kernel (ops/pallas/
+    ssm_step.py at P = 64, N = 128), the paged kernel over the ONE attention
+    block's pool and the grouped matmuls over the held stacks, each in
+    place: nothing of a layer's states' extent but the step kernel's aliased
+    result, nothing of the K/V leaves' but the paged kernel's, no operation
+    whose result is an expert stack's or a layer's slice of one; the donated
+    leaves are the program's results."""
+    compiled, state, pool, pool_bytes, stacks = nemotron_programs
+    step = compiled["_decode"]
+    text = step.as_text()
+    assert "ssm_step" in text and "grouped_matmul" in text
+    assert {o[0] for o in _pool_extent_ops(step, pool)} <= {"custom-call"}
+    # the kernel reads the leaf FOLDED, two heads of 64 a (128, 128) tile:
+    # the same bytes (a bitcast either way), so the leaf's extent as the
+    # pool holds it appears in no operation and the folded one in the
+    # kernel's aliased result alone
+    slots, h, p, n = state
+    assert _state_extent_ops(step, state) == []
+    ops = _state_extent_ops(step, (slots, h // 2, 2 * p, n))
+    assert ops and {o[0] for o in ops} == {"custom-call"}, ops
+    for shape in stacks.values():
+        assert _stack_extent_ops(step, shape) == []
+    mem = step.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < int(np.prod(state)) * 4 // 2
+
+
+def test_nemotron_finish_installs_blocks_and_state_without_a_pool_copy(
+        nemotron_programs):
+    compiled, state, pool, pool_bytes, _ = nemotron_programs
+    finish = compiled["_prefill_finish"]
+    for ops in (_state_extent_ops(finish, state),
+                _pool_extent_ops(finish, pool)):
+        assert {o[0] for o in ops} <= {"dynamic-update-slice", "fusion",
+                                       "scatter"}, ops
+    mem = finish.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < 2 ** 26
+
+
+def test_nemotron_chunk_program_fits_beside_the_pool(nemotron_programs):
+    """The chunk program works on the transient row alone (K and V of 10 240
+    positions of one layer, one slot's state and tail a state layer): the
+    prefill kernel, the grouped matmuls at K = 1024 / N = 2688 and back and
+    the rounds' row_accumulate; no expert stack is cut or copied and its
+    temporaries stay under 1 GB."""
+    compiled, state, pool, _, stacks = nemotron_programs
+    chunk = compiled["_prefill_chunk"]
+    text = chunk.as_text()
+    assert _state_extent_ops(chunk, state) == []
+    assert _pool_extent_ops(chunk, pool) == []
+    assert "grouped_matmul" in text and "row_accumulate" in text
+    for shape in stacks.values():
+        assert _stack_extent_ops(chunk, shape) == []
+    assert chunk.memory_analysis().temp_size_in_bytes < 2 ** 30
+
+
+def test_ssm_step_kernel_compiles_at_the_nemotron_heads(chip):
+    """ops/pallas/ssm_step.py on the cell's whole state leaf of five layers
+    (1.34 GB) at P = 64, N = 128, 16 heads a group, folded two heads a
+    tile: the leaf is the call's aliased result, nothing else of its size
+    exists (the fold's reshapes are bitcasts) and both transposes are 128 x
+    128."""
+    from dnn_tpu.ops.pallas.ssm_step import ssm_step
+
+    b, h, g, p, n = 64, 128, 8, 64, 128
+    pool = (5, b, h, p, n)
+    shapes = ((pool, F32), ((), jnp.int32), ((b, h), F32), ((b, h), F32),
+              ((h,), F32), ((b, h, p), F32), ((b, g, n), F32),
+              ((b, g, n), F32))
+    fn = functools.partial(ssm_step, interpret=False)
+    call, = _kernel_calls(fn, shapes)
+    eqns = _eqns(call.params["jaxpr"])
+    turned = [e.invars[0].aval.shape for e in eqns
+              if e.primitive.name == "transpose"]
+    assert turned == [(128, 128), (128, 128)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=chip) for s, t in shapes]
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"f32\[5,64,(128,64|64,128),128\]\S* copy\(", text)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= int(np.prod(pool)) * 4
+    assert mem.temp_size_in_bytes < 2 ** 23

@@ -128,6 +128,32 @@ def test_the_published_model_and_its_cut():
     assert leaves["conv_tail"] == ((3, 5120), None)
 
 
+def test_the_rule_is_still_beside_attention_with_its_multipliers(model):
+    """ISSUE 66 moved the rule's kind and `beside` into the config
+    (`Mamba2Config.beside`, `state_kind.Rule.resolve`): Falcon-H1's stays
+    the "full" kind's, BESIDE attention, under its multipliers; the same
+    widths with `beside` False resolve to the rule in attention's place, a
+    kind of its own."""
+    _, cfg, _ = model
+    rule = state_kind.config_rule(cfg)
+    assert cfg.mamba.beside and not cfg.one_mixer
+    assert rule is mamba2.RULE and state_kind.layer_rule(cfg, None) is rule
+    assert (rule.kind, rule.beside, rule.forms) == (
+        "full", mamba2.mixers_sum, ("ssm_prefill", "ssm_decode"))
+    attn_o, ssm_o = jnp.full((1, 2, 4), 3.0), jnp.full((1, 2, 4), 2.0)
+    want = cfg.mup.attention_out * 3.0 + cfg.mamba.ssm_out * 2.0
+    assert float(jnp.abs(rule.beside(attn_o, ssm_o, cfg) - want).max()) < 1e-6
+    assert mamba2._mup_vector(cfg.mamba) is not None  # they are traced
+    assert list(llama.family_rows(cfg).cache_kinds) == ["full"]
+    alone = state_kind.config_rule(dataclasses.replace(
+        cfg, mamba=dataclasses.replace(cfg.mamba, beside=False)))
+    assert alone is mamba2.RULE_ALONE
+    assert (alone.kind, alone.beside, alone.forms) == (
+        "ssm", None, ("prefill", "decode"))
+    assert (alone.chunk, alone.step, alone.slot_leaves) == (
+        rule.chunk, rule.step, rule.slot_leaves)
+
+
 def test_a_config_refuses_what_the_block_was_not_built_for():
     base = llama.PRESETS["falcon-h1-test"]
     for wrong in (dict(sliding_window=8), dict(attn_softcap=30.0),

@@ -480,10 +480,11 @@ def test_worker_streams_interleaved_and_overlap_tokens(model):
 #: all (Brumby's state: nothing paged, no allocator), ONE kind with
 #: paged K and V AND a state a slot in every layer (Falcon-H1's), and a
 #: STRIDED leaf beside K and V read by lists of blocks, with a state kind of
-#: one leaf (MiniCPM-SALA's)
+#: one leaf (MiniCPM-SALA's), and blocks of ONE mixer whose expert blocks
+#: keep nothing (Nemotron-H's)
 FAMILIES = ["gpt2-test", "olmoe-test", "keye-test", "joyai-test",
             "dots3-test", "k-exaone-test", "solar-open2-test", "brumby-test",
-            "falcon-h1-test", "minicpm-sala-test"]
+            "falcon-h1-test", "minicpm-sala-test", "nemotron-h-test"]
 _BUILT: dict = {}
 
 
@@ -750,7 +751,7 @@ def test_the_daemon_pipelines_by_default(model):
 @pytest.mark.parametrize("name", ["keye-test", "joyai-test", "dots3-test",
                                   "k-exaone-test", "solar-open2-test",
                                   "brumby-test", "falcon-h1-test",
-                                  "minicpm-sala-test"])
+                                  "minicpm-sala-test", "nemotron-h-test"])
 def test_interleaved_admission_stays_refused_by_name(name):
     """A family that lives in the paged pool alone still refuses the
     mixed step, by name, with or without the pipeline — which it takes."""
