@@ -382,8 +382,10 @@ def check_chunk_program(name, jit_fn, args, state_leaves=()
     axis, models/state_kind.py) IS its layer's whole content and is written
     whole at the layer's index."""
     row = args[1]
+    # shapes a caller describes (for a device it names) stay as they are
     avals = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(jnp.shape(x), x.dtype), args)
+        lambda x: x if isinstance(x, jax.ShapeDtypeStruct)
+        else jax.ShapeDtypeStruct(jnp.shape(x), x.dtype), args)
     text = jit_fn.lower(*avals).as_text()
     aliased, expected = count_aliased(text), len(jax.tree.leaves(row))
     where = f"runtime/serving.prefill_chunk[{name}]"

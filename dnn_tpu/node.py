@@ -262,8 +262,14 @@ def build_parser() -> argparse.ArgumentParser:
                         "instead of max_len (runtime/decode_buckets.py; "
                         "dense pools only)")
     p.add_argument("--prompt_pad", type=int, default=None,
-                   help="--serve_lm: prompt padding bucket (one prefill "
-                        "compilation; default min(64, max_len))")
+                   help="--serve_lm: prompt padding bucket and the width "
+                        "of the prefill chunk (one prefill compilation). "
+                        "Default: the chip's ridge — peak FLOP/s x the "
+                        "compute dtype's itemsize / (2 x peak HBM bytes/s) "
+                        "tokens rounded up to a power of two, 256 for "
+                        "bfloat16 on a v5e (runtime/serving.ridge_pad) — "
+                        "and min(64, max_len) off the TPU; replicas that "
+                        "hand K/V to each other need the same one")
     p.add_argument("--weights", choices=["f32", "int8"], default="f32",
                    help="--serve_lm: served weight precision. 'int8' "
                         "quantizes the model ONCE at startup (symmetric "

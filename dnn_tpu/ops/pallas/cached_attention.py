@@ -272,6 +272,24 @@ def _kernel_call(q3, k3, v3, pos1d, ks3, vs3, *, block_q, block_s, interpret,
 # attn.paged_decode) and the kernels' `name=` are what a device trace
 # calls this file's work (the HLO op_name path; the custom call's own
 # name) — chipbench/spans.py reads them, so renaming one moves a metric.
+def chunk_tiles(t: int, s_len: int) -> dict:
+    """`block_q` / `block_s` for `t` queries over a row of `s_len` positions
+    whose rows are NOT folded (the GPT codec's calls, `kvcache.attend`):
+    the largest row tile up to 256 that divides the queries (a chunk under
+    128 is its own tile) and the largest column tile up to 1024 that
+    divides the row. A grid step of this kernel costs ~0.3 us on a v5e
+    whatever it holds — every column tile is fetched, a dead one's compute
+    alone is skipped — and a (128, 128) tile of 64-wide heads is ~0.02 us
+    of products: a 256-query launch over GPT-2 Large's 1024-position row
+    is 320 steps a layer at (128, 128) and 20 at (256, 1024), 10.0 -> 5.3
+    ms a launch at start 768 (PERF.md section 6, PR 67). Same float32
+    operands and accumulation; the column tile sets the order in which the
+    running softmax meets the columns."""
+    return {"block_q": 256 if t % 256 == 0 else 128,
+            "block_s": next((n for n in (1024, 512, 256, 128)
+                             if s_len % n == 0), 128)}
+
+
 def cached_attention(q, k, v, pos, *, window=None, **kw):
     """`_cached_attention` under the scope `attn.prefill` — or, with a
     `window`, under none of its own: a window kind's read lies in its

@@ -239,7 +239,7 @@ class FloatKV(_KernelDispatch):
         if (self._kernel_on(c) and base is not None and self.window is None
                 and window is None and self.softcap is None):
             from dnn_tpu.ops.pallas.cached_attention import (
-                cached_attention, decode_attention,
+                cached_attention, chunk_tiles, decode_attention,
             )
 
             pos_b = jnp.broadcast_to(base, (q.shape[0],))
@@ -251,8 +251,9 @@ class FloatKV(_KernelDispatch):
                     q, c["k"], c["v"], pos_b,
                     interpret=self._interp()).astype(c["v"].dtype)
             return cached_attention(
-                q, c["k"], c["v"], pos_b,
-                interpret=self._interp()).astype(c["v"].dtype)
+                q, c["k"], c["v"], pos_b, interpret=self._interp(),
+                **chunk_tiles(q.shape[2], c["k"].shape[2])
+            ).astype(c["v"].dtype)
         d = q.shape[-1]
         s = jnp.einsum("bhtd,bhsd->bhts", q, c["k"]).astype(jnp.float32) / jnp.sqrt(d)
         s = self._cap(s)
@@ -382,7 +383,7 @@ class Int8KV(_KernelDispatch):
         if (self._kernel_on(c) and base is not None and self.window is None
                 and window is None and self.softcap is None):
             from dnn_tpu.ops.pallas.cached_attention import (
-                cached_attention, decode_attention,
+                cached_attention, chunk_tiles, decode_attention,
             )
 
             pos_b = jnp.broadcast_to(base, (q.shape[0],))
@@ -392,7 +393,8 @@ class Int8KV(_KernelDispatch):
                     interpret=self._interp())
             return cached_attention(
                 q, c["k"], c["v"], pos_b,
-                ks=c["ks"], vs=c["vs"], interpret=self._interp())
+                ks=c["ks"], vs=c["vs"], interpret=self._interp(),
+                **chunk_tiles(q.shape[2], c["k"].shape[2]))
         d = q.shape[-1]
         # scores in f32; the per-position K scale lands on the score matrix
         # (commutes with the D contraction)

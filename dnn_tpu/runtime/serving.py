@@ -20,7 +20,11 @@ programs total — chunk prefill, prefill finish, decode step):
   * ONE prefill-chunk program: prompts prefill as full prompt_pad-sized
     chunks plus one right-padded tail, each at its absolute position —
     any prompt length (up to max_len - max_new) reuses the same compiled
-    chunk. Tail pad positions write garbage K/V that is never attended
+    chunk. Where no `prompt_pad` is asked for it is the chip's ridge
+    (`ridge_pad`: the tokens a launch must hold before its matmuls cost
+    more than streaming the block weights once — below it a launch costs
+    that stream whatever rows it holds). Tail pad positions write garbage
+    K/V that is never attended
     (the per-row position mask stops at the true length) and is
     overwritten as the sequence grows through it; a second small program
     FINISHES AND INSTALLS: it derives the request's rng stream, samples
@@ -60,6 +64,7 @@ from dnn_tpu import obs
 from dnn_tpu.obs import profile as _profile
 from dnn_tpu.obs.profile import annotation_ctx as _prof_annotation
 from dnn_tpu.models.gpt import GPTConfig, head
+from dnn_tpu.utils import flops
 from dnn_tpu.utils.metrics import Throughput, labeled
 from dnn_tpu.ops.attention import merge_heads
 from dnn_tpu.ops.nn import gelu, layer_norm, linear
@@ -274,11 +279,34 @@ def _mask_rows(ctable, crow, vocab: int):
     return unpack_mask_table(words, vocab, jnp)
 
 
+def ridge_pad(itemsize: int, max_len: int, positions: int) -> Optional[int]:
+    """The `prompt_pad` of a batcher that is asked for none, on a chip
+    that states its peaks: the chip's ridge in tokens — `device_peak_flops
+    x itemsize / (2 x device_peak_hbm_bw)`, the rows a matmul over weights
+    of `itemsize` bytes must hold before it costs the MXU more than the
+    weights' stream costs the HBM (241 for bfloat16 on a v5e) — rounded up
+    to a power of two, at most `max_len`. Below the ridge a chunk
+    launch costs its weight stream whatever rows it holds, so a prompt is
+    cheapest in the fewest launches. None off the TPU (no peaks), and
+    where a row of whole such chunks would reach past the model's
+    `positions` (a learned position table has no row there, and its fill
+    value is a NaN no mask hides)."""
+    peak, bw = flops.device_peak_flops(), flops.device_peak_hbm_bw()
+    if peak is None or bw is None:
+        return None
+    pad = 1
+    while pad < peak * itemsize / (2 * bw):
+        pad *= 2
+    pad = min(pad, max_len)
+    return pad if -(-max_len // pad) * pad <= positions else None
+
+
 class ContinuousBatcher:
     """Slot-pool decode server. `slots` concurrent sequences over one
     static cache of `max_len` positions; prompts prefill in
     `prompt_pad`-sized chunks (one prefill compilation for all requests,
-    any prompt length).
+    any prompt length; by default the chip's ridge, `ridge_pad`, and
+    min(64, max_len) off the TPU).
 
     Usage:
         srv = ContinuousBatcher(cfg, prepared, slots=4, max_len=96)
@@ -336,7 +364,6 @@ class ContinuousBatcher:
         self._decode_view = None
         self._pf_views: dict = {}  # aid -> memoized single-row prefill view
         self.max_len = min(max_len or cfg.block_size, cfg.block_size)
-        self.prompt_pad = prompt_pad or min(64, self.max_len)
         self.eos_id = eos_id
         self._seed = seed
         # constructor values become the per-request DEFAULTS; submit() may
@@ -375,6 +402,11 @@ class ContinuousBatcher:
         self.family = family or GPTFamilyRows(
             cfg, compute_dtype=compute_dtype, ffn=ffn,
             attn_kernel=attn_kernel)
+        # the pad no caller chose follows the chip: the ridge of the dtype
+        # the blocks' matmuls stream their weights in (`ridge_pad`)
+        self.prompt_pad = prompt_pad or ridge_pad(
+            jnp.dtype(compute_dtype or jnp.float32).itemsize, self.max_len,
+            cfg.block_size) or min(64, self.max_len)
         # a family whose cache is not K and V alone (models/dsa.py: the
         # index key of every position as a third leaf; models/mla.py: ONE
         # compressed latent a position) serves from the paged pool alone
@@ -927,6 +959,14 @@ class ContinuousBatcher:
         # or a rounding error against 1e6 misses)
         self.prefix_evictions = 0
         self.prefill_chunks_run = 0  # chunk programs actually executed
+        # scrape-time: the launches and the positions they held, pad
+        # included, under the width the batcher launches (`prompt_pad`,
+        # which the code may have chosen: `ridge_pad`)
+        for what, each in (("launches", 1), ("positions", self.prompt_pad)):
+            self._obs_gauges[labeled(
+                f"serving.prefill_chunk_{what}_total",
+                width=self.prompt_pad)] = _weak_gauge(
+                    "_chunks_run_read", each)
         if self._prefix_cache is not None or self._prefix_store is not None:
             # scrape-time effectiveness ratio (ROADMAP item 2's metric):
             # hits / (hits + misses) over the pool's lifetime, weakly
@@ -1984,13 +2024,18 @@ class ContinuousBatcher:
                     # compiled shape
                     hidden = self._row_hidden(hit_entry[1], p_pad - 1)
             pf_prepared = self._lora_prefill_view(aid)
-            sp_pf = adm.child("prefill", chunks=n_chunks - start_chunk,
+            # the admission's launches and the positions they hold, pad
+            # included
+            n_launch = n_chunks - start_chunk
+            sp_pf = adm.child("prefill", chunks=n_launch,
+                              positions=n_launch * p_pad,
                               prompt_len=len(prompt))
             t_pf = time.perf_counter()  # the PREFILL interval only —
             # submit-entry-to-here is validation/slot/host bookkeeping,
             # which belongs to the admit span, not this metric
             _sp = _profile.open_span("admit.prefill", rid=rid,
-                                     chunks=n_chunks - start_chunk)
+                                     chunks=n_launch,
+                                     positions=n_launch * p_pad)
             chunks_before = self.prefill_chunks_run
             last_local = (len(prompt) - 1) % p_pad
             kv_boundary_rows: dict = {}
@@ -3268,6 +3313,9 @@ class ContinuousBatcher:
 
     def _kind_used_read(self, tables: str) -> float:
         return float(self._allocator.of(tables).n_used)
+
+    def _chunks_run_read(self, each: int) -> float:
+        return float(self.prefill_chunks_run * each)
 
     def _pipelined_read(self) -> float:
         return float(self.steps_pipelined)

@@ -10,6 +10,7 @@ attn_kernel="interpret") with token parity against the einsum path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from dnn_tpu.models import gpt
 from dnn_tpu.ops.pallas.cached_attention import (
@@ -65,6 +66,47 @@ def test_kernel_prefill_chunk_at_dynamic_start():
         got = cached_attention(q, k, v, pos, interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=1e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("t,s_len,tiles", [
+    (256, 1024, (256, 1024)),  # GPT-2 Large's chunk at a v5e's ridge
+    (64, 1024, (128, 1024)), (5, 1024, (128, 1024)),  # own tile under 128
+    (512, 2048, (256, 1024)), (384, 1536, (128, 512)),
+    (256, 640, (256, 128)), (128, 100, (128, 128))])  # no tiling: the plain form
+def test_chunk_tiles(t, s_len, tiles):
+    from dnn_tpu.ops.pallas.cached_attention import chunk_tiles
+
+    assert chunk_tiles(t, s_len) == dict(zip(("block_q", "block_s"), tiles))
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["float", "int8"])
+@pytest.mark.parametrize("t,start", [(256, 0), (256, 256), (256, 768),
+                                     (64, 0), (64, 448), (5, 700)])
+def test_kernel_chunk_tiles_at_dynamic_start(t, start, quant):
+    """ISSUE 67: the GPT codec's tiles (`chunk_tiles`) — the whole
+    1024-position row in ONE column tile, heads of 64 — for a ridge-wide
+    chunk, a narrow one and a verify's few rows, at the row's start,
+    inside it and at its end, float and int8 with scales: the plain
+    form's rows."""
+    from dnn_tpu.ops.pallas.cached_attention import chunk_tiles
+
+    B, H, S, D = 1, 2, 1024, 64
+    q = _rand((B, H, t, D))
+    pos = jnp.full((B,), start, jnp.int32)
+    if quant:
+        k, v = (jnp.asarray(RNG.integers(-127, 128, (B, H, S, D)), jnp.int8)
+                for _ in range(2))
+        scales = dict(zip(("ks", "vs"), (jnp.asarray(
+            RNG.uniform(0.005, 0.02, (B, H, S)), jnp.float32)
+            for _ in range(2))))
+    else:
+        k, v, scales = _rand((B, H, S, D)), _rand((B, H, S, D)), {}
+    want = reference_cached_attention(q, k, v, pos, **scales)
+    got = cached_attention(q, k, v, pos, interpret=True, **scales,
+                           **chunk_tiles(t, S))
+    tol = 1e-4 if quant else 1e-5
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=tol, rtol=tol)
 
 
 def test_kernel_nontiling_falls_back():

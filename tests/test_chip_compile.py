@@ -20,6 +20,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or the compiler logs to /tmp
 # all but the first fail to describe the topology and skip
 os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
+import contextlib
 import functools
 import re
 
@@ -74,6 +75,7 @@ OLMOE = (16, 16, 1, 128)
 KEYE = (16, 4, 8, 128)
 GROUPED = (64, 8, 8, 128)
 BLOCK_LEN, CTX = 16, 1024
+RIDGE = 256  # GPT-2 Large's prefill chunk on a v5e: its ridge (ISSUE 67)
 MASK_ROWS = 3600  # the daemon's constraint pools (lm_server.py)
 
 # (shape, pool dtype, block_len, positions a slot): the pool holds its rows
@@ -299,6 +301,26 @@ def test_flash_attention_compiles(chip, shape, grad):
     _compile(chip, fn, *[(shape, BF16)] * 3)
 
 
+def _large_convoy(chip, **kw):
+    """(cfg, prepared, batcher): GPT-2 Large's widths at 2 layers behind
+    the daemon's defaults, built while the default device states the
+    described chip's peaks: asked for no `prompt_pad`, the batcher takes a
+    v5e's ridge (241 tokens for bfloat16, `serving.ridge_pad`)."""
+    from dnn_tpu.models import gpt
+    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.runtime.serving import ContinuousBatcher
+
+    cfg = gpt.GPTConfig(n_layer=2, n_embd=1280, n_head=20)
+    # as the daemon holds it: matmul kernels and the head in bfloat16
+    prepared = _stack_and_release(gpt.init(jax.random.PRNGKey(0), cfg), cfg,
+                                  BF16)
+    with pytest.MonkeyPatch.context() as m:
+        _described_peaks(m, chip)
+        convoy = ContinuousBatcher(cfg, prepared, slots=16,
+                                   compute_dtype=BF16, kv="auto", **kw)
+    return cfg, prepared, convoy
+
+
 @pytest.fixture(scope="module")
 def step_programs(chip):
     """The daemon's step programs compiled for the chip — the whole
@@ -312,16 +334,13 @@ def step_programs(chip):
     (the backend answers "tpu" while lowering), not through an option of
     the program. -> {name: compiled}, the pool's (n_blocks, H, block_len,
     Dp) and its bytes."""
-    from dnn_tpu.models import gpt
-    from dnn_tpu.node import _stack_and_release
+    from dnn_tpu.analysis.program import chunk_args
     from dnn_tpu.runtime.serving import ContinuousBatcher
 
-    cfg = gpt.GPTConfig(n_layer=2, n_embd=1280, n_head=20)
-    # as the daemon holds it: matmul kernels and the head in bfloat16
-    prepared = _stack_and_release(gpt.init(jax.random.PRNGKey(0), cfg), cfg,
-                                  BF16)
-    convoy = ContinuousBatcher(cfg, prepared, slots=16, compute_dtype=BF16,
-                               kv="auto")
+    cfg, prepared, convoy = _large_convoy(chip)
+    assert (convoy.prompt_pad, convoy._row_len) == (RIDGE, CTX)
+    # and the chunk a `--prompt_pad 64` deployment launches
+    narrow = _large_convoy(chip, prompt_pad=64)[2]
     # the interleaved batcher with the per-request capabilities the daemon
     # compiles in (node.py: bias and constraints on; lm_server.py: a mask
     # pool of MASK_ROWS rows), so that its programs carry the pools
@@ -333,11 +352,24 @@ def step_programs(chip):
     compiled = _lower_programs(chip, [
         (convoy, ("_prefill_chunk", "_prefill_finish", "_decode")),
         (mixed, ("_mixed", "_ilv_finish=_prefill_finish",
-                 "_decode_constrained=_decode"))])
+                 "_decode_constrained=_decode"))],
+        more={"_prefill_chunk_64": (
+            narrow._prefill_chunk, _shapes(chunk_args(narrow)))})
     pool_bytes = sum(x.nbytes for x in jax.tree.leaves(convoy.cache)
                      if x.ndim > 3)
     return (compiled, convoy.cache["k"].shape[1:], pool_bytes,
             _held_weight_shapes(prepared))
+
+
+def _shapes(args):
+    """`args` with every array as its shape and dtype."""
+    def shape(x):
+        if isinstance(x, (jax.Array, np.ndarray)):
+            return jax.ShapeDtypeStruct(
+                x.shape, x.dtype, weak_type=getattr(x, "weak_type", False))
+        return x
+
+    return jax.tree.map(shape, args)
 
 
 def first_calls(batchers, prompt_len=69):
@@ -355,12 +387,6 @@ def first_calls(batchers, prompt_len=69):
     `_prefill_finish`, as an INTERLEAVED admission calls it."""
     calls = {}
 
-    def shape(x):
-        if isinstance(x, (jax.Array, np.ndarray)):
-            return jax.ShapeDtypeStruct(
-                x.shape, x.dtype, weak_type=getattr(x, "weak_type", False))
-        return x
-
     def zeros(s):
         assert not s.weak_type, s  # a result fed back would change type
         return jnp.zeros(s.shape, s.dtype)
@@ -370,7 +396,7 @@ def first_calls(batchers, prompt_len=69):
 
         def call(*args):
             if name is not None:
-                calls.setdefault(name, (fn, jax.tree.map(shape, args)))
+                calls.setdefault(name, (fn, _shapes(args)))
             return jax.tree.map(zeros, jax.eval_shape(fn, *args))
 
         setattr(b, attr, call)
@@ -392,28 +418,48 @@ def first_calls(batchers, prompt_len=69):
     return calls
 
 
-def _lower_programs(chip, batchers):
+def _described_peaks(m, chip):
+    """While `m` lasts the default device's peaks are the described
+    chip's (`utils/flops`'s own tables): a batcher built meanwhile reckons
+    its `prompt_pad` (`serving.ridge_pad`) as it does on the chip."""
+    from dnn_tpu.utils import flops
+
+    device = next(iter(chip.device_set))
+    for name in ("device_peak_flops", "device_peak_hbm_bw"):
+        m.setattr(flops, name, functools.partial(getattr(flops, name),
+                                                 device))
+
+
+def _lower_programs(chip, batchers, more=None):
     """{name: compiled for the chip} of the named step programs of each
     (batcher, names), each lowered from its first real call's arguments
-    (`first_calls`) with the backend answering "tpu"."""
+    (`first_calls`) with the backend answering "tpu"; `more`: further
+    {name: (jitted program, arguments)} compiled the same way."""
     def described(x):
         if isinstance(x, jax.ShapeDtypeStruct):
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip,
                                         weak_type=x.weak_type)
         return x
 
-    calls = first_calls(batchers)
+    calls = {**first_calls(batchers), **(more or {})}
     compiled = {}
-    jax.clear_caches()  # the traces the requests made with the kernels off
-    with pytest.MonkeyPatch.context() as m:
-        m.setattr(jax, "default_backend", lambda: "tpu")
-        # and its devices are the described chip's: a chunk program carries
-        # its row in the layout the DEVICE holds it in (`scan_rows`)
-        m.setattr(jax, "devices", lambda *a: list(chip._device_assignment))
+    with _answering_as(chip):
         for name, (fn, args) in calls.items():
             compiled[name] = fn.lower(*jax.tree.map(described, args)).compile()
-    jax.clear_caches()  # drop the traces made under the patch
     return compiled
+
+
+@contextlib.contextmanager
+def _answering_as(chip):
+    """While it lasts the backend answers "tpu" (`_kernel_on`) and its
+    devices are the described chip's: a chunk program carries its row in
+    the layout the DEVICE holds it in (`scan_rows`)."""
+    jax.clear_caches()  # the traces made so far, with the kernels off
+    with pytest.MonkeyPatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        m.setattr(jax, "devices", lambda *a: list(chip._device_assignment))
+        yield
+    jax.clear_caches()  # drop the traces made under the patch
 
 
 @pytest.fixture(scope="module")
@@ -474,19 +520,21 @@ def _extent_ops(compiled, extent):
 
 
 @pytest.mark.parametrize("name,kernel", [
-    ("_prefill_chunk", True), ("_prefill_finish", False), ("_decode", True),
+    ("_prefill_chunk", True), ("_prefill_chunk_64", True),
+    ("_prefill_finish", False), ("_decode", True),
     ("_mixed", True), ("_ilv_finish", False)])
 def test_serving_step_programs_compile_with_the_kernels(step_programs, name,
                                                         kernel):
     compiled, _, pool_bytes, _ = step_programs
     assert ("tpu_custom_call" in compiled[name].as_text()) == kernel
-    if name != "_prefill_chunk":  # it works on the transient row
+    if not name.startswith("_prefill_chunk"):  # on the transient row
         # the donated pool aliases the program's result
         assert compiled[name].memory_analysis().alias_size_in_bytes \
             >= pool_bytes
 
 
-def test_chunk_program_writes_its_row_in_place(step_programs):
+@pytest.mark.parametrize("name", ["_prefill_chunk", "_prefill_chunk_64"])
+def test_chunk_program_writes_its_row_in_place(step_programs, name):
     """ISSUE 63: the chunk program carries its donated transient row through
     the layer loop (`paged_kvcache.scan_rows`) in the layout the device
     holds it in — positions minor-most for GPT-2's heads of 64 — and writes
@@ -495,7 +543,7 @@ def test_chunk_program_writes_its_row_in_place(step_programs):
     once a chunk, `copy.55` on the chip; carried in the layout the kernel
     reads, the compiler transposed it whole into the loop and out again),
     the row aliased to the result and no temporary of a leaf's size."""
-    chunk = step_programs[0]["_prefill_chunk"]
+    chunk = step_programs[0][name]
     row = re.compile(r"\[2,1,20,%d,64\]" % CTX)
     assert sorted(op for op, _ in _extent_ops(chunk, row)) == [
         "dynamic-update-slice"] * 2
@@ -503,6 +551,28 @@ def test_chunk_program_writes_its_row_in_place(step_programs):
     mem = chunk.memory_analysis()
     assert mem.alias_size_in_bytes >= 2 * leaf
     assert mem.temp_size_in_bytes < leaf
+
+
+def test_ridge_chunk_program_moves_a_chunks_positions(chip):
+    """ISSUE 67: `analysis/program.check_chunk_program` on GPT-2 Large's
+    256-token launch as it is lowered for the chip (the prefill kernel at
+    64-wide heads and 256 queries, the row in the layout the device holds):
+    both leaves of the donated row alias a result and nothing but a
+    chunk's positions is moved — no whole-row copy or transpose at the
+    loop's edges."""
+    from dnn_tpu.analysis.program import check_chunk_program, chunk_args
+
+    _, _, convoy = _large_convoy(chip)
+    args = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=chip),
+        _shapes(chunk_args(convoy)))
+    assert args[2].shape == (1, RIDGE)
+    with _answering_as(chip):
+        report, findings = check_chunk_program(
+            "gpt2-large@256", convoy._prefill_chunk, args)
+    assert findings == []
+    assert report["aliased"] == report["expected"] == 2
+    assert report["cache_sized_ops"] == {}
 
 
 @pytest.mark.parametrize("name", ["_decode_constrained", "_mixed",
